@@ -155,7 +155,7 @@ def audit_entry_point(ep: EntryPoint) -> List[Finding]:
                                 f"entry point '{ep.name}': {message}"))
 
     top_pjit = [eqn for eqn in closed.jaxpr.eqns
-                if eqn.primitive.name == "pjit"]
+                if eqn.primitive.name == "jit"]
 
     for eqn, in_scope in _iter_eqns(closed.jaxpr):
         name = eqn.primitive.name

@@ -77,7 +77,7 @@ RULES: Dict[str, str] = {
 }
 
 #: collectives that move bytes on the wire. ``pbroadcast`` is excluded:
-#: shard_map's check_rep rewrite inserts it as zero-wire replication
+#: shard_map's check_vma rewrite inserts it as zero-wire replication
 #: bookkeeping (including into cond branches with no collectives), so
 #: counting it would make every benign cond look divergent.
 WIRE_COLLECTIVES = frozenset(_COLLECTIVE_PRIMS - {"pbroadcast"})
@@ -267,10 +267,10 @@ def _visit(jaxpr: Any, axis_sizes: Dict[str, int], scope: str,
                            f"{scope}/while" if scope else "while",
                            None, ops, defects)
             continue
-        # pjit is transparent; other higher-order prims (remat, custom
+        # jit is transparent; other higher-order prims (remat, custom
         # vjp/jvp, ...) contribute their lexical name to the scope path
         inner_scope = scope
-        if prim != "pjit":
+        if prim != "jit":
             inner_scope = f"{scope}/{prim}" if scope else prim
         for sub in _subjaxprs(eqn.params):
             _visit(sub, axis_sizes, inner_scope, trips, ops, defects)

@@ -36,6 +36,45 @@ def _csrc_path() -> str:
         "data_loader.cpp")
 
 
+def _so_path() -> str:
+    return os.path.join(os.path.dirname(_csrc_path()),
+                        "libnxd_data_loader.so")
+
+
+def _compile_and_bind():
+    subprocess.run(
+        ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
+         _csrc_path(), "-o", _so_path()],
+        check=True, capture_output=True)
+    return _bind(ctypes.CDLL(_so_path()))
+
+
+def _bind(lib):
+    lib.nxd_loader_create.restype = ctypes.c_void_p
+    lib.nxd_loader_create.argtypes = [
+        ctypes.c_char_p, ctypes.c_int, ctypes.c_long, ctypes.c_long,
+        ctypes.c_long, ctypes.c_int, ctypes.c_int]
+    lib.nxd_loader_num_sequences.restype = ctypes.c_long
+    lib.nxd_loader_num_sequences.argtypes = [ctypes.c_void_p]
+    lib.nxd_loader_next.restype = ctypes.c_int
+    lib.nxd_loader_next.argtypes = [ctypes.c_void_p,
+                                    ctypes.POINTER(ctypes.c_int32)]
+    lib.nxd_loader_destroy.argtypes = [ctypes.c_void_p]
+    return lib
+
+
+def build_native() -> None:
+    """Compile ``csrc/data_loader.cpp`` now and make it the library every
+    later :class:`TokenBatchLoader` binds, whatever ``.so`` was on disk.
+    Raises when the toolchain fails — for entry scripts that must measure
+    the native path or nothing (``bench.py``). Call before the first
+    loader is made in the process."""
+    global _LIB, _LIB_FAILED
+    with _BUILD_LOCK:
+        _LIB = _compile_and_bind()
+        _LIB_FAILED = False
+
+
 def _load_native():
     global _LIB, _LIB_FAILED
     if _LIB is not None or _LIB_FAILED:
@@ -43,27 +82,13 @@ def _load_native():
     with _BUILD_LOCK:
         if _LIB is not None or _LIB_FAILED:
             return _LIB
-        src = _csrc_path()
-        so = os.path.join(os.path.dirname(src), "libnxd_data_loader.so")
+        src, so = _csrc_path(), _so_path()
         try:
             if (not os.path.exists(so)
                     or os.path.getmtime(so) < os.path.getmtime(src)):
-                subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                     "-pthread", src, "-o", so],
-                    check=True, capture_output=True)
-            lib = ctypes.CDLL(so)
-            lib.nxd_loader_create.restype = ctypes.c_void_p
-            lib.nxd_loader_create.argtypes = [
-                ctypes.c_char_p, ctypes.c_int, ctypes.c_long, ctypes.c_long,
-                ctypes.c_long, ctypes.c_int, ctypes.c_int]
-            lib.nxd_loader_num_sequences.restype = ctypes.c_long
-            lib.nxd_loader_num_sequences.argtypes = [ctypes.c_void_p]
-            lib.nxd_loader_next.restype = ctypes.c_int
-            lib.nxd_loader_next.argtypes = [ctypes.c_void_p,
-                                            ctypes.POINTER(ctypes.c_int32)]
-            lib.nxd_loader_destroy.argtypes = [ctypes.c_void_p]
-            _LIB = lib
+                _LIB = _compile_and_bind()
+            else:
+                _LIB = _bind(ctypes.CDLL(so))
         except Exception:
             _LIB_FAILED = True
             _LIB = None

@@ -360,9 +360,9 @@ def mixtral_forward_with_cache(cfg: MixtralConfig, params,
                 f"got batch {input_ids.shape[0]}")
     # token-generation-sized calls only: at prefill (large batch*seq) most
     # experts are hit anyway and the decode kernel's partial-sum layout
-    # would cost O(num_ib * tokens * H) HBM for nothing (measured crossover
-    # ~T=4 tokens TOTAL, BASELINE.md r3 decode-MoE table — so the batch dim
-    # counts, advisor r3)
+    # would cost O(num_ib * tokens * H) HBM for nothing (a crossover near
+    # T=4 tokens TOTAL was measured once, in a record since deleted — so
+    # the batch dim counts; ROADMAP S6 re-measures it)
     total_tokens = input_ids.shape[0] * input_ids.shape[1]
     if (cfg.moe_dispatch == "blockwise" and not cfg.moe_sentinel_empty
             and total_tokens * cfg.top_k <= cfg.num_experts):

@@ -31,6 +31,7 @@ from jax import lax
 
 from ..parallel import comm
 from ..parallel import mesh as ps
+from ..utils.device import on_tpu
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -349,7 +350,7 @@ def ring_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     b, s_local, n, d = q.shape
     bq, bk = min(block_q, s_local), min(block_k, s_local)
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = not on_tpu()
     # compiled TPU Mosaic requires 128-aligned blocks (flash_attention's
     # tileable_strict); interpret mode accepts 8-aligned for tests
     align = 8 if interpret else 128

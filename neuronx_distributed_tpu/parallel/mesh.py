@@ -76,8 +76,9 @@ def _topology_device_order(devices: Sequence[Any], shape: Tuple[int, ...]) -> np
 
     On real TPU slices delegates to ``mesh_utils.create_device_mesh`` (which
     plays the role of the reference's LOGIC1/LOGIC2 ring layouts,
-    ``parallel_state.py:107,177,341``). On CPU/virtual devices (tests) or when
-    the topology solver rejects the shape, falls back to id-sorted reshape.
+    ``parallel_state.py:107,177,341``); a shape the topology solver rejects
+    is an error there, not an id-ordered guess. CPU/virtual devices (tests)
+    have no topology and take the id-sorted reshape.
     """
     devs = sorted(devices, key=lambda d: d.id)
     if int(np.prod(shape)) != len(devs):
@@ -85,13 +86,9 @@ def _topology_device_order(devices: Sequence[Any], shape: Tuple[int, ...]) -> np
             f"mesh shape {shape} does not match device count {len(devs)}")
     plat = getattr(devs[0], "platform", "cpu")
     if plat == "tpu" and len(devs) > 1:
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            return np.asarray(
-                mesh_utils.create_device_mesh(shape, devices=devs))
-        except Exception as e:  # pragma: no cover - topology-solver fallback
-            logger.warning("create_device_mesh failed (%s); id-order fallback", e)
+        return np.asarray(mesh_utils.create_device_mesh(shape, devices=devs))
     return np.asarray(devs, dtype=object).reshape(shape)
 
 
@@ -129,16 +126,11 @@ def _hybrid_device_order(devices: Sequence[Any], shape: Tuple[int, ...],
                                           d.id))
     plat = getattr(devs[0], "platform", "cpu")
     if plat == "tpu":
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            return np.asarray(mesh_utils.create_hybrid_device_mesh(
-                (pp, dp // dcn_dp, cp, tp), (1, dcn_dp, 1, 1),
-                devices=devs))
-        except Exception as e:  # pragma: no cover - solver fallback
-            logger.warning("create_hybrid_device_mesh failed (%s); "
-                           "process-blocked fallback", e)
-    # virtual/CPU fallback: contiguous per-slice blocks stacked on dp
+        return np.asarray(mesh_utils.create_hybrid_device_mesh(
+            (pp, dp // dcn_dp, cp, tp), (1, dcn_dp, 1, 1), devices=devs))
+    # virtual/CPU devices: contiguous per-slice blocks stacked on dp
     per = len(devs) // dcn_dp
     blocks = [np.asarray(devs[i * per:(i + 1) * per], dtype=object)
               .reshape(pp, dp // dcn_dp, cp, tp) for i in range(dcn_dp)]
@@ -516,14 +508,6 @@ def with_sharding_constraint(x, *spec: Any):
     return jax.lax.with_sharding_constraint(x, named_sharding(*spec))
 
 
-try:
-    _SHARD_MAP_IMPL = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except AttributeError:  # jax < 0.6: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _SHARD_MAP_IMPL
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
 def shard_map(f, mesh: Optional[Mesh] = None, *, in_specs, out_specs,
               check_vma: bool = False, **kw):
     """``jax.shard_map`` over the global mesh.
@@ -531,11 +515,9 @@ def shard_map(f, mesh: Optional[Mesh] = None, *, in_specs, out_specs,
     ``check_vma`` defaults to False: TP-style programs routinely all-gather a
     sharded value and treat the result as replicated (e.g. the output of
     ``gather_from_tensor_parallel_region``), which JAX's static
-    varying-manual-axes analysis cannot prove replicated. (On pre-0.6 jax
-    the same switch is spelled ``check_rep``.)
+    varying-manual-axes analysis cannot prove replicated.
     """
     if mesh is None:
         mesh = get_mesh()
-    kw[_SHARD_MAP_CHECK_KW] = check_vma
-    return _SHARD_MAP_IMPL(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)

@@ -54,7 +54,6 @@ def proxy_measure(plan: Plan, m: ModelSpec, *, seed: int = 0,
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     n_dev = len(jax.devices())
     axis = min(plan.tp * plan.dp, n_dev) or 1
@@ -84,9 +83,9 @@ def proxy_measure(plan: Plan, m: ModelSpec, *, seed: int = 0,
                 y = y + jnp.sum(g) * 0
             return y
 
-        return shard_map(body, mesh=mesh,
-                         in_specs=(P("dp", None), P(None, None)),
-                         out_specs=P("dp", None))(x, w)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P("dp", None), P(None, None)),
+                             out_specs=P("dp", None))(x, w)
 
     out = step(x, w)
     out.block_until_ready()   # compile outside the timed region

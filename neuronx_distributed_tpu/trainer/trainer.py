@@ -541,9 +541,9 @@ def make_train_step(
     batch_shardings = NamedSharding(mesh, batch_spec)
     if scan_steps > 1:
         # run `scan_steps` optimizer steps in ONE dispatch: batch leaves gain
-        # a leading scan dim. Keeps host round-trips (and, through remote
-        # tunnels, dispatch latency) out of the training loop — the XLA
-        # program is the same per-step program, iterated on device.
+        # a leading scan dim. Keeps host round-trips and dispatch latency
+        # out of the training loop — the XLA program is the same per-step
+        # program, iterated on device.
         def multi_step_fn(state: TrainState, batches):
             def body(s, mb):
                 s2, metrics = step_fn(s, mb)

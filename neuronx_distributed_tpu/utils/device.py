@@ -1,0 +1,36 @@
+"""Which device the process runs on, and where its compiled programs go.
+
+The single home for the two questions every entry script and kernel
+dispatcher asks: "is the default backend a TPU?" and "where does JAX's
+persistent compilation cache live?".
+"""
+
+from __future__ import annotations
+
+import os
+
+
+def on_tpu() -> bool:
+    """True when the default JAX backend is a TPU (the Pallas kernels run
+    compiled; everywhere else they run in interpret mode or not at all)."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def place_compile_cache(checkout: str) -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    directory is set in code. Otherwise the cache goes to the fixed path
+    ``<checkout>/.jax_cache`` — the path is part of the cache key, so it is
+    never a temporary name. Call before the first compile."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.abspath(checkout), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    # cache every program, however quick its compile
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

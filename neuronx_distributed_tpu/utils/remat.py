@@ -18,8 +18,8 @@ Policy guide (v5e, 350M llama slice, bs=8 seq=2048, measured r3):
   re-running the attention forward kernel — the single biggest recompute
   item (~13% of step compute at bench shapes);
 * ``"dots_and_attention"`` — the union of "dots" and "save_attention"
-  (``save_from_both_policies``): both measured levers at once, for when
-  activation memory allows (``tpu_bench_sweep.py`` has its sweep column);
+  (``save_from_both_policies``): both levers at once, for when
+  activation memory allows;
 * any other name resolves via ``getattr(jax.checkpoint_policies, name)``.
 """
 

@@ -1,0 +1,125 @@
+"""Compile the main path's Pallas kernels for the chip, without the chip.
+
+The TPU compiler is installed here and compiles for a described
+``v5e:2x2`` (section 2 of the ``on-chip-measurement`` guide): what it
+refuses — a block spec off the (8, 128) tiling, too much VMEM, a
+relayout Mosaic lacks — costs no chip time. Interpret mode shows none
+of that, so each case calls the inner kernel function with
+``interpret=False`` (the dispatchers would pick interpret mode or the
+XLA path under the tests' CPU backend) and looks for the kernel
+(``tpu_custom_call``) in the compiled program.
+
+The topology is described inside a module-scoped fixture, never at
+import: one process at a time may load the TPU's library, and every
+xdist worker imports every test file. Keep these compiles in this one
+file for the same reason.
+"""
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` -> an abstract array placed on one v5e chip.
+    The persistent compile cache is off around these compiles: an entry
+    written for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _assert_kernel_compiles(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- flash attention: b=1 s=2048 n=32 d=128 (Llama-2-7B, the smoke's) -------
+
+def _flash_args(chip):
+    qkv = chip((1, 2048, 32, 128), jnp.bfloat16)
+    return qkv, qkv, qkv, chip((1,), jnp.uint32)
+
+
+def _flash(dropout_p):
+    from neuronx_distributed_tpu.ops.flash_attention import _flash_pallas
+
+    def fwd(q, k, v, seed):
+        return _flash_pallas(q, k, v, seed, True, 512, 512,
+                             1.0 / math.sqrt(128), False, dropout_p)
+    return fwd
+
+
+@pytest.mark.parametrize("dropout_p", [0.0, 0.1], ids=["plain", "dropout"])
+def test_flash_forward(chip, dropout_p):
+    _assert_kernel_compiles(_flash(dropout_p), *_flash_args(chip))
+
+
+def test_flash_forward_backward(chip):
+    fwd = _flash(0.0)
+
+    def loss(q, k, v, seed):
+        return jnp.sum(fwd(q, k, v, seed).astype(jnp.float32))
+
+    _assert_kernel_compiles(jax.grad(loss, argnums=(0, 1, 2)),
+                            *_flash_args(chip))
+
+
+# -- paged attention: the packed serving step's shapes ----------------------
+
+@pytest.mark.parametrize("tokens", [32, 256], ids=["T32", "T256"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+def test_paged_attention(chip, quantized, tokens):
+    from neuronx_distributed_tpu.ops.paged_attention import (
+        _paged_attention_pallas)
+
+    n, d, bs, kv, nb, cols = 32, 128, 128, 32, 64, 16
+    pool = chip((nb, bs, kv, d), jnp.int8 if quantized else jnp.bfloat16)
+    scale = chip((nb, bs, kv), jnp.float32) if quantized else None
+    fn = functools.partial(_paged_attention_pallas,
+                           scale=1.0 / math.sqrt(d), interpret=False)
+    _assert_kernel_compiles(
+        fn, chip((tokens, n, d), jnp.bfloat16), pool, pool,
+        chip((nb, bs), jnp.int32), chip((tokens, cols), jnp.int32),
+        chip((tokens,), jnp.int32), scale, scale)
+
+
+# -- grouped GLU decode (MoE serving) at OLMoE's widths (ROADMAP R1): hidden
+# 2048, expert width 1024. Mixtral's 4096 compiles too, in about ten seconds.
+
+def test_grouped_glu_decode(chip):
+    from neuronx_distributed_tpu.ops.blockwise_moe import (
+        _grouped_glu_decode_pallas)
+
+    e, h, i, block, block_i = 8, 2048, 1024, 128, 512
+    fn = functools.partial(_grouped_glu_decode_pallas, block_size=block,
+                           block_i=block_i, interpret=False)
+    _assert_kernel_compiles(
+        fn, chip((e * block, h), jnp.bfloat16),
+        chip((e, h, 2, i), jnp.bfloat16), chip((e, i, h), jnp.bfloat16),
+        chip((e,), jnp.int32))
