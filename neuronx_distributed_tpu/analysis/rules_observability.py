@@ -11,12 +11,12 @@ Three failure classes the ``obs`` subsystem makes tempting:
   compiled call, on the host.
 
 * **Metric-record calls inside traced code** — ``counter.inc()``,
-  ``gauge.dec()``, ``histogram.observe()``, ``tracer.span()`` and the
-  Timeline ``mark_event_*`` surface are host-side APIs; inside traced
-  code they fire once per trace (counting compiles, not events) and are
-  exactly the host callbacks the no-callbacks invariant forbids. Only
-  attribute calls (``x.inc(...)``) are matched — ``.set`` is deliberately
-  not in the list (``x.at[i].set(...)`` is core JAX).
+  ``gauge.dec()``, ``histogram.observe()`` and ``tracer.span()`` are
+  host-side APIs; inside traced code they fire once per trace (counting
+  compiles, not events) and are exactly the host callbacks the
+  no-callbacks invariant forbids. Only attribute calls (``x.inc(...)``)
+  are matched — ``.set`` is deliberately not in the list
+  (``x.at[i].set(...)`` is core JAX).
 
 * **Bare ``print()`` in library modules** — output that bypasses the
   logger (rank-0 gating, levels) and the event channel (metrics, NXD_EVENT
@@ -46,7 +46,6 @@ _CLOCKS = frozenset({
 #: method tails of the obs record surface (attribute calls only).
 _METRIC_TAILS = frozenset({
     "inc", "dec", "observe", "span",
-    "mark_event_start", "mark_event_end",
 })
 
 _PRINT_EXEMPT_SEGMENTS = ("obs", "scripts", "examples")

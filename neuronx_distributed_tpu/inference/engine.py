@@ -1888,26 +1888,34 @@ class ServingEngine:
                 self.dcache = cow_copy_blocks(self.dcache, src, dst, keep)
         self._pending_cow.clear()
 
-    def _run_worker(self, fn, rows, width: int, rng):
+    def _run_worker(self, fn, rows, width: int, rng, span: str):
         """Pack ``rows`` into a fixed ``width`` batch and run one jitted
-        worker; returns per-row sampled tokens (aligned with ``rows``)."""
-        tokens = np.zeros((1, width), np.int32)
-        positions = np.full((1, width), PAD_POSITION, np.int32)
-        slot_ids = np.full((width,), self.ecfg.max_slots, np.int32)
-        for i, (req, tok, pos, _) in enumerate(rows):
-            tokens[0, i] = tok
-            positions[0, i] = pos
-            slot_ids[i] = req.slot
-        if self._spec is not None:
-            sampled, self.cache, self.dcache = fn(
-                self.params, self._draft_params, self.cache, self.dcache,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(slot_ids), rng)
-        else:
-            sampled, self.cache = fn(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(positions), jnp.asarray(slot_ids), rng)
-        return np.asarray(sampled)
+        worker; returns per-row sampled tokens (aligned with ``rows``).
+        ``span`` is the caller's open span: its three children split the
+        host's packing, the uploads and the enqueue, and the wait for the
+        device."""
+        tracer = get_tracer()
+        with tracer.span(span + "/pack"):
+            tokens = np.zeros((1, width), np.int32)
+            positions = np.full((1, width), PAD_POSITION, np.int32)
+            slot_ids = np.full((width,), self.ecfg.max_slots, np.int32)
+            for i, (req, tok, pos, _) in enumerate(rows):
+                tokens[0, i] = tok
+                positions[0, i] = pos
+                slot_ids[i] = req.slot
+        with tracer.span(span + "/dispatch"):
+            if self._spec is not None:
+                sampled, self.cache, self.dcache = fn(
+                    self.params, self._draft_params, self.cache,
+                    self.dcache, jnp.asarray(tokens),
+                    jnp.asarray(positions), jnp.asarray(slot_ids), rng)
+            else:
+                sampled, self.cache = fn(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(positions), jnp.asarray(slot_ids), rng)
+        with tracer.span(span + "/fetch"):
+            # the host blocks here until the device has finished the step
+            return np.asarray(sampled)
 
     def _maybe_insert_prefix(self, req: _RequestState) -> None:
         """Publish this request's fully-written prompt blocks into the
@@ -2081,31 +2089,34 @@ class ServingEngine:
             self.stats.first_step_t = t_start
         with tracer.span("engine/cow"):
             self._apply_pending_cow()
-        if self._freed_dirty:
-            mask = np.zeros((self._pool_blocks,), np.bool_)
-            mask[list(self._freed_dirty)] = True
-            self._freed_dirty.clear()
-            fmask = jnp.asarray(mask)
-            self.cache = self.cache.replace(pos=_clear_freed_positions(
-                self.cache.pos, fmask))
+        with tracer.span("engine/hygiene"):
+            if self._freed_dirty:
+                mask = np.zeros((self._pool_blocks,), np.bool_)
+                mask[list(self._freed_dirty)] = True
+                self._freed_dirty.clear()
+                fmask = jnp.asarray(mask)
+                self.cache = self.cache.replace(pos=_clear_freed_positions(
+                    self.cache.pos, fmask))
+                if self.dcache is not None:
+                    self.dcache = self.dcache.replace(
+                        pos=_clear_freed_positions(self.dcache.pos, fmask))
+        with tracer.span("engine/tables"):
+            # committed to the cache's sharding: the disaggregated decode
+            # worker otherwise sees two sharding keys for its cache operand
+            # (prefill's committed output vs a fresh uncommitted replace)
+            # and compiles twice
+            lengths = np.zeros((self._table_rows,), np.int32)
+            for i, s in enumerate(self._slots):
+                if s is not None:
+                    lengths[i] = s.n_cached
+            tbl = jax.device_put(jnp.asarray(self._tables), self._sharding)
+            lens = jax.device_put(jnp.asarray(lengths), self._sharding)
+            self.cache = self.cache.replace(block_tables=tbl, lengths=lens)
             if self.dcache is not None:
-                self.dcache = self.dcache.replace(
-                    pos=_clear_freed_positions(self.dcache.pos, fmask))
-        # committed to the cache's sharding: the disaggregated decode
-        # worker otherwise sees two sharding keys for its cache operand
-        # (prefill's committed output vs a fresh uncommitted replace)
-        # and compiles twice
-        lengths = np.zeros((self._table_rows,), np.int32)
-        for i, s in enumerate(self._slots):
-            if s is not None:
-                lengths[i] = s.n_cached
-        tbl = jax.device_put(jnp.asarray(self._tables), self._sharding)
-        lens = jax.device_put(jnp.asarray(lengths), self._sharding)
-        self.cache = self.cache.replace(block_tables=tbl, lengths=lens)
-        if self.dcache is not None:
-            self.dcache = self.dcache.replace(block_tables=tbl,
-                                              lengths=lens)
-        self._rng, sub = jax.random.split(self._rng)
+                self.dcache = self.dcache.replace(block_tables=tbl,
+                                                  lengths=lens)
+            self._rng, sub = jax.random.split(self._rng)
+        pad_rows = 0
         if self.ecfg.disaggregated or self._cp > 1:
             cp = self._cp > 1
             p_width = (self._cp_width if cp
@@ -2115,22 +2126,26 @@ class ServingEngine:
             d_width = self.ecfg.token_budget if cp else self.ecfg.max_slots
             sampled = np.zeros((len(rows),), np.int32)
             if prefill_rows:          # prefill first: TTFT, and new KV
-                with tracer.span("engine/cp_prefill" if cp
-                                 else "engine/prefill"):
+                name = "engine/cp_prefill" if cp else "engine/prefill"
+                with tracer.span(name):
                     sampled[len(decode_rows):] = self._run_worker(
                         self._prefill_fn, prefill_rows, p_width,
-                        sub)[:len(prefill_rows)]
+                        sub, name)[:len(prefill_rows)]
+                pad_rows += p_width - len(prefill_rows)
             if decode_rows:           # ... lands before decode reads
                 with tracer.span("engine/decode"):
                     sampled[:len(decode_rows)] = self._run_worker(
                         d_fn, decode_rows, d_width,
-                        sub)[:len(decode_rows)]
+                        sub, "engine/decode")[:len(decode_rows)]
+                pad_rows += d_width - len(decode_rows)
         else:
             sampled = np.zeros((0,), np.int32)
             if rows:
                 with tracer.span("engine/packed"):
                     sampled = self._run_worker(
-                        self._step_fn, rows, self.ecfg.token_budget, sub)
+                        self._step_fn, rows, self.ecfg.token_budget, sub,
+                        "engine/packed")
+                pad_rows = self.ecfg.token_budget - len(rows)
         emit = alen = bstar = None
         if spec_live:
             # one speculation round: draft proposes k tokens per branch
@@ -2196,16 +2211,18 @@ class ServingEngine:
             if spec_live:
                 self._land_spec_round(round_state, emit, alen, bstar,
                                       now)
-        self.stats.steps += 1
-        self.stats.step_latency_s.append(now - t_start)
-        self.stats.last_step_t = now
-        self.stats.occupancy.append(
-            self.allocator.num_allocated / self.allocator.num_blocks)
-        self.stats.shared_fraction.append(
-            self.allocator.num_shared
-            / max(1, self.allocator.num_allocated))
-        self.stats.queue_depth = self.queue_depth()
-        self._publish_obs(now - t_start)
+        with tracer.span("engine/publish"):
+            self.stats.steps += 1
+            self.stats.step_latency_s.append(now - t_start)
+            self.stats.last_step_t = now
+            self.stats.occupancy.append(
+                self.allocator.num_allocated / self.allocator.num_blocks)
+            self.stats.shared_fraction.append(
+                self.allocator.num_shared
+                / max(1, self.allocator.num_allocated))
+            self.stats.queue_depth = self.queue_depth()
+            self._publish_obs(now - t_start, len(decode_rows),
+                              len(prefill_rows), pad_rows)
         return len(rows) + len(spec_live)
 
     #: EngineStats scalar fields bridged into ``nxd_engine_stats`` each
@@ -2220,13 +2237,16 @@ class ServingEngine:
         "migrated_out", "migrated_tokens", "spec_rounds",
         "spec_accepted_tokens")
 
-    def _publish_obs(self, step_latency_s: float) -> None:
-        """Bridge :class:`EngineStats` into registry gauges and poll the
-        per-worker compile trackers. One bool check when obs is disabled;
-        the no-host-callback invariant holds — everything here runs after
-        the compiled workers returned. Child handles are cached against
-        the registry's reset generation so the steady state is one
-        attribute read + set per field."""
+    def _publish_obs(self, step_latency_s: float, decode_rows: int,
+                     prefill_rows: int, pad_rows: int) -> None:
+        """Bridge :class:`EngineStats` into registry gauges, count the
+        step's rows by kind where they were packed (over the steps that
+        ran a worker the three kinds sum to steps x worker width) and poll
+        the per-worker compile trackers. One bool check when obs is
+        disabled; the no-host-callback invariant holds — everything here
+        runs after the compiled workers returned. Child handles are
+        cached against the registry's reset generation so the steady
+        state is one attribute read + set per field."""
         reg = get_registry()
         if not reg.enabled:
             return
@@ -2252,19 +2272,30 @@ class ServingEngine:
 
             for v in self.stats.step_latency_s[-HISTOGRAM_RESERVOIR:-1]:
                 step_h.observe(v)
+            rows_c = reg.counter(
+                "nxd_engine_rows_total",
+                "Rows of the serving workers' fixed-width batches by what "
+                "filled them: a decoding slot's token, a prefill chunk's "
+                "token, or padding.",
+                labels=("kind",))
             cache = self._obs_cache = (
                 reg, reg.generation,
                 {f: stats_g.labels(field=f)
                  for f in self._OBS_SCALAR_FIELDS},
                 reg.gauge("nxd_engine_pool_free_blocks",
                           "Unallocated KV blocks."),
-                step_h)
-        _, _, fields, free_g, step_h = cache
+                step_h,
+                tuple(rows_c.labels(kind=k)
+                      for k in ("decode", "prefill", "pad")))
+        _, _, fields, free_g, step_h, rows_by_kind = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
         free_g.set(self.pool_free_blocks())
         step_h.observe(step_latency_s)
+        for child, n in zip(rows_by_kind,
+                            (decode_rows, prefill_rows, pad_rows)):
+            child.inc(n)
 
     def _retire(self, req: _RequestState, now: float) -> None:
         self._release(req)

@@ -1,16 +1,13 @@
 """Host-side span tracer: nested spans, chrome-trace export, latency stats.
 
-Subsumes the old ``utils/timeline.Timeline`` (which stays as a thin shim).
 Spans are host-side only — the tracer must never be entered from inside a
 jitted/shard_mapped function (the nxdlint ``observability`` rule enforces
 this): a span around ``step_fn(...)`` measures dispatch+execution, a span
 *inside* would measure trace time once and then lie forever.
 
-Four surfaces:
+Three surfaces:
 
 * ``span(name, **attrs)`` — context manager, nests via a per-thread stack;
-* ``mark_event_start/end(name)`` — name-keyed flat events (the Timeline
-  compatibility surface, also handy across callback boundaries);
 * ``request_*`` — request-scoped traces keyed by request uid. A serving
   request crosses threads and step boundaries (router admission → engine
   queue → chunked-prefill slices → per-step decode → retirement, possibly
@@ -25,12 +22,15 @@ Four surfaces:
   attribution in ``args``.
 * ``profile_step(logdir)`` — wraps ``jax.profiler`` start/stop_trace and
   records a host span carrying the logdir attribute, so the device trace
-  is findable from the host timeline.
+  is findable from the host timeline. While it is open every ``span()``
+  also opens a ``jax.profiler.TraceAnnotation`` of the same name: the
+  spans are then in the ``.xplane.pb`` itself, on the profiler's clock,
+  beside the device operations.
 
-``chrome_trace()`` / ``save()`` snapshot everything **under the lock** and
-emit still-open spans as zero-duration ``"incomplete"`` events instead of
-silently dropping them (the old Timeline.save raced writers and lost open
-spans).
+``chrome_trace()`` / ``save()`` snapshot everything **under the lock**. A
+``span()`` is recorded when it closes, so one still open at the snapshot
+is not in it; a request trace still live is, as a zero-duration
+``"incomplete"`` event.
 """
 
 from __future__ import annotations
@@ -39,12 +39,13 @@ import contextlib
 import json
 import math
 import os
+import random
 import threading
 import time
 import zlib
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-from .metrics import QUANTILES
+from .metrics import HISTOGRAM_RESERVOIR, QUANTILES
 
 #: live request traces kept before the oldest is evicted — a leak guard
 #: for callers that begin traces and never retire them, not a window.
@@ -73,6 +74,37 @@ class _RequestTrace:
         self.phase_n[phase] = self.phase_n.get(phase, 0) + n
 
 
+class _SpanStats:
+    """Running latency stats of one span name: exact count, total, min
+    and max, and a uniform sample of ``HISTOGRAM_RESERVOIR`` durations
+    for the quantiles (Algorithm R, as ``metrics._HistChild``), so an
+    operator who leaves obs on holds a bounded number of floats a name."""
+
+    __slots__ = ("count", "total", "min", "max", "reservoir", "_rng")
+
+    def __init__(self, name: str):
+        self.count = 0
+        self.total = 0.0
+        self.min = math.inf
+        self.max = -math.inf
+        self.reservoir: List[float] = []
+        self._rng = random.Random(zlib.crc32(name.encode("utf-8")))
+
+    def add(self, dur: float) -> None:
+        self.count += 1
+        self.total += dur
+        if dur < self.min:
+            self.min = dur
+        if dur > self.max:
+            self.max = dur
+        if len(self.reservoir) < HISTOGRAM_RESERVOIR:
+            self.reservoir.append(dur)
+        else:
+            j = self._rng.randrange(self.count)
+            if j < HISTOGRAM_RESERVOIR:
+                self.reservoir[j] = dur
+
+
 class _NullSpan:
     """Returned when tracing is disabled: one shared, reentrant no-op."""
 
@@ -92,7 +124,8 @@ _NULL_SPAN = _NullSpan()
 
 
 class Span:
-    __slots__ = ("tracer", "name", "attrs", "t0_us", "parent")
+    __slots__ = ("tracer", "name", "attrs", "t0_us", "parent",
+                 "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str,
                  attrs: Dict[str, Any]):
@@ -101,6 +134,7 @@ class Span:
         self.attrs = attrs
         self.t0_us = 0.0
         self.parent: Optional[str] = None
+        self._annotation = None
 
     def set_attribute(self, key: str, value: Any) -> None:
         self.attrs[key] = value
@@ -109,11 +143,21 @@ class Span:
         stack = self.tracer._stack()
         self.parent = stack[-1].name if stack else None
         stack.append(self)
+        if self.tracer._annotate:
+            # the annotation opens before and closes after the span's own
+            # readings: what it costs is outside the recorded duration
+            import jax
+
+            self._annotation = jax.profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self.t0_us = time.perf_counter_ns() / 1000.0
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         end_us = time.perf_counter_ns() / 1000.0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         stack = self.tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -136,8 +180,10 @@ class SpanTracer:
         self.max_events = max_events
         self._events: List[Dict[str, Any]] = []
         self._next = 0
-        self._open_named: Dict[str, float] = {}
-        self._stats: Dict[str, List[float]] = {}
+        self._stats: Dict[str, _SpanStats] = {}
+        # on only while ``profile_step`` is open: spans then also open a
+        # profiler annotation of their name
+        self._annotate = False
         self._requests: Dict[str, _RequestTrace] = {}
         self._lock = threading.Lock()
         self._tls = threading.local()
@@ -157,6 +203,13 @@ class SpanTracer:
             self._events[self._next] = ev
             self._next = (self._next + 1) % self.max_events
 
+    def _add_stat(self, name: str, dur: float) -> None:
+        # caller holds self._lock
+        st = self._stats.get(name)
+        if st is None:
+            st = self._stats[name] = _SpanStats(name)
+        st.add(dur)
+
     def _record(self, span: Span, end_us: float) -> None:
         dur = end_us - span.t0_us
         ev = {
@@ -170,43 +223,13 @@ class SpanTracer:
             ev["args"] = args
         with self._lock:
             self._append_event(ev)
-            self._stats.setdefault(span.name, []).append(dur)
+            self._add_stat(span.name, dur)
 
     # -- span surface -----------------------------------------------
     def span(self, name: str, **attrs: Any):
         if not self.enabled:
             return _NULL_SPAN
         return Span(self, name, attrs)
-
-    # -- Timeline-compatible name-keyed surface ----------------------
-    def mark_event_start(self, name: str) -> None:
-        if not self.enabled:
-            return
-        with self._lock:
-            self._open_named[name] = time.perf_counter_ns() / 1000.0
-
-    def mark_event_end(self, name: str) -> None:
-        if not self.enabled:
-            return
-        now = time.perf_counter_ns() / 1000.0
-        with self._lock:
-            start = self._open_named.pop(name, None)
-            if start is None:
-                return
-            dur = now - start
-            self._append_event({
-                "name": name, "ph": "X", "ts": start, "dur": dur,
-                "pid": os.getpid(), "tid": threading.get_ident() % 10000,
-            })
-            self._stats.setdefault(name, []).append(dur)
-
-    @contextlib.contextmanager
-    def event(self, name: str):
-        self.mark_event_start(name)
-        try:
-            yield
-        finally:
-            self.mark_event_end(name)
 
     # -- request-scoped traces ---------------------------------------
     def request_begin(self, uid: str, trace_id: Optional[str] = None,
@@ -379,8 +402,7 @@ class SpanTracer:
                 "tid": zlib.crc32(uid.encode("utf-8")) % 10000,
                 "args": args,
             })
-            self._stats.setdefault("request/%s" % outcome,
-                                   []).append(total_us)
+            self._add_stat("request/%s" % outcome, total_us)
             return {"uid": uid, "trace_id": tr.trace_id,
                     "outcome": outcome, "total_us": total_us,
                     "phase_us": dict(tr.phase_us),
@@ -390,15 +412,19 @@ class SpanTracer:
     @contextlib.contextmanager
     def profile_step(self, logdir: str = "/tmp/nxd_profile"):
         """Attach an XLA device trace (viewable in Perfetto/TensorBoard)
-        to a host span, so device and host timelines cross-reference."""
+        to a host span, so device and host timelines cross-reference.
+        Every span opened while it is open (this one included) is also a
+        ``jax.profiler.TraceAnnotation``: it lands on the host plane of
+        the written ``.xplane.pb``, on the clock of the device events."""
         import jax
 
         jax.profiler.start_trace(logdir)
-        span = self.span("profile_step", logdir=logdir)
+        was, self._annotate = self._annotate, True
         try:
-            with span:
+            with self.span("profile_step", logdir=logdir):
                 yield logdir
         finally:
+            self._annotate = was
             jax.profiler.stop_trace()
 
     # -- export ------------------------------------------------------
@@ -406,9 +432,9 @@ class SpanTracer:
         """Snapshot as a chrome-trace dict.
 
         Taken entirely under the lock so concurrent writers can't tear
-        the event list; spans still open at snapshot time (both the
-        name-keyed kind and ``span()`` stacks) appear as zero-duration
-        events tagged ``{"incomplete": true}`` rather than vanishing.
+        the event list. Request traces still live at snapshot time appear
+        as zero-duration events tagged ``{"incomplete": true}``; a
+        ``span()`` still open is recorded when it closes and not before.
         """
         now = time.perf_counter_ns() / 1000.0
         with self._lock:
@@ -417,17 +443,10 @@ class SpanTracer:
             else:  # unroll the ring into chronological order
                 events = (self._events[self._next:]
                           + self._events[:self._next])
-            open_named = dict(self._open_named)
             open_requests = [
                 (tr.uid, tr.trace_id, tr.t0_us, dict(tr.phase_us))
                 for tr in self._requests.values()]
         events = [dict(ev) for ev in events]
-        for name, start in sorted(open_named.items()):
-            events.append({
-                "name": name, "ph": "X", "ts": start, "dur": 0.0,
-                "pid": os.getpid(), "tid": threading.get_ident() % 10000,
-                "args": {"incomplete": True, "open_for_us": now - start},
-            })
         for uid, trace_id, start, phase_us in sorted(open_requests):
             events.append({
                 "name": "request:%s" % uid, "ph": "X", "ts": start,
@@ -446,20 +465,24 @@ class SpanTracer:
         return path
 
     def stats(self) -> Dict[str, Dict[str, float]]:
-        """Per-span-name latency stats (durations in microseconds)."""
+        """Per-span-name latency stats (durations in microseconds): count,
+        total, mean, min and max over every span recorded, quantiles over
+        the name's reservoir."""
         with self._lock:
-            snap = {name: list(durs) for name, durs in self._stats.items()}
+            snap = {name: (st.count, st.total, st.min, st.max,
+                           list(st.reservoir))
+                    for name, st in self._stats.items()}
         out: Dict[str, Dict[str, float]] = {}
-        for name, durs in sorted(snap.items()):
+        for name, (count, total, lo, hi, durs) in sorted(snap.items()):
             durs.sort()
-            n = len(durs)
             entry = {
-                "count": float(n),
-                "total_us": sum(durs),
-                "mean_us": sum(durs) / n,
-                "min_us": durs[0],
-                "max_us": durs[-1],
+                "count": float(count),
+                "total_us": total,
+                "mean_us": total / count,
+                "min_us": lo,
+                "max_us": hi,
             }
+            n = len(durs)
             for q in QUANTILES:
                 idx = max(0, min(n - 1, int(math.ceil(q * n)) - 1))
                 entry["p%g_us" % (q * 100)] = durs[idx]
@@ -470,7 +493,6 @@ class SpanTracer:
         with self._lock:
             self._events.clear()
             self._next = 0
-            self._open_named.clear()
             self._stats.clear()
             self._requests.clear()
 

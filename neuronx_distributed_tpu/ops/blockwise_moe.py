@@ -203,6 +203,7 @@ def _grouped_glu_pallas(xs, gate_up, down, block_expert, block_size,
         grid_spec=grid_spec,
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
+        name="grouped_glu_fwd",
     )(block_expert, xs, gate_up, down)
 
 
@@ -276,6 +277,7 @@ def _grouped_glu_decode_pallas(xs, gate_up, down, block_expert, block_size,
         ),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
+        name="grouped_glu_fwd_decode",
     )(block_expert, xs, gate_up, down)
     return jnp.sum(partial, axis=0).astype(xs.dtype)
 
@@ -311,6 +313,7 @@ def _grouped_glu_pallas_bwd(xs, gate_up, down, block_expert, dy, block_size,
         ),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
+        name="grouped_glu_bwd_dx",
     )(block_expert, xs, gate_up, down, dy)
 
     dgu, ddn = pl.pallas_call(
@@ -338,6 +341,7 @@ def _grouped_glu_pallas_bwd(xs, gate_up, down, block_expert, dy, block_size,
         ),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
+        name="grouped_glu_bwd_dw",
     )(block_expert, xs, gate_up, down, dy)
     return dx, dgu.astype(gate_up.dtype), ddn.astype(down.dtype)
 

@@ -341,6 +341,7 @@ def _flash_pallas_fwd(q, k, v, seed, causal, block_q, block_k, scale,
                         pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
+        name="flash_attention_fwd",
     )(qt, kt, vt, seed)
     return (jnp.swapaxes(out.reshape(b, n, sq, d), 1, 2),
             lse.reshape(b, n, sq))
@@ -582,6 +583,7 @@ def _flash_pallas_bwd(q, k, v, out, lse, g, seed, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
+        name="flash_attention_bwd_dq",
     )(qt, kt, vt, gt, lse_t, delta, seed)
 
     dk, dv = pl.pallas_call(
@@ -608,6 +610,7 @@ def _flash_pallas_bwd(q, k, v, out, lse, g, seed, causal, block_q, block_k,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
+        name="flash_attention_bwd_dkv",
     )(kt, vt, qt, gt, lse_t, delta, seed)
 
     return (jnp.swapaxes(dq.reshape(b, n, sq, d), 1, 2),

@@ -57,6 +57,17 @@ def chip(topo):
 def _assert_kernel_compiles(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled.as_text()
+
+
+def _kernel_instruction_names(hlo_text):
+    """Names of the instructions that are Mosaic kernels: what a device
+    trace shows for them (``%flash_attention_fwd.1`` -> the stem)."""
+    import re
+
+    return {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_][\w-]*?)(?:\.\d+)? = [^\n]*custom_call_target="
+        r'"tpu_custom_call"', hlo_text)}
 
 
 # -- flash attention: b=1 s=2048 n=32 d=128 (Llama-2-7B, the smoke's) -------
@@ -90,6 +101,28 @@ def test_flash_forward_backward(chip):
                             *_flash_args(chip))
 
 
+def test_kernel_names_are_the_device_instruction_names(chip):
+    """``pl.pallas_call(name=)`` names the custom call's instruction,
+    which is the name of its events in a device trace: the benchmark's
+    ``device_op_share`` readers search it. A transformation applied with
+    no ``jit`` between wraps the name (``jvp_flash_attention_fwd_``
+    here; through the jitted ``flash_attention`` the name stands alone).
+    Without a name the call takes its enclosing scope's (``shard_map``,
+    ``jvp_jit_flash_attention__``)."""
+    fwd = _flash(0.0)
+
+    def loss(q, k, v, seed):
+        return jnp.sum(fwd(q, k, v, seed).astype(jnp.float32))
+
+    text = _assert_kernel_compiles(jax.grad(loss, argnums=(0, 1, 2)),
+                                   *_flash_args(chip))
+    got = _kernel_instruction_names(text)
+    assert len(got) == 3
+    for want in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert sum(want in name for name in got) == 1, (want, got)
+
+
 # -- paged attention: the packed serving step's shapes ----------------------
 
 @pytest.mark.parametrize("tokens", [32, 256], ids=["T32", "T256"])
@@ -119,7 +152,8 @@ def test_grouped_glu_decode(chip):
     e, h, i, block, block_i = 8, 2048, 1024, 128, 512
     fn = functools.partial(_grouped_glu_decode_pallas, block_size=block,
                            block_i=block_i, interpret=False)
-    _assert_kernel_compiles(
+    text = _assert_kernel_compiles(
         fn, chip((e * block, h), jnp.bfloat16),
         chip((e, h, 2, i), jnp.bfloat16), chip((e, i, h), jnp.bfloat16),
         chip((e,), jnp.int32))
+    assert _kernel_instruction_names(text) == {"grouped_glu_fwd_decode"}

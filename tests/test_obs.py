@@ -1,5 +1,5 @@
 """Unified observability subsystem (``obs/``): registry semantics,
-Prometheus exposition, span tracer + Timeline shim (save-race regression),
+Prometheus exposition, span tracer (save-race regression, engine spans),
 compile tracking, wire-byte accounting vs the codec's predictions, the
 event channel, and the logger satellites."""
 
@@ -215,28 +215,35 @@ def test_tracer_stats_per_name():
     assert stats["work"]["total_us"] >= stats["work"]["max_us"]
 
 
-def test_named_events_and_incomplete_snapshot():
+def test_open_spans_and_incomplete_snapshot():
+    """A span is recorded when it closes; a live request trace shows in
+    the snapshot as an incomplete event and is closable afterwards."""
     tracer = SpanTracer()
-    with tracer.event("closed"):
+    with tracer.span("closed"):
         pass
-    tracer.mark_event_start("still_open")
-    events = tracer.chrome_trace()["traceEvents"]
+    tracer.request_begin("still_open")
+    with tracer.span("open_span"):
+        events = tracer.chrome_trace()["traceEvents"]
     by_name = {ev["name"]: ev for ev in events}
     assert by_name["closed"]["dur"] >= 0.0
     assert "args" not in by_name["closed"]
-    assert by_name["still_open"]["dur"] == 0.0
-    assert by_name["still_open"]["args"]["incomplete"] is True
-    assert by_name["still_open"]["args"]["open_for_us"] >= 0.0
-    # the open span is still closable after the snapshot
-    tracer.mark_event_end("still_open")
+    assert "open_span" not in by_name
+    assert by_name["request:still_open"]["dur"] == 0.0
+    assert by_name["request:still_open"]["args"]["incomplete"] is True
+    assert by_name["request:still_open"]["args"]["open_for_us"] >= 0.0
+    # the open request is still closable after the snapshot
+    tracer.request_end("still_open")
     closed = [ev for ev in tracer.chrome_trace()["traceEvents"]
-              if ev["name"] == "still_open"]
-    assert len(closed) == 1 and "args" not in closed[0]
+              if ev["name"] == "request:still_open"]
+    assert len(closed) == 1 and "incomplete" not in closed[0]["args"]
+    assert "open_span" in {
+        ev["name"] for ev in tracer.chrome_trace()["traceEvents"]}
 
 
-def test_mark_event_end_without_start_is_ignored():
+def test_request_end_without_begin_is_ignored():
     tracer = SpanTracer()
-    tracer.mark_event_end("never_started")
+    assert tracer.request_end("never_started") is None
+    tracer.request_phase_end("never_started", "queue")
     assert tracer.chrome_trace()["traceEvents"] == []
 
 
@@ -246,54 +253,74 @@ def test_disabled_tracer_records_nothing():
     assert s is tracer.span("y")  # one shared null span
     with s:
         pass
-    tracer.mark_event_start("a")
-    tracer.mark_event_end("a")
+    tracer.request_begin("a")
+    tracer.request_end("a")
     assert tracer.chrome_trace()["traceEvents"] == []
     assert tracer.stats() == {}
 
 
+def test_tracer_stats_keep_a_bounded_reservoir(monkeypatch):
+    """An operator who leaves obs on: a span name holds count, total, min,
+    max and at most the reservoir's samples, however many spans ran."""
+    from neuronx_distributed_tpu.obs import tracing
+
+    monkeypatch.setattr(tracing, "HISTOGRAM_RESERVOIR", 16)
+    tracer = SpanTracer(max_events=8)
+    span = tracing.Span(tracer, "work", {})
+    for i in range(1000):               # durations 1..1000 us, no clock
+        span.t0_us = 0.0
+        tracer._record(span, float(i + 1))
+    assert len(tracer._stats["work"].reservoir) == 16
+    st = tracer.stats()["work"]
+    assert st["count"] == 1000.0 and st["total_us"] == 500500.0
+    assert st["mean_us"] == 500.5
+    assert st["min_us"] == 1.0 and st["max_us"] == 1000.0
+    # quantiles come from a uniform sample of the whole run, not its tail
+    assert st["min_us"] <= st["p50_us"] <= st["p90_us"] <= st["p99_us"] \
+        <= st["max_us"]
+    assert 200.0 < st["p50_us"] < 800.0
+    assert set(st) == {"count", "total_us", "mean_us", "min_us", "max_us",
+                       "p50_us", "p90_us", "p99_us"}
+
+
 # ---------------------------------------------------------------------------
-# Timeline shim + save-race regression
+# chrome-trace export + save-race regression
 # ---------------------------------------------------------------------------
 
 
-def test_timeline_shim_roundtrip(tmp_path):
-    from neuronx_distributed_tpu.utils.timeline import Timeline
-
-    tl = Timeline(str(tmp_path / "t.json"))
-    with tl.event("step"):
+def test_save_roundtrip_and_tracer_isolation(tmp_path):
+    tl = SpanTracer()
+    with tl.span("step"):
         pass
-    tl.mark_event_start("manual")
-    tl.mark_event_end("manual")
-    with open(tl.save()) as f:
+    with tl.span("manual"):
+        pass
+    with open(tl.save(str(tmp_path / "t.json"))) as f:
         names = {ev["name"] for ev in json.load(f)["traceEvents"]}
     assert names == {"step", "manual"}
-    # per-Timeline isolation: a second Timeline sees none of it
-    assert json.load(open(Timeline(str(tmp_path / "u.json")).save())) \
+    # per-tracer isolation: a second tracer sees none of it
+    assert json.load(open(SpanTracer().save(str(tmp_path / "u.json")))) \
         == {"traceEvents": []}
 
 
-def test_timeline_disabled_flag(tmp_path):
-    from neuronx_distributed_tpu.utils.timeline import Timeline
-
-    tl = Timeline(str(tmp_path / "t.json"), enabled=False)
-    with tl.event("ignored"):
+def test_tracer_enabled_flag_toggles_recording(tmp_path):
+    tl = SpanTracer(enabled=False)
+    path = str(tmp_path / "t.json")
+    with tl.span("ignored"):
         pass
-    assert json.load(open(tl.save()))["traceEvents"] == []
+    assert json.load(open(tl.save(path)))["traceEvents"] == []
     tl.enabled = True
     assert tl.enabled
-    with tl.event("kept"):
+    with tl.span("kept"):
         pass
-    assert len(json.load(open(tl.save()))["traceEvents"]) == 1
+    assert len(json.load(open(tl.save(path)))["traceEvents"]) == 1
 
 
-def test_timeline_save_concurrent_with_writer_thread(tmp_path):
+def test_save_concurrent_with_writer_thread(tmp_path):
     """Regression: the old Timeline.save iterated the event list while a
-    writer thread appended (RuntimeError / torn JSON) and silently
-    dropped open spans. Every save must now produce valid JSON."""
-    from neuronx_distributed_tpu.utils.timeline import Timeline
-
-    tl = Timeline(str(tmp_path / "race.json"))
+    writer thread appended (RuntimeError / torn JSON). Every save must
+    produce valid JSON."""
+    tl = SpanTracer(max_events=64)      # the ring wraps under the saves
+    path = str(tmp_path / "race.json")
     stop = threading.Event()
     errors = []
 
@@ -301,8 +328,8 @@ def test_timeline_save_concurrent_with_writer_thread(tmp_path):
         i = 0
         try:
             while not stop.is_set():
-                tl.mark_event_start(f"ev{i % 7}")
-                tl.mark_event_end(f"ev{i % 7}")
+                with tl.span(f"ev{i % 7}"):
+                    pass
                 i += 1
         except Exception as e:  # pragma: no cover - the regression
             errors.append(e)
@@ -311,7 +338,7 @@ def test_timeline_save_concurrent_with_writer_thread(tmp_path):
     t.start()
     try:
         for _ in range(50):
-            with open(tl.save()) as f:
+            with open(tl.save(path)) as f:
                 trace = json.load(f)  # torn writes would fail to parse
             assert "traceEvents" in trace
     finally:
@@ -320,15 +347,59 @@ def test_timeline_save_concurrent_with_writer_thread(tmp_path):
     assert errors == []
 
 
-def test_timeline_save_emits_open_span_as_incomplete(tmp_path):
-    from neuronx_distributed_tpu.utils.timeline import Timeline
-
-    tl = Timeline(str(tmp_path / "open.json"))
-    tl.mark_event_start("open_span")
-    with open(tl.save()) as f:
+def test_save_emits_live_request_as_incomplete(tmp_path):
+    tl = SpanTracer()
+    tl.request_begin("open_req")
+    with open(tl.save(str(tmp_path / "open.json"))) as f:
         [ev] = json.load(f)["traceEvents"]
-    assert ev["name"] == "open_span" and ev["dur"] == 0.0
+    assert ev["name"] == "request:open_req" and ev["dur"] == 0.0
     assert ev["args"]["incomplete"] is True
+
+
+def _host_plane_names(logdir):
+    import glob
+
+    [path] = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    return {ev.name for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events}
+
+
+def test_profile_step_puts_spans_on_the_profilers_host_plane(tmp_path):
+    tracer = SpanTracer()
+    with tracer.span("outside/before"):
+        pass
+    with tracer.profile_step(str(tmp_path / "prof")):
+        assert tracer._annotate
+        with tracer.span("inside/annotated", step=1):
+            jnp.ones((4,)).block_until_ready()
+    assert not tracer._annotate
+    names = _host_plane_names(tmp_path / "prof")
+    assert "inside/annotated" in names and "profile_step" in names
+    assert "outside/before" not in names
+    # the tracer's own record is the same with and without the profiler
+    by_name = {ev["name"]: ev for ev in tracer.chrome_trace()["traceEvents"]}
+    assert by_name["inside/annotated"]["args"] == {
+        "step": 1, "parent": "profile_step"}
+    assert by_name["profile_step"]["args"]["logdir"].endswith("prof")
+
+
+def test_profiler_alone_does_not_annotate_spans(tmp_path):
+    """Outside ``profile_step`` a span is no profiler annotation, even
+    under a running profiler: the benchmark's traced segment starts the
+    profiler itself and must not see the package's spans twice."""
+    tracer = SpanTracer()
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        with tracer.span("plain/span"):
+            with jax.profiler.TraceAnnotation("bench/marker"):
+                jnp.ones((4,)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_plane_names(tmp_path / "prof")
+    assert "bench/marker" in names and "plain/span" not in names
+    assert [ev["name"] for ev in tracer.chrome_trace()["traceEvents"]] \
+        == ["plain/span"]
 
 
 # ---------------------------------------------------------------------------
@@ -434,6 +505,117 @@ def test_engine_compile_once_with_obs_enabled():
     names = set(obs.get_tracer().stats())
     assert {"engine/admission", "engine/packed",
             "engine/retirement"} <= names
+
+
+def _tiny_engine(**engine_kw):
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.inference.engine import (EngineConfig,
+                                                          ServingEngine)
+    from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
+                                                      tiny_config)
+
+    ps.initialize_model_parallel()
+    cfg = tiny_config(dtype=jnp.float32, param_dtype=jnp.float32,
+                      num_layers=2)
+    params = meta.unbox(LlamaForCausalLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    ecfg = EngineConfig(block_size=4, num_blocks=16, max_slots=2,
+                        max_blocks_per_seq=8, token_budget=8,
+                        kv_dtype=jnp.float32, **engine_kw)
+    eng = ServingEngine(cfg, params, ecfg)
+    rng = np.random.RandomState(0)
+    for i, (n, new) in enumerate([(11, 3), (5, 4), (7, 2)]):
+        eng.submit(rng.randint(0, cfg.vocab_size, (n,)).tolist(), new,
+                   uid=f"r{i}")
+    return eng
+
+
+def _engine_spans(tracer):
+    """The tracer's ``engine/`` events in the order they opened."""
+    events = [ev for ev in tracer.chrome_trace()["traceEvents"]
+              if ev["name"].startswith("engine/")]
+    return sorted(events, key=lambda ev: (ev["ts"], -ev["dur"]))
+
+
+def test_engine_packed_step_is_covered_by_flat_spans():
+    obs.enable()
+    eng = _tiny_engine()
+    eng.step()                          # compiles; the second is the subject
+    tracer = obs.get_tracer()
+    tracer.reset()
+    assert eng.step() > 0
+    spans = _engine_spans(tracer)
+    assert [ev["name"] for ev in spans] == [
+        "engine/admission", "engine/cow", "engine/hygiene",
+        "engine/tables", "engine/packed", "engine/packed/pack",
+        "engine/packed/dispatch", "engine/packed/fetch",
+        "engine/retirement", "engine/publish"]
+    parents = {ev["name"]: ev.get("args", {}).get("parent")
+               for ev in spans}
+    children = {n for n in parents if n.startswith("engine/packed/")}
+    assert all(parents[n] == "engine/packed" for n in children)
+    # flat otherwise: sched_ms_per_step reads the self time of admission,
+    # cow and retirement, so nothing may open inside them
+    assert all(parents[n] is None for n in parents if n not in children)
+    packed = next(ev for ev in spans if ev["name"] == "engine/packed")
+    inside = sum(ev["dur"] for ev in spans if ev["name"] in children)
+    assert inside <= packed["dur"]
+
+
+def test_engine_disaggregated_workers_name_children_by_parent():
+    obs.enable()
+    eng = _tiny_engine(disaggregated=True, prefill_budget=8)
+    eng.run()
+    names = set(obs.get_tracer().stats())
+    for parent in ("engine/prefill", "engine/decode"):
+        assert {parent, parent + "/pack", parent + "/dispatch",
+                parent + "/fetch"} <= names
+    assert not any(n.startswith("engine/packed") for n in names)
+    by_parent = {}
+    for ev in _engine_spans(obs.get_tracer()):
+        by_parent.setdefault(ev.get("args", {}).get("parent"),
+                             set()).add(ev["name"])
+    assert by_parent["engine/decode"] == {
+        "engine/decode/pack", "engine/decode/dispatch",
+        "engine/decode/fetch"}
+    # pad is each worker's width less its rows
+    rows = {c.labels["kind"]: c.value for c in obs.get_registry().get(
+        "nxd_engine_rows_total").children()}
+    st = obs.get_tracer().stats()
+    assert sum(rows.values()) == (
+        st["engine/prefill"]["count"] * 8
+        + st["engine/decode"]["count"] * eng.ecfg.max_slots)
+
+
+def test_engine_rows_counter_sums_to_steps_times_width():
+    obs.enable()
+    eng = _tiny_engine()
+    returned = []
+    while eng.has_work():
+        returned.append(eng.step())
+    assert all(r.status == "completed" for r in eng.results.values())
+    rows = {c.labels["kind"]: c.value for c in obs.get_registry().get(
+        "nxd_engine_rows_total").children()}
+    assert set(rows) == {"decode", "prefill", "pad"}
+    steps = sum(1 for n in returned if n)
+    assert steps == eng.stats.steps
+    assert sum(rows.values()) == steps * eng.ecfg.token_budget
+    assert rows["decode"] + rows["prefill"] == sum(returned)
+    assert rows["prefill"] == 11 + 5 + 7    # every prompt token, once
+    assert rows["pad"] > 0
+
+
+def test_engine_with_obs_off_records_no_span_and_no_rows_counter():
+    assert not obs.enabled()
+    eng = _tiny_engine()
+    eng.run()
+    assert eng.stats.steps > 0
+    tracer = obs.get_tracer()
+    assert tracer.chrome_trace()["traceEvents"] == []
+    assert tracer.stats() == {}
+    assert obs.get_registry().get("nxd_engine_rows_total") is None
+    assert eng._obs_cache is None
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""Aux utils: logger, timeline, tensor capture/replacement."""
+"""Aux utils: logger, chrome-trace export, tensor capture/replacement."""
 
 import json
 
@@ -12,7 +12,7 @@ from flax.core import meta
 from neuronx_distributed_tpu.parallel import mesh as ps
 from neuronx_distributed_tpu.utils import tensor_capture as tc
 from neuronx_distributed_tpu.utils.logger import get_logger, rmsg
-from neuronx_distributed_tpu.utils.timeline import Timeline
+from neuronx_distributed_tpu.obs.tracing import SpanTracer
 
 
 def test_logger_and_rmsg():
@@ -24,12 +24,12 @@ def test_logger_and_rmsg():
 
 
 def test_timeline_chrome_trace(tmp_path):
-    t = Timeline(str(tmp_path / "tl.json"))
-    with t.event("fwd"):
+    t = SpanTracer()
+    with t.span("fwd"):
         pass
-    t.mark_event_start("bwd")
-    t.mark_event_end("bwd")
-    p = t.save()
+    with t.span("bwd"):
+        pass
+    p = t.save(str(tmp_path / "tl.json"))
     data = json.load(open(p))
     names = [e["name"] for e in data["traceEvents"]]
     assert names == ["fwd", "bwd"]
