@@ -40,6 +40,7 @@ from ..obs.accounting import CompileTracker
 from ..obs.events import emit_event
 from ..obs.metrics import get_registry
 from ..obs.tracing import get_tracer
+from ..ops.paged_attention import column_live
 from ..utils.device import on_tpu
 from ..resilience.integrity import (
     IntegrityError,
@@ -686,6 +687,9 @@ class ServingEngine:
             np.int32)
         self._slot_blocks: List[List[int]] = (
             [[] for _ in range(engine_cfg.max_slots)])
+        #: live and skipped table columns of the rows packed since the
+        #: last publish (obs on only): ``nxd_paged_columns_total``
+        self._paged_cols = [0, 0]
         self._rng = rng if rng is not None else jax.random.key(0)
         self._clock = clock or time.monotonic
         self._t0 = self._clock()
@@ -1903,6 +1907,16 @@ class ServingEngine:
                 tokens[0, i] = tok
                 positions[0, i] = pos
                 slot_ids[i] = req.slot
+            if get_registry().enabled:
+                # what the paged kernel's walk finds in this batch; a pad
+                # row reads the last table row, as the forward's clip does
+                tbl = self._tables[np.minimum(slot_ids,
+                                              self._table_rows - 1)]
+                live = int(column_live(
+                    tbl, np.arange(tbl.shape[1]), positions[0][:, None],
+                    self.ecfg.block_size).sum())
+                self._paged_cols[0] += live
+                self._paged_cols[1] += tbl.size - live
         with tracer.span(span + "/dispatch"):
             if self._spec is not None:
                 sampled, self.cache, self.dcache = fn(
@@ -2241,8 +2255,10 @@ class ServingEngine:
                      prefill_rows: int, pad_rows: int) -> None:
         """Bridge :class:`EngineStats` into registry gauges, count the
         step's rows by kind where they were packed (over the steps that
-        ran a worker the three kinds sum to steps x worker width) and poll
-        the per-worker compile trackers. One bool check when obs is
+        ran a worker the three kinds sum to steps x worker width) and
+        their table columns by whether the paged kernel computes or skips
+        them (rows x ``max_blocks_per_seq``), and poll the per-worker
+        compile trackers. One bool check when obs is
         disabled; the no-host-callback invariant holds — everything here
         runs after the compiled workers returned. Child handles are
         cached against the registry's reset generation so the steady
@@ -2278,6 +2294,13 @@ class ServingEngine:
                 "filled them: a decoding slot's token, a prefill chunk's "
                 "token, or padding.",
                 labels=("kind",))
+            cols_c = reg.counter(
+                "nxd_paged_columns_total",
+                "Table columns of the serving workers' rows by what the "
+                "paged kernel's walk does with them: live (mapped and not "
+                "wholly behind the row's position) is computed, skipped "
+                "is not.",
+                labels=("kind",))
             cache = self._obs_cache = (
                 reg, reg.generation,
                 {f: stats_g.labels(field=f)
@@ -2286,8 +2309,9 @@ class ServingEngine:
                           "Unallocated KV blocks."),
                 step_h,
                 tuple(rows_c.labels(kind=k)
-                      for k in ("decode", "prefill", "pad")))
-        _, _, fields, free_g, step_h, rows_by_kind = cache
+                      for k in ("decode", "prefill", "pad")),
+                tuple(cols_c.labels(kind=k) for k in ("live", "skipped")))
+        _, _, fields, free_g, step_h, rows_by_kind, cols_by_kind = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
@@ -2296,6 +2320,9 @@ class ServingEngine:
         for child, n in zip(rows_by_kind,
                             (decode_rows, prefill_rows, pad_rows)):
             child.inc(n)
+        for child, n in zip(cols_by_kind, self._paged_cols):
+            child.inc(n)
+        self._paged_cols = [0, 0]
 
     def _retire(self, req: _RequestState, now: float) -> None:
         self._release(req)

@@ -125,21 +125,30 @@ def test_kernel_names_are_the_device_instruction_names(chip):
 
 # -- paged attention: the packed serving step's shapes ----------------------
 
-@pytest.mark.parametrize("tokens", [32, 256], ids=["T32", "T256"])
+# (tokens, kv heads, table columns, pool blocks): the smoke test's widths
+# at two packed widths, and the benchmark cell mixtral-8x7b.serve-batch
+_PAGED_SHAPES = {"T32": (32, 32, 16, 64), "T256": (256, 32, 16, 64),
+                 "serve_batch": (128, 8, 20, 320)}
+
+
+@pytest.mark.parametrize("shape", list(_PAGED_SHAPES))
 @pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
-def test_paged_attention(chip, quantized, tokens):
+def test_paged_attention(chip, quantized, shape):
     from neuronx_distributed_tpu.ops.paged_attention import (
         _paged_attention_pallas)
 
-    n, d, bs, kv, nb, cols = 32, 128, 128, 32, 64, 16
+    tokens, kv, cols, nb = _PAGED_SHAPES[shape]
+    n, d, bs = 32, 128, 128
     pool = chip((nb, bs, kv, d), jnp.int8 if quantized else jnp.bfloat16)
     scale = chip((nb, bs, kv), jnp.float32) if quantized else None
     fn = functools.partial(_paged_attention_pallas,
                            scale=1.0 / math.sqrt(d), interpret=False)
-    _assert_kernel_compiles(
+    text = _assert_kernel_compiles(
         fn, chip((tokens, n, d), jnp.bfloat16), pool, pool,
         chip((nb, bs), jnp.int32), chip((tokens, cols), jnp.int32),
         chip((tokens,), jnp.int32), scale, scale)
+    # the benchmark's readers and its `correct` find the kernel by name
+    assert _kernel_instruction_names(text) == {"paged_attention"}
 
 
 # -- grouped GLU decode (MoE serving) at OLMoE's widths (ROADMAP R1): hidden

@@ -606,6 +606,41 @@ def test_engine_rows_counter_sums_to_steps_times_width():
     assert rows["pad"] > 0
 
 
+def test_engine_paged_columns_counter_sums_to_rows_times_columns():
+    """Each packed step's ``nxd_paged_columns_total`` children sum to the
+    worker's width x ``max_blocks_per_seq``, and ``live`` is what
+    :func:`column_live` counts over the rows the step packed (a pad row
+    reads the last slot's table at PAD_POSITION, as the forward does)."""
+    from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
+    from neuronx_distributed_tpu.ops.paged_attention import column_live
+
+    obs.enable()
+    eng = _tiny_engine()
+    width, maxb = eng.ecfg.token_budget, eng.ecfg.max_blocks_per_seq
+    packed = []
+    run_worker = eng._run_worker
+
+    def spy(fn, rows, *args):
+        pads = [(-1, PAD_POSITION)] * (width - len(rows))
+        packed.append(sum(
+            int(column_live(eng._tables[slot], np.arange(maxb), pos,
+                            eng.ecfg.block_size).sum())
+            for slot, pos in [(r[0].slot, r[2]) for r in rows] + pads))
+        return run_worker(fn, rows, *args)
+
+    eng._run_worker = spy
+    before = {"live": 0, "skipped": 0}
+    while eng.has_work():
+        if not eng.step():
+            continue
+        now = {c.labels["kind"]: c.value for c in obs.get_registry().get(
+            "nxd_paged_columns_total").children()}
+        assert now["live"] - before["live"] == packed.pop() > 0
+        assert sum(now.values()) - sum(before.values()) == width * maxb
+        before = now
+    assert before["skipped"] > before["live"]
+
+
 def test_engine_with_obs_off_records_no_span_and_no_rows_counter():
     assert not obs.enabled()
     eng = _tiny_engine()
@@ -615,6 +650,7 @@ def test_engine_with_obs_off_records_no_span_and_no_rows_counter():
     assert tracer.chrome_trace()["traceEvents"] == []
     assert tracer.stats() == {}
     assert obs.get_registry().get("nxd_engine_rows_total") is None
+    assert obs.get_registry().get("nxd_paged_columns_total") is None
     assert eng._obs_cache is None
 
 

@@ -19,7 +19,9 @@ from neuronx_distributed_tpu.inference.paging import (
 from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
                                                   llama_forward_with_cache,
                                                   tiny_config)
-from neuronx_distributed_tpu.ops.paged_attention import paged_attention
+from neuronx_distributed_tpu.ops.paged_attention import (_paged_walk,
+                                                          column_live,
+                                                          paged_attention)
 from neuronx_distributed_tpu.parallel import mesh as ps
 
 
@@ -92,15 +94,45 @@ def test_write_pool_rows_drops_invalid_rows():
 # paged attention op
 # ---------------------------------------------------------------------------
 
-def _rand_pool(rng, quantized=False):
-    T, N, D, NB, BS, KV, MAXB = 5, 4, 16, 8, 4, 2, 3
-    q = jnp.asarray(rng.randn(T, N, D).astype(np.float32))
-    k = jnp.asarray(rng.randn(NB, BS, KV, D).astype(np.float32))
-    v = jnp.asarray(rng.randn(NB, BS, KV, D).astype(np.float32))
-    pool_pos = jnp.asarray(rng.randint(0, 12, (NB, BS)).astype(np.int32))
-    pool_pos = pool_pos.at[0, 2].set(PAD_POSITION)
-    tables = jnp.asarray(rng.randint(-1, NB, (T, MAXB)).astype(np.int32))
-    q_pos = jnp.asarray(rng.randint(0, 12, (T,)).astype(np.int32))
+# Three sequences in one pool laid out as the engine writes it: position
+# ``p`` of a sequence sits in its table's column ``p // BS``, slot
+# ``p % BS``; every other slot holds PAD_POSITION. "a" fills all four
+# columns; "b" has 6 tokens and a third block mapped ahead of its chunks,
+# still empty; "c" shares a's first two blocks (a forked prefix) and goes
+# on in a block of its own; "hole" is b with its second column unmapped.
+_BS, _MAXB, _NB = 4, 4, 12
+_TABLES = {"a": [1, 2, 3, 4], "b": [5, 6, 7, -1], "c": [1, 2, 8, -1],
+           "hole": [5, -1, 7, -1], "unmapped": [-1] * _MAXB}
+_FILLED = {1: 4, 2: 4, 3: 4, 4: 4, 5: 4, 6: 2, 8: 3}   # block -> tokens
+
+#: case -> rows of (table, q_pos)
+_RAGGED_CASES = {
+    "block_first_position": [("a", 4), ("a", 8), ("b", 4), ("c", 8)],
+    "block_last_position": [("a", 3), ("a", 7), ("b", 3), ("c", 7)],
+    "one_live_column": [("a", 0), ("b", 2), ("c", 3)],
+    "all_columns_live": [("a", 15), ("a", 12)],
+    "shared_table": [("a", 9), ("a", 9), ("c", 9), ("a", 5), ("c", 5)],
+    "pad_row": [("a", 6), ("unmapped", PAD_POSITION), ("b", 5)],
+    "beyond_table": [("a", 16), ("a", 4 * _BS * _MAXB), ("b", 16)],
+    "ragged": [("a", 13), ("b", 5), ("hole", 5), ("c", 10), ("b", 0),
+               ("hole", 1), ("a", 7)],
+}
+
+
+def _paged_case(rows, quantized=False, seed=1):
+    rng = np.random.RandomState(seed)
+    N, D, KV = 4, 16, 2
+    q = jnp.asarray(rng.randn(len(rows), N, D).astype(np.float32))
+    k = jnp.asarray(rng.randn(_NB, _BS, KV, D).astype(np.float32))
+    v = jnp.asarray(rng.randn(_NB, _BS, KV, D).astype(np.float32))
+    pool_pos = np.full((_NB, _BS), PAD_POSITION, np.int32)
+    for table in _TABLES.values():
+        for col, blk in enumerate(table):
+            n = _FILLED.get(blk, 0)
+            pool_pos[blk, :n] = col * _BS + np.arange(n)
+    tables = jnp.asarray([_TABLES[name] for name, _ in rows], jnp.int32)
+    q_pos = jnp.asarray([pos for _, pos in rows], jnp.int32)
+    pool_pos = jnp.asarray(pool_pos)
     if not quantized:
         return q, k, v, pool_pos, tables, q_pos, None, None
     kq, ks = quantize_kv(k)
@@ -108,20 +140,87 @@ def _rand_pool(rng, quantized=False):
     return q, kq, vq, pool_pos, tables, q_pos, ks, vs
 
 
-@pytest.mark.parametrize("quantized", [False, True])
-def test_paged_attention_pallas_interpret_matches_xla(quantized):
-    q, k, v, pp, tb, qp, ks, vs = _rand_pool(np.random.RandomState(1),
-                                             quantized)
+@pytest.mark.parametrize("case", list(_RAGGED_CASES))
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+def test_paged_attention_pallas_interpret_matches_xla(quantized, case):
+    rows = _RAGGED_CASES[case]
+    q, k, v, pp, tb, qp, ks, vs = _paged_case(rows, quantized)
     ref = paged_attention(q, k, v, pp, tb, qp, k_scale=ks, v_scale=vs,
                           force_pallas=False)
     ker = paged_attention(q, k, v, pp, tb, qp, k_scale=ks, v_scale=vs,
                           force_pallas=True)
-    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
+    # a row with no mapped column: the kernel writes zeros, the reference
+    # a uniform average over whatever block 0 holds; the engine drops both
+    real = np.asarray([name != "unmapped" for name, _ in rows])
+    np.testing.assert_allclose(np.asarray(ker)[real], np.asarray(ref)[real],
                                rtol=1e-5, atol=1e-5)
+    assert not np.asarray(ker)[~real].any()
+
+
+@pytest.mark.parametrize("rewire", ["other_block", "unmapped"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+def test_paged_attention_ignores_columns_behind_the_row(quantized, rewire):
+    """What the walk skips cannot matter: every column beyond
+    ``q_pos // block_size`` rewired to a block full of another sequence's
+    live positions, or to -1, leaves the output bitwise the same."""
+    rows = [r for case in _RAGGED_CASES.values() for r in case]
+    q, k, v, pp, tb, qp, ks, vs = _paged_case(rows, quantized, seed=3)
+    behind = np.arange(_MAXB)[None, :] > (np.asarray(qp) // _BS)[:, None]
+    assert behind.any() and not behind.all()
+    rewired = np.where(behind, 1 if rewire == "other_block" else -1,
+                       np.asarray(tb))
+    assert (rewired != np.asarray(tb)).any()
+    out, out_rewired = (
+        paged_attention(q, k, v, pp, jnp.asarray(t, jnp.int32), qp,
+                        k_scale=ks, v_scale=vs, force_pallas=True)
+        for t in (tb, rewired))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_rewired))
+
+
+def test_column_live_matches_brute_count():
+    """A column is live iff it is mapped and holds at least one position
+    the row may attend to, counted position by position."""
+    rng = np.random.RandomState(4)
+    bs, maxb, rows = 4, 5, 64
+    tables = rng.randint(-1, 6, (rows, maxb))
+    q_pos = rng.randint(0, bs * (maxb + 2), (rows,))
+    q_pos[:4] = [0, bs - 1, bs, PAD_POSITION]
+    got = column_live(tables, np.arange(maxb), q_pos[:, None], bs)
+    want = np.zeros((rows, maxb), bool)
+    for r in range(rows):
+        for c in range(maxb):
+            want[r, c] = tables[r, c] >= 0 and any(
+                p <= q_pos[r] for p in range(c * bs, (c + 1) * bs))
+    np.testing.assert_array_equal(got, want)
+    # with the mapped columns a prefix, as the engine maps them, the count
+    # has a closed form
+    prefix = np.sort(tables >= 0, axis=1)[:, ::-1]
+    np.testing.assert_array_equal(
+        column_live(np.where(prefix, 0, -1), np.arange(maxb),
+                    q_pos[:, None], bs).sum(axis=1),
+        np.minimum(q_pos // bs + 1, prefix.sum(axis=1)))
+
+
+def test_paged_walk_fetches_live_columns_and_repeats_behind_them():
+    """The kernel's walk: a live column names its own block; a skipped one
+    carries the complement of the block it names, which behind the row's
+    last causal column is that column's (no fresh DMA), 0 if unmapped."""
+    bs = 4
+    tables = np.asarray([[3, 5, 7, 9], [3, -1, 7, -1], [-1] * 4,
+                         [0, 2, -1, -1]])
+    q_pos = np.asarray([6, 9, PAD_POSITION, 40])
+    walk = np.asarray(_paged_walk(jnp.asarray(tables, jnp.int32),
+                                  jnp.asarray(q_pos, jnp.int32), bs))
+    assert walk.tolist() == [[3, 5, ~5, ~5], [3, ~0, 7, ~7], [~0] * 4,
+                             [0, 2, ~0, ~0]]
+    live = column_live(tables, np.arange(4), q_pos[:, None], bs)
+    np.testing.assert_array_equal(walk >= 0, live)
+    np.testing.assert_array_equal(walk[live], tables[live])
 
 
 def test_paged_attention_validates_scales_and_heads():
-    q, k, v, pp, tb, qp, ks, vs = _rand_pool(np.random.RandomState(2), True)
+    q, k, v, pp, tb, qp, ks, vs = _paged_case(_RAGGED_CASES["ragged"], True,
+                                              seed=2)
     with pytest.raises(ValueError):
         paged_attention(q, k, v, pp, tb, qp, k_scale=ks)  # missing v_scale
     with pytest.raises(ValueError):
