@@ -10,46 +10,26 @@ the package under test. It follows the published descriptions:
   two selected router logits, and every token reaches both of its experts
   (no capacity, no dropped token).
 
-It reads the system's own parameter tree (the names and layouts listed in
-``_LAYOUT``), one layer at a time and for experts one expert at a time,
-upcast to float32, so the published widths fit beside the served weights.
+It reads ``weights(name, layer=None, expert=None)``: the published
+checkpoint's tensors in float32 and in the checkpoint's orientation (a
+projection is ``[out, in]``) -- ``embedding``, ``final_norm``, ``lm_head``;
+a layer's ``input_norm``, ``post_norm``, ``q_proj``, ``k_proj``, ``v_proj``,
+``o_proj``, ``router``; ``gate``, ``up`` and ``down`` of a layer or of one
+of its experts -- one layer and one expert at a time, so the published
+widths fit beside the served weights. How the system stores them is the
+business of ``families/<family>.py`` ``published``; sizes and constants
+come from the configuration file's published keys.
 On a TPU a float32 matmul runs in reduced precision unless told otherwise:
 everything here runs under ``jax.default_matmul_precision("highest")``.
 
-Departures from the papers, each because the system's checkpoint layout
-asks for it: rotary embedding in the half-split form (the HuggingFace
-layout of these checkpoints), gate and up projections read from one fused
-``[hidden, 2, intermediate]`` kernel.
+Departure from the papers: rotary embedding in the half-split form (the
+HuggingFace layout of these checkpoints).
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-# where each tensor sits in the system's tree: params["params"][...]
-_LAYOUT = {
-    "embedding": ("model", "embed", "embedding"),        # [V, H]
-    "final_norm": ("model", "norm", "scale"),            # [H]
-    "lm_head": ("lm_head", "kernel"),                    # [H, V]
-    "layers": ("model", "layers", "layer"),              # leaves lead with [L]
-}
-
-
-def _get(tree, path):
-    for k in path:
-        tree = tree[k]
-    return tree
-
-
-def _f32(x):
-    return jnp.asarray(x, jnp.float32)
-
-
-def _at(layers, index, *path):
-    """One layer's (or one expert's) tensor, sliced out and upcast only
-    when it is used, so no more than one of them is held in float32."""
-    return _f32(_get(layers, path)[index])
 
 
 def rms_norm(x, scale, eps):
@@ -67,13 +47,12 @@ def rotary(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def attention(x, layers, li, n_heads, n_kv, theta):
+def attention(x, weights, li, n_heads, n_kv, theta):
     """Causal grouped-query attention over one sequence ``[S, H]``."""
     s = x.shape[0]
-    q = (x @ _at(layers, li, "attn", "qkv", "q_kernel")).reshape(
-        s, n_heads, -1)
-    k = (x @ _at(layers, li, "attn", "qkv", "k_kernel")).reshape(s, n_kv, -1)
-    v = (x @ _at(layers, li, "attn", "qkv", "v_kernel")).reshape(s, n_kv, -1)
+    q = (x @ weights("q_proj", li).T).reshape(s, n_heads, -1)
+    k = (x @ weights("k_proj", li).T).reshape(s, n_kv, -1)
+    v = (x @ weights("v_proj", li).T).reshape(s, n_kv, -1)
     d = q.shape[-1]
     q, k = rotary(q, theta), rotary(k, theta)
     rep = n_heads // n_kv                      # query head i reads kv head i // rep
@@ -82,66 +61,64 @@ def attention(x, layers, li, n_heads, n_kv, theta):
     causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
     scores = jnp.where(causal[None], scores, -jnp.inf)
     out = jnp.einsum("nqk,knd->qnd", jax.nn.softmax(scores, axis=-1), v)
-    return out.reshape(s, n_heads * d) @ _at(
-        layers, li, "attn", "o_proj", "kernel")
+    return out.reshape(s, n_heads * d) @ weights("o_proj", li).T
 
 
-def swiglu(x, gate_up, down):
-    """``gate_up [H, 2, I]`` (gate first), ``down [I, H]``."""
+def swiglu(x, weights, li, expert=None):
+    """``down(silu(gate(x)) * up(x))`` of a layer's MLP or of one expert;
+    gate and up in one contraction over the two stacked ``[H, 2, I]``."""
+    gate_up = jnp.stack([weights("gate", li, expert).T,
+                         weights("up", li, expert).T], axis=1)
     gu = jnp.einsum("sh,hki->ski", x, gate_up)
-    return (jax.nn.silu(gu[:, 0]) * gu[:, 1]) @ down
+    return (jax.nn.silu(gu[:, 0]) * gu[:, 1]) @ weights("down", li, expert).T
 
 
-def mixtral_block(x, layers, li, top_k):
+def mixtral_block(x, weights, li, top_k):
     """Every expert's SwiGLU, weighted by the softmax over the token's
     ``top_k`` router logits; an unselected expert weighs 0."""
-    logits = x @ _at(layers, li, "moe", "router", "kernel")     # [S, E]
+    logits = x @ weights("router", li).T                    # [S, E]
     top, idx = jax.lax.top_k(logits, top_k)
     gates = jax.nn.softmax(top, axis=-1)                    # [S, K]
     n_experts = logits.shape[-1]
     weight = jnp.sum(jax.nn.one_hot(idx, n_experts) * gates[..., None], 1)
     y = jnp.zeros_like(x)
     for e in range(n_experts):
-        y = y + weight[:, e:e + 1] * swiglu(
-            x, _at(layers, (li, e), "moe", "experts", "gate_up"),
-            _at(layers, (li, e), "moe", "experts", "down"))
+        y = y + weight[:, e:e + 1] * swiglu(x, weights, li, e)
     return y, logits
 
 
-def forward(params, tokens, *, num_heads, num_kv_heads, rope_theta, rms_eps,
-            top_k=0):
-    """Logits ``[B, S, V]`` (float32) for ``tokens [B, S]``.
+def forward(weights, tokens, config):
+    """Logits ``[B, S, V]`` (float32) for ``tokens [B, S]``; ``config`` is
+    the configuration file's dict (the published keys).
 
-    Also returns, for a mixture-of-experts tree, each layer's router
-    margin ``[B, L, S]``: the gap between the last selected and the first
+    Also returns, for a mixture of experts, each layer's router margin
+    ``[B, L, S]``: the gap between the last selected and the first
     unselected router logit, so the comparison can tell a token whose
     routing is decided by rounding."""
-    p = params["params"]
-    layers = _get(p, _LAYOUT["layers"])
-    depth = jax.tree_util.tree_leaves(layers)[0].shape[0]
-    embedding = _f32(_get(p, _LAYOUT["embedding"]))
+    heads, kv_heads = (config["num_attention_heads"],
+                       config["num_key_value_heads"])
+    theta, eps = float(config["rope_theta"]), float(config["rms_norm_eps"])
+    top_k = int(config.get("num_experts_per_tok", 0))
+    sparse = bool(config.get("num_local_experts"))
     out, margins = [], []
     with jax.default_matmul_precision("highest"):
+        embedding = weights("embedding")
         for seq in tokens:
             x = embedding[jnp.asarray(seq)]
             seq_margins = []
-            for li in range(depth):
-                h = rms_norm(x, _at(layers, li, "input_norm", "scale"),
-                             rms_eps)
-                x = x + attention(h, layers, li, num_heads, num_kv_heads,
-                                  rope_theta)
-                h = rms_norm(x, _at(layers, li, "post_norm", "scale"),
-                             rms_eps)
-                if "moe" in layers:
-                    y, logits = mixtral_block(h, layers, li, top_k)
+            for li in range(config["num_hidden_layers"]):
+                h = rms_norm(x, weights("input_norm", li), eps)
+                x = x + attention(h, weights, li, heads, kv_heads, theta)
+                h = rms_norm(x, weights("post_norm", li), eps)
+                if sparse:
+                    y, logits = mixtral_block(h, weights, li, top_k)
                     ranked = jnp.sort(logits, axis=-1)[:, ::-1]
                     seq_margins.append(ranked[:, top_k - 1] - ranked[:, top_k])
                 else:
-                    y = swiglu(h, _at(layers, li, "mlp", "gate_up_kernel"),
-                               _at(layers, li, "mlp", "down", "kernel"))
+                    y = swiglu(h, weights, li)
                 x = x + y
-            x = rms_norm(x, _f32(_get(p, _LAYOUT["final_norm"])), rms_eps)
-            out.append(x @ _f32(_get(p, _LAYOUT["lm_head"])))
+            x = rms_norm(x, weights("final_norm"), eps)
+            out.append(x @ weights("lm_head").T)
             if seq_margins:
                 margins.append(jnp.stack(seq_margins))
     return jnp.stack(out), (jnp.stack(margins) if margins else None)

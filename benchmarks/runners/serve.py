@@ -53,15 +53,13 @@ def _engine_config(s: dict, dtype):
         token_budget=s["token_budget"], kv_dtype=dtype)
 
 
-def run(cell) -> RunResult:
+def prepare(cell):
+    """``(mcfg, forward, params, ecfg)``: the cell's model under its serve
+    settings, its weights from ``cell.seed`` and the engine's geometry."""
     import jax
     import jax.numpy as jnp
     from flax.core import meta
 
-    from neuronx_distributed_tpu import obs
-    from neuronx_distributed_tpu.inference.engine import ServingEngine
-    from neuronx_distributed_tpu.ops.paged_attention import (
-        paged_attention_impl)
     from neuronx_distributed_tpu.parallel import mesh as ps
 
     settings = cell.config["serve"]
@@ -71,7 +69,27 @@ def run(cell) -> RunResult:
     mcfg, model, forward = models.build(
         cell.config, dtype=dtype, param_dtype=dtype,
         **settings.get("model", {}))
-    ecfg = _engine_config(settings, dtype)
+    say("serve", device_ready_s=round(cell.clock(), 2))
+    shapes = meta.unbox(jax.eval_shape(
+        model.init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    params = harness.make_weights(shapes, cell.seed,
+                                  float(cell.config["initializer_range"]))
+    jax.block_until_ready(params)
+    say("serve", weights_s=round(cell.clock(), 2),
+        params=sum(x.size for x in jax.tree_util.tree_leaves(params)))
+    return mcfg, forward, params, _engine_config(settings, dtype)
+
+
+def run(cell) -> RunResult:
+    import jax
+
+    from neuronx_distributed_tpu import obs
+    from neuronx_distributed_tpu.inference.engine import ServingEngine
+    from neuronx_distributed_tpu.ops.paged_attention import (
+        paged_attention_impl)
+
+    settings = cell.config["serve"]
+    mcfg, forward, params, ecfg = prepare(cell)
     impl = paged_attention_impl(mcfg.head_dim_, ecfg.block_size,
                                 mcfg.attn_force_pallas)
     want_impl = settings["paged_attention"]
@@ -85,15 +103,6 @@ def run(cell) -> RunResult:
                            cell.seconds + trace_s)
     requests, lead_in = traffic["requests"], traffic["lead_in_s"]
     open_loop = traffic["open_loop"]
-
-    say("serve", device_ready_s=round(cell.clock(), 2))
-    shapes = meta.unbox(jax.eval_shape(
-        model.init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
-    params = harness.make_weights(shapes, cell.seed,
-                                  float(cell.config["initializer_range"]))
-    jax.block_until_ready(params)
-    say("serve", weights_s=round(cell.clock(), 2),
-        params=sum(x.size for x in jax.tree_util.tree_leaves(params)))
 
     if cell.trace:
         obs.enable()
@@ -346,11 +355,10 @@ def probe_schedule(prompt_len: int, decode: int, width: int
     return steps
 
 
-def check_logits(cell, mcfg, forward, params, ecfg, settings) -> List[str]:
+def probe_logits(seed: int, mcfg, forward, params, ecfg, chk):
     """Two seeded sequences through the paged forward the engine's packed
     step runs (same pool geometry, same width, prefill chunks beside
-    decode rows and pad rows), every position's logits against the plain
-    reference's full forward."""
+    decode rows and pad rows): ``(tokens [2, S], logits [2, S, V])``."""
     import functools
 
     import jax
@@ -358,12 +366,10 @@ def check_logits(cell, mcfg, forward, params, ecfg, settings) -> List[str]:
 
     from neuronx_distributed_tpu.inference import paging
     from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
-    from reference import decoder_f32
 
-    chk = settings["logit_check"]
     plen, ndec, width = chk["prompt_tokens"], chk["decode_steps"], \
         ecfg.token_budget
-    rng = np.random.default_rng([cell.seed, 1])
+    rng = np.random.default_rng([seed, 1])
     seqs = rng.integers(0, mcfg.vocab_size, (2, plen + ndec))
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -394,14 +400,27 @@ def check_logits(cell, mcfg, forward, params, ecfg, settings) -> List[str]:
         for i, (s, p) in enumerate(rows):
             got[s, p] = logits[i]
     del cache
-    want, margins = decoder_f32.forward(
-        params, seqs, **models.reference_kwargs(cell.config))
-    want = np.asarray(want)
-    # errors in units of the reference logits' spread, so that one pair
-    # of tolerances serves every width
+    return seqs, got
+
+
+def logit_errors(got, want, chk):
+    """``(scale, err [2, S], parts)``: the largest logit difference at each
+    position in units of the reference logits' spread, so that one pair of
+    tolerances serves every width; ``parts`` are the prefill and the decode
+    positions' errors."""
+    plen = chk["prompt_tokens"]
     scale = float(np.std(want))
     err = np.abs(got - want).max(axis=-1) / scale      # [2, plen + ndec]
-    parts = {"prefill": err[:, :plen].ravel(), "decode": err[:, plen:].ravel()}
+    return scale, err, {"prefill": err[:, :plen].ravel(),
+                        "decode": err[:, plen:].ravel()}
+
+
+def judge_logits(got, want, chk) -> List[str]:
+    """Hold logits ``[2, S, V]`` to the configuration's ``logit_check``
+    against the reference's: the reasons they fail it, if any. Each number
+    compared is printed beside its limit."""
+    plen = chk["prompt_tokens"]
+    scale, err, parts = logit_errors(got, want, chk)
     why = []
     for part, e in parts.items():
         typical = float(np.median(e))
@@ -411,6 +430,9 @@ def check_logits(cell, mcfg, forward, params, ecfg, settings) -> List[str]:
             p99_rel_err=round(float(np.percentile(e, 99)), 5),
             max_rel_err=round(float(e.max()), 5),
             share_over_outlier_rtol=round(outliers, 5))
+        say("limits", part=part, median_rel_err=chk["typical_rtol"],
+            share_over_outlier_rtol=chk["outlier_share"][part],
+            outlier_rtol=chk["outlier_rtol"])
         if not np.isfinite(e).all():
             why.append(f"logit check ({part}): non-finite logits")
         if typical > chk["typical_rtol"]:
@@ -425,6 +447,17 @@ def check_logits(cell, mcfg, forward, params, ecfg, settings) -> List[str]:
         round(float(x), 4) for x in err[0, :4]],
         median_by_quarter=[round(float(np.median(q)), 4)
                            for q in np.array_split(err[0, :plen], 4)])
+    return why
+
+
+def check_logits(cell, mcfg, forward, params, ecfg, settings) -> List[str]:
+    """Every position's logits of ``probe_logits`` against the plain
+    reference's full forward."""
+    chk = settings["logit_check"]
+    seqs, got = probe_logits(cell.seed, mcfg, forward, params, ecfg, chk)
+    want, margins = models.reference(cell.config).forward(
+        models.published(params, cell.config), seqs, cell.config)
+    why = judge_logits(got, np.asarray(want), chk)
     if margins is not None:
         m = np.asarray(margins)
         say("check", router_margin_p01=round(float(np.percentile(m, 1)), 5),

@@ -77,7 +77,6 @@ def run(cell) -> RunResult:
         initialize_parallel_model, initialize_parallel_optimizer,
         make_train_step)
     from neuronx_distributed_tpu.utils.device import on_tpu
-    from reference import decoder_f32
 
     settings, mix = cell.config["train"], cell.traffic
     seq, per_step = int(mix["seq_len"]), int(mix["batch"]) * int(mix["seq_len"])
@@ -113,6 +112,7 @@ def run(cell) -> RunResult:
     # ---- the loss check, while the optimizer state is not there yet ----
     t_check = time.perf_counter()
     atol = float(settings["loss_check"]["atol"])
+    reference = models.reference(cell.config)
     loss_fn = jax.jit(lambda p, b: pm.module.apply(
         p, b["input_ids"], b["labels"], method="loss"))
     why = []
@@ -122,9 +122,10 @@ def run(cell) -> RunResult:
     for i in range(int(settings["loss_check"]["sequences"])):
         one = {k: v[i:i + 1] for k, v in first.items()}
         got = float(loss_fn(params, one))
-        want_logits, _ = decoder_f32.forward(
-            params, one["input_ids"], **models.reference_kwargs(cell.config))
-        want = float(decoder_f32.cross_entropy(want_logits, one["labels"]))
+        want_logits, _ = reference.forward(
+            models.published(params, cell.config), one["input_ids"],
+            cell.config)
+        want = float(reference.cross_entropy(want_logits, one["labels"]))
         del want_logits
         say("check", sequence=i, loss=got, reference_loss=want,
             abs_diff=abs(got - want), atol=atol)

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -24,6 +25,7 @@ sys.path.insert(0, ROOT)
 import harness  # noqa: E402
 from generators import requests as gen_requests  # noqa: E402
 from generators import token_batches  # noqa: E402
+from runners import models  # noqa: E402
 from tracereduce import xplane  # noqa: E402
 
 MANIFESTS = [harness.MANIFEST, os.path.join(HERE, "manifest.json")]
@@ -108,6 +110,11 @@ def test_every_name_resolves_to_its_files(path):
         harness.load_plugin("runners", cell.config["runner"]).run
         gen = harness.load_plugin("generators", cell.traffic["kind"])
         assert callable(gen.generate)
+        family = harness.load_plugin("families", cell.config["family"])
+        assert callable(family.build) and callable(family.published)
+        reference = models.reference(cell.config)
+        assert callable(reference.forward)
+        assert callable(reference.cross_entropy)
     for x in m["per_layer"]:
         spec = harness.read_json(harness.data_file("layer_metrics", x["name"]))
         for k in ("name", "layer", "unit", "better", "source", "moves"):
@@ -116,19 +123,54 @@ def test_every_name_resolves_to_its_files(path):
             "readers", spec["reader"]["kind"]).read)
 
 
-def test_configurations_cut_depth_only():
-    m = harness.load_manifest()
-    for c in m["configs"]:
+# the published (hidden, intermediate, heads, kv heads) of the configurations
+# this file knows; one it does not know is held to the general rules alone
+PUBLISHED_WIDTHS = {"mixtral-8x7b": (4096, 14336, 32, 8),
+                    "mistral-7b": (4096, 14336, 32, 8),
+                    "mistral-7b-serve": (4096, 14336, 32, 8)}
+WIDTH_KEY = re.compile(r"hidden_size|intermediate|latent|state|proj|head_|"
+                       r"_dim$|_rank$|expan|per_tok")
+
+
+def _check_configurations(manifest: dict):
+    """Every configuration states its source, what it stands for and what
+    it assumed, cuts no width, and runs at the size its ``reduced`` says."""
+    for c in manifest["configs"]:
         cfg = harness.read_json(os.path.join(ROOT, c["file"]))
-        assert c["reduced"] == ["num_hidden_layers"] == list(cfg["reduced"])
+        assert c["reduced"] == list(cfg["reduced"]), c["name"]
         assert cfg["source"] == c["source"] and "stands_for" in cfg
         assert "assumed" in cfg and not cfg.get("rehearsal")
-        # the published widths of both models
-        assert (cfg["hidden_size"], cfg["intermediate_size"],
-                cfg["num_attention_heads"], cfg["num_key_value_heads"]) == (
-                    4096, 14336, 32, 8)
-        assert cfg["reduced"]["num_hidden_layers"]["to"] == \
-            cfg["num_hidden_layers"]
+        for key, cut in cfg["reduced"].items():
+            assert not WIDTH_KEY.search(key), (c["name"], key)
+            assert cut["to"] == cfg[key] != cut["from"], (c["name"], key)
+        if c["name"] in PUBLISHED_WIDTHS:
+            assert (cfg["hidden_size"], cfg["intermediate_size"],
+                    cfg["num_attention_heads"], cfg["num_key_value_heads"]
+                    ) == PUBLISHED_WIDTHS[c["name"]]
+            assert c["reduced"] == ["num_hidden_layers"]
+
+
+def test_configurations_cut_depth_only(tmp_path):
+    m = harness.load_manifest()
+    assert set(PUBLISHED_WIDTHS) <= {c["name"] for c in m["configs"]}
+    _check_configurations(m)
+    # a later PR's configuration of other widths, uncut, passes as it comes
+    added = dict(harness.read_json(os.path.join(
+        ROOT, "benchmarks", "configs", "mistral-7b-serve.json")),
+        source="https://example.org/added", hidden_size=2048,
+        intermediate_size=1024, num_attention_heads=16,
+        num_key_value_heads=16, num_hidden_layers=16, reduced={})
+    (tmp_path / "added.json").write_text(json.dumps(added))
+    m["configs"].append({"name": "added", "source": added["source"],
+                         "file": str(tmp_path / "added.json"), "reduced": [],
+                         "why": "added by the test"})
+    _check_configurations(m)
+    # and one that cuts a width does not
+    added["reduced"] = {"hidden_size": {"from": 4096, "to": 2048}}
+    (tmp_path / "added.json").write_text(json.dumps(added))
+    m["configs"][-1]["reduced"] = ["hidden_size"]
+    with pytest.raises(AssertionError):
+        _check_configurations(m)
     peaks = harness.peaks_for("TPU v5 lite")
     assert peaks["bf16_flops_per_s"] == 197e12
     assert peaks["hbm_bytes_per_s"] == 819e9
@@ -304,26 +346,31 @@ def test_probe_schedule_feeds_every_position_once():
 
 # -- the reference against the package's models ---------------------------------------
 
+TINY = {"vocab_size": 256, "hidden_size": 64, "intermediate_size": 128,
+        "num_hidden_layers": 2, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "rope_theta": 1e6, "rms_norm_eps": 1e-5,
+        "max_position_embeddings": 128}
+TINY_MOE = dict(TINY, num_local_experts=8, num_experts_per_tok=2)
+
+
 @pytest.mark.parametrize("family", ["llama", "mixtral"])
 def test_reference_agrees_with_the_package(family):
     import jax
     import jax.numpy as jnp
     from flax.core import meta
 
-    from neuronx_distributed_tpu.models import llama, mixtral
     from neuronx_distributed_tpu.parallel import mesh as ps
-    from reference import decoder_f32
 
     ps.destroy_model_parallel()
     ps.initialize_model_parallel()
-    kw = dict(dtype=jnp.float32, param_dtype=jnp.float32, rope_theta=1e6)
+    kw = dict(dtype=jnp.float32, param_dtype=jnp.float32)
     if family == "llama":
-        cfg = llama.tiny_config(**kw)
-        model = llama.LlamaForCausalLM(cfg)
+        config = dict(TINY, family="llama")
     else:
         # 8 experts at a capacity that drops nothing: the published block
-        cfg = mixtral.tiny_moe_config(num_experts=8, capacity_factor=4.0, **kw)
-        model = mixtral.MixtralForCausalLM(cfg)
+        config = dict(TINY_MOE, family="mixtral")
+        kw["capacity_factor"] = 4.0
+    cfg, model, _ = models.build(config, **kw)
     ids = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 48))
     shapes = meta.unbox(jax.eval_shape(model.init, jax.random.key(0),
                                        jnp.zeros((1, 8), jnp.int32)))
@@ -331,24 +378,88 @@ def test_reference_agrees_with_the_package(family):
     with jax.default_matmul_precision("highest"):
         got = model.apply(params, jnp.asarray(ids))
     got = got[0] if isinstance(got, tuple) else got
-    want, margins = decoder_f32.forward(
-        params, ids, num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-        rope_theta=cfg.rope_theta, rms_eps=cfg.rms_eps,
-        top_k=getattr(cfg, "top_k", 0))
+    reference = models.reference(config)
+    assert reference.__name__ == "reference.decoder_f32"
+    want, margins = reference.forward(models.published(params, config), ids,
+                                      config)
     assert (margins is None) == (family == "llama")
     # float32 on both sides: only the order of sums differs
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=0, atol=2e-5 * float(np.std(want)) * 50)
     labels = np.roll(ids, -1, axis=1)
-    loss = float(decoder_f32.cross_entropy(want, labels))
+    loss = float(reference.cross_entropy(want, labels))
     assert abs(loss - np.log(cfg.vocab_size)) < 0.5
     ps.destroy_model_parallel()
+
+
+def _tree(config, form, rng):
+    """The package's tree for ``config`` from seeded published tensors,
+    with gate and up stored in ``form``."""
+    L, H, I = (config["num_hidden_layers"], config["hidden_size"],
+               config["intermediate_size"])
+    E = config.get("num_local_experts")
+    D = H // config["num_attention_heads"]
+    kv = config["num_key_value_heads"] * D
+    w = lambda *shape: rng.normal(0, 0.05, shape).astype(np.float32)
+    lead = (L, E) if E else (L,)
+    gate, up, down = w(*lead, H, I), w(*lead, H, I), w(*lead, I, H)
+    fused, two = ("gate_up", ("gate", "up")) if E else (
+        "gate_up_kernel", ("gate_kernel", "up_kernel"))
+    mlp = {"H2I": {fused: np.stack([gate, up], -2)},
+           "2HI": {fused: np.stack([gate, up], -3)},
+           "H2I_flat": {fused: np.concatenate([gate, up], -1)},
+           "two_leaves": {two[0]: gate, two[1]: up}}[form]
+    if E:
+        block = {"moe": {"router": {"kernel": w(L, H, E)},
+                         "experts": dict(mlp, down=down)}}
+    else:
+        block = {"mlp": dict(mlp, down={"kernel": down})}
+    layer = dict(block,
+                 attn={"qkv": {"q_kernel": w(L, H, H), "k_kernel": w(L, H, kv),
+                               "v_kernel": w(L, H, kv)},
+                       "o_proj": {"kernel": w(L, H, H)}},
+                 input_norm={"scale": 1 + w(L, H)},
+                 post_norm={"scale": 1 + w(L, H)})
+    V = config["vocab_size"]
+    return {"params": {"lm_head": {"kernel": w(H, V)},
+                       "model": {"embed": {"embedding": w(V, H)},
+                                 "norm": {"scale": 1 + w(H)},
+                                 "layers": {"layer": layer}}}}
+
+
+FORMS = ["H2I", "2HI", "H2I_flat", "two_leaves"]
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("family", ["llama", "mixtral"])
+def test_reference_reads_gate_and_up_in_any_stored_form(family, form):
+    """``[H,2,I]`` (what the package stores today), ``[2,H,I]``, ``[H,2I]``
+    and two leaves, dense and expert: the same published tensors, so the
+    reference's logits agree."""
+    config = dict(TINY if family == "llama" else TINY_MOE, family=family)
+    ids = np.random.default_rng(3).integers(0, config["vocab_size"], (2, 24))
+    reference = models.reference(config)
+    logits = {}
+    for f in dict.fromkeys(["H2I", form]):
+        tree = _tree(config, f, np.random.default_rng(11))
+        logits[f], _ = reference.forward(models.published(tree, config), ids,
+                                         config)
+    assert float(np.std(logits["H2I"])) > 0.01
+    np.testing.assert_allclose(np.asarray(logits[form]),
+                               np.asarray(logits["H2I"]), rtol=0, atol=1e-6)
+    from families import llama
+
+    with pytest.raises(ValueError):
+        llama.gate_or_up({"gate_up": np.zeros((2, 3, 8, 16))}, 0, 0, 8, 8)
+    with pytest.raises(ValueError):
+        llama.gate_or_up({"down": np.zeros((2, 8, 16))}, 0, 1, 8, 16)
 
 
 # -- the command -----------------------------------------------------------------------
 
 def test_no_tpu_is_a_nonzero_exit_and_no_result():
-    for cell in ("mixtral-8x7b.serve-batch", "mistral-7b.train-tp4"):
+    for cell in ("mixtral-8x7b.serve-batch", "mistral-7b.train-tp4",
+                 "mistral-7b.serve-batch"):
         p = _run(["--workload", cell, "--seed", "1", "--seconds", "1",
                   "--trace", "0"])
         assert p.returncode != 0
@@ -369,6 +480,8 @@ def test_rehearsal_configuration_cannot_be_a_real_cell(tmp_path):
 @pytest.mark.parametrize("cell,trace,env", [
     ("tiny-mixtral.serve-batch", "0", {}),
     ("tiny-mixtral.serve-chat", "1", {}),
+    ("tiny-mistral.serve-batch", "0", {}),
+    ("tiny-mistral.serve-batch", "1", {}),
     ("tiny-mistral.train-tp4", "0",
      {"XLA_FLAGS": "--xla_force_host_platform_device_count=4"}),
     ("tiny-mistral.train-tp4", "1",
@@ -446,3 +559,94 @@ def test_a_cell_a_mix_and_a_metric_are_added_as_files_only(tmp_path):
             if os.path.exists(path):
                 os.remove(path)
         os.rmdir(os.path.join(HERE, "layer_metrics"))
+
+
+def test_a_family_and_its_reference_are_added_as_files_only(tmp_path):
+    """A new architecture: a family file, a reference file, a configuration
+    that names both (with a key of its own that only its reference reads)
+    and two manifest entries; no file that was there is edited."""
+    config = harness.read_json(os.path.join(HERE, "configs",
+                                            "tiny-mistral-serve.json"))
+    config.update(family="added_family", reference="added_reference",
+                  added_logit_scale=1.0)
+    added = {
+        os.path.join(BENCH, "families", "added_family.py"): (
+            "from families import llama\n\n"
+            "build = llama.build\n\n\n"
+            "def published(params, config):\n"
+            "    view = llama.published(params, config)\n"
+            "    return lambda name, layer=None, expert=None: view(\n"
+            "        name, layer, expert)\n"),
+        os.path.join(BENCH, "reference", "added_reference.py"): (
+            "from reference import decoder_f32\n\n"
+            "cross_entropy = decoder_f32.cross_entropy\n\n\n"
+            "def forward(weights, tokens, config):\n"
+            "    print('[added_reference] scale',\n"
+            "          config['added_logit_scale'], flush=True)\n"
+            "    logits, extra = decoder_f32.forward(weights, tokens, config)\n"
+            "    return logits * config['added_logit_scale'], extra\n"),
+        os.path.join(HERE, "configs", "added-arch.json"): json.dumps(config)}
+    m = harness.load_manifest(os.path.join(HERE, "manifest.json"))
+    m["configs"].append({"name": "added-arch", "source": "none (rehearsal)",
+                         "file": "benchmarks/tests/configs/added-arch.json",
+                         "reduced": [], "why": "added by the test"})
+    m["workloads"].append({"name": "added-arch.serve-batch",
+                           "config": "added-arch", "traffic": "tiny-offline",
+                           "chips": 1, "why": "added by the test"})
+    for x in m["end_to_end"] + m["per_layer"]:
+        if "tiny-mistral.serve-batch" in x.get("workloads", ()):
+            x["workloads"].append("added-arch.serve-batch")
+    manifest = tmp_path / "manifest.json"
+    manifest.write_text(json.dumps(m))
+    try:
+        for path, body in added.items():
+            assert not os.path.exists(path)
+            with open(path, "w") as f:
+                f.write(body)
+        for trace in ("0", "1"):
+            p = _run(["--manifest", str(manifest), "--workload",
+                      "added-arch.serve-batch", "--seconds", "1",
+                      "--trace", trace])
+            assert p.returncode == 0, p.stderr[-2000:]
+            line = _last_json(p.stdout)
+            want = ("rehearsal.step_ms.batch" if trace == "1"
+                    else "rehearsal.serve_tok_s")
+            assert want in line["metrics"] and line["correct"]
+            assert "[added_reference] scale 1.0" in p.stdout
+    finally:
+        for path in added:
+            if os.path.exists(path):
+                os.remove(path)
+    # a family or a reference that is not there is the harness's own error
+    for key in ("family", "reference"):
+        with pytest.raises(harness.BenchError, match="no-such"):
+            harness.load_plugin({"family": "families"}.get(key, key),
+                                "no-such")
+
+
+def test_the_control_fails_the_logit_check():
+    """``control.py`` at the rehearsal's size: the reference computed with
+    fp8 weights, put in the program's place, is not correct; the program
+    is (the chip readings at the cells' own sizes are in PERF.md)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    p = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "control.py"), "--manifest",
+         os.path.join(HERE, "manifest.json"), "--workload",
+         "tiny-mistral.serve-batch", "--seeds", f"5,{2 ** 31 + 9},77",
+         "--kinds", "fp8,tier-int8"],
+        capture_output=True, text=True, env=env, cwd=ROOT, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = _last_json(p.stdout)
+    assert len(out) == 3
+    for seed, r in out.items():
+        assert r["program"]["correct"] is True, seed
+        assert r["fp8"]["correct"] is False, seed
+        # the package's int8 tier runs and reads farther off than bf16
+        assert (r["tier-int8"]["prefill"]["median"]
+                > r["program"]["prefill"]["median"]), seed
+    sound = max(r["program"][part]["median"] for r in out.values()
+                for part in ("prefill", "decode"))
+    control = min(r["fp8"][part]["median"] for r in out.values()
+                  for part in ("prefill", "decode"))
+    assert control > 3 * sound
