@@ -40,7 +40,6 @@ from ..obs.accounting import CompileTracker
 from ..obs.events import emit_event
 from ..obs.metrics import get_registry
 from ..obs.tracing import get_tracer
-from ..ops.paged_attention import column_live
 from ..utils.device import on_tpu
 from ..resilience.integrity import (
     IntegrityError,
@@ -513,18 +512,31 @@ class ServingEngine:
         self.model_cfg = model_cfg
         self.params = params
         self.ecfg = engine_cfg
-        # model-family forward: any callable with the
-        # llama_forward_with_cache paged signature ``(cfg, params, tokens,
-        # positions, cache, slot_ids=...) -> (logits, cache)``. None
-        # auto-selects by config type — a MixtralConfig serves through
-        # mixtral_forward_with_cache (MoE decode over the same paged pool).
+        # the model family says how it is served: its cached forward (any
+        # callable with the llama_forward_with_cache paged signature
+        # ``(cfg, params, tokens, positions, cache, slot_ids=...) ->
+        # (logits, cache)``; ``forward_fn`` overrides it), the cache kind
+        # its table rows follow, and the engine features it cannot serve
+        family = model_cfg.serving_family()
+        self._cache_kind = family.cache_kind.geometry(
+            engine_cfg.block_size,
+            max(engine_cfg.token_budget, engine_cfg.prefill_budget or 0))
+        self._unsupported = frozenset(family.unsupported)
+        asked = {
+            "prefix_sharing": engine_cfg.prefix_sharing,
+            "speculation": engine_cfg.speculation is not None,
+            "cp": int(getattr(engine_cfg, "cp", 1)) > 1,
+            "quantized": engine_cfg.quantized}
+        for feature in sorted(self._unsupported):
+            if asked.get(feature):
+                raise ValueError(
+                    f"{type(model_cfg).__name__} cannot be served with "
+                    f"{feature}: its {self._cache_kind.name} cache does "
+                    "not keep position // block_size rows (a ring block "
+                    "is not a prefix, a lane clone, a cp shard or an "
+                    "int8 row)")
         if forward_fn is None:
-            from ..models.mixtral import (MixtralConfig,
-                                          mixtral_forward_with_cache)
-
-            forward_fn = (mixtral_forward_with_cache
-                          if isinstance(model_cfg, MixtralConfig)
-                          else llama_forward_with_cache)
+            forward_fn = family.forward
         self._forward_fn = forward_fn
         # elastic-fleet hooks: an AOT cache makes worker construction
         # load-or-compile (replicas after the first spin up without
@@ -659,16 +671,9 @@ class ServingEngine:
             self._draft_cfg = draft_cfg or model_cfg
             self._draft_params = (draft_params if draft_params is not None
                                   else params)
-            if draft_cfg is None:
-                self._draft_forward_fn = forward_fn
-            else:
-                from ..models.mixtral import (MixtralConfig,
-                                              mixtral_forward_with_cache)
-
-                self._draft_forward_fn = (
-                    mixtral_forward_with_cache
-                    if isinstance(draft_cfg, MixtralConfig)
-                    else llama_forward_with_cache)
+            self._draft_forward_fn = (
+                forward_fn if draft_cfg is None
+                else draft_cfg.serving_family().forward)
             k, nb = spec.speculation_length, spec.num_branches
             self._spec_slots = spec.max_spec_slots or min(
                 engine_cfg.max_slots,
@@ -687,9 +692,14 @@ class ServingEngine:
             np.int32)
         self._slot_blocks: List[List[int]] = (
             [[] for _ in range(engine_cfg.max_slots)])
-        #: live and skipped table columns of the rows packed since the
-        #: last publish (obs on only): ``nxd_paged_columns_total``
-        self._paged_cols = [0, 0]
+        #: skipped, exact and summary table columns of the rows packed
+        #: since the last publish (obs on only): ``nxd_paged_columns_total``
+        #: or, for a window-summary cache, ``nxd_eva_columns_total``
+        self._paged_cols = np.zeros((3,), np.int64)
+        self._windows_rolled = 0
+        #: summary blocks taken by the schedule for windows that the next
+        #: step completes, ``(slot, column) -> block``: ``engine/roll``
+        self._pending_roll: Dict[Tuple[int, int], int] = {}
         self._rng = rng if rng is not None else jax.random.key(0)
         self._clock = clock or time.monotonic
         self._t0 = self._clock()
@@ -1161,6 +1171,14 @@ class ServingEngine:
     def _now(self) -> float:
         return self._clock() - self._t0
 
+    def _refuse_session_export(self) -> None:
+        if "session_export" in self._unsupported:
+            raise ValueError(
+                f"{type(self.model_cfg).__name__} cannot be served with "
+                f"session_export: a ticket ships position // block_size "
+                f"blocks, and a {self._cache_kind.name} cache keeps a "
+                "ring and summaries")
+
     def max_model_len(self) -> int:
         """Longest request (prompt + new tokens) this engine can ever
         serve: the model's rope/context bound, the block-table width,
@@ -1170,8 +1188,9 @@ class ServingEngine:
         tier)."""
         e = self.ecfg
         return min(self.model_cfg.max_seq_len,
-                   e.max_blocks_per_seq * e.block_size,
-                   self._pool_blocks * e.block_size)
+                   self._cache_kind.max_positions(
+                       min(e.max_blocks_per_seq, self._pool_blocks),
+                       e.block_size))
 
     def fits(self, prompt_len: int, max_new_tokens: int) -> bool:
         """Whether a request of this size could ever run on this engine
@@ -1331,6 +1350,7 @@ class ServingEngine:
         to the importer). Unlike :meth:`evict`, generated tokens and
         cached KV *survive*: landing the ticket elsewhere re-prefills
         nothing. Raises ``KeyError`` if the request is not live here."""
+        self._refuse_session_export()
         now = self._now()
         for req in self._queue:
             if req.uid == request_id:
@@ -1387,6 +1407,7 @@ class ServingEngine:
         ``integrity`` on, a ticket that ships KV *without* fingerprints
         is also rejected — fail closed; importing unverifiable blocks
         would silently disable the very check the config asked for."""
+        self._refuse_session_export()
         if self._draining:
             raise RequestRejected(
                 "draining", f"{ticket.uid}: engine is draining")
@@ -1510,6 +1531,7 @@ class ServingEngine:
         pool blocks and returns an opaque handle for the other three
         phases. Raises like :meth:`import_session`'s admission checks;
         nothing is reserved on failure."""
+        self._refuse_session_export()
         if self._draining:
             raise RequestRejected(
                 "draining", f"{ticket.uid}: engine is draining")
@@ -1708,13 +1730,39 @@ class ServingEngine:
             return self.allocator.alloc(n)
 
     def _ensure_block(self, req: _RequestState, position: int) -> None:
-        """Map the block covering ``position`` into the slot's table,
-        allocating from the pool (raises CacheExhaustedError dry). A
-        write landing in a block other owners also reference clones it
-        first (copy-on-write): the clone replaces the shared block in
-        this slot's table and the copy itself runs as a fixed-shape
-        jitted pass at the next step boundary."""
-        blk_i = position // self.ecfg.block_size
+        """Map the blocks a row at ``position`` needs into the slot's
+        table (the cache kind says which columns: the one covering the
+        position and, where the row completes a window of a
+        window-summary cache, that window's summary column), allocating
+        from the pool (raises CacheExhaustedError dry). A write landing
+        in a block other owners also reference clones it first
+        (copy-on-write): the clone replaces the shared block in this
+        slot's table and the copy itself runs as a fixed-shape jitted
+        pass at the next step boundary."""
+        columns = self._cache_kind.columns_to_map(position,
+                                                  self.ecfg.block_size)
+        self._map_column(req, columns[0], position)
+        for col in columns[1:]:
+            # this row ends a window: the step writes the window's
+            # summaries into a block of their own. It is taken here, where
+            # a dry pool is the schedule's to handle, and mapped by
+            # ``engine/roll``, once the schedule stands
+            if (req.slot, col) not in self._pending_roll:
+                blk = self._alloc_blocks(1)[0]
+                self._slot_blocks[req.slot].append(blk)
+                self._pending_roll[(req.slot, col)] = blk
+
+    def _roll(self) -> None:
+        """Map the summary blocks of the windows this step completes (the
+        ring columns are reused as they are: mapped, refcount 1)."""
+        for (slot, col), blk in self._pending_roll.items():
+            self._tables[slot, col] = blk
+        if get_registry().enabled:
+            self._windows_rolled += len(self._pending_roll)
+        self._pending_roll.clear()
+
+    def _map_column(self, req: _RequestState, blk_i: int,
+                    position: int) -> None:
         cur = int(self._tables[req.slot, blk_i])
         if cur >= 0:
             if self.allocator.refcount(cur) <= 1:
@@ -1743,6 +1791,8 @@ class ServingEngine:
         self._slot_blocks[slot] = []
         self._tables[slot, :] = -1
         self._slots[slot] = None
+        for key in [k for k in self._pending_roll if k[0] == slot]:
+            del self._pending_roll[key]
 
     def _preempt_youngest(self, keep: _RequestState) -> None:
         """Evict the most recently admitted running request — possibly
@@ -1912,11 +1962,10 @@ class ServingEngine:
                 # row reads the last table row, as the forward's clip does
                 tbl = self._tables[np.minimum(slot_ids,
                                               self._table_rows - 1)]
-                live = int(column_live(
+                kinds = np.bincount(self._cache_kind.column_kinds(
                     tbl, np.arange(tbl.shape[1]), positions[0][:, None],
-                    self.ecfg.block_size).sum())
-                self._paged_cols[0] += live
-                self._paged_cols[1] += tbl.size - live
+                    self.ecfg.block_size).ravel(), minlength=3)
+                self._paged_cols += kinds       # skipped, exact, summary
         with tracer.span(span + "/dispatch"):
             if self._spec is not None:
                 sampled, self.cache, self.dcache = fn(
@@ -2101,6 +2150,9 @@ class ServingEngine:
         t_start = self._now()
         if self.stats.first_step_t is None:
             self.stats.first_step_t = t_start
+        if self._pending_roll:
+            with tracer.span("engine/roll"):
+                self._roll()
         with tracer.span("engine/cow"):
             self._apply_pending_cow()
         with tracer.span("engine/hygiene"):
@@ -2294,13 +2346,28 @@ class ServingEngine:
                 "filled them: a decoding slot's token, a prefill chunk's "
                 "token, or padding.",
                 labels=("kind",))
-            cols_c = reg.counter(
-                "nxd_paged_columns_total",
-                "Table columns of the serving workers' rows by what the "
-                "paged kernel's walk does with them: live (mapped and not "
-                "wholly behind the row's position) is computed, skipped "
-                "is not.",
-                labels=("kind",))
+            if self._cache_kind.ring is None:
+                cols_c = reg.counter(
+                    "nxd_paged_columns_total",
+                    "Table columns of the serving workers' rows by what "
+                    "the paged kernel's walk does with them: live (mapped "
+                    "and not wholly behind the row's position) is "
+                    "computed, skipped is not.",
+                    labels=("kind",))
+                col_kinds, windows_c = ("skipped", "live"), None
+            else:
+                cols_c = reg.counter(
+                    "nxd_eva_columns_total",
+                    "Table columns of the serving workers' rows by what "
+                    "the eva_attention kernel's walk finds there: exact "
+                    "rows of the row's own window, an earlier window's "
+                    "chunk summaries, or nothing (skipped).",
+                    labels=("kind",))
+                col_kinds = ("skipped", "exact", "summary")
+                windows_c = reg.counter(
+                    "nxd_eva_windows_total",
+                    "Windows whose last position was in a packed step: "
+                    "summarised into a block of the pool by that step.")
             cache = self._obs_cache = (
                 reg, reg.generation,
                 {f: stats_g.labels(field=f)
@@ -2310,8 +2377,10 @@ class ServingEngine:
                 step_h,
                 tuple(rows_c.labels(kind=k)
                       for k in ("decode", "prefill", "pad")),
-                tuple(cols_c.labels(kind=k) for k in ("live", "skipped")))
-        _, _, fields, free_g, step_h, rows_by_kind, cols_by_kind = cache
+                tuple(cols_c.labels(kind=k) for k in col_kinds),
+                windows_c)
+        (_, _, fields, free_g, step_h, rows_by_kind, cols_by_kind,
+         windows_c) = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
@@ -2321,8 +2390,11 @@ class ServingEngine:
                             (decode_rows, prefill_rows, pad_rows)):
             child.inc(n)
         for child, n in zip(cols_by_kind, self._paged_cols):
-            child.inc(n)
-        self._paged_cols = [0, 0]
+            child.inc(int(n))
+        self._paged_cols[:] = 0
+        if windows_c is not None:
+            windows_c.inc(self._windows_rolled)
+        self._windows_rolled = 0
 
     def _retire(self, req: _RequestState, now: float) -> None:
         self._release(req)

@@ -19,8 +19,10 @@ entries are never attended, so no separate attention mask is plumbed.
 
 from __future__ import annotations
 
+import dataclasses
 import json
-from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +33,143 @@ from .kv_cache import PAD_POSITION
 
 class CacheExhaustedError(RuntimeError):
     """The block pool has no free block for a required allocation."""
+
+
+# ---------------------------------------------------------------------------
+# Cache kinds: where a sequence's positions live in its row of the block
+# table. The engine (block mapping, admission, counters), the pool writes
+# and the paged kernel's walk ask the model family's kind; none of them
+# divides by block_size itself.
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class FullCache:
+    """Every position's K and V stay for the request's life: position ``p``
+    lives in table column ``p // block_size``, and a row attends every
+    mapped column that does not lie wholly behind it."""
+
+    name = "full"
+    #: table columns holding exact K/V rows (None: all of them)
+    ring = None
+
+    def geometry(self, block_size: int, step_rows: int = 0) -> "FullCache":
+        return self
+
+    def column_of(self, positions, block_size: int):
+        """Table column of a position's K/V row (ints, NumPy or jnp)."""
+        return positions // block_size
+
+    def columns_to_map(self, position: int, block_size: int
+                       ) -> Tuple[int, ...]:
+        """Table columns that must hold a block before a row at
+        ``position`` runs."""
+        return (position // block_size,)
+
+    def blocks_for(self, n: int, block_size: int) -> int:
+        """Blocks a sequence of ``n`` processed positions holds."""
+        return -(-n // block_size)
+
+    def max_positions(self, columns: int, block_size: int) -> int:
+        """Longest sequence that ``columns`` table columns (or pool
+        blocks) can hold."""
+        return columns * block_size
+
+    def column_kinds(self, entry, column, q_pos, block_size: int):
+        """Per (row, column): 0 skipped, 1 computed (exact rows)."""
+        from ..ops.paged_attention import column_live
+
+        return column_live(entry, column, q_pos, block_size) * 1
+
+
+FULL_CACHE = FullCache()
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowSummaryCache:
+    """An exact window and chunk summaries, two kinds of row in the one
+    pool (EVA, arXiv:2302.04542): a sequence keeps exact K/V for the
+    positions of its current ``window`` and one summary row pair per
+    ``chunk`` positions of every earlier window.
+
+    With ``bpw = window // block_size`` blocks a window, the exact rows
+    live in a ring of ``bpw + 1`` table columns, position ``p`` in column
+    ``(p // block_size) % (bpw + 1)`` (the extra column lets a packed
+    chunk of up to ``block_size`` rows straddle a window's end without
+    overwriting rows that earlier rows of the same step still attend, or
+    that the step's summarisation still reads), and window ``w``'s
+    ``window // chunk == block_size`` summaries fill the one block of
+    column ``bpw + 1 + w``. Stale ring rows are masked by the window's
+    lower bound, never cleared. A sequence of ``n`` positions holds
+    ``min(bpw + 1, ceil(n / block_size)) + n // window`` blocks."""
+
+    window: int
+    chunk: int
+    name = "window_summary"
+
+    def geometry(self, block_size: int, step_rows: int = 0
+                 ) -> "WindowSummaryCache":
+        """Checks that this kind tiles a pool of ``block_size`` and that a
+        packed step of ``step_rows`` rows stays inside the ring's one
+        spare column."""
+        if step_rows > block_size:
+            raise ValueError(
+                f"window_summary cache: a packed step of {step_rows} rows "
+                f"can straddle a window's end by more than the ring's one "
+                f"spare block of {block_size}; token_budget must not "
+                "exceed block_size")
+        if (self.window % block_size
+                or self.window // self.chunk != block_size):
+            raise ValueError(
+                f"window_summary cache (window {self.window}, chunk "
+                f"{self.chunk}) needs block_size == window / chunk = "
+                f"{self.window // self.chunk} so that a window is whole "
+                f"blocks and its summaries fill one; got {block_size}")
+        return self
+
+    @property
+    def ring(self) -> int:
+        return self.chunk + 1       # window // (window // chunk) + 1
+
+    def column_of(self, positions, block_size: int):
+        return (positions // block_size) % self.ring
+
+    def columns_to_map(self, position: int, block_size: int
+                       ) -> Tuple[int, ...]:
+        exact = int(self.column_of(position, block_size))
+        if (position + 1) % self.window:
+            return (exact,)
+        # the row that completes a window: its step writes the summaries
+        return (exact, self.ring + position // self.window)
+
+    def blocks_for(self, n: int, block_size: int) -> int:
+        return min(self.ring, -(-n // block_size)) + n // self.window
+
+    def max_positions(self, columns: int, block_size: int) -> int:
+        if columns < self.ring:
+            return min(columns * block_size, self.window - 1)
+        return (columns - self.ring + 1) * self.window - 1
+
+    def column_kinds(self, entry, column, q_pos, block_size: int):
+        """Per (row, column): 0 skipped, 1 exact rows of the row's own
+        window, 2 the summaries of an earlier window."""
+        from ..ops.paged_attention import window_column_kinds
+
+        return window_column_kinds(entry, column, q_pos, block_size,
+                                   self.window, self.ring)
+
+
+@dataclasses.dataclass(frozen=True)
+class ServingFamily:
+    """What :class:`.engine.ServingEngine` asks of a model config
+    (``model_cfg.serving_family()``): the cached forward with the
+    ``llama_forward_with_cache`` paged signature, the cache kind its
+    table rows follow, and the engine features the family cannot serve
+    (refused by name at construction: ``prefix_sharing``, ``speculation``,
+    ``cp``, ``quantized``, ``session_export``)."""
+
+    forward: Callable
+    cache_kind: Any = FULL_CACHE
+    unsupported: Tuple[str, ...] = ()
 
 
 class PagedKVCache(struct.PyTreeNode):
@@ -106,7 +245,9 @@ class PagedCacheView(struct.PyTreeNode):
     is the per-token block table (each packed token carries its own
     slot's row); ``write_idx [T]`` is the precomputed flat pool index for
     this step's K/V rows (== pool capacity for rows that must not land —
-    scatters use ``mode="drop"``)."""
+    scatters use ``mode="drop"``). ``roll`` is the routing of the
+    summaries a window-summary family writes in this step
+    (:func:`window_roll`; None for a full cache)."""
 
     k: jax.Array
     v: jax.Array
@@ -115,6 +256,7 @@ class PagedCacheView(struct.PyTreeNode):
     pos: jax.Array
     tables: jax.Array
     write_idx: jax.Array
+    roll: Any = None
 
 
 class CPPrefillView(struct.PyTreeNode):
@@ -340,13 +482,15 @@ class BlockAllocator:
 # ---------------------------------------------------------------------------
 
 def flat_write_indices(tok_tables: jax.Array, positions: jax.Array,
-                       block_size: int, capacity: int) -> jax.Array:
+                       block_size: int, capacity: int,
+                       kind=FULL_CACHE) -> jax.Array:
     """``[T, max_blocks_per_seq]`` per-token block tables + ``[T]`` true
-    positions -> ``[T]`` flat pool indices. Rows whose position is padding
+    positions -> ``[T]`` flat pool indices (the column of a position is
+    the cache ``kind``'s). Rows whose position is padding
     (PAD_POSITION), beyond the table, or mapped to ``-1`` get index ==
     ``capacity`` — out of bounds, so ``mode="drop"`` scatters discard
     them."""
-    blk_of_pos = positions // block_size
+    blk_of_pos = kind.column_of(positions, block_size)
     maxb = tok_tables.shape[1]
     safe = jnp.clip(blk_of_pos, 0, maxb - 1)
     blk = jnp.take_along_axis(tok_tables, safe[:, None], axis=1)[:, 0]
@@ -363,6 +507,34 @@ def write_pool_rows(pool: jax.Array, rows: jax.Array,
     flat = pool.reshape((nb * bs,) + pool.shape[2:])
     flat = flat.at[flat_idx].set(rows.astype(pool.dtype), mode="drop")
     return flat.reshape(pool.shape)
+
+
+def window_roll(kind: WindowSummaryCache, block_tables: jax.Array,
+                slot_ids: jax.Array, positions: jax.Array,
+                block_size: int, num_blocks: int):
+    """Routing of the summaries this step writes, once for all layers:
+    ``(any, src [S, bpw], dst [S])``. A slot whose packed rows hold the
+    last position of a window ``w`` reads that window's ``bpw`` ring
+    blocks ``src`` (in position order) and writes their ``block_size``
+    chunk summaries into the block of column ``ring + w``, ``dst``
+    (``num_blocks``, dropped by the scatter, for every other slot).
+    A packed step is at most ``window`` rows, so a slot completes at most
+    one window in it."""
+    slots = block_tables.shape[0]
+    ends = (positions < PAD_POSITION) & ((positions + 1) % kind.window == 0)
+    done = jnp.full((slots,), -1, jnp.int32).at[slot_ids].max(
+        jnp.where(ends, positions // kind.window, -1), mode="drop")
+    bpw = kind.window // block_size
+    w = jnp.maximum(done, 0)
+    cols = (w[:, None] * bpw + jnp.arange(bpw, dtype=jnp.int32)) % kind.ring
+    src = jnp.take_along_axis(block_tables, cols, axis=1)
+    dst = jnp.take_along_axis(
+        block_tables,
+        jnp.minimum(kind.ring + w, block_tables.shape[1] - 1)[:, None],
+        axis=1)[:, 0]
+    ok = (done >= 0) & (dst >= 0) & (kind.ring + w < block_tables.shape[1])
+    return (jnp.any(ok), jnp.clip(src, 0, num_blocks - 1),
+            jnp.where(ok, dst, num_blocks))
 
 
 def write_pool_positions(pos: jax.Array, positions: jax.Array,

@@ -179,8 +179,27 @@ class LlamaConfig:
     # streams `chunk`-token slices through head-matmul + CE so [B, S, V]
     # logits never materialise. None = classic full-logits path.
     loss_chunk: Optional[int] = None
+    # attention kind of every layer: "full" (causal softmax over every
+    # earlier position) or "eva" (exact inside the query's window, chunk
+    # summaries of the earlier windows: ops/eva_attention.py; the config
+    # then carries window_size and chunk_size, models/evabyte.py)
+    attention_kind: str = "full"
+    # carry the residual stream between layers in float32 (the adds run
+    # in float32; norms, projections and the MLP still in ``dtype``)
+    residual_fp32: bool = False
+
+    def serving_family(self):
+        """What :class:`..inference.engine.ServingEngine` asks of a model
+        family: its cached forward, its cache kind, what it cannot do."""
+        from ..inference.paging import ServingFamily
+
+        return ServingFamily(forward=llama_forward_with_cache)
 
     def __post_init__(self) -> None:
+        if self.attention_kind not in ("full", "eva"):
+            raise ValueError(
+                f"attention_kind must be 'full' or 'eva', got "
+                f"{self.attention_kind!r}")
         if self.cp_attn_impl not in ("ring", "ring_pallas", "ulysses"):
             raise ValueError(
                 f"cp_attn_impl must be 'ring', 'ring_pallas' or "
@@ -369,6 +388,39 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
     return out.astype(cfg.dtype), new_view
 
 
+def _eva_attend(cfg: LlamaConfig, q, k, v, positions, cache, phi, mu):
+    """EVA attention of one layer. No cache: the whole sequence, windows
+    by reshape (positions ``0..S-1``). Paged pool: write this step's rows
+    into the ring columns, summarise the windows the step completes into
+    their summary blocks of the same pool, then attend both kinds of row
+    through the table under one softmax."""
+    import math as _math
+
+    from ..ops import eva_attention as eva
+
+    scale = 1.0 / _math.sqrt(q.shape[-1])
+    if cache is None:
+        out = eva.eva_attention_full(q, k, v, phi, mu, cfg.window_size,
+                                     cfg.chunk_size, scale)
+        return out.astype(cfg.dtype), None
+    if not _is_paged_cache_view(cache) or cache.k_scale is not None:
+        raise ValueError("attention_kind='eva' serves through the float "
+                         "paged pool only")
+    from ..inference import paging
+    from ..ops.paged_attention import paged_attention
+
+    kind = cfg.serving_family().cache_kind
+    new_k = paging.write_pool_rows(cache.k, k[0], cache.write_idx)
+    new_v = paging.write_pool_rows(cache.v, v[0], cache.write_idx)
+    new_k, new_v = eva.write_window_summaries(
+        new_k, new_v, cache.roll, phi, mu, cfg.chunk_size, scale)
+    out = paged_attention(
+        q[0], new_k, new_v, cache.pos, cache.tables, positions[0],
+        scale=scale, force_pallas=cfg.attn_force_pallas,
+        window=(kind.window, kind.ring))[None]
+    return out.astype(cfg.dtype), cache.replace(k=new_k, v=new_v)
+
+
 class LlamaAttention(nn.Module):
     """Attention with optional KV cache for autoregressive decode.
 
@@ -427,7 +479,18 @@ class LlamaAttention(nn.Module):
         q = attn_mod.apply_rotary(q, cos, sin, positions)
         k = attn_mod.apply_rotary(k, cos, sin, positions)
         new_cache = None
-        if cache is not None and _is_cp_prefill_view(cache):
+        if cfg.attention_kind == "eva":
+            # learned per-head pooling vectors (adaptive_phi,
+            # adaptive_mu_k); the attention itself is ops/eva_attention.py
+            # and, over the pool, the paged kernel with both masks
+            pooling = [self.param(
+                nm, nn.with_partitioning(nn.initializers.normal(0.02),
+                                         (ps.TP_AXIS, None)),
+                (n_kv_local, head_dim), cfg.param_dtype)
+                for nm in ("eva_phi", "eva_mu")]
+            out, new_cache = _eva_attend(cfg, q, k, v, positions, cache,
+                                         *pooling)
+        elif cache is not None and _is_cp_prefill_view(cache):
             # CP ring prefill (inference/engine.py cp>1): write this
             # rank's rows into the local pool shard, ring-attend the
             # whole prompt across the cp axis
@@ -829,7 +892,7 @@ class _PagedScanBody(nn.Module):
 
     @nn.compact
     def __call__(self, x, cache_kv, pool_pos, tables, write_idx, cos, sin,
-                 positions):
+                 positions, roll=None):
         from ..inference.paging import PagedCacheView
 
         if len(cache_kv) == 4:
@@ -838,7 +901,7 @@ class _PagedScanBody(nn.Module):
             (k_l, v_l), ks_l, vs_l = cache_kv, None, None
         view = PagedCacheView(k=k_l, v=v_l, k_scale=ks_l, v_scale=vs_l,
                               pos=pool_pos, tables=tables,
-                              write_idx=write_idx)
+                              write_idx=write_idx, roll=roll)
         x, new_view = LlamaDecoderLayer(self.cfg, name="layer")(
             x, cos, sin, positions, cache=view, cache_index=None)
         if len(cache_kv) == 4:
@@ -882,6 +945,8 @@ class LlamaModel(nn.Module):
             num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="embed",
             **_lora_kw(cfg, "embed"))(input_ids)
+        if cfg.residual_fp32:
+            x = x.astype(jnp.float32)
         positions = context_parallel_positions(input_ids, positions)
         if cfg.sequence_parallel:
             x = mappings.scatter_to_sequence_parallel_region(x, seq_dim=1)
@@ -1091,6 +1156,8 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
         dtype=cfg.dtype, param_dtype=cfg.param_dtype,
         **_lora_kw(cfg, "embed"))
     x = embed.apply({"params": p["model"]["embed"]}, input_ids)
+    if cfg.residual_fp32:
+        x = x.astype(jnp.float32)
     cos, sin = attn_mod.precompute_rope(
         cfg.head_dim_, cfg.max_seq_len, cfg.rope_theta,
         use_scaled=cfg.rope_scaling)
@@ -1102,6 +1169,9 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
         from ..inference import paging as _paging
 
         slot_ids = jnp.asarray(slot_ids, jnp.int32)
+        # where a position lives in its slot's table row is the family's
+        # cache kind's to say (full: position // block_size)
+        kind = cfg.serving_family().cache_kind.geometry(kv_cache.block_size)
         # per-token routing: each packed token carries its slot's block
         # table row and a flat pool index for this step's K/V write (==
         # capacity for pad rows -> dropped by the mode="drop" scatters)
@@ -1109,7 +1179,7 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
             jnp.clip(slot_ids, 0, kv_cache.max_slots - 1)]
         write_idx = _paging.flat_write_indices(
             tok_tables, positions[0], kv_cache.block_size,
-            kv_cache.capacity)
+            kv_cache.capacity, kind)
         slot_pos = _paging.write_pool_positions(kv_cache.pos, positions[0],
                                                 write_idx)
         quantized = isinstance(kv_cache, QuantizedPagedKVCache)
@@ -1133,18 +1203,22 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
                 {"params": p["model"]["layers"]}, x, cache_kv, slot_pos,
                 write_idx, cos, sin, rope_pos)
         else:
+            # a window-summary kind also routes the summaries this step
+            # writes, once for all layers
+            roll = () if kind.ring is None else (_paging.window_roll(
+                kind, kv_cache.block_tables, slot_ids, positions[0],
+                kv_cache.block_size, kv_cache.num_blocks),)
             scanned = nn.scan(
                 _PagedScanBody,
                 variable_axes={"params": 0},
                 split_rngs={"params": True},
-                in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast,
-                         nn.broadcast, nn.broadcast, nn.broadcast),
+                in_axes=(0,) + (nn.broadcast,) * (6 + len(roll)),
                 out_axes=0,
                 length=cfg.num_layers,
             )(cfg)
             x, new_kv = scanned.apply(
                 {"params": p["model"]["layers"]}, x, cache_kv, slot_pos,
-                tok_tables, write_idx, cos, sin, rope_pos)
+                tok_tables, write_idx, cos, sin, rope_pos, *roll)
     else:
         # record this step's true positions in the slot-position table
         # (pads carry the PAD_POSITION sentinel and are thereby never

@@ -53,6 +53,11 @@ class MixtralConfig(LlamaConfig):
     router_aux_coef: float = 0.02
     router_z_coef: float = 0.001
 
+    def serving_family(self):
+        from ..inference.paging import ServingFamily
+
+        return ServingFamily(forward=mixtral_forward_with_cache)
+
     def __post_init__(self):
         super().__post_init__()
         if (self.weight_quant is not None

@@ -54,7 +54,8 @@ logger = get_logger(__name__)
 
 
 def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos,
-                         k_scale, v_scale, scale, combine_axis=None):
+                         k_scale, v_scale, scale, combine_axis=None,
+                         window=None):
     t, n, d = q.shape
     nb, bs, kv, _ = k_pool.shape
     n_rep = n // kv
@@ -74,7 +75,22 @@ def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos,
     pg = pg.reshape(t, length)
     scores = jnp.einsum("bqnd,bknd->bnqk", q[:, None].astype(jnp.float32),
                         k_full.astype(jnp.float32)) * scale
-    mask = q_pos[:, None, None, None] >= pg[:, None, None, :]
+    if window is None:
+        mask = q_pos[:, None, None, None] >= pg[:, None, None, :]
+    else:
+        # two kinds of row under two masks: an exact row counts if it is
+        # of the query's own window and not later than the query (stale
+        # ring rows fall under the window's start); a summary column
+        # counts whole, if its window lies before the query's
+        size, ring = window
+        cols = jnp.arange(tables.shape[1], dtype=jnp.int32)
+        kinds = window_column_kinds(tables, cols, q_pos[:, None], bs, size,
+                                    ring)                     # [T, maxb]
+        lo = (q_pos // size) * size
+        exact = ((pg <= q_pos[:, None]) & (pg >= lo[:, None])
+                 ).reshape(t, -1, bs) & (kinds == 1)[:, :, None]
+        mask = (exact | (kinds == 2)[:, :, None]).reshape(
+            t, 1, 1, length)
     scores = jnp.where(mask, scores, -1e30)
     if combine_axis is None:
         probs = jax.nn.softmax(scores, axis=-1)
@@ -113,6 +129,45 @@ def column_live(entry, column, q_pos, block_size: int):
     return (entry >= 0) & (column * block_size <= q_pos)
 
 
+def window_column_kinds(entry, column, q_pos, block_size: int, window: int,
+                        ring: int):
+    """What a row at ``q_pos`` finds in table column ``column`` (holding
+    block id ``entry``) of a window-summary cache
+    (:class:`..inference.paging.WindowSummaryCache`): 1, exact rows it
+    attends (a ring column whose block holds positions of the row's own
+    window that are not beyond the row); 2, the summaries of an earlier
+    window (column ``ring + w'`` with ``w' < q_pos // window``); 0,
+    nothing (unmapped, a stale ring column, a later window's summaries,
+    a pad row). Broadcasts over jnp arrays (the kernel's walk) and NumPy
+    ones (the engine's ``nxd_eva_columns_total``, the tests)."""
+    w = q_pos // window
+    bpw = window // block_size
+    # the ring column of block b is b % ring; of the row's own window the
+    # blocks w * bpw .. q_pos // block_size are resident
+    first = w * bpw
+    held = q_pos // block_size - first + 1          # 1 .. bpw
+    exact = (column < ring) & ((column - first) % ring < held)
+    summary = (column >= ring) & (column - ring < w)
+    real = (entry >= 0) & (q_pos < PAD_POSITION)
+    return (real & exact) * 1 + (real & summary) * 2
+
+
+def _window_walk(tables, q_pos, block_size: int, window: int, ring: int):
+    """:func:`_paged_walk` for a window-summary cache: a live column's
+    block id, or for a skipped column the complement of the block the
+    previous live column named (the row's first live one before any; 0
+    where the row has none), so that a skipped step's DMA is elided."""
+    maxb = tables.shape[1]
+    cols = jnp.arange(maxb, dtype=jnp.int32)[None, :]
+    live = window_column_kinds(tables, cols, q_pos[:, None], block_size,
+                               window, ring) > 0
+    last = jax.lax.cummax(jnp.where(live, cols, -1), axis=1)
+    first = jnp.argmax(live, axis=1).astype(jnp.int32)[:, None]
+    name = jnp.where(last >= 0, last, first)
+    fetch = jnp.maximum(jnp.take_along_axis(tables, name, axis=1), 0)
+    return jnp.where(live, fetch, ~fetch)
+
+
 def _paged_walk(tables, q_pos, block_size: int):
     """``[T, max_blocks_per_seq]`` int32, one entry a grid step of the
     kernel: a live column's block id (>= 0: fetch it and compute), or for
@@ -131,9 +186,9 @@ def _paged_walk(tables, q_pos, block_size: int):
                      fetch, ~fetch)
 
 
-def _paged_kernel(walk_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
+def _paged_kernel(walk_ref, qpos_ref, *refs,
                   num_blocks_per_seq: int, n_rep: int, scale: float,
-                  quantized: bool):
+                  quantized: bool, ring: Optional[int] = None):
     """One (token, table column) grid step: online softmax of the token's
     heads over one pool block, if the column is live for the token
     (``walk_ref[t, j] >= 0``, :func:`_paged_walk`); a skipped column
@@ -145,9 +200,18 @@ def _paged_kernel(walk_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
     elementwise products, lane/major reductions and lane broadcasts:
     scores are ``[BS, KV, 1]`` (one per (slot, head) row), the running
     max/sum ``[KV, 1]`` and the accumulator ``[KV, D]`` per query-head
-    replica ``r`` (query head ``h * n_rep + r`` reads KV head ``h``)."""
+    replica ``r`` (query head ``h * n_rep + r`` reads KV head ``h``).
+
+    With ``ring`` (a window-summary cache, the ``eva_attention`` kernel)
+    a third prefetched scalar a row is the first position of its window:
+    the rows of columns under ``ring`` are exact and count from there to
+    the row's own position, those of the columns from ``ring`` on are
+    summaries and count whole; one softmax runs over both."""
     from jax.experimental import pallas as pl
 
+    if ring is not None:
+        qlo_ref, *refs = refs
+    q_ref, k_ref, v_ref, pos_ref, *rest = refs
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -169,7 +233,12 @@ def _paged_kernel(walk_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
         v = v_ref[0].astype(jnp.float32)
         # per-slot validity arrives slot-on-lanes ([1, 1, BS]); a one-hot
         # select + lane max moves it to slot-on-major ([BS, 1, 1])
-        ok = (qpos_ref[t] >= pos_ref[...]).astype(jnp.float32)
+        ok = qpos_ref[t] >= pos_ref[...]
+        if ring is not None:
+            ok = ok & (pos_ref[...] >= qlo_ref[t])
+        ok = ok.astype(jnp.float32)
+        if ring is not None:
+            ok = jnp.where(j >= ring, 1.0, ok)
         eye = (jax.lax.broadcasted_iota(jnp.int32, (bs, 1, bs), 0)
                == jax.lax.broadcasted_iota(jnp.int32, (bs, 1, bs), 2))
         valid = jnp.max(jnp.where(eye, ok, 0.0), axis=-1,
@@ -212,7 +281,8 @@ def _paged_kernel(walk_ref, qpos_ref, q_ref, k_ref, v_ref, pos_ref, *rest,
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
-                            k_scale, v_scale, scale, interpret=False):
+                            k_scale, v_scale, scale, interpret=False,
+                            window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -223,9 +293,16 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
     quantized = k_scale is not None
 
     q_pos = q_pos.astype(jnp.int32)
-    walk = _paged_walk(tables.astype(jnp.int32), q_pos, bs)
+    if window is None:
+        walk = _paged_walk(tables.astype(jnp.int32), q_pos, bs)
+        prefetch, ring, name = (walk, q_pos), None, "paged_attention"
+    else:
+        size, ring = window
+        walk = _window_walk(tables.astype(jnp.int32), q_pos, bs, size, ring)
+        prefetch = (walk, q_pos, (q_pos // size) * size)
+        name = "eva_attention"
 
-    def block(ti, j, walk_s, qpos_s):
+    def block(ti, j, walk_s, *_):
         w = walk_s[ti, j]
         return jnp.where(w < 0, ~w, w)
 
@@ -255,7 +332,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(t, maxb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, n_rep, kv, d), tok),
@@ -265,13 +342,14 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, num_blocks_per_seq=maxb,
-                          n_rep=n_rep, scale=scale, quantized=quantized),
+                          n_rep=n_rep, scale=scale, quantized=quantized,
+                          ring=ring),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, n_rep, kv, d), q.dtype),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
-        name="paged_attention",
-    )(walk, q_pos, *operands)
+        name=name,
+    )(*prefetch, *operands)
     return out.swapaxes(1, 2).reshape(t, n, d)
 
 
@@ -311,7 +389,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     v_scale: Optional[jax.Array] = None,
                     scale: Optional[float] = None,
                     force_pallas: Optional[bool] = None,
-                    combine_axis: Optional[str] = None) -> jax.Array:
+                    combine_axis: Optional[str] = None,
+                    window: Optional[tuple] = None) -> jax.Array:
     """Paged decode attention.
 
     ``q [T, N, D]`` one query row per packed token; ``k_pool``/``v_pool``
@@ -331,6 +410,13 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     one pmax and two psums regardless of session length. Must be called
     inside ``shard_map`` with the axis bound; implies the XLA path (the
     Pallas kernel computes no cross-rank combine).
+
+    ``window``: ``(window_size, ring_columns)`` of a window-summary cache
+    (:class:`..inference.paging.WindowSummaryCache`): the table's first
+    ``ring_columns`` columns hold exact rows, attended within the query's
+    own window, the later ones hold an earlier window's chunk summaries
+    each, attended whole, under the one softmax. The kernel is then named
+    ``eva_attention`` in a device trace. Not with ``combine_axis``.
     """
     t, n, d = q.shape
     nb, bs, kv, _ = k_pool.shape
@@ -340,6 +426,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         raise ValueError("k_scale and v_scale must be passed together")
     scale_ = (1.0 / math.sqrt(d)) if scale is None else scale
 
+    if window is not None and (combine_axis is not None
+                               or k_scale is not None):
+        raise ValueError("a window-summary cache serves neither a cp-"
+                         "sharded nor an int8 pool")
     if combine_axis is not None:
         # the CP merge lives in XLA-land (collectives between the local
         # gather and the normalisation); the kernel path has no axis
@@ -349,7 +439,9 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     impl = paged_attention_impl(d, bs, force_pallas)
     if impl == "xla":
         return _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables,
-                                    q_pos, k_scale, v_scale, scale_)
+                                    q_pos, k_scale, v_scale, scale_,
+                                    window=window)
     return _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables,
                                    q_pos, k_scale, v_scale, scale_,
-                                   interpret=impl == "pallas-interpret")
+                                   interpret=impl == "pallas-interpret",
+                                   window=window)

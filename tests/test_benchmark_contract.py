@@ -1,0 +1,200 @@
+"""Tier-1 guard of the on-chip benchmark's manifest: ``BENCHMARK.json`` keeps
+its contract, and every name in it (configuration, family, reference,
+traffic, generator, runner, metric, reader) resolves to a file. Nothing
+here runs a model: ``benchmarks/tests`` rehearses the runners, outside
+tier-1, and would not notice a manifest that names a missing file.
+"""
+
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import harness  # noqa: E402  (benchmarks/)
+
+MANIFEST = harness.load_manifest()
+CELLS = {w["name"]: w for w in MANIFEST["workloads"]}
+E2E = {m["name"]: m for m in MANIFEST["end_to_end"]}
+SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+# keys that name a width: never to be listed under ``reduced``
+WIDTHS = ("hidden_size", "intermediate_size", "head_dim", "window_size",
+          "chunk_size", "num_experts_per_tok")
+
+
+def _ids(entries):
+    return [e["name"] for e in entries]
+
+
+def test_the_manifest_has_the_contracts_shape():
+    m = MANIFEST
+    assert set(m) == {"command", "paths", "run_seconds", "configs",
+                      "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(harness.MANIFEST) <= 64 * 1024
+    assert 1 <= len(m["configs"]) <= 24 and 1 <= len(m["workloads"]) <= 24
+    assert 1 <= len(m["per_layer"]) <= 128
+    names = [x["name"] for g in ("configs", "workloads", "end_to_end",
+                                 "per_layer") for x in m[g]]
+    assert all(harness.NAME.match(n) for n in names)
+    for group in ("configs", "workloads"):
+        assert len(set(_ids(m[group]))) == len(m[group])
+    metrics = _ids(m["end_to_end"] + m["per_layer"])
+    assert len(set(metrics)) == len(metrics)
+    pairs = [(w["config"], w["traffic"]) for w in m["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+    # a four-chip cell costs four times the chip time of every later check
+    assert sum(w["chips"] == 4 for w in m["workloads"]) <= max(
+        1, len(m["workloads"]) // 4)
+    assert "setup_s" in E2E and "workloads" not in E2E["setup_s"]
+    # a full check of the driver fits its limit
+    cells = len(m["workloads"])
+    assert ((2 + 14 * cells) * (m["run_seconds"] + 60) + 2 * 90 * cells
+            + 1200) <= 43200
+
+
+@pytest.mark.parametrize("entry", MANIFEST["configs"],
+                         ids=_ids(MANIFEST["configs"]))
+def test_a_configuration_resolves_and_cuts_depth_only(entry):
+    assert set(entry) == {"name", "source", "file", "reduced", "why"}
+    assert entry["file"].startswith(tuple(
+        p + "/" for p in MANIFEST["paths"]))
+    assert 1 <= len(entry["why"]) <= 200 and 1 <= len(entry["source"]) <= 200
+    assert any(w["config"] == entry["name"] for w in MANIFEST["workloads"])
+    files = [c["file"] for c in MANIFEST["configs"]]
+    assert files.count(entry["file"]) == 1
+    config = harness.read_json(os.path.join(ROOT, entry["file"]))
+    assert config["source"] == entry["source"]
+    assert not config.get("rehearsal")
+    assert sorted(config["reduced"]) == sorted(entry["reduced"])
+    assert len(entry["reduced"]) <= 16
+    for key in entry["reduced"]:
+        assert key not in WIDTHS and not key.endswith(("_dim", "_rank"))
+        cut = config["reduced"][key]
+        assert config[key] == cut["to"] != cut["from"] and cut["why"]
+    for key in ("assumed", "stands_for", "family", "runner", "chips"):
+        assert config.get(key), key
+    family = harness.load_plugin("families", config["family"])
+    assert callable(family.build) and callable(family.published)
+    reference = harness.load_plugin(
+        "reference", config.get("reference", "decoder_f32"))
+    assert callable(reference.forward) and callable(reference.cross_entropy)
+    assert callable(harness.load_plugin("runners", config["runner"]).run)
+
+
+@pytest.mark.parametrize("entry", MANIFEST["configs"],
+                         ids=_ids(MANIFEST["configs"]))
+def test_a_catalogued_configuration_keeps_every_published_number(entry):
+    if not os.path.exists(CATALOG):
+        pytest.skip("no architecture catalog on this machine")
+    with open(CATALOG) as f:
+        rows = [json.loads(line) for line in f if line.strip()]
+    row = [r for r in rows if r["source_url"] == entry["source"]]
+    if not row:
+        pytest.skip("the configuration's source is not in the catalog")
+    config = harness.read_json(os.path.join(ROOT, entry["file"]))
+    for key, value in row[0]["config"].items():
+        if key in entry["reduced"]:
+            continue
+        assert key in config and config[key] == value, key
+
+
+@pytest.mark.parametrize("cell", MANIFEST["workloads"],
+                         ids=_ids(MANIFEST["workloads"]))
+def test_a_cell_resolves_to_its_files_and_reports_enough(cell):
+    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    assert cell["chips"] in (1, 4) and 1 <= len(cell["why"]) <= 200
+    assert "\n" not in cell["why"] and "\t" not in cell["why"]
+    entry = harness.by_name(MANIFEST["configs"], cell["config"],
+                            "configuration")
+    config = harness.read_json(os.path.join(ROOT, entry["file"]))
+    assert config["chips"] == cell["chips"]
+    traffic = harness.read_json(harness.data_file("traffic",
+                                                  cell["traffic"]))
+    generator = harness.load_plugin("generators", traffic["kind"])
+    assert callable(generator.generate)
+    e2e = harness.metrics_of(MANIFEST, "end_to_end", cell["name"])
+    assert "setup_s" in _ids(e2e) and len(e2e) >= 2
+    assert len(harness.metrics_of(MANIFEST, "per_layer", cell["name"])) >= 1
+    if traffic["kind"] == "requests":
+        # the mix's longest request fits the engine's table and model
+        serve = config["serve"]
+
+        def most(spec):
+            return spec["max"] if "max" in spec else spec["value"]
+
+        longest = (most(traffic["prompt_tokens"])
+                   + most(traffic["answer_tokens"]))
+        assert longest <= config["max_position_embeddings"]
+        if config["family"] != "evabyte":
+            assert longest <= (serve["max_blocks_per_seq"]
+                               * serve["block_size"])
+
+
+@pytest.mark.parametrize("metric", MANIFEST["end_to_end"],
+                         ids=_ids(MANIFEST["end_to_end"]))
+def test_an_end_to_end_metric_keeps_its_form(metric):
+    assert set(metric) <= {"name", "unit", "better", "bound", "source",
+                           "workloads"}
+    assert harness.UNIT.match(metric["unit"])
+    assert 0.01 <= metric["bound"] <= 0.1
+    assert metric["source"] in ("host_clock", "device_trace")
+    assert metric["better"] in ("lower", "higher")
+    assert set(metric.get("workloads", ())) <= set(CELLS)
+
+
+@pytest.mark.parametrize("metric", MANIFEST["per_layer"],
+                         ids=_ids(MANIFEST["per_layer"]))
+def test_a_per_layer_metric_resolves_to_its_file_and_reader(metric):
+    assert set(metric) <= {"name", "unit", "better", "source", "layer",
+                           "moves", "workloads"}
+    assert harness.UNIT.match(metric["unit"])
+    assert metric["source"] in SOURCES
+    assert metric["better"] in ("lower", "higher")
+    assert 1 <= len(metric["layer"]) <= 200
+    moved = E2E[metric["moves"]]
+    for cell in metric.get("workloads", CELLS):
+        assert cell in CELLS
+        assert cell in moved.get("workloads", CELLS), (metric["name"], cell)
+    spec = harness.read_json(harness.data_file("layer_metrics",
+                                               metric["name"]))
+    for key in ("name", "layer", "unit", "better", "source", "moves"):
+        assert spec[key] == metric[key], key
+    reader = harness.load_plugin("readers", spec["reader"]["kind"])
+    assert callable(reader.read)
+    if "roofline" in metric["name"] or "mfu" in metric["name"]:
+        assert metric["unit"] == "%"
+
+
+def test_the_evabyte_cell_is_sized_by_its_cache_kind():
+    """The mix's longest request holds the blocks the configuration says:
+    the ring and the summaries of the windows it completes, with one to
+    spare a slot, so the pool never preempts."""
+    from neuronx_distributed_tpu.inference.paging import WindowSummaryCache
+
+    config = harness.read_json(os.path.join(
+        BENCH, "configs", "evabyte-6.5b.json"))
+    traffic = harness.read_json(harness.data_file("traffic",
+                                                  "offline-docs-bytes"))
+    serve = config["serve"]
+    kind = WindowSummaryCache(config["window_size"], config["chunk_size"]
+                              ).geometry(serve["block_size"],
+                                         serve["token_budget"])
+    longest = (traffic["prompt_tokens"]["max"]
+               + traffic["answer_tokens"]["max"])
+    assert longest == 8576
+    held = kind.blocks_for(longest, serve["block_size"])
+    assert held == 17 + 4
+    assert serve["num_blocks"] == serve["max_slots"] * (held + 1)
+    assert longest <= kind.max_positions(serve["max_blocks_per_seq"],
+                                         serve["block_size"])
+    # the logit check's two sequences cross two window ends
+    chk = serve["logit_check"]
+    assert chk["prompt_tokens"] // config["window_size"] == 2
+    assert ((chk["prompt_tokens"] + chk["decode_steps"])
+            <= serve["max_blocks_per_seq"] * serve["block_size"])

@@ -1,0 +1,341 @@
+"""EvaByte (EVA chunked linearized attention) through the model, the paged
+forward, the Pallas kernel and ``ServingEngine``, against the benchmark's
+plain reference ``benchmarks/reference/evabyte_f32.py``.
+
+Tiny widths: hidden 64, 4 heads of 16, window 32, chunk 4, blocks of 8, so
+that a window is still 4 blocks and a window's summaries fill one block.
+The weights are seeded with ``phi``, ``mu`` and the norms' offsets of order
+one: at the chip's initialisation they are tiny and a wrong pooling would
+not show.
+"""
+
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from flax.core import meta
+
+from neuronx_distributed_tpu import obs
+from neuronx_distributed_tpu.inference import paging
+from neuronx_distributed_tpu.inference.engine import (EngineConfig,
+                                                      ServingEngine)
+from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
+from neuronx_distributed_tpu.inference.speculative import SpeculationConfig
+from neuronx_distributed_tpu.ops import paged_attention as pa
+from neuronx_distributed_tpu.parallel import mesh as ps
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.join(os.path.dirname(HERE), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import harness  # noqa: E402  (benchmarks/)
+from runners import serve  # noqa: E402
+
+sys.path.insert(0, HERE)
+import engine_parity  # noqa: E402
+
+W, C, BS = 32, 4, 8
+PUBLISHED = dict(
+    vocab_size=320, hidden_size=64, intermediate_size=128,
+    num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+    rope_theta=1e5, rms_norm_eps=1e-5, max_position_embeddings=1024,
+    window_size=W, chunk_size=C, num_pred_heads=8, fp32_skip_add=True,
+    family="evabyte", reference="evabyte_f32")
+KIND = paging.WindowSummaryCache(W, C)
+
+
+def _model(**kw):
+    ps.initialize_model_parallel()
+    family = harness.load_plugin("families", "evabyte")
+    cfg, model, forward = family.build(
+        PUBLISHED, dtype=jnp.float32, param_dtype=jnp.float32, **kw)
+    shapes = meta.unbox(model.init(jax.random.key(0),
+                                   jnp.zeros((1, 8), jnp.int32)))
+
+    def draw(path, x):
+        name = jax.tree_util.keystr(path)
+        key = jax.random.fold_in(jax.random.key(5),
+                                 sum(map(ord, name)) % 2 ** 31)
+        noise = jax.random.normal(key, x.shape, x.dtype)
+        if "eva_" in name:
+            return 0.5 * noise                  # order head_dim ** -0.5 ...
+        if name.endswith("['scale']"):
+            return 1.0 + 0.3 * noise            # ... and an offset that shows
+        return 0.08 * noise
+
+    return cfg, model, forward, jax.tree_util.tree_map_with_path(draw,
+                                                                 shapes)
+
+
+def _reference(params):
+    return (harness.load_plugin("reference", "evabyte_f32"),
+            harness.load_plugin("families", "evabyte").published(params,
+                                                                 PUBLISHED))
+
+
+def _ecfg(**kw):
+    base = dict(block_size=BS, num_blocks=40, max_slots=3,
+                max_blocks_per_seq=16, token_budget=8,
+                kv_dtype=jnp.float32)
+    base.update(kw)
+    return EngineConfig(**base)
+
+
+def _greedy_by_reference(params, prompt, tokens):
+    """The reference's greedy next byte after each prefix of ``prompt +
+    tokens`` that ends where the engine sampled (one full forward: equal
+    lists mean the engine's greedy continuation is the reference's)."""
+    ref, weights = _reference(params)
+    logits, _ = ref.forward(weights, np.asarray([prompt + tokens]),
+                            PUBLISHED)
+    return np.argmax(np.asarray(logits)[0, len(prompt) - 1:-1], -1).tolist()
+
+
+# -- (a) the module's full forward ------------------------------------------
+
+def test_full_forward_matches_the_reference_over_three_windows():
+    cfg, model, _, params = _model()
+    tokens = np.random.RandomState(1).randint(0, 320, (2, 3 * W + 11))
+    with jax.default_matmul_precision("highest"):
+        got = model.apply(params, jnp.asarray(tokens))
+    ref, weights = _reference(params)
+    want = ref.forward_all_heads(weights, tokens, PUBLISHED)
+    assert got.shape == want.shape == (2, 3 * W + 11, 8, 320)
+    assert got.dtype == jnp.float32                          # fp32_logits
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(np.std(want)))
+    # the next-byte head is what serving samples
+    np.testing.assert_array_equal(
+        np.asarray(ref.forward(weights, tokens, PUBLISHED)[0]),
+        np.asarray(want[:, :, 0]))
+
+
+def test_a_short_sequence_pads_to_chunks_not_to_a_window():
+    cfg, model, _, params = _model()
+    tokens = np.random.RandomState(2).randint(0, 320, (1, 7))
+    ref, weights = _reference(params)
+    with jax.default_matmul_precision("highest"):
+        got = model.apply(params, jnp.asarray(tokens))
+    want = ref.forward_all_heads(weights, tokens, PUBLISHED)
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(np.std(want)))
+
+
+# -- (b), (c) the paged forward, XLA path and Pallas kernel ------------------
+
+@pytest.mark.parametrize("impl", ["xla", "pallas-interpret"])
+def test_paged_forward_matches_the_reference_across_window_ends(impl):
+    """The harness's own probe: its table convention (consecutive blocks
+    in every column a length needs, more than the ring layout uses),
+    prefill in 8-row and then unaligned 7-row chunks beside a decode row
+    and pad rows, then decode; 105 positions cross three window ends, the
+    second sequence's inside a chunk."""
+    cfg, _, forward, params = _model(
+        attn_force_pallas=impl == "pallas-interpret")
+    assert pa.paged_attention_impl(cfg.head_dim_, BS,
+                                   cfg.attn_force_pallas) == impl
+    chk = dict(prompt_tokens=75, decode_steps=30)
+    schedule = serve.probe_schedule(75, 30, 8)
+    assert any(len(rows) < 8 for rows in schedule)           # pad rows
+    assert any({s for s, _ in rows} == {0, 1} and len(rows) == 8
+               and (rows[1][1] + 1) % W not in (0, 1)
+               and any((p + 1) % W == 0 for _, p in rows[1:-1])
+               for rows in schedule)            # a window ends inside a chunk
+    with jax.default_matmul_precision("highest"):
+        seqs, got = serve.probe_logits(7, cfg, forward, params, _ecfg(), chk)
+    ref, weights = _reference(params)
+    want = np.asarray(ref.forward(weights, seqs, PUBLISHED)[0])
+    assert got.shape == want.shape == (2, 105, 320)
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(np.std(want)))
+
+
+def test_pallas_kernel_in_interpret_mode_equals_the_xla_path():
+    """Both masks and the bounded walk: a ring that has wrapped (stale rows
+    of two windows ago in a live column), summaries of two earlier windows
+    and a later window's summary column that must be skipped, unmapped
+    columns, a pad row."""
+    rng = np.random.RandomState(3)
+    nb, kv, d, maxb, t = 24, 4, 16, 10, 6
+    k_pool = jnp.asarray(rng.randn(nb, BS, kv, d), jnp.float32)
+    v_pool = jnp.asarray(rng.randn(nb, BS, kv, d), jnp.float32)
+    length = 2 * W + 13                     # window 2, 13 positions into it
+    pos = np.full((nb, BS), PAD_POSITION, np.int32)
+    table = np.full((maxb,), -1, np.int32)
+    table[:KIND.ring] = [3, 7, 1, 12, 9]
+    for p in range(length):                 # later positions overwrite
+        pos[table[KIND.column_of(p, BS)], p % BS] = p
+    table[KIND.ring:KIND.ring + 3] = [15, 4, 20]    # windows 0, 1 and "2"
+    q_pos = np.array([length - 1, length - 3, W + 5, 2 * W + 5, 2 * W,
+                      PAD_POSITION], np.int32)
+    args = (jnp.asarray(rng.randn(t, kv, d), jnp.float32), k_pool, v_pool,
+            jnp.asarray(pos), jnp.asarray(np.tile(table, (t, 1))),
+            jnp.asarray(q_pos))
+    kinds = KIND.column_kinds(np.tile(table, (t, 1)), np.arange(maxb),
+                              q_pos[:, None], BS)
+    assert kinds[0].tolist() == [0, 0, 0, 1, 1, 2, 2, 0, 0, 0]
+    assert kinds[2].tolist() == [0, 0, 0, 0, 1, 2, 0, 0, 0, 0]
+    assert kinds[3].tolist() == [0, 0, 0, 1, 0, 2, 2, 0, 0, 0]
+    assert not kinds[5].any()               # a pad row walks nothing
+    want = pa.paged_attention(*args, force_pallas=False, scale=0.25,
+                              window=(W, KIND.ring))
+    got = pa.paged_attention(*args, force_pallas=True, scale=0.25,
+                             window=(W, KIND.ring))
+    np.testing.assert_allclose(got[:5], want[:5], atol=1e-5, rtol=1e-5)
+    walk = np.asarray(pa._window_walk(args[4], args[5], BS, W, KIND.ring))
+    np.testing.assert_array_equal(walk >= 0, kinds > 0)
+    # a skipped step names the block of the live step before it
+    assert (~walk[0, 7:]).tolist() == [4, 4, 4]
+
+
+# -- (d) through ServingEngine -----------------------------------------------
+
+@pytest.fixture(scope="module")
+def served():
+    """Three requests that cross windows, one of them preempted on the
+    way, through one engine; what each step held."""
+    cfg, _, _, params = _model()
+    eng = ServingEngine(cfg, params, _ecfg(num_blocks=11, max_slots=2))
+    rng = np.random.RandomState(11)
+    prompts = {"a": rng.randint(0, 320, (70,)).tolist(),
+               "b": rng.randint(0, 320, (37,)).tolist(),
+               "c": rng.randint(0, 320, (5,)).tolist()}
+    new = {"a": 30, "b": 12, "c": 4}
+    obs.enable()
+    obs.get_registry().reset()
+    for uid, prompt in prompts.items():
+        eng.submit(prompt, new[uid], uid=uid)
+    held = []
+    while eng.has_work():
+        eng.step()
+        held.append([(r.uid, r.n_cached, len(eng._slot_blocks[r.slot]))
+                     for r in eng._slots if r is not None])
+    counters = {
+        name: {c.labels.get("kind", ""): c.value
+               for c in obs.get_registry().get(name).children()}
+        for name in ("nxd_eva_columns_total", "nxd_eva_windows_total")}
+    spans = {e["name"] for e in obs.get_tracer().chrome_trace()["traceEvents"]}
+    obs.disable()
+    ps.destroy_model_parallel()
+    return cfg, params, eng, prompts, new, held, counters, spans
+
+
+def test_engine_greedy_tokens_equal_the_reference(served):
+    cfg, params, eng, prompts, new, *_ = served
+    for uid, prompt in prompts.items():
+        assert eng.results[uid].status == "completed"
+        tokens = eng.results[uid].tokens
+        assert len(tokens) == new[uid]
+        assert tokens == _greedy_by_reference(params, prompt, tokens), uid
+
+
+def test_a_sequence_holds_the_blocks_its_cache_kind_says(served):
+    *_, held, _, _ = served
+    seen = 0
+    for step in held:
+        for uid, n, blocks in step:
+            assert blocks == KIND.blocks_for(n, BS), (uid, n)
+            seen = max(seen, n)
+    assert seen >= 3 * W                    # "a" rolled the ring three times
+    assert KIND.blocks_for(100, BS) == 5 + 3 < -(-100 // BS)
+
+
+def test_a_preempted_request_restarts_and_the_pool_is_whole(served):
+    _, _, eng, *_ = served
+    assert eng.stats.preempted >= 1         # 11 blocks do not hold a and b
+    assert eng.allocator.num_allocated == 0
+    assert eng.pool_free_blocks() == 11
+    assert (eng._tables == -1).all()
+    assert eng.compile_count() == 1
+
+
+def test_roll_span_and_eva_counters(served):
+    *_, held, counters, spans = served
+    assert "engine/roll" in spans
+    cols = counters["nxd_eva_columns_total"]
+    assert set(cols) == {"exact", "summary", "skipped"}
+    assert cols["exact"] > 0 and cols["summary"] > 0 and cols["skipped"] > 0
+    # every window end a packed row held: a's two in its prompt and one
+    # while decoding, b's one, and those run again after the preemption
+    assert counters["nxd_eva_windows_total"][""] >= 4
+
+
+def test_sixteen_windows_hold_the_ring_and_sixteen_summary_blocks():
+    cfg, _, _, params = _model()
+    eng = ServingEngine(cfg, params, _ecfg(
+        num_blocks=24, max_slots=1, max_blocks_per_seq=22))
+    n = 16 * W
+    # from the cache kind and max_position_embeddings, not 22 * 8
+    assert eng.max_model_len() == min(cfg.max_seq_len,
+                                      (22 - KIND.ring + 1) * W - 1) == 575
+    assert eng.fits(n, 1) and not eng.fits(575, 1)
+    uid = eng.submit(np.random.RandomState(5).randint(0, 320, (n,)).tolist(),
+                     2)
+    while eng.has_work():
+        if eng._slots[0] is not None:
+            peak = len(eng._slot_blocks[0])
+        eng.step()
+    assert peak == KIND.ring + 16 == KIND.blocks_for(n, BS)
+    assert peak < -(-n // BS)
+    assert eng.results[uid].status == "completed"
+
+
+@pytest.mark.parametrize("feature,kw", [
+    ("prefix_sharing", dict(prefix_sharing=True)),
+    ("speculation", dict(speculation=SpeculationConfig())),
+    ("cp", dict(cp=2)),
+    ("quantized", dict(quantized=True)),
+])
+def test_refused_features_raise_by_name(feature, kw):
+    cfg, _, _, params = _model()
+    with pytest.raises(ValueError, match=feature):
+        ServingEngine(cfg, params, _ecfg(**kw))
+
+
+def test_session_export_and_wide_steps_are_refused_by_name():
+    cfg, _, _, params = _model()
+    eng = ServingEngine(cfg, params, _ecfg())
+    uid = eng.submit([1, 2, 3], 4)
+    eng.step()
+    with pytest.raises(ValueError, match="session_export"):
+        eng.export_session(uid)
+    with pytest.raises(ValueError, match="token_budget"):
+        ServingEngine(cfg, params, _ecfg(token_budget=16))
+    with pytest.raises(ValueError, match="block_size"):
+        ServingEngine(cfg, params, _ecfg(block_size=4, token_budget=4))
+
+
+def test_cache_kinds_answer_for_their_layouts():
+    full = paging.FULL_CACHE
+    assert full.columns_to_map(37, 8) == (4,)
+    assert full.blocks_for(37, 8) == 5 and full.max_positions(6, 8) == 48
+    assert KIND.ring == W // BS + 1 == 5
+    assert KIND.columns_to_map(30, BS) == (3,)
+    assert KIND.columns_to_map(31, BS) == (3, 5)    # ends window 0
+    assert KIND.columns_to_map(32, BS) == (4,)      # the spare column
+    assert KIND.columns_to_map(40, BS) == (0,)      # the ring wraps
+    assert KIND.columns_to_map(63, BS) == (2, 6)
+    assert [KIND.blocks_for(n, BS) for n in (1, 8, 9, 31, 32, 40, 41, 64)
+            ] == [1, 1, 2, 4, 5, 6, 6, 7]
+    assert KIND.max_positions(4, BS) == 31 and KIND.max_positions(6, BS) == 63
+    big = paging.WindowSummaryCache(2048, 16).geometry(128, 128)
+    assert big.ring == 17 and big.blocks_for(32768, 128) == 17 + 16
+    assert big.blocks_for(8576, 128) == 21
+
+
+# -- (e) llama and Mixtral through the changed engine ------------------------
+
+@pytest.mark.parametrize("family", ["llama", "mixtral"])
+def test_llama_and_mixtral_serve_as_the_parent_did(family):
+    with open(os.path.join(HERE, "fixtures", "engine_parity_pr27.json")) as f:
+        want = json.load(f)[family]
+    got = engine_parity.record(family)
+    assert got["max_model_len"] == want["max_model_len"]
+    assert got["allocated"] == want["allocated"]
+    assert got["tables"] == want["tables"]
+    assert got["tokens"] == want["tokens"]
+    np.testing.assert_array_equal(np.asarray(got["logits"], np.float32),
+                                  np.asarray(want["logits"], np.float32))
