@@ -2254,8 +2254,11 @@ def moe_metric(platform: str, n_dev: int) -> dict:
         ps.destroy_model_parallel()
         nxd.neuronx_distributed_config(expert_parallel_size=ep)
         em = ps.get_expert_mesh()
-        pspec = {"params": {"gate_up": P("ep", None, None, None),
-                            "down": P("ep", None, None)}}
+        from neuronx_distributed_tpu.modules import glu
+
+        pspec = {"params": {
+            **dict.fromkeys(glu.EXPERTS, P("ep", None, None)),
+            "down": P("ep", None, None)}}
 
         def run_ep(overlap):
             m = ExpertMLPs(

@@ -28,7 +28,8 @@ import jax.numpy as jnp
 import optax
 
 LORA_KEYS = ("lora_a", "lora_b", "q_lora_a", "q_lora_b", "k_lora_a",
-             "k_lora_b", "v_lora_a", "v_lora_b")
+             "k_lora_b", "v_lora_a", "v_lora_b", "gate_lora_a",
+             "gate_lora_b", "up_lora_a", "up_lora_b")
 
 # (kernel key, A key, B key) triples that merge_lora_params folds together
 _MERGE_TRIPLES = (
@@ -37,6 +38,8 @@ _MERGE_TRIPLES = (
     ("q_kernel", "q_lora_a", "q_lora_b"),
     ("k_kernel", "k_lora_a", "k_lora_b"),
     ("v_kernel", "v_lora_a", "v_lora_b"),
+    ("gate_kernel", "gate_lora_a", "gate_lora_b"),
+    ("up_kernel", "up_lora_a", "up_lora_b"),
 )
 
 
@@ -115,27 +118,24 @@ def merge_lora_state(params: Any, lora_state: Any) -> Any:
 def merge_lora_params(params: Any, cfg: LoraConfig) -> Any:
     """Fold adapters into base kernels and drop them (reference merge-and-
     unload). Handles 2-D kernels, the embedding table, fused GQA kernels and
-    the llama fused ``gate_up_kernel`` ([H, 2, I]: B is [r, 2, I])."""
+    the llama MLP's ``gate_kernel``/``up_kernel`` (one adapter each)."""
     scale = cfg.scale
 
     def ab(a, b):
-        # a: [h, r] or [L, h, r] (stacked scan layers); b matches with a
-        # possibly >2-D output tail (fused gate_up [r, 2, I]). Conv pairs:
-        # a [kh, kw, cin, r] with a 1x1 b [1, 1, r, cout] compose into one
-        # conv kernel (B is pointwise, so the composition is exact).
+        # a: [h, r] or [L, h, r] (stacked scan layers), b: [r, o] or
+        # [L, r, o]. Conv pairs: a [kh, kw, cin, r] with a 1x1 b
+        # [1, 1, r, cout] compose into one conv kernel (B is pointwise, so
+        # the composition is exact).
         if a.ndim == 4 and b.ndim == 4:
             return jnp.einsum("hwir,ro->hwio", a, b[0, 0])
-        if a.ndim == 2:
-            return jnp.einsum("hr,r...->h...", a, b)
-        return jnp.einsum("lhr,lr...->lh...", a, b)
+        return jnp.matmul(a, b)
 
     def walk(node):
         if not isinstance(node, dict):
             return node
         out = {k: walk(v) for k, v in node.items()
                if k not in LORA_KEYS}
-        for kern, a_key, b_key in _MERGE_TRIPLES + (
-                ("gate_up_kernel", "lora_a", "lora_b"),):
+        for kern, a_key, b_key in _MERGE_TRIPLES:
             if kern in node and a_key in node and b_key in node:
                 out[kern] = node[kern] + scale * ab(node[a_key], node[b_key])
         return out

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..modules import attention as attn_mod
+from ..modules import glu
 from ..modules.norms import RMSNorm
 from ..ops import collective_matmul as cm
 from ..parallel import layers as pl
@@ -642,35 +643,46 @@ class LlamaMLP(nn.Module):
         cfg = self.cfg
         if cfg.weight_quant is not None:
             return self._quantized_call(x)
-        # Fused gate+up in ONE column-parallel matmul (one MXU pass; the
-        # reference keeps separate gate/up projections). The kernel is
-        # [H, 2, I] with the tp shard on the *last* dim, so the gate/up split
-        # (dim 1) is layout-identical under shard_map, GSPMD and dense.
+        # gate and up are two column-parallel kernels [H, I_local] (tp on
+        # the last dim), stored and contracted as modules/glu.py says: one
+        # fused [hidden, 2, intermediate] leaf made XLA copy a layer's
+        # weights out of the scan's stack before every matmul on the chip
         i_local = pl._maybe_local(cfg.intermediate_size, ps.TP_AXIS)
-        kernel = self.param(
-            "gate_up_kernel",
-            nn.with_partitioning(pl.default_kernel_init,
-                                 (None, None, ps.TP_AXIS)),
-            (cfg.hidden_size, 2, i_local), cfg.param_dtype)
+        gate, up = glu.declare(
+            self, glu.DENSE, pl.default_kernel_init, (None, ps.TP_AXIS),
+            (cfg.hidden_size, i_local), cfg.param_dtype)
         lora_on = (cfg.lora is not None
                    and "gate_up" in cfg.lora.target_modules)
         lora_act = (lora_on and cfg.lora.dropout > 0.0
                     and self.has_rng("dropout"))
         if lora_on:
-            lora_a = self.param(
-                "lora_a", nn.with_partitioning(pl.default_kernel_init,
-                                               (None, None)),
-                (cfg.hidden_size, cfg.lora.r), cfg.param_dtype)
-            lora_b = self.param(
-                "lora_b", nn.with_partitioning(
-                    nn.initializers.zeros_init(), (None, None, ps.TP_AXIS)),
-                (cfg.lora.r, 2, i_local), cfg.param_dtype)
+            # one adapter a projection, as q/k/v have
+            def adapter(which):
+                a = self.param(
+                    f"{which}_lora_a", nn.with_partitioning(
+                        pl.default_kernel_init, (None, None)),
+                    (cfg.hidden_size, cfg.lora.r), cfg.param_dtype)
+                b = self.param(
+                    f"{which}_lora_b", nn.with_partitioning(
+                        nn.initializers.zeros_init(), (None, ps.TP_AXIS)),
+                    (cfg.lora.r, i_local), cfg.param_dtype)
+                return a, b
+
+            adapters = adapter("gate"), adapter("up")
             if not lora_act:
-                kernel = kernel + cfg.lora.scale * jnp.einsum(
-                    "hr,rki->hki", lora_a, lora_b)
-        # the fused [H, 2, I] kernel rides the decomposed collective-matmul
-        # directly (last-dim contraction, gate/up split preserved);
-        # activation-space LoRA needs the gathered input, so it falls back
+                gate, up = (w + cfg.lora.scale * jnp.dot(a, b)
+                            for w, (a, b) in zip((gate, up), adapters))
+        down = pl.RowParallelLinear(
+            features=cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+            sequence_parallel=cfg.sequence_parallel,
+            overlap_comm=cfg.overlap_comm, name="down",
+            tp_sync=self.tp_sync,
+            **_act_kw(cfg), **_lora_kw(cfg, "down"))
+        gate, up = gate.astype(cfg.dtype), up.astype(cfg.dtype)
+        # both kernels ride one decomposed collective-matmul ring, as qkv
+        # does; activation-space LoRA needs the gathered input, so it
+        # falls back
         wire = cm.wire_config(cfg.activation_comm_dtype,
                               cfg.activation_comm_block_size)
         engaged = not lora_act and cm.overlap_engaged(
@@ -679,45 +691,28 @@ class LlamaMLP(nn.Module):
         if engaged or (wire is not None and not lora_act
                        and pl._bound_size(ps.TP_AXIS) is not None):
             impl = "decomposed" if engaged else "monolithic"
-            x = x.astype(cfg.dtype)
-            if cfg.sequence_parallel:
-                h = cm.all_gather_matmul(x, kernel.astype(cfg.dtype),
-                                         ps.TP_AXIS, 1, impl=impl,
-                                         wire=wire)
-            else:
-                h = cm.copy_matmul(x, kernel.astype(cfg.dtype),
-                                   ps.TP_AXIS, 1, impl=impl, wire=wire)
-            h = nn.silu(h[..., 0, :]) * h[..., 1, :]
-            return pl.RowParallelLinear(
-                features=cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                sequence_parallel=cfg.sequence_parallel,
-                overlap_comm=cfg.overlap_comm, name="down",
-                tp_sync=self.tp_sync,
-                **_act_kw(cfg), **_lora_kw(cfg, "down"))(h)
+            matmul = (cm.all_gather_matmul if cfg.sequence_parallel
+                      else cm.copy_matmul)
+            g, u = matmul(x.astype(cfg.dtype), (gate, up), ps.TP_AXIS, 1,
+                          impl=impl, wire=wire)
+            return down(glu.gated(g, u))
         if cfg.sequence_parallel:
             x = mappings.gather_from_sequence_parallel_region(
                 x, seq_dim=1, to_model_parallel=True)
         else:
             x = mappings.copy_to_tensor_parallel_region(x)
         x = x.astype(cfg.dtype)
-        h = jnp.einsum("bsh,hki->bski", x, kernel.astype(cfg.dtype))
+        g, u = glu.project(x, gate, up)
         if lora_act:
             # dropout on the adapter input cannot fold into the kernel
             x_l = nn.Dropout(rate=cfg.lora.dropout)(x, deterministic=False)
-            h = h + cfg.lora.scale * jnp.einsum(
-                "bsr,rki->bski", jnp.dot(x_l, lora_a.astype(cfg.dtype)),
-                lora_b.astype(cfg.dtype))
+            g, u = (h + cfg.lora.scale * jnp.dot(
+                jnp.dot(x_l, a.astype(cfg.dtype)), b.astype(cfg.dtype))
+                    for h, (a, b) in zip((g, u), adapters))
         if pl._bound_size(ps.TP_AXIS) is None:
-            h = ps.with_sharding_constraint(h, None, None, None, ps.TP_AXIS)
-        h = nn.silu(h[..., 0, :]) * h[..., 1, :]
-        return pl.RowParallelLinear(
-            features=cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            sequence_parallel=cfg.sequence_parallel,
-            overlap_comm=cfg.overlap_comm, name="down",
-            tp_sync=self.tp_sync,
-            **_act_kw(cfg), **_lora_kw(cfg, "down"))(h)
+            g, u = (ps.with_sharding_constraint(h, None, None, ps.TP_AXIS)
+                    for h in (g, u))
+        return down(glu.gated(g, u))
 
     def _quantized_call(self, x: jax.Array) -> jax.Array:
         """Weight-quantized (w8a16) gate_up + down: the fused [H, 2, I]

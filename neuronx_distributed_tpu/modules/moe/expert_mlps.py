@@ -5,9 +5,10 @@ Analogue of the reference's ``modules/moe/expert_mlps_v2.py``
 :394``, ``forward_capacity_factor:484``) and the expert-fused TP layers
 (``moe/moe_parallel_layers.py``: 3-D ``[E, in, out]`` column/row parallel).
 
-TPU-native design: expert weights are stacked ``[E, H, 2, I]`` / ``[E, I, H]``
-tensors whose expert dim shards over ``ep`` and whose intermediate dim shards
-over ``tp`` (the expert-fused column/row layers are these einsums + the same
+TPU-native design: expert weights are stacked ``gate``, ``up`` ``[E, H, I]``
+(two leaves: :mod:`..glu` says why) and ``down`` ``[E, I, H]`` tensors whose
+expert dim shards over ``ep`` and whose intermediate dim shards over ``tp``
+(the expert-fused column/row layers are these batched matmuls + the same
 collective mappings as the 2-D layers). Dispatch is the capacity-factor
 mask-einsum formulation — dense, static-shaped, MXU-friendly (the reference's
 dropless/blockwise NKI path maps to a future Pallas block-sparse kernel; the
@@ -29,6 +30,7 @@ from flax import linen as nn
 from ...parallel import comm, ep_dispatch, mappings
 from ...parallel import layers as pl
 from ...parallel import mesh as ps
+from .. import glu
 
 
 def compute_capacity(num_tokens: int, num_experts: int, top_k: int,
@@ -100,11 +102,10 @@ class ExpertMLPs(nn.Module):
         i_local = pl._maybe_local(self.intermediate_size, self.tp_axis)
         ep = comm._axis_size(self.ep_axis)
 
-        gate_up = self.param(
-            "gate_up",
-            nn.with_partitioning(pl.default_kernel_init,
-                                 (self.ep_axis, None, None, self.tp_axis)),
-            (e_local, self.hidden_size, 2, i_local), self.param_dtype)
+        gate_up = glu.declare(
+            self, glu.EXPERTS, pl.default_kernel_init,
+            (self.ep_axis, None, self.tp_axis),
+            (e_local, self.hidden_size, i_local), self.param_dtype)
         down = self.param(
             "down",
             nn.with_partitioning(pl.default_kernel_init,
@@ -138,8 +139,8 @@ class ExpertMLPs(nn.Module):
         # expert-fused column parallel (3-D einsum; reference
         # ExpertFusedColumnParallelLinear moe_parallel_layers.py:175)
         xin = mappings.copy_to_tensor_parallel_region(xin, self.tp_axis)
-        h = jnp.einsum("ech,ehki->ecki", xin, gate_up.astype(self.dtype))
-        h = nn.silu(h[..., 0, :]) * h[..., 1, :]
+        h = glu.gated(*glu.project(
+            xin, *(w.astype(self.dtype) for w in gate_up)))
         out = jnp.einsum("eci,eih->ech", h, down.astype(self.dtype))
         # expert-fused row parallel exit (reference
         # ExpertFusedRowParallelLinear moe_parallel_layers.py:303)
@@ -168,7 +169,7 @@ class ExpertMLPs(nn.Module):
                   else bw.grouped_glu)
         # force_pallas=None: Pallas on TPU, the bit-exact jnp reference on
         # CPU (ops.blockwise_moe auto-dispatch)
-        return kernel(xs, gate_up.astype(self.dtype),
+        return kernel(xs, *(w.astype(self.dtype) for w in gate_up),
                       down.astype(self.dtype), be, self.block_size, bi)
 
     def _forward_blockwise(self, x, gates, idx, gate_up, down, i_local):

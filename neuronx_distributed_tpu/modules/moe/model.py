@@ -14,6 +14,7 @@ from flax import linen as nn
 
 from ...parallel import layers as pl
 from ...parallel import mesh as ps
+from .. import glu
 from .expert_mlps import ExpertMLPs
 from .routing import GroupLimitedRouter, RouterSinkhorn, RouterTopK
 
@@ -36,16 +37,14 @@ class SharedExperts(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         i_local = pl._maybe_local(self.intermediate_size, ps.TP_AXIS)
-        kernel = self.param(
-            "gate_up_kernel",
-            nn.with_partitioning(pl.default_kernel_init,
-                                 (None, None, ps.TP_AXIS)),
-            (self.hidden_size, 2, i_local), self.param_dtype)
+        gate, up = glu.declare(
+            self, glu.DENSE, pl.default_kernel_init, (None, ps.TP_AXIS),
+            (self.hidden_size, i_local), self.param_dtype)
         from ...parallel import mappings
 
         h = mappings.copy_to_tensor_parallel_region(x).astype(self.dtype)
-        g = jnp.einsum("th,hki->tki", h, kernel.astype(self.dtype))
-        g = nn.silu(g[..., 0, :]) * g[..., 1, :]
+        g = glu.gated(*glu.project(h, gate.astype(self.dtype),
+                                   up.astype(self.dtype)))
         return pl.RowParallelLinear(
             features=self.hidden_size, use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, name="down")(g)

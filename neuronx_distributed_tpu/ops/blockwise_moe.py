@@ -27,7 +27,9 @@ expert that owns it. TPU-native design, following the
   the reference.
 
 Weight layouts are the stacked expert banks of
-:class:`...modules.moe.expert_mlps.ExpertMLPs`: ``gate_up [E, H, 2, I]``,
+:class:`...modules.moe.expert_mlps.ExpertMLPs`: ``gate [E, H, I]``,
+``up [E, H, I]`` (two operands, the stored form: :mod:`...modules.glu`; a
+``stack`` at the call would put back the copy that form removed),
 ``down [E, I, H]``. Blocks whose ``block_expert[b] >= E`` are *sentinels*
 (padding or non-local EP pairs): their compute is skipped and their output
 rows are zero; their weight-tile index clamps to the last real expert so a
@@ -58,12 +60,20 @@ def _dsilu(x):
     return s * (1 + x * (1 - s))
 
 
+def _gate_up(x, wg, wu):
+    """``x [B, H]`` through one gate and one up tile ``[H, bI]``, float32:
+    the two dots every kernel body and its reference start with."""
+    dims = (((1,), (0,)), ((), ()))
+    return (lax.dot_general(x, wg, dims, preferred_element_type=jnp.float32),
+            lax.dot_general(x, wu, dims, preferred_element_type=jnp.float32))
+
+
 # ---------------------------------------------------------------------------
 # Pallas kernels (training fwd/bwd + decode fwd)
 # ---------------------------------------------------------------------------
 
-def _glu_fwd_kernel(be_ref, x_ref, gu_ref, dn_ref, y_ref, *, num_ib: int,
-                    num_real: int):
+def _glu_fwd_kernel(be_ref, x_ref, g_ref, u_ref, dn_ref, y_ref, *,
+                    num_ib: int, num_real: int):
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
@@ -78,18 +88,16 @@ def _glu_fwd_kernel(be_ref, x_ref, gu_ref, dn_ref, y_ref, *, num_ib: int,
     @pl.when(be_ref[b] < num_real)
     def _compute():
         x = x_ref[...].astype(jnp.float32)            # [B, H]
-        gu = gu_ref[0].astype(jnp.float32)            # [H, 2, bI]
-        g = jax.lax.dot_general(x, gu[:, 0], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        u = jax.lax.dot_general(x, gu[:, 1], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        wg = g_ref[0].astype(jnp.float32)             # [H, bI]
+        wu = u_ref[0].astype(jnp.float32)
+        g, u = _gate_up(x, wg, wu)
         a = _silu(g) * u                              # [B, bI]
         y_ref[...] = y_ref[...] + jax.lax.dot_general(
             a, dn_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
 
-def _glu_dx_kernel(be_ref, x_ref, gu_ref, dn_ref, dy_ref, dx_ref, *,
+def _glu_dx_kernel(be_ref, x_ref, g_ref, u_ref, dn_ref, dy_ref, dx_ref, *,
                    num_ib: int, num_real: int):
     from jax.experimental import pallas as pl
 
@@ -104,25 +112,23 @@ def _glu_dx_kernel(be_ref, x_ref, gu_ref, dn_ref, dy_ref, dx_ref, *,
     def _compute():
         x = x_ref[...].astype(jnp.float32)
         dy = dy_ref[...].astype(jnp.float32)
-        gu = gu_ref[0].astype(jnp.float32)            # [H, 2, bI]
+        wg = g_ref[0].astype(jnp.float32)             # [H, bI]
+        wu = u_ref[0].astype(jnp.float32)
         dn = dn_ref[0].astype(jnp.float32)            # [bI, H]
-        g = jax.lax.dot_general(x, gu[:, 0], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        u = jax.lax.dot_general(x, gu[:, 1], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        g, u = _gate_up(x, wg, wu)
         da = jax.lax.dot_general(dy, dn, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         dg = da * u * _dsilu(g)
         du = da * _silu(g)
-        dx = jax.lax.dot_general(dg, gu[:, 0], (((1,), (1,)), ((), ())),
+        dx = jax.lax.dot_general(dg, wg, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        dx = dx + jax.lax.dot_general(du, gu[:, 1], (((1,), (1,)), ((), ())),
+        dx = dx + jax.lax.dot_general(du, wu, (((1,), (1,)), ((), ())),
                                       preferred_element_type=jnp.float32)
         dx_ref[...] = dx_ref[...] + dx.astype(dx_ref.dtype)
 
 
-def _glu_dw_kernel(be_ref, x_ref, gu_ref, dn_ref, dy_ref, dgu_ref, ddn_ref,
-                   *, num_ib: int, num_real: int):
+def _glu_dw_kernel(be_ref, x_ref, g_ref, u_ref, dn_ref, dy_ref, dg_ref,
+                   du_ref, ddn_ref, *, num_ib: int, num_real: int):
     """Grid (ib, b): consecutive b of one expert revisit the same dW output
     block, accumulating in VMEM; zero it on the expert's first block."""
     from jax.experimental import pallas as pl
@@ -137,25 +143,24 @@ def _glu_dw_kernel(be_ref, x_ref, gu_ref, dn_ref, dy_ref, dgu_ref, ddn_ref,
 
     @pl.when(first_of_expert)
     def _init():
-        dgu_ref[...] = jnp.zeros_like(dgu_ref)
+        dg_ref[...] = jnp.zeros_like(dg_ref)
+        du_ref[...] = jnp.zeros_like(du_ref)
         ddn_ref[...] = jnp.zeros_like(ddn_ref)
 
     @pl.when(be_ref[b] < num_real)
     def _compute():
         x = x_ref[...].astype(jnp.float32)
         dy = dy_ref[...].astype(jnp.float32)
-        gu = gu_ref[0].astype(jnp.float32)
+        wg = g_ref[0].astype(jnp.float32)
+        wu = u_ref[0].astype(jnp.float32)
         dn = dn_ref[0].astype(jnp.float32)
-        g = jax.lax.dot_general(x, gu[:, 0], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        u = jax.lax.dot_general(x, gu[:, 1], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        g, u = _gate_up(x, wg, wu)
         a = _silu(g) * u
         da = jax.lax.dot_general(dy, dn, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         dg = da * u * _dsilu(g)
         du = da * _silu(g)
-        # ddown[e, ib] += a^T @ dy ; dgu[e, :, 0/1, ib] += x^T @ dg/du
+        # ddown[e, ib] += a^T @ dy ; dgate/dup[e, :, ib] += x^T @ dg/du
         ddn_ref[0] = ddn_ref[0] + jax.lax.dot_general(
             a, dy, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(ddn_ref.dtype)
@@ -163,17 +168,35 @@ def _glu_dw_kernel(be_ref, x_ref, gu_ref, dn_ref, dy_ref, dgu_ref, ddn_ref,
                                   preferred_element_type=jnp.float32)
         duw = jax.lax.dot_general(x, du, (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-        dgu_ref[0] = dgu_ref[0] + jnp.stack([dgw, duw], axis=1).astype(
-            dgu_ref.dtype)
+        dg_ref[0] = dg_ref[0] + dgw.astype(dg_ref.dtype)
+        du_ref[0] = du_ref[0] + duw.astype(du_ref.dtype)
 
 
-def _grouped_glu_pallas(xs, gate_up, down, block_expert, block_size,
+def _weight_specs(pl, h, block_i, we, b_first: bool):
+    """BlockSpecs of one block's expert tiles: gate and up ``[1, H, bI]``,
+    down ``[1, bI, H]``, on a grid ``(b, ib)`` if ``b_first`` else
+    ``(ib, b)``; ``we`` clamps a sentinel's expert id."""
+    def at(i, j):
+        return (i, j) if b_first else (j, i)
+
+    def col():
+        return pl.BlockSpec(
+            (1, h, block_i),
+            lambda i, j, be: (we(be[at(i, j)[0]]), 0, at(i, j)[1]))
+
+    row = pl.BlockSpec(
+        (1, block_i, h),
+        lambda i, j, be: (we(be[at(i, j)[0]]), at(i, j)[1], 0))
+    return [col(), col(), row]
+
+
+def _grouped_glu_pallas(xs, gate, up, down, block_expert, block_size,
                         block_i, interpret, num_real):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     p, h = xs.shape
-    e, _, _, i = gate_up.shape
+    i = gate.shape[-1]
     nb = p // block_size
     num_ib = i // block_i
     # sentinel blocks (be >= num_real) borrow the LAST real expert's weight
@@ -189,10 +212,7 @@ def _grouped_glu_pallas(xs, gate_up, down, block_expert, block_size,
         grid=(nb, num_ib),
         in_specs=[
             pl.BlockSpec((block_size, h), lambda b, ib, be: (b, 0)),
-            pl.BlockSpec((1, h, 2, block_i),
-                         lambda b, ib, be: (we(be[b]), 0, 0, ib)),
-            pl.BlockSpec((1, block_i, h),
-                         lambda b, ib, be: (we(be[b]), ib, 0)),
+            *_weight_specs(pl, h, block_i, we, True),
         ],
         out_specs=pl.BlockSpec((block_size, h), lambda b, ib, be: (b, 0)),
     )
@@ -204,10 +224,10 @@ def _grouped_glu_pallas(xs, gate_up, down, block_expert, block_size,
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
         name="grouped_glu_fwd",
-    )(block_expert, xs, gate_up, down)
+    )(block_expert, xs, gate, up, down)
 
 
-def _glu_fwd_decode_kernel(be_ref, x_ref, gu_ref, dn_ref, y_ref, *,
+def _glu_fwd_decode_kernel(be_ref, x_ref, g_ref, u_ref, dn_ref, y_ref, *,
                            num_real: int):
     from jax.experimental import pallas as pl
 
@@ -219,18 +239,16 @@ def _glu_fwd_decode_kernel(be_ref, x_ref, gu_ref, dn_ref, y_ref, *,
     @pl.when(be_ref[b] < num_real)
     def _compute():
         x = x_ref[...].astype(jnp.float32)            # [B, H]
-        gu = gu_ref[0].astype(jnp.float32)            # [H, 2, bI]
-        g = jax.lax.dot_general(x, gu[:, 0], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        u = jax.lax.dot_general(x, gu[:, 1], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        wg = g_ref[0].astype(jnp.float32)             # [H, bI]
+        wu = u_ref[0].astype(jnp.float32)
+        g, u = _gate_up(x, wg, wu)
         a = _silu(g) * u                              # [B, bI]
         y_ref[...] = jax.lax.dot_general(
             a, dn_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(y_ref.dtype)[None]
 
 
-def _grouped_glu_decode_pallas(xs, gate_up, down, block_expert, block_size,
+def _grouped_glu_decode_pallas(xs, gate, up, down, block_expert, block_size,
                                block_i, interpret):
     """Forward-only grouped GLU tuned for decode HBM traffic.
 
@@ -251,8 +269,7 @@ def _grouped_glu_decode_pallas(xs, gate_up, down, block_expert, block_size,
     from jax.experimental.pallas import tpu as pltpu
 
     p, h = xs.shape
-    e, _, _, i = gate_up.shape
-    num_real = e
+    num_real, _, i = gate.shape
     nb = p // block_size
     num_ib = i // block_i
     we = functools.partial(jnp.minimum, num_real - 1)
@@ -267,10 +284,7 @@ def _grouped_glu_decode_pallas(xs, gate_up, down, block_expert, block_size,
             grid=(num_ib, nb),
             in_specs=[
                 pl.BlockSpec((block_size, h), lambda ib, b, be: (b, 0)),
-                pl.BlockSpec((1, h, 2, block_i),
-                             lambda ib, b, be: (we(be[b]), 0, 0, ib)),
-                pl.BlockSpec((1, block_i, h),
-                             lambda ib, b, be: (we(be[b]), ib, 0)),
+                *_weight_specs(pl, h, block_i, we, False),
             ],
             out_specs=pl.BlockSpec((1, block_size, h),
                                    lambda ib, b, be: (ib, b, 0)),
@@ -278,17 +292,17 @@ def _grouped_glu_decode_pallas(xs, gate_up, down, block_expert, block_size,
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
         name="grouped_glu_fwd_decode",
-    )(block_expert, xs, gate_up, down)
+    )(block_expert, xs, gate, up, down)
     return jnp.sum(partial, axis=0).astype(xs.dtype)
 
 
-def _grouped_glu_pallas_bwd(xs, gate_up, down, block_expert, dy, block_size,
+def _grouped_glu_pallas_bwd(xs, gate, up, down, block_expert, dy, block_size,
                             block_i, interpret, num_real):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     p, h = xs.shape
-    e, _, _, i = gate_up.shape
+    i = gate.shape[-1]
     nb = p // block_size
     num_ib = i // block_i
     we = functools.partial(jnp.minimum, num_real - 1)
@@ -302,10 +316,7 @@ def _grouped_glu_pallas_bwd(xs, gate_up, down, block_expert, dy, block_size,
             grid=(nb, num_ib),
             in_specs=[
                 pl.BlockSpec((block_size, h), lambda b, ib, be: (b, 0)),
-                pl.BlockSpec((1, h, 2, block_i),
-                             lambda b, ib, be: (we(be[b]), 0, 0, ib)),
-                pl.BlockSpec((1, block_i, h),
-                             lambda b, ib, be: (we(be[b]), ib, 0)),
+                *_weight_specs(pl, h, block_i, we, True),
                 pl.BlockSpec((block_size, h), lambda b, ib, be: (b, 0)),
             ],
             out_specs=pl.BlockSpec((block_size, h),
@@ -314,36 +325,30 @@ def _grouped_glu_pallas_bwd(xs, gate_up, down, block_expert, dy, block_size,
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
         name="grouped_glu_bwd_dx",
-    )(block_expert, xs, gate_up, down, dy)
+    )(block_expert, xs, gate, up, down, dy)
 
-    dgu, ddn = pl.pallas_call(
+    dgate, dup, ddn = pl.pallas_call(
         functools.partial(_glu_dw_kernel, num_ib=num_ib,
                           num_real=num_real),
-        out_shape=[jax.ShapeDtypeStruct(gate_up.shape, jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct(gate.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(up.shape, jnp.float32),
                    jax.ShapeDtypeStruct(down.shape, jnp.float32)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(num_ib, nb),
             in_specs=[
                 pl.BlockSpec((block_size, h), lambda ib, b, be: (b, 0)),
-                pl.BlockSpec((1, h, 2, block_i),
-                             lambda ib, b, be: (we(be[b]), 0, 0, ib)),
-                pl.BlockSpec((1, block_i, h),
-                             lambda ib, b, be: (we(be[b]), ib, 0)),
+                *_weight_specs(pl, h, block_i, we, False),
                 pl.BlockSpec((block_size, h), lambda ib, b, be: (b, 0)),
             ],
-            out_specs=[
-                pl.BlockSpec((1, h, 2, block_i),
-                             lambda ib, b, be: (we(be[b]), 0, 0, ib)),
-                pl.BlockSpec((1, block_i, h),
-                             lambda ib, b, be: (we(be[b]), ib, 0)),
-            ],
+            out_specs=_weight_specs(pl, h, block_i, we, False),
         ),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
         name="grouped_glu_bwd_dw",
-    )(block_expert, xs, gate_up, down, dy)
-    return dx, dgu.astype(gate_up.dtype), ddn.astype(down.dtype)
+    )(block_expert, xs, gate, up, down, dy)
+    return (dx, dgate.astype(gate.dtype), dup.astype(up.dtype),
+            ddn.astype(down.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -357,21 +362,32 @@ def _grouped_glu_pallas_bwd(xs, gate_up, down, block_expert, dy, block_size,
 # never a masked add that could flip a -0.0).
 # ---------------------------------------------------------------------------
 
-def _ref_block_fwd(x_blk, gu_e, dn_e, live, block_i, num_ib, out_dtype):
+def _expert_weights(gate, up, down, be_b, num_real):
+    """The (clamped) expert's ``gate [H, I]``, ``up [H, I]``,
+    ``down [I, H]``, and its clamped index."""
+    we = jnp.minimum(be_b, num_real - 1)
+    return tuple(lax.dynamic_index_in_dim(w, we, 0, keepdims=False)
+                 for w in (gate, up, down)) + (we,)
+
+
+def _tiles(g_e, u_e, dn_e, ib, block_i):
+    """Tile ``ib`` of one expert's weights, float32: ``[H, bI]`` twice and
+    ``[bI, H]``."""
+    return tuple(
+        lax.dynamic_slice_in_dim(w, ib * block_i, block_i,
+                                 axis=axis).astype(jnp.float32)
+        for w, axis in ((g_e, 1), (u_e, 1), (dn_e, 0)))
+
+
+def _ref_block_fwd(x_blk, g_e, u_e, dn_e, live, block_i, num_ib, out_dtype):
     """One token block through the GLU with its (clamped) expert weights:
     the per-``ib`` fp32 partials accumulate in ``out_dtype`` exactly like
     ``y_ref[...] = y_ref[...] + partial.astype(y_ref.dtype)``."""
     x = x_blk.astype(jnp.float32)
     y = jnp.zeros((x.shape[0], dn_e.shape[-1]), out_dtype)
     for ib in range(num_ib):
-        gu = lax.dynamic_slice_in_dim(gu_e, ib * block_i, block_i, axis=2)
-        dn = lax.dynamic_slice_in_dim(dn_e, ib * block_i, block_i, axis=0)
-        gu = gu.astype(jnp.float32)
-        dn = dn.astype(jnp.float32)
-        g = lax.dot_general(x, gu[:, 0], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        u = lax.dot_general(x, gu[:, 1], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+        wg, wu, dn = _tiles(g_e, u_e, dn_e, ib, block_i)
+        g, u = _gate_up(x, wg, wu)
         a = _silu(g) * u
         y = y + lax.dot_general(
             a, dn, (((1,), (0,)), ((), ())),
@@ -379,20 +395,18 @@ def _ref_block_fwd(x_blk, gu_e, dn_e, live, block_i, num_ib, out_dtype):
     return jnp.where(live, y, jnp.zeros_like(y))
 
 
-def _ref_fwd(xs, gate_up, down, block_expert, block_size, block_i,
+def _ref_fwd(xs, gate, up, down, block_expert, block_size, block_i,
              num_real):
     p, h = xs.shape
-    i = gate_up.shape[-1]
+    i = gate.shape[-1]
     nb = p // block_size
     num_ib = i // block_i
     xb = xs.reshape(nb, block_size, h)
 
     def step(_, inp):
         x_blk, be_b = inp
-        we = jnp.minimum(be_b, num_real - 1)
-        gu_e = lax.dynamic_index_in_dim(gate_up, we, 0, keepdims=False)
-        dn_e = lax.dynamic_index_in_dim(down, we, 0, keepdims=False)
-        y = _ref_block_fwd(x_blk, gu_e, dn_e, be_b < num_real, block_i,
+        g_e, u_e, dn_e, _ = _expert_weights(gate, up, down, be_b, num_real)
+        y = _ref_block_fwd(x_blk, g_e, u_e, dn_e, be_b < num_real, block_i,
                            num_ib, xs.dtype)
         return None, y
 
@@ -400,33 +414,24 @@ def _ref_fwd(xs, gate_up, down, block_expert, block_size, block_i,
     return ys.reshape(p, h)
 
 
-def _ref_decode_fwd(xs, gate_up, down, block_expert, block_size, block_i):
+def _ref_decode_fwd(xs, gate, up, down, block_expert, block_size, block_i):
     """Decode reference: per-(ib, b) partials land in a [num_ib, P, H]
     fp32 layout summed at the end — the same ``jnp.sum(partial, axis=0)``
     the Pallas decode path performs outside the kernel."""
     p, h = xs.shape
-    i = gate_up.shape[-1]
-    num_real = gate_up.shape[0]
+    num_real, _, i = gate.shape
     nb = p // block_size
     num_ib = i // block_i
     xb = xs.reshape(nb, block_size, h)
 
     def step(_, inp):
         x_blk, be_b = inp
-        we = jnp.minimum(be_b, num_real - 1)
-        gu_e = lax.dynamic_index_in_dim(gate_up, we, 0, keepdims=False)
-        dn_e = lax.dynamic_index_in_dim(down, we, 0, keepdims=False)
+        g_e, u_e, dn_e, _ = _expert_weights(gate, up, down, be_b, num_real)
         x = x_blk.astype(jnp.float32)
         parts = []
         for ib in range(num_ib):
-            gu = lax.dynamic_slice_in_dim(
-                gu_e, ib * block_i, block_i, axis=2).astype(jnp.float32)
-            dn = lax.dynamic_slice_in_dim(
-                dn_e, ib * block_i, block_i, axis=0).astype(jnp.float32)
-            g = lax.dot_general(x, gu[:, 0], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-            u = lax.dot_general(x, gu[:, 1], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+            wg, wu, dn = _tiles(g_e, u_e, dn_e, ib, block_i)
+            g, u = _gate_up(x, wg, wu)
             a = _silu(g) * u
             y = lax.dot_general(a, dn, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -438,10 +443,10 @@ def _ref_decode_fwd(xs, gate_up, down, block_expert, block_size, block_i):
     return jnp.sum(partial, axis=0).astype(xs.dtype)
 
 
-def _ref_dx(xs, gate_up, down, block_expert, dy, block_size, block_i,
+def _ref_dx(xs, gate, up, down, block_expert, dy, block_size, block_i,
             num_real):
     p, h = xs.shape
-    i = gate_up.shape[-1]
+    i = gate.shape[-1]
     nb = p // block_size
     num_ib = i // block_i
     xb = xs.reshape(nb, block_size, h)
@@ -449,28 +454,20 @@ def _ref_dx(xs, gate_up, down, block_expert, dy, block_size, block_i,
 
     def step(_, inp):
         x_blk, dy_blk, be_b = inp
-        we = jnp.minimum(be_b, num_real - 1)
-        gu_e = lax.dynamic_index_in_dim(gate_up, we, 0, keepdims=False)
-        dn_e = lax.dynamic_index_in_dim(down, we, 0, keepdims=False)
+        g_e, u_e, dn_e, _ = _expert_weights(gate, up, down, be_b, num_real)
         x = x_blk.astype(jnp.float32)
         dyf = dy_blk.astype(jnp.float32)
         dx = jnp.zeros((block_size, h), xs.dtype)
         for ib in range(num_ib):
-            gu = lax.dynamic_slice_in_dim(
-                gu_e, ib * block_i, block_i, axis=2).astype(jnp.float32)
-            dn = lax.dynamic_slice_in_dim(
-                dn_e, ib * block_i, block_i, axis=0).astype(jnp.float32)
-            g = lax.dot_general(x, gu[:, 0], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-            u = lax.dot_general(x, gu[:, 1], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+            wg, wu, dn = _tiles(g_e, u_e, dn_e, ib, block_i)
+            g, u = _gate_up(x, wg, wu)
             da = lax.dot_general(dyf, dn, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
             dg = da * u * _dsilu(g)
             du = da * _silu(g)
-            d = lax.dot_general(dg, gu[:, 0], (((1,), (1,)), ((), ())),
+            d = lax.dot_general(dg, wg, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-            d = d + lax.dot_general(du, gu[:, 1], (((1,), (1,)), ((), ())),
+            d = d + lax.dot_general(du, wu, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             dx = dx + d.astype(xs.dtype)
         return None, jnp.where(be_b < num_real, dx, jnp.zeros_like(dx))
@@ -479,39 +476,37 @@ def _ref_dx(xs, gate_up, down, block_expert, dy, block_size, block_i,
     return dxs.reshape(p, h)
 
 
-def _ref_dw(xs, gate_up, down, block_expert, dy, block_size, block_i,
+def _accumulate(acc, part, start):
+    """``acc[start : start + part.shape] += part`` (``part`` leads with a
+    1): one fp32 add per tile, as the kernel's VMEM accumulation does."""
+    tile = lax.dynamic_slice(acc, start, part.shape)
+    return lax.dynamic_update_slice(acc, tile + part, start)
+
+
+def _ref_dw(xs, gate, up, down, block_expert, dy, block_size, block_i,
             num_real):
     """dW reference: fp32 accumulators updated block-by-block in ascending
     ``b`` order (the kernel's grid (ib, b) VMEM accumulation per expert
     tile is exactly this sequence of fp32 adds); sentinel blocks are
     skipped via ``lax.cond`` so they contribute no add at all."""
     p, h = xs.shape
-    i = gate_up.shape[-1]
+    i = gate.shape[-1]
     nb = p // block_size
     num_ib = i // block_i
     xb = xs.reshape(nb, block_size, h)
     dyb = dy.reshape(nb, block_size, h)
 
     def step(carry, inp):
-        dgu, ddn = carry
         x_blk, dy_blk, be_b = inp
-        we = jnp.minimum(be_b, num_real - 1)
-        gu_e = lax.dynamic_index_in_dim(gate_up, we, 0, keepdims=False)
-        dn_e = lax.dynamic_index_in_dim(down, we, 0, keepdims=False)
+        g_e, u_e, dn_e, we = _expert_weights(gate, up, down, be_b, num_real)
         x = x_blk.astype(jnp.float32)
         dyf = dy_blk.astype(jnp.float32)
 
         def upd(c):
-            dgu, ddn = c
+            dgate, dup, ddn = c
             for ib in range(num_ib):
-                gu = lax.dynamic_slice_in_dim(
-                    gu_e, ib * block_i, block_i, axis=2).astype(jnp.float32)
-                dn = lax.dynamic_slice_in_dim(
-                    dn_e, ib * block_i, block_i, axis=0).astype(jnp.float32)
-                g = lax.dot_general(x, gu[:, 0], (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-                u = lax.dot_general(x, gu[:, 1], (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+                wg, wu, dn = _tiles(g_e, u_e, dn_e, ib, block_i)
+                g, u = _gate_up(x, wg, wu)
                 a = _silu(g) * u
                 da = lax.dot_general(dyf, dn, (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
@@ -523,24 +518,17 @@ def _ref_dw(xs, gate_up, down, block_expert, dy, block_size, block_i,
                                       preferred_element_type=jnp.float32)
                 duw = lax.dot_general(x, du, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
-                dgu_c = jnp.stack([dgw, duw], axis=1)     # [H, 2, bI]
-                tile = lax.dynamic_slice(
-                    dgu, (we, 0, 0, ib * block_i), (1, h, 2, block_i))
-                dgu = lax.dynamic_update_slice(
-                    dgu, tile + dgu_c[None], (we, 0, 0, ib * block_i))
-                tile = lax.dynamic_slice(
-                    ddn, (we, ib * block_i, 0), (1, block_i, h))
-                ddn = lax.dynamic_update_slice(
-                    ddn, tile + ddn_c[None], (we, ib * block_i, 0))
-            return dgu, ddn
+                dgate = _accumulate(dgate, dgw[None], (we, 0, ib * block_i))
+                dup = _accumulate(dup, duw[None], (we, 0, ib * block_i))
+                ddn = _accumulate(ddn, ddn_c[None], (we, ib * block_i, 0))
+            return dgate, dup, ddn
 
-        carry = lax.cond(be_b < num_real, upd, lambda c: c, (dgu, ddn))
-        return carry, None
+        return lax.cond(be_b < num_real, upd, lambda c: c, carry), None
 
-    init = (jnp.zeros(gate_up.shape, jnp.float32),
-            jnp.zeros(down.shape, jnp.float32))
-    (dgu, ddn), _ = lax.scan(step, init, (xb, dyb, block_expert))
-    return dgu.astype(gate_up.dtype), ddn.astype(down.dtype)
+    init = tuple(jnp.zeros(w.shape, jnp.float32) for w in (gate, up, down))
+    (dgate, dup, ddn), _ = lax.scan(step, init, (xb, dyb, block_expert))
+    return (dgate.astype(gate.dtype), dup.astype(up.dtype),
+            ddn.astype(down.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -548,57 +536,57 @@ def _ref_dw(xs, gate_up, down, block_expert, dy, block_size, block_i,
 # signatures, so autodiff works through whichever path auto-dispatch picks
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _grouped_glu_kernel(xs, gate_up, down, block_expert, block_size,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _grouped_glu_kernel(xs, gate, up, down, block_expert, block_size,
                         block_i, interpret):
-    return _grouped_glu_pallas(xs, gate_up, down, block_expert, block_size,
-                               block_i, interpret, gate_up.shape[0])
+    return _grouped_glu_pallas(xs, gate, up, down, block_expert, block_size,
+                               block_i, interpret, gate.shape[0])
 
 
-def _kernel_fwd(xs, gate_up, down, block_expert, block_size, block_i,
+def _kernel_fwd(xs, gate, up, down, block_expert, block_size, block_i,
                 interpret):
-    ys = _grouped_glu_pallas(xs, gate_up, down, block_expert, block_size,
-                             block_i, interpret, gate_up.shape[0])
-    return ys, (xs, gate_up, down, block_expert)
+    ys = _grouped_glu_pallas(xs, gate, up, down, block_expert, block_size,
+                             block_i, interpret, gate.shape[0])
+    return ys, (xs, gate, up, down, block_expert)
 
 
 def _kernel_bwd(block_size, block_i, interpret, res, dy):
-    xs, gate_up, down, block_expert = res
-    dx, dgu, ddn = _grouped_glu_pallas_bwd(
-        xs, gate_up, down, block_expert, dy, block_size, block_i, interpret,
-        gate_up.shape[0])
+    xs, gate, up, down, block_expert = res
+    dx, dgate, dup, ddn = _grouped_glu_pallas_bwd(
+        xs, gate, up, down, block_expert, dy, block_size, block_i,
+        interpret, gate.shape[0])
     dbe = jnp.zeros(block_expert.shape, jax.dtypes.float0)
-    return dx, dgu, ddn, dbe
+    return dx, dgate, dup, ddn, dbe
 
 
 _grouped_glu_kernel.defvjp(_kernel_fwd, _kernel_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def grouped_glu_reference(xs, gate_up, down, block_expert, block_size,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def grouped_glu_reference(xs, gate, up, down, block_expert, block_size,
                           block_i):
     """Pure-jnp grouped GLU, arithmetic-identical to the Pallas kernel
     (the golden reference of the interpret-mode parity gate, and the
     silent CPU fallback of :func:`grouped_glu`)."""
-    return _ref_fwd(xs, gate_up, down, block_expert, block_size, block_i,
-                    gate_up.shape[0])
+    return _ref_fwd(xs, gate, up, down, block_expert, block_size, block_i,
+                    gate.shape[0])
 
 
-def _ref_vjp_fwd(xs, gate_up, down, block_expert, block_size, block_i):
-    ys = _ref_fwd(xs, gate_up, down, block_expert, block_size, block_i,
-                  gate_up.shape[0])
-    return ys, (xs, gate_up, down, block_expert)
+def _ref_vjp_fwd(xs, gate, up, down, block_expert, block_size, block_i):
+    ys = _ref_fwd(xs, gate, up, down, block_expert, block_size, block_i,
+                  gate.shape[0])
+    return ys, (xs, gate, up, down, block_expert)
 
 
 def _ref_vjp_bwd(block_size, block_i, res, dy):
-    xs, gate_up, down, block_expert = res
-    num_real = gate_up.shape[0]
-    dx = _ref_dx(xs, gate_up, down, block_expert, dy, block_size, block_i,
+    xs, gate, up, down, block_expert = res
+    num_real = gate.shape[0]
+    dx = _ref_dx(xs, gate, up, down, block_expert, dy, block_size, block_i,
                  num_real)
-    dgu, ddn = _ref_dw(xs, gate_up, down, block_expert, dy, block_size,
-                       block_i, num_real)
+    dgate, dup, ddn = _ref_dw(xs, gate, up, down, block_expert, dy,
+                              block_size, block_i, num_real)
     dbe = jnp.zeros(block_expert.shape, jax.dtypes.float0)
-    return dx, dgu, ddn, dbe
+    return dx, dgate, dup, ddn, dbe
 
 
 grouped_glu_reference.defvjp(_ref_vjp_fwd, _ref_vjp_bwd)
@@ -618,7 +606,7 @@ def use_pallas(force_pallas=None) -> bool:
     return bool(force_pallas)
 
 
-def grouped_glu(xs, gate_up, down, block_expert, block_size, block_i,
+def grouped_glu(xs, gate, up, down, block_expert, block_size, block_i,
                 force_pallas=None):
     """Block-sparse grouped GLU: ``ys[b] = silu(x_b@Wg_e)·(x_b@Wu_e) @ Wd_e``
     with ``e = block_expert[b]`` (the dropless expert matmul; training
@@ -631,20 +619,20 @@ def grouped_glu(xs, gate_up, down, block_expert, block_size, block_i,
     expert owns >= 1 block, so no dW tile is left unwritten."""
     if use_pallas(force_pallas):
         interpret = not on_tpu()
-        return _grouped_glu_kernel(xs, gate_up, down, block_expert,
+        return _grouped_glu_kernel(xs, gate, up, down, block_expert,
                                    block_size, block_i, interpret)
-    return grouped_glu_reference(xs, gate_up, down, block_expert,
+    return grouped_glu_reference(xs, gate, up, down, block_expert,
                                  block_size, block_i)
 
 
-def grouped_glu_decode(xs, gate_up, down, block_expert, block_size,
+def grouped_glu_decode(xs, gate, up, down, block_expert, block_size,
                        block_i, force_pallas=None):
     """Forward-only grouped GLU tuned for decode HBM traffic (token blocks
     innermost so one expert's weight DMA serves its whole block run; pair
     with ``sentinel_empty`` metadata so only hit experts are read)."""
     if use_pallas(force_pallas):
         interpret = not on_tpu()
-        return _grouped_glu_decode_pallas(xs, gate_up, down, block_expert,
+        return _grouped_glu_decode_pallas(xs, gate, up, down, block_expert,
                                           block_size, block_i, interpret)
-    return _ref_decode_fwd(xs, gate_up, down, block_expert, block_size,
+    return _ref_decode_fwd(xs, gate, up, down, block_expert, block_size,
                            block_i)

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
+from ..modules import glu
 from ..parallel import layers as pl
 from ..parallel import mappings
 from ..parallel import mesh as ps
@@ -357,10 +358,10 @@ class MXExpertMLPs(nn.Module):
 
 def mx_pack_expert_params(params, mx_format: str = "fp4"):
     """Transform an :class:`...modules.moe.ExpertMLPs` param subtree
-    (``gate_up [E,H,2,I]`` / ``down [E,I,H]``) into :class:`MXExpertMLPs`
+    (``gate``, ``up [E,H,I]`` / ``down [E,I,H]``) into :class:`MXExpertMLPs`
     params (contraction-last packed layout) — the converter-side MX
     transform (reference ``microscaling/transform_weights.py``)."""
-    gu = np.asarray(params["gate_up"], np.float32)   # [E, H, 2, I]
+    gu = glu.fused(params, glu.EXPERTS).astype(np.float32)  # [E, H, 2, I]
     dn = np.asarray(params["down"], np.float32)      # [E, I, H]
     gu_t = np.transpose(gu, (0, 2, 3, 1))            # [E, 2, I, H]
     dn_t = np.transpose(dn, (0, 2, 1))               # [E, H, I]

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..modules import glu
 from ..parallel import layers as pl
 from ..parallel import mappings
 from ..parallel import mesh as ps
@@ -333,12 +334,12 @@ class QuantizedExpertMLPs(nn.Module):
 
 
 def quantize_expert_params(params, quantized_dtype=QuantizedDtype.INT8):
-    """Convert an :class:`ExpertMLPs` param subtree (``gate_up``/``down``)
-    into :class:`QuantizedExpertMLPs` params (per-expert, per-out-channel
-    symmetric scales)."""
+    """Convert an :class:`ExpertMLPs` param subtree (``gate``/``up``/
+    ``down``) into :class:`QuantizedExpertMLPs` params (per-expert,
+    per-out-channel symmetric scales)."""
     import numpy as np
 
-    gu = np.asarray(params["gate_up"])      # [E, H, 2, I]
+    gu = glu.fused(params, glu.EXPERTS)     # [E, H, 2, I]
     dn = np.asarray(params["down"])         # [E, I, H]
     out = {}
     # per (expert, gate/up, out-channel) over the contraction dim H

@@ -21,6 +21,7 @@ from typing import Any, Dict
 import jax.numpy as jnp
 import numpy as np
 
+from ..modules import glu
 from .microscaling import mx_quantize_fp4, mx_quantize_fp8
 from .quantization_utils import QuantizedDtype
 
@@ -121,7 +122,7 @@ def quantize_params_for_serving(cfg, params) -> Dict[str, Any]:
         elif name == "mlp":
             mlp: Dict[str, Any] = {}
             # [L, hidden, 2, intermediate]
-            mlp.update(pair(mod["gate_up_kernel"], 1, "gate_up"))
+            mlp.update(pair(glu.fused(mod, glu.DENSE), 1, "gate_up"))
             # [L, intermediate, hidden]
             mlp["down"] = pair(mod["down"]["kernel"], 1, "kernel")
             new_layer[name] = mlp
@@ -129,7 +130,8 @@ def quantize_params_for_serving(cfg, params) -> Dict[str, Any]:
             moe = dict(mod)  # router / shared stay float
             experts: Dict[str, Any] = {}
             # [L, E, hidden, 2, intermediate]
-            experts.update(pair(mod["experts"]["gate_up"], 2, "gate_up"))
+            experts.update(pair(glu.fused(mod["experts"], glu.EXPERTS), 2,
+                                "gate_up"))
             # [L, E, intermediate, hidden]
             experts.update(pair(mod["experts"]["down"], 2, "down"))
             moe["experts"] = experts

@@ -19,13 +19,19 @@ model.embed_tokens.weight                      model/embed/embedding
 model.layers.N.self_attn.{q,k,v}_proj.weight   model/layers/layer/attn/qkv/
                                                {q,k,v}_kernel
 model.layers.N.self_attn.o_proj.weight         model/layers/layer/attn/o_proj
-model.layers.N.mlp.{gate,up}_proj.weight       fused gate_up_kernel [H, 2, I]
+model.layers.N.mlp.{gate,up}_proj.weight       .../mlp/{gate,up}_kernel [H, I]
 model.layers.N.mlp.down_proj.weight            model/layers/layer/mlp/down
 model.layers.N.input_layernorm.weight          .../input_norm/scale
 model.layers.N.post_attention_layernorm.weight .../post_norm/scale
 model.norm.weight                              model/norm/scale
 lm_head.weight                                 lm_head/kernel
 =============================================  =============================
+
+Gate and up are two leaves (Mixtral's ``w1``/``w3``: ``experts/gate``,
+``experts/up`` ``[L, E, H, I]``) through ``modules/glu.py``, which owns the
+stored form and says why it is not one fused leaf (the chip's tiling); it
+refuses a tree in the fused form by name, and converting from the
+published tensors is the way across.
 """
 
 from __future__ import annotations
@@ -33,6 +39,8 @@ from __future__ import annotations
 from typing import Any, Dict
 
 import numpy as np
+
+from ..modules import glu
 
 
 def _t(w) -> np.ndarray:
@@ -64,12 +72,10 @@ def convert_hf_llama_to_nxd(state_dict: Dict[str, Any], cfg) -> Dict:
                 "model.layers.{}.self_attn.o_proj.weight")},
         },
         "mlp": {
-            # fused [L, H, 2, I]: index 0 = gate, 1 = up
-            "gate_up_kernel": np.stack([
-                np.stack([_t(sd[f"model.layers.{i}.mlp.gate_proj.weight"]),
-                          _t(sd[f"model.layers.{i}.mlp.up_proj.weight"])],
-                         axis=1)
-                for i in range(L)]),
+            **glu.from_published(
+                stack("model.layers.{}.mlp.gate_proj.weight", np.asarray),
+                stack("model.layers.{}.mlp.up_proj.weight", np.asarray),
+                glu.DENSE),
             "down": {"kernel": stack("model.layers.{}.mlp.down_proj.weight")},
         },
         "input_norm": {"scale": stack(
@@ -109,6 +115,7 @@ def convert_nxd_to_hf_llama(params: Dict, cfg) -> Dict[str, np.ndarray]:
     # tied models (no lm_head param) export the HF tie_word_embeddings
     # convention: lm_head.weight omitted, embed_tokens carries the table
     L = cfg.num_layers
+    gate_proj, up_proj = glu.to_published(layers["mlp"], glu.DENSE)
     for i in range(L):
         pre = f"model.layers.{i}."
         qkv = layers["attn"]["qkv"]
@@ -117,9 +124,8 @@ def convert_nxd_to_hf_llama(params: Dict, cfg) -> Dict[str, np.ndarray]:
         out[pre + "self_attn.v_proj.weight"] = _t(qkv["v_kernel"][i])
         out[pre + "self_attn.o_proj.weight"] = _t(
             layers["attn"]["o_proj"]["kernel"][i])
-        gu = np.asarray(layers["mlp"]["gate_up_kernel"][i])  # [H, 2, I]
-        out[pre + "mlp.gate_proj.weight"] = _t(gu[:, 0])
-        out[pre + "mlp.up_proj.weight"] = _t(gu[:, 1])
+        out[pre + "mlp.gate_proj.weight"] = gate_proj[i]
+        out[pre + "mlp.up_proj.weight"] = up_proj[i]
         out[pre + "mlp.down_proj.weight"] = _t(
             layers["mlp"]["down"]["kernel"][i])
         out[pre + "input_layernorm.weight"] = np.asarray(
@@ -145,22 +151,16 @@ def _asnp(w) -> np.ndarray:
 def convert_hf_mixtral_to_nxd(state_dict: Dict[str, Any], cfg) -> Dict:
     """HF Mixtral state dict → our param tree (``MixtralForCausalLM``,
     ``scan_layers=True``). Expert stacking: HF's per-expert ``w1``
-    (gate) / ``w3`` (up) fuse into ``gate_up [L, E, H, 2, I]``; ``w2``
+    (gate) / ``w3`` (up) stack to ``gate``, ``up`` ``[L, E, H, I]``; ``w2``
     (down) stacks to ``[L, E, I, H]`` (reference Mixtral conversion)."""
     sd = {k: np.asarray(v) for k, v in state_dict.items()}
     L, E = cfg.num_layers, cfg.num_experts
 
-    def expert_gate_up(i):
-        pre = f"model.layers.{i}.block_sparse_moe.experts"
-        return np.stack([
-            np.stack([_t(sd[f"{pre}.{e}.w1.weight"]),
-                      _t(sd[f"{pre}.{e}.w3.weight"])], axis=1)
-            for e in range(E)])  # [E, H, 2, I]
-
-    def expert_down(i):
-        pre = f"model.layers.{i}.block_sparse_moe.experts"
-        return np.stack([_t(sd[f"{pre}.{e}.w2.weight"])
-                         for e in range(E)])  # [E, I, H]
+    def experts(w: str, transform=_t) -> np.ndarray:
+        """One published expert tensor, stacked ``[L, E, ...]``."""
+        return np.stack([np.stack([transform(sd[
+            f"model.layers.{i}.block_sparse_moe.experts.{e}.{w}.weight"])
+            for e in range(E)]) for i in range(L)])
 
     layers = {
         "attn": {
@@ -179,8 +179,9 @@ def convert_hf_mixtral_to_nxd(state_dict: Dict[str, Any], cfg) -> Dict:
             "router": {"kernel": _stack(
                 sd, "model.layers.{}.block_sparse_moe.gate.weight", L)},
             "experts": {
-                "gate_up": np.stack([expert_gate_up(i) for i in range(L)]),
-                "down": np.stack([expert_down(i) for i in range(L)]),
+                **glu.from_published(experts("w1", _asnp),
+                                      experts("w3", _asnp), glu.EXPERTS),
+                "down": experts("w2"),  # [L, E, I, H]
             },
         },
         "input_norm": {"scale": _stack(
@@ -408,7 +409,7 @@ def convert_hf_vit_to_nxd(state_dict: Dict[str, Any], cfg) -> Dict:
 
 def convert_nxd_to_hf_mixtral(params: Dict, cfg) -> Dict[str, np.ndarray]:
     """Inverse of :func:`convert_hf_mixtral_to_nxd` (per-expert w1/w3/w2
-    unstacked from the fused ``gate_up``/``down`` banks)."""
+    unstacked from the ``gate``/``up``/``down`` banks)."""
     p = params["params"]
     layers = p["model"]["layers"]["layer"]
     out: Dict[str, np.ndarray] = {
@@ -417,6 +418,7 @@ def convert_nxd_to_hf_mixtral(params: Dict, cfg) -> Dict[str, np.ndarray]:
         "model.norm.weight": np.asarray(p["model"]["norm"]["scale"]),
         "lm_head.weight": _t(p["lm_head"]["kernel"]),
     }
+    w1, w3 = glu.to_published(layers["moe"]["experts"], glu.EXPERTS)
     for i in range(cfg.num_layers):
         pre = f"model.layers.{i}."
         qkv = layers["attn"]["qkv"]
@@ -427,12 +429,11 @@ def convert_nxd_to_hf_mixtral(params: Dict, cfg) -> Dict[str, np.ndarray]:
             layers["attn"]["o_proj"]["kernel"][i])
         out[pre + "block_sparse_moe.gate.weight"] = _t(
             layers["moe"]["router"]["kernel"][i])
-        gu = np.asarray(layers["moe"]["experts"]["gate_up"][i])  # [E,H,2,I]
         dn = np.asarray(layers["moe"]["experts"]["down"][i])     # [E,I,H]
         for e in range(cfg.num_experts):
             epre = pre + f"block_sparse_moe.experts.{e}."
-            out[epre + "w1.weight"] = _t(gu[e, :, 0])
-            out[epre + "w3.weight"] = _t(gu[e, :, 1])
+            out[epre + "w1.weight"] = w1[i, e]
+            out[epre + "w3.weight"] = w3[i, e]
             out[epre + "w2.weight"] = _t(dn[e])
         out[pre + "input_layernorm.weight"] = np.asarray(
             layers["input_norm"]["scale"][i])

@@ -27,7 +27,8 @@ def _problem(T=16, H=8, I=16, E=4, K=2, B=8, seed=0, sentinel_empty=False,
     order, src, dest, be, num_blocks, padded = bw.compute_block_metadata(
         idx, E, B, sentinel_empty=sentinel_empty)
     xs = bw.scatter_to_blocks(x, src, dest, padded)
-    gate_up = jax.random.normal(ks[2], (E, H, 2, I), jnp.float32) * 0.3
+    # gate and up: the two operands, in the stored form (modules/glu.py)
+    gate_up = tuple(jax.random.normal(ks[2], (2, E, H, I), jnp.float32) * 0.3)
     down = jax.random.normal(ks[3], (E, I, H), jnp.float32) * 0.3
     return xs, gate_up, down, be, B, num_blocks
 
@@ -35,28 +36,28 @@ def _problem(T=16, H=8, I=16, E=4, K=2, B=8, seed=0, sentinel_empty=False,
 @pytest.mark.parametrize("bi_frac", [1, 2])
 def test_grouped_glu_interpret_bitwise_forward(bi_frac):
     xs, gate_up, down, be, B, _ = _problem()
-    bi = gate_up.shape[-1] // bi_frac  # exercise intermediate-dim tiling
-    y_k = ops_bw.grouped_glu(xs, gate_up, down, be, B, bi,
+    bi = gate_up[0].shape[-1] // bi_frac  # exercise intermediate-dim tiling
+    y_k = ops_bw.grouped_glu(xs, *gate_up, down, be, B, bi,
                              force_pallas=True)
-    y_r = ops_bw.grouped_glu(xs, gate_up, down, be, B, bi,
+    y_r = ops_bw.grouped_glu(xs, *gate_up, down, be, B, bi,
                              force_pallas=False)
     np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
     # force_pallas=False is literally the reference
-    y_ref = ops_bw.grouped_glu_reference(xs, gate_up, down, be, B, bi)
+    y_ref = ops_bw.grouped_glu_reference(xs, *gate_up, down, be, B, bi)
     np.testing.assert_array_equal(np.asarray(y_r), np.asarray(y_ref))
 
 
 def test_grouped_glu_interpret_bitwise_grads():
     xs, gate_up, down, be, B, _ = _problem()
-    bi = gate_up.shape[-1] // 2
+    bi = gate_up[0].shape[-1] // 2
     cot = jax.random.normal(jax.random.key(9), xs.shape, jnp.float32)
 
     def loss(force):
-        def f(xs_, gu_, dn_):
-            y = ops_bw.grouped_glu(xs_, gu_, dn_, be, B, bi,
+        def f(xs_, g_, u_, dn_):
+            y = ops_bw.grouped_glu(xs_, g_, u_, dn_, be, B, bi,
                                    force_pallas=force)
             return jnp.sum(y * cot)  # non-uniform cotangent
-        return jax.grad(f, argnums=(0, 1, 2))(xs, gate_up, down)
+        return jax.grad(f, argnums=(0, 1, 2, 3))(xs, *gate_up, down)
 
     for g_k, g_r in zip(loss(True), loss(False)):
         np.testing.assert_array_equal(np.asarray(g_k), np.asarray(g_r))
@@ -69,10 +70,10 @@ def test_grouped_glu_decode_interpret_bitwise_with_sentinels():
     xs, gate_up, down, be, B, _ = _problem(T=T, K=K, E=E, B=4,
                                            sentinel_empty=True, idx=idx)
     assert bool(jnp.any(be >= E)), "fixture must produce sentinel blocks"
-    bi = gate_up.shape[-1]
-    y_k = ops_bw.grouped_glu_decode(xs, gate_up, down, be, B, bi,
+    bi = gate_up[0].shape[-1]
+    y_k = ops_bw.grouped_glu_decode(xs, *gate_up, down, be, B, bi,
                                     force_pallas=True)
-    y_r = ops_bw.grouped_glu_decode(xs, gate_up, down, be, B, bi,
+    y_r = ops_bw.grouped_glu_decode(xs, *gate_up, down, be, B, bi,
                                     force_pallas=False)
     np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
     # sentinel blocks' rows are hard zero in both impls
@@ -85,9 +86,9 @@ def test_cpu_auto_dispatch_is_the_reference():
     assert ops_bw.use_pallas(None) is False
     assert ops_bw.use_pallas(True) is True
     xs, gate_up, down, be, B, _ = _problem(seed=3)
-    bi = gate_up.shape[-1]
-    y_auto = ops_bw.grouped_glu(xs, gate_up, down, be, B, bi)
-    y_ref = ops_bw.grouped_glu_reference(xs, gate_up, down, be, B, bi)
+    bi = gate_up[0].shape[-1]
+    y_auto = ops_bw.grouped_glu(xs, *gate_up, down, be, B, bi)
+    y_ref = ops_bw.grouped_glu_reference(xs, *gate_up, down, be, B, bi)
     np.testing.assert_array_equal(np.asarray(y_auto), np.asarray(y_ref))
 
 

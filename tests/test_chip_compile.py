@@ -163,6 +163,135 @@ def test_grouped_glu_decode(chip):
                            block_i=block_i, interpret=False)
     text = _assert_kernel_compiles(
         fn, chip((e * block, h), jnp.bfloat16),
-        chip((e, h, 2, i), jnp.bfloat16), chip((e, i, h), jnp.bfloat16),
-        chip((e,), jnp.int32))
+        chip((e, h, i), jnp.bfloat16), chip((e, h, i), jnp.bfloat16),
+        chip((e, i, h), jnp.bfloat16), chip((e,), jnp.int32))
     assert _kernel_instruction_names(text) == {"grouped_glu_fwd_decode"}
+
+
+# -- gate and up in a layer scan: the scan's slice fuses into the matmul ----
+# A fused leaf with a 2 second from last is tiled T(2,128) on the chip; the
+# matmul then cannot take the scan's dynamic-slice into its fusion, and XLA
+# copies a layer's gate and up out of the stack first (PERF.md, PR 28).
+# modules/glu.py stores two leaves, which compile clean; these cases hold
+# every later form to that.
+
+_PLUMBING = ("parameter", "get-tuple-element", "tuple", "while", "bitcast",
+             "conditional", "call", "constant")
+
+
+def _top_level_results(hlo_text, at_least):
+    """``(opcode, name, shape)`` of every instruction outside a fused
+    computation whose result has ``at_least`` elements or more: what the
+    device runs as an operation of its own and writes to memory."""
+    import re
+
+    fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.\-]+)", hlo_text))
+    found, comp = [], None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"^\s*(?:ROOT )?%([\w.\-]+) = (\(?[a-z0-9]+\[.*?) "
+                     r"([a-z\-]+)\(", line)
+        if comp in fused or not m or m.group(3) in _PLUMBING:
+            continue
+        for shape in re.finditer(r"[a-z0-9]+\[([\d,]+)\]", m.group(2)):
+            if math.prod(map(int, shape.group(1).split(","))) >= at_least:
+                found.append((m.group(3), m.group(1), shape.group(0)))
+                break
+    return found
+
+
+def _stacked(chip, module, layers, *sample, dtype):
+    """Abstract parameters of ``layers`` copies of ``module``, stacked on a
+    leading dimension as the layer scan holds them."""
+    from flax.core import meta
+
+    shapes = meta.unbox(jax.eval_shape(module.init, jax.random.key(0),
+                                       *sample))
+    return jax.tree_util.tree_map(
+        lambda s: chip((layers,) + s.shape, dtype), shapes)
+
+
+def _dense_mlp(inter, dtype, param_dtype):
+    from neuronx_distributed_tpu.models.llama import LlamaConfig, LlamaMLP
+
+    return LlamaMLP(LlamaConfig(
+        vocab_size=256, hidden_size=4096, intermediate_size=inter,
+        num_layers=2, num_heads=32, num_kv_heads=8, max_seq_len=4096,
+        dtype=dtype, param_dtype=param_dtype))
+
+
+# (layer, rows of the packed step); Mixtral's bank at the capacity the
+# serving cells run (factor 4.0: 128 slots an expert)
+_GLU_SCANS = {"mistral": 14336, "evabyte": 11008, "mixtral_experts": 14336}
+
+
+@pytest.mark.parametrize("which", list(_GLU_SCANS))
+def test_layer_scan_reads_gate_and_up_in_place(chip, which):
+    from neuronx_distributed_tpu.modules.moe import ExpertMLPs
+
+    h, inter, rows = 4096, _GLU_SCANS[which], 128
+    x = jnp.zeros((rows, h), jnp.bfloat16)
+    if which == "mixtral_experts":
+        experts = 8
+        layer = ExpertMLPs(num_experts=experts, hidden_size=h,
+                           intermediate_size=inter, top_k=2,
+                           capacity_factor=4.0, dtype=jnp.bfloat16,
+                           param_dtype=jnp.bfloat16)
+        extra = (jnp.zeros((rows, 2), jnp.bfloat16),
+                 jnp.zeros((rows, 2), jnp.int32))
+        apply = lambda p, x, g, i: layer.apply(p, x, g, i)[0]
+    else:
+        experts, extra = 1, ()
+        layer = _dense_mlp(inter, jnp.bfloat16, jnp.bfloat16)
+        x = x[None]
+        apply = layer.apply
+    stacked = _stacked(chip, layer, 2, x, *extra, dtype=jnp.bfloat16)
+
+    def step(stacked, x, *extra):
+        def body(x, p):
+            return x + apply(p, x, *extra), None
+        return jax.lax.scan(body, x, stacked)[0]
+
+    compiled = jax.jit(step).lower(
+        stacked, *(chip(a.shape, a.dtype) for a in (x, *extra))).compile()
+    gate_up_bytes = 2 * experts * h * inter * 2
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < gate_up_bytes / 4)
+    # a whole layer's gate or up: no operation but a matmul reads one, and
+    # a matmul's result is rows wide
+    assert not _top_level_results(compiled.as_text(), experts * h * inter)
+
+
+def test_train_scan_writes_gate_and_up_gradients_in_place(chip):
+    """The train cell's per-chip shapes (tp=4: I 14,336 / 4, float32
+    leaves, bf16 compute, full remat): each layer's dW matmul writes into
+    the stacked gradient (``dynamic-update-slice`` fused in), with no
+    per-layer ``copy`` of a gate or up leaf in front of it."""
+    h, inter, layers = 4096, 3584, 2
+    mlp = _dense_mlp(inter, jnp.bfloat16, jnp.float32)
+    x = jnp.zeros((2, 4096, h), jnp.bfloat16)
+    stacked = _stacked(chip, mlp, layers, x, dtype=jnp.float32)
+
+    def loss(stacked, x):
+        def body(x, p):
+            return x + mlp.apply(p, x), None
+        y, _ = jax.lax.scan(jax.checkpoint(body), x, stacked)
+        return jnp.sum(y.astype(jnp.float32))
+
+    def step(stacked, x):
+        grads = jax.grad(loss)(stacked, x)
+        return jax.tree_util.tree_map(lambda p, g: p - 1e-4 * g, stacked,
+                                      grads)
+
+    text = jax.jit(step, donate_argnums=(0,)).lower(
+        stacked, chip(x.shape, x.dtype)).compile().as_text()
+    # results shaped as a gate or up leaf, one layer's or the whole stack's
+    leafs = [r for r in _top_level_results(text, h * inter)
+             if r[2].endswith(f"{h},{inter}]")]
+    assert not [r for r in leafs if r[0] == "copy"], leafs
+    # gate's and up's gradients: two matmuls that end in the write
+    assert sum("dynamic-update-slice" in name for _, name, _ in leafs) == 2, (
+        leafs)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import neuronx_distributed_tpu as nxd
 from neuronx_distributed_tpu.models.llama import LlamaForCausalLM, tiny_config
 from neuronx_distributed_tpu.models import llama_pipeline as lpp
+from neuronx_distributed_tpu.modules import glu
 from neuronx_distributed_tpu.trainer import (initialize_parallel_model,
                                              initialize_parallel_optimizer,
                                              make_train_step)
@@ -170,17 +171,20 @@ def test_ep_matrix_one_step(tp, ep, zero1, dispatch):
     tx, state, sh = initialize_parallel_optimizer(pm, params, 1e-3)
 
     # GSPMD EP is real: expert weights shard over ep on the expert mesh view
-    gate_up = state.params["params"]["model"]["layers"]["layer"]["moe"][
-        "experts"]["gate_up"]
-    assert "ep" in jax.tree_util.tree_leaves(
-        [list(gate_up.sharding.spec)]), gate_up.sharding
+    experts = state.params["params"]["model"]["layers"]["layer"]["moe"][
+        "experts"]
+    for name in glu.EXPERTS:
+        assert "ep" in jax.tree_util.tree_leaves(
+            [list(experts[name].sharding.spec)]), experts[name].sharding
     if zero1:
         # expert optimizer state is ZeRO-sharded over expert-DP (reference
         # NeuronEPZero1Optimizer, zero_redundancy_optimizer.py:163)
         def find_mu(tree):
             return [s for path, s in
                     jax.tree_util.tree_leaves_with_path(tree)
-                    if "gate_up" in jax.tree_util.keystr(path)]
+                    if any(f"experts'][{name!r}]"
+                           in jax.tree_util.keystr(path)
+                           for name in glu.EXPERTS)]
         mu_shardings = find_mu(sh.opt_state)
         assert mu_shardings and all(
             "dp_exp" in [a for p in s.spec if p is not None

@@ -25,6 +25,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 import neuronx_distributed_tpu as nxd
+from neuronx_distributed_tpu.modules import glu
 from neuronx_distributed_tpu.modules.moe.expert_mlps import ExpertMLPs
 from neuronx_distributed_tpu.parallel import comm
 from neuronx_distributed_tpu.parallel import ep_dispatch as epd
@@ -189,7 +190,7 @@ def test_overlap_engaged_predicate():
 # layer-level: ExpertMLPs blockwise-EP over the dispatch module
 # ---------------------------------------------------------------------------
 
-_PSPEC = {"params": {"gate_up": P("ep", None, None, None),
+_PSPEC = {"params": {**dict.fromkeys(glu.EXPERTS, P("ep", None, None)),
                      "down": P("ep", None, None)}}
 
 
@@ -233,7 +234,8 @@ def _mlp_grads(em, m, params, x, gates, idx):
 
 
 def _leaves(g):
-    return [g[0]["params"]["gate_up"], g[0]["params"]["down"], g[1], g[2]]
+    return [*(g[0]["params"][name] for name in glu.EXPERTS),
+            g[0]["params"]["down"], g[1], g[2]]
 
 
 def test_expert_mlps_fp32_ring_bitwise_vs_baseline():

@@ -330,7 +330,7 @@ def test_cache_kinds_answer_for_their_layouts():
 
 @pytest.mark.parametrize("family", ["llama", "mixtral"])
 def test_llama_and_mixtral_serve_as_the_parent_did(family):
-    with open(os.path.join(HERE, "fixtures", "engine_parity_pr27.json")) as f:
+    with open(os.path.join(HERE, "fixtures", "engine_parity_pr28.json")) as f:
         want = json.load(f)[family]
     got = engine_parity.record(family)
     assert got["max_model_len"] == want["max_model_len"]

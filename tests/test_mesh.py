@@ -11,6 +11,7 @@ import pytest
 import jax
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_tpu.modules import glu
 from neuronx_distributed_tpu.parallel import mesh as ps
 
 
@@ -144,7 +145,7 @@ def test_moe_phase_mesh_views():
 
     for mesh in (cte, tkg):
         spec = {"params": {
-            "gate_up": P("ep", None, None, "tp"),
+            **dict.fromkeys(glu.EXPERTS, P("ep", None, "tp")),
             "down": P("ep", "tp", None)}}
         got, _ = jax.jit(ps.shard_map(
             lambda p, a, g, i: mod.apply(p, a, g, i), mesh,

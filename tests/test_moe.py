@@ -9,6 +9,7 @@ from flax.core import meta
 from jax.sharding import PartitionSpec as P
 
 import neuronx_distributed_tpu as nxd
+from neuronx_distributed_tpu.modules import glu
 from neuronx_distributed_tpu.modules.moe import (
     ExpertMLPs, MoE, RouterSinkhorn, RouterTopK, GroupLimitedRouter,
     build_dispatch_combine, compute_capacity)
@@ -86,7 +87,7 @@ def test_expert_mlps_tp_parity():
     params = meta.unbox(m.init(jax.random.key(2), x, gates, idx))
     dense, _ = m.apply(params, x, gates, idx)
 
-    pspec = {"params": {"gate_up": P(None, None, None, "tp"),
+    pspec = {"params": {**dict.fromkeys(glu.EXPERTS, P(None, None, "tp")),
                         "down": P(None, "tp", None)}}
     y, _ = jax.jit(ps.shard_map(
         lambda p, x, g, i: m.apply(p, x, g, i), mesh,
@@ -108,7 +109,7 @@ def test_expert_mlps_ep_parity():
     params = meta.unbox(m.init(jax.random.key(2), x, gates, idx))
     dense, _ = m.apply(params, x, gates, idx)
 
-    pspec = {"params": {"gate_up": P("ep", None, None, None),
+    pspec = {"params": {**dict.fromkeys(glu.EXPERTS, P("ep", None, None)),
                         "down": P("ep", None, None)}}
     # tokens sharded over the ep axis (each shard routes its own tokens)
     y, _ = jax.jit(ps.shard_map(
@@ -378,7 +379,7 @@ def test_blockwise_tp_parity():
     dense, _ = blk.apply(params, x, gates, idx)
 
     mesh = ps.initialize_model_parallel(tensor_model_parallel_size=2)
-    pspec = {"params": {"gate_up": P(None, None, None, "tp"),
+    pspec = {"params": {**dict.fromkeys(glu.EXPERTS, P(None, None, "tp")),
                         "down": P(None, "tp", None)}}
     y, _ = jax.jit(ps.shard_map(
         lambda p, x, g, i: blk.apply(p, x, g, i), mesh,
@@ -446,8 +447,8 @@ def test_blockwise_every_expert_owns_a_block():
     idx2 = jnp.where(jnp.arange(16)[:, None] < 8, 0, 2).astype(jnp.int32)
     g = jax.grad(lambda p: jnp.sum(blk.apply(p, x, gates[:, :1], idx2)[0]
                                    ** 2))(params)
-    np.testing.assert_array_equal(
-        np.asarray(g["params"]["gate_up"][1]), 0.0)
+    for name in glu.EXPERTS:
+        np.testing.assert_array_equal(np.asarray(g["params"][name][1]), 0.0)
 
 
 @pytest.mark.slow
@@ -596,7 +597,8 @@ def test_blockwise_router_grads_under_tp():
     gd = jax.grad(lambda p, x: jnp.sum(moe.apply(p, x)[0] ** 2),
                   argnums=(0, 1))(params, x)
     pspec = jax.tree_util.tree_map(lambda _: P(), params)
-    pspec["params"]["experts"]["gate_up"] = P(None, None, None, "tp")
+    for name in glu.EXPERTS:
+        pspec["params"]["experts"][name] = P(None, None, "tp")
     pspec["params"]["experts"]["down"] = P(None, "tp", None)
 
     def inner(p, x):
@@ -744,7 +746,7 @@ def test_blockwise_bound_ep_parity_and_grads(tp, ep):
     cap, blk, params, x, gates, idx = _blockwise_pair()
     dense, _ = blk.apply(params, x, gates, idx)
 
-    pspec = {"params": {"gate_up": P("ep", None, None, "tp"),
+    pspec = {"params": {**dict.fromkeys(glu.EXPERTS, P("ep", None, "tp")),
                         "down": P("ep", "tp", None)}}
     sharded = jax.jit(ps.shard_map(
         lambda p, x, g, i: blk.apply(p, x, g, i), em,
@@ -780,7 +782,7 @@ def test_blockwise_bound_ep_parity_and_grads(tp, ep):
     ge = ep_grads(params, x, gates, idx)
     paths_d = jax.tree_util.tree_leaves_with_path(gd)
     paths_e = jax.tree_util.tree_leaves_with_path(ge)
-    assert len(paths_d) == len(paths_e) == 4  # gate_up, down, dx, dgates
+    assert len(paths_d) == len(paths_e) == 5  # gate, up, down, dx, dgates
     for (path, a), (_, b) in zip(paths_d, paths_e):
         np.testing.assert_allclose(
             np.asarray(b), np.asarray(a), rtol=5e-4, atol=5e-4,

@@ -299,8 +299,8 @@ def test_mx_expert_decode_end_to_end():
     # scanned layers: leaves lead with the layer dim — pack layer by layer
     L = cfg.num_layers
     packed_layers = [mx_pack_expert_params(
-        {"gate_up": np.asarray(experts["gate_up"])[l],
-         "down": np.asarray(experts["down"])[l]}, "fp8") for l in range(L)]
+        {k: np.asarray(v)[l] for k, v in experts.items()}, "fp8")
+        for l in range(L)]
     mx_params["params"]["model"]["layers"]["layer"]["moe"]["experts"] = {
         k: jnp.stack([jnp.asarray(pl_[k]) for pl_ in packed_layers])
         for k in packed_layers[0]}
