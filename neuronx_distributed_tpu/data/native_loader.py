@@ -67,7 +67,7 @@ def build_native() -> None:
     """Compile ``csrc/data_loader.cpp`` now and make it the library every
     later :class:`TokenBatchLoader` binds, whatever ``.so`` was on disk.
     Raises when the toolchain fails — for entry scripts that must measure
-    the native path or nothing (``bench.py``). Call before the first
+    the native path or nothing. Call before the first
     loader is made in the process."""
     global _LIB, _LIB_FAILED
     with _BUILD_LOCK:

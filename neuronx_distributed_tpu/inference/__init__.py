@@ -22,7 +22,6 @@ from . import aot_cache
 from . import generation
 from . import kv_cache
 from . import model_builder
-from . import benchmark
 from . import paging
 from . import engine
 from . import sampling
@@ -53,7 +52,7 @@ from .speculative import make_speculation_round_fn
 
 __all__ = [
     "generation", "kv_cache", "model_builder", "sampling",
-    "benchmark", "speculative", "paging", "engine", "router", "aot_cache",
+    "speculative", "paging", "engine", "router", "aot_cache",
     "AotExecutableCache", "AotWorker",
     "DECODE_BUCKETS", "decode_step", "generate", "pick_bucket", "prefill",
     "KVCache", "init_kv_cache",

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.llama import LlamaConfig, llama_forward_with_cache
+from ..models.llama import LlamaConfig
 from ..obs.accounting import CompileTracker
 from ..obs.events import emit_event
 from ..obs.metrics import get_registry
@@ -506,7 +506,6 @@ class ServingEngine:
                  clock: Optional[Callable[[], float]] = None,
                  aot_cache: Optional[AotExecutableCache] = None,
                  name: Optional[str] = None,
-                 forward_fn: Optional[Callable] = None,
                  draft_cfg: Optional[LlamaConfig] = None,
                  draft_params=None):
         self.model_cfg = model_cfg
@@ -515,13 +514,13 @@ class ServingEngine:
         # the model family says how it is served: its cached forward (any
         # callable with the llama_forward_with_cache paged signature
         # ``(cfg, params, tokens, positions, cache, slot_ids=...) ->
-        # (logits, cache)``; ``forward_fn`` overrides it), the cache kind
-        # its table rows follow, and the engine features it cannot serve
+        # (logits, cache)``), the cache kind its table rows follow, and
+        # the engine features it cannot serve, each with why
         family = model_cfg.serving_family()
         self._cache_kind = family.cache_kind.geometry(
             engine_cfg.block_size,
             max(engine_cfg.token_budget, engine_cfg.prefill_budget or 0))
-        self._unsupported = frozenset(family.unsupported)
+        self._unsupported = dict(family.unsupported)
         asked = {
             "prefix_sharing": engine_cfg.prefix_sharing,
             "speculation": engine_cfg.speculation is not None,
@@ -529,15 +528,8 @@ class ServingEngine:
             "quantized": engine_cfg.quantized}
         for feature in sorted(self._unsupported):
             if asked.get(feature):
-                raise ValueError(
-                    f"{type(model_cfg).__name__} cannot be served with "
-                    f"{feature}: its {self._cache_kind.name} cache does "
-                    "not keep position // block_size rows (a ring block "
-                    "is not a prefix, a lane clone, a cp shard or an "
-                    "int8 row)")
-        if forward_fn is None:
-            forward_fn = family.forward
-        self._forward_fn = forward_fn
+                self._refuse(feature)
+        self._forward_fn = family.forward
         # elastic-fleet hooks: an AOT cache makes worker construction
         # load-or-compile (replicas after the first spin up without
         # compiling); a name scopes this engine's obs compile-tracker
@@ -602,11 +594,6 @@ class ServingEngine:
                 raise ValueError(
                     "cp>1 does not support quantized pools yet (the ring "
                     "prefill writes fp rows)")
-            if self._forward_fn is not llama_forward_with_cache:
-                raise ValueError(
-                    "cp>1 currently serves Llama-family configs only "
-                    "(the ring-prefill path lives in "
-                    "llama_forward_with_cache)")
             if (not ps.model_parallel_is_initialized()
                     or ps.get_context_parallel_size() != cp):
                 raise ValueError(
@@ -672,8 +659,7 @@ class ServingEngine:
             self._draft_params = (draft_params if draft_params is not None
                                   else params)
             self._draft_forward_fn = (
-                forward_fn if draft_cfg is None
-                else draft_cfg.serving_family().forward)
+                self._draft_cfg.serving_family().forward)
             k, nb = spec.speculation_length, spec.num_branches
             self._spec_slots = spec.max_spec_slots or min(
                 engine_cfg.max_slots,
@@ -1171,13 +1157,14 @@ class ServingEngine:
     def _now(self) -> float:
         return self._clock() - self._t0
 
+    def _refuse(self, feature: str) -> None:
+        raise ValueError(
+            f"{type(self.model_cfg).__name__} cannot be served with "
+            f"{feature}: {self._unsupported[feature]}")
+
     def _refuse_session_export(self) -> None:
         if "session_export" in self._unsupported:
-            raise ValueError(
-                f"{type(self.model_cfg).__name__} cannot be served with "
-                f"session_export: a ticket ships position // block_size "
-                f"blocks, and a {self._cache_kind.name} cache keeps a "
-                "ring and summaries")
+            self._refuse("session_export")
 
     def max_model_len(self) -> int:
         """Longest request (prompt + new tokens) this engine can ever

@@ -21,8 +21,8 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import (Any, Callable, Dict, List, Optional, Sequence, Set,
-                    Tuple)
+from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
+                    Set, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -163,13 +163,13 @@ class ServingFamily:
     """What :class:`.engine.ServingEngine` asks of a model config
     (``model_cfg.serving_family()``): the cached forward with the
     ``llama_forward_with_cache`` paged signature, the cache kind its
-    table rows follow, and the engine features the family cannot serve
-    (refused by name at construction: ``prefix_sharing``, ``speculation``,
-    ``cp``, ``quantized``, ``session_export``)."""
+    table rows follow, and the engine features the family cannot serve,
+    each with why (refused by name at construction: ``prefix_sharing``,
+    ``speculation``, ``cp``, ``quantized``, ``session_export``)."""
 
     forward: Callable
     cache_kind: Any = FULL_CACHE
-    unsupported: Tuple[str, ...] = ()
+    unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
 
 
 class PagedKVCache(struct.PyTreeNode):

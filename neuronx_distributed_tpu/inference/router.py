@@ -77,8 +77,7 @@ deterministic under fake clocks; the fleet-level tick consults
 ``op="scale"``, ``path="fleet"`` for ``scale_burst`` directives, and the
 fabric's link consults ``op="link"``, ``path=<route>`` for the
 ``link_*`` kinds. See :func:`chaos_drill`, :func:`elastic_chaos_drill`,
-:func:`fabric_chaos_drill` and ``bench.py --router`` / ``--elastic`` /
-``--disagg-fabric``.
+:func:`fabric_chaos_drill`.
 """
 
 from __future__ import annotations
@@ -1749,7 +1748,7 @@ def chaos_drill(model_cfg, params, engine_cfg: EngineConfig,
                 num_replicas: int = 2,
                 clock: Optional[Callable[[], float]] = None,
                 seed: int = 0) -> Dict[str, Any]:
-    """Deterministic failover drill for tests and ``bench.py --router``.
+    """Deterministic failover drill for tests.
 
     Runs the same request set twice — fault-free on one replica, then on
     ``num_replicas`` replicas under ``plan_spec`` — and reports
@@ -1803,8 +1802,7 @@ def sdc_serving_drill(model_cfg, params, engine_cfg: EngineConfig,
                       num_replicas: int = 2,
                       clock: Optional[Callable[[], float]] = None,
                       seed: int = 0) -> Dict[str, Any]:
-    """Deterministic silent-data-corruption drill for serving (tests and
-    ``bench.py --sdc``).
+    """Deterministic silent-data-corruption drill for serving (tests).
 
     A chaos ``bitflip`` corrupts one generated token on a replica — the
     request *completes*, so nothing in the crash/latency machinery can
@@ -1863,7 +1861,7 @@ def elastic_chaos_drill(model_cfg, params, engine_cfg: EngineConfig,
                         cache_dir: Optional[str] = None,
                         scale_down_step: int = 8) -> Dict[str, Any]:
     """Deterministic elastic-fleet drill: the full scale cycle under
-    ragged-Poisson load (tests and ``bench.py --elastic``).
+    ragged-Poisson load (tests).
 
     Sequence: measure replica spin-up cold (first build populates the
     shared AOT cache) vs warm (second build loads), run the request set
@@ -1998,7 +1996,7 @@ def fabric_chaos_drill(model_cfg, params, engine_cfg: EngineConfig,
                        seed: int = 0) -> Dict[str, Any]:
     """Deterministic two-host fabric drill: disaggregated prefill→decode
     serving with the KV handoff streamed over a (faulty) DCN link
-    (tests and ``bench.py --disagg-fabric``).
+    (tests).
 
     Runs the request set fault-free on one colocated replica for
     reference, then on a 1-prefill + 1-decode fabric where ``plan_spec``

@@ -59,10 +59,12 @@ class EvaByteConfig(LlamaConfig):
         return ServingFamily(
             forward=evabyte_forward_with_cache,
             cache_kind=WindowSummaryCache(self.window_size, self.chunk_size),
-            # a ring block is not a prefix; lanes, cp shards, int8 rows
-            # and shipped sessions all assume position // block_size
-            unsupported=("prefix_sharing", "speculation", "cp", "quantized",
-                         "session_export"))
+            unsupported=dict.fromkeys(
+                ("prefix_sharing", "speculation", "cp", "quantized",
+                 "session_export"),
+                "its window-summary cache does not keep position // "
+                "block_size rows (a ring block is not a prefix, a lane "
+                "clone, a cp shard, an int8 row or a shipped session)"))
 
 
 def tiny_config(**kw) -> EvaByteConfig:
@@ -86,7 +88,7 @@ class EvaByteForCausalLM(nn.Module):
     def __call__(self, input_ids: jax.Array,
                  positions: Optional[jax.Array] = None) -> jax.Array:
         cfg = self.cfg
-        x = LlamaModel(cfg, name="model")(input_ids, positions)
+        x, _ = LlamaModel(cfg, name="model")(input_ids, positions)
         logits = pl.ColumnParallelLinear(
             features=cfg.num_pred_heads * cfg.vocab_size, use_bias=False,
             gather_output=True, dtype=cfg.dtype,

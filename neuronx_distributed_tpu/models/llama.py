@@ -196,6 +196,13 @@ class LlamaConfig:
 
         return ServingFamily(forward=llama_forward_with_cache)
 
+    def feed_forward(self, h: jax.Array, tp_sync: bool = True):
+        """A decoder layer's feed-forward over its post-norm hidden
+        states: ``(output, router aux pair or None)``. Called inside
+        :class:`LlamaDecoderLayer`'s scope, which the block it builds
+        thereby joins under its own name."""
+        return LlamaMLP(self, tp_sync=tp_sync, name="mlp")(h), None
+
     def __post_init__(self) -> None:
         if self.attention_kind not in ("full", "eva"):
             raise ValueError(
@@ -773,9 +780,15 @@ class LlamaMLP(nn.Module):
 
 
 class LlamaDecoderLayer(nn.Module):
+    """The decoder layer of every family: norm, attention, residual,
+    norm, the config's feed-forward, residual. Returns ``(x, aux,
+    new_cache)``: ``aux`` is the router's ``[load_balance, z]`` pair where
+    the feed-forward has a router and ``None`` where it has none,
+    ``new_cache`` ``None`` without a cache."""
+
     cfg: LlamaConfig
     # False elides this layer's row-parallel exit all-reduces (o_proj and
-    # down); LlamaModel's non-scan loop schedules it per layer
+    # the feed-forward's); LlamaModel's non-scan loop schedules it per layer
     tp_sync: bool = True
 
     @nn.compact
@@ -795,10 +808,8 @@ class LlamaDecoderLayer(nn.Module):
         h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
                     sequence_parallel=cfg.sequence_parallel,
                     name="post_norm")(x)
-        x = x + LlamaMLP(cfg, tp_sync=self.tp_sync, name="mlp")(h)
-        if cache is not None:
-            return x, new_cache
-        return x
+        ff_out, aux = cfg.feed_forward(h, self.tp_sync)
+        return x + ff_out, aux, new_cache
 
 
 def context_parallel_positions(input_ids: jax.Array,
@@ -822,14 +833,16 @@ def context_parallel_positions(input_ids: jax.Array,
 
 
 class _ScanBody(nn.Module):
-    """nn.scan body: carries the hidden states, emits nothing."""
+    """nn.scan body: carries the hidden states, emits the layer's router
+    aux pair (nothing where the layer has no router)."""
 
     cfg: LlamaConfig
 
     @nn.compact
     def __call__(self, x, cos, sin, positions):
-        x = LlamaDecoderLayer(self.cfg, name="layer")(x, cos, sin, positions)
-        return x, None
+        x, aux, _ = LlamaDecoderLayer(self.cfg, name="layer")(
+            x, cos, sin, positions)
+        return x, aux
 
 
 class _DecodeScanBody(nn.Module):
@@ -852,7 +865,7 @@ class _DecodeScanBody(nn.Module):
             v_l = dequantize_kv(qv, vs, self.cfg.dtype)
         else:
             k_l, v_l = cache_kv
-        x, (nk, nv) = LlamaDecoderLayer(self.cfg, name="layer")(
+        x, _, (nk, nv) = LlamaDecoderLayer(self.cfg, name="layer")(
             x, cos, sin, positions, cache=(k_l, v_l, slot_pos),
             cache_index=cache_index)
         if len(cache_kv) == 4:
@@ -897,7 +910,7 @@ class _PagedScanBody(nn.Module):
         view = PagedCacheView(k=k_l, v=v_l, k_scale=ks_l, v_scale=vs_l,
                               pos=pool_pos, tables=tables,
                               write_idx=write_idx, roll=roll)
-        x, new_view = LlamaDecoderLayer(self.cfg, name="layer")(
+        x, _, new_view = LlamaDecoderLayer(self.cfg, name="layer")(
             x, cos, sin, positions, cache=view, cache_index=None)
         if len(cache_kv) == 4:
             return x, (new_view.k, new_view.v, new_view.k_scale,
@@ -922,19 +935,21 @@ class _CPPrefillScanBody(nn.Module):
         k_l, v_l = cache_kv
         view = CPPrefillView(k=k_l, v=v_l, pos=pool_pos,
                              write_idx=write_idx)
-        x, new_view = LlamaDecoderLayer(self.cfg, name="layer")(
+        x, _, new_view = LlamaDecoderLayer(self.cfg, name="layer")(
             x, cos, sin, positions, cache=view, cache_index=None)
         return x, (new_view.k, new_view.v)
 
 
 class LlamaModel(nn.Module):
-    """Transformer body: embedding + decoder stack + final norm."""
+    """Transformer body: embedding + decoder stack + final norm. Returns
+    ``(hidden states, aux)``: the layers' router aux pairs summed, ``None``
+    for a stack without routers."""
 
     cfg: LlamaConfig
 
     @nn.compact
     def __call__(self, input_ids: jax.Array,
-                 positions: Optional[jax.Array] = None) -> jax.Array:
+                 positions: Optional[jax.Array] = None):
         cfg = self.cfg
         x = pl.ParallelEmbedding(
             num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
@@ -963,8 +978,9 @@ class LlamaModel(nn.Module):
                 length=cfg.num_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, name="layers")
-            x, _ = scanned(x, cos, sin, positions)
+            x, aux = scanned(x, cos, sin, positions)
         else:
+            auxes = []
             layer_cls = LlamaDecoderLayer
             if cfg.remat:
                 layer_cls = nn.remat(
@@ -992,13 +1008,18 @@ class LlamaModel(nn.Module):
                     x = x_ref + mappings.reduce_from_tensor_parallel_region(
                         x - x_ref)
                     pending = False
-                x = layer_cls(cfg, tp_sync=sched[i] if reduced else True,
-                              name=f"layer_{i}")(x, cos, sin, positions)
+                x, a, _ = layer_cls(
+                    cfg, tp_sync=sched[i] if reduced else True,
+                    name=f"layer_{i}")(x, cos, sin, positions)
+                auxes.append(a)
                 if reduced:
                     if sched[i]:
                         x_ref = x
                     else:
                         pending = True
+            aux = None if auxes[0] is None else jnp.stack(auxes)
+        if aux is not None:
+            aux = jnp.sum(aux, axis=0)
         x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
                     sequence_parallel=cfg.sequence_parallel, name="norm")(x)
         # NOTE: when sequence_parallel, the returned hidden states are still
@@ -1007,7 +1028,7 @@ class LlamaModel(nn.Module):
         # gather's backward reduce-scatter correctly pairs with the head's
         # partial input-grads. Gathering here AND entering the head through
         # copy_to would double-reduce gradients (inflate by tp).
-        return x
+        return x, aux
 
 
 class _LMHeadKernel(nn.Module):
@@ -1043,7 +1064,7 @@ class LlamaForCausalLM(nn.Module):
                  ignore_index: int = -100) -> jax.Array:
         cfg = self.cfg
         model = LlamaModel(cfg, name="model")
-        x = model(input_ids, positions)
+        x, _ = model(input_ids, positions)
         if cfg.tie_embeddings:
             if _lora_kw(cfg, "lm_head"):
                 raise ValueError(
@@ -1106,9 +1127,13 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
                              cp_prefill: bool = False):
     """KV-cached forward for prefill ("context_encoding") and decode
     ("token_generation") — the two compiled graphs of the reference's
-    serving path (``trace/model_builder.py:495`` keys).
+    serving path (``trace/model_builder.py:495`` keys). The one cached
+    forward of every family: the layer's feed-forward is the config's
+    (:meth:`LlamaConfig.feed_forward`), and a family's own forward
+    (``mixtral_forward_with_cache``, ``evabyte_forward_with_cache``) adds
+    what is its own and calls this.
 
-    ``params``: LlamaForCausalLM variables (scan_layers=True layout).
+    ``params``: the family's causal-LM variables (scan_layers=True layout).
     ``kv_cache``: :class:`..inference.kv_cache.KVCache` or
     :class:`..inference.kv_cache.QuantizedKVCache` (int8 cache; reference
     kv_cache_quant, ``quantization_config.py:72``). Writes this step's K/V

@@ -29,8 +29,9 @@ from ..parallel import loss_functions as lf
 from ..parallel import mappings
 from ..parallel import mesh as ps
 from ..pipeline import spmd_engine as eng
+from .llama import _ScanBody
 from .llama_pipeline import PIPELINE_LOGICAL_RULES  # noqa: F401 (re-export)
-from .mixtral import MixtralConfig, _MoEScanBody
+from .mixtral import MixtralConfig
 
 
 def pipelined_moe_loss_fn(cfg: MixtralConfig, num_microbatches: int,
@@ -78,15 +79,15 @@ def pipelined_moe_loss_fn(cfg: MixtralConfig, num_microbatches: int,
             x = embed_mod.apply({"params": embed_p}, ids_)
             if cfg.sequence_parallel:
                 # stage activations ride the ring SP-sharded; the MoE
-                # block's own gather/scatter (MixtralDecoderLayer) handles
-                # the regather inside each stage (reference
+                # block's own gather/scatter (MixtralConfig.feed_forward)
+                # handles the regather inside each stage (reference
                 # moe/model.py:154 delayed reduce-scatter inside NxDPPModel)
                 x = mappings.scatter_to_sequence_parallel_region(x,
                                                                  seq_dim=1)
             return x
 
         body = nn.scan(
-            _MoEScanBody,
+            _ScanBody,
             variable_axes={"params": 0},
             split_rngs={"params": True},
             in_axes=(nn.broadcast, nn.broadcast, nn.broadcast),
@@ -227,7 +228,7 @@ def make_moe_1f1b_grad_fn(cfg: MixtralConfig, num_microbatches: int,
             return x
 
         body = nn.scan(
-            _MoEScanBody,
+            _ScanBody,
             variable_axes={"params": 0},
             split_rngs={"params": True},
             in_axes=(nn.broadcast, nn.broadcast, nn.broadcast),

@@ -4,7 +4,7 @@
 and the watchdog) routes through here, so every event simultaneously
 
 * emits the grep/parse-friendly ``NXD_EVENT {json}`` log line exactly as
-  before (launch tooling and bench.py depend on the format),
+  before (launch tooling depends on the format),
 * increments ``nxd_events_total{event=...}`` in the metrics registry, and
 * fans out to in-process subscribers (tests, custom alert hooks).
 

@@ -10,7 +10,7 @@ so every subsystem reports health through the same pipe instead of ad-hoc
 * **thread-safe** — the serving engine, router collector threads, and the
   threaded stall watchdog all record concurrently;
 * **two export formats** — Prometheus text exposition for scraping, and a
-  nested JSON snapshot that drops into ``bench.py``'s one-line convention.
+  nested JSON snapshot.
 
 Stdlib-only on purpose: this module must be importable before JAX and from
 every layer of the package without creating an import cycle.

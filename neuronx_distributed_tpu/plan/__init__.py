@@ -36,9 +36,9 @@ from .search import (PRUNE_DOMINATED, PRUNE_INDIVISIBLE, PRUNE_OOM, Pruned,
 
 def handpicked_plan(devices: int, *, platform: str = "cpu",
                     dcn_dp: int = 1) -> Plan:
-    """The static layout ``bench.py`` hard-codes for this device count —
-    the baseline the planner is measured against (``--plan`` reports
-    ``plan_advantage_ratio`` vs this plan's modeled cost). ``dcn_dp`` is
+    """A static hand-picked layout for this device count (tp=2 on CPU
+    hosts, tp up to 8 on a TPU slice, ZeRO-1, flat fp32 rings) — the
+    baseline the CLI prints the search's winner beside. ``dcn_dp`` is
     the fleet's cross-slice degree: the baseline runs on the same fleet
     as the search, it just doesn't adapt to it (flat fp32 rings)."""
     if platform == "cpu" or devices < 8:

@@ -29,7 +29,8 @@ def _model_spec(name: str, *, seq: Optional[int], batch: int) -> ModelSpec:
         "llama2-70b": llama.LLAMA2_70B,
         "llama3-8b": llama.LLAMA3_8B,
         "tiny": llama.tiny_config(),
-        # the layout bench.py runs on CPU hosts — the acceptance target
+        # a 4-layer, hidden-256 model for CPU hosts (the planner's tests
+        # and docs/planner.md use it; ROADMAP D9)
         "bench-cpu": llama.LlamaConfig(
             vocab_size=1024, hidden_size=256, intermediate_size=704,
             num_layers=4, num_heads=8, num_kv_heads=8, max_seq_len=512),
@@ -121,9 +122,9 @@ def main(argv=None) -> int:
                     "match-rate a quantized tier must clear; tiers with "
                     "no recorded quality are refused (fail closed)")
     ap.add_argument("--quality-file", default=None, metavar="JSON",
-                    help="per-tier quality records as bench --quantized "
-                         "emits them (a JSON object mapping tier name to "
-                         "a match-rate or a {'greedy_match': ...} record)")
+                    help="per-tier quality records (a JSON object "
+                         "mapping tier name to a match-rate or a "
+                         "{'greedy_match': ...} record)")
     ap.add_argument("--slo-ttft-p99-ms", type=float, default=None,
                     help="TTFT p99 target (ms) the serving config must "
                          "meet")

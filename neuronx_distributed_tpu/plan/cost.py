@@ -326,8 +326,8 @@ def tp_overlap_engagement(plan: Plan, m: ModelSpec) -> bool:
 
 
 #: fraction of decomposed-ring transfer time hidden behind the per-shard
-#: partial matmuls when overlap engages (bench.py --overlap measures the
-#: realized value; docs/tp_overlap.md)
+#: partial matmuls when overlap engages (a guess: no chip run has
+#: measured it; docs/tp_overlap.md)
 TP_OVERLAP_HIDDEN_FRACTION = 0.7
 
 
@@ -392,8 +392,8 @@ def pp_comm_s(plan: Plan, m: ModelSpec, hw: HardwareSpec) -> float:
 
 
 #: fraction of the decomposed EP-ring transfer hidden behind the per-chunk
-#: expert matmuls when ep_overlap engages (bench.py --moe reports the
-#: realized moe_overlap_speedup; docs/moe.md)
+#: expert matmuls when ep_overlap engages (a guess: no chip run has
+#: measured it; docs/moe.md)
 EP_OVERLAP_HIDDEN_FRACTION = 0.6
 
 
@@ -1013,8 +1013,8 @@ def serving_search(m: ModelSpec, hw: HardwareSpec, traffic: TrafficSpec, *,
     tax. Quantized tiers are **quality-gated**: with ``quality_bar``
     set, a tier is only proposed when ``quality`` (a mapping from
     format to its *recorded* greedy match-rate vs fp32 — either the
-    rate itself or a dict with a ``"greedy_match"`` key, the shape
-    ``bench.py --quantized`` emits) attests a match-rate >= the bar.
+    rate itself or a dict with a ``"greedy_match"`` key) attests a
+    match-rate >= the bar.
     A tier with no recorded quality is refused outright (fail-closed):
     the planner does not guess what quantization does to a model.
 

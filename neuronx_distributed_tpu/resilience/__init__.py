@@ -9,7 +9,7 @@ commit protocol with done-markers, tenacity-style storage retries,
 * :mod:`chaos` — :class:`FaultPlan` / :class:`ChaosCheckpointStorage`:
   deterministic, seed-driven fault injection over any
   ``BaseCheckpointStorage`` so the retry/backoff and commit-protocol
-  invariants are testable (and exercisable from ``bench.py --chaos``).
+  invariants are testable.
 * :mod:`preemption` — :class:`PreemptionGuard`: SIGTERM/SIGINT turns into a
   synchronous emergency checkpoint at the next step boundary, then a
   resumable exit (:data:`EXIT_PREEMPTED`), with a grace deadline.
@@ -25,8 +25,7 @@ commit protocol with done-markers, tenacity-style storage retries,
   fingerprints at a train-step cadence, cross-dp-replica consensus with
   majority vote, wire-payload spot checks, and the
   :class:`IntegrityMonitor` callback composing detection with the
-  watchdog's rewind (driven by the chaos ``bitflip`` fault kind;
-  ``bench.py --sdc``).
+  watchdog's rewind (driven by the chaos ``bitflip`` fault kind).
 
 See ``docs/resilience.md``.
 """

@@ -37,7 +37,7 @@ name):
   integrity cadence boundary (``consult_detail("integrity", "params")``),
   a decoded token on a serving replica, a wire payload. Consult-only like
   ``preempt``: corruption is injected by the caller, never raised. See
-  ``resilience/integrity.py`` and ``bench.py --sdc``.
+  ``resilience/integrity.py``.
 
 Link fault kinds (the DCN handoff fabric of ``inference/transport.py``,
 where ``op`` is ``"link"`` and ``path`` is the route, e.g. ``p0->d0``;
@@ -62,7 +62,7 @@ virtual (deterministic under fake clocks) and the caller decides how a
 crash or an exhaustion storm manifests.
 
 The plan is buildable programmatically or parsed from a compact spec string
-usable from the CLI (``bench.py --chaos`` / ``--router``)::
+usable from a command line (:meth:`FaultPlan.parse`)::
 
     seed=7; save_text|*/checkpoint : transient, p=0.5, times=2; * : latency=0.01
     step|r1 : crash, after=6, times=1        # kill replica r1 at its 7th step

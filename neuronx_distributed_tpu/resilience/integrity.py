@@ -37,7 +37,7 @@ independent host-triggered recompute of the live params, emits an
 discipline (``report_anomaly``) to restore the newest *content-verified*
 checkpoint (manifests carry per-shard digests; see ``manifest.py``). The
 chaos ``bitflip`` fault kind drives deterministic drills end to end
-(``bench.py --sdc``).
+(``tests/test_integrity.py``).
 
 See docs/resilience.md ("Silent data corruption").
 """

@@ -95,7 +95,7 @@ def log_event(logger: logging.Logger, event: str, **fields) -> None:
     """One-line machine-parseable event record: ``NXD_EVENT {json}``.
 
     The resilience subsystem (preemption, watchdog, chaos drills) emits its
-    operational events through this so ``bench.py`` and launch tooling can
+    operational events through this so launch tooling can
     grep/parse them without scraping free-form log text. WARNING level:
     rank0_only loggers on non-zero processes drop below WARNING, and a
     resilience event from *any* rank must stay visible.

@@ -1,4 +1,4 @@
-"""Speculative decoding, benchmark harness, head padding, fp32 masters."""
+"""Speculative decoding, head padding, fp32 masters."""
 
 import numpy as np
 import pytest
@@ -7,7 +7,6 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from neuronx_distributed_tpu.inference.benchmark import benchmark
 from neuronx_distributed_tpu.inference.speculative import (
     build_medusa_tree, medusa_accept_longest, verify_draft_greedy)
 from neuronx_distributed_tpu.parallel.pad import (get_number_of_extra_heads,
@@ -46,15 +45,6 @@ def test_medusa_tree_acceptance():
     logits = logits.at[0, 1, 6].set(9.0)   # at node 1, target says 6 (node 3)
     best, depth = medusa_accept_longest(logits, tree_tokens, buffers)
     assert int(best[0]) == 3 and int(depth[0]) == 2
-
-
-def test_benchmark_harness():
-    x = jnp.ones((128, 128))
-    f = jax.jit(lambda: x @ x)
-    rep = benchmark(f, n_runs=5, warmup=1)
-    assert rep["n"] == 5
-    assert rep["p50_ms"] <= rep["p99_ms"]
-    assert rep["mean_ms"] > 0
 
 
 def test_head_padding():

@@ -10,8 +10,6 @@ make the planner worse than uncalibrated.
 
 import json
 import math
-import subprocess
-import sys
 
 import pytest
 
@@ -348,37 +346,3 @@ def test_serving_search_ranked_by_goodput_then_latency():
     assert all(best.cost.tokens_per_s >= p.cost.tokens_per_s * 0.98
                for p in plans if p.meets_slo == best.meets_slo
                and p.cost.saturated == best.cost.saturated)
-
-
-# ---------------------------------------------------------------------------
-# bench --regress (no backend init: must answer fast from history alone)
-# ---------------------------------------------------------------------------
-
-def _write_bench(d, n, metric, value, unit="tok/s/chip"):
-    (d / f"BENCH_{n:03d}.json").write_text(json.dumps(
-        {"n": n, "cmd": "bench", "rc": 0, "tail": "",
-         "parsed": {"metric": metric, "value": value, "unit": unit,
-                    "vs_baseline": 0.0}}))
-
-
-def test_bench_regress_cli(tmp_path):
-    import os
-
-    repo = str(tmp_path)  # isolated history dir
-    _write_bench(tmp_path, 1, "llama_tokens_per_sec_per_chip_cpu8", 100.0)
-    _write_bench(tmp_path, 2, "llama_tokens_per_sec_per_chip_cpu8", 50.0)
-    bench_py = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py")
-    r = subprocess.run(
-        [sys.executable, bench_py, "--regress", "--regress-dir", repo],
-        capture_output=True, text=True, timeout=60)
-    assert r.returncode == 1, r.stdout + r.stderr
-    rec = json.loads(r.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "bench_regressions" and rec["value"] == 1
-    assert rec["regressions"][0]["ratio"] == pytest.approx(0.5)
-    # recovering run -> green
-    _write_bench(tmp_path, 3, "llama_tokens_per_sec_per_chip_cpu8", 99.0)
-    r = subprocess.run(
-        [sys.executable, bench_py, "--regress", "--regress-dir", repo],
-        capture_output=True, text=True, timeout=60)
-    assert r.returncode == 0, r.stdout + r.stderr

@@ -170,6 +170,17 @@ def test_cp_guard_rails_reject_incompatible_features(tiny_model, kw, msg):
         _cp(tiny_model, **kw)
 
 
+def test_cp_refuses_a_family_that_names_it_and_says_why():
+    """The cp tier asks the family, not the identity of its forward: a
+    MixtralConfig is refused at construction (params never read), with
+    the family's own reason."""
+    from neuronx_distributed_tpu.models.mixtral import tiny_moe_config
+
+    with pytest.raises(ValueError, match="MixtralConfig cannot be served "
+                                         "with cp: .*expert capacity"):
+        _cp((tiny_moe_config(), None))
+
+
 def test_cp_requires_matching_mesh(tiny_model):
     ps.initialize_model_parallel()      # plain mesh, no cp axis
     with pytest.raises(ValueError, match="context_parallel_size"):

@@ -186,27 +186,6 @@ def test_stats_report_fields(tiny_model):
         assert key in rep and rep[key] >= 0
 
 
-def test_benchmark_suite_reports_ttft(tiny_model):
-    """Satellite: the decode benchmark emits TTFT + p99 and a single
-    JSON line in the bench.py convention."""
-    import json
-
-    from neuronx_distributed_tpu.inference.benchmark import (
-        decode_benchmark_suite, emit_json_line)
-
-    cfg, params = tiny_model
-    suite = decode_benchmark_suite(cfg, params, prompt_len=8, new_tokens=4,
-                                   n_runs=1, buckets=(8,))
-    rep = suite["greedy"]
-    for key in ("tokens_per_sec", "ttft_ms", "ttft_p99_ms", "p99_ms"):
-        assert key in rep
-    line = emit_json_line(suite, platform="cpu")
-    parsed = json.loads(line)
-    assert parsed["unit"] == "tokens/sec"
-    assert "greedy_ttft_ms_cpu" in parsed["aux"]
-    assert "\n" not in line.strip()
-
-
 def test_decode_buckets_share_one_compile(tiny_model):
     """Satellite: two different max_new_tokens within one decode bucket
     reuse a single compiled scan."""

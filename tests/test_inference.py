@@ -603,20 +603,6 @@ def test_medusa_generate_exact(tiny_model):
     assert int(stats["rounds"]) >= 1
 
 
-@pytest.mark.slow
-def test_decode_benchmark_suite_smoke(tiny_model):
-    from neuronx_distributed_tpu.inference.benchmark import (
-        decode_benchmark_suite)
-
-    cfg, model, params = tiny_model
-    rep = decode_benchmark_suite(cfg, params, draft_cfg=cfg,
-                                 draft_params=params, batch=1,
-                                 prompt_len=8, new_tokens=4, n_runs=1,
-                                 buckets=(8,))
-    assert set(rep) == {"greedy", "speculative"}
-    assert rep["greedy"]["tokens_per_sec"] > 0
-
-
 def test_generate_buckets():
     """Log2-spaced bucket generation (reference autobucketing.py:6):
     round(log2(max)) spacing never emits a bucket one step under max."""

@@ -166,7 +166,7 @@ def test_snapshot_nests_into_json():
     reg.counter("nxd_reqs_total", labels=("kind",)).labels(kind="a").inc(3)
     reg.histogram("nxd_lat_seconds").observe(2.0)
     snap = reg.snapshot()
-    json.dumps(snap)  # must be JSON-serialisable as-is (bench.py aux)
+    json.dumps(snap)  # must be JSON-serialisable as-is
     assert snap["nxd_reqs_total"]["type"] == "counter"
     assert snap["nxd_reqs_total"]["samples"] == [
         {"labels": {"kind": "a"}, "value": 3.0}]

@@ -17,8 +17,9 @@ from neuronx_distributed_tpu.inference.paging import (
     BlockAllocator, CacheExhaustedError, flat_write_indices,
     init_paged_kv_cache, init_quantized_paged_kv_cache, write_pool_rows)
 from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
-                                                  llama_forward_with_cache,
                                                   tiny_config)
+from neuronx_distributed_tpu.models.mixtral import (MixtralForCausalLM,
+                                                    tiny_moe_config)
 from neuronx_distributed_tpu.ops.paged_attention import (_paged_walk,
                                                           column_live,
                                                           paged_attention)
@@ -231,14 +232,27 @@ def test_paged_attention_validates_scales_and_heads():
 # full-model parity vs the contiguous cache
 # ---------------------------------------------------------------------------
 
-@pytest.fixture
-def tiny_model():
+@pytest.fixture(params=["llama", "mixtral"])
+def tiny_model(request):
+    """Both feed-forwards through the one cached forward, each by the
+    name its family serves under. Mixtral at ``capacity_factor`` 4.0 (as
+    ``engine_parity.py``): no token is dropped at any chunk width, so a
+    chunked prefill and a token-by-token decode route alike."""
     ps.initialize_model_parallel()
-    cfg = tiny_config(dtype=jnp.float32, param_dtype=jnp.float32,
-                      num_layers=2)
-    params = meta.unbox(LlamaForCausalLM(cfg).init(
+    kw = dict(dtype=jnp.float32, param_dtype=jnp.float32, num_layers=2)
+    if request.param == "mixtral":
+        cfg = tiny_moe_config(capacity_factor=4.0, **kw)
+        module = MixtralForCausalLM(cfg)
+    else:
+        cfg = tiny_config(**kw)
+        module = LlamaForCausalLM(cfg)
+    params = meta.unbox(module.init(
         jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
     return cfg, params
+
+
+def _forward(cfg, *args, **kw):
+    return cfg.serving_family().forward(cfg, *args, **kw)
 
 
 def _contiguous_decode(cfg, params, toks, quantized=False):
@@ -247,7 +261,7 @@ def _contiguous_decode(cfg, params, toks, quantized=False):
     cache = init(cfg.num_layers, 1, 16, cfg.num_kv_heads, cfg.head_dim_)
     out = []
     for i in range(toks.shape[1]):
-        lg, cache = llama_forward_with_cache(
+        lg, cache = _forward(
             cfg, params, toks[:, i:i + 1], jnp.array([[i]], jnp.int32),
             cache)
         out.append(lg[0, 0])
@@ -277,7 +291,7 @@ def test_paged_decode_bitwise_matches_contiguous_fp32(tiny_model):
     cache = _paged_cache(cfg)
     out = []
     for i in range(10):
-        lg, cache = llama_forward_with_cache(
+        lg, cache = _forward(
             cfg, params, toks[:, i:i + 1], jnp.array([[i]], jnp.int32),
             cache, slot_ids=jnp.array([0], jnp.int32))
         out.append(lg[0, 0])
@@ -300,7 +314,7 @@ def test_paged_chunked_prefill_matches_token_by_token(tiny_model):
     out = []
     for a, b in ((0, 4), (4, 7), (7, 10)):
         pos = jnp.arange(a, b, dtype=jnp.int32)[None]
-        lg, cache = llama_forward_with_cache(
+        lg, cache = _forward(
             cfg, params, toks[:, a:b], pos, cache,
             slot_ids=jnp.full((b - a,), 0, jnp.int32))
         out.append(lg[0])
@@ -321,7 +335,7 @@ def test_paged_decode_int8_pool_close_to_contiguous(tiny_model):
     cache = _paged_cache(cfg, quantized=True)
     out = []
     for i in range(10):
-        lg, cache = llama_forward_with_cache(
+        lg, cache = _forward(
             cfg, params, toks[:, i:i + 1], jnp.array([[i]], jnp.int32),
             cache, slot_ids=jnp.array([0], jnp.int32))
         out.append(lg[0, 0])
@@ -335,7 +349,7 @@ def test_paged_forward_requires_slot_ids(tiny_model):
     cfg, params = tiny_model
     cache = _paged_cache(cfg)
     with pytest.raises(ValueError, match="slot_ids"):
-        llama_forward_with_cache(cfg, params, jnp.zeros((1, 1), jnp.int32),
+        _forward(cfg, params, jnp.zeros((1, 1), jnp.int32),
                                  jnp.zeros((1, 1), jnp.int32), cache)
 
 
@@ -356,11 +370,11 @@ def test_two_slots_are_isolated(tiny_model):
     cache = cache.replace(block_tables=jnp.asarray(tables))
     out = []
     for i in range(6):
-        lg, cache = llama_forward_with_cache(
+        lg, cache = _forward(
             cfg, params, ta[:, i:i + 1], jnp.array([[i]], jnp.int32),
             cache, slot_ids=jnp.array([0], jnp.int32))
         out.append(lg[0, 0])
-        _, cache = llama_forward_with_cache(
+        _, cache = _forward(
             cfg, params, tb[:, i:i + 1], jnp.array([[i]], jnp.int32),
             cache, slot_ids=jnp.array([1], jnp.int32))
     np.testing.assert_allclose(np.asarray(jnp.stack(out)), np.asarray(ref),

@@ -580,18 +580,6 @@ def test_cli_unknown_model_errors():
         plan_cli(["--model", "nope", "--devices", "8"])
 
 
-def test_bench_plan_metric_keys():
-    import bench
-
-    aux = bench.plan_metric("cpu", len(jax.devices()))
-    n = len(jax.devices())
-    for key in (f"plan_best_cost_cpu{n}", f"plan_handpicked_cost_cpu{n}",
-                f"plan_advantage_ratio_cpu{n}", f"plan_search_ms_cpu{n}"):
-        assert key in aux
-        assert set(aux[key]) == {"value", "unit", "vs_baseline"}
-    assert aux[f"plan_advantage_ratio_cpu{n}"]["value"] >= 1.0
-
-
 # ---------------------------------------------------------------------------
 # serving plans
 # ---------------------------------------------------------------------------

@@ -133,14 +133,18 @@ def test_quantized_expert_mlps_close_to_float():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_quantized_kv_cache_decode():
+@pytest.mark.parametrize("family", ["llama", "mixtral"])
+def test_quantized_kv_cache_decode(family):
     """int8 KV cache decode (reference kv_cache_quant,
     quantization_config.py:72): logits track the fp cache within quant
-    error; resident slots don't drift across steps."""
+    error; resident slots don't drift across steps. Every family, each
+    through the forward it serves under."""
     from neuronx_distributed_tpu.inference.kv_cache import (
         dequantize_kv, init_quantized_kv_cache, quantize_kv)
-    from neuronx_distributed_tpu.models.llama import (
-        LlamaForCausalLM, llama_forward_with_cache, tiny_config)
+    from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
+                                                      tiny_config)
+    from neuronx_distributed_tpu.models.mixtral import (MixtralForCausalLM,
+                                                        tiny_moe_config)
 
     # roundtrip: quantize-dequantize-quantize is a fixed point
     x = jax.random.normal(jax.random.key(40), (2, 3, 4, 8))
@@ -150,9 +154,14 @@ def test_quantized_kv_cache_decode():
     np.testing.assert_array_equal(np.asarray(q), np.asarray(q2))
 
     ps.initialize_model_parallel()
-    cfg = tiny_config(dtype=jnp.float32, param_dtype=jnp.float32,
-                      num_layers=2)
-    model = LlamaForCausalLM(cfg)
+    kw = dict(dtype=jnp.float32, param_dtype=jnp.float32, num_layers=2)
+    if family == "mixtral":
+        cfg = tiny_moe_config(capacity_factor=4.0, **kw)
+        model = MixtralForCausalLM(cfg)
+    else:
+        cfg = tiny_config(**kw)
+        model = LlamaForCausalLM(cfg)
+    forward = cfg.serving_family().forward
     ids = jax.random.randint(jax.random.key(41), (1, 8), 0, cfg.vocab_size)
     params = meta.unbox(model.init(jax.random.key(42), ids))
 
@@ -163,16 +172,16 @@ def test_quantized_kv_cache_decode():
     qc = init_quantized_kv_cache(cfg.num_layers, 1, 16, cfg.num_kv_heads,
                                  cfg.head_dim_)
     pos = jnp.arange(8)[None]
-    ref, fpc = llama_forward_with_cache(cfg, params, ids, pos, fpc)
-    got, qc = llama_forward_with_cache(cfg, params, ids, pos, qc)
+    ref, fpc = forward(cfg, params, ids, pos, fpc)
+    got, qc = forward(cfg, params, ids, pos, qc)
     assert np.abs(np.asarray(got) - np.asarray(ref)).max() < 0.15
 
     # several decode steps: stays close, no drift blowup
     for t in range(8, 12):
         tok = jnp.argmax(ref[:, -1:], axis=-1)
         p = jnp.full((1, 1), t, jnp.int32)
-        ref, fpc = llama_forward_with_cache(cfg, params, tok, p, fpc)
-        got, qc = llama_forward_with_cache(cfg, params, tok, p, qc)
+        ref, fpc = forward(cfg, params, tok, p, fpc)
+        got, qc = forward(cfg, params, tok, p, qc)
         assert np.abs(np.asarray(got) - np.asarray(ref)).max() < 0.2, t
 
 
