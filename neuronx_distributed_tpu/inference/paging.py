@@ -239,20 +239,26 @@ class QuantizedPagedKVCache(struct.PyTreeNode):
 
 
 class PagedCacheView(struct.PyTreeNode):
-    """One layer's pool slice plus this step's routing arrays, threaded
-    through ``LlamaDecoderLayer`` in place of the contiguous
-    ``(k, v, slot_pos)`` cache tuple. ``tables [T, max_blocks_per_seq]``
-    is the per-token block table (each packed token carries its own
-    slot's row); ``write_idx [T]`` is the precomputed flat pool index for
-    this step's K/V rows (== pool capacity for rows that must not land —
-    scatters use ``mode="drop"``). ``roll`` is the routing of the
-    summaries a window-summary family writes in this step
-    (:func:`window_roll`; None for a full cache)."""
+    """The pool's whole stacks, the layer the holder is at, and this
+    step's routing arrays, threaded through ``LlamaDecoderLayer`` in
+    place of the contiguous ``(k, v, slot_pos)`` cache tuple. ``k``/``v``
+    (and the int8 pool's scales) are ``[L, num_blocks, block_size, ...]``
+    and ride the layer scan as its carry: a layer writes and reads rows
+    at ``(layer, block)`` and never holds a slice of its own (a slice is
+    a copy of one layer's pool in and another out, every layer of every
+    step). ``tables [T, max_blocks_per_seq]`` is the per-token block
+    table (each packed token carries its own slot's row); ``write_idx
+    [T]`` is the precomputed flat index, within a layer, of this step's
+    K/V rows (== pool capacity for rows that must not land:
+    :func:`write_pool_rows`). ``roll`` is the routing of the summaries a
+    window-summary family writes in this step (:func:`window_roll`; None
+    for a full cache)."""
 
     k: jax.Array
     v: jax.Array
     k_scale: Optional[jax.Array]
     v_scale: Optional[jax.Array]
+    layer: jax.Array
     pos: jax.Array
     tables: jax.Array
     write_idx: jax.Array
@@ -260,16 +266,17 @@ class PagedCacheView(struct.PyTreeNode):
 
 
 class CPPrefillView(struct.PyTreeNode):
-    """One layer's LOCAL pool shard plus this rank's write routing for
-    context-parallel ring prefill: the attention itself is ring attention
-    over the cp axis (no block-table gather — every rank sees the whole
-    prompt via the rotating KV chunks), so only the scatter routing
-    rides: ``write_idx [W_local]`` flat indices into this rank's pool
-    shard (pool capacity = drop, for pad rows and rows another rank
-    owns)."""
+    """The LOCAL pool shard's stacks, the layer the holder is at, and
+    this rank's write routing for context-parallel ring prefill: the
+    attention itself is ring attention over the cp axis (no block-table
+    gather — every rank sees the whole prompt via the rotating KV
+    chunks), so only the scatter routing rides: ``write_idx [W_local]``
+    flat indices into a layer of this rank's pool shard (pool capacity =
+    drop, for pad rows and rows another rank owns)."""
 
     k: jax.Array
     v: jax.Array
+    layer: jax.Array
     pos: jax.Array
     write_idx: jax.Array
 
@@ -485,11 +492,16 @@ def flat_write_indices(tok_tables: jax.Array, positions: jax.Array,
                        block_size: int, capacity: int,
                        kind=FULL_CACHE) -> jax.Array:
     """``[T, max_blocks_per_seq]`` per-token block tables + ``[T]`` true
-    positions -> ``[T]`` flat pool indices (the column of a position is
-    the cache ``kind``'s). Rows whose position is padding
-    (PAD_POSITION), beyond the table, or mapped to ``-1`` get index ==
-    ``capacity`` — out of bounds, so ``mode="drop"`` scatters discard
-    them."""
+    positions -> ``[T]`` flat pool indices within a layer. This is where
+    the pool's layout is decided: position ``p`` of a sequence lives in
+    the block its table names in column ``kind.column_of(p)`` (a full
+    cache: ``p // block_size``), at slot ``p % block_size``, in every
+    layer alike; the paged kernel's walk
+    (:func:`..ops.paged_attention.column_live`, ``window_column_kinds``)
+    and the engine's block mapping rely on it. Rows whose position is
+    padding (PAD_POSITION), beyond the table, or mapped to ``-1`` get
+    index == ``capacity`` — out of bounds of a layer's rows, so the
+    ``mode="drop"`` scatters discard them (:func:`write_pool_rows`)."""
     blk_of_pos = kind.column_of(positions, block_size)
     maxb = tok_tables.shape[1]
     safe = jnp.clip(blk_of_pos, 0, maxb - 1)
@@ -500,12 +512,25 @@ def flat_write_indices(tok_tables: jax.Array, positions: jax.Array,
 
 
 def write_pool_rows(pool: jax.Array, rows: jax.Array,
-                    flat_idx: jax.Array) -> jax.Array:
-    """Scatter ``rows [T, ...]`` into ``pool [num_blocks, block_size,
-    ...]`` at the flat indices from :func:`flat_write_indices`."""
-    nb, bs = pool.shape[:2]
-    flat = pool.reshape((nb * bs,) + pool.shape[2:])
-    flat = flat.at[flat_idx].set(rows.astype(pool.dtype), mode="drop")
+                    flat_idx: jax.Array, layer) -> jax.Array:
+    """Scatter ``rows [T, ...]`` into layer ``layer`` of the stack
+    ``pool [L, num_blocks, block_size, ...]`` at the flat indices from
+    :func:`flat_write_indices`: one scatter into the stack, in place
+    where the stack is the layer scan's carry.
+
+    The pool's layout: a row of layer ``l``, block ``b``, slot ``s`` is
+    ``pool[l, b, s]``, and a flat index counts ``b * block_size + s``
+    within one layer. The stack is therefore addressed as ``[L,
+    num_blocks * block_size, ...]`` by the pair ``(layer, flat)`` and
+    never as one run of ``L * capacity`` rows: the drop sentinel
+    ``flat_idx == capacity`` lies past the end of its own column of the
+    pair, so ``mode="drop"`` discards the row in every layer, where
+    ``layer * capacity + capacity`` would be the next layer's first
+    row."""
+    n_layers, nb, bs = pool.shape[:3]
+    flat = pool.reshape((n_layers, nb * bs) + pool.shape[3:])
+    flat = flat.at[layer, flat_idx].set(rows.astype(pool.dtype),
+                                        mode="drop")
     return flat.reshape(pool.shape)
 
 

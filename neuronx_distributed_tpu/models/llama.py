@@ -324,11 +324,11 @@ def _is_cp_prefill_view(cache) -> bool:
 
 def _cp_prefill_attend(cfg: LlamaConfig, q, k, v, positions, view):
     """Context-parallel ring prefill against the CP-sharded paged pool:
-    scatter this rank's chunk of K/V rows into the LOCAL pool shard at
-    the precomputed flat indices (rows another rank owns carry the drop
-    sentinel), then attend the whole prompt with ring attention — the KV
-    chunks rotate around the cp ring, quantized per
-    ``cfg.cp_wire_dtype``. Called inside shard_map with the cp axis
+    scatter this rank's chunk of K/V rows into the view's layer of the
+    LOCAL pool shard at the precomputed flat indices (rows another rank
+    owns carry the drop sentinel), then attend the whole prompt with
+    ring attention — the KV chunks rotate around the cp ring, quantized
+    per ``cfg.cp_wire_dtype``. Called inside shard_map with the cp axis
     bound; the packed batch is this rank's ``[1, W_local]`` slice of the
     right-padded prompt, so ring's global arange coordinates equal the
     true token positions and causality is exact across ranks."""
@@ -338,8 +338,10 @@ def _cp_prefill_attend(cfg: LlamaConfig, q, k, v, positions, view):
     from ..ops.ring_attention import ring_attention
 
     k_rows, v_rows = k[0], v[0]                      # [W_local, KV, D]
-    new_k = paging.write_pool_rows(view.k, k_rows, view.write_idx)
-    new_v = paging.write_pool_rows(view.v, v_rows, view.write_idx)
+    new_k = paging.write_pool_rows(view.k, k_rows, view.write_idx,
+                                   view.layer)
+    new_v = paging.write_pool_rows(view.v, v_rows, view.write_idx,
+                                   view.layer)
     n_rep = q.shape[2] // k.shape[2]
     kf = attn_mod.repeat_kv(k, n_rep)
     vf = attn_mod.repeat_kv(v, n_rep)
@@ -353,11 +355,12 @@ def _cp_prefill_attend(cfg: LlamaConfig, q, k, v, positions, view):
 
 def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
     """Attention against the paged block pool: (optionally quantize and)
-    scatter this step's K/V rows into the layer's pool slice at the
-    precomputed flat indices, then gather-attend through the per-token
-    block tables (:mod:`..ops.paged_attention`). The packed batch is
-    ``[1, T]``; rows with a dropped write index (pads, preempted slots)
-    never land in the pool and their outputs are discarded by the caller.
+    scatter this step's K/V rows into the view's layer of the stacks at
+    the precomputed flat indices, then attend that layer's blocks through
+    the per-token block tables (:mod:`..ops.paged_attention`). The packed
+    batch is ``[1, T]``; rows with a dropped write index (pads, preempted
+    slots) never land in the pool and their outputs are discarded by the
+    caller.
     """
     import math as _math
 
@@ -374,20 +377,21 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
     cp = comm._axis_size(ps.CP_AXIS)
     combine = ps.CP_AXIS if cp not in (None, 1) else None
     k_rows, v_rows = k[0], v[0]                      # [T, KV_local, D]
+
+    def write(pool, rows):
+        return paging.write_pool_rows(pool, rows, view.write_idx, view.layer)
+
     if view.k_scale is not None:
         qk, ks = quantize_kv(k_rows)
         qv, vs = quantize_kv(v_rows)
-        new_k = paging.write_pool_rows(view.k, qk, view.write_idx)
-        new_v = paging.write_pool_rows(view.v, qv, view.write_idx)
-        new_ks = paging.write_pool_rows(view.k_scale, ks, view.write_idx)
-        new_vs = paging.write_pool_rows(view.v_scale, vs, view.write_idx)
+        new_k, new_v = write(view.k, qk), write(view.v, qv)
+        new_ks, new_vs = write(view.k_scale, ks), write(view.v_scale, vs)
     else:
-        new_k = paging.write_pool_rows(view.k, k_rows, view.write_idx)
-        new_v = paging.write_pool_rows(view.v, v_rows, view.write_idx)
+        new_k, new_v = write(view.k, k_rows), write(view.v, v_rows)
         new_ks = new_vs = None
     out = paged_attention(
         q[0], new_k, new_v, view.pos, view.tables, positions[0],
-        k_scale=new_ks, v_scale=new_vs,
+        view.layer, k_scale=new_ks, v_scale=new_vs,
         scale=1.0 / _math.sqrt(q.shape[-1]),
         force_pallas=cfg.attn_force_pallas,
         combine_axis=combine)[None]
@@ -399,9 +403,9 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
 def _eva_attend(cfg: LlamaConfig, q, k, v, positions, cache, phi, mu):
     """EVA attention of one layer. No cache: the whole sequence, windows
     by reshape (positions ``0..S-1``). Paged pool: write this step's rows
-    into the ring columns, summarise the windows the step completes into
-    their summary blocks of the same pool, then attend both kinds of row
-    through the table under one softmax."""
+    into the ring columns of the view's layer, summarise the windows the
+    step completes into their summary blocks of the same layer, then
+    attend both kinds of row through the table under one softmax."""
     import math as _math
 
     from ..ops import eva_attention as eva
@@ -418,13 +422,16 @@ def _eva_attend(cfg: LlamaConfig, q, k, v, positions, cache, phi, mu):
     from ..ops.paged_attention import paged_attention
 
     kind = cfg.serving_family().cache_kind
-    new_k = paging.write_pool_rows(cache.k, k[0], cache.write_idx)
-    new_v = paging.write_pool_rows(cache.v, v[0], cache.write_idx)
+    new_k = paging.write_pool_rows(cache.k, k[0], cache.write_idx,
+                                   cache.layer)
+    new_v = paging.write_pool_rows(cache.v, v[0], cache.write_idx,
+                                   cache.layer)
     new_k, new_v = eva.write_window_summaries(
-        new_k, new_v, cache.roll, phi, mu, cfg.chunk_size, scale)
+        new_k, new_v, cache.layer, cache.roll, phi, mu, cfg.chunk_size,
+        scale)
     out = paged_attention(
         q[0], new_k, new_v, cache.pos, cache.tables, positions[0],
-        scale=scale, force_pallas=cfg.attn_force_pallas,
+        cache.layer, scale=scale, force_pallas=cfg.attn_force_pallas,
         window=(kind.window, kind.ring))[None]
     return out.astype(cfg.dtype), cache.replace(k=new_k, v=new_v)
 
@@ -889,55 +896,55 @@ class _DecodeScanBody(nn.Module):
 
 
 class _PagedScanBody(nn.Module):
-    """nn.scan body for paged decode: carries hidden states, maps each
-    layer's pool slice (leading layer dim) through, broadcasts the step's
-    routing arrays (pool positions, per-token block tables, flat write
-    indices). Parameter layout is identical to :class:`_DecodeScanBody`
-    (same ``layer`` scope), so the same checkpoint serves both cache
+    """nn.scan body for paged decode. The carry is the hidden states and
+    the pool's whole stacks ``(k, v, k_scale, v_scale)`` (the scales
+    ``None`` for a float pool); the scanned input is the layer's index,
+    and the step's routing arrays (pool positions, per-token block
+    tables, flat write indices) are broadcast. A layer writes and reads
+    the stacks at ``(layer, block)`` and hands them on, so the loop
+    updates the donated pool in place; scanned in and out instead, every
+    layer's pool was sliced out of one stack and written into another.
+    Parameter layout is identical to :class:`_DecodeScanBody` (same
+    ``layer`` scope), so the same checkpoint serves both cache
     protocols."""
 
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, cache_kv, pool_pos, tables, write_idx, cos, sin,
+    def __call__(self, carry, layer, pool_pos, tables, write_idx, cos, sin,
                  positions, roll=None):
         from ..inference.paging import PagedCacheView
 
-        if len(cache_kv) == 4:
-            k_l, v_l, ks_l, vs_l = cache_kv
-        else:
-            (k_l, v_l), ks_l, vs_l = cache_kv, None, None
-        view = PagedCacheView(k=k_l, v=v_l, k_scale=ks_l, v_scale=vs_l,
-                              pos=pool_pos, tables=tables,
+        x, (k, v, k_scale, v_scale) = carry
+        view = PagedCacheView(k=k, v=v, k_scale=k_scale, v_scale=v_scale,
+                              layer=layer, pos=pool_pos, tables=tables,
                               write_idx=write_idx, roll=roll)
-        x, _, new_view = LlamaDecoderLayer(self.cfg, name="layer")(
+        x, _, new = LlamaDecoderLayer(self.cfg, name="layer")(
             x, cos, sin, positions, cache=view, cache_index=None)
-        if len(cache_kv) == 4:
-            return x, (new_view.k, new_view.v, new_view.k_scale,
-                       new_view.v_scale)
-        return x, (new_view.k, new_view.v)
+        return (x, (new.k, new.v, new.k_scale, new.v_scale)), None
 
 
 class _CPPrefillScanBody(nn.Module):
-    """nn.scan body for context-parallel ring prefill: carries hidden
-    states, maps each layer's LOCAL pool shard (leading layer dim)
-    through, broadcasts the rank's write routing. Parameter layout is
-    identical to :class:`_PagedScanBody` (same ``layer`` scope), so the
-    same checkpoint serves the ring-prefill and paged-decode workers."""
+    """nn.scan body for context-parallel ring prefill: the carry and the
+    scanned layer index of :class:`_PagedScanBody` over the LOCAL pool
+    shard's stacks, the rank's write routing broadcast. Parameter layout
+    is identical to :class:`_PagedScanBody` (same ``layer`` scope), so
+    the same checkpoint serves the ring-prefill and paged-decode
+    workers."""
 
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, cache_kv, pool_pos, write_idx, cos, sin,
+    def __call__(self, carry, layer, pool_pos, write_idx, cos, sin,
                  positions):
         from ..inference.paging import CPPrefillView
 
-        k_l, v_l = cache_kv
-        view = CPPrefillView(k=k_l, v=v_l, pos=pool_pos,
+        x, (k, v, *no_scales) = carry
+        view = CPPrefillView(k=k, v=v, layer=layer, pos=pool_pos,
                              write_idx=write_idx)
-        x, _, new_view = LlamaDecoderLayer(self.cfg, name="layer")(
+        x, _, new = LlamaDecoderLayer(self.cfg, name="layer")(
             x, cos, sin, positions, cache=view, cache_index=None)
-        return x, (new_view.k, new_view.v)
+        return (x, (new.k, new.v, *no_scales)), None
 
 
 class LlamaModel(nn.Module):
@@ -1203,42 +1210,36 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
         slot_pos = _paging.write_pool_positions(kv_cache.pos, positions[0],
                                                 write_idx)
         quantized = isinstance(kv_cache, QuantizedPagedKVCache)
-        cache_kv = ((kv_cache.k, kv_cache.v, kv_cache.k_scale,
-                     kv_cache.v_scale) if quantized
-                    else (kv_cache.k, kv_cache.v))
+        if cp_prefill and quantized:
+            raise ValueError(
+                "cp_prefill does not support quantized paged caches")
+        # the pool rides the layer scan as its carry, beside x, and the
+        # layers' indices are what is scanned: no layer's pool is sliced
+        # out of the stacks or written back into them
+        stacks = (kv_cache.k, kv_cache.v,
+                  kv_cache.k_scale if quantized else None,
+                  kv_cache.v_scale if quantized else None)
+        routing = (write_idx, cos, sin, rope_pos)
         if cp_prefill:
-            if quantized:
-                raise ValueError(
-                    "cp_prefill does not support quantized paged caches")
-            scanned = nn.scan(
-                _CPPrefillScanBody,
-                variable_axes={"params": 0},
-                split_rngs={"params": True},
-                in_axes=(0, nn.broadcast, nn.broadcast, nn.broadcast,
-                         nn.broadcast, nn.broadcast),
-                out_axes=0,
-                length=cfg.num_layers,
-            )(cfg)
-            x, new_kv = scanned.apply(
-                {"params": p["model"]["layers"]}, x, cache_kv, slot_pos,
-                write_idx, cos, sin, rope_pos)
+            body, routing = _CPPrefillScanBody, (slot_pos,) + routing
         else:
             # a window-summary kind also routes the summaries this step
             # writes, once for all layers
             roll = () if kind.ring is None else (_paging.window_roll(
                 kind, kv_cache.block_tables, slot_ids, positions[0],
                 kv_cache.block_size, kv_cache.num_blocks),)
-            scanned = nn.scan(
-                _PagedScanBody,
-                variable_axes={"params": 0},
-                split_rngs={"params": True},
-                in_axes=(0,) + (nn.broadcast,) * (6 + len(roll)),
-                out_axes=0,
-                length=cfg.num_layers,
-            )(cfg)
-            x, new_kv = scanned.apply(
-                {"params": p["model"]["layers"]}, x, cache_kv, slot_pos,
-                tok_tables, write_idx, cos, sin, rope_pos, *roll)
+            body = _PagedScanBody
+            routing = (slot_pos, tok_tables) + routing + roll
+        scanned = nn.scan(
+            body,
+            variable_axes={"params": 0},
+            split_rngs={"params": True},
+            in_axes=(0,) + (nn.broadcast,) * len(routing),
+            length=cfg.num_layers,
+        )(cfg)
+        (x, new_kv), _ = scanned.apply(
+            {"params": p["model"]["layers"]}, (x, stacks),
+            jnp.arange(cfg.num_layers, dtype=jnp.int32), *routing)
     else:
         # record this step's true positions in the slot-position table
         # (pads carry the PAD_POSITION sentinel and are thereby never
@@ -1287,13 +1288,10 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
             **_act_kw(cfg), **_lora_kw(cfg, "lm_head"))
         logits = head.apply({"params": p["lm_head"]}, x)
     if paged:
-        if quantized:
-            new_k, new_v, nks, nvs = new_kv
-            new_cache = kv_cache.replace(k=new_k, v=new_v, k_scale=nks,
-                                         v_scale=nvs, pos=slot_pos)
-        else:
-            new_k, new_v = new_kv
-            new_cache = kv_cache.replace(k=new_k, v=new_v, pos=slot_pos)
+        new_k, new_v, nks, nvs = new_kv
+        scales = dict(k_scale=nks, v_scale=nvs) if quantized else {}
+        new_cache = kv_cache.replace(k=new_k, v=new_v, pos=slot_pos,
+                                     **scales)
     elif quantized:
         new_k, new_v, nks, nvs = new_kv
         new_cache = QuantizedKVCache(

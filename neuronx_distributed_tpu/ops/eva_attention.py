@@ -74,23 +74,27 @@ def eva_attention_full(q, k, v, phi, mu, window: int, chunk: int,
     return out.reshape(b, nw * span, n, d)[:, :s]
 
 
-def write_window_summaries(k_pool, v_pool, roll, phi, mu, chunk: int,
+def write_window_summaries(k_pool, v_pool, layer, roll, phi, mu, chunk: int,
                            scale: float):
-    """The paged step's summarisation, one layer: ``roll`` is
+    """The paged step's summarisation at layer ``layer`` of the stacks
+    ``[L, num_blocks, block_size, KV, D]``: ``roll`` is
     :func:`..inference.paging.window_roll`'s ``(any, src [S, bpw], dst
     [S])``. For each slot whose window this step completes, its ``bpw``
-    ring blocks (already holding this step's rows) are pooled into
-    ``block_size`` summary row pairs and written as the block ``dst`` of
-    the same pool. A step that completes no window skips the reads and
-    the pooling (the ``cond``), and its writes are all dropped."""
+    ring blocks (already holding this step's rows) are gathered at
+    ``(layer, block)``, pooled into ``block_size`` summary row pairs and
+    written as the block ``(layer, dst)`` of the same stacks. A step
+    that completes no window skips the reads and the pooling (the
+    ``cond``, which hands back the summaries and never a pool), and its
+    writes are all dropped: ``dst == num_blocks`` is out of bounds of
+    the block dimension, whatever the layer."""
     any_done, src, dst = roll
-    nb, bs, kv, d = k_pool.shape
+    bs, kv, d = k_pool.shape[2:]
 
     def pooled(kp, vp):
         def one_slot(blocks):
             kt, vt = chunk_summaries(
-                kp[blocks].reshape(-1, kv, d), vp[blocks].reshape(-1, kv, d),
-                phi, mu, chunk, scale)
+                kp[layer, blocks].reshape(-1, kv, d),
+                vp[layer, blocks].reshape(-1, kv, d), phi, mu, chunk, scale)
             return kt.astype(kp.dtype), vt.astype(vp.dtype)
 
         return jax.lax.map(one_slot, src)
@@ -100,5 +104,5 @@ def write_window_summaries(k_pool, v_pool, roll, phi, mu, chunk: int,
         return z, z.astype(vp.dtype)
 
     ktil, vtil = jax.lax.cond(any_done, pooled, nothing, k_pool, v_pool)
-    return (k_pool.at[dst].set(ktil, mode="drop"),
-            v_pool.at[dst].set(vtil, mode="drop"))
+    return (k_pool.at[layer, dst].set(ktil, mode="drop"),
+            v_pool.at[layer, dst].set(vtil, mode="drop"))

@@ -1,9 +1,12 @@
 """Paged decode attention over a shared block pool.
 
 Decode-time attention where K/V live in the paged pool of
-:mod:`..inference.paging` (``[num_blocks, block_size, KV, D]`` per layer)
-and each query token reads the blocks named by its slot's block table —
-the attention half of the vLLM design, on the fixed-shape serving step.
+:mod:`..inference.paging` (``[L, num_blocks, block_size, KV, D]``, every
+layer's in one stack) and each query token reads the blocks of one layer
+named by its slot's block table — the attention half of the vLLM design,
+on the fixed-shape serving step. Both implementations read the stack at
+``(layer, block)``: a ``pool[layer]`` in front of them would be a copy
+of a layer's pool, every layer of every step.
 
 Two implementations behind one signature, following
 :mod:`.flash_attention` / :mod:`.flash_decoding`:
@@ -15,13 +18,13 @@ Two implementations behind one signature, following
   on the contiguous cache; runs everywhere and is the tier-1/CPU path.
 * ``_paged_attention_pallas`` — a Mosaic TPU kernel: grid ``(tokens,
   max_blocks_per_seq)``, the walk over each row's block table
-  (:func:`_paged_walk`) scalar-prefetched into SMEM so a grid step DMAs
-  at most one pool block into VMEM (online-softmax m/l/acc in VMEM
-  scratch). The walk follows the row's context: a column that is
-  unmapped (-1) or lies wholly behind the row's own position
-  (:func:`column_live`) is skipped, not masked — its grid step runs no
-  arithmetic, and past the row's last causal column the block index
-  repeats that column's, so the same-block DMA is elided too.
+  (:func:`_paged_walk`) and the layer scalar-prefetched into SMEM so a
+  grid step DMAs at most one pool block into VMEM (online-softmax
+  m/l/acc in VMEM scratch). The walk follows the row's context: a
+  column that is unmapped (-1) or lies wholly behind the row's own
+  position (:func:`column_live`) is skipped, not masked — its grid step
+  runs no arithmetic, and past the row's last causal column the block
+  index repeats that column's, so the same-block DMA is elided too.
 
 Auto-dispatch picks the kernel on TPU when the shapes tile; CPU runs the
 kernel in interpret mode when forced (CI coverage of the mask path).
@@ -53,22 +56,22 @@ from .pallas_utils import compiler_params as _compiler_params
 logger = get_logger(__name__)
 
 
-def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos,
+def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos, layer,
                          k_scale, v_scale, scale, combine_axis=None,
                          window=None):
     t, n, d = q.shape
-    nb, bs, kv, _ = k_pool.shape
+    _, nb, bs, kv, _ = k_pool.shape
     n_rep = n // kv
     safe = jnp.clip(tables, 0, nb - 1)
-    kg = k_pool[safe]                          # [T, maxb, bs, KV, D]
-    vg = v_pool[safe]
+    kg = k_pool[layer, safe]                   # [T, maxb, bs, KV, D]
+    vg = v_pool[layer, safe]
     pg = pool_pos[safe]                        # [T, maxb, bs]
     # entries gathered through an unmapped (-1) table slot are another
     # sequence's data — force their stored position to the pad sentinel
     pg = jnp.where(tables[:, :, None] >= 0, pg, PAD_POSITION)
     if k_scale is not None:
-        kg = dequantize_kv(kg, k_scale[safe], q.dtype)
-        vg = dequantize_kv(vg, v_scale[safe], q.dtype)
+        kg = dequantize_kv(kg, k_scale[layer, safe], q.dtype)
+        vg = dequantize_kv(vg, v_scale[layer, safe], q.dtype)
     length = tables.shape[1] * bs
     k_full = repeat_kv(kg.reshape(t, length, kv, d).astype(q.dtype), n_rep)
     v_full = repeat_kv(vg.reshape(t, length, kv, d).astype(q.dtype), n_rep)
@@ -186,14 +189,15 @@ def _paged_walk(tables, q_pos, block_size: int):
                      fetch, ~fetch)
 
 
-def _paged_kernel(walk_ref, qpos_ref, *refs,
+def _paged_kernel(walk_ref, qpos_ref, layer_ref, *refs,
                   num_blocks_per_seq: int, n_rep: int, scale: float,
                   quantized: bool, ring: Optional[int] = None):
     """One (token, table column) grid step: online softmax of the token's
     heads over one pool block, if the column is live for the token
     (``walk_ref[t, j] >= 0``, :func:`_paged_walk`); a skipped column
     leaves the running max, sum and accumulator as they are, which is
-    what its all-masked block did.
+    what its all-masked block did. ``layer_ref`` is the index maps' (which
+    layer of the stacks a block is fetched from); the body never reads it.
 
     Everything stays in the pool block's own layout — slots on the major
     dim, KV heads on sublanes, head_dim on lanes — so Mosaic sees only
@@ -203,7 +207,7 @@ def _paged_kernel(walk_ref, qpos_ref, *refs,
     replica ``r`` (query head ``h * n_rep + r`` reads KV head ``h``).
 
     With ``ring`` (a window-summary cache, the ``eva_attention`` kernel)
-    a third prefetched scalar a row is the first position of its window:
+    a further prefetched scalar a row is the first position of its window:
     the rows of columns under ``ring`` are exact and count from there to
     the row's own position, those of the columns from ``ring`` on are
     summaries and count whole; one softmax runs over both."""
@@ -225,7 +229,7 @@ def _paged_kernel(walk_ref, qpos_ref, *refs,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bs, kv, _ = k_ref.shape[1:]
+    bs = k_ref.shape[1]
 
     @pl.when(walk_ref[t, j] >= 0)
     def _accumulate():
@@ -244,15 +248,12 @@ def _paged_kernel(walk_ref, qpos_ref, *refs,
         valid = jnp.max(jnp.where(eye, ok, 0.0), axis=-1,
                         keepdims=True) > 0.5
         if quantized:
-            # scales arrive head-on-lanes ([BS, KV]); same trick puts each
-            # on its head's sublane ([BS, KV, 1]). They scale the score and
-            # the probability, not the [BS, KV, D] operands.
-            eye_kv = (jax.lax.broadcasted_iota(jnp.int32, (1, kv, kv), 1)
-                      == jax.lax.broadcasted_iota(jnp.int32, (1, kv, kv), 2))
-
+            # scales arrive slot-on-lanes too ([1, KV, BS]); the same trick
+            # puts each on its slot's major index ([BS, KV, 1]). They scale
+            # the score and the probability, not the [BS, KV, D] operands.
             def per_row(s_ref):
-                return jnp.sum(jnp.where(eye_kv, s_ref[0][:, None, :], 0.0),
-                               axis=-1, keepdims=True)
+                return jnp.sum(jnp.where(eye, s_ref[...], 0.0), axis=-1,
+                               keepdims=True)
 
             k_scale = per_row(ks_ref)
             v_scale = per_row(vs_ref)
@@ -281,33 +282,39 @@ def _paged_kernel(walk_ref, qpos_ref, *refs,
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
-                            k_scale, v_scale, scale, interpret=False,
+                            layer, k_scale, v_scale, scale, interpret=False,
                             window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     t, n, d = q.shape
-    nb, bs, kv, _ = k_pool.shape
+    _, nb, bs, kv, _ = k_pool.shape
     maxb = tables.shape[1]
     n_rep = n // kv
     quantized = k_scale is not None
 
     q_pos = q_pos.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     if window is None:
         walk = _paged_walk(tables.astype(jnp.int32), q_pos, bs)
-        prefetch, ring, name = (walk, q_pos), None, "paged_attention"
+        prefetch, ring, name = (walk, q_pos, layer), None, "paged_attention"
     else:
         size, ring = window
         walk = _window_walk(tables.astype(jnp.int32), q_pos, bs, size, ring)
-        prefetch = (walk, q_pos, (q_pos // size) * size)
+        prefetch = (walk, q_pos, layer, (q_pos // size) * size)
         name = "eva_attention"
 
     def block(ti, j, walk_s, *_):
         w = walk_s[ti, j]
         return jnp.where(w < 0, ~w, w)
 
-    def blk4(*idx):
-        return (block(*idx), 0, 0, 0)
+    # a block of the stacks: the layer's dim is squeezed out of the block,
+    # so the body sees one layer's [1, bs, ...] block as it always has
+    def stack5(ti, j, walk_s, qpos_s, layer_s, *_):
+        return (layer_s[0], block(ti, j, walk_s), 0, 0, 0)
+
+    def stack4(ti, j, walk_s, qpos_s, layer_s, *_):
+        return (layer_s[0], block(ti, j, walk_s), 0, 0)
 
     def blk3(*idx):
         return (block(*idx), 0, 0)
@@ -316,20 +323,25 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
         return (ti, 0, 0, 0)
 
     # Mosaic wants the last two block dims (8, 128)-aligned or whole: the
-    # positions ride as [nb, 1, bs] rows, the scales as whole [bs, kv]
-    # planes, and q/out as [T, n_rep, KV, D] so a replica is a leading index
+    # positions (shared by the layers) ride as [nb, 1, bs] rows, the scales
+    # as [kv, bs] planes, and q/out as [T, n_rep, KV, D] so a replica is a
+    # leading index
     in_specs = [
         pl.BlockSpec((1, n_rep, kv, d), tok),
-        pl.BlockSpec((1, bs, kv, d), blk4),
-        pl.BlockSpec((1, bs, kv, d), blk4),
+        pl.BlockSpec((None, 1, bs, kv, d), stack5),
+        pl.BlockSpec((None, 1, bs, kv, d), stack5),
         pl.BlockSpec((1, 1, bs), blk3),
     ]
     operands = [q.reshape(t, kv, n_rep, d).swapaxes(1, 2), k_pool, v_pool,
                 pool_pos.reshape(nb, 1, bs)]
     if quantized:
-        in_specs += [pl.BlockSpec((1, bs, kv), blk3),
-                     pl.BlockSpec((1, bs, kv), blk3)]
-        operands += [k_scale, v_scale]
+        # slots on lanes is how the chip stores an array whose last dim is
+        # a few heads wide, and how the step's scatter writes it: the swap
+        # is then no copy, where a [bs, kv] plane made the whole stack
+        # change layout in front of the kernel and back behind it
+        in_specs += [pl.BlockSpec((None, 1, kv, bs), stack4),
+                     pl.BlockSpec((None, 1, kv, bs), stack4)]
+        operands += [k_scale.swapaxes(2, 3), v_scale.swapaxes(2, 3)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
@@ -384,7 +396,7 @@ def paged_attention_impl(head_dim: int, block_size: int,
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     pool_pos: jax.Array, tables: jax.Array,
-                    q_pos: jax.Array,
+                    q_pos: jax.Array, layer,
                     k_scale: Optional[jax.Array] = None,
                     v_scale: Optional[jax.Array] = None,
                     scale: Optional[float] = None,
@@ -394,9 +406,11 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     """Paged decode attention.
 
     ``q [T, N, D]`` one query row per packed token; ``k_pool``/``v_pool``
-    ``[num_blocks, block_size, KV, D]`` (int8 when ``k_scale``/``v_scale``
-    ``[num_blocks, block_size, KV]`` are given); ``pool_pos [num_blocks,
-    block_size]`` stored token positions (PAD_POSITION = empty);
+    the stacks ``[L, num_blocks, block_size, KV, D]`` (int8 when
+    ``k_scale``/``v_scale`` ``[L, num_blocks, block_size, KV]`` are
+    given) and ``layer`` the (traced) index of the layer to attend, read
+    in place at ``(layer, block)``; ``pool_pos [num_blocks, block_size]``
+    stored token positions (PAD_POSITION = empty; shared by the layers);
     ``tables [T, max_blocks_per_seq]`` per-token block table (-1 =
     unmapped); ``q_pos [T]`` query positions. Returns ``[T, N, D]``.
 
@@ -419,7 +433,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     ``eva_attention`` in a device trace. Not with ``combine_axis``.
     """
     t, n, d = q.shape
-    nb, bs, kv, _ = k_pool.shape
+    _, nb, bs, kv, _ = k_pool.shape
     if n % kv != 0:
         raise ValueError(f"q heads {n} not a multiple of kv heads {kv}")
     if (k_scale is None) != (v_scale is None):
@@ -434,14 +448,14 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         # the CP merge lives in XLA-land (collectives between the local
         # gather and the normalisation); the kernel path has no axis
         return _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables,
-                                    q_pos, k_scale, v_scale, scale_,
+                                    q_pos, layer, k_scale, v_scale, scale_,
                                     combine_axis=combine_axis)
     impl = paged_attention_impl(d, bs, force_pallas)
     if impl == "xla":
         return _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables,
-                                    q_pos, k_scale, v_scale, scale_,
+                                    q_pos, layer, k_scale, v_scale, scale_,
                                     window=window)
     return _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables,
-                                   q_pos, k_scale, v_scale, scale_,
+                                   q_pos, layer, k_scale, v_scale, scale_,
                                    interpret=impl == "pallas-interpret",
                                    window=window)

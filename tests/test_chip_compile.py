@@ -138,17 +138,136 @@ def test_paged_attention(chip, quantized, shape):
         _paged_attention_pallas)
 
     tokens, kv, cols, nb = _PAGED_SHAPES[shape]
-    n, d, bs = 32, 128, 128
-    pool = chip((nb, bs, kv, d), jnp.int8 if quantized else jnp.bfloat16)
-    scale = chip((nb, bs, kv), jnp.float32) if quantized else None
+    n, d, bs, layers = 32, 128, 128, 2
+    pool = chip((layers, nb, bs, kv, d),
+                jnp.int8 if quantized else jnp.bfloat16)
+    scale = chip((layers, nb, bs, kv), jnp.float32) if quantized else None
     fn = functools.partial(_paged_attention_pallas,
                            scale=1.0 / math.sqrt(d), interpret=False)
     text = _assert_kernel_compiles(
         fn, chip((tokens, n, d), jnp.bfloat16), pool, pool,
         chip((nb, bs), jnp.int32), chip((tokens, cols), jnp.int32),
-        chip((tokens,), jnp.int32), scale, scale)
+        chip((tokens,), jnp.int32), chip((), jnp.int32), scale, scale)
     # the benchmark's readers and its `correct` find the kernel by name
     assert _kernel_instruction_names(text) == {"paged_attention"}
+
+
+# -- the paged forward: the pool rides the layer scan as its carry -----------
+# Scanned in and out, every layer's pool was sliced out of one stack
+# (dynamic-slice), written into another (dynamic-update-slice), and the
+# donated argument copied whole because an output built beside it cannot
+# alias it: three pool-sized copies a step (PERF.md, PR 30). As the carry,
+# addressed at (layer, block) by the scatters and by the kernel's index
+# map, the donated stacks are written in place. These cases hold every
+# later form of the paged body to that, at the serving cells' pool shapes.
+
+# family, K/V heads, blocks, slots, table columns, int8 pool
+_POOLS = {"full": ("llama", 8, 320, 32, 20, False),
+          "window_summary": ("evabyte", 32, 176, 8, 40, False),
+          "int8": ("llama", 8, 320, 32, 20, True)}
+
+
+def _in_place_writes(hlo_text, large):
+    """Of ``large`` (:func:`_top_level_results`), the fusions that end in a
+    ``scatter``: the device writes those into their operand's buffer."""
+    import re
+
+    root, comp = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+        m = re.match(r"^\s*ROOT %[\w.\-]+ = \(?[a-z0-9]+\[.*? ([a-z\-]+)\(",
+                     line)
+        if m:
+            root[comp] = m.group(1)
+    calls = dict(re.findall(
+        r"%([\w.\-]+) = [^\n]* fusion\([^\n]*calls=%([\w.\-]+)", hlo_text))
+    return [r for r in large
+            if r[0] == "fusion" and root.get(calls.get(r[1])) == "scatter"]
+
+
+@pytest.fixture
+def on_one_chip(topo, monkeypatch):
+    """The mesh on a described chip and the paged dispatcher steered to
+    the compiled kernel, as the chip's own backend would steer it."""
+    from neuronx_distributed_tpu.ops import paged_attention as pa
+    from neuronx_distributed_tpu.parallel import mesh as ps
+
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    pa.paged_attention_impl.cache_clear()
+    ps.destroy_model_parallel()
+    ps.initialize_model_parallel(devices=[topo.devices[0]])
+    yield
+    ps.destroy_model_parallel()
+    pa.paged_attention_impl.cache_clear()
+
+
+@pytest.mark.parametrize("pool", list(_POOLS))
+def test_paged_forward_writes_the_donated_pool_in_place(chip, on_one_chip,
+                                                        pool):
+    import re
+
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.inference import paging
+    from neuronx_distributed_tpu.models import evabyte, llama
+
+    family, kv, nb, slots, cols, quantized = _POOLS[pool]
+    layers, heads, d, bs, tokens = 2, 32, 128, 128, 128
+    widths = dict(hidden_size=heads * d, intermediate_size=1024,
+                  num_layers=layers, num_heads=heads, num_kv_heads=kv,
+                  vocab_size=512, max_seq_len=32768, dtype=jnp.bfloat16,
+                  param_dtype=jnp.bfloat16)
+    if family == "evabyte":
+        cfg = evabyte.EvaByteConfig(**widths)
+        model = evabyte.EvaByteForCausalLM(cfg)
+    else:
+        cfg = llama.LlamaConfig(**widths)
+        model = llama.LlamaForCausalLM(cfg)
+    forward = cfg.serving_family().forward
+    abstract = functools.partial(
+        jax.tree_util.tree_map, lambda x: chip(x.shape, x.dtype))
+    params = abstract(meta.unbox(jax.eval_shape(
+        model.init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))
+    if quantized:
+        init = paging.init_quantized_paged_kv_cache
+    else:
+        init = functools.partial(paging.init_paged_kv_cache,
+                                 dtype=jnp.bfloat16)
+    cache = abstract(jax.eval_shape(
+        lambda: init(layers, nb, bs, kv, d, slots, cols)))
+
+    def step(params, cache, tokens, positions, slot_ids):
+        return forward(cfg, params, tokens, positions, cache,
+                       slot_ids=slot_ids)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {
+        "eva_attention" if family == "evabyte" else "paged_attention"}
+
+    # one layer's K (or V) pool: nothing that large is computed or copied;
+    # what is left writes rows into the stacks where they lie
+    layer_pool = nb * bs * kv * d
+    large = _top_level_results(text, layer_pool)
+    writes = _in_place_writes(text, large)
+    assert [r for r in large if r not in writes] == []
+    assert len(writes) == (4 if family == "evabyte" else 2), writes
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < layer_pool * cache.k.dtype.itemsize / 4)
+
+    # every stack is handed back in the buffer it came in
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape.startswith(f"{layers},{nb},{bs},{kv}")]
+    assert len(stacks) == (4 if quantized else 2)
+    assert set(stacks) <= aliased, (stacks, header)
 
 
 # -- grouped GLU decode (MoE serving) at OLMoE's widths (ROADMAP R1): hidden

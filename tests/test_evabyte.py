@@ -171,9 +171,9 @@ def test_pallas_kernel_in_interpret_mode_equals_the_xla_path():
     table[KIND.ring:KIND.ring + 3] = [15, 4, 20]    # windows 0, 1 and "2"
     q_pos = np.array([length - 1, length - 3, W + 5, 2 * W + 5, 2 * W,
                       PAD_POSITION], np.int32)
-    args = (jnp.asarray(rng.randn(t, kv, d), jnp.float32), k_pool, v_pool,
-            jnp.asarray(pos), jnp.asarray(np.tile(table, (t, 1))),
-            jnp.asarray(q_pos))
+    args = (jnp.asarray(rng.randn(t, kv, d), jnp.float32), k_pool[None],
+            v_pool[None], jnp.asarray(pos),
+            jnp.asarray(np.tile(table, (t, 1))), jnp.asarray(q_pos), 0)
     kinds = KIND.column_kinds(np.tile(table, (t, 1)), np.arange(maxb),
                               q_pos[:, None], BS)
     assert kinds[0].tolist() == [0, 0, 0, 1, 1, 2, 2, 0, 0, 0]
@@ -189,6 +189,39 @@ def test_pallas_kernel_in_interpret_mode_equals_the_xla_path():
     np.testing.assert_array_equal(walk >= 0, kinds > 0)
     # a skipped step names the block of the live step before it
     assert (~walk[0, 7:]).tolist() == [4, 4, 4]
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
+def test_window_summaries_land_in_one_layer_and_the_sentinel_in_none(layer):
+    """``write_window_summaries`` on the stacks: a slot whose window
+    completes writes block ``(layer, dst)`` with what the pooling of
+    ``(layer, src)`` gives and changes no other layer; ``dst ==
+    num_blocks`` (every other slot) changes no byte of any layer."""
+    from neuronx_distributed_tpu.ops import eva_attention as eva
+
+    rng = np.random.RandomState(4)
+    layers, nb, kv, d = 3, 6, 2, 16
+    k, v = (jnp.asarray(rng.randn(layers, nb, BS, kv, d), jnp.float32)
+            for _ in range(2))
+    phi, mu = (jnp.asarray(rng.randn(kv, d), jnp.float32) for _ in range(2))
+    src = jnp.asarray([[4, 1, 3, 0], [0, 0, 0, 0]], jnp.int32)
+    write = jax.jit(lambda l, done, dst: eva.write_window_summaries(
+        k, v, l, (done, src, dst), phi, mu, C, 0.25))
+    new_k, new_v = write(jnp.int32(layer), True,
+                         jnp.asarray([5, nb], jnp.int32))
+    for new, pool, want in zip(
+            (new_k, new_v), (k, v), eva.chunk_summaries(
+                k[layer, src[0]].reshape(-1, kv, d),
+                v[layer, src[0]].reshape(-1, kv, d), phi, mu, C, 0.25)):
+        # the pooling fuses otherwise under jit: close, not bit for bit
+        np.testing.assert_allclose(new[layer, 5], want, atol=1e-6)
+        expect = np.asarray(pool).copy()
+        expect[layer, 5] = new[layer, 5]
+        np.testing.assert_array_equal(np.asarray(new), expect)
+    for done in (True, False):
+        dropped = write(jnp.int32(layer), done, jnp.full((2,), nb, jnp.int32))
+        np.testing.assert_array_equal(np.asarray(dropped[0]), np.asarray(k))
+        np.testing.assert_array_equal(np.asarray(dropped[1]), np.asarray(v))
 
 
 # -- (d) through ServingEngine -----------------------------------------------

@@ -82,13 +82,26 @@ def test_flat_write_indices_routes_pads_out_of_range():
     assert idx.tolist()[2] == nb * bs  # pad routed past the pool
 
 
-def test_write_pool_rows_drops_invalid_rows():
-    pool = jnp.zeros((2, 2, 3), jnp.float32)
-    rows = jnp.ones((2, 3), jnp.float32)
-    out = write_pool_rows(pool, rows, jnp.asarray([1, 4], jnp.int32))
-    out = np.asarray(out)
-    assert out[0, 1].tolist() == [1, 1, 1]
-    assert out.sum() == 3  # the index-4 (== capacity) row was dropped
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
+def test_write_pool_rows_writes_one_layer_and_drops_the_sentinel(layer):
+    """The stack is addressed by (layer, flat index): a kept row changes
+    its layer only, and the drop sentinel (``flat_idx == capacity``, one
+    layer's row count) changes no byte of any layer — as one run of
+    ``L * capacity`` rows it would be the next layer's first row."""
+    layers, nb, bs = 3, 2, 2
+    capacity = nb * bs
+    pool = jnp.arange(layers * capacity * 3, dtype=jnp.float32).reshape(
+        layers, nb, bs, 3)
+    rows = -jnp.ones((2, 3), jnp.float32)
+    write = jax.jit(write_pool_rows)         # the layer traced, as in a scan
+    out = np.asarray(write(pool, rows, jnp.asarray([1, capacity], jnp.int32),
+                           jnp.int32(layer)))
+    want = np.asarray(pool).copy()
+    want[layer, 0, 1] = -1
+    np.testing.assert_array_equal(out, want)
+    dropped = write(pool, rows, jnp.full((2,), capacity, jnp.int32),
+                    jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(dropped), np.asarray(pool))
 
 
 # ---------------------------------------------------------------------------
@@ -124,8 +137,9 @@ def _paged_case(rows, quantized=False, seed=1):
     rng = np.random.RandomState(seed)
     N, D, KV = 4, 16, 2
     q = jnp.asarray(rng.randn(len(rows), N, D).astype(np.float32))
-    k = jnp.asarray(rng.randn(_NB, _BS, KV, D).astype(np.float32))
-    v = jnp.asarray(rng.randn(_NB, _BS, KV, D).astype(np.float32))
+    # the pools are stacks of one layer
+    k = jnp.asarray(rng.randn(1, _NB, _BS, KV, D).astype(np.float32))
+    v = jnp.asarray(rng.randn(1, _NB, _BS, KV, D).astype(np.float32))
     pool_pos = np.full((_NB, _BS), PAD_POSITION, np.int32)
     for table in _TABLES.values():
         for col, blk in enumerate(table):
@@ -146,9 +160,9 @@ def _paged_case(rows, quantized=False, seed=1):
 def test_paged_attention_pallas_interpret_matches_xla(quantized, case):
     rows = _RAGGED_CASES[case]
     q, k, v, pp, tb, qp, ks, vs = _paged_case(rows, quantized)
-    ref = paged_attention(q, k, v, pp, tb, qp, k_scale=ks, v_scale=vs,
+    ref = paged_attention(q, k, v, pp, tb, qp, 0, k_scale=ks, v_scale=vs,
                           force_pallas=False)
-    ker = paged_attention(q, k, v, pp, tb, qp, k_scale=ks, v_scale=vs,
+    ker = paged_attention(q, k, v, pp, tb, qp, 0, k_scale=ks, v_scale=vs,
                           force_pallas=True)
     # a row with no mapped column: the kernel writes zeros, the reference
     # a uniform average over whatever block 0 holds; the engine drops both
@@ -156,6 +170,38 @@ def test_paged_attention_pallas_interpret_matches_xla(quantized, case):
     np.testing.assert_allclose(np.asarray(ker)[real], np.asarray(ref)[real],
                                rtol=1e-5, atol=1e-5)
     assert not np.asarray(ker)[~real].any()
+
+
+@pytest.mark.parametrize("kind", ["full", "int8", "window"])
+@pytest.mark.parametrize("impl", ["xla", "kernel"])
+def test_paged_attention_on_a_stack_equals_the_layers_own_pool(impl, kind):
+    """``paged_attention`` reads the stacks at (layer, block): with the
+    traced layer ``l`` it gives what the same call gives on ``pool[l]``
+    alone (a stack of that one layer), bit for bit, for every layer, from
+    the XLA reference and from the kernel (interpret mode) alike."""
+    layers = 3
+    rows = _RAGGED_CASES["ragged"] + _RAGGED_CASES["pad_row"]
+    cases = [_paged_case(rows, kind == "int8", seed=5 + l)
+             for l in range(layers)]
+    q, _, _, pp, tb, qp, _, _ = cases[0]
+    k, v, ks, vs = (
+        None if cases[0][i] is None else jnp.concatenate(
+            [c[i] for c in cases]) for i in (1, 2, 6, 7))
+    # a window of two blocks and a ring of three columns: the table's
+    # fourth column then holds summaries
+    kw = dict(force_pallas=impl == "kernel",
+              window=(2 * _BS, 3) if kind == "window" else None)
+    on_stack = jax.jit(lambda l: paged_attention(
+        q, k, v, pp, tb, qp, l, k_scale=ks, v_scale=vs, **kw))
+    for l in range(layers):
+        alone = paged_attention(
+            q, k[l:l + 1], v[l:l + 1], pp, tb, qp, 0,
+            k_scale=None if ks is None else ks[l:l + 1],
+            v_scale=None if vs is None else vs[l:l + 1], **kw)
+        np.testing.assert_array_equal(np.asarray(on_stack(jnp.int32(l))),
+                                      np.asarray(alone))
+    assert not np.array_equal(np.asarray(on_stack(jnp.int32(0))),
+                              np.asarray(on_stack(jnp.int32(2))))
 
 
 @pytest.mark.parametrize("rewire", ["other_block", "unmapped"])
@@ -172,7 +218,7 @@ def test_paged_attention_ignores_columns_behind_the_row(quantized, rewire):
                        np.asarray(tb))
     assert (rewired != np.asarray(tb)).any()
     out, out_rewired = (
-        paged_attention(q, k, v, pp, jnp.asarray(t, jnp.int32), qp,
+        paged_attention(q, k, v, pp, jnp.asarray(t, jnp.int32), qp, 0,
                         k_scale=ks, v_scale=vs, force_pallas=True)
         for t in (tb, rewired))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out_rewired))
@@ -223,9 +269,9 @@ def test_paged_attention_validates_scales_and_heads():
     q, k, v, pp, tb, qp, ks, vs = _paged_case(_RAGGED_CASES["ragged"], True,
                                               seed=2)
     with pytest.raises(ValueError):
-        paged_attention(q, k, v, pp, tb, qp, k_scale=ks)  # missing v_scale
+        paged_attention(q, k, v, pp, tb, qp, 0, k_scale=ks)  # no v_scale
     with pytest.raises(ValueError):
-        paged_attention(q[:, :3], k, v, pp, tb, qp)  # 3 heads vs 2 kv
+        paged_attention(q[:, :3], k, v, pp, tb, qp, 0)  # 3 heads vs 2 kv
 
 
 # ---------------------------------------------------------------------------

@@ -266,10 +266,10 @@ def test_paged_attention_invariant_under_shared_tables(force_pallas):
     pos = pos.at[7].set(pos[2])
     shared = jnp.asarray([[0, 2, -1], [1, 2, -1]], jnp.int32)
     private = jnp.asarray([[0, 2, -1], [1, 7, -1]], jnp.int32)
-    out_shared = paged_attention(q, k, v, pos, shared, q_pos,
+    out_shared = paged_attention(q, k[None], v[None], pos, shared, q_pos, 0,
                                  force_pallas=force_pallas)
-    out_private = paged_attention(q, k, v, pos, private, q_pos,
-                                  force_pallas=force_pallas)
+    out_private = paged_attention(q, k[None], v[None], pos, private, q_pos,
+                                  0, force_pallas=force_pallas)
     np.testing.assert_array_equal(np.asarray(out_shared),
                                   np.asarray(out_private))
 
