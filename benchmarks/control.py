@@ -116,14 +116,16 @@ def serve_control(cell, seeds, kinds) -> dict:
         say("control", seed=seed, what="program")
         seqs, got = serve.probe_logits(seed, mcfg, forward, params, ecfg, chk)
         weights = models.published(params, cell.config)
-        want = np.asarray(reference.forward(weights, seqs, cell.config)[0])
+        want = np.asarray(serve.reference_logits(
+            reference, weights, seqs, cell.config, chk)[0])
         out[seed] = {"program": readings(got, want, chk)}
         for kind in kinds:
             if kind == "tier-int8":     # below: it gives up the float weights
                 continue
             say("control", seed=seed, what=kind)
-            ctl = np.asarray(reference.forward(
-                lower_precision(weights, kind), seqs, cell.config)[0])
+            ctl = np.asarray(serve.reference_logits(
+                reference, lower_precision(weights, kind), seqs,
+                cell.config, chk)[0])
             out[seed][kind] = readings(ctl, want, chk)
         if "tier-int8" in kinds:
             say("control", seed=seed, what="tier-int8")

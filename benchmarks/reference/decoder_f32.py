@@ -87,9 +87,12 @@ def mixtral_block(x, weights, li, top_k):
     return y, logits
 
 
-def forward(weights, tokens, config):
+def forward(weights, tokens, config, positions=None):
     """Logits ``[B, S, V]`` (float32) for ``tokens [B, S]``; ``config`` is
-    the configuration file's dict (the published keys).
+    the configuration file's dict (the published keys). With ``positions``
+    (ascending indices into ``S``) the final norm and the head run on
+    those rows of the last layer's output only, and the logits are
+    ``[B, len(positions), V]``: every position still passes every layer.
 
     Also returns, for a mixture of experts, each layer's router margin
     ``[B, L, S]``: the gap between the last selected and the first
@@ -117,6 +120,8 @@ def forward(weights, tokens, config):
                 else:
                     y = swiglu(h, weights, li)
                 x = x + y
+            if positions is not None:
+                x = x[jnp.asarray(positions)]
             x = rms_norm(x, weights("final_norm"), eps)
             out.append(x @ weights("lm_head").T)
             if seq_margins:

@@ -118,10 +118,12 @@ def layer(x, weights, li, config):
     return x + (jax.nn.silu(gate) * up) @ weights("down", li).T
 
 
-def forward_all_heads(weights, tokens, config):
+def forward_all_heads(weights, tokens, config, positions=None):
     """Logits ``[B, S, P, V]`` (float32) for ``tokens [B, S]``: output
     ``j`` of the ``P = num_pred_heads`` at position ``t`` is over byte
-    ``t + 1 + j``."""
+    ``t + 1 + j``. With ``positions`` (ascending indices into ``S``) the
+    final norm and the head run on those rows of the last layer's output
+    only: ``[B, len(positions), P, V]``."""
     out = []
     with jax.default_matmul_precision("highest"):
         embedding = weights("embedding")
@@ -129,6 +131,8 @@ def forward_all_heads(weights, tokens, config):
             x = embedding[jnp.asarray(seq)]
             for li in range(config["num_hidden_layers"]):
                 x = layer(x, weights, li, config)
+            if positions is not None:
+                x = x[jnp.asarray(positions)]
             x = unit_offset_norm(x, weights("final_norm"),
                                  float(config["rms_norm_eps"]))
             logits = x @ weights("lm_head").T
@@ -137,10 +141,10 @@ def forward_all_heads(weights, tokens, config):
     return jnp.stack(out)
 
 
-def forward(weights, tokens, config):
-    """The next-byte head's logits ``[B, S, V]``, which serving samples,
-    and ``None`` (no router)."""
-    return forward_all_heads(weights, tokens, config)[:, :, 0], None
+def forward(weights, tokens, config, positions=None):
+    """The next-byte head's logits ``[B, S, V]``, which serving samples
+    (at ``positions`` only, where given), and ``None`` (no router)."""
+    return forward_all_heads(weights, tokens, config, positions)[:, :, 0], None
 
 
 def cross_entropy(logits, labels):

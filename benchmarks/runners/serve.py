@@ -355,16 +355,50 @@ def probe_schedule(prompt_len: int, decode: int, width: int
     return steps
 
 
+def compared_positions(chk) -> np.ndarray:
+    """The positions of a sequence at which the check compares logits,
+    ascending: all of them, or under ``logit_check.compare = {"every": n,
+    "tail": m}`` every ``n``-th prompt position, the last ``m`` prompt
+    positions and every decode position, so that a context of thousands
+    at a vocabulary of tens of thousands is not held as ``[2, S, V]``."""
+    plen, total = chk["prompt_tokens"], \
+        chk["prompt_tokens"] + chk["decode_steps"]
+    compare = chk.get("compare")
+    if compare is None:
+        return np.arange(total)
+    every, tail = int(compare["every"]), int(compare["tail"])
+    if every < 1 or tail < 0:
+        raise BenchError(f"logit_check.compare wants every >= 1 and "
+                         f"tail >= 0, got {compare}")
+    keep = np.zeros(total, bool)
+    keep[0:plen:every] = True
+    keep[max(plen - tail, 0):] = True
+    return np.flatnonzero(keep)
+
+
+def serving_cache(mcfg, params, ecfg):
+    """``(cache, kind)``: the cache the family is served from, as the
+    engine's own constructor builds it, and the cache kind whose columns
+    the engine maps. The harness builds neither."""
+    from neuronx_distributed_tpu.inference.engine import ServingEngine
+
+    kind = mcfg.serving_family().cache_kind.geometry(ecfg.block_size,
+                                                     ecfg.token_budget)
+    return ServingEngine(mcfg, params, ecfg).cache, kind
+
+
 def probe_logits(seed: int, mcfg, forward, params, ecfg, chk):
     """Two seeded sequences through the paged forward the engine's packed
-    step runs (same pool geometry, same width, prefill chunks beside
-    decode rows and pad rows): ``(tokens [2, S], logits [2, S, V])``."""
+    step runs (the engine's own cache, same width, prefill chunks beside
+    decode rows and pad rows; before a row runs, the columns its cache
+    kind names for its position are mapped to fresh blocks in order, as
+    the engine maps them): ``(tokens [2, S], logits [2, P, V])``, the
+    logits at the ``P`` positions of :func:`compared_positions`."""
     import functools
 
     import jax
     import jax.numpy as jnp
 
-    from neuronx_distributed_tpu.inference import paging
     from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
 
     plen, ndec, width = chk["prompt_tokens"], chk["decode_steps"], \
@@ -378,48 +412,67 @@ def probe_logits(seed: int, mcfg, forward, params, ecfg, chk):
                                 slot_ids=slot_ids)
         return logits[0].astype(jnp.float32), cache
 
-    cache = paging.init_paged_kv_cache(
-        mcfg.num_layers, ecfg.num_blocks, ecfg.block_size,
-        mcfg.num_kv_heads, mcfg.head_dim_, ecfg.max_slots,
-        ecfg.max_blocks_per_seq, dtype=ecfg.kv_dtype)
-    per_seq = -(-(plen + ndec) // ecfg.block_size)
-    table = np.full((ecfg.max_slots, ecfg.max_blocks_per_seq), -1, np.int32)
-    for s in (0, 1):
-        table[s, :per_seq] = np.arange(s * per_seq, (s + 1) * per_seq)
-    cache = cache.replace(block_tables=jnp.asarray(table))
-    got = np.zeros((2, plen + ndec, mcfg.vocab_size), np.float32)
+    cache, kind = serving_cache(mcfg, params, ecfg)
+    table = np.array(cache.block_tables)
+    mapped = 0
+    compared = compared_positions(chk)
+    row_of = np.full(plen + ndec, -1)
+    row_of[compared] = np.arange(compared.size)
+    got = np.zeros((2, compared.size, mcfg.vocab_size), np.float32)
     for rows in probe_schedule(plen, ndec, width):
         tok = np.zeros((1, width), np.int32)
         pos = np.full((1, width), PAD_POSITION, np.int32)
         slot = np.full((width,), ecfg.max_slots, np.int32)
+        before = mapped
         for i, (s, p) in enumerate(rows):
             tok[0, i], pos[0, i], slot[i] = seqs[s, p], p, s
+            for column in kind.columns_to_map(p, ecfg.block_size):
+                if table[s, column] < 0:
+                    table[s, column], mapped = mapped, mapped + 1
+        if mapped > ecfg.num_blocks:
+            raise BenchError(
+                f"the logit check's two sequences need {mapped} blocks "
+                f"and the pool has {ecfg.num_blocks}")
+        if mapped > before:
+            cache = cache.replace(block_tables=jnp.asarray(table))
         logits, cache = probe(params, cache, jnp.asarray(tok),
                               jnp.asarray(pos), jnp.asarray(slot))
         logits = np.asarray(logits)
         for i, (s, p) in enumerate(rows):
-            got[s, p] = logits[i]
+            if row_of[p] >= 0:
+                got[s, row_of[p]] = logits[i]
     del cache
     return seqs, got
 
 
+def reference_logits(reference, weights, seqs, config, chk):
+    """The reference's ``(logits [2, P, V], router margins or None)`` at
+    the compared positions. Without ``compare`` the call is the one every
+    reference has always taken, ``forward(weights, tokens, config)``."""
+    if chk.get("compare") is None:
+        return reference.forward(weights, seqs, config)
+    return reference.forward(weights, seqs, config,
+                             positions=compared_positions(chk))
+
+
 def logit_errors(got, want, chk):
-    """``(scale, err [2, S], parts)``: the largest logit difference at each
-    position in units of the reference logits' spread, so that one pair of
-    tolerances serves every width; ``parts`` are the prefill and the decode
-    positions' errors."""
-    plen = chk["prompt_tokens"]
+    """``(scale, err [2, P], parts)``: the largest logit difference at each
+    compared position in units of the reference logits' spread, so that
+    one pair of tolerances serves every width; ``parts`` are the prefill
+    and the decode positions' errors."""
+    prefill = int(np.sum(compared_positions(chk) < chk["prompt_tokens"]))
     scale = float(np.std(want))
-    err = np.abs(got - want).max(axis=-1) / scale      # [2, plen + ndec]
-    return scale, err, {"prefill": err[:, :plen].ravel(),
-                        "decode": err[:, plen:].ravel()}
+    err = np.abs(got - want).max(axis=-1) / scale      # [2, P]
+    return scale, err, {"prefill": err[:, :prefill].ravel(),
+                        "decode": err[:, prefill:].ravel()}
 
 
 def judge_logits(got, want, chk) -> List[str]:
-    """Hold logits ``[2, S, V]`` to the configuration's ``logit_check``
-    against the reference's: the reasons they fail it, if any. Each number
-    compared is printed beside its limit."""
-    plen = chk["prompt_tokens"]
+    """Hold logits ``[2, P, V]`` at the compared positions to the
+    configuration's ``logit_check`` against the reference's: the reasons
+    they fail it, if any. Each number compared is printed beside its
+    limit."""
+    compared = compared_positions(chk)
     scale, err, parts = logit_errors(got, want, chk)
     why = []
     for part, e in parts.items():
@@ -443,20 +496,25 @@ def judge_logits(got, want, chk) -> List[str]:
                        f"differ by more than {chk['outlier_rtol']} of the "
                        f"logits' spread (allowed "
                        f"{chk['outlier_share'][part]:.1%})")
-    say("check", rel_err_at_positions_0_1_2_3=[
-        round(float(x), 4) for x in err[0, :4]],
-        median_by_quarter=[round(float(np.median(q)), 4)
-                           for q in np.array_split(err[0, :plen], 4)])
+    # the prompt's quarters by position, whichever of them are compared
+    quarters = np.array_split(np.arange(chk["prompt_tokens"]), 4)
+    say("check", **{
+        "rel_err_at_positions_" + "_".join(map(str, compared[:4])): [
+            round(float(x), 4) for x in err[0, :4]]},
+        median_by_quarter=[
+            round(float(np.median(err[0, np.isin(compared, q)])), 4)
+            for q in quarters])
     return why
 
 
 def check_logits(cell, mcfg, forward, params, ecfg, settings) -> List[str]:
-    """Every position's logits of ``probe_logits`` against the plain
-    reference's full forward."""
+    """The compared positions' logits of ``probe_logits`` against the
+    plain reference's forward over the whole of both sequences."""
     chk = settings["logit_check"]
     seqs, got = probe_logits(cell.seed, mcfg, forward, params, ecfg, chk)
-    want, margins = models.reference(cell.config).forward(
-        models.published(params, cell.config), seqs, cell.config)
+    want, margins = reference_logits(
+        models.reference(cell.config),
+        models.published(params, cell.config), seqs, cell.config, chk)
     why = judge_logits(got, np.asarray(want), chk)
     if margins is not None:
         m = np.asarray(margins)
