@@ -40,8 +40,10 @@ from .model_builder import (ModelBuilder, NxDModel, bundle_generate,
                             register_serving_workers, serving_state_spec,
                             shard_checkpoint)
 from .paging import (BlockAllocator, CacheExhaustedError, PagedKVCache,
-                     PrefixCache, QuantizedPagedKVCache, cow_copy_blocks,
-                     init_paged_kv_cache, init_quantized_paged_kv_cache)
+                     PrefixCache, QuantizedPagedKVCache,
+                     SparseStatePagedCache, cow_copy_blocks,
+                     init_paged_kv_cache, init_quantized_paged_kv_cache,
+                     init_serving_cache)
 from .router import (FabricConfig, ReplicaRouter, RouterConfig, RouterResult,
                      RouterStats, ScalePolicy, ServingPreempted,
                      TenantPolicy, elastic_chaos_drill, fabric_chaos_drill)
@@ -57,7 +59,8 @@ __all__ = [
     "DECODE_BUCKETS", "decode_step", "generate", "pick_bucket", "prefill",
     "KVCache", "init_kv_cache",
     "BlockAllocator", "CacheExhaustedError", "PagedKVCache",
-    "PrefixCache", "QuantizedPagedKVCache", "cow_copy_blocks",
+    "PrefixCache", "QuantizedPagedKVCache", "SparseStatePagedCache",
+    "init_serving_cache", "cow_copy_blocks",
     "init_paged_kv_cache", "init_quantized_paged_kv_cache",
     "ServingEngine", "EngineConfig", "EngineStats", "RequestRejected",
     "RequestResult", "SessionTicket", "TICKET_MAGIC", "TicketWireError",

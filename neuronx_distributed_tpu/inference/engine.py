@@ -50,8 +50,7 @@ from .aot_cache import AotExecutableCache, AotWorker, source_fingerprint
 from .kv_cache import PAD_POSITION
 from .paging import (PAYLOAD_BLOCK_AXES, BlockAllocator, CacheExhaustedError,
                      PrefixCache, cow_copy_blocks, extract_blocks,
-                     flat_write_indices, init_paged_kv_cache,
-                     init_quantized_paged_kv_cache, inject_blocks,
+                     flat_write_indices, init_serving_cache, inject_blocks,
                      mask_pool_positions)
 from .sampling import SamplingConfig, sample
 from .speculative import (SpeculationConfig, branch_of_nodes,
@@ -678,11 +677,21 @@ class ServingEngine:
             np.int32)
         self._slot_blocks: List[List[int]] = (
             [[] for _ in range(engine_cfg.max_slots)])
-        #: skipped, exact and summary table columns of the rows packed
-        #: since the last publish (obs on only): ``nxd_paged_columns_total``
-        #: or, for a window-summary cache, ``nxd_eva_columns_total``
-        self._paged_cols = np.zeros((3,), np.int64)
-        self._windows_rolled = 0
+        #: what the attention kernel's walk did with the rows packed since
+        #: the last publish (obs on only). Skipped, exact and summary table
+        #: columns, counted on the host as the rows are packed:
+        #: ``nxd_paged_columns_total`` or, for a window-summary cache,
+        #: ``nxd_eva_columns_total``. A sparse-state cache's selections are
+        #: known to the device alone: the step leaves their six counts in
+        #: ``cache.counts`` and the fetch adds them here
+        #: (``nxd_sparse_columns_total``, ``nxd_sparse_positions_total``)
+        self._counts_on_device = self._cache_kind.name == "sparse_state"
+        self._paged_cols = np.zeros((6 if self._counts_on_device else 3,),
+                                    np.int64)
+        #: windows a window-summary cache rolled (``nxd_eva_windows_total``)
+        #: or rows at position 0 of a sparse-state cache, each of which
+        #: starts a slot's state anew (``nxd_state_resets_total``)
+        self._kind_events = 0
         #: summary blocks taken by the schedule for windows that the next
         #: step completes, ``(slot, column) -> block``: ``engine/roll``
         self._pending_roll: Dict[Tuple[int, int], int] = {}
@@ -754,18 +763,14 @@ class ServingEngine:
 
     def _init_cache(self):
         e, m = self.ecfg, self.model_cfg
-        # speculation widens the table with lane rows; the pool itself
-        # (num_blocks) is unchanged — lanes borrow blocks per round
-        if e.quantized:
-            cache = init_quantized_paged_kv_cache(
-                m.num_layers, self._pool_blocks, e.block_size,
-                m.num_kv_heads, m.head_dim_, self._table_rows,
-                e.max_blocks_per_seq)
-        else:
-            cache = init_paged_kv_cache(
-                m.num_layers, self._pool_blocks, e.block_size,
-                m.num_kv_heads, m.head_dim_, self._table_rows,
-                e.max_blocks_per_seq, dtype=e.kv_dtype or m.dtype)
+        # the family's cache kind builds what it is served from; speculation
+        # widens the table with lane rows, the pool itself (num_blocks) is
+        # unchanged: lanes borrow blocks per round
+        cache = init_serving_cache(
+            m, num_blocks=self._pool_blocks, block_size=e.block_size,
+            table_rows=self._table_rows,
+            max_blocks_per_seq=e.max_blocks_per_seq,
+            dtype=e.kv_dtype or m.dtype, quantized=e.quantized)
         # commit to the sharding the jitted step will leave its outputs
         # on (replicated over the active mesh, else the default device):
         # an uncommitted first-step cache has a different sharding key
@@ -805,15 +810,11 @@ class ServingEngine:
         if self._spec is None:
             return None
         e, d = self.ecfg, self._draft_cfg
-        if e.quantized:
-            dc = init_quantized_paged_kv_cache(
-                d.num_layers, e.num_blocks, e.block_size, d.num_kv_heads,
-                d.head_dim_, self._table_rows, e.max_blocks_per_seq)
-        else:
-            dc = init_paged_kv_cache(
-                d.num_layers, e.num_blocks, e.block_size, d.num_kv_heads,
-                d.head_dim_, self._table_rows, e.max_blocks_per_seq,
-                dtype=e.kv_dtype or d.dtype)
+        dc = init_serving_cache(
+            d, num_blocks=e.num_blocks, block_size=e.block_size,
+            table_rows=self._table_rows,
+            max_blocks_per_seq=e.max_blocks_per_seq,
+            dtype=e.kv_dtype or d.dtype, quantized=e.quantized)
         return jax.device_put(dc, self._sharding)
 
     def _cp_cache_specs(self):
@@ -1745,7 +1746,7 @@ class ServingEngine:
         for (slot, col), blk in self._pending_roll.items():
             self._tables[slot, col] = blk
         if get_registry().enabled:
-            self._windows_rolled += len(self._pending_roll)
+            self._kind_events += len(self._pending_roll)
         self._pending_roll.clear()
 
     def _map_column(self, req: _RequestState, blk_i: int,
@@ -1944,7 +1945,11 @@ class ServingEngine:
                 tokens[0, i] = tok
                 positions[0, i] = pos
                 slot_ids[i] = req.slot
-            if get_registry().enabled:
+            counted = get_registry().enabled
+            by_device = counted and self._counts_on_device
+            if by_device:
+                self._kind_events += int(np.sum(positions == 0))
+            elif counted:
                 # what the paged kernel's walk finds in this batch; a pad
                 # row reads the last table row, as the forward's clip does
                 tbl = self._tables[np.minimum(slot_ids,
@@ -1963,9 +1968,15 @@ class ServingEngine:
                 sampled, self.cache = fn(
                     self.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(positions), jnp.asarray(slot_ids), rng)
+            if by_device:
+                # on its way while the host waits for the tokens
+                self.cache.counts.copy_to_host_async()
         with tracer.span(span + "/fetch"):
             # the host blocks here until the device has finished the step
-            return np.asarray(sampled)
+            sampled = np.asarray(sampled)
+            if by_device:
+                self._paged_cols += np.asarray(self.cache.counts)
+            return sampled
 
     def _maybe_insert_prefix(self, req: _RequestState) -> None:
         """Publish this request's fully-written prompt blocks into the
@@ -2333,7 +2344,33 @@ class ServingEngine:
                 "filled them: a decoding slot's token, a prefill chunk's "
                 "token, or padding.",
                 labels=("kind",))
-            if self._cache_kind.ring is None:
+            if self._counts_on_device:
+                cols_c = reg.counter(
+                    "nxd_sparse_columns_total",
+                    "Grid steps of the sparse_paged_attention kernel's walk "
+                    "(rows x K/V groups x walk width, summed over the "
+                    "sparse layers) by what is in them: a pool block the "
+                    "selection picked, one the first blocks or the local "
+                    "window forced, one of a row below the dense "
+                    "threshold, or nothing (skipped). Counted on the "
+                    "device, fetched with the step's tokens.",
+                    labels=("kind",))
+                events_c = reg.counter(
+                    "nxd_state_resets_total",
+                    "Packed rows at position 0: each starts its slot's "
+                    "lightning states from zero inside the step.")
+                pos_c = reg.counter(
+                    "nxd_sparse_positions_total",
+                    "Causal positions of the packed rows (x K/V groups x "
+                    "sparse layers) by whether the selection attended "
+                    "them.",
+                    labels=("kind",))
+                cols_by_kind = tuple(
+                    [cols_c.labels(kind=k) for k in
+                     ("selected", "forced", "dense", "skipped")]
+                    + [pos_c.labels(kind=k) for k in
+                       ("attended", "skipped")])
+            elif self._cache_kind.ring is None:
                 cols_c = reg.counter(
                     "nxd_paged_columns_total",
                     "Table columns of the serving workers' rows by what "
@@ -2341,7 +2378,8 @@ class ServingEngine:
                     "and not wholly behind the row's position) is "
                     "computed, skipped is not.",
                     labels=("kind",))
-                col_kinds, windows_c = ("skipped", "live"), None
+                cols_by_kind, events_c = tuple(
+                    cols_c.labels(kind=k) for k in ("skipped", "live")), None
             else:
                 cols_c = reg.counter(
                     "nxd_eva_columns_total",
@@ -2350,8 +2388,9 @@ class ServingEngine:
                     "rows of the row's own window, an earlier window's "
                     "chunk summaries, or nothing (skipped).",
                     labels=("kind",))
-                col_kinds = ("skipped", "exact", "summary")
-                windows_c = reg.counter(
+                cols_by_kind = tuple(cols_c.labels(kind=k) for k in
+                                     ("skipped", "exact", "summary"))
+                events_c = reg.counter(
                     "nxd_eva_windows_total",
                     "Windows whose last position was in a packed step: "
                     "summarised into a block of the pool by that step.")
@@ -2364,10 +2403,9 @@ class ServingEngine:
                 step_h,
                 tuple(rows_c.labels(kind=k)
                       for k in ("decode", "prefill", "pad")),
-                tuple(cols_c.labels(kind=k) for k in col_kinds),
-                windows_c)
+                cols_by_kind, events_c)
         (_, _, fields, free_g, step_h, rows_by_kind, cols_by_kind,
-         windows_c) = cache
+         events_c) = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
@@ -2379,9 +2417,9 @@ class ServingEngine:
         for child, n in zip(cols_by_kind, self._paged_cols):
             child.inc(int(n))
         self._paged_cols[:] = 0
-        if windows_c is not None:
-            windows_c.inc(self._windows_rolled)
-        self._windows_rolled = 0
+        if events_c is not None:
+            events_c.inc(self._kind_events)
+        self._kind_events = 0
 
     def _retire(self, req: _RequestState, now: float) -> None:
         self._release(req)

@@ -37,9 +37,10 @@ class CacheExhaustedError(RuntimeError):
 
 # ---------------------------------------------------------------------------
 # Cache kinds: where a sequence's positions live in its row of the block
-# table. The engine (block mapping, admission, counters), the pool writes
-# and the paged kernel's walk ask the model family's kind; none of them
-# divides by block_size itself.
+# table, and what the family is served from (``init_cache``: the pytree
+# :func:`init_serving_cache` hands the engine). The engine (block mapping,
+# admission, counters), the pool writes and the paged kernel's walk ask
+# the model family's kind; none of them divides by block_size itself.
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +80,9 @@ class FullCache:
         from ..ops.paged_attention import column_live
 
         return column_live(entry, column, q_pos, block_size) * 1
+
+    def init_cache(self, model_cfg, **geometry):
+        return _uniform_pool_cache(model_cfg, **geometry)
 
 
 FULL_CACHE = FullCache()
@@ -156,6 +160,62 @@ class WindowSummaryCache:
 
         return window_column_kinds(entry, column, q_pos, block_size,
                                    self.window, self.ring)
+
+    def init_cache(self, model_cfg, **geometry):
+        return _uniform_pool_cache(model_cfg, **geometry)
+
+
+@dataclasses.dataclass(frozen=True)
+class SparseStateCache(FullCache):
+    """A cache that differs by layer kind, three kinds of leaf behind one
+    block table and one allocator: paged K/V for the ``sparse_layers``
+    block-sparse layers only (position ``p`` in column ``p //
+    block_size``, as a full cache), a side pool of their compressed keys
+    indexed by the same block ids (a freed block frees its compressed
+    keys), and a float32 state per table row for each of the
+    ``state_layers`` lightning layers, which is no block at all: a row at
+    position 0 starts its slot's state from zero inside the step
+    (:func:`..ops.lightning_attention.lightning_attention_packed`), so a
+    preempted and re-admitted request inherits nothing."""
+
+    sparse_layers: int = 0
+    state_layers: int = 0
+    #: positions a compressed key advances by, and a selection block
+    stride: int = 16
+    select_block: int = 64
+    name = "sparse_state"
+
+    def geometry(self, block_size: int, step_rows: int = 0
+                 ) -> "SparseStateCache":
+        if block_size % self.select_block or block_size % self.stride:
+            raise ValueError(
+                f"sparse_state cache: pool blocks of {block_size} positions "
+                f"must be whole selection blocks of {self.select_block} and "
+                f"whole strides of {self.stride}")
+        return self
+
+    def init_cache(self, model_cfg, *, num_blocks: int, block_size: int,
+                   table_rows: int, max_blocks_per_seq: int, dtype: Any,
+                   quantized: bool = False) -> "SparseStatePagedCache":
+        if quantized:
+            raise ValueError("a sparse_state cache has no int8 pool: the "
+                             "selection over one is another kernel")
+        kv, d = model_cfg.num_kv_heads, model_cfg.head_dim_
+        pool = (self.sparse_layers, num_blocks, kv, block_size, d)
+        heads = model_cfg.state_heads
+        return SparseStatePagedCache(
+            k=jnp.zeros(pool, dtype), v=jnp.zeros(pool, dtype),
+            ck=jnp.zeros((self.sparse_layers,
+                          num_blocks * (block_size // self.stride), kv * d),
+                         dtype),
+            state=jnp.zeros((self.state_layers, heads, table_rows, d, d),
+                            jnp.float32),
+            counts=jnp.zeros((6,), jnp.int32),
+            pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
+            block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
+                                  jnp.int32),
+            lengths=jnp.zeros((table_rows,), jnp.int32),
+            block_size=block_size)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -238,6 +298,48 @@ class QuantizedPagedKVCache(struct.PyTreeNode):
         return self.block_tables.shape[1]
 
 
+class SparseStatePagedCache(struct.PyTreeNode):
+    """The cache of :class:`SparseStateCache`. ``k``/``v`` ``[Ls,
+    num_blocks, KV, block_size, D]`` over the ``Ls`` block-sparse layers
+    (heads before slots: with two K/V heads a minor pair ``(KV, D)`` would
+    be padded to the 16-row tile, eight times its bytes); ``ck`` ``[Ls,
+    num_blocks * block_size / stride, KV * D]`` their compressed keys,
+    entry ``b * block_size / stride + i`` the kernel that starts at slot
+    ``stride * i`` of block ``b``; ``state`` ``[Ll, H, table rows, D, D]``
+    float32 over the ``Ll`` lightning layers (a slot's ``S^T`` a head:
+    :func:`..ops.lightning_attention.lightning_attention_packed`); ``counts [6]`` what the last
+    step's selections attended
+    (:data:`..ops.sparse_attention.COUNT_KINDS`, summed over the sparse
+    layers); ``pos``, ``block_tables`` and ``lengths`` as
+    :class:`PagedKVCache`."""
+
+    k: jax.Array
+    v: jax.Array
+    ck: jax.Array
+    state: jax.Array
+    counts: jax.Array
+    pos: jax.Array
+    block_tables: jax.Array
+    lengths: jax.Array
+    block_size: int = struct.field(pytree_node=False, default=128)
+
+    @property
+    def num_blocks(self) -> int:
+        return self.k.shape[1]
+
+    @property
+    def capacity(self) -> int:
+        return self.k.shape[1] * self.k.shape[3]
+
+    @property
+    def max_slots(self) -> int:
+        return self.block_tables.shape[0]
+
+    @property
+    def max_blocks_per_seq(self) -> int:
+        return self.block_tables.shape[1]
+
+
 class PagedCacheView(struct.PyTreeNode):
     """The pool's whole stacks, the layer the holder is at, and this
     step's routing arrays, threaded through ``LlamaDecoderLayer`` in
@@ -263,6 +365,34 @@ class PagedCacheView(struct.PyTreeNode):
     tables: jax.Array
     write_idx: jax.Array
     roll: Any = None
+
+
+class SparseLayerView(struct.PyTreeNode):
+    """What a block-sparse layer of a :class:`SparseStatePagedCache` is
+    handed in :class:`PagedCacheView`'s place: the K/V and compressed-key
+    stacks and the running ``counts`` (the layer scan's carry), the
+    layer's index in them, the per-token block tables, the flat write
+    indices and the rows' true positions (PAD_POSITION for padding)."""
+
+    k: jax.Array
+    v: jax.Array
+    ck: jax.Array
+    counts: jax.Array
+    layer: jax.Array
+    tables: jax.Array
+    write_idx: jax.Array
+    q_pos: jax.Array
+
+
+class StateLayerView(struct.PyTreeNode):
+    """What a lightning layer is handed: the per-slot state stack (the
+    layer scan's carry), the layer's index in it, and the rows' slots and
+    true positions."""
+
+    state: jax.Array
+    layer: jax.Array
+    slot_ids: jax.Array
+    q_pos: jax.Array
 
 
 class CPPrefillView(struct.PyTreeNode):
@@ -350,6 +480,34 @@ def init_quantized_paged_kv_cache(num_layers: int, num_blocks: int,
                               jnp.int32),
         lengths=jnp.zeros((max_slots,), jnp.int32),
         block_size=block_size)
+
+
+def _uniform_pool_cache(model_cfg, *, num_blocks: int, block_size: int,
+                        table_rows: int, max_blocks_per_seq: int, dtype: Any,
+                        quantized: bool = False):
+    """One uniform K/V pool over every layer, float or int8."""
+    shape = (model_cfg.num_layers, num_blocks, block_size,
+             model_cfg.num_kv_heads, model_cfg.head_dim_, table_rows,
+             max_blocks_per_seq)
+    if quantized:
+        return init_quantized_paged_kv_cache(*shape)
+    return init_paged_kv_cache(*shape, dtype=dtype)
+
+
+def init_serving_cache(model_cfg, *, num_blocks: int, block_size: int,
+                       table_rows: int, max_blocks_per_seq: int, dtype: Any,
+                       quantized: bool = False):
+    """The cache a model family is served from, built by its cache kind
+    (``model_cfg.serving_family().cache_kind``) from the model config and
+    the engine's geometry: what :class:`.engine.ServingEngine` holds, and
+    its draft model's pool. A full and a window-summary cache are
+    :func:`init_paged_kv_cache`'s (``quantized``:
+    :func:`init_quantized_paged_kv_cache`'s) pytree; a
+    :class:`SparseStateCache` is a :class:`SparseStatePagedCache`."""
+    return model_cfg.serving_family().cache_kind.init_cache(
+        model_cfg, num_blocks=num_blocks, block_size=block_size,
+        table_rows=table_rows, max_blocks_per_seq=max_blocks_per_seq,
+        dtype=dtype, quantized=quantized)
 
 
 # ---------------------------------------------------------------------------

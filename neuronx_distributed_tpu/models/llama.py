@@ -88,6 +88,9 @@ def _quant_lm_head(cfg: "LlamaConfig", gather_output: bool, name=None):
         param_dtype=cfg.param_dtype, **kw)
 
 
+ATTENTION_KINDS = ("full", "eva", "sparse", "lightning")
+
+
 @dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
@@ -180,11 +183,27 @@ class LlamaConfig:
     # streams `chunk`-token slices through head-matmul + CE so [B, S, V]
     # logits never materialise. None = classic full-logits path.
     loss_chunk: Optional[int] = None
-    # attention kind of every layer: "full" (causal softmax over every
-    # earlier position) or "eva" (exact inside the query's window, chunk
+    # attention kind of a layer: "full" (causal softmax over every
+    # earlier position), "eva" (exact inside the query's window, chunk
     # summaries of the earlier windows: ops/eva_attention.py; the config
-    # then carries window_size and chunk_size, models/evabyte.py)
+    # then carries window_size and chunk_size, models/evabyte.py),
+    # "sparse" (exact softmax over the blocks a top-k selection over
+    # compressed keys picks: ops/sparse_attention.py; the config carries
+    # ``sparse``, its SparseSpec) or "lightning" (a decayed outer-product
+    # state: ops/lightning_attention.py). A model whose layers differ in
+    # kind (models/minicpm_sala.py) derives one config a kind.
     attention_kind: str = "full"
+    # per-head RMSNorm of q and k before the rotary embedding
+    qk_norm: bool = False
+    # rotary position embedding on q and k
+    use_rope: bool = True
+    # attention output times sigmoid(o_gate(x)) ahead of o_proj
+    attn_output_gate: bool = False
+    # per-head RMSNorm of the attention output, ahead of the gate
+    attn_output_norm: bool = False
+    # what a layer's two residual branches are multiplied by (muP depth
+    # scaling); 1.0 multiplies nothing
+    residual_scale: float = 1.0
     # carry the residual stream between layers in float32 (the adds run
     # in float32; norms, projections and the MLP still in ``dtype``)
     residual_fp32: bool = False
@@ -204,9 +223,9 @@ class LlamaConfig:
         return LlamaMLP(self, tp_sync=tp_sync, name="mlp")(h), None
 
     def __post_init__(self) -> None:
-        if self.attention_kind not in ("full", "eva"):
+        if self.attention_kind not in ATTENTION_KINDS:
             raise ValueError(
-                f"attention_kind must be 'full' or 'eva', got "
+                f"attention_kind must be one of {ATTENTION_KINDS}, got "
                 f"{self.attention_kind!r}")
         if self.cp_attn_impl not in ("ring", "ring_pallas", "ulysses"):
             raise ValueError(
@@ -436,6 +455,47 @@ def _eva_attend(cfg: LlamaConfig, q, k, v, positions, cache, phi, mu):
     return out.astype(cfg.dtype), cache.replace(k=new_k, v=new_v)
 
 
+def _sparse_attend(cfg: LlamaConfig, q, k, v, view):
+    """Block-sparse attention of one layer. No cache: the whole sequence
+    under the selection's mask. Paged: write this step's K/V rows and the
+    compressed keys of the kernels they complete into the view's layer,
+    then select and attend through the table."""
+    import math as _math
+
+    from ..ops import sparse_attention as sp
+
+    scale = 1.0 / _math.sqrt(q.shape[-1])
+    if view is None:
+        return sp.sparse_attention_full(q, k, v, cfg.sparse, scale).astype(
+            cfg.dtype), None
+    new_k = sp.write_sparse_rows(view.k, k[0], view.write_idx, view.layer)
+    new_v = sp.write_sparse_rows(view.v, v[0], view.write_idx, view.layer)
+    new_ck = sp.write_compressed_keys(view.ck, new_k, view.layer,
+                                      view.tables, view.q_pos, cfg.sparse)
+    out, counts = sp.sparse_paged_attention(
+        q[0], new_k, new_v, new_ck, view.layer, view.tables, view.q_pos,
+        cfg.sparse, scale=scale, force_pallas=cfg.attn_force_pallas)
+    return out[None].astype(cfg.dtype), view.replace(
+        k=new_k, v=new_v, ck=new_ck, counts=view.counts + counts)
+
+
+def _lightning_attend(cfg: LlamaConfig, q, k, v, view):
+    """Lightning attention of one layer. No cache: the whole sequence in
+    chunks. Paged: the packed rows continue and advance their slots'
+    states in the view's layer of the state stack."""
+    import math as _math
+
+    from ..ops import lightning_attention as la
+
+    scale = 1.0 / _math.sqrt(q.shape[-1])
+    if view is None:
+        return la.lightning_attention_full(q, k, v, scale), None
+    out, state = la.lightning_attention_packed(
+        q[0], k[0], v[0], view.state, view.layer, view.slot_ids,
+        view.q_pos, scale)
+    return out[None], view.replace(state=state)
+
+
 class LlamaAttention(nn.Module):
     """Attention with optional KV cache for autoregressive decode.
 
@@ -491,10 +551,18 @@ class LlamaAttention(nn.Module):
         q = q.reshape(b, s, n_q_local, head_dim)
         k = k.reshape(b, s, n_kv_local, head_dim)
         v = v.reshape(b, s, n_kv_local, head_dim)
-        q = attn_mod.apply_rotary(q, cos, sin, positions)
-        k = attn_mod.apply_rotary(k, cos, sin, positions)
+        if cfg.qk_norm:
+            q = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="q_norm")(q)
+            k = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="k_norm")(k)
+        if cfg.use_rope:
+            q = attn_mod.apply_rotary(q, cos, sin, positions)
+            k = attn_mod.apply_rotary(k, cos, sin, positions)
         new_cache = None
-        if cfg.attention_kind == "eva":
+        if cfg.attention_kind in ("sparse", "lightning"):
+            attend = (_sparse_attend if cfg.attention_kind == "sparse"
+                      else _lightning_attend)
+            out, new_cache = attend(cfg, q, k, v, cache)
+        elif cfg.attention_kind == "eva":
             # learned per-head pooling vectors (adaptive_phi,
             # adaptive_mu_k); the attention itself is ops/eva_attention.py
             # and, over the pool, the paged kernel with both masks
@@ -616,7 +684,15 @@ class LlamaAttention(nn.Module):
                 out = attn_mod.sdpa_reference(q, k, v, causal=True,
                                               dropout_p=dropout_p,
                                               dropout_seed=dropout_seed)
+        if cfg.attn_output_norm:
+            out = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="o_norm")(out)
         out = out.reshape(b, s, n_q_local * head_dim)
+        if cfg.attn_output_gate:
+            gate = pl.ColumnParallelLinear(
+                features=cfg.num_heads * head_dim, use_bias=False,
+                gather_output=False, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="o_gate")(x)
+            out = out.astype(cfg.dtype) * jax.nn.sigmoid(gate)
         if cfg.weight_quant is not None and cfg.weight_quant.startswith(
                 "mx"):
             from ..quantization.mx_layers import MXQuantizedRowParallel
@@ -811,11 +887,15 @@ class LlamaDecoderLayer(nn.Module):
         new_cache = None
         if cache is not None:
             attn_out, new_cache = attn_out
+        if cfg.residual_scale != 1.0:
+            attn_out = attn_out * cfg.residual_scale
         x = x + attn_out
         h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
                     sequence_parallel=cfg.sequence_parallel,
                     name="post_norm")(x)
         ff_out, aux = cfg.feed_forward(h, self.tp_sync)
+        if cfg.residual_scale != 1.0:
+            ff_out = ff_out * cfg.residual_scale
         return x + ff_out, aux, new_cache
 
 

@@ -270,6 +270,108 @@ def test_paged_forward_writes_the_donated_pool_in_place(chip, on_one_chip,
     assert set(stacks) <= aliased, (stacks, header)
 
 
+# -- the sparse-state cache (MiniCPM-SALA): the selection is the walk ----------
+# The cell minicpm-sala.serve-longdocs: 128 rows, 32 query heads in 2 groups
+# over 2 K/V heads of 128, 264 table columns, 4,224 blocks of 128.
+
+def test_sparse_paged_attention(chip):
+    from neuronx_distributed_tpu.ops import sparse_attention as sp
+
+    tokens, groups, rep, d, bs, cols, nb, layers = 128, 2, 16, 128, 128, \
+        264, 4224, 4
+    spec = sp.SparseSpec()
+    assert spec.walk_width(bs, cols) == 64
+    pool = chip((layers, nb, groups, bs, d), jnp.bfloat16)
+    fn = functools.partial(sp._sparse_paged_pallas, spec=spec,
+                           scale=1.0 / math.sqrt(d), interpret=False)
+    text = _assert_kernel_compiles(
+        lambda q, k, v, layer, tables, q_pos, sel: fn(
+            q, k, v, layer, tables, q_pos, sel),
+        chip((tokens, groups, rep, d), jnp.bfloat16), pool, pool,
+        chip((), jnp.int32), chip((tokens, cols), jnp.int32),
+        chip((tokens,), jnp.int32),
+        chip((tokens, groups, cols * bs // spec.block), jnp.bool_))
+    assert _kernel_instruction_names(text) == {"sparse_paged_attention"}
+
+
+def test_sparse_state_forward_writes_its_stacks_in_place(chip, on_one_chip):
+    """The cell's layer pattern, widths and cache geometry (a narrow
+    vocabulary): runs of 1, 6, 2, 4, 1 and 2 like layers, each a scan over
+    the layer's index. No K/V, compressed-key or state stack is copied or
+    changes layout on the way through the runs, no layer's weights are
+    sliced out of their stack (a run of one layer included), and every
+    stack is handed back in the buffer it came in."""
+    import re
+
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.inference import paging
+    from neuronx_distributed_tpu.models import minicpm_sala
+
+    nb, bs, slots, cols, tokens, inter = 4224, 128, 16, 264, 128, 16384
+    cfg = minicpm_sala.MiniCPMSALAConfig(
+        hidden_size=4096, intermediate_size=inter, num_layers=16,
+        vocab_size=512, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        mixer_types=minicpm_sala.PUBLISHED_MIXERS[9:25])
+    assert [r[2] for r in cfg.runs()] == [1, 6, 2, 4, 1, 2]
+    model = minicpm_sala.MiniCPMSALAForCausalLM(cfg)
+    forward = cfg.serving_family().forward
+    abstract = functools.partial(
+        jax.tree_util.tree_map, lambda x: chip(x.shape, x.dtype))
+    params = abstract(meta.unbox(jax.eval_shape(
+        model.init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))
+    cache = abstract(jax.eval_shape(lambda: paging.init_serving_cache(
+        cfg, num_blocks=nb, block_size=bs, table_rows=slots,
+        max_blocks_per_seq=cols, dtype=jnp.bfloat16)))
+
+    def step(params, cache, tokens, positions, slot_ids):
+        return forward(cfg, params, tokens, positions, cache,
+                       slot_ids=slot_ids)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"sparse_paged_attention"}
+
+    # of the results one layer's states large or larger: none is a
+    # layer's weights sliced out of their stack; a K/V stack is only ever
+    # the scatter that writes rows where they lie, the state stack the
+    # update of one layer's states where they lie
+    large = _top_level_results(text, 32 * 16 * 128 * 128)
+    writes = _in_place_writes(text, large)
+    assert not [r for r in large if str(inter) in r[2]], large
+    kv_stack, state_stack = f"bf16[4,{nb * 2 * bs},128]", \
+        "f32[12,32,16,128,128]"
+    assert [r for r in large if r[2] == kv_stack] == [
+        r for r in writes if r[2] == kv_stack]
+    assert sum(r[2] == kv_stack for r in writes) == 6   # K and V, 3 runs
+    assert sum(r[2] == state_stack for r in large) == 3     # 3 runs
+    assert all("dynamic-update-slice" in r[1] for r in large
+               if r[2] == state_stack)
+    # the compressed keys (66 MiB) are written in place too; what
+    # else names their stack is the compiler's own prefetch of it into
+    # VMEM ahead of the gather (asynchronous, sliced), not a change of
+    # layout
+    ck_stack = f"bf16[4,{nb * 8},256]"
+    assert sum(r[2] == ck_stack for r in writes) == 3
+    assert {r[0] for r in large if r[2] == ck_stack and r not in writes
+            } <= {"copy-start", "copy-done", "slice-start", "slice-done",
+                  "custom-call"}
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < cache.k.size * 2 / 4)          # one layer of K
+
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape in (f"4,{nb},2,{bs},128", f"4,{nb * 8},256",
+                     f"12,32,{slots},128,128")]
+    assert len(stacks) == 4
+    assert set(stacks) <= aliased, (stacks, header)
+
+
 # -- grouped GLU decode (MoE serving) at OLMoE's widths (ROADMAP R1): hidden
 # 2048, expert width 1024. Mixtral's 4096 compiles too, in about ten seconds.
 

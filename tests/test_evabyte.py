@@ -129,11 +129,14 @@ def test_a_short_sequence_pads_to_chunks_not_to_a_window():
 
 @pytest.mark.parametrize("impl", ["xla", "pallas-interpret"])
 def test_paged_forward_matches_the_reference_across_window_ends(impl):
-    """The harness's own probe: its table convention (consecutive blocks
-    in every column a length needs, more than the ring layout uses),
-    prefill in 8-row and then unaligned 7-row chunks beside a decode row
-    and pad rows, then decode; 105 positions cross three window ends, the
-    second sequence's inside a chunk."""
+    """The harness's own probe: the engine's own cache, and before a row
+    runs the columns the cache kind names for its position
+    (``columns_to_map``: the ring's column and, where the row completes a
+    window, that window's summary column) mapped to fresh blocks in order,
+    as ``_ensure_block`` maps them; prefill in 8-row and then unaligned
+    7-row chunks beside a decode row and pad rows, then decode; 105
+    positions cross three window ends, the second sequence's inside a
+    chunk."""
     cfg, _, forward, params = _model(
         attn_force_pallas=impl == "pallas-interpret")
     assert pa.paged_attention_impl(cfg.head_dim_, BS,

@@ -1,0 +1,418 @@
+"""MiniCPM-SALA (block-sparse layers beside lightning layers) through the
+model, the paged forward, the Pallas kernel and ``ServingEngine``, against
+the benchmark's plain reference ``benchmarks/reference/minicpm_sala_f32.py``.
+
+Tiny widths: hidden 64, 4 query heads of 16 over 2 K/V heads (sparse
+layers) or 4 (lightning layers), six layers in runs of 1, 2, 2, 1, pool
+blocks of 16; the selection scaled down (kernel 4, stride 2, blocks of 8,
+top 6 with 1 first block and a window of 16, dense below 64), so that a
+sequence of a hundred positions crosses the dense threshold and selects.
+The weights are seeded with norm multipliers of order one.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from flax.core import meta
+
+from neuronx_distributed_tpu import obs
+from neuronx_distributed_tpu.inference import paging
+from neuronx_distributed_tpu.inference.engine import (EngineConfig,
+                                                      ServingEngine)
+from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
+from neuronx_distributed_tpu.inference.speculative import SpeculationConfig
+from neuronx_distributed_tpu.ops import lightning_attention as la
+from neuronx_distributed_tpu.ops import paged_attention as pa
+from neuronx_distributed_tpu.ops import sparse_attention as sp
+from neuronx_distributed_tpu.parallel import mesh as ps
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.join(os.path.dirname(HERE), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import harness  # noqa: E402  (benchmarks/)
+from runners import serve  # noqa: E402
+
+BS = 16
+SPARSE = dict(kernel=4, stride=2, block=8, topk=6, init_blocks=1, window=16,
+              dense_len=64)
+SPEC = sp.SparseSpec(**SPARSE)
+MIXERS = ["minicpm4", "lightning-attn", "lightning-attn", "minicpm4",
+          "minicpm4", "lightning-attn"]
+PUBLISHED = dict(
+    vocab_size=256, hidden_size=64, intermediate_size=128,
+    num_hidden_layers=6, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=16, lightning_head_dim=16, lightning_nh=4, lightning_nkv=4,
+    lightning_use_rope=True, attn_use_rope=False, qk_norm=True,
+    attn_use_output_gate=True, rope_theta=1e4, rms_norm_eps=1e-6,
+    max_position_embeddings=4096, scale_emb=12, scale_depth=1.4,
+    dim_model_base=256, mixer_types=MIXERS, sparse=SPARSE,
+    reduced={"num_hidden_layers": {"from": 32, "to": 6}},
+    family="minicpm_sala", reference="minicpm_sala_f32")
+
+
+def _model(**kw):
+    ps.initialize_model_parallel()
+    family = harness.load_plugin("families", "minicpm_sala")
+    cfg, model, forward = family.build(
+        PUBLISHED, dtype=jnp.float32, param_dtype=jnp.float32, **kw)
+    shapes = meta.unbox(model.init(jax.random.key(0),
+                                   jnp.zeros((1, 8), jnp.int32)))
+
+    def draw(path, x):
+        name = jax.tree_util.keystr(path)
+        key = jax.random.fold_in(jax.random.key(5),
+                                 sum(map(ord, name)) % 2 ** 31)
+        noise = jax.random.normal(key, x.shape, x.dtype)
+        if name.endswith("['scale']"):
+            return 1.0 + 0.3 * noise
+        return 0.08 * noise
+
+    return cfg, model, forward, jax.tree_util.tree_map_with_path(draw,
+                                                                 shapes)
+
+
+def _reference(params):
+    return (harness.load_plugin("reference", "minicpm_sala_f32"),
+            harness.load_plugin("families", "minicpm_sala").published(
+                params, PUBLISHED))
+
+
+def _ecfg(**kw):
+    base = dict(block_size=BS, num_blocks=40, max_slots=3,
+                max_blocks_per_seq=12, token_budget=16,
+                kv_dtype=jnp.float32)
+    base.update(kw)
+    return EngineConfig(**base)
+
+
+def _greedy_by_reference(params, prompt, tokens):
+    ref, weights = _reference(params)
+    logits, _ = ref.forward(weights, np.asarray([prompt + tokens]),
+                            PUBLISHED)
+    return np.argmax(np.asarray(logits)[0, len(prompt) - 1:-1], -1).tolist()
+
+
+# -- (a) the module's full forward ------------------------------------------
+
+def test_the_layer_pattern_is_one_stack_a_kind_and_a_scan_a_run():
+    cfg, _, _, params = _model()
+    assert cfg.runs() == (("sparse", 0, 1), ("lightning", 0, 2),
+                          ("sparse", 1, 2), ("lightning", 2, 1))
+    layers = params["params"]["model"]
+    assert layers["layers_sparse"]["layer"]["attn"]["qkv"][
+        "k_kernel"].shape == (3, 64, 32)
+    assert layers["layers_lightning"]["layer"]["attn"]["qkv"][
+        "k_kernel"].shape == (3, 64, 64)
+    assert "o_norm" in layers["layers_lightning"]["layer"]["attn"]
+    assert "o_norm" not in layers["layers_sparse"]["layer"]["attn"]
+
+
+def test_full_forward_matches_the_reference_across_the_dense_threshold():
+    cfg, model, _, params = _model()
+    tokens = np.random.RandomState(1).randint(0, 256, (2, 150))
+    with jax.default_matmul_precision("highest"):
+        got = model.apply(params, jnp.asarray(tokens))
+    ref, weights = _reference(params)
+    want, _ = ref.forward(weights, tokens, PUBLISHED)
+    assert got.shape == want.shape == (2, 150, 256)
+    np.testing.assert_allclose(got, want, atol=3e-5 * float(np.std(want)))
+    # at chosen positions the reference cuts its head, not its layers
+    at = np.array([0, 70, 149])
+    np.testing.assert_allclose(
+        ref.forward(weights, tokens, PUBLISHED, positions=at)[0],
+        np.asarray(want)[:, at], atol=1e-5)
+
+
+# -- (b) the paged forward, XLA path and Pallas kernel ------------------------
+
+@pytest.mark.parametrize("impl", ["xla", "pallas-interpret"])
+def test_paged_forward_matches_the_reference_across_the_threshold(impl):
+    """The harness's own probe: prefill in 16-row and then unaligned 15-row
+    chunks beside a decode row and pad rows, then decode; 110 positions
+    cross the dense threshold (64) in prefill and every decode row
+    selects."""
+    cfg, _, forward, params = _model(
+        attn_force_pallas=impl == "pallas-interpret")
+    assert pa.paged_attention_impl(cfg.head_dim_, BS,
+                                   cfg.attn_force_pallas) == impl
+    chk = dict(prompt_tokens=90, decode_steps=20)
+    schedule = serve.probe_schedule(90, 20, 16)
+    assert any(len(rows) < 16 for rows in schedule)          # pad rows
+    assert any({s for s, _ in rows} == {0, 1} for rows in schedule)
+    with jax.default_matmul_precision("highest"):
+        seqs, got = serve.probe_logits(7, cfg, forward, params, _ecfg(), chk)
+    ref, weights = _reference(params)
+    want = np.asarray(ref.forward(weights, seqs, PUBLISHED)[0])
+    assert got.shape == want.shape == (2, 110, 256)
+    np.testing.assert_allclose(got, want, atol=3e-5 * float(np.std(want)))
+
+
+# -- (c) the selection ---------------------------------------------------------
+
+def test_selected_blocks_equal_the_reference_for_every_row_and_group():
+    rng = np.random.RandomState(3)
+    s, g, r, d = 200, 2, 2, 16
+    q = jnp.asarray(rng.randn(s, g, r, d), jnp.float32)
+    k = jnp.asarray(rng.randn(s, g, d), jnp.float32)
+    ref = harness.load_plugin("reference", "minicpm_sala_f32")
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref.selected_blocks(q, k, SPARSE))
+        ck = sp.compress_keys(k, SPEC)
+        got, forced = sp.select_blocks(q, ck, jnp.arange(s), SPEC, d ** -0.5)
+    got, forced = np.asarray(got), np.asarray(forced)
+    np.testing.assert_array_equal(got, want)
+    t = np.arange(s)
+    # dense below the threshold, exactly topk blocks beyond it, the first
+    # block and the window among them, and never a block ahead of the row
+    assert (got.sum(-1)[t < 64] == (t[t < 64] // 8 + 1)[:, None]).all()
+    assert (got.sum(-1)[t >= 64] == 6).all()
+    assert got[t >= 64, :, 0].all() and not forced[t < 64].any()
+    for row in (64, 131, 199):
+        assert got[row, :, (row - 15) // 8:row // 8 + 1].all()
+        assert not got[row, :, row // 8 + 1:].any()
+        assert forced[row].sum(-1).tolist() == [2 + row // 8
+                                                - (row - 15) // 8] * 2
+    # the free picks differ between groups and rows: it is a selection
+    assert (got[64:, 0] != got[64:, 1]).any()
+
+
+# -- (d) the packed step's state ----------------------------------------------
+
+def test_a_packed_step_of_three_slots_equals_each_sequences_recurrence():
+    rng = np.random.RandomState(4)
+    h, d, slots = 4, 16, 4
+    lens = {0: 23, 2: 9, 3: 40}
+    seqs = {s: [jnp.asarray(rng.randn(n, h, d), jnp.float32)
+                for _ in range(3)] for s, n in lens.items()}
+    ref = harness.load_plugin("reference", "minicpm_sala_f32")
+    want = {s: np.asarray(ref.lightning_attention(*qkv))
+            for s, qkv in seqs.items()}
+    state = jnp.asarray(rng.randn(2, h, slots, d, d), jnp.float32)  # stale
+    got = {s: [] for s in lens}
+    done = {s: 0 for s in lens}
+    step = 0
+    while any(done[s] < lens[s] for s in lens):
+        rows = []                      # (slot, position): ragged chunks
+        for s, take in ((3, 5), (0, 7 if step else 1), (2, 3)):
+            rows += [(s, p) for p in range(done[s],
+                                           min(done[s] + take, lens[s]))]
+        rows = rows[:14] + [(slots, PAD_POSITION)] * (16 - len(rows[:14]))
+        pick = lambda i: jnp.stack([  # noqa: E731
+            seqs[s][i][p] if s < slots else jnp.zeros((h, d))
+            for s, p in rows])
+        out, state = la.lightning_attention_packed(
+            pick(0), pick(1), pick(2), state, 1,
+            jnp.asarray([s for s, _ in rows]),
+            jnp.asarray([p for _, p in rows]), d ** -0.5)
+        for i, (s, p) in enumerate(rows):
+            if s < slots:
+                got[s].append(np.asarray(out[i]))
+                done[s] = p + 1
+        step += 1
+    for s in lens:
+        np.testing.assert_allclose(np.stack(got[s]), want[s], atol=2e-5)
+    # a slot with no rows keeps its state, the other layer is untouched
+    assert (np.asarray(state[1, :, 1]) != 0).all()
+    full = la.lightning_attention_full(*(x[None] for x in seqs[3]),
+                                       d ** -0.5, chunk=16)
+    np.testing.assert_allclose(full[0], want[3], atol=2e-5)
+
+
+# -- (e) the kernel -----------------------------------------------------------
+
+def test_pallas_kernel_in_interpret_mode_equals_the_xla_path():
+    """Dense rows, selecting rows whose pool blocks are half selected, a
+    row in the first block, an unmapped table row and a pad row."""
+    rng = np.random.RandomState(6)
+    layers, nb, kv, d, maxb, n = 2, 24, 2, 16, 12, 4
+    k_pool = jnp.asarray(rng.randn(layers, nb, kv, BS, d), jnp.float32)
+    v_pool = jnp.asarray(rng.randn(layers, nb, kv, BS, d), jnp.float32)
+    ck = jnp.asarray(rng.randn(layers, nb * (BS // 2), kv * d), jnp.float32)
+    q_pos = np.array([3, 40, 63, 64, 100, 150, 191, 120, PAD_POSITION])
+    tables = np.stack([rng.permutation(nb)[:maxb] for _ in q_pos])
+    tables[7] = -1
+    q = jnp.asarray(rng.randn(len(q_pos), n, d), jnp.float32)
+    outs = {}
+    for force in (False, True):
+        outs[force], counts = sp.sparse_paged_attention(
+            q, k_pool, v_pool, ck, 1, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(q_pos, jnp.int32), SPEC, force_pallas=force)
+    live = q_pos < PAD_POSITION
+    live[7] = False
+    np.testing.assert_allclose(outs[True][live], outs[False][live],
+                               atol=2e-6)
+    assert (np.asarray(outs[True])[8] == 0).all()
+    counts = dict(zip(sp.COUNT_KINDS, np.asarray(counts).tolist()))
+    width = SPEC.walk_width(BS, maxb)
+    assert width == 6
+    assert (counts["selected"] + counts["forced"] + counts["dense"]
+            + counts["skipped"]) == len(q_pos) * kv * width
+    assert counts["dense"] == kv * (1 + 3 + 4)
+    assert counts["attended"] + counts["skipped_positions"] == kv * int(
+        (q_pos[:-1] + 1).sum())
+    assert counts["selected"] > 0 and counts["forced"] > 0
+
+
+def test_compressed_keys_land_when_their_kernel_is_whole():
+    """Rows written a chunk at a time: entry ``i`` of a sequence's
+    compressed keys is the mean of its keys ``2i .. 2i + 3``, a kernel
+    that straddles two pool blocks included, and nothing else is written."""
+    rng = np.random.RandomState(8)
+    nb, kv, d, maxb = 6, 2, 16, 4
+    keys = jnp.asarray(rng.randn(40, kv, d), jnp.float32)
+    table = jnp.asarray([[4, 1, 3, -1]], jnp.int32)
+    k_pool = jnp.zeros((1, nb, kv, BS, d), jnp.float32)
+    ck = jnp.full((1, nb * 8, kv * d), 7.0, jnp.float32)
+    for lo in range(0, 40, 7):
+        pos = jnp.arange(lo, min(lo + 7, 40))
+        tbl = jnp.broadcast_to(table, (len(pos), maxb))
+        idx = paging.flat_write_indices(tbl, pos, BS, nb * BS,
+                                        paging.FULL_CACHE)
+        k_pool = sp.write_sparse_rows(k_pool, keys[pos], idx, 0)
+        ck = sp.write_compressed_keys(ck, k_pool, 0, tbl, pos, SPEC)
+    got = sp.gather_compressed_keys(ck, 0, table, SPEC, BS, kv)[0]
+    want = np.asarray(sp.compress_keys(keys, SPEC))
+    np.testing.assert_allclose(got[:19], want[:19], atol=1e-6)
+    assert (np.asarray(got[19:24]) == 7.0).all()     # 19: not whole yet
+    assert int(np.asarray(ck != 7.0).all(-1).sum()) == 19
+    np.testing.assert_allclose(want[7], np.asarray(keys[14:18]).mean(0),
+                               atol=1e-6)            # positions 14..17
+
+
+# -- through ServingEngine ------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def served():
+    """Three requests that cross the dense threshold, one of them preempted
+    on the way, through one engine."""
+    cfg, _, _, params = _model()
+    eng = ServingEngine(cfg, params, _ecfg(num_blocks=13, max_slots=2))
+    rng = np.random.RandomState(11)
+    prompts = {"a": rng.randint(0, 256, (100,)).tolist(),
+               "b": rng.randint(0, 256, (70,)).tolist(),
+               "c": rng.randint(0, 256, (5,)).tolist()}
+    new = {"a": 40, "b": 12, "c": 4}
+    obs.enable()
+    obs.get_registry().reset()
+    for uid, prompt in prompts.items():
+        eng.submit(prompt, new[uid], uid=uid)
+    while eng.has_work():
+        eng.step()
+    counters = {
+        name: {c.labels.get("kind", ""): c.value
+               for c in obs.get_registry().get(name).children()}
+        for name in ("nxd_sparse_columns_total",
+                     "nxd_sparse_positions_total", "nxd_state_resets_total",
+                     "nxd_engine_rows_total")}
+    obs.disable()
+    ps.destroy_model_parallel()
+    return cfg, params, eng, prompts, new, counters
+
+
+def test_engine_greedy_tokens_equal_the_reference(served):
+    cfg, params, eng, prompts, new, _ = served
+    for uid, prompt in prompts.items():
+        assert eng.results[uid].status == "completed"
+        tokens = eng.results[uid].tokens
+        assert len(tokens) == new[uid]
+        assert tokens == _greedy_by_reference(params, prompt, tokens), uid
+
+
+def test_a_preempted_request_decodes_as_a_fresh_one(served):
+    """13 blocks do not hold a and b: b is preempted, re-admitted into a
+    slot whose lightning states another request left, and still decodes
+    what the reference does (above); the pool is whole at the end."""
+    _, _, eng, *_ = served
+    assert eng.stats.preempted >= 1
+    assert eng.allocator.num_allocated == 0
+    assert (eng._tables == -1).all()
+    assert eng.compile_count() == 1
+    assert float(jnp.abs(eng.cache.state).max()) > 0    # never cleared
+
+
+def test_sparse_and_state_counters(served):
+    *_, counters = served
+    cols = counters["nxd_sparse_columns_total"]
+    assert set(cols) == {"selected", "forced", "dense", "skipped"}
+    assert all(v > 0 for v in cols.values())
+    rows = counters["nxd_engine_rows_total"]
+    width = SPEC.walk_width(BS, 12)
+    # rows x groups x walk width x sparse layers, every step
+    assert sum(cols.values()) == sum(rows.values()) * 2 * width * 3
+    pos = counters["nxd_sparse_positions_total"]
+    assert pos["attended"] > 0 and pos["skipped"] > 0
+    # three admissions and b's second
+    assert counters["nxd_state_resets_total"][""] >= 4
+
+
+@pytest.mark.parametrize("feature,kw", [
+    ("prefix_sharing", dict(prefix_sharing=True)),
+    ("speculation", dict(speculation=SpeculationConfig())),
+    ("cp", dict(cp=2)),
+    ("quantized", dict(quantized=True)),
+])
+def test_refused_features_raise_by_name(feature, kw):
+    cfg, _, _, params = _model()
+    with pytest.raises(ValueError, match=feature):
+        ServingEngine(cfg, params, _ecfg(**kw))
+
+
+def test_session_export_is_refused_and_the_cache_is_the_kinds():
+    cfg, _, _, params = _model()
+    eng = ServingEngine(cfg, params, _ecfg())
+    uid = eng.submit([1, 2, 3], 4)
+    eng.step()
+    with pytest.raises(ValueError, match="session_export"):
+        eng.export_session(uid)
+    cache = eng.cache
+    assert isinstance(cache, paging.SparseStatePagedCache)
+    assert cache.k.shape == (3, 40, 2, BS, 16) == cache.v.shape
+    assert cache.ck.shape == (3, 40 * 8, 2 * 16)
+    assert cache.state.shape == (3, 4, 3, 16, 16)
+    assert cache.state.dtype == jnp.float32
+    assert cache.capacity == 40 * BS and cache.max_slots == 3
+    with pytest.raises(ValueError, match="whole selection blocks"):
+        ServingEngine(cfg, params, _ecfg(block_size=4, token_budget=4))
+
+
+# -- (f) the constructor and the two old kinds --------------------------------
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("family", ["llama", "evabyte"])
+def test_the_constructor_returns_the_old_kinds_pytrees_leaf_for_leaf(
+        family, quantized):
+    from neuronx_distributed_tpu.models import evabyte, llama
+
+    cfg = (llama.tiny_config() if family == "llama"
+           else evabyte.tiny_config())
+    geometry = dict(num_blocks=12, block_size=8, table_rows=3,
+                    max_blocks_per_seq=5)
+    got = paging.init_serving_cache(cfg, dtype=jnp.float32,
+                                    quantized=quantized, **geometry)
+    args = (cfg.num_layers, 12, 8, cfg.num_kv_heads, cfg.head_dim_, 3, 5)
+    want = (paging.init_quantized_paged_kv_cache(*args) if quantized
+            else paging.init_paged_kv_cache(*args, dtype=jnp.float32))
+    assert type(got) is type(want)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(a, b)
+
+
+def test_attention_kinds_are_checked_by_name():
+    from neuronx_distributed_tpu.models import llama
+
+    assert llama.ATTENTION_KINDS == ("full", "eva", "sparse", "lightning")
+    with pytest.raises(ValueError, match="attention_kind"):
+        llama.tiny_config(attention_kind="linear")
+    with pytest.raises(ValueError, match="mixer_types"):
+        _model(mixer_types=("minicpm4",))
