@@ -688,6 +688,11 @@ class ServingEngine:
         self._counts_on_device = self._cache_kind.name == "sparse_state"
         self._paged_cols = np.zeros((6 if self._counts_on_device else 3,),
                                     np.int64)
+        #: what the kernel's tiles fetched for those rows, by the walk's own
+        #: function: pool blocks fetched, one a (tile, pair), and the further
+        #: live (row, column) each fetch served
+        #: (``nxd_paged_block_visits_total``)
+        self._block_visits = np.zeros((2,), np.int64)
         #: windows a window-summary cache rolled (``nxd_eva_windows_total``)
         #: or rows at position 0 of a sparse-state cache, each of which
         #: starts a slot's state anew (``nxd_state_resets_total``)
@@ -1950,14 +1955,25 @@ class ServingEngine:
             if by_device:
                 self._kind_events += int(np.sum(positions == 0))
             elif counted:
-                # what the paged kernel's walk finds in this batch; a pad
-                # row reads the last table row, as the forward's clip does
+                # what the paged kernel's walk finds in this batch (a pad
+                # row attends nothing, whatever table row it is handed)
                 tbl = self._tables[np.minimum(slot_ids,
                                               self._table_rows - 1)]
-                kinds = np.bincount(self._cache_kind.column_kinds(
+                kinds = self._cache_kind.column_kinds(
                     tbl, np.arange(tbl.shape[1]), positions[0][:, None],
-                    self.ecfg.block_size).ravel(), minlength=3)
-                self._paged_cols += kinds       # skipped, exact, summary
+                    self.ecfg.block_size)
+                self._paged_cols += np.bincount(
+                    kinds.ravel(), minlength=3)  # skipped, exact, summary
+                # and what its tiles fetch for them
+                from ..ops.paged_attention import tile_pairs, tile_rows
+
+                mcfg = self.model_cfg
+                fetched = int(tile_pairs(
+                    np.where(kinds > 0, tbl, -1),
+                    tile_rows(mcfg.num_heads // mcfg.num_kv_heads, width),
+                    self._pool_blocks, xp=np)[0].sum())
+                self._block_visits += (
+                    fetched, np.count_nonzero(kinds) - fetched)
         with tracer.span(span + "/dispatch"):
             if self._spec is not None:
                 sampled, self.cache, self.dcache = fn(
@@ -2394,6 +2410,16 @@ class ServingEngine:
                     "nxd_eva_windows_total",
                     "Windows whose last position was in a packed step: "
                     "summarised into a block of the pool by that step.")
+            visits_c = None if self._counts_on_device else reg.counter(
+                "nxd_paged_block_visits_total",
+                "Live (row, table column) of the serving workers' rows by "
+                "how the paged kernel came by the column's pool block: "
+                "fetched, one a (tile of rows, pair of column and block), "
+                "or shared, served by a fetch that another row of the tile "
+                "is counted for.",
+                labels=("kind",))
+            visits_by_kind = () if visits_c is None else tuple(
+                visits_c.labels(kind=k) for k in ("fetched", "shared"))
             cache = self._obs_cache = (
                 reg, reg.generation,
                 {f: stats_g.labels(field=f)
@@ -2403,9 +2429,9 @@ class ServingEngine:
                 step_h,
                 tuple(rows_c.labels(kind=k)
                       for k in ("decode", "prefill", "pad")),
-                cols_by_kind, events_c)
+                cols_by_kind, events_c, visits_by_kind)
         (_, _, fields, free_g, step_h, rows_by_kind, cols_by_kind,
-         events_c) = cache
+         events_c, visits_by_kind) = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
@@ -2417,6 +2443,9 @@ class ServingEngine:
         for child, n in zip(cols_by_kind, self._paged_cols):
             child.inc(int(n))
         self._paged_cols[:] = 0
+        for child, n in zip(visits_by_kind, self._block_visits):
+            child.inc(int(n))
+        self._block_visits[:] = 0
         if events_c is not None:
             events_c.inc(self._kind_events)
         self._kind_events = 0

@@ -352,9 +352,11 @@ class PagedCacheView(struct.PyTreeNode):
     table (each packed token carries its own slot's row); ``write_idx
     [T]`` is the precomputed flat index, within a layer, of this step's
     K/V rows (== pool capacity for rows that must not land:
-    :func:`write_pool_rows`). ``roll`` is the routing of the summaries a
-    window-summary family writes in this step (:func:`window_roll`; None
-    for a full cache)."""
+    :func:`write_pool_rows`). ``walk`` is the attention kernel's routing
+    of the step (:func:`..ops.paged_attention.step_walk`: which pool
+    blocks each tile of rows fetches; None where the XLA path serves).
+    ``roll`` is the routing of the summaries a window-summary family
+    writes in this step (:func:`window_roll`; None for a full cache)."""
 
     k: jax.Array
     v: jax.Array
@@ -364,6 +366,7 @@ class PagedCacheView(struct.PyTreeNode):
     pos: jax.Array
     tables: jax.Array
     write_idx: jax.Array
+    walk: Any = None
     roll: Any = None
 
 

@@ -413,7 +413,7 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
         view.layer, k_scale=new_ks, v_scale=new_vs,
         scale=1.0 / _math.sqrt(q.shape[-1]),
         force_pallas=cfg.attn_force_pallas,
-        combine_axis=combine)[None]
+        combine_axis=combine, walk=view.walk)[None]
     new_view = view.replace(k=new_k, v=new_v, k_scale=new_ks,
                             v_scale=new_vs)
     return out.astype(cfg.dtype), new_view
@@ -451,7 +451,7 @@ def _eva_attend(cfg: LlamaConfig, q, k, v, positions, cache, phi, mu):
     out = paged_attention(
         q[0], new_k, new_v, cache.pos, cache.tables, positions[0],
         cache.layer, scale=scale, force_pallas=cfg.attn_force_pallas,
-        window=(kind.window, kind.ring))[None]
+        window=(kind.window, kind.ring), walk=cache.walk)[None]
     return out.astype(cfg.dtype), cache.replace(k=new_k, v=new_v)
 
 
@@ -991,14 +991,14 @@ class _PagedScanBody(nn.Module):
     cfg: LlamaConfig
 
     @nn.compact
-    def __call__(self, carry, layer, pool_pos, tables, write_idx, cos, sin,
-                 positions, roll=None):
+    def __call__(self, carry, layer, pool_pos, tables, write_idx, walk, cos,
+                 sin, positions, roll=None):
         from ..inference.paging import PagedCacheView
 
         x, (k, v, k_scale, v_scale) = carry
         view = PagedCacheView(k=k, v=v, k_scale=k_scale, v_scale=v_scale,
                               layer=layer, pos=pool_pos, tables=tables,
-                              write_idx=write_idx, roll=roll)
+                              write_idx=write_idx, walk=walk, roll=roll)
         x, _, new = LlamaDecoderLayer(self.cfg, name="layer")(
             x, cos, sin, positions, cache=view, cache_index=None)
         return (x, (new.k, new.v, new.k_scale, new.v_scale)), None
@@ -1274,6 +1274,7 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
 
     if paged:
         from ..inference import paging as _paging
+        from ..ops import paged_attention as _paged_attention
 
         slot_ids = jnp.asarray(slot_ids, jnp.int32)
         # where a position lives in its slot's table row is the family's
@@ -1299,17 +1300,26 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
         stacks = (kv_cache.k, kv_cache.v,
                   kv_cache.k_scale if quantized else None,
                   kv_cache.v_scale if quantized else None)
-        routing = (write_idx, cos, sin, rope_pos)
+        rope = (cos, sin, rope_pos)
         if cp_prefill:
-            body, routing = _CPPrefillScanBody, (slot_pos,) + routing
+            body, routing = _CPPrefillScanBody, (slot_pos, write_idx) + rope
         else:
             # a window-summary kind also routes the summaries this step
             # writes, once for all layers
             roll = () if kind.ring is None else (_paging.window_roll(
                 kind, kv_cache.block_tables, slot_ids, positions[0],
                 kv_cache.block_size, kv_cache.num_blocks),)
+            # so is the attention kernel's walk, which follows the tables
+            # and the positions alone (None where the XLA path serves)
+            walk = _paged_attention.step_walk(
+                tok_tables, positions[0], kv_cache.block_size,
+                kv_cache.num_blocks, cfg.head_dim_,
+                cfg.num_heads // cfg.num_kv_heads,
+                window=None if kind.ring is None else (kind.window,
+                                                       kind.ring),
+                force_pallas=cfg.attn_force_pallas)
             body = _PagedScanBody
-            routing = (slot_pos, tok_tables) + routing + roll
+            routing = (slot_pos, tok_tables, write_idx, walk) + rope + roll
         scanned = nn.scan(
             body,
             variable_axes={"params": 0},

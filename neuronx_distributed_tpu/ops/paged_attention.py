@@ -16,15 +16,46 @@ Two implementations behind one signature, following
   einsums, same ``-1e30`` position-sentinel masking), so paged decode is
   bit-for-bit comparable with :func:`..models.llama.llama_forward_with_cache`
   on the contiguous cache; runs everywhere and is the tier-1/CPU path.
-* ``_paged_attention_pallas`` — a Mosaic TPU kernel: grid ``(tokens,
-  max_blocks_per_seq)``, the walk over each row's block table
-  (:func:`_paged_walk`) and the layer scalar-prefetched into SMEM so a
-  grid step DMAs at most one pool block into VMEM (online-softmax
-  m/l/acc in VMEM scratch). The walk follows the row's context: a
-  column that is unmapped (-1) or lies wholly behind the row's own
-  position (:func:`column_live`) is skipped, not masked — its grid step
-  runs no arithmetic, and past the row's last causal column the block
-  index repeats that column's, so the same-block DMA is elided too.
+* ``_paged_attention_pallas`` — a Mosaic TPU kernel whose unit is a
+  *tile* of ``R`` consecutive packed rows against one *pair* (table
+  column, pool block) that some row of the tile attends. ``R``
+  (:func:`tile_rows`) follows the shapes: with the ``n_rep`` query heads
+  of a K/V head stacked a tile is about the MXU's 128 rows, 32 rows
+  under GQA-4 and 128 under MHA. The grid is the tiles. Inside, a loop
+  over the tile's pairs copies each pair's block once from the stacks in
+  HBM into one of two VMEM buffers while the block before it is
+  computed, and multiplies a K/V head at a time on the MXU: scores
+  ``[R * n_rep, D] x [D, block_size]`` from the stored operands into
+  float32, ``p x v`` with ``p`` float32 (:func:`_p_times_v`), the running
+  max, sum and accumulator float32 scratch. So a prefill chunk's rows,
+  which name the same blocks of one slot, fetch them once a tile and not
+  once a row, and the kernel's time follows the pairs and not the
+  table's width. The rows
+  of the tile that do not name a pair's block are masked (``-inf``, as a
+  dead slot position is); where a single group of rows names it (a
+  decode row's blocks: its neighbours are other slots') the products run
+  over that group alone.
+
+  The walk (:func:`tile_walk`) lists, a tile, the distinct pairs that
+  are live for at least one of its rows, each once. Live is
+  :func:`column_live` (a window-summary cache:
+  :func:`window_column_kinds`): an unmapped column, one wholly behind
+  the row's position and every column of a pad row belong to no pair,
+  and a pad row's output is zero. Membership is by the row's own table
+  entry, not by its slot: two slots that share a prefix block share its
+  fetch. The walk follows the tables and positions alone, so the cached
+  forward builds it once a step beside the write indices
+  (:func:`step_walk`, ``PagedCacheView.walk``), not once a layer.
+
+  Two things the kernel leans on. Row order: the engine packs decode
+  rows first and then one contiguous run a prefill chunk
+  (``ServingEngine._build_schedule``), so a slot's rows are neighbours
+  and a tile of a chunk has one slot's pairs; any order is correct, a
+  scattered one shares less. The pool's layout, ``[.., block_size, KV,
+  D]`` with a slot's heads on sublanes
+  (:func:`..inference.paging.write_pool_rows`): a head's ``[block_size,
+  D]`` operand is a sublane-strided read of the block in VMEM
+  (:func:`_head_rows`).
 
 Auto-dispatch picks the kernel on TPU when the shapes tile; CPU runs the
 kernel in interpret mode when forced (CI coverage of the mask path).
@@ -42,7 +73,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -121,15 +152,16 @@ def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos, layer,
 
 def column_live(entry, column, q_pos, block_size: int):
     """Whether table column ``column`` (holding block id ``entry``) can
-    contribute to a row at ``q_pos``: it is mapped, and its first position
-    ``column * block_size`` is not beyond the row's own (a position ``p``
-    lives in column ``p // block_size``,
+    contribute to a row at ``q_pos``: the row is no padding, the column is
+    mapped, and its first position ``column * block_size`` is not beyond
+    the row's own (a position ``p`` lives in column ``p // block_size``,
     :func:`..inference.paging.flat_write_indices`, and a row attends only
     to positions ``<= q_pos``). Every other column adds exactly nothing to
     the online softmax. Broadcasts over jnp arrays (the kernel's walk)
     and NumPy ones (the engine's ``nxd_paged_columns_total``, the tests).
     """
-    return (entry >= 0) & (column * block_size <= q_pos)
+    return ((entry >= 0) & (column * block_size <= q_pos)
+            & (q_pos < PAD_POSITION))
 
 
 def window_column_kinds(entry, column, q_pos, block_size: int, window: int,
@@ -155,135 +187,329 @@ def window_column_kinds(entry, column, q_pos, block_size: int, window: int,
     return (real & exact) * 1 + (real & summary) * 2
 
 
-def _window_walk(tables, q_pos, block_size: int, window: int, ring: int):
-    """:func:`_paged_walk` for a window-summary cache: a live column's
-    block id, or for a skipped column the complement of the block the
-    previous live column named (the row's first live one before any; 0
-    where the row has none), so that a skipped step's DMA is elided."""
-    maxb = tables.shape[1]
+def tile_rows(n_rep: int, tokens: int) -> int:
+    """Rows of a tile of the kernel: with the ``n_rep`` query heads of a
+    K/V head stacked, about the MXU's 128 rows (32 under GQA-4, 128 under
+    MHA), whole sublanes, and no more than the packed rows there are."""
+    return min(max(8, 128 // n_rep), -(-tokens // 8) * 8)
+
+
+def narrow_rows(n_rep: int) -> int:
+    """Rows (query heads stacked) of the narrow product of a pair that a
+    single packed row names: its ``n_rep`` heads, in whole sublanes."""
+    return -(-n_rep // 8) * 8
+
+
+def tile_pairs(served, rows: int, num_blocks: int, xp=jnp):
+    """The kernel's walk over ``served [T, max_blocks_per_seq]``, a row's
+    table entry in the columns it attends and -1 elsewhere (tiles of
+    ``rows`` consecutive rows, the last one filled up with rows that
+    attend nothing): for every tile the distinct
+    ``(table column, block id)`` pairs that at least one row of the tile
+    attends, each once, in order of column and block.
+
+    Returns ``(count [tiles], blocks [tiles, P], cols [tiles, P])`` with
+    ``P = rows * max_blocks_per_seq``, what a tile of rows that share
+    nothing lists; entries from a tile's ``count`` on mean nothing.
+    ``xp`` is ``jnp`` (the walk the kernel is handed) or ``numpy`` (the
+    engine's ``nxd_paged_block_visits_total``, the tests)."""
+    t, maxb = served.shape
+    none = maxb * num_blocks
+    cols = xp.arange(maxb, dtype=xp.int32)[None, :]
+    key = xp.where(served >= 0, cols * num_blocks + served, none)
+    key = xp.concatenate(
+        [key, xp.full((-t % rows, maxb), none, key.dtype)]).reshape(
+            -1, rows * maxb)
+    key = xp.sort(key, axis=-1)
+    # a pair named by several rows of the tile lies in one run: keep the
+    # run's first, and sort the rest behind the tile's pairs
+    again = xp.concatenate(
+        [xp.zeros_like(key[:, :1], dtype=bool), key[:, 1:] == key[:, :-1]],
+        axis=1)
+    key = xp.sort(xp.where(again, none, key), axis=-1)
+    count = xp.sum(key < none, axis=-1).astype(xp.int32)
+    return (count, (key % num_blocks).astype(xp.int32),
+            (key // num_blocks).astype(xp.int32))
+
+
+class TileWalk(NamedTuple):
+    """What the kernel is handed of a step's routing
+    (:func:`tile_walk`), the same for every layer: ``count [tiles]``,
+    ``blocks`` and ``cols [tiles * P]`` the tiles' pairs
+    (:func:`tile_pairs`); ``narrow [tiles * P]``, where the rows that
+    name a pair lie inside one group of :func:`narrow_rows` rows of the
+    tile (a decode row's pairs: nothing to share), the group's first row,
+    else -1; and a tile at a time with each of its ``rows`` rows repeated
+    once a query head of a K/V head (row ``r * n_rep + rep`` of the tile
+    is packed row ``r``'s head ``rep``): ``served [tiles, rows * n_rep,
+    max_blocks_per_seq]`` the row's table entry in the columns it
+    attends, -1 elsewhere; ``q_pos [tiles, rows * n_rep, 1]``; and for a
+    window-summary cache ``q_lo``, the first position of the row's window
+    (else ``None``)."""
+
+    count: jax.Array
+    blocks: jax.Array
+    cols: jax.Array
+    narrow: jax.Array
+    served: jax.Array
+    q_pos: jax.Array
+    q_lo: Optional[jax.Array]
+
+
+def tile_walk(tables, q_pos, block_size: int, num_blocks: int, n_rep: int,
+              window=None) -> TileWalk:
+    """The walk of one packed step, from ``tables [T, max_blocks_per_seq]``
+    and ``q_pos [T]`` alone: routing, built once a step beside the write
+    indices and handed to every layer's kernel. ``T`` is padded to whole
+    tiles with pad rows. A pad row attends nothing and belongs to no
+    pair."""
+    t, maxb = tables.shape
+    rows = tile_rows(n_rep, t)
+    pad = -t % rows
+    tables = jnp.pad(tables.astype(jnp.int32), ((0, pad), (0, 0)),
+                     constant_values=-1)
+    q_pos = jnp.pad(q_pos.astype(jnp.int32), (0, pad),
+                    constant_values=PAD_POSITION)
     cols = jnp.arange(maxb, dtype=jnp.int32)[None, :]
-    live = window_column_kinds(tables, cols, q_pos[:, None], block_size,
-                               window, ring) > 0
-    last = jax.lax.cummax(jnp.where(live, cols, -1), axis=1)
-    first = jnp.argmax(live, axis=1).astype(jnp.int32)[:, None]
-    name = jnp.where(last >= 0, last, first)
-    fetch = jnp.maximum(jnp.take_along_axis(tables, name, axis=1), 0)
-    return jnp.where(live, fetch, ~fetch)
-
-
-def _paged_walk(tables, q_pos, block_size: int):
-    """``[T, max_blocks_per_seq]`` int32, one entry a grid step of the
-    kernel: a live column's block id (>= 0: fetch it and compute), or for
-    a skipped column the complement (``~b`` < 0) of the block the step
-    names and never reads — the row's last causal column's, 0 where that
-    is unmapped — so consecutive skipped steps repeat the index and their
-    DMA is elided. Worked out here, ahead of the kernel, and not per grid
-    step from the table in SMEM: on the v5e that scalar work was 0.11 us
-    of every step, 4% of a live one and 44% of a skipped one."""
-    maxb = tables.shape[1]
-    cols = jnp.arange(maxb, dtype=jnp.int32)
-    last = jnp.clip(q_pos // block_size, 0, maxb - 1)[:, None]
-    fetch = jnp.maximum(jnp.take_along_axis(
-        tables, jnp.minimum(cols, last), axis=1), 0)
-    return jnp.where(column_live(tables, cols, q_pos[:, None], block_size),
-                     fetch, ~fetch)
-
-
-def _paged_kernel(walk_ref, qpos_ref, layer_ref, *refs,
-                  num_blocks_per_seq: int, n_rep: int, scale: float,
-                  quantized: bool, ring: Optional[int] = None):
-    """One (token, table column) grid step: online softmax of the token's
-    heads over one pool block, if the column is live for the token
-    (``walk_ref[t, j] >= 0``, :func:`_paged_walk`); a skipped column
-    leaves the running max, sum and accumulator as they are, which is
-    what its all-masked block did. ``layer_ref`` is the index maps' (which
-    layer of the stacks a block is fetched from); the body never reads it.
-
-    Everything stays in the pool block's own layout — slots on the major
-    dim, KV heads on sublanes, head_dim on lanes — so Mosaic sees only
-    elementwise products, lane/major reductions and lane broadcasts:
-    scores are ``[BS, KV, 1]`` (one per (slot, head) row), the running
-    max/sum ``[KV, 1]`` and the accumulator ``[KV, D]`` per query-head
-    replica ``r`` (query head ``h * n_rep + r`` reads KV head ``h``).
-
-    With ``ring`` (a window-summary cache, the ``eva_attention`` kernel)
-    a further prefetched scalar a row is the first position of its window:
-    the rows of columns under ``ring`` are exact and count from there to
-    the row's own position, those of the columns from ``ring`` on are
-    summaries and count whole; one softmax runs over both."""
-    from jax.experimental import pallas as pl
-
-    if ring is not None:
-        qlo_ref, *refs = refs
-    q_ref, k_ref, v_ref, pos_ref, *rest = refs
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    if window is None:
+        live = column_live(tables, cols, q_pos[:, None], block_size)
     else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-    t = pl.program_id(0)
-    j = pl.program_id(1)
+        live = window_column_kinds(tables, cols, q_pos[:, None], block_size,
+                                   *window) > 0
+    served = jnp.where(live, tables, -1)
+    count, blocks, pair_cols = tile_pairs(served, rows, num_blocks)
+    # the first and last row of its tile that names each pair
+    names = (jnp.take_along_axis(
+        served.reshape(-1, rows, maxb).swapaxes(1, 2),
+        jnp.minimum(pair_cols, maxb - 1)[:, :, None], axis=1)
+        == blocks[:, :, None])                           # [tiles, P, rows]
+    first = jnp.argmax(names, axis=-1) * n_rep
+    last = (rows - jnp.argmax(names[:, :, ::-1], axis=-1)) * n_rep
+    group = narrow_rows(n_rep)
+    start = first // group * group
+    narrow = jnp.where(last <= start + group, start, -1).astype(jnp.int32)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def by_tile(x):
+        return jnp.repeat(x, n_rep, axis=0).reshape(
+            (-1, rows * n_rep) + x.shape[1:])
 
-    bs = k_ref.shape[1]
+    return TileWalk(
+        count=count, blocks=blocks.reshape(-1), cols=pair_cols.reshape(-1),
+        narrow=narrow.reshape(-1),
+        served=by_tile(served), q_pos=by_tile(q_pos[:, None]),
+        q_lo=None if window is None else by_tile(
+            ((q_pos // window[0]) * window[0])[:, None]))
 
-    @pl.when(walk_ref[t, j] >= 0)
-    def _accumulate():
-        k = k_ref[0].astype(jnp.float32)               # [BS, KV, D]
-        v = v_ref[0].astype(jnp.float32)
-        # per-slot validity arrives slot-on-lanes ([1, 1, BS]); a one-hot
-        # select + lane max moves it to slot-on-major ([BS, 1, 1])
-        ok = qpos_ref[t] >= pos_ref[...]
-        if ring is not None:
-            ok = ok & (pos_ref[...] >= qlo_ref[t])
-        ok = ok.astype(jnp.float32)
-        if ring is not None:
-            ok = jnp.where(j >= ring, 1.0, ok)
-        eye = (jax.lax.broadcasted_iota(jnp.int32, (bs, 1, bs), 0)
-               == jax.lax.broadcasted_iota(jnp.int32, (bs, 1, bs), 2))
-        valid = jnp.max(jnp.where(eye, ok, 0.0), axis=-1,
-                        keepdims=True) > 0.5
+
+def step_walk(tables, q_pos, block_size: int, num_blocks: int, head_dim: int,
+              n_rep: int, window=None, force_pallas: Optional[bool] = None
+              ) -> Optional[TileWalk]:
+    """:func:`tile_walk` for the layers of one step, or ``None`` where
+    :func:`paged_attention` runs the XLA reference for these shapes
+    (:func:`paged_attention_impl`)."""
+    if paged_attention_impl(head_dim, block_size, force_pallas) == "xla":
+        return None
+    return tile_walk(tables, q_pos, block_size, num_blocks, n_rep, window)
+
+
+def _head_rows(block_ref):
+    """The ``[block_size, D]`` rows of every K/V head of a pool block
+    ``block_ref [block_size, KV, D]`` in VMEM, widened exactly to float32
+    (``KV`` arrays). The block lies as the pool does, a slot's heads on
+    sublanes, so a head's rows are a sublane-strided read of it: every
+    ``KV``-th row of the block seen as ``[block_size * KV, D]``. bf16 and
+    int8 rows lie two and four to a 32-bit sublane; those are read as
+    words, one strided read for the heads that share them, and taken
+    apart with shifts."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bs, kv, d = block_ref.shape
+    dtype = block_ref.dtype
+    packing = 4 // dtype.itemsize
+    if kv % packing:
+        # heads that fill no whole word (tiny shapes, off the chip)
+        return [block_ref[:, h, :].astype(jnp.float32) for h in range(kv)]
+    flat = block_ref.reshape(bs * kv, d)
+    if packing == 1:
+        return [flat[pl.ds(h, bs, stride=kv), :].astype(jnp.float32)
+                for h in range(kv)]
+    words = flat.bitcast(jnp.uint32)
+    out = []
+    for g in range(kv // packing):
+        w = words[pl.ds(g, bs, stride=kv // packing), :]       # [bs, D] u32
+        for part in range(packing):
+            if dtype == jnp.bfloat16:
+                # a bf16 is the upper half of the float32 of its value
+                bits = w << 16 if part == 0 else w & jnp.uint32(0xFFFF0000)
+                out.append(pltpu.bitcast(bits, jnp.float32))
+            else:
+                signed = pltpu.bitcast(w << (24 - 8 * part), jnp.int32)
+                out.append((signed >> 24).astype(jnp.float32))
+    return out
+
+
+def _p_times_v(p, v):
+    """``p [rows, block_size]`` float32 times ``v [block_size, D]`` into
+    float32. Against a float32 ``v`` that is one float32 product. A bf16
+    ``v`` (a bf16 pool's values as stored, an int8 pool's widened exactly)
+    has no low part, so ``p`` goes through the MXU in two bf16 parts, its
+    upper 16 bits of mantissa, and is not rounded to bf16. On the v5e
+    that read the float32 product's own error against the reference at
+    18-26% less of the kernel's time, and three parts a quarter of the
+    error at 60% more (``PERF.md``, Findings, PR 33)."""
+    def dot(a, b):
+        return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    if v.dtype == jnp.float32:
+        return dot(p, v)
+    high = p.astype(v.dtype)
+    return dot(high, v) + dot((p - high.astype(jnp.float32)).astype(v.dtype),
+                              v)
+
+
+def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
+                  *refs, pairs: int, group: int, scale: float,
+                  quantized: bool, window: Optional[tuple]):
+    """One tile of packed rows against the pool blocks its rows attend:
+    a loop over the tile's ``count_ref[tile]`` pairs (:func:`tile_pairs`),
+    each block copied once from the stacks in HBM into one of two VMEM
+    buffers while the block before it is computed, so the kernel's time
+    follows the pairs and not the table's width.
+
+    A pair's block serves the rows of the tile that name it in the pair's
+    column (``served_ref``); for the others every score is masked, as a
+    dead slot position is. A K/V head at a time: scores ``[rows * n_rep,
+    D] x [D, block_size]`` on the MXU from the stored operands into
+    float32, the online softmax in float32 scratch, and ``p x v`` with
+    ``p`` float32 (:func:`_p_times_v`). An int8 block is widened exactly
+    and its scales multiply the score and the probability. Where one
+    group of ``group`` rows holds every row that names the pair
+    (``narrow_ref``: a decode row's block, which its neighbours in the
+    tile do not share) the products and the softmax run over that group
+    alone.
+
+    With ``window`` (a window-summary cache, the ``eva_attention``
+    kernel) the rows of columns under the ring's width are exact and
+    count from the first position of the row's window to the row's own,
+    those of the columns from there on are summaries and count whole; one
+    softmax runs over both."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    served_ref, qpos_ref, *refs = refs
+    if window is not None:
+        qlo_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, pos_hbm, *refs = refs
+    if quantized:
+        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, pos_buf, ks_buf, vs_buf, \
+            sems, m_ref, l_ref, acc_ref = refs
+    else:
+        o_ref, k_buf, v_buf, pos_buf, sems, m_ref, l_ref, acc_ref = refs
+    tile = pl.program_id(0)
+    count = count_ref[tile]
+    layer = layer_ref[0]
+    kv = q_ref.shape[0]
+    # bf16 queries meet bf16 (or exactly widened int8) keys as stored;
+    # any other pairing is multiplied in float32
+    operand = (jnp.bfloat16 if q_ref.dtype == jnp.bfloat16
+               and k_buf.dtype != jnp.float32 else jnp.float32)
+
+    def copies(j, slot):
+        b = blocks_ref[tile * pairs + j]
+        moves = [(k_hbm.at[layer, b], k_buf), (v_hbm.at[layer, b], v_buf),
+                 (pos_hbm.at[b], pos_buf)]
         if quantized:
-            # scales arrive slot-on-lanes too ([1, KV, BS]); the same trick
-            # puts each on its slot's major index ([BS, KV, 1]). They scale
-            # the score and the probability, not the [BS, KV, D] operands.
-            def per_row(s_ref):
-                return jnp.sum(jnp.where(eye, s_ref[...], 0.0), axis=-1,
-                               keepdims=True)
+            moves += [(ks_hbm.at[layer, b], ks_buf),
+                      (vs_hbm.at[layer, b], vs_buf)]
+        return [pltpu.make_async_copy(src, buf.at[slot], sems.at[slot, i])
+                for i, (src, buf) in enumerate(moves)]
 
-            k_scale = per_row(ks_ref)
-            v_scale = per_row(vs_ref)
-        for r in range(n_rep):
-            q = q_ref[0, r].astype(jnp.float32) * scale    # [KV, D]
-            s = jnp.sum(k * q[None], axis=-1, keepdims=True)   # [BS, KV, 1]
-            if quantized:
-                s = s * k_scale
-            s = jnp.where(valid, s, -jnp.inf)
-            m_prev = m_ref[r]                              # [KV, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            p = jnp.where(valid, jnp.exp(s - m_safe[None]), 0.0)
-            corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe),
-                             0.0)
-            m_ref[r] = m_new
-            l_ref[r] = l_ref[r] * corr + jnp.sum(p, axis=0)
-            if quantized:
-                p = p * v_scale
-            acc_ref[r] = acc_ref[r] * corr + jnp.sum(p * v, axis=0)
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j == num_blocks_per_seq - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                    ).astype(o_ref.dtype)
+    @pl.when(count > 0)
+    def _first():
+        for c in copies(0, 0):
+            c.start()
+
+    def pair(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < count)
+        def _next():
+            for c in copies(j + 1, 1 - slot):
+                c.start()
+
+        for c in copies(j, slot):
+            c.wait()
+        block = blocks_ref[tile * pairs + j]
+        col = cols_ref[tile * pairs + j]
+        pos = pos_buf[slot]                             # [1, bs]
+        k_heads = _head_rows(k_buf.at[slot])
+        v_heads = _head_rows(v_buf.at[slot])
+
+        def attend(rows):
+            """The pair against the tile's rows ``rows`` (a slice)."""
+            served = served_ref[rows, :]                # [rows', maxb]
+            column = jax.lax.broadcasted_iota(jnp.int32, served.shape, 1)
+            named = jnp.max(
+                jnp.where((column == col) & (served == block), 1, 0),
+                axis=1, keepdims=True) > 0              # [rows', 1]
+            ok = pos <= qpos_ref[rows, :]               # [rows', bs]
+            if window is not None:
+                ok = jnp.logical_or(ok & (pos >= qlo_ref[rows, :]),
+                                    col >= window[1])
+            ok = ok & named
+            for h in range(kv):
+                s = jax.lax.dot_general(
+                    q_ref[h, rows, :].astype(operand),
+                    k_heads[h].astype(operand), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if quantized:
+                    s = s * ks_buf[slot, pl.ds(h, 1), :]
+                s = jnp.where(ok, s, -jnp.inf)                # [rows', bs]
+                m_prev = m_ref[h, rows, :]                    # [rows', 1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+                p = jnp.where(ok, jnp.exp(s - m_safe), 0.0)
+                corr = jnp.where(jnp.isfinite(m_prev),
+                                 jnp.exp(m_prev - m_safe), 0.0)
+                m_ref[h, rows, :] = m_new
+                l_ref[h, rows, :] = l_ref[h, rows, :] * corr + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                if quantized:
+                    p = p * vs_buf[slot, pl.ds(h, 1), :]
+                acc_ref[h, rows, :] = (
+                    acc_ref[h, rows, :] * corr
+                    + _p_times_v(p, v_heads[h].astype(operand)))
+
+        wide = q_ref.shape[1]
+        if group >= wide:
+            attend(slice(None))
+        else:
+            start = narrow_ref[tile * pairs + j]
+
+            @pl.when(start >= 0)
+            def _narrow():
+                attend(pl.ds(pl.multiple_of(start, 8), group))
+
+            @pl.when(start < 0)
+            def _whole():
+                attend(slice(None))
+        return carry
+
+    jax.lax.fori_loop(0, count, pair, None)
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                  ).astype(o_ref.dtype)
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
                             layer, k_scale, v_scale, scale, interpret=False,
-                            window=None):
+                            window=None, walk=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -292,77 +518,71 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
     maxb = tables.shape[1]
     n_rep = n // kv
     quantized = k_scale is not None
+    if walk is None:
+        walk = tile_walk(tables, q_pos, bs, nb, n_rep, window)
+    tiles, wide, _ = walk.served.shape          # wide = rows * n_rep
+    rows = wide // n_rep
+    pairs = rows * maxb
 
-    q_pos = q_pos.astype(jnp.int32)
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    if window is None:
-        walk = _paged_walk(tables.astype(jnp.int32), q_pos, bs)
-        prefetch, ring, name = (walk, q_pos, layer), None, "paged_attention"
-    else:
-        size, ring = window
-        walk = _window_walk(tables.astype(jnp.int32), q_pos, bs, size, ring)
-        prefetch = (walk, q_pos, layer, (q_pos // size) * size)
-        name = "eva_attention"
+    # a tile's queries a K/V head at a time, the rows' n_rep query heads
+    # of it stacked: [tiles, KV, rows * n_rep, D], as the walk's rows lie
+    def by_tile(x):
+        x = jnp.pad(x, ((0, tiles * rows - t), (0, 0), (0, 0)))
+        return x.reshape(tiles, rows, kv, n_rep, d).swapaxes(1, 2).reshape(
+            tiles, kv, wide, d)
 
-    def block(ti, j, walk_s, *_):
-        w = walk_s[ti, j]
-        return jnp.where(w < 0, ~w, w)
+    def row_block(*last):
+        return pl.BlockSpec((None, wide) + last, lambda i, *_: (i, 0, 0))
 
-    # a block of the stacks: the layer's dim is squeezed out of the block,
-    # so the body sees one layer's [1, bs, ...] block as it always has
-    def stack5(ti, j, walk_s, qpos_s, layer_s, *_):
-        return (layer_s[0], block(ti, j, walk_s), 0, 0, 0)
+    def head_block():
+        return pl.BlockSpec((None, kv, wide, d), lambda i, *_: (i, 0, 0, 0))
 
-    def stack4(ti, j, walk_s, qpos_s, layer_s, *_):
-        return (layer_s[0], block(ti, j, walk_s), 0, 0)
-
-    def blk3(*idx):
-        return (block(*idx), 0, 0)
-
-    def tok(ti, j, *_):
-        return (ti, 0, 0, 0)
-
-    # Mosaic wants the last two block dims (8, 128)-aligned or whole: the
-    # positions (shared by the layers) ride as [nb, 1, bs] rows, the scales
-    # as [kv, bs] planes, and q/out as [T, n_rep, KV, D] so a replica is a
-    # leading index
-    in_specs = [
-        pl.BlockSpec((1, n_rep, kv, d), tok),
-        pl.BlockSpec((None, 1, bs, kv, d), stack5),
-        pl.BlockSpec((None, 1, bs, kv, d), stack5),
-        pl.BlockSpec((1, 1, bs), blk3),
-    ]
-    operands = [q.reshape(t, kv, n_rep, d).swapaxes(1, 2), k_pool, v_pool,
-                pool_pos.reshape(nb, 1, bs)]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [row_block(maxb), row_block(1)]
+    operands = [walk.served, walk.q_pos]
+    if window is not None:
+        in_specs.append(row_block(1))
+        operands.append(walk.q_lo)
+    # the stacks stay in HBM: the kernel copies the blocks it walks. The
+    # positions (shared by the layers) ride as [nb, 1, bs] rows
+    in_specs += [head_block(), hbm, hbm, hbm]
+    operands += [by_tile(q), k_pool, v_pool, pool_pos.reshape(nb, 1, bs)]
+    scratch = [pltpu.VMEM((2, bs, kv, d), k_pool.dtype),
+               pltpu.VMEM((2, bs, kv, d), v_pool.dtype),
+               pltpu.VMEM((2, 1, bs), jnp.int32)]
     if quantized:
         # slots on lanes is how the chip stores an array whose last dim is
         # a few heads wide, and how the step's scatter writes it: the swap
-        # is then no copy, where a [bs, kv] plane made the whole stack
-        # change layout in front of the kernel and back behind it
-        in_specs += [pl.BlockSpec((None, 1, kv, bs), stack4),
-                     pl.BlockSpec((None, 1, kv, bs), stack4)]
+        # is then no copy, and a head's scales are a row over the slots
+        in_specs += [hbm, hbm]
         operands += [k_scale.swapaxes(2, 3), v_scale.swapaxes(2, 3)]
+        scratch += [pltpu.VMEM((2, kv, bs), jnp.float32),
+                    pltpu.VMEM((2, kv, bs), jnp.float32)]
+    scratch += [pltpu.SemaphoreType.DMA((2, 5 if quantized else 3)),
+                pltpu.VMEM((kv, wide, 1), jnp.float32),
+                pltpu.VMEM((kv, wide, 1), jnp.float32),
+                pltpu.VMEM((kv, wide, d), jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(t, maxb),
+        num_scalar_prefetch=5,
+        grid=(tiles,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n_rep, kv, d), tok),
-        scratch_shapes=[pltpu.VMEM((n_rep, kv, 1), jnp.float32),
-                        pltpu.VMEM((n_rep, kv, 1), jnp.float32),
-                        pltpu.VMEM((n_rep, kv, d), jnp.float32)],
+        out_specs=head_block(),
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, num_blocks_per_seq=maxb,
-                          n_rep=n_rep, scale=scale, quantized=quantized,
-                          ring=ring),
+        functools.partial(_paged_kernel, pairs=pairs,
+                          group=narrow_rows(n_rep), scale=scale,
+                          quantized=quantized, window=window),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, n_rep, kv, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((tiles, kv, wide, d), q.dtype),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
-        name=name,
-    )(*prefetch, *operands)
-    return out.swapaxes(1, 2).reshape(t, n, d)
+        name="paged_attention" if window is None else "eva_attention",
+    )(walk.count, walk.blocks, walk.cols, walk.narrow,
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
+    return out.reshape(tiles, kv, rows, n_rep, d).swapaxes(1, 2).reshape(
+        tiles * rows, n, d)[:t]
 
 
 @functools.lru_cache(maxsize=None)
@@ -402,7 +622,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     scale: Optional[float] = None,
                     force_pallas: Optional[bool] = None,
                     combine_axis: Optional[str] = None,
-                    window: Optional[tuple] = None) -> jax.Array:
+                    window: Optional[tuple] = None,
+                    walk: Optional[TileWalk] = None) -> jax.Array:
     """Paged decode attention.
 
     ``q [T, N, D]`` one query row per packed token; ``k_pool``/``v_pool``
@@ -431,6 +652,9 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     own window, the later ones hold an earlier window's chunk summaries
     each, attended whole, under the one softmax. The kernel is then named
     ``eva_attention`` in a device trace. Not with ``combine_axis``.
+
+    ``walk``: the kernel's routing of this step (:func:`step_walk`), built
+    once for all layers; built here when not given.
     """
     t, n, d = q.shape
     _, nb, bs, kv, _ = k_pool.shape
@@ -458,4 +682,4 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables,
                                    q_pos, layer, k_scale, v_scale, scale_,
                                    interpret=impl == "pallas-interpret",
-                                   window=window)
+                                   window=window, walk=walk)

@@ -125,31 +125,58 @@ def test_kernel_names_are_the_device_instruction_names(chip):
 
 # -- paged attention: the packed serving step's shapes ----------------------
 
-# (tokens, kv heads, table columns, pool blocks): the smoke test's widths
-# at two packed widths, and the benchmark cell mixtral-8x7b.serve-batch
-_PAGED_SHAPES = {"T32": (32, 32, 16, 64), "T256": (256, 32, 16, 64),
-                 "serve_batch": (128, 8, 20, 320)}
+# (tokens, kv heads, table columns, pool blocks, window): the smoke test's
+# widths at two packed widths, the benchmark cells mixtral-8x7b.serve-batch
+# (and mistral-7b's: tiles of 32 rows) and evabyte.serve-docs (one tile of
+# 128 rows, the two masks), and a disaggregated prefill worker's width
+# that is no whole number of tiles
+_PAGED_SHAPES = {"T32": (32, 32, 16, 64, None), "T256": (256, 32, 16, 64, None),
+                 "serve_batch": (128, 8, 20, 320, None),
+                 "serve_docs": (128, 32, 40, 176, (2048, 17)),
+                 "T100": (100, 8, 20, 320, None)}
 
 
-@pytest.mark.parametrize("shape", list(_PAGED_SHAPES))
-@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+def _mosaic_ops(hlo_text):
+    """Names of the operations in the bodies of the Mosaic kernels of a
+    compiled program (the custom call carries its MLIR as bytecode, whose
+    string section holds them)."""
+    import base64
+    import re
+
+    bodies = [base64.b64decode(b) for b in re.findall(
+        r'"custom_call_config":\{"body":"([^"]+)"', hlo_text)]
+    assert bodies
+    return {op.decode() for body in bodies
+            for op in re.findall(rb"tpu\.[a-z_]+", body)}
+
+
+@pytest.mark.parametrize("quantized,shape", [
+    pytest.param(quantized, shape, id=f"{shape}-{'int8' if quantized else 'fp'}")
+    for quantized in (False, True) for shape, geometry in _PAGED_SHAPES.items()
+    if not (quantized and geometry[-1])])   # a window-summary pool is float
 def test_paged_attention(chip, quantized, shape):
     from neuronx_distributed_tpu.ops.paged_attention import (
         _paged_attention_pallas)
 
-    tokens, kv, cols, nb = _PAGED_SHAPES[shape]
+    tokens, kv, cols, nb, window = _PAGED_SHAPES[shape]
     n, d, bs, layers = 32, 128, 128, 2
     pool = chip((layers, nb, bs, kv, d),
                 jnp.int8 if quantized else jnp.bfloat16)
     scale = chip((layers, nb, bs, kv), jnp.float32) if quantized else None
     fn = functools.partial(_paged_attention_pallas,
-                           scale=1.0 / math.sqrt(d), interpret=False)
+                           scale=1.0 / math.sqrt(d), interpret=False,
+                           window=window)
     text = _assert_kernel_compiles(
         fn, chip((tokens, n, d), jnp.bfloat16), pool, pool,
         chip((nb, bs), jnp.int32), chip((tokens, cols), jnp.int32),
         chip((tokens,), jnp.int32), chip((), jnp.int32), scale, scale)
     # the benchmark's readers and its `correct` find the kernel by name
-    assert _kernel_instruction_names(text) == {"paged_attention"}
+    assert _kernel_instruction_names(text) == {
+        "eva_attention" if window else "paged_attention"}
+    # the products are the MXU's, the blocks the kernel's own copies, a
+    # head's rows a strided read of the block as the pool lays it
+    assert {"tpu.matmul", "tpu.enqueue_dma", "tpu.strided_load"} <= (
+        _mosaic_ops(text))
 
 
 # -- the paged forward: the pool rides the layer scan as its carry -----------

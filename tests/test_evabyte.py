@@ -39,6 +39,7 @@ from runners import serve  # noqa: E402
 
 sys.path.insert(0, HERE)
 import engine_parity  # noqa: E402
+from walk_checks import check_tile_walk  # noqa: E402
 
 W, C, BS = 32, 4, 8
 PUBLISHED = dict(
@@ -156,13 +157,17 @@ def test_paged_forward_matches_the_reference_across_window_ends(impl):
     np.testing.assert_allclose(got, want, atol=2e-5 * float(np.std(want)))
 
 
-def test_pallas_kernel_in_interpret_mode_equals_the_xla_path():
-    """Both masks and the bounded walk: a ring that has wrapped (stale rows
-    of two windows ago in a live column), summaries of two earlier windows
-    and a later window's summary column that must be skipped, unmapped
-    columns, a pad row."""
+@pytest.mark.parametrize("heads", [4, 64], ids=["one_tile", "two_tiles"])
+def test_pallas_kernel_in_interpret_mode_equals_the_xla_path(heads):
+    """Both masks and the walk: a ring that has wrapped (stale rows of two
+    windows ago in a live column), summaries of two earlier windows and a
+    later window's summary column that must be skipped, unmapped columns,
+    a pad row. With 64 query heads over the 4 K/V heads a tile is 8 rows
+    (``two_tiles``): the chunk of rows behind the first six then crosses
+    a window's end inside the second tile, whose rows share the ring's
+    blocks but not the summaries."""
     rng = np.random.RandomState(3)
-    nb, kv, d, maxb, t = 24, 4, 16, 10, 6
+    nb, kv, d, maxb = 24, 4, 16, 10
     k_pool = jnp.asarray(rng.randn(nb, BS, kv, d), jnp.float32)
     v_pool = jnp.asarray(rng.randn(nb, BS, kv, d), jnp.float32)
     length = 2 * W + 13                     # window 2, 13 positions into it
@@ -173,8 +178,10 @@ def test_pallas_kernel_in_interpret_mode_equals_the_xla_path():
         pos[table[KIND.column_of(p, BS)], p % BS] = p
     table[KIND.ring:KIND.ring + 3] = [15, 4, 20]    # windows 0, 1 and "2"
     q_pos = np.array([length - 1, length - 3, W + 5, 2 * W + 5, 2 * W,
-                      PAD_POSITION], np.int32)
-    args = (jnp.asarray(rng.randn(t, kv, d), jnp.float32), k_pool[None],
+                      PAD_POSITION] + list(range(2 * W - 4, 2 * W + 5)),
+                     np.int32)
+    t = len(q_pos)
+    args = (jnp.asarray(rng.randn(t, heads, d), jnp.float32), k_pool[None],
             v_pool[None], jnp.asarray(pos),
             jnp.asarray(np.tile(table, (t, 1))), jnp.asarray(q_pos), 0)
     kinds = KIND.column_kinds(np.tile(table, (t, 1)), np.arange(maxb),
@@ -187,11 +194,20 @@ def test_pallas_kernel_in_interpret_mode_equals_the_xla_path():
                               window=(W, KIND.ring))
     got = pa.paged_attention(*args, force_pallas=True, scale=0.25,
                              window=(W, KIND.ring))
-    np.testing.assert_allclose(got[:5], want[:5], atol=1e-5, rtol=1e-5)
-    walk = np.asarray(pa._window_walk(args[4], args[5], BS, W, KIND.ring))
-    np.testing.assert_array_equal(walk >= 0, kinds > 0)
-    # a skipped step names the block of the live step before it
-    assert (~walk[0, 7:]).tolist() == [4, 4, 4]
+    real = q_pos != PAD_POSITION
+    np.testing.assert_allclose(np.asarray(got)[real], np.asarray(want)[real],
+                               atol=1e-5, rtol=1e-5)
+    assert not np.asarray(got)[~real].any()
+    # the walk: every (row, column) of either kind by one pair of its tile
+    n_rep = heads // kv
+    rows = pa.tile_rows(n_rep, t)
+    assert -(-t // rows) == (1 if heads == 4 else 2)
+    walk = jax.tree_util.tree_map(np.asarray, pa.tile_walk(
+        args[4], args[5], BS, nb, n_rep, (W, KIND.ring)))
+    check_tile_walk(walk, kinds > 0, np.tile(table, (t, 1)), rows, n_rep)
+    # one slot's rows: a tile fetches a column once, however many attend it
+    assert walk.count.tolist() == [
+        int((kinds[i:i + rows] > 0).any(0).sum()) for i in range(0, t, rows)]
 
 
 @pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
@@ -252,7 +268,8 @@ def served():
     counters = {
         name: {c.labels.get("kind", ""): c.value
                for c in obs.get_registry().get(name).children()}
-        for name in ("nxd_eva_columns_total", "nxd_eva_windows_total")}
+        for name in ("nxd_eva_columns_total", "nxd_eva_windows_total",
+                     "nxd_paged_block_visits_total")}
     spans = {e["name"] for e in obs.get_tracer().chrome_trace()["traceEvents"]}
     obs.disable()
     ps.destroy_model_parallel()
@@ -297,6 +314,11 @@ def test_roll_span_and_eva_counters(served):
     # every window end a packed row held: a's two in its prompt and one
     # while decoding, b's one, and those run again after the preemption
     assert counters["nxd_eva_windows_total"][""] >= 4
+    # every exact or summary column a row attends is served by one fetch
+    # of its tile; a chunk's rows share theirs
+    visits = counters["nxd_paged_block_visits_total"]
+    assert sum(visits.values()) == cols["exact"] + cols["summary"]
+    assert visits["shared"] > visits["fetched"] > 0
 
 
 def test_sixteen_windows_hold_the_ring_and_sixteen_summary_blocks():

@@ -610,7 +610,10 @@ def test_engine_paged_columns_counter_sums_to_rows_times_columns():
     """Each packed step's ``nxd_paged_columns_total`` children sum to the
     worker's width x ``max_blocks_per_seq``, and ``live`` is what
     :func:`column_live` counts over the rows the step packed (a pad row
-    reads the last slot's table at PAD_POSITION, as the forward does)."""
+    is handed the last slot's table row and attends none of it).
+    ``nxd_paged_block_visits_total``'s children sum, a step, to that live
+    count: every live (row, column) is either the one its tile's fetch is
+    counted for or shares it. A pad row adds to neither counter."""
     from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
     from neuronx_distributed_tpu.ops.paged_attention import column_live
 
@@ -622,23 +625,36 @@ def test_engine_paged_columns_counter_sums_to_rows_times_columns():
 
     def spy(fn, rows, *args):
         pads = [(-1, PAD_POSITION)] * (width - len(rows))
-        packed.append(sum(
-            int(column_live(eng._tables[slot], np.arange(maxb), pos,
-                            eng.ecfg.block_size).sum())
-            for slot, pos in [(r[0].slot, r[2]) for r in rows] + pads))
+        live = [column_live(eng._tables[slot], np.arange(maxb), pos,
+                            eng.ecfg.block_size)
+                for slot, pos in [(r[0].slot, r[2]) for r in rows] + pads]
+        assert not np.asarray(live[len(rows):]).any()   # pad rows: nothing
+        # one tile (8 rows): a block is fetched once a (column, block)
+        distinct = {(c, int(eng._tables[r[0].slot, c]))
+                    for r, row in zip(rows, live) for c in np.flatnonzero(row)}
+        packed.append((int(np.sum(live)), len(distinct)))
         return run_worker(fn, rows, *args)
+
+    def read(name):
+        return {c.labels["kind"]: c.value
+                for c in obs.get_registry().get(name).children()}
 
     eng._run_worker = spy
     before = {"live": 0, "skipped": 0}
+    visits = {"fetched": 0, "shared": 0}
     while eng.has_work():
         if not eng.step():
             continue
-        now = {c.labels["kind"]: c.value for c in obs.get_registry().get(
-            "nxd_paged_columns_total").children()}
-        assert now["live"] - before["live"] == packed.pop() > 0
+        now, seen = read("nxd_paged_columns_total"), read(
+            "nxd_paged_block_visits_total")
+        live, fetched = packed.pop()
+        assert now["live"] - before["live"] == live > 0
         assert sum(now.values()) - sum(before.values()) == width * maxb
-        before = now
+        assert seen["fetched"] - visits["fetched"] == fetched
+        assert sum(seen.values()) - sum(visits.values()) == live
+        before, visits = now, seen
     assert before["skipped"] > before["live"]
+    assert visits["shared"] > 0         # a prefill chunk's rows share blocks
 
 
 def test_engine_with_obs_off_records_no_span_and_no_rows_counter():

@@ -20,10 +20,13 @@ from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
                                                   tiny_config)
 from neuronx_distributed_tpu.models.mixtral import (MixtralForCausalLM,
                                                     tiny_moe_config)
-from neuronx_distributed_tpu.ops.paged_attention import (_paged_walk,
-                                                          column_live,
-                                                          paged_attention)
+from neuronx_distributed_tpu.ops.paged_attention import (column_live,
+                                                          paged_attention,
+                                                          tile_pairs,
+                                                          tile_rows,
+                                                          tile_walk)
 from neuronx_distributed_tpu.parallel import mesh as ps
+from walk_checks import check_tile_walk
 
 
 # ---------------------------------------------------------------------------
@@ -131,11 +134,27 @@ _RAGGED_CASES = {
     "ragged": [("a", 13), ("b", 5), ("hole", 5), ("c", 10), ("b", 0),
                ("hole", 1), ("a", 7)],
 }
+_PAD = ("unmapped", PAD_POSITION)
+#: the same, at 32 query heads over 2 K/V heads: tiles of 8 rows
+#: (:func:`tile_rows`), so that a few rows are several tiles
+_TILED_CASES = {
+    "chunk_spans_two_tiles": [("a", p) for p in range(3, 15)],
+    "decode_rows_of_different_slots": [
+        ("a", 13), ("b", 5), ("c", 10), ("hole", 5), ("a", 7), ("b", 3),
+        ("c", 9), ("hole", 1), ("c", 3)],
+    "chunk_beside_decode_rows": [("b", 5), ("c", 10), ("hole", 4)] + [
+        ("a", p) for p in range(6, 14)],
+    "pad_rows_in_the_middle_and_at_the_end": [
+        ("a", 6), _PAD, ("b", 5), _PAD, _PAD, ("a", 9), ("c", 10), _PAD,
+        ("c", 8), ("a", 15), _PAD, _PAD],
+    "shared_prefix_block_in_one_tile": [
+        ("a", 5), ("c", 5), ("a", 9), ("c", 9), ("c", 10), ("a", 7)],
+}
 
 
-def _paged_case(rows, quantized=False, seed=1):
+def _paged_case(rows, quantized=False, seed=1, heads=(4, 2)):
     rng = np.random.RandomState(seed)
-    N, D, KV = 4, 16, 2
+    (N, KV), D = heads, 16
     q = jnp.asarray(rng.randn(len(rows), N, D).astype(np.float32))
     # the pools are stacks of one layer
     k = jnp.asarray(rng.randn(1, _NB, _BS, KV, D).astype(np.float32))
@@ -170,6 +189,60 @@ def test_paged_attention_pallas_interpret_matches_xla(quantized, case):
     np.testing.assert_allclose(np.asarray(ker)[real], np.asarray(ref)[real],
                                rtol=1e-5, atol=1e-5)
     assert not np.asarray(ker)[~real].any()
+
+
+@pytest.mark.parametrize("case", list(_TILED_CASES))
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+def test_paged_kernel_tiles_match_xla(quantized, case):
+    """The kernel's unit is (a tile of rows, a pool block some row of it
+    attends): the cases put a chunk across two tiles, a tile of rows that
+    share nothing, a chunk beside decode rows, pad rows between real ones
+    and two slots' shared prefix block in one tile. A pad row's output is
+    zero, and takes nothing from and adds nothing to the real rows: with
+    the pad rows left out the real rows' outputs are the same bits."""
+    rows = _TILED_CASES[case]
+    assert tile_rows(16, len(rows)) == 8
+    q, k, v, pp, tb, qp, ks, vs = _paged_case(rows, quantized, heads=(32, 2))
+    kw = dict(k_scale=ks, v_scale=vs)
+    ref = paged_attention(q, k, v, pp, tb, qp, 0, force_pallas=False, **kw)
+    ker = np.asarray(paged_attention(q, k, v, pp, tb, qp, 0,
+                                     force_pallas=True, **kw))
+    real = np.asarray([r != _PAD for r in rows])
+    np.testing.assert_allclose(ker[real], np.asarray(ref)[real], rtol=1e-5,
+                               atol=1e-5)
+    assert not ker[~real].any()
+    if not real.all():
+        alone = paged_attention(q[real], k, v, pp, tb[real], qp[real], 0,
+                                force_pallas=True, **kw)
+        np.testing.assert_array_equal(ker[real], np.asarray(alone))
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_paged_kernel_reads_heads_that_share_a_word(pool):
+    """bf16 and int8 rows lie two and four to a 32-bit sublane of the
+    block: with four K/V heads the kernel reads them as words by a strided
+    load and takes them apart (``_head_rows``), widened exactly: what
+    is left against the reference is the bf16 output's rounding."""
+    rng = np.random.RandomState(0)
+    T, N, KV, D, NB, BS = 10, 8, 4, 16, 6, 8
+    q, k, v = (jnp.asarray(rng.randn(*shape), jnp.bfloat16) for shape in
+               ((T, N, D), (1, NB, BS, KV, D), (1, NB, BS, KV, D)))
+    ks = vs = None
+    if pool == "int8":
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+    pos = np.tile(np.arange(BS), (NB, 1))
+    pos[[1, 4]] += BS
+    pos[2] += 2 * BS
+    tables = jnp.asarray([[0, 1, 2]] * 5 + [[3, 4, -1]] * 5, jnp.int32)
+    q_pos = jnp.asarray([3, 9, 17, 23, 12, 2, 8, 15, 11, PAD_POSITION],
+                        jnp.int32)
+    ref, ker = (np.asarray(paged_attention(
+        q, k, v, jnp.asarray(pos, jnp.int32), tables, q_pos, 0, k_scale=ks,
+        v_scale=vs, force_pallas=force).astype(jnp.float32))
+        for force in (False, True))
+    np.testing.assert_allclose(ker[:9], ref[:9],
+                               atol=4e-3 if pool == "bf16" else 1e-2)
+    assert not ker[9].any()
 
 
 @pytest.mark.parametrize("kind", ["full", "int8", "window"])
@@ -226,7 +299,8 @@ def test_paged_attention_ignores_columns_behind_the_row(quantized, rewire):
 
 def test_column_live_matches_brute_count():
     """A column is live iff it is mapped and holds at least one position
-    the row may attend to, counted position by position."""
+    the row may attend to, counted position by position; a pad row
+    attends none."""
     rng = np.random.RandomState(4)
     bs, maxb, rows = 4, 5, 64
     tables = rng.randint(-1, 6, (rows, maxb))
@@ -236,33 +310,55 @@ def test_column_live_matches_brute_count():
     want = np.zeros((rows, maxb), bool)
     for r in range(rows):
         for c in range(maxb):
-            want[r, c] = tables[r, c] >= 0 and any(
-                p <= q_pos[r] for p in range(c * bs, (c + 1) * bs))
+            want[r, c] = (tables[r, c] >= 0 and q_pos[r] != PAD_POSITION
+                          and any(p <= q_pos[r]
+                                  for p in range(c * bs, (c + 1) * bs)))
     np.testing.assert_array_equal(got, want)
+    assert tables[3].max() >= 0 and not got[3].any()
     # with the mapped columns a prefix, as the engine maps them, the count
     # has a closed form
     prefix = np.sort(tables >= 0, axis=1)[:, ::-1]
     np.testing.assert_array_equal(
         column_live(np.where(prefix, 0, -1), np.arange(maxb),
                     q_pos[:, None], bs).sum(axis=1),
-        np.minimum(q_pos // bs + 1, prefix.sum(axis=1)))
+        np.where(q_pos == PAD_POSITION, 0,
+                 np.minimum(q_pos // bs + 1, prefix.sum(axis=1))))
 
 
-def test_paged_walk_fetches_live_columns_and_repeats_behind_them():
-    """The kernel's walk: a live column names its own block; a skipped one
-    carries the complement of the block it names, which behind the row's
-    last causal column is that column's (no fresh DMA), 0 if unmapped."""
-    bs = 4
-    tables = np.asarray([[3, 5, 7, 9], [3, -1, 7, -1], [-1] * 4,
-                         [0, 2, -1, -1]])
-    q_pos = np.asarray([6, 9, PAD_POSITION, 40])
-    walk = np.asarray(_paged_walk(jnp.asarray(tables, jnp.int32),
-                                  jnp.asarray(q_pos, jnp.int32), bs))
-    assert walk.tolist() == [[3, 5, ~5, ~5], [3, ~0, 7, ~7], [~0] * 4,
-                             [0, 2, ~0, ~0]]
-    live = column_live(tables, np.arange(4), q_pos[:, None], bs)
-    np.testing.assert_array_equal(walk >= 0, live)
-    np.testing.assert_array_equal(walk[live], tables[live])
+@pytest.mark.parametrize("case", ["random_tables", "shared_prefix",
+                                  "pad_rows", "one_slot_chunk"])
+def test_tile_walk_serves_every_live_column_by_one_pair_of_its_tile(case):
+    rng = np.random.RandomState(6)
+    bs, maxb, nb, t, n_rep = 4, 5, 9, 21, 16
+    rows = tile_rows(n_rep, t)
+    assert rows == 8
+    tables = rng.randint(-1, nb, (t, maxb))
+    q_pos = rng.randint(0, bs * (maxb + 1), (t,))
+    if case == "shared_prefix":
+        tables[:, :2] = [2, 7]              # every slot's first two blocks
+    elif case == "pad_rows":
+        q_pos[[0, 3, 4, 11, 19, 20]] = PAD_POSITION
+    elif case == "one_slot_chunk":
+        tables[2:19] = [1, 3, 5, 8, -1]
+        q_pos[2:19] = 2 + np.arange(17)
+    walk = jax.tree_util.tree_map(np.asarray, tile_walk(
+        jnp.asarray(tables, jnp.int32), jnp.asarray(q_pos, jnp.int32), bs,
+        nb, n_rep))
+    live = column_live(tables, np.arange(maxb), q_pos[:, None], bs)
+    check_tile_walk(walk, live, tables, rows, n_rep)
+    # the engine counts with the same function over NumPy arrays
+    count, blocks, cols = tile_pairs(np.where(live, tables, -1), rows, nb,
+                                     xp=np)
+    np.testing.assert_array_equal(count, walk.count)
+    for i, n in enumerate(count):
+        np.testing.assert_array_equal(
+            blocks[i, :n], walk.blocks.reshape(len(count), -1)[i, :n])
+        np.testing.assert_array_equal(
+            cols[i, :n], walk.cols.reshape(len(count), -1)[i, :n])
+    if case == "one_slot_chunk":
+        # a tile of one slot's rows fetches each of its blocks once
+        assert count[1] == int(live[8:16].any(0).sum())
+        assert int(live[8:16].sum()) > 2 * count[1]
 
 
 def test_paged_attention_validates_scales_and_heads():

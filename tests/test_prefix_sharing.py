@@ -29,9 +29,12 @@ from neuronx_distributed_tpu.inference.router import (ReplicaRouter,
                                                       RouterConfig)
 from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
                                                   tiny_config)
-from neuronx_distributed_tpu.ops.paged_attention import paged_attention
+from neuronx_distributed_tpu.ops.paged_attention import (column_live,
+                                                          paged_attention,
+                                                          tile_walk)
 from neuronx_distributed_tpu.parallel import mesh as ps
 from neuronx_distributed_tpu.resilience.chaos import FaultPlan
+from walk_checks import check_tile_walk
 
 
 @pytest.fixture
@@ -272,6 +275,17 @@ def test_paged_attention_invariant_under_shared_tables(force_pallas):
                                   0, force_pallas=force_pallas)
     np.testing.assert_array_equal(np.asarray(out_shared),
                                   np.asarray(out_private))
+    # membership in a tile's pair is by the row's own table entry, not by
+    # its slot: the two rows' tile fetches the block they share once, and
+    # the private copies once each
+    live = column_live(np.asarray(shared), np.arange(3),
+                       np.asarray(q_pos)[:, None], BS)
+    for tables, blocks in ((shared, [0, 1, 2]), (private, [0, 1, 2, 7])):
+        walk = jax.tree_util.tree_map(
+            np.asarray, tile_walk(tables, q_pos, BS, NB, N // 2))
+        assert walk.count.tolist() == [len(blocks)]
+        assert sorted(walk.blocks[:len(blocks)].tolist()) == blocks
+        check_tile_walk(walk, live, np.asarray(tables), 8, N // 2)
 
 
 # ---------------------------------------------------------------------------
