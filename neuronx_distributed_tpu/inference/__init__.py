@@ -39,8 +39,8 @@ from .model_builder import (ModelBuilder, NxDModel, bundle_generate,
                             bundle_speculative_generate, generate_buckets,
                             register_serving_workers, serving_state_spec,
                             shard_checkpoint)
-from .paging import (BlockAllocator, CacheExhaustedError, PagedKVCache,
-                     PrefixCache, QuantizedPagedKVCache,
+from .paging import (BlockAllocator, CacheExhaustedError, LatentPagedCache,
+                     PagedKVCache, PrefixCache, QuantizedPagedKVCache,
                      SparseStatePagedCache, cow_copy_blocks,
                      init_paged_kv_cache, init_quantized_paged_kv_cache,
                      init_serving_cache)
@@ -60,6 +60,7 @@ __all__ = [
     "KVCache", "init_kv_cache",
     "BlockAllocator", "CacheExhaustedError", "PagedKVCache",
     "PrefixCache", "QuantizedPagedKVCache", "SparseStatePagedCache",
+    "LatentPagedCache",
     "init_serving_cache", "cow_copy_blocks",
     "init_paged_kv_cache", "init_quantized_paged_kv_cache",
     "ServingEngine", "EngineConfig", "EngineStats", "RequestRejected",

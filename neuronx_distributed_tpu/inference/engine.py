@@ -693,6 +693,12 @@ class ServingEngine:
         #: live (row, column) each fetch served
         #: (``nxd_paged_block_visits_total``)
         self._block_visits = np.zeros((2,), np.int64)
+        #: routed-expert assignments of the real rows that were kept and
+        #: that were dropped, which the step of a family that declares
+        #: ``moe_counts`` counts on the device (``cache.moe_counts``) and
+        #: the fetch adds here (``nxd_moe_assignments_total``)
+        self._moe_on_device = family.moe_counts
+        self._moe_assignments = np.zeros((2,), np.int64)
         #: windows a window-summary cache rolled (``nxd_eva_windows_total``)
         #: or rows at position 0 of a sparse-state cache, each of which
         #: starts a slot's state anew (``nxd_state_resets_total``)
@@ -1987,11 +1993,16 @@ class ServingEngine:
             if by_device:
                 # on its way while the host waits for the tokens
                 self.cache.counts.copy_to_host_async()
+            moe_counted = counted and self._moe_on_device
+            if moe_counted:
+                self.cache.moe_counts.copy_to_host_async()
         with tracer.span(span + "/fetch"):
             # the host blocks here until the device has finished the step
             sampled = np.asarray(sampled)
             if by_device:
                 self._paged_cols += np.asarray(self.cache.counts)
+            if moe_counted:
+                self._moe_assignments += np.asarray(self.cache.moe_counts)
             return sampled
 
     def _maybe_insert_prefix(self, req: _RequestState) -> None:
@@ -2420,6 +2431,17 @@ class ServingEngine:
                 labels=("kind",))
             visits_by_kind = () if visits_c is None else tuple(
                 visits_c.labels(kind=k) for k in ("fetched", "shared"))
+            moe_by_kind = () if not self._moe_on_device else tuple(
+                reg.counter(
+                    "nxd_moe_assignments_total",
+                    "Routed-expert assignments (a real row's choice of an "
+                    "expert, top_k a row an expert layer) of the serving "
+                    "workers' rows by whether the dispatch gave them a "
+                    "slot (kept) or had none left (dropped). Pad rows "
+                    "choose nothing. Counted on the device, fetched with "
+                    "the step's tokens.",
+                    labels=("kind",)).labels(kind=k)
+                for k in ("kept", "dropped"))
             cache = self._obs_cache = (
                 reg, reg.generation,
                 {f: stats_g.labels(field=f)
@@ -2429,9 +2451,9 @@ class ServingEngine:
                 step_h,
                 tuple(rows_c.labels(kind=k)
                       for k in ("decode", "prefill", "pad")),
-                cols_by_kind, events_c, visits_by_kind)
+                cols_by_kind, events_c, visits_by_kind, moe_by_kind)
         (_, _, fields, free_g, step_h, rows_by_kind, cols_by_kind,
-         events_c, visits_by_kind) = cache
+         events_c, visits_by_kind, moe_by_kind) = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
@@ -2446,6 +2468,9 @@ class ServingEngine:
         for child, n in zip(visits_by_kind, self._block_visits):
             child.inc(int(n))
         self._block_visits[:] = 0
+        for child, n in zip(moe_by_kind, self._moe_assignments):
+            child.inc(int(n))
+        self._moe_assignments[:] = 0
         if events_c is not None:
             events_c.inc(self._kind_events)
         self._kind_events = 0

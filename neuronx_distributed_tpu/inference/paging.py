@@ -219,20 +219,80 @@ class SparseStateCache(FullCache):
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentCache(FullCache):
+    """A full cache whose row is no ``KV x D`` pair: one latent and one
+    rotary key, shared by every query head, laid on ``row`` whole lanes
+    (the lanes past the two stay zero). There is no V pool: a value is
+    the latent's lanes of the same row (:mod:`..ops.mla_attention`). Every position stays, so block
+    mapping, allocation, copy-on-write and preemption are
+    :class:`FullCache`'s."""
+
+    row: int = 640
+    name = "latent"
+
+    def init_cache(self, model_cfg, *, num_blocks: int, block_size: int,
+                   table_rows: int, max_blocks_per_seq: int, dtype: Any,
+                   quantized: bool = False) -> "LatentPagedCache":
+        if quantized:
+            raise ValueError("a latent cache has no int8 pool: a row's "
+                             "latent and rotary key want scales of their "
+                             "own, and the kernel reads neither")
+        return LatentPagedCache(
+            rows=jnp.zeros((model_cfg.num_layers, num_blocks, block_size,
+                            self.row), dtype),
+            moe_counts=(jnp.zeros((2,), jnp.int32)
+                        if model_cfg.serving_family().moe_counts else None),
+            pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
+            block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
+                                  jnp.int32),
+            lengths=jnp.zeros((table_rows,), jnp.int32),
+            block_size=block_size)
+
+
+@dataclasses.dataclass(frozen=True)
 class ServingFamily:
     """What :class:`.engine.ServingEngine` asks of a model config
     (``model_cfg.serving_family()``): the cached forward with the
     ``llama_forward_with_cache`` paged signature, the cache kind its
     table rows follow, and the engine features the family cannot serve,
     each with why (refused by name at construction: ``prefix_sharing``,
-    ``speculation``, ``cp``, ``quantized``, ``session_export``)."""
+    ``speculation``, ``cp``, ``quantized``, ``session_export``).
+    ``moe_counts``: the family's forward leaves in the cache's
+    ``moe_counts [2]`` the routed-expert assignments of the step's real
+    rows that were kept and that were dropped, which the engine fetches
+    with the step's tokens (``nxd_moe_assignments_total``); the family's
+    cache kind builds the leaf."""
 
     forward: Callable
     cache_kind: Any = FULL_CACHE
     unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
+    moe_counts: bool = False
 
 
-class PagedKVCache(struct.PyTreeNode):
+class _BlockPool:
+    """The geometry of a cache whose pool leaves (``POOL_LEAVES``: what
+    copy-on-write clones and block transport ships) are ``[layers,
+    num_blocks, block_size, ...]``."""
+
+    @property
+    def num_blocks(self) -> int:
+        return getattr(self, self.POOL_LEAVES[0]).shape[1]
+
+    @property
+    def capacity(self) -> int:
+        shape = getattr(self, self.POOL_LEAVES[0]).shape
+        return shape[1] * shape[2]
+
+    @property
+    def max_slots(self) -> int:
+        return self.block_tables.shape[0]
+
+    @property
+    def max_blocks_per_seq(self) -> int:
+        return self.block_tables.shape[1]
+
+
+class PagedKVCache(_BlockPool, struct.PyTreeNode):
     """Shared-pool paged cache.
 
     ``k``/``v`` ``[L, num_blocks, block_size, KV, D]``; ``pos``
@@ -249,25 +309,10 @@ class PagedKVCache(struct.PyTreeNode):
     block_tables: jax.Array
     lengths: jax.Array
     block_size: int = struct.field(pytree_node=False, default=16)
-
-    @property
-    def num_blocks(self) -> int:
-        return self.k.shape[1]
-
-    @property
-    def capacity(self) -> int:
-        return self.k.shape[1] * self.k.shape[2]
-
-    @property
-    def max_slots(self) -> int:
-        return self.block_tables.shape[0]
-
-    @property
-    def max_blocks_per_seq(self) -> int:
-        return self.block_tables.shape[1]
+    POOL_LEAVES = ("k", "v")
 
 
-class QuantizedPagedKVCache(struct.PyTreeNode):
+class QuantizedPagedKVCache(_BlockPool, struct.PyTreeNode):
     """Int8 pool variant: K/V int8 with one fp32 scale per pool vector
     (``[L, num_blocks, block_size, KV]``), same symmetric per-vector
     scheme as :class:`.kv_cache.QuantizedKVCache` (``quantize_kv``)."""
@@ -280,22 +325,26 @@ class QuantizedPagedKVCache(struct.PyTreeNode):
     block_tables: jax.Array
     lengths: jax.Array
     block_size: int = struct.field(pytree_node=False, default=16)
+    POOL_LEAVES = ("k", "v", "k_scale", "v_scale")
 
-    @property
-    def num_blocks(self) -> int:
-        return self.k.shape[1]
 
-    @property
-    def capacity(self) -> int:
-        return self.k.shape[1] * self.k.shape[2]
+class LatentPagedCache(_BlockPool, struct.PyTreeNode):
+    """The cache of :class:`LatentCache`. ``rows`` ``[L, num_blocks,
+    block_size, row]``: a position's latent, its rotary key and idle
+    lanes, one row for all heads, and the only leaf that holds the
+    sequence (no V, nothing a head); ``moe_counts [2]``, where the family
+    declares it (:class:`ServingFamily`; else None), the routed experts'
+    assignments of the last step's real rows that were kept and that were
+    dropped, summed over the expert layers; ``pos``, ``block_tables`` and
+    ``lengths`` as :class:`PagedKVCache`."""
 
-    @property
-    def max_slots(self) -> int:
-        return self.block_tables.shape[0]
-
-    @property
-    def max_blocks_per_seq(self) -> int:
-        return self.block_tables.shape[1]
+    rows: jax.Array
+    moe_counts: Optional[jax.Array]
+    pos: jax.Array
+    block_tables: jax.Array
+    lengths: jax.Array
+    block_size: int = struct.field(pytree_node=False, default=128)
+    POOL_LEAVES = ("rows",)
 
 
 class SparseStatePagedCache(struct.PyTreeNode):
@@ -385,6 +434,22 @@ class SparseLayerView(struct.PyTreeNode):
     tables: jax.Array
     write_idx: jax.Array
     q_pos: jax.Array
+
+
+class LatentLayerView(struct.PyTreeNode):
+    """What a latent-attention layer is handed in :class:`PagedCacheView`'s
+    place: the row stack (the layer scan's carry), the layer's index in
+    it, the pool's positions, the per-token block tables, the flat write
+    indices, the rows' true positions (PAD_POSITION for padding) and the
+    kernel's walk of the step (None where the XLA path serves)."""
+
+    rows: jax.Array
+    layer: jax.Array
+    pos: jax.Array
+    tables: jax.Array
+    write_idx: jax.Array
+    q_pos: jax.Array
+    walk: Any = None
 
 
 class StateLayerView(struct.PyTreeNode):
@@ -506,7 +571,8 @@ def init_serving_cache(model_cfg, *, num_blocks: int, block_size: int,
     its draft model's pool. A full and a window-summary cache are
     :func:`init_paged_kv_cache`'s (``quantized``:
     :func:`init_quantized_paged_kv_cache`'s) pytree; a
-    :class:`SparseStateCache` is a :class:`SparseStatePagedCache`."""
+    :class:`SparseStateCache` is a :class:`SparseStatePagedCache`, a
+    :class:`LatentCache` a :class:`LatentPagedCache`."""
     return model_cfg.serving_family().cache_kind.init_cache(
         model_cfg, num_blocks=num_blocks, block_size=block_size,
         table_rows=table_rows, max_blocks_per_seq=max_blocks_per_seq,
@@ -1008,7 +1074,8 @@ class PrefixCache:
 #: axis 1, ``pos`` is ``[blocks, block_size]``. Single source of truth for
 #: per-block integrity fingerprints over shipped payloads
 #: (``resilience.integrity.kv_payload_fingerprints``).
-PAYLOAD_BLOCK_AXES = {"k": 1, "v": 1, "pos": 0, "k_scale": 1, "v_scale": 1}
+PAYLOAD_BLOCK_AXES = {"k": 1, "v": 1, "pos": 0, "k_scale": 1, "v_scale": 1,
+                      "rows": 1}
 
 
 def extract_blocks(cache: Any, blocks: Sequence[int],
@@ -1026,12 +1093,9 @@ def extract_blocks(cache: Any, blocks: Sequence[int],
     idx = jnp.asarray(list(blocks), jnp.int32)
     pos = jnp.take(cache.pos, idx, axis=0)
     pos = jnp.where(pos < keep_upto, pos, PAD_POSITION)
-    payload = {"k": jnp.take(cache.k, idx, axis=1),
-               "v": jnp.take(cache.v, idx, axis=1),
-               "pos": pos}
-    if isinstance(cache, QuantizedPagedKVCache):
-        payload["k_scale"] = jnp.take(cache.k_scale, idx, axis=1)
-        payload["v_scale"] = jnp.take(cache.v_scale, idx, axis=1)
+    payload = {name: jnp.take(getattr(cache, name), idx, axis=1)
+               for name in cache.POOL_LEAVES}
+    payload["pos"] = pos
     return {name: jax.device_get(arr) for name, arr in payload.items()}
 
 
@@ -1046,16 +1110,11 @@ def inject_blocks(cache: Any, blocks: Sequence[int],
             f"payload carries {payload['pos'].shape[0]} block(s) but "
             f"{len(blocks)} destination ids were given")
     idx = jnp.asarray(list(blocks), jnp.int32)
-    updates = dict(
-        k=cache.k.at[:, idx].set(jnp.asarray(payload["k"], cache.k.dtype)),
-        v=cache.v.at[:, idx].set(jnp.asarray(payload["v"], cache.v.dtype)),
-        pos=cache.pos.at[idx].set(jnp.asarray(payload["pos"], jnp.int32)))
-    if isinstance(cache, QuantizedPagedKVCache):
-        updates.update(
-            k_scale=cache.k_scale.at[:, idx].set(
-                jnp.asarray(payload["k_scale"], jnp.float32)),
-            v_scale=cache.v_scale.at[:, idx].set(
-                jnp.asarray(payload["v_scale"], jnp.float32)))
+    updates = {name: getattr(cache, name).at[:, idx].set(
+        jnp.asarray(payload[name], getattr(cache, name).dtype))
+        for name in cache.POOL_LEAVES}
+    updates["pos"] = cache.pos.at[idx].set(
+        jnp.asarray(payload["pos"], jnp.int32))
     return cache.replace(**updates)
 
 
@@ -1081,8 +1140,6 @@ def cow_copy_blocks(cache: Any, src: jax.Array, dst: jax.Array,
     rows_pos = jnp.take(cache.pos, src, axis=0)
     rows_pos = jnp.where(rows_pos < keep_upto[:, None], rows_pos,
                          PAD_POSITION)
-    updates = dict(k=cp(cache.k), v=cp(cache.v),
-                   pos=cache.pos.at[dst].set(rows_pos, mode="drop"))
-    if isinstance(cache, QuantizedPagedKVCache):
-        updates.update(k_scale=cp(cache.k_scale), v_scale=cp(cache.v_scale))
+    updates = {name: cp(getattr(cache, name)) for name in cache.POOL_LEAVES}
+    updates["pos"] = cache.pos.at[dst].set(rows_pos, mode="drop")
     return cache.replace(**updates)

@@ -88,7 +88,7 @@ def _quant_lm_head(cfg: "LlamaConfig", gather_output: bool, name=None):
         param_dtype=cfg.param_dtype, **kw)
 
 
-ATTENTION_KINDS = ("full", "eva", "sparse", "lightning")
+ATTENTION_KINDS = ("full", "eva", "sparse", "lightning", "mla")
 
 
 @dataclass(frozen=True)
@@ -190,8 +190,10 @@ class LlamaConfig:
     # "sparse" (exact softmax over the blocks a top-k selection over
     # compressed keys picks: ops/sparse_attention.py; the config carries
     # ``sparse``, its SparseSpec) or "lightning" (a decayed outer-product
-    # state: ops/lightning_attention.py). A model whose layers differ in
-    # kind (models/minicpm_sala.py) derives one config a kind.
+    # state: ops/lightning_attention.py) or "mla" (a latent and a rotary
+    # key shared by the heads, kv_b absorbed: models/glm_moe_lite.py's
+    # LatentAttention in LlamaAttention's place). A model whose layers
+    # differ in kind (models/minicpm_sala.py) derives one config a kind.
     attention_kind: str = "full"
     # per-head RMSNorm of q and k before the rotary embedding
     qk_norm: bool = False
@@ -215,12 +217,20 @@ class LlamaConfig:
 
         return ServingFamily(forward=llama_forward_with_cache)
 
-    def feed_forward(self, h: jax.Array, tp_sync: bool = True):
+    def feed_forward(self, h: jax.Array, tp_sync: bool = True, valid=None):
         """A decoder layer's feed-forward over its post-norm hidden
         states: ``(output, router aux pair or None)``. Called inside
         :class:`LlamaDecoderLayer`'s scope, which the block it builds
-        thereby joins under its own name."""
+        thereby joins under its own name. ``valid`` (bool, ``h``'s shape
+        without its last dimension, or None) marks the real rows of a
+        packed serving step, for a feed-forward that must tell them from
+        the pad rows."""
         return LlamaMLP(self, tp_sync=tp_sync, name="mlp")(h), None
+
+    def attention(self, tp_sync: bool = True):
+        """The decoder layer's attention module, under the scope name
+        ``attn``."""
+        return LlamaAttention(self, tp_sync=tp_sync, name="attn")
 
     def __post_init__(self) -> None:
         if self.attention_kind not in ATTENTION_KINDS:
@@ -877,12 +887,12 @@ class LlamaDecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, cos: jax.Array, sin: jax.Array,
                  positions: Optional[jax.Array] = None,
-                 cache=None, cache_index=None):
+                 cache=None, cache_index=None, valid=None):
         cfg = self.cfg
         h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
                     sequence_parallel=cfg.sequence_parallel,
                     name="input_norm")(x)
-        attn_out = LlamaAttention(cfg, tp_sync=self.tp_sync, name="attn")(
+        attn_out = cfg.attention(self.tp_sync)(
             h, cos, sin, positions, cache=cache, cache_index=cache_index)
         new_cache = None
         if cache is not None:
@@ -893,10 +903,58 @@ class LlamaDecoderLayer(nn.Module):
         h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
                     sequence_parallel=cfg.sequence_parallel,
                     name="post_norm")(x)
-        ff_out, aux = cfg.feed_forward(h, self.tp_sync)
+        ff_out, aux = cfg.feed_forward(h, self.tp_sync, valid)
         if cfg.residual_scale != 1.0:
             ff_out = ff_out * cfg.residual_scale
         return x + ff_out, aux, new_cache
+
+
+def run_layers(cfg, stacks, x, cos, sin, carried, carry=None, view_of=None,
+               merge=None, valid=None):
+    """The layer pattern of a model whose layers differ in kind: one
+    ``lax.scan`` a run of like layers (``cfg.runs()``: ``(kind, first,
+    count)``, ``first`` the run's first index in its kind's stack), each
+    kind's layer :class:`LlamaDecoderLayer` under ``cfg.kind_config(kind)``.
+    ``stacks[kind]`` is that kind's parameter stack (leaves lead with the
+    kind's depth); ``carry`` a dict of the cache's stacks, handed from
+    layer to layer and run to run (None: no cache), ``carried[kind]`` the
+    names of those a layer of ``kind`` reads and writes (the carry of its
+    run's scan; the rest passes the run by) and ``view_of(kind, carried,
+    layer)`` the view a layer is given of them; what the layer hands back
+    under a carried name replaces it, or ``merge(carried, new view, aux)``
+    says what does (``aux`` what the layer's feed-forward returned beside
+    its output). ``valid`` (bool ``[1, T]`` or None) marks the packed
+    step's real rows for the layers' feed-forward."""
+    for kind, first, count in cfg.runs():
+        layer = LlamaDecoderLayer(cfg.kind_config(kind))
+        stack = stacks[kind]["layer"]
+
+        def body(state, i, layer=layer, stack=stack, kind=kind):
+            h, cache = state
+            # a run of one layer is no loop once compiled, and its index a
+            # constant: behind the barrier it stays an index, and the
+            # layer's weights are read where they lie in the stack, as a
+            # longer run reads them (a constant index made each a slice:
+            # a copy of the layer's weights, every step)
+            i = jax.lax.optimization_barrier(i)
+            weights = jax.tree_util.tree_map(lambda w: w[i], stack)
+            view = None if cache is None else view_of(kind, cache, i)
+            h, aux, new = layer.apply({"params": weights}, h, cos, sin, None,
+                                      cache=view, valid=valid)
+            if cache is not None and merge is not None:
+                cache = merge(cache, new, aux)
+            elif cache is not None:
+                cache = {name: getattr(new, name) for name in cache}
+            return (h, cache), None
+
+        run = (None if carry is None
+               else {name: carry[name] for name in carried[kind]})
+        (x, run), _ = jax.lax.scan(
+            body, (x, run), jnp.arange(first, first + count,
+                                       dtype=jnp.int32))
+        if carry is not None:
+            carry = {**carry, **run}
+    return x, carry
 
 
 def context_parallel_positions(input_ids: jax.Array,

@@ -11,13 +11,15 @@ sqrt(published depth)``, the final hidden states over ``hidden_size /
 dim_model_base``).
 
 The layer is :class:`.llama.LlamaDecoderLayer` under one derived config a
-kind (:meth:`MiniCPMSALAConfig.kind_config`). What is this file's own is
-the **layer pattern**: the two kinds have unlike parameter shapes, so the
+kind (:meth:`MiniCPMSALAConfig.kind_config`). The **layer pattern** came
+with this family: the two kinds have unlike parameter shapes, so the
 parameters are one stack a kind (``layers_sparse``, ``layers_lightning``),
-and the layers run as one ``lax.scan`` a run of like layers, over the
-layer's index in its kind's stack: the body reads its weights, its K/V
-pool or its states at that index of stacks that ride along whole, so no
-stack is sliced or copied between runs. Remat is not threaded through.
+and the layers run as one ``lax.scan`` a run of like layers
+(:func:`.llama.run_layers`, shared since by every family whose layers
+differ in kind), over the layer's index in its kind's stack: the body
+reads its weights, its K/V pool or its states at that index of stacks
+that ride along whole, so no stack is sliced or copied between runs.
+Remat is not threaded through.
 """
 
 from __future__ import annotations
@@ -31,11 +33,12 @@ import jax.numpy as jnp
 from flax import linen as nn
 from flax.core import meta
 
+from ..modules.attention import rope_rows
 from ..modules.norms import RMSNorm
 from ..ops.sparse_attention import SparseSpec
 from ..parallel import layers as pl
 from ..parallel import loss_functions as lf
-from .llama import LlamaConfig, LlamaDecoderLayer, _ScanBody
+from .llama import LlamaConfig, _ScanBody, run_layers
 
 #: a published mixer's name -> the layer's ``attention_kind``
 MIXERS = {"minicpm4": "sparse", "lightning-attn": "lightning"}
@@ -149,58 +152,9 @@ def tiny_config(**kw) -> MiniCPMSALAConfig:
     return MiniCPMSALAConfig(**base)
 
 
-def rope_rows(positions: jax.Array, head_dim: int, theta: float):
-    """cos and sin ``[T, head_dim // 2]`` at ``positions [T]``: the rows of
-    :func:`..modules.attention.precompute_rope`'s tables, without a table
-    of ``max_position_embeddings`` (524,288) rows."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
-                                           dtype=jnp.float32) / head_dim))
-    freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    return jnp.cos(freqs), jnp.sin(freqs)
-
-
 #: what of the cache's stacks a layer of each kind reads and writes: the
 #: carry of its run's scan (the rest passes the run by)
 CARRIED = {"sparse": ("k", "v", "ck", "counts"), "lightning": ("state",)}
-
-
-def run_layers(cfg: MiniCPMSALAConfig, stacks, x, cos, sin, carry=None,
-               view_of=None):
-    """The layer pattern: one ``lax.scan`` a run of like layers.
-    ``stacks[kind]`` is that kind's parameter stack (leaves lead with the
-    kind's depth); ``carry`` a dict of the cache's stacks, handed from
-    layer to layer and run to run (None: no cache), and ``view_of(kind,
-    carried, layer)`` the view a layer is given of its kind's
-    (:data:`CARRIED`); what the layer hands back under a carried name
-    replaces it."""
-    for kind, first, count in cfg.runs():
-        layer = LlamaDecoderLayer(cfg.kind_config(kind))
-        stack = stacks[kind]["layer"]
-
-        def body(state, i, layer=layer, stack=stack, kind=kind):
-            h, cache = state
-            # a run of one layer is no loop once compiled, and its index a
-            # constant: behind the barrier it stays an index, and the
-            # layer's weights are read where they lie in the stack, as a
-            # longer run reads them (a constant index made each a slice:
-            # a copy of the layer's weights, every step)
-            i = jax.lax.optimization_barrier(i)
-            weights = jax.tree_util.tree_map(lambda w: w[i], stack)
-            view = None if cache is None else view_of(kind, cache, i)
-            h, _, new = layer.apply({"params": weights}, h, cos, sin, None,
-                                    cache=view)
-            if cache is not None:
-                cache = {name: getattr(new, name) for name in cache}
-            return (h, cache), None
-
-        carried = (None if carry is None
-                   else {name: carry[name] for name in CARRIED[kind]})
-        (x, carried), _ = jax.lax.scan(
-            body, (x, carried), jnp.arange(first, first + count,
-                                           dtype=jnp.int32))
-        if carry is not None:
-            carry = {**carry, **carried}
-    return x, carry
 
 
 class MiniCPMSALAModel(nn.Module):
@@ -235,7 +189,7 @@ class MiniCPMSALAModel(nn.Module):
             stacks = {kind: meta.unbox(
                 self.variables["params"][f"layers_{kind}"])
                 for kind in ("sparse", "lightning")}
-            x, _ = run_layers(cfg, stacks, x, cos, sin)
+            x, _ = run_layers(cfg, stacks, x, cos, sin, CARRIED)
         return RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="norm")(x)
 
 
@@ -306,7 +260,7 @@ def minicpm_sala_forward_with_cache(cfg: MiniCPMSALAConfig, params,
                  counts=jnp.zeros_like(kv_cache.counts))
     stacks = {kind: p["model"][f"layers_{kind}"]
               for kind in ("sparse", "lightning")}
-    x, carry = run_layers(cfg, stacks, x, cos, sin, carry, view_of)
+    x, carry = run_layers(cfg, stacks, x, cos, sin, CARRIED, carry, view_of)
     x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype).apply(
         {"params": p["model"]["norm"]}, x)
     x = x / (cfg.hidden_size / cfg.dim_model_base)

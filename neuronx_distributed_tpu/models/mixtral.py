@@ -65,7 +65,7 @@ class MixtralConfig(LlamaConfig):
                 "route each slice against an expert capacity of its own; "
                 "no test or measured cell has run that")})
 
-    def feed_forward(self, h: jax.Array, tp_sync: bool = True):
+    def feed_forward(self, h: jax.Array, tp_sync: bool = True, valid=None):
         """The MoE block under the scope name ``moe``: ``(output, [load
         balance loss, z loss])``. ``tp_sync=False`` (reduced-sync TP)
         cannot elide the block's internal tp reduction, because its

@@ -66,6 +66,16 @@ def precompute_rope(head_dim: int, max_len: int, theta: float = 10000.0,
     return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
 
 
+def rope_rows(positions: jax.Array, head_dim: int, theta: float):
+    """cos and sin ``[T, head_dim // 2]`` at ``positions [T]``: the rows of
+    :func:`precompute_rope`'s tables, without a table
+    of ``max_position_embeddings`` (524,288) rows."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                           dtype=jnp.float32) / head_dim))
+    freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.cos(freqs), jnp.sin(freqs)
+
+
 def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array,
                  positions: Optional[jax.Array] = None) -> jax.Array:
     """Apply rotary embedding. ``x: [B, S, N, D]``; cos/sin ``[L, D/2]``;

@@ -8,7 +8,8 @@ from . import token_shuffling
 from .config_validator import validate_moe_config
 from .expert_mlps import ExpertMLPs, build_dispatch_combine, compute_capacity
 from .model import MoE, SharedExperts
-from .routing import GroupLimitedRouter, RouterSinkhorn, RouterTopK
+from .routing import (GroupLimitedRouter, RouterSigmoid, RouterSinkhorn,
+                      RouterTopK)
 
 __all__ = [
     "config_validator",
@@ -24,5 +25,6 @@ __all__ = [
     "SharedExperts",
     "GroupLimitedRouter",
     "RouterSinkhorn",
+    "RouterSigmoid",
     "RouterTopK",
 ]

@@ -43,16 +43,21 @@ def compute_capacity(num_tokens: int, num_experts: int, top_k: int,
 
 def build_dispatch_combine(
     gates: jax.Array, idx: jax.Array, num_experts: int, capacity: int,
+    valid: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Capacity-limited dispatch/combine masks.
 
     gates/idx: ``[T, K]``. Returns ``(dispatch [T, E, C], combine [T, E, C],
     dropped_fraction scalar)``. Priority is choice-rank-major then token
     order (tokens beyond an expert's capacity are dropped, matching the
-    reference's capacity-factor semantics).
+    reference's capacity-factor semantics). Rows that ``valid [T]`` (bool)
+    marks false choose nothing: they take no slot and count neither as
+    kept nor as dropped.
     """
     t, k = idx.shape
     choice = jax.nn.one_hot(idx, num_experts, dtype=jnp.float32)  # [T,K,E]
+    if valid is not None:
+        choice = choice * valid.astype(jnp.float32)[:, None, None]
     flat = jnp.transpose(choice, (1, 0, 2)).reshape(k * t, num_experts)
     pos_flat = jnp.cumsum(flat, axis=0) - flat
     pos = jnp.transpose(pos_flat.reshape(k, t, num_experts), (1, 0, 2))
@@ -61,7 +66,8 @@ def build_dispatch_combine(
     slot = jax.nn.one_hot(pos_clipped, capacity, dtype=jnp.float32)  # [T,K,E,C]
     dispatch = jnp.einsum("tke,tkec->tec", keep, slot)
     combine = jnp.einsum("tk,tke,tkec->tec", gates, keep, slot)
-    dropped = 1.0 - jnp.sum(keep) / jnp.maximum(float(t * k), 1.0)
+    asked = float(t * k) if valid is None else jnp.sum(choice)
+    dropped = 1.0 - jnp.sum(keep) / jnp.maximum(asked, 1.0)
     return dispatch, combine, dropped
 
 
@@ -94,9 +100,14 @@ class ExpertMLPs(nn.Module):
     ep_axis: str = ps.EP_AXIS
 
     @nn.compact
-    def __call__(self, x: jax.Array, gates: jax.Array,
-                 idx: jax.Array) -> Tuple[jax.Array, Dict]:
-        """x: [T, H] flat tokens; gates/idx: [T, K]. Returns ([T, H], aux)."""
+    def __call__(self, x: jax.Array, gates: jax.Array, idx: jax.Array,
+                 valid: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, Dict]:
+        """x: [T, H] flat tokens; gates/idx: [T, K]. Returns ([T, H], aux).
+        ``valid [T]`` (bool; capacity dispatch, no ep axis) marks the real
+        rows of a packed serving step: a pad row takes no expert's slot,
+        and ``aux`` then holds ``assignments``, the real rows' ``[kept,
+        dropped]`` int32."""
         t = x.shape[0]
         e_local = pl._maybe_local(self.num_experts, self.ep_axis)
         i_local = pl._maybe_local(self.intermediate_size, self.tp_axis)
@@ -112,6 +123,11 @@ class ExpertMLPs(nn.Module):
                                  (self.ep_axis, self.tp_axis, None)),
             (e_local, i_local, self.hidden_size), self.param_dtype)
 
+        if valid is not None and (self.dispatch_mode != "capacity"
+                                  or (ep is not None and ep > 1)):
+            raise ValueError("ExpertMLPs: valid rows are threaded through "
+                             "the capacity dispatch without an ep axis "
+                             "alone")
         if self.dispatch_mode == "blockwise":
             if ep is not None and ep > 1:
                 return self._forward_blockwise_ep(x, gates, idx, gate_up,
@@ -125,7 +141,7 @@ class ExpertMLPs(nn.Module):
         capacity = compute_capacity(t, self.num_experts, self.top_k,
                                     self.capacity_factor)
         dispatch, combine, dropped = build_dispatch_combine(
-            gates, idx, self.num_experts, capacity)
+            gates, idx, self.num_experts, capacity, valid)
 
         xin = jnp.einsum("tec,th->ech", dispatch.astype(self.dtype),
                          x.astype(self.dtype))  # [E, C, H]
@@ -153,6 +169,11 @@ class ExpertMLPs(nn.Module):
         y = jnp.einsum("tec,ech->th", combine.astype(self.dtype),
                        out)
         aux = {"dropped_fraction": dropped}
+        if valid is not None:
+            # every kept assignment holds exactly one slot
+            kept = jnp.sum(dispatch).astype(jnp.int32)
+            asked = jnp.sum(valid).astype(jnp.int32) * idx.shape[1]
+            aux["assignments"] = jnp.stack([kept, asked - kept])
         return y.astype(self.dtype), aux
 
     def _run_grouped_glu(self, xs, gate_up, down, be, i_local):

@@ -16,12 +16,14 @@ from ...parallel import layers as pl
 from ...parallel import mesh as ps
 from .. import glu
 from .expert_mlps import ExpertMLPs
-from .routing import GroupLimitedRouter, RouterSinkhorn, RouterTopK
+from .routing import (GroupLimitedRouter, RouterSigmoid, RouterSinkhorn,
+                      RouterTopK)
 
 ROUTERS = {
     "top_k": RouterTopK,
     "sinkhorn": RouterSinkhorn,
     "group_limited": GroupLimitedRouter,
+    "sigmoid": RouterSigmoid,
 }
 
 
@@ -70,12 +72,19 @@ class MoE(nn.Module):
     # (packed microscaling weights, quantization.mx_layers.MXExpertMLPs)
     expert_impl: str = "float"
     router_type: str = "top_k"
+    # the sigmoid router's ``routed_scaling_factor``
+    router_scale: float = 1.0
     shared_expert_intermediate: int = 0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, Dict]:
+    def __call__(self, x: jax.Array, valid: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, Dict]:
+        """``valid`` (bool, ``x``'s shape without its last dimension; float
+        experts only) marks the real rows of a packed serving step: the
+        others take no expert's slot and ``aux["assignments"]`` counts the
+        real rows' ``[kept, dropped]``."""
         orig_shape = x.shape
         h = self.hidden_size
         flat = x.reshape(-1, h)
@@ -85,6 +94,8 @@ class MoE(nn.Module):
                          param_dtype=self.param_dtype, name="router")
         if self.router_type != "sinkhorn":
             router_kw["top_k"] = self.top_k
+        if self.router_type == "sigmoid":
+            router_kw["scale"] = self.router_scale
         gates, idx, aux = router_cls(**router_kw)(flat)
 
         if self.expert_impl.startswith("mx_"):
@@ -140,7 +151,13 @@ class MoE(nn.Module):
                 ep_overlap=self.ep_overlap,
                 dtype=self.dtype, param_dtype=self.param_dtype,
                 name="experts")
-        y, eaux = experts(flat, gates, idx)
+        if valid is None:
+            y, eaux = experts(flat, gates, idx)
+        else:
+            # a scope by which a device trace tells the routed experts
+            # (dispatch, the bank's products, combine) from the rest
+            with jax.named_scope("routed_experts"):
+                y, eaux = experts(flat, gates, idx, valid=valid.reshape(-1))
         aux.update(eaux)
 
         if self.shared_expert_intermediate > 0:

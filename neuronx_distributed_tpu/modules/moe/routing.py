@@ -74,6 +74,41 @@ class RouterTopK(RouterBase):
         return gates.astype(jnp.float32), idx, aux
 
 
+class RouterSigmoid(RouterBase):
+    """Sigmoid router with a selection bias (DeepSeek-V3's ``noaux_tc``,
+    GLM-4.x): every expert scores ``s_e = sigmoid(logit_e)`` on its own;
+    the ``top_k`` largest ``s_e + bias_e`` are chosen (``bias``, the
+    load-balancing correction, is a parameter that takes no gradient from
+    the task) and weighed by ``s_e`` alone, normalised over the chosen
+    (the checkpoints' ``norm_topk_prob``) and times ``scale``
+    (``routed_scaling_factor``). Equal scores take the lower expert
+    index, as ``lax.top_k`` does."""
+
+    top_k: int = 2
+    scale: float = 1.0
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array, Dict]:
+        logits = self.logits(x)  # [T, E]
+        bias = self.param(
+            "bias", nn.with_partitioning(nn.initializers.zeros_init(),
+                                         (None,)),
+            (self.num_experts,), jnp.float32)
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)),
+            self.top_k)
+        gates = jnp.take_along_axis(scores, idx, axis=-1)
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        gates = gates * self.scale
+        mask = jnp.sum(jax.nn.one_hot(idx, self.num_experts,
+                                      dtype=jnp.float32), axis=1)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        aux = {"load_balance_loss": _load_balance_loss(probs, mask),
+               "z_loss": _z_loss(logits)}
+        return gates.astype(jnp.float32), idx, aux
+
+
 class RouterSinkhorn(RouterBase):
     """Sinkhorn-balanced top-1 router (reference ``RouterSinkhorn:213``):
     iteratively normalise the token×expert matrix toward doubly-stochastic

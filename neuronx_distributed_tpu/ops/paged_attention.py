@@ -587,7 +587,8 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
 
 @functools.lru_cache(maxsize=None)
 def paged_attention_impl(head_dim: int, block_size: int,
-                         force_pallas: Optional[bool] = None) -> str:
+                         force_pallas: Optional[bool] = None,
+                         kernel_only: bool = False) -> str:
     """Which implementation :func:`paged_attention` runs for a pool of this
     ``head_dim`` and ``block_size`` on the default backend: ``"pallas"``
     (the compiled TPU kernel), ``"pallas-interpret"`` (the kernel, forced,
@@ -595,18 +596,22 @@ def paged_attention_impl(head_dim: int, block_size: int,
 
     The compiled kernel wants ``head_dim`` on whole lanes and whole
     128-slot blocks (the shapes ``tests/test_chip_compile.py`` covers). A
-    TPU that falls to the reference for its shapes says so once, here."""
+    TPU that falls to the reference for its shapes says so once, here;
+    with ``kernel_only`` (a family whose pool only the kernel can serve at
+    its size: the reference gathers every row's whole table) it raises
+    instead."""
     if force_pallas is False:
         return "xla"
     if not on_tpu():
         return "pallas-interpret" if force_pallas else "xla"
     if head_dim % 128 == 0 and block_size % 128 == 0:
         return "pallas"
-    if force_pallas:
+    if force_pallas or kernel_only:
         raise ValueError(
-            f"force_pallas: paged shapes (head_dim={head_dim}, "
-            f"block_size={block_size}) don't tile for the TPU kernel; "
-            "non-tiling shapes are only valid in CPU interpret mode")
+            f"paged shapes (head_dim={head_dim}, block_size={block_size}) "
+            "don't tile for the TPU kernel, which this pool is served by "
+            "alone (force_pallas, or a kernel-only family); non-tiling "
+            "shapes are only valid off the TPU")
     logger.warning(
         "paged_attention: head_dim=%d block_size=%d does not tile for the "
         "TPU kernel (both must be multiples of 128); this pool is served "

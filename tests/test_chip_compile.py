@@ -399,6 +399,111 @@ def test_sparse_state_forward_writes_its_stacks_in_place(chip, on_one_chip):
     assert set(stacks) <= aliased, (stacks, header)
 
 
+# -- the latent cache (GLM-4.7-Flash): one row of 640 lanes a position -----------
+# The cell glm-4.7-flash.serve-agentic: 128 rows, 20 heads over one row of
+# a 512 latent and a 64 rotary key, 160 table columns, 4,096 blocks of 128.
+
+def test_mla_paged_attention(chip):
+    from neuronx_distributed_tpu.ops import mla_attention as mla
+
+    tokens, heads, rank, bs, cols, nb, layers = 128, 20, 512, 128, 160, \
+        4096, 7
+    row = mla.row_width(rank, 64)
+    assert row == 640 and mla.stacked_heads(heads) == 24
+    fn = functools.partial(mla._mla_attention_pallas, rank=rank,
+                           scale=1.0 / 16, interpret=False)
+    text = _assert_kernel_compiles(
+        fn, chip((tokens, heads, row), jnp.bfloat16),
+        chip((layers, nb, bs, row), jnp.bfloat16), chip((nb, bs), jnp.int32),
+        chip((tokens, cols), jnp.int32), chip((tokens,), jnp.int32),
+        chip((), jnp.int32))
+    assert _kernel_instruction_names(text) == {"mla_paged_attention"}
+    assert {"tpu.matmul", "tpu.enqueue_dma"} <= _mosaic_ops(text)
+
+
+def test_latent_forward_writes_its_rows_in_place(chip, on_one_chip):
+    """The cell's widths, depth and cache geometry (a narrow vocabulary):
+    a run of one dense layer and a run of six expert layers over the one
+    row stack. The stack is handed back in the buffer it came in and is
+    only ever the scatter that writes rows where they lie; no layer's
+    experts are sliced out of their stack; the kernel is the Pallas one."""
+    import re
+
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.inference import paging
+    from neuronx_distributed_tpu.models import glm_moe_lite
+
+    nb, bs, slots, cols, tokens = 4096, 128, 64, 160, 128
+    cfg = glm_moe_lite.GlmMoeLiteConfig(
+        num_layers=7, vocab_size=512, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    assert cfg.runs() == (("dense", 0, 1), ("moe", 0, 6))
+    model = glm_moe_lite.GlmMoeLiteForCausalLM(cfg)
+    forward = cfg.serving_family().forward
+    abstract = functools.partial(
+        jax.tree_util.tree_map, lambda x: chip(x.shape, x.dtype))
+    params = abstract(meta.unbox(jax.eval_shape(
+        model.init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))
+    cache = abstract(jax.eval_shape(lambda: paging.init_serving_cache(
+        cfg, num_blocks=nb, block_size=bs, table_rows=slots,
+        max_blocks_per_seq=cols, dtype=jnp.bfloat16)))
+
+    def step(params, cache, tokens, positions, slot_ids):
+        return forward(cfg, params, tokens, positions, cache,
+                       slot_ids=slot_ids)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"mla_paged_attention"}
+    # of the results one expert's weights large or larger: none is a
+    # layer's experts (or a part of them) out of their stack, and the row
+    # stack is only ever written in place, once a run
+    large = _top_level_results(text, 2048 * 1536)
+    assert not [r for r in large if re.search(r"\[(64,)?2048,1536\]|"
+                                              r"\[(64,)?1536,2048\]", r[2])]
+    stack = f"bf16[7,{nb * bs},640]"
+    assert [r for r in large if r[2] == stack] == [
+        r for r in _in_place_writes(text, large) if r[2] == stack]
+    assert sum(r[2] == stack for r in large) == 2
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < cache.rows.size * 2 / 7)       # one layer of rows
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape == f"7,{nb},{bs},640"]
+    assert len(stacks) == 1 and set(stacks) <= aliased, (stacks, header)
+    # moe_expert_share_pct.batch finds the routed experts by the text of
+    # an instruction as the profiler names it (result and operand types):
+    # what it matches is what the compiler credits to the routed_experts
+    # scope and nothing else, the three matmuls over the experts' stacks
+    # among it (the down matmul's result type is o_proj's and the shared
+    # expert's too)
+    import json
+    import os
+
+    metric = json.load(open(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks", "layer_metrics", "moe_expert_share_pct.batch.json")))
+    assert metric["reader"]["kind"] == "device_text_share"
+    pattern = re.compile(metric["reader"]["match"])
+    fusions = list(_fusions_as_the_profiler_names_them(text))
+    hit = [(scope, shown) for scope, shown in fusions
+           if pattern.search(shown)]
+    assert hit and all("/routed_experts/" in scope for scope, _ in hit), hit
+    streamed = [shown for scope, shown in fusions
+                if re.search(r"bf16\[6,64,(2048,1536|1536,2048)\]", shown)]
+    assert len(streamed) == 3 and all(pattern.search(x) for x in streamed)
+    assert sum(" = bf16[1,128,2048]" in x for x in streamed) == 1
+    big = [shown for scope, shown in fusions if "/routed_experts/" in scope
+           and re.search(r"dot_general", scope)]
+    assert big and all(pattern.search(x) for x in big), big
+
+
 # -- grouped GLU decode (MoE serving) at OLMoE's widths (ROADMAP R1): hidden
 # 2048, expert width 1024. Mixtral's 4096 compiles too, in about ten seconds.
 
@@ -425,6 +530,34 @@ def test_grouped_glu_decode(chip):
 
 _PLUMBING = ("parameter", "get-tuple-element", "tuple", "while", "bitcast",
              "conditional", "call", "constant")
+
+
+def _fusions_as_the_profiler_names_them(hlo_text):
+    """``(op_name, text)`` of every fusion outside a fused computation:
+    the scope path the compiler credits it to, and the instruction as a
+    device trace's event names it, each operand with its type."""
+    import re
+
+    fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.\-]+)", hlo_text))
+    types, comp = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            comp, types = head.group(1), {}
+            continue
+        m = re.match(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\(?[a-z0-9]+\[.*?) "
+                     r"([a-z\-]+)\((.*?)\), ", line)
+        if comp in fused or not m:
+            continue
+        name, result, opcode, operands = m.groups()
+        types[name] = result
+        if opcode != "fusion":
+            continue
+        scope = re.search(r'op_name="([^"]*)"', line)
+        shown = ", ".join(f"{types.get(o, '?')} {o}"
+                          for o in re.findall(r"%[\w.\-]+", operands))
+        yield (scope.group(1) if scope else "",
+               f"{name} = {result} fusion({shown}), kind=")
 
 
 def _top_level_results(hlo_text, at_least):

@@ -411,7 +411,8 @@ def test_the_constructor_returns_the_old_kinds_pytrees_leaf_for_leaf(
 def test_attention_kinds_are_checked_by_name():
     from neuronx_distributed_tpu.models import llama
 
-    assert llama.ATTENTION_KINDS == ("full", "eva", "sparse", "lightning")
+    assert llama.ATTENTION_KINDS == ("full", "eva", "sparse", "lightning",
+                                     "mla")
     with pytest.raises(ValueError, match="attention_kind"):
         llama.tiny_config(attention_kind="linear")
     with pytest.raises(ValueError, match="mixer_types"):
