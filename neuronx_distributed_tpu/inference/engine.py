@@ -682,11 +682,12 @@ class ServingEngine:
         #: columns, counted on the host as the rows are packed:
         #: ``nxd_paged_columns_total`` or, for a window-summary cache,
         #: ``nxd_eva_columns_total``. A sparse-state cache's selections are
-        #: known to the device alone: the step leaves their six counts in
+        #: known to the device alone: the step leaves their eight counts in
         #: ``cache.counts`` and the fetch adds them here
-        #: (``nxd_sparse_columns_total``, ``nxd_sparse_positions_total``)
+        #: (``nxd_sparse_columns_total``, ``nxd_sparse_positions_total``,
+        #: ``nxd_sparse_block_visits_total``)
         self._counts_on_device = self._cache_kind.name == "sparse_state"
-        self._paged_cols = np.zeros((6 if self._counts_on_device else 3,),
+        self._paged_cols = np.zeros((8 if self._counts_on_device else 3,),
                                     np.int64)
         #: what the kernel's tiles fetched for those rows, by the walk's own
         #: function: pool blocks fetched, one a (tile, pair), and the further
@@ -2392,11 +2393,24 @@ class ServingEngine:
                     "sparse layers) by whether the selection attended "
                     "them.",
                     labels=("kind",))
+                sparse_visits_c = reg.counter(
+                    "nxd_sparse_block_visits_total",
+                    "Live (row, K/V group, table column) of the packed "
+                    "rows, summed over the sparse layers, by how the "
+                    "sparse_paged_attention kernel came by the column's "
+                    "pool block: fetched, for the first row of the tile "
+                    "that attends it (or the only one), or shared, served "
+                    "by the fetch made for an earlier row of the tile. "
+                    "Counted on the device, fetched with the step's "
+                    "tokens.",
+                    labels=("kind",))
                 cols_by_kind = tuple(
                     [cols_c.labels(kind=k) for k in
                      ("selected", "forced", "dense", "skipped")]
                     + [pos_c.labels(kind=k) for k in
-                       ("attended", "skipped")])
+                       ("attended", "skipped")]
+                    + [sparse_visits_c.labels(kind=k) for k in
+                       ("fetched", "shared")])
             elif self._cache_kind.ring is None:
                 cols_c = reg.counter(
                     "nxd_paged_columns_total",

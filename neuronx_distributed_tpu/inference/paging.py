@@ -210,7 +210,7 @@ class SparseStateCache(FullCache):
                          dtype),
             state=jnp.zeros((self.state_layers, heads, table_rows, d, d),
                             jnp.float32),
-            counts=jnp.zeros((6,), jnp.int32),
+            counts=jnp.zeros((8,), jnp.int32),
             pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
             block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
                                   jnp.int32),
@@ -356,7 +356,7 @@ class SparseStatePagedCache(struct.PyTreeNode):
     entry ``b * block_size / stride + i`` the kernel that starts at slot
     ``stride * i`` of block ``b``; ``state`` ``[Ll, H, table rows, D, D]``
     float32 over the ``Ll`` lightning layers (a slot's ``S^T`` a head:
-    :func:`..ops.lightning_attention.lightning_attention_packed`); ``counts [6]`` what the last
+    :func:`..ops.lightning_attention.lightning_attention_packed`); ``counts [8]`` what the last
     step's selections attended
     (:data:`..ops.sparse_attention.COUNT_KINDS`, summed over the sparse
     layers); ``pos``, ``block_tables`` and ``lengths`` as

@@ -11,12 +11,34 @@ causal block is attended.
 * :func:`sparse_paged_attention` attends the paged pool of
   :class:`..inference.paging.SparseStatePagedCache`: the selection is
   the walk. The Pallas kernel (``sparse_paged_attention`` in a device
-  trace) runs a grid of ``(rows, groups, walk width)`` where the width is
-  ``max(dense_len / block_size, topk)`` whatever the context; a grid step
-  fetches one selected pool block of one K/V head and multiplies it with
-  the group's query heads on the MXU, masking the part of the pool block
-  that was not selected. The XLA path gathers the row's whole table
-  (CPU tests).
+  trace) has :mod:`.paged_attention`'s unit with a body of its own: a
+  *tile* of consecutive packed rows of one K/V group, the group's query
+  heads stacked head by head (``[heads x rows, D]``: one tile of 128 rows
+  of 16 heads at the published widths, :func:`tile_height`), against a
+  *pair* (table column, pool block) that at least one row of the tile
+  selected, was forced to, or attends densely. The grid is ``(tiles,
+  groups)``; inside, a loop over the (tile, group)'s pairs copies each
+  pair's K and V block of the group's head (one contiguous ``[block_size,
+  D]`` of the pool's heads-before-slots layout) once from the stacks in
+  HBM into one of two VMEM buffers while the pair before it is computed,
+  and multiplies on the MXU: scores from the stored operands into
+  float32, ``p x v`` with ``p`` float32
+  (:func:`.paged_attention._p_times_v`), the running max, sum and
+  accumulator float32 scratch. So a prefill chunk's rows fetch a block
+  their slot holds once and not once a row, and the selection is a mask:
+  a row attends, of a pair, the causal positions of the selection blocks
+  it picked in that pool block (its ``parts`` bits); a row that does not
+  name the pair is all ``-inf``; a pair that lies inside one part of the
+  tile (:func:`narrow_height` rows: a decode row's, whose neighbours are
+  other slots') runs over that part of every head alone.
+
+  The walk (:func:`sparse_tile_walk`) depends on the layer's queries, so
+  it is built on the device once a layer, from the selection and the
+  tables, without a sort: a pair belongs to the first row of its tile
+  that attends it (:func:`first_namers`, an all-pairs comparison of the
+  tile's rows), and each row's pairs (at most
+  :meth:`SparseSpec.walk_width`) are put in order by a one-hot of their
+  rank. The XLA path gathers the row's whole table (CPU tests).
 """
 
 from __future__ import annotations
@@ -24,18 +46,23 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..inference.kv_cache import PAD_POSITION
-from .paged_attention import paged_attention_impl
+from .paged_attention import _p_times_v, paged_attention_impl
 from .pallas_utils import compiler_params as _compiler_params
 
-#: what :func:`selection_counts` counts, in order
+#: ``cache.counts``, in order: what :func:`selection_counts` counts, then
+#: the live (row, group, column) a tile fetches a pool block for
+#: (:func:`first_namers`) and those an earlier row's fetch serves
 COUNT_KINDS = ("selected", "forced", "dense", "skipped", "attended",
-               "skipped_positions")
+               "skipped_positions", "fetched", "shared")
+
+#: VMEM a tile's float32 accumulator may take (:func:`tile_height`)
+ACC_BYTES = 1 << 20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,10 +166,11 @@ def _attended(sel, q_pos, spec: SparseSpec):
 
 def selection_counts(sel, forced, q_pos, spec: SparseSpec, block_size: int,
                      width: int) -> jax.Array:
-    """``[6]`` int32, :data:`COUNT_KINDS`: the walk's grid steps of the
-    rows by what is in them (a selected pool block, one the window or
-    the first blocks forced, one of a row below ``dense_len``, nothing),
-    and the causal positions attended and skipped."""
+    """``[6]`` int32, the first six of :data:`COUNT_KINDS`: the ``width``
+    table columns a (row, group) can attend (:meth:`SparseSpec.walk_width`)
+    by what is in them (a selected pool block, one the window or the
+    first blocks forced, one of a row below ``dense_len``, nothing), and
+    the causal positions attended and skipped."""
     t, g, nb = sel.shape
     per = block_size // spec.block
     cols = sel.reshape(t, g, -1, per).any(-1)
@@ -271,119 +299,315 @@ def _sparse_paged_xla(q, k_pool, v_pool, layer, tables, q_pos, sel, spec,
     return jnp.einsum("tgrp,tpgd->tgrd", probs, vg.astype(jnp.float32))
 
 
-def sparse_walk(sel: jax.Array, tables: jax.Array, spec: SparseSpec,
-                block_size: int, width: int):
-    """The kernel's walk, ``(blocks, marks) [T, G * width]`` int32. Grid
-    step ``j`` of (row, group) fetches pool block ``blocks[.., j] >= 0``
-    and attends the parts ``marks & parts`` of it (a pool block is
-    ``block_size / block`` selection blocks; ``marks >> parts`` is the
-    block's table column, which says what positions it holds); past the
-    (row, group)'s selected pool blocks ``blocks`` is the complement of
-    the last one's id: nothing is computed, and the repeated index elides
-    the DMA."""
+def narrow_height(dtype) -> int:
+    """Rows of the part of a tile that a pair named inside it alone runs
+    over (a decode row's pairs: its neighbours are other slots'): one
+    tile of sublanes of the queries' dtype, 8 rows of float32 and 16 of
+    bf16, so that the part of every head is whole vregs."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def tile_height(tokens: int, heads: int, head_dim: int, dtype) -> int:
+    """Packed rows of a tile of the kernel, from the shapes alone: as
+    many as there are, in whole parts (:func:`narrow_height`), while the
+    tile's float32 accumulator, ``rows x heads x head_dim``, stays within
+    :data:`ACC_BYTES` of VMEM. A tile that spans a prefill chunk lists a
+    pool block once for all the chunk's rows and turns the selection into
+    a mask; measured on the v5e at 8, 32 and 128 rows of 16 heads of 128
+    (``PERF.md``, Findings, PR 35), the tallest won."""
+    part = narrow_height(dtype)
+    fit = max(part, ACC_BYTES // (heads * head_dim * 4) // part * part)
+    return min(fit, -(-tokens // part) * part)
+
+
+class SparseWalk(NamedTuple):
+    """What the kernel is handed of one layer's selection
+    (:func:`sparse_tile_walk`). The packed rows, padded to whole tiles,
+    are ``Tp``; ``W`` is :meth:`SparseSpec.walk_width`. A pair (table
+    column, pool block) of a (tile, group) is listed by the first row of
+    the tile that attends it, so the lists are a row's: ``count [Tp * G]``
+    the pairs (row, group) lists, ``blocks`` and ``marks [Tp * G * W]``
+    each pair's pool block and ``column * 2 + narrow`` (``narrow``: every
+    row that attends the pair lies in the lister's part of
+    :func:`narrow_height` rows); ``after [Tp * G]`` the next row of the
+    tile that lists any (the tile's height if none); ``total`` and
+    ``start [tiles * G]`` a (tile, group)'s pairs and the first row that
+    lists any. ``keys [tiles, G, rows, max_blocks_per_seq]`` is what a
+    row attends of each column: ``block << per | bits`` with bit ``i``
+    the ``i``-th of the pool block's ``per`` selection blocks, 0 for
+    nothing."""
+
+    total: jax.Array
+    start: jax.Array
+    count: jax.Array
+    after: jax.Array
+    blocks: jax.Array
+    marks: jax.Array
+    keys: jax.Array
+
+
+def column_parts(sel: jax.Array, tables: jax.Array, per: int) -> jax.Array:
+    """``[T, G, max_blocks_per_seq]`` int32: the selection blocks (bits,
+    ``per`` a pool block) that (row, group) attends of each mapped table
+    column; 0 where it attends none of it."""
     t, g, _ = sel.shape
-    per = block_size // spec.block
-    maxb = tables.shape[1]
-    parts = jnp.sum(sel.reshape(t, g, maxb, per).astype(jnp.int32)
-                    << jnp.arange(per, dtype=jnp.int32), -1)    # [T, G, maxb]
-    live = (parts > 0) & (tables >= 0)[:, None]
-    cols = jnp.arange(maxb, dtype=jnp.int32)
-    order = jnp.argsort(jnp.where(live, cols, maxb + cols), -1)[..., :width]
-    count = jnp.sum(live, -1, keepdims=True)
-    blk = jnp.take_along_axis(
-        jnp.broadcast_to(jnp.maximum(tables, 0)[:, None], live.shape),
-        order, -1)
-    last = jnp.take_along_axis(blk, jnp.maximum(count - 1, 0), -1)
-    step = jnp.arange(width, dtype=jnp.int32)
-    blocks = jnp.where(step < count, blk, ~last)
-    marks = (order << per) | jnp.take_along_axis(parts, order, -1)
-    return (blocks.reshape(t, g * width).astype(jnp.int32),
-            marks.reshape(t, g * width).astype(jnp.int32))
+    parts = jnp.sum(sel.reshape(t, g, -1, per).astype(jnp.int32)
+                    << jnp.arange(per, dtype=jnp.int32), -1)
+    return jnp.where((tables >= 0)[:, None], parts, 0)
 
 
-def _sparse_kernel(blocks_ref, marks_ref, qpos_ref, layer_ref, q_ref, k_ref,
-                   v_ref, o_ref, m_ref, l_ref, acc_ref, *, width: int,
-                   per: int, part: int, scale: float):
-    """One (row, group, walk step): the group's ``R`` query heads against
-    one pool block of the group's K/V head, ``[R, D] x [D, BS]`` on the
-    MXU, online softmax in float32 scratch."""
-    from jax.experimental import pallas as pl
-
-    t, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(blocks_ref[t, g * width + j] >= 0)
-    def _accumulate():
-        mark = marks_ref[t, g * width + j]
-        bs = k_ref.shape[0]
-        s = jax.lax.dot_general(
-            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale           # [R, BS]
-        slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = ((mark >> per) * bs + slot <= qpos_ref[t]) & (
-            ((mark >> (slot // part)) & 1) == 1)
-        s = jnp.where(ok, s, -jnp.inf)
-        m_prev = m_ref[...]                                       # [R, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(ok, jnp.exp(s - m_safe), 0.0)
-        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(j == width - 1)
-    def _finalize():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                      ).astype(o_ref.dtype)
+def _tile_namers(parts, tables, rows: int):
+    """``parts [T, G, maxb]`` and ``tables [T, maxb]`` in tiles of ``rows``
+    rows (padded with rows that attend nothing), ``[tiles, rows, ...]``
+    each, and ``same [tiles, rows, rows, G, maxb]``: whether row ``r2``
+    (third axis) of the tile attends, in the column, the pool block that
+    row ``r`` has there."""
+    t, g, maxb = parts.shape
+    pad = -t % rows
+    parts = jnp.pad(parts, ((0, pad), (0, 0), (0, 0))).reshape(
+        -1, rows, g, maxb)
+    tables = jnp.pad(tables, ((0, pad), (0, 0))).reshape(-1, rows, maxb)
+    same = ((tables[:, :, None] == tables[:, None])[:, :, :, None]
+            & (parts > 0)[:, None])
+    return parts, tables, same
 
 
-def _sparse_paged_pallas(q, k_pool, v_pool, layer, tables, q_pos, sel, spec,
-                         scale, interpret=False):
+def _none_among(same, among):
+    """Of ``same`` (:func:`_tile_namers`), whether no row ``r2`` with
+    ``among [rows, rows]`` (by ``r``, ``r2``) attends row ``r``'s block."""
+    return ~jnp.any(same & among[None, :, :, None, None], axis=2)
+
+
+def _first(parts, same):
+    """Of a tile's ``parts`` and ``same`` (:func:`_tile_namers`), the live
+    (row, group, column) whose pool block no earlier row attends."""
+    r = jnp.arange(parts.shape[1], dtype=jnp.int32)
+    return (parts > 0) & _none_among(same, r[None, :] < r[:, None])
+
+
+def first_namers(parts, tables, rows: int):
+    """``[tiles, rows, G, maxb]`` bool: the live (row, group, column)
+    whose pool block no earlier row of the tile attends in that column:
+    the tile fetches the block for it (and for the later rows that name
+    it). Rows of one slot share a table, so a prefill chunk's rows are
+    served by their first; membership is by the table entry, any order of
+    rows is correct."""
+    parts, _, same = _tile_namers(parts, tables, rows)
+    return _first(parts, same)
+
+
+def sparse_tile_walk(parts: jax.Array, tables: jax.Array, rows: int,
+                     part_rows: int, width: int, per: int) -> SparseWalk:
+    """The kernel's walk of one layer, built on the device from the
+    layer's selection (``parts``, :func:`column_parts`) and the tables,
+    without a sort: a tile's pairs are its first namers
+    (:func:`first_namers`), a row's are put in order of column by their
+    rank among the row's (at most ``width``: what a row can attend)."""
+    parts, tables, same = _tile_namers(parts, tables, rows)
+    r = jnp.arange(rows, dtype=jnp.int32)
+    first = _first(parts, same)
+    narrow = _none_among(
+        same, r[None, :] >= (r[:, None] // part_rows + 1) * part_rows)
+    rank = jnp.cumsum(first, axis=-1, dtype=jnp.int32) - first
+    count = jnp.sum(first, axis=-1, dtype=jnp.int32)      # [tiles, rows, G]
+    cols = jnp.arange(tables.shape[-1], dtype=jnp.int32)
+    slot = first[..., None, :] & (
+        rank[..., None, :] == jnp.arange(width, dtype=jnp.int32)[:, None])
+    blocks = jnp.sum(jnp.where(slot, tables[:, :, None, None, :], 0), -1)
+    marks = jnp.sum(jnp.where(slot, (cols * 2 + narrow)[..., None, :], 0),
+                    -1)
+    lists = jnp.where(count > 0, r[:, None], rows)        # [tiles, rows, G]
+    later = (r[None, :] > r[:, None])[None, :, :, None]
+    after = jnp.min(jnp.where(later, lists[:, None], rows), axis=2)
+    keys = jnp.where(parts > 0, (tables[:, :, None] << per) | parts, 0)
+    return SparseWalk(
+        total=jnp.sum(count, axis=1).reshape(-1),
+        start=jnp.min(lists, axis=1).reshape(-1).astype(jnp.int32),
+        count=count.reshape(-1), after=after.reshape(-1).astype(jnp.int32),
+        blocks=blocks.reshape(-1).astype(jnp.int32),
+        marks=marks.reshape(-1).astype(jnp.int32),
+        keys=keys.swapaxes(1, 2).astype(jnp.int32))
+
+
+def _sparse_kernel(total_ref, start_ref, count_ref, after_ref, blocks_ref,
+                   marks_ref, layer_ref, keys_ref, qpos_ref, q_ref, k_hbm,
+                   v_hbm, o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref,
+                   *, part_rows: int, width: int, per: int, part: int,
+                   scale: float):
+    """One tile of packed rows and one K/V group against the pool blocks
+    the tile's rows attend: a loop over the (tile, group)'s pairs, row by
+    listing row (:class:`SparseWalk`), each pair's K and V block of the
+    group's head copied once from the stacks in HBM into one of two VMEM
+    buffers while the pair before it is computed.
+
+    The group's query heads are stacked head by head (``q_ref [heads,
+    rows, D]``), so a row's mask serves every head as it is: scores
+    ``[heads * rows, D] x [D, block_size]`` on the MXU from the stored
+    operands into float32, masked by row (the positions of the pair's
+    column that are causal and lie in a selection block the row attends
+    of this very pool block; a row that does not name the pair is all
+    ``-inf``), the online softmax in float32 scratch, ``p x v`` with
+    ``p`` float32 (:func:`.paged_attention._p_times_v`). A pair marked
+    narrow runs over its lister's ``part_rows`` rows of every head
+    alone."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    t, g, r, d = q.shape
+    tile, g = pl.program_id(0), pl.program_id(1)
+    groups = pl.num_programs(1)
+    heads, rows, d = q_ref.shape
+    bs = k_buf.shape[1]
+    total = total_ref[tile * groups + g]
+    layer = layer_ref[0]
+    operand = (jnp.bfloat16 if q_ref.dtype == jnp.bfloat16
+               and k_buf.dtype == jnp.bfloat16 else jnp.float32)
+
+    def lists(r):
+        return (tile * rows + r) * groups + g
+
+    def copies(r, k, slot):
+        """The copies of pair ``k`` of row ``r``'s list into ``slot``."""
+        b = blocks_ref[lists(r) * width + k]
+        return [pltpu.make_async_copy(src.at[layer, b, g], buf.at[slot],
+                                      sems.at[slot, i])
+                for i, (src, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf)))]
+
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    first = start_ref[tile * groups + g]
+
+    @pl.when(total > 0)
+    def _first():
+        for c in copies(first, 0, 0):
+            c.start()
+
+    def pair(i, at):
+        r, k = at
+        slot = i % 2
+        last = k + 1 >= count_ref[lists(r)]
+        nxt = (jnp.where(last, after_ref[lists(r)], r),
+               jnp.where(last, 0, k + 1))
+
+        @pl.when(i + 1 < total)
+        def _next():
+            for c in copies(*nxt, 1 - slot):
+                c.start()
+
+        for c in copies(r, k, slot):
+            c.wait()
+        block = blocks_ref[lists(r) * width + k]
+        mark = marks_ref[lists(r) * width + k]
+        col = mark >> 1
+        keys = k_buf[slot].astype(operand)                  # [bs, D]
+        values = v_buf[slot].astype(operand)
+
+        def attend(rs, n):
+            """The pair against rows ``rs`` (``n`` of them) of the tile."""
+            key = keys_ref[rs, :]                           # [n, maxb]
+            column = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+            mine = jnp.max(jnp.where(column == col, key, 0), axis=1,
+                           keepdims=True)                   # [n, 1]
+            bits = jnp.where((mine >> per) == block, mine, 0)
+            pos = jax.lax.broadcasted_iota(jnp.int32, (n, bs), 1)
+            ok = (((bits >> (pos // part)) & 1) == 1) & (
+                col * bs + pos <= qpos_ref[rs, :])          # [n, bs]
+            s = jax.lax.dot_general(
+                q_ref[:, rs, :].reshape(heads * n, d).astype(operand), keys,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(ok[None], s.reshape(heads, n, bs), -jnp.inf)
+            m_prev = m_ref[:, rs, :]                        # [heads, n, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            # a masked score is -inf and m_safe is finite: p is 0 there,
+            # and a row that has seen nothing yet corrects by 0
+            p = jnp.exp(s - m_safe)
+            corr = jnp.exp(m_prev - m_safe)
+            m_ref[:, rs, :] = m_new
+            l_ref[:, rs, :] = l_ref[:, rs, :] * corr + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_ref[:, rs, :] = acc_ref[:, rs, :] * corr + _p_times_v(
+                p.reshape(heads * n, bs), values).reshape(heads, n, d)
+
+        if part_rows >= rows:
+            attend(slice(None), rows)
+        else:
+            @pl.when(mark & 1 == 1)
+            def _narrow():
+                attend(pl.ds(pl.multiple_of(r // part_rows * part_rows,
+                                            part_rows), part_rows),
+                       part_rows)
+
+            @pl.when(mark & 1 == 0)
+            def _whole():
+                attend(slice(None), rows)
+        return nxt
+
+    jax.lax.fori_loop(0, total, pair, (first, jnp.int32(0)))
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                  ).astype(o_ref.dtype)
+
+
+def _sparse_paged_pallas(q, k_pool, v_pool, layer, tables, q_pos, parts,
+                         spec, scale, interpret=False):
+    """``q [T, G, R, D]``, ``parts`` :func:`column_parts`. Returns the
+    output ``[T, G, R, D]`` and the pairs the tiles fetched."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, g, heads, d = q.shape
     bs = k_pool.shape[3]
-    width = spec.walk_width(bs, tables.shape[1])
-    blocks, marks = sparse_walk(sel, tables.astype(jnp.int32), spec, bs,
-                                width)
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    maxb = tables.shape[1]
+    per = bs // spec.block
+    width = spec.walk_width(bs, maxb)
+    rows = tile_height(t, heads, d, q.dtype)
+    part_rows = narrow_height(q.dtype)
+    walk = sparse_tile_walk(parts, tables.astype(jnp.int32), rows,
+                            part_rows, width, per)
+    tiles = walk.keys.shape[0]
+    pad = tiles * rows - t
+    # a tile's queries a group at a time, head by head: [tiles, G, R,
+    # rows, D], as the kernel stacks them
+    q_tiles = jnp.pad(q, ((0, pad), (0, 0), (0, 0), (0, 0))).reshape(
+        tiles, rows, g, heads, d).transpose(0, 2, 3, 1, 4)
+    positions = jnp.pad(q_pos.astype(jnp.int32), (0, pad),
+                        constant_values=PAD_POSITION).reshape(tiles, rows, 1)
 
-    def row(ti, gi, j, *_):
-        return (ti, gi, 0, 0)
+    def head_block():
+        return pl.BlockSpec((None, None, heads, rows, d),
+                            lambda i, j, *_: (i, j, 0, 0, 0))
 
-    def block(ti, gi, j, blocks_s, marks_s, qpos_s, layer_s):
-        b = blocks_s[ti, gi * width + j]
-        return (layer_s[0], jnp.where(b < 0, ~b, b), gi, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(t, g, width),
-        in_specs=[pl.BlockSpec((None, None, r, d), row),
-                  pl.BlockSpec((None, None, None, bs, d), block),
-                  pl.BlockSpec((None, None, None, bs, d), block)],
-        out_specs=pl.BlockSpec((None, None, r, d), row),
-        scratch_shapes=[pltpu.VMEM((r, 1), jnp.float32),
-                        pltpu.VMEM((r, 1), jnp.float32),
-                        pltpu.VMEM((r, d), jnp.float32)])
-    return pl.pallas_call(
-        functools.partial(_sparse_kernel, width=width,
-                          per=bs // spec.block, part=spec.block,
-                          scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, g, r, d), q.dtype),
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(_sparse_kernel, part_rows=part_rows, width=width,
+                          per=per, part=spec.block, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(tiles, g),
+            in_specs=[pl.BlockSpec((None, None, rows, maxb),
+                                   lambda i, j, *_: (i, j, 0, 0)),
+                      pl.BlockSpec((None, rows, 1),
+                                   lambda i, j, *_: (i, 0, 0)),
+                      head_block(), hbm, hbm],
+            out_specs=head_block(),
+            scratch_shapes=[
+                pltpu.VMEM((2, bs, d), k_pool.dtype),
+                pltpu.VMEM((2, bs, d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((heads, rows, 1), jnp.float32),
+                pltpu.VMEM((heads, rows, 1), jnp.float32),
+                pltpu.VMEM((heads, rows, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((tiles, g, heads, rows, d), q.dtype),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
         name="sparse_paged_attention",
-    )(blocks, marks, q_pos.astype(jnp.int32), layer, q, k_pool, v_pool)
+    )(walk.total, walk.start, walk.count, walk.after, walk.blocks,
+      walk.marks, jnp.asarray(layer, jnp.int32).reshape(1), walk.keys,
+      positions, q_tiles, k_pool, v_pool)
+    return out.transpose(0, 3, 1, 2, 4).reshape(
+        tiles * rows, g, heads, d)[:t], jnp.sum(walk.total)
 
 
 def sparse_paged_attention(q: jax.Array, k_pool: jax.Array,
@@ -396,8 +620,8 @@ def sparse_paged_attention(q: jax.Array, k_pool: jax.Array,
     num_blocks * block_size / stride, KV * D]`` the stacks, read at
     ``layer``; ``tables [T, max_blocks_per_seq]`` per-token block tables
     (position ``p`` in column ``p // block_size``); ``q_pos [T]``.
-    Returns ``(out [T, N, D], counts [6] int32)``
-    (:func:`selection_counts`). ``force_pallas`` as
+    Returns ``(out [T, N, D], counts [8] int32)``
+    (:data:`COUNT_KINDS`). ``force_pallas`` as
     :func:`.paged_attention.paged_attention`."""
     t, n, d = q.shape
     _, _, kv, bs, _ = k_pool.shape
@@ -409,14 +633,24 @@ def sparse_paged_attention(q: jax.Array, k_pool: jax.Array,
     sel, forced = select_blocks(
         qg, gather_compressed_keys(ck, layer, tables, spec, bs, kv), q_pos,
         spec, scale)
-    counts = selection_counts(sel, forced, q_pos, spec, bs,
-                              spec.walk_width(bs, tables.shape[1]))
+    parts = column_parts(sel, tables, bs // spec.block)
     impl = paged_attention_impl(d, bs, force_pallas)
     if impl == "xla":
         out = _sparse_paged_xla(qg, k_pool, v_pool, layer, tables, q_pos,
                                 sel, spec, scale)
+        # no walk here: the tiles' fetches are counted for the counter
+        # alone, as the kernel's tiles would make them
+        fetched = jnp.sum(first_namers(parts, tables,
+                                       tile_height(t, n // kv, d, q.dtype)))
     else:
-        out = _sparse_paged_pallas(qg, k_pool, v_pool, layer, tables, q_pos,
-                                   sel, spec, scale,
-                                   interpret=impl == "pallas-interpret")
+        out, fetched = _sparse_paged_pallas(
+            qg, k_pool, v_pool, layer, tables, q_pos, parts, spec, scale,
+            interpret=impl == "pallas-interpret")
+    # of the live (row, group, column), those whose pool block a tile
+    # fetches for them, alone or first, and those an earlier row's serves
+    counts = jnp.concatenate([
+        selection_counts(sel, forced, q_pos, spec, bs,
+                         spec.walk_width(bs, tables.shape[1])),
+        jnp.stack([fetched, jnp.sum(parts > 0) - fetched]).astype(
+            jnp.int32)])
     return out.reshape(t, n, d).astype(q.dtype), counts

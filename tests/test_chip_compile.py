@@ -301,24 +301,30 @@ def test_paged_forward_writes_the_donated_pool_in_place(chip, on_one_chip,
 # The cell minicpm-sala.serve-longdocs: 128 rows, 32 query heads in 2 groups
 # over 2 K/V heads of 128, 264 table columns, 4,224 blocks of 128.
 
-def test_sparse_paged_attention(chip):
+@pytest.mark.parametrize("tokens,rows", [(128, 128), (100, 112)])
+def test_sparse_paged_attention(chip, tokens, rows):
+    """The tile kernel at the cell's shapes: one tile of the packed rows
+    (whole parts of 16: a prefill worker's 100 rows ride as 112), a
+    group's 16 heads stacked; under the one name, the Mosaic body copies
+    its blocks itself and multiplies on the MXU, and the walk beside it
+    is built without a sort."""
     from neuronx_distributed_tpu.ops import sparse_attention as sp
 
-    tokens, groups, rep, d, bs, cols, nb, layers = 128, 2, 16, 128, 128, \
-        264, 4224, 4
+    groups, rep, d, bs, cols, nb, layers = 2, 16, 128, 128, 264, 4224, 4
     spec = sp.SparseSpec()
     assert spec.walk_width(bs, cols) == 64
+    assert sp.narrow_height(jnp.bfloat16) == 16
+    assert sp.tile_height(tokens, rep, d, jnp.bfloat16) == rows
     pool = chip((layers, nb, groups, bs, d), jnp.bfloat16)
     fn = functools.partial(sp._sparse_paged_pallas, spec=spec,
                            scale=1.0 / math.sqrt(d), interpret=False)
     text = _assert_kernel_compiles(
-        lambda q, k, v, layer, tables, q_pos, sel: fn(
-            q, k, v, layer, tables, q_pos, sel),
-        chip((tokens, groups, rep, d), jnp.bfloat16), pool, pool,
-        chip((), jnp.int32), chip((tokens, cols), jnp.int32),
-        chip((tokens,), jnp.int32),
-        chip((tokens, groups, cols * bs // spec.block), jnp.bool_))
+        lambda *a: fn(*a), chip((tokens, groups, rep, d), jnp.bfloat16),
+        pool, pool, chip((), jnp.int32), chip((tokens, cols), jnp.int32),
+        chip((tokens,), jnp.int32), chip((tokens, groups, cols), jnp.int32))
     assert _kernel_instruction_names(text) == {"sparse_paged_attention"}
+    assert {"tpu.matmul", "tpu.enqueue_dma"} <= _mosaic_ops(text)
+    assert " sort(" not in text
 
 
 def test_sparse_state_forward_writes_its_stacks_in_place(chip, on_one_chip):

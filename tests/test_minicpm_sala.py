@@ -38,6 +38,7 @@ if BENCH not in sys.path:
 
 import harness  # noqa: E402  (benchmarks/)
 from runners import serve  # noqa: E402
+from walk_checks import check_sparse_walk  # noqa: E402
 
 BS = 16
 SPARSE = dict(kernel=4, stride=2, block=8, topk=6, init_blocks=1, window=16,
@@ -225,39 +226,239 @@ def test_a_packed_step_of_three_slots_equals_each_sequences_recurrence():
     np.testing.assert_allclose(full[0], want[3], atol=2e-5)
 
 
-# -- (e) the kernel -----------------------------------------------------------
+# -- (e) the kernel and its walk ----------------------------------------------
 
-def test_pallas_kernel_in_interpret_mode_equals_the_xla_path():
-    """Dense rows, selecting rows whose pool blocks are half selected, a
-    row in the first block, an unmapped table row and a pad row."""
-    rng = np.random.RandomState(6)
-    layers, nb, kv, d, maxb, n = 2, 24, 2, 16, 12, 4
+PAD = (0, PAD_POSITION)
+#: packed rows as (slot, position): the engine's order, decode rows first
+#: and then a prefill chunk's run; the tile's height where not one tile
+SCENES = {
+    "chunk_crosses_dense_len_inside_one_tile": (
+        [(0, p) for p in range(58, 70)], None),
+    "chunk_over_two_tiles": ([(0, p) for p in range(90, 106)], 8),
+    "decode_rows_of_three_slots_beside_a_chunk": (
+        [(1, 150), (2, 100), (3, 30)] + [(0, p) for p in range(70, 83)],
+        None),
+    "decode_rows_and_a_chunk_over_three_tiles": (
+        [(1, 150), (2, 100), (3, 30)] + [(0, p) for p in range(120, 139)],
+        8),
+    "pad_rows_in_the_middle_and_at_the_end": (
+        [(1, 150), PAD] + [(0, p) for p in range(70, 76)] + [PAD, PAD]
+        + [(0, p) for p in range(76, 80)] + [PAD, PAD], None),
+    "scattered_slots_and_an_unmapped_table": (
+        [(0, 100), (1, 150), (0, 101), (2, 3), (1, 151), (3, 120), (0, 102),
+         PAD, (2, 63)], None),
+}
+
+
+def _pools(seed, nb=64, layers=2, kv=2, d=16):
+    rng = np.random.RandomState(seed)
     k_pool = jnp.asarray(rng.randn(layers, nb, kv, BS, d), jnp.float32)
     v_pool = jnp.asarray(rng.randn(layers, nb, kv, BS, d), jnp.float32)
     ck = jnp.asarray(rng.randn(layers, nb * (BS // 2), kv * d), jnp.float32)
-    q_pos = np.array([3, 40, 63, 64, 100, 150, 191, 120, PAD_POSITION])
-    tables = np.stack([rng.permutation(nb)[:maxb] for _ in q_pos])
-    tables[7] = -1
-    q = jnp.asarray(rng.randn(len(q_pos), n, d), jnp.float32)
-    outs = {}
+    return rng, k_pool, v_pool, ck
+
+
+def _scene(name, seed=6, maxb=12, n=4, d=16):
+    """The scene's rows over four slots' tables (distinct blocks of a pool
+    of 64; slot 3's is unmapped in ``scattered_...``)."""
+    rows, height = SCENES[name]
+    rng, k_pool, v_pool, ck = _pools(seed, d=d)
+    slot_tables = rng.permutation(64)[:4 * maxb].reshape(4, maxb)
+    if name.startswith("scattered"):
+        slot_tables[3] = -1
+    tables = jnp.asarray([slot_tables[s] for s, _ in rows], jnp.int32)
+    q_pos = jnp.asarray([p for _, p in rows], jnp.int32)
+    q = jnp.asarray(rng.randn(len(rows), n, d), jnp.float32)
+    return q, k_pool, v_pool, ck, tables, q_pos, height
+
+
+def _set_tile_height(monkeypatch, height, heads=2, d=16):
+    if height is not None:
+        monkeypatch.setattr(sp, "ACC_BYTES", height * heads * d * 4)
+        assert sp.tile_height(100, heads, d, jnp.float32) == height
+
+
+@pytest.mark.parametrize("scene", list(SCENES))
+def test_pallas_kernel_in_interpret_mode_equals_the_xla_path(scene,
+                                                             monkeypatch):
+    """The selection of the rows' own queries, through the tile kernel and
+    through the gather reference: the same rows attend the same
+    positions. A pad row's output is zero, and so is a row's whose table
+    is unmapped."""
+    q, k_pool, v_pool, ck, tables, q_pos, height = _scene(scene)
+    _set_tile_height(monkeypatch, height)
+    outs, counts = {}, {}
     for force in (False, True):
-        outs[force], counts = sp.sparse_paged_attention(
-            q, k_pool, v_pool, ck, 1, jnp.asarray(tables, jnp.int32),
-            jnp.asarray(q_pos, jnp.int32), SPEC, force_pallas=force)
-    live = q_pos < PAD_POSITION
-    live[7] = False
-    np.testing.assert_allclose(outs[True][live], outs[False][live],
-                               atol=2e-6)
-    assert (np.asarray(outs[True])[8] == 0).all()
-    counts = dict(zip(sp.COUNT_KINDS, np.asarray(counts).tolist()))
-    width = SPEC.walk_width(BS, maxb)
+        outs[force], counts[force] = sp.sparse_paged_attention(
+            q, k_pool, v_pool, ck, 1, tables, q_pos, SPEC,
+            force_pallas=force)
+    live = np.asarray((q_pos < PAD_POSITION) & (tables[:, 0] >= 0))
+    assert live.sum() >= 7
+    np.testing.assert_allclose(np.asarray(outs[True])[live],
+                               np.asarray(outs[False])[live], atol=2e-6)
+    assert (np.asarray(outs[True])[~live] == 0).all()
+    # both paths count the same, the tiles' fetches included
+    np.testing.assert_array_equal(counts[True], counts[False])
+    got = dict(zip(sp.COUNT_KINDS, np.asarray(counts[True]).tolist()))
+    # the selection's counts, exactly, from the scene's positions: every
+    # (row, group, column of the walk's width) is counted once; a row
+    # below dense_len attends every causal column; every causal position
+    # of a real row is attended or skipped
+    kv, width = 2, SPEC.walk_width(BS, tables.shape[1])
     assert width == 6
-    assert (counts["selected"] + counts["forced"] + counts["dense"]
-            + counts["skipped"]) == len(q_pos) * kv * width
-    assert counts["dense"] == kv * (1 + 3 + 4)
-    assert counts["attended"] + counts["skipped_positions"] == kv * int(
-        (q_pos[:-1] + 1).sum())
-    assert counts["selected"] > 0 and counts["forced"] > 0
+    real = [p for _, p in SCENES[scene][0] if p < PAD_POSITION]
+    assert (got["selected"] + got["forced"] + got["dense"]
+            + got["skipped"]) == len(SCENES[scene][0]) * kv * width
+    assert got["dense"] == kv * sum(p // BS + 1 for p in real
+                                    if p < SPEC.dense_len)
+    assert got["attended"] + got["skipped_positions"] == kv * sum(
+        p + 1 for p in real)
+    assert got["selected"] > 0 and got["forced"] > 0
+    assert got["skipped_positions"] > 0
+    # (the selection counts an unmapped table's columns too: no tile
+    # fetches those)
+    assert (got["fetched"] + got["shared"] == got["selected"]
+            + got["forced"] + got["dense"]) == (
+                not scene.startswith("scattered"))
+    assert got["fetched"] > 0
+
+
+@pytest.mark.parametrize("scene", list(SCENES))
+def test_selection_counts_equal_a_brute_count(scene):
+    """``selection_counts`` against loops over the selection's bits: the
+    sources of ``nxd_sparse_columns_total`` and
+    ``nxd_sparse_positions_total``."""
+    q, _, _, ck, tables, q_pos, _ = _scene(scene)
+    sel, forced = sp.select_blocks(
+        q.reshape(q.shape[0], 2, 2, -1),
+        sp.gather_compressed_keys(ck, 1, tables, SPEC, BS, 2), q_pos, SPEC,
+        0.25)
+    width = SPEC.walk_width(BS, tables.shape[1])
+    got = dict(zip(sp.COUNT_KINDS, np.asarray(sp.selection_counts(
+        sel, forced, q_pos, SPEC, BS, width)).tolist()))
+    sel, forced, q_pos = (np.asarray(x) for x in (sel, forced, q_pos))
+    per = BS // SPEC.block
+    want = dict.fromkeys(sp.COUNT_KINDS[:6], 0)
+    for t, p in enumerate(q_pos):
+        for g in range(sel.shape[1]):
+            live = 0
+            for c in range(tables.shape[1]):
+                blocks = slice(c * per, (c + 1) * per)
+                if not sel[t, g, blocks].any():
+                    continue
+                live += 1
+                kind = ("dense" if p < SPEC.dense_len else "forced"
+                        if forced[t, g, blocks].any() else "selected")
+                want[kind] += 1
+            assert live <= width
+            want["skipped"] += width - live
+            mine = sum(1 for b in np.flatnonzero(sel[t, g])
+                       for x in range(b * SPEC.block, (b + 1) * SPEC.block)
+                       if x <= p)
+            want["attended"] += mine
+            want["skipped_positions"] += (p + 1 if p < PAD_POSITION
+                                          else 0) - mine
+    assert got == want
+
+
+def _selection(scene):
+    q, k_pool, v_pool, ck, tables, q_pos, height = _scene(scene)
+    qg = q.reshape(q.shape[0], 2, 2, -1)
+    sel, _ = sp.select_blocks(
+        qg, sp.gather_compressed_keys(ck, 1, tables, SPEC, BS, 2), q_pos,
+        SPEC, 0.25)
+    return qg, k_pool, v_pool, tables, q_pos, sel, height
+
+
+@pytest.mark.parametrize("scene", list(SCENES))
+def test_every_live_column_is_served_by_one_pair_of_its_tile(scene):
+    """The walk by brute count (``walk_checks.check_sparse_walk``), at the
+    scene's tile height and at the others: a chunk's rows share their
+    slot's blocks, decode rows' pairs are narrow where their neighbours
+    in the part are other slots' rows."""
+    _, _, _, tables, q_pos, sel, height = _selection(scene)
+    per, width = BS // SPEC.block, SPEC.walk_width(BS, tables.shape[1])
+    parts = sp.column_parts(sel, tables, per)
+    live = int((np.asarray(parts) > 0).sum())
+    fetched = {}
+    for rows in sorted({height or 16, 8, 16, 24}):
+        walk = sp.sparse_tile_walk(parts, tables, rows, 8, width, per)
+        fetched[rows] = check_sparse_walk(walk, parts, tables, rows, 8,
+                                          width, per)
+        assert fetched[rows] == int(np.asarray(
+            sp.first_namers(parts, tables, rows)).sum())
+        assert 0 < fetched[rows] <= live
+    # a taller tile fetches no more
+    assert fetched[24] <= fetched[16] <= fetched[8]
+    if scene.startswith("chunk"):
+        assert fetched[8] < live            # the chunk's rows share
+
+
+def test_real_rows_are_unchanged_by_pad_rows_beside_them():
+    """The rows of ``pad_rows_...`` with and without the pad rows between
+    and after them: the real rows' outputs are the same bits."""
+    q, k_pool, v_pool, ck, tables, q_pos, _ = _scene(
+        "pad_rows_in_the_middle_and_at_the_end")
+    real = np.flatnonzero(np.asarray(q_pos) < PAD_POSITION)
+    with_pads, _ = sp.sparse_paged_attention(
+        q, k_pool, v_pool, ck, 1, tables, q_pos, SPEC, force_pallas=True)
+    alone, _ = sp.sparse_paged_attention(
+        q[real], k_pool, v_pool, ck, 1, tables[real], q_pos[real], SPEC,
+        force_pallas=True)
+    np.testing.assert_array_equal(np.asarray(with_pads)[real],
+                                  np.asarray(alone))
+    assert (np.delete(np.asarray(with_pads), real, 0) == 0).all()
+
+
+def _attend_given(sel, q_pos, seed=9):
+    """Rows of one slot under a selection written by hand: the kernel
+    against the reference, where a (row, group) attends anything (the
+    reference's softmax over nothing is uniform; the kernel's is zero)."""
+    t = len(q_pos)
+    rng, k_pool, v_pool, _ = _pools(seed)
+    tables = jnp.broadcast_to(
+        jnp.asarray(rng.permutation(64)[:12], jnp.int32), (t, 12))
+    q = jnp.asarray(rng.randn(t, 2, 2, 16), jnp.float32)
+    q_pos = jnp.asarray(q_pos, jnp.int32)
+    sel = jnp.asarray(sel)
+    want = sp._sparse_paged_xla(q, k_pool, v_pool, 0, tables, q_pos, sel,
+                                SPEC, 0.25)
+    parts = sp.column_parts(sel, tables, BS // SPEC.block)
+    got, fetched = sp._sparse_paged_pallas(q, k_pool, v_pool, 0, tables,
+                                           q_pos, parts, SPEC, 0.25,
+                                           interpret=True)
+    some = np.asarray(sel).any(-1)
+    np.testing.assert_allclose(np.asarray(got)[some], np.asarray(want)[some],
+                               atol=2e-6)
+    assert (np.asarray(got)[~some] == 0).all()
+    return np.asarray(parts), int(fetched)
+
+
+def test_two_rows_that_select_different_halves_of_one_pool_block():
+    """Pool block 3 holds selection blocks 6 and 7: one row attends the
+    first, its neighbour the second, a third both, in one group; the
+    other group the other way round. One fetch a group serves all three,
+    each under its own mask."""
+    sel = np.zeros((3, 2, 24), bool)
+    sel[0, 0, 6] = sel[1, 0, 7] = sel[2, 0, 6] = sel[2, 0, 7] = True
+    sel[0, 1, 7] = sel[1, 1, 6] = sel[2, 1, 6] = sel[2, 1, 7] = True
+    sel[:, :, 0] = True
+    parts, fetched = _attend_given(sel, [180, 181, 182])
+    assert parts[:, 0, 3].tolist() == [1, 2, 3]
+    assert parts[:, 1, 3].tolist() == [2, 1, 3]
+    assert fetched == 4                 # columns 0 and 3, two groups
+
+
+def test_a_row_whose_selection_is_disjoint_from_its_tiles():
+    """Nine rows of one slot: eight attend blocks 0-5, the ninth blocks
+    16-21 alone (and in the other group nothing at all: its output there
+    is zero, as a row's that attends nothing is)."""
+    sel = np.zeros((9, 2, 24), bool)
+    sel[:8, :, :6] = True
+    sel[8, 0, 16:22] = True
+    parts, fetched = _attend_given(sel, list(range(176, 185)))
+    assert fetched == 3 * 2 + 3
+    assert not (parts[:8, 0] > 0)[:, 8:].any() and not parts[8, 0, :8].any()
 
 
 def test_compressed_keys_land_when_their_kernel_is_whole():
@@ -309,8 +510,9 @@ def served():
         name: {c.labels.get("kind", ""): c.value
                for c in obs.get_registry().get(name).children()}
         for name in ("nxd_sparse_columns_total",
-                     "nxd_sparse_positions_total", "nxd_state_resets_total",
-                     "nxd_engine_rows_total")}
+                     "nxd_sparse_positions_total",
+                     "nxd_sparse_block_visits_total",
+                     "nxd_state_resets_total", "nxd_engine_rows_total")}
     obs.disable()
     ps.destroy_model_parallel()
     return cfg, params, eng, prompts, new, counters
@@ -348,6 +550,13 @@ def test_sparse_and_state_counters(served):
     assert sum(cols.values()) == sum(rows.values()) * 2 * width * 3
     pos = counters["nxd_sparse_positions_total"]
     assert pos["attended"] > 0 and pos["skipped"] > 0
+    # every live (row, group, column) is fetched for or shares a fetch;
+    # a prefill chunk's rows share their slot's blocks
+    visits = counters["nxd_sparse_block_visits_total"]
+    assert set(visits) == {"fetched", "shared"}
+    assert visits["fetched"] + visits["shared"] == sum(
+        cols[k] for k in ("selected", "forced", "dense"))
+    assert visits["shared"] > visits["fetched"] > 0
     # three admissions and b's second
     assert counters["nxd_state_resets_total"][""] >= 4
 

@@ -1,6 +1,8 @@
-"""What every walk of the paged kernel must hold, whatever the cache kind:
+"""What every walk of the paged kernels must hold, whatever the cache kind:
 shared by ``test_paging.py`` (a full cache), ``test_evabyte.py`` (a
-window-summary cache) and ``test_prefix_sharing.py``."""
+window-summary cache), ``test_prefix_sharing.py`` and
+``test_minicpm_sala.py`` (a sparse-state cache, whose walk is a layer's
+selection)."""
 
 import numpy as np
 
@@ -41,3 +43,64 @@ def check_tile_walk(walk, live, tables, rows, n_rep):
         for r in mine:
             np.testing.assert_array_equal(
                 served[r - i * rows, 0], np.where(live[r], tables[r], -1))
+
+
+def check_sparse_walk(walk, parts, tables, rows, part_rows, width, per):
+    """What :func:`..ops.sparse_attention.sparse_tile_walk` must hold, by
+    brute count: following a (tile, group)'s lists from ``start`` through
+    ``after``, every live (row, group, column) is served by exactly one
+    pair of its tile (the pair of the column and the row's own table
+    entry), no pair is listed twice, a row lists only pairs no earlier row
+    of the tile attends, in order of column, ``total`` is the count of
+    the tile's distinct pairs, a pair is narrow exactly if every row that
+    attends it lies in its lister's part, and ``keys`` hold what each row
+    attends of each column. Returns the pairs counted."""
+    parts, tables = np.asarray(parts), np.asarray(tables)
+    t, groups, maxb = parts.shape
+    walk = type(walk)(*(np.asarray(x) for x in walk))
+    tiles = walk.keys.shape[0]
+    assert tiles * rows >= t and walk.keys.shape == (tiles, groups, rows,
+                                                     maxb)
+    fetched = 0
+    for i in range(tiles):
+        mine = range(i * rows, min((i + 1) * rows, t))
+        for g in range(groups):
+            want = {}       # (column, block) -> the tile's rows that name it
+            for r in mine:
+                for c in np.flatnonzero(parts[r, g]):
+                    want.setdefault((int(c), int(tables[r, c])),
+                                    []).append(r - i * rows)
+            got = {}
+            r, seen = int(walk.start[i * groups + g]), 0
+            while r < rows:
+                at = (i * rows + r) * groups + g
+                n = int(walk.count[at])
+                assert 0 < n <= width
+                cols = []
+                for k in range(n):
+                    mark = int(walk.marks[at * width + k])
+                    pair = (mark >> 1, int(walk.blocks[at * width + k]))
+                    assert pair not in got                  # none twice
+                    got[pair] = (r, mark & 1)
+                    cols.append(pair[0])
+                assert cols == sorted(cols)
+                seen += n
+                assert int(walk.after[at]) > r
+                r = int(walk.after[at])
+            assert r == rows
+            assert set(got) == set(want)                # each served, once
+            assert seen == int(walk.total[i * groups + g]) == len(want)
+            for pair, (lister, narrow) in got.items():
+                namers = want[pair]
+                assert lister == namers[0]              # its first namer
+                end = (lister // part_rows + 1) * part_rows
+                assert narrow == int(namers[-1] < end)
+            for r in range(rows):
+                row = i * rows + r
+                for c in range(maxb):
+                    live = row < t and parts[row, g, c] > 0
+                    assert int(walk.keys[i, g, r, c]) == (
+                        (int(tables[row, c]) << per | int(parts[row, g, c]))
+                        if live else 0)
+            fetched += len(want)
+    return fetched
