@@ -37,6 +37,7 @@ import numpy as np
 
 from ..models.llama import LlamaConfig
 from ..obs.accounting import CompileTracker
+from ..obs.device_scopes import device_scope
 from ..obs.events import emit_event
 from ..obs.metrics import get_registry
 from ..obs.tracing import get_tracer
@@ -882,7 +883,8 @@ class ServingEngine:
             logits, new_cache = forward(
                 model_cfg, params, tokens, positions, local,
                 slot_ids=slot_ids, **kw)
-            toks = sample(logits[0], rng, sampling)
+            with device_scope("sample"):
+                toks = sample(logits[0], rng, sampling)
             return toks, new_cache.replace(block_tables=tbl)
 
         row = P(None, ps.CP_AXIS) if prefill else P()
@@ -909,7 +911,8 @@ class ServingEngine:
                 logits, cache = forward(
                     model_cfg, params, tokens, positions, cache,
                     slot_ids=slot_ids)
-                toks = sample(logits[0], rng, sampling)
+                with device_scope("sample"):
+                    toks = sample(logits[0], rng, sampling)
                 return toks, cache
 
             return jax.jit(step_fn,
@@ -930,7 +933,8 @@ class ServingEngine:
             _, dcache = draft_forward(
                 draft_cfg, draft_params, tokens, positions, dcache,
                 slot_ids=slot_ids)
-            toks = sample(logits[0], rng, sampling)
+            with device_scope("sample"):
+                toks = sample(logits[0], rng, sampling)
             return toks, cache, dcache
 
         return jax.jit(spec_step_fn,
@@ -961,15 +965,17 @@ class ServingEngine:
             # branch split: lane (s, b) continues from the b-th most
             # likely draft token (rows of one slot are identical — read
             # lane b=0's row)
-            _, top = jax.lax.top_k(logits[0], nb)            # [S*B, B]
-            d1 = top.reshape(s, nb, nb)[:, 0, :].reshape(s * nb)
+            with device_scope("sample"):
+                _, top = jax.lax.top_k(logits[0], nb)        # [S*B, B]
+                d1 = top.reshape(s, nb, nb)[:, 0, :].reshape(s * nb)
 
             def body(carry, d):
                 dc, tok = carry
                 p = jnp.where(pos0 < PAD_POSITION, pos0 + d, PAD_POSITION)
                 lg, dc = forward(dcfg, draft_params, tok[None, :],
                                  p[None, :], dc, slot_ids=lanes)
-                nxt = jnp.argmax(lg[0], axis=-1)
+                with device_scope("sample"):
+                    nxt = jnp.argmax(lg[0], axis=-1)
                 return (dc, nxt), tok
 
             (dcache, _), toks = jax.lax.scan(
@@ -1014,43 +1020,45 @@ class ServingEngine:
             logits, cache = forward(
                 cfg, params, lane_tok.reshape(1, rows), positions, cache,
                 slot_ids=slot_ids)
-            lg = logits[0].reshape(s, nb, k + 1, logits.shape[-1])
-            # tree node order matches SpeculationConfig.tree_choices():
-            # root, then branch-major chains — node (b, d) at 1 + b*k+d-1
-            tree_logits = jnp.concatenate(
-                [lg[:, 0, :1], lg[:, :, 1:].reshape(s, nb * k, -1)],
-                axis=1)
-            tree_tokens = jnp.concatenate(
-                [committed[:, None], drafted.reshape(s, nb * k)], axis=1)
-            best, alen = medusa_accept_longest(tree_logits, tree_tokens,
-                                               buffers)
-            bonus = jnp.take_along_axis(
-                jnp.argmax(tree_logits, axis=-1), best[:, None],
-                axis=1)[:, 0]
-            bstar = jnp.maximum(branch_of[best], 0)
-            sel = jnp.take_along_axis(
-                drafted, bstar[:, None, None], axis=1)[:, 0]  # [S, k]
-            jj = offs[None, :]
-            emit = jnp.where(jj < alen[:, None],
-                             jnp.pad(sel, ((0, 0), (0, 1))),
-                             bonus[:, None])
+            with device_scope("sample"):
+                lg = logits[0].reshape(s, nb, k + 1, logits.shape[-1])
+                # tree node order matches SpeculationConfig.tree_choices():
+                # root, then branch-major chains — node (b, d) at 1 + b*k+d-1
+                tree_logits = jnp.concatenate(
+                    [lg[:, 0, :1], lg[:, :, 1:].reshape(s, nb * k, -1)],
+                    axis=1)
+                tree_tokens = jnp.concatenate(
+                    [committed[:, None], drafted.reshape(s, nb * k)], axis=1)
+                best, alen = medusa_accept_longest(tree_logits, tree_tokens,
+                                                   buffers)
+                bonus = jnp.take_along_axis(
+                    jnp.argmax(tree_logits, axis=-1), best[:, None],
+                    axis=1)[:, 0]
+                bstar = jnp.maximum(branch_of[best], 0)
+                sel = jnp.take_along_axis(
+                    drafted, bstar[:, None, None], axis=1)[:, 0]  # [S, k]
+                jj = offs[None, :]
+                emit = jnp.where(jj < alen[:, None],
+                                 jnp.pad(sel, ((0, 0), (0, 1))),
+                                 bonus[:, None])
             # rollback: un-publish every row outside the accepted path of
             # the winning branch, in both pools (same tables, same flat
             # indices — the pools share block geometry by construction)
-            brow = jnp.broadcast_to(
-                jnp.arange(nb)[None, :, None], (s, nb, k + 1))
-            keep = ((brow == bstar[:, None, None])
-                    & (offs[None, None, :] <= alen[:, None, None]))
-            tok_tables = cache.block_tables[
-                jnp.clip(slot_ids, 0, cache.max_slots - 1)]
-            flat_idx = flat_write_indices(tok_tables, positions[0],
-                                          cache.block_size,
-                                          cache.capacity)
-            reject = (~keep).reshape(rows)
-            cache = cache.replace(pos=mask_pool_positions(
-                cache.pos, flat_idx, reject))
-            dcache = dcache.replace(pos=mask_pool_positions(
-                dcache.pos, flat_idx, reject))
+            with device_scope("attn.pool_write"):
+                brow = jnp.broadcast_to(
+                    jnp.arange(nb)[None, :, None], (s, nb, k + 1))
+                keep = ((brow == bstar[:, None, None])
+                        & (offs[None, None, :] <= alen[:, None, None]))
+                tok_tables = cache.block_tables[
+                    jnp.clip(slot_ids, 0, cache.max_slots - 1)]
+                flat_idx = flat_write_indices(tok_tables, positions[0],
+                                              cache.block_size,
+                                              cache.capacity)
+                reject = (~keep).reshape(rows)
+                cache = cache.replace(pos=mask_pool_positions(
+                    cache.pos, flat_idx, reject))
+                dcache = dcache.replace(pos=mask_pool_positions(
+                    dcache.pos, flat_idx, reject))
             return cache, dcache, emit, alen, bstar
 
         on_accel = on_tpu()

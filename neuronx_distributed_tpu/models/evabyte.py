@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..obs.device_scopes import device_scope
 from ..parallel import layers as pl
 from .llama import LlamaConfig, LlamaModel, llama_forward_with_cache
 
@@ -89,12 +90,13 @@ class EvaByteForCausalLM(nn.Module):
                  positions: Optional[jax.Array] = None) -> jax.Array:
         cfg = self.cfg
         x, _ = LlamaModel(cfg, name="model")(input_ids, positions)
-        logits = pl.ColumnParallelLinear(
-            features=cfg.num_pred_heads * cfg.vocab_size, use_bias=False,
-            gather_output=True, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name="lm_head")(x)
-        return logits.astype(jnp.float32).reshape(
-            *logits.shape[:-1], cfg.num_pred_heads, cfg.vocab_size)
+        with device_scope("head"):
+            logits = pl.ColumnParallelLinear(
+                features=cfg.num_pred_heads * cfg.vocab_size, use_bias=False,
+                gather_output=True, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="lm_head")(x)
+            return logits.astype(jnp.float32).reshape(
+                *logits.shape[:-1], cfg.num_pred_heads, cfg.vocab_size)
 
 
 def evabyte_forward_with_cache(cfg: EvaByteConfig, params, input_ids,
@@ -107,4 +109,5 @@ def evabyte_forward_with_cache(cfg: EvaByteConfig, params, input_ids,
     out = llama_forward_with_cache(
         cfg, {"params": {**p, "lm_head": head}}, input_ids, positions,
         kv_cache, slot_ids=slot_ids, **kw)
-    return (out[0].astype(jnp.float32),) + tuple(out[1:])
+    with device_scope("head"):
+        return (out[0].astype(jnp.float32),) + tuple(out[1:])

@@ -50,6 +50,7 @@ from ..modules import attention as attn_mod
 from ..modules.attention import rope_rows
 from ..modules.moe import MoE
 from ..modules.norms import RMSNorm
+from ..obs.device_scopes import device_scope
 from ..ops import mla_attention as mla
 from ..parallel import layers as pl
 from ..parallel import loss_functions as lf
@@ -181,56 +182,63 @@ class LatentAttention(nn.Module):
                 (x.shape[-1], features), cfg.param_dtype)
             return jnp.dot(x.astype(cfg.dtype), kernel.astype(cfg.dtype))
 
-        c_q = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="q_a_norm")(
-            dense("q_a", cfg.q_lora_rank))
-        q = pl.ColumnParallelLinear(
-            features=cfg.num_heads * (nope + rope), use_bias=False,
-            gather_output=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name="q_b")(c_q)
-        q = q.reshape(b, s, heads, nope + rope)
-        kv = dense("kv_a", rank + rope)
-        latent = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                         name="kv_a_norm")(kv[..., :rank])
-        k_rope = attn_mod.apply_rotary(kv[..., None, rank:], cos, sin)
-        q_rope = attn_mod.apply_rotary(q[..., nope:], cos, sin)
-        k_up, v_up = (self.param(
-            name, nn.with_partitioning(pl.default_kernel_init,
-                                       (ps.TP_AXIS, None, None)),
-            shape, cfg.param_dtype) for name, shape in (
-                ("k_up", (heads, nope, rank)),
-                ("v_up", (heads, rank, cfg.v_head_dim))))
-        scale = 1.0 / math.sqrt(nope + rope)
-        row = cfg.head_dim_
-        rows = jnp.concatenate(
-            [latent, k_rope[:, :, 0],
-             jnp.zeros((b, s, row - rank - rope), latent.dtype)], axis=-1)
+        with device_scope("attn.proj"):
+            c_q = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="q_a_norm")(
+                dense("q_a", cfg.q_lora_rank))
+            q = pl.ColumnParallelLinear(
+                features=cfg.num_heads * (nope + rope), use_bias=False,
+                gather_output=False, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="q_b")(c_q)
+            q = q.reshape(b, s, heads, nope + rope)
+            kv = dense("kv_a", rank + rope)
+            latent = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                             name="kv_a_norm")(kv[..., :rank])
+            k_rope = attn_mod.apply_rotary(kv[..., None, rank:], cos, sin)
+            q_rope = attn_mod.apply_rotary(q[..., nope:], cos, sin)
+            k_up, v_up = (self.param(
+                name, nn.with_partitioning(pl.default_kernel_init,
+                                           (ps.TP_AXIS, None, None)),
+                shape, cfg.param_dtype) for name, shape in (
+                    ("k_up", (heads, nope, rank)),
+                    ("v_up", (heads, rank, cfg.v_head_dim))))
+            scale = 1.0 / math.sqrt(nope + rope)
+            row = cfg.head_dim_
+            rows = jnp.concatenate(
+                [latent, k_rope[:, :, 0],
+                 jnp.zeros((b, s, row - rank - rope), latent.dtype)], axis=-1)
 
-        q_row = mla.absorb_queries(q[..., :nope], q_rope, k_up, row)
+            q_row = mla.absorb_queries(q[..., :nope], q_rope, k_up, row)
         new_cache = None
         if cache is None:
-            scores = jnp.einsum(
-                "btnw,bkw->bntk", q_row.astype(jnp.float32),
-                rows.astype(jnp.float32)) * scale
-            causal = jnp.tril(jnp.ones((s, s), bool))
-            probs = jax.nn.softmax(jnp.where(causal, scores, -1e30), axis=-1)
-            ctx = jnp.einsum("bntk,bkr->btnr", probs,
-                             rows[..., :rank].astype(jnp.float32)
-                             ).astype(cfg.dtype)
+            with device_scope("attn.kernel"):
+                scores = jnp.einsum(
+                    "btnw,bkw->bntk", q_row.astype(jnp.float32),
+                    rows.astype(jnp.float32)) * scale
+                causal = jnp.tril(jnp.ones((s, s), bool))
+                probs = jax.nn.softmax(jnp.where(causal, scores, -1e30),
+                                       axis=-1)
+                ctx = jnp.einsum("bntk,bkr->btnr", probs,
+                                 rows[..., :rank].astype(jnp.float32)
+                                 ).astype(cfg.dtype)
         else:
             from ..inference import paging
 
-            pool = paging.write_pool_rows(cache.rows, rows[0],
-                                          cache.write_idx, cache.layer)
-            ctx = mla.mla_paged_attention(
-                q_row[0], pool, cache.pos, cache.tables, cache.q_pos,
-                cache.layer, rank, scale,
-                force_pallas=cfg.attn_force_pallas, walk=cache.walk)[None]
+            with device_scope("attn.pool_write"):
+                pool = paging.write_pool_rows(cache.rows, rows[0],
+                                              cache.write_idx, cache.layer)
+            with device_scope("attn.kernel"):
+                ctx = mla.mla_paged_attention(
+                    q_row[0], pool, cache.pos, cache.tables, cache.q_pos,
+                    cache.layer, rank, scale,
+                    force_pallas=cfg.attn_force_pallas,
+                    walk=cache.walk)[None]
             new_cache = cache.replace(rows=pool)
-        out = mla.expand_values(ctx, v_up)
-        out = pl.RowParallelLinear(
-            features=cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name="o_proj")(
-            out.reshape(b, s, heads * cfg.v_head_dim).astype(cfg.dtype))
+        with device_scope("attn.proj"):
+            out = mla.expand_values(ctx, v_up)
+            out = pl.RowParallelLinear(
+                features=cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="o_proj")(
+                out.reshape(b, s, heads * cfg.v_head_dim).astype(cfg.dtype))
         if cache is not None:
             return out, new_cache
         return out
@@ -245,12 +253,14 @@ class GlmMoeLiteModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids: jax.Array) -> jax.Array:
         cfg = self.cfg
-        x = pl.ParallelEmbedding(
-            num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            name="embed")(input_ids)
-        cos, sin = rope_rows(jnp.arange(input_ids.shape[1]),
-                             cfg.qk_rope_head_dim, cfg.rope_theta)
+        with device_scope("embed"):
+            x = pl.ParallelEmbedding(
+                num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
+                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                name="embed")(input_ids)
+        with device_scope("attn.proj"):
+            cos, sin = rope_rows(jnp.arange(input_ids.shape[1]),
+                                 cfg.qk_rope_head_dim, cfg.rope_theta)
         if self.is_initializing():
             # the parameters: one stack a kind (the order the layers run
             # in is run_layers' business, and makes no parameter)
@@ -267,7 +277,8 @@ class GlmMoeLiteModel(nn.Module):
                 self.variables["params"][f"layers_{kind}"])
                 for kind, _, _ in cfg.runs()}
             x, _ = run_layers(cfg, stacks, x, cos, sin, CARRIED)
-        return RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="norm")(x)
+        with device_scope("norm"):
+            return RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="norm")(x)
 
 
 class GlmMoeLiteForCausalLM(nn.Module):
@@ -279,12 +290,15 @@ class GlmMoeLiteForCausalLM(nn.Module):
                  ignore_index: int = -100) -> jax.Array:
         cfg = self.cfg
         x = GlmMoeLiteModel(cfg, name="model")(input_ids)
-        logits = pl.ColumnParallelLinear(
-            features=cfg.vocab_size, use_bias=False, gather_output=False,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="lm_head")(x)
+        with device_scope("head"):
+            logits = pl.ColumnParallelLinear(
+                features=cfg.vocab_size, use_bias=False, gather_output=False,
+                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                name="lm_head")(x)
         if labels is not None:
-            return lf.causal_lm_loss(logits, labels,
-                                     ignore_index=ignore_index)
+            with device_scope("loss"):
+                return lf.causal_lm_loss(logits, labels,
+                                         ignore_index=ignore_index)
         return logits
 
 
@@ -309,22 +323,28 @@ def glm_moe_lite_forward_with_cache(cfg: GlmMoeLiteConfig, params,
     p = params["params"]
     q_pos = jnp.asarray(positions, jnp.int32)[0]
     slot_ids = jnp.asarray(slot_ids, jnp.int32)
-    x = pl.ParallelEmbedding(
-        num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
-        dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
-        {"params": p["model"]["embed"]}, input_ids)
-    cos, sin = rope_rows(jnp.minimum(q_pos, cfg.max_seq_len - 1),
-                         cfg.qk_rope_head_dim, cfg.rope_theta)
+    with device_scope("embed"):
+        x = pl.ParallelEmbedding(
+            num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
+            {"params": p["model"]["embed"]}, input_ids)
+    with device_scope("attn.proj"):
+        cos, sin = rope_rows(jnp.minimum(q_pos, cfg.max_seq_len - 1),
+                             cfg.qk_rope_head_dim, cfg.rope_theta)
     kind = cfg.serving_family().cache_kind.geometry(kv_cache.block_size)
-    tables = kv_cache.block_tables[
-        jnp.clip(slot_ids, 0, kv_cache.max_slots - 1)]
-    write_idx = paging.flat_write_indices(
-        tables, q_pos, kv_cache.block_size, kv_cache.capacity, kind)
-    pool_pos = paging.write_pool_positions(kv_cache.pos, q_pos, write_idx)
-    walk = mla.step_walk(tables, q_pos, kv_cache.block_size,
-                         kv_cache.num_blocks, cfg.head_dim_,
-                         cfg.kv_lora_rank, cfg.num_heads,
-                         force_pallas=cfg.attn_force_pallas)
+    with device_scope("attn.walk"):
+        tables = kv_cache.block_tables[
+            jnp.clip(slot_ids, 0, kv_cache.max_slots - 1)]
+        write_idx = paging.flat_write_indices(
+            tables, q_pos, kv_cache.block_size, kv_cache.capacity, kind)
+    with device_scope("attn.pool_write"):
+        pool_pos = paging.write_pool_positions(kv_cache.pos, q_pos,
+                                               write_idx)
+    with device_scope("attn.walk"):
+        walk = mla.step_walk(tables, q_pos, kv_cache.block_size,
+                             kv_cache.num_blocks, cfg.head_dim_,
+                             cfg.kv_lora_rank, cfg.num_heads,
+                             force_pallas=cfg.attn_force_pallas)
 
     def view_of(kind, carry, layer):
         # a layer's rows in the stack: the dense layers lead
@@ -343,10 +363,12 @@ def glm_moe_lite_forward_with_cache(cfg: GlmMoeLiteConfig, params,
               for kind, _, _ in cfg.runs()}
     x, carry = run_layers(cfg, stacks, x, cos, sin, CARRIED, carry, view_of,
                           merge, valid=(q_pos < PAD_POSITION)[None])
-    x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype).apply(
-        {"params": p["model"]["norm"]}, x)
-    logits = pl.ColumnParallelLinear(
-        features=cfg.vocab_size, use_bias=False, gather_output=True,
-        dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
-        {"params": p["lm_head"]}, x)
+    with device_scope("norm"):
+        x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype).apply(
+            {"params": p["model"]["norm"]}, x)
+    with device_scope("head"):
+        logits = pl.ColumnParallelLinear(
+            features=cfg.vocab_size, use_bias=False, gather_output=True,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
+            {"params": p["lm_head"]}, x)
     return logits, kv_cache.replace(pos=pool_pos, **carry)

@@ -30,6 +30,7 @@ from flax import linen as nn
 from ..modules import attention as attn_mod
 from ..modules import glu
 from ..modules.norms import RMSNorm
+from ..obs.device_scopes import device_scope
 from ..ops import collective_matmul as cm
 from ..parallel import layers as pl
 from ..parallel import loss_functions as lf
@@ -367,10 +368,11 @@ def _cp_prefill_attend(cfg: LlamaConfig, q, k, v, positions, view):
     from ..ops.ring_attention import ring_attention
 
     k_rows, v_rows = k[0], v[0]                      # [W_local, KV, D]
-    new_k = paging.write_pool_rows(view.k, k_rows, view.write_idx,
-                                   view.layer)
-    new_v = paging.write_pool_rows(view.v, v_rows, view.write_idx,
-                                   view.layer)
+    with device_scope("attn.pool_write"):
+        new_k = paging.write_pool_rows(view.k, k_rows, view.write_idx,
+                                       view.layer)
+        new_v = paging.write_pool_rows(view.v, v_rows, view.write_idx,
+                                       view.layer)
     n_rep = q.shape[2] // k.shape[2]
     kf = attn_mod.repeat_kv(k, n_rep)
     vf = attn_mod.repeat_kv(v, n_rep)
@@ -410,14 +412,15 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
     def write(pool, rows):
         return paging.write_pool_rows(pool, rows, view.write_idx, view.layer)
 
-    if view.k_scale is not None:
-        qk, ks = quantize_kv(k_rows)
-        qv, vs = quantize_kv(v_rows)
-        new_k, new_v = write(view.k, qk), write(view.v, qv)
-        new_ks, new_vs = write(view.k_scale, ks), write(view.v_scale, vs)
-    else:
-        new_k, new_v = write(view.k, k_rows), write(view.v, v_rows)
-        new_ks = new_vs = None
+    with device_scope("attn.pool_write"):
+        if view.k_scale is not None:
+            qk, ks = quantize_kv(k_rows)
+            qv, vs = quantize_kv(v_rows)
+            new_k, new_v = write(view.k, qk), write(view.v, qv)
+            new_ks, new_vs = write(view.k_scale, ks), write(view.v_scale, vs)
+        else:
+            new_k, new_v = write(view.k, k_rows), write(view.v, v_rows)
+            new_ks = new_vs = None
     out = paged_attention(
         q[0], new_k, new_v, view.pos, view.tables, positions[0],
         view.layer, k_scale=new_ks, v_scale=new_vs,
@@ -451,13 +454,15 @@ def _eva_attend(cfg: LlamaConfig, q, k, v, positions, cache, phi, mu):
     from ..ops.paged_attention import paged_attention
 
     kind = cfg.serving_family().cache_kind
-    new_k = paging.write_pool_rows(cache.k, k[0], cache.write_idx,
-                                   cache.layer)
-    new_v = paging.write_pool_rows(cache.v, v[0], cache.write_idx,
-                                   cache.layer)
-    new_k, new_v = eva.write_window_summaries(
-        new_k, new_v, cache.layer, cache.roll, phi, mu, cfg.chunk_size,
-        scale)
+    with device_scope("attn.pool_write"):
+        new_k = paging.write_pool_rows(cache.k, k[0], cache.write_idx,
+                                       cache.layer)
+        new_v = paging.write_pool_rows(cache.v, v[0], cache.write_idx,
+                                       cache.layer)
+    with device_scope("attn.summarise"):
+        new_k, new_v = eva.write_window_summaries(
+            new_k, new_v, cache.layer, cache.roll, phi, mu, cfg.chunk_size,
+            scale)
     out = paged_attention(
         q[0], new_k, new_v, cache.pos, cache.tables, positions[0],
         cache.layer, scale=scale, force_pallas=cfg.attn_force_pallas,
@@ -478,10 +483,14 @@ def _sparse_attend(cfg: LlamaConfig, q, k, v, view):
     if view is None:
         return sp.sparse_attention_full(q, k, v, cfg.sparse, scale).astype(
             cfg.dtype), None
-    new_k = sp.write_sparse_rows(view.k, k[0], view.write_idx, view.layer)
-    new_v = sp.write_sparse_rows(view.v, v[0], view.write_idx, view.layer)
-    new_ck = sp.write_compressed_keys(view.ck, new_k, view.layer,
-                                      view.tables, view.q_pos, cfg.sparse)
+    with device_scope("attn.pool_write"):
+        new_k = sp.write_sparse_rows(view.k, k[0], view.write_idx,
+                                     view.layer)
+        new_v = sp.write_sparse_rows(view.v, v[0], view.write_idx,
+                                     view.layer)
+        new_ck = sp.write_compressed_keys(view.ck, new_k, view.layer,
+                                          view.tables, view.q_pos,
+                                          cfg.sparse)
     out, counts = sp.sparse_paged_attention(
         q[0], new_k, new_v, new_ck, view.layer, view.tables, view.q_pos,
         cfg.sparse, scale=scale, force_pallas=cfg.attn_force_pallas)
@@ -499,10 +508,12 @@ def _lightning_attend(cfg: LlamaConfig, q, k, v, view):
 
     scale = 1.0 / _math.sqrt(q.shape[-1])
     if view is None:
-        return la.lightning_attention_full(q, k, v, scale), None
-    out, state = la.lightning_attention_packed(
-        q[0], k[0], v[0], view.state, view.layer, view.slot_ids,
-        view.q_pos, scale)
+        with device_scope("attn.state"):
+            return la.lightning_attention_full(q, k, v, scale), None
+    with device_scope("attn.state"):
+        out, state = la.lightning_attention_packed(
+            q[0], k[0], v[0], view.state, view.layer, view.slot_ids,
+            view.q_pos, scale)
     return out[None], view.replace(state=state)
 
 
@@ -527,207 +538,218 @@ class LlamaAttention(nn.Module):
                  cache=None, cache_index=None):
         cfg = self.cfg
         head_dim = cfg.head_dim_
-        if cfg.weight_quant is not None and cfg.weight_quant.startswith(
-                "mx"):
-            from ..quantization.mx_layers import MXGQAQKVColumnParallelLinear
+        with device_scope("attn.proj"):
+            if cfg.weight_quant is not None and cfg.weight_quant.startswith(
+                    "mx"):
+                from ..quantization.mx_layers import \
+                    MXGQAQKVColumnParallelLinear
 
-            q, k, v = MXGQAQKVColumnParallelLinear(
-                num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-                head_dim=head_dim, mx_format=cfg.weight_quant[2:],
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                tp_size=cfg.tp_size, name="qkv")(x)
-        elif cfg.weight_quant is not None:
-            from ..quantization.quantization_layers import \
-                QuantizedGQAQKVColumnParallelLinear
+                q, k, v = MXGQAQKVColumnParallelLinear(
+                    num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                    head_dim=head_dim, mx_format=cfg.weight_quant[2:],
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    tp_size=cfg.tp_size, name="qkv")(x)
+            elif cfg.weight_quant is not None:
+                from ..quantization.quantization_layers import \
+                    QuantizedGQAQKVColumnParallelLinear
 
-            q, k, v = QuantizedGQAQKVColumnParallelLinear(
-                num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-                head_dim=head_dim,
-                quantized_dtype=_weight_quant_dtype(cfg.weight_quant),
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                tp_size=cfg.tp_size, name="qkv")(x)
-        else:
-            q, k, v = pl.GQAQKVColumnParallelLinear(
-                num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-                head_dim=head_dim, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                sequence_parallel=cfg.sequence_parallel,
-                tp_size=cfg.tp_size,
-                overlap_comm=cfg.overlap_comm, name="qkv",
-                **_act_kw(cfg), **_lora_kw(cfg, "qkv"))(x)
-        b, s = q.shape[0], q.shape[1]
-        n_q_local = q.shape[-1] // head_dim
-        n_kv_local = k.shape[-1] // head_dim
-        q = q.reshape(b, s, n_q_local, head_dim)
-        k = k.reshape(b, s, n_kv_local, head_dim)
-        v = v.reshape(b, s, n_kv_local, head_dim)
-        if cfg.qk_norm:
-            q = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="q_norm")(q)
-            k = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="k_norm")(k)
-        if cfg.use_rope:
-            q = attn_mod.apply_rotary(q, cos, sin, positions)
-            k = attn_mod.apply_rotary(k, cos, sin, positions)
+                q, k, v = QuantizedGQAQKVColumnParallelLinear(
+                    num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                    head_dim=head_dim,
+                    quantized_dtype=_weight_quant_dtype(cfg.weight_quant),
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    tp_size=cfg.tp_size, name="qkv")(x)
+            else:
+                q, k, v = pl.GQAQKVColumnParallelLinear(
+                    num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                    head_dim=head_dim, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype,
+                    sequence_parallel=cfg.sequence_parallel,
+                    tp_size=cfg.tp_size,
+                    overlap_comm=cfg.overlap_comm, name="qkv",
+                    **_act_kw(cfg), **_lora_kw(cfg, "qkv"))(x)
+            b, s = q.shape[0], q.shape[1]
+            n_q_local = q.shape[-1] // head_dim
+            n_kv_local = k.shape[-1] // head_dim
+            q = q.reshape(b, s, n_q_local, head_dim)
+            k = k.reshape(b, s, n_kv_local, head_dim)
+            v = v.reshape(b, s, n_kv_local, head_dim)
+            if cfg.qk_norm:
+                q = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="q_norm")(q)
+                k = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="k_norm")(k)
+            if cfg.use_rope:
+                q = attn_mod.apply_rotary(q, cos, sin, positions)
+                k = attn_mod.apply_rotary(k, cos, sin, positions)
         new_cache = None
-        if cfg.attention_kind in ("sparse", "lightning"):
-            attend = (_sparse_attend if cfg.attention_kind == "sparse"
-                      else _lightning_attend)
-            out, new_cache = attend(cfg, q, k, v, cache)
-        elif cfg.attention_kind == "eva":
-            # learned per-head pooling vectors (adaptive_phi,
-            # adaptive_mu_k); the attention itself is ops/eva_attention.py
-            # and, over the pool, the paged kernel with both masks
-            pooling = [self.param(
-                nm, nn.with_partitioning(nn.initializers.normal(0.02),
-                                         (ps.TP_AXIS, None)),
-                (n_kv_local, head_dim), cfg.param_dtype)
-                for nm in ("eva_phi", "eva_mu")]
-            out, new_cache = _eva_attend(cfg, q, k, v, positions, cache,
-                                         *pooling)
-        elif cache is not None and _is_cp_prefill_view(cache):
-            # CP ring prefill (inference/engine.py cp>1): write this
-            # rank's rows into the local pool shard, ring-attend the
-            # whole prompt across the cp axis
-            out, new_cache = _cp_prefill_attend(cfg, q, k, v, positions,
-                                                cache)
-        elif cache is not None and _is_paged_cache_view(cache):
-            # paged pool (inference/paging.py): write this step's rows at
-            # the precomputed flat indices, gather-attend via block tables
-            out, new_cache = _paged_cache_attend(cfg, q, k, v, positions,
-                                                 cache)
-        elif cache is not None:
-            # cache = (k_cache, v_cache, slot_positions); slot_positions
-            # [B, S_max] holds each slot's true token position (PAD_POSITION
-            # sentinel for pads), updated once per step by the caller.
-            k_cache, v_cache, slot_pos = cache
-            if cfg.use_flash_decoding:
-                # slot-sharded cache (flash decoding): masked write into
-                # this rank's slot shard, partial attention + LSE combine
-                # over the decode group (ops.flash_decoding)
-                from ..inference.kv_cache import sharded_slot_update
-                from ..ops.flash_decoding import flash_decode_attention
+        with device_scope("attn.kernel"):
+            if cfg.attention_kind in ("sparse", "lightning"):
+                attend = (_sparse_attend if cfg.attention_kind == "sparse"
+                          else _lightning_attend)
+                out, new_cache = attend(cfg, q, k, v, cache)
+            elif cfg.attention_kind == "eva":
+                # learned per-head pooling vectors (adaptive_phi,
+                # adaptive_mu_k); the attention itself is ops/eva_attention.py
+                # and, over the pool, the paged kernel with both masks
+                pooling = [self.param(
+                    nm, nn.with_partitioning(nn.initializers.normal(0.02),
+                                             (ps.TP_AXIS, None)),
+                    (n_kv_local, head_dim), cfg.param_dtype)
+                    for nm in ("eva_phi", "eva_mu")]
+                out, new_cache = _eva_attend(cfg, q, k, v, positions, cache,
+                                             *pooling)
+            elif cache is not None and _is_cp_prefill_view(cache):
+                # CP ring prefill (inference/engine.py cp>1): write this
+                # rank's rows into the local pool shard, ring-attend the
+                # whole prompt across the cp axis
+                out, new_cache = _cp_prefill_attend(cfg, q, k, v, positions,
+                                                    cache)
+            elif cache is not None and _is_paged_cache_view(cache):
+                # paged pool (inference/paging.py): write this step's rows at
+                # the precomputed flat indices, gather-attend via block tables
+                out, new_cache = _paged_cache_attend(cfg, q, k, v, positions,
+                                                     cache)
+            elif cache is not None:
+                # cache = (k_cache, v_cache, slot_positions); slot_positions
+                # [B, S_max] holds each slot's true token position
+                # (PAD_POSITION sentinel for pads), updated once per step by
+                # the caller.
+                k_cache, v_cache, slot_pos = cache
+                if cfg.use_flash_decoding:
+                    # slot-sharded cache (flash decoding): masked write into
+                    # this rank's slot shard, partial attention + LSE combine
+                    # over the decode group (ops.flash_decoding)
+                    from ..inference.kv_cache import sharded_slot_update
+                    from ..ops.flash_decoding import flash_decode_attention
 
-                k_cache = sharded_slot_update(
-                    k_cache, k.astype(k_cache.dtype), cache_index,
-                    ps.CP_AXIS)
-                v_cache = sharded_slot_update(
-                    v_cache, v.astype(v_cache.dtype), cache_index,
-                    ps.CP_AXIS)
-                new_cache = (k_cache, v_cache)
-                out = flash_decode_attention(
-                    q, k_cache.astype(cfg.dtype), v_cache.astype(cfg.dtype),
-                    slot_pos, positions, axis=ps.CP_AXIS).astype(cfg.dtype)
-            else:
-                k_cache = jax.lax.dynamic_update_slice_in_dim(
-                    k_cache, k.astype(k_cache.dtype), cache_index, axis=1)
-                v_cache = jax.lax.dynamic_update_slice_in_dim(
-                    v_cache, v.astype(v_cache.dtype), cache_index, axis=1)
-                new_cache = (k_cache, v_cache)
-                k_full = attn_mod.repeat_kv(k_cache.astype(cfg.dtype),
-                                            n_q_local // n_kv_local)
-                v_full = attn_mod.repeat_kv(v_cache.astype(cfg.dtype),
-                                            n_q_local // n_kv_local)
-                import math as _math
-
-                scale = 1.0 / _math.sqrt(head_dim)
-                scores = jnp.einsum(
-                    "bqnd,bknd->bnqk", q.astype(jnp.float32),
-                    k_full.astype(jnp.float32)) * scale
-                # causal mask by stored positions: pads carry PAD_POSITION
-                # and are never attended, so ragged batches need no extra
-                # mask
-                mask = positions[:, :, None] >= slot_pos[:, None, :]
-                scores = jnp.where(mask[:, None], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1)
-                out = jnp.einsum("bnqk,bknd->bqnd", probs,
-                                 v_full.astype(jnp.float32)
-                                 ).astype(cfg.dtype)
-        else:
-            from ..parallel import comm
-
-            # attention dropout: active iff the config rate > 0 AND the
-            # caller supplied a "dropout" rng (training); eval calls without
-            # the rng are deterministic with no flag-threading
-            dropout_p, dropout_seed = attn_mod.attention_dropout_seed(
-                self, cfg.attention_dropout)
-            cp = comm._axis_size(ps.CP_AXIS)
-            if cp is not None and cp > 1 and cfg.cp_attn_impl == "ulysses":
-                # Ulysses moves the raw GQA kv heads through its
-                # all-to-alls and expands after the reshard; dropout masks
-                # there are per-rank-deterministic (see ulysses_attention)
-                from ..ops.ulysses import ulysses_attention
-
-                out = ulysses_attention(q, k, v, causal=True,
-                                        dropout_p=dropout_p,
-                                        dropout_seed=dropout_seed)
-            elif cp is not None and cp > 1:
-                # context parallel: KV rotates around the cp ring
-                # (reference kernels/ring_attention_kernel.py); dropout
-                # masks use GLOBAL seq coordinates, bit-identical to the
-                # cp=1 model at the same TP degree ("ring"); "ring_pallas"
-                # fuses the flash kernel into each ring step and draws
-                # per-(rank, chunk) in-kernel masks instead
-                from ..ops.ring_attention import (ring_attention,
-                                                  ring_attention_pallas)
-
-                k = attn_mod.repeat_kv(k, n_q_local // n_kv_local)
-                v = attn_mod.repeat_kv(v, n_q_local // n_kv_local)
-                if cfg.cp_attn_impl == "ring_pallas":
-                    out = ring_attention_pallas(q, k, v,
-                                                dropout_p=dropout_p,
-                                                dropout_seed=dropout_seed)
+                    with device_scope("attn.pool_write"):
+                        k_cache = sharded_slot_update(
+                            k_cache, k.astype(k_cache.dtype), cache_index,
+                            ps.CP_AXIS)
+                        v_cache = sharded_slot_update(
+                            v_cache, v.astype(v_cache.dtype), cache_index,
+                            ps.CP_AXIS)
+                    new_cache = (k_cache, v_cache)
+                    out = flash_decode_attention(
+                        q, k_cache.astype(cfg.dtype),
+                        v_cache.astype(cfg.dtype), slot_pos, positions,
+                        axis=ps.CP_AXIS).astype(cfg.dtype)
                 else:
-                    out = ring_attention(q, k, v, causal=True,
-                                         dropout_p=dropout_p,
-                                         dropout_seed=dropout_seed)
-            elif cfg.use_flash_attention:
-                from ..ops.flash_attention import flash_attention
+                    with device_scope("attn.pool_write"):
+                        k_cache = jax.lax.dynamic_update_slice_in_dim(
+                            k_cache, k.astype(k_cache.dtype), cache_index,
+                            axis=1)
+                        v_cache = jax.lax.dynamic_update_slice_in_dim(
+                            v_cache, v.astype(v_cache.dtype), cache_index,
+                            axis=1)
+                    new_cache = (k_cache, v_cache)
+                    k_full = attn_mod.repeat_kv(k_cache.astype(cfg.dtype),
+                                                n_q_local // n_kv_local)
+                    v_full = attn_mod.repeat_kv(v_cache.astype(cfg.dtype),
+                                                n_q_local // n_kv_local)
+                    import math as _math
 
-                k = attn_mod.repeat_kv(k, n_q_local // n_kv_local)
-                v = attn_mod.repeat_kv(v, n_q_local // n_kv_local)
-                out = flash_attention(q, k, v, causal=True,
-                                      force_pallas=cfg.attn_force_pallas,
-                                      dropout_p=dropout_p,
-                                      dropout_seed=dropout_seed)
+                    scale = 1.0 / _math.sqrt(head_dim)
+                    scores = jnp.einsum(
+                        "bqnd,bknd->bnqk", q.astype(jnp.float32),
+                        k_full.astype(jnp.float32)) * scale
+                    # causal mask by stored positions: pads carry PAD_POSITION
+                    # and are never attended, so ragged batches need no extra
+                    # mask
+                    mask = positions[:, :, None] >= slot_pos[:, None, :]
+                    scores = jnp.where(mask[:, None], scores, -1e30)
+                    probs = jax.nn.softmax(scores, axis=-1)
+                    out = jnp.einsum("bnqk,bknd->bqnd", probs,
+                                     v_full.astype(jnp.float32)
+                                     ).astype(cfg.dtype)
             else:
-                k = attn_mod.repeat_kv(k, n_q_local // n_kv_local)
-                v = attn_mod.repeat_kv(v, n_q_local // n_kv_local)
-                out = attn_mod.sdpa_reference(q, k, v, causal=True,
-                                              dropout_p=dropout_p,
-                                              dropout_seed=dropout_seed)
-        if cfg.attn_output_norm:
-            out = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="o_norm")(out)
-        out = out.reshape(b, s, n_q_local * head_dim)
-        if cfg.attn_output_gate:
-            gate = pl.ColumnParallelLinear(
-                features=cfg.num_heads * head_dim, use_bias=False,
-                gather_output=False, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype, name="o_gate")(x)
-            out = out.astype(cfg.dtype) * jax.nn.sigmoid(gate)
-        if cfg.weight_quant is not None and cfg.weight_quant.startswith(
-                "mx"):
-            from ..quantization.mx_layers import MXQuantizedRowParallel
+                from ..parallel import comm
 
-            out = MXQuantizedRowParallel(
-                features=cfg.num_heads * head_dim,
-                mx_format=cfg.weight_quant[2:], dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype, name="o_proj")(out)
-        elif cfg.weight_quant is not None:
-            from ..quantization.quantization_layers import \
-                QuantizedRowParallel
+                # attention dropout: active iff the config rate > 0 AND the
+                # caller supplied a "dropout" rng (training); eval calls
+                # without the rng are deterministic with no flag-threading
+                dropout_p, dropout_seed = attn_mod.attention_dropout_seed(
+                    self, cfg.attention_dropout)
+                cp = comm._axis_size(ps.CP_AXIS)
+                if cp is not None and cp > 1 and cfg.cp_attn_impl == "ulysses":
+                    # Ulysses moves the raw GQA kv heads through its
+                    # all-to-alls and expands after the reshard; dropout masks
+                    # there are per-rank-deterministic (see ulysses_attention)
+                    from ..ops.ulysses import ulysses_attention
 
-            out = QuantizedRowParallel(
-                features=cfg.num_heads * head_dim,
-                quantized_dtype=_weight_quant_dtype(cfg.weight_quant),
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                name="o_proj")(out)
-        else:
-            out = pl.RowParallelLinear(
-                features=cfg.num_heads * head_dim, use_bias=False,
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                sequence_parallel=cfg.sequence_parallel,
-                overlap_comm=cfg.overlap_comm, name="o_proj",
-                tp_sync=self.tp_sync,
-                **_act_kw(cfg), **_lora_kw(cfg, "o_proj"))(out)
+                    out = ulysses_attention(q, k, v, causal=True,
+                                            dropout_p=dropout_p,
+                                            dropout_seed=dropout_seed)
+                elif cp is not None and cp > 1:
+                    # context parallel: KV rotates around the cp ring
+                    # (reference kernels/ring_attention_kernel.py); dropout
+                    # masks use GLOBAL seq coordinates, bit-identical to the
+                    # cp=1 model at the same TP degree ("ring"); "ring_pallas"
+                    # fuses the flash kernel into each ring step and draws
+                    # per-(rank, chunk) in-kernel masks instead
+                    from ..ops.ring_attention import (ring_attention,
+                                                      ring_attention_pallas)
+
+                    k = attn_mod.repeat_kv(k, n_q_local // n_kv_local)
+                    v = attn_mod.repeat_kv(v, n_q_local // n_kv_local)
+                    if cfg.cp_attn_impl == "ring_pallas":
+                        out = ring_attention_pallas(q, k, v,
+                                                    dropout_p=dropout_p,
+                                                    dropout_seed=dropout_seed)
+                    else:
+                        out = ring_attention(q, k, v, causal=True,
+                                             dropout_p=dropout_p,
+                                             dropout_seed=dropout_seed)
+                elif cfg.use_flash_attention:
+                    from ..ops.flash_attention import flash_attention
+
+                    k = attn_mod.repeat_kv(k, n_q_local // n_kv_local)
+                    v = attn_mod.repeat_kv(v, n_q_local // n_kv_local)
+                    out = flash_attention(q, k, v, causal=True,
+                                          force_pallas=cfg.attn_force_pallas,
+                                          dropout_p=dropout_p,
+                                          dropout_seed=dropout_seed)
+                else:
+                    k = attn_mod.repeat_kv(k, n_q_local // n_kv_local)
+                    v = attn_mod.repeat_kv(v, n_q_local // n_kv_local)
+                    out = attn_mod.sdpa_reference(q, k, v, causal=True,
+                                                  dropout_p=dropout_p,
+                                                  dropout_seed=dropout_seed)
+        with device_scope("attn.proj"):
+            if cfg.attn_output_norm:
+                out = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                              name="o_norm")(out)
+            out = out.reshape(b, s, n_q_local * head_dim)
+            if cfg.attn_output_gate:
+                gate = pl.ColumnParallelLinear(
+                    features=cfg.num_heads * head_dim, use_bias=False,
+                    gather_output=False, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, name="o_gate")(x)
+                out = out.astype(cfg.dtype) * jax.nn.sigmoid(gate)
+            if cfg.weight_quant is not None and cfg.weight_quant.startswith(
+                    "mx"):
+                from ..quantization.mx_layers import MXQuantizedRowParallel
+
+                out = MXQuantizedRowParallel(
+                    features=cfg.num_heads * head_dim,
+                    mx_format=cfg.weight_quant[2:], dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, name="o_proj")(out)
+            elif cfg.weight_quant is not None:
+                from ..quantization.quantization_layers import \
+                    QuantizedRowParallel
+
+                out = QuantizedRowParallel(
+                    features=cfg.num_heads * head_dim,
+                    quantized_dtype=_weight_quant_dtype(cfg.weight_quant),
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    name="o_proj")(out)
+            else:
+                out = pl.RowParallelLinear(
+                    features=cfg.num_heads * head_dim, use_bias=False,
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    sequence_parallel=cfg.sequence_parallel,
+                    overlap_comm=cfg.overlap_comm, name="o_proj",
+                    tp_sync=self.tp_sync,
+                    **_act_kw(cfg), **_lora_kw(cfg, "o_proj"))(out)
         if cache is not None:
             return out, new_cache
         return out
@@ -740,9 +762,13 @@ class LlamaMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
+        with device_scope("ffn.dense"):
+            if self.cfg.weight_quant is not None:
+                return self._quantized_call(x)
+            return self._float_call(x)
+
+    def _float_call(self, x: jax.Array) -> jax.Array:
         cfg = self.cfg
-        if cfg.weight_quant is not None:
-            return self._quantized_call(x)
         # gate and up are two column-parallel kernels [H, I_local] (tp on
         # the last dim), stored and contracted as modules/glu.py says: one
         # fused [hidden, 2, intermediate] leaf made XLA copy a layer's
@@ -889,24 +915,32 @@ class LlamaDecoderLayer(nn.Module):
                  positions: Optional[jax.Array] = None,
                  cache=None, cache_index=None, valid=None):
         cfg = self.cfg
-        h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                    sequence_parallel=cfg.sequence_parallel,
-                    name="input_norm")(x)
-        attn_out = cfg.attention(self.tp_sync)(
-            h, cos, sin, positions, cache=cache, cache_index=cache_index)
-        new_cache = None
-        if cache is not None:
-            attn_out, new_cache = attn_out
-        if cfg.residual_scale != 1.0:
-            attn_out = attn_out * cfg.residual_scale
-        x = x + attn_out
-        h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                    sequence_parallel=cfg.sequence_parallel,
-                    name="post_norm")(x)
-        ff_out, aux = cfg.feed_forward(h, self.tp_sync, valid)
-        if cfg.residual_scale != 1.0:
-            ff_out = ff_out * cfg.residual_scale
-        return x + ff_out, aux, new_cache
+        # a block's scope takes in the residual add that takes its output:
+        # XLA fuses a matmul's epilogue into that add and names the fusion
+        # by its root (obs/device_scopes.py)
+        with device_scope("norm"):
+            h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                        sequence_parallel=cfg.sequence_parallel,
+                        name="input_norm")(x)
+        with device_scope("attn"):
+            attn_out = cfg.attention(self.tp_sync)(
+                h, cos, sin, positions, cache=cache, cache_index=cache_index)
+            new_cache = None
+            if cache is not None:
+                attn_out, new_cache = attn_out
+            if cfg.residual_scale != 1.0:
+                attn_out = attn_out * cfg.residual_scale
+            x = x + attn_out
+        with device_scope("norm"):
+            h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                        sequence_parallel=cfg.sequence_parallel,
+                        name="post_norm")(x)
+        with device_scope("ffn"):
+            ff_out, aux = cfg.feed_forward(h, self.tp_sync, valid)
+            if cfg.residual_scale != 1.0:
+                ff_out = ff_out * cfg.residual_scale
+            x = x + ff_out
+        return x, aux, new_cache
 
 
 def run_layers(cfg, stacks, x, cos, sin, carried, carry=None, view_of=None,
@@ -1096,18 +1130,21 @@ class LlamaModel(nn.Module):
     def __call__(self, input_ids: jax.Array,
                  positions: Optional[jax.Array] = None):
         cfg = self.cfg
-        x = pl.ParallelEmbedding(
-            num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="embed",
-            **_lora_kw(cfg, "embed"))(input_ids)
-        if cfg.residual_fp32:
-            x = x.astype(jnp.float32)
+        with device_scope("embed"):
+            x = pl.ParallelEmbedding(
+                num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
+                dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="embed",
+                **_lora_kw(cfg, "embed"))(input_ids)
+            if cfg.residual_fp32:
+                x = x.astype(jnp.float32)
+            if cfg.sequence_parallel:
+                x = mappings.scatter_to_sequence_parallel_region(x,
+                                                                 seq_dim=1)
         positions = context_parallel_positions(input_ids, positions)
-        if cfg.sequence_parallel:
-            x = mappings.scatter_to_sequence_parallel_region(x, seq_dim=1)
-        cos, sin = attn_mod.precompute_rope(
-            cfg.head_dim_, cfg.max_seq_len, cfg.rope_theta,
-            use_scaled=cfg.rope_scaling)
+        with device_scope("attn.proj"):
+            cos, sin = attn_mod.precompute_rope(
+                cfg.head_dim_, cfg.max_seq_len, cfg.rope_theta,
+                use_scaled=cfg.rope_scaling)
 
         if cfg.scan_layers:
             body_cls = _ScanBody
@@ -1165,8 +1202,10 @@ class LlamaModel(nn.Module):
             aux = None if auxes[0] is None else jnp.stack(auxes)
         if aux is not None:
             aux = jnp.sum(aux, axis=0)
-        x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                    sequence_parallel=cfg.sequence_parallel, name="norm")(x)
+        with device_scope("norm"):
+            x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                        sequence_parallel=cfg.sequence_parallel,
+                        name="norm")(x)
         # NOTE: when sequence_parallel, the returned hidden states are still
         # sequence-sharded; the LM head (a column-parallel linear with
         # sequence_parallel=True) performs the final gather itself, so the
@@ -1224,41 +1263,47 @@ class LlamaForCausalLM(nn.Module):
 
             table = meta.unbox(
                 model.variables["params"]["embed"]["embedding"])
-            logits = pl.embedding_attend(
-                table, x, sequence_parallel=cfg.sequence_parallel,
-                dtype=cfg.dtype)
+            with device_scope("head"):
+                logits = pl.embedding_attend(
+                    table, x, sequence_parallel=cfg.sequence_parallel,
+                    dtype=cfg.dtype)
             if labels is not None:
-                return lf.causal_lm_loss(logits, labels,
-                                         ignore_index=ignore_index)
+                with device_scope("loss"):
+                    return lf.causal_lm_loss(logits, labels,
+                                             ignore_index=ignore_index)
             return logits
         if (labels is not None and cfg.loss_chunk
                 and not _lora_kw(cfg, "lm_head")):
             # fused chunked head+CE: enter the TP region exactly where
-            # ColumnParallelLinear would, then stream chunks
-            if cfg.sequence_parallel:
-                x = mappings.gather_from_sequence_parallel_region(
-                    x, seq_dim=1, to_model_parallel=True)
+            # ColumnParallelLinear would, then stream chunks (the head's
+            # matmul is inside the loss here, and reads as it)
+            with device_scope("loss"):
+                if cfg.sequence_parallel:
+                    x = mappings.gather_from_sequence_parallel_region(
+                        x, seq_dim=1, to_model_parallel=True)
+                else:
+                    x = mappings.copy_to_tensor_parallel_region(x)
+                kernel = _LMHeadKernel(cfg, name="lm_head")()
+                return lf.fused_linear_cross_entropy(
+                    x.astype(cfg.dtype), kernel, labels,
+                    ignore_index=ignore_index, chunk=cfg.loss_chunk,
+                    dtype=cfg.dtype)
+        with device_scope("head"):
+            if cfg.weight_quant is not None:
+                logits = _quant_lm_head(cfg, False, name="lm_head")(x)
             else:
-                x = mappings.copy_to_tensor_parallel_region(x)
-            kernel = _LMHeadKernel(cfg, name="lm_head")()
-            return lf.fused_linear_cross_entropy(
-                x.astype(cfg.dtype), kernel, labels,
-                ignore_index=ignore_index, chunk=cfg.loss_chunk,
-                dtype=cfg.dtype)
-        if cfg.weight_quant is not None:
-            logits = _quant_lm_head(cfg, False, name="lm_head")(x)
-        else:
-            logits = pl.ColumnParallelLinear(
-                features=cfg.vocab_size, use_bias=False,
-                gather_output=False,
-                sequence_parallel=cfg.sequence_parallel,
-                overlap_comm=cfg.overlap_comm,
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                name="lm_head",
-                **_act_kw(cfg), **_lora_kw(cfg, "lm_head"))(x)
+                logits = pl.ColumnParallelLinear(
+                    features=cfg.vocab_size, use_bias=False,
+                    gather_output=False,
+                    sequence_parallel=cfg.sequence_parallel,
+                    overlap_comm=cfg.overlap_comm,
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    name="lm_head",
+                    **_act_kw(cfg), **_lora_kw(cfg, "lm_head"))(x)
         if labels is not None:
-            return lf.causal_lm_loss(logits, labels,
-                                     ignore_index=ignore_index)
+            with device_scope("loss"):
+                return lf.causal_lm_loss(logits, labels,
+                                         ignore_index=ignore_index)
         return logits
 
     def loss(self, input_ids: jax.Array, labels: jax.Array,
@@ -1320,15 +1365,17 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
         num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
         dtype=cfg.dtype, param_dtype=cfg.param_dtype,
         **_lora_kw(cfg, "embed"))
-    x = embed.apply({"params": p["model"]["embed"]}, input_ids)
-    if cfg.residual_fp32:
-        x = x.astype(jnp.float32)
-    cos, sin = attn_mod.precompute_rope(
-        cfg.head_dim_, cfg.max_seq_len, cfg.rope_theta,
-        use_scaled=cfg.rope_scaling)
-    # rope lookup needs in-table indices; sentinel pads clamp to the last
-    # entry (their K values are garbage but masked out)
-    rope_pos = jnp.minimum(positions, cfg.max_seq_len - 1)
+    with device_scope("embed"):
+        x = embed.apply({"params": p["model"]["embed"]}, input_ids)
+        if cfg.residual_fp32:
+            x = x.astype(jnp.float32)
+    with device_scope("attn.proj"):
+        cos, sin = attn_mod.precompute_rope(
+            cfg.head_dim_, cfg.max_seq_len, cfg.rope_theta,
+            use_scaled=cfg.rope_scaling)
+        # rope lookup needs in-table indices; sentinel pads clamp to the
+        # last entry (their K values are garbage but masked out)
+        rope_pos = jnp.minimum(positions, cfg.max_seq_len - 1)
 
     if paged:
         from ..inference import paging as _paging
@@ -1341,13 +1388,15 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
         # per-token routing: each packed token carries its slot's block
         # table row and a flat pool index for this step's K/V write (==
         # capacity for pad rows -> dropped by the mode="drop" scatters)
-        tok_tables = kv_cache.block_tables[
-            jnp.clip(slot_ids, 0, kv_cache.max_slots - 1)]
-        write_idx = _paging.flat_write_indices(
-            tok_tables, positions[0], kv_cache.block_size,
-            kv_cache.capacity, kind)
-        slot_pos = _paging.write_pool_positions(kv_cache.pos, positions[0],
-                                                write_idx)
+        with device_scope("attn.walk"):
+            tok_tables = kv_cache.block_tables[
+                jnp.clip(slot_ids, 0, kv_cache.max_slots - 1)]
+            write_idx = _paging.flat_write_indices(
+                tok_tables, positions[0], kv_cache.block_size,
+                kv_cache.capacity, kind)
+        with device_scope("attn.pool_write"):
+            slot_pos = _paging.write_pool_positions(
+                kv_cache.pos, positions[0], write_idx)
         quantized = isinstance(kv_cache, QuantizedPagedKVCache)
         if cp_prefill and quantized:
             raise ValueError(
@@ -1364,18 +1413,20 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
         else:
             # a window-summary kind also routes the summaries this step
             # writes, once for all layers
-            roll = () if kind.ring is None else (_paging.window_roll(
-                kind, kv_cache.block_tables, slot_ids, positions[0],
-                kv_cache.block_size, kv_cache.num_blocks),)
+            with device_scope("attn.summarise"):
+                roll = () if kind.ring is None else (_paging.window_roll(
+                    kind, kv_cache.block_tables, slot_ids, positions[0],
+                    kv_cache.block_size, kv_cache.num_blocks),)
             # so is the attention kernel's walk, which follows the tables
             # and the positions alone (None where the XLA path serves)
-            walk = _paged_attention.step_walk(
-                tok_tables, positions[0], kv_cache.block_size,
-                kv_cache.num_blocks, cfg.head_dim_,
-                cfg.num_heads // cfg.num_kv_heads,
-                window=None if kind.ring is None else (kind.window,
-                                                       kind.ring),
-                force_pallas=cfg.attn_force_pallas)
+            with device_scope("attn.walk"):
+                walk = _paged_attention.step_walk(
+                    tok_tables, positions[0], kv_cache.block_size,
+                    kv_cache.num_blocks, cfg.head_dim_,
+                    cfg.num_heads // cfg.num_kv_heads,
+                    window=None if kind.ring is None else (kind.window,
+                                                           kind.ring),
+                    force_pallas=cfg.attn_force_pallas)
             body = _PagedScanBody
             routing = (slot_pos, tok_tables, write_idx, walk) + rope + roll
         scanned = nn.scan(
@@ -1395,12 +1446,14 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
         if cfg.use_flash_decoding:
             from ..inference.kv_cache import sharded_slot_update
 
-            slot_pos = sharded_slot_update(kv_cache.pos, positions,
-                                           kv_cache.index, ps.CP_AXIS,
-                                           slot_dim=1)
+            with device_scope("attn.pool_write"):
+                slot_pos = sharded_slot_update(kv_cache.pos, positions,
+                                               kv_cache.index, ps.CP_AXIS,
+                                               slot_dim=1)
         else:
-            slot_pos = jax.lax.dynamic_update_slice_in_dim(
-                kv_cache.pos, positions, kv_cache.index, axis=1)
+            with device_scope("attn.pool_write"):
+                slot_pos = jax.lax.dynamic_update_slice_in_dim(
+                    kv_cache.pos, positions, kv_cache.index, axis=1)
 
         scanned = nn.scan(
             _DecodeScanBody,
@@ -1420,21 +1473,23 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
             slot_pos, cos, sin, rope_pos, kv_cache.index)
 
     norm = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype)
-    x = norm.apply({"params": p["model"]["norm"]}, x)
-    if cfg.tie_embeddings:
-        logits = pl.embedding_attend(
-            p["model"]["embed"]["embedding"], x, dtype=cfg.dtype,
-            gather_output=True)
-    elif cfg.weight_quant is not None:
-        head = _quant_lm_head(cfg, True)
-        logits = head.apply({"params": p["lm_head"]}, x)
-    else:
-        head = pl.ColumnParallelLinear(
-            features=cfg.vocab_size, use_bias=False, gather_output=True,
-            overlap_comm=cfg.overlap_comm,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            **_act_kw(cfg), **_lora_kw(cfg, "lm_head"))
-        logits = head.apply({"params": p["lm_head"]}, x)
+    with device_scope("norm"):
+        x = norm.apply({"params": p["model"]["norm"]}, x)
+    with device_scope("head"):
+        if cfg.tie_embeddings:
+            logits = pl.embedding_attend(
+                p["model"]["embed"]["embedding"], x, dtype=cfg.dtype,
+                gather_output=True)
+        elif cfg.weight_quant is not None:
+            head = _quant_lm_head(cfg, True)
+            logits = head.apply({"params": p["lm_head"]}, x)
+        else:
+            head = pl.ColumnParallelLinear(
+                features=cfg.vocab_size, use_bias=False, gather_output=True,
+                overlap_comm=cfg.overlap_comm,
+                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                **_act_kw(cfg), **_lora_kw(cfg, "lm_head"))
+            logits = head.apply({"params": p["lm_head"]}, x)
     if paged:
         new_k, new_v, nks, nvs = new_kv
         scales = dict(k_scale=nks, v_scale=nvs) if quantized else {}

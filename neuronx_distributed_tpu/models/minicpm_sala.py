@@ -35,6 +35,7 @@ from flax.core import meta
 
 from ..modules.attention import rope_rows
 from ..modules.norms import RMSNorm
+from ..obs.device_scopes import device_scope
 from ..ops.sparse_attention import SparseSpec
 from ..parallel import layers as pl
 from ..parallel import loss_functions as lf
@@ -166,12 +167,14 @@ class MiniCPMSALAModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids: jax.Array) -> jax.Array:
         cfg = self.cfg
-        x = pl.ParallelEmbedding(
-            num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            name="embed")(input_ids) * cfg.scale_emb
-        cos, sin = rope_rows(jnp.arange(input_ids.shape[1]), cfg.head_dim_,
-                             cfg.rope_theta)
+        with device_scope("embed"):
+            x = pl.ParallelEmbedding(
+                num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
+                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                name="embed")(input_ids) * cfg.scale_emb
+        with device_scope("attn.proj"):
+            cos, sin = rope_rows(jnp.arange(input_ids.shape[1]),
+                                 cfg.head_dim_, cfg.rope_theta)
         if self.is_initializing():
             # the parameters: one stack a kind, each made by scanning the
             # kind's layer over its depth (the order the layers run in is
@@ -190,7 +193,8 @@ class MiniCPMSALAModel(nn.Module):
                 self.variables["params"][f"layers_{kind}"])
                 for kind in ("sparse", "lightning")}
             x, _ = run_layers(cfg, stacks, x, cos, sin, CARRIED)
-        return RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="norm")(x)
+        with device_scope("norm"):
+            return RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="norm")(x)
 
 
 class MiniCPMSALAForCausalLM(nn.Module):
@@ -202,13 +206,16 @@ class MiniCPMSALAForCausalLM(nn.Module):
                  ignore_index: int = -100) -> jax.Array:
         cfg = self.cfg
         x = MiniCPMSALAModel(cfg, name="model")(input_ids)
-        x = x / (cfg.hidden_size / cfg.dim_model_base)
-        logits = pl.ColumnParallelLinear(
-            features=cfg.vocab_size, use_bias=False, gather_output=False,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="lm_head")(x)
+        with device_scope("head"):
+            x = x / (cfg.hidden_size / cfg.dim_model_base)
+            logits = pl.ColumnParallelLinear(
+                features=cfg.vocab_size, use_bias=False, gather_output=False,
+                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                name="lm_head")(x)
         if labels is not None:
-            return lf.causal_lm_loss(logits, labels,
-                                     ignore_index=ignore_index)
+            with device_scope("loss"):
+                return lf.causal_lm_loss(logits, labels,
+                                         ignore_index=ignore_index)
         return logits
 
 
@@ -233,18 +240,23 @@ def minicpm_sala_forward_with_cache(cfg: MiniCPMSALAConfig, params,
     p = params["params"]
     q_pos = jnp.asarray(positions, jnp.int32)[0]
     slot_ids = jnp.asarray(slot_ids, jnp.int32)
-    x = pl.ParallelEmbedding(
-        num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
-        dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
-        {"params": p["model"]["embed"]}, input_ids) * cfg.scale_emb
-    cos, sin = rope_rows(jnp.minimum(q_pos, cfg.max_seq_len - 1),
-                         cfg.head_dim_, cfg.rope_theta)
+    with device_scope("embed"):
+        x = pl.ParallelEmbedding(
+            num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
+            {"params": p["model"]["embed"]}, input_ids) * cfg.scale_emb
+    with device_scope("attn.proj"):
+        cos, sin = rope_rows(jnp.minimum(q_pos, cfg.max_seq_len - 1),
+                             cfg.head_dim_, cfg.rope_theta)
     kind = cfg.serving_family().cache_kind.geometry(kv_cache.block_size)
-    tables = kv_cache.block_tables[
-        jnp.clip(slot_ids, 0, kv_cache.max_slots - 1)]
-    write_idx = paging.flat_write_indices(
-        tables, q_pos, kv_cache.block_size, kv_cache.capacity, kind)
-    pool_pos = paging.write_pool_positions(kv_cache.pos, q_pos, write_idx)
+    with device_scope("attn.walk"):
+        tables = kv_cache.block_tables[
+            jnp.clip(slot_ids, 0, kv_cache.max_slots - 1)]
+        write_idx = paging.flat_write_indices(
+            tables, q_pos, kv_cache.block_size, kv_cache.capacity, kind)
+    with device_scope("attn.pool_write"):
+        pool_pos = paging.write_pool_positions(kv_cache.pos, q_pos,
+                                               write_idx)
 
     def view_of(kind, carry, layer):
         if kind == "sparse":
@@ -261,11 +273,13 @@ def minicpm_sala_forward_with_cache(cfg: MiniCPMSALAConfig, params,
     stacks = {kind: p["model"][f"layers_{kind}"]
               for kind in ("sparse", "lightning")}
     x, carry = run_layers(cfg, stacks, x, cos, sin, CARRIED, carry, view_of)
-    x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype).apply(
-        {"params": p["model"]["norm"]}, x)
-    x = x / (cfg.hidden_size / cfg.dim_model_base)
-    logits = pl.ColumnParallelLinear(
-        features=cfg.vocab_size, use_bias=False, gather_output=True,
-        dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
-        {"params": p["lm_head"]}, x)
+    with device_scope("norm"):
+        x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype).apply(
+            {"params": p["model"]["norm"]}, x)
+    with device_scope("head"):
+        x = x / (cfg.hidden_size / cfg.dim_model_base)
+        logits = pl.ColumnParallelLinear(
+            features=cfg.vocab_size, use_bias=False, gather_output=True,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
+            {"params": p["lm_head"]}, x)
     return logits, kv_cache.replace(pos=pool_pos, **carry)

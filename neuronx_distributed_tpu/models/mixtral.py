@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..modules.moe import MoE
+from ..obs.device_scopes import device_scope
 from ..parallel import layers as pl
 from ..parallel import loss_functions as lf
 from ..parallel import mappings
@@ -160,24 +161,27 @@ class MixtralForCausalLM(nn.Module):
                 "tie_embeddings is not supported for Mixtral (HF Mixtral "
                 "never ties); use an explicit lm_head")
         x, aux = LlamaModel(cfg, name="model")(input_ids, positions)
-        if cfg.weight_quant is not None:
-            logits = _quant_lm_head(cfg, False, name="lm_head")(x)
-        else:
-            logits = pl.ColumnParallelLinear(
-                features=cfg.vocab_size, use_bias=False,
-                gather_output=False,
-                sequence_parallel=cfg.sequence_parallel,
-                overlap_comm=cfg.overlap_comm, **_act_kw(cfg),
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                name="lm_head")(x)
+        with device_scope("head"):
+            if cfg.weight_quant is not None:
+                logits = _quant_lm_head(cfg, False, name="lm_head")(x)
+            else:
+                logits = pl.ColumnParallelLinear(
+                    features=cfg.vocab_size, use_bias=False,
+                    gather_output=False,
+                    sequence_parallel=cfg.sequence_parallel,
+                    overlap_comm=cfg.overlap_comm, **_act_kw(cfg),
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    name="lm_head")(x)
         return logits, aux
 
     def loss(self, input_ids, labels, ignore_index: int = -100):
         cfg = self.cfg
         logits, aux = self(input_ids)
-        ce = lf.causal_lm_loss(logits, labels, ignore_index=ignore_index)
-        return (ce + cfg.router_aux_coef * aux[0]
-                + cfg.router_z_coef * aux[1])
+        with device_scope("loss"):
+            ce = lf.causal_lm_loss(logits, labels,
+                                   ignore_index=ignore_index)
+            return (ce + cfg.router_aux_coef * aux[0]
+                    + cfg.router_z_coef * aux[1])
 
 
 def mixtral_forward_with_cache(cfg: MixtralConfig, params,

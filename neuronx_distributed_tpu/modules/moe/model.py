@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ...obs.device_scopes import device_scope
 from ...parallel import layers as pl
 from ...parallel import mesh as ps
 from .. import glu
@@ -96,7 +97,8 @@ class MoE(nn.Module):
             router_kw["top_k"] = self.top_k
         if self.router_type == "sigmoid":
             router_kw["scale"] = self.router_scale
-        gates, idx, aux = router_cls(**router_kw)(flat)
+        with device_scope("ffn.router"):
+            gates, idx, aux = router_cls(**router_kw)(flat)
 
         if self.expert_impl.startswith("mx_"):
             if self.dispatch_mode != "capacity":
@@ -151,19 +153,24 @@ class MoE(nn.Module):
                 ep_overlap=self.ep_overlap,
                 dtype=self.dtype, param_dtype=self.param_dtype,
                 name="experts")
-        if valid is None:
-            y, eaux = experts(flat, gates, idx)
-        else:
-            # a scope by which a device trace tells the routed experts
-            # (dispatch, the bank's products, combine) from the rest
-            with jax.named_scope("routed_experts"):
-                y, eaux = experts(flat, gates, idx, valid=valid.reshape(-1))
+        # the routed experts: dispatch, the bank's products, combine
+        with device_scope("ffn.experts"):
+            if valid is None:
+                y, eaux = experts(flat, gates, idx)
+            else:
+                # the older name of the same scope: the benchmark's
+                # moe_expert_share_pct.batch is held to it
+                # (tests/test_chip_compile.py)
+                with jax.named_scope("routed_experts"):
+                    y, eaux = experts(flat, gates, idx,
+                                      valid=valid.reshape(-1))
         aux.update(eaux)
 
         if self.shared_expert_intermediate > 0:
-            y = y + SharedExperts(
-                hidden_size=h,
-                intermediate_size=self.shared_expert_intermediate,
-                dtype=self.dtype, param_dtype=self.param_dtype,
-                name="shared")(flat)
+            with device_scope("ffn.shared"):
+                y = y + SharedExperts(
+                    hidden_size=h,
+                    intermediate_size=self.shared_expert_intermediate,
+                    dtype=self.dtype, param_dtype=self.param_dtype,
+                    name="shared")(flat)
         return y.reshape(orig_shape), aux

@@ -557,6 +557,8 @@ def _sparse_paged_pallas(q, k_pool, v_pool, layer, tables, q_pos, parts,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from ..obs.device_scopes import device_scope
+
     t, g, heads, d = q.shape
     bs = k_pool.shape[3]
     maxb = tables.shape[1]
@@ -564,8 +566,9 @@ def _sparse_paged_pallas(q, k_pool, v_pool, layer, tables, q_pos, parts,
     width = spec.walk_width(bs, maxb)
     rows = tile_height(t, heads, d, q.dtype)
     part_rows = narrow_height(q.dtype)
-    walk = sparse_tile_walk(parts, tables.astype(jnp.int32), rows,
-                            part_rows, width, per)
+    with device_scope("attn.walk"):
+        walk = sparse_tile_walk(parts, tables.astype(jnp.int32), rows,
+                                part_rows, width, per)
     tiles = walk.keys.shape[0]
     pad = tiles * rows - t
     # a tile's queries a group at a time, head by head: [tiles, G, R,
@@ -623,6 +626,8 @@ def sparse_paged_attention(q: jax.Array, k_pool: jax.Array,
     Returns ``(out [T, N, D], counts [8] int32)``
     (:data:`COUNT_KINDS`). ``force_pallas`` as
     :func:`.paged_attention.paged_attention`."""
+    from ..obs.device_scopes import device_scope
+
     t, n, d = q.shape
     _, _, kv, bs, _ = k_pool.shape
     if bs % spec.block:
@@ -630,27 +635,31 @@ def sparse_paged_attention(q: jax.Array, k_pool: jax.Array,
                          f"selection blocks of {spec.block}")
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
     qg = q.reshape(t, kv, n // kv, d)
-    sel, forced = select_blocks(
-        qg, gather_compressed_keys(ck, layer, tables, spec, bs, kv), q_pos,
-        spec, scale)
-    parts = column_parts(sel, tables, bs // spec.block)
+    with device_scope("attn.select"):
+        sel, forced = select_blocks(
+            qg, gather_compressed_keys(ck, layer, tables, spec, bs, kv),
+            q_pos, spec, scale)
+    with device_scope("attn.walk"):
+        parts = column_parts(sel, tables, bs // spec.block)
     impl = paged_attention_impl(d, bs, force_pallas)
     if impl == "xla":
         out = _sparse_paged_xla(qg, k_pool, v_pool, layer, tables, q_pos,
                                 sel, spec, scale)
         # no walk here: the tiles' fetches are counted for the counter
         # alone, as the kernel's tiles would make them
-        fetched = jnp.sum(first_namers(parts, tables,
-                                       tile_height(t, n // kv, d, q.dtype)))
+        with device_scope("attn.walk"):
+            fetched = jnp.sum(first_namers(
+                parts, tables, tile_height(t, n // kv, d, q.dtype)))
     else:
         out, fetched = _sparse_paged_pallas(
             qg, k_pool, v_pool, layer, tables, q_pos, parts, spec, scale,
             interpret=impl == "pallas-interpret")
     # of the live (row, group, column), those whose pool block a tile
     # fetches for them, alone or first, and those an earlier row's serves
-    counts = jnp.concatenate([
-        selection_counts(sel, forced, q_pos, spec, bs,
-                         spec.walk_width(bs, tables.shape[1])),
-        jnp.stack([fetched, jnp.sum(parts > 0) - fetched]).astype(
-            jnp.int32)])
+    with device_scope("attn.select"):
+        counts = jnp.concatenate([
+            selection_counts(sel, forced, q_pos, spec, bs,
+                             spec.walk_width(bs, tables.shape[1])),
+            jnp.stack([fetched, jnp.sum(parts > 0) - fetched]).astype(
+                jnp.int32)])
     return out.reshape(t, n, d).astype(q.dtype), counts

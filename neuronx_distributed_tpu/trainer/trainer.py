@@ -26,6 +26,7 @@ from flax.core import meta
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..config import NxDConfig
+from ..obs.device_scopes import device_scope
 from ..parallel import comm
 from ..parallel import comm_compressed as cc
 from ..parallel import grads as grads_mod
@@ -498,9 +499,11 @@ def make_train_step(
         else:
             loss, grads, new_err = one_grad(state.params, batch, rngs,
                                             state.comm_error)
-        grad_norm = optax.global_norm(grads)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with device_scope("optimizer"):
+            grad_norm = optax.global_norm(grads)
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            new_params = optax.apply_updates(state.params, updates)
         metrics = {
             "loss": loss,
             "grad_norm": grad_norm,

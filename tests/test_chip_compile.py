@@ -682,3 +682,223 @@ def test_train_scan_writes_gate_and_up_gradients_in_place(chip):
     # gate's and up's gradients: two matmuls that end in the write
     assert sum("dynamic-update-slice" in name for _, name, _ in leafs) == 2, (
         leafs)
+
+
+# -- device scopes: a fusion is one event and takes one scope ----------------
+# A device trace's event is a top-level instruction of the compiled step,
+# and its scope is the innermost marker of that instruction's own op_name
+# (obs/device_scopes.py). XLA gives a fusion its root's metadata, so a
+# matmul fused into another layer's epilogue would read as that layer.
+# These cases compile the benchmark's steps at the cells' widths and
+# geometry (a shallower stack where the layers are one scan body) and hold
+# every fusion with a matmul inside to the scope of its heaviest one.
+
+# cell's configuration, layers compiled (None: the cell's own)
+_SCOPED_STEPS = {"mistral-7b-serve": 2, "mixtral-8x7b": 2, "evabyte-6.5b": 2,
+                 "minicpm-sala-9b": None, "glm-4.7-flash": None,
+                 "mistral-7b": 2}
+
+
+def _matmul_fusions(hlo_text):
+    """``(name, own op_name, heaviest matmul's op_name, its largest
+    operand's elements)`` of every top-level fusion whose body holds a
+    ``dot`` or a ``convolution``, and ``(name, op_name)`` of every
+    top-level custom call (a Mosaic kernel)."""
+    import re
+
+    bodies, comp = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            comp = bodies.setdefault(head.group(1), [])
+        elif comp is not None:
+            comp.append(line)
+    fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.\-]+)", hlo_text))
+
+    def op_name(line):
+        m = re.search(r'op_name="([^"]*)"', line)
+        return m.group(1) if m else ""
+
+    def heaviest(body):
+        types, best = {}, (0, None)
+        for line in body:
+            m = re.match(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\(?[a-z0-9]+\[.*?) "
+                         r"([a-z\-]+)\((.*?)\)[,\n]", line + "\n")
+            if not m:
+                continue
+            name, result, opcode, operands = m.groups()
+            types[name] = result
+            if opcode not in ("dot", "convolution"):
+                continue
+            size = max(math.prod(map(int, dims.split(",")))
+                       for o in re.findall(r"%[\w.\-]+", operands)
+                       for dims in re.findall(r"\[([\d,]+)\]",
+                                              types.get(o, "[1]"))[:1])
+            if size > best[0]:
+                best = (size, op_name(line))
+        return best
+
+    fusions, kernels = [], []
+    for comp, body in bodies.items():
+        if comp in fused:
+            continue
+        for line in body:
+            call = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+            name = re.match(r"^\s*(?:ROOT )?(%[\w.\-]+) = ", line)
+            if call and call.group(1) in bodies:
+                size, inner = heaviest(bodies[call.group(1)])
+                if inner is not None:
+                    fusions.append((name.group(1), op_name(line), inner,
+                                    size))
+            elif name and 'custom_call_target="tpu_custom_call"' in line:
+                kernels.append((name.group(1), op_name(line)))
+    return fusions, kernels
+
+
+def _scoped_step(chip, topo, config_name, layers):
+    """The compiled text of a cell's step: the packed serving step as the
+    engine builds it (forward and sampling, the cache donated), or the
+    train step of ``make_train_step`` over the described 2x2."""
+    import os
+    import sys
+
+    from flax.core import meta
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import harness
+    from runners import models
+
+    config = harness.read_json(os.path.join(bench, "configs",
+                                            config_name + ".json"))
+    if layers is not None:
+        config = dict(config, num_hidden_layers=layers)
+    if config["runner"] == "train":
+        return _scoped_train_step(topo, config, models)
+    from neuronx_distributed_tpu.inference import paging
+    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
+                                                            sample)
+
+    s = config["serve"]
+    dtype = models.dtype_of(s["dtype"])
+    cfg, model, forward = models.build(config, dtype=dtype,
+                                       param_dtype=dtype,
+                                       **s.get("model", {}))
+    abstract = functools.partial(
+        jax.tree_util.tree_map, lambda x: chip(x.shape, x.dtype))
+    params = abstract(meta.unbox(jax.eval_shape(
+        model.init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32))))
+    cache = abstract(jax.eval_shape(lambda: paging.init_serving_cache(
+        cfg, num_blocks=s["num_blocks"], block_size=s["block_size"],
+        table_rows=s["max_slots"],
+        max_blocks_per_seq=s["max_blocks_per_seq"], dtype=dtype)))
+    tokens = s["token_budget"]
+
+    def step_fn(params, cache, tokens, positions, slot_ids, rng):
+        from neuronx_distributed_tpu.obs.device_scopes import device_scope
+
+        logits, cache = forward(cfg, params, tokens, positions, cache,
+                                slot_ids=slot_ids)
+        with device_scope("sample"):
+            return sample(logits[0], rng, SamplingConfig()), cache
+
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    return jax.jit(step_fn, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
+        chip(rng.shape, rng.dtype)).compile().as_text()
+
+
+def _scoped_train_step(topo, config, models):
+    import neuronx_distributed_tpu as nxd
+    from flax.core import meta
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from neuronx_distributed_tpu.parallel import mesh as ps
+    from neuronx_distributed_tpu.trainer import optimizer as opt_mod
+    from neuronx_distributed_tpu.trainer import trainer
+
+    s = config["train"]
+    ps.destroy_model_parallel()
+    cfg = nxd.neuronx_distributed_config(
+        tensor_parallel_size=s["tensor_parallel_size"],
+        optimizer_config=nxd.OptimizerConfig(zero_one_enabled=s["zero1"]),
+        activation_checkpoint_config=nxd.ActivationCheckpointConfig(
+            mode=s["activation_checkpoint"]),
+        sequence_parallel=s["sequence_parallel"], devices=topo.devices)
+    try:
+        seq, batch = 4096, 2
+        base, module, _ = models.build(
+            config, max_seq_len=seq,
+            dtype=models.dtype_of(s["compute_dtype"]),
+            param_dtype=models.dtype_of(s["param_dtype"]),
+            use_flash_attention=s["flash_attention"])
+        model = type(module)(nxd.configure_model(cfg, base))
+        mesh = ps.get_mesh()
+        boxed = jax.eval_shape(model.init, jax.random.key(0),
+                               jnp.zeros((batch, seq), jnp.int32))
+        specs = trainer._spec_tree(boxed)
+        shapes = jax.tree_util.tree_map(lambda x: tuple(x.shape),
+                                        meta.unbox(boxed))
+        pm = trainer.ParallelModel(module=model, config=cfg,
+                                   param_specs=specs, param_shapes=shapes)
+        is_spec = dict(is_leaf=lambda x: isinstance(x, PartitionSpec))
+        to_shard = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+            ps.named_sharding_for_spec, tree, **is_spec)
+        placed = lambda shapes, shard: jax.tree_util.tree_map(  # noqa: E731
+            lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=sh), shapes, shard)
+        params = placed(meta.unbox(boxed), to_shard(specs))
+        tx = opt_mod.make_optimizer(cfg, learning_rate=s["learning_rate"],
+                                    weight_decay=0.01)
+        opt_shape = jax.eval_shape(tx.init, params)
+        opt_shard = to_shard(opt_mod.zero1_state_specs(
+            opt_shape, specs, shapes, enabled=s["zero1"]))
+        everywhere = NamedSharding(mesh, PartitionSpec())
+        state = trainer.TrainState(
+            step=jax.ShapeDtypeStruct((), jnp.int32, sharding=everywhere),
+            params=params,
+            opt_state=jax.jit(tx.init, out_shardings=opt_shard).eval_shape(
+                params),
+            comm_error=None)
+        shardings = trainer.TrainState(
+            step=everywhere, params=to_shard(specs), opt_state=opt_shard,
+            comm_error=None)
+        ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                                   sharding=everywhere)
+        return trainer.make_train_step(pm, tx, shardings).lower(
+            state, {"input_ids": ids, "labels": ids}).compile().as_text()
+    finally:
+        ps.destroy_model_parallel()
+
+
+def scope_disagreements(hlo_text):
+    """``(matmul elements in all, [(fusion, own scope, heaviest matmul's
+    scope, elements)] where the two differ, kernels' scopes)`` of a
+    compiled step."""
+    from neuronx_distributed_tpu.obs.device_scopes import scope_of
+
+    fusions, kernels = _matmul_fusions(hlo_text)
+    differ = [(name, scope_of(own), scope_of(inner), size)
+              for name, own, inner, size in fusions
+              if scope_of(own) != scope_of(inner)]
+    return (sum(f[3] for f in fusions), differ,
+            {scope_of(path) for _, path in kernels})
+
+
+@pytest.mark.parametrize("config_name", list(_SCOPED_STEPS))
+def test_a_fusion_reads_the_layer_of_its_heaviest_matmul(
+        chip, topo, on_one_chip, monkeypatch, config_name):
+    from neuronx_distributed_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    text = _scoped_step(chip, topo, config_name, _SCOPED_STEPS[config_name])
+    total, differ, kernels = scope_disagreements(text)
+    assert total > 0 and kernels == {"attn.kernel"}
+    top = [d for d in differ
+           if d[1].split(".")[0] != d[2].split(".")[0]]
+    # a layer (attn, ffn, head..) misread for at most 2% of the matmuls'
+    # parameters; a child misread within its layer is PERF.md section 7's
+    assert sum(d[3] for d in top) <= 0.02 * total, top

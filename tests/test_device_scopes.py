@@ -1,0 +1,302 @@
+"""Device scopes (``obs/device_scopes.py``): what ``scope_of`` reads from a
+path, and that the program's step functions open the scopes where the
+taxonomy says, on every operation that carries a step's weight, without
+changing an operation.
+
+The steps are the benchmark's rehearsal configurations
+(``benchmarks/tests/configs``) through ``ServingEngine``'s own packed
+step and ``make_train_step``, lowered on the CPU and never run.
+"""
+
+import contextlib
+import functools
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from flax.core import meta
+
+from neuronx_distributed_tpu.obs import device_scopes as ds
+from neuronx_distributed_tpu.obs.device_scopes import (SCOPES, UNSCOPED,
+                                                       device_scope,
+                                                       scope_of, within)
+from neuronx_distributed_tpu.parallel import mesh as ps
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.join(os.path.dirname(HERE), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import harness  # noqa: E402  (benchmarks/)
+from runners import models  # noqa: E402
+
+SERVING = {"llama": "tiny-mistral-serve", "mixtral": "tiny-mixtral",
+           "evabyte": "tiny-evabyte", "minicpm_sala": "tiny-minicpm-sala",
+           "glm_moe_lite": "tiny-glm-moe-lite"}
+#: the children a family's step must open, and no other family's may
+OWN = {"attn.select": {"minicpm_sala"}, "attn.state": {"minicpm_sala"},
+       "attn.summarise": {"evabyte"},
+       "ffn.experts": {"mixtral", "glm_moe_lite"},
+       "ffn.router": {"mixtral", "glm_moe_lite"},
+       "ffn.shared": {"glm_moe_lite"}}
+#: the operations that carry a step's device time
+HEAVY = ("stablehlo.dot_general", "stablehlo.custom_call",
+         "stablehlo.scatter", "stablehlo.gather", "stablehlo.sort",
+         "chlo.top_k", "stablehlo.convolution")
+
+
+# -- scope_of -----------------------------------------------------------------
+
+@pytest.mark.parametrize("path,want", [
+    ("jit(step_fn)/nxd.sample/argmax", "sample"),
+    ("jit(step_fn)/while/body/closed_call/_PagedScanBody/layer/nxd.attn/"
+     "attn/nxd.attn.kernel/nxd.attn.pool_write/scatter", "attn.pool_write"),
+    ("jit(step_fn)/while/body/closed_call/LlamaDecoderLayer/nxd.ffn/moe/"
+     "nxd.ffn.experts/routed_experts/experts/dot_general", "ffn.experts"),
+    ("jit(step)/transpose(jvp(nxd.loss))/add_any", "loss"),
+    ("jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+     "rematted_computation/nxd.attn/nxd.attn.proj/dot_general", "attn.proj"),
+    ("jit(step)/jvp()/while/body/closed_call/nxd.ffn/add", "ffn"),
+    ("jit(step_fn)/while/body/closed_call/_PagedScanBody/layer/attn/qkv/"
+     "dot_general", UNSCOPED),
+    ("scatter", UNSCOPED),
+    ("", UNSCOPED),
+    (None, UNSCOPED),
+    # a module that happens to be called like a marker's stem is no marker
+    ("jit(f)/attn.kernel/dot_general", UNSCOPED),
+])
+def test_scope_of_reads_the_innermost_marker(path, want):
+    assert scope_of(path) == want
+
+
+def test_device_scope_refuses_a_name_outside_the_tuple():
+    with pytest.raises(ValueError, match="no device scope"):
+        device_scope("attention")
+    for name in SCOPES:
+        assert scope_of(f"jit(f)/{ds.PREFIX}{name}/mul") == name
+        stem = name.split(".")[0]
+        assert stem in SCOPES and within(name, [stem])
+    assert not within("attn", ["attn.kernel"])
+    assert not within("attnx", ["attn"])
+
+
+def test_a_scope_survives_jit_scan_remat_and_differentiation():
+    def layer(x, w):
+        with device_scope("attn"):
+            with device_scope("attn.proj"):
+                y = x @ w
+            return x + jnp.tanh(y)
+
+    def loss(x, ws):
+        x, _ = jax.lax.scan(
+            lambda c, w: (jax.checkpoint(layer)(c, w), None), x, ws)
+        with device_scope("loss"):
+            return jnp.sum(x * x)
+
+    text = jax.jit(jax.grad(loss, argnums=1)).lower(
+        jnp.ones((8, 8)), jnp.ones((3, 8, 8))).compile().as_text()
+    seen = {}
+    for line in text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m and " dot(" in line:
+            seen.setdefault(scope_of(m.group(1)), []).append(m.group(1))
+    assert set(seen) == {"attn.proj"}
+    assert any("rematted_computation" in p for p in seen["attn.proj"])
+    assert any("transpose(jvp" in p for p in seen["attn.proj"])
+
+
+# -- the lowered steps ---------------------------------------------------------
+
+def _paths(text):
+    """``{#locN: path}`` of a module printed with debug info: the name an
+    operation was traced under, ``#loc7 = loc("jit(f)/a/mul"(#loc3))``."""
+    return dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"\(#loc\d+\)\)$',
+                           text, re.M))
+
+
+def _operations(lowered):
+    """``[(operation, path)]`` of every operation of the lowered module,
+    as the compiled program will name it: inside a private function an
+    operation's path is relative, and continues that of the ``call`` that
+    reaches it (``.../while/body/closed_call`` + ``/nxd.attn/add``)."""
+    text = lowered.as_text(debug_info=True)
+    named = _paths(text)
+    funcs, current, open_ops, pending = {}, None, [], None
+    name = re.compile(r'"?((?:stablehlo|chlo|func)\.[a-z_]+|call)"?[ (]')
+    for line in text.splitlines():
+        line = line.strip()
+        head = re.match(r"func\.func (?:public |private )?@([\w.]+)\(", line)
+        if head:
+            current = funcs.setdefault(head.group(1), [])
+        loc = re.search(r"loc\((#loc\d+)\)$", line)
+        op = None if head else name.search(line)
+        if line.startswith("}"):
+            # a region ends: of an operation with a body (a scatter, a
+            # sort, a while), whose location follows its last, or of a
+            # function; "} do {" goes on to the operation's next
+            if not line.endswith("{"):
+                ended = open_ops.pop()
+                if ended and loc:
+                    current.append((ended, named.get(loc.group(1), ""),
+                                    None))
+        elif line.endswith("{"):
+            open_ops.append(op.group(1) if op else pending)
+            pending = None
+        elif op and loc:
+            callee = re.search(r"call @([\w.]+)\(", line)
+            current.append((op.group(1), named.get(loc.group(1), ""),
+                            callee and callee.group(1)))
+        elif op:
+            pending = op.group(1)       # its regions follow
+    assert not open_ops, open_ops
+    out = []
+
+    def walk(name, prefix):
+        for op, path, callee in funcs[name]:
+            full = f"{prefix}/{path}" if prefix and path else prefix or path
+            if callee:
+                walk(callee, full)
+            else:
+                out.append((op, full))
+
+    walk("main", "")
+    return out
+
+
+def _heavy_ops(lowered):
+    return [(op, path) for op, path in _operations(lowered) if op in HEAVY]
+
+
+def _stripped(lowered):
+    """The lowered module without its locations: the operations alone."""
+    return lowered.as_text(debug_info=False)
+
+
+def _serving_step(family):
+    from neuronx_distributed_tpu.inference.engine import ServingEngine
+    from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
+
+    config = harness.read_json(os.path.join(
+        BENCH, "tests", "configs", SERVING[family] + ".json"))
+    settings = config["serve"]
+    from runners import serve
+
+    dtype = models.dtype_of(settings["dtype"])
+    ps.destroy_model_parallel()
+    ps.initialize_model_parallel()
+    mcfg, model, _ = models.build(config, dtype=dtype, param_dtype=dtype,
+                                  **settings.get("model", {}))
+    params = meta.unbox(jax.eval_shape(
+        model.init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+    params = jax.tree_util.tree_map(
+        lambda x: jnp.zeros(x.shape, x.dtype), params)
+    ecfg = serve._engine_config(settings, dtype)
+    engine = ServingEngine(mcfg, params, ecfg)
+    width = ecfg.token_budget
+    return engine._build_step().lower(
+        engine.params, engine.cache, jnp.zeros((1, width), jnp.int32),
+        jnp.full((1, width), PAD_POSITION, jnp.int32),
+        jnp.zeros((width,), jnp.int32), jax.random.key(0))
+
+
+def _train_step():
+    import neuronx_distributed_tpu as nxd
+    from neuronx_distributed_tpu.trainer import (
+        initialize_parallel_model, initialize_parallel_optimizer,
+        make_train_step)
+
+    config = harness.read_json(os.path.join(
+        BENCH, "tests", "configs", "tiny-mistral.json"))
+    settings = config["train"]
+    ps.destroy_model_parallel()
+    cfg = nxd.neuronx_distributed_config(
+        tensor_parallel_size=settings["tensor_parallel_size"],
+        optimizer_config=nxd.OptimizerConfig(
+            zero_one_enabled=settings["zero1"]),
+        activation_checkpoint_config=nxd.ActivationCheckpointConfig(
+            mode=settings["activation_checkpoint"]),
+        sequence_parallel=settings["sequence_parallel"])
+    base, module, _ = models.build(
+        config, max_seq_len=64,
+        dtype=models.dtype_of(settings["compute_dtype"]),
+        param_dtype=models.dtype_of(settings["param_dtype"]),
+        use_flash_attention=settings["flash_attention"])
+    mcfg = nxd.configure_model(cfg, base)
+    model = type(module)(mcfg)
+    batch = {"input_ids": jnp.zeros((2, 64), jnp.int32),
+             "labels": jnp.zeros((2, 64), jnp.int32)}
+    pm, params = initialize_parallel_model(
+        cfg, model, jax.random.key(0), batch["input_ids"])
+    tx, state, shardings = initialize_parallel_optimizer(
+        pm, params, learning_rate=1e-4)
+    return make_train_step(pm, tx, shardings).lower(state, batch)
+
+
+@functools.lru_cache(maxsize=None)
+def _lowered(which):
+    try:
+        return _train_step() if which == "train" else _serving_step(which)
+    finally:
+        ps.destroy_model_parallel()
+
+
+@pytest.mark.parametrize("which", list(SERVING) + ["train"])
+def test_every_heavy_operation_of_a_step_has_a_scope(which):
+    ops = _heavy_ops(_lowered(which))
+    assert len(ops) > 10
+    assert any(op == "stablehlo.dot_general" for op, _ in ops)
+    bare = [(op, path) for op, path in ops if scope_of(path) == UNSCOPED]
+    assert bare == []
+
+
+@pytest.mark.parametrize("which", list(SERVING) + ["train"])
+def test_a_step_opens_the_children_its_family_has_and_no_others(which):
+    seen = {scope_of(path) for _, path in _heavy_ops(_lowered(which))}
+    assert seen <= set(SCOPES)
+    assert {"attn.proj", "attn.kernel", "head"} <= seen
+    for child, families in OWN.items():
+        assert (child in seen) == (which in families), (child, seen)
+    dense = which in ("llama", "evabyte", "minicpm_sala", "glm_moe_lite",
+                      "train")
+    assert ("ffn.dense" in seen) == dense
+    if which == "train":
+        assert "optimizer" not in seen      # elementwise: no heavy operation
+        assert "sample" not in seen
+    else:
+        assert {"attn.pool_write", "attn.walk"} <= seen
+
+
+@pytest.mark.parametrize("which", list(SERVING))
+def test_a_serving_step_samples_under_its_own_scope(which):
+    paths = {path for _, path in _operations(_lowered(which))}
+    assert any(scope_of(p) == "sample" for p in paths)
+    assert not any(scope_of(p) in ("loss", "optimizer") for p in paths)
+
+
+def test_the_train_step_marks_its_loss_and_its_optimizer():
+    paths = {path for _, path in _operations(_lowered("train"))}
+    scopes = {scope_of(p) for p in paths}
+    assert {"loss", "optimizer", "attn.kernel", "ffn.dense", "norm",
+            "embed", "head"} <= scopes
+    assert any("rematted_computation" in p and scope_of(p) == "ffn.dense"
+               for p in paths)
+
+
+@pytest.mark.parametrize("which", list(SERVING) + ["train"])
+def test_the_markers_change_no_operation(which, monkeypatch):
+    """Lowered with ``jax.named_scope`` a null context, the step is the
+    same text once locations are stripped: a marker is metadata."""
+    marked = _stripped(_lowered(which))
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    try:
+        bare_lowered = (_train_step() if which == "train"
+                        else _serving_step(which))
+    finally:
+        ps.destroy_model_parallel()
+    assert not any(scope_of(path) != UNSCOPED
+                   for _, path in _operations(bare_lowered))
+    assert _stripped(bare_lowered) == marked
