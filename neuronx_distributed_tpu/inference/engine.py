@@ -58,6 +58,24 @@ from .speculative import (SpeculationConfig, branch_of_nodes,
                           build_medusa_tree, medusa_accept_longest)
 
 
+#: leaves of a serving cache that the host writes before a step (and the
+#: forward hands back as it got them), and those a step leaves for the host
+#: to read after it (what its kernels' walks and its router did). Neither
+#: is donated to the packed step: the host may hold such an array, and read
+#: it, while the step after the one that made it runs.
+_HOST_WRITTEN = ("block_tables", "lengths")
+_HOST_READ = ("counts", "moe_counts")
+
+
+def _hold_out(cache):
+    """``(pool, held)``: ``cache`` with the leaves the host writes or reads
+    set to ``None``, and those leaves by name; ``pool.replace(**held)`` is
+    ``cache`` again."""
+    held = {n: getattr(cache, n) for n in _HOST_WRITTEN + _HOST_READ
+            if getattr(cache, n, None) is not None}
+    return cache.replace(**dict.fromkeys(held)), held
+
+
 @jax.jit
 def _clear_freed_positions(pos, freed_mask):
     """Reset freed blocks' stored positions to the pad sentinel.
@@ -196,6 +214,13 @@ class _RequestState:
     spec_rounds: int = 0            # speculation rounds this request ran
     spec_accepted: int = 0          # draft tokens accepted across rounds
     spec_ok: bool = True            # False: draft KV cold (imported KV)
+    # one step deep in flight: tokens sampled for this request by steps the
+    # host has enqueued and not read yet, the row of the newest such step
+    # that samples its next token, and whether it has been retired
+    in_flight: int = 0
+    take_row: int = -1
+    finished: bool = False
+    epoch: int = 0                  # restarts so far: older rows land stale
 
     @property
     def prompt_len(self) -> int:
@@ -224,6 +249,9 @@ class _RequestState:
         self.spec_accepted = 0
         # a restart re-prefills, which re-warms the draft pool too
         self.spec_ok = True
+        # the token a row in flight samples is discarded with the others
+        self.in_flight = 0
+        self.epoch += 1
 
 
 #: SessionTicket wire format magic — same shape as the AOT cache's
@@ -496,9 +524,30 @@ class EngineStats:
         return d
 
 
+@dataclasses.dataclass
+class _InFlight:
+    """A worker's step between its enqueue and the host's reading of it:
+    the rows it packed, each with its request's ``epoch`` then, and what
+    the device returns for them (``sampled`` and, where the family counts
+    on the device, the counts: device arrays until the fetch, host arrays
+    after it)."""
+    rows: List[Tuple]
+    epochs: List[int]
+    sampled: Any
+    counts: Any = None
+    moe_counts: Any = None
+
+
 class ServingEngine:
     """Request queue + slot map + token-budget scheduler over one
-    compiled fixed-shape step."""
+    compiled fixed-shape step.
+
+    The packed step runs one deep in flight: :meth:`step` schedules and
+    enqueues step n+1 while the device still runs step n, and only then
+    reads step n's tokens (a decode row whose token is still on the device
+    takes it there, from the row of step n that samples it). Disaggregated
+    workers, ``cp > 1`` and speculation read a step before they schedule
+    the next: the same :meth:`step`, at depth 0."""
 
     def __init__(self, model_cfg: LlamaConfig, params,
                  engine_cfg: EngineConfig = EngineConfig(),
@@ -716,6 +765,15 @@ class ServingEngine:
         self._draining = False
         self._freed_dirty: set = set()  # freed blocks with stale positions
         self._pending_cow: List[Tuple[int, int, int]] = []  # (src, dst, keep)
+        #: how many steps may be enqueued and not yet read (0 where the
+        #: next schedule needs the step's values: a speculation round's
+        #: verdict, the cp and disaggregated workers' own handoffs), the
+        #: packed step that is, and per worker width the sampled tokens
+        #: of its last step as the device holds them
+        self._depth = int(spec is None and cp == 1
+                          and not engine_cfg.disaggregated)
+        self._inflight: Optional[_InFlight] = None
+        self._last_sampled: Dict[int, jax.Array] = {}
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(self.allocator, engine_cfg.block_size)
             if engine_cfg.prefix_sharing else None)
@@ -907,13 +965,26 @@ class ServingEngine:
         if self._cp > 1:
             return self._build_cp_step(prefill=False)
         if self._spec is None:
-            def step_fn(params, cache, tokens, positions, slot_ids, rng):
+            def step_fn(params, pool, held, tokens, positions, slot_ids,
+                        prev, take, rng):
+                # ``pool`` is the cache without the leaves ``held`` (see
+                # :func:`_hold_out`): they are not donated, so the arrays
+                # the host keeps stay readable while a later step runs.
+                # ``take[i] >= 0``: row i is a decode row whose token the
+                # step before this one sampled in its row ``take[i]`` and
+                # the host has not read: it is taken from ``prev``, that
+                # step's output, here on the device.
+                with device_scope("embed"):
+                    tokens = jnp.where(take >= 0, prev[jnp.maximum(take, 0)],
+                                       tokens[0])[None, :]
                 logits, cache = forward(
-                    model_cfg, params, tokens, positions, cache,
-                    slot_ids=slot_ids)
+                    model_cfg, params, tokens, positions,
+                    pool.replace(**held), slot_ids=slot_ids)
                 with device_scope("sample"):
                     toks = sample(logits[0], rng, sampling)
-                return toks, cache
+                pool, held = _hold_out(cache)
+                return toks, pool, {n: held[n] for n in held
+                                    if n not in _HOST_WRITTEN}
 
             return jax.jit(step_fn,
                            donate_argnums=(1,) if on_accel else ())
@@ -1118,7 +1189,10 @@ class ServingEngine:
         return (repr(self.model_cfg), e.block_size, e.num_blocks,
                 e.max_slots, e.max_blocks_per_seq, e.quantized,
                 str(e.kv_dtype), repr(e.sampling),
-                source_fingerprint(self._forward_fn, sample),
+                # the step's own operands are part of the program: an
+                # executable cached for another signature must miss
+                source_fingerprint(self._forward_fn, sample,
+                                   ServingEngine._build_step, _hold_out),
                 params_spec) + spec_fp + cp_fp
 
     def _example_args(self, width: int):
@@ -1131,8 +1205,22 @@ class ServingEngine:
         if self._spec is not None:
             return (self.params, self._draft_params, self.cache,
                     self.dcache, tokens, positions, slot_ids, self._rng)
-        return (self.params, self.cache, tokens, positions, slot_ids,
-                self._rng)
+        if self._cp > 1:
+            return (self.params, self.cache, tokens, positions, slot_ids,
+                    self._rng)
+        return (self.params, *_hold_out(self.cache), tokens, positions,
+                slot_ids, self._prev_sampled(width),
+                jnp.full((width,), -1, jnp.int32), self._rng)
+
+    def _prev_sampled(self, width: int):
+        """The tokens the worker of ``width`` sampled in its last step, as
+        the device holds them; before its first step, zeros of that
+        output's type on the cache's sharding (one sharding key, one
+        compile)."""
+        if width not in self._last_sampled:
+            self._last_sampled[width] = jax.device_put(
+                jnp.zeros((width,), jnp.int32), self._sharding)
+        return self._last_sampled[width]
 
     def _spec_example_args(self, worker: str):
         """AOT lowering inputs for the two speculation workers (all-pad
@@ -1261,7 +1349,11 @@ class ServingEngine:
         raise RequestRejected(reason, detail, trace_id=trace_id)
 
     def has_work(self) -> bool:
-        return bool(self._queue) or any(s is not None for s in self._slots)
+        """Whether a :meth:`step` has anything left to do: a request
+        queued or in a slot, or a step in flight whose tokens (and the
+        results of the requests they finish) have not landed."""
+        return (bool(self._queue) or self._inflight is not None
+                or any(s is not None for s in self._slots))
 
     # -- router hooks -----------------------------------------------------
 
@@ -1314,6 +1406,7 @@ class ServingEngine:
     def drain(self) -> None:
         """Stop admitting new requests; in-flight work keeps stepping to
         completion (``submit`` now rejects with ``reason="draining"``)."""
+        self._settle()
         self._draining = True
 
     def evict(self, request_id: str):
@@ -1322,6 +1415,7 @@ class ServingEngine:
         caller can resubmit it elsewhere; raises ``KeyError`` if the
         request is not live here. The request leaves no entry in
         ``results`` — its fate now belongs to the resubmitter."""
+        self._settle()
         for req in self._queue:
             if req.uid == request_id:
                 self._queue.remove(req)
@@ -1359,6 +1453,7 @@ class ServingEngine:
         cached KV *survive*: landing the ticket elsewhere re-prefills
         nothing. Raises ``KeyError`` if the request is not live here."""
         self._refuse_session_export()
+        self._settle()
         now = self._now()
         for req in self._queue:
             if req.uid == request_id:
@@ -1603,7 +1698,9 @@ class ServingEngine:
     def handoff_ready(self, request_id: str) -> bool:
         """True once ``request_id`` has finished prefill *and* produced
         its first token here — the earliest point where exporting it
-        ships a complete prompt KV and an honest ``ttft_s``."""
+        ships a complete prompt KV and an honest ``ttft_s``. A first
+        token still in flight has not been produced yet: a poll, it waits
+        for no step."""
         for req in self._slots:
             if req is not None and req.uid == request_id:
                 return req.decoding and bool(req.generated)
@@ -1615,6 +1712,7 @@ class ServingEngine:
         their pool blocks — warm-start material for a fresh replica, so
         scale-up doesn't start with a cold trie. ``None`` when there is
         nothing to ship."""
+        self._settle()
         if self.prefix_cache is None or self.prefix_cache.size == 0:
             return None
         nodes = self.prefix_cache.snapshot(max_blocks)
@@ -1660,7 +1758,8 @@ class ServingEngine:
         clock, waits out gaps before future ``arrival_time``s; an injected
         clock should drive :meth:`step` directly instead."""
         while self.has_work():
-            if not any(s is not None for s in self._slots):
+            if (self._inflight is None
+                    and not any(s is not None for s in self._slots)):
                 pending = [r.arrival_time for r in self._queue]
                 gap = min(pending) - self._now() if pending else 0.0
                 if gap > 0:
@@ -1801,6 +1900,7 @@ class ServingEngine:
         self._slots[slot] = None
         for key in [k for k in self._pending_roll if k[0] == slot]:
             del self._pending_roll[key]
+        req.slot = None
 
     def _preempt_youngest(self, keep: _RequestState) -> None:
         """Evict the most recently admitted running request — possibly
@@ -1821,9 +1921,11 @@ class ServingEngine:
 
     def _build_schedule(self, skip=frozenset()):
         """Pack this step's rows: (req, token, position, produce) — one
-        decode row per decoding slot, then prefill chunks. Preempts
-        (youngest first) when a decode row can't get its next block;
-        prefill chunks merely truncate. Returns ``(decode_rows,
+        decode row per decoding slot, then prefill chunks. A decode row
+        whose token the step in flight samples carries ``None`` for it:
+        the device takes it from that step's row ``req.take_row``.
+        Preempts (youngest first) when a decode row can't get its next
+        block; prefill chunks merely truncate. Returns ``(decode_rows,
         prefill_rows)``: packed mode shares one ``token_budget`` across
         both lists; disaggregated mode gives each worker its own width
         (decode = ``max_slots``, prefill = ``prefill_budget``). ``skip``
@@ -1854,7 +1956,9 @@ class ServingEngine:
                         break
                     pos = req.n_cached
                     self._ensure_block(req, pos)
-                    decode_rows.append((req, req.tokens[pos], pos, True))
+                    tok = (None if req.in_flight
+                           else req.generated[pos - req.prompt_len])
+                    decode_rows.append((req, tok, pos, True))
                 break
             except CacheExhaustedError:
                 self._preempt_youngest(req)
@@ -1950,19 +2054,23 @@ class ServingEngine:
                 self.dcache = cow_copy_blocks(self.dcache, src, dst, keep)
         self._pending_cow.clear()
 
-    def _run_worker(self, fn, rows, width: int, rng, span: str):
-        """Pack ``rows`` into a fixed ``width`` batch and run one jitted
-        worker; returns per-row sampled tokens (aligned with ``rows``).
-        ``span`` is the caller's open span: its three children split the
-        host's packing, the uploads and the enqueue, and the wait for the
-        device."""
+    def _dispatch(self, fn, rows, width: int, rng, span: str) -> _InFlight:
+        """Pack ``rows`` into a fixed ``width`` batch and enqueue one
+        jitted worker; returns the step in flight (its sampled tokens
+        aligned with ``rows``, still on the device). ``span`` is the
+        caller's open span: two of its children split the host's packing
+        from the uploads and the enqueue, the third is :meth:`_fetch`'s."""
         tracer = get_tracer()
         with tracer.span(span + "/pack"):
             tokens = np.zeros((1, width), np.int32)
             positions = np.full((1, width), PAD_POSITION, np.int32)
             slot_ids = np.full((width,), self.ecfg.max_slots, np.int32)
+            take = np.full((width,), -1, np.int32)
             for i, (req, tok, pos, _) in enumerate(rows):
-                tokens[0, i] = tok
+                if tok is None:
+                    take[i] = req.take_row
+                else:
+                    tokens[0, i] = tok
                 positions[0, i] = pos
                 slot_ids[i] = req.slot
             counted = get_registry().enabled
@@ -1995,24 +2103,43 @@ class ServingEngine:
                     self.params, self._draft_params, self.cache,
                     self.dcache, jnp.asarray(tokens),
                     jnp.asarray(positions), jnp.asarray(slot_ids), rng)
-            else:
+            elif self._cp > 1:
                 sampled, self.cache = fn(
                     self.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(positions), jnp.asarray(slot_ids), rng)
+            else:
+                pool, held = _hold_out(self.cache)
+                sampled, pool, counts = fn(
+                    self.params, pool, held, jnp.asarray(tokens),
+                    jnp.asarray(positions), jnp.asarray(slot_ids),
+                    self._prev_sampled(width), jnp.asarray(take), rng)
+                # the tables and lengths are the arrays the host uploaded:
+                # they are no result of the step, and reading them waits
+                # for none
+                self.cache = pool.replace(**{**held, **counts})
+                self._last_sampled[width] = sampled
+            flight = _InFlight(rows, [r[0].epoch for r in rows], sampled)
+            # on their way to the host before the next step is enqueued
+            # behind them
+            sampled.copy_to_host_async()
             if by_device:
-                # on its way while the host waits for the tokens
-                self.cache.counts.copy_to_host_async()
-            moe_counted = counted and self._moe_on_device
-            if moe_counted:
-                self.cache.moe_counts.copy_to_host_async()
-        with tracer.span(span + "/fetch"):
-            # the host blocks here until the device has finished the step
-            sampled = np.asarray(sampled)
-            if by_device:
-                self._paged_cols += np.asarray(self.cache.counts)
-            if moe_counted:
-                self._moe_assignments += np.asarray(self.cache.moe_counts)
-            return sampled
+                flight.counts = self.cache.counts
+                flight.counts.copy_to_host_async()
+            if counted and self._moe_on_device:
+                flight.moe_counts = self.cache.moe_counts
+                flight.moe_counts.copy_to_host_async()
+        return flight
+
+    def _fetch(self, flight: _InFlight, span: str) -> None:
+        """Read what the device returned for ``flight``: the one place the
+        host blocks until the device has finished a step (with a step in
+        flight, the one before the step it has just enqueued)."""
+        with get_tracer().span(span + "/fetch"):
+            flight.sampled = np.asarray(flight.sampled)
+            if flight.counts is not None:
+                self._paged_cols += np.asarray(flight.counts)
+            if flight.moe_counts is not None:
+                self._moe_assignments += np.asarray(flight.moe_counts)
 
     def _maybe_insert_prefix(self, req: _RequestState) -> None:
         """Publish this request's fully-written prompt blocks into the
@@ -2165,11 +2292,16 @@ class ServingEngine:
         self._tables[e.max_slots:, :] = -1
 
     def step(self) -> int:
-        """One serving step. Returns the number of live rows packed
-        (0 = nothing was runnable). Packed mode runs one fixed-shape
-        worker; disaggregated mode runs the prefill worker then the
-        decode worker — the KV handoff between them is the shared block
-        pool itself (table-row surgery, no tensor copies)."""
+        """One serving step. Returns the number of live rows packed by
+        this call, or landed by it when it had nothing to pack and a step
+        was in flight (0 = nothing was runnable and nothing in flight).
+        Packed mode enqueues one fixed-shape worker and then reads the
+        step enqueued by the call before (:meth:`_fetch`: the device goes
+        from one step to the next with the next already queued; what the
+        host does in between is under the device's work, not beside it);
+        disaggregated mode runs the prefill worker then the decode worker
+        and reads each at once — the KV handoff between them is the shared
+        block pool itself (table-row surgery, no tensor copies)."""
         tracer = get_tracer()
         with tracer.span("engine/admission"):
             self._admit()
@@ -2180,7 +2312,7 @@ class ServingEngine:
         rows = decode_rows + prefill_rows
         spec_live = [x for x in round_state if x is not None]
         if not rows and not spec_live:
-            return 0
+            return self._settle()
         t_start = self._now()
         if self.stats.first_step_t is None:
             self.stats.first_step_t = t_start
@@ -2209,7 +2341,11 @@ class ServingEngine:
             for i, s in enumerate(self._slots):
                 if s is not None:
                     lengths[i] = s.n_cached
-            tbl = jax.device_put(jnp.asarray(self._tables), self._sharding)
+            # a copy: the step that reads it may still be running when the
+            # next schedule writes the host's tables, and an upload may
+            # alias or read its source after it returns
+            tbl = jax.device_put(jnp.asarray(self._tables.copy()),
+                                 self._sharding)
             lens = jax.device_put(jnp.asarray(lengths), self._sharding)
             self.cache = self.cache.replace(block_tables=tbl, lengths=lens)
             if self.dcache is not None:
@@ -2217,6 +2353,10 @@ class ServingEngine:
                                                   lengths=lens)
             self._rng, sub = jax.random.split(self._rng)
         pad_rows = 0
+        # what this call reads: at depth 0 the steps it enqueues, at depth
+        # 1 the step the call before it enqueued
+        landing: List[_InFlight] = []
+        overlapped = self._inflight is not None
         if self.ecfg.disaggregated or self._cp > 1:
             cp = self._cp > 1
             p_width = (self._cp_width if cp
@@ -2224,28 +2364,31 @@ class ServingEngine:
                        or self.ecfg.token_budget)
             d_fn = self._step_fn if cp else self._decode_fn
             d_width = self.ecfg.token_budget if cp else self.ecfg.max_slots
-            sampled = np.zeros((len(rows),), np.int32)
-            if prefill_rows:          # prefill first: TTFT, and new KV
-                name = "engine/cp_prefill" if cp else "engine/prefill"
-                with tracer.span(name):
-                    sampled[len(decode_rows):] = self._run_worker(
-                        self._prefill_fn, prefill_rows, p_width,
-                        sub, name)[:len(prefill_rows)]
-                pad_rows += p_width - len(prefill_rows)
-            if decode_rows:           # ... lands before decode reads
-                with tracer.span("engine/decode"):
-                    sampled[:len(decode_rows)] = self._run_worker(
-                        d_fn, decode_rows, d_width,
-                        sub, "engine/decode")[:len(decode_rows)]
-                pad_rows += d_width - len(decode_rows)
+            p_name = "engine/cp_prefill" if cp else "engine/prefill"
+            # prefill first: TTFT, and new KV lands before decode reads
+            for fn, worker_rows, width, name in (
+                    (self._prefill_fn, prefill_rows, p_width, p_name),
+                    (d_fn, decode_rows, d_width, "engine/decode")):
+                if worker_rows:
+                    with tracer.span(name):
+                        flight = self._dispatch(fn, worker_rows, width,
+                                                sub, name)
+                        self._fetch(flight, name)
+                    landing.insert(0, flight)   # lands decode rows first
+                    pad_rows += width - len(worker_rows)
         else:
-            sampled = np.zeros((0,), np.int32)
-            if rows:
-                with tracer.span("engine/packed"):
-                    sampled = self._run_worker(
+            with tracer.span("engine/packed"):
+                flight = None
+                if rows:
+                    flight = self._dispatch(
                         self._step_fn, rows, self.ecfg.token_budget, sub,
                         "engine/packed")
-                pad_rows = self.ecfg.token_budget - len(rows)
+                    pad_rows = self.ecfg.token_budget - len(rows)
+                if self._depth:
+                    flight, self._inflight = self._inflight, flight
+                if flight is not None:
+                    self._fetch(flight, "engine/packed")
+                    landing.append(flight)
         emit = alen = bstar = None
         if spec_live:
             # one speculation round: draft proposes k tokens per branch
@@ -2275,6 +2418,8 @@ class ServingEngine:
             emit, alen, bstar = (np.asarray(emit_d), np.asarray(alen_d),
                                  np.asarray(bstar_d))
         if self.prefix_cache is not None and prefill_rows:
+            # the rows are enqueued: whoever maps these blocks reads them
+            # in a later step, which the device runs after this one
             for req in {id(r[0]): r[0] for r in prefill_rows}.values():
                 self._maybe_insert_prefix(req)
 
@@ -2293,21 +2438,9 @@ class ServingEngine:
                 + [(x[0].uid, "decode_step", step_us)
                    for x in spec_live])
         with tracer.span("engine/retirement"):
-            for i, (req, _, pos, produce) in enumerate(rows):
-                if req.decoding and pos == req.n_cached:
-                    req.n_cached += 1  # this decode row cached its token
-                if not produce:
-                    continue
-                tok = int(sampled[i])
-                req.generated.append(tok)
-                self.stats.tokens_generated += 1
-                if req.first_token_time is None:
-                    req.first_token_time = now
-                    self.stats.ttft_s.append(now - req.arrival_time)
-                if (len(req.generated) >= req.max_new_tokens
-                        or (self.ecfg.eos_id is not None
-                            and tok == self.ecfg.eos_id)):
-                    self._retire(req, now)
+            self._note_enqueued(rows)
+            for flight in landing:
+                self._land(flight, now)
             if spec_live:
                 self._land_spec_round(round_state, emit, alen, bstar,
                                       now)
@@ -2322,8 +2455,73 @@ class ServingEngine:
                 / max(1, self.allocator.num_allocated))
             self.stats.queue_depth = self.queue_depth()
             self._publish_obs(now - t_start, len(decode_rows),
-                              len(prefill_rows), pad_rows)
+                              len(prefill_rows), pad_rows,
+                              "overlapped" if overlapped else "serial")
         return len(rows) + len(spec_live)
+
+    def _note_enqueued(self, rows) -> None:
+        """What the host knows of a step once it is enqueued, before it
+        has run: a decode row cached its token; the request of a row that
+        samples has one more token in flight, in that row; and one whose
+        ``max_new_tokens`` that token reaches leaves its slot now (its
+        blocks can be handed on: the device runs steps in order, so the
+        next step's writes come after this one's reads). Its result is
+        published when the token lands."""
+        for i, (req, _, pos, produce) in enumerate(rows):
+            if req.decoding and pos == req.n_cached:
+                req.n_cached += 1
+            if not produce:
+                continue
+            req.in_flight += 1
+            req.take_row = i
+            if len(req.generated) + req.in_flight >= req.max_new_tokens:
+                self._release(req)
+
+    def _land(self, flight: _InFlight, now: float) -> None:
+        """Append the tokens of a step the host has read to their
+        requests, and retire those they finish. A request that sampled
+        ``eos_id`` a step ago was given one more row before the host knew:
+        that row's token is dropped. A request preempted since the row was
+        enqueued restarts from its prompt: the token is counted as
+        generated, as the others it loses were, and dropped."""
+        eos = self.ecfg.eos_id
+        for i, ((req, _, _, produce), epoch) in enumerate(
+                zip(flight.rows, flight.epochs)):
+            if not produce or req.finished:
+                continue
+            self.stats.tokens_generated += 1
+            if epoch != req.epoch:
+                continue
+            tok = int(flight.sampled[i])
+            req.in_flight -= 1
+            req.generated.append(tok)
+            if req.first_token_time is None:
+                req.first_token_time = now
+                self.stats.ttft_s.append(now - req.arrival_time)
+            if (len(req.generated) >= req.max_new_tokens
+                    or (eos is not None and tok == eos)):
+                self._retire(req, now)
+
+    def _settle(self) -> int:
+        """Read and land the step in flight, if there is one; returns its
+        rows. :meth:`step` ends here when it has nothing to enqueue, and
+        whatever reads or moves request state between two steps
+        (:meth:`evict`, :meth:`export_session`, :meth:`export_prefixes`,
+        :meth:`drain`) starts here."""
+        flight, self._inflight = self._inflight, None
+        if flight is None:
+            return 0
+        tracer = get_tracer()
+        with tracer.span("engine/packed"):
+            self._fetch(flight, "engine/packed")
+        now = self._now()
+        with tracer.span("engine/retirement"):
+            self._land(flight, now)
+        with tracer.span("engine/publish"):
+            self.stats.last_step_t = now
+            self.stats.queue_depth = self.queue_depth()
+            self._publish_obs(None, 0, 0, 0, None)
+        return len(flight.rows)
 
     #: EngineStats scalar fields bridged into ``nxd_engine_stats`` each
     #: step. Derived percentiles (ttft_p50 etc.) stay in
@@ -2337,9 +2535,13 @@ class ServingEngine:
         "migrated_out", "migrated_tokens", "spec_rounds",
         "spec_accepted_tokens")
 
-    def _publish_obs(self, step_latency_s: float, decode_rows: int,
-                     prefill_rows: int, pad_rows: int) -> None:
+    def _publish_obs(self, step_latency_s: Optional[float],
+                     decode_rows: int, prefill_rows: int, pad_rows: int,
+                     kind: Optional[str]) -> None:
         """Bridge :class:`EngineStats` into registry gauges, count the
+        step by ``kind`` (``overlapped``: enqueued while the step before
+        it had not been read; ``serial``; ``None`` with no latency: the
+        call enqueued nothing and only landed a step), count the
         step's rows by kind where they were packed (over the steps that
         ran a worker the three kinds sum to steps x worker width) and
         their table columns by whether the paged kernel computes or skips
@@ -2374,6 +2576,15 @@ class ServingEngine:
 
             for v in self.stats.step_latency_s[-HISTOGRAM_RESERVOIR:-1]:
                 step_h.observe(v)
+            steps_c = reg.counter(
+                "nxd_engine_steps_total",
+                "Steps the engine enqueued by whether the step before was "
+                "still unread then (overlapped: the device had its next "
+                "program queued before the host fetched the last one's "
+                "tokens) or not (serial: the first step, a step after the "
+                "engine ran dry, and every step of a disaggregated, cp or "
+                "speculating engine).",
+                labels=("kind",))
             rows_c = reg.counter(
                 "nxd_engine_rows_total",
                 "Rows of the serving workers' fixed-width batches by what "
@@ -2473,14 +2684,18 @@ class ServingEngine:
                 step_h,
                 tuple(rows_c.labels(kind=k)
                       for k in ("decode", "prefill", "pad")),
-                cols_by_kind, events_c, visits_by_kind, moe_by_kind)
+                cols_by_kind, events_c, visits_by_kind, moe_by_kind,
+                {k: steps_c.labels(kind=k)
+                 for k in ("overlapped", "serial")})
         (_, _, fields, free_g, step_h, rows_by_kind, cols_by_kind,
-         events_c, visits_by_kind, moe_by_kind) = cache
+         events_c, visits_by_kind, moe_by_kind, steps_by_kind) = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
         free_g.set(self.pool_free_blocks())
-        step_h.observe(step_latency_s)
+        if kind is not None:
+            step_h.observe(step_latency_s)
+            steps_by_kind[kind].inc(1)
         for child, n in zip(rows_by_kind,
                             (decode_rows, prefill_rows, pad_rows)):
             child.inc(n)
@@ -2498,7 +2713,9 @@ class ServingEngine:
         self._kind_events = 0
 
     def _retire(self, req: _RequestState, now: float) -> None:
-        self._release(req)
+        if req.slot is not None:    # else it left its slot at the enqueue
+            self._release(req)
+        req.finished = True
         self.stats.completed += 1
         ttft = (req.first_token_time - req.arrival_time
                 if req.first_token_time is not None else None)

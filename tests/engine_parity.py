@@ -87,7 +87,10 @@ def record(family: str) -> dict:
                    uid=f"r{i}")
     allocated, tables = [], []
     while eng.has_work():
+        steps = eng.stats.steps
         eng.step()
+        if eng.stats.steps == steps:
+            continue    # the last call packs nothing: it lands a step
         allocated.append(int(eng.allocator.num_allocated))
         tables.append(np.asarray(eng._tables).tolist())
     out = {"max_model_len": int(eng.max_model_len()),

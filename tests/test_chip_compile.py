@@ -755,14 +755,9 @@ def _matmul_fusions(hlo_text):
     return fusions, kernels
 
 
-def _scoped_step(chip, topo, config_name, layers):
-    """The compiled text of a cell's step: the packed serving step as the
-    engine builds it (forward and sampling, the cache donated), or the
-    train step of ``make_train_step`` over the described 2x2."""
+def _cell_config(config_name, layers):
     import os
     import sys
-
-    from flax.core import meta
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     bench = os.path.join(root, "benchmarks")
@@ -775,11 +770,15 @@ def _scoped_step(chip, topo, config_name, layers):
                                             config_name + ".json"))
     if layers is not None:
         config = dict(config, num_hidden_layers=layers)
-    if config["runner"] == "train":
-        return _scoped_train_step(topo, config, models)
+    return config, models
+
+
+def _serving_parts(chip, config, models):
+    """``(cfg, forward, params, cache, width)`` of a serving cell, the
+    arrays abstract and on the described chip."""
+    from flax.core import meta
+
     from neuronx_distributed_tpu.inference import paging
-    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
-                                                            sample)
 
     s = config["serve"]
     dtype = models.dtype_of(s["dtype"])
@@ -794,7 +793,21 @@ def _scoped_step(chip, topo, config_name, layers):
         cfg, num_blocks=s["num_blocks"], block_size=s["block_size"],
         table_rows=s["max_slots"],
         max_blocks_per_seq=s["max_blocks_per_seq"], dtype=dtype)))
-    tokens = s["token_budget"]
+    return cfg, forward, params, cache, s["token_budget"]
+
+
+def _scoped_step(chip, topo, config_name, layers):
+    """The compiled text of a cell's step: the packed serving step as the
+    engine builds it (forward and sampling, the cache donated), or the
+    train step of ``make_train_step`` over the described 2x2."""
+    config, models = _cell_config(config_name, layers)
+    if config["runner"] == "train":
+        return _scoped_train_step(topo, config, models)
+    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
+                                                            sample)
+
+    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
+                                                         models)
 
     def step_fn(params, cache, tokens, positions, slot_ids, rng):
         from neuronx_distributed_tpu.obs.device_scopes import device_scope
@@ -902,3 +915,58 @@ def test_a_fusion_reads_the_layer_of_its_heaviest_matmul(
     # a layer (attn, ffn, head..) misread for at most 2% of the matmuls'
     # parameters; a child misread within its layer is PERF.md section 7's
     assert sum(d[3] for d in top) <= 0.02 * total, top
+
+
+# -- the engine's own packed step: one deep in flight -------------------------
+# The CPU tests never donate, so only a compile for the chip shows what the
+# step's operands are there: the pool donated and written in place, the
+# tables, lengths and device-side counts held out of the donation (the host
+# keeps those arrays while the next step runs), no table handed back.
+
+@pytest.mark.parametrize("config_name, layers", [
+    ("mistral-7b-serve", 2), ("evabyte-6.5b", 2),
+    ("minicpm-sala-9b", None), ("glm-4.7-flash", None)])
+def test_the_engines_step_donates_its_pool_and_not_the_hosts_leaves(
+        chip, on_one_chip, monkeypatch, config_name, layers):
+    import re
+    import types
+
+    from neuronx_distributed_tpu.inference import engine as eng
+    from neuronx_distributed_tpu.inference.sampling import SamplingConfig
+
+    monkeypatch.setattr(eng, "on_tpu", lambda: True)
+    config, models = _cell_config(config_name, layers)
+    cfg, forward, params, cache, width = _serving_parts(chip, config,
+                                                        models)
+    step = eng.ServingEngine._build_step(types.SimpleNamespace(
+        model_cfg=cfg, _forward_fn=forward, _cp=1, _spec=None,
+        ecfg=types.SimpleNamespace(sampling=SamplingConfig(greedy=True))))
+    pool, held = eng._hold_out(cache)
+    assert set(held) >= {"block_tables", "lengths"}
+    row = chip((width,), jnp.int32)
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = step.lower(
+        params, pool, held, chip((1, width), jnp.int32),
+        chip((1, width), jnp.int32), row, row, row,
+        chip(rng.shape, rng.dtype)).compile()
+    text = compiled.as_text()
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    shape_of = {int(n): shape for shape, n in re.findall(
+        r" = \w+\[([\d,]*)\]\S* parameter\((\d+)\)", entry)}
+    aliased = {shape_of[int(n)] for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+
+    def dims(x):
+        return ",".join(map(str, x.shape))
+
+    # every leaf of the pool is handed back in the buffer it came in ...
+    large = {dims(x) for x in jax.tree_util.tree_leaves(pool)}
+    assert large and large <= aliased, (large, aliased)
+    # ... and nothing the host keeps is: not the tables, not the counts
+    kept = {dims(x) for x in held.values()}
+    assert not kept & aliased
+    results = re.search(r"->\s*\((.*?)\)\}", header).group(1)
+    assert f"s32[{dims(held['block_tables'])}]" not in results
+    pool_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                     for x in jax.tree_util.tree_leaves(pool))
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4

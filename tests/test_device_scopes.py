@@ -195,11 +195,8 @@ def _serving_step(family):
         lambda x: jnp.zeros(x.shape, x.dtype), params)
     ecfg = serve._engine_config(settings, dtype)
     engine = ServingEngine(mcfg, params, ecfg)
-    width = ecfg.token_budget
     return engine._build_step().lower(
-        engine.params, engine.cache, jnp.zeros((1, width), jnp.int32),
-        jnp.full((1, width), PAD_POSITION, jnp.int32),
-        jnp.zeros((width,), jnp.int32), jax.random.key(0))
+        *engine._example_args(ecfg.token_budget))
 
 
 def _train_step():

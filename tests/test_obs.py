@@ -593,7 +593,11 @@ def test_engine_rows_counter_sums_to_steps_times_width():
     eng = _tiny_engine()
     returned = []
     while eng.has_work():
-        returned.append(eng.step())
+        steps = eng.stats.steps
+        n = eng.step()
+        # a call that packs nothing lands the step in flight: it counts
+        # no step and no row (its return value is the rows it landed)
+        returned.append(n if eng.stats.steps > steps else 0)
     assert all(r.status == "completed" for r in eng.results.values())
     rows = {c.labels["kind"]: c.value for c in obs.get_registry().get(
         "nxd_engine_rows_total").children()}
@@ -621,7 +625,7 @@ def test_engine_paged_columns_counter_sums_to_rows_times_columns():
     eng = _tiny_engine()
     width, maxb = eng.ecfg.token_budget, eng.ecfg.max_blocks_per_seq
     packed = []
-    run_worker = eng._run_worker
+    run_worker = eng._dispatch
 
     def spy(fn, rows, *args):
         pads = [(-1, PAD_POSITION)] * (width - len(rows))
@@ -639,12 +643,12 @@ def test_engine_paged_columns_counter_sums_to_rows_times_columns():
         return {c.labels["kind"]: c.value
                 for c in obs.get_registry().get(name).children()}
 
-    eng._run_worker = spy
+    eng._dispatch = spy
     before = {"live": 0, "skipped": 0}
     visits = {"fetched": 0, "shared": 0}
     while eng.has_work():
-        if not eng.step():
-            continue
+        if not eng.step() or not packed:
+            continue    # nothing ran, or the call only landed a step
         now, seen = read("nxd_paged_columns_total"), read(
             "nxd_paged_block_visits_total")
         live, fetched = packed.pop()
