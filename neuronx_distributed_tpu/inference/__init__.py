@@ -41,7 +41,8 @@ from .model_builder import (ModelBuilder, NxDModel, bundle_generate,
                             shard_checkpoint)
 from .paging import (BlockAllocator, CacheExhaustedError, LatentPagedCache,
                      PagedKVCache, PrefixCache, QuantizedPagedKVCache,
-                     SparseStatePagedCache, cow_copy_blocks,
+                     SparseStatePagedCache, StatePoolPagedCache,
+                     cow_copy_blocks,
                      init_paged_kv_cache, init_quantized_paged_kv_cache,
                      init_serving_cache)
 from .router import (FabricConfig, ReplicaRouter, RouterConfig, RouterResult,
@@ -60,7 +61,7 @@ __all__ = [
     "KVCache", "init_kv_cache",
     "BlockAllocator", "CacheExhaustedError", "PagedKVCache",
     "PrefixCache", "QuantizedPagedKVCache", "SparseStatePagedCache",
-    "LatentPagedCache",
+    "LatentPagedCache", "StatePoolPagedCache",
     "init_serving_cache", "cow_copy_blocks",
     "init_paged_kv_cache", "init_quantized_paged_kv_cache",
     "ServingEngine", "EngineConfig", "EngineStats", "RequestRejected",

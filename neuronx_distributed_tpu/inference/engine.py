@@ -751,9 +751,15 @@ class ServingEngine:
         self._moe_on_device = family.moe_counts
         self._moe_assignments = np.zeros((2,), np.int64)
         #: windows a window-summary cache rolled (``nxd_eva_windows_total``)
-        #: or rows at position 0 of a sparse-state cache, each of which
-        #: starts a slot's state anew (``nxd_state_resets_total``)
+        #: or rows at position 0 of a cache with per-slot state leaves
+        #: (``paging.StateLeaf``), each of which starts a slot's states
+        #: anew (``nxd_state_resets_total``)
         self._kind_events = 0
+        #: of such a cache, per step: the slots whose states the step
+        #: advanced (they had rows in it) and the occupied slots it held
+        #: untouched (``nxd_state_slot_steps_total``)
+        self._has_state = bool(self._cache_kind.leaves)
+        self._state_slots = np.zeros((2,), np.int64)
         #: summary blocks taken by the schedule for windows that the next
         #: step completes, ``(slot, column) -> block``: ``engine/roll``
         self._pending_roll: Dict[Tuple[int, int], int] = {}
@@ -2075,9 +2081,12 @@ class ServingEngine:
                 slot_ids[i] = req.slot
             counted = get_registry().enabled
             by_device = counted and self._counts_on_device
-            if by_device:
+            if counted and self._has_state:
                 self._kind_events += int(np.sum(positions == 0))
-            elif counted:
+                advanced = len({r[0].slot for r in rows})
+                self._state_slots += (advanced, sum(
+                    s is not None for s in self._slots) - advanced)
+            if counted and not by_device:
                 # what the paged kernel's walk finds in this batch (a pad
                 # row attends nothing, whatever table row it is handed)
                 tbl = self._tables[np.minimum(slot_ids,
@@ -2093,7 +2102,8 @@ class ServingEngine:
                 mcfg = self.model_cfg
                 fetched = int(tile_pairs(
                     np.where(kinds > 0, tbl, -1),
-                    tile_rows(mcfg.num_heads // mcfg.num_kv_heads, width),
+                    tile_rows(mcfg.num_heads // mcfg.num_kv_heads
+                              * self._cache_kind.pack, width),
                     self._pool_blocks, xp=np)[0].sum())
                 self._block_visits += (
                     fetched, np.count_nonzero(kinds) - fetched)
@@ -2591,6 +2601,7 @@ class ServingEngine:
                 "filled them: a decoding slot's token, a prefill chunk's "
                 "token, or padding.",
                 labels=("kind",))
+            events_c = None
             if self._counts_on_device:
                 cols_c = reg.counter(
                     "nxd_sparse_columns_total",
@@ -2602,10 +2613,6 @@ class ServingEngine:
                     "threshold, or nothing (skipped). Counted on the "
                     "device, fetched with the step's tokens.",
                     labels=("kind",))
-                events_c = reg.counter(
-                    "nxd_state_resets_total",
-                    "Packed rows at position 0: each starts its slot's "
-                    "lightning states from zero inside the step.")
                 pos_c = reg.counter(
                     "nxd_sparse_positions_total",
                     "Causal positions of the packed rows (x K/V groups x "
@@ -2638,8 +2645,8 @@ class ServingEngine:
                     "and not wholly behind the row's position) is "
                     "computed, skipped is not.",
                     labels=("kind",))
-                cols_by_kind, events_c = tuple(
-                    cols_c.labels(kind=k) for k in ("skipped", "live")), None
+                cols_by_kind = tuple(
+                    cols_c.labels(kind=k) for k in ("skipped", "live"))
             else:
                 cols_c = reg.counter(
                     "nxd_eva_columns_total",
@@ -2654,6 +2661,22 @@ class ServingEngine:
                     "nxd_eva_windows_total",
                     "Windows whose last position was in a packed step: "
                     "summarised into a block of the pool by that step.")
+            if self._has_state:
+                events_c = reg.counter(
+                    "nxd_state_resets_total",
+                    "Packed rows at position 0: each starts its slot's "
+                    "per-slot states (a lightning layer's, a state-space "
+                    "layer's and its convolution tail) from zero inside "
+                    "the step.")
+            state_by_kind = () if not self._has_state else tuple(
+                reg.counter(
+                    "nxd_state_slot_steps_total",
+                    "Slots of a cache with per-slot states, a step: "
+                    "advanced, the slot had rows in the step and its "
+                    "states moved on by them, or held, the slot was "
+                    "occupied and the step left its states as they were.",
+                    labels=("kind",)).labels(kind=k)
+                for k in ("advanced", "held"))
             visits_c = None if self._counts_on_device else reg.counter(
                 "nxd_paged_block_visits_total",
                 "Live (row, table column) of the serving workers' rows by "
@@ -2686,9 +2709,10 @@ class ServingEngine:
                       for k in ("decode", "prefill", "pad")),
                 cols_by_kind, events_c, visits_by_kind, moe_by_kind,
                 {k: steps_c.labels(kind=k)
-                 for k in ("overlapped", "serial")})
+                 for k in ("overlapped", "serial")}, state_by_kind)
         (_, _, fields, free_g, step_h, rows_by_kind, cols_by_kind,
-         events_c, visits_by_kind, moe_by_kind, steps_by_kind) = cache
+         events_c, visits_by_kind, moe_by_kind, steps_by_kind,
+         state_by_kind) = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
@@ -2711,6 +2735,9 @@ class ServingEngine:
         if events_c is not None:
             events_c.inc(self._kind_events)
         self._kind_events = 0
+        for child, n in zip(state_by_kind, self._state_slots):
+            child.inc(int(n))
+        self._state_slots[:] = 0
 
     def _retire(self, req: _RequestState, now: float) -> None:
         if req.slot is not None:    # else it left its slot at the enqueue

@@ -52,6 +52,12 @@ class FullCache:
     name = "full"
     #: table columns holding exact K/V rows (None: all of them)
     ring = None
+    #: the per-slot states the family keeps beside the pool
+    #: (:class:`StateLeaf`; none for a cache that is blocks alone)
+    leaves = ()
+    #: K/V heads laid side by side on one pool row (the paged kernel then
+    #: sees that many times the query heads a K/V head)
+    pack = 1
 
     def geometry(self, block_size: int, step_rows: int = 0) -> "FullCache":
         return self
@@ -109,6 +115,8 @@ class WindowSummaryCache:
     window: int
     chunk: int
     name = "window_summary"
+    leaves = ()                 # as :class:`FullCache`'s: blocks alone,
+    pack = 1                    # a K/V head a pool row
 
     def geometry(self, block_size: int, step_rows: int = 0
                  ) -> "WindowSummaryCache":
@@ -166,20 +174,47 @@ class WindowSummaryCache:
 
 
 @dataclasses.dataclass(frozen=True)
+class StateLeaf:
+    """A per-slot state that a family keeps beside its pool, which is no
+    block at all: one entry a table row, ``lead + (table rows,) + trail``
+    in ``dtype`` (None: the pool's), whatever the sequence's length. The
+    family names its leaves and their shapes in its cache kind
+    (``leaves``); the kind's cache holds them, and the family's layers
+    read and write them at ``(layer, slot)``. One contract for all of
+    them: a slot whose rows start at position 0 starts every leaf from
+    zero *inside the step*, so admission, preemption and re-prefill clear
+    nothing on the host and a re-admitted request inherits nothing
+    (``nxd_state_resets_total``); a slot without rows in a step keeps its
+    leaves as they are (``nxd_state_slot_steps_total``)."""
+
+    name: str
+    lead: Tuple[int, ...]
+    trail: Tuple[int, ...]
+    dtype: Any = None
+
+
+def init_state_leaves(leaves: Sequence[StateLeaf], table_rows: int,
+                      dtype: Any) -> Dict[str, jax.Array]:
+    """The leaves' arrays by name, zero."""
+    return {leaf.name: jnp.zeros(
+        tuple(leaf.lead) + (table_rows,) + tuple(leaf.trail),
+        leaf.dtype or dtype) for leaf in leaves}
+
+
+@dataclasses.dataclass(frozen=True)
 class SparseStateCache(FullCache):
     """A cache that differs by layer kind, three kinds of leaf behind one
     block table and one allocator: paged K/V for the ``sparse_layers``
     block-sparse layers only (position ``p`` in column ``p //
     block_size``, as a full cache), a side pool of their compressed keys
     indexed by the same block ids (a freed block frees its compressed
-    keys), and a float32 state per table row for each of the
-    ``state_layers`` lightning layers, which is no block at all: a row at
-    position 0 starts its slot's state from zero inside the step
-    (:func:`..ops.lightning_attention.lightning_attention_packed`), so a
-    preempted and re-admitted request inherits nothing."""
+    keys), and the family's per-slot state leaves (:class:`StateLeaf`):
+    one, ``state``, the lightning layers' float32 ``[Ll, H, table rows,
+    D, D]``, advanced by
+    :func:`..ops.lightning_attention.lightning_attention_packed`."""
 
     sparse_layers: int = 0
-    state_layers: int = 0
+    leaves: Tuple[StateLeaf, ...] = ()
     #: positions a compressed key advances by, and a selection block
     stride: int = 16
     select_block: int = 64
@@ -202,15 +237,13 @@ class SparseStateCache(FullCache):
                              "selection over one is another kernel")
         kv, d = model_cfg.num_kv_heads, model_cfg.head_dim_
         pool = (self.sparse_layers, num_blocks, kv, block_size, d)
-        heads = model_cfg.state_heads
         return SparseStatePagedCache(
             k=jnp.zeros(pool, dtype), v=jnp.zeros(pool, dtype),
             ck=jnp.zeros((self.sparse_layers,
                           num_blocks * (block_size // self.stride), kv * d),
                          dtype),
-            state=jnp.zeros((self.state_layers, heads, table_rows, d, d),
-                            jnp.float32),
             counts=jnp.zeros((8,), jnp.int32),
+            **init_state_leaves(self.leaves, table_rows, dtype),
             pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
             block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
                                   jnp.int32),
@@ -242,6 +275,46 @@ class LatentCache(FullCache):
                             self.row), dtype),
             moe_counts=(jnp.zeros((2,), jnp.int32)
                         if model_cfg.serving_family().moe_counts else None),
+            pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
+            block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
+                                  jnp.int32),
+            lengths=jnp.zeros((table_rows,), jnp.int32),
+            block_size=block_size)
+
+
+@dataclasses.dataclass(frozen=True)
+class StatePoolCache(FullCache):
+    """A full K/V pool over the ``pool_layers`` attention layers only, and
+    the family's named per-slot state leaves (:class:`StateLeaf`) for the
+    layers that keep no K/V: a slot costs its leaves' bytes whatever its
+    length and the pool's bytes a position, so the slots and not the
+    blocks may be what bounds the batch. ``pack`` K/V heads lie side by
+    side on one pool row (a head narrower than the 128 lanes: two heads
+    of 64), which :func:`..ops.paged_attention.paged_attention` meets
+    with each query head zero-padded into its share of the lanes. Block
+    mapping, allocation, copy-on-write and preemption are
+    :class:`FullCache`'s."""
+
+    pool_layers: int = 0
+    pack: int = 1
+    leaves: Tuple[StateLeaf, ...] = ()
+    name = "state_pool"
+
+    def init_cache(self, model_cfg, *, num_blocks: int, block_size: int,
+                   table_rows: int, max_blocks_per_seq: int, dtype: Any,
+                   quantized: bool = False) -> "StatePoolPagedCache":
+        if quantized:
+            raise ValueError("a state_pool cache has no int8 pool: its "
+                             "states are float32 beside it")
+        kv, d = model_cfg.num_kv_heads, model_cfg.head_dim_
+        if kv % self.pack:
+            raise ValueError(f"{kv} K/V heads do not lie {self.pack} a "
+                             "pool row")
+        pool = (self.pool_layers, num_blocks, block_size, kv // self.pack,
+                d * self.pack)
+        return StatePoolPagedCache(
+            k=jnp.zeros(pool, dtype), v=jnp.zeros(pool, dtype),
+            states=init_state_leaves(self.leaves, table_rows, dtype),
             pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
             block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
                                   jnp.int32),
@@ -345,6 +418,23 @@ class LatentPagedCache(_BlockPool, struct.PyTreeNode):
     lengths: jax.Array
     block_size: int = struct.field(pytree_node=False, default=128)
     POOL_LEAVES = ("rows",)
+
+
+class StatePoolPagedCache(_BlockPool, struct.PyTreeNode):
+    """The cache of :class:`StatePoolCache`. ``k``/``v`` ``[La,
+    num_blocks, block_size, KV / pack, D * pack]`` over the ``La``
+    attention layers; ``states`` the family's per-slot leaves by name
+    (:class:`StateLeaf`: ``lead + (table rows,) + trail`` each); ``pos``,
+    ``block_tables`` and ``lengths`` as :class:`PagedKVCache`."""
+
+    k: jax.Array
+    v: jax.Array
+    states: Dict[str, jax.Array]
+    pos: jax.Array
+    block_tables: jax.Array
+    lengths: jax.Array
+    block_size: int = struct.field(pytree_node=False, default=128)
+    POOL_LEAVES = ("k", "v")
 
 
 class SparseStatePagedCache(struct.PyTreeNode):
@@ -463,6 +553,19 @@ class StateLayerView(struct.PyTreeNode):
     q_pos: jax.Array
 
 
+class StateSpaceLayerView(struct.PyTreeNode):
+    """What a state-space (Mamba-2) layer is handed: its two per-slot
+    state leaves' stacks (the layer scan's carry: ``ssm [L, J, d_state,
+    d_inner]`` float32 and the convolution's tails ``conv [L, d_conv - 1,
+    J, channels]``), the layer's index in them, and the step's rows by
+    slot (:class:`..ops.ssd.StepSegments`, built once a step)."""
+
+    ssm: jax.Array
+    conv: jax.Array
+    layer: jax.Array
+    seg: Any
+
+
 class CPPrefillView(struct.PyTreeNode):
     """The LOCAL pool shard's stacks, the layer the holder is at, and
     this rank's write routing for context-parallel ring prefill: the
@@ -572,7 +675,8 @@ def init_serving_cache(model_cfg, *, num_blocks: int, block_size: int,
     :func:`init_paged_kv_cache`'s (``quantized``:
     :func:`init_quantized_paged_kv_cache`'s) pytree; a
     :class:`SparseStateCache` is a :class:`SparseStatePagedCache`, a
-    :class:`LatentCache` a :class:`LatentPagedCache`."""
+    :class:`LatentCache` a :class:`LatentPagedCache`, a
+    :class:`StatePoolCache` a :class:`StatePoolPagedCache`."""
     return model_cfg.serving_family().cache_kind.init_cache(
         model_cfg, num_blocks=num_blocks, block_size=block_size,
         table_rows=table_rows, max_blocks_per_seq=max_blocks_per_seq,
@@ -756,6 +860,9 @@ def write_pool_rows(pool: jax.Array, rows: jax.Array,
     row."""
     n_layers, nb, bs = pool.shape[:3]
     flat = pool.reshape((n_layers, nb * bs) + pool.shape[3:])
+    # a row's heads as the pool lays them (side by side on a row where
+    # the cache kind packs them: the same values in the same order)
+    rows = rows.reshape(rows.shape[:1] + pool.shape[3:])
     flat = flat.at[layer, flat_idx].set(rows.astype(pool.dtype),
                                         mode="drop")
     return flat.reshape(pool.shape)

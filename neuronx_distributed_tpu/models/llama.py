@@ -89,7 +89,7 @@ def _quant_lm_head(cfg: "LlamaConfig", gather_output: bool, name=None):
         param_dtype=cfg.param_dtype, **kw)
 
 
-ATTENTION_KINDS = ("full", "eva", "sparse", "lightning", "mla")
+ATTENTION_KINDS = ("full", "eva", "sparse", "lightning", "mla", "mamba2")
 
 
 @dataclass(frozen=True)
@@ -193,9 +193,14 @@ class LlamaConfig:
     # ``sparse``, its SparseSpec) or "lightning" (a decayed outer-product
     # state: ops/lightning_attention.py) or "mla" (a latent and a rotary
     # key shared by the heads, kv_b absorbed: models/glm_moe_lite.py's
-    # LatentAttention in LlamaAttention's place). A model whose layers
-    # differ in kind (models/minicpm_sala.py) derives one config a kind.
+    # LatentAttention in LlamaAttention's place) or "mamba2" (no attention:
+    # a Mamba-2 state-space mixer, models/granite_hybrid.py's Mamba2Mixer
+    # in LlamaAttention's place). A model whose layers differ in kind
+    # (models/minicpm_sala.py, models/granite_hybrid.py) derives one
+    # config a kind.
     attention_kind: str = "full"
+    # what softmax attention multiplies q . k by (None: 1 / sqrt(head_dim))
+    attn_scale: Optional[float] = None
     # per-head RMSNorm of q and k before the rotary embedding
     qk_norm: bool = False
     # rotary position embedding on q and k
@@ -321,6 +326,13 @@ class LlamaConfig:
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    @property
+    def attn_scale_(self) -> float:
+        import math as _math
+
+        return (1.0 / _math.sqrt(self.head_dim_) if self.attn_scale is None
+                else self.attn_scale)
+
 
 # Canonical configs (reference fixtures:
 # examples/training/llama/tp_zero1_llama_hf_pretrain/7B_config_llama2 etc.)
@@ -393,8 +405,6 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
     slots) never land in the pool and their outputs are discarded by the
     caller.
     """
-    import math as _math
-
     from ..inference import paging
     from ..inference.kv_cache import quantize_kv
     from ..ops.paged_attention import paged_attention
@@ -424,7 +434,7 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
     out = paged_attention(
         q[0], new_k, new_v, view.pos, view.tables, positions[0],
         view.layer, k_scale=new_ks, v_scale=new_vs,
-        scale=1.0 / _math.sqrt(q.shape[-1]),
+        scale=cfg.attn_scale_,
         force_pallas=cfg.attn_force_pallas,
         combine_axis=combine, walk=view.walk)[None]
     new_view = view.replace(k=new_k, v=new_v, k_scale=new_ks,
@@ -646,12 +656,9 @@ class LlamaAttention(nn.Module):
                                                 n_q_local // n_kv_local)
                     v_full = attn_mod.repeat_kv(v_cache.astype(cfg.dtype),
                                                 n_q_local // n_kv_local)
-                    import math as _math
-
-                    scale = 1.0 / _math.sqrt(head_dim)
                     scores = jnp.einsum(
                         "bqnd,bknd->bnqk", q.astype(jnp.float32),
-                        k_full.astype(jnp.float32)) * scale
+                        k_full.astype(jnp.float32)) * cfg.attn_scale_
                     # causal mask by stored positions: pads carry PAD_POSITION
                     # and are never attended, so ragged batches need no extra
                     # mask
@@ -705,6 +712,7 @@ class LlamaAttention(nn.Module):
                     k = attn_mod.repeat_kv(k, n_q_local // n_kv_local)
                     v = attn_mod.repeat_kv(v, n_q_local // n_kv_local)
                     out = flash_attention(q, k, v, causal=True,
+                                          scale=cfg.attn_scale,
                                           force_pallas=cfg.attn_force_pallas,
                                           dropout_p=dropout_p,
                                           dropout_seed=dropout_seed)
@@ -712,6 +720,7 @@ class LlamaAttention(nn.Module):
                     k = attn_mod.repeat_kv(k, n_q_local // n_kv_local)
                     v = attn_mod.repeat_kv(v, n_q_local // n_kv_local)
                     out = attn_mod.sdpa_reference(q, k, v, causal=True,
+                                                  scale=cfg.attn_scale,
                                                   dropout_p=dropout_p,
                                                   dropout_seed=dropout_seed)
         with device_scope("attn.proj"):
@@ -944,7 +953,7 @@ class LlamaDecoderLayer(nn.Module):
 
 
 def run_layers(cfg, stacks, x, cos, sin, carried, carry=None, view_of=None,
-               merge=None, valid=None):
+               merge=None, valid=None, positions=None):
     """The layer pattern of a model whose layers differ in kind: one
     ``lax.scan`` a run of like layers (``cfg.runs()``: ``(kind, first,
     count)``, ``first`` the run's first index in its kind's stack), each
@@ -958,7 +967,9 @@ def run_layers(cfg, stacks, x, cos, sin, carried, carry=None, view_of=None,
     under a carried name replaces it, or ``merge(carried, new view, aux)``
     says what does (``aux`` what the layer's feed-forward returned beside
     its output). ``valid`` (bool ``[1, T]`` or None) marks the packed
-    step's real rows for the layers' feed-forward."""
+    step's real rows for the layers' feed-forward; ``positions`` (``[1,
+    T]`` or None) are the rows' positions for a layer whose view does not
+    carry them (:class:`..inference.paging.PagedCacheView`)."""
     for kind, first, count in cfg.runs():
         layer = LlamaDecoderLayer(cfg.kind_config(kind))
         stack = stacks[kind]["layer"]
@@ -973,8 +984,8 @@ def run_layers(cfg, stacks, x, cos, sin, carried, carry=None, view_of=None,
             i = jax.lax.optimization_barrier(i)
             weights = jax.tree_util.tree_map(lambda w: w[i], stack)
             view = None if cache is None else view_of(kind, cache, i)
-            h, aux, new = layer.apply({"params": weights}, h, cos, sin, None,
-                                      cache=view, valid=valid)
+            h, aux, new = layer.apply({"params": weights}, h, cos, sin,
+                                      positions, cache=view, valid=valid)
             if cache is not None and merge is not None:
                 cache = merge(cache, new, aux)
             elif cache is not None:

@@ -80,10 +80,6 @@ class MiniCPMSALAConfig(LlamaConfig):
                 f"mixer_types must name one of {sorted(MIXERS)} for each of "
                 f"the {self.num_layers} layers, got {self.mixer_types}")
 
-    @property
-    def state_heads(self) -> int:
-        return self.lightning_heads
-
     def kind_config(self, kind: str) -> "MiniCPMSALAConfig":
         """The config :class:`.llama.LlamaDecoderLayer` builds a layer of
         ``kind`` from."""
@@ -116,13 +112,19 @@ class MiniCPMSALAConfig(LlamaConfig):
         return tuple(tuple(r) for r in out)
 
     def serving_family(self):
-        from ..inference.paging import ServingFamily, SparseStateCache
+        from ..inference.paging import (ServingFamily, SparseStateCache,
+                                        StateLeaf)
 
+        d = self.head_dim_
         return ServingFamily(
             forward=minicpm_sala_forward_with_cache,
             cache_kind=SparseStateCache(
                 sparse_layers=self.layers_of("sparse"),
-                state_layers=self.layers_of("lightning"),
+                # a slot's S^T a head, heads before slots
+                # (ops/lightning_attention.lightning_attention_packed)
+                leaves=(StateLeaf(
+                    "state", (self.layers_of("lightning"),
+                              self.lightning_heads), (d, d), jnp.float32),),
                 stride=self.sparse.stride, select_block=self.sparse.block),
             unsupported={
                 "prefix_sharing": "a lightning layer's state is not a "

@@ -44,7 +44,7 @@ UNSCOPED = "(unscoped)"
 SCOPES = (
     "embed", "norm",
     "attn", "attn.proj", "attn.kernel", "attn.walk", "attn.select",
-    "attn.state", "attn.summarise", "attn.pool_write",
+    "attn.state", "attn.conv", "attn.summarise", "attn.pool_write",
     "ffn", "ffn.dense", "ffn.router", "ffn.experts", "ffn.shared",
     "head", "sample",
     "loss", "optimizer",

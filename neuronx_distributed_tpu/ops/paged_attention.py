@@ -594,8 +594,14 @@ def paged_attention_impl(head_dim: int, block_size: int,
     (the compiled TPU kernel), ``"pallas-interpret"`` (the kernel, forced,
     off TPU) or ``"xla"`` (the gather reference).
 
-    The compiled kernel wants ``head_dim`` on whole lanes and whole
+    The compiled kernel wants pool rows of whole lanes and whole
     128-slot blocks (the shapes ``tests/test_chip_compile.py`` covers). A
+    head narrower than 128 lanes is served by it where the family's cache
+    kind lays ``128 // head_dim`` K/V heads side by side on a pool row
+    (:class:`..inference.paging.StatePoolCache`: :func:`paged_attention`
+    then pads each query head into its share of the lanes); a narrow pool
+    that is not laid so falls to the reference where it is traced, and
+    says so there. A
     TPU that falls to the reference for its shapes says so once, here;
     with ``kernel_only`` (a family whose pool only the kernel can serve at
     its size: the reference gathers every row's whole table) it raises
@@ -604,7 +610,7 @@ def paged_attention_impl(head_dim: int, block_size: int,
         return "xla"
     if not on_tpu():
         return "pallas-interpret" if force_pallas else "xla"
-    if head_dim % 128 == 0 and block_size % 128 == 0:
+    if (head_dim % 128 == 0 or 128 % head_dim == 0) and block_size % 128 == 0:
         return "pallas"
     if force_pallas or kernel_only:
         raise ValueError(
@@ -614,8 +620,10 @@ def paged_attention_impl(head_dim: int, block_size: int,
             "shapes are only valid off the TPU")
     logger.warning(
         "paged_attention: head_dim=%d block_size=%d does not tile for the "
-        "TPU kernel (both must be multiples of 128); this pool is served "
-        "by the XLA gather reference", head_dim, block_size)
+        "TPU kernel (whole 128-slot blocks, and heads of whole lanes or "
+        "of a share of 128 lanes laid side by side on a pool row: "
+        "inference/paging.StatePoolCache.pack); this pool is served by "
+        "the XLA gather reference", head_dim, block_size)
     return "xla"
 
 
@@ -662,12 +670,40 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     once for all layers; built here when not given.
     """
     t, n, d = q.shape
-    _, nb, bs, kv, _ = k_pool.shape
+    _, nb, bs, kv, lanes = k_pool.shape
     if n % kv != 0:
         raise ValueError(f"q heads {n} not a multiple of kv heads {kv}")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
     scale_ = (1.0 / math.sqrt(d)) if scale is None else scale
+    if lanes != d:
+        # ``lanes // d`` K/V heads side by side on a pool row: a query
+        # head zero-padded into its own head's share of the lanes meets
+        # that head's keys alone, and its share of the output is that
+        # head's values; the products of the other shares are with zeros
+        pack = lanes // d
+        if lanes % d or n % (kv * pack) or k_scale is not None:
+            raise ValueError(
+                f"a float pool row of {lanes} lanes holds whole heads of "
+                f"{d}, and {n} query heads divide over {kv} x {pack} K/V "
+                "heads")
+        share = (jnp.arange(n) // (n // (kv * pack))) % pack
+        wide = (q[:, :, None, :] * jax.nn.one_hot(
+            share, pack, dtype=q.dtype)[None, :, :, None]).reshape(
+                t, n, lanes)
+        out = paged_attention(
+            wide, k_pool, v_pool, pool_pos, tables, q_pos, layer,
+            scale=scale_, force_pallas=force_pallas,
+            combine_axis=combine_axis, window=window, walk=walk)
+        return jnp.take_along_axis(
+            out.reshape(t, n, pack, d), share[None, :, None, None],
+            axis=2)[:, :, 0]
+    if bs % 128 == 0 and d % 128 and on_tpu() and force_pallas is None:
+        logger.warning(
+            "paged_attention: a pool row of %d lanes is served by the XLA "
+            "gather reference; lay %d K/V heads side by side on a row "
+            "(inference/paging.StatePoolCache.pack)", d, max(128 // d, 1))
+        force_pallas = False
 
     if window is not None and (combine_axis is not None
                                or k_scale is not None):

@@ -917,6 +917,77 @@ def test_a_fusion_reads_the_layer_of_its_heaviest_matmul(
     assert sum(d[3] for d in top) <= 0.02 * total, top
 
 
+# -- the state-pool cache (Granite-4.0-H-Micro): the whole model a chip ----------
+# The cell granite-4.0-h-micro.serve-longgen: 128 rows, 80 slots, 36 mamba
+# layers over float32 states [36, 80, 128, 4096] and tails [36, 3, 80,
+# 4352], 4 attention layers of 32 heads of 64 over a pool [4, 1600, 128, 4,
+# 128] (two K/V heads a row), the vocabulary of 100,352 tied to the head.
+
+def test_state_pool_step_at_the_published_widths(chip, topo, on_one_chip,
+                                                 monkeypatch):
+    """The packed step of the cell's configuration file, uncut: it
+    compiles for the chip with both kernels in it (the paged kernel on
+    heads of 64, the scan's state update), holds what the configuration
+    says it holds, leaves no stack copied (temporaries under one layer's
+    states), hands every stack back in the buffer it came in, and every
+    fusion with a matmul inside reads the scope of its heaviest one."""
+    import re
+
+    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
+                                                            sample)
+    from neuronx_distributed_tpu.obs.device_scopes import device_scope
+    from neuronx_distributed_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "on_tpu", lambda: True)
+    config, models = _cell_config("granite-4.0-h-micro", None)
+    assert config["reduced"] == {} and config["num_hidden_layers"] == 40
+    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
+                                                         models)
+    slots = config["serve"]["max_slots"]
+    assert cache.k.shape == (4, slots * 20, 128, 4, 128)
+    assert cache.states["ssm"].shape == (36, slots, 128, 4096)
+    assert cache.states["conv"].shape == (36, 3, slots, 4352)
+
+    def step_fn(params, cache, tokens, positions, slot_ids, rng):
+        logits, cache = forward(cfg, params, tokens, positions, cache,
+                                slot_ids=slot_ids)
+        with device_scope("sample"):
+            return sample(logits[0], rng, SamplingConfig()), cache
+
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
+        chip(rng.shape, rng.dtype)).compile()
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"paged_attention",
+                                               "ssd_state_update"}
+    gib = 2.0 ** 30
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) / gib
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params)) / gib
+    assert 5.9 < weights < 6.0                       # 3,191M in bfloat16
+    assert mem.temp_size_in_bytes < slots * 128 * 4096 * 4   # one layer's
+    assert 13.0 < held < 13.5, held              # of 15.75: the file's 84%
+    assert abs(held - config["assumed"]["serve_aot_gib"]["total"]) < 0.05
+
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape in (f"4,{slots * 20},128,4,128", f"36,{slots},128,4096",
+                     f"36,3,{slots},4352")]
+    assert len(stacks) == 4 and set(stacks) <= aliased, (stacks, header)
+
+    total, differ, kernels = scope_disagreements(text)
+    assert total > 0 and kernels == {"attn.kernel", "attn.state"}
+    top = [d for d in differ if d[1].split(".")[0] != d[2].split(".")[0]]
+    assert sum(d[3] for d in top) <= 0.02 * total, top
+
+
 # -- the engine's own packed step: one deep in flight -------------------------
 # The CPU tests never donate, so only a compile for the chip shows what the
 # step's operands are there: the pool donated and written in place, the

@@ -35,10 +35,12 @@ from runners import models  # noqa: E402
 
 SERVING = {"llama": "tiny-mistral-serve", "mixtral": "tiny-mixtral",
            "evabyte": "tiny-evabyte", "minicpm_sala": "tiny-minicpm-sala",
-           "glm_moe_lite": "tiny-glm-moe-lite"}
+           "glm_moe_lite": "tiny-glm-moe-lite",
+           "granite_hybrid": "tiny-granite-hybrid"}
 #: the children a family's step must open, and no other family's may
-OWN = {"attn.select": {"minicpm_sala"}, "attn.state": {"minicpm_sala"},
-       "attn.summarise": {"evabyte"},
+OWN = {"attn.select": {"minicpm_sala"},
+       "attn.state": {"minicpm_sala", "granite_hybrid"},
+       "attn.conv": {"granite_hybrid"}, "attn.summarise": {"evabyte"},
        "ffn.experts": {"mixtral", "glm_moe_lite"},
        "ffn.router": {"mixtral", "glm_moe_lite"},
        "ffn.shared": {"glm_moe_lite"}}
@@ -257,7 +259,7 @@ def test_a_step_opens_the_children_its_family_has_and_no_others(which):
     for child, families in OWN.items():
         assert (child in seen) == (which in families), (child, seen)
     dense = which in ("llama", "evabyte", "minicpm_sala", "glm_moe_lite",
-                      "train")
+                      "granite_hybrid", "train")
     assert ("ffn.dense" in seen) == dense
     if which == "train":
         assert "optimizer" not in seen      # elementwise: no heavy operation

@@ -621,7 +621,7 @@ def test_attention_kinds_are_checked_by_name():
     from neuronx_distributed_tpu.models import llama
 
     assert llama.ATTENTION_KINDS == ("full", "eva", "sparse", "lightning",
-                                     "mla")
+                                     "mla", "mamba2")
     with pytest.raises(ValueError, match="attention_kind"):
         llama.tiny_config(attention_kind="linear")
     with pytest.raises(ValueError, match="mixer_types"):
