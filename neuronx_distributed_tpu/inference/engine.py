@@ -744,6 +744,10 @@ class ServingEngine:
         #: live (row, column) each fetch served
         #: (``nxd_paged_block_visits_total``)
         self._block_visits = np.zeros((2,), np.int64)
+        #: pool blocks the mla_paged_attention kernel fetches for these
+        #: rows, a layer's worth: in_run, alone, whole
+        #: (``nxd_mla_block_fetches_total``; a latent cache's alone)
+        self._mla_fetches = np.zeros((3,), np.int64)
         #: routed-expert assignments of the real rows that were kept and
         #: that were dropped, which the step of a family that declares
         #: ``moe_counts`` counts on the device (``cache.moe_counts``) and
@@ -2107,6 +2111,14 @@ class ServingEngine:
                     self._pool_blocks, xp=np)[0].sum())
                 self._block_visits += (
                     fetched, np.count_nonzero(kinds) - fetched)
+                if self._cache_kind.name == "latent":
+                    # and how the latent kernel's units come by them
+                    from ..ops.mla_attention import block_fetches
+
+                    self._mla_fetches += block_fetches(
+                        np.where(kinds > 0, tbl, -1), mcfg.num_heads,
+                        self._cache_kind.row, self.ecfg.block_size,
+                        self.cache.rows.dtype.itemsize)
         with tracer.span(span + "/dispatch"):
             if self._spec is not None:
                 sampled, self.cache, self.dcache = fn(
@@ -2687,6 +2699,20 @@ class ServingEngine:
                 labels=("kind",))
             visits_by_kind = () if visits_c is None else tuple(
                 visits_c.labels(kind=k) for k in ("fetched", "shared"))
+            fetches_by_kind = () if self._cache_kind.name != "latent" else (
+                tuple(reg.counter(
+                    "nxd_mla_block_fetches_total",
+                    "Pool blocks the mla_paged_attention kernel fetches "
+                    "for the serving workers' rows (one layer's worth, as "
+                    "nxd_paged_block_visits_total's fetched), by how: "
+                    "in_run, with one or more other blocks of the same "
+                    "row in one unit of the kernel (one step of the "
+                    "online softmax over all of them); alone, a one-row "
+                    "pair that is a unit by itself; whole, a pair that "
+                    "rows of the tile share, computed over the whole "
+                    "tile.",
+                    labels=("kind",)).labels(kind=k)
+                    for k in ("in_run", "alone", "whole")))
             moe_by_kind = () if not self._moe_on_device else tuple(
                 reg.counter(
                     "nxd_moe_assignments_total",
@@ -2709,10 +2735,11 @@ class ServingEngine:
                       for k in ("decode", "prefill", "pad")),
                 cols_by_kind, events_c, visits_by_kind, moe_by_kind,
                 {k: steps_c.labels(kind=k)
-                 for k in ("overlapped", "serial")}, state_by_kind)
+                 for k in ("overlapped", "serial")}, state_by_kind,
+                fetches_by_kind)
         (_, _, fields, free_g, step_h, rows_by_kind, cols_by_kind,
          events_c, visits_by_kind, moe_by_kind, steps_by_kind,
-         state_by_kind) = cache
+         state_by_kind, fetches_by_kind) = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
@@ -2729,6 +2756,9 @@ class ServingEngine:
         for child, n in zip(visits_by_kind, self._block_visits):
             child.inc(int(n))
         self._block_visits[:] = 0
+        for child, n in zip(fetches_by_kind, self._mla_fetches):
+            child.inc(int(n))
+        self._mla_fetches[:] = 0
         for child, n in zip(moe_by_kind, self._moe_assignments):
             child.inc(int(n))
         self._moe_assignments[:] = 0

@@ -344,6 +344,7 @@ def glm_moe_lite_forward_with_cache(cfg: GlmMoeLiteConfig, params,
         walk = mla.step_walk(tables, q_pos, kv_cache.block_size,
                              kv_cache.num_blocks, cfg.head_dim_,
                              cfg.kv_lora_rank, cfg.num_heads,
+                             kv_cache.rows.dtype.itemsize,
                              force_pallas=cfg.attn_force_pallas)
 
     def view_of(kind, carry, layer):

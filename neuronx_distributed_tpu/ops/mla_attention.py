@@ -22,12 +22,18 @@ Two implementations behind one signature, as :mod:`.paged_attention` has:
   query heads as its ``n_rep``, padded to whole sublanes
   (:func:`stacked_heads`: 20 heads ride as 24) so that a decode row's
   heads are exactly one narrow group of the tile: a tile is ``tile_rows``
-  packed rows (8) times the stacked heads (192 MXU rows), a pair's block
-  is copied once into one of two VMEM buffers while the block before it
-  is computed, scores are ``[rows, row] x [row, block_size]`` from the
-  stored operands into float32, and ``p x v`` runs against the block's
-  first ``rank`` lanes with ``p`` float32 (:func:`_p_times_v`). Prefill
-  chunks, decode rows and pad rows take the one path.
+  packed rows (8) times the stacked heads (192 MXU rows). The kernel's
+  unit of work is a *run* of pairs (:func:`pair_runs`): up to 8 pairs
+  that one row alone names (a decode row's own blocks, against its 24
+  stacked heads), or up to 4 that rows of the tile share (a chunk's
+  blocks, against the whole tile; :func:`unit_blocks`). A unit's blocks
+  are copied side by side into one half of a ring while the unit before
+  it is computed, scored in one product ``[rows, row] x [row, blocks x
+  block_size]`` from the stored operands into float32 and taken through
+  one step of the online softmax, and ``p x v`` is one product against
+  the blocks' first ``rank`` lanes with ``p``'s two bf16 parts stacked on
+  rows (:func:`_p_times_v`). Prefill chunks, decode rows and pad rows
+  take the one path.
 
 On a TPU there is no silent fall to the reference: shapes the kernel
 cannot tile raise (:func:`mla_attention_impl`).
@@ -36,17 +42,22 @@ cannot tile raise (:func:`mla_attention_impl`).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..inference.kv_cache import PAD_POSITION
-from .paged_attention import (TileWalk, _p_times_v, narrow_rows,
-                              paged_attention_impl, tile_walk)
+from .paged_attention import (TileWalk, narrow_rows, paged_attention_impl,
+                              tile_rows, tile_walk)
 from .pallas_utils import compiler_params as _compiler_params
 
 LANES = 128
+#: what the ring of a unit's blocks may take of VMEM (two halves), and a
+#: unit's float32 scores ``[stacked rows, positions]``: :func:`unit_blocks`
+RING_BYTES = 4 << 20
+SCORE_BYTES = 384 << 10
 
 
 def row_width(rank: int, rope: int) -> int:
@@ -77,15 +88,151 @@ def mla_attention_impl(row: int, rank: int, block_size: int,
     return impl
 
 
+def unit_blocks(rows: int, row: int, block_size: int, itemsize: int) -> int:
+    """Blocks of a unit of the kernel that ``rows`` stacked rows attend
+    (a run's 24, a shared pair's whole tile): the power of two, 8 at
+    most, whose two ring halves fit :data:`RING_BYTES` and whose scores
+    ``[rows, blocks x block_size]`` fit :data:`SCORE_BYTES`. A row of 640
+    bf16 lanes in blocks of 128: 8 blocks for a run (a ring of 2.5 MiB,
+    1,024 positions a step of the online softmax) and 4 for a tile of
+    192 rows, what the chip read fastest (``PERF.md``, Findings, PR 39)."""
+    most = min(8, RING_BYTES // (2 * block_size * row * itemsize),
+               SCORE_BYTES // (4 * rows * block_size))
+    return 1 << max(most, 1).bit_length() - 1
+
+
+class RunWalk(NamedTuple):
+    """What the kernel is handed of a step's routing (:func:`step_walk`),
+    the same for every layer: a :class:`..paged_attention.TileWalk`'s
+    pairs in the order of the kernel's units (:func:`pair_runs`):
+    ``units [tiles]`` the units of a tile; ``blocks``, ``cols`` and
+    ``narrow [tiles * P + room]`` the tile's pairs, the shared ones first
+    and then each one-row group's by column; ``lens``, at a unit's first
+    pair its number of pairs (:func:`unit_blocks` at most) and 0
+    elsewhere; ``served`` and ``q_pos`` the walk's own."""
+
+    units: jax.Array
+    blocks: jax.Array
+    cols: jax.Array
+    narrow: jax.Array
+    lens: jax.Array
+    served: jax.Array
+    q_pos: jax.Array
+
+
+def pair_runs(count, blocks, cols, narrow, num_blocks: int, max_cols: int,
+              group: int, groups: int, run: int, whole_run: int):
+    """A walk's pairs (``count [tiles]``; ``blocks``, ``cols``, ``narrow
+    [tiles, P]``, ``P`` no less than the largest count:
+    :func:`..paged_attention.tile_walk` over tables of ``max_cols``
+    columns, a tile of ``groups`` groups of ``group`` stacked rows) cut
+    into the kernel's units: the pairs that rows of several groups name
+    (``narrow < 0``) in runs of up to ``whole_run``, whichever rows name
+    each; the pairs that one group names alone (a decode row's own
+    blocks, one a column) in runs of up to ``run`` successive ones of
+    that group, the last shorter. Returns ``(units [tiles], blocks, cols,
+    narrow, lens [tiles, P])`` with the pairs reordered, the shared ones
+    first and then group by group, each in order of column and block, and
+    ``lens`` the length of the unit that starts at a pair, 0 where none
+    does. One sort of one key and no gather (a gather of the pairs cost
+    the step 0.6 ms: ``PERF.md``, Findings, PR 39)."""
+    tiles, per = blocks.shape
+    span = max_cols * num_blocks            # a (column, block) as one number
+    assert (groups + 2) * span < 2 ** 31
+    at = jnp.arange(per, dtype=jnp.int32)[None, :]
+    live = at < count[:, None]
+    # 0 a shared pair, 1 + g a pair of group g, last what lies beyond the
+    # tile's count
+    kind = jnp.where(live, jnp.where(narrow < 0, 0, 1 + narrow // group),
+                     groups + 1).astype(jnp.int32)
+    key = jnp.sort(
+        kind * span + jnp.where(live, cols * num_blocks + blocks, 0), axis=-1)
+    kind, pair = key // span, key % span
+    # the pairs lie sorted by kind: a kind's first is at the number of
+    # pairs of the kinds before it (a handful of kinds: a select each)
+    first, end, before = 0, 0, 0
+    for k in range(groups + 1):
+        mine = kind == k
+        many = jnp.sum(mine, axis=-1, keepdims=True)
+        first = jnp.where(mine, before, first)
+        end = jnp.where(mine, before + many, end)
+        before = before + many
+    most = jnp.where(kind == 0, whole_run, run)
+    lens = jnp.where(live & ((at - first) % most == 0),
+                     jnp.minimum(most, end - at), 0).astype(jnp.int32)
+    lone = (kind > 0) & (kind <= groups)
+    return (jnp.sum(lens > 0, axis=-1).astype(jnp.int32),
+            pair % num_blocks, pair // num_blocks,
+            jnp.where(lone, (kind - 1) * group, -1), lens)
+
+
 def step_walk(tables, q_pos, block_size: int, num_blocks: int, row: int,
-              rank: int, num_heads: int,
-              force_pallas: Optional[bool] = None) -> Optional[TileWalk]:
-    """The kernel's walk of one packed step (once for all layers), or
-    ``None`` where the XLA reference serves."""
+              rank: int, num_heads: int, itemsize: int,
+              force_pallas: Optional[bool] = None) -> Optional[RunWalk]:
+    """The kernel's walk of one packed step (once for all layers) over a
+    pool of ``itemsize`` bytes a value, or ``None`` where the XLA
+    reference serves."""
     if mla_attention_impl(row, rank, block_size, force_pallas) == "xla":
         return None
-    return tile_walk(tables, q_pos, block_size, num_blocks,
-                     stacked_heads(num_heads))
+    heads = stacked_heads(num_heads)
+    return run_walk(tile_walk(tables, q_pos, block_size, num_blocks, heads),
+                    num_blocks, heads, row, block_size, itemsize)
+
+
+def _unit_lengths(heads: int, wide: int, row: int, block_size: int,
+                  itemsize: int):
+    """Blocks of a run of one row's pairs and of a run of pairs that a
+    tile of ``wide`` stacked rows shares."""
+    return (unit_blocks(heads, row, block_size, itemsize),
+            unit_blocks(wide, row, block_size, itemsize))
+
+
+def run_walk(walk: TileWalk, num_blocks: int, heads: int, row: int,
+             block_size: int, itemsize: int) -> RunWalk:
+    """:func:`pair_runs` of a step's :class:`..paged_attention.TileWalk`
+    at the lengths the kernel takes for these shapes."""
+    tiles, wide, _ = walk.served.shape
+    run, whole_run = _unit_lengths(heads, wide, row, block_size, itemsize)
+    units, *pairs = pair_runs(
+        walk.count, *(x.reshape(tiles, -1) for x in
+                      (walk.blocks, walk.cols, walk.narrow)),
+        num_blocks, walk.served.shape[2], heads, wide // heads, run,
+        whole_run)
+    # the kernel reads a shared unit's pairs to its full length: room
+    # past the last tile's
+    blocks, cols, narrow, lens = (
+        jnp.pad(x.reshape(-1), (0, whole_run)) for x in pairs)
+    return RunWalk(units=units, blocks=blocks, cols=cols, narrow=narrow,
+                   lens=lens, served=walk.served, q_pos=walk.q_pos)
+
+
+def block_fetches(served, num_heads: int, row: int, block_size: int,
+                  itemsize: int) -> np.ndarray:
+    """Pool blocks the kernel fetches for one layer of a packed step whose
+    rows attend ``served [T, max_blocks_per_seq]`` (NumPy: a row's table
+    entry in the columns it attends, -1 elsewhere), by how: ``[in_run,
+    alone, whole]``, a fetch that rode a run of two or more blocks of one
+    row, a one-row pair that is a unit by itself, a pair that rows of the
+    tile share. What :func:`pair_runs` makes of the step, counted on the
+    host from the tables themselves (``nxd_mla_block_fetches_total``;
+    ``tests/walk_checks.py`` holds the two to each other)."""
+    heads = stacked_heads(num_heads)
+    tokens, maxb = served.shape
+    rows = tile_rows(heads, tokens)
+    run, _ = _unit_lengths(heads, rows * heads, row, block_size, itemsize)
+    tile = np.concatenate(
+        [served, np.full((-tokens % rows, maxb), -1, served.dtype)]
+    ).reshape(-1, rows, maxb)
+    # the rows of its tile that name a row's (column, block), itself among
+    # them: one makes the pair the row's own, more the tile's, counted at
+    # its first namer
+    same = (tile[:, :, None] == tile[:, None]) & (tile >= 0)[:, :, None]
+    namers = same.sum(axis=2)
+    first = ~(same & np.tri(rows, k=-1, dtype=bool)[:, :, None]).any(axis=2)
+    own = (namers == 1).sum(axis=-1)         # [tiles, rows]: a row's run
+    alone = own if run == 1 else own % run == 1
+    return np.array([own.sum() - alone.sum(), alone.sum(),
+                     ((namers > 1) & first).sum()], np.int64)
 
 
 def absorb_queries(q_nope, q_rope, k_up, row: int):
@@ -124,89 +271,137 @@ def _mla_attention_xla(q, pool, pool_pos, tables, q_pos, layer, rank, scale):
     return jnp.where(live, out, 0.0).astype(q.dtype)
 
 
-def _mla_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
-                served_ref, qpos_ref, q_ref, pool_hbm, pos_hbm, o_ref,
-                row_buf, pos_buf, sems, m_ref, l_ref, acc_ref, *,
-                pairs: int, group: int, scale: float, rank: int):
+def _p_times_v(p, v):
+    """:func:`.paged_attention._p_times_v` in one product: ``p [rows,
+    positions]`` float32 against ``v [positions, D]``. A bf16 ``v`` meets
+    ``p``'s two bf16 parts (its upper 16 bits of mantissa, not rounded to
+    bf16) stacked on rows, so the values are pushed through the MXU once
+    and the halves are added in float32."""
+    def dot(a, b):
+        return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    if v.dtype == jnp.float32:
+        return dot(p, v)
+    high = p.astype(v.dtype).astype(jnp.float32)
+    both = dot(jnp.concatenate([high, p - high], axis=0).astype(v.dtype), v)
+    return both[:p.shape[0]] + both[p.shape[0]:]
+
+
+def _mla_kernel(units_ref, blocks_ref, cols_ref, narrow_ref, lens_ref,
+                layer_ref, served_ref, qpos_ref, q_ref, pool_hbm, pos_hbm,
+                o_ref, row_buf, pos_buf, sems, m_ref, l_ref, acc_ref, *,
+                pairs: int, group: int, run: int, whole_run: int,
+                scale: float, rank: int):
     """One tile of packed rows (every row's heads stacked) against the
-    pool blocks its rows attend: :func:`.paged_attention._paged_kernel`'s
-    loop over the tile's pairs with one buffer a block, which is the keys
-    whole and the values in its first ``rank`` lanes."""
+    pool blocks its rows attend, a unit of the walk at a time
+    (:func:`pair_runs`): the unit's blocks, which are the keys whole and
+    the values in their first ``rank`` lanes, are copied side by side into
+    one half of the ring while the unit before it is computed from the
+    other, and are one step of the online softmax
+    (:func:`.paged_attention._paged_kernel`'s, over ``blocks x
+    block_size`` positions) of the rows it serves: one group's for a run
+    of one row's pairs, the tile's for pairs that its rows share, each
+    block under the mask of the rows that name it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     tile = pl.program_id(0)
-    count = count_ref[tile]
+    units = units_ref[tile]
     layer = layer_ref[0]
+    base = tile * pairs
+    bs = pool_hbm.shape[2]
     operand = (jnp.bfloat16 if q_ref.dtype == jnp.bfloat16
                and row_buf.dtype != jnp.float32 else jnp.float32)
 
-    def copies(j, slot):
-        b = blocks_ref[tile * pairs + j]
-        moves = [(pool_hbm.at[layer, b], row_buf), (pos_hbm.at[b], pos_buf)]
-        return [pltpu.make_async_copy(src, buf.at[slot], sems.at[slot, i])
-                for i, (src, buf) in enumerate(moves)]
+    def copies(first, side, then):
+        # the copies of the unit that starts at pair ``first``
+        n = lens_ref[base + first]
+        for k in range(run):
+            @pl.when(k < n)
+            def _block():
+                b = blocks_ref[base + first + k]
+                at = pl.ds(k * bs, bs)
+                then(pltpu.make_async_copy(
+                    pool_hbm.at[layer, b], row_buf.at[side, at],
+                    sems.at[side, k, 0]))
+                then(pltpu.make_async_copy(
+                    pos_hbm.at[b], pos_buf.at[side, :, at],
+                    sems.at[side, k, 1]))
+
+    @pl.when(tile == 0)
+    def _clean():
+        # what a short run leaves of the ring is multiplied by p = 0
+        row_buf[...] = jnp.zeros_like(row_buf)
+
+    @pl.when(units > 0)
+    def _first():
+        copies(0, 0, lambda c: c.start())
 
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(count > 0)
-    def _first():
-        for c in copies(0, 0):
-            c.start()
+    def attend(rows, q_pos_ok, keys, values):
+        # one step of the online softmax of ``rows`` over ``keys``
+        s = jax.lax.dot_general(
+            q_ref[rows, :].astype(operand), keys.astype(operand),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(q_pos_ok, s, -jnp.inf)
+        m_prev = m_ref[rows, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.where(q_pos_ok, jnp.exp(s - m_safe), 0.0)
+        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
+        m_ref[rows, :] = m_new
+        l_ref[rows, :] = l_ref[rows, :] * corr + jnp.sum(
+            p, axis=-1, keepdims=True)
+        acc_ref[rows, :] = acc_ref[rows, :] * corr + _p_times_v(
+            p, values.astype(operand))
 
-    def pair(j, carry):
-        slot = j % 2
+    def unit(u, first):
+        side = u % 2
+        n = lens_ref[base + first]
 
-        @pl.when(j + 1 < count)
+        @pl.when(u + 1 < units)
         def _next():
-            for c in copies(j + 1, 1 - slot):
-                c.start()
+            copies(first + n, 1 - side, lambda c: c.start())
 
-        for c in copies(j, slot):
-            c.wait()
-        block = blocks_ref[tile * pairs + j]
-        col = cols_ref[tile * pairs + j]
-        pos = pos_buf[slot]                             # [1, bs]
-        keys = row_buf[slot].astype(operand)            # [bs, row]
-        values = row_buf[slot, :, :rank].astype(operand)
-
-        def attend(rows):
-            served = served_ref[rows, :]                # [rows', maxb]
-            column = jax.lax.broadcasted_iota(jnp.int32, served.shape, 1)
-            named = jnp.max(
-                jnp.where((column == col) & (served == block), 1, 0),
-                axis=1, keepdims=True) > 0              # [rows', 1]
-            ok = (pos <= qpos_ref[rows, :]) & named     # [rows', bs]
-            s = jax.lax.dot_general(
-                q_ref[rows, :].astype(operand), keys,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(ok, s, -jnp.inf)
-            m_prev = m_ref[rows, :]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-            p = jnp.where(ok, jnp.exp(s - m_safe), 0.0)
-            corr = jnp.where(jnp.isfinite(m_prev),
-                             jnp.exp(m_prev - m_safe), 0.0)
-            m_ref[rows, :] = m_new
-            l_ref[rows, :] = l_ref[rows, :] * corr + jnp.sum(
-                p, axis=-1, keepdims=True)
-            acc_ref[rows, :] = acc_ref[rows, :] * corr + _p_times_v(p, values)
-
-        start = narrow_ref[tile * pairs + j]
+        copies(first, side, lambda c: c.wait())
+        start = narrow_ref[base + first]
 
         @pl.when(start >= 0)
-        def _narrow():
-            attend(pl.ds(pl.multiple_of(start, 8), group))
+        def _run():
+            # one row's blocks: the group's rows name every one of them
+            # (a narrow pair has no other namer), and a short run's
+            # missing blocks are dead positions
+            rows = pl.ds(pl.multiple_of(start, 8), group)
+            pos = pos_buf[side]                         # [1, run * bs]
+            lane = jax.lax.broadcasted_iota(jnp.int32, pos.shape, 1)
+            ok = (pos <= qpos_ref[rows, :]) & (lane < n * bs)
+            attend(rows, ok, row_buf[side], row_buf[side, :, :rank])
 
         @pl.when(start < 0)
         def _whole():
-            attend(slice(None))
-        return carry
+            served = served_ref[...]                    # [wide, maxb]
+            column = jax.lax.broadcasted_iota(jnp.int32, served.shape, 1)
+            ok = []
+            for k in range(whole_run):
+                block = blocks_ref[base + first + k]
+                col = cols_ref[base + first + k]
+                named = jnp.max(
+                    jnp.where((column == col) & (served == block), 1, 0),
+                    axis=1, keepdims=True) > 0          # [wide, 1]
+                ok.append((pos_buf[side, :, k * bs:(k + 1) * bs]
+                           <= qpos_ref[...]) & named & (k < n))
+            span = whole_run * bs
+            attend(slice(None), jnp.concatenate(ok, axis=1),
+                   row_buf[side, :span], row_buf[side, :span, :rank])
 
-    jax.lax.fori_loop(0, count, pair, None)
+        return first + n
+
+    jax.lax.fori_loop(0, units, unit, 0)
     o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                   ).astype(o_ref.dtype)
 
@@ -221,10 +416,12 @@ def _mla_attention_pallas(q, pool, pool_pos, tables, q_pos, layer, rank,
     maxb = tables.shape[1]
     heads = stacked_heads(n)
     if walk is None:
-        walk = tile_walk(tables, q_pos, bs, nb, heads)
+        walk = run_walk(tile_walk(tables, q_pos, bs, nb, heads), nb, heads,
+                        row, bs, pool.dtype.itemsize)
     tiles, wide, _ = walk.served.shape          # wide = rows * heads
     rows = wide // heads
     pairs = rows * maxb
+    run, whole_run = _unit_lengths(heads, wide, row, bs, pool.dtype.itemsize)
 
     def row_block(last):
         return pl.BlockSpec((None, wide, last), lambda i, *_: (i, 0, 0))
@@ -235,18 +432,18 @@ def _mla_attention_pallas(q, pool, pool_pos, tables, q_pos, layer, rank,
     q_tiles = jnp.pad(q, ((0, tiles * rows - t), (0, heads - n), (0, 0))
                       ).reshape(tiles, wide, row)
     out = pl.pallas_call(
-        functools.partial(_mla_kernel, pairs=pairs, group=heads,
-                          scale=scale, rank=rank),
+        functools.partial(_mla_kernel, pairs=pairs, group=heads, run=run,
+                          whole_run=whole_run, scale=scale, rank=rank),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=6,
             grid=(tiles,),
             in_specs=[row_block(maxb), row_block(1), row_block(row), hbm,
                       hbm],
             out_specs=row_block(rank),
             scratch_shapes=[
-                pltpu.VMEM((2, bs, row), pool.dtype),
-                pltpu.VMEM((2, 1, bs), jnp.int32),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((2, run * bs, row), pool.dtype),
+                pltpu.VMEM((2, 1, run * bs), jnp.int32),
+                pltpu.SemaphoreType.DMA((2, run, 2)),
                 pltpu.VMEM((wide, 1), jnp.float32),
                 pltpu.VMEM((wide, 1), jnp.float32),
                 pltpu.VMEM((wide, rank), jnp.float32)]),
@@ -254,7 +451,7 @@ def _mla_attention_pallas(q, pool, pool_pos, tables, q_pos, layer, rank,
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
         name="mla_paged_attention",
-    )(walk.count, walk.blocks, walk.cols, walk.narrow,
+    )(walk.units, walk.blocks, walk.cols, walk.narrow, walk.lens,
       jnp.asarray(layer, jnp.int32).reshape(1), walk.served, walk.q_pos,
       q_tiles, pool, pool_pos.reshape(nb, 1, bs))
     return out.reshape(tiles * rows, heads, rank)[:t, :n]
@@ -264,7 +461,7 @@ def mla_paged_attention(q: jax.Array, pool: jax.Array, pool_pos: jax.Array,
                         tables: jax.Array, q_pos: jax.Array, layer,
                         rank: int, scale: float,
                         force_pallas: Optional[bool] = None,
-                        walk: Optional[TileWalk] = None) -> jax.Array:
+                        walk: Optional[RunWalk] = None) -> jax.Array:
     """``q [T, N, row]`` absorbed queries (:func:`absorb_queries`) against
     layer ``layer`` of ``pool [L, num_blocks, block_size, row]`` through
     ``tables [T, max_blocks_per_seq]`` (-1 unmapped), ``pool_pos

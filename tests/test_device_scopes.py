@@ -299,3 +299,33 @@ def test_the_markers_change_no_operation(which, monkeypatch):
     assert not any(scope_of(path) != UNSCOPED
                    for _, path in _operations(bare_lowered))
     assert _stripped(bare_lowered) == marked
+
+
+#: sha256 of a family's lowered packed step at the rehearsal widths,
+#: locations stripped, as PR 38's tree lowered it (``_stripped(_lowered(
+#: family))`` there): the families that run no ``ops/mla_attention.py``
+LOWERED_AT_PR_38 = {
+    "llama":
+        "26e1d5185b42b1a3ebb9e2ce736f5e85f46ef1bae8c338549a38499737339aa4",
+    "mixtral":
+        "980493c6574fdd048c8fc81af4a42da9f8e4f1d8cdc346900a88ff1592f19bcf",
+    "evabyte":
+        "c947e1b8bab1fdb356b3c957e80421564c15e960e94a849b27e073c8bb9fdc98",
+    "minicpm_sala":
+        "3cbda88f4fcfc41c4f64e42930f8a2f55231b5f5333a66e97acf1e3b5f1d2e19",
+    "granite_hybrid":
+        "986d4a577868df39b211138f57cc1cb0a1f2880925b82928852429e39db15783",
+}
+
+
+@pytest.mark.parametrize("which", list(LOWERED_AT_PR_38))
+def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
+    """PR 39 changed the latent kernel and its walk alone: the packed
+    steps of the five families that run the shared helpers of
+    ``ops/paged_attention.py`` are the parent's text. A PR that changes
+    one of these programs on purpose records its new hash here."""
+    import hashlib
+
+    text = _stripped(_lowered(which))
+    assert hashlib.sha256(text.encode()).hexdigest() == LOWERED_AT_PR_38[
+        which]

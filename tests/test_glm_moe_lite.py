@@ -41,6 +41,7 @@ if BENCH not in sys.path:
 
 import harness  # noqa: E402  (benchmarks/)
 from runners import serve  # noqa: E402
+from walk_checks import check_tile_walk  # noqa: E402  (tests/)
 
 BS = 16
 PUBLISHED = dict(
@@ -246,6 +247,134 @@ def test_kernel_equals_the_xla_path_at_the_published_row(dtype, atol):
                       np.asarray(walk.narrow)[second].tolist()))
     assert all(narrow[b] == 4 * 24 for b in tables[2])
     assert narrow[2] == 5 * 24 and narrow[3] == -1 and narrow[12] == 3 * 24
+    # and the kernel's units of it, at the lengths these shapes take
+    live = np.asarray(pa.column_live(tok_tables, np.arange(maxb),
+                                     q_pos[:, None], bs))
+    lengths = mla._unit_lengths(24, 8 * 24, row, bs, 4)
+    kinds = check_tile_walk(
+        type(walk)(*(None if x is None else np.asarray(x) for x in walk)),
+        live, tok_tables, 8, 24,
+        runs=(mla.run_walk(walk, nb, 24, row, bs, 4), *lengths))
+    assert tuple(kinds) == tuple(mla.block_fetches(
+        np.where(live, tok_tables, -1), n, row, bs, 4)) == (6, 2, 6)
+
+
+def _latent_scene(lengths, shared=(), nb=48, bs=16, maxb=12, n=20,
+                  rank=128, rope=64, seed=11):
+    """A pool of random float32 rows in which slot ``s`` holds
+    ``lengths[s]`` positions in blocks of its own (``shared``: ``(slot,
+    other, blocks)``, the slot's first blocks are the other's), and
+    queries for ``rows`` (built by the caller): ``(pool, pos, tables, q
+    maker)``."""
+    rng = np.random.RandomState(seed)
+    row = mla.row_width(rank, rope)
+    pool = rng.randn(1, nb, bs, row)
+    pool[..., rank + rope:] = 0
+    free = list(rng.permutation(nb))
+    tables = np.full((len(lengths) + 1, maxb), -1)     # the last: unmapped
+    pos = np.full((nb, bs), PAD_POSITION)
+    for s, length in enumerate(lengths):
+        for c in range(-(-length // bs)):
+            tables[s, c] = free.pop()
+    for s, other, blocks in shared:
+        tables[s, :blocks] = tables[other, :blocks]
+    for s, length in enumerate(lengths):
+        for p in range(length):
+            pos[tables[s, p // bs], p % bs] = p
+
+    def queries(count):
+        q = rng.randn(count, n, row)
+        q[..., rank + rope:] = 0
+        return q
+
+    return pool, pos, tables, queries
+
+
+#: name -> (positions a slot holds, shared prefixes, the packed rows as
+#: (slot, position), what block_fetches must count: in_run, alone, whole),
+#: under runs of 4 (one row's pairs) and 2 (shared pairs), blocks of 16
+_RUN_CASES = {
+    # 7 live blocks: a run of four and one of three
+    "no_multiple_of_the_run": ([102], (), [(0, 101)], (7, 0, 0)),
+    # 8 live blocks, the last holds 4 positions: two full runs
+    "last_block_partly_filled": ([116], (), [(0, 115)], (8, 0, 0)),
+    # one live column: a run of one
+    "a_single_live_column": ([6], (), [(0, 5)], (0, 1, 0)),
+    # a tile of 8 decode rows of 8 slots, 1 to 10 blocks each
+    "eight_decode_rows_a_tile": (
+        [10, 150, 33, 64, 97, 16, 160, 120], (),
+        [(s, n - 1) for s, n in enumerate(
+            [10, 150, 33, 64, 97, 16, 160, 120])],
+        (42, 2, 0)),
+    # a chunk's 5 rows (3 blocks, shared by the tile) and 3 decode rows
+    "a_decode_row_beside_a_chunk": (
+        [42, 70, 20, 130], (),
+        [(0, p) for p in range(37, 42)] + [(1, 69), (2, 19), (3, 129)],
+        (14, 2, 3)),
+    # two decode rows whose first two blocks are one prefix: those pairs
+    # are the tile's, the rest run
+    "two_rows_share_a_prefix": (
+        [90, 75], ((1, 0, 2),), [(0, 89), (1, 74)], (7, 0, 2)),
+}
+
+
+@pytest.mark.parametrize("case", list(_RUN_CASES) + ["pad_rows_between"])
+def test_a_decode_rows_blocks_are_walked_in_runs(case, monkeypatch):
+    """The kernel's units against the gather reference in float32,
+    interpret mode, with runs of 4 and 2 so that small tables cut them:
+    each case's rows equal the reference's, the walk serves every live
+    (row, column) exactly once in units of one kind, and the host's count
+    of the fetches is the case's. ``pad_rows_between``: pad rows in the
+    middle and at the end of the batch leave the real rows' bits as they
+    were."""
+    monkeypatch.setattr(mla, "unit_blocks",
+                        lambda rows, *_: 4 if rows == 24 else 2)
+    n, rank, bs, nb = 20, 128, 16, 48
+    if case == "pad_rows_between":
+        lengths, shared = [102, 6, 75], ()
+        rows = [(0, 101), (1, 5), (2, 74)]
+        counts = None
+    else:
+        lengths, shared, rows, counts = _RUN_CASES[case]
+    pool, pos, tables, queries = _latent_scene(lengths, shared, nb=nb, bs=bs)
+    q = queries(len(rows))
+
+    def run(rows, q, force):
+        slot, q_pos = (np.array(x) for x in zip(*rows))
+        return np.asarray(mla.mla_paged_attention(
+            jnp.asarray(q, jnp.float32), jnp.asarray(pool, jnp.float32),
+            jnp.asarray(pos, jnp.int32),
+            jnp.asarray(tables[np.minimum(slot, len(lengths))], jnp.int32),
+            jnp.asarray(q_pos, jnp.int32), 0, rank, 0.125,
+            force_pallas=force))
+
+    got = run(rows, q, True)
+    np.testing.assert_allclose(got, run(rows, q, False), atol=2e-5)
+    if counts is None:
+        # the same rows with pad rows among and behind them
+        pad = (len(lengths), PAD_POSITION)
+        spread = [rows[0], pad, rows[1], pad, pad, rows[2]] + [pad] * 5
+        zeros = np.zeros_like(q[:1])
+        wide = np.concatenate([q[:1], zeros, q[1:2], zeros, zeros, q[2:]]
+                              + [zeros] * 5)
+        padded = run(spread, wide, True)
+        np.testing.assert_array_equal(padded[[0, 2, 5]], got)
+        assert (padded[[1, 3, 4, 6, 7, 8, 9, 10]] == 0).all()
+        return
+    slot, q_pos = (np.array(x) for x in zip(*rows))
+    tok_tables = tables[slot]
+    live = np.asarray(pa.column_live(tok_tables, np.arange(tables.shape[1]),
+                                     q_pos[:, None], bs))
+    walk = pa.tile_walk(jnp.asarray(tok_tables, jnp.int32),
+                        jnp.asarray(q_pos, jnp.int32), bs, nb, 24)
+    runs = mla.run_walk(walk, nb, 24, 256, bs, 4)
+    kinds = check_tile_walk(
+        type(walk)(*(None if x is None else np.asarray(x) for x in walk)),
+        live, tok_tables, 8, 24, runs=(runs, 4, 2))
+    fetches = mla.block_fetches(np.where(live, tok_tables, -1), n, 256, bs,
+                                4)
+    assert tuple(fetches) == tuple(kinds) == counts
+    assert fetches.sum() == int(np.asarray(walk.count).sum())
 
 
 def test_on_a_tpu_no_shape_falls_to_the_reference(monkeypatch):
@@ -385,6 +514,7 @@ def served():
                for c in obs.get_registry().get(name).children()}
         for name in ("nxd_moe_assignments_total", "nxd_paged_columns_total",
                      "nxd_paged_block_visits_total",
+                     "nxd_mla_block_fetches_total",
                      "nxd_engine_rows_total")}
     obs.disable()
     ps.destroy_model_parallel()
@@ -424,6 +554,13 @@ def test_the_counters_of_the_latent_walk_and_of_the_experts(served):
     visits = counters["nxd_paged_block_visits_total"]
     assert visits["fetched"] > 0 and visits["shared"] > 0
     assert visits["fetched"] + visits["shared"] == cols["live"]
+    # every fetch is one of the latent kernel's three kinds: a prefill
+    # chunk's blocks are the tile's, a decode row past its first block
+    # runs
+    fetches = counters["nxd_mla_block_fetches_total"]
+    assert set(fetches) == {"in_run", "alone", "whole"}
+    assert sum(fetches.values()) == visits["fetched"]
+    assert fetches["in_run"] > 0 and fetches["whole"] > 0
 
 
 def test_prefix_sharing_maps_latent_blocks_and_copies_on_write():

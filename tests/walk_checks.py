@@ -7,12 +7,15 @@ selection)."""
 import numpy as np
 
 
-def check_tile_walk(walk, live, tables, rows, n_rep):
+def check_tile_walk(walk, live, tables, rows, n_rep, runs=None):
     """What every walk of the kernel must hold (a full cache's and a
     window-summary cache's alike, ``tests/test_evabyte.py``): each live
     (row, column) is served by exactly one pair of its tile, no pair is
     listed twice, a tile's count is the brute count of its distinct
-    pairs, and a pair is narrow only if one group of rows names it."""
+    pairs, and a pair is narrow only if one group of rows names it.
+    ``runs``, the latent kernel's cut of this walk into units
+    (``(run_walk, run, whole_run)``), is held to :func:`check_pair_runs`,
+    whose count of the fetches by kind is returned."""
     t, maxb = tables.shape
     tiles = len(walk.count)
     per = walk.blocks.size // tiles
@@ -43,6 +46,66 @@ def check_tile_walk(walk, live, tables, rows, n_rep):
         for r in mine:
             np.testing.assert_array_equal(
                 served[r - i * rows, 0], np.where(live[r], tables[r], -1))
+    if runs is not None:
+        return check_pair_runs(walk, live, tables, rows, n_rep, *runs)
+
+
+def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run):
+    """What :func:`..ops.mla_attention.pair_runs` must hold of a tile
+    walk, by brute count: following a tile's units from its first pair,
+    every pair of the walk (so every live (row, column)) lies in exactly
+    one unit, no block is in two, a unit is no longer than its kind may
+    be and holds either pairs that one and the same row names alone, in
+    order of column, or pairs that several rows name; a row's units are
+    full but its last; and ``lens`` is 0 off a unit's first pair. Returns
+    the walk's fetches by kind, ``[in_run, alone, whole]``, which the
+    caller holds the host's count to
+    (:func:`..ops.mla_attention.block_fetches`, NumPy over the tables
+    themselves)."""
+    t, maxb = tables.shape
+    tiles = len(walk.count)
+    per = rows * maxb
+    group = -(-n_rep // 8) * 8
+    assert group == n_rep              # a group is one packed row's heads
+    units, blocks, cols, narrow, lens = (np.asarray(x) for x in runs[:5])
+    kinds = np.zeros((3,), np.int64)        # in_run, alone, whole
+    for i in range(tiles):
+        mine = range(i * rows, min((i + 1) * rows, t))
+        want = {}           # (column, block) -> the tile's rows that name it
+        for r in mine:
+            for c in np.flatnonzero(live[r]):
+                want.setdefault((int(c), int(tables[r, c])), []).append(r)
+        seen, heads_at, first = [], set(), 0
+        last_of = {}                    # a row's units' lengths, in order
+        for _ in range(int(units[i])):
+            at = i * per + first
+            n = int(lens[at])
+            heads_at.add(first)
+            pairs = list(zip(cols[at:at + n].tolist(),
+                             blocks[at:at + n].tolist()))
+            starts = set(narrow[at:at + n].tolist())
+            assert len(starts) == 1     # one group's pairs, or shared ones
+            start = starts.pop()
+            kinds[2 if start < 0 else int(n == 1)] += n
+            if start < 0:
+                assert 1 <= n <= whole_run
+                assert all(len(want[p]) > 1 for p in pairs)
+            else:
+                assert 1 <= n <= run
+                row = i * rows + start // group
+                assert all(want[p] == [row] for p in pairs)
+                assert [c for c, _ in pairs] == sorted(c for c, _ in pairs)
+                if row in last_of:      # after its row's earlier columns
+                    assert last_of[row][-1][1] < pairs[0][0]
+                    assert last_of[row][-1][0] == run   # which were full
+                last_of.setdefault(row, []).append((n, pairs[-1][0]))
+            seen += pairs
+            first += n
+        assert first == int(walk.count[i])
+        assert len(set(seen)) == len(seen) and set(seen) == set(want)
+        off = np.setdiff1d(np.arange(per), sorted(heads_at))
+        assert (lens[i * per + off] == 0).all()
+    return kinds
 
 
 def check_sparse_walk(walk, parts, tables, rows, part_rows, width, per):
