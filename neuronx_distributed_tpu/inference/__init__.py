@@ -42,7 +42,7 @@ from .model_builder import (ModelBuilder, NxDModel, bundle_generate,
 from .paging import (BlockAllocator, CacheExhaustedError, LatentPagedCache,
                      PagedKVCache, PrefixCache, QuantizedPagedKVCache,
                      SparseStatePagedCache, StatePoolPagedCache,
-                     cow_copy_blocks,
+                     WindowPoolPagedCache, cow_copy_blocks,
                      init_paged_kv_cache, init_quantized_paged_kv_cache,
                      init_serving_cache)
 from .router import (FabricConfig, ReplicaRouter, RouterConfig, RouterResult,
@@ -61,7 +61,7 @@ __all__ = [
     "KVCache", "init_kv_cache",
     "BlockAllocator", "CacheExhaustedError", "PagedKVCache",
     "PrefixCache", "QuantizedPagedKVCache", "SparseStatePagedCache",
-    "LatentPagedCache", "StatePoolPagedCache",
+    "LatentPagedCache", "StatePoolPagedCache", "WindowPoolPagedCache",
     "init_serving_cache", "cow_copy_blocks",
     "init_paged_kv_cache", "init_quantized_paged_kv_cache",
     "ServingEngine", "EngineConfig", "EngineStats", "RequestRejected",

@@ -752,8 +752,22 @@ class ServingEngine:
         #: that were dropped, which the step of a family that declares
         #: ``moe_counts`` counts on the device (``cache.moe_counts``) and
         #: the fetch adds here (``nxd_moe_assignments_total``)
+        #: and, where the device holds a share of the experts (a third
+        #: count in the leaf), those that chose an expert held elsewhere
+        #: (``nxd_moe_held_total``)
         self._moe_on_device = family.moe_counts
-        self._moe_assignments = np.zeros((2,), np.int64)
+        self._moe_assignments = np.zeros((3,), np.int64)
+        #: a cache with sliding-window layers (``paging.WindowPoolCache``),
+        #: counted on the host as the rows are packed: of the columns the
+        #: rows have mapped in the full layers' table, those that hold a
+        #: position of the row's window and those wholly behind it
+        #: (``nxd_window_columns_total``), and the K/V blocks the occupied
+        #: slots hold, times the layers that hold them, in the full
+        #: layers' pool and in the window layers' rings
+        #: (``nxd_kv_blocks_held_total``)
+        self._windowed = self._cache_kind.name == "window_pool"
+        self._window_cols = np.zeros((2,), np.int64)
+        self._kv_held = np.zeros((2,), np.int64)
         #: windows a window-summary cache rolled (``nxd_eva_windows_total``)
         #: or rows at position 0 of a cache with per-slot state leaves
         #: (``paging.StateLeaf``), each of which starts a slot's states
@@ -2090,6 +2104,22 @@ class ServingEngine:
                 advanced = len({r[0].slot for r in rows})
                 self._state_slots += (advanced, sum(
                     s is not None for s in self._slots) - advanced)
+            if counted and self._windowed:
+                # by the sliding kernel's own guard: of the columns up to
+                # a row's position, those its ring still holds
+                from ..ops.paged_attention import sliding_column_live
+
+                kind, bs = self._cache_kind, self.ecfg.block_size
+                ring = kind.window_ring(bs)
+                at = positions[0][positions[0] < PAD_POSITION]
+                live = np.count_nonzero(sliding_column_live(
+                    0, np.arange(ring), at[:, None], bs, kind.window, ring))
+                self._window_cols += (live, (at // bs + 1).sum() - live)
+                held = [len(self._slot_blocks[r.slot]) for r in self._slots
+                        if r is not None]
+                self._kv_held += (
+                    kind.full_layers * sum(held),
+                    kind.window_layers * sum(min(n, ring) for n in held))
             if counted and not by_device:
                 # what the paged kernel's walk finds in this batch (a pad
                 # row attends nothing, whatever table row it is handed)
@@ -2161,7 +2191,8 @@ class ServingEngine:
             if flight.counts is not None:
                 self._paged_cols += np.asarray(flight.counts)
             if flight.moe_counts is not None:
-                self._moe_assignments += np.asarray(flight.moe_counts)
+                counts = np.asarray(flight.moe_counts)
+                self._moe_assignments[:counts.size] += counts
 
     def _maybe_insert_prefix(self, req: _RequestState) -> None:
         """Publish this request's fully-written prompt blocks into the
@@ -2724,6 +2755,44 @@ class ServingEngine:
                     "the step's tokens.",
                     labels=("kind",)).labels(kind=k)
                 for k in ("kept", "dropped"))
+            shares = (self._moe_on_device
+                      and self.cache.moe_counts.shape[0] == 3)
+            moe_held_by_kind = () if not shares else tuple(
+                reg.counter(
+                    "nxd_moe_held_total",
+                    "Routed-expert assignments of the serving workers' "
+                    "real rows by where the chosen expert is: held, among "
+                    "the experts this device holds of those the router "
+                    "scores (kept or dropped: nxd_moe_assignments_total), "
+                    "or elsewhere, on a device that shares the layer, "
+                    "where it takes no slot here and adds nothing. "
+                    "Counted on the device, fetched with the step's "
+                    "tokens.",
+                    labels=("kind",)).labels(kind=k)
+                for k in ("held", "elsewhere"))
+            window_by_kind = () if not self._windowed else tuple(
+                [reg.counter(
+                    "nxd_window_columns_total",
+                    "Table columns that the serving workers' rows have "
+                    "mapped in the full-attention layers' table (those "
+                    "not beyond the row's position) by what a "
+                    "sliding-window layer does with the same positions: "
+                    "live, the column holds a position of the row's "
+                    "window and the row's ring holds its block, or "
+                    "behind, the window has passed it and the ring has "
+                    "overwritten it.",
+                    labels=("kind",)).labels(kind=k)
+                 for k in ("live", "behind")]
+                + [reg.counter(
+                    "nxd_kv_blocks_held_total",
+                    "K/V blocks the occupied slots hold, a step, times the "
+                    "layers that hold them: full, blocks of the "
+                    "full-attention layers' pool (they grow with the "
+                    "context), or window, blocks of the slots' rings in "
+                    "the sliding-window layers' pool (at most the ring a "
+                    "slot).",
+                    labels=("kind",)).labels(kind=k)
+                   for k in ("full", "window")])
             cache = self._obs_cache = (
                 reg, reg.generation,
                 {f: stats_g.labels(field=f)
@@ -2736,10 +2805,11 @@ class ServingEngine:
                 cols_by_kind, events_c, visits_by_kind, moe_by_kind,
                 {k: steps_c.labels(kind=k)
                  for k in ("overlapped", "serial")}, state_by_kind,
-                fetches_by_kind)
+                fetches_by_kind, moe_held_by_kind, window_by_kind)
         (_, _, fields, free_g, step_h, rows_by_kind, cols_by_kind,
          events_c, visits_by_kind, moe_by_kind, steps_by_kind,
-         state_by_kind, fetches_by_kind) = cache
+         state_by_kind, fetches_by_kind, moe_held_by_kind,
+         window_by_kind) = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
@@ -2761,7 +2831,15 @@ class ServingEngine:
         self._mla_fetches[:] = 0
         for child, n in zip(moe_by_kind, self._moe_assignments):
             child.inc(int(n))
+        kept, dropped, elsewhere = self._moe_assignments
+        for child, n in zip(moe_held_by_kind, (kept + dropped, elsewhere)):
+            child.inc(int(n))
         self._moe_assignments[:] = 0
+        for child, n in zip(window_by_kind, (*self._window_cols,
+                                             *self._kv_held)):
+            child.inc(int(n))
+        self._window_cols[:] = 0
+        self._kv_held[:] = 0
         if events_c is not None:
             events_c.inc(self._kind_events)
         self._kind_events = 0

@@ -323,6 +323,78 @@ class StatePoolCache(FullCache):
 
 
 @dataclasses.dataclass(frozen=True)
+class WindowPoolCache(FullCache):
+    """Two kinds of block in one cache, for a model whose layers are
+    full-attention or sliding-window. The ``full_layers`` keep every
+    position: a K/V pool of their own under the one block table and the
+    one allocator, :class:`FullCache`'s in every respect (position ``p`` in
+    column ``p // block_size``; mapping, admission, preemption,
+    copy-on-write and the freed-position hygiene count and touch these
+    blocks alone). The ``window_layers`` attend the last ``window``
+    positions and keep no others: a second pool in which a slot owns a
+    ring of ``window_ring = window / block_size + 1`` blocks for as long
+    as it is a slot (blocks ``slot * ring .. slot * ring + ring - 1``,
+    block ``b`` of the sequence in ring column ``b % ring``; the spare
+    block lets a packed chunk of up to ``block_size`` rows overwrite only
+    rows that no row of the same step still attends). A ring is no
+    allocation: it never exhausts, the host maps, releases and clears
+    nothing of it, and a request that takes a slot over (admission, or
+    re-admission after preemption) overwrites it from position 0. Stale
+    ring rows hold positions behind every window that can see them, or a
+    former tenant's positions beyond the new one's, and fall to the
+    attention mask by their stored positions
+    (:func:`..ops.paged_attention.paged_attention`, ``sliding``). So the
+    window layers cost ``ring`` blocks a slot whatever the context, where
+    one table for all layers would cost them the context's."""
+
+    full_layers: int = 0
+    window_layers: int = 0
+    window: int = 0
+    name = "window_pool"
+
+    def geometry(self, block_size: int, step_rows: int = 0
+                 ) -> "WindowPoolCache":
+        if self.window % block_size:
+            raise ValueError(
+                f"window_pool cache: a window of {self.window} positions "
+                f"is whole blocks; got block_size {block_size}")
+        if step_rows > block_size:
+            raise ValueError(
+                f"window_pool cache: a packed step of {step_rows} rows "
+                f"overwrites ring rows that its first rows still attend; "
+                f"token_budget must not exceed block_size ({block_size})")
+        return self
+
+    def window_ring(self, block_size: int) -> int:
+        """Blocks of a slot's ring in the window pool."""
+        return self.window // block_size + 1
+
+    def init_cache(self, model_cfg, *, num_blocks: int, block_size: int,
+                   table_rows: int, max_blocks_per_seq: int, dtype: Any,
+                   quantized: bool = False) -> "WindowPoolPagedCache":
+        if quantized:
+            raise ValueError("a window_pool cache has no int8 pool: the "
+                             "ring's rows want scales of their own")
+        self.geometry(block_size)
+        kv, d = model_cfg.num_kv_heads, model_cfg.head_dim_
+        ring_blocks = table_rows * self.window_ring(block_size)
+        full = (self.full_layers, num_blocks, block_size, kv, d)
+        ring = (self.window_layers, ring_blocks, block_size, kv, d)
+        return WindowPoolPagedCache(
+            k=jnp.zeros(full, dtype), v=jnp.zeros(full, dtype),
+            wk=jnp.zeros(ring, dtype), wv=jnp.zeros(ring, dtype),
+            wpos=jnp.full((ring_blocks, block_size), PAD_POSITION,
+                          jnp.int32),
+            moe_counts=(jnp.zeros((3,), jnp.int32)
+                        if model_cfg.serving_family().moe_counts else None),
+            pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
+            block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
+                                  jnp.int32),
+            lengths=jnp.zeros((table_rows,), jnp.int32),
+            block_size=block_size)
+
+
+@dataclasses.dataclass(frozen=True)
 class ServingFamily:
     """What :class:`.engine.ServingEngine` asks of a model config
     (``model_cfg.serving_family()``): the cached forward with the
@@ -331,10 +403,12 @@ class ServingFamily:
     each with why (refused by name at construction: ``prefix_sharing``,
     ``speculation``, ``cp``, ``quantized``, ``session_export``).
     ``moe_counts``: the family's forward leaves in the cache's
-    ``moe_counts [2]`` the routed-expert assignments of the step's real
-    rows that were kept and that were dropped, which the engine fetches
-    with the step's tokens (``nxd_moe_assignments_total``); the family's
-    cache kind builds the leaf."""
+    ``moe_counts`` the routed-expert assignments of the step's real
+    rows that were kept and that were dropped (``[2]``; ``[3]`` where
+    the device holds a share of the experts: and those that chose an
+    expert held elsewhere), which the engine fetches with the step's
+    tokens (``nxd_moe_assignments_total``, ``nxd_moe_held_total``); the
+    family's cache kind builds the leaf."""
 
     forward: Callable
     cache_kind: Any = FULL_CACHE
@@ -437,6 +511,34 @@ class StatePoolPagedCache(_BlockPool, struct.PyTreeNode):
     POOL_LEAVES = ("k", "v")
 
 
+class WindowPoolPagedCache(_BlockPool, struct.PyTreeNode):
+    """The cache of :class:`WindowPoolCache`. ``k``/``v`` ``[Lf,
+    num_blocks, block_size, KV, D]`` over the ``Lf`` full-attention
+    layers, with ``pos``, ``block_tables`` and ``lengths`` as
+    :class:`PagedKVCache`; ``wk``/``wv`` ``[Lw, table rows * ring,
+    block_size, KV, D]`` over the ``Lw`` sliding-window layers, a table
+    row's ring the blocks ``row * ring ..`` (no table: a ring is its
+    slot's), and ``wpos [table rows * ring, block_size]`` the positions
+    its rows hold; ``moe_counts [3]`` where the family declares it
+    (:class:`ServingFamily`; else None)."""
+
+    k: jax.Array
+    v: jax.Array
+    wk: jax.Array
+    wv: jax.Array
+    wpos: jax.Array
+    moe_counts: Optional[jax.Array]
+    pos: jax.Array
+    block_tables: jax.Array
+    lengths: jax.Array
+    block_size: int = struct.field(pytree_node=False, default=128)
+    POOL_LEAVES = ("k", "v")
+
+    @property
+    def window_ring(self) -> int:
+        return self.wk.shape[1] // self.block_tables.shape[0]
+
+
 class SparseStatePagedCache(struct.PyTreeNode):
     """The cache of :class:`SparseStateCache`. ``k``/``v`` ``[Ls,
     num_blocks, KV, block_size, D]`` over the ``Ls`` block-sparse layers
@@ -495,7 +597,10 @@ class PagedCacheView(struct.PyTreeNode):
     of the step (:func:`..ops.paged_attention.step_walk`: which pool
     blocks each tile of rows fetches; None where the XLA path serves).
     ``roll`` is the routing of the summaries a window-summary family
-    writes in this step (:func:`window_roll`; None for a full cache)."""
+    writes in this step (:func:`window_roll`; None for a full cache).
+    ``sliding`` is the causal window of a sliding-window layer, whose
+    ``k``/``v``, ``pos``, ``tables`` and ``write_idx`` are then its ring
+    pool's (:func:`ring_write_indices`; None: every earlier position)."""
 
     k: jax.Array
     v: jax.Array
@@ -507,6 +612,7 @@ class PagedCacheView(struct.PyTreeNode):
     write_idx: jax.Array
     walk: Any = None
     roll: Any = None
+    sliding: Optional[int] = struct.field(pytree_node=False, default=None)
 
 
 class SparseLayerView(struct.PyTreeNode):
@@ -676,7 +782,8 @@ def init_serving_cache(model_cfg, *, num_blocks: int, block_size: int,
     :func:`init_quantized_paged_kv_cache`'s) pytree; a
     :class:`SparseStateCache` is a :class:`SparseStatePagedCache`, a
     :class:`LatentCache` a :class:`LatentPagedCache`, a
-    :class:`StatePoolCache` a :class:`StatePoolPagedCache`."""
+    :class:`StatePoolCache` a :class:`StatePoolPagedCache`, a
+    :class:`WindowPoolCache` a :class:`WindowPoolPagedCache`."""
     return model_cfg.serving_family().cache_kind.init_cache(
         model_cfg, num_blocks=num_blocks, block_size=block_size,
         table_rows=table_rows, max_blocks_per_seq=max_blocks_per_seq,
@@ -840,6 +947,26 @@ def flat_write_indices(tok_tables: jax.Array, positions: jax.Array,
     flat = blk * block_size + positions % block_size
     valid = (positions < PAD_POSITION) & (blk_of_pos < maxb) & (blk >= 0)
     return jnp.where(valid, flat, capacity)
+
+
+def ring_write_indices(slot_ids: jax.Array, positions: jax.Array,
+                       block_size: int, ring: int, table_rows: int):
+    """``(tables [T, ring], flat [T])`` of a sliding-window layer's pool
+    (:class:`WindowPoolCache`) for the packed rows: a row's ring (block
+    ``slot * ring + c`` in column ``c``; -1 for a row without a slot) and
+    the flat index within a layer of its own K/V row (position ``p`` in
+    ring column ``(p // block_size) % ring``, slot ``p % block_size``;
+    the pool's capacity, which the scatters drop, for a pad row)."""
+    real = ((positions < PAD_POSITION) & (slot_ids >= 0)
+            & (slot_ids < table_rows))
+    base = jnp.where(real, slot_ids, 0) * ring
+    tables = jnp.where(
+        real[:, None], base[:, None] + jnp.arange(ring, dtype=jnp.int32),
+        -1)
+    safe = jnp.where(real, positions, 0)
+    flat = ((base + (safe // block_size) % ring) * block_size
+            + safe % block_size)
+    return tables, jnp.where(real, flat, table_rows * ring * block_size)
 
 
 def write_pool_rows(pool: jax.Array, rows: jax.Array,

@@ -436,7 +436,7 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
         view.layer, k_scale=new_ks, v_scale=new_vs,
         scale=cfg.attn_scale_,
         force_pallas=cfg.attn_force_pallas,
-        combine_axis=combine, walk=view.walk)[None]
+        combine_axis=combine, walk=view.walk, sliding=view.sliding)[None]
     new_view = view.replace(k=new_k, v=new_v, k_scale=new_ks,
                             v_scale=new_vs)
     return out.astype(cfg.dtype), new_view

@@ -66,12 +66,45 @@ def precompute_rope(head_dim: int, max_len: int, theta: float = 10000.0,
     return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
 
 
-def rope_rows(positions: jax.Array, head_dim: int, theta: float):
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """YaRN's inverse frequencies (arXiv:2309.00071, the checkpoints'
+    ``rope_type: yarn``) over ``dim`` rotated values, ``[dim // 2]``
+    float32: with ``f_i = theta^(-2i / dim)`` the pairs that turn more
+    than ``beta_fast`` times in ``original_max_position`` positions keep
+    ``f_i``, those that turn fewer than ``beta_slow`` times take ``f_i /
+    factor``, and a linear ramp over the pair's index joins the two:
+    ``lo = floor(dim ln(original / (beta_fast 2 pi)) / (2 ln theta))``,
+    ``hi = ceil(dim ln(original / (beta_slow 2 pi)) / (2 ln theta))``,
+    ``r_i = clip((i - lo) / (hi - lo), 0, 1)``, ``inv_freq_i = (f_i /
+    factor) r_i + f_i (1 - r_i)``. The ``attention_factor`` that goes
+    with it (``0.1 ln(factor) + 1`` by default) multiplies cos and sin,
+    and is the caller's."""
+    import numpy as np
+
+    def turns_at(rotations):
+        return (dim * math.log(original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(turns_at(beta_fast)), 0)
+    hi = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0, 1)
+    return jnp.asarray(f / factor * ramp + f * (1 - ramp), jnp.float32)
+
+
+def rope_rows(positions: jax.Array, head_dim: int, theta: float,
+              inv_freq: Optional[jax.Array] = None):
     """cos and sin ``[T, head_dim // 2]`` at ``positions [T]``: the rows of
     :func:`precompute_rope`'s tables, without a table
-    of ``max_position_embeddings`` (524,288) rows."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
-                                           dtype=jnp.float32) / head_dim))
+    of ``max_position_embeddings`` (524,288) rows. ``inv_freq``
+    (``[head_dim // 2]``) replaces the plain ``theta^(-2i / head_dim)``
+    (:func:`yarn_inv_freq`)."""
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(
+            0, head_dim, 2, dtype=jnp.float32) / head_dim))
     freqs = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     return jnp.cos(freqs), jnp.sin(freqs)
 

@@ -44,6 +44,7 @@ def compute_capacity(num_tokens: int, num_experts: int, top_k: int,
 def build_dispatch_combine(
     gates: jax.Array, idx: jax.Array, num_experts: int, capacity: int,
     valid: Optional[jax.Array] = None,
+    held: Optional[Tuple[int, int]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Capacity-limited dispatch/combine masks.
 
@@ -53,8 +54,16 @@ def build_dispatch_combine(
     reference's capacity-factor semantics). Rows that ``valid [T]`` (bool)
     marks false choose nothing: they take no slot and count neither as
     kept nor as dropped.
+
+    ``held = (first, count)``: ``idx`` are choices among all the experts
+    the router scores and the masks are over the ``count == num_experts``
+    experts from ``first`` on that this device holds: a choice of an
+    expert held elsewhere takes no slot, adds nothing and counts neither
+    as kept nor as dropped.
     """
     t, k = idx.shape
+    if held is not None:
+        return _held_dispatch_combine(gates, idx, capacity, valid, held)
     choice = jax.nn.one_hot(idx, num_experts, dtype=jnp.float32)  # [T,K,E]
     if valid is not None:
         choice = choice * valid.astype(jnp.float32)[:, None, None]
@@ -71,6 +80,33 @@ def build_dispatch_combine(
     return dispatch, combine, dropped
 
 
+def _held_dispatch_combine(gates, idx, capacity, valid, held):
+    """:func:`build_dispatch_combine` over the held experts. A choice has
+    one expert and one slot, so the masks are two small one-hots' product
+    (``[T, K, E]`` by ``[T, K, C]``) and never ``[T, K, E, C]``: 84 MB a
+    layer at 128 rows, top-10 and 128 experts of capacity 128."""
+    first, count = held
+    local = idx - first
+    mine = (local >= 0) & (local < count)
+    if valid is not None:
+        mine = mine & valid[:, None]
+    # one_hot of an index out of range is zeros: no slot, no weight
+    choice = jax.nn.one_hot(jnp.where(mine, local, count), count,
+                            dtype=jnp.float32)                  # [T,K,E]
+    t, k = idx.shape
+    flat = jnp.transpose(choice, (1, 0, 2)).reshape(k * t, count)
+    pos = jnp.transpose((jnp.cumsum(flat, axis=0) - flat).reshape(
+        k, t, count), (1, 0, 2))
+    at = jnp.sum(pos * choice, axis=-1).astype(jnp.int32)       # [T,K]
+    keep = choice * (at < capacity)[:, :, None]
+    slot = jax.nn.one_hot(jnp.minimum(at, capacity - 1), capacity,
+                          dtype=jnp.float32)                    # [T,K,C]
+    dispatch = jnp.einsum("tke,tkc->tec", keep, slot)
+    combine = jnp.einsum("tk,tke,tkc->tec", gates, keep, slot)
+    dropped = 1.0 - jnp.sum(keep) / jnp.maximum(jnp.sum(choice), 1.0)
+    return dispatch, combine, dropped
+
+
 class ExpertMLPs(nn.Module):
     """Stacked GLU expert MLPs with capacity-factor dispatch, TP- and
     EP-sharded."""
@@ -79,7 +115,8 @@ class ExpertMLPs(nn.Module):
     hidden_size: int
     intermediate_size: int
     top_k: int = 2
-    capacity_factor: float = 2.0
+    # None: an expert's capacity is the step's rows, so nothing can drop
+    capacity_factor: Optional[float] = 2.0
     # "capacity" (mask-einsum, may drop) or "blockwise" (dropless Pallas
     # grouped matmul, reference expert_mlps_v2.py:691)
     dispatch_mode: str = "capacity"
@@ -94,6 +131,11 @@ class ExpertMLPs(nn.Module):
     # decomposed (ppermute-ring) EP dispatch overlapping per-chunk expert
     # compute with later hops; None = auto (ep >= MIN_AUTO_AXIS_SIZE)
     ep_overlap: Optional[bool] = None
+    # ``(first, count)`` of the experts ``idx`` chooses among that this
+    # bank holds (``count == num_experts``; None: ``idx`` are the bank's
+    # own). Capacity dispatch under ``valid`` rows, no ep axis: the
+    # exchange between devices that share a layer is not built
+    held: Optional[Tuple[int, int]] = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     tp_axis: str = ps.TP_AXIS
@@ -107,7 +149,8 @@ class ExpertMLPs(nn.Module):
         ``valid [T]`` (bool; capacity dispatch, no ep axis) marks the real
         rows of a packed serving step: a pad row takes no expert's slot,
         and ``aux`` then holds ``assignments``, the real rows' ``[kept,
-        dropped]`` int32."""
+        dropped]`` int32 (with ``held``, ``[kept, dropped, elsewhere]``:
+        ``dropped`` of the held experts' alone)."""
         t = x.shape[0]
         e_local = pl._maybe_local(self.num_experts, self.ep_axis)
         i_local = pl._maybe_local(self.intermediate_size, self.tp_axis)
@@ -128,6 +171,11 @@ class ExpertMLPs(nn.Module):
             raise ValueError("ExpertMLPs: valid rows are threaded through "
                              "the capacity dispatch without an ep axis "
                              "alone")
+        if self.held is not None and (valid is None
+                                      or self.held[1] != self.num_experts):
+            raise ValueError("ExpertMLPs: held=(first, count) is a bank of "
+                             "count experts under the packed step's valid "
+                             "rows")
         if self.dispatch_mode == "blockwise":
             if ep is not None and ep > 1:
                 return self._forward_blockwise_ep(x, gates, idx, gate_up,
@@ -138,10 +186,10 @@ class ExpertMLPs(nn.Module):
             raise ValueError(
                 f"unknown dispatch_mode {self.dispatch_mode!r}")
 
-        capacity = compute_capacity(t, self.num_experts, self.top_k,
-                                    self.capacity_factor)
+        capacity = t if self.capacity_factor is None else compute_capacity(
+            t, self.num_experts, self.top_k, self.capacity_factor)
         dispatch, combine, dropped = build_dispatch_combine(
-            gates, idx, self.num_experts, capacity, valid)
+            gates, idx, self.num_experts, capacity, valid, self.held)
 
         xin = jnp.einsum("tec,th->ech", dispatch.astype(self.dtype),
                          x.astype(self.dtype))  # [E, C, H]
@@ -173,7 +221,14 @@ class ExpertMLPs(nn.Module):
             # every kept assignment holds exactly one slot
             kept = jnp.sum(dispatch).astype(jnp.int32)
             asked = jnp.sum(valid).astype(jnp.int32) * idx.shape[1]
-            aux["assignments"] = jnp.stack([kept, asked - kept])
+            if self.held is None:
+                aux["assignments"] = jnp.stack([kept, asked - kept])
+            else:
+                first, count = self.held
+                mine = jnp.sum(valid[:, None] & (idx >= first)
+                               & (idx < first + count)).astype(jnp.int32)
+                aux["assignments"] = jnp.stack(
+                    [kept, mine - kept, asked - mine])
         return y.astype(self.dtype), aux
 
     def _run_grouped_glu(self, xs, gate_up, down, be, i_local):

@@ -61,7 +61,8 @@ class MoE(nn.Module):
     hidden_size: int
     intermediate_size: int
     top_k: int = 2
-    capacity_factor: float = 2.0
+    # None: an expert's capacity is the step's rows (float experts)
+    capacity_factor: Optional[float] = 2.0
     dispatch_mode: str = "capacity"  # or "blockwise" (dropless)
     block_size: int = 512
     sentinel_empty: bool = False  # decode: DMA-elide unhit experts
@@ -73,9 +74,16 @@ class MoE(nn.Module):
     # (packed microscaling weights, quantization.mx_layers.MXExpertMLPs)
     expert_impl: str = "float"
     router_type: str = "top_k"
-    # the sigmoid router's ``routed_scaling_factor``
+    # what the chosen experts' weights are multiplied by (a checkpoint's
+    # ``routed_scaling_factor``; the sigmoid and the top-k router)
     router_scale: float = 1.0
     shared_expert_intermediate: int = 0
+    # ``(first, count)``: the experts this device holds of the
+    # ``num_experts`` the router scores (None: all of them). The bank has
+    # ``count`` experts, an assignment to an expert held elsewhere takes
+    # no slot and adds nothing, the shared expert is whole; capacity
+    # dispatch of float experts, no ep axis (:class:`ExpertMLPs`)
+    held: Optional[Tuple[int, int]] = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -85,7 +93,8 @@ class MoE(nn.Module):
         """``valid`` (bool, ``x``'s shape without its last dimension; float
         experts only) marks the real rows of a packed serving step: the
         others take no expert's slot and ``aux["assignments"]`` counts the
-        real rows' ``[kept, dropped]``."""
+        real rows' ``[kept, dropped]`` (with ``held``: ``[kept, dropped,
+        elsewhere]``)."""
         orig_shape = x.shape
         h = self.hidden_size
         flat = x.reshape(-1, h)
@@ -95,8 +104,12 @@ class MoE(nn.Module):
                          param_dtype=self.param_dtype, name="router")
         if self.router_type != "sinkhorn":
             router_kw["top_k"] = self.top_k
-        if self.router_type == "sigmoid":
+        if self.router_type == "sigmoid" or self.router_scale != 1.0:
             router_kw["scale"] = self.router_scale
+        if self.held is not None and (self.expert_impl != "float"
+                                      or valid is None):
+            raise ValueError("MoE: a share of the experts (held) is float "
+                             "experts under the packed step's valid rows")
         with device_scope("ffn.router"):
             gates, idx, aux = router_cls(**router_kw)(flat)
 
@@ -143,7 +156,8 @@ class MoE(nn.Module):
             raise ValueError(f"unknown expert_impl {self.expert_impl!r}")
         else:
             experts = ExpertMLPs(
-                num_experts=self.num_experts, hidden_size=h,
+                num_experts=(self.num_experts if self.held is None
+                             else self.held[1]), hidden_size=h,
                 intermediate_size=self.intermediate_size,
                 top_k=gates.shape[-1], capacity_factor=self.capacity_factor,
                 dispatch_mode=self.dispatch_mode,
@@ -152,7 +166,7 @@ class MoE(nn.Module):
                 ep_wire_dtype=self.ep_wire_dtype,
                 ep_overlap=self.ep_overlap,
                 dtype=self.dtype, param_dtype=self.param_dtype,
-                name="experts")
+                held=self.held, name="experts")
         # the routed experts: dispatch, the bank's products, combine
         with device_scope("ffn.experts"):
             if valid is None:

@@ -53,11 +53,13 @@ class RouterTopK(RouterBase):
     """Top-k softmax router (reference ``RouterTopK:155``).
 
     Returns ``(gates [T, k], indices [T, k], aux)`` where gates are the
-    renormalised top-k probabilities.
+    renormalised top-k probabilities, times ``scale`` (a checkpoint's
+    ``moe_routed_scaling_factor``).
     """
 
     top_k: int = 2
     norm_topk: bool = True
+    scale: float = 1.0
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array, Dict]:
@@ -67,6 +69,8 @@ class RouterTopK(RouterBase):
         if self.norm_topk:
             gates = gates / jnp.maximum(
                 jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+        if self.scale != 1.0:
+            gates = gates * self.scale
         mask = jnp.sum(jax.nn.one_hot(idx, self.num_experts,
                                       dtype=jnp.float32), axis=1)
         aux = {"load_balance_loss": _load_balance_loss(probs, mask),

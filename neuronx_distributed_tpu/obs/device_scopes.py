@@ -43,7 +43,8 @@ UNSCOPED = "(unscoped)"
 #: every scope the program opens; a dotted name is a child of its stem
 SCOPES = (
     "embed", "norm",
-    "attn", "attn.proj", "attn.kernel", "attn.walk", "attn.select",
+    "attn", "attn.proj", "attn.kernel", "attn.kernel.full",
+    "attn.kernel.window", "attn.walk", "attn.select",
     "attn.state", "attn.conv", "attn.summarise", "attn.pool_write",
     "ffn", "ffn.dense", "ffn.router", "ffn.experts", "ffn.shared",
     "head", "sample",

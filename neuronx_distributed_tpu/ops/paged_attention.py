@@ -39,9 +39,10 @@ Two implementations behind one signature, following
   The walk (:func:`tile_walk`) lists, a tile, the distinct pairs that
   are live for at least one of its rows, each once. Live is
   :func:`column_live` (a window-summary cache:
-  :func:`window_column_kinds`): an unmapped column, one wholly behind
-  the row's position and every column of a pad row belong to no pair,
-  and a pad row's output is zero. Membership is by the row's own table
+  :func:`window_column_kinds`; a sliding-window layer's ring:
+  :func:`sliding_column_live`): an unmapped column, one wholly beyond
+  the row's position (or wholly behind its window) and every column of
+  a pad row belong to no pair, and a pad row's output is zero. Membership is by the row's own table
   entry, not by its slot: two slots that share a prefix block share its
   fetch. The walk follows the tables and positions alone, so the cached
   forward builds it once a step beside the write indices
@@ -89,7 +90,7 @@ logger = get_logger(__name__)
 
 def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos, layer,
                          k_scale, v_scale, scale, combine_axis=None,
-                         window=None):
+                         window=None, sliding=None):
     t, n, d = q.shape
     _, nb, bs, kv, _ = k_pool.shape
     n_rep = n // kv
@@ -109,7 +110,12 @@ def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos, layer,
     pg = pg.reshape(t, length)
     scores = jnp.einsum("bqnd,bknd->bnqk", q[:, None].astype(jnp.float32),
                         k_full.astype(jnp.float32)) * scale
-    if window is None:
+    if sliding is not None:
+        # a causal window: the last ``sliding`` positions and no others (a
+        # ring's stale rows are earlier ones, or a later tenant's)
+        mask = ((q_pos[:, None] >= pg) & (q_pos[:, None] - pg < sliding)
+                )[:, None, None, :]
+    elif window is None:
         mask = q_pos[:, None, None, None] >= pg[:, None, None, :]
     else:
         # two kinds of row under two masks: an exact row counts if it is
@@ -187,11 +193,30 @@ def window_column_kinds(entry, column, q_pos, block_size: int, window: int,
     return (real & exact) * 1 + (real & summary) * 2
 
 
+def sliding_column_live(entry, column, q_pos, block_size: int, sliding: int,
+                        ring: int):
+    """Whether ring column ``column`` (holding block id ``entry``) of a
+    sliding-window layer's table
+    (:class:`..inference.paging.WindowPoolCache`) holds a position a row
+    at ``q_pos`` attends: the blocks of positions ``q_pos - sliding + 1 ..
+    q_pos`` (block ``b`` in column ``b % ring``), of a row that is no
+    padding. Broadcasts over jnp arrays (the kernel's walk) and NumPy ones
+    (the engine's ``nxd_window_columns_total``, the tests)."""
+    first = (q_pos - sliding + 1).clip(0) // block_size
+    held = q_pos // block_size - first + 1           # 1 .. ring
+    return ((entry >= 0) & (q_pos < PAD_POSITION)
+            & ((column - first) % ring < held))
+
+
 def tile_rows(n_rep: int, tokens: int) -> int:
     """Rows of a tile of the kernel: with the ``n_rep`` query heads of a
     K/V head stacked, about the MXU's 128 rows (32 under GQA-4, 128 under
-    MHA), whole sublanes, and no more than the packed rows there are."""
-    return min(max(8, 128 // n_rep), -(-tokens // 8) * 8)
+    MHA, 20 under GQA-6, 8 under GQA-9) and whole sublanes of them, and
+    no more than the packed rows there are."""
+    rows = max(8, 128 // n_rep)
+    while rows * n_rep % 8:
+        rows -= 1
+    return min(rows, -(-tokens // 8) * 8)
 
 
 def narrow_rows(n_rep: int) -> int:
@@ -243,9 +268,10 @@ class TileWalk(NamedTuple):
     once a query head of a K/V head (row ``r * n_rep + rep`` of the tile
     is packed row ``r``'s head ``rep``): ``served [tiles, rows * n_rep,
     max_blocks_per_seq]`` the row's table entry in the columns it
-    attends, -1 elsewhere; ``q_pos [tiles, rows * n_rep, 1]``; and for a
-    window-summary cache ``q_lo``, the first position of the row's window
-    (else ``None``)."""
+    attends, -1 elsewhere; ``q_pos [tiles, rows * n_rep, 1]``; and
+    ``q_lo``, the first position the row attends exactly: of its window
+    (a window-summary cache) or ``q_pos - sliding + 1`` (a sliding-window
+    layer), else ``None``."""
 
     count: jax.Array
     blocks: jax.Array
@@ -257,12 +283,14 @@ class TileWalk(NamedTuple):
 
 
 def tile_walk(tables, q_pos, block_size: int, num_blocks: int, n_rep: int,
-              window=None) -> TileWalk:
+              window=None, sliding=None) -> TileWalk:
     """The walk of one packed step, from ``tables [T, max_blocks_per_seq]``
     and ``q_pos [T]`` alone: routing, built once a step beside the write
     indices and handed to every layer's kernel. ``T`` is padded to whole
     tiles with pad rows. A pad row attends nothing and belongs to no
-    pair."""
+    pair. With ``sliding`` the tables are a sliding-window layer's rings
+    (:func:`sliding_column_live`): a column wholly behind the window of
+    every row of a tile is no pair."""
     t, maxb = tables.shape
     rows = tile_rows(n_rep, t)
     pad = -t % rows
@@ -271,7 +299,10 @@ def tile_walk(tables, q_pos, block_size: int, num_blocks: int, n_rep: int,
     q_pos = jnp.pad(q_pos.astype(jnp.int32), (0, pad),
                     constant_values=PAD_POSITION)
     cols = jnp.arange(maxb, dtype=jnp.int32)[None, :]
-    if window is None:
+    if sliding is not None:
+        live = sliding_column_live(tables, cols, q_pos[:, None], block_size,
+                                   sliding, maxb)
+    elif window is None:
         live = column_live(tables, cols, q_pos[:, None], block_size)
     else:
         live = window_column_kinds(tables, cols, q_pos[:, None], block_size,
@@ -297,19 +328,21 @@ def tile_walk(tables, q_pos, block_size: int, num_blocks: int, n_rep: int,
         count=count, blocks=blocks.reshape(-1), cols=pair_cols.reshape(-1),
         narrow=narrow.reshape(-1),
         served=by_tile(served), q_pos=by_tile(q_pos[:, None]),
-        q_lo=None if window is None else by_tile(
-            ((q_pos // window[0]) * window[0])[:, None]))
+        q_lo=(by_tile((q_pos - sliding + 1)[:, None]) if sliding is not None
+              else None if window is None else by_tile(
+                  ((q_pos // window[0]) * window[0])[:, None])))
 
 
 def step_walk(tables, q_pos, block_size: int, num_blocks: int, head_dim: int,
-              n_rep: int, window=None, force_pallas: Optional[bool] = None
-              ) -> Optional[TileWalk]:
+              n_rep: int, window=None, force_pallas: Optional[bool] = None,
+              sliding=None) -> Optional[TileWalk]:
     """:func:`tile_walk` for the layers of one step, or ``None`` where
     :func:`paged_attention` runs the XLA reference for these shapes
     (:func:`paged_attention_impl`)."""
     if paged_attention_impl(head_dim, block_size, force_pallas) == "xla":
         return None
-    return tile_walk(tables, q_pos, block_size, num_blocks, n_rep, window)
+    return tile_walk(tables, q_pos, block_size, num_blocks, n_rep, window,
+                     sliding)
 
 
 def _head_rows(block_ref):
@@ -394,7 +427,9 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
     kernel) the rows of columns under the ring's width are exact and
     count from the first position of the row's window to the row's own,
     those of the columns from there on are summaries and count whole; one
-    softmax runs over both."""
+    softmax runs over both. A sliding-window layer (``swa_attention``) is
+    that with a ring as wide as the table, so no column is a summary, and
+    the window's first position the row's own less the window."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -509,7 +544,7 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
 
 def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
                             layer, k_scale, v_scale, scale, interpret=False,
-                            window=None, walk=None):
+                            window=None, walk=None, sliding=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -519,7 +554,11 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
     n_rep = n // kv
     quantized = k_scale is not None
     if walk is None:
-        walk = tile_walk(tables, q_pos, bs, nb, n_rep, window)
+        walk = tile_walk(tables, q_pos, bs, nb, n_rep, window, sliding)
+    if sliding is not None:
+        # the window-summary kernel's exact rows, and no summary column:
+        # a row counts from ``q_lo`` to the query's own position
+        window = (sliding, maxb)
     tiles, wide, _ = walk.served.shape          # wide = rows * n_rep
     rows = wide // n_rep
     pairs = rows * maxb
@@ -578,7 +617,8 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
         out_shape=jax.ShapeDtypeStruct((tiles, kv, wide, d), q.dtype),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
-        name="paged_attention" if window is None else "eva_attention",
+        name=("swa_attention" if sliding is not None else "paged_attention"
+              if window is None else "eva_attention"),
     )(walk.count, walk.blocks, walk.cols, walk.narrow,
       jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     return out.reshape(tiles, kv, rows, n_rep, d).swapaxes(1, 2).reshape(
@@ -636,7 +676,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     force_pallas: Optional[bool] = None,
                     combine_axis: Optional[str] = None,
                     window: Optional[tuple] = None,
-                    walk: Optional[TileWalk] = None) -> jax.Array:
+                    walk: Optional[TileWalk] = None,
+                    sliding: Optional[int] = None) -> jax.Array:
     """Paged decode attention.
 
     ``q [T, N, D]`` one query row per packed token; ``k_pool``/``v_pool``
@@ -666,6 +707,15 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     each, attended whole, under the one softmax. The kernel is then named
     ``eva_attention`` in a device trace. Not with ``combine_axis``.
 
+    ``sliding``: the causal window of a sliding-window layer
+    (:class:`..inference.paging.WindowPoolCache`): ``tables`` are then the
+    rows' rings (block ``b`` of a sequence in column ``b % ring``, every
+    column mapped), ``pool_pos`` the window pool's, and a row attends the
+    positions ``q_pos - sliding < p <= q_pos`` and no others: a ring's
+    stale rows fall to the mask by their stored positions. The kernel is
+    then named ``swa_attention`` in a device trace. Not with ``window``,
+    ``combine_axis`` or an int8 pool.
+
     ``walk``: the kernel's routing of this step (:func:`step_walk`), built
     once for all layers; built here when not given.
     """
@@ -694,7 +744,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         out = paged_attention(
             wide, k_pool, v_pool, pool_pos, tables, q_pos, layer,
             scale=scale_, force_pallas=force_pallas,
-            combine_axis=combine_axis, window=window, walk=walk)
+            combine_axis=combine_axis, window=window, walk=walk,
+            sliding=sliding)
         return jnp.take_along_axis(
             out.reshape(t, n, pack, d), share[None, :, None, None],
             axis=2)[:, :, 0]
@@ -709,6 +760,11 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                                or k_scale is not None):
         raise ValueError("a window-summary cache serves neither a cp-"
                          "sharded nor an int8 pool")
+    if sliding is not None and (window is not None or k_scale is not None
+                                or combine_axis is not None):
+        raise ValueError("a sliding-window layer is served from a float "
+                         "ring of its own: no window-summary cache, int8 "
+                         "pool or cp-sharded pool")
     if combine_axis is not None:
         # the CP merge lives in XLA-land (collectives between the local
         # gather and the normalisation); the kernel path has no axis
@@ -719,8 +775,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     if impl == "xla":
         return _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables,
                                     q_pos, layer, k_scale, v_scale, scale_,
-                                    window=window)
+                                    window=window, sliding=sliding)
     return _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables,
                                    q_pos, layer, k_scale, v_scale, scale_,
                                    interpret=impl == "pallas-interpret",
-                                   window=window, walk=walk)
+                                   window=window, walk=walk, sliding=sliding)

@@ -993,6 +993,113 @@ def test_state_pool_step_at_the_published_widths(chip, topo, on_one_chip,
     assert sum(d[3] for d in top) <= 0.02 * total, top
 
 
+# -- the window-pool cache (Laguna-S-2.1): two pools, two head counts -----------
+# The cell laguna-s-2.1.serve-agentic: 128 rows, 64 slots; 2 full layers of
+# 48 heads over a pool of 160 columns a slot, 3 sliding layers of 72 heads
+# over rings of 5 blocks a slot, 8 K/V heads of 128 under both; 128 of 256
+# experts held, half the vocabulary.
+
+@pytest.mark.parametrize("n_rep,cols,sliding", [(6, 160, None), (9, 5, 512)],
+                         ids=["full-48-heads", "sliding-72-heads"])
+def test_paged_attention_at_two_head_counts(chip, n_rep, cols, sliding):
+    """The kernel at ``n_rep`` 6 (tiles of 20 rows) and, with a causal
+    window over a slot's ring, at ``n_rep`` 9 (tiles of 8 rows), which is
+    then ``swa_attention`` in a trace."""
+    from neuronx_distributed_tpu.ops.paged_attention import (
+        _paged_attention_pallas, tile_rows)
+
+    tokens, kv, d, bs, layers = 128, 8, 128, 128, 2
+    nb = 320 if sliding else 3072
+    assert tile_rows(n_rep, tokens) * n_rep in (120, 72)
+    pool = chip((layers, nb, bs, kv, d), jnp.bfloat16)
+    fn = functools.partial(_paged_attention_pallas,
+                           scale=1.0 / math.sqrt(d), interpret=False,
+                           sliding=sliding)
+    text = _assert_kernel_compiles(
+        fn, chip((tokens, kv * n_rep, d), jnp.bfloat16), pool, pool,
+        chip((nb, bs), jnp.int32), chip((tokens, cols), jnp.int32),
+        chip((tokens,), jnp.int32), chip((), jnp.int32), None, None)
+    assert _kernel_instruction_names(text) == {
+        "swa_attention" if sliding else "paged_attention"}
+    assert {"tpu.matmul", "tpu.enqueue_dma", "tpu.strided_load"} <= (
+        _mosaic_ops(text))
+
+
+def test_window_pool_step_at_the_published_widths(chip, topo, on_one_chip):
+    """The packed step of the cell's configuration file: it compiles for
+    the chip with both kernels in it, holds what the configuration says
+    it holds, leaves no pool copied, hands both pools back in the buffers
+    they came in, and every fusion with a matmul inside reads the scope
+    of its heaviest one."""
+    import re
+
+    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
+                                                            sample)
+    from neuronx_distributed_tpu.obs.device_scopes import device_scope
+
+    config, models = _cell_config("laguna-s-2.1", None)
+    assert sorted(config["reduced"]) == [
+        "gating_types", "layer_types", "mlp_layer_types",
+        "num_attention_heads_per_layer", "num_experts", "num_hidden_layers",
+        "vocab_size"]
+    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
+                                                         models)
+    s = config["serve"]
+    slots, blocks = s["max_slots"], s["num_blocks"]
+    assert cache.k.shape == (2, blocks, 128, 8, 128)
+    assert cache.wk.shape == (3, slots * 5, 128, 8, 128)
+    assert cache.wpos.shape == (slots * 5, 128)
+    tree = params["params"]["model"]
+    assert tree["layers_sliding_sparse"]["layer"]["moe"]["experts"][
+        "down"].shape == (3, 128, 1024, 3072)
+    assert tree["layers_sliding_sparse"]["layer"]["moe"]["router"][
+        "kernel"].shape == (3, 3072, 256)
+    assert tree["layers_sliding_sparse"]["layer"]["attn"]["qkv"][
+        "q_kernel"].shape == (3, 3072, 9216)
+    assert params["params"]["lm_head"]["kernel"].shape == (3072, 50176)
+
+    def step_fn(params, cache, tokens, positions, slot_ids, rng):
+        logits, cache = forward(cfg, params, tokens, positions, cache,
+                                slot_ids=slot_ids)
+        with device_scope("sample"):
+            return sample(logits[0], rng, SamplingConfig()), cache
+
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
+        chip(rng.shape, rng.dtype)).compile()
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"paged_attention",
+                                               "swa_attention"}
+    gib = 2.0 ** 30
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) / gib
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params)) / gib
+    assert 10.3 < weights < 10.45                    # 5,572M in bfloat16
+    aot = config["assumed"]["serve_aot_gib"]
+    assert abs(weights - aot["weights"]) < 0.01
+    assert abs(mem.temp_size_in_bytes / gib - aot["temporaries"]) < 0.05
+    assert abs(held - aot["total"]) < 0.05, (held, mem)
+    assert held > 0.8 * 15.75                        # the file's share
+
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape in (f"2,{blocks},128,8,128", f"3,{slots * 5},128,8,128")]
+    assert len(stacks) == 4 and set(stacks) <= aliased, (stacks, header)
+
+    total, differ, kernels = scope_disagreements(text)
+    assert total > 0 and kernels == {"attn.kernel.full",
+                                     "attn.kernel.window"}
+    top = [d for d in differ if d[1].split(".")[0] != d[2].split(".")[0]]
+    assert sum(d[3] for d in top) <= 0.02 * total, top
+
+
 # -- the engine's own packed step: one deep in flight -------------------------
 # The CPU tests never donate, so only a compile for the chip shows what the
 # step's operands are there: the pool donated and written in place, the

@@ -36,14 +36,16 @@ from runners import models  # noqa: E402
 SERVING = {"llama": "tiny-mistral-serve", "mixtral": "tiny-mixtral",
            "evabyte": "tiny-evabyte", "minicpm_sala": "tiny-minicpm-sala",
            "glm_moe_lite": "tiny-glm-moe-lite",
-           "granite_hybrid": "tiny-granite-hybrid"}
+           "granite_hybrid": "tiny-granite-hybrid",
+           "laguna": "tiny-laguna"}
 #: the children a family's step must open, and no other family's may
 OWN = {"attn.select": {"minicpm_sala"},
        "attn.state": {"minicpm_sala", "granite_hybrid"},
        "attn.conv": {"granite_hybrid"}, "attn.summarise": {"evabyte"},
-       "ffn.experts": {"mixtral", "glm_moe_lite"},
-       "ffn.router": {"mixtral", "glm_moe_lite"},
-       "ffn.shared": {"glm_moe_lite"}}
+       "attn.kernel.full": {"laguna"}, "attn.kernel.window": {"laguna"},
+       "ffn.experts": {"mixtral", "glm_moe_lite", "laguna"},
+       "ffn.router": {"mixtral", "glm_moe_lite", "laguna"},
+       "ffn.shared": {"glm_moe_lite", "laguna"}}
 #: the operations that carry a step's device time
 HEAVY = ("stablehlo.dot_general", "stablehlo.custom_call",
          "stablehlo.scatter", "stablehlo.gather", "stablehlo.sort",
@@ -255,11 +257,13 @@ def test_every_heavy_operation_of_a_step_has_a_scope(which):
 def test_a_step_opens_the_children_its_family_has_and_no_others(which):
     seen = {scope_of(path) for _, path in _heavy_ops(_lowered(which))}
     assert seen <= set(SCOPES)
-    assert {"attn.proj", "attn.kernel", "head"} <= seen
+    assert {"attn.proj", "head"} <= seen
+    # the kernel's scope, or its children where a family's layers differ
+    assert any(within(name, ["attn.kernel"]) for name in seen)
     for child, families in OWN.items():
         assert (child in seen) == (which in families), (child, seen)
     dense = which in ("llama", "evabyte", "minicpm_sala", "glm_moe_lite",
-                      "granite_hybrid", "train")
+                      "granite_hybrid", "laguna", "train")
     assert ("ffn.dense" in seen) == dense
     if which == "train":
         assert "optimizer" not in seen      # elementwise: no heavy operation
@@ -318,14 +322,27 @@ LOWERED_AT_PR_38 = {
 }
 
 
-@pytest.mark.parametrize("which", list(LOWERED_AT_PR_38))
+#: and the latent family's, as PR 39's tree lowered it
+LOWERED_AT_PR_39 = {
+    "glm_moe_lite":
+        "63c8641022c750d872b9e8ea66180b6757a26e9d639a71b44108bf4b6fcc44df",
+}
+
+
+@pytest.mark.parametrize("which",
+                         list(LOWERED_AT_PR_38) + list(LOWERED_AT_PR_39))
 def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     """PR 39 changed the latent kernel and its walk alone: the packed
     steps of the five families that run the shared helpers of
-    ``ops/paged_attention.py`` are the parent's text. A PR that changes
-    one of these programs on purpose records its new hash here."""
+    ``ops/paged_attention.py`` are the parent's text. PR 40 gave the
+    paged kernel a sliding window, the expert layer a share of the
+    experts (``held``) and the top-k router a scale, each off where a
+    family does not ask for it: the six families' steps, Mixtral's and
+    GLM's expert layers among them, are still the text they were. A PR
+    that changes one of these programs on purpose records its new hash
+    here."""
     import hashlib
 
     text = _stripped(_lowered(which))
-    assert hashlib.sha256(text.encode()).hexdigest() == LOWERED_AT_PR_38[
-        which]
+    assert hashlib.sha256(text.encode()).hexdigest() == {
+        **LOWERED_AT_PR_38, **LOWERED_AT_PR_39}[which]
