@@ -732,13 +732,16 @@ class ServingEngine:
         #: columns, counted on the host as the rows are packed:
         #: ``nxd_paged_columns_total`` or, for a window-summary cache,
         #: ``nxd_eva_columns_total``. A sparse-state cache's selections are
-        #: known to the device alone: the step leaves their eight counts in
+        #: known to the device alone: the step leaves their ten counts in
         #: ``cache.counts`` and the fetch adds them here
         #: (``nxd_sparse_columns_total``, ``nxd_sparse_positions_total``,
-        #: ``nxd_sparse_block_visits_total``)
+        #: ``nxd_sparse_block_visits_total``,
+        #: ``nxd_sparse_key_visits_total``)
+        from ..ops.sparse_attention import COUNT_KINDS
+
         self._counts_on_device = self._cache_kind.name == "sparse_state"
-        self._paged_cols = np.zeros((8 if self._counts_on_device else 3,),
-                                    np.int64)
+        self._paged_cols = np.zeros(
+            (len(COUNT_KINDS) if self._counts_on_device else 3,), np.int64)
         #: what the kernel's tiles fetched for those rows, by the walk's own
         #: function: pool blocks fetched, one a (tile, pair), and the further
         #: live (row, column) each fetch served
@@ -2673,13 +2676,26 @@ class ServingEngine:
                     "Counted on the device, fetched with the step's "
                     "tokens.",
                     labels=("kind",))
+                key_visits_c = reg.counter(
+                    "nxd_sparse_key_visits_total",
+                    "(Row, table column) of the packed rows whose "
+                    "compressed keys the selection scores (rows at or "
+                    "past the dense threshold, columns that hold a whole "
+                    "kernel), summed over the sparse layers, by how the "
+                    "compressed_key_scores kernel came by the column's "
+                    "keys: fetched, a (tile, column, pool block) it "
+                    "copied, or shared, served by the copy made for "
+                    "another row of the tile. Counted on the device from "
+                    "the step's walk, fetched with the step's tokens.",
+                    labels=("kind",))
                 cols_by_kind = tuple(
                     [cols_c.labels(kind=k) for k in
                      ("selected", "forced", "dense", "skipped")]
                     + [pos_c.labels(kind=k) for k in
                        ("attended", "skipped")]
-                    + [sparse_visits_c.labels(kind=k) for k in
-                       ("fetched", "shared")])
+                    + [c.labels(kind=k) for c in (sparse_visits_c,
+                                                  key_visits_c)
+                       for k in ("fetched", "shared")])
             elif self._cache_kind.ring is None:
                 cols_c = reg.counter(
                     "nxd_paged_columns_total",

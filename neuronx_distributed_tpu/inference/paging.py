@@ -235,6 +235,8 @@ class SparseStateCache(FullCache):
         if quantized:
             raise ValueError("a sparse_state cache has no int8 pool: the "
                              "selection over one is another kernel")
+        from ..ops.sparse_attention import COUNT_KINDS
+
         kv, d = model_cfg.num_kv_heads, model_cfg.head_dim_
         pool = (self.sparse_layers, num_blocks, kv, block_size, d)
         return SparseStatePagedCache(
@@ -242,7 +244,7 @@ class SparseStateCache(FullCache):
             ck=jnp.zeros((self.sparse_layers,
                           num_blocks * (block_size // self.stride), kv * d),
                          dtype),
-            counts=jnp.zeros((8,), jnp.int32),
+            counts=jnp.zeros((len(COUNT_KINDS),), jnp.int32),
             **init_state_leaves(self.leaves, table_rows, dtype),
             pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
             block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
@@ -548,7 +550,7 @@ class SparseStatePagedCache(struct.PyTreeNode):
     entry ``b * block_size / stride + i`` the kernel that starts at slot
     ``stride * i`` of block ``b``; ``state`` ``[Ll, H, table rows, D, D]``
     float32 over the ``Ll`` lightning layers (a slot's ``S^T`` a head:
-    :func:`..ops.lightning_attention.lightning_attention_packed`); ``counts [8]`` what the last
+    :func:`..ops.lightning_attention.lightning_attention_packed`); ``counts [10]`` what the last
     step's selections attended
     (:data:`..ops.sparse_attention.COUNT_KINDS`, summed over the sparse
     layers); ``pos``, ``block_tables`` and ``lengths`` as
@@ -620,7 +622,11 @@ class SparseLayerView(struct.PyTreeNode):
     handed in :class:`PagedCacheView`'s place: the K/V and compressed-key
     stacks and the running ``counts`` (the layer scan's carry), the
     layer's index in them, the per-token block tables, the flat write
-    indices and the rows' true positions (PAD_POSITION for padding)."""
+    indices, the rows' true positions (PAD_POSITION for padding) and the
+    step's walk of the compressed keys
+    (:func:`..ops.sparse_attention.score_walk`: which keys each tile of
+    rows scores, the same for every sparse layer; None: the layer builds
+    its own)."""
 
     k: jax.Array
     v: jax.Array
@@ -630,6 +636,7 @@ class SparseLayerView(struct.PyTreeNode):
     tables: jax.Array
     write_idx: jax.Array
     q_pos: jax.Array
+    walk: Any = None
 
 
 class LatentLayerView(struct.PyTreeNode):

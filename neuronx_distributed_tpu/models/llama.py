@@ -503,7 +503,8 @@ def _sparse_attend(cfg: LlamaConfig, q, k, v, view):
                                           cfg.sparse)
     out, counts = sp.sparse_paged_attention(
         q[0], new_k, new_v, new_ck, view.layer, view.tables, view.q_pos,
-        cfg.sparse, scale=scale, force_pallas=cfg.attn_force_pallas)
+        cfg.sparse, scale=scale, force_pallas=cfg.attn_force_pallas,
+        walk=view.walk)
     return out[None].astype(cfg.dtype), view.replace(
         k=new_k, v=new_v, ck=new_ck, counts=view.counts + counts)
 

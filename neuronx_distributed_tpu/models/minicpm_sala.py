@@ -36,7 +36,7 @@ from flax.core import meta
 from ..modules.attention import rope_rows
 from ..modules.norms import RMSNorm
 from ..obs.device_scopes import device_scope
-from ..ops.sparse_attention import SparseSpec
+from ..ops.sparse_attention import SparseSpec, score_walk
 from ..parallel import layers as pl
 from ..parallel import loss_functions as lf
 from .llama import LlamaConfig, _ScanBody, run_layers
@@ -256,6 +256,12 @@ def minicpm_sala_forward_with_cache(cfg: MiniCPMSALAConfig, params,
             jnp.clip(slot_ids, 0, kv_cache.max_slots - 1)]
         write_idx = paging.flat_write_indices(
             tables, q_pos, kv_cache.block_size, kv_cache.capacity, kind)
+        # which compressed keys each tile of rows scores follows the
+        # tables and positions alone: one walk for the sparse layers
+        walk = score_walk(
+            tables, q_pos, cfg.sparse, kv_cache.block_size,
+            cfg.num_heads // cfg.num_kv_heads, cfg.head_dim_, cfg.dtype,
+            cfg.attn_force_pallas)
     with device_scope("attn.pool_write"):
         pool_pos = paging.write_pool_positions(kv_cache.pos, q_pos,
                                                write_idx)
@@ -265,7 +271,7 @@ def minicpm_sala_forward_with_cache(cfg: MiniCPMSALAConfig, params,
             return paging.SparseLayerView(
                 k=carry["k"], v=carry["v"], ck=carry["ck"],
                 counts=carry["counts"], layer=layer, tables=tables,
-                write_idx=write_idx, q_pos=q_pos)
+                write_idx=write_idx, q_pos=q_pos, walk=walk)
         return paging.StateLayerView(state=carry["state"], layer=layer,
                                      slot_ids=slot_ids, q_pos=q_pos)
 

@@ -6,7 +6,27 @@ block takes the largest score of the kernels that overlap it, and the
 among them) are attended with exact softmax. Below ``dense_len`` every
 causal block is attended.
 
-* :func:`select_blocks` is the selection, shared by every path.
+* The selection is its scores (:func:`key_scores`) and what follows them
+  (:func:`blocks_of_scores`: the mask of whole kernels, the softmax, the
+  block maxima, the forced blocks, the exact top-k), one tail for every
+  path. Where the scores come from is the path's: :func:`select_blocks`
+  multiplies the queries with a sequence's whole compressed keys (the
+  no-cache forward, and the XLA path of the paged pool over
+  :func:`gather_compressed_keys`, each row's whole table: the CPU tests
+  and the kernel's reference); on the chip the ``compressed_key_scores``
+  kernel (:func:`_score_kernel`) reads the keys where they lie in the
+  pool's stack, by block id: a tile of packed rows, both K/V groups'
+  heads stacked, against a unit of :data:`SCORE_LANES` compressed keys
+  (16 table columns of 8) at a time; the distinct pool blocks of a
+  column are its *layers*, a layer's keys are copied once (one
+  ``[per, G x D]`` piece a column, into one of two VMEM buffers while
+  the layer before is multiplied) and serve every row of the tile whose
+  table names them, so a prefill chunk's rows read their slot's keys
+  once and a decode row's keys are multiplied for its part of the tile
+  alone. Which pairs a tile scores follows the tables and positions
+  alone: :func:`score_walk`, built once a step for all sparse layers. A
+  row below ``dense_len``, a pad row and a column beyond the row's
+  position name no pair; their scores are 0 and never read.
 * :func:`sparse_attention_full` attends a whole sequence, no cache.
 * :func:`sparse_paged_attention` attends the paged pool of
   :class:`..inference.paging.SparseStatePagedCache`: the selection is
@@ -39,6 +59,9 @@ causal block is attended.
   tile's rows), and each row's pairs (at most
   :meth:`SparseSpec.walk_width`) are put in order by a one-hot of their
   rank. The XLA path gathers the row's whole table (CPU tests).
+
+Which path runs follows :func:`.paged_attention.paged_attention_impl`,
+for the scores and the attention alike.
 """
 
 from __future__ import annotations
@@ -57,12 +80,17 @@ from .pallas_utils import compiler_params as _compiler_params
 
 #: ``cache.counts``, in order: what :func:`selection_counts` counts, then
 #: the live (row, group, column) a tile fetches a pool block for
-#: (:func:`first_namers`) and those an earlier row's fetch serves
+#: (:func:`first_namers`) and those an earlier row's fetch serves, then
+#: the same two of the score kernel's compressed keys (:func:`score_walk`)
 COUNT_KINDS = ("selected", "forced", "dense", "skipped", "attended",
-               "skipped_positions", "fetched", "shared")
+               "skipped_positions", "fetched", "shared", "keys_fetched",
+               "keys_shared")
 
 #: VMEM a tile's float32 accumulator may take (:func:`tile_height`)
 ACC_BYTES = 1 << 20
+#: compressed keys of a unit of the score kernel: one product's width and
+#: the lanes of the block of scores it writes (:func:`score_walk`)
+SCORE_LANES = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,19 +131,12 @@ def compress_keys(k: jax.Array, spec: SparseSpec) -> jax.Array:
     return (halves + nxt) / spec.kernel
 
 
-def select_blocks(q: jax.Array, ck: jax.Array, q_pos: jax.Array,
-                  spec: SparseSpec, scale: float):
-    """``q [T, G, R, D]`` (``R`` query heads a K/V group), ``ck [T, N, G,
-    D]`` (or ``[N, G, D]``, shared by the rows) the compressed keys in
-    position order (entry ``i`` covers ``stride * i .. + kernel``),
-    ``q_pos [T]``. Returns ``(sel, forced) [T, G, N * stride / block]``
-    bool: the blocks each (row, group) attends, and those of them that
-    the first blocks and the local window forced. A pad row selects
-    nothing; a row below ``dense_len`` every causal block."""
+def key_scores(q: jax.Array, ck: jax.Array, scale: float) -> jax.Array:
+    """``q [T, G, R, D]`` (``R`` query heads a K/V group) against ``ck [T,
+    N, G, D]`` (or ``[N, G, D]``, shared by the rows), the compressed keys
+    in position order: the scaled scores ``[T, G, R, N]`` float32."""
     t = q.shape[0]
     n = ck.shape[-3]
-    per = spec.block // spec.stride
-    nb = n // per
     g, r, d = q.shape[1:]
     # the groups' heads against the keys of all groups at once, each head
     # zero outside its own group's D values: the keys are contracted as
@@ -125,8 +146,22 @@ def select_blocks(q: jax.Array, ck: jax.Array, q_pos: jax.Array,
             ).reshape(t, g * r, g * d)
     keys = ck.astype(jnp.float32).reshape(ck.shape[:-2] + (g * d,))
     eq = "tqk,tnk->tqn" if ck.ndim == 4 else "tqk,nk->tqn"
-    s = jnp.einsum(eq, wide, keys, precision=jax.lax.Precision.HIGHEST
-                   ).reshape(t, g, r, n) * scale
+    return jnp.einsum(eq, wide, keys, precision=jax.lax.Precision.HIGHEST
+                      ).reshape(t, g, r, n) * scale
+
+
+def blocks_of_scores(s: jax.Array, q_pos: jax.Array, spec: SparseSpec):
+    """The selection from its scores ``s [T, G, R, N]`` (entry ``i`` of
+    ``N`` is the kernel of positions ``stride * i .. + kernel``; what lies
+    at a kernel that is not whole for the row, or at a row below
+    ``dense_len``, is never read, and must be no NaN) and ``q_pos [T]``.
+    Returns ``(sel, forced) [T, G, N * stride / block]`` bool: the blocks
+    each (row, group) attends, and those of them that the first blocks
+    and the local window forced. A pad row selects nothing; a row below
+    ``dense_len`` every causal block."""
+    t, _, _, n = s.shape
+    per = spec.block // spec.stride
+    nb = n // per
     ends = jnp.arange(n) * spec.stride + spec.kernel - 1
     whole = ends[None, :] <= q_pos[:, None]                     # [T, N]
     s = jnp.where(whole[:, None, None, :], s, -jnp.inf)
@@ -154,6 +189,15 @@ def select_blocks(q: jax.Array, ck: jax.Array, q_pos: jax.Array,
     dense = (q_pos < spec.dense_len)[:, None, None]
     sel = jnp.where(dense, causal[:, None], picked)
     return sel, forced[:, None] & ~dense & sel
+
+
+def select_blocks(q: jax.Array, ck: jax.Array, q_pos: jax.Array,
+                  spec: SparseSpec, scale: float):
+    """:func:`blocks_of_scores` of :func:`key_scores`: the selection of
+    ``q [T, G, R, D]`` over whole compressed keys ``ck`` in position
+    order (the no-cache forward, the XLA path over
+    :func:`gather_compressed_keys`)."""
+    return blocks_of_scores(key_scores(q, ck, scale), q_pos, spec)
 
 
 def _attended(sel, q_pos, spec: SparseSpec):
@@ -318,6 +362,15 @@ def tile_height(tokens: int, heads: int, head_dim: int, dtype) -> int:
     part = narrow_height(dtype)
     fit = max(part, ACC_BYTES // (heads * head_dim * 4) // part * part)
     return min(fit, -(-tokens // part) * part)
+
+
+def _tile_queries(q: jax.Array, rows: int) -> jax.Array:
+    """``q [T, G, R, D]`` -> ``[tiles, G, R, rows, D]``: a tile's queries
+    a group at a time, head by head, as the kernels stack them; the last
+    tile filled up with rows of zeros."""
+    t, g, heads, d = q.shape
+    return jnp.pad(q, ((0, -t % rows), (0, 0), (0, 0), (0, 0))).reshape(
+        -1, rows, g, heads, d).transpose(0, 2, 3, 1, 4)
 
 
 class SparseWalk(NamedTuple):
@@ -570,12 +623,7 @@ def _sparse_paged_pallas(q, k_pool, v_pool, layer, tables, q_pos, parts,
         walk = sparse_tile_walk(parts, tables.astype(jnp.int32), rows,
                                 part_rows, width, per)
     tiles = walk.keys.shape[0]
-    pad = tiles * rows - t
-    # a tile's queries a group at a time, head by head: [tiles, G, R,
-    # rows, D], as the kernel stacks them
-    q_tiles = jnp.pad(q, ((0, pad), (0, 0), (0, 0), (0, 0))).reshape(
-        tiles, rows, g, heads, d).transpose(0, 2, 3, 1, 4)
-    positions = jnp.pad(q_pos.astype(jnp.int32), (0, pad),
+    positions = jnp.pad(q_pos.astype(jnp.int32), (0, tiles * rows - t),
                         constant_values=PAD_POSITION).reshape(tiles, rows, 1)
 
     def head_block():
@@ -608,24 +656,281 @@ def _sparse_paged_pallas(q, k_pool, v_pool, layer, tables, q_pos, parts,
         name="sparse_paged_attention",
     )(walk.total, walk.start, walk.count, walk.after, walk.blocks,
       walk.marks, jnp.asarray(layer, jnp.int32).reshape(1), walk.keys,
-      positions, q_tiles, k_pool, v_pool)
+      positions, _tile_queries(q, rows), k_pool, v_pool)
     return out.transpose(0, 3, 1, 2, 4).reshape(
         tiles * rows, g, heads, d)[:t], jnp.sum(walk.total)
+
+
+# ---------------------------------------------------------------------------
+# The selection's scores from the pool: a tile's rows against the
+# compressed keys of the (table column, pool block) pairs they score.
+# ---------------------------------------------------------------------------
+
+class ScoreWalk(NamedTuple):
+    """What the score kernel is handed of a step's routing
+    (:func:`score_walk`), the same for every sparse layer. ``visits [2]``
+    int32: the (tile, column, block) whose keys a layer's kernel copies,
+    and the further (row, column) each copy serves. The rest is ``None``
+    where the XLA path serves. A tile's columns lie in *units* of
+    :data:`SCORE_LANES` compressed keys; of a unit, *layer* ``j`` is the
+    ``j``-th distinct pool block of each of its columns (those that rows
+    of several parts of :func:`narrow_height` rows name before those of
+    one part, each in the order of the rows that name them first):
+    ``depth [tiles * units]`` the layers of a unit, ``blocks [tiles *
+    units * rows * columns a unit]`` a layer's pool block a column (-1:
+    none), ``narrow [tiles * units * rows]`` the one part of the tile
+    that names a layer's blocks (-1: rows of several), ``lanes [tiles,
+    rows, units x SCORE_LANES]`` the layer that holds a row's keys of
+    each compressed key's column (-1: the row scores nothing there)."""
+
+    visits: jax.Array
+    depth: Optional[jax.Array] = None
+    blocks: Optional[jax.Array] = None
+    narrow: Optional[jax.Array] = None
+    lanes: Optional[jax.Array] = None
+
+
+def scored_columns(tables: jax.Array, q_pos: jax.Array, spec: SparseSpec,
+                   block_size: int) -> jax.Array:
+    """``[T, max_blocks_per_seq]`` bool: the table columns whose
+    compressed keys a row's selection reads: mapped, of a row at or past
+    ``dense_len`` that is no padding, and holding a kernel that is whole
+    at the row's position (the column's first is)."""
+    first_end = (jnp.arange(tables.shape[1]) * block_size + spec.kernel - 1)
+    return ((tables >= 0) & (first_end[None, :] <= q_pos[:, None])
+            & ((q_pos >= spec.dense_len) & (q_pos < PAD_POSITION))[:, None])
+
+
+def _score_units(columns: int, per: int):
+    """Table columns of a unit of the score kernel (:data:`SCORE_LANES`
+    compressed keys, ``per`` a column; every column where there are
+    fewer) and the units of a table."""
+    ucols = min(max(1, SCORE_LANES // per), columns)
+    return ucols, -(-columns // ucols)
+
+
+def score_walk(tables: jax.Array, q_pos: jax.Array, spec: SparseSpec,
+               block_size: int, heads: int, head_dim: int, dtype,
+               force_pallas: Optional[bool] = None) -> ScoreWalk:
+    """The score kernel's walk of one packed step, from ``tables [T,
+    max_blocks_per_seq]`` and ``q_pos [T]`` alone (never a layer's
+    queries): built once a step and handed to every sparse layer. A pair
+    (column, block) belongs to the first row of its tile that scores it
+    (:func:`first_namers`: rows of one slot, and slots that share a
+    prefix block, are served by one copy); a row below ``dense_len``, a
+    pad row, an unmapped column and a column beyond the row's position
+    name none. Without a sort or a gather: a column's pairs are ranked by
+    running counts of their first namers and laid out by a one-hot of
+    the rank."""
+    t, maxb = tables.shape
+    rows = tile_height(t, heads, head_dim, dtype)
+    part_rows = narrow_height(dtype)
+    tables = tables.astype(jnp.int32)
+    live = scored_columns(tables, q_pos, spec, block_size)
+    parts, tiled, same = _tile_namers(live[:, None].astype(jnp.int32),
+                                      tables, rows)
+    first = _first(parts, same)[:, :, 0]                # [tiles, rows, maxb]
+    fetched = jnp.sum(first)
+    visits = jnp.stack([fetched, jnp.sum(live) - fetched]).astype(jnp.int32)
+    if paged_attention_impl(head_dim, block_size, force_pallas) == "xla":
+        return ScoreWalk(visits=visits)
+    per = block_size // spec.stride
+    ucols, units = _score_units(maxb, per)
+    same, live = same[:, :, :, 0], parts[:, :, 0] > 0
+    # a column's pairs that rows of several parts name come first, then
+    # those of one part (a decode row's), each in the order of their first
+    # namers: the layers past a chunk's are narrow
+    part = jnp.arange(rows, dtype=jnp.int32) // part_rows
+    wide = jnp.any(same & (part[:, None] != part[None, :])[None, :, :, None],
+                   axis=2)
+    ahead, behind = first & wide, first & ~wide
+    rank = jnp.where(
+        wide, jnp.cumsum(ahead, axis=1, dtype=jnp.int32) - ahead,
+        jnp.sum(ahead, axis=1, keepdims=True, dtype=jnp.int32)
+        + jnp.cumsum(behind, axis=1, dtype=jnp.int32) - behind)
+    # the layer of a row's block: the rank of the block's first namer
+    layer = jnp.where(live, jnp.sum(jnp.where(
+        same & first[:, None], rank[:, None], 0), axis=2), -1)
+    # layer j of a column: its pair of rank j, by a one-hot of the rank;
+    # with the block, the part of the tile that names it (``rows``, which
+    # is no part, for a pair that several do)
+    at = first[:, None] & (rank[:, None] == jnp.arange(
+        rows, dtype=jnp.int32)[:, None, None])      # [tiles, j, rows, maxb]
+    blocks = jnp.sum(jnp.where(at, tiled[:, None] + 1, 0), axis=2) - 1
+    namer = jnp.sum(jnp.where(
+        at, jnp.where(wide, rows, part[:, None])[:, None] + 1, 0),
+        axis=2) - 1
+
+    def by_unit(x, fill):
+        """``[tiles, n, maxb]`` -> ``[tiles, units, n, ucols]``."""
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, units * ucols - maxb)),
+                    constant_values=fill)
+        return x.reshape(x.shape[:2] + (units, ucols)).swapaxes(1, 2)
+
+    depth = jnp.max(by_unit(jnp.sum(first, axis=1, dtype=jnp.int32)[:, None],
+                            0), axis=(2, 3))
+    namer = by_unit(namer, -1)
+    lo = jnp.min(jnp.where(namer >= 0, namer, rows), axis=-1)
+    hi = jnp.max(namer, axis=-1)
+    lanes = jnp.repeat(jnp.pad(
+        layer, ((0, 0), (0, 0), (0, units * ucols - maxb)),
+        constant_values=-1), per, axis=-1)
+    narrow = jnp.where((lo == hi) & (lo < rows), lo, -1)
+    return ScoreWalk(
+        visits=visits, depth=depth.reshape(-1),
+        blocks=by_unit(blocks, -1).reshape(-1).astype(jnp.int32),
+        narrow=narrow.reshape(-1).astype(jnp.int32), lanes=lanes)
+
+
+def _score_kernel(depth_ref, blocks_ref, narrow_ref, layer_ref, lanes_ref,
+                  q_ref, ck_hbm, s_ref, key_buf, sems, *, part_rows: int,
+                  per: int, scale: float):
+    """One tile of packed rows, every K/V group's query heads stacked head
+    by head (``q_ref [G, heads, rows, D]``), against one unit of its
+    columns (:class:`ScoreWalk`): a loop over the unit's layers, each
+    layer's compressed keys (``per`` entries a pool block, the groups'
+    side by side as the pool holds them) copied from the stack in HBM
+    into one of two VMEM buffers while the layer before it is multiplied,
+    ``[heads * rows, D] x [D, keys]`` a group on the MXU from the stored
+    operands into float32. A row keeps, of a layer's scores, those of the
+    columns where the layer holds its own block; what no layer serves
+    stays 0. A layer that one part of the tile names alone is multiplied
+    for that part of every head alone."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tile, unit = pl.program_id(0), pl.program_id(1)
+    groups, heads, rows, d = q_ref.shape
+    lanes = key_buf.shape[1]
+    ucols = lanes // per
+    at = tile * pl.num_programs(1) + unit
+    depth = depth_ref[at]
+    layer = layer_ref[0]
+    operand = (jnp.bfloat16 if q_ref.dtype == jnp.bfloat16
+               and key_buf.dtype == jnp.bfloat16 else jnp.float32)
+
+    def copies(j, slot, then):
+        # the copies of layer ``j``: a block a column, where it has one
+        def column(c, carry):
+            b = blocks_ref[(at * rows + j) * ucols + c]
+
+            @pl.when(b >= 0)
+            def _block():
+                then(pltpu.make_async_copy(
+                    ck_hbm.at[layer, pl.ds(pl.multiple_of(b * per, per),
+                                           per)],
+                    key_buf.at[slot, pl.ds(pl.multiple_of(c * per, per),
+                                           per)],
+                    sems.at[slot, c]))
+            return carry
+
+        jax.lax.fori_loop(0, ucols, column, 0)
+
+    s_ref[...] = jnp.zeros_like(s_ref)
+
+    @pl.when(depth > 0)
+    def _first():
+        copies(0, 0, lambda c: c.start())
+
+    def one(j, carry):
+        slot = j % 2
+
+        @pl.when(j + 1 < depth)
+        def _next():
+            copies(j + 1, 1 - slot, lambda c: c.start())
+
+        copies(j, slot, lambda c: c.wait())
+        keys = key_buf[slot].astype(operand)              # [lanes, G * D]
+
+        def score(rs, n):
+            """The layer against rows ``rs`` (``n`` of them) of the tile;
+            a column the layer has no block for holds another layer's
+            keys or none, and no row keeps its scores."""
+            mine = (lanes_ref[rs, :] == j)[None]          # [1, n, lanes]
+            for g in range(groups):
+                s = jax.lax.dot_general(
+                    q_ref[g, :, rs, :].reshape(heads * n, d).astype(operand),
+                    keys[:, g * d:(g + 1) * d], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s_ref[g, :, rs, :] = jnp.where(
+                    mine, s.reshape(heads, n, lanes), s_ref[g, :, rs, :])
+
+        if part_rows >= rows:
+            score(slice(None), rows)
+        else:
+            part = narrow_ref[at * rows + j]
+
+            @pl.when(part >= 0)
+            def _narrow():
+                score(pl.ds(pl.multiple_of(part * part_rows, part_rows),
+                            part_rows), part_rows)
+
+            @pl.when(part < 0)
+            def _whole():
+                score(slice(None), rows)
+        return carry
+
+    jax.lax.fori_loop(0, depth, one, 0)
+
+
+def _key_scores_pallas(q, ck, layer, walk: ScoreWalk, per: int, columns: int,
+                       scale, interpret=False):
+    """:func:`key_scores` of ``q [T, G, R, D]`` over the rows' own
+    sequences' compressed keys, read where they lie in ``ck [L,
+    num_blocks * per, G * D]`` (``per`` a pool block) by the walk's block
+    ids: ``[T, G, R, columns * per]`` float32, 0 where a row scores
+    nothing (:func:`scored_columns`)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, g, heads, d = q.shape
+    n = columns * per
+    tiles, rows, _ = walk.lanes.shape
+    ucols, units = _score_units(columns, per)
+    lanes = ucols * per
+    out = pl.pallas_call(
+        functools.partial(_score_kernel, part_rows=narrow_height(q.dtype),
+                          per=per, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(tiles, units),
+            in_specs=[pl.BlockSpec((None, rows, lanes),
+                                   lambda i, u, *_: (i, 0, u)),
+                      pl.BlockSpec((None, g, heads, rows, d),
+                                   lambda i, u, *_: (i, 0, 0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, g, heads, rows, lanes),
+                                   lambda i, u, *_: (i, 0, 0, 0, u)),
+            scratch_shapes=[pltpu.VMEM((2, lanes, g * d), ck.dtype),
+                            pltpu.SemaphoreType.DMA((2, ucols))]),
+        out_shape=jax.ShapeDtypeStruct((tiles, g, heads, rows, n),
+                                       jnp.float32),
+        interpret=interpret,
+        compiler_params=None if interpret else _compiler_params(),
+        name="compressed_key_scores",
+    )(walk.depth, walk.blocks, walk.narrow,
+      jnp.asarray(layer, jnp.int32).reshape(1), walk.lanes,
+      _tile_queries(q, rows), ck)
+    return out.transpose(0, 3, 1, 2, 4).reshape(tiles * rows, g, heads, n)[:t]
 
 
 def sparse_paged_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, ck: jax.Array, layer,
                            tables: jax.Array, q_pos: jax.Array,
                            spec: SparseSpec, scale: Optional[float] = None,
-                           force_pallas: Optional[bool] = None):
+                           force_pallas: Optional[bool] = None,
+                           walk: Optional[ScoreWalk] = None):
     """Select and attend. ``q [T, N, D]`` one query row a packed token;
     ``k_pool, v_pool [L, num_blocks, KV, block_size, D]`` and ``ck [L,
     num_blocks * block_size / stride, KV * D]`` the stacks, read at
     ``layer``; ``tables [T, max_blocks_per_seq]`` per-token block tables
-    (position ``p`` in column ``p // block_size``); ``q_pos [T]``.
-    Returns ``(out [T, N, D], counts [8] int32)``
+    (position ``p`` in column ``p // block_size``); ``q_pos [T]``;
+    ``walk`` the step's :func:`score_walk` (built here where the caller
+    has none). Returns ``(out [T, N, D], counts [10] int32)``
     (:data:`COUNT_KINDS`). ``force_pallas`` as
-    :func:`.paged_attention.paged_attention`."""
+    :func:`.paged_attention.paged_attention`: the kernels on the chip
+    (the scores from the pool in place, then the selected blocks), the
+    gathers of the rows' whole tables elsewhere."""
     from ..obs.device_scopes import device_scope
 
     t, n, d = q.shape
@@ -635,13 +940,23 @@ def sparse_paged_attention(q: jax.Array, k_pool: jax.Array,
                          f"selection blocks of {spec.block}")
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
     qg = q.reshape(t, kv, n // kv, d)
+    impl = paged_attention_impl(d, bs, force_pallas)
+    if walk is None:
+        with device_scope("attn.walk"):
+            walk = score_walk(tables, q_pos, spec, bs, n // kv, d, q.dtype,
+                              force_pallas)
     with device_scope("attn.select"):
-        sel, forced = select_blocks(
-            qg, gather_compressed_keys(ck, layer, tables, spec, bs, kv),
-            q_pos, spec, scale)
+        if impl == "xla":
+            s = key_scores(
+                qg, gather_compressed_keys(ck, layer, tables, spec, bs, kv),
+                scale)
+        else:
+            s = _key_scores_pallas(qg, ck, layer, walk, bs // spec.stride,
+                                   tables.shape[1], scale,
+                                   interpret=impl == "pallas-interpret")
+        sel, forced = blocks_of_scores(s, q_pos, spec)
     with device_scope("attn.walk"):
         parts = column_parts(sel, tables, bs // spec.block)
-    impl = paged_attention_impl(d, bs, force_pallas)
     if impl == "xla":
         out = _sparse_paged_xla(qg, k_pool, v_pool, layer, tables, q_pos,
                                 sel, spec, scale)
@@ -655,11 +970,12 @@ def sparse_paged_attention(q: jax.Array, k_pool: jax.Array,
             qg, k_pool, v_pool, layer, tables, q_pos, parts, spec, scale,
             interpret=impl == "pallas-interpret")
     # of the live (row, group, column), those whose pool block a tile
-    # fetches for them, alone or first, and those an earlier row's serves
+    # fetches for them, alone or first, and those an earlier row's serves;
+    # then the same of the scored (row, column) and their compressed keys
     with device_scope("attn.select"):
         counts = jnp.concatenate([
             selection_counts(sel, forced, q_pos, spec, bs,
                              spec.walk_width(bs, tables.shape[1])),
             jnp.stack([fetched, jnp.sum(parts > 0) - fetched]).astype(
-                jnp.int32)])
+                jnp.int32), walk.visits])
     return out.reshape(t, n, d).astype(q.dtype), counts

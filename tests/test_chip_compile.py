@@ -327,6 +327,35 @@ def test_sparse_paged_attention(chip, tokens, rows):
     assert " sort(" not in text
 
 
+@pytest.mark.parametrize("tokens", [128, 100])
+def test_compressed_key_scores(chip, tokens):
+    """The score kernel at the cell's shapes: one tile of the packed
+    rows, both groups' 16 heads stacked, 17 units of 16 table columns
+    (the last one half: 2,112 keys); the Mosaic body copies 8 compressed
+    keys a pair from the stack itself and multiplies on the MXU, and the
+    step's walk beside it is built without a sort or a gather."""
+    from neuronx_distributed_tpu.ops import sparse_attention as sp
+
+    groups, rep, d, bs, cols, nb, layers = 2, 16, 128, 128, 264, 4224, 4
+    spec = sp.SparseSpec()
+
+    def scores(q, ck, layer, tables, q_pos):
+        walk = sp.score_walk(tables, q_pos, spec, bs, rep, d, q.dtype,
+                             force_pallas=True)
+        return sp._key_scores_pallas(q, ck, layer, walk, bs // spec.stride,
+                                     cols, 1.0 / math.sqrt(d)), walk.visits
+
+    text = _assert_kernel_compiles(
+        scores, chip((tokens, groups, rep, d), jnp.bfloat16),
+        chip((layers, nb * 8, groups * d), jnp.bfloat16),
+        chip((), jnp.int32), chip((tokens, cols), jnp.int32),
+        chip((tokens,), jnp.int32))
+    assert _kernel_instruction_names(text) == {"compressed_key_scores"}
+    assert {"tpu.matmul", "tpu.enqueue_dma"} <= _mosaic_ops(text)
+    assert " sort(" not in text and " gather(" not in text
+    assert f"f32[1,2,16,{-(-tokens // 16) * 16},2112]" in text
+
+
 def test_sparse_state_forward_writes_its_stacks_in_place(chip, on_one_chip):
     """The cell's layer pattern, widths and cache geometry (a narrow
     vocabulary): runs of 1, 6, 2, 4, 1 and 2 like layers, each a scan over
@@ -365,7 +394,8 @@ def test_sparse_state_forward_writes_its_stacks_in_place(chip, on_one_chip):
         params, cache, chip((1, tokens), jnp.int32),
         chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32)).compile()
     text = compiled.as_text()
-    assert _kernel_instruction_names(text) == {"sparse_paged_attention"}
+    assert _kernel_instruction_names(text) == {"sparse_paged_attention",
+                                               "compressed_key_scores"}
 
     # of the results one layer's states large or larger: none is a
     # layer's weights sliced out of their stack; a K/V stack is only ever
@@ -801,10 +831,21 @@ def _serving_parts(chip, config, models):
     return cfg, forward, params, cache, s["token_budget"]
 
 
+_STEP_TEXTS = {}
+
+
 def _scoped_step(chip, topo, config_name, layers):
     """The compiled text of a cell's step: the packed serving step as the
     engine builds it (forward and sampling, the cache donated), or the
-    train step of ``make_train_step`` over the described 2x2."""
+    train step of ``make_train_step`` over the described 2x2. Compiled
+    once for the cases that read it."""
+    if (config_name, layers) not in _STEP_TEXTS:
+        _STEP_TEXTS[config_name, layers] = _compile_scoped_step(
+            chip, topo, config_name, layers)
+    return _STEP_TEXTS[config_name, layers]
+
+
+def _compile_scoped_step(chip, topo, config_name, layers):
     config, models = _cell_config(config_name, layers)
     if config["runner"] == "train":
         return _scoped_train_step(topo, config, models)
@@ -914,12 +955,45 @@ def test_a_fusion_reads_the_layer_of_its_heaviest_matmul(
     monkeypatch.setattr(fa, "on_tpu", lambda: True)
     text = _scoped_step(chip, topo, config_name, _SCOPED_STEPS[config_name])
     total, differ, kernels = scope_disagreements(text)
-    assert total > 0 and kernels == {"attn.kernel"}
+    # (the sparse layers' selection scores its keys in a kernel of its own)
+    assert total > 0 and kernels == {"attn.kernel"} | (
+        {"attn.select"} if config_name == "minicpm-sala-9b" else set())
     top = [d for d in differ
            if d[1].split(".")[0] != d[2].split(".")[0]]
     # a layer (attn, ffn, head..) misread for at most 2% of the matmuls'
     # parameters; a child misread within its layer is PERF.md section 7's
     assert sum(d[3] for d in top) <= 0.02 * total, top
+
+
+def test_the_sparse_step_scores_its_compressed_keys_in_the_pool(
+        chip, topo, on_one_chip, monkeypatch):
+    """The packed step of ``minicpm-sala.serve-longdocs`` (128 rows, 264
+    table columns of 8 compressed keys): no row's whole table of keys is
+    gathered (``bf16[270336,256]``: 128 x 2,112 rows of both groups'
+    keys), and every run of sparse layers holds one
+    ``compressed_key_scores`` call under ``attn.select`` beside its
+    ``sparse_paged_attention`` under ``attn.kernel``."""
+    from neuronx_distributed_tpu.obs.device_scopes import scope_of
+    from neuronx_distributed_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    text = _scoped_step(chip, topo, "minicpm-sala-9b", None)
+    assert "bf16[270336,256]" not in text and "[128,2112,256]" not in text
+    _, kernels = _matmul_fusions(text)
+    by_scope = {}
+    for name, path in kernels:
+        by_scope.setdefault(scope_of(path), []).append(
+            name.lstrip("%").split(".")[0])
+    assert set(by_scope) == {"attn.select", "attn.kernel"}
+    assert set(by_scope["attn.select"]) == {"compressed_key_scores"}
+    assert set(by_scope["attn.kernel"]) == {"sparse_paged_attention"}
+    config, models = _cell_config("minicpm-sala-9b", None)
+    cfg = models.build(config)[0]
+    runs = sum(kind == "sparse" for kind, _, _ in cfg.runs())
+    assert len(by_scope["attn.select"]) == runs == len(
+        by_scope["attn.kernel"])
+    # the scores a layer: [1 tile, 2 groups, 16 heads, 128 rows, 2112 keys]
+    assert "f32[1,2,16,128,2112]" in text
 
 
 # -- the state-pool cache (Granite-4.0-H-Micro): the whole model a chip ----------

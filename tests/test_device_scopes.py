@@ -315,8 +315,6 @@ LOWERED_AT_PR_38 = {
         "980493c6574fdd048c8fc81af4a42da9f8e4f1d8cdc346900a88ff1592f19bcf",
     "evabyte":
         "c947e1b8bab1fdb356b3c957e80421564c15e960e94a849b27e073c8bb9fdc98",
-    "minicpm_sala":
-        "3cbda88f4fcfc41c4f64e42930f8a2f55231b5f5333a66e97acf1e3b5f1d2e19",
     "granite_hybrid":
         "986d4a577868df39b211138f57cc1cb0a1f2880925b82928852429e39db15783",
 }
@@ -329,8 +327,16 @@ LOWERED_AT_PR_39 = {
 }
 
 
-@pytest.mark.parametrize("which",
-                         list(LOWERED_AT_PR_38) + list(LOWERED_AT_PR_39))
+#: and the sparse-state family's, as PR 42's tree lowers it (the step's
+#: walk of the compressed keys, two more counts)
+LOWERED_AT_PR_42 = {
+    "minicpm_sala":
+        "a961d5bb332c7f169899be79a0ba2bea38c677e4ff03895bf3a945a194710585",
+}
+
+
+@pytest.mark.parametrize("which", list(LOWERED_AT_PR_38)
+                         + list(LOWERED_AT_PR_39) + list(LOWERED_AT_PR_42))
 def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     """PR 39 changed the latent kernel and its walk alone: the packed
     steps of the five families that run the shared helpers of
@@ -338,11 +344,13 @@ def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     paged kernel a sliding window, the expert layer a share of the
     experts (``held``) and the top-k router a scale, each off where a
     family does not ask for it: the six families' steps, Mixtral's and
-    GLM's expert layers among them, are still the text they were. A PR
-    that changes one of these programs on purpose records its new hash
-    here."""
+    GLM's expert layers among them, are still the text they were. PR 42
+    changed the sparse-state family's step (``ops/sparse_attention.py``:
+    the selection's scores, their walk and its counts) and no other. A
+    PR that changes one of these programs on purpose records its new
+    hash here."""
     import hashlib
 
     text = _stripped(_lowered(which))
     assert hashlib.sha256(text.encode()).hexdigest() == {
-        **LOWERED_AT_PR_38, **LOWERED_AT_PR_39}[which]
+        **LOWERED_AT_PR_38, **LOWERED_AT_PR_39, **LOWERED_AT_PR_42}[which]

@@ -38,7 +38,8 @@ if BENCH not in sys.path:
 
 import harness  # noqa: E402  (benchmarks/)
 from runners import serve  # noqa: E402
-from walk_checks import check_sparse_walk  # noqa: E402
+from walk_checks import (check_score_walk, check_sparse_walk,  # noqa: E402
+                         scored_pairs)
 
 BS = 16
 SPARSE = dict(kernel=4, stride=2, block=8, topk=6, init_blocks=1, window=16,
@@ -461,6 +462,167 @@ def test_a_row_whose_selection_is_disjoint_from_its_tiles():
     assert not (parts[:8, 0] > 0)[:, 8:].any() and not parts[8, 0, :8].any()
 
 
+# -- (e2) the selection's scores from the pool -------------------------------
+
+def _step_scene(seed=12, maxb=20, nb=96):
+    """A serving step of the cell's shape at tiny widths: three decode
+    rows of other slots, one prefill chunk of 124 rows past ``dense_len``
+    and a pad row (128 rows, one tile; 20 table columns: a whole unit of
+    16 and a unit of 4)."""
+    rows = ([(1, 200), (2, 90), (3, 310)]
+            + [(0, p) for p in range(150, 274)] + [PAD])
+    rng, _, _, ck = _pools(seed, nb=nb)
+    slot_tables = rng.permutation(nb)[:4 * maxb].reshape(4, maxb)
+    tables = jnp.asarray([slot_tables[s] for s, _ in rows], jnp.int32)
+    q_pos = jnp.asarray([p for _, p in rows], jnp.int32)
+    q = jnp.asarray(rng.randn(len(rows), 4, 16), jnp.float32)
+    return q, ck, tables, q_pos
+
+
+def _scores_both_ways(q, ck, tables, q_pos, layer=1):
+    """The scores by the kernel (interpret mode, the walk built as a step
+    builds it) and by the gather, and the walk."""
+    qg = q.reshape(q.shape[0], 2, 2, -1)
+    walk = sp.score_walk(tables, q_pos, SPEC, BS, 2, qg.shape[-1], q.dtype,
+                         force_pallas=True)
+    got = sp._key_scores_pallas(qg, ck, layer, walk, BS // SPEC.stride,
+                                tables.shape[1], 0.25, interpret=True)
+    want = sp.key_scores(
+        qg, sp.gather_compressed_keys(ck, layer, tables, SPEC, BS, 2), 0.25)
+    return np.asarray(got), np.asarray(want), walk
+
+
+def _read(tables, q_pos):
+    """``[T, N]`` bool: the scores the selection reads: whole kernels of
+    real rows at or past ``dense_len``."""
+    n = tables.shape[1] * (BS // SPEC.stride)
+    ends = np.arange(n) * SPEC.stride + SPEC.kernel - 1
+    p = np.asarray(q_pos)
+    return (ends[None] <= p[:, None]) & ((p >= SPEC.dense_len)
+                                         & (p < PAD_POSITION))[:, None]
+
+
+def _assert_same_selection(got, want, tables, q_pos):
+    """(An unmapped table's row reads block 0's keys through the gather
+    and nothing through the kernel: it attends nothing either way.)"""
+    mapped = np.asarray(tables[:, 0] >= 0)
+    read = (_read(tables, q_pos) & mapped[:, None])[:, None, None]
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(np.where(read, got, 0),
+                               np.where(read, want, 0), atol=2e-6)
+    ours, theirs = (sp.blocks_of_scores(jnp.asarray(s), q_pos, SPEC)
+                    for s in (got, want))
+    for a, b in zip(ours, theirs):              # sel, forced: bit for bit
+        np.testing.assert_array_equal(np.asarray(a)[mapped],
+                                      np.asarray(b)[mapped])
+    return np.asarray(ours[0])
+
+
+@pytest.mark.parametrize("scene", list(SCENES) + ["a_serving_step"])
+def test_score_kernel_in_interpret_mode_equals_the_gathered_scores(
+        scene, monkeypatch):
+    """``compressed_key_scores`` reads the compressed keys where they lie
+    in the pool, by block id; ``select_blocks`` over
+    ``gather_compressed_keys`` reads each row's whole table. Where the
+    selection reads a score the two agree to a float32 sum's order, and
+    the selections are the same bits."""
+    if scene == "a_serving_step":
+        q, ck, tables, q_pos = _step_scene()
+        height = 128
+    else:
+        q, _, _, ck, tables, q_pos, height = _scene(scene)
+        _set_tile_height(monkeypatch, height)
+        height = height or sp.tile_height(len(q_pos), 2, 16, jnp.float32)
+    got, want, walk = _scores_both_ways(q, ck, tables, q_pos)
+    sel = _assert_same_selection(got, want, tables, q_pos)
+    assert sel.any()
+    ucols = min(16, tables.shape[1])
+    fetched, shared = check_score_walk(walk, tables, q_pos, SPEC, BS, height,
+                                       8, ucols)
+    selecting = np.asarray(q_pos >= SPEC.dense_len) & np.asarray(
+        q_pos < PAD_POSITION) & np.asarray(tables[:, 0] >= 0)
+    assert (fetched > 0) == bool(selecting.any())
+    if scene == "a_serving_step":
+        # 124 rows of one slot: a column's keys are copied once for them
+        assert walk.lanes.shape == (1, 128, 2 * 128) and got.shape[-1] == 160
+        assert shared / (fetched + shared) > 0.9
+        # the decode rows' layers run over their part alone, the chunk's
+        # over the tile
+        depth = np.asarray(walk.depth)
+        narrow = np.asarray(walk.narrow).reshape(2, 128)
+        assert depth.tolist() == [4, 2]
+        assert narrow[0, :4].tolist() == [-1, 0, 0, 0]
+        assert narrow[1, :2].tolist() == [-1, 0]
+
+
+def test_a_step_below_dense_len_lists_no_pair_and_selects_every_causal_block():
+    rows = [(1, 20), (2, 63)] + [(0, p) for p in range(30, 43)] + [PAD]
+    rng, _, _, ck = _pools(13)
+    slot_tables = rng.permutation(64)[:36].reshape(3, 12)
+    tables = jnp.asarray([slot_tables[s] for s, _ in rows], jnp.int32)
+    q_pos = jnp.asarray([p for _, p in rows], jnp.int32)
+    q = jnp.asarray(rng.randn(16, 4, 16), jnp.float32)
+    got, want, walk = _scores_both_ways(q, ck, tables, q_pos)
+    assert np.asarray(walk.depth).tolist() == [0]
+    assert (np.asarray(walk.blocks) == -1).all()
+    assert np.asarray(walk.visits).tolist() == [0, 0]
+    assert (got == 0).all()
+    sel = _assert_same_selection(got, want, tables, q_pos)
+    p = np.asarray(q_pos)[:15]
+    assert (sel[:15].sum(-1) == (p // SPEC.block + 1)[:, None]).all()
+    assert not sel[15].any()
+
+
+def test_unmapped_columns_and_columns_beyond_the_row_are_never_fetched():
+    """A row at 100 of a table that maps columns 0-6 and 9 (a stale entry
+    beyond the row) beside a row at 130 whose table lost column 3: the
+    first fetches columns 0-6 (column 6 holds positions 96-111, its first
+    kernel ends at 99), the second 0-2 and 4-7 (column 8's first kernel
+    ends at 131)."""
+    rng, _, _, ck = _pools(14)
+    blocks = rng.permutation(64)[:24].reshape(2, 12)
+    blocks[0, 7:9] = blocks[0, 10:] = -1
+    blocks[1, 3] = blocks[1, 9:] = -1
+    tables = jnp.asarray(blocks, jnp.int32)
+    q_pos = jnp.asarray([100, 130], jnp.int32)
+    q = jnp.asarray(rng.randn(2, 4, 16), jnp.float32)
+    got, _, walk = _scores_both_ways(q, ck, tables, q_pos)
+    pairs, = scored_pairs(tables, q_pos, SPEC, BS, 8)
+    assert sorted(c for c, _ in pairs) == sorted(
+        list(range(7)) + [0, 1, 2, 4, 5, 6, 7])
+    listed = np.asarray(walk.blocks).reshape(8, 12)
+    assert {(c, int(b)) for j in range(8) for c, b in enumerate(listed[j])
+            if b >= 0} == set(pairs)
+    check_score_walk(walk, tables, q_pos, SPEC, BS, 8, 8, 12)
+    # what was not fetched reads 0, the rest is the keys' own
+    assert (got[0, ..., 7 * 8:] == 0).all()
+    assert (got[1, ..., 3 * 8:4 * 8] == 0).all()
+    assert (got[1, ..., 4 * 8:8 * 8] != 0).all()
+    assert (got[1, ..., 8 * 8:] == 0).all()
+
+
+def test_two_slots_that_share_a_prefix_block_are_one_pair():
+    """Two slots whose tables name the same pool blocks in columns 0 and
+    1 (a shared prefix) and their own beyond: the shared columns' keys
+    are copied once for both rows, and both rows read them."""
+    rng, _, _, ck = _pools(15)
+    blocks = rng.permutation(64)[:24].reshape(2, 12)
+    blocks[1, :2] = blocks[0, :2]
+    tables = jnp.asarray(blocks, jnp.int32)
+    q_pos = jnp.asarray([150, 170], jnp.int32)
+    q = jnp.asarray(rng.randn(2, 4, 16), jnp.float32)
+    got, want, walk = _scores_both_ways(q, ck, tables, q_pos)
+    _assert_same_selection(got, want, tables, q_pos)
+    fetched, shared = check_score_walk(walk, tables, q_pos, SPEC, BS, 8, 8,
+                                       12)
+    # columns 0-9 of the first row, 0-10 of the second, two of them one
+    assert (fetched, shared) == (10 + 11 - 2, 2)
+    listed = np.asarray(walk.blocks).reshape(8, 12)
+    assert (listed[1, :2] == -1).all() and (listed[1, 2:10] >= 0).all()
+    assert (listed[0, :2] == blocks[0, :2]).all()
+    np.testing.assert_array_equal(got[0, ..., :16], want[0, ..., :16])
+
+
 def test_compressed_keys_land_when_their_kernel_is_whole():
     """Rows written a chunk at a time: entry ``i`` of a sequence's
     compressed keys is the mean of its keys ``2i .. 2i + 3``, a kernel
@@ -504,6 +666,17 @@ def served():
     obs.get_registry().reset()
     for uid, prompt in prompts.items():
         eng.submit(prompt, new[uid], uid=uid)
+    steps, dispatch = [], eng._dispatch
+
+    def recording(fn, rows, width, *rest):
+        # every step's rows as the device sees them: table and position
+        steps.append(([eng._tables[r[0].slot].copy() for r in rows]
+                      + [np.full(12, -1)] * (width - len(rows)),
+                      [r[2] for r in rows]
+                      + [PAD_POSITION] * (width - len(rows))))
+        return dispatch(fn, rows, width, *rest)
+
+    eng._dispatch = recording
     while eng.has_work():
         eng.step()
     counters = {
@@ -512,7 +685,9 @@ def served():
         for name in ("nxd_sparse_columns_total",
                      "nxd_sparse_positions_total",
                      "nxd_sparse_block_visits_total",
+                     "nxd_sparse_key_visits_total",
                      "nxd_state_resets_total", "nxd_engine_rows_total")}
+    counters["steps"] = steps
     obs.disable()
     ps.destroy_model_parallel()
     return cfg, params, eng, prompts, new, counters
@@ -557,6 +732,16 @@ def test_sparse_and_state_counters(served):
     assert visits["fetched"] + visits["shared"] == sum(
         cols[k] for k in ("selected", "forced", "dense"))
     assert visits["shared"] > visits["fetched"] > 0
+    # the score kernel's copies, by a brute count over every step's
+    # tables and positions: one tile of 16 rows, three sparse layers
+    keys = counters["nxd_sparse_key_visits_total"]
+    want = [0, 0]
+    for tables, positions in counters["steps"]:
+        for pairs in scored_pairs(np.stack(tables), positions, SPEC, BS, 16):
+            want[0] += 3 * len(pairs)
+            want[1] += 3 * (sum(map(len, pairs.values())) - len(pairs))
+    assert [keys["fetched"], keys["shared"]] == want
+    assert keys["shared"] > 0 and keys["fetched"] > 0
     # three admissions and b's second
     assert counters["nxd_state_resets_total"][""] >= 4
 

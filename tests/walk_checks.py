@@ -6,6 +6,8 @@ selection)."""
 
 import numpy as np
 
+from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
+
 
 def check_tile_walk(walk, live, tables, rows, n_rep, runs=None):
     """What every walk of the kernel must hold (a full cache's and a
@@ -167,3 +169,72 @@ def check_sparse_walk(walk, parts, tables, rows, part_rows, width, per):
                         if live else 0)
             fetched += len(want)
     return fetched
+
+
+def scored_pairs(tables, q_pos, spec, block_size, rows):
+    """By loops over a step's rows: for every tile of ``rows`` rows, the
+    (column, block) pairs whose compressed keys the selection reads (a
+    real row at or past ``dense_len``, a mapped column whose first
+    kernel is whole at the row's position) -> the rows of the tile that
+    name each. A list of dicts, one a tile."""
+    tables, q_pos = np.asarray(tables), np.asarray(q_pos)
+    tiles = [{} for _ in range(-(-len(q_pos) // rows))]
+    for r, p in enumerate(q_pos):
+        if p >= PAD_POSITION or p < spec.dense_len:
+            continue
+        for c, b in enumerate(tables[r]):
+            if b >= 0 and c * block_size + spec.kernel - 1 <= p:
+                tiles[r // rows].setdefault((c, int(b)), []).append(
+                    r % rows)
+    return tiles
+
+
+def check_score_walk(walk, tables, q_pos, spec, block_size, rows, part_rows,
+                     ucols):
+    """What :func:`..ops.sparse_attention.score_walk` must hold, by brute
+    count: a unit's layers list every scored pair of the unit's columns
+    once and nothing else, a column's in the order of their first namers
+    (those that several parts of the tile name first),
+    ``depth`` is the most pairs a column of the unit has, ``lanes`` send
+    every scoring (row, column) to the layer that holds its own block and
+    every other to none, and a layer is narrow exactly if one part of the
+    tile holds all its namers. Returns ``(fetched, shared)``."""
+    want = scored_pairs(tables, q_pos, spec, block_size, rows)
+    maxb = np.asarray(tables).shape[1]
+    per = block_size // spec.stride
+    units = -(-maxb // ucols)
+    depth = np.asarray(walk.depth).reshape(len(want), units)
+    blocks = np.asarray(walk.blocks).reshape(len(want), units, rows, ucols)
+    narrow = np.asarray(walk.narrow).reshape(len(want), units, rows)
+    lanes = np.asarray(walk.lanes)
+    assert lanes.shape == (len(want), rows, units * ucols * per)
+    fetched = shared = 0
+    for i, pairs in enumerate(want):
+        fetched += len(pairs)
+        shared += sum(len(v) for v in pairs.values()) - len(pairs)
+        serves = np.full((rows, units * ucols), -1)
+        for u in range(units):
+            by_col = {}
+            for (c, b), namers in pairs.items():
+                if c // ucols == u:
+                    lone = len({r // part_rows for r in namers}) == 1
+                    by_col.setdefault(c, []).append((lone, namers[0], b,
+                                                     namers))
+            assert depth[i, u] == max(map(len, by_col.values()), default=0)
+            for j in range(rows):
+                namers = []
+                for k in range(ucols):
+                    mine = sorted(by_col.get(u * ucols + k, []))
+                    if j < len(mine):
+                        assert blocks[i, u, j, k] == mine[j][2]
+                        namers += mine[j][3]
+                        serves[mine[j][3], u * ucols + k] = j
+                    else:
+                        assert blocks[i, u, j, k] == -1
+                if j < depth[i, u]:
+                    where = {r // part_rows for r in namers}
+                    assert narrow[i, u, j] == (where.pop() if len(where) == 1
+                                               else -1)
+        np.testing.assert_array_equal(lanes[i], np.repeat(serves, per, -1))
+    assert np.asarray(walk.visits).tolist() == [fetched, shared]
+    return fetched, shared
