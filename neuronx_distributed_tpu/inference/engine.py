@@ -751,6 +751,12 @@ class ServingEngine:
         #: rows, a layer's worth: in_run, alone, whole
         #: (``nxd_mla_block_fetches_total``; a latent cache's alone)
         self._mla_fetches = np.zeros((3,), np.int64)
+        #: the paged kernel's pairs for these rows, a layer's worth, by how
+        #: it computes them: narrow, one_row_whole
+        #: (``nxd_paged_pairs_total``), shared
+        #: (``nxd_paged_shared_pairs_total``); not a latent cache's, whose
+        #: kernel takes its pairs in runs
+        self._pair_kinds = np.zeros((3,), np.int64)
         #: routed-expert assignments of the real rows that were kept and
         #: that were dropped, which the step of a family that declares
         #: ``moe_counts`` counts on the device (``cache.moe_counts``) and
@@ -2134,14 +2140,16 @@ class ServingEngine:
                 self._paged_cols += np.bincount(
                     kinds.ravel(), minlength=3)  # skipped, exact, summary
                 # and what its tiles fetch for them
-                from ..ops.paged_attention import tile_pairs, tile_rows
+                from ..ops.paged_attention import (pair_kinds, tile_pairs,
+                                                   tile_rows)
 
                 mcfg = self.model_cfg
+                served = np.where(kinds > 0, tbl, -1)
+                n_rep = (mcfg.num_heads // mcfg.num_kv_heads
+                         * self._cache_kind.pack)
                 fetched = int(tile_pairs(
-                    np.where(kinds > 0, tbl, -1),
-                    tile_rows(mcfg.num_heads // mcfg.num_kv_heads
-                              * self._cache_kind.pack, width),
-                    self._pool_blocks, xp=np)[0].sum())
+                    served, tile_rows(n_rep, width), self._pool_blocks,
+                    xp=np)[0].sum())
                 self._block_visits += (
                     fetched, np.count_nonzero(kinds) - fetched)
                 if self._cache_kind.name == "latent":
@@ -2149,9 +2157,12 @@ class ServingEngine:
                     from ..ops.mla_attention import block_fetches
 
                     self._mla_fetches += block_fetches(
-                        np.where(kinds > 0, tbl, -1), mcfg.num_heads,
-                        self._cache_kind.row, self.ecfg.block_size,
-                        self.cache.rows.dtype.itemsize)
+                        served, mcfg.num_heads, self._cache_kind.row,
+                        self.ecfg.block_size, self.cache.rows.dtype.itemsize)
+                else:
+                    # or over which rows the paged kernel computes them
+                    self._pair_kinds += pair_kinds(served, n_rep,
+                                                   self._pool_blocks)
         with tracer.span(span + "/dispatch"):
             if self._spec is not None:
                 sampled, self.cache, self.dcache = fn(
@@ -2760,6 +2771,27 @@ class ServingEngine:
                     "tile.",
                     labels=("kind",)).labels(kind=k)
                     for k in ("in_run", "alone", "whole")))
+            paged = (visits_c is not None
+                     and self._cache_kind.name != "latent")
+            pairs_by_kind = () if not paged else tuple(
+                [reg.counter(
+                    "nxd_paged_pairs_total",
+                    "Pairs (table column, pool block) that one packed row "
+                    "of its tile alone names, or neighbouring rows whose "
+                    "heads one group of the tile holds (one layer's "
+                    "worth), by the rows the paged kernel computes them "
+                    "over: narrow, the group that holds the naming rows' "
+                    "heads, or one_row_whole, the whole tile although "
+                    "one row names the pair.",
+                    labels=("kind",)).labels(kind=k)
+                 for k in ("narrow", "one_row_whole")]
+                + [reg.counter(
+                    "nxd_paged_shared_pairs_total",
+                    "Pairs that several packed rows of a tile name and "
+                    "no one group holds (a prefill chunk's blocks): the "
+                    "paged kernel computes them over the whole tile. "
+                    "With nxd_paged_pairs_total's two kinds they sum to "
+                    "nxd_paged_block_visits_total's fetched.")])
             moe_by_kind = () if not self._moe_on_device else tuple(
                 reg.counter(
                     "nxd_moe_assignments_total",
@@ -2821,11 +2853,12 @@ class ServingEngine:
                 cols_by_kind, events_c, visits_by_kind, moe_by_kind,
                 {k: steps_c.labels(kind=k)
                  for k in ("overlapped", "serial")}, state_by_kind,
-                fetches_by_kind, moe_held_by_kind, window_by_kind)
+                fetches_by_kind, moe_held_by_kind, window_by_kind,
+                pairs_by_kind)
         (_, _, fields, free_g, step_h, rows_by_kind, cols_by_kind,
          events_c, visits_by_kind, moe_by_kind, steps_by_kind,
          state_by_kind, fetches_by_kind, moe_held_by_kind,
-         window_by_kind) = cache
+         window_by_kind, pairs_by_kind) = cache
         st = self.stats
         for f, child in fields.items():
             child.set(float(getattr(st, f)))
@@ -2845,6 +2878,9 @@ class ServingEngine:
         for child, n in zip(fetches_by_kind, self._mla_fetches):
             child.inc(int(n))
         self._mla_fetches[:] = 0
+        for child, n in zip(pairs_by_kind, self._pair_kinds):
+            child.inc(int(n))
+        self._pair_kinds[:] = 0
         for child, n in zip(moe_by_kind, self._moe_assignments):
             child.inc(int(n))
         kept, dropped, elsewhere = self._moe_assignments

@@ -49,8 +49,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..inference.kv_cache import PAD_POSITION
-from .paged_attention import (TileWalk, narrow_rows, paged_attention_impl,
-                              tile_rows, tile_walk)
+from .paged_attention import (TileWalk, paged_attention_impl, tile_rows,
+                              tile_walk)
 from .pallas_utils import compiler_params as _compiler_params
 
 LANES = 128
@@ -70,8 +70,10 @@ def row_width(rank: int, rope: int) -> int:
 
 def stacked_heads(num_heads: int) -> int:
     """Query heads of a packed row as the walk stacks them: whole
-    sublanes, so each row's heads are one narrow group of its tile."""
-    return narrow_rows(num_heads)
+    sublanes (20 ride as 24), so each row's heads are one narrow group of
+    its tile (:func:`.paged_attention.narrow_rows` of whole sublanes is
+    their own number)."""
+    return -(-num_heads // 8) * 8
 
 
 def mla_attention_impl(row: int, rank: int, block_size: int,

@@ -32,9 +32,12 @@ Two implementations behind one signature, following
   once a row, and the kernel's time follows the pairs and not the
   table's width. The rows
   of the tile that do not name a pair's block are masked (``-inf``, as a
-  dead slot position is); where a single group of rows names it (a
-  decode row's blocks: its neighbours are other slots') the products run
-  over that group alone.
+  dead slot position is); where one packed row names it (a decode row's
+  blocks: its neighbours are other slots') the products run over a
+  narrow group of the tile alone: the whole sublanes that hold the row's
+  ``n_rep`` stacked heads wherever they begin (:func:`narrow_rows`,
+  :func:`narrow_start`; 8 stacked rows under GQA-4, GQA-8 and MHA, 16
+  where the heads cross sublanes, 6 or 9 of them).
 
   The walk (:func:`tile_walk`) lists, a tile, the distinct pairs that
   are live for at least one of its rows, each once. Live is
@@ -78,6 +81,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..inference.kv_cache import PAD_POSITION, dequantize_kv
 from ..modules.attention import repeat_kv
@@ -210,8 +214,9 @@ def sliding_column_live(entry, column, q_pos, block_size: int, sliding: int,
 
 def tile_rows(n_rep: int, tokens: int) -> int:
     """Rows of a tile of the kernel: with the ``n_rep`` query heads of a
-    K/V head stacked, about the MXU's 128 rows (32 under GQA-4, 128 under
-    MHA, 20 under GQA-6, 8 under GQA-9) and whole sublanes of them, and
+    K/V head stacked (row ``r``'s at ``r * n_rep`` of the tile, unpadded),
+    about the MXU's 128 rows (32 under GQA-4, 128 under MHA, 16 under
+    GQA-8, 20 under GQA-6, 8 under GQA-9) and whole sublanes of them, and
     no more than the packed rows there are."""
     rows = max(8, 128 // n_rep)
     while rows * n_rep % 8:
@@ -221,8 +226,32 @@ def tile_rows(n_rep: int, tokens: int) -> int:
 
 def narrow_rows(n_rep: int) -> int:
     """Rows (query heads stacked) of the narrow product of a pair that a
-    single packed row names: its ``n_rep`` heads, in whole sublanes."""
-    return -(-n_rep // 8) * 8
+    single packed row names: whole sublanes that hold the row's ``n_rep``
+    heads wherever they begin. Row ``r``'s heads begin at stacked row ``r
+    * n_rep``, a multiple of ``gcd(n_rep, 8)`` past a whole sublane, so
+    at most ``8 - gcd(n_rep, 8)`` past it: 8 rows for 1, 4 and 8 heads,
+    16 for 6, 9 and 10, and their own number for heads of whole
+    sublanes, which begin on one."""
+    return -(-(8 - math.gcd(n_rep, 8) + n_rep) // 8) * 8
+
+
+def narrow_start(first, last, n_rep: int, wide: int, xp=jnp):
+    """Where the narrow product of a pair begins in a tile of ``wide``
+    stacked rows, or -1 where it runs over the whole tile: ``first`` the
+    stacked row of the first head that names the pair, ``last`` one past
+    the last such head. The group of :func:`narrow_rows` rows begins at
+    the whole sublane ``first`` lies in, or where the tile's last group
+    does if that is earlier (a group wider than the heads' own sublanes
+    would pass the tile's end from its last rows: at ``n_rep`` 6 row
+    19's heads lie at 114..119 of 120, and its group begins at 104). A
+    pair that one packed row names always fits; one that neighbouring
+    rows name, where they all do. Broadcasts over jnp arrays (the walk)
+    and NumPy ones (:func:`pair_kinds`, the tests)."""
+    group = narrow_rows(n_rep)
+    start = first // 8 * 8
+    if group > -(-n_rep // 8) * 8:      # else no row's group passes the end
+        start = xp.minimum(start, wide - group)
+    return xp.where(last <= start + group, start, -1)
 
 
 def tile_pairs(served, rows: int, num_blocks: int, xp=jnp):
@@ -257,13 +286,41 @@ def tile_pairs(served, rows: int, num_blocks: int, xp=jnp):
             (key // num_blocks).astype(xp.int32))
 
 
+def pair_kinds(served, n_rep: int, num_blocks: int):
+    """A step's pairs (one layer's worth) by how the kernel computes
+    them, ``[narrow, one_row_whole, shared]``, from ``served [T,
+    max_blocks_per_seq]`` (NumPy: a row's table entry in the columns it
+    attends, -1 elsewhere): ``narrow``, over one group of the tile
+    (:func:`narrow_start`, the walk's own rule); ``one_row_whole``, a
+    pair that one packed row alone names and that runs over the whole
+    tile all the same; ``shared``, a pair that several rows of the tile
+    name and no group holds. They sum to :func:`tile_pairs`' count. The
+    engine's ``nxd_paged_pairs_total`` and
+    ``nxd_paged_shared_pairs_total``, counted on the host."""
+    tokens, maxb = served.shape
+    rows = tile_rows(n_rep, tokens)
+    row, col = np.nonzero(served >= 0)               # by row, then column
+    key = ((row // rows).astype(np.int64) * maxb + col) * num_blocks + served[
+        row, col]
+    _, first, pair = np.unique(key, return_index=True, return_inverse=True)
+    first = row[first] % rows                        # a pair's first namer
+    last = np.zeros_like(first)
+    np.maximum.at(last, pair, row % rows)
+    narrow = narrow_start(first * n_rep, (last + 1) * n_rep, n_rep,
+                          rows * n_rep, xp=np) >= 0
+    return np.array([narrow.sum(), (~narrow & (first == last)).sum(),
+                     (~narrow & (first != last)).sum()], np.int64)
+
+
 class TileWalk(NamedTuple):
     """What the kernel is handed of a step's routing
     (:func:`tile_walk`), the same for every layer: ``count [tiles]``,
     ``blocks`` and ``cols [tiles * P]`` the tiles' pairs
-    (:func:`tile_pairs`); ``narrow [tiles * P]``, where the rows that
-    name a pair lie inside one group of :func:`narrow_rows` rows of the
-    tile (a decode row's pairs: nothing to share), the group's first row,
+    (:func:`tile_pairs`); ``narrow [tiles * P]``, where one group of
+    :func:`narrow_rows` stacked rows of the tile holds the heads of every
+    row that names a pair (every pair that one packed row names, a decode
+    row's: nothing to share; neighbours that share a block where they
+    fit), the group's first row, a whole sublane (:func:`narrow_start`),
     else -1; and a tile at a time with each of its ``rows`` rows repeated
     once a query head of a K/V head (row ``r * n_rep + rep`` of the tile
     is packed row ``r``'s head ``rep``): ``served [tiles, rows * n_rep,
@@ -316,9 +373,7 @@ def tile_walk(tables, q_pos, block_size: int, num_blocks: int, n_rep: int,
         == blocks[:, :, None])                           # [tiles, P, rows]
     first = jnp.argmax(names, axis=-1) * n_rep
     last = (rows - jnp.argmax(names[:, :, ::-1], axis=-1)) * n_rep
-    group = narrow_rows(n_rep)
-    start = first // group * group
-    narrow = jnp.where(last <= start + group, start, -1).astype(jnp.int32)
+    narrow = narrow_start(first, last, n_rep, rows * n_rep).astype(jnp.int32)
 
     def by_tile(x):
         return jnp.repeat(x, n_rep, axis=0).reshape(
@@ -419,9 +474,9 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
     ``p`` float32 (:func:`_p_times_v`). An int8 block is widened exactly
     and its scales multiply the score and the probability. Where one
     group of ``group`` rows holds every row that names the pair
-    (``narrow_ref``: a decode row's block, which its neighbours in the
-    tile do not share) the products and the softmax run over that group
-    alone.
+    (``narrow_ref``, the group's first row, a whole sublane: a decode
+    row's block, which its neighbours in the tile do not share) the
+    products and the softmax run over that group alone.
 
     With ``window`` (a window-summary cache, the ``eva_attention``
     kernel) the rows of columns under the ring's width are exact and

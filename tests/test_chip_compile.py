@@ -1078,13 +1078,17 @@ def test_state_pool_step_at_the_published_widths(chip, topo, on_one_chip,
 def test_paged_attention_at_two_head_counts(chip, n_rep, cols, sliding):
     """The kernel at ``n_rep`` 6 (tiles of 20 rows) and, with a causal
     window over a slot's ring, at ``n_rep`` 9 (tiles of 8 rows), which is
-    then ``swa_attention`` in a trace."""
+    then ``swa_attention`` in a trace. At both a decode row's narrow
+    product is 16 stacked rows from the whole sublane its first head lies
+    in: a 16-row slice of the bf16 queries (16 rows a vreg) at a start
+    that is a multiple of 8 and not of 16, which Mosaic takes."""
     from neuronx_distributed_tpu.ops.paged_attention import (
-        _paged_attention_pallas, tile_rows)
+        _paged_attention_pallas, narrow_rows, tile_rows)
 
     tokens, kv, d, bs, layers = 128, 8, 128, 128, 2
     nb = 320 if sliding else 3072
     assert tile_rows(n_rep, tokens) * n_rep in (120, 72)
+    assert narrow_rows(n_rep) == 16
     pool = chip((layers, nb, bs, kv, d), jnp.bfloat16)
     fn = functools.partial(_paged_attention_pallas,
                            scale=1.0 / math.sqrt(d), interpret=False,

@@ -540,7 +540,9 @@ def served():
             2, RING, BS) < PAD_POSITION).any(-1).sum(-1).max()))
     names = ("nxd_window_columns_total", "nxd_kv_blocks_held_total",
              "nxd_moe_held_total", "nxd_moe_assignments_total",
-             "nxd_paged_columns_total")
+             "nxd_paged_columns_total", "nxd_paged_pairs_total",
+             "nxd_paged_shared_pairs_total",
+             "nxd_paged_block_visits_total")
     counters = {
         name: {c.labels.get("kind", ""): c.value
                for c in obs.get_registry().get(name).children()}
@@ -601,6 +603,20 @@ def test_window_counters(served):
     assert kept["dropped"] == 0
     assert moe["held"] == kept["kept"] and moe["elsewhere"] > 0
     assert (moe["held"] + moe["elsewhere"]) % (4 * 3) == 0
+
+
+def test_no_decode_rows_pair_runs_over_the_whole_tile(served):
+    """The full layers' pairs a step by how the kernel computes them: a
+    pair that one packed row names is narrow whatever the row's place
+    (here a chunk's too: its four rows of two heads lie in one group of
+    8), and with the shared ones they are the pairs fetched."""
+    *_, counters, _ = served
+    pairs = counters["nxd_paged_pairs_total"]
+    assert set(pairs) == {"narrow", "one_row_whole"}
+    assert pairs["narrow"] > 0 and pairs["one_row_whole"] == 0
+    shared = counters["nxd_paged_shared_pairs_total"][""]
+    assert pairs["narrow"] + shared == counters[
+        "nxd_paged_block_visits_total"]["fetched"]
 
 
 @pytest.mark.parametrize("feature,kw", [

@@ -661,6 +661,56 @@ def test_engine_paged_columns_counter_sums_to_rows_times_columns():
     assert visits["shared"] > 0         # a prefill chunk's rows share blocks
 
 
+def test_engine_paged_pairs_counters_split_the_fetched_pairs_by_kind():
+    """``nxd_paged_pairs_total``'s two kinds and
+    ``nxd_paged_shared_pairs_total`` are, a step, the ``fetched`` of
+    ``nxd_paged_block_visits_total`` by the rows the kernel computes
+    them over, and :func:`pair_kinds` of the step's own tables; no pair
+    that one row names runs over the whole tile."""
+    from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
+    from neuronx_distributed_tpu.ops.paged_attention import (column_live,
+                                                             pair_kinds)
+
+    obs.enable()
+    eng = _tiny_engine()
+    mcfg, width = eng.model_cfg, eng.ecfg.token_budget
+    n_rep = mcfg.num_heads // mcfg.num_kv_heads
+    packed = []
+    run_worker = eng._dispatch
+
+    def spy(fn, rows, *args):
+        tables = np.full((width, eng.ecfg.max_blocks_per_seq), -1)
+        q_pos = np.full((width,), PAD_POSITION)
+        for i, r in enumerate(rows):
+            tables[i], q_pos[i] = eng._tables[r[0].slot], r[2]
+        live = column_live(tables, np.arange(tables.shape[1]),
+                           q_pos[:, None], eng.ecfg.block_size)
+        packed.append(pair_kinds(np.where(live, tables, -1), n_rep,
+                                 eng._pool_blocks))
+        return run_worker(fn, rows, *args)
+
+    def read():
+        reg = obs.get_registry()
+        kinds = {c.labels["kind"]: c.value
+                 for c in reg.get("nxd_paged_pairs_total").children()}
+        shared, = reg.get("nxd_paged_shared_pairs_total").children()
+        fetched = {c.labels["kind"]: c.value for c in reg.get(
+            "nxd_paged_block_visits_total").children()}["fetched"]
+        return np.array([kinds["narrow"], kinds["one_row_whole"],
+                         shared.value]), fetched
+
+    eng._dispatch = spy
+    before = (np.zeros(3), 0)
+    while eng.has_work():
+        if not eng.step() or not packed:
+            continue    # nothing ran, or the call only landed a step
+        now = read()
+        np.testing.assert_array_equal(now[0] - before[0], packed.pop())
+        assert now[0].sum() == now[1]
+        before = now
+    assert before[0][0] > 0 and before[0][1] == 0
+
+
 def test_engine_with_obs_off_records_no_span_and_no_rows_counter():
     assert not obs.enabled()
     eng = _tiny_engine()

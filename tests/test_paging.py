@@ -20,13 +20,15 @@ from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
                                                   tiny_config)
 from neuronx_distributed_tpu.models.mixtral import (MixtralForCausalLM,
                                                     tiny_moe_config)
+from neuronx_distributed_tpu.ops import paged_attention as pa
 from neuronx_distributed_tpu.ops.paged_attention import (column_live,
                                                           paged_attention,
+                                                          pair_kinds,
                                                           tile_pairs,
                                                           tile_rows,
                                                           tile_walk)
 from neuronx_distributed_tpu.parallel import mesh as ps
-from walk_checks import check_tile_walk
+from walk_checks import check_tile_walk, narrow_group
 
 
 # ---------------------------------------------------------------------------
@@ -359,6 +361,163 @@ def test_tile_walk_serves_every_live_column_by_one_pair_of_its_tile(case):
         # a tile of one slot's rows fetches each of its blocks once
         assert count[1] == int(live[8:16].any(0).sum())
         assert int(live[8:16].sum()) > 2 * count[1]
+
+
+def _decode_rows_beside_a_chunk(n_rep, bs=4, maxb=5, seed=8):
+    """A packed step as the engine packs it, two tiles of rows: decode
+    rows of slots that share nothing, a whole tile of them and more (so
+    every place of a tile holds one, its last among them), then a chunk
+    of one slot; rows 1 and 2, and rows 4 and 5, have forked from one
+    prefix block. ``(tables, q_pos, num_blocks, rows)``."""
+    rng = np.random.RandomState(seed)
+    rows = tile_rows(n_rep, 2 * 128)
+    t = 2 * rows
+    chunk = min(rows // 2, 12)
+    slots = t - chunk + 1
+    tables = np.full((t, maxb), -1, np.int64)
+    q_pos = np.zeros((t,), np.int64)
+    for r in range(t):
+        slot = min(r, slots - 1)
+        if slot < slots - 1:
+            q_pos[r] = rng.randint(0, bs * maxb)
+        else:
+            q_pos[r] = bs * maxb - chunk + (r - slot)
+        held = q_pos[r] // bs + 1
+        tables[r, :held] = slot * maxb + np.arange(held)
+    tables[2, 0] = tables[1, 0]
+    tables[5, 0] = tables[4, 0]
+    return tables, q_pos, slots * maxb, rows
+
+
+def _pairs_by_brute_count(live, tables, rows, n_rep, group):
+    """``[narrow, one_row_whole, shared]`` over a step's tiles by loops:
+    a pair is narrow where ``group`` rows from the sublane of the first
+    head that names it (from the tile's last group, if earlier) hold the
+    last one too."""
+    kinds = [0, 0, 0]
+    for i in range(-(-len(tables) // rows)):
+        want = {}
+        for r in range(i * rows, min((i + 1) * rows, len(tables))):
+            for c in np.flatnonzero(live[r]):
+                want.setdefault((int(c), int(tables[r, c])), []).append(
+                    r - i * rows)
+        for namers in want.values():
+            begin = min(namers[0] * n_rep // 8 * 8, rows * n_rep - group)
+            if (namers[-1] + 1) * n_rep <= begin + group:
+                kinds[0] += 1
+            else:
+                kinds[1 if len(namers) == 1 else 2] += 1
+    return kinds
+
+
+@pytest.mark.parametrize("n_rep", [1, 4, 6, 8, 9, 10])
+def test_every_pair_that_one_packed_row_names_is_narrow(n_rep):
+    """Whatever the head count, and wherever in its tile the row lies
+    (the last row, whose group ends with the tile, included): a decode
+    row's pair runs over one group of :func:`narrow_rows` rows. Two
+    neighbours that share a block stay narrow where one group holds
+    both."""
+    bs = 4
+    tables, q_pos, nb, rows = _decode_rows_beside_a_chunk(n_rep)
+    group = narrow_group(n_rep)
+    assert pa.narrow_rows(n_rep) == group == {
+        1: 8, 4: 8, 6: 16, 8: 8, 9: 16, 10: 16}[n_rep]
+    assert group <= rows * n_rep and rows * n_rep % 8 == 0
+    walk = jax.tree_util.tree_map(np.asarray, tile_walk(
+        jnp.asarray(tables, jnp.int32), jnp.asarray(q_pos, jnp.int32), bs,
+        nb, n_rep))
+    live = column_live(tables, np.arange(tables.shape[1]), q_pos[:, None],
+                       bs)
+    check_tile_walk(walk, live, tables, rows, n_rep)
+    per = walk.blocks.size // len(walk.count)
+    seen = set()
+    for i, n in enumerate(walk.count):
+        for j in range(n):
+            col, blk = walk.cols[i * per + j], walk.blocks[i * per + j]
+            namers = np.flatnonzero(
+                live[i * rows:(i + 1) * rows, col]
+                & (tables[i * rows:(i + 1) * rows, col] == blk))
+            start = walk.narrow[i * per + j]
+            if len(namers) == 1:
+                seen.add(int(namers[0]))
+                first = namers[0] * n_rep
+                assert 0 <= start <= first
+                assert first + n_rep <= start + group <= rows * n_rep
+            elif blk == tables[1, 0]:
+                # rows 1 and 2: n_rep .. 3 n_rep, from sublane n_rep // 8
+                assert (start >= 0) == (3 * n_rep <= n_rep // 8 * 8 + group)
+    assert set(range(rows)) <= seen                 # every place of a tile
+    kinds = pair_kinds(np.where(live, tables, -1), n_rep, nb)
+    assert kinds.tolist() == _pairs_by_brute_count(live, tables, rows,
+                                                   n_rep, group)
+    assert kinds[0] > 0 and kinds[1] == 0 and kinds[2] > 0
+    assert kinds.sum() == walk.count.sum()
+
+
+@pytest.mark.parametrize("n_rep,group", [(6, 8), (6, 16), (9, 16), (4, 8)])
+def test_pair_kinds_are_the_brute_count_of_a_steps_tables(monkeypatch, n_rep,
+                                                          group):
+    """The engine's ``nxd_paged_pairs_total`` and
+    ``nxd_paged_shared_pairs_total`` by loops over the tables, at the
+    kernel's group and, at 6 heads, at the 8 rows the group was before
+    PR 43 (rows whose heads cross a multiple of 8 ran over the whole
+    tile)."""
+    monkeypatch.setattr(pa, "narrow_rows", lambda n: group)
+    tables, q_pos, nb, rows = _decode_rows_beside_a_chunk(n_rep, seed=9)
+    live = column_live(tables, np.arange(tables.shape[1]), q_pos[:, None],
+                       4)
+    kinds = pair_kinds(np.where(live, tables, -1), n_rep, nb)
+    assert kinds.tolist() == _pairs_by_brute_count(live, tables, rows,
+                                                   n_rep, group)
+    assert kinds.sum() == tile_pairs(np.where(live, tables, -1), rows, nb,
+                                     xp=np)[0].sum()
+    straddles = (n_rep, group) == (6, 8)
+    assert (kinds[1] > 0) == straddles
+    if straddles:
+        # about every second decode row's heads cross a multiple
+        assert 0.3 < kinds[1] / (kinds[0] + kinds[1]) < 0.7
+
+
+@pytest.mark.parametrize("sliding", [None, 8], ids=["full", "sliding"])
+@pytest.mark.parametrize("n_rep", [6, 9])
+def test_paged_kernel_at_heads_that_cross_sublanes_matches_xla(n_rep,
+                                                               sliding):
+    """The kernel (interpret mode) against the gather reference at 6 and
+    9 query heads a K/V head, whose rows begin at every offset of a
+    sublane: decode rows in every place of a tile, its last rows among
+    them, a chunk beside them, with and without a causal window over a
+    ring."""
+    bs, kv, d = 4, 2, 16
+    rng = np.random.RandomState(n_rep)
+    tables, q_pos, nb, rows = _decode_rows_beside_a_chunk(n_rep)
+    t, maxb = tables.shape
+    pool_pos = np.full((nb, bs), PAD_POSITION, np.int32)
+    if sliding is None:
+        for blk in range(nb):
+            pool_pos[blk] = blk % maxb * bs + np.arange(bs)
+    else:
+        # a slot's ring of 3 blocks, its own alone: position p in ring
+        # column (p // bs) % 3, later laps overwrite
+        ring = sliding // bs + 1
+        slot = np.minimum(np.arange(t), nb // maxb - 1)
+        tables = slot[:, None] * maxb + np.arange(ring)
+        for s in range(nb // maxb):
+            for p in range(q_pos[slot == s].max() + 1):
+                pool_pos[s * maxb + p // bs % ring, p % bs] = p
+    q = jnp.asarray(rng.randn(t, kv * n_rep, d).astype(np.float32))
+    k = jnp.asarray(rng.randn(2, nb, bs, kv, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(2, nb, bs, kv, d).astype(np.float32))
+    args = (q, k, v, jnp.asarray(pool_pos), jnp.asarray(tables, jnp.int32),
+            jnp.asarray(q_pos, jnp.int32), 1)
+    ref = paged_attention(*args, force_pallas=False, sliding=sliding)
+    ker = paged_attention(*args, force_pallas=True, sliding=sliding)
+    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), rtol=1e-5,
+                               atol=1e-5)
+    # the walk the kernel ran: no one-row pair over the whole tile
+    walk = tile_walk(args[4], args[5], bs, nb, n_rep, sliding=sliding)
+    narrow = np.asarray(walk.narrow).reshape(len(walk.count), -1)
+    assert sum((narrow[i, :n] >= 0).sum()
+               for i, n in enumerate(np.asarray(walk.count))) >= rows
 
 
 def test_paged_attention_validates_scales_and_heads():

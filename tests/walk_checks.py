@@ -9,12 +9,24 @@ import numpy as np
 from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
 
 
+def narrow_group(n_rep):
+    """Stacked rows of the kernel's narrow product, from ``n_rep`` alone:
+    a packed row's ``n_rep`` heads begin a multiple of ``gcd(n_rep, 8)``
+    past a whole sublane, and the group is the whole sublanes that hold
+    them from the furthest such offset (1, 4, 8 -> 8; 6, 9, 10 -> 16)."""
+    furthest = max(r * n_rep % 8 for r in range(8))
+    return -(-(furthest + n_rep) // 8) * 8
+
+
 def check_tile_walk(walk, live, tables, rows, n_rep, runs=None):
     """What every walk of the kernel must hold (a full cache's and a
     window-summary cache's alike, ``tests/test_evabyte.py``): each live
     (row, column) is served by exactly one pair of its tile, no pair is
     listed twice, a tile's count is the brute count of its distinct
-    pairs, and a pair is narrow only if one group of rows names it.
+    pairs, and a pair is narrow exactly if one group of rows holds the
+    heads that name it: :func:`narrow_group` rows from the whole sublane
+    the first of them lies in (from the tile's last group, if that is
+    earlier), so every pair that one packed row names is narrow.
     ``runs``, the latent kernel's cut of this walk into units
     (``(run_walk, run, whole_run)``), is held to :func:`check_pair_runs`,
     whose count of the fetches by kind is returned."""
@@ -22,7 +34,7 @@ def check_tile_walk(walk, live, tables, rows, n_rep, runs=None):
     tiles = len(walk.count)
     per = walk.blocks.size // tiles
     assert per == rows * maxb and tiles * rows >= t
-    group = -(-n_rep // 8) * 8
+    group = narrow_group(n_rep)
     for i in range(tiles):
         mine = range(i * rows, min((i + 1) * rows, t))
         want = {}           # (column, block) -> the tile's rows that name it
@@ -38,10 +50,11 @@ def check_tile_walk(walk, live, tables, rows, n_rep, runs=None):
             first = (want[pair][0] - i * rows) * n_rep
             last = (want[pair][-1] - i * rows + 1) * n_rep
             start = int(walk.narrow[i * per + j])
-            if last <= first // group * group + group:
-                assert start == first // group * group
+            begin = min(first // 8 * 8, rows * n_rep - group)
+            if last <= begin + group:
+                assert start == begin and start % 8 == 0
             else:
-                assert start == -1
+                assert start == -1 and len(want[pair]) > 1
         # what the kernel masks by: a row's entry where it attends
         served = walk.served[i].reshape(rows, n_rep, maxb)
         assert (served == served[:, :1]).all()
