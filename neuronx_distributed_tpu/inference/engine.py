@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import types
 from collections import deque
 from typing import (Any, Callable, Deque, Dict, List, Optional, Sequence,
                     Tuple)
@@ -49,29 +50,29 @@ from ..resilience.integrity import (
 )
 from .aot_cache import AotExecutableCache, AotWorker, source_fingerprint
 from .kv_cache import PAD_POSITION
-from .paging import (PAYLOAD_BLOCK_AXES, BlockAllocator, CacheExhaustedError,
-                     PrefixCache, cow_copy_blocks, extract_blocks,
-                     flat_write_indices, init_serving_cache, inject_blocks,
-                     mask_pool_positions)
+from .paging import (COUNT_LEAVES, PAYLOAD_BLOCK_AXES, BlockAllocator,
+                     CacheExhaustedError, PrefixCache, cow_copy_blocks,
+                     extract_blocks, flat_write_indices, init_serving_cache,
+                     inject_blocks, mask_pool_positions, step_counter)
 from .sampling import SamplingConfig, sample
 from .speculative import (SpeculationConfig, branch_of_nodes,
                           build_medusa_tree, medusa_accept_longest)
 
 
 #: leaves of a serving cache that the host writes before a step (and the
-#: forward hands back as it got them), and those a step leaves for the host
-#: to read after it (what its kernels' walks and its router did). Neither
-#: is donated to the packed step: the host may hold such an array, and read
-#: it, while the step after the one that made it runs.
+#: forward hands back as it got them). Neither they nor those a step leaves
+#: for the host to read after it (``paging.COUNT_LEAVES``: what its kernels'
+#: walks and its router did) are donated to the packed step: the host may
+#: hold such an array, and read it, while the step after the one that made
+#: it runs.
 _HOST_WRITTEN = ("block_tables", "lengths")
-_HOST_READ = ("counts", "moe_counts")
 
 
 def _hold_out(cache):
     """``(pool, held)``: ``cache`` with the leaves the host writes or reads
     set to ``None``, and those leaves by name; ``pool.replace(**held)`` is
     ``cache`` again."""
-    held = {n: getattr(cache, n) for n in _HOST_WRITTEN + _HOST_READ
+    held = {n: getattr(cache, n) for n in _HOST_WRITTEN + COUNT_LEAVES
             if getattr(cache, n, None) is not None}
     return cache.replace(**dict.fromkeys(held)), held
 
@@ -528,14 +529,13 @@ class EngineStats:
 class _InFlight:
     """A worker's step between its enqueue and the host's reading of it:
     the rows it packed, each with its request's ``epoch`` then, and what
-    the device returns for them (``sampled`` and, where the family counts
-    on the device, the counts: device arrays until the fetch, host arrays
-    after it)."""
+    the device returns for them (``sampled``, a device array until the
+    fetch and a host array after it, and, where the family counts on the
+    device and obs is on, each declared leaf with its declaration)."""
     rows: List[Tuple]
     epochs: List[int]
     sampled: Any
-    counts: Any = None
-    moe_counts: Any = None
+    counts: Sequence[Tuple[Any, Any]] = ()
 
 
 class ServingEngine:
@@ -727,66 +727,23 @@ class ServingEngine:
             np.int32)
         self._slot_blocks: List[List[int]] = (
             [[] for _ in range(engine_cfg.max_slots)])
-        #: what the attention kernel's walk did with the rows packed since
-        #: the last publish (obs on only). Skipped, exact and summary table
-        #: columns, counted on the host as the rows are packed:
-        #: ``nxd_paged_columns_total`` or, for a window-summary cache,
-        #: ``nxd_eva_columns_total``. A sparse-state cache's selections are
-        #: known to the device alone: the step leaves their ten counts in
-        #: ``cache.counts`` and the fetch adds them here
-        #: (``nxd_sparse_columns_total``, ``nxd_sparse_positions_total``,
-        #: ``nxd_sparse_block_visits_total``,
-        #: ``nxd_sparse_key_visits_total``)
-        from ..ops.sparse_attention import COUNT_KINDS
-
-        self._counts_on_device = self._cache_kind.name == "sparse_state"
-        self._paged_cols = np.zeros(
-            (len(COUNT_KINDS) if self._counts_on_device else 3,), np.int64)
-        #: what the kernel's tiles fetched for those rows, by the walk's own
-        #: function: pool blocks fetched, one a (tile, pair), and the further
-        #: live (row, column) each fetch served
-        #: (``nxd_paged_block_visits_total``)
-        self._block_visits = np.zeros((2,), np.int64)
-        #: pool blocks the mla_paged_attention kernel fetches for these
-        #: rows, a layer's worth: in_run, alone, whole
-        #: (``nxd_mla_block_fetches_total``; a latent cache's alone)
-        self._mla_fetches = np.zeros((3,), np.int64)
-        #: the paged kernel's pairs for these rows, a layer's worth, by how
-        #: it computes them: narrow, one_row_whole
-        #: (``nxd_paged_pairs_total``), shared
-        #: (``nxd_paged_shared_pairs_total``); not a latent cache's, whose
-        #: kernel takes its pairs in runs
-        self._pair_kinds = np.zeros((3,), np.int64)
-        #: routed-expert assignments of the real rows that were kept and
-        #: that were dropped, which the step of a family that declares
-        #: ``moe_counts`` counts on the device (``cache.moe_counts``) and
-        #: the fetch adds here (``nxd_moe_assignments_total``)
-        #: and, where the device holds a share of the experts (a third
-        #: count in the leaf), those that chose an expert held elsewhere
-        #: (``nxd_moe_held_total``)
-        self._moe_on_device = family.moe_counts
-        self._moe_assignments = np.zeros((3,), np.int64)
-        #: a cache with sliding-window layers (``paging.WindowPoolCache``),
-        #: counted on the host as the rows are packed: of the columns the
-        #: rows have mapped in the full layers' table, those that hold a
-        #: position of the row's window and those wholly behind it
-        #: (``nxd_window_columns_total``), and the K/V blocks the occupied
-        #: slots hold, times the layers that hold them, in the full
-        #: layers' pool and in the window layers' rings
-        #: (``nxd_kv_blocks_held_total``)
-        self._windowed = self._cache_kind.name == "window_pool"
-        self._window_cols = np.zeros((2,), np.int64)
-        self._kv_held = np.zeros((2,), np.int64)
-        #: windows a window-summary cache rolled (``nxd_eva_windows_total``)
-        #: or rows at position 0 of a cache with per-slot state leaves
-        #: (``paging.StateLeaf``), each of which starts a slot's states
-        #: anew (``nxd_state_resets_total``)
-        self._kind_events = 0
-        #: of such a cache, per step: the slots whose states the step
-        #: advanced (they had rows in it) and the occupied slots it held
-        #: untouched (``nxd_state_slot_steps_total``)
-        self._has_state = bool(self._cache_kind.leaves)
-        self._state_slots = np.zeros((2,), np.int64)
+        #: what the family's step counts, obs on only (``paging``, "A step's
+        #: counters"): the kind's hook, bound here to the engine's geometry
+        #: and called once a dispatched step with the step's own host
+        #: arrays; the leaves of the cache that the step counts into on the
+        #: device, fetched with its tokens; and what both have counted
+        #: since the last publish, by counter name and in its kinds' order
+        self._count_step = step_counter(
+            self._cache_kind, model_cfg, block_size=engine_cfg.block_size,
+            pool_blocks=self._pool_blocks,
+            itemsize=jnp.dtype(engine_cfg.kv_dtype
+                               or model_cfg.dtype).itemsize)
+        self._device_counts = family.device_counts()
+        self._counters = family.counters()
+        self._counted = {c.name: np.zeros((max(1, len(c.kinds)),), np.int64)
+                         for c in self._counters}
+        #: summary blocks ``engine/roll`` mapped for the step being packed
+        self._rolled = 0
         #: summary blocks taken by the schedule for windows that the next
         #: step completes, ``(slot, column) -> block``: ``engine/roll``
         self._pending_roll: Dict[Tuple[int, int], int] = {}
@@ -856,7 +813,9 @@ class ServingEngine:
         self._compile_trackers = {
             wn: CompileTracker.for_function(f"{site}/{wn}", fn)
             for wn, fn in workers.items()}
-        self._obs_cache = None  # (registry, generation, handles...)
+        #: the registry handles ``_publish_obs`` writes to, registered once a
+        #: registry generation
+        self._obs_cache: Optional[types.SimpleNamespace] = None
         # request-lifecycle ownership: a fleet router retires request
         # traces and histograms itself (it knows tenant and outcome);
         # it clears this flag on engines it manages so samples are
@@ -1897,8 +1856,7 @@ class ServingEngine:
         ring columns are reused as they are: mapped, refcount 1)."""
         for (slot, col), blk in self._pending_roll.items():
             self._tables[slot, col] = blk
-        if get_registry().enabled:
-            self._kind_events += len(self._pending_roll)
+        self._rolled += len(self._pending_roll)
         self._pending_roll.clear()
 
     def _map_column(self, req: _RequestState, blk_i: int,
@@ -2107,62 +2065,12 @@ class ServingEngine:
                 positions[0, i] = pos
                 slot_ids[i] = req.slot
             counted = get_registry().enabled
-            by_device = counted and self._counts_on_device
-            if counted and self._has_state:
-                self._kind_events += int(np.sum(positions == 0))
-                advanced = len({r[0].slot for r in rows})
-                self._state_slots += (advanced, sum(
-                    s is not None for s in self._slots) - advanced)
-            if counted and self._windowed:
-                # by the sliding kernel's own guard: of the columns up to
-                # a row's position, those its ring still holds
-                from ..ops.paged_attention import sliding_column_live
-
-                kind, bs = self._cache_kind, self.ecfg.block_size
-                ring = kind.window_ring(bs)
-                at = positions[0][positions[0] < PAD_POSITION]
-                live = np.count_nonzero(sliding_column_live(
-                    0, np.arange(ring), at[:, None], bs, kind.window, ring))
-                self._window_cols += (live, (at // bs + 1).sum() - live)
-                held = [len(self._slot_blocks[r.slot]) for r in self._slots
-                        if r is not None]
-                self._kv_held += (
-                    kind.full_layers * sum(held),
-                    kind.window_layers * sum(min(n, ring) for n in held))
-            if counted and not by_device:
-                # what the paged kernel's walk finds in this batch (a pad
-                # row attends nothing, whatever table row it is handed)
-                tbl = self._tables[np.minimum(slot_ids,
-                                              self._table_rows - 1)]
-                kinds = self._cache_kind.column_kinds(
-                    tbl, np.arange(tbl.shape[1]), positions[0][:, None],
-                    self.ecfg.block_size)
-                self._paged_cols += np.bincount(
-                    kinds.ravel(), minlength=3)  # skipped, exact, summary
-                # and what its tiles fetch for them
-                from ..ops.paged_attention import (pair_kinds, tile_pairs,
-                                                   tile_rows)
-
-                mcfg = self.model_cfg
-                served = np.where(kinds > 0, tbl, -1)
-                n_rep = (mcfg.num_heads // mcfg.num_kv_heads
-                         * self._cache_kind.pack)
-                fetched = int(tile_pairs(
-                    served, tile_rows(n_rep, width), self._pool_blocks,
-                    xp=np)[0].sum())
-                self._block_visits += (
-                    fetched, np.count_nonzero(kinds) - fetched)
-                if self._cache_kind.name == "latent":
-                    # and how the latent kernel's units come by them
-                    from ..ops.mla_attention import block_fetches
-
-                    self._mla_fetches += block_fetches(
-                        served, mcfg.num_heads, self._cache_kind.row,
-                        self.ecfg.block_size, self.cache.rows.dtype.itemsize)
-                else:
-                    # or over which rows the paged kernel computes them
-                    self._pair_kinds += pair_kinds(served, n_rep,
-                                                   self._pool_blocks)
+            rolled, self._rolled = self._rolled, 0
+            if counted:
+                self._add_counts(self._count_step(
+                    positions[0], slot_ids, self._tables,
+                    [len(self._slot_blocks[r.slot]) for r in self._slots
+                     if r is not None], rolled))
         with tracer.span(span + "/dispatch"):
             if self._spec is not None:
                 sampled, self.cache, self.dcache = fn(
@@ -2188,12 +2096,11 @@ class ServingEngine:
             # on their way to the host before the next step is enqueued
             # behind them
             sampled.copy_to_host_async()
-            if by_device:
-                flight.counts = self.cache.counts
-                flight.counts.copy_to_host_async()
-            if counted and self._moe_on_device:
-                flight.moe_counts = self.cache.moe_counts
-                flight.moe_counts.copy_to_host_async()
+            if counted:
+                flight.counts = [(leaf, getattr(self.cache, leaf.leaf))
+                                 for leaf in self._device_counts]
+                for _, on_device in flight.counts:
+                    on_device.copy_to_host_async()
         return flight
 
     def _fetch(self, flight: _InFlight, span: str) -> None:
@@ -2202,11 +2109,14 @@ class ServingEngine:
         flight, the one before the step it has just enqueued)."""
         with get_tracer().span(span + "/fetch"):
             flight.sampled = np.asarray(flight.sampled)
-            if flight.counts is not None:
-                self._paged_cols += np.asarray(flight.counts)
-            if flight.moe_counts is not None:
-                counts = np.asarray(flight.moe_counts)
-                self._moe_assignments[:counts.size] += counts
+            for leaf, on_device in flight.counts:
+                self._add_counts(leaf.read(np.asarray(on_device)))
+
+    def _add_counts(self, counts) -> None:
+        """Add what a step counted, by counter name, to what the next
+        publish increments."""
+        for name, n in counts.items():
+            self._counted[name] += n
 
     def _maybe_insert_prefix(self, req: _RequestState) -> None:
         """Publish this request's fully-written prompt blocks into the
@@ -2610,10 +2520,10 @@ class ServingEngine:
         it had not been read; ``serial``; ``None`` with no latency: the
         call enqueued nothing and only landed a step), count the
         step's rows by kind where they were packed (over the steps that
-        ran a worker the three kinds sum to steps x worker width) and
-        their table columns by whether the paged kernel computes or skips
-        them (rows x ``max_blocks_per_seq``), and poll the per-worker
-        compile trackers. One bool check when obs is
+        ran a worker the three kinds sum to steps x worker width),
+        increment the family's declared counters by what its steps
+        counted since the last publish (``_counted``), and poll the
+        per-worker compile trackers. One bool check when obs is
         disabled; the no-host-callback invariant holds — everything here
         runs after the compiled workers returned. Child handles are
         cached against the registry's reset generation so the steady
@@ -2624,8 +2534,8 @@ class ServingEngine:
         for tracker in self._compile_trackers.values():
             tracker.poll()
         cache = self._obs_cache
-        if (cache is None or cache[0] is not reg
-                or cache[1] != reg.generation):
+        if (cache is None or cache.registry is not reg
+                or cache.generation != reg.generation):
             stats_g = reg.gauge(
                 "nxd_engine_stats",
                 "EngineStats scalar counters bridged per step "
@@ -2658,246 +2568,39 @@ class ServingEngine:
                 "filled them: a decoding slot's token, a prefill chunk's "
                 "token, or padding.",
                 labels=("kind",))
-            events_c = None
-            if self._counts_on_device:
-                cols_c = reg.counter(
-                    "nxd_sparse_columns_total",
-                    "Grid steps of the sparse_paged_attention kernel's walk "
-                    "(rows x K/V groups x walk width, summed over the "
-                    "sparse layers) by what is in them: a pool block the "
-                    "selection picked, one the first blocks or the local "
-                    "window forced, one of a row below the dense "
-                    "threshold, or nothing (skipped). Counted on the "
-                    "device, fetched with the step's tokens.",
-                    labels=("kind",))
-                pos_c = reg.counter(
-                    "nxd_sparse_positions_total",
-                    "Causal positions of the packed rows (x K/V groups x "
-                    "sparse layers) by whether the selection attended "
-                    "them.",
-                    labels=("kind",))
-                sparse_visits_c = reg.counter(
-                    "nxd_sparse_block_visits_total",
-                    "Live (row, K/V group, table column) of the packed "
-                    "rows, summed over the sparse layers, by how the "
-                    "sparse_paged_attention kernel came by the column's "
-                    "pool block: fetched, for the first row of the tile "
-                    "that attends it (or the only one), or shared, served "
-                    "by the fetch made for an earlier row of the tile. "
-                    "Counted on the device, fetched with the step's "
-                    "tokens.",
-                    labels=("kind",))
-                key_visits_c = reg.counter(
-                    "nxd_sparse_key_visits_total",
-                    "(Row, table column) of the packed rows whose "
-                    "compressed keys the selection scores (rows at or "
-                    "past the dense threshold, columns that hold a whole "
-                    "kernel), summed over the sparse layers, by how the "
-                    "compressed_key_scores kernel came by the column's "
-                    "keys: fetched, a (tile, column, pool block) it "
-                    "copied, or shared, served by the copy made for "
-                    "another row of the tile. Counted on the device from "
-                    "the step's walk, fetched with the step's tokens.",
-                    labels=("kind",))
-                cols_by_kind = tuple(
-                    [cols_c.labels(kind=k) for k in
-                     ("selected", "forced", "dense", "skipped")]
-                    + [pos_c.labels(kind=k) for k in
-                       ("attended", "skipped")]
-                    + [c.labels(kind=k) for c in (sparse_visits_c,
-                                                  key_visits_c)
-                       for k in ("fetched", "shared")])
-            elif self._cache_kind.ring is None:
-                cols_c = reg.counter(
-                    "nxd_paged_columns_total",
-                    "Table columns of the serving workers' rows by what "
-                    "the paged kernel's walk does with them: live (mapped "
-                    "and not wholly behind the row's position) is "
-                    "computed, skipped is not.",
-                    labels=("kind",))
-                cols_by_kind = tuple(
-                    cols_c.labels(kind=k) for k in ("skipped", "live"))
-            else:
-                cols_c = reg.counter(
-                    "nxd_eva_columns_total",
-                    "Table columns of the serving workers' rows by what "
-                    "the eva_attention kernel's walk finds there: exact "
-                    "rows of the row's own window, an earlier window's "
-                    "chunk summaries, or nothing (skipped).",
-                    labels=("kind",))
-                cols_by_kind = tuple(cols_c.labels(kind=k) for k in
-                                     ("skipped", "exact", "summary"))
-                events_c = reg.counter(
-                    "nxd_eva_windows_total",
-                    "Windows whose last position was in a packed step: "
-                    "summarised into a block of the pool by that step.")
-            if self._has_state:
-                events_c = reg.counter(
-                    "nxd_state_resets_total",
-                    "Packed rows at position 0: each starts its slot's "
-                    "per-slot states (a lightning layer's, a state-space "
-                    "layer's and its convolution tail) from zero inside "
-                    "the step.")
-            state_by_kind = () if not self._has_state else tuple(
-                reg.counter(
-                    "nxd_state_slot_steps_total",
-                    "Slots of a cache with per-slot states, a step: "
-                    "advanced, the slot had rows in the step and its "
-                    "states moved on by them, or held, the slot was "
-                    "occupied and the step left its states as they were.",
-                    labels=("kind",)).labels(kind=k)
-                for k in ("advanced", "held"))
-            visits_c = None if self._counts_on_device else reg.counter(
-                "nxd_paged_block_visits_total",
-                "Live (row, table column) of the serving workers' rows by "
-                "how the paged kernel came by the column's pool block: "
-                "fetched, one a (tile of rows, pair of column and block), "
-                "or shared, served by a fetch that another row of the tile "
-                "is counted for.",
-                labels=("kind",))
-            visits_by_kind = () if visits_c is None else tuple(
-                visits_c.labels(kind=k) for k in ("fetched", "shared"))
-            fetches_by_kind = () if self._cache_kind.name != "latent" else (
-                tuple(reg.counter(
-                    "nxd_mla_block_fetches_total",
-                    "Pool blocks the mla_paged_attention kernel fetches "
-                    "for the serving workers' rows (one layer's worth, as "
-                    "nxd_paged_block_visits_total's fetched), by how: "
-                    "in_run, with one or more other blocks of the same "
-                    "row in one unit of the kernel (one step of the "
-                    "online softmax over all of them); alone, a one-row "
-                    "pair that is a unit by itself; whole, a pair that "
-                    "rows of the tile share, computed over the whole "
-                    "tile.",
-                    labels=("kind",)).labels(kind=k)
-                    for k in ("in_run", "alone", "whole")))
-            paged = (visits_c is not None
-                     and self._cache_kind.name != "latent")
-            pairs_by_kind = () if not paged else tuple(
-                [reg.counter(
-                    "nxd_paged_pairs_total",
-                    "Pairs (table column, pool block) that one packed row "
-                    "of its tile alone names, or neighbouring rows whose "
-                    "heads one group of the tile holds (one layer's "
-                    "worth), by the rows the paged kernel computes them "
-                    "over: narrow, the group that holds the naming rows' "
-                    "heads, or one_row_whole, the whole tile although "
-                    "one row names the pair.",
-                    labels=("kind",)).labels(kind=k)
-                 for k in ("narrow", "one_row_whole")]
-                + [reg.counter(
-                    "nxd_paged_shared_pairs_total",
-                    "Pairs that several packed rows of a tile name and "
-                    "no one group holds (a prefill chunk's blocks): the "
-                    "paged kernel computes them over the whole tile. "
-                    "With nxd_paged_pairs_total's two kinds they sum to "
-                    "nxd_paged_block_visits_total's fetched.")])
-            moe_by_kind = () if not self._moe_on_device else tuple(
-                reg.counter(
-                    "nxd_moe_assignments_total",
-                    "Routed-expert assignments (a real row's choice of an "
-                    "expert, top_k a row an expert layer) of the serving "
-                    "workers' rows by whether the dispatch gave them a "
-                    "slot (kept) or had none left (dropped). Pad rows "
-                    "choose nothing. Counted on the device, fetched with "
-                    "the step's tokens.",
-                    labels=("kind",)).labels(kind=k)
-                for k in ("kept", "dropped"))
-            shares = (self._moe_on_device
-                      and self.cache.moe_counts.shape[0] == 3)
-            moe_held_by_kind = () if not shares else tuple(
-                reg.counter(
-                    "nxd_moe_held_total",
-                    "Routed-expert assignments of the serving workers' "
-                    "real rows by where the chosen expert is: held, among "
-                    "the experts this device holds of those the router "
-                    "scores (kept or dropped: nxd_moe_assignments_total), "
-                    "or elsewhere, on a device that shares the layer, "
-                    "where it takes no slot here and adds nothing. "
-                    "Counted on the device, fetched with the step's "
-                    "tokens.",
-                    labels=("kind",)).labels(kind=k)
-                for k in ("held", "elsewhere"))
-            window_by_kind = () if not self._windowed else tuple(
-                [reg.counter(
-                    "nxd_window_columns_total",
-                    "Table columns that the serving workers' rows have "
-                    "mapped in the full-attention layers' table (those "
-                    "not beyond the row's position) by what a "
-                    "sliding-window layer does with the same positions: "
-                    "live, the column holds a position of the row's "
-                    "window and the row's ring holds its block, or "
-                    "behind, the window has passed it and the ring has "
-                    "overwritten it.",
-                    labels=("kind",)).labels(kind=k)
-                 for k in ("live", "behind")]
-                + [reg.counter(
-                    "nxd_kv_blocks_held_total",
-                    "K/V blocks the occupied slots hold, a step, times the "
-                    "layers that hold them: full, blocks of the "
-                    "full-attention layers' pool (they grow with the "
-                    "context), or window, blocks of the slots' rings in "
-                    "the sliding-window layers' pool (at most the ring a "
-                    "slot).",
-                    labels=("kind",)).labels(kind=k)
-                   for k in ("full", "window")])
-            cache = self._obs_cache = (
-                reg, reg.generation,
-                {f: stats_g.labels(field=f)
-                 for f in self._OBS_SCALAR_FIELDS},
-                reg.gauge("nxd_engine_pool_free_blocks",
-                          "Unallocated KV blocks."),
-                step_h,
-                tuple(rows_c.labels(kind=k)
-                      for k in ("decode", "prefill", "pad")),
-                cols_by_kind, events_c, visits_by_kind, moe_by_kind,
-                {k: steps_c.labels(kind=k)
-                 for k in ("overlapped", "serial")}, state_by_kind,
-                fetches_by_kind, moe_held_by_kind, window_by_kind,
-                pairs_by_kind)
-        (_, _, fields, free_g, step_h, rows_by_kind, cols_by_kind,
-         events_c, visits_by_kind, moe_by_kind, steps_by_kind,
-         state_by_kind, fetches_by_kind, moe_held_by_kind,
-         window_by_kind, pairs_by_kind) = cache
+            counted = {}
+            for c in self._counters:
+                metric = reg.counter(c.name, c.help,
+                                     labels=("kind",) if c.kinds else ())
+                counted[c.name] = tuple(
+                    metric.labels(kind=k) for k in c.kinds) or (metric,)
+            cache = self._obs_cache = types.SimpleNamespace(
+                registry=reg, generation=reg.generation,
+                fields={f: stats_g.labels(field=f)
+                        for f in self._OBS_SCALAR_FIELDS},
+                free=reg.gauge("nxd_engine_pool_free_blocks",
+                               "Unallocated KV blocks."),
+                step_seconds=step_h,
+                rows=tuple(rows_c.labels(kind=k)
+                           for k in ("decode", "prefill", "pad")),
+                steps={k: steps_c.labels(kind=k)
+                       for k in ("overlapped", "serial")},
+                counted=counted)
         st = self.stats
-        for f, child in fields.items():
+        for f, child in cache.fields.items():
             child.set(float(getattr(st, f)))
-        free_g.set(self.pool_free_blocks())
+        cache.free.set(self.pool_free_blocks())
         if kind is not None:
-            step_h.observe(step_latency_s)
-            steps_by_kind[kind].inc(1)
-        for child, n in zip(rows_by_kind,
+            cache.step_seconds.observe(step_latency_s)
+            cache.steps[kind].inc(1)
+        for child, n in zip(cache.rows,
                             (decode_rows, prefill_rows, pad_rows)):
             child.inc(n)
-        for child, n in zip(cols_by_kind, self._paged_cols):
-            child.inc(int(n))
-        self._paged_cols[:] = 0
-        for child, n in zip(visits_by_kind, self._block_visits):
-            child.inc(int(n))
-        self._block_visits[:] = 0
-        for child, n in zip(fetches_by_kind, self._mla_fetches):
-            child.inc(int(n))
-        self._mla_fetches[:] = 0
-        for child, n in zip(pairs_by_kind, self._pair_kinds):
-            child.inc(int(n))
-        self._pair_kinds[:] = 0
-        for child, n in zip(moe_by_kind, self._moe_assignments):
-            child.inc(int(n))
-        kept, dropped, elsewhere = self._moe_assignments
-        for child, n in zip(moe_held_by_kind, (kept + dropped, elsewhere)):
-            child.inc(int(n))
-        self._moe_assignments[:] = 0
-        for child, n in zip(window_by_kind, (*self._window_cols,
-                                             *self._kv_held)):
-            child.inc(int(n))
-        self._window_cols[:] = 0
-        self._kv_held[:] = 0
-        if events_c is not None:
-            events_c.inc(self._kind_events)
-        self._kind_events = 0
-        for child, n in zip(state_by_kind, self._state_slots):
-            child.inc(int(n))
-        self._state_slots[:] = 0
+        for name, children in cache.counted.items():
+            since = self._counted[name]
+            for child, n in zip(children, since):
+                child.inc(int(n))
+            since[:] = 0
 
     def _retire(self, req: _RequestState, now: float) -> None:
         if req.slot is not None:    # else it left its slot at the enqueue

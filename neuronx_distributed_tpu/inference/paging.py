@@ -20,12 +20,14 @@ entries are never attended, so no separate attention mask is plumbed.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
-from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
-                    Set, Tuple)
+from typing import (Any, Callable, Dict, List, Mapping, NamedTuple, Optional,
+                    Sequence, Set, Tuple)
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import struct
 
 from .kv_cache import PAD_POSITION
@@ -33,6 +35,275 @@ from .kv_cache import PAD_POSITION
 
 class CacheExhaustedError(RuntimeError):
     """The block pool has no free block for a required allocation."""
+
+
+# ---------------------------------------------------------------------------
+# A step's counters. What the attention kernel's walk did with the rows of
+# a packed step, and what the step's router did with them, is counted by
+# the cache kind whose walk it is (``counters`` and ``count_step``: on the
+# host, from the step's own arrays, by the kernel's own functions) or by
+# the compiled step itself, into a leaf of the cache that the kind or the
+# family declares (:class:`DeviceCounts`). :class:`.engine.ServingEngine`
+# registers what is declared, calls the hook once a dispatched step, reads
+# the leaves with the step's tokens and adds up what it is handed; it
+# knows no family's counter.
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class CounterFamily:
+    """One counter of the registry: its name, its help text and its
+    ``kind`` labels in the order its counts come in (none: one count, no
+    label)."""
+
+    name: str
+    help: str
+    kinds: Tuple[str, ...] = ()
+
+
+#: the leaves a :class:`DeviceCounts` may name: a step leaves them for the
+#: host to read after it, so the engine keeps them out of the pool it
+#: donates (the host may hold such an array, and read it, while the step
+#: after the one that made it runs)
+COUNT_LEAVES = ("counts", "moe_counts")
+
+
+@dataclasses.dataclass(frozen=True)
+class DeviceCounts:
+    """A leaf of the serving cache that the compiled step counts into and
+    the engine fetches with the step's tokens: its name (one of
+    :data:`COUNT_LEAVES`), its length and, for each counter family it
+    feeds, the entries that sum to each of the family's kinds, in the
+    kinds' order."""
+
+    leaf: str
+    entries: int
+    reads: Tuple[Tuple[CounterFamily, Tuple[Tuple[int, ...], ...]], ...]
+
+    def __post_init__(self):
+        if self.leaf not in COUNT_LEAVES:
+            raise ValueError(f"{self.leaf!r} is not one of the leaves a "
+                             f"step counts into ({COUNT_LEAVES})")
+
+    def read(self, values) -> Dict[str, List[int]]:
+        """Counts by counter name from the leaf as the host fetched it."""
+        return {family.name: [sum(int(values[i]) for i in entry)
+                              for entry in entries]
+                for family, entries in self.reads}
+
+
+class StepGeometry(NamedTuple):
+    """What a kind's :meth:`FullCache.count_step` needs of the engine and
+    the model it was bound to (:func:`step_counter`): the pool's block
+    size, its blocks and the bytes of one of its elements, the model's
+    query heads, and the query heads the paged kernel sees a K/V head
+    (the model's, times the K/V heads the kind lays on a row)."""
+
+    block_size: int
+    pool_blocks: int
+    itemsize: int
+    heads: int
+    n_rep: int
+
+
+def step_counter(kind, model_cfg, *, block_size: int, pool_blocks: int,
+                 itemsize: int) -> Callable[..., Dict[str, Any]]:
+    """``kind.count_step`` bound, once an engine, to the geometry it
+    counts by."""
+    return functools.partial(kind.count_step, StepGeometry(
+        block_size, pool_blocks, itemsize, model_cfg.num_heads,
+        model_cfg.num_heads // model_cfg.num_kv_heads * kind.pack))
+
+
+PAGED_COLUMNS = CounterFamily(
+    "nxd_paged_columns_total",
+    "Table columns of the serving workers' rows by what the paged kernel's "
+    "walk does with them: live (mapped and not wholly behind the row's "
+    "position) is computed, skipped is not.",
+    ("skipped", "live"))
+EVA_COLUMNS = CounterFamily(
+    "nxd_eva_columns_total",
+    "Table columns of the serving workers' rows by what the eva_attention "
+    "kernel's walk finds there: exact rows of the row's own window, an "
+    "earlier window's chunk summaries, or nothing (skipped).",
+    ("skipped", "exact", "summary"))
+EVA_WINDOWS = CounterFamily(
+    "nxd_eva_windows_total",
+    "Windows whose last position was in a packed step: summarised into a "
+    "block of the pool by that step.")
+PAGED_BLOCK_VISITS = CounterFamily(
+    "nxd_paged_block_visits_total",
+    "Live (row, table column) of the serving workers' rows by how the "
+    "paged kernel came by the column's pool block: fetched, one a (tile of "
+    "rows, pair of column and block), or shared, served by a fetch that "
+    "another row of the tile is counted for.",
+    ("fetched", "shared"))
+PAGED_PAIRS = CounterFamily(
+    "nxd_paged_pairs_total",
+    "Pairs (table column, pool block) that one packed row of its tile "
+    "alone names, or neighbouring rows whose heads one group of the tile "
+    "holds (one layer's worth), by the rows the paged kernel computes them "
+    "over: narrow, the group that holds the naming rows' heads, or "
+    "one_row_whole, the whole tile although one row names the pair.",
+    ("narrow", "one_row_whole"))
+PAGED_SHARED_PAIRS = CounterFamily(
+    "nxd_paged_shared_pairs_total",
+    "Pairs that several packed rows of a tile name and no one group holds "
+    "(a prefill chunk's blocks): the paged kernel computes them over the "
+    "whole tile. With nxd_paged_pairs_total's two kinds they sum to "
+    "nxd_paged_block_visits_total's fetched.")
+MLA_BLOCK_FETCHES = CounterFamily(
+    "nxd_mla_block_fetches_total",
+    "Pool blocks the mla_paged_attention kernel fetches for the serving "
+    "workers' rows (one layer's worth, as nxd_paged_block_visits_total's "
+    "fetched), by how: in_run, with one or more other blocks of the same "
+    "row in one unit of the kernel (one step of the online softmax over "
+    "all of them); alone, a one-row pair that is a unit by itself; whole, "
+    "a pair that rows of the tile share, computed over the whole tile.",
+    ("in_run", "alone", "whole"))
+STATE_RESETS = CounterFamily(
+    "nxd_state_resets_total",
+    "Packed rows at position 0: each starts its slot's per-slot states (a "
+    "lightning layer's, a state-space layer's and its convolution tail) "
+    "from zero inside the step.")
+STATE_SLOT_STEPS = CounterFamily(
+    "nxd_state_slot_steps_total",
+    "Slots of a cache with per-slot states, a step: advanced, the slot had "
+    "rows in the step and its states moved on by them, or held, the slot "
+    "was occupied and the step left its states as they were.",
+    ("advanced", "held"))
+WINDOW_COLUMNS = CounterFamily(
+    "nxd_window_columns_total",
+    "Table columns that the serving workers' rows have mapped in the "
+    "full-attention layers' table (those not beyond the row's position) by "
+    "what a sliding-window layer does with the same positions: live, the "
+    "column holds a position of the row's window and the row's ring holds "
+    "its block, or behind, the window has passed it and the ring has "
+    "overwritten it.",
+    ("live", "behind"))
+KV_BLOCKS_HELD = CounterFamily(
+    "nxd_kv_blocks_held_total",
+    "K/V blocks the occupied slots hold, a step, times the layers that hold "
+    "them: full, blocks of the full-attention layers' pool (they grow with "
+    "the context), or window, blocks of the slots' rings in the "
+    "sliding-window layers' pool (at most the ring a slot).",
+    ("full", "window"))
+SPARSE_COLUMNS = CounterFamily(
+    "nxd_sparse_columns_total",
+    "Grid steps of the sparse_paged_attention kernel's walk (rows x K/V "
+    "groups x walk width, summed over the sparse layers) by what is in "
+    "them: a pool block the selection picked, one the first blocks or the "
+    "local window forced, one of a row below the dense threshold, or "
+    "nothing (skipped). Counted on the device, fetched with the step's "
+    "tokens.",
+    ("selected", "forced", "dense", "skipped"))
+SPARSE_POSITIONS = CounterFamily(
+    "nxd_sparse_positions_total",
+    "Causal positions of the packed rows (x K/V groups x sparse layers) by "
+    "whether the selection attended them.",
+    ("attended", "skipped"))
+SPARSE_BLOCK_VISITS = CounterFamily(
+    "nxd_sparse_block_visits_total",
+    "Live (row, K/V group, table column) of the packed rows, summed over "
+    "the sparse layers, by how the sparse_paged_attention kernel came by "
+    "the column's pool block: fetched, for the first row of the tile that "
+    "attends it (or the only one), or shared, served by the fetch made for "
+    "an earlier row of the tile. Counted on the device, fetched with the "
+    "step's tokens.",
+    ("fetched", "shared"))
+SPARSE_KEY_VISITS = CounterFamily(
+    "nxd_sparse_key_visits_total",
+    "(Row, table column) of the packed rows whose compressed keys the "
+    "selection scores (rows at or past the dense threshold, columns that "
+    "hold a whole kernel), summed over the sparse layers, by how the "
+    "compressed_key_scores kernel came by the column's keys: fetched, a "
+    "(tile, column, pool block) it copied, or shared, served by the copy "
+    "made for another row of the tile. Counted on the device from the "
+    "step's walk, fetched with the step's tokens.",
+    ("fetched", "shared"))
+MOE_ASSIGNMENTS = CounterFamily(
+    "nxd_moe_assignments_total",
+    "Routed-expert assignments (a real row's choice of an expert, top_k a "
+    "row an expert layer) of the serving workers' rows by whether the "
+    "dispatch gave them a slot (kept) or had none left (dropped). Pad rows "
+    "choose nothing. Counted on the device, fetched with the step's "
+    "tokens.",
+    ("kept", "dropped"))
+MOE_HELD = CounterFamily(
+    "nxd_moe_held_total",
+    "Routed-expert assignments of the serving workers' real rows by where "
+    "the chosen expert is: held, among the experts this device holds of "
+    "those the router scores (kept or dropped: nxd_moe_assignments_total), "
+    "or elsewhere, on a device that shares the layer, where it takes no "
+    "slot here and adds nothing. Counted on the device, fetched with the "
+    "step's tokens.",
+    ("held", "elsewhere"))
+
+#: the ``moe_counts`` leaf of a family that declares it
+#: (:class:`ServingFamily`), as the kind that builds it lays it out: the
+#: assignments kept and dropped, and, where the device holds a share of
+#: the experts, a third count of those that chose an expert held elsewhere
+MOE_KEPT_DROPPED = DeviceCounts(
+    "moe_counts", 2, ((MOE_ASSIGNMENTS, ((0,), (1,))),))
+MOE_KEPT_DROPPED_ELSEWHERE = DeviceCounts(
+    "moe_counts", 3, ((MOE_ASSIGNMENTS, ((0,), (1,))),
+                      (MOE_HELD, ((0, 1), (2,)))))
+
+#: ``counts`` of a sparse-state cache: a counter family and, in its kinds'
+#: order, the names of :data:`..ops.sparse_attention.COUNT_KINDS` it reads
+_SPARSE_COUNTS = (
+    (SPARSE_COLUMNS, ("selected", "forced", "dense", "skipped")),
+    (SPARSE_POSITIONS, ("attended", "skipped_positions")),
+    (SPARSE_BLOCK_VISITS, ("fetched", "shared")),
+    (SPARSE_KEY_VISITS, ("keys_fetched", "keys_shared")))
+
+#: what the paged kernel's tiles fetch for a step's rows, and over which
+#: rows they compute it
+_PAGED_FETCHES = (PAGED_BLOCK_VISITS, PAGED_PAIRS, PAGED_SHARED_PAIRS)
+#: what a step does to the per-slot states of a kind that has ``leaves``
+_STATES = (STATE_RESETS, STATE_SLOT_STEPS)
+
+
+def _count_walk(kind, geo: StepGeometry, columns: CounterFamily, positions,
+                slot_ids, tables):
+    """What the paged kernel's walk finds in a step's rows, by the walk's
+    own functions: the rows' table columns by ``kind.column_kinds`` under
+    ``columns``' name (a pad row attends nothing, whatever table row it is
+    handed), the pool blocks its tiles fetch, one a (tile, pair), and the
+    further live (row, column) each fetch serves. Returns the counts and
+    ``served [T, max_blocks_per_seq]``, a row's table entry in the columns
+    it attends and -1 elsewhere."""
+    from ..ops.paged_attention import tile_pairs, tile_rows
+
+    tbl = tables[np.minimum(slot_ids, tables.shape[0] - 1)]
+    kinds = kind.column_kinds(tbl, np.arange(tbl.shape[1]),
+                              positions[:, None], geo.block_size)
+    served = np.where(kinds > 0, tbl, -1)
+    fetched = int(tile_pairs(served, tile_rows(geo.n_rep, len(positions)),
+                             geo.pool_blocks, xp=np)[0].sum())
+    return {columns.name: np.bincount(kinds.ravel(),
+                                      minlength=len(columns.kinds)),
+            PAGED_BLOCK_VISITS.name: (
+                fetched, np.count_nonzero(kinds) - fetched)}, served
+
+
+def _count_pairs(geo: StepGeometry, served) -> Dict[str, Any]:
+    """A step's pairs (one layer's worth) by the rows the paged kernel
+    computes them over."""
+    from ..ops.paged_attention import pair_kinds
+
+    narrow, one_row_whole, shared = pair_kinds(served, geo.n_rep,
+                                               geo.pool_blocks)
+    return {PAGED_PAIRS.name: (narrow, one_row_whole),
+            PAGED_SHARED_PAIRS.name: (shared,)}
+
+
+def _count_states(positions, slot_ids, held) -> Dict[str, Any]:
+    """Rows at position 0, each of which starts its slot's states anew,
+    the slots whose states the step advanced (they had rows in it) and the
+    occupied slots it held untouched."""
+    advanced = len(np.unique(slot_ids[positions < PAD_POSITION]))
+    return {STATE_RESETS.name: (np.count_nonzero(positions == 0),),
+            STATE_SLOT_STEPS.name: (advanced, len(held) - advanced)}
 
 
 # ---------------------------------------------------------------------------
@@ -89,6 +360,33 @@ class FullCache:
 
     def init_cache(self, model_cfg, **geometry):
         return _uniform_pool_cache(model_cfg, **geometry)
+
+    #: leaves of the kind's cache that its step counts into on the device
+    #: (:class:`DeviceCounts`)
+    device_counts = ()
+
+    @property
+    def counters(self) -> Tuple[CounterFamily, ...]:
+        """The counter families :meth:`count_step` counts."""
+        return ((PAGED_COLUMNS,) + _PAGED_FETCHES
+                + (_STATES if self.leaves else ()))
+
+    def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
+                   held: Sequence[int], rolled: int) -> Dict[str, Any]:
+        """What this kind's kernels and states do with one dispatched
+        step, counted on the host from the step's own NumPy arrays:
+        ``positions`` and ``slot_ids [W]`` of the worker's rows
+        (PAD_POSITION and a slot past the table for a pad row), the
+        host's block ``tables``, the blocks each occupied slot ``held``
+        and the summary blocks the schedule ``rolled`` into the tables
+        for this step. Counts by counter name, a family's in its kinds'
+        order (:attr:`counters`)."""
+        counts, served = _count_walk(self, geo, PAGED_COLUMNS, positions,
+                                     slot_ids, tables)
+        counts.update(_count_pairs(geo, served))
+        if self.leaves:
+            counts.update(_count_states(positions, slot_ids, held))
+        return counts
 
 
 FULL_CACHE = FullCache()
@@ -172,6 +470,19 @@ class WindowSummaryCache:
     def init_cache(self, model_cfg, **geometry):
         return _uniform_pool_cache(model_cfg, **geometry)
 
+    device_counts = ()
+    counters = (EVA_COLUMNS, EVA_WINDOWS) + _PAGED_FETCHES
+
+    def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
+                   held: Sequence[int], rolled: int) -> Dict[str, Any]:
+        """As :meth:`FullCache.count_step`, by the eva_attention kernel's
+        walk, and the windows the step summarises."""
+        counts, served = _count_walk(self, geo, EVA_COLUMNS, positions,
+                                     slot_ids, tables)
+        counts.update(_count_pairs(geo, served))
+        counts[EVA_WINDOWS.name] = (rolled,)
+        return counts
+
 
 @dataclasses.dataclass(frozen=True)
 class StateLeaf:
@@ -229,6 +540,27 @@ class SparseStateCache(FullCache):
                 f"whole strides of {self.stride}")
         return self
 
+    @property
+    def device_counts(self) -> Tuple[DeviceCounts, ...]:
+        """``counts``: a sparse layer's selections are known to the
+        device alone."""
+        from ..ops.sparse_attention import COUNT_KINDS
+
+        return (DeviceCounts("counts", len(COUNT_KINDS), tuple(
+            (family, tuple((COUNT_KINDS.index(n),) for n in names))
+            for family, names in _SPARSE_COUNTS)),)
+
+    @property
+    def counters(self) -> Tuple[CounterFamily, ...]:
+        return _STATES if self.leaves else ()
+
+    def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
+                   held: Sequence[int], rolled: int) -> Dict[str, Any]:
+        """The states alone: the walk is the device's to count."""
+        if not self.leaves:
+            return {}
+        return _count_states(positions, slot_ids, held)
+
     def init_cache(self, model_cfg, *, num_blocks: int, block_size: int,
                    table_rows: int, max_blocks_per_seq: int, dtype: Any,
                    quantized: bool = False) -> "SparseStatePagedCache":
@@ -264,6 +596,22 @@ class LatentCache(FullCache):
 
     row: int = 640
     name = "latent"
+    #: how the kind lays out the ``moe_counts`` of a family that declares it
+    moe_leaf = MOE_KEPT_DROPPED
+    counters = (PAGED_COLUMNS, PAGED_BLOCK_VISITS, MLA_BLOCK_FETCHES)
+
+    def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
+                   held: Sequence[int], rolled: int) -> Dict[str, Any]:
+        """As :meth:`FullCache.count_step`, with the pool blocks by how the
+        mla_paged_attention kernel's units come by them in place of the
+        paged kernel's pairs (it takes its pairs in runs)."""
+        from ..ops.mla_attention import block_fetches
+
+        counts, served = _count_walk(self, geo, PAGED_COLUMNS, positions,
+                                     slot_ids, tables)
+        counts[MLA_BLOCK_FETCHES.name] = block_fetches(
+            served, geo.heads, self.row, geo.block_size, geo.itemsize)
+        return counts
 
     def init_cache(self, model_cfg, *, num_blocks: int, block_size: int,
                    table_rows: int, max_blocks_per_seq: int, dtype: Any,
@@ -275,7 +623,7 @@ class LatentCache(FullCache):
         return LatentPagedCache(
             rows=jnp.zeros((model_cfg.num_layers, num_blocks, block_size,
                             self.row), dtype),
-            moe_counts=(jnp.zeros((2,), jnp.int32)
+            moe_counts=(jnp.zeros((self.moe_leaf.entries,), jnp.int32)
                         if model_cfg.serving_family().moe_counts else None),
             pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
             block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
@@ -353,6 +701,35 @@ class WindowPoolCache(FullCache):
     window_layers: int = 0
     window: int = 0
     name = "window_pool"
+    #: as :attr:`LatentCache.moe_leaf`; its device holds a share of the
+    #: experts
+    moe_leaf = MOE_KEPT_DROPPED_ELSEWHERE
+
+    @property
+    def counters(self) -> Tuple[CounterFamily, ...]:
+        return super().counters + (WINDOW_COLUMNS, KV_BLOCKS_HELD)
+
+    def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
+                   held: Sequence[int], rolled: int) -> Dict[str, Any]:
+        """As :meth:`FullCache.count_step` for the full layers, and for
+        the window layers, by the sliding kernel's own guard: of the
+        columns up to a row's position those its ring still holds, and
+        the blocks the occupied slots hold, times the layers that hold
+        them, in the full layers' pool and in the rings."""
+        from ..ops.paged_attention import sliding_column_live
+
+        counts = super().count_step(geo, positions, slot_ids, tables, held,
+                                    rolled)
+        bs = geo.block_size
+        ring = self.window_ring(bs)
+        at = positions[positions < PAD_POSITION]
+        live = np.count_nonzero(sliding_column_live(
+            0, np.arange(ring), at[:, None], bs, self.window, ring))
+        counts[WINDOW_COLUMNS.name] = (live, (at // bs + 1).sum() - live)
+        counts[KV_BLOCKS_HELD.name] = (
+            self.full_layers * sum(held),
+            self.window_layers * sum(min(n, ring) for n in held))
+        return counts
 
     def geometry(self, block_size: int, step_rows: int = 0
                  ) -> "WindowPoolCache":
@@ -387,7 +764,7 @@ class WindowPoolCache(FullCache):
             wk=jnp.zeros(ring, dtype), wv=jnp.zeros(ring, dtype),
             wpos=jnp.full((ring_blocks, block_size), PAD_POSITION,
                           jnp.int32),
-            moe_counts=(jnp.zeros((3,), jnp.int32)
+            moe_counts=(jnp.zeros((self.moe_leaf.entries,), jnp.int32)
                         if model_cfg.serving_family().moe_counts else None),
             pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
             block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
@@ -416,6 +793,20 @@ class ServingFamily:
     cache_kind: Any = FULL_CACHE
     unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
     moe_counts: bool = False
+
+    def device_counts(self) -> Tuple[DeviceCounts, ...]:
+        """The leaves of the family's cache that its step counts into:
+        the kind's own and, where the family declares ``moe_counts``,
+        that leaf as the kind lays it out."""
+        return tuple(self.cache_kind.device_counts) + (
+            (self.cache_kind.moe_leaf,) if self.moe_counts else ())
+
+    def counters(self) -> Tuple[CounterFamily, ...]:
+        """Every counter family of the family's step: those its kind
+        counts on the host and those its leaves feed."""
+        return tuple(self.cache_kind.counters) + tuple(
+            family for leaf in self.device_counts()
+            for family, _ in leaf.reads)
 
 
 class _BlockPool:

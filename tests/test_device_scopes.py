@@ -335,8 +335,17 @@ LOWERED_AT_PR_42 = {
 }
 
 
+#: and the window-pool family's, as PR 43's tree lowers it (recorded by
+#: PR 44 before it moved the step's counters behind the cache kinds)
+LOWERED_AT_PR_43 = {
+    "laguna":
+        "950d46e755521f7e1b0cb917d6c7c2829d5a01561f4ad1788529531d64116000",
+}
+
+
 @pytest.mark.parametrize("which", list(LOWERED_AT_PR_38)
-                         + list(LOWERED_AT_PR_39) + list(LOWERED_AT_PR_42))
+                         + list(LOWERED_AT_PR_39) + list(LOWERED_AT_PR_42)
+                         + list(LOWERED_AT_PR_43))
 def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     """PR 39 changed the latent kernel and its walk alone: the packed
     steps of the five families that run the shared helpers of
@@ -346,11 +355,14 @@ def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     family does not ask for it: the six families' steps, Mixtral's and
     GLM's expert layers among them, are still the text they were. PR 42
     changed the sparse-state family's step (``ops/sparse_attention.py``:
-    the selection's scores, their walk and its counts) and no other. A
-    PR that changes one of these programs on purpose records its new
-    hash here."""
+    the selection's scores, their walk and its counts) and no other.
+    PR 44 moved what the host counts of a step behind the cache kinds
+    and changed no program: all seven are the text they were. A PR that
+    changes one of these programs on purpose records its new hash
+    here."""
     import hashlib
 
     text = _stripped(_lowered(which))
     assert hashlib.sha256(text.encode()).hexdigest() == {
-        **LOWERED_AT_PR_38, **LOWERED_AT_PR_39, **LOWERED_AT_PR_42}[which]
+        **LOWERED_AT_PR_38, **LOWERED_AT_PR_39, **LOWERED_AT_PR_42,
+        **LOWERED_AT_PR_43}[which]

@@ -35,6 +35,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402  (benchmarks/)
+from counter_checks import check_registered_counters  # noqa: E402  (tests/)
 from runners import serve  # noqa: E402
 
 sys.path.insert(0, HERE)
@@ -271,6 +272,7 @@ def served():
         for name in ("nxd_eva_columns_total", "nxd_eva_windows_total",
                      "nxd_paged_block_visits_total")}
     spans = {e["name"] for e in obs.get_tracer().chrome_trace()["traceEvents"]}
+    check_registered_counters(obs.get_registry(), cfg.serving_family())
     obs.disable()
     ps.destroy_model_parallel()
     return cfg, params, eng, prompts, new, held, counters, spans

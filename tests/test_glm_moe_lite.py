@@ -40,6 +40,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402  (benchmarks/)
+from counter_checks import check_registered_counters  # noqa: E402  (tests/)
 from runners import serve  # noqa: E402
 from walk_checks import check_tile_walk  # noqa: E402  (tests/)
 
@@ -516,6 +517,7 @@ def served():
                      "nxd_paged_block_visits_total",
                      "nxd_mla_block_fetches_total",
                      "nxd_engine_rows_total")}
+    check_registered_counters(obs.get_registry(), cfg.serving_family())
     obs.disable()
     ps.destroy_model_parallel()
     return cfg, params, eng, prompts, new, counters
@@ -661,4 +663,6 @@ def test_the_family_and_not_the_cache_kind_declares_the_experts_counter():
 
     assert family.cache_kind.init_cache(Dense(), **geometry).moe_counts \
         is None
-    assert ServingEngine(cfg, params, _ecfg())._moe_on_device
+    assert [leaf.leaf for leaf in
+            ServingEngine(cfg, params, _ecfg())._device_counts] \
+        == ["moe_counts"]

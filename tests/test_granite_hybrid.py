@@ -39,6 +39,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402  (benchmarks/)
+from counter_checks import check_registered_counters  # noqa: E402  (tests/)
 
 BS = 16
 LAYERS = ["mamba", "attention", "mamba", "mamba", "attention"]
@@ -603,6 +604,7 @@ def served():
         for name in ("nxd_state_slot_steps_total", "nxd_state_resets_total",
                      "nxd_paged_columns_total", "nxd_engine_rows_total",
                      "nxd_engine_steps_total")}
+    check_registered_counters(obs.get_registry(), cfg.serving_family())
     obs.disable()
     ps.destroy_model_parallel()
     return cfg, params, eng, prompts, new, counters, steps

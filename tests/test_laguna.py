@@ -41,6 +41,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402  (benchmarks/)
+from counter_checks import check_registered_counters  # noqa: E402  (tests/)
 
 BS, WINDOW, RING = 4, 8, 3
 LAYERS = ["full_attention"] + ["sliding_attention"] * 3 + ["full_attention"]
@@ -547,6 +548,7 @@ def served():
         name: {c.labels.get("kind", ""): c.value
                for c in obs.get_registry().get(name).children()}
         for name in names}
+    check_registered_counters(obs.get_registry(), cfg.serving_family())
     obs.disable()
     ps.destroy_model_parallel()
     return cfg, params, eng, prompts, new, counters, ring_blocks

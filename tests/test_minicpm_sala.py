@@ -37,6 +37,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402  (benchmarks/)
+from counter_checks import check_registered_counters  # noqa: E402  (tests/)
 from runners import serve  # noqa: E402
 from walk_checks import (check_score_walk, check_sparse_walk,  # noqa: E402
                          scored_pairs)
@@ -688,6 +689,7 @@ def served():
                      "nxd_sparse_key_visits_total",
                      "nxd_state_resets_total", "nxd_engine_rows_total")}
     counters["steps"] = steps
+    check_registered_counters(obs.get_registry(), cfg.serving_family())
     obs.disable()
     ps.destroy_model_parallel()
     return cfg, params, eng, prompts, new, counters

@@ -18,6 +18,7 @@ from neuronx_distributed_tpu import obs
 from neuronx_distributed_tpu.obs.metrics import MetricsRegistry
 from neuronx_distributed_tpu.obs.tracing import SpanTracer
 from neuronx_distributed_tpu.parallel import mesh as ps
+from counter_checks import check_registered_counters
 
 
 @pytest.fixture(autouse=True)
@@ -659,6 +660,8 @@ def test_engine_paged_columns_counter_sums_to_rows_times_columns():
         before, visits = now, seen
     assert before["skipped"] > before["live"]
     assert visits["shared"] > 0         # a prefill chunk's rows share blocks
+    check_registered_counters(obs.get_registry(),
+                              eng.model_cfg.serving_family())
 
 
 def test_engine_paged_pairs_counters_split_the_fetched_pairs_by_kind():
@@ -709,6 +712,7 @@ def test_engine_paged_pairs_counters_split_the_fetched_pairs_by_kind():
         assert now[0].sum() == now[1]
         before = now
     assert before[0][0] > 0 and before[0][1] == 0
+    check_registered_counters(obs.get_registry(), mcfg.serving_family())
 
 
 def test_engine_with_obs_off_records_no_span_and_no_rows_counter():

@@ -15,7 +15,8 @@ from neuronx_distributed_tpu.inference.kv_cache import (PAD_POSITION,
                                                         quantize_kv)
 from neuronx_distributed_tpu.inference.paging import (
     BlockAllocator, CacheExhaustedError, flat_write_indices,
-    init_paged_kv_cache, init_quantized_paged_kv_cache, write_pool_rows)
+    init_paged_kv_cache, init_quantized_paged_kv_cache, step_counter,
+    write_pool_rows)
 from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
                                                   tiny_config)
 from neuronx_distributed_tpu.models.mixtral import (MixtralForCausalLM,
@@ -28,6 +29,7 @@ from neuronx_distributed_tpu.ops.paged_attention import (column_live,
                                                           tile_rows,
                                                           tile_walk)
 from neuronx_distributed_tpu.parallel import mesh as ps
+from counter_checks import declared_anywhere
 from walk_checks import check_tile_walk, narrow_group
 
 
@@ -700,3 +702,146 @@ def test_model_builder_init_state_paged_kind():
     qcache = m.init_state()
     assert isinstance(qcache, QuantizedPagedKVCache)
     assert qcache.k.dtype == jnp.int8
+
+
+# ---------------------------------------------------------------------------
+# A step's counters: what a family's cache kind and its serving_family()
+# declare. The names and labels are what the benchmark's readers, the
+# documents and the dashboards find them by.
+# ---------------------------------------------------------------------------
+
+_PAGED = {"nxd_paged_columns_total": ("skipped", "live"),
+          "nxd_paged_block_visits_total": ("fetched", "shared"),
+          "nxd_paged_pairs_total": ("narrow", "one_row_whole"),
+          "nxd_paged_shared_pairs_total": ()}
+_STATES = {"nxd_state_resets_total": (),
+           "nxd_state_slot_steps_total": ("advanced", "held")}
+_MOE = {"nxd_moe_assignments_total": ("kept", "dropped")}
+DECLARED = {
+    "llama": _PAGED,
+    "mixtral": _PAGED,
+    "evabyte": {
+        "nxd_eva_columns_total": ("skipped", "exact", "summary"),
+        "nxd_eva_windows_total": (),
+        **{n: _PAGED[n] for n in list(_PAGED)[1:]}},
+    "minicpm_sala": {
+        **_STATES,
+        "nxd_sparse_columns_total": ("selected", "forced", "dense",
+                                     "skipped"),
+        "nxd_sparse_positions_total": ("attended", "skipped"),
+        "nxd_sparse_block_visits_total": ("fetched", "shared"),
+        "nxd_sparse_key_visits_total": ("fetched", "shared")},
+    "glm_moe_lite": {
+        "nxd_paged_columns_total": ("skipped", "live"),
+        "nxd_paged_block_visits_total": ("fetched", "shared"),
+        "nxd_mla_block_fetches_total": ("in_run", "alone", "whole"),
+        **_MOE},
+    "granite_hybrid": {**_PAGED, **_STATES},
+    "laguna": {
+        **_PAGED,
+        "nxd_window_columns_total": ("live", "behind"),
+        "nxd_kv_blocks_held_total": ("full", "window"),
+        **_MOE,
+        "nxd_moe_held_total": ("held", "elsewhere")},
+}
+#: the leaves a family's step counts into on the device, and their lengths
+ON_DEVICE = {"minicpm_sala": {"counts": 10}, "glm_moe_lite": {"moe_counts": 2},
+             "laguna": {"moe_counts": 3}}
+
+
+def _tiny_family(which):
+    import importlib
+
+    module = importlib.import_module(
+        f"neuronx_distributed_tpu.models.{which}")
+    if which == "glm_moe_lite":     # a config alone: nothing is built
+        return module.GlmMoeLiteConfig().serving_family()
+    make = tiny_moe_config if which == "mixtral" else module.tiny_config
+    return make().serving_family()
+
+
+@pytest.mark.parametrize("which", list(DECLARED))
+def test_a_family_declares_its_steps_counters(which):
+    family = _tiny_family(which)
+    declared = family.counters()
+    assert [(c.name, c.kinds) for c in declared] \
+        == list(DECLARED[which].items())
+    assert all(c.help and declared_anywhere()[c.name] is c
+               for c in declared)
+    on_host = {c.name for c in family.cache_kind.counters}
+    leaves = family.device_counts()
+    assert {leaf.leaf: leaf.entries for leaf in leaves} \
+        == ON_DEVICE.get(which, {})
+    fed = [c for leaf in leaves for c, _ in leaf.reads]
+    assert on_host | {c.name for c in fed} == set(DECLARED[which])
+    assert not on_host & {c.name for c in fed}
+    for leaf in leaves:
+        read = leaf.read(np.arange(1, leaf.entries + 1))
+        assert {n: len(v) for n, v in read.items()} \
+            == {c.name: max(1, len(c.kinds)) for c, _ in leaf.reads}
+        # every entry of the leaf is read, each kind from entries of its own
+        used = [i for _, entries in leaf.reads for e in entries for i in e]
+        assert set(used) == set(range(leaf.entries))
+    if which == "laguna":
+        assert leaves[0].read(np.array([5, 2, 4])) == {
+            "nxd_moe_assignments_total": [5, 2],
+            "nxd_moe_held_total": [7, 4]}
+
+
+@pytest.mark.parametrize("which", list(DECLARED))
+def test_a_kind_counts_a_step_under_the_names_it_declares(which):
+    """The hook's keys are the kind's declared families, each with a count
+    a kind (one with none): of a step of two rows of one slot and a pad
+    row."""
+    positions = np.array([0, 1, PAD_POSITION], np.int32)
+    slot_ids = np.array([0, 0, 2], np.int32)
+    tables = np.array([[3, -1, -1, -1, -1, -1], [-1] * 6], np.int32)
+    kind = _tiny_family(which).cache_kind
+    cfg = tiny_config(num_heads=4, num_kv_heads=2)
+    counts = step_counter(kind, cfg, block_size=8, pool_blocks=4,
+                          itemsize=4)(positions, slot_ids, tables, [1], 1)
+    assert {n: len(v) for n, v in counts.items()} == {
+        c.name: max(1, len(c.kinds)) for c in kind.counters}
+    if "nxd_paged_columns_total" in counts:
+        assert list(counts["nxd_paged_columns_total"]) == [16, 2]
+        assert list(counts["nxd_paged_block_visits_total"]) == [1, 1]
+    if "nxd_state_slot_steps_total" in counts:
+        assert list(counts["nxd_state_slot_steps_total"]) == [1, 0]
+        assert list(counts["nxd_state_resets_total"]) == [1]
+
+
+def test_the_benchmarks_counters_are_declared_and_documented():
+    """Read from the files, none edited: every counter a metric of the
+    benchmark reads is declared by a kind, by a family, or is one of the
+    engine's own two; every declared counter has its row in the
+    catalog."""
+    import glob
+    import json
+    import os
+
+    root = os.path.join(os.path.dirname(__file__), os.pardir)
+    read = set()
+
+    def collect(node):
+        if isinstance(node, dict):
+            if isinstance(node.get("counter"), str):
+                read.add(node["counter"])
+            for value in node.values():
+                collect(value)
+        elif isinstance(node, list):
+            for value in node:
+                collect(value)
+
+    for path in [os.path.join(root, "BENCHMARK.json")] + sorted(glob.glob(
+            os.path.join(root, "benchmarks", "layer_metrics", "*.json"))):
+        with open(path) as f:
+            collect(json.load(f))
+    assert len(read) >= 16
+    by_families = {n for names in DECLARED.values() for n in names}
+    assert by_families == set(declared_anywhere())
+    assert read <= by_families | {"nxd_engine_rows_total",
+                                  "nxd_engine_steps_total"}
+    with open(os.path.join(root, "docs", "observability.md")) as f:
+        catalog = {line.split("`")[1] for line in f
+                   if line.startswith("| `nxd_")}
+    assert by_families <= catalog
