@@ -187,6 +187,22 @@ KV_BLOCKS_HELD = CounterFamily(
     "the context), or window, blocks of the slots' rings in the "
     "sliding-window layers' pool (at most the ring a slot).",
     ("full", "window"))
+KV_BYTES_HELD = CounterFamily(
+    "nxd_kv_bytes_held_total",
+    "Bytes of K and of V that the occupied slots hold, a step, over the "
+    "layers that hold them: in the full-attention layers' pool (full_k, "
+    "full_v: they grow with the context) and in the slots' rings of the "
+    "sliding-window layers' pool (window_k, window_v). Blocks of the two "
+    "pools are unlike in bytes where their K/V heads or their K and V "
+    "rows are.",
+    ("full_k", "full_v", "window_k", "window_v"))
+STEP_ROWS_BY_CONTEXT = CounterFamily(
+    "nxd_step_rows_by_context_total",
+    "Real rows of the serving workers' packed steps by the row's "
+    "position: to_2k (under 2,048), to_8k (under 8,192) or past_8k. A "
+    "full-attention layer's walk is as long as the row's context, a "
+    "sliding-window layer's is not.",
+    ("to_2k", "to_8k", "past_8k"))
 SPARSE_COLUMNS = CounterFamily(
     "nxd_sparse_columns_total",
     "Grid steps of the sparse_paged_attention kernel's walk (rows x K/V "
@@ -700,6 +716,12 @@ class WindowPoolCache(FullCache):
     full_layers: int = 0
     window_layers: int = 0
     window: int = 0
+    #: ``(K/V heads, K row, V row)`` of a position in the full layers'
+    #: pool and in the rings, from the family. A K row wider than the V
+    #: row lies in whole lanes
+    #: (:func:`..ops.paged_attention.keys_to_lanes`)
+    full_rows: Tuple[int, int, int] = (0, 0, 0)
+    window_rows: Tuple[int, int, int] = (0, 0, 0)
     name = "window_pool"
     #: as :attr:`LatentCache.moe_leaf`; its device holds a share of the
     #: experts
@@ -707,7 +729,8 @@ class WindowPoolCache(FullCache):
 
     @property
     def counters(self) -> Tuple[CounterFamily, ...]:
-        return super().counters + (WINDOW_COLUMNS, KV_BLOCKS_HELD)
+        return super().counters + (WINDOW_COLUMNS, KV_BLOCKS_HELD,
+                                   KV_BYTES_HELD, STEP_ROWS_BY_CONTEXT)
 
     def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
                    held: Sequence[int], rolled: int) -> Dict[str, Any]:
@@ -715,7 +738,8 @@ class WindowPoolCache(FullCache):
         the window layers, by the sliding kernel's own guard: of the
         columns up to a row's position those its ring still holds, and
         the blocks the occupied slots hold, times the layers that hold
-        them, in the full layers' pool and in the rings."""
+        them, in the full layers' pool and in the rings, their bytes by
+        pool and operand, and the real rows by their position."""
         from ..ops.paged_attention import sliding_column_live
 
         counts = super().count_step(geo, positions, slot_ids, tables, held,
@@ -726,9 +750,16 @@ class WindowPoolCache(FullCache):
         live = np.count_nonzero(sliding_column_live(
             0, np.arange(ring), at[:, None], bs, self.window, ring))
         counts[WINDOW_COLUMNS.name] = (live, (at // bs + 1).sum() - live)
-        counts[KV_BLOCKS_HELD.name] = (
-            self.full_layers * sum(held),
-            self.window_layers * sum(min(n, ring) for n in held))
+        blocks = (self.full_layers * sum(held),
+                  self.window_layers * sum(min(n, ring) for n in held))
+        counts[KV_BLOCKS_HELD.name] = blocks
+        counts[KV_BYTES_HELD.name] = tuple(
+            n * bs * kv * width * geo.itemsize
+            for n, (kv, *widths) in zip(blocks, (self.full_rows,
+                                                 self.window_rows))
+            for width in widths)
+        counts[STEP_ROWS_BY_CONTEXT.name] = np.bincount(
+            np.searchsorted((2048, 8192), at, side="right"), minlength=3)
         return counts
 
     def geometry(self, block_size: int, step_rows: int = 0
@@ -755,13 +786,19 @@ class WindowPoolCache(FullCache):
             raise ValueError("a window_pool cache has no int8 pool: the "
                              "ring's rows want scales of their own")
         self.geometry(block_size)
-        kv, d = model_cfg.num_kv_heads, model_cfg.head_dim_
         ring_blocks = table_rows * self.window_ring(block_size)
-        full = (self.full_layers, num_blocks, block_size, kv, d)
-        ring = (self.window_layers, ring_blocks, block_size, kv, d)
+
+        def pool(layers, blocks, rows):
+            kv, k_row, v_row = rows
+            lead = (layers, blocks, block_size)
+            return (jnp.zeros(lead + ((kv, k_row) if k_row == v_row
+                                      else (kv * k_row,)), dtype),
+                    jnp.zeros(lead + (kv, v_row), dtype))
+
+        k, v = pool(self.full_layers, num_blocks, self.full_rows)
+        wk, wv = pool(self.window_layers, ring_blocks, self.window_rows)
         return WindowPoolPagedCache(
-            k=jnp.zeros(full, dtype), v=jnp.zeros(full, dtype),
-            wk=jnp.zeros(ring, dtype), wv=jnp.zeros(ring, dtype),
+            k=k, v=v, wk=wk, wv=wv,
             wpos=jnp.full((ring_blocks, block_size), PAD_POSITION,
                           jnp.int32),
             moe_counts=(jnp.zeros((self.moe_leaf.entries,), jnp.int32)

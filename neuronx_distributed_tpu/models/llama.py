@@ -201,6 +201,9 @@ class LlamaConfig:
     attention_kind: str = "full"
     # what softmax attention multiplies q . k by (None: 1 / sqrt(head_dim))
     attn_scale: Optional[float] = None
+    # the size of a V head where it is not a q/k head's (None: head_dim);
+    # the attended values and o_proj's input are heads of this size
+    v_head_dim: Optional[int] = None
     # per-head RMSNorm of q and k before the rotary embedding
     qk_norm: bool = False
     # rotary position embedding on q and k
@@ -327,6 +330,10 @@ class LlamaConfig:
         return self.head_dim or self.hidden_size // self.num_heads
 
     @property
+    def v_head_dim_(self) -> int:
+        return self.v_head_dim or self.head_dim_
+
+    @property
     def attn_scale_(self) -> float:
         import math as _math
 
@@ -396,18 +403,22 @@ def _cp_prefill_attend(cfg: LlamaConfig, q, k, v, positions, view):
     return out.astype(cfg.dtype), new_view
 
 
-def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
+def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view,
+                        sink=None):
     """Attention against the paged block pool: (optionally quantize and)
     scatter this step's K/V rows into the view's layer of the stacks at
     the precomputed flat indices, then attend that layer's blocks through
     the per-token block tables (:mod:`..ops.paged_attention`). The packed
     batch is ``[1, T]``; rows with a dropped write index (pads, preempted
     slots) never land in the pool and their outputs are discarded by the
-    caller.
+    caller. ``v`` may be heads of ``cfg.v_head_dim_`` beside ``k``'s of
+    ``cfg.head_dim_`` (the view's K stack is then a wide-key pool's, and
+    the output heads of the V size); ``sink [N]`` is the layer's logit a
+    query head in the softmax's denominator (None: none).
     """
     from ..inference import paging
     from ..inference.kv_cache import quantize_kv
-    from ..ops.paged_attention import paged_attention
+    from ..ops.paged_attention import keys_to_lanes, paged_attention
     from ..parallel import comm
 
     # inside a cp shard_map the pool's block dim is sharded over the cp
@@ -418,6 +429,8 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
     cp = comm._axis_size(ps.CP_AXIS)
     combine = ps.CP_AXIS if cp not in (None, 1) else None
     k_rows, v_rows = k[0], v[0]                      # [T, KV_local, D]
+    if view.k.ndim == 4:
+        k_rows = keys_to_lanes(k_rows)
 
     def write(pool, rows):
         return paging.write_pool_rows(pool, rows, view.write_idx, view.layer)
@@ -436,7 +449,8 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view):
         view.layer, k_scale=new_ks, v_scale=new_vs,
         scale=cfg.attn_scale_,
         force_pallas=cfg.attn_force_pallas,
-        combine_axis=combine, walk=view.walk, sliding=view.sliding)[None]
+        combine_axis=combine, walk=view.walk, sliding=view.sliding,
+        sink=sink)[None]
     new_view = view.replace(k=new_k, v=new_v, k_scale=new_ks,
                             v_scale=new_vs)
     return out.astype(cfg.dtype), new_view

@@ -83,6 +83,12 @@ def mla_attention_impl(row: int, rank: int, block_size: int,
     of ``row`` lanes; on a TPU the answer is the kernel or an error."""
     impl = paged_attention_impl(row, block_size, force_pallas,
                                 kernel_only=True)
+    if impl == "pallas" and row % LANES:
+        # the paged kernel also serves heads that share a row's lanes; a
+        # latent row is one row for all heads
+        raise ValueError(f"latent shapes (row={row}, block_size="
+                         f"{block_size}) don't tile for the TPU kernel: a "
+                         "latent row is whole lanes")
     if impl == "pallas" and rank % LANES:
         raise ValueError(f"mla_paged_attention: a latent of {rank} values "
                          f"is no whole lanes; the kernel slices the value "

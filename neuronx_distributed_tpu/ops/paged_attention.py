@@ -94,13 +94,16 @@ logger = get_logger(__name__)
 
 def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos, layer,
                          k_scale, v_scale, scale, combine_axis=None,
-                         window=None, sliding=None):
+                         window=None, sliding=None, sink=None):
     t, n, d = q.shape
-    _, nb, bs, kv, _ = k_pool.shape
+    nb, bs = k_pool.shape[1:3]
+    kv, dv = v_pool.shape[3:]
     n_rep = n // kv
     safe = jnp.clip(tables, 0, nb - 1)
     kg = k_pool[layer, safe]                   # [T, maxb, bs, KV, D]
     vg = v_pool[layer, safe]
+    if kg.ndim == 4:
+        kg = keys_of_lanes(kg, kv, d)
     pg = pool_pos[safe]                        # [T, maxb, bs]
     # entries gathered through an unmapped (-1) table slot are another
     # sequence's data — force their stored position to the pad sentinel
@@ -110,7 +113,7 @@ def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos, layer,
         vg = dequantize_kv(vg, v_scale[layer, safe], q.dtype)
     length = tables.shape[1] * bs
     k_full = repeat_kv(kg.reshape(t, length, kv, d).astype(q.dtype), n_rep)
-    v_full = repeat_kv(vg.reshape(t, length, kv, d).astype(q.dtype), n_rep)
+    v_full = repeat_kv(vg.reshape(t, length, kv, dv).astype(q.dtype), n_rep)
     pg = pg.reshape(t, length)
     scores = jnp.einsum("bqnd,bknd->bnqk", q[:, None].astype(jnp.float32),
                         k_full.astype(jnp.float32)) * scale
@@ -137,7 +140,14 @@ def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos, layer,
             t, 1, 1, length)
     scores = jnp.where(mask, scores, -1e30)
     if combine_axis is None:
-        probs = jax.nn.softmax(scores, axis=-1)
+        if sink is None:
+            probs = jax.nn.softmax(scores, axis=-1)
+        else:
+            # a column of the softmax that holds no value: a logit a head
+            probs = jax.nn.softmax(jnp.concatenate(
+                [scores, jnp.broadcast_to(
+                    sink.astype(jnp.float32)[None, :, None, None],
+                    scores.shape[:3] + (1,))], axis=-1), axis=-1)[..., :-1]
         out = jnp.einsum("bnqk,bknd->bqnd", probs,
                          v_full.astype(jnp.float32))
         return out[:, 0].astype(q.dtype)
@@ -154,6 +164,81 @@ def _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables, q_pos, layer,
         combine_axis)                                          # [T,1,N,D]
     out = o / jnp.maximum(l[..., 0], 1e-30)[:, None, :, None]
     return out[:, 0].astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Keys wider than the values (a K head of 192 beside a V head of 128). Such
+# a K pool is ``[L, num_blocks, block_size, KV * D]``, a position's keys one
+# row of whole 128-lane chunks with no lane idle in HBM (a minor pair ``(KV,
+# 192)`` would be stored as 256 lanes a head): first each head's leading
+# whole chunks (head ``h``'s ``j``-th at chunk ``h * whole + j``), then the
+# heads' remaining ``rest = D % 128`` values, ``128 // rest`` heads side by
+# side a chunk. The kernel reads a chunk as an aligned lane slice of the
+# block and meets it with the same chunk of the query, the query's ``rest``
+# values zero-padded into its head's share of their chunk (as a head of 64
+# is met where two lie on a pool row).
+# ---------------------------------------------------------------------------
+
+LANES = 128
+
+
+def _key_split(kv: int, d: int):
+    """``(whole, rest, pack)`` of a K head of ``d`` values: its whole
+    chunks, what is left, and the heads whose rest shares a chunk."""
+    whole, rest = divmod(d, LANES)
+    pack = LANES // rest if rest else 1
+    if rest and (LANES % rest or kv % pack):
+        raise ValueError(
+            f"{kv} K heads of {d} values fill no whole {LANES}-lane chunks: "
+            f"what a head has past its whole chunks divides {LANES}, and "
+            "the heads divide by how many share a chunk")
+    return whole, rest, pack
+
+
+def key_chunks(kv: int, d: int):
+    """Where the pool row holds head ``h``'s keys: ``[h]`` the chunks of
+    its whole parts and then, where it has a rest, the chunk its rest
+    shares."""
+    whole, rest, pack = _key_split(kv, d)
+    return tuple(
+        tuple(range(h * whole, (h + 1) * whole))
+        + ((kv * whole + h // pack,) if rest else ())
+        for h in range(kv))
+
+
+def keys_to_lanes(k: jax.Array) -> jax.Array:
+    """``k [..., KV, D]`` as the rows of a wide-key pool ``[..., KV * D]``."""
+    *lead, kv, d = k.shape
+    whole, rest, _ = _key_split(kv, d)
+    return jnp.concatenate(
+        [k[..., :whole * LANES].reshape(*lead, kv * whole * LANES),
+         k[..., whole * LANES:].reshape(*lead, kv * rest)], axis=-1)
+
+
+def keys_of_lanes(rows: jax.Array, kv: int, d: int) -> jax.Array:
+    """:func:`keys_to_lanes` undone: ``rows [..., KV * D]`` as ``[..., KV,
+    D]``."""
+    whole, rest, _ = _key_split(kv, d)
+    lead = rows.shape[:-1]
+    cut = kv * whole * LANES
+    return jnp.concatenate(
+        [rows[..., :cut].reshape(*lead, kv, whole * LANES),
+         rows[..., cut:].reshape(*lead, kv, rest)], axis=-1)
+
+
+def queries_to_lanes(q: jax.Array, kv: int) -> jax.Array:
+    """``q [T, N, D]`` as the kernel meets a wide-key pool's chunks: a
+    head's whole chunks as they are and, where it has a rest, one more
+    chunk with the rest in its K head's share of the lanes and zeros in
+    the other heads' shares."""
+    t, n, d = q.shape
+    whole, rest, pack = _key_split(kv, d)
+    if not rest:
+        return q
+    share = (jnp.arange(n) // (n // kv)) % pack
+    tail = (q[:, :, None, whole * LANES:] * jax.nn.one_hot(
+        share, pack, dtype=q.dtype)[None, :, :, None]).reshape(t, n, LANES)
+    return jnp.concatenate([q[..., :whole * LANES], tail], axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -408,10 +493,15 @@ def _head_rows(block_ref):
     ``KV``-th row of the block seen as ``[block_size * KV, D]``. bf16 and
     int8 rows lie two and four to a 32-bit sublane; those are read as
     words, one strided read for the heads that share them, and taken
-    apart with shifts."""
+    apart with shifts. A wide-key pool's block ``[block_size, KV * D]``
+    gives its 128-lane chunks instead (:func:`key_chunks`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if len(block_ref.shape) == 2:
+        # a wide-key pool's block: its chunks, aligned lane slices
+        return [block_ref[:, c * LANES:(c + 1) * LANES].astype(jnp.float32)
+                for c in range(block_ref.shape[1] // LANES)]
     bs, kv, d = block_ref.shape
     dtype = block_ref.dtype
     packing = 4 // dtype.itemsize
@@ -459,7 +549,8 @@ def _p_times_v(p, v):
 
 def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
                   *refs, pairs: int, group: int, scale: float,
-                  quantized: bool, window: Optional[tuple]):
+                  quantized: bool, window: Optional[tuple],
+                  key_at: Optional[tuple] = None, sink: bool = False):
     """One tile of packed rows against the pool blocks its rows attend:
     a loop over the tile's ``count_ref[tile]`` pairs (:func:`tile_pairs`),
     each block copied once from the stacks in HBM into one of two VMEM
@@ -484,7 +575,14 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
     those of the columns from there on are summaries and count whole; one
     softmax runs over both. A sliding-window layer (``swa_attention``) is
     that with a ring as wide as the table, so no column is a summary, and
-    the window's first position the row's own less the window."""
+    the window's first position the row's own less the window.
+
+    ``key_at`` (a wide-key pool, :func:`key_chunks`): head ``h``'s score
+    is the sum over its chunks of the query's chunk times the block's.
+    ``sink``: a logit a query head that stands in the softmax's
+    denominator and holds no value, the online softmax's starting state
+    (``m`` the logit, ``l`` 1, the accumulator 0); a row that attends
+    nothing still gives zeros."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -492,6 +590,8 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
     if window is not None:
         qlo_ref, *refs = refs
     q_ref, k_hbm, v_hbm, pos_hbm, *refs = refs
+    if sink:
+        sink_ref, *refs = refs
     if quantized:
         ks_hbm, vs_hbm, o_ref, k_buf, v_buf, pos_buf, ks_buf, vs_buf, \
             sems, m_ref, l_ref, acc_ref = refs
@@ -516,8 +616,12 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
         return [pltpu.make_async_copy(src, buf.at[slot], sems.at[slot, i])
                 for i, (src, buf) in enumerate(moves)]
 
-    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    if sink:
+        m_ref[...] = sink_ref[...]
+        l_ref[...] = jnp.ones_like(l_ref)
+    else:
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(count > 0)
@@ -541,6 +645,18 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
         k_heads = _head_rows(k_buf.at[slot])
         v_heads = _head_rows(v_buf.at[slot])
 
+        def q_times_k(h, rows):
+            def dot(a, b):
+                return jax.lax.dot_general(
+                    a.astype(operand), b.astype(operand),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+            if key_at is None:
+                return dot(q_ref[h, rows, :], k_heads[h])
+            return sum(dot(q_ref[h, rows, i * LANES:(i + 1) * LANES],
+                           k_heads[c]) for i, c in enumerate(key_at[h]))
+
         def attend(rows):
             """The pair against the tile's rows ``rows`` (a slice)."""
             served = served_ref[rows, :]                # [rows', maxb]
@@ -554,10 +670,7 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
                                     col >= window[1])
             ok = ok & named
             for h in range(kv):
-                s = jax.lax.dot_general(
-                    q_ref[h, rows, :].astype(operand),
-                    k_heads[h].astype(operand), (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
+                s = q_times_k(h, rows) * scale
                 if quantized:
                     s = s * ks_buf[slot, pl.ds(h, 1), :]
                 s = jnp.where(ok, s, -jnp.inf)                # [rows', bs]
@@ -599,12 +712,18 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
 
 def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
                             layer, k_scale, v_scale, scale, interpret=False,
-                            window=None, walk=None, sliding=None):
+                            window=None, walk=None, sliding=None, sink=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     t, n, d = q.shape
-    _, nb, bs, kv, _ = k_pool.shape
+    nb, bs = k_pool.shape[1:3]
+    kv, dv = v_pool.shape[3:]
+    key_at = None
+    if k_pool.ndim == 4:
+        key_at = key_chunks(kv, d)
+        q = queries_to_lanes(q, kv)
+        d = q.shape[-1]
     maxb = tables.shape[1]
     n_rep = n // kv
     quantized = k_scale is not None
@@ -628,8 +747,9 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
     def row_block(*last):
         return pl.BlockSpec((None, wide) + last, lambda i, *_: (i, 0, 0))
 
-    def head_block():
-        return pl.BlockSpec((None, kv, wide, d), lambda i, *_: (i, 0, 0, 0))
+    def head_block(width=d):
+        return pl.BlockSpec((None, kv, wide, width),
+                            lambda i, *_: (i, 0, 0, 0))
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [row_block(maxb), row_block(1)]
@@ -641,8 +761,15 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
     # positions (shared by the layers) ride as [nb, 1, bs] rows
     in_specs += [head_block(), hbm, hbm, hbm]
     operands += [by_tile(q), k_pool, v_pool, pool_pos.reshape(nb, 1, bs)]
-    scratch = [pltpu.VMEM((2, bs, kv, d), k_pool.dtype),
-               pltpu.VMEM((2, bs, kv, d), v_pool.dtype),
+    if sink is not None:
+        # a query head's logit where the walk's rows have the head: row
+        # ``r * n_rep + rep`` of K/V head ``h`` is head ``h * n_rep + rep``
+        in_specs.append(pl.BlockSpec((kv, wide, 1), lambda i, *_: (0, 0, 0)))
+        operands.append(jnp.tile(
+            sink.astype(jnp.float32).reshape(kv, 1, n_rep),
+            (1, rows, 1)).reshape(kv, wide, 1))
+    scratch = [pltpu.VMEM((2, bs) + k_pool.shape[3:], k_pool.dtype),
+               pltpu.VMEM((2, bs, kv, dv), v_pool.dtype),
                pltpu.VMEM((2, 1, bs), jnp.int32)]
     if quantized:
         # slots on lanes is how the chip stores an array whose last dim is
@@ -655,29 +782,30 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
     scratch += [pltpu.SemaphoreType.DMA((2, 5 if quantized else 3)),
                 pltpu.VMEM((kv, wide, 1), jnp.float32),
                 pltpu.VMEM((kv, wide, 1), jnp.float32),
-                pltpu.VMEM((kv, wide, d), jnp.float32)]
+                pltpu.VMEM((kv, wide, dv), jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(tiles,),
         in_specs=in_specs,
-        out_specs=head_block(),
+        out_specs=head_block(dv),
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, pairs=pairs,
                           group=narrow_rows(n_rep), scale=scale,
-                          quantized=quantized, window=window),
+                          quantized=quantized, window=window, key_at=key_at,
+                          sink=sink is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((tiles, kv, wide, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((tiles, kv, wide, dv), q.dtype),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
         name=("swa_attention" if sliding is not None else "paged_attention"
               if window is None else "eva_attention"),
     )(walk.count, walk.blocks, walk.cols, walk.narrow,
       jnp.asarray(layer, jnp.int32).reshape(1), *operands)
-    return out.reshape(tiles, kv, rows, n_rep, d).swapaxes(1, 2).reshape(
-        tiles * rows, n, d)[:t]
+    return out.reshape(tiles, kv, rows, n_rep, dv).swapaxes(1, 2).reshape(
+        tiles * rows, n, dv)[:t]
 
 
 @functools.lru_cache(maxsize=None)
@@ -696,7 +824,10 @@ def paged_attention_impl(head_dim: int, block_size: int,
     (:class:`..inference.paging.StatePoolCache`: :func:`paged_attention`
     then pads each query head into its share of the lanes); a narrow pool
     that is not laid so falls to the reference where it is traced, and
-    says so there. A
+    says so there. A head of whole lanes and such a share (192) is served
+    by it from a wide-key pool (:func:`keys_to_lanes`:
+    :class:`..inference.paging.WindowPoolCache` with keys wider than the
+    values). A
     TPU that falls to the reference for its shapes says so once, here;
     with ``kernel_only`` (a family whose pool only the kernel can serve at
     its size: the reference gathers every row's whole table) it raises
@@ -705,7 +836,8 @@ def paged_attention_impl(head_dim: int, block_size: int,
         return "xla"
     if not on_tpu():
         return "pallas-interpret" if force_pallas else "xla"
-    if (head_dim % 128 == 0 or 128 % head_dim == 0) and block_size % 128 == 0:
+    rest = head_dim % LANES
+    if (rest == 0 or LANES % rest == 0) and block_size % 128 == 0:
         return "pallas"
     if force_pallas or kernel_only:
         raise ValueError(
@@ -732,7 +864,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     combine_axis: Optional[str] = None,
                     window: Optional[tuple] = None,
                     walk: Optional[TileWalk] = None,
-                    sliding: Optional[int] = None) -> jax.Array:
+                    sliding: Optional[int] = None,
+                    sink: Optional[jax.Array] = None) -> jax.Array:
     """Paged decode attention.
 
     ``q [T, N, D]`` one query row per packed token; ``k_pool``/``v_pool``
@@ -773,9 +906,26 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
     ``walk``: the kernel's routing of this step (:func:`step_walk`), built
     once for all layers; built here when not given.
+
+    Keys wider than the values: ``k_pool [L, num_blocks, block_size, KV *
+    D]``, a position's keys in whole 128-lane chunks
+    (:func:`keys_to_lanes`), beside ``v_pool [.., KV, Dv]``; returns ``[T,
+    N, Dv]``. A float pool, full or ``sliding``.
+
+    ``sink [N]``: one logit a query head that stands in the softmax's
+    denominator and holds no value (``p_j = exp(s_j) / (exp(sink) + sum
+    exp(s))``). Not with ``combine_axis``.
     """
     t, n, d = q.shape
-    _, nb, bs, kv, lanes = k_pool.shape
+    if sink is not None and combine_axis is not None:
+        raise ValueError("a sink term is not combined across cp ranks")
+    wide_keys = k_pool.ndim == 4
+    if wide_keys and (window is not None or combine_axis is not None
+                      or k_scale is not None):
+        raise ValueError("a wide-key pool is a float pool of a full or a "
+                         "sliding layer")
+    bs, kv = k_pool.shape[2], v_pool.shape[3]
+    lanes = d if wide_keys else k_pool.shape[-1]
     if n % kv != 0:
         raise ValueError(f"q heads {n} not a multiple of kv heads {kv}")
     if (k_scale is None) != (v_scale is None):
@@ -800,11 +950,12 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             wide, k_pool, v_pool, pool_pos, tables, q_pos, layer,
             scale=scale_, force_pallas=force_pallas,
             combine_axis=combine_axis, window=window, walk=walk,
-            sliding=sliding)
+            sliding=sliding, sink=sink)
         return jnp.take_along_axis(
             out.reshape(t, n, pack, d), share[None, :, None, None],
             axis=2)[:, :, 0]
-    if bs % 128 == 0 and d % 128 and on_tpu() and force_pallas is None:
+    if (bs % 128 == 0 and d % 128 and not wide_keys and on_tpu()
+            and force_pallas is None):
         logger.warning(
             "paged_attention: a pool row of %d lanes is served by the XLA "
             "gather reference; lay %d K/V heads side by side on a row "
@@ -830,8 +981,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     if impl == "xla":
         return _paged_attention_xla(q, k_pool, v_pool, pool_pos, tables,
                                     q_pos, layer, k_scale, v_scale, scale_,
-                                    window=window, sliding=sliding)
+                                    window=window, sliding=sliding,
+                                    sink=sink)
     return _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables,
                                    q_pos, layer, k_scale, v_scale, scale_,
                                    interpret=impl == "pallas-interpret",
-                                   window=window, walk=walk, sliding=sliding)
+                                   window=window, walk=walk, sliding=sliding,
+                                   sink=sink)

@@ -17,6 +17,7 @@ file for the same reason.
 
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -1169,6 +1170,126 @@ def test_window_pool_step_at_the_published_widths(chip, topo, on_one_chip):
     stacks = [int(n) for shape, n in re.findall(
         r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
         if shape in (f"2,{blocks},128,8,128", f"3,{slots * 5},128,8,128")]
+    assert len(stacks) == 4 and set(stacks) <= aliased, (stacks, header)
+
+    total, differ, kernels = scope_disagreements(text)
+    assert total > 0 and kernels == {"attn.kernel.full",
+                                     "attn.kernel.window"}
+    top = [d for d in differ if d[1].split(".")[0] != d[2].split(".")[0]]
+    assert sum(d[3] for d in top) <= 0.02 * total, top
+
+
+# -- keys of 192 beside values of 128: a wide-key pool, a sink term ----------
+
+@pytest.mark.parametrize("kv,cols,nb,sliding", [(4, 256, 10240, None),
+                                                (8, 2, 256, 128)],
+                         ids=["full-gqa16", "sliding-gqa8-sink"])
+def test_paged_attention_at_wide_keys(chip, kv, cols, nb, sliding):
+    """The kernel at MiMo-V2-Flash's widths: 64 query heads of 192 over 4
+    K/V heads (a full layer: tiles of 8 rows, a decode row's narrow
+    product 16 stacked rows) and over 8 under a window of one block with a
+    sink a head (a ring of 2: tiles of 16 rows, a narrow product of 8).
+    The K pool is rows of ``KV * 192`` values in whole 128-lane chunks,
+    which the kernel reads as aligned lane slices of the block; V is by
+    head, 128 wide, as every other pool."""
+    from neuronx_distributed_tpu.ops.paged_attention import (
+        _paged_attention_pallas, narrow_rows, tile_rows)
+
+    tokens, n, d, dv, bs, layers = 128, 64, 192, 128, 128, 2
+    assert tile_rows(n // kv, tokens) * (n // kv) == 128
+    assert narrow_rows(n // kv) == n // kv
+    fn = functools.partial(_paged_attention_pallas,
+                           scale=1.0 / math.sqrt(d), interpret=False,
+                           sliding=sliding)
+    text = _assert_kernel_compiles(
+        lambda q, k, v, pos, tables, q_pos, layer, sink: fn(
+            q, k, v, pos, tables, q_pos, layer, None, None,
+            sink=sink if sliding else None),
+        chip((tokens, n, d), jnp.bfloat16),
+        chip((layers, nb, bs, kv * d), jnp.bfloat16),
+        chip((layers, nb, bs, kv, dv), jnp.bfloat16),
+        chip((nb, bs), jnp.int32), chip((tokens, cols), jnp.int32),
+        chip((tokens,), jnp.int32), chip((), jnp.int32),
+        chip((n,), jnp.float32))
+    assert _kernel_instruction_names(text) == {
+        "swa_attention" if sliding else "paged_attention"}
+    assert {"tpu.matmul", "tpu.enqueue_dma"} <= _mosaic_ops(text)
+    # the pools are read where they lie: no copy of either beside them
+    assert not re.findall(
+        rf"bf16\[{layers},{nb},{bs},[\d,]+\]\S* (?:copy|transpose)\(", text)
+
+
+def test_wide_key_window_pool_step_at_the_published_widths(chip, topo,
+                                                           on_one_chip):
+    """The packed step of MiMo-V2-Flash's configuration file: it compiles
+    for the chip with both kernels in it, holds what the configuration
+    says it holds, and hands all four pool leaves (the keys in whole
+    lanes, the values by head, two head counts) back in the buffers they
+    came in."""
+    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
+                                                            sample)
+    from neuronx_distributed_tpu.obs.device_scopes import device_scope
+
+    config, models = _cell_config("mimo-v2-flash", None)
+    assert sorted(config["reduced"]) == [
+        "hybrid_layer_pattern", "moe_layer_freq", "n_routed_experts",
+        "num_hidden_layers", "vocab_size"]
+    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
+                                                         models)
+    s = config["serve"]
+    slots, blocks = s["max_slots"], s["num_blocks"]
+    assert cache.k.shape == (2, blocks, 128, 4 * 192)
+    assert cache.v.shape == (2, blocks, 128, 4, 128)
+    assert cache.wk.shape == (5, slots * 2, 128, 8 * 192)
+    assert cache.wv.shape == (5, slots * 2, 128, 8, 128)
+    tree = params["params"]["model"]
+    sliding = tree["layers_sliding_sparse"]["layer"]
+    assert sliding["attn"]["q_proj"]["kernel"].shape == (5, 4096, 12288)
+    assert sliding["attn"]["k_proj"]["kernel"].shape == (5, 4096, 1536)
+    assert sliding["attn"]["v_proj"]["kernel"].shape == (5, 4096, 1024)
+    assert sliding["attn"]["o_proj"]["kernel"].shape == (5, 8192, 4096)
+    assert sliding["attn"]["sink"].shape == (5, 64)
+    assert sliding["moe"]["experts"]["down"].shape == (5, 16, 2048, 4096)
+    assert sliding["moe"]["router"]["kernel"].shape == (5, 4096, 256)
+    full = tree["layers_full_sparse"]["layer"]["attn"]
+    assert full["k_proj"]["kernel"].shape == (1, 4096, 768)
+    assert "sink" not in full
+    assert params["params"]["lm_head"]["kernel"].shape == (4096, 19072)
+
+    def step_fn(params, cache, tokens, positions, slot_ids, rng):
+        logits, cache = forward(cfg, params, tokens, positions, cache,
+                                slot_ids=slot_ids)
+        with device_scope("sample"):
+            return sample(logits[0], rng, SamplingConfig()), cache
+
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
+        chip(rng.shape, rng.dtype)).compile()
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"paged_attention",
+                                               "swa_attention"}
+    gib = 2.0 ** 30
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) / gib
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params)) / gib
+    assert 6.38 < weights < 6.40                     # 3,430M in bfloat16
+    aot = config["assumed"]["serve_aot_gib"]
+    assert abs(weights - aot["weights"]) < 0.01
+    assert abs(mem.temp_size_in_bytes / gib - aot["temporaries"]) < 0.05
+    assert abs(held - aot["total"]) < 0.05, (held, mem)
+    assert held > 0.8 * 15.75                        # the file's share
+
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape in (f"2,{blocks},128,768", f"2,{blocks},128,4,128",
+                     f"5,{slots * 2},128,1536", f"5,{slots * 2},128,8,128")]
     assert len(stacks) == 4 and set(stacks) <= aliased, (stacks, header)
 
     total, differ, kernels = scope_disagreements(text)

@@ -37,14 +37,16 @@ SERVING = {"llama": "tiny-mistral-serve", "mixtral": "tiny-mixtral",
            "evabyte": "tiny-evabyte", "minicpm_sala": "tiny-minicpm-sala",
            "glm_moe_lite": "tiny-glm-moe-lite",
            "granite_hybrid": "tiny-granite-hybrid",
-           "laguna": "tiny-laguna"}
+           "laguna": "tiny-laguna",
+           "mimo_v2_flash": "tiny-mimo-v2-flash"}
 #: the children a family's step must open, and no other family's may
 OWN = {"attn.select": {"minicpm_sala"},
        "attn.state": {"minicpm_sala", "granite_hybrid"},
        "attn.conv": {"granite_hybrid"}, "attn.summarise": {"evabyte"},
-       "attn.kernel.full": {"laguna"}, "attn.kernel.window": {"laguna"},
-       "ffn.experts": {"mixtral", "glm_moe_lite", "laguna"},
-       "ffn.router": {"mixtral", "glm_moe_lite", "laguna"},
+       "attn.kernel.full": {"laguna", "mimo_v2_flash"},
+       "attn.kernel.window": {"laguna", "mimo_v2_flash"},
+       "ffn.experts": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash"},
+       "ffn.router": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash"},
        "ffn.shared": {"glm_moe_lite", "laguna"}}
 #: the operations that carry a step's device time
 HEAVY = ("stablehlo.dot_general", "stablehlo.custom_call",
@@ -263,7 +265,7 @@ def test_a_step_opens_the_children_its_family_has_and_no_others(which):
     for child, families in OWN.items():
         assert (child in seen) == (which in families), (child, seen)
     dense = which in ("llama", "evabyte", "minicpm_sala", "glm_moe_lite",
-                      "granite_hybrid", "laguna", "train")
+                      "granite_hybrid", "laguna", "mimo_v2_flash", "train")
     assert ("ffn.dense" in seen) == dense
     if which == "train":
         assert "optimizer" not in seen      # elementwise: no heavy operation
@@ -343,9 +345,17 @@ LOWERED_AT_PR_43 = {
 }
 
 
+#: and the second window-pool family's, as PR 45's tree lowers it (keys
+#: wider than the values, a sink operand): new with that PR
+LOWERED_AT_PR_45 = {
+    "mimo_v2_flash":
+        "c21da2185cc28ad082a28d43b35445c4a3da6500e538fad6cedfdbfdd7a4acbd",
+}
+
+
 @pytest.mark.parametrize("which", list(LOWERED_AT_PR_38)
                          + list(LOWERED_AT_PR_39) + list(LOWERED_AT_PR_42)
-                         + list(LOWERED_AT_PR_43))
+                         + list(LOWERED_AT_PR_43) + list(LOWERED_AT_PR_45))
 def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     """PR 39 changed the latent kernel and its walk alone: the packed
     steps of the five families that run the shared helpers of
@@ -357,12 +367,16 @@ def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     changed the sparse-state family's step (``ops/sparse_attention.py``:
     the selection's scores, their walk and its counts) and no other.
     PR 44 moved what the host counts of a step behind the cache kinds
-    and changed no program: all seven are the text they were. A PR that
-    changes one of these programs on purpose records its new hash
+    and changed no program: all seven are the text they were. PR 45 gave
+    the paged kernel keys wider than the values and a sink operand, each
+    off where a family does not ask for it, and moved the window-pool
+    family's pattern and forward to ``models/window_pool.py``: the seven
+    are still the text they were, and the new family's is recorded. A PR
+    that changes one of these programs on purpose records its new hash
     here."""
     import hashlib
 
     text = _stripped(_lowered(which))
     assert hashlib.sha256(text.encode()).hexdigest() == {
         **LOWERED_AT_PR_38, **LOWERED_AT_PR_39, **LOWERED_AT_PR_42,
-        **LOWERED_AT_PR_43}[which]
+        **LOWERED_AT_PR_43, **LOWERED_AT_PR_45}[which]

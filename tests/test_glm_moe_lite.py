@@ -383,14 +383,18 @@ def test_on_a_tpu_no_shape_falls_to_the_reference(monkeypatch):
     pa.paged_attention_impl.cache_clear()
     try:
         assert mla.mla_attention_impl(640, 512, 128) == "pallas"
-        assert pa.paged_attention_impl(576, 128) == "xla"    # warns, serves
+        # a row of whole lanes and a rest that shares none (600 = 4 x 128
+        # + 88) warns and serves; 576 = 4 x 128 + 64 is what a wide-key
+        # pool lays in whole lanes (ops/paged_attention.keys_to_lanes)
+        assert pa.paged_attention_impl(600, 128) == "xla"
+        assert pa.paged_attention_impl(576, 128) == "pallas"
         for row, rank, bs in ((576, 512, 128), (640, 512, 16)):
             with pytest.raises(ValueError, match="don't tile"):
                 mla.mla_attention_impl(row, rank, bs)
         with pytest.raises(ValueError, match="no whole lanes"):
             mla.mla_attention_impl(640, 500, 128)
         with pytest.raises(ValueError, match="don't tile"):
-            pa.paged_attention_impl(576, 128, None, kernel_only=True)
+            pa.paged_attention_impl(600, 128, None, kernel_only=True)
     finally:
         pa.paged_attention_impl.cache_clear()
 

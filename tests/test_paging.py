@@ -741,12 +741,17 @@ DECLARED = {
         **_PAGED,
         "nxd_window_columns_total": ("live", "behind"),
         "nxd_kv_blocks_held_total": ("full", "window"),
+        "nxd_kv_bytes_held_total": ("full_k", "full_v", "window_k",
+                                    "window_v"),
+        "nxd_step_rows_by_context_total": ("to_2k", "to_8k", "past_8k"),
         **_MOE,
         "nxd_moe_held_total": ("held", "elsewhere")},
 }
+#: the second window-pool family declares what the first does
+DECLARED["mimo_v2"] = DECLARED["laguna"]
 #: the leaves a family's step counts into on the device, and their lengths
 ON_DEVICE = {"minicpm_sala": {"counts": 10}, "glm_moe_lite": {"moe_counts": 2},
-             "laguna": {"moe_counts": 3}}
+             "laguna": {"moe_counts": 3}, "mimo_v2": {"moe_counts": 3}}
 
 
 def _tiny_family(which):
@@ -782,7 +787,7 @@ def test_a_family_declares_its_steps_counters(which):
         # every entry of the leaf is read, each kind from entries of its own
         used = [i for _, entries in leaf.reads for e in entries for i in e]
         assert set(used) == set(range(leaf.entries))
-    if which == "laguna":
+    if which in ("laguna", "mimo_v2"):
         assert leaves[0].read(np.array([5, 2, 4])) == {
             "nxd_moe_assignments_total": [5, 2],
             "nxd_moe_held_total": [7, 4]}
@@ -845,3 +850,75 @@ def test_the_benchmarks_counters_are_declared_and_documented():
         catalog = {line.split("`")[1] for line in f
                    if line.startswith("| `nxd_")}
     assert by_families <= catalog
+
+
+# ---------------------------------------------------------------------------
+# A window-pool cache whose two pools have unlike rows
+# ---------------------------------------------------------------------------
+
+def test_a_window_pool_of_two_head_counts_and_two_row_widths():
+    """The full layers' pool holds 2 K/V heads a position and the rings 4,
+    the keys 192 values a head in whole lanes beside values of 128: the
+    leaves' shapes, a ring's write indices (they follow the slot and the
+    position, not the rows) and the bytes ``count_step`` counts by pool
+    and operand. A family of one head count and one head size has both
+    operands by head in both pools."""
+    from neuronx_distributed_tpu.inference import paging
+    from neuronx_distributed_tpu.models import laguna, mimo_v2
+
+    bs, rows = 4, 3
+    cfg = mimo_v2.tiny_config(dtype=jnp.float32)
+    kind = cfg.serving_family().cache_kind
+    assert (kind.full_rows, kind.window_rows) == ((2, 192, 128),
+                                                  (4, 192, 128))
+    cache = paging.init_serving_cache(
+        cfg, num_blocks=10, block_size=bs, table_rows=rows,
+        max_blocks_per_seq=6, dtype=jnp.float32)
+    ring = kind.window_ring(bs)
+    assert ring == 3 and cache.window_ring == ring
+    assert cache.k.shape == (2, 10, bs, 2 * 192)
+    assert cache.v.shape == (2, 10, bs, 2, 128)
+    assert cache.wk.shape == (3, rows * ring, bs, 4 * 192)
+    assert cache.wv.shape == (3, rows * ring, bs, 4, 128)
+    assert (cache.num_blocks, cache.capacity) == (10, 40)
+    # position 13 of slot 2 lies in ring column 3 % 3 = 0, row 1
+    tables, flat = paging.ring_write_indices(
+        jnp.asarray([2, 0, 5]), jnp.asarray([13, 6, PAD_POSITION]), bs,
+        ring, rows)
+    assert tables.tolist() == [[6, 7, 8], [0, 1, 2], [-1, -1, -1]]
+    assert flat.tolist() == [6 * bs + 1, 1 * bs + 2, rows * ring * bs]
+    # a row of 4 heads of 192 lands whole in its ring row's lanes
+    k = jnp.arange(4 * 192, dtype=jnp.float32).reshape(1, 4, 192)
+    wk = paging.write_pool_rows(cache.wk, pa.keys_to_lanes(k), flat[:1], 1)
+    got = pa.keys_of_lanes(wk[1, 6, 1], 4, 192)
+    assert (np.asarray(got) == np.asarray(k[0])).all()
+    assert float(jnp.abs(wk).sum()) == float(jnp.abs(k).sum())
+
+    # two occupied slots hold 5 and 2 blocks of the full pool; rows at
+    # positions 17 (slot 0), 5 and 9,000 (slot 1; its table is short: the
+    # host's counts follow the positions)
+    positions = np.array([17, 5, 9000, PAD_POSITION], np.int32)
+    slot_ids = np.array([0, 1, 1, rows], np.int32)
+    tables = np.full((rows, 6), -1, np.int32)
+    tables[0, :5], tables[1, :2] = np.arange(5), [5, 6]
+    counts = step_counter(kind, cfg, block_size=bs, pool_blocks=10,
+                          itemsize=2)(positions, slot_ids, tables, [5, 2], 0)
+    full, window = 2 * (5 + 2), 3 * (3 + 2)       # layers x blocks held
+    assert list(counts["nxd_kv_blocks_held_total"]) == [full, window]
+    assert list(counts["nxd_kv_bytes_held_total"]) == [
+        full * bs * 2 * 192 * 2, full * bs * 2 * 128 * 2,
+        window * bs * 4 * 192 * 2, window * bs * 4 * 128 * 2]
+    assert list(counts["nxd_step_rows_by_context_total"]) == [2, 0, 1]
+
+    one = laguna.tiny_config(dtype=jnp.float32)
+    plain = one.serving_family().cache_kind
+    assert plain.full_rows == plain.window_rows == (2, 16, 16)
+    cache = paging.init_serving_cache(
+        one, num_blocks=10, block_size=bs, table_rows=rows,
+        max_blocks_per_seq=6, dtype=jnp.float32)
+    assert cache.k.shape == cache.v.shape == (2, 10, bs, 2, 16)
+    assert cache.wk.shape == cache.wv.shape == (3, rows * 3, bs, 2, 16)
+    counts = step_counter(plain, one, block_size=bs, pool_blocks=10,
+                          itemsize=4)(positions, slot_ids, tables, [5, 2], 0)
+    assert list(counts["nxd_kv_bytes_held_total"]) == [
+        n * bs * 2 * 16 * 4 for n in (full, full, window, window)]
