@@ -95,14 +95,16 @@ class StepGeometry(NamedTuple):
     """What a kind's :meth:`FullCache.count_step` needs of the engine and
     the model it was bound to (:func:`step_counter`): the pool's block
     size, its blocks and the bytes of one of its elements, the model's
-    query heads, and the query heads the paged kernel sees a K/V head
-    (the model's, times the K/V heads the kind lays on a row)."""
+    query heads, the query heads the paged kernel sees a K/V head (the
+    model's, times the K/V heads the kind lays on a row), and the K and V
+    values a position holds a layer (:meth:`FullCache.row_values`)."""
 
     block_size: int
     pool_blocks: int
     itemsize: int
     heads: int
     n_rep: int
+    row_values: int
 
 
 def step_counter(kind, model_cfg, *, block_size: int, pool_blocks: int,
@@ -111,7 +113,8 @@ def step_counter(kind, model_cfg, *, block_size: int, pool_blocks: int,
     counts by."""
     return functools.partial(kind.count_step, StepGeometry(
         block_size, pool_blocks, itemsize, model_cfg.num_heads,
-        model_cfg.num_heads // model_cfg.num_kv_heads * kind.pack))
+        model_cfg.num_heads // model_cfg.num_kv_heads * kind.pack,
+        kind.row_values(model_cfg)))
 
 
 PAGED_COLUMNS = CounterFamily(
@@ -151,6 +154,17 @@ PAGED_SHARED_PAIRS = CounterFamily(
     "(a prefill chunk's blocks): the paged kernel computes them over the "
     "whole tile. With nxd_paged_pairs_total's two kinds they sum to "
     "nxd_paged_block_visits_total's fetched.")
+PAGED_BLOCK_FETCHES = CounterFamily(
+    "nxd_paged_block_fetches_total",
+    "Pool blocks the paged kernel fetches for the serving workers' rows "
+    "(one layer's worth at the full layers' head count; they sum to "
+    "nxd_paged_block_visits_total's fetched), by how: in_run, with one or "
+    "more other blocks of the same narrow group in one unit of the kernel "
+    "(one step of the online softmax over all of them); alone, a narrow "
+    "pair that is a unit by itself (every one where the pool's blocks ride "
+    "in no runs); whole, a pair that rows of the tile share beyond a "
+    "group, computed over the whole tile.",
+    ("in_run", "alone", "whole"))
 MLA_BLOCK_FETCHES = CounterFamily(
     "nxd_mla_block_fetches_total",
     "Pool blocks the mla_paged_attention kernel fetches for the serving "
@@ -274,7 +288,8 @@ _SPARSE_COUNTS = (
 
 #: what the paged kernel's tiles fetch for a step's rows, and over which
 #: rows they compute it
-_PAGED_FETCHES = (PAGED_BLOCK_VISITS, PAGED_PAIRS, PAGED_SHARED_PAIRS)
+_PAGED_FETCHES = (PAGED_BLOCK_VISITS, PAGED_PAIRS, PAGED_SHARED_PAIRS,
+                  PAGED_BLOCK_FETCHES)
 #: what a step does to the per-slot states of a kind that has ``leaves``
 _STATES = (STATE_RESETS, STATE_SLOT_STEPS)
 
@@ -304,13 +319,21 @@ def _count_walk(kind, geo: StepGeometry, columns: CounterFamily, positions,
 
 def _count_pairs(geo: StepGeometry, served) -> Dict[str, Any]:
     """A step's pairs (one layer's worth) by the rows the paged kernel
-    computes them over."""
-    from ..ops.paged_attention import pair_kinds
+    computes them over, and its fetches by the units they ride in (runs
+    of as many blocks as the kernel takes for the pool's block bytes)."""
+    from ..ops.paged_attention import (block_fetches, host_pairs, pair_kinds,
+                                       run_length)
 
+    pairs = host_pairs(served, geo.n_rep, geo.pool_blocks)
     narrow, one_row_whole, shared = pair_kinds(served, geo.n_rep,
-                                               geo.pool_blocks)
+                                               geo.pool_blocks, pairs)
+    run = run_length(geo.n_rep,
+                     geo.block_size * geo.row_values * geo.itemsize,
+                     geo.block_size, served.shape[1])
     return {PAGED_PAIRS.name: (narrow, one_row_whole),
-            PAGED_SHARED_PAIRS.name: (shared,)}
+            PAGED_SHARED_PAIRS.name: (shared,),
+            PAGED_BLOCK_FETCHES.name: block_fetches(served, geo.n_rep, run,
+                                                    pairs)}
 
 
 def _count_states(positions, slot_ids, held) -> Dict[str, Any]:
@@ -348,6 +371,11 @@ class FullCache:
 
     def geometry(self, block_size: int, step_rows: int = 0) -> "FullCache":
         return self
+
+    def row_values(self, model_cfg) -> int:
+        """K and V values a position holds in a layer of the pool the
+        paged kernel's counters follow (the full layers')."""
+        return 2 * model_cfg.num_kv_heads * model_cfg.head_dim_
 
     def column_of(self, positions, block_size: int):
         """Table column of a position's K/V row (ints, NumPy or jnp)."""
@@ -488,6 +516,7 @@ class WindowSummaryCache:
 
     device_counts = ()
     counters = (EVA_COLUMNS, EVA_WINDOWS) + _PAGED_FETCHES
+    row_values = FullCache.row_values
 
     def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
                    held: Sequence[int], rolled: int) -> Dict[str, Any]:
@@ -774,6 +803,10 @@ class WindowPoolCache(FullCache):
                 f"overwrites ring rows that its first rows still attend; "
                 f"token_budget must not exceed block_size ({block_size})")
         return self
+
+    def row_values(self, model_cfg) -> int:
+        kv, k_row, v_row = self.full_rows
+        return kv * (k_row + v_row)
 
     def window_ring(self, block_size: int) -> int:
         """Blocks of a slot's ring in the window pool."""

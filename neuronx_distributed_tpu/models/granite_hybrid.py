@@ -365,7 +365,8 @@ def granite_hybrid_forward_with_cache(cfg: GraniteHybridConfig, params,
             tables, q_pos, kv_cache.block_size, kv_cache.num_blocks,
             cfg.head_dim_ * kind.pack,
             cfg.num_heads // cfg.num_kv_heads * kind.pack,
-            force_pallas=cfg.attn_force_pallas)
+            force_pallas=cfg.attn_force_pallas,
+            pools=(kv_cache.k, kv_cache.v))
         seg = ssd.step_segments(slot_ids, q_pos, kv_cache.max_slots)
     with device_scope("attn.pool_write"):
         pool_pos = paging.write_pool_positions(kv_cache.pos, q_pos,

@@ -1452,7 +1452,8 @@ def llama_forward_with_cache(cfg: LlamaConfig, params, input_ids: jax.Array,
                     cfg.num_heads // cfg.num_kv_heads,
                     window=None if kind.ring is None else (kind.window,
                                                            kind.ring),
-                    force_pallas=cfg.attn_force_pallas)
+                    force_pallas=cfg.attn_force_pallas,
+                    pools=(kv_cache.k, kv_cache.v))
             body = _PagedScanBody
             routing = (slot_pos, tok_tables, write_idx, walk) + rope + roll
         scanned = nn.scan(

@@ -235,11 +235,13 @@ def window_pool_forward_with_cache(cfg, params, input_ids, positions,
         walk = {
             "full": pa.step_walk(tables["full"], q_pos, bs,
                                  kv_cache.num_blocks, cfg.head_dim_,
-                                 n_rep["full"], force_pallas=force),
+                                 n_rep["full"], force_pallas=force,
+                                 pools=(kv_cache.k, kv_cache.v)),
             "sliding": pa.step_walk(tables["sliding"], q_pos, bs,
                                     kv_cache.wk.shape[1], cfg.head_dim_,
                                     n_rep["sliding"], force_pallas=force,
-                                    sliding=cfg.sliding_window)}
+                                    sliding=cfg.sliding_window,
+                                    pools=(kv_cache.wk, kv_cache.wv))}
     with device_scope("attn.pool_write"):
         pool_pos = {
             "full": paging.write_pool_positions(kv_cache.pos, q_pos,

@@ -23,17 +23,18 @@ Two implementations behind one signature, as :mod:`.paged_attention` has:
   (:func:`stacked_heads`: 20 heads ride as 24) so that a decode row's
   heads are exactly one narrow group of the tile: a tile is ``tile_rows``
   packed rows (8) times the stacked heads (192 MXU rows). The kernel's
-  unit of work is a *run* of pairs (:func:`pair_runs`): up to 8 pairs
-  that one row alone names (a decode row's own blocks, against its 24
-  stacked heads), or up to 4 that rows of the tile share (a chunk's
-  blocks, against the whole tile; :func:`unit_blocks`). A unit's blocks
-  are copied side by side into one half of a ring while the unit before
-  it is computed, scored in one product ``[rows, row] x [row, blocks x
+  unit of work is a *run* of pairs (:func:`.paged_attention.pair_runs`,
+  the one cut of a walk into units, which the paged kernel takes too): up
+  to 8 pairs that one row alone names (a decode row's own blocks, against
+  its 24 stacked heads), or up to 4 that rows of the tile share (a chunk's
+  blocks, against the whole tile; :func:`.paged_attention.unit_blocks`).
+  A unit's blocks are copied side by side into one half of a ring while
+  the unit before it is computed, scored in one product ``[rows, row] x [row, blocks x
   block_size]`` from the stored operands into float32 and taken through
   one step of the online softmax, and ``p x v`` is one product against
   the blocks' first ``rank`` lanes with ``p``'s two bf16 parts stacked on
-  rows (:func:`_p_times_v`). Prefill chunks, decode rows and pad rows
-  take the one path.
+  rows (:func:`.paged_attention._p_times_v_stacked`). Prefill chunks,
+  decode rows and pad rows take the one path.
 
 On a TPU there is no silent fall to the reference: shapes the kernel
 cannot tile raise (:func:`mla_attention_impl`).
@@ -42,22 +43,21 @@ cannot tile raise (:func:`mla_attention_impl`).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..inference.kv_cache import PAD_POSITION
-from .paged_attention import (TileWalk, paged_attention_impl, tile_rows,
-                              tile_walk)
+from .paged_attention import (RunWalk, TileWalk, paged_attention_impl,
+                              tile_rows, tile_walk, unit_blocks)
+from .paged_attention import _p_times_v_stacked as _p_times_v
+from .paged_attention import block_fetches as _block_fetches
+from .paged_attention import run_walk as _run_walk
 from .pallas_utils import compiler_params as _compiler_params
 
 LANES = 128
-#: what the ring of a unit's blocks may take of VMEM (two halves), and a
-#: unit's float32 scores ``[stacked rows, positions]``: :func:`unit_blocks`
-RING_BYTES = 4 << 20
-SCORE_BYTES = 384 << 10
 
 
 def row_width(rank: int, rope: int) -> int:
@@ -96,84 +96,6 @@ def mla_attention_impl(row: int, rank: int, block_size: int,
     return impl
 
 
-def unit_blocks(rows: int, row: int, block_size: int, itemsize: int) -> int:
-    """Blocks of a unit of the kernel that ``rows`` stacked rows attend
-    (a run's 24, a shared pair's whole tile): the power of two, 8 at
-    most, whose two ring halves fit :data:`RING_BYTES` and whose scores
-    ``[rows, blocks x block_size]`` fit :data:`SCORE_BYTES`. A row of 640
-    bf16 lanes in blocks of 128: 8 blocks for a run (a ring of 2.5 MiB,
-    1,024 positions a step of the online softmax) and 4 for a tile of
-    192 rows, what the chip read fastest (``PERF.md``, Findings, PR 39)."""
-    most = min(8, RING_BYTES // (2 * block_size * row * itemsize),
-               SCORE_BYTES // (4 * rows * block_size))
-    return 1 << max(most, 1).bit_length() - 1
-
-
-class RunWalk(NamedTuple):
-    """What the kernel is handed of a step's routing (:func:`step_walk`),
-    the same for every layer: a :class:`..paged_attention.TileWalk`'s
-    pairs in the order of the kernel's units (:func:`pair_runs`):
-    ``units [tiles]`` the units of a tile; ``blocks``, ``cols`` and
-    ``narrow [tiles * P + room]`` the tile's pairs, the shared ones first
-    and then each one-row group's by column; ``lens``, at a unit's first
-    pair its number of pairs (:func:`unit_blocks` at most) and 0
-    elsewhere; ``served`` and ``q_pos`` the walk's own."""
-
-    units: jax.Array
-    blocks: jax.Array
-    cols: jax.Array
-    narrow: jax.Array
-    lens: jax.Array
-    served: jax.Array
-    q_pos: jax.Array
-
-
-def pair_runs(count, blocks, cols, narrow, num_blocks: int, max_cols: int,
-              group: int, groups: int, run: int, whole_run: int):
-    """A walk's pairs (``count [tiles]``; ``blocks``, ``cols``, ``narrow
-    [tiles, P]``, ``P`` no less than the largest count:
-    :func:`..paged_attention.tile_walk` over tables of ``max_cols``
-    columns, a tile of ``groups`` groups of ``group`` stacked rows) cut
-    into the kernel's units: the pairs that rows of several groups name
-    (``narrow < 0``) in runs of up to ``whole_run``, whichever rows name
-    each; the pairs that one group names alone (a decode row's own
-    blocks, one a column) in runs of up to ``run`` successive ones of
-    that group, the last shorter. Returns ``(units [tiles], blocks, cols,
-    narrow, lens [tiles, P])`` with the pairs reordered, the shared ones
-    first and then group by group, each in order of column and block, and
-    ``lens`` the length of the unit that starts at a pair, 0 where none
-    does. One sort of one key and no gather (a gather of the pairs cost
-    the step 0.6 ms: ``PERF.md``, Findings, PR 39)."""
-    tiles, per = blocks.shape
-    span = max_cols * num_blocks            # a (column, block) as one number
-    assert (groups + 2) * span < 2 ** 31
-    at = jnp.arange(per, dtype=jnp.int32)[None, :]
-    live = at < count[:, None]
-    # 0 a shared pair, 1 + g a pair of group g, last what lies beyond the
-    # tile's count
-    kind = jnp.where(live, jnp.where(narrow < 0, 0, 1 + narrow // group),
-                     groups + 1).astype(jnp.int32)
-    key = jnp.sort(
-        kind * span + jnp.where(live, cols * num_blocks + blocks, 0), axis=-1)
-    kind, pair = key // span, key % span
-    # the pairs lie sorted by kind: a kind's first is at the number of
-    # pairs of the kinds before it (a handful of kinds: a select each)
-    first, end, before = 0, 0, 0
-    for k in range(groups + 1):
-        mine = kind == k
-        many = jnp.sum(mine, axis=-1, keepdims=True)
-        first = jnp.where(mine, before, first)
-        end = jnp.where(mine, before + many, end)
-        before = before + many
-    most = jnp.where(kind == 0, whole_run, run)
-    lens = jnp.where(live & ((at - first) % most == 0),
-                     jnp.minimum(most, end - at), 0).astype(jnp.int32)
-    lone = (kind > 0) & (kind <= groups)
-    return (jnp.sum(lens > 0, axis=-1).astype(jnp.int32),
-            pair % num_blocks, pair // num_blocks,
-            jnp.where(lone, (kind - 1) * group, -1), lens)
-
-
 def step_walk(tables, q_pos, block_size: int, num_blocks: int, row: int,
               rank: int, num_heads: int, itemsize: int,
               force_pallas: Optional[bool] = None) -> Optional[RunWalk]:
@@ -190,57 +112,33 @@ def step_walk(tables, q_pos, block_size: int, num_blocks: int, row: int,
 def _unit_lengths(heads: int, wide: int, row: int, block_size: int,
                   itemsize: int):
     """Blocks of a run of one row's pairs and of a run of pairs that a
-    tile of ``wide`` stacked rows shares."""
-    return (unit_blocks(heads, row, block_size, itemsize),
-            unit_blocks(wide, row, block_size, itemsize))
+    tile of ``wide`` stacked rows shares
+    (:func:`.paged_attention.unit_blocks` of a block of latent rows)."""
+    block_bytes = block_size * row * itemsize
+    return (unit_blocks(heads, block_bytes, block_size),
+            unit_blocks(wide, block_bytes, block_size))
 
 
 def run_walk(walk: TileWalk, num_blocks: int, heads: int, row: int,
              block_size: int, itemsize: int) -> RunWalk:
-    """:func:`pair_runs` of a step's :class:`..paged_attention.TileWalk`
-    at the lengths the kernel takes for these shapes."""
-    tiles, wide, _ = walk.served.shape
-    run, whole_run = _unit_lengths(heads, wide, row, block_size, itemsize)
-    units, *pairs = pair_runs(
-        walk.count, *(x.reshape(tiles, -1) for x in
-                      (walk.blocks, walk.cols, walk.narrow)),
-        num_blocks, walk.served.shape[2], heads, wide // heads, run,
-        whole_run)
-    # the kernel reads a shared unit's pairs to its full length: room
-    # past the last tile's
-    blocks, cols, narrow, lens = (
-        jnp.pad(x.reshape(-1), (0, whole_run)) for x in pairs)
-    return RunWalk(units=units, blocks=blocks, cols=cols, narrow=narrow,
-                   lens=lens, served=walk.served, q_pos=walk.q_pos)
+    """:func:`.paged_attention.run_walk` of a step's walk at the lengths
+    the kernel takes for these shapes."""
+    run, whole_run = _unit_lengths(heads, walk.served.shape[1], row,
+                                   block_size, itemsize)
+    # the kernel reads a shared unit's pairs to its full length, a run's
+    # as far as the run goes
+    return _run_walk(walk, num_blocks, heads, run, whole_run, room=whole_run)
 
 
 def block_fetches(served, num_heads: int, row: int, block_size: int,
                   itemsize: int) -> np.ndarray:
-    """Pool blocks the kernel fetches for one layer of a packed step whose
-    rows attend ``served [T, max_blocks_per_seq]`` (NumPy: a row's table
-    entry in the columns it attends, -1 elsewhere), by how: ``[in_run,
-    alone, whole]``, a fetch that rode a run of two or more blocks of one
-    row, a one-row pair that is a unit by itself, a pair that rows of the
-    tile share. What :func:`pair_runs` makes of the step, counted on the
-    host from the tables themselves (``nxd_mla_block_fetches_total``;
-    ``tests/walk_checks.py`` holds the two to each other)."""
+    """:func:`.paged_attention.block_fetches` of one layer of a packed
+    step, ``[in_run, alone, whole]``, at the run the kernel takes for
+    these shapes (``nxd_mla_block_fetches_total``)."""
     heads = stacked_heads(num_heads)
-    tokens, maxb = served.shape
-    rows = tile_rows(heads, tokens)
-    run, _ = _unit_lengths(heads, rows * heads, row, block_size, itemsize)
-    tile = np.concatenate(
-        [served, np.full((-tokens % rows, maxb), -1, served.dtype)]
-    ).reshape(-1, rows, maxb)
-    # the rows of its tile that name a row's (column, block), itself among
-    # them: one makes the pair the row's own, more the tile's, counted at
-    # its first namer
-    same = (tile[:, :, None] == tile[:, None]) & (tile >= 0)[:, :, None]
-    namers = same.sum(axis=2)
-    first = ~(same & np.tri(rows, k=-1, dtype=bool)[:, :, None]).any(axis=2)
-    own = (namers == 1).sum(axis=-1)         # [tiles, rows]: a row's run
-    alone = own if run == 1 else own % run == 1
-    return np.array([own.sum() - alone.sum(), alone.sum(),
-                     ((namers > 1) & first).sum()], np.int64)
+    run, _ = _unit_lengths(heads, tile_rows(heads, len(served)) * heads, row,
+                           block_size, itemsize)
+    return _block_fetches(served, heads, run)
 
 
 def absorb_queries(q_nope, q_rope, k_up, row: int):
@@ -279,23 +177,6 @@ def _mla_attention_xla(q, pool, pool_pos, tables, q_pos, layer, rank, scale):
     return jnp.where(live, out, 0.0).astype(q.dtype)
 
 
-def _p_times_v(p, v):
-    """:func:`.paged_attention._p_times_v` in one product: ``p [rows,
-    positions]`` float32 against ``v [positions, D]``. A bf16 ``v`` meets
-    ``p``'s two bf16 parts (its upper 16 bits of mantissa, not rounded to
-    bf16) stacked on rows, so the values are pushed through the MXU once
-    and the halves are added in float32."""
-    def dot(a, b):
-        return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-
-    if v.dtype == jnp.float32:
-        return dot(p, v)
-    high = p.astype(v.dtype).astype(jnp.float32)
-    both = dot(jnp.concatenate([high, p - high], axis=0).astype(v.dtype), v)
-    return both[:p.shape[0]] + both[p.shape[0]:]
-
-
 def _mla_kernel(units_ref, blocks_ref, cols_ref, narrow_ref, lens_ref,
                 layer_ref, served_ref, qpos_ref, q_ref, pool_hbm, pos_hbm,
                 o_ref, row_buf, pos_buf, sems, m_ref, l_ref, acc_ref, *,
@@ -303,7 +184,8 @@ def _mla_kernel(units_ref, blocks_ref, cols_ref, narrow_ref, lens_ref,
                 scale: float, rank: int):
     """One tile of packed rows (every row's heads stacked) against the
     pool blocks its rows attend, a unit of the walk at a time
-    (:func:`pair_runs`): the unit's blocks, which are the keys whole and
+    (:func:`.paged_attention.pair_runs`): the unit's blocks, which are
+    the keys whole and
     the values in their first ``rank`` lanes, are copied side by side into
     one half of the ring while the unit before it is computed from the
     other, and are one step of the online softmax

@@ -16,28 +16,45 @@ Two implementations behind one signature, following
   einsums, same ``-1e30`` position-sentinel masking), so paged decode is
   bit-for-bit comparable with :func:`..models.llama.llama_forward_with_cache`
   on the contiguous cache; runs everywhere and is the tier-1/CPU path.
-* ``_paged_attention_pallas`` — a Mosaic TPU kernel whose unit is a
-  *tile* of ``R`` consecutive packed rows against one *pair* (table
-  column, pool block) that some row of the tile attends. ``R``
-  (:func:`tile_rows`) follows the shapes: with the ``n_rep`` query heads
-  of a K/V head stacked a tile is about the MXU's 128 rows, 32 rows
-  under GQA-4 and 128 under MHA. The grid is the tiles. Inside, a loop
-  over the tile's pairs copies each pair's block once from the stacks in
-  HBM into one of two VMEM buffers while the block before it is
-  computed, and multiplies a K/V head at a time on the MXU: scores
-  ``[R * n_rep, D] x [D, block_size]`` from the stored operands into
-  float32, ``p x v`` with ``p`` float32 (:func:`_p_times_v`), the running
-  max, sum and accumulator float32 scratch. So a prefill chunk's rows,
-  which name the same blocks of one slot, fetch them once a tile and not
-  once a row, and the kernel's time follows the pairs and not the
-  table's width. The rows
+* ``_paged_attention_pallas`` — a Mosaic TPU kernel over *tiles* of
+  ``R`` consecutive packed rows and the *pairs* (table column, pool
+  block) that some row of the tile attends. ``R`` (:func:`tile_rows`)
+  follows the shapes: with the ``n_rep`` query heads of a K/V head
+  stacked a tile is about the MXU's 128 rows, 32 rows under GQA-4 and 128
+  under MHA. The grid is the tiles. Inside, a loop over the tile's
+  *units* (:func:`pair_runs`): a unit is up to :func:`run_blocks` pairs of
+  one narrow group of the tile (a decode row's own blocks, column after
+  column: 8 blocks of 320 KiB, 4 of 512 KiB, 2 over a ring of two
+  columns), or one pair that rows of the tile share beyond a group (a
+  prefill chunk's block). A unit's blocks are copied once from the stacks
+  in HBM side by side into one half of a ring in VMEM while the unit
+  before it is computed from the other half, and are one step of the
+  online softmax, a K/V head at a time on the MXU: scores ``[rows, D] x
+  [D, blocks x block_size]`` from the stored operands into float32, one
+  read, correction and write of the running max, sum and accumulator
+  (float32 scratch), ``p x v`` with ``p`` float32 (:func:`_p_times_v`).
+  So a pair's fixed part (copies started and awaited, the statistics'
+  round trip, the MXU's fill) is paid once a run and not once a block,
+  and the kernel's time follows the blocks' bytes. Where a pool's blocks
+  are too large for two ring halves of more than one (32 heads of 128: 2
+  MiB) the unit is the pair, the walk is the tile walk as it is and the
+  kernel takes a pair a turn (:func:`_paged_kernel`); the length is a
+  function of the pools' shapes alone (:func:`unit_blocks`). A prefill
+  chunk's rows, which name the same blocks of one slot, fetch them once
+  a tile and not once a row, and the kernel's time follows the pairs and
+  not the table's width. The rows
   of the tile that do not name a pair's block are masked (``-inf``, as a
-  dead slot position is); where one packed row names it (a decode row's
+  dead slot position is), block by block inside a run; where one packed
+  row names it (a decode row's
   blocks: its neighbours are other slots') the products run over a
   narrow group of the tile alone: the whole sublanes that hold the row's
   ``n_rep`` stacked heads wherever they begin (:func:`narrow_rows`,
   :func:`narrow_start`; 8 stacked rows under GQA-4, GQA-8 and MHA, 16
-  where the heads cross sublanes, 6 or 9 of them).
+  where the heads cross sublanes, 6 or 9 of them). A run is keyed by its
+  group's first row: where the group is one packed row's heads (8 or 16
+  of them) the run is that row's blocks and every row of the group names
+  each; under GQA-4 two packed rows share a group and their blocks
+  interleave in its runs.
 
   The walk (:func:`tile_walk`) lists, a tile, the distinct pairs that
   are live for at least one of its rows, each once. Live is
@@ -371,7 +388,7 @@ def tile_pairs(served, rows: int, num_blocks: int, xp=jnp):
             (key // num_blocks).astype(xp.int32))
 
 
-def pair_kinds(served, n_rep: int, num_blocks: int):
+def pair_kinds(served, n_rep: int, num_blocks: int, pairs=None):
     """A step's pairs (one layer's worth) by how the kernel computes
     them, ``[narrow, one_row_whole, shared]``, from ``served [T,
     max_blocks_per_seq]`` (NumPy: a row's table entry in the columns it
@@ -381,20 +398,35 @@ def pair_kinds(served, n_rep: int, num_blocks: int):
     tile all the same; ``shared``, a pair that several rows of the tile
     name and no group holds. They sum to :func:`tile_pairs`' count. The
     engine's ``nxd_paged_pairs_total`` and
-    ``nxd_paged_shared_pairs_total``, counted on the host."""
+    ``nxd_paged_shared_pairs_total``, counted on the host (``pairs``:
+    :func:`host_pairs` of the same step, where the caller has them)."""
+    first, last, start, _ = pairs or host_pairs(served, n_rep, num_blocks)
+    narrow = start >= 0
+    return np.array([narrow.sum(), (~narrow & (first == last)).sum(),
+                     (~narrow & (first != last)).sum()], np.int64)
+
+
+def host_pairs(served, n_rep: int, num_blocks: Optional[int] = None):
+    """A step's pairs from ``served [T, max_blocks_per_seq]`` (NumPy), one
+    entry a (tile, column, block) that some row of the tile attends:
+    ``(first, last, start, group)``, the first and last row of its tile
+    that names it, where its narrow product begins in the tile's stacked
+    rows (:func:`narrow_start`, -1 over the whole tile), and a number of
+    its (tile, start)."""
     tokens, maxb = served.shape
     rows = tile_rows(n_rep, tokens)
+    blocks = num_blocks or int(served.max(initial=0)) + 1
     row, col = np.nonzero(served >= 0)               # by row, then column
-    key = ((row // rows).astype(np.int64) * maxb + col) * num_blocks + served[
+    key = ((row // rows).astype(np.int64) * maxb + col) * blocks + served[
         row, col]
-    _, first, pair = np.unique(key, return_index=True, return_inverse=True)
+    key, first, pair = np.unique(key, return_index=True, return_inverse=True)
     first = row[first] % rows                        # a pair's first namer
     last = np.zeros_like(first)
     np.maximum.at(last, pair, row % rows)
-    narrow = narrow_start(first * n_rep, (last + 1) * n_rep, n_rep,
-                          rows * n_rep, xp=np) >= 0
-    return np.array([narrow.sum(), (~narrow & (first == last)).sum(),
-                     (~narrow & (first != last)).sum()], np.int64)
+    wide = rows * n_rep
+    start = narrow_start(first * n_rep, (last + 1) * n_rep, n_rep, wide,
+                         xp=np)
+    return first, last, start, key // (maxb * blocks) * wide + start
 
 
 class TileWalk(NamedTuple):
@@ -473,58 +505,226 @@ def tile_walk(tables, q_pos, block_size: int, num_blocks: int, n_rep: int,
                   ((q_pos // window[0]) * window[0])[:, None])))
 
 
+#: what the ring of a unit's blocks may take of VMEM (two halves), and a
+#: unit's float32 scores ``[stacked rows, positions]``: :func:`unit_blocks`
+RING_BYTES = 5 << 20
+SCORE_BYTES = 384 << 10
+
+
+def unit_blocks(rows: int, block_bytes: int, block_size: int) -> int:
+    """Blocks of a unit of a paged kernel that ``rows`` stacked rows
+    attend (a run's narrow group, a shared pair's whole tile), a block
+    ``block_bytes`` in the pool (its keys and its values): the power of
+    two, 8 at most, whose two ring halves fit :data:`RING_BYTES` and
+    whose scores ``[rows, blocks x block_size]`` fit :data:`SCORE_BYTES`.
+    The latent kernel's rows of 640 bf16 lanes in blocks of 128 (160
+    KiB): 8 blocks for a run of 24 stacked rows and 4 for a tile of 192
+    (``PERF.md``, Findings, PR 39). The paged kernel's runs: 8 for a
+    block of 320 KiB (4 K heads of 192 beside 4 V heads of 128) or less,
+    4 for 512 KiB (8 K/V heads of 128), 1 for 2 MiB (32 heads), which is
+    the walk and the kernel without runs (``PERF.md``, Findings, PR 46)."""
+    most = min(8, RING_BYTES // (2 * block_bytes),
+               SCORE_BYTES // (4 * rows * block_size))
+    return 1 << max(most, 1).bit_length() - 1
+
+
+def run_length(n_rep: int, block_bytes: int, block_size: int,
+               max_cols: int) -> int:
+    """Blocks of a run of the paged kernel for rows of ``n_rep`` query
+    heads a K/V head over blocks of ``block_bytes`` (keys and values)
+    under tables of ``max_cols`` columns: :func:`unit_blocks` of a narrow
+    group's rows, and no more than a row has columns (a ring of two is a
+    run of two). 1 is the kernel without runs."""
+    return min(unit_blocks(narrow_rows(n_rep), block_bytes, block_size),
+               1 << max_cols.bit_length() - 1)
+
+
+def run_blocks(k_pool, v_pool, n_rep: int, max_cols: int) -> int:
+    """:func:`run_length` over these pools (the stacks ``[L, num_blocks,
+    block_size, ...]``, arrays or their shapes' structs)."""
+    block_bytes = sum(math.prod(x.shape[2:]) * jnp.dtype(x.dtype).itemsize
+                      for x in (k_pool, v_pool))
+    return run_length(n_rep, block_bytes, k_pool.shape[2], max_cols)
+
+
+class RunWalk(NamedTuple):
+    """What a kernel that takes its pairs in runs is handed of a step's
+    routing, the same for every layer: a :class:`TileWalk`'s pairs in the
+    order of the kernel's units (:func:`pair_runs`): ``units [tiles]`` the
+    units of a tile; ``blocks``, ``cols`` and ``narrow [tiles * P + room]``
+    the tile's pairs, the shared ones first and then each narrow group's
+    by column; ``lens``, at a unit's first pair its number of pairs
+    (:func:`unit_blocks` at most) and 0 elsewhere; ``served``, ``q_pos``
+    and ``q_lo`` the walk's own."""
+
+    units: jax.Array
+    blocks: jax.Array
+    cols: jax.Array
+    narrow: jax.Array
+    lens: jax.Array
+    served: jax.Array
+    q_pos: jax.Array
+    q_lo: Optional[jax.Array] = None
+
+
+def pair_runs(count, blocks, cols, narrow, num_blocks: int, max_cols: int,
+              stride: int, groups: int, run: int, whole_run: int):
+    """A walk's pairs (``count [tiles]``; ``blocks``, ``cols``, ``narrow
+    [tiles, P]``, ``P`` no less than the largest count: :func:`tile_walk`
+    over tables of ``max_cols`` columns) cut into a kernel's units: the
+    pairs that no one group holds (``narrow < 0``) in runs of up to
+    ``whole_run``, whichever rows name each; the pairs of one narrow
+    group, keyed by the group's first row (``narrow``, a multiple of
+    ``stride``, ``groups`` of them a tile: a decode row's own blocks, one
+    a column; under GQA-4 the blocks of the two packed rows whose heads
+    share a sublane, under GQA-6 those of the rows whose groups begin on
+    one) in runs of up to ``run`` successive ones of that group, the last
+    shorter. Returns ``(units [tiles], blocks, cols, narrow, lens [tiles,
+    P])`` with the pairs reordered, the shared ones first and then group
+    by group, each in order of column and block, and ``lens`` the length
+    of the unit that starts at a pair, 0 where none does. One sort of one
+    key and no gather (a gather of the pairs cost the step 0.6 ms:
+    ``PERF.md``, Findings, PR 39)."""
+    tiles, per = blocks.shape
+    span = max_cols * num_blocks            # a (column, block) as one number
+    assert (groups + 2) * span < 2 ** 31
+    at = jnp.arange(per, dtype=jnp.int32)[None, :]
+    live = at < count[:, None]
+    # 0 a shared pair, 1 + g a pair of group g, last what lies beyond the
+    # tile's count
+    kind = jnp.where(live, jnp.where(narrow < 0, 0, 1 + narrow // stride),
+                     groups + 1).astype(jnp.int32)
+    key = jnp.sort(
+        kind * span + jnp.where(live, cols * num_blocks + blocks, 0), axis=-1)
+    kind, pair = key // span, key % span
+    # the pairs lie sorted by kind: a kind's first is at the number of
+    # pairs of the kinds before it (a handful of kinds: a select each)
+    first, end, before = 0, 0, 0
+    for k in range(groups + 1):
+        mine = kind == k
+        many = jnp.sum(mine, axis=-1, keepdims=True)
+        first = jnp.where(mine, before, first)
+        end = jnp.where(mine, before + many, end)
+        before = before + many
+    most = jnp.where(kind == 0, whole_run, run)
+    lens = jnp.where(live & ((at - first) % most == 0),
+                     jnp.minimum(most, end - at), 0).astype(jnp.int32)
+    lone = (kind > 0) & (kind <= groups)
+    return (jnp.sum(lens > 0, axis=-1).astype(jnp.int32),
+            pair % num_blocks, pair // num_blocks,
+            jnp.where(lone, (kind - 1) * stride, -1), lens)
+
+
+def run_stride(n_rep: int) -> int:
+    """What the first rows of a tile's narrow groups are multiples of
+    (:func:`narrow_start`): the group itself where it is one packed row's
+    heads (``n_rep`` whole sublanes), else a sublane."""
+    return n_rep if n_rep % 8 == 0 else 8
+
+
+def run_walk(walk: TileWalk, num_blocks: int, n_rep: int, run: int,
+             whole_run: int, room: Optional[int] = None) -> RunWalk:
+    """:func:`pair_runs` of a step's :class:`TileWalk` over rows of
+    ``n_rep`` stacked heads, in runs of ``run`` blocks of a narrow group
+    and ``whole_run`` that a tile shares, with ``room`` entries past the
+    last tile's pairs: as far as the kernel reads a unit's pairs whatever
+    its length (the longer kind of unit, unless told)."""
+    tiles, wide, max_cols = walk.served.shape
+    stride = run_stride(n_rep)
+    units, *pairs = pair_runs(
+        walk.count, *(x.reshape(tiles, -1) for x in
+                      (walk.blocks, walk.cols, walk.narrow)),
+        num_blocks, max_cols, stride, wide // stride, run, whole_run)
+    room = max(run, whole_run) if room is None else room
+    blocks, cols, narrow, lens = (
+        jnp.pad(x.reshape(-1), (0, room)) for x in pairs)
+    return RunWalk(units=units, blocks=blocks, cols=cols, narrow=narrow,
+                   lens=lens, served=walk.served, q_pos=walk.q_pos,
+                   q_lo=walk.q_lo)
+
+
+def block_fetches(served, n_rep: int, run: int, pairs=None) -> np.ndarray:
+    """Pool blocks a kernel that takes its pairs in runs fetches for one
+    layer of a packed step whose rows attend ``served [T,
+    max_blocks_per_seq]`` (NumPy: a row's table entry in the columns it
+    attends, -1 elsewhere), by how: ``[in_run, alone, whole]``, a fetch
+    that rode a unit of two or more blocks of one narrow group, a narrow
+    pair that is a unit by itself (every one where ``run`` is 1), a pair
+    that rows of the tile share beyond a group. What :func:`pair_runs`
+    makes of the step, counted on the host from the tables themselves by
+    the walk's own rule (:func:`narrow_start`;
+    ``nxd_paged_block_fetches_total``, ``nxd_mla_block_fetches_total``;
+    ``tests/walk_checks.py`` holds the two to each other; ``pairs``:
+    :func:`host_pairs` of the same step, where the caller has them)."""
+    *_, start, group = pairs or host_pairs(served, n_rep)
+    # a (tile, group)'s narrow pairs are cut into runs, the last shorter
+    many = np.bincount(group[start >= 0])
+    alone = many if run == 1 else many % run == 1
+    return np.array([many.sum() - alone.sum(), alone.sum(),
+                     (start < 0).sum()], np.int64)
+
+
 def step_walk(tables, q_pos, block_size: int, num_blocks: int, head_dim: int,
               n_rep: int, window=None, force_pallas: Optional[bool] = None,
-              sliding=None) -> Optional[TileWalk]:
-    """:func:`tile_walk` for the layers of one step, or ``None`` where
-    :func:`paged_attention` runs the XLA reference for these shapes
-    (:func:`paged_attention_impl`)."""
+              sliding=None, *, pools):
+    """The walk of the layers of one step over ``pools``, the K and V
+    stacks they attend, or ``None`` where :func:`paged_attention` runs the
+    XLA reference for these shapes (:func:`paged_attention_impl`):
+    :func:`tile_walk`, and where the kernel takes these pools' pairs in
+    runs (:func:`run_blocks` more than 1) its cut into units
+    (:func:`run_walk`; a pair that a tile shares stays a unit by itself).
+    A run of 1 is the tile walk as it is: no second sort."""
     if paged_attention_impl(head_dim, block_size, force_pallas) == "xla":
         return None
-    return tile_walk(tables, q_pos, block_size, num_blocks, n_rep, window,
+    walk = tile_walk(tables, q_pos, block_size, num_blocks, n_rep, window,
                      sliding)
+    run = run_blocks(*pools, n_rep, tables.shape[1])
+    return walk if run == 1 else run_walk(walk, num_blocks, n_rep, run, 1)
 
 
 def _head_rows(block_ref):
-    """The ``[block_size, D]`` rows of every K/V head of a pool block
-    ``block_ref [block_size, KV, D]`` in VMEM, widened exactly to float32
-    (``KV`` arrays). The block lies as the pool does, a slot's heads on
-    sublanes, so a head's rows are a sublane-strided read of it: every
-    ``KV``-th row of the block seen as ``[block_size * KV, D]``. bf16 and
-    int8 rows lie two and four to a 32-bit sublane; those are read as
-    words, one strided read for the heads that share them, and taken
-    apart with shifts. A wide-key pool's block ``[block_size, KV * D]``
-    gives its 128-lane chunks instead (:func:`key_chunks`)."""
+    """The ``[positions, D]`` rows of every K/V head of pool blocks
+    ``block_ref [positions, KV, D]`` in VMEM (a block, or a run's blocks
+    side by side), widened exactly to float32, a head at a time (``KV``
+    arrays, made as they are asked for). The blocks lie as the pool does,
+    a slot's heads on sublanes, so a head's rows are a sublane-strided
+    read: every ``KV``-th row of the blocks seen as ``[positions * KV,
+    D]``. bf16 and int8 rows lie two and four to a 32-bit sublane; those
+    are read as words, one strided read for the heads that share them,
+    and taken apart with shifts. A wide-key pool's blocks ``[positions,
+    KV * D]`` give their 128-lane chunks instead (:func:`key_chunks`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if len(block_ref.shape) == 2:
-        # a wide-key pool's block: its chunks, aligned lane slices
-        return [block_ref[:, c * LANES:(c + 1) * LANES].astype(jnp.float32)
-                for c in range(block_ref.shape[1] // LANES)]
+        # a wide-key pool's blocks: their chunks, aligned lane slices
+        for c in range(block_ref.shape[1] // LANES):
+            yield block_ref[:, c * LANES:(c + 1) * LANES].astype(jnp.float32)
+        return
     bs, kv, d = block_ref.shape
     dtype = block_ref.dtype
     packing = 4 // dtype.itemsize
     if kv % packing:
         # heads that fill no whole word (tiny shapes, off the chip)
-        return [block_ref[:, h, :].astype(jnp.float32) for h in range(kv)]
+        for h in range(kv):
+            yield block_ref[:, h, :].astype(jnp.float32)
+        return
     flat = block_ref.reshape(bs * kv, d)
     if packing == 1:
-        return [flat[pl.ds(h, bs, stride=kv), :].astype(jnp.float32)
-                for h in range(kv)]
+        for h in range(kv):
+            yield flat[pl.ds(h, bs, stride=kv), :].astype(jnp.float32)
+        return
     words = flat.bitcast(jnp.uint32)
-    out = []
     for g in range(kv // packing):
         w = words[pl.ds(g, bs, stride=kv // packing), :]       # [bs, D] u32
         for part in range(packing):
             if dtype == jnp.bfloat16:
                 # a bf16 is the upper half of the float32 of its value
                 bits = w << 16 if part == 0 else w & jnp.uint32(0xFFFF0000)
-                out.append(pltpu.bitcast(bits, jnp.float32))
+                yield pltpu.bitcast(bits, jnp.float32)
             else:
                 signed = pltpu.bitcast(w << (24 - 8 * part), jnp.int32)
-                out.append((signed >> 24).astype(jnp.float32))
-    return out
+                yield (signed >> 24).astype(jnp.float32)
 
 
 def _p_times_v(p, v):
@@ -547,15 +747,126 @@ def _p_times_v(p, v):
                               v)
 
 
+def _p_times_v_stacked(p, v):
+    """:func:`_p_times_v` in one product: ``p [rows, positions]`` float32
+    against ``v [positions, D]``. A bf16 ``v`` meets ``p``'s two bf16
+    parts (its upper 16 bits of mantissa, not rounded to bf16) stacked on
+    rows, so the values are pushed through the MXU once and the halves
+    are added in float32: the same products and sums. Few rows against
+    many positions (a run's narrow group, the latent kernel's units) are
+    bound by the pushes, a 128 x 128 tile of ``v`` each, not by the rows
+    streamed past them."""
+    def dot(a, b):
+        return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    if v.dtype == jnp.float32:
+        return dot(p, v)
+    high = p.astype(v.dtype).astype(jnp.float32)
+    both = dot(jnp.concatenate([high, p - high], axis=0).astype(v.dtype), v)
+    return both[:p.shape[0]] + both[p.shape[0]:]
+
+
+def _kernel_refs(refs, quantized: bool, window, sink: bool):
+    """The paged kernels' operands after the scalars, by name: the tile's
+    rows, the stacks in HBM, the output, then the scratch."""
+    import types
+
+    scales = ["ks", "vs"] if quantized else []
+    names = (["served", "qpos"] + ["qlo"] * (window is not None)
+             + ["q", "k_hbm", "v_hbm", "pos_hbm"] + ["sink"] * sink
+             + [f"{x}_hbm" for x in scales]
+             + ["o", "k_buf", "v_buf", "pos_buf"]
+             + [f"{x}_buf" for x in scales] + ["sems", "m", "l", "acc"])
+    assert len(names) == len(refs)
+    r = types.SimpleNamespace(**dict(zip(names, refs)))
+    # bf16 queries meet bf16 (or exactly widened int8) keys as stored;
+    # any other pairing is multiplied in float32
+    r.operand = (jnp.bfloat16 if r.q.dtype == jnp.bfloat16
+                 and r.k_buf.dtype != jnp.float32 else jnp.float32)
+    return r
+
+
+def _start_softmax(r):
+    """The online softmax's starting state: nothing attended, or the
+    sink's logit in the denominator."""
+    if hasattr(r, "sink"):
+        r.m[...] = r.sink[...]
+        r.l[...] = jnp.ones_like(r.l)
+    else:
+        r.m[...] = jnp.full_like(r.m, -jnp.inf)
+        r.l[...] = jnp.zeros_like(r.l)
+    r.acc[...] = jnp.zeros_like(r.acc)
+
+
+def _q_times_k(r, h, rows, keys):
+    """Head ``h``'s scores of the tile's rows ``rows`` against ``keys``:
+    its keys whole (one array ``[positions, D]``), or its 128-lane chunks
+    of a wide-key pool, each met by the query's chunk."""
+    def dot(a, b):
+        return jax.lax.dot_general(
+            a.astype(r.operand), b.astype(r.operand),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+    if len(keys) == 1:
+        return dot(r.q[h, rows, :], keys[0])
+    return sum(dot(r.q[h, rows, i * LANES:(i + 1) * LANES], k)
+               for i, k in enumerate(keys))
+
+
+def _softmax_step(r, h, rows, s, ok, v, p_scale=None,
+                  p_times_v=_p_times_v):
+    """One step of head ``h``'s online softmax over the tile's rows
+    ``rows``: scores ``s [rows', positions]`` that count where ``ok``,
+    values ``v [positions, Dv]``, ``p_scale`` an int8 pool's value
+    scales, ``p_times_v`` the form of the last product."""
+    s = jnp.where(ok, s, -jnp.inf)                    # [rows', positions]
+    m_prev = r.m[h, rows, :]                          # [rows', 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+    p = jnp.where(ok, jnp.exp(s - m_safe), 0.0)
+    corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
+    r.m[h, rows, :] = m_new
+    r.l[h, rows, :] = r.l[h, rows, :] * corr + jnp.sum(
+        p, axis=-1, keepdims=True)
+    if p_scale is not None:
+        p = p * p_scale
+    r.acc[h, rows, :] = (r.acc[h, rows, :] * corr
+                         + p_times_v(p, v.astype(r.operand)))
+
+
+def _head_keys(block_ref, key_at):
+    """What :func:`_q_times_k` meets of pool blocks ``block_ref``, a K/V
+    head at a time: the head's rows (:func:`_head_rows`), or its chunks
+    of a wide-key pool (``key_at``, :func:`key_chunks`)."""
+    rows = _head_rows(block_ref)
+    if key_at is None:
+        for k in rows:
+            yield [k]
+    else:
+        chunks = list(rows)             # lane slices, whichever head's
+        for mine in key_at:
+            yield [chunks[c] for c in mine]
+
+
+def _named(served, column, col, block):
+    """Which of the rows ``served [rows', maxb]`` name pool block
+    ``block`` in table column ``col``: ``[rows', 1]``."""
+    return jnp.max(jnp.where((column == col) & (served == block), 1, 0),
+                   axis=1, keepdims=True) > 0
+
+
 def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
                   *refs, pairs: int, group: int, scale: float,
                   quantized: bool, window: Optional[tuple],
                   key_at: Optional[tuple] = None, sink: bool = False):
-    """One tile of packed rows against the pool blocks its rows attend:
-    a loop over the tile's ``count_ref[tile]`` pairs (:func:`tile_pairs`),
-    each block copied once from the stacks in HBM into one of two VMEM
-    buffers while the block before it is computed, so the kernel's time
-    follows the pairs and not the table's width.
+    """One tile of packed rows against the pool blocks its rows attend, a
+    pair a turn (the kernel of a pool whose blocks ride in no runs,
+    :func:`run_blocks` 1): a loop over the tile's ``count_ref[tile]``
+    pairs (:func:`tile_pairs`), each block copied once from the stacks in
+    HBM into one of two VMEM buffers while the block before it is
+    computed, so the kernel's time follows the pairs and not the table's
+    width.
 
     A pair's block serves the rows of the tile that name it in the pair's
     column (``served_ref``); for the others every score is masked, as a
@@ -586,43 +897,23 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    served_ref, qpos_ref, *refs = refs
-    if window is not None:
-        qlo_ref, *refs = refs
-    q_ref, k_hbm, v_hbm, pos_hbm, *refs = refs
-    if sink:
-        sink_ref, *refs = refs
-    if quantized:
-        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, pos_buf, ks_buf, vs_buf, \
-            sems, m_ref, l_ref, acc_ref = refs
-    else:
-        o_ref, k_buf, v_buf, pos_buf, sems, m_ref, l_ref, acc_ref = refs
+    r = _kernel_refs(refs, quantized, window, sink)
     tile = pl.program_id(0)
     count = count_ref[tile]
     layer = layer_ref[0]
-    kv = q_ref.shape[0]
-    # bf16 queries meet bf16 (or exactly widened int8) keys as stored;
-    # any other pairing is multiplied in float32
-    operand = (jnp.bfloat16 if q_ref.dtype == jnp.bfloat16
-               and k_buf.dtype != jnp.float32 else jnp.float32)
+    kv = r.q.shape[0]
 
     def copies(j, slot):
         b = blocks_ref[tile * pairs + j]
-        moves = [(k_hbm.at[layer, b], k_buf), (v_hbm.at[layer, b], v_buf),
-                 (pos_hbm.at[b], pos_buf)]
+        moves = [(r.k_hbm.at[layer, b], r.k_buf),
+                 (r.v_hbm.at[layer, b], r.v_buf), (r.pos_hbm.at[b], r.pos_buf)]
         if quantized:
-            moves += [(ks_hbm.at[layer, b], ks_buf),
-                      (vs_hbm.at[layer, b], vs_buf)]
-        return [pltpu.make_async_copy(src, buf.at[slot], sems.at[slot, i])
+            moves += [(r.ks_hbm.at[layer, b], r.ks_buf),
+                      (r.vs_hbm.at[layer, b], r.vs_buf)]
+        return [pltpu.make_async_copy(src, buf.at[slot], r.sems.at[slot, i])
                 for i, (src, buf) in enumerate(moves)]
 
-    if sink:
-        m_ref[...] = sink_ref[...]
-        l_ref[...] = jnp.ones_like(l_ref)
-    else:
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    _start_softmax(r)
 
     @pl.when(count > 0)
     def _first():
@@ -641,56 +932,29 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
             c.wait()
         block = blocks_ref[tile * pairs + j]
         col = cols_ref[tile * pairs + j]
-        pos = pos_buf[slot]                             # [1, bs]
-        k_heads = _head_rows(k_buf.at[slot])
-        v_heads = _head_rows(v_buf.at[slot])
-
-        def q_times_k(h, rows):
-            def dot(a, b):
-                return jax.lax.dot_general(
-                    a.astype(operand), b.astype(operand),
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-
-            if key_at is None:
-                return dot(q_ref[h, rows, :], k_heads[h])
-            return sum(dot(q_ref[h, rows, i * LANES:(i + 1) * LANES],
-                           k_heads[c]) for i, c in enumerate(key_at[h]))
+        pos = r.pos_buf[slot]                           # [1, bs]
+        k_heads = list(_head_keys(r.k_buf.at[slot], key_at))
+        v_heads = list(_head_rows(r.v_buf.at[slot]))
 
         def attend(rows):
             """The pair against the tile's rows ``rows`` (a slice)."""
-            served = served_ref[rows, :]                # [rows', maxb]
+            served = r.served[rows, :]                  # [rows', maxb]
             column = jax.lax.broadcasted_iota(jnp.int32, served.shape, 1)
-            named = jnp.max(
-                jnp.where((column == col) & (served == block), 1, 0),
-                axis=1, keepdims=True) > 0              # [rows', 1]
-            ok = pos <= qpos_ref[rows, :]               # [rows', bs]
+            named = _named(served, column, col, block)  # [rows', 1]
+            ok = pos <= r.qpos[rows, :]                 # [rows', bs]
             if window is not None:
-                ok = jnp.logical_or(ok & (pos >= qlo_ref[rows, :]),
+                ok = jnp.logical_or(ok & (pos >= r.qlo[rows, :]),
                                     col >= window[1])
             ok = ok & named
             for h in range(kv):
-                s = q_times_k(h, rows) * scale
+                s = _q_times_k(r, h, rows, k_heads[h]) * scale
                 if quantized:
-                    s = s * ks_buf[slot, pl.ds(h, 1), :]
-                s = jnp.where(ok, s, -jnp.inf)                # [rows', bs]
-                m_prev = m_ref[h, rows, :]                    # [rows', 1]
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=-1, keepdims=True))
-                m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-                p = jnp.where(ok, jnp.exp(s - m_safe), 0.0)
-                corr = jnp.where(jnp.isfinite(m_prev),
-                                 jnp.exp(m_prev - m_safe), 0.0)
-                m_ref[h, rows, :] = m_new
-                l_ref[h, rows, :] = l_ref[h, rows, :] * corr + jnp.sum(
-                    p, axis=-1, keepdims=True)
-                if quantized:
-                    p = p * vs_buf[slot, pl.ds(h, 1), :]
-                acc_ref[h, rows, :] = (
-                    acc_ref[h, rows, :] * corr
-                    + _p_times_v(p, v_heads[h].astype(operand)))
+                    s = s * r.ks_buf[slot, pl.ds(h, 1), :]
+                _softmax_step(r, h, rows, s, ok, v_heads[h],
+                              r.vs_buf[slot, pl.ds(h, 1), :] if quantized
+                              else None)
 
-        wide = q_ref.shape[1]
+        wide = r.q.shape[1]
         if group >= wide:
             attend(slice(None))
         else:
@@ -706,8 +970,139 @@ def _paged_kernel(count_ref, blocks_ref, cols_ref, narrow_ref, layer_ref,
         return carry
 
     jax.lax.fori_loop(0, count, pair, None)
-    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                  ).astype(o_ref.dtype)
+    r.o[...] = (r.acc[...] / jnp.maximum(r.l[...], 1e-30)).astype(r.o.dtype)
+
+
+def _paged_run_kernel(units_ref, blocks_ref, cols_ref, narrow_ref, lens_ref,
+                      layer_ref, *refs, pairs: int, group: int, run: int,
+                      whole_named: bool, scale: float, quantized: bool,
+                      window: Optional[tuple],
+                      key_at: Optional[tuple] = None, sink: bool = False):
+    """:func:`_paged_kernel` a *unit* of the walk a turn (:func:`pair_runs`;
+    the kernel of a pool whose blocks ride in runs of ``run``): up to
+    ``run`` blocks that one narrow group of the tile names (a decode row's
+    own blocks, column after column), or one block that rows of the tile
+    share beyond a group. A unit's blocks are copied side by side into one
+    half of a ring (keys, values, positions, an int8 pool's scales, each
+    ``run`` blocks long) while the unit before it is computed from the
+    other half, and are one step of the online softmax of the rows they
+    serve: a K/V head at a time one score product ``[group, D] x [D, run x
+    block_size]`` (the sum of two over a wide-key pool's chunks), one
+    read, correction and write of the running max, sum and accumulator,
+    and one ``p x v`` against ``[run x block_size, Dv]``; precisions as
+    the pair's. Each block of a run stands under its own mask: the rows
+    of the group that name it (two packed rows share a group under GQA-4,
+    neighbouring rows' groups overlap under GQA-6; ``whole_named``: the
+    group is one packed row's heads and names every block of its runs, so
+    nothing is compared), its column's kind (a window-summary cache's
+    exact and summary columns may share a run), and whether the run has
+    it at all: a run is computed over the least power of two of blocks
+    that holds it, what is missing of those are dead positions, and the
+    ring is zeroed once so that ``p = 0`` meets no stale NaN there."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    r = _kernel_refs(refs, quantized, window, sink)
+    tile = pl.program_id(0)
+    units = units_ref[tile]
+    layer = layer_ref[0]
+    base = tile * pairs
+    kv = r.q.shape[0]
+    bs = r.pos_hbm.shape[2]
+
+    def copies(first, side, then):
+        # the copies of the unit that starts at pair ``first``
+        n = lens_ref[base + first]
+        for k in range(run):
+            @pl.when(k < n)
+            def _block():
+                b = blocks_ref[base + first + k]
+                at = pl.ds(k * bs, bs)
+                moves = [(r.k_hbm.at[layer, b], r.k_buf.at[side, at]),
+                         (r.v_hbm.at[layer, b], r.v_buf.at[side, at]),
+                         (r.pos_hbm.at[b], r.pos_buf.at[side, :, at])]
+                if quantized:
+                    moves += [
+                        (r.ks_hbm.at[layer, b], r.ks_buf.at[side, :, at]),
+                        (r.vs_hbm.at[layer, b], r.vs_buf.at[side, :, at])]
+                for i, (src, dst) in enumerate(moves):
+                    then(pltpu.make_async_copy(src, dst,
+                                               r.sems.at[side, k, i]))
+
+    @pl.when(tile == 0)
+    def _clean():
+        # what a short run leaves of the ring is multiplied by p = 0
+        for buf in (r.k_buf, r.v_buf) + (
+                (r.ks_buf, r.vs_buf) if quantized else ()):
+            buf[...] = jnp.zeros_like(buf)
+
+    @pl.when(units > 0)
+    def _first():
+        copies(0, 0, lambda c: c.start())
+
+    _start_softmax(r)
+
+    def attend(first, side, rows, blocks: int, n, named: bool):
+        """The unit's first ``blocks`` blocks, ``n`` of them fetched,
+        against the tile's rows ``rows`` (a slice)."""
+        span = pl.ds(0, blocks * bs)
+        if named:
+            served = r.served[rows, :]                  # [rows', maxb]
+            column = jax.lax.broadcasted_iota(jnp.int32, served.shape, 1)
+        ok = []
+        for k in range(blocks):
+            pos = r.pos_buf[side, :, k * bs:(k + 1) * bs]       # [1, bs]
+            mine = pos <= r.qpos[rows, :]               # [rows', bs]
+            col = cols_ref[base + first + k]
+            if window is not None:
+                mine = mine & (pos >= r.qlo[rows, :])
+                if window[1] < r.served.shape[1]:       # summary columns
+                    mine = jnp.logical_or(mine, col >= window[1])
+            if named:
+                mine = mine & _named(served, column, col,
+                                     blocks_ref[base + first + k])
+            # more than half of ``blocks`` are fetched: the rest may be
+            ok.append(mine if k < max(blocks // 2, 1) else mine & (k < n))
+        ok = ok[0] if blocks == 1 else jnp.concatenate(ok, axis=1)
+        # a head's keys and values are taken apart as its turn comes
+        for h, (keys, v) in enumerate(zip(
+                _head_keys(r.k_buf.at[side, span], key_at),
+                _head_rows(r.v_buf.at[side, span]))):
+            s = _q_times_k(r, h, rows, keys) * scale
+            if quantized:
+                s = s * r.ks_buf[side, pl.ds(h, 1), span]
+            _softmax_step(r, h, rows, s, ok, v,
+                          r.vs_buf[side, pl.ds(h, 1), span] if quantized
+                          else None, p_times_v=_p_times_v_stacked)
+
+    def unit(u, first):
+        side = u % 2
+        n = lens_ref[base + first]
+
+        @pl.when(u + 1 < units)
+        def _next():
+            copies(first + n, 1 - side, lambda c: c.start())
+
+        copies(first, side, lambda c: c.wait())
+        start = narrow_ref[base + first]
+
+        # a run over the least power of two of blocks that holds it: a
+        # group's last run is short, and a ring of five columns is a run
+        # of four and one of one
+        for blocks in (1 << i for i in range(run.bit_length())):
+            @pl.when((start >= 0) & (n > blocks // 2) & (n <= blocks))
+            def _run():
+                attend(first, side, pl.ds(pl.multiple_of(start, 8), group),
+                       blocks, n, not whole_named)
+
+        @pl.when(start < 0)
+        def _whole():
+            attend(first, side, slice(None), 1, n, True)
+
+        return first + n
+
+    jax.lax.fori_loop(0, units, unit, 0)
+    r.o[...] = (r.acc[...] / jnp.maximum(r.l[...], 1e-30)).astype(r.o.dtype)
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
@@ -727,8 +1122,16 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
     maxb = tables.shape[1]
     n_rep = n // kv
     quantized = k_scale is not None
+    run = run_blocks(k_pool, v_pool, n_rep, maxb)
     if walk is None:
         walk = tile_walk(tables, q_pos, bs, nb, n_rep, window, sliding)
+        if run > 1:
+            walk = run_walk(walk, nb, n_rep, run, 1)
+    if isinstance(walk, RunWalk) != (run > 1):
+        raise ValueError(
+            f"the kernel takes these pools' pairs in runs of {run} and was "
+            f"handed a {type(walk).__name__}: build the step's walk with "
+            "step_walk(..., pools=) over the pools the layer attends")
     if sliding is not None:
         # the window-summary kernel's exact rows, and no summary column:
         # a row counts from ``q_lo`` to the query's own position
@@ -768,42 +1171,55 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
         operands.append(jnp.tile(
             sink.astype(jnp.float32).reshape(kv, 1, n_rep),
             (1, rows, 1)).reshape(kv, wide, 1))
-    scratch = [pltpu.VMEM((2, bs) + k_pool.shape[3:], k_pool.dtype),
-               pltpu.VMEM((2, bs, kv, dv), v_pool.dtype),
-               pltpu.VMEM((2, 1, bs), jnp.int32)]
+    # two buffers of a block, or the two halves of a ring of ``run``
+    scratch = [pltpu.VMEM((2, run * bs) + k_pool.shape[3:], k_pool.dtype),
+               pltpu.VMEM((2, run * bs, kv, dv), v_pool.dtype),
+               pltpu.VMEM((2, 1, run * bs), jnp.int32)]
     if quantized:
         # slots on lanes is how the chip stores an array whose last dim is
         # a few heads wide, and how the step's scatter writes it: the swap
         # is then no copy, and a head's scales are a row over the slots
         in_specs += [hbm, hbm]
         operands += [k_scale.swapaxes(2, 3), v_scale.swapaxes(2, 3)]
-        scratch += [pltpu.VMEM((2, kv, bs), jnp.float32),
-                    pltpu.VMEM((2, kv, bs), jnp.float32)]
-    scratch += [pltpu.SemaphoreType.DMA((2, 5 if quantized else 3)),
+        scratch += [pltpu.VMEM((2, kv, run * bs), jnp.float32),
+                    pltpu.VMEM((2, kv, run * bs), jnp.float32)]
+    copies = 5 if quantized else 3
+    scratch += [pltpu.SemaphoreType.DMA((2, copies) if run == 1
+                                        else (2, run, copies)),
                 pltpu.VMEM((kv, wide, 1), jnp.float32),
                 pltpu.VMEM((kv, wide, 1), jnp.float32),
                 pltpu.VMEM((kv, wide, dv), jnp.float32)]
 
+    shared = dict(pairs=pairs, group=narrow_rows(n_rep), scale=scale,
+                  quantized=quantized, window=window, key_at=key_at,
+                  sink=sink is not None)
+    if run == 1:
+        kernel = functools.partial(_paged_kernel, **shared)
+        scalars = (walk.count, walk.blocks, walk.cols, walk.narrow)
+    else:
+        # a group that is one packed row's heads names every block of its
+        # runs; any other shares its sublanes with its neighbours' heads
+        kernel = functools.partial(
+            _paged_run_kernel, run=run,
+            whole_named=narrow_rows(n_rep) == n_rep, **shared)
+        scalars = (walk.units, walk.blocks, walk.cols, walk.narrow,
+                   walk.lens)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=len(scalars) + 1,
         grid=(tiles,),
         in_specs=in_specs,
         out_specs=head_block(dv),
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, pairs=pairs,
-                          group=narrow_rows(n_rep), scale=scale,
-                          quantized=quantized, window=window, key_at=key_at,
-                          sink=sink is not None),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tiles, kv, wide, dv), q.dtype),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
         name=("swa_attention" if sliding is not None else "paged_attention"
               if window is None else "eva_attention"),
-    )(walk.count, walk.blocks, walk.cols, walk.narrow,
-      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
+    )(*scalars, jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     return out.reshape(tiles, kv, rows, n_rep, dv).swapaxes(1, 2).reshape(
         tiles * rows, n, dv)[:t]
 

@@ -157,13 +157,20 @@ def _mosaic_ops(hlo_text):
     if not (quantized and geometry[-1])])   # a window-summary pool is float
 def test_paged_attention(chip, quantized, shape):
     from neuronx_distributed_tpu.ops.paged_attention import (
-        _paged_attention_pallas)
+        _paged_attention_pallas, run_blocks)
 
     tokens, kv, cols, nb, window = _PAGED_SHAPES[shape]
     n, d, bs, layers = 32, 128, 128, 2
     pool = chip((layers, nb, bs, kv, d),
                 jnp.int8 if quantized else jnp.bfloat16)
     scale = chip((layers, nb, bs, kv), jnp.float32) if quantized else None
+    # a narrow group's blocks ride in runs where two ring halves of them
+    # fit (``unit_blocks``): 8 K/V heads of 128 in bf16 are 512 KiB a
+    # block and ride in fours, 32 heads are 2 MiB and ride in none, which
+    # is the kernel a pair a turn
+    assert run_blocks(pool, pool, n // kv, cols) == {
+        (8, False): 4, (8, True): 8, (32, False): 1, (32, True): 2}[
+            kv, quantized]
     fn = functools.partial(_paged_attention_pallas,
                            scale=1.0 / math.sqrt(d), interpret=False,
                            window=window)
@@ -178,6 +185,33 @@ def test_paged_attention(chip, quantized, shape):
     # head's rows a strided read of the block as the pool lays it
     assert {"tpu.matmul", "tpu.enqueue_dma", "tpu.strided_load"} <= (
         _mosaic_ops(text))
+
+
+def test_a_pool_of_large_blocks_takes_the_kernel_it_took():
+    """EvaByte's pool (32 heads of 128: 2 MiB a block) rides in no runs,
+    and its kernel is the kernel as PR 45's tree traced it at the cell's
+    shapes: the jaxpr of the call, kernel body and all (a jaxpr carries no
+    source locations; the Mosaic bytecode does), by its sha256. A PR that
+    changes the pair-a-turn kernel on purpose records its new hash here."""
+    import hashlib
+
+    from neuronx_distributed_tpu.ops.paged_attention import (
+        _paged_attention_pallas, run_blocks)
+
+    tokens, kv, cols, nb, window = _PAGED_SHAPES["serve_docs"]
+    n, d, bs, layers = 32, 128, 128, 2
+    shape = jax.ShapeDtypeStruct
+    pool = shape((layers, nb, bs, kv, d), jnp.bfloat16)
+    assert run_blocks(pool, pool, n // kv, cols) == 1
+    text = str(jax.make_jaxpr(functools.partial(
+        _paged_attention_pallas, k_scale=None, v_scale=None,
+        scale=1.0 / math.sqrt(d), interpret=False, window=window))(
+            shape((tokens, n, d), jnp.bfloat16), pool, pool,
+            shape((nb, bs), jnp.int32), shape((tokens, cols), jnp.int32),
+            shape((tokens,), jnp.int32), shape((), jnp.int32)))
+    assert "eva_attention" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "7b4aa3f7535ae086398a0265ef65dcd560ac0c9810ccce1e4a5768bdf9570879")
 
 
 # -- the paged forward: the pool rides the layer scan as its carry -----------
@@ -459,8 +493,7 @@ def test_mla_paged_attention(chip):
     assert {"tpu.matmul", "tpu.enqueue_dma"} <= _mosaic_ops(text)
     # at these widths a decode row's blocks run by eight (a ring of two
     # halves of 8 x 160 KiB) and a tile's shared blocks by four
-    assert mla.unit_blocks(24, row, bs, 2) == 8
-    assert mla.unit_blocks(8 * 24, row, bs, 2) == 4
+    assert mla._unit_lengths(24, 8 * 24, row, bs, 2) == (8, 4)
 
 
 def test_latent_forward_writes_its_rows_in_place(chip, on_one_chip):
@@ -1084,13 +1117,16 @@ def test_paged_attention_at_two_head_counts(chip, n_rep, cols, sliding):
     in: a 16-row slice of the bf16 queries (16 rows a vreg) at a start
     that is a multiple of 8 and not of 16, which Mosaic takes."""
     from neuronx_distributed_tpu.ops.paged_attention import (
-        _paged_attention_pallas, narrow_rows, tile_rows)
+        _paged_attention_pallas, narrow_rows, run_blocks, tile_rows)
 
     tokens, kv, d, bs, layers = 128, 8, 128, 128, 2
     nb = 320 if sliding else 3072
     assert tile_rows(n_rep, tokens) * n_rep in (120, 72)
     assert narrow_rows(n_rep) == 16
     pool = chip((layers, nb, bs, kv, d), jnp.bfloat16)
+    # runs of four blocks of a group whose rows name them block by block
+    # (neighbouring rows' groups overlap at 6 and 9 heads)
+    assert run_blocks(pool, pool, n_rep, cols) == 4
     fn = functools.partial(_paged_attention_pallas,
                            scale=1.0 / math.sqrt(d), interpret=False,
                            sliding=sliding)
@@ -1193,11 +1229,17 @@ def test_paged_attention_at_wide_keys(chip, kv, cols, nb, sliding):
     which the kernel reads as aligned lane slices of the block; V is by
     head, 128 wide, as every other pool."""
     from neuronx_distributed_tpu.ops.paged_attention import (
-        _paged_attention_pallas, narrow_rows, tile_rows)
+        _paged_attention_pallas, narrow_rows, run_blocks, tile_rows)
 
     tokens, n, d, dv, bs, layers = 128, 64, 192, 128, 128, 2
     assert tile_rows(n // kv, tokens) * (n // kv) == 128
     assert narrow_rows(n // kv) == n // kv
+    # a full layer's block is 320 KiB and rides in runs of 8 (a ring of 5
+    # MiB); a ring of two columns is a run of two. A group is one packed
+    # row's heads and names every block of its runs
+    assert run_blocks(chip((layers, nb, bs, kv * d), jnp.bfloat16),
+                      chip((layers, nb, bs, kv, dv), jnp.bfloat16),
+                      n // kv, cols) == (2 if sliding else 8)
     fn = functools.partial(_paged_attention_pallas,
                            scale=1.0 / math.sqrt(d), interpret=False,
                            sliding=sliding)

@@ -40,7 +40,7 @@ from runners import serve  # noqa: E402
 
 sys.path.insert(0, HERE)
 import engine_parity  # noqa: E402
-from walk_checks import check_tile_walk  # noqa: E402
+from walk_checks import check_paged_runs, check_tile_walk  # noqa: E402
 
 W, C, BS = 32, 4, 8
 PUBLISHED = dict(
@@ -158,10 +158,15 @@ def test_paged_forward_matches_the_reference_across_window_ends(impl):
     np.testing.assert_allclose(got, want, atol=2e-5 * float(np.std(want)))
 
 
+@pytest.mark.parametrize("run", [1, 4], ids=["pairs", "runs_of_4"])
 @pytest.mark.parametrize("heads", [4, 64], ids=["one_tile", "two_tiles"])
-def test_pallas_kernel_in_interpret_mode_equals_the_xla_path(heads):
-    """Both masks and the walk: a ring that has wrapped (stale rows of two
-    windows ago in a live column), summaries of two earlier windows and a
+def test_pallas_kernel_in_interpret_mode_equals_the_xla_path(monkeypatch,
+                                                             heads, run):
+    """The kernel a pair a turn (what a block of 32 heads takes on the
+    chip) and with a narrow group's blocks in runs of 4, exact and
+    summary columns side by side in one run. Both masks and the walk: a
+    ring that has wrapped (stale rows of two windows ago in a live
+    column), summaries of two earlier windows and a
     later window's summary column that must be skipped, unmapped columns,
     a pad row. With 64 query heads over the 4 K/V heads a tile is 8 rows
     (``two_tiles``): the chunk of rows behind the first six then crosses
@@ -182,15 +187,24 @@ def test_pallas_kernel_in_interpret_mode_equals_the_xla_path(heads):
                       PAD_POSITION] + list(range(2 * W - 4, 2 * W + 5)),
                      np.int32)
     t = len(q_pos)
+    # row 0 is another slot's: the same rows in blocks of its own, which
+    # no other row of its tile names
+    twin = dict(zip([3, 7, 1, 12, 9, 15, 4, 20], [0, 2, 5, 6, 8, 10, 11, 13]))
+    old, new = (np.array(x) for x in zip(*twin.items()))
+    k_pool, v_pool = k_pool.at[new].set(k_pool[old]), v_pool.at[new].set(
+        v_pool[old])
+    pos[new] = pos[old]
+    tables = np.tile(table, (t, 1))
+    tables[0] = [twin.get(b, -1) for b in table]
     args = (jnp.asarray(rng.randn(t, heads, d), jnp.float32), k_pool[None],
-            v_pool[None], jnp.asarray(pos),
-            jnp.asarray(np.tile(table, (t, 1))), jnp.asarray(q_pos), 0)
-    kinds = KIND.column_kinds(np.tile(table, (t, 1)), np.arange(maxb),
-                              q_pos[:, None], BS)
+            v_pool[None], jnp.asarray(pos), jnp.asarray(tables),
+            jnp.asarray(q_pos), 0)
+    kinds = KIND.column_kinds(tables, np.arange(maxb), q_pos[:, None], BS)
     assert kinds[0].tolist() == [0, 0, 0, 1, 1, 2, 2, 0, 0, 0]
     assert kinds[2].tolist() == [0, 0, 0, 0, 1, 2, 0, 0, 0, 0]
     assert kinds[3].tolist() == [0, 0, 0, 1, 0, 2, 2, 0, 0, 0]
     assert not kinds[5].any()               # a pad row walks nothing
+    monkeypatch.setattr(pa, "run_blocks", lambda *_: run)
     want = pa.paged_attention(*args, force_pallas=False, scale=0.25,
                               window=(W, KIND.ring))
     got = pa.paged_attention(*args, force_pallas=True, scale=0.25,
@@ -205,10 +219,19 @@ def test_pallas_kernel_in_interpret_mode_equals_the_xla_path(heads):
     assert -(-t // rows) == (1 if heads == 4 else 2)
     walk = jax.tree_util.tree_map(np.asarray, pa.tile_walk(
         args[4], args[5], BS, nb, n_rep, (W, KIND.ring)))
-    check_tile_walk(walk, kinds > 0, np.tile(table, (t, 1)), rows, n_rep)
-    # one slot's rows: a tile fetches a column once, however many attend it
+    check_tile_walk(walk, kinds > 0, tables, rows, n_rep)
+    # one slot's rows: a tile fetches a column once, however many attend
+    # it; row 0's blocks are its own
+    shared = kinds.copy()
+    shared[0] = 0
     assert walk.count.tolist() == [
-        int((kinds[i:i + rows] > 0).any(0).sum()) for i in range(0, t, rows)]
+        int((shared[i:i + rows] > 0).any(0).sum()) + (i == 0) * 4
+        for i in range(0, t, rows)]
+    if run > 1:
+        # row 0's two exact and two summary columns are one unit
+        fetches = check_paged_runs(tables, q_pos, kinds > 0, BS, nb, n_rep,
+                                   run, window=(W, KIND.ring))
+        assert fetches[0] == 4
 
 
 @pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])

@@ -398,6 +398,28 @@ def test_the_walk_lists_a_rows_window_columns_and_no_others():
     assert walk.served.shape == (1, 72, 3)
 
 
+@pytest.mark.parametrize("run", [2, 4])
+@pytest.mark.parametrize("n_rep", [6, 9])
+def test_a_rings_columns_ride_in_runs(n_rep, run):
+    """The walk of a sliding layer's rings cut into the kernel's units
+    (``tests/walk_checks.py``): at 6 and 9 heads a row's group is 16
+    stacked rows from the sublane its first head lies in, and the units
+    are keyed by that."""
+    from walk_checks import check_paged_runs
+
+    bs, window, ring = 4, 8, 3
+    slot_ids = jnp.asarray([0, 1, 1, 1, 5, 2, 3, 4, 6], jnp.int32)
+    q_pos = jnp.asarray([30, 6, 7, 8, PAD_POSITION, 11, 3, 40, 9], jnp.int32)
+    tables, _ = paging.ring_write_indices(slot_ids, q_pos, bs, ring, 7)
+    live = np.asarray(pa.sliding_column_live(
+        np.asarray(tables), np.arange(ring), np.asarray(q_pos)[:, None], bs,
+        window, ring))
+    kinds = check_paged_runs(tables, q_pos, live, bs, 21, n_rep, run,
+                             sliding=window)
+    # slot 1's three rows share their ring's three live columns
+    assert kinds[0] > 0 and kinds.sum() == 3 + 3 + 2 + 1 + 3 + 3
+
+
 # -- (d) the share of the experts ties to the model --------------------------
 
 def test_two_shares_and_the_shared_expert_once_are_the_uncut_layer():

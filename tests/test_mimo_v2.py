@@ -273,6 +273,25 @@ def test_the_kernel_at_keys_of_192_and_values_of_128(n_rep, sliding, sink):
             np.testing.assert_allclose(xla[t, n], want, atol=1e-5)
 
 
+@pytest.mark.parametrize("run", [2, 8])
+@pytest.mark.parametrize("n_rep,sliding", [(16, None), (8, 12)],
+                         ids=["full-gqa16", "sliding-gqa8"])
+def test_a_rows_blocks_ride_in_runs_at_both_head_counts(n_rep, sliding, run):
+    """The same step's walk cut into the kernel's units
+    (``tests/walk_checks.py``): at 16 and 8 heads a group is one packed
+    row's heads, so a run is one row's blocks."""
+    from walk_checks import check_paged_runs
+
+    *_, pos, tables, q_pos = _pool_case(32, 32 // n_rep, sliding)
+    cols = np.arange(tables.shape[1])
+    live = (pa.column_live(tables, cols, q_pos[:, None], BS)
+            if sliding is None else pa.sliding_column_live(
+                tables, cols, q_pos[:, None], BS, sliding, tables.shape[1]))
+    kinds = check_paged_runs(tables, q_pos, np.asarray(live), BS,
+                             pos.shape[0], n_rep, run, sliding=sliding)
+    assert kinds[0] > 0
+
+
 # -- (c) the model and the paged forward against the reference ---------------
 
 def test_full_forward_matches_the_reference_and_a_wide_window_does_not():

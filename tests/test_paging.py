@@ -30,7 +30,7 @@ from neuronx_distributed_tpu.ops.paged_attention import (column_live,
                                                           tile_walk)
 from neuronx_distributed_tpu.parallel import mesh as ps
 from counter_checks import declared_anywhere
-from walk_checks import check_tile_walk, narrow_group
+from walk_checks import check_paged_runs, check_tile_walk, narrow_group
 
 
 # ---------------------------------------------------------------------------
@@ -197,13 +197,15 @@ def test_paged_attention_pallas_interpret_matches_xla(quantized, case):
 
 @pytest.mark.parametrize("case", list(_TILED_CASES))
 @pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
-def test_paged_kernel_tiles_match_xla(quantized, case):
+def test_paged_kernel_tiles_match_xla(monkeypatch, quantized, case):
     """The kernel's unit is (a tile of rows, a pool block some row of it
     attends): the cases put a chunk across two tiles, a tile of rows that
     share nothing, a chunk beside decode rows, pad rows between real ones
     and two slots' shared prefix block in one tile. A pad row's output is
     zero, and takes nothing from and adds nothing to the real rows: with
-    the pad rows left out the real rows' outputs are the same bits."""
+    the pad rows left out the real rows' outputs are the same, to the
+    bit where the kernel takes a pair a turn (a run's rounding follows
+    the units its tile is cut into, and those follow the rows' places)."""
     rows = _TILED_CASES[case]
     assert tile_rows(16, len(rows)) == 8
     q, k, v, pp, tb, qp, ks, vs = _paged_case(rows, quantized, heads=(32, 2))
@@ -216,9 +218,17 @@ def test_paged_kernel_tiles_match_xla(quantized, case):
                                atol=1e-5)
     assert not ker[~real].any()
     if not real.all():
-        alone = paged_attention(q[real], k, v, pp, tb[real], qp[real], 0,
-                                force_pallas=True, **kw)
-        np.testing.assert_array_equal(ker[real], np.asarray(alone))
+        def alone():
+            return np.asarray(paged_attention(
+                q[real], k, v, pp, tb[real], qp[real], 0, force_pallas=True,
+                **kw))
+
+        np.testing.assert_allclose(ker[real], alone(), rtol=1e-6, atol=1e-6)
+        monkeypatch.setattr(pa, "run_blocks", lambda *_: 1)
+        np.testing.assert_array_equal(
+            np.asarray(paged_attention(q, k, v, pp, tb, qp, 0,
+                                       force_pallas=True, **kw))[real],
+            alone())
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
@@ -522,6 +532,187 @@ def test_paged_kernel_at_heads_that_cross_sublanes_matches_xla(n_rep,
                for i, n in enumerate(np.asarray(walk.count))) >= rows
 
 
+# ---------------------------------------------------------------------------
+# The kernel's units: the blocks of one narrow group ride in runs, up to
+# ``run_blocks`` of them side by side and one step of the online softmax
+# over all their positions. Tiny pools would all take 8: the cases patch
+# the length (no option chooses it), 1 being the kernel without runs.
+# ---------------------------------------------------------------------------
+
+_UNMAPPED = -1      # a real row whose table maps nothing: attends nothing
+
+
+def _run_scene(rows, heads, d=16, dv=None, bs=4, maxb=8, sliding=None,
+               quantized=False, sink=False, seed=0):
+    """A pool in which every slot holds its own blocks in a scrambled
+    order and ``rows`` (``(slot, position)``, ``None`` a pad row,
+    ``(_UNMAPPED, position)`` a row that attends nothing) are one packed
+    step: ``(args, kwargs, tables, q_pos, live)`` for ``paged_attention``.
+    ``sliding``: a slot's ring of ``sliding // bs + 1`` blocks, later laps
+    overwriting earlier ones. ``dv``: values narrower than the keys, the K
+    pool in whole lanes."""
+    n, kv = heads
+    dv = dv or d
+    rng = np.random.RandomState(seed)
+    last = {}
+    for slot, p in filter(None, rows):
+        if slot != _UNMAPPED:
+            last[slot] = max(last.get(slot, 0), p)
+    ring = None if sliding is None else sliding // bs + 1
+    maxb = ring or maxb
+    held = {s: ring or p // bs + 1 for s, p in last.items()}
+    nb = sum(held.values()) + 1
+    free = rng.permutation(nb).tolist()
+    table = {_UNMAPPED: np.full((maxb,), -1, np.int64)}
+    pos = np.full((nb, bs), PAD_POSITION, np.int32)
+    for s, many in held.items():
+        table[s] = np.full((maxb,), -1, np.int64)
+        table[s][:many] = [free.pop() for _ in range(many)]
+        for p in range(last[s] + 1):
+            pos[table[s][p // bs % maxb], p % bs] = p
+    tables = np.stack([table[r[0] if r else _UNMAPPED] for r in rows])
+    q_pos = np.array([r[1] if r else PAD_POSITION for r in rows], np.int64)
+    q = jnp.asarray(rng.randn(len(rows), n, d).astype(np.float32))
+    k = jnp.asarray(rng.randn(2, nb, bs, kv, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(2, nb, bs, kv, dv).astype(np.float32))
+    kw = dict(sliding=sliding)
+    if quantized:
+        (k, kw["k_scale"]), (v, kw["v_scale"]) = quantize_kv(k), quantize_kv(v)
+    if dv != d:
+        k = pa.keys_to_lanes(k)
+    if sink:
+        kw["sink"] = jnp.asarray(rng.randn(n).astype(np.float32))
+    cols = np.arange(maxb)
+    live = (column_live(tables, cols, q_pos[:, None], bs) if sliding is None
+            else pa.sliding_column_live(tables, cols, q_pos[:, None], bs,
+                                        sliding, maxb))
+    args = (q, k, v, jnp.asarray(pos), jnp.asarray(tables, jnp.int32),
+            jnp.asarray(q_pos, jnp.int32), 1)
+    return args, kw, tables, q_pos, np.asarray(live)
+
+
+def _decode_rows(lengths, first=0):
+    return [(first + s, n - 1) for s, n in enumerate(lengths)]
+
+
+#: name -> (the scene's arguments, ``[in_run, alone, whole]`` under runs
+#: of 4 where the case fixes them)
+_RUN_KERNEL_CASES = {
+    # 27 positions are 7 blocks, a run of four and one of three; a row of
+    # one block is a unit by itself
+    "a_short_last_run": (dict(rows=[(0, 26), (1, 2)], heads=(32, 2)),
+                         (7, 1, 0)),
+    # a chunk of five rows (3 blocks, the tile's) beside three decode rows
+    "a_chunk_beside_decode_rows": (dict(
+        rows=[(0, p) for p in range(7, 12)] + _decode_rows([23, 4, 30], 1),
+        heads=(32, 2)), (14, 1, 3)),
+    # GQA-4: rows 0 and 1 share a sublane, so one group; their blocks lie
+    # column by column in one sequence of runs, each block under the mask
+    # of the row that names it
+    "two_packed_rows_in_one_group": (dict(
+        rows=_decode_rows([30, 9, 14, 27, 5, 21]), heads=(8, 2)),
+        (30, 0, 0)),
+    # GQA-6, a tile of 20 rows filled: the last rows' groups begin where
+    # the tile's last group does, and neighbouring rows' groups overlap
+    "overlapping_groups_at_the_tiles_end": (dict(
+        rows=_decode_rows([5 + 3 * (r % 7) for r in range(20)]),
+        heads=(12, 2)), None),
+    # keys of 192 beside values of 128, a sink a head
+    "wide_keys_with_a_sink": (dict(
+        rows=_decode_rows([30, 9, 18]) + [(3, p) for p in range(20, 25)],
+        heads=(16, 2), d=192, dv=128, sink=True), None),
+    # a window of one block over a ring of two columns, a sink a head
+    "a_sliding_ring_of_two_columns": (dict(
+        rows=_decode_rows([30, 3, 9, 18]) + [(4, p) for p in range(9, 13)],
+        heads=(16, 2), sliding=4, sink=True), None),
+    "an_int8_pool": (dict(
+        rows=_decode_rows([30, 9, 14]) + [(3, p) for p in range(14, 19)],
+        heads=(8, 2), quantized=True), None),
+    # a pad row between real ones, and a real row whose table maps nothing
+    "a_pad_row_and_a_row_that_attends_nothing": (dict(
+        rows=[(0, 22), None, (1, 9), (_UNMAPPED, 5), (2, 17), None],
+        heads=(32, 2)), (13, 1, 0)),
+}
+
+
+@pytest.mark.parametrize("run", [1, 2, 4])
+@pytest.mark.parametrize("case", list(_RUN_KERNEL_CASES))
+def test_paged_kernel_in_runs_matches_xla(monkeypatch, case, run):
+    """The kernel (interpret mode) with a narrow group's blocks in runs of
+    ``run`` (1: a pair a turn, the kernel of a pool whose blocks are too
+    large to ride in runs) against the gather reference; a pad row and a
+    row that attends nothing give zeros; the walk's cut into units holds
+    and the host's count of the fetches is the walk's."""
+    scene, counts = _RUN_KERNEL_CASES[case]
+    args, kw, tables, q_pos, live = _run_scene(**scene)
+    n_rep = scene["heads"][0] // scene["heads"][1]
+    run = min(run, 1 << tables.shape[1].bit_length() - 1)
+    monkeypatch.setattr(pa, "run_blocks", lambda *_: run)
+    ref = np.asarray(paged_attention(*args, force_pallas=False, **kw))
+    ker = np.asarray(paged_attention(*args, force_pallas=True, **kw))
+    assert ker.shape == ref.shape
+    real = live.any(axis=1)
+    np.testing.assert_allclose(ker[real], ref[real], rtol=1e-5, atol=1e-5)
+    assert not ker[~real].any()
+    if run == 1:
+        return
+    nb = args[3].shape[0]
+    kinds = check_paged_runs(tables, q_pos, live, 4, nb, n_rep, run,
+                             sliding=scene.get("sliding"))
+    if run == 4 and counts is not None:
+        assert tuple(kinds) == counts
+    assert kinds[0] > 0
+
+
+@pytest.mark.parametrize("run", [2, 4, 8])
+@pytest.mark.parametrize("n_rep", [1, 4, 6, 8, 9, 16])
+def test_a_narrow_groups_pairs_are_cut_into_runs(n_rep, run):
+    """:func:`pair_runs` keyed by a group's first row, at every kind of
+    group: one packed row's heads (8, 16), two rows' in one sublane (4),
+    eight rows' (1), neighbours whose groups overlap (6, 9): decode rows
+    in every place of two tiles beside a chunk."""
+    tables, q_pos, nb, rows = _decode_rows_beside_a_chunk(n_rep)
+    live = column_live(tables, np.arange(tables.shape[1]), q_pos[:, None],
+                       4)
+    kinds = check_paged_runs(tables, q_pos, live, 4, nb, n_rep, run)
+    narrow, one_row_whole, shared = pair_kinds(np.where(live, tables, -1),
+                                               n_rep, nb)
+    assert (kinds[0] + kinds[1], kinds[2]) == (narrow + one_row_whole,
+                                               shared)
+    assert kinds[0] > kinds[1] > 0
+
+
+def test_run_blocks_follow_the_pools_shapes():
+    """The run's length from shapes alone: the cells' pools."""
+    def pools(kv, d, dv=None, dtype=jnp.bfloat16):
+        k = (2, 64, 128, kv, d) if dv is None else (2, 64, 128, kv * d)
+        return (jax.ShapeDtypeStruct(k, dtype),
+                jax.ShapeDtypeStruct((2, 64, 128, kv, dv or d), dtype))
+
+    assert pa.run_blocks(*pools(4, 192, 128), 16, 256) == 8   # MiMo, full
+    assert pa.run_blocks(*pools(8, 192, 128), 8, 2) == 2      # its rings
+    assert pa.run_blocks(*pools(8, 128), 4, 20) == 4          # Mistral
+    assert pa.run_blocks(*pools(8, 128), 6, 160) == 4         # Laguna, full
+    assert pa.run_blocks(*pools(8, 128), 9, 5) == 4           # its rings
+    assert pa.run_blocks(*pools(4, 128), 8, 20) == 8          # Granite
+    assert pa.run_blocks(*pools(32, 128), 1, 40) == 1         # EvaByte
+    assert pa.run_blocks(*pools(8, 128, dtype=jnp.int8), 4, 20) == 8
+
+
+def test_a_walk_of_the_other_form_is_refused(monkeypatch):
+    """The kernel computes its run from the pools it is handed; a walk
+    built for other pools (a tile walk where it takes runs) raises."""
+    args, kw, *_ = _run_scene(rows=_decode_rows([9, 5]), heads=(8, 2))
+    walk = tile_walk(args[4], args[5], 4, args[3].shape[0], 4)
+    with pytest.raises(ValueError, match="runs of"):
+        paged_attention(*args, force_pallas=True, walk=walk)
+    monkeypatch.setattr(pa, "run_blocks", lambda *_: 1)
+    out = paged_attention(*args, force_pallas=True, walk=walk)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(paged_attention(
+            *args, force_pallas=False)), rtol=1e-5, atol=1e-5)
+
+
 def test_paged_attention_validates_scales_and_heads():
     q, k, v, pp, tb, qp, ks, vs = _paged_case(_RAGGED_CASES["ragged"], True,
                                               seed=2)
@@ -713,7 +904,8 @@ def test_model_builder_init_state_paged_kind():
 _PAGED = {"nxd_paged_columns_total": ("skipped", "live"),
           "nxd_paged_block_visits_total": ("fetched", "shared"),
           "nxd_paged_pairs_total": ("narrow", "one_row_whole"),
-          "nxd_paged_shared_pairs_total": ()}
+          "nxd_paged_shared_pairs_total": (),
+          "nxd_paged_block_fetches_total": ("in_run", "alone", "whole")}
 _STATES = {"nxd_state_resets_total": (),
            "nxd_state_slot_steps_total": ("advanced", "held")}
 _MOE = {"nxd_moe_assignments_total": ("kept", "dropped")}

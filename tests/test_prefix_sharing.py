@@ -34,7 +34,7 @@ from neuronx_distributed_tpu.ops.paged_attention import (column_live,
                                                           tile_walk)
 from neuronx_distributed_tpu.parallel import mesh as ps
 from neuronx_distributed_tpu.resilience.chaos import FaultPlan
-from walk_checks import check_tile_walk
+from walk_checks import check_paged_runs, check_tile_walk
 
 
 @pytest.fixture
@@ -286,6 +286,22 @@ def test_paged_attention_invariant_under_shared_tables(force_pallas):
         assert walk.count.tolist() == [len(blocks)]
         assert sorted(walk.blocks[:len(blocks)].tolist()) == blocks
         check_tile_walk(walk, live, np.asarray(tables), 8, N // 2)
+
+
+@pytest.mark.parametrize("run", [2, 4])
+@pytest.mark.parametrize("tables", ["shared", "private"])
+def test_a_shared_prefix_block_rides_in_its_groups_run(tables, run):
+    """Two decode rows whose heads one narrow group holds: the block they
+    share is one pair of that group's run, named by both rows, and the
+    private copies one each (``tests/walk_checks.py``)."""
+    q_pos = np.asarray([7, 7])
+    tables = np.asarray({"shared": [[0, 2, -1], [1, 2, -1]],
+                         "private": [[0, 2, -1], [1, 7, -1]]}[tables])
+    live = column_live(tables, np.arange(3), q_pos[:, None], 4)
+    kinds = check_paged_runs(tables, q_pos, live, 4, 8, 2, run)
+    pairs = 3 if tables[1, 1] == 2 else 4
+    alone = int(pairs % run == 1)
+    assert kinds.tolist() == [pairs - alone, alone, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -27,8 +27,8 @@ def check_tile_walk(walk, live, tables, rows, n_rep, runs=None):
     heads that name it: :func:`narrow_group` rows from the whole sublane
     the first of them lies in (from the tile's last group, if that is
     earlier), so every pair that one packed row names is narrow.
-    ``runs``, the latent kernel's cut of this walk into units
-    (``(run_walk, run, whole_run)``), is held to :func:`check_pair_runs`,
+    ``runs``, a kernel's cut of this walk into units (``(run_walk, run,
+    whole_run)``), is held to :func:`check_pair_runs`,
     whose count of the fetches by kind is returned."""
     t, maxb = tables.shape
     tiles = len(walk.count)
@@ -66,22 +66,24 @@ def check_tile_walk(walk, live, tables, rows, n_rep, runs=None):
 
 
 def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run):
-    """What :func:`..ops.mla_attention.pair_runs` must hold of a tile
+    """What :func:`..ops.paged_attention.pair_runs` must hold of a tile
     walk, by brute count: following a tile's units from its first pair,
     every pair of the walk (so every live (row, column)) lies in exactly
     one unit, no block is in two, a unit is no longer than its kind may
-    be and holds either pairs that one and the same row names alone, in
-    order of column, or pairs that several rows name; a row's units are
-    full but its last; and ``lens`` is 0 off a unit's first pair. Returns
-    the walk's fetches by kind, ``[in_run, alone, whole]``, which the
-    caller holds the host's count to
-    (:func:`..ops.mla_attention.block_fetches`, NumPy over the tables
-    themselves)."""
+    be and holds either pairs of one narrow group (the walk's own
+    ``narrow`` for each of them, the group's first row: the rows that
+    name them have their heads inside it; one packed row under 8 or 16
+    heads, the two that share a sublane under 4, the neighbours whose
+    groups begin on one under 6 and 9), in order of column and block, or
+    pairs that no group holds; a group's units are full but its last;
+    and ``lens`` is 0 off a unit's first pair. Returns the walk's fetches
+    by kind, ``[in_run, alone, whole]``, which the caller holds the
+    host's count to (:func:`..ops.paged_attention.block_fetches`, NumPy
+    over the tables themselves)."""
     t, maxb = tables.shape
     tiles = len(walk.count)
     per = rows * maxb
-    group = -(-n_rep // 8) * 8
-    assert group == n_rep              # a group is one packed row's heads
+    group = narrow_group(n_rep)
     units, blocks, cols, narrow, lens = (np.asarray(x) for x in runs[:5])
     kinds = np.zeros((3,), np.int64)        # in_run, alone, whole
     for i in range(tiles):
@@ -90,8 +92,14 @@ def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run):
         for r in mine:
             for c in np.flatnonzero(live[r]):
                 want.setdefault((int(c), int(tables[r, c])), []).append(r)
+        # where the tile walk has each pair run
+        count = int(walk.count[i])
+        at_walk = dict(zip(
+            zip(walk.cols[i * per:i * per + count].tolist(),
+                walk.blocks[i * per:i * per + count].tolist()),
+            walk.narrow[i * per:i * per + count].tolist()))
         seen, heads_at, first = [], set(), 0
-        last_of = {}                    # a row's units' lengths, in order
+        last_of = {}                # a group's units, in order
         for _ in range(int(units[i])):
             at = i * per + first
             n = int(lens[at])
@@ -101,25 +109,54 @@ def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run):
             starts = set(narrow[at:at + n].tolist())
             assert len(starts) == 1     # one group's pairs, or shared ones
             start = starts.pop()
+            assert all(at_walk[p] == start for p in pairs)
             kinds[2 if start < 0 else int(n == 1)] += n
             if start < 0:
                 assert 1 <= n <= whole_run
                 assert all(len(want[p]) > 1 for p in pairs)
             else:
-                assert 1 <= n <= run
-                row = i * rows + start // group
-                assert all(want[p] == [row] for p in pairs)
-                assert [c for c, _ in pairs] == sorted(c for c, _ in pairs)
-                if row in last_of:      # after its row's earlier columns
-                    assert last_of[row][-1][1] < pairs[0][0]
-                    assert last_of[row][-1][0] == run   # which were full
-                last_of.setdefault(row, []).append((n, pairs[-1][0]))
+                assert 1 <= n <= run and start % 8 == 0
+                for p in pairs:         # the group holds its namers' heads
+                    assert start <= (want[p][0] - i * rows) * n_rep
+                    assert ((want[p][-1] - i * rows + 1) * n_rep
+                            <= start + group)
+                assert pairs == sorted(pairs)
+                if start in last_of:    # after its group's earlier pairs
+                    assert last_of[start][-1][1] < pairs[0]
+                    assert last_of[start][-1][0] == run  # which were full
+                last_of.setdefault(start, []).append((n, pairs[-1]))
             seen += pairs
             first += n
-        assert first == int(walk.count[i])
+        assert first == count
         assert len(set(seen)) == len(seen) and set(seen) == set(want)
         off = np.setdiff1d(np.arange(per), sorted(heads_at))
         assert (lens[i * per + off] == 0).all()
+    return kinds
+
+
+def check_paged_runs(tables, q_pos, live, block_size, num_blocks, n_rep, run,
+                     window=None, sliding=None):
+    """The paged kernel's walk of a step in runs of ``run``: the tile walk
+    and its cut into units hold (:func:`check_tile_walk`,
+    :func:`check_pair_runs`), and the host's count of the fetches by kind
+    is the walk's. Returns ``[in_run, alone, whole]``."""
+    import jax.numpy as jnp
+
+    from neuronx_distributed_tpu.ops import paged_attention as pa
+
+    tables, q_pos = np.asarray(tables), np.asarray(q_pos)
+    rows = pa.tile_rows(n_rep, len(q_pos))
+    walk = pa.tile_walk(jnp.asarray(tables, jnp.int32),
+                        jnp.asarray(q_pos, jnp.int32), block_size,
+                        num_blocks, n_rep, window, sliding)
+    runs = pa.run_walk(walk, num_blocks, n_rep, run, 1)
+    assert runs.q_lo is walk.q_lo and runs.served is walk.served
+    kinds = check_tile_walk(
+        type(walk)(*(None if x is None else np.asarray(x) for x in walk)),
+        live, tables, rows, n_rep, runs=(runs, run, 1))
+    fetches = pa.block_fetches(np.where(live, tables, -1), n_rep, run)
+    assert tuple(fetches) == tuple(kinds)
+    assert fetches.sum() == int(np.asarray(walk.count).sum())
     return kinds
 
 
