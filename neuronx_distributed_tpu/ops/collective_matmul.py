@@ -28,25 +28,39 @@ copy_matmul             x @ w (x replicated)         AR(g @ w^T) = AG(RS(.))
 
 Bit-exactness contract
 ----------------------
-``impl="decomposed"`` and ``impl="monolithic"`` are bit-identical in fp32
-(fwd AND grad), by construction rather than by tolerance:
+In fp32 ``impl="decomposed"`` is bit-identical (fwd AND grad) to the
+ascending-rank sum of the per-block partial products, by construction
+rather than by tolerance:
 
-* XLA accumulates ``psum`` / ``psum_scatter`` contributions left-to-right
-  in ascending rank order, so the decomposed reduce-scatter delivers each
-  partial block directly to its destination (per-step shifted ppermutes),
-  buffers them by *source rank*, and performs one ordered left-to-right
-  summation — the same additions in the same order as the monolithic
-  collective.
-* matmuls are row-block stable: block ``j`` of ``concat(shards) @ w``
-  equals ``shard_j @ w`` bit-for-bit, so the ring's per-step partial
-  matmuls reproduce the monolithic product exactly.
-* gathers are pure data movement and cannot perturb bits.
+* the decomposed reduce-scatter delivers each partial block directly to its
+  destination (per-step shifted ppermutes), buffers them by *source rank*,
+  and performs one ordered left-to-right summation, whatever order the
+  blocks arrived in.
+* gathers are pure data movement and cannot perturb bits, so the gather
+  forms (``all_gather_matmul``, and ``copy_matmul``'s forward) equal their
+  monolithic counterparts exactly.
+
+Against ``impl="monolithic"`` the reduce forms promise 2 ulp (of the
+result's largest value: a sum that cancels keeps its terms' rounding), not
+the bit:
+``psum`` / ``psum_scatter`` add in an order of XLA's choosing, and the
+monolithic path multiplies the whole sequence at once, where a backend's
+product of a row block need not be the rows of the whole product to the
+last bit (the CPU backend's is not at blocks of two rows; every case the
+tests hold bit-equal at tp 2 and 4 is so by that backend's grace).
+``tests/test_collective_matmul.py`` holds both: the ordered sum exactly,
+the monolithic collective to 2 ulp forward and to rounding in the grads.
+
+Below 32 bits (bf16 compute, full-precision wire) there is no order to
+reproduce, and the reduce-scatter ring adds as it forwards
+(``_sums_in_transit``, ``_mm_rs_forwarding``): neighbour hops alone, no
+buffer, float32 adds rounded to the compute dtype once a hop.
 
 Bidirectional (two-stream) variants split the ring into clockwise and
 counter-clockwise halves for even axis sizes — each shard travels at most
 ``n/2`` hops instead of ``n-1``, halving ring latency on bidirectional ICI
-links. The buffered ordered summation makes the result independent of the
-streaming direction, so uni/bidi are bit-identical too.
+links. The buffered ordered summation makes the fp32 result independent of
+the streaming direction, so uni/bidi are bit-identical too.
 
 Quantized wire format (activation-collective compression)
 ----------------------------------------------------------
@@ -91,6 +105,7 @@ from typing import Any, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..parallel import comm
@@ -124,6 +139,25 @@ def _record_act_wire(kind: str, shape: Tuple[int, ...],
     raw_b = 4.0 * m * passes
     record_wire_bytes(kind, wire.dtype if wire is not None else "fp32",
                       wire_b, raw_b)
+
+
+def _record_decision(op: str, decomposed: bool) -> None:
+    """One call site's decision, counted at trace time like
+    :func:`_record_act_wire` (once per trace of the site — a layer scan's
+    body is one site — never per execution):
+    ``nxd_tp_collective_matmuls_total{impl, op}``."""
+    from ..obs.metrics import get_registry
+
+    reg = get_registry()
+    if not reg.enabled:
+        return
+    reg.counter("nxd_tp_collective_matmuls_total",
+                "Collective-matmul call sites traced over a bound tp axis, "
+                "by the ring decision taken (counted once per trace).",
+                labels=("impl", "op")).labels(
+                    impl="decomposed" if decomposed else "monolithic",
+                    op=op).inc()
+
 
 #: auto mode (``overlap_comm=None``) engages only at axis sizes where the
 #: ring has enough steps to pipeline; below this the monolithic collective
@@ -187,10 +221,29 @@ def will_decompose(impl: str, axis, x_shape: Tuple[int, ...], dim: int,
 
 
 def _resolve_bidi(bidirectional: Optional[bool], n: int) -> bool:
-    """Two-stream ring only for even axis sizes (auto: even and ≥ 4)."""
+    """Two-stream ring only for even axis sizes (auto: even and ≥ 4; at
+    n = 4 on a v5e 2x2 the train cell's step read 386.2 ms in two streams
+    and 399.4 in one: PERF.md, PR 47)."""
     if bidirectional is None:
         return n % 2 == 0 and n >= 4
     return bool(bidirectional) and n % 2 == 0
+
+
+def overlap_engaged_at(overlap_comm: Optional[bool],
+                       axis_size: Optional[int],
+                       x_shape: Tuple[int, ...], dim: int, *,
+                       needs_divisible: bool) -> bool:
+    """:func:`overlap_engaged` by axis SIZE: the rule itself, for callers
+    that decide before the axis is bound (the trainer binds the tp axis
+    exactly when the layers' rings would then engage)."""
+    if overlap_comm is False:
+        return False
+    if not shapes_tile(x_shape, dim, axis_size,
+                       needs_divisible=needs_divisible):
+        return False
+    if overlap_comm is None:
+        return axis_size >= MIN_AUTO_AXIS_SIZE
+    return True
 
 
 def overlap_engaged(overlap_comm: Optional[bool], axis,
@@ -203,15 +256,8 @@ def overlap_engaged(overlap_comm: Optional[bool], axis,
     shapes tile (never an error — non-tileable shapes fall back);
     ``False``: off.
     """
-    if overlap_comm is False:
-        return False
-    if not will_decompose("decomposed", axis, x_shape, dim,
-                          needs_divisible=needs_divisible):
-        return False
-    if overlap_comm is None:
-        n = comm._axis_size(axis)
-        return n is not None and n >= MIN_AUTO_AXIS_SIZE
-    return True
+    return overlap_engaged_at(overlap_comm, comm._axis_size(axis), x_shape,
+                              dim, needs_divisible=needs_divisible)
 
 
 # ---------------------------------------------------------------------------
@@ -435,29 +481,111 @@ def _ag_matmul_monolithic(x: Array, ws: Tuple[Array, ...], axis, dim: int,
     return tuple(_contract(xg, w) for w in ws)
 
 
+def _delivery_shifts(n: int, bidi: bool) -> Tuple[int, ...]:
+    """The reduce-scatter ring's hops in issue order: the block for the
+    rank ``shift`` ahead ships by one shift-``shift`` ppermute. One stream
+    walks the distances up; two alternate ahead and behind, so the nearest
+    neighbours' blocks leave first in both directions."""
+    if not bidi:
+        return tuple(range(1, n))
+    shifts = []
+    for t in range(1, n // 2 + 1):
+        shifts.append(t)
+        if t != n - t:
+            shifts.append(-t)
+    return tuple(shifts)
+
+
+def _sums_in_transit(dtype, wire: Optional[CompressionConfig]) -> bool:
+    """Whether the reduce-scatter ring adds as it forwards
+    (:func:`_mm_rs_forwarding`) instead of delivering every partial to its
+    owner and buffering by source rank: below 32 bits on a full-precision
+    wire, where there is no order to reproduce (the float32 contract and
+    the quantized wire's dequantized-domain order keep the buffer)."""
+    return wire is None and jnp.dtype(dtype).itemsize < 4
+
+
+def _mm_rs_forwarding(block, n: int, l: int, axis, dim: int,
+                      bidi: bool) -> Array:
+    """Accumulate-and-forward ring matmul-reduce-scatter over
+    ``block(k, lo, size)``, rows ``lo:lo+size`` of this rank's partial
+    product for the rank ``k`` ahead of it: a destination's block starts at
+    the rank after it, and every rank on the way adds its own partial
+    product and passes the sum to its neighbour — ``n - 1`` neighbour hops
+    of one block a link, where direct delivery's shifts of two and more
+    load a link once a hop (at n = 4 on a 2x2 ring: 1.0 ms of wire a set of
+    [2, 1024, 4096] bf16 blocks against 0.5). Two streams split
+    every block's rows, one half a direction. Each hop needs the sum that
+    arrived and a product that did not wait for it: the barrier keeps XLA
+    from fusing the add into the matmul, which would hold the matmul back
+    until the hop before it lands. Sums travel in the compute dtype (one
+    rounding a hop, added in float32)."""
+    f32 = jnp.float32
+
+    def summed(a, b):
+        # lax, not jnp operators: a ring is traced a dozen times a step
+        # (forward, recomputation, each dual) and the wrappers' cost is
+        # the step's start-up
+        return lax.convert_element_type(
+            lax.add(lax.convert_element_type(a, f32),
+                    lax.convert_element_type(b, f32)), b.dtype)
+
+    def stream(sign, lo, size):
+        acc = None
+        for t in range(1, n):
+            p = lax.optimization_barrier(block(-sign * t, lo, size))
+            if acc is not None:
+                p = summed(acc, p)
+            acc = comm.ppermute(p, axis, _shift_perm(n, sign))
+        return summed(acc, lax.optimization_barrier(block(0, lo, size)))
+
+    if bidi and l % 2 == 0:
+        return jnp.concatenate(
+            [stream(+1, 0, l // 2), stream(-1, l // 2, l // 2)], axis=dim)
+    return stream(+1, 0, l)
+
+
+def _rows(x: Array, start, size: int, dim: int) -> Array:
+    """``lax.dynamic_slice_in_dim`` at an int32 ``start`` known to be in
+    range, bound as the primitive: the wrapper's index normalisation is
+    1.5 ms of tracing a call, 96 calls a train step."""
+    zero = np.int32(0)
+    starts = [zero] * x.ndim
+    starts[dim] = start
+    sizes = list(x.shape)
+    sizes[dim] = size
+    return lax.dynamic_slice_p.bind(x, *starts, slice_sizes=tuple(sizes))
+
+
 def _mm_rs_decomposed(xs: Tuple[Array, ...], ws: Tuple[Array, ...], axis,
                       dim: int, bidi: bool,
                       wire: Optional[CompressionConfig]) -> Array:
     """Ring matmul-reduce-scatter: each destination's partial block is
-    computed, shipped straight to its owner (shift-``t`` ppermute — one
-    hop's worth of latency per step regardless of distance on a torus),
+    computed, shipped straight to its owner (shift-``t`` ppermute),
     buffered by source rank, and summed once left-to-right in ascending
-    rank order — the exact addition order of XLA's ``psum_scatter``. With
-    a quantized ``wire`` each partial block is encoded before its ppermute
-    and the accumulation happens in the dequantized domain, preserving
-    that same ascending-rank order."""
+    rank order. With a quantized ``wire`` each partial block is encoded
+    before its ppermute and the accumulation happens in the dequantized
+    domain, preserving that same ascending-rank order. Below 32 bits on a
+    full-precision wire the ring forwards sums instead
+    (:func:`_mm_rs_forwarding`)."""
     n = comm._axis_size(axis)
     idx = lax.axis_index(axis)
     dim = _norm_dim(dim, xs[0].ndim)
     big = xs[0].shape[dim]
     l = big // n
 
-    def block(j):
-        parts = [lax.dynamic_slice_in_dim(x, j * l, l, axis=dim)
-                 for x in xs]
+    def block(ahead, lo=0, size=l):
+        """Rows ``lo:lo+size`` of the partial block for the rank ``ahead``
+        ranks on (lax index arithmetic: see ``_mm_rs_forwarding``)."""
+        start = lax.add(
+            lax.mul(lax.rem(lax.add(idx, np.int32(ahead % n)), np.int32(n)),
+                    np.int32(l)), np.int32(lo))
+        parts = [_rows(x, start, size, dim) for x in xs]
         return _contract_sum(parts, ws)
 
-    p_own = block(idx)
+    if _sums_in_transit(jnp.result_type(xs[0], ws[0]), wire):
+        return _mm_rs_forwarding(block, n, l, axis, dim, bidi)
+    p_own = block(0)
     dt = p_own.dtype
     # the own partial round-trips through DQ(Q(·)) like every shipped one,
     # so rank position doesn't change which contributions are exact —
@@ -470,20 +598,10 @@ def _mm_rs_decomposed(xs: Tuple[Array, ...], ws: Tuple[Array, ...], axis,
             buf, p[None], (src,) + (0,) * p.ndim)
 
     buf = store(buf, own, idx)
-    if not bidi:
-        for t in range(1, n):
-            p = encode_payload(block((idx + t) % n), wire)
-            p = _ship(p, axis, _shift_perm(n, t))
-            buf = store(buf, _open(p, wire, dt), (idx - t) % n)
-    else:
-        for t in range(1, n // 2 + 1):
-            p = encode_payload(block((idx + t) % n), wire)
-            p = _ship(p, axis, _shift_perm(n, t))
-            buf = store(buf, _open(p, wire, dt), (idx - t) % n)
-            if t != n - t:
-                q = encode_payload(block((idx - t) % n), wire)
-                q = _ship(q, axis, _shift_perm(n, -t))
-                buf = store(buf, _open(q, wire, dt), (idx + t) % n)
+    for shift in _delivery_shifts(n, bidi):
+        p = encode_payload(block(shift), wire)
+        p = _ship(p, axis, _shift_perm(n, shift))
+        buf = store(buf, _open(p, wire, dt), (idx - shift) % n)
     return _ordered_sum(buf, n)
 
 
@@ -724,6 +842,7 @@ def all_gather_matmul(x: Array, kernels: Kernels, axis=ps.TP_AXIS,
                 x.astype(jnp.float32) - dq).astype(error.dtype)
     # ring: each rank's shard takes n-1 hops (monolithic AG moves the same)
     _record_act_wire("act_all_gather_matmul", tuple(x.shape), wire, n - 1)
+    _record_decision("all_gather_matmul", decomposed)
     out = _unwrap(_ag_matmul(x, ws, axis, gather_dim, decomposed, bidi,
                              wire), kernels)
     return (out, new_error) if error is not None else out
@@ -751,6 +870,7 @@ def matmul_reduce_scatter(x: Array, kernel: Array, axis=ps.TP_AXIS,
     _record_act_wire("act_matmul_reduce_scatter",
                      _scatter_block_shape(x, kernel, scatter_dim, n),
                      wire, n - 1)
+    _record_decision("matmul_reduce_scatter", decomposed)
     return _mm_rs(x, kernel, axis, scatter_dim, decomposed, bidi, wire)
 
 
@@ -775,6 +895,7 @@ def matmul_all_reduce(x: Array, kernel: Array, axis=ps.TP_AXIS,
     _record_act_wire("act_matmul_all_reduce",
                      _scatter_block_shape(x, kernel, pipeline_dim, n),
                      wire, 2 * (n - 1))
+    _record_decision("matmul_all_reduce", decomposed)
     return _mm_ar(x, kernel, axis, pipeline_dim, decomposed, bidi, wire)
 
 
@@ -792,5 +913,6 @@ def copy_matmul(x: Array, kernels: Kernels, axis=ps.TP_AXIS,
     n = comm._axis_size(axis)
     if n is None or n <= 1:
         return _unwrap(tuple(_contract(x, w) for w in ws), kernels)
+    _record_decision("copy_matmul", decomposed)
     return _unwrap(_copy_mm(x, ws, axis, pipeline_dim, decomposed, bidi,
                             wire), kernels)

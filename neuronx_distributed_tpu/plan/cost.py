@@ -325,10 +325,12 @@ def tp_overlap_engagement(plan: Plan, m: ModelSpec) -> bool:
     return entry and exit_ and plan.tp >= MIN_AUTO_AXIS_SIZE
 
 
-#: fraction of decomposed-ring transfer time hidden behind the per-shard
-#: partial matmuls when overlap engages (a guess: no chip run has
-#: measured it; docs/tp_overlap.md)
-TP_OVERLAP_HIDDEN_FRACTION = 0.7
+#: fraction of the tp activation collectives' time hidden behind the
+#: per-shard partial matmuls when overlap engages: 66.5 ms a step exposed
+#: under GSPMD, 26.3 with the rings, at tp=4 on a v5e 2x2, 2 x 4,096
+#: tokens, Mistral-7B widths, no sequence parallelism (PERF.md, PR 47;
+#: docs/tp_overlap.md)
+TP_OVERLAP_HIDDEN_FRACTION = 0.6
 
 
 def tp_comm_s(plan: Plan, m: ModelSpec, hw: HardwareSpec) -> float:

@@ -7,9 +7,14 @@ per-step path of ``trainer/optimizer.py`` / ``NxDModel.run_train``.
 
 TPU-native shape: one jitted SPMD ``train_step`` (loss → grad → update) with
 ``NamedSharding``-annotated params and optimizer state. Sharded-grad
-reduction, ZeRO-1 reduce-scatter/all-gather and collective overlap all come
-from GSPMD + the XLA latency-hiding scheduler rather than hand-written
-bucketed all-reduce (reference ``grads.py:259``).
+reduction and ZeRO-1's reduce-scatter/all-gather come from GSPMD + the XLA
+latency-hiding scheduler rather than hand-written bucketed all-reduce
+(reference ``grads.py:259``). The tensor-parallel collectives do not: a
+row-parallel exit's all-reduce is a true dependence no scheduler hides, so
+where the projections' decomposed rings (``ops/collective_matmul``) would
+engage, the default step computes loss and gradients inside ``shard_map``
+with the mesh's axes bound (``_tp_rings_engage``) and GSPMD keeps the
+optimizer around it.
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..config import NxDConfig
 from ..obs.device_scopes import device_scope
+from ..ops import collective_matmul as cm
 from ..parallel import comm
 from ..parallel import comm_compressed as cc
 from ..parallel import grads as grads_mod
@@ -255,6 +261,23 @@ def initialize_parallel_optimizer(
     return tx, state, state_shardings
 
 
+def _tp_rings_engage(pm: ParallelModel, mesh, batch) -> bool:
+    """Whether the default step binds the mesh's axes for this batch: the
+    layers' own rule (``cm.overlap_engaged``) read from the mesh instead of
+    a bound axis — the tp axis has ``cm.MIN_AUTO_AXIS_SIZE`` ranks or more
+    (any size with ``tp_overlap_comm=True``, never with ``False``) and the
+    batch's sequence tiles over it. Only tensor x data meshes: pipeline,
+    context and expert parallelism keep their GSPMD step."""
+    sizes = dict(mesh.shape)
+    if any(sizes.get(ax, 1) > 1 for ax in (ps.PP_AXIS, ps.CP_AXIS)) \
+            or ps.get_expert_model_parallel_size() > 1:
+        return False
+    shape = tuple(jnp.shape(batch["input_ids"]))
+    return len(shape) == 2 and cm.overlap_engaged_at(
+        pm.config.parallel.tp_overlap_comm, sizes.get(ps.TP_AXIS, 1),
+        shape + (1,), 1, needs_divisible=True)
+
+
 def make_train_step(
     pm: ParallelModel,
     tx: optax.GradientTransformation,
@@ -273,7 +296,8 @@ def make_train_step(
     """Build the jitted SPMD train step.
 
     Either ``loss_fn(module, params, batch) -> scalar`` (differentiated here
-    under GSPMD; default calls ``module.apply(..., method="loss")``) or
+    under GSPMD; default calls ``module.apply(..., method="loss")``, under
+    GSPMD or the explicit path below) or
     ``grad_fn(params, batch) -> (loss, grads)`` for paths that must compute
     gradients themselves (e.g. the shard_map pipeline engine, whose gradients
     may not cross the shard_map boundary as cotangents — see
@@ -314,13 +338,24 @@ def make_train_step(
     keep ``integrity_every`` a multiple of ``scan_steps`` (or 1) for a
     usable cadence.
 
+    The default loss has an *explicit* path too: loss and grads computed
+    inside ``shard_map`` over the mesh, gradients averaged over the data
+    axes by hand. The step takes it when binding the tp axis lets the
+    layers' decomposed collective-matmuls engage (``_tp_rings_engage``: the
+    axis has ``cm.MIN_AUTO_AXIS_SIZE`` ranks or ``tp_overlap_comm=True``,
+    the batch's sequence tiles over it, the mesh is tensor x data), decided
+    from the batch's shape at trace time; otherwise, and always with a
+    custom ``loss_fn``/``grad_fn``, loss and grads come from GSPMD. Like
+    ``grad_accum_steps``, the explicit path's loss over data-parallel ranks
+    is the mean of their means: the global mean when ranks carry equal
+    valid-token counts.
+
     ``compression``: a ``parallel.CompressionConfig`` (typically
     ``comm_compressed.from_config(pm.config)``) switching gradient
-    synchronisation to the quantized / hierarchical collectives. This
-    builds the *explicit* path internally — loss and grads computed inside
-    ``shard_map`` with the compressed all-reduce on the data axes (GSPMD
-    cannot be told to quantize its implicit reductions) — so it composes
-    only with the default loss (``loss_fn=None, grad_fn=None``); pipeline
+    synchronisation to the quantized / hierarchical collectives. It always
+    takes the explicit path, with the compressed all-reduce on the data
+    axes (GSPMD cannot be told to quantize its implicit reductions), so it
+    composes only with the default loss (``loss_fn=None, grad_fn=None``); pipeline
     ``grad_fn``s own their collectives and stay uncompressed. With a
     quantized dtype + error feedback, the state must carry ``comm_error``
     buffers (``initialize_parallel_optimizer`` allocates them when the
@@ -364,9 +399,16 @@ def make_train_step(
     else:
         default_loss = False
 
-    compressed_grad = None
-    if compression is not None:
-        use_ef = compression.quantized and compression.error_feedback
+    # One explicit path: loss and gradients inside ``shard_map`` over the
+    # mesh, every axis bound. ``compression=`` always takes it (GSPMD cannot
+    # be told to quantize its implicit reductions); the default step takes
+    # it when binding the tp axis lets the projections' decomposed rings
+    # engage (``_tp_rings_engage``, decided per batch shape at trace time),
+    # with a plain mean over the data axes.
+    explicit_grad = None
+    if default_loss:
+        use_ef = (compression is not None and compression.quantized
+                  and compression.error_feedback)
         with_rng = dropout_rng is not None
         red_axes = tuple(ax for ax in (ps.DP_AXIS, ps.CP_AXIS)
                          if dict(mesh.shape).get(ax, 1) > 1)
@@ -424,7 +466,7 @@ def make_train_step(
         sm_grad = ps.shard_map(inner, mesh, in_specs=tuple(in_specs),
                                out_specs=out_specs)
 
-        def compressed_grad(params, batch, rngs, err):
+        def explicit_grad(params, batch, rngs, err):
             args = [params, batch["input_ids"], batch["labels"]]
             if with_rng:
                 args.append(rngs["dropout"])
@@ -438,8 +480,10 @@ def make_train_step(
     def one_grad(params, batch, rngs=None, err=None):
         """→ ``(loss, grads, new_err)``; ``err`` passes through untouched
         on the uncompressed paths (None stays None)."""
-        if compressed_grad is not None:
-            return compressed_grad(params, batch, rngs, err)
+        if explicit_grad is not None and (
+                compression is not None
+                or _tp_rings_engage(pm, mesh, batch)):
+            return explicit_grad(params, batch, rngs, err)
         if grad_fn is not None:
             loss, g = grad_fn(params, batch)
             return loss, g, err

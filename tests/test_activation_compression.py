@@ -293,6 +293,10 @@ def test_reduced_sync_and_int8_forward_finite_tp4(fam):
     else:
         from neuronx_distributed_tpu.models.mixtral import (
             MixtralForCausalLM as Model, tiny_moe_config as tiny_config)
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.trainer.trainer import _spec_tree
+
     ps.destroy_model_parallel()
     mesh = _tp_mesh(4)
     ids = jax.random.randint(jax.random.key(0), (2, 16), 0, 256)
@@ -302,17 +306,21 @@ def test_reduced_sync_and_int8_forward_finite_tp4(fam):
                            num_layers=4, scan_layers=False, **kw)
         model = Model(mcfg)
 
-        # init inside the shard_map so each rank builds its own local
-        # shards (mixtral's expert specs name the ep axis, which a
-        # tp-only mesh does not carry — replicated entry sidesteps it)
-        def fwd(i):
-            params = model.init(jax.random.key(1), i)
+        # init outside the bound axis (flax's Partitioned.unbox constrains
+        # a sharding, which a Manual mesh refuses) and hand each rank its
+        # shards; mixtral's expert specs name the ep axis, which a tp-only
+        # mesh does not carry: _spec_tree replicates over it
+        boxed = model.init(jax.random.key(1), ids)
+        specs = _spec_tree(boxed)
+
+        def fwd(params, i):
             out = model.apply(params, i)
             return out[0] if isinstance(out, tuple) else out
 
         return jax.jit(ps.shard_map(
-            fwd, mesh, in_specs=P(),
-            out_specs=P(None, None, "tp"), check_vma=False))(ids)
+            fwd, mesh, in_specs=(specs, P()),
+            out_specs=P(None, None, "tp"), check_vma=False))(
+                meta.unbox(boxed), ids)
 
     ref = np.asarray(run())
     got = np.asarray(run(activation_comm_dtype="int8",

@@ -1,14 +1,21 @@
 """Decomposed collective-matmul tests (docs/tp_overlap.md).
 
 The contract under test: the ppermute-ring decomposition is **bit-exact in
-fp32** against the monolithic collective+matmul — forward AND backward, at
-every supported tp size, uni- and bidirectional — because it reproduces the
-collective's accumulation order instead of approximating it. Non-tileable
+fp32** against the ascending-rank sum of the per-block partial products — at
+every supported tp size, uni- and bidirectional — because it buffers by
+source rank and adds in that order. The monolithic collective multiplies the
+whole sequence at once, and a backend's product of a row block need not be
+the rows of the whole product to the last bit (the CPU backend's is not for
+blocks of 2 rows), nor does ``psum`` promise an order: it is held to 2 ulp
+forward and to rounding in the gradients; the gather forms move data only
+and stay bit-exact. Non-tileable
 shapes silently fall back to the monolithic path (never an error), the
 ``overlap_comm`` knob resolves statically from shapes (no recompiles), and
 the sequence-parallel mappings fail with named shapes when a sequence
 cannot tile.
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -34,6 +41,37 @@ def _assert_trees_equal(a, b):
     for x, y in zip(jax.tree_util.tree_leaves(a),
                     jax.tree_util.tree_leaves(b)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _ordered_all_reduce(x, w, tp):
+    """The sum the ring promises: every rank's partial product, computed a
+    destination's block of rows at a time as the ring does, gathered by
+    source rank and added in ascending order."""
+    l = x.shape[1] // tp
+    y = jnp.concatenate(
+        [jax.lax.dynamic_slice_in_dim(x, j * l, l, axis=1) @ w
+         for j in range(tp)], axis=1)
+    parts = jax.lax.all_gather(y, "tp")
+    acc = parts[0]
+    for r in range(1, tp):
+        acc = acc + parts[r]
+    return acc
+
+
+def _assert_ring_matches_psum(ring, mono):
+    """``(y, grads)`` of the ring against the monolithic collective: the
+    forward sum to 2 ulp of its largest value (the whole product's rows and
+    ``psum``'s order are the backend's own, and a sum that cancels keeps
+    its terms' rounding), the gradients, which see that last bit through
+    ``cos(y)``, to rounding."""
+    y, y_mono = np.asarray(ring[0]), np.asarray(mono[0])
+    np.testing.assert_allclose(
+        y, y_mono, rtol=0,
+        atol=2 * np.finfo(np.float32).eps * np.abs(y_mono).max())
+    for g, h in zip(jax.tree_util.tree_leaves(ring[1:]),
+                    jax.tree_util.tree_leaves(mono[1:])):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(h), rtol=1e-5,
+                                   atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
@@ -72,8 +110,8 @@ def test_all_gather_matmul_bit_exact_fwd_bwd(tp):
 @pytest.mark.parametrize("tp", [2, 4, 8])
 def test_matmul_reduce_scatter_bit_exact_fwd_bwd(tp):
     """SP-exit row linear: reduce_scatter(x @ w, seq) — the buffered
-    ascending-rank sum reproduces psum_scatter's accumulation order, so
-    fp32 equality is exact, not approximate."""
+    ascending-rank sum is exact against the ordered sum of the per-block
+    partials, and within 2 ulp of ``psum_scatter`` of the whole product."""
     mesh = _tp_mesh(tp)
     rng = np.random.RandomState(1)
     x = jnp.asarray(rng.randn(2, 16, 4 * tp).astype(np.float32))
@@ -95,7 +133,17 @@ def test_matmul_reduce_scatter_bit_exact_fwd_bwd(tp):
             ((P(None, "tp", None)),
              (P(None, None, "tp"), P("tp", None))))(x, w)
 
-    _assert_trees_equal(run("decomposed"), run("monolithic"))
+    def ordered(xl, wl):
+        y = _ordered_all_reduce(xl, wl, tp)
+        l = y.shape[1] // tp
+        return jax.lax.dynamic_slice_in_dim(
+            y, jax.lax.axis_index("tp") * l, l, axis=1)
+
+    ring = run("decomposed")
+    _assert_trees_equal(ring[0], _jit_shard(
+        ordered, mesh, (P(None, None, "tp"), P("tp", None)),
+        P(None, "tp", None))(x, w))
+    _assert_ring_matches_psum(ring, run("monolithic"))
 
 
 @pytest.mark.parametrize("op", ["matmul_all_reduce", "copy_matmul"])
@@ -139,7 +187,60 @@ def test_plain_tp_ops_bit_exact_fwd_bwd(op):
             return y, dx, dw
         return out
 
-    _assert_trees_equal(run("decomposed"), run("monolithic"))
+    if op == "copy_matmul":
+        _assert_trees_equal(run("decomposed"), run("monolithic"))
+        return
+    ring = run("decomposed")
+    _assert_trees_equal(ring[0], _jit_shard(
+        lambda xl, wl: _ordered_all_reduce(xl, wl, tp), mesh, in_specs,
+        y_spec)(x, w))
+    _assert_ring_matches_psum(ring, run("monolithic"))
+
+
+@pytest.mark.parametrize("bidi", [False, None], ids=["uni", "auto"])
+@pytest.mark.parametrize("tp,rows", [(2, 8), (4, 16), (4, 12), (8, 16)])
+def test_forwarding_ring_below_32_bits(tp, rows, bidi):
+    """bf16 on a full-precision wire has no order to keep: the ring adds
+    as it forwards, over neighbour hops alone and in two half-block streams
+    where the axis is even and at least 4 (``rows`` 12 at tp=4: blocks of 3
+    rows do not halve, one stream). Forward and both gradients equal the
+    monolithic collective's to bf16 rounding."""
+    mesh = _tp_mesh(tp)
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(2, rows, 4 * tp), jnp.bfloat16)
+    w = jnp.asarray(rng.randn(4 * tp, 6), jnp.bfloat16)
+    assert cm._sums_in_transit(x.dtype, None)
+    assert not cm._sums_in_transit(jnp.float32, None)
+
+    def run(fn, impl, y_spec):
+        def f(xl, wl):
+            def loss(xv, wv):
+                y = fn(xv, wv, "tp", 1, impl=impl, bidirectional=bidi)
+                return jnp.sum(jnp.sin(y.astype(jnp.float32))), y
+
+            (_, y), grads = jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True)(xl, wl)
+            return y, grads
+
+        specs = (P(None, None, "tp"), P("tp", None))
+        text = jax.jit(ps.shard_map(f, mesh, in_specs=specs, out_specs=(
+            y_spec, specs))).lower(x, w).as_text()
+        return _jit_shard(f, mesh, specs, (y_spec, specs))(x, w), text
+
+    for fn, y_spec in ((cm.matmul_reduce_scatter, P(None, "tp", None)),
+                       (cm.matmul_all_reduce, P(None, None, None))):
+        ring, text = run(fn, "decomposed", y_spec)
+        mono, _ = run(fn, "monolithic", y_spec)
+        # every hop goes to a neighbour
+        pairs = set(re.findall(r"source_target_pairs = dense<\[\[(\d+), "
+                               r"(\d+)\]", text))
+        assert pairs and all((int(b) - int(a)) % tp in (1, tp - 1)
+                             for a, b in pairs), pairs
+        for g, h in zip(jax.tree_util.tree_leaves(ring),
+                        jax.tree_util.tree_leaves(mono)):
+            np.testing.assert_allclose(
+                np.asarray(g, np.float32), np.asarray(h, np.float32),
+                rtol=0, atol=0.03 * np.abs(np.asarray(h, np.float32)).max())
 
 
 @pytest.mark.parametrize("bidi", [False, True])

@@ -417,6 +417,20 @@ def test_compressed_explicit_path_fp32_matches_gspmd():
     assert st.comm_error is None  # fp32 carries no residue buffers
 
 
+def test_explicit_path_identity_quantizer_matches_gspmd_in_two_steps():
+    """The slow case above in two steps, so that the fast gate runs the
+    gradient function the compressed and the bound default step share
+    (``make_train_step``'s ``inner``): tp=2 x dp=4, a hierarchical fp32
+    reduce on the data axes against GSPMD's own."""
+    losses_ref, _, _ = _train(nxd.OptimizerConfig(), None, steps=2)
+    oc = nxd.OptimizerConfig(grad_comm_dtype="fp32",
+                             grad_comm_hierarchical=True)
+    comp = cc.from_config(type("C", (), {"optimizer": oc}))
+    losses_h, metrics, st = _train(oc, comp, steps=2)
+    np.testing.assert_allclose(losses_h, losses_ref, rtol=1e-4)
+    assert st.comm_error is None and "grad_comm_ratio" in metrics
+
+
 def test_make_train_step_compression_rejects_custom_grad_fn():
     from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
                                                       tiny_config)

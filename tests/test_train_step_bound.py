@@ -1,0 +1,133 @@
+"""The default train step binds its mesh when the tp rings would engage.
+
+``make_train_step`` with the default loss computes loss and gradients
+inside ``shard_map`` when the tp axis has ``cm.MIN_AUTO_AXIS_SIZE`` ranks
+and the batch's sequence tiles over it (``trainer._tp_rings_engage``), so
+the projections take their decomposed collective-matmuls; below that it is
+the GSPMD step, text for text. Tiny llama, float32, the 8-device CPU mesh
+(tp=4 x dp=2), full remat and ZeRO-1 as the train cell has them.
+"""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import neuronx_distributed_tpu as nxd
+from neuronx_distributed_tpu import obs
+from neuronx_distributed_tpu.models.llama import LlamaForCausalLM, tiny_config
+from neuronx_distributed_tpu.parallel import mesh as ps
+from neuronx_distributed_tpu.trainer import (initialize_parallel_model,
+                                             initialize_parallel_optimizer,
+                                             make_train_step)
+from neuronx_distributed_tpu.trainer import trainer as tr
+
+
+def _step_and_state(tp=4, seq=16, **cfg_kw):
+    ps.destroy_model_parallel()
+    cfg = nxd.neuronx_distributed_config(
+        tensor_parallel_size=tp,
+        optimizer_config=nxd.OptimizerConfig(zero_one_enabled=True),
+        activation_checkpoint_config=nxd.ActivationCheckpointConfig(
+            mode="full"), **cfg_kw)
+    # four K/V heads, one a rank as in the train cell (8 over tp=4): fewer
+    # heads than ranks replicate them, and a replicated head's q/k/v entry
+    # keeps its monolithic all-reduce
+    mcfg = nxd.configure_model(
+        cfg, tiny_config(param_dtype=jnp.float32, num_kv_heads=4))
+    # configure_model sets the config's compute dtype (bf16): float32 here
+    mcfg = dataclasses.replace(mcfg, dtype=jnp.float32)
+    model = LlamaForCausalLM(mcfg)
+    ids = jax.random.randint(jax.random.key(0), (4, seq + 1), 0,
+                             mcfg.vocab_size)
+    batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+    pm, params = initialize_parallel_model(cfg, model, jax.random.key(1),
+                                           batch["input_ids"])
+    tx, state, sh = initialize_parallel_optimizer(pm, params,
+                                                  learning_rate=1e-2)
+    return make_train_step(pm, tx, sh, donate=False), state, batch
+
+
+def _gspmd(monkeypatch):
+    """The step as it was: the rule says no, whatever the mesh."""
+    monkeypatch.setattr(tr, "_tp_rings_engage", lambda *a: False)
+
+
+def _run(step, state, batch, steps=3):
+    """Losses and, a step, the optimizer's moments: AdamW's ``mu`` is
+    linear in the gradients, so every leaf of it is every gradient leaf."""
+    losses, moments = [], []
+    for _ in range(steps):
+        state, metrics = step(state, batch)
+        losses.append(float(metrics["loss"]))
+        moments.append([np.asarray(m) for m in jax.tree_util.tree_leaves(
+            state.opt_state) if getattr(m, "ndim", 0) > 0])
+    return losses, moments
+
+
+def _scan_body_primitives(jaxpr, inside=False, out=None):
+    """Names of the primitives inside the ``scan`` bodies of a jaxpr: the
+    layer scan's forward and its backward."""
+    out = set() if out is None else out
+    for eqn in jaxpr.eqns:
+        here = inside or eqn.primitive.name == "scan"
+        if inside:
+            out.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _scan_body_primitives(sub, here, out)
+    return out
+
+
+def test_default_step_at_tp4_is_the_bound_step_and_equals_gspmd(monkeypatch):
+    step, state, batch = _step_and_state()
+    names = _scan_body_primitives(jax.make_jaxpr(step)(state, batch).jaxpr)
+    assert "ppermute" in names and "psum" not in names, sorted(names)
+    assert "collective_permute" in step.lower(state, batch).as_text()
+    bound = _run(step, state, batch)
+
+    _gspmd(monkeypatch)
+    step, state, batch = _step_and_state()
+    assert "collective_permute" not in step.lower(state, batch).as_text()
+    gspmd = _run(step, state, batch)
+    np.testing.assert_allclose(bound[0], gspmd[0], rtol=1e-5)
+    for got, want in zip(bound[1], gspmd[1]):
+        assert len(got) == len(want) and got
+        for g, w in zip(got, want):
+            # a leaf's small elements are sums that cancel: they keep the
+            # rounding of its large ones
+            np.testing.assert_allclose(g, w, rtol=1e-5,
+                                       atol=5e-5 * np.abs(w).max())
+
+
+@pytest.mark.parametrize("kw", [
+    dict(tp=2), dict(tp=4, seq=18), dict(tp=4, tp_overlap_comm=False)],
+    ids=["tp2", "sequence_does_not_tile", "overlap_off"])
+def test_below_the_rule_the_step_is_the_gspmd_step(monkeypatch, kw):
+    step, state, batch = _step_and_state(**kw)
+    text = step.lower(state, batch).as_text()
+    _gspmd(monkeypatch)
+    step, state, batch = _step_and_state(**kw)
+    assert text == step.lower(state, batch).as_text()
+    assert "collective_permute" not in text
+
+
+def test_the_bound_step_counts_its_ring_decisions():
+    obs.reset()
+    obs.enable()
+    try:
+        step, state, batch = _step_and_state()
+        step.lower(state, batch)
+        counter = obs.get_registry().get("nxd_tp_collective_matmuls_total")
+        seen = {(c.labels["impl"], c.labels["op"]): c.value
+                for c in counter.children()}
+    finally:
+        obs.disable()
+        obs.reset()
+    # o_proj and down leave through matmul_all_reduce, q/k/v and gate/up
+    # enter through copy_matmul; the layer scan's body is one site each
+    assert set(seen) == {("decomposed", "matmul_all_reduce"),
+                         ("decomposed", "copy_matmul")}, seen
+    assert all(v >= 2 for v in seen.values()), seen
