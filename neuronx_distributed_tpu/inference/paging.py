@@ -210,6 +210,15 @@ KV_BYTES_HELD = CounterFamily(
     "pools are unlike in bytes where their K/V heads or their K and V "
     "rows are.",
     ("full_k", "full_v", "window_k", "window_v"))
+STATE_BYTES_HELD = CounterFamily(
+    "nxd_state_bytes_held_total",
+    "Bytes the occupied slots hold, a step, by what holds them: state, the "
+    "slots' entries in the per-slot leaves that a layer's recurrence "
+    "carries (a state-space layer's, a delta-rule layer's: the same bytes "
+    "a slot whatever its context), tail, their entries in the convolution "
+    "tails, or kv, the K and V of the blocks they have mapped in the "
+    "attention layers' pool (they grow with the context).",
+    ("state", "tail", "kv"))
 STEP_ROWS_BY_CONTEXT = CounterFamily(
     "nxd_step_rows_by_context_total",
     "Real rows of the serving workers' packed steps by the row's "
@@ -547,6 +556,16 @@ class StateLeaf:
     lead: Tuple[int, ...]
     trail: Tuple[int, ...]
     dtype: Any = None
+    #: the kind of ``nxd_state_bytes_held_total`` its bytes count under:
+    #: ``state`` (what a layer's recurrence carries) or ``tail`` (a
+    #: convolution's last inputs)
+    counted_as: str = "state"
+
+    def slot_bytes(self, itemsize: int) -> int:
+        """Bytes of one slot's entry, over every layer that has one, where
+        a pool's element has ``itemsize``."""
+        return int(np.prod(self.lead + self.trail, dtype=np.int64)) * (
+            jnp.dtype(self.dtype).itemsize if self.dtype else itemsize)
 
 
 def init_state_leaves(leaves: Sequence[StateLeaf], table_rows: int,
@@ -694,6 +713,29 @@ class StatePoolCache(FullCache):
     pack: int = 1
     leaves: Tuple[StateLeaf, ...] = ()
     name = "state_pool"
+    #: as :attr:`LatentCache.moe_leaf`; its device may hold a share of the
+    #: experts
+    moe_leaf = MOE_KEPT_DROPPED_ELSEWHERE
+
+    @property
+    def counters(self) -> Tuple[CounterFamily, ...]:
+        return super().counters + (STATE_BYTES_HELD,)
+
+    def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
+                   held: Sequence[int], rolled: int) -> Dict[str, Any]:
+        """As :meth:`FullCache.count_step`, and the bytes the occupied
+        slots hold: their entries in the leaves, by what each leaf is
+        counted as, and their mapped blocks over the pool's layers."""
+        counts = super().count_step(geo, positions, slot_ids, tables, held,
+                                    rolled)
+        a_slot = [sum(leaf.slot_bytes(geo.itemsize) for leaf in self.leaves
+                      if leaf.counted_as == kind)
+                  for kind in ("state", "tail")]
+        counts[STATE_BYTES_HELD.name] = (
+            len(held) * a_slot[0], len(held) * a_slot[1],
+            self.pool_layers * sum(held) * geo.block_size * geo.row_values
+            * geo.itemsize)
+        return counts
 
     def init_cache(self, model_cfg, *, num_blocks: int, block_size: int,
                    table_rows: int, max_blocks_per_seq: int, dtype: Any,
@@ -710,6 +752,8 @@ class StatePoolCache(FullCache):
         return StatePoolPagedCache(
             k=jnp.zeros(pool, dtype), v=jnp.zeros(pool, dtype),
             states=init_state_leaves(self.leaves, table_rows, dtype),
+            moe_counts=(jnp.zeros((self.moe_leaf.entries,), jnp.int32)
+                        if model_cfg.serving_family().moe_counts else None),
             pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
             block_tables=jnp.full((table_rows, max_blocks_per_seq), -1,
                                   jnp.int32),
@@ -961,12 +1005,15 @@ class StatePoolPagedCache(_BlockPool, struct.PyTreeNode):
     """The cache of :class:`StatePoolCache`. ``k``/``v`` ``[La,
     num_blocks, block_size, KV / pack, D * pack]`` over the ``La``
     attention layers; ``states`` the family's per-slot leaves by name
-    (:class:`StateLeaf`: ``lead + (table rows,) + trail`` each); ``pos``,
-    ``block_tables`` and ``lengths`` as :class:`PagedKVCache`."""
+    (:class:`StateLeaf`: ``lead + (table rows,) + trail`` each);
+    ``moe_counts [3]`` where the family declares it
+    (:class:`ServingFamily`; else None); ``pos``, ``block_tables`` and
+    ``lengths`` as :class:`PagedKVCache`."""
 
     k: jax.Array
     v: jax.Array
     states: Dict[str, jax.Array]
+    moe_counts: Optional[jax.Array]
     pos: jax.Array
     block_tables: jax.Array
     lengths: jax.Array
@@ -1128,11 +1175,12 @@ class StateLayerView(struct.PyTreeNode):
 
 
 class StateSpaceLayerView(struct.PyTreeNode):
-    """What a state-space (Mamba-2) layer is handed: its two per-slot
-    state leaves' stacks (the layer scan's carry: ``ssm [L, J, d_state,
-    d_inner]`` float32 and the convolution's tails ``conv [L, d_conv - 1,
-    J, channels]``), the layer's index in them, and the step's rows by
-    slot (:class:`..ops.ssd.StepSegments`, built once a step)."""
+    """What a state-space (Mamba-2) or a delta-rule (KDA) layer is
+    handed: its two per-slot state leaves' stacks (the layer scan's carry:
+    ``ssm`` the recurrence's float32 states, ``[L, J, d_state, d_inner]``
+    or ``[L, J, H, dk, dv]``, and the convolution's tails ``conv [L,
+    d_conv - 1, J, channels]``), the layer's index in them, and the step's
+    rows by slot (:class:`..ops.ssd.StepSegments`, built once a step)."""
 
     ssm: jax.Array
     conv: jax.Array

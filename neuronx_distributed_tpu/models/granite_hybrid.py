@@ -42,7 +42,7 @@ from ..obs.device_scopes import device_scope
 from ..ops import ssd
 from ..parallel import layers as pl
 from ..parallel import loss_functions as lf
-from .llama import LlamaConfig, _ScanBody, run_layers
+from .llama import LlamaConfig, _ScanBody, run_layers, runs_of
 
 #: a published layer type -> the layer's ``attention_kind``
 LAYER_KINDS = {"mamba": "mamba2", "attention": "full"}
@@ -128,14 +128,7 @@ class GraniteHybridConfig(LlamaConfig):
     def runs(self) -> Tuple[Tuple[str, int, int], ...]:
         """``(kind, first, count)`` of each run of like layers, ``first``
         the run's first index in its kind's stack."""
-        out, seen = [], dict.fromkeys(CARRIED, 0)
-        for kind in self.kinds():
-            if out and out[-1][0] == kind:
-                out[-1][2] += 1
-            else:
-                out.append([kind, seen[kind], 1])
-            seen[kind] += 1
-        return tuple(tuple(r) for r in out)
+        return runs_of(self.kinds())
 
     def serving_family(self):
         from ..inference.paging import (ServingFamily, StateLeaf,
@@ -151,7 +144,7 @@ class GraniteHybridConfig(LlamaConfig):
                               (self.mamba_d_state, self.d_inner),
                               jnp.float32),
                     StateLeaf("conv", (layers, self.mamba_d_conv - 1),
-                              (self.conv_channels,)))),
+                              (self.conv_channels,), counted_as="tail"))),
             unsupported={
                 "prefix_sharing": "a mamba layer's state and convolution "
                 "tail are no blocks: a shared prefix's blocks carry "

@@ -967,6 +967,20 @@ class LlamaDecoderLayer(nn.Module):
         return x, aux, new_cache
 
 
+def runs_of(kinds):
+    """``(kind, first, count)`` of each run of like layers in ``kinds`` (a
+    kind a layer), ``first`` the run's first index in its kind's stack:
+    what a config's ``runs()`` hands :func:`run_layers`."""
+    out, seen = [], {}
+    for kind in kinds:
+        if out and out[-1][0] == kind:
+            out[-1][2] += 1
+        else:
+            out.append([kind, seen.get(kind, 0), 1])
+        seen[kind] = seen.get(kind, 0) + 1
+    return tuple(tuple(r) for r in out)
+
+
 def run_layers(cfg, stacks, x, cos, sin, carried, carry=None, view_of=None,
                merge=None, valid=None, positions=None):
     """The layer pattern of a model whose layers differ in kind: one

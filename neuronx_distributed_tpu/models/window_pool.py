@@ -26,7 +26,7 @@ from ..modules.norms import RMSNorm
 from ..obs.device_scopes import device_scope
 from ..parallel import layers as pl
 from ..parallel import loss_functions as lf
-from .llama import _ScanBody, run_layers
+from .llama import _ScanBody, run_layers, runs_of
 
 #: the pool a layer's attention reads and writes, by attention type
 POOL = {"full": ("k", "v"), "sliding": ("wk", "wv")}
@@ -58,14 +58,7 @@ class WindowPoolPattern:
     def runs(self) -> Tuple[Tuple[str, int, int], ...]:
         """``(kind, first, count)`` of each run of like layers, ``first``
         the run's first index in its kind's stack."""
-        out, seen = [], {}
-        for kind in self.kinds():
-            if out and out[-1][0] == kind:
-                out[-1][2] += 1
-            else:
-                out.append([kind, seen.get(kind, 0), 1])
-            seen[kind] = seen.get(kind, 0) + 1
-        return tuple(tuple(r) for r in out)
+        return runs_of(self.kinds())
 
     def carried(self):
         """What of the cache's stacks a layer of each kind reads and

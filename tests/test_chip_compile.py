@@ -1341,6 +1341,130 @@ def test_wide_key_window_pool_step_at_the_published_widths(chip, topo,
     assert sum(d[3] for d in top) <= 0.02 * total, top
 
 
+# -- the state-pool cache with held experts (Solar-Open2-250B): chip 0 of 16 ----
+# The cell solar-open2-250b.serve-reasoning: 128 rows, 128 slots; 6 KDA layers
+# over float32 states [6, 128, 64, 128, 128] (4 MiB a slot a layer) and
+# tails [6, 3, 128, 24576], 2 gated NoPE GQA layers of 64 heads over a pool
+# of 8 K/V heads of 128; 20 of 320 experts held beside a shared expert, an
+# eighth of the vocabulary.
+
+def test_kda_state_update(chip):
+    """The delta rule's kernel at the published widths: a slot's state by
+    tiles of eight heads, 128 rows against 128 slots."""
+    from neuronx_distributed_tpu.ops import kda, ssd
+
+    t, h, d, layers, slots = 128, 64, 128, 6, 128
+
+    def fn(dt, kt, qt, bv, bb, state, layer, slot_ids, positions):
+        seg = ssd.step_segments(slot_ids, positions, slots)
+        return kda._kda_update_pallas(dt, kt, qt, bv, bb, state, layer, seg,
+                                      interpret=False)
+
+    by_column, by_row = chip((h, d, t), jnp.float32), chip((t, h * d),
+                                                           jnp.float32)
+    compiled = jax.jit(fn, donate_argnums=(5,)).lower(
+        by_column, by_column, by_column, by_row, by_row,
+        chip((layers, slots, h, d, d), jnp.float32), chip((), jnp.int32),
+        chip((t,), jnp.int32), chip((t,), jnp.int32)).compile()
+    assert _kernel_instruction_names(compiled.as_text()) == {
+        "kda_state_update"}
+    # the states are written where they lie: nothing the size of a layer's
+    # beside them
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= layers * slots * h * d * d * 4
+    assert mem.temp_size_in_bytes < 8 * 2 ** 20
+
+
+def test_delta_rule_state_pool_step_at_the_published_widths(
+        chip, topo, on_one_chip, monkeypatch):
+    """The packed step of Solar-Open2-250B's configuration file: it
+    compiles for the chip with both kernels in it, holds what the
+    configuration says it holds (``assumed.serve_aot_gib`` is this
+    analysis), leaves no stack copied, hands the pool, the states and the
+    tails back in the buffers they came in, and every fusion with a
+    matmul inside reads the scope of its heaviest one."""
+    import re
+
+    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
+                                                            sample)
+    from neuronx_distributed_tpu.obs.device_scopes import device_scope
+    from neuronx_distributed_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "on_tpu", lambda: True)
+    config, models = _cell_config("solar-open2-250b", None)
+    assert sorted(config["reduced"]) == [
+        "gqa_layers", "n_routed_experts", "num_hidden_layers", "vocab_size"]
+    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
+                                                         models)
+    s = config["serve"]
+    slots, blocks = s["max_slots"], s["num_blocks"]
+    assert cache.k.shape == (2, blocks, 128, 8, 128) == cache.v.shape
+    assert cache.states["kda"].shape == (6, slots, 64, 128, 128)
+    assert cache.states["kda"].dtype == jnp.float32
+    assert cache.states["conv"].shape == (6, 3, slots, 24576)
+    assert cache.moe_counts.shape == (3,)
+    tree = params["params"]["model"]
+    mixer = tree["layers_kda"]["layer"]["attn"]
+    assert mixer["qkv_proj"]["kernel"].shape == (6, 4096, 24576)
+    assert mixer["low_proj"]["kernel"].shape == (6, 4096, 128 + 128 + 64)
+    assert mixer["f_b_proj"]["kernel"].shape == (6, 128, 8192)
+    assert mixer["g_b_proj"]["bias"].shape == (6, 8192)
+    assert mixer["conv_kernel"].shape == (6, 24576, 4)
+    assert mixer["A_log"].shape == (6, 64)
+    assert mixer["dt_bias"].shape == (6, 8192)
+    assert mixer["o_norm"]["scale"].shape == (6, 128)
+    assert mixer["o_proj"]["kernel"].shape == (6, 8192, 4096)
+    gqa = tree["layers_full"]["layer"]
+    assert gqa["attn"]["g_proj"]["kernel"].shape == (2, 4096, 8192)
+    assert gqa["attn"]["k_proj"]["kernel"].shape == (2, 4096, 1024)
+    assert gqa["moe"]["router"]["kernel"].shape == (2, 4096, 320)
+    assert gqa["moe"]["experts"]["down"].shape == (2, 20, 1280, 4096)
+    assert gqa["moe"]["shared"]["down"]["kernel"].shape == (2, 1280, 4096)
+    assert params["params"]["lm_head"]["kernel"].shape == (4096, 24576)
+
+    def step_fn(params, cache, tokens, positions, slot_ids, rng):
+        logits, cache = forward(cfg, params, tokens, positions, cache,
+                                slot_ids=slot_ids)
+        with device_scope("sample"):
+            return sample(logits[0], rng, SamplingConfig()), cache
+
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
+        chip(rng.shape, rng.dtype)).compile()
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"paged_attention",
+                                               "kda_state_update"}
+    gib = 2.0 ** 30
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) / gib
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params)) / gib
+    assert 7.25 < weights < 7.27                     # 3,899M in bfloat16
+    aot = config["assumed"]["serve_aot_gib"]
+    assert abs(weights - aot["weights"]) < 0.01
+    assert abs(mem.temp_size_in_bytes / gib - aot["temporaries"]) < 0.05
+    assert abs(held - aot["total"]) < 0.05, (held, mem)
+    assert held > 0.8 * 15.75                        # the file's share
+    assert mem.temp_size_in_bytes < slots * 64 * 128 * 128 * 4  # a layer's
+
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape in (f"2,{blocks},128,8,128", f"6,{slots},64,128,128",
+                     f"6,3,{slots},24576")]
+    assert len(stacks) == 4 and set(stacks) <= aliased, (stacks, header)
+
+    total, differ, kernels = scope_disagreements(text)
+    assert total > 0 and kernels == {"attn.kernel", "attn.state"}
+    top = [d for d in differ if d[1].split(".")[0] != d[2].split(".")[0]]
+    assert sum(d[3] for d in top) <= 0.02 * total, top
+
+
 # -- the engine's own packed step: one deep in flight -------------------------
 # The CPU tests never donate, so only a compile for the chip shows what the
 # step's operands are there: the pool donated and written in place, the

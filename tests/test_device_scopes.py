@@ -38,16 +38,20 @@ SERVING = {"llama": "tiny-mistral-serve", "mixtral": "tiny-mixtral",
            "glm_moe_lite": "tiny-glm-moe-lite",
            "granite_hybrid": "tiny-granite-hybrid",
            "laguna": "tiny-laguna",
-           "mimo_v2_flash": "tiny-mimo-v2-flash"}
+           "mimo_v2_flash": "tiny-mimo-v2-flash",
+           "solar_open2": "tiny-solar-open2"}
 #: the children a family's step must open, and no other family's may
 OWN = {"attn.select": {"minicpm_sala"},
-       "attn.state": {"minicpm_sala", "granite_hybrid"},
-       "attn.conv": {"granite_hybrid"}, "attn.summarise": {"evabyte"},
+       "attn.state": {"minicpm_sala", "granite_hybrid", "solar_open2"},
+       "attn.conv": {"granite_hybrid", "solar_open2"},
+       "attn.summarise": {"evabyte"},
        "attn.kernel.full": {"laguna", "mimo_v2_flash"},
        "attn.kernel.window": {"laguna", "mimo_v2_flash"},
-       "ffn.experts": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash"},
-       "ffn.router": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash"},
-       "ffn.shared": {"glm_moe_lite", "laguna"}}
+       "ffn.experts": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
+                       "solar_open2"},
+       "ffn.router": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
+                      "solar_open2"},
+       "ffn.shared": {"glm_moe_lite", "laguna", "solar_open2"}}
 #: the operations that carry a step's device time
 HEAVY = ("stablehlo.dot_general", "stablehlo.custom_call",
          "stablehlo.scatter", "stablehlo.gather", "stablehlo.sort",
@@ -353,9 +357,19 @@ LOWERED_AT_PR_45 = {
 }
 
 
+#: and the state-pool family with held experts, as PR 48's tree lowers it
+#: (the delta rule's packed step, ``moe_counts`` beside the states): new
+#: with that PR
+LOWERED_AT_PR_48 = {
+    "solar_open2":
+        "15a36b8ea18473ccbfa4c375e43d71216b895561dcaf3ad429434cb1b8702195",
+}
+
+
 @pytest.mark.parametrize("which", list(LOWERED_AT_PR_38)
                          + list(LOWERED_AT_PR_39) + list(LOWERED_AT_PR_42)
-                         + list(LOWERED_AT_PR_43) + list(LOWERED_AT_PR_45))
+                         + list(LOWERED_AT_PR_43) + list(LOWERED_AT_PR_45)
+                         + list(LOWERED_AT_PR_48))
 def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     """PR 39 changed the latent kernel and its walk alone: the packed
     steps of the five families that run the shared helpers of
@@ -371,12 +385,15 @@ def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     the paged kernel keys wider than the values and a sink operand, each
     off where a family does not ask for it, and moved the window-pool
     family's pattern and forward to ``models/window_pool.py``: the seven
-    are still the text they were, and the new family's is recorded. A PR
-    that changes one of these programs on purpose records its new hash
-    here."""
+    are still the text they were, and the new family's is recorded.
+    PR 48 gave the state-pool kind a ``moe_counts`` leaf, built only where
+    a family declares it, and a counter of held bytes that the host
+    counts: Granite's step, Laguna's and MiMo's are the text they were,
+    and the delta-rule family's is recorded. A PR that changes one of
+    these programs on purpose records its new hash here."""
     import hashlib
 
     text = _stripped(_lowered(which))
     assert hashlib.sha256(text.encode()).hexdigest() == {
         **LOWERED_AT_PR_38, **LOWERED_AT_PR_39, **LOWERED_AT_PR_42,
-        **LOWERED_AT_PR_43, **LOWERED_AT_PR_45}[which]
+        **LOWERED_AT_PR_43, **LOWERED_AT_PR_45, **LOWERED_AT_PR_48}[which]

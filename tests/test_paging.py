@@ -909,6 +909,7 @@ _PAGED = {"nxd_paged_columns_total": ("skipped", "live"),
 _STATES = {"nxd_state_resets_total": (),
            "nxd_state_slot_steps_total": ("advanced", "held")}
 _MOE = {"nxd_moe_assignments_total": ("kept", "dropped")}
+_HELD = {"nxd_state_bytes_held_total": ("state", "tail", "kv")}
 DECLARED = {
     "llama": _PAGED,
     "mixtral": _PAGED,
@@ -928,7 +929,7 @@ DECLARED = {
         "nxd_paged_block_visits_total": ("fetched", "shared"),
         "nxd_mla_block_fetches_total": ("in_run", "alone", "whole"),
         **_MOE},
-    "granite_hybrid": {**_PAGED, **_STATES},
+    "granite_hybrid": {**_PAGED, **_STATES, **_HELD},
     "laguna": {
         **_PAGED,
         "nxd_window_columns_total": ("live", "behind"),
@@ -941,9 +942,14 @@ DECLARED = {
 }
 #: the second window-pool family declares what the first does
 DECLARED["mimo_v2"] = DECLARED["laguna"]
+#: the state-pool family with held experts: the first's, and the routed
+#: assignments its step counts into the kind's ``moe_counts``
+DECLARED["solar_open2"] = {**DECLARED["granite_hybrid"], **_MOE,
+                           "nxd_moe_held_total": ("held", "elsewhere")}
 #: the leaves a family's step counts into on the device, and their lengths
 ON_DEVICE = {"minicpm_sala": {"counts": 10}, "glm_moe_lite": {"moe_counts": 2},
-             "laguna": {"moe_counts": 3}, "mimo_v2": {"moe_counts": 3}}
+             "laguna": {"moe_counts": 3}, "mimo_v2": {"moe_counts": 3},
+             "solar_open2": {"moe_counts": 3}}
 
 
 def _tiny_family(which):
@@ -979,7 +985,7 @@ def test_a_family_declares_its_steps_counters(which):
         # every entry of the leaf is read, each kind from entries of its own
         used = [i for _, entries in leaf.reads for e in entries for i in e]
         assert set(used) == set(range(leaf.entries))
-    if which in ("laguna", "mimo_v2"):
+    if which in ("laguna", "mimo_v2", "solar_open2"):
         assert leaves[0].read(np.array([5, 2, 4])) == {
             "nxd_moe_assignments_total": [5, 2],
             "nxd_moe_held_total": [7, 4]}
@@ -1005,6 +1011,15 @@ def test_a_kind_counts_a_step_under_the_names_it_declares(which):
     if "nxd_state_slot_steps_total" in counts:
         assert list(counts["nxd_state_slot_steps_total"]) == [1, 0]
         assert list(counts["nxd_state_resets_total"]) == [1]
+    if "nxd_state_bytes_held_total" in counts:
+        # one occupied slot's leaves, by what each is counted as, and its
+        # one block of 8 positions over the pool's layers
+        state, tail = (sum(leaf.slot_bytes(4) for leaf in kind.leaves
+                           if leaf.counted_as == name)
+                       for name in ("state", "tail"))
+        assert state > 0 < tail
+        assert list(counts["nxd_state_bytes_held_total"]) == [
+            state, tail, kind.pool_layers * 8 * 2 * 2 * 16 * 4]
 
 
 def test_the_benchmarks_counters_are_declared_and_documented():
@@ -1042,6 +1057,44 @@ def test_the_benchmarks_counters_are_declared_and_documented():
         catalog = {line.split("`")[1] for line in f
                    if line.startswith("| `nxd_")}
     assert by_families <= catalog
+
+
+def test_a_state_pool_has_the_routed_counts_only_where_declared():
+    """``StatePoolCache`` lays out ``moe_counts [kept, dropped,
+    elsewhere]`` for a family that declares it and builds no such leaf
+    for one that does not: Granite's cache is the leaves it was, to the
+    byte, and the bytes an occupied slot holds are counted for both by
+    what each leaf is."""
+    from neuronx_distributed_tpu.inference import paging
+    from neuronx_distributed_tpu.models import granite_hybrid, solar_open2
+
+    def cache_of(cfg):
+        return paging.init_serving_cache(
+            cfg, num_blocks=6, block_size=8, table_rows=3,
+            max_blocks_per_seq=4, dtype=jnp.bfloat16)
+
+    granite, solar = granite_hybrid.tiny_config(), solar_open2.tiny_config()
+    assert paging.StatePoolCache.moe_leaf is paging.MOE_KEPT_DROPPED_ELSEWHERE
+    plain, counted = cache_of(granite), cache_of(solar)
+    assert plain.moe_counts is None and not granite.serving_family(
+    ).moe_counts
+    assert sorted(jax.tree_util.keystr(path) for path, _ in
+                  jax.tree_util.tree_flatten_with_path(plain)[0]) == [
+        ".block_tables", ".k", ".lengths", ".pos", ".states['conv']",
+        ".states['ssm']", ".v"]
+    assert sum(x.nbytes for x in jax.tree_util.tree_leaves(plain)) == (
+        2 * 2 * 6 * 8 * 1 * 32 * 2            # K and V, two heads a row
+        + 3 * 3 * 8 * 64 * 4 + 3 * 3 * 3 * 80 * 2     # ssm, conv
+        + 6 * 8 * 4 + 3 * 4 * 4 + 3 * 4)      # pos, tables, lengths
+    assert counted.moe_counts.shape == (3,)
+    assert counted.moe_counts.dtype == jnp.int32
+    kinds = {leaf.name: leaf.counted_as for cfg in (granite, solar)
+             for leaf in cfg.serving_family().cache_kind.leaves}
+    assert kinds == {"ssm": "state", "kda": "state", "conv": "tail"}
+    (leaf,) = [leaf for leaf in solar.serving_family().cache_kind.leaves
+               if leaf.name == "kda"]
+    assert leaf.slot_bytes(2) == 5 * 2 * 128 * 128 * 4      # float32 stays
+    assert paging.StateLeaf("x", (2,), (3,)).slot_bytes(2) == 12
 
 
 # ---------------------------------------------------------------------------
