@@ -31,11 +31,22 @@ where a matmul carries one):
   in a device trace) walks the segments by scalar-prefetched slot ids,
   tiles a slot's ``[H, dk, dv]`` state by heads, reads a tile once,
   applies the segment's rows one after another with the head's state in
-  registers, and writes it back in place; elsewhere the same walk is a
-  gather of the segments' states, a ``lax.scan`` over the rows and a
-  scatter, as :mod:`.ssd` has it. A slot whose segment starts at position
-  0 starts from zero inside the step; pad rows and the slots without rows
-  are not touched.
+  registers, and writes it back in place. A row is a latency chain (three
+  masked lane reductions on the XLU, the decay, a tree of adds, the rank-1
+  add, the read-out) that keeps a third of any unit busy, and a loop of a
+  dynamic trip count is a wall the scheduler moves no work across. So the
+  one row is iterated two ways, by the segment's row count: a segment of
+  one row (every decode row) runs the tile's heads as straight-line code,
+  head ``h + 1``'s loads and reductions issuing under head ``h``'s chain
+  (117 static bundles a (row, head) where a one-trip loop a head took 179,
+  and the grid step falls under its own state's DMA); a segment of
+  several (a prefill chunk) takes ``UNROLL`` rows a loop body, the next
+  rows' reductions under the current row's chain (125 where it took
+  162), and the ``rows % UNROLL`` tail a row a trip. Elsewhere the same
+  walk is a gather of the segments' states, a ``lax.scan`` over the rows
+  and a scatter, as :mod:`.ssd` has it. A slot whose segment starts at
+  position 0 starts from zero inside the step; pad rows and the slots
+  without rows are not touched.
 
 The state of the packed form: ``kda [L, J, H, dk, dv]`` float32, a slot's
 head one ``[dk, dv]`` tile with ``dv`` on lanes: the decay, the key and
@@ -45,6 +56,8 @@ reduce no lane, and ``v`` and the output are lane vectors.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +71,9 @@ _HI = jax.lax.Precision.HIGHEST
 #: heads of a slot's state a kernel step holds at a time (``[HEADS, dk,
 #: dv]`` float32: 512 KiB at 128 x 128, in and out and double-buffered)
 HEADS = 8
+
+#: rows of a prefill chunk that one loop body applies, one after another
+UNROLL = 4
 
 
 def kda_step(state: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
@@ -166,38 +182,57 @@ def _kda_kernel(layer_ref, count_ref, slot_ref, start_ref, rows_ref,
         column = jax.lax.broadcasted_iota(jnp.int32, (dk, steps), 1)
         sublane = jax.lax.broadcasted_iota(jnp.int32, (8, dv), 0)
 
-        for h in range(heads):
+        def row(r, s, h):
+            t = start + r
+            mine = column == t
             lanes = pl.ds(h * dv, dv)
 
-            def row(r, s, h=h, lanes=lanes):
-                t = start + r
-                mine = column == t
+            def col(ref):
+                return jnp.sum(jnp.where(mine, ref[h], 0.0), axis=1,
+                               keepdims=True)                     # [dk, 1]
 
-                def col(ref):
-                    return jnp.sum(jnp.where(mine, ref[h], 0.0), axis=1,
-                                   keepdims=True)                 # [dk, 1]
+            # a row of a [T, lanes] array by its aligned group of eight
+            # (a load or store at a row the compiler cannot place is not
+            # built)
+            group = pl.ds(pl.multiple_of(t // 8 * 8, 8), 8)
+            own = sublane == t % 8
 
-                # a row of a [T, lanes] array by its aligned group of
-                # eight (a load or store at a row the compiler cannot
-                # place is not built)
-                group = pl.ds(pl.multiple_of(t // 8 * 8, 8), 8)
-                own = sublane == t % 8
+            def lane_row(ref):
+                return jnp.sum(jnp.where(own, ref[group, lanes], 0.0),
+                               axis=0, keepdims=True)             # [1, dv]
 
-                def lane_row(ref):
-                    return jnp.sum(jnp.where(own, ref[group, lanes], 0.0),
-                                   axis=0, keepdims=True)         # [1, dv]
+            k_t = col(kt_ref)
+            s = col(dt_ref) * s
+            read = jnp.sum(s * k_t, axis=0, keepdims=True)        # [1, dv]
+            s = s + k_t * (lane_row(bv_ref) - lane_row(bb_ref) * read)
+            o_ref[group, lanes] = jnp.where(
+                own, jnp.sum(s * col(qt_ref), axis=0, keepdims=True),
+                o_ref[group, lanes])
+            return s
 
-                k_t = col(kt_ref)
-                s = col(dt_ref) * s
-                read = jnp.sum(s * k_t, axis=0, keepdims=True)    # [1, dv]
-                s = s + k_t * (lane_row(bv_ref) - lane_row(bb_ref) * read)
-                o_ref[group, lanes] = jnp.where(
-                    own, jnp.sum(s * col(qt_ref), axis=0, keepdims=True),
-                    o_ref[group, lanes])
-                return s
+        def state(h):
+            return jnp.where(fresh, 0.0, s_in_ref[0, 0, h])
 
-            s_out_ref[0, 0, h] = jax.lax.fori_loop(
-                0, rows, row, jnp.where(fresh, 0.0, s_in_ref[0, 0, h]))
+        # the one row iterated two ways (the module's docstring): a
+        # decode row's heads in straight-line code, a chunk's rows
+        # ``UNROLL`` a loop body and its tail a row a trip
+        @pl.when(rows == 1)
+        def _():
+            for h in range(heads):
+                s_out_ref[0, 0, h] = row(0, state(h), h)
+
+        @pl.when(rows != 1)
+        def _():
+            for h in range(heads):
+                def body(i, s, h=h):
+                    for r in range(UNROLL):
+                        s = row(i * UNROLL + r, s, h)
+                    return s
+
+                s = jax.lax.fori_loop(0, rows // UNROLL, body, state(h))
+                s_out_ref[0, 0, h] = jax.lax.fori_loop(
+                    rows // UNROLL * UNROLL, rows,
+                    functools.partial(row, h=h), s)
 
 
 def _kda_update_pallas(dt, kt, qt, bv, bb, kda, layer, seg: StepSegments,

@@ -201,17 +201,11 @@ def _drive(steps, rows, impl, slots=6, width=12, state=None):
     return out, state
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas-interpret"])
-@pytest.mark.parametrize("chunk", [1, 2, 3, 5])
-def test_the_packed_step_equals_kda_full_for_chunks_beside_decode_rows(
-        impl, chunk):
-    """Three sequences in slots 4, 1 and 3: the first prefills in chunks
-    and then decodes a row a step, the second prefills beside its decode
-    rows, the third beside both; steps of decode rows alone, of chunks
-    alone and of both, with pad rows behind them (a width of 12: the
-    kernel pads the rows to its group of eight)."""
-    rows = _rows(3, 3, 14, heads=2, dk=8, dv=128)
-    want = np.asarray(kda.kda_full(*rows, chunk=4))
+def _staggered_steps(chunk):
+    """Three sequences of 14 in slots 4, 1 and 3: the first prefills in
+    chunks and then decodes a row a step, the second prefills beside its
+    decode rows, the third beside both; steps of decode rows alone, of
+    chunks alone and of both."""
     steps, left = [], [14, 14, 14]
     while max(left):
         plan = []
@@ -223,8 +217,63 @@ def test_the_packed_step_equals_kda_full_for_chunks_beside_decode_rows(
                 plan.append((seq, slot, n))
                 left[seq] -= n
         steps.append(plan)
-    got, _ = _drive(steps, rows, impl)
-    np.testing.assert_allclose(got, want, atol=1e-5)
+    return steps
+
+
+def _beside_steps(chunk, length, width=16):
+    """Twelve sequences, four steps of 16 rows: sequences 0 and then 1
+    prefill ``chunk`` rows a step (a tail of fewer where the sequence
+    ends), the ten others decode a row a step around the chunk, as many
+    as the step has room for. The chunk starts at rows 3, 5, 1 and 6 (no
+    multiple of the kernel's group of eight), so over the steps the
+    decode rows stand at every ``t % 8``."""
+    steps, left, turn = [], [length, length], 0
+    for lead in (3, 5, 1, 6):
+        seq = 0 if left[0] else 1
+        n = min(chunk, left[seq])
+        left[seq] -= n
+        decoding = [2 + (turn + i) % 10 for i in range(min(10, width - n))]
+        turn += len(decoding)
+        steps.append([(d, d, 1) for d in decoding[:lead]] + [(seq, seq, n)]
+                     + [(d, d, 1) for d in decoding[lead:]])
+    return steps
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas-interpret"])
+@pytest.mark.parametrize(
+    "chunk,heads,dk",
+    [(c, 2, 8) for c in (1, 2, 3, 5)] + [(c, 16, 128) for c in range(1, 10)])
+def test_the_packed_step_equals_kda_full_for_chunks_beside_decode_rows(
+        impl, chunk, heads, dk):
+    """Chunks beside decode rows with pad rows behind them. At two heads
+    of 8 channels (the kernel a head a tile) three staggered sequences
+    and a width of 12: the kernel pads the rows to its group of eight.
+    At sixteen heads of 128 (two tiles of eight heads, the compiled
+    form's shapes) every chunk length from 1 to 9 beside one-row
+    segments: the one-row branch, the four-row body and every tail of
+    ``rows % 4`` in one step."""
+    if heads == 2:
+        rows, geometry = _rows(3, 3, 14, heads, dk, 128), {}
+        steps = _staggered_steps(chunk)
+    else:
+        # two chunks and a tail of sequence 0, then a chunk of sequence 1
+        length = max(4, 2 * chunk + max(1, chunk // 2))
+        rows = _rows(3, 12, length, heads, dk, 128)
+        steps, geometry = _beside_steps(chunk, length), {"slots": 12,
+                                                         "width": 16}
+        first = [(t, seq) for plan in steps for t, (seq, _, _) in zip(
+            np.cumsum([0] + [n for *_, n in plan]), plan)]
+        assert all(t % 8 for t, seq in first if seq < 2)
+        assert {t % 8 for t, seq in first if seq > 1} == set(range(8))
+    want = np.asarray(kda.kda_full(*rows, chunk=4))
+    got, _ = _drive(steps, rows, impl, **geometry)
+    fed = np.zeros(len(want), int)          # a sequence's positions so far
+    for plan in steps:
+        for seq, _, n in plan:
+            fed[seq] += n
+    assert chunk == 1 or fed.all()
+    done = np.arange(want.shape[1]) < fed[:, None]
+    np.testing.assert_allclose(got[done], want[done], atol=1e-5)
     assert chunk == 1 or any(len(p) > 1 and {n for *_, n in p} != {1}
                              for p in steps)
 
