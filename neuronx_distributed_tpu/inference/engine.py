@@ -26,6 +26,8 @@ requests are admitted mid-flight.
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import statistics
 import time
 import types
 from collections import deque
@@ -41,7 +43,7 @@ from ..obs.accounting import CompileTracker
 from ..obs.device_scopes import device_scope
 from ..obs.events import emit_event
 from ..obs.metrics import get_registry
-from ..obs.tracing import get_tracer
+from ..obs.tracing import GC_SPAN, get_tracer
 from ..utils.device import on_tpu
 from ..resilience.integrity import (
     IntegrityError,
@@ -66,6 +68,20 @@ from .speculative import (SpeculationConfig, branch_of_nodes,
 #: hold such an array, and read it, while the step after the one that made
 #: it runs.
 _HOST_WRITTEN = ("block_tables", "lengths")
+
+#: engines of this process, counted: each numbers its ``step()`` calls from
+#: a base of its own, so two replicas' calls never carry the same ``step``
+_ENGINE_SEQ = itertools.count()
+
+#: the stalled-step rule (``_account_call``): a call is slow when its wall
+#: is over ``STALL_FACTOR`` x the median of the last ``STALL_WINDOW`` calls'
+#: walls; the median is taken at the ``STALL_FIRST``-th accounted call and
+#: at every ``STALL_REFRESH``-th, so that no step sorts
+STALL_FACTOR, STALL_WINDOW, STALL_FIRST, STALL_REFRESH = 3.0, 256, 8, 64
+
+#: where a slow call's time over the median went
+#: (``nxd_engine_step_wall_seconds_total{where}``; ``steady`` is the rest)
+STALL_CAUSES = ("host_pause", "device", "transfer", "compile", "host")
 
 
 def _hold_out(cache):
@@ -816,6 +832,12 @@ class ServingEngine:
         #: the registry handles ``_publish_obs`` writes to, registered once a
         #: registry generation
         self._obs_cache: Optional[types.SimpleNamespace] = None
+        #: this engine's ``step()`` calls, counted: the ``step`` attribute
+        #: of every span a call opens
+        self._calls = next(_ENGINE_SEQ) << 32
+        #: the stalled-step rule's state, made by the first call that is
+        #: accounted (obs on): the last walls, their median, the call before
+        self._stall: Optional[types.SimpleNamespace] = None
         # request-lifecycle ownership: a fleet router retires request
         # traces and histograms itself (it knows tenant and outcome);
         # it clears this flag on engines it manages so samples are
@@ -2045,14 +2067,16 @@ class ServingEngine:
                 self.dcache = cow_copy_blocks(self.dcache, src, dst, keep)
         self._pending_cow.clear()
 
-    def _dispatch(self, fn, rows, width: int, rng, span: str) -> _InFlight:
+    def _dispatch(self, fn, rows, width: int, rng, span: str,
+                  step: int) -> _InFlight:
         """Pack ``rows`` into a fixed ``width`` batch and enqueue one
         jitted worker; returns the step in flight (its sampled tokens
         aligned with ``rows``, still on the device). ``span`` is the
         caller's open span: two of its children split the host's packing
-        from the uploads and the enqueue, the third is :meth:`_fetch`'s."""
+        from the uploads and the enqueue, the third is :meth:`_fetch`'s;
+        ``step`` is the number of the ``step()`` call they belong to."""
         tracer = get_tracer()
-        with tracer.span(span + "/pack"):
+        with tracer.span(span + "/pack", step=step):
             tokens = np.zeros((1, width), np.int32)
             positions = np.full((1, width), PAD_POSITION, np.int32)
             slot_ids = np.full((width,), self.ecfg.max_slots, np.int32)
@@ -2071,7 +2095,7 @@ class ServingEngine:
                     positions[0], slot_ids, self._tables,
                     [len(self._slot_blocks[r.slot]) for r in self._slots
                      if r is not None], rolled))
-        with tracer.span(span + "/dispatch"):
+        with tracer.span(span + "/dispatch", step=step):
             if self._spec is not None:
                 sampled, self.cache, self.dcache = fn(
                     self.params, self._draft_params, self.cache,
@@ -2103,14 +2127,28 @@ class ServingEngine:
                     on_device.copy_to_host_async()
         return flight
 
-    def _fetch(self, flight: _InFlight, span: str) -> None:
+    def _fetch(self, flight: _InFlight, span: str, **attrs) -> None:
         """Read what the device returned for ``flight``: the one place the
         host blocks until the device has finished a step (with a step in
-        flight, the one before the step it has just enqueued)."""
-        with get_tracer().span(span + "/fetch"):
+        flight, the one before the step it has just enqueued). With the
+        tracer on the span says which wait it was: ``ready_us`` until the
+        step's tokens are ready on the device, ``copy_us`` the copies to
+        the host after that (issued at the enqueue, so near 0 unless a
+        copy itself stalls); ``attrs`` are the span's (the call's
+        ``step``)."""
+        tracer = get_tracer()
+        timed = tracer.enabled
+        with tracer.span(span + "/fetch", **attrs) as sp:
+            if timed:
+                flight.sampled.block_until_ready()
+                ready = time.perf_counter_ns() / 1000.0
             flight.sampled = np.asarray(flight.sampled)
             for leaf, on_device in flight.counts:
                 self._add_counts(leaf.read(np.asarray(on_device)))
+            if timed:
+                sp.set_attribute("ready_us", ready - sp.t0_us)
+                sp.set_attribute(
+                    "copy_us", time.perf_counter_ns() / 1000.0 - ready)
 
     def _add_counts(self, counts) -> None:
         """Add what a step counted, by counter name, to what the next
@@ -2280,7 +2318,9 @@ class ServingEngine:
         and reads each at once — the KV handoff between them is the shared
         block pool itself (table-row surgery, no tensor copies)."""
         tracer = get_tracer()
-        with tracer.span("engine/admission"):
+        self._calls += 1
+        call = self._calls
+        with tracer.span("engine/admission", step=call) as entered:
             self._admit()
             round_state = self._begin_spec_round()
             decode_rows, prefill_rows = self._build_schedule(
@@ -2289,17 +2329,20 @@ class ServingEngine:
         rows = decode_rows + prefill_rows
         spec_live = [x for x in round_state if x is not None]
         if not rows and not spec_live:
-            return self._settle()
+            return self._settle(call)
         t_start = self._now()
         if self.stats.first_step_t is None:
             self.stats.first_step_t = t_start
+        rolled = cleared = 0
         if self._pending_roll:
-            with tracer.span("engine/roll"):
+            with tracer.span("engine/roll", step=call):
+                rolled = len(self._pending_roll)
                 self._roll()
-        with tracer.span("engine/cow"):
+        with tracer.span("engine/cow", step=call):
             self._apply_pending_cow()
-        with tracer.span("engine/hygiene"):
+        with tracer.span("engine/hygiene", step=call):
             if self._freed_dirty:
+                cleared = len(self._freed_dirty)
                 mask = np.zeros((self._pool_blocks,), np.bool_)
                 mask[list(self._freed_dirty)] = True
                 self._freed_dirty.clear()
@@ -2309,7 +2352,7 @@ class ServingEngine:
                 if self.dcache is not None:
                     self.dcache = self.dcache.replace(
                         pos=_clear_freed_positions(self.dcache.pos, fmask))
-        with tracer.span("engine/tables"):
+        with tracer.span("engine/tables", step=call):
             # committed to the cache's sharding: the disaggregated decode
             # worker otherwise sees two sharding keys for its cache operand
             # (prefill's committed output vs a fresh uncommitted replace)
@@ -2347,24 +2390,24 @@ class ServingEngine:
                     (self._prefill_fn, prefill_rows, p_width, p_name),
                     (d_fn, decode_rows, d_width, "engine/decode")):
                 if worker_rows:
-                    with tracer.span(name):
+                    with tracer.span(name, step=call):
                         flight = self._dispatch(fn, worker_rows, width,
-                                                sub, name)
-                        self._fetch(flight, name)
+                                                sub, name, call)
+                        self._fetch(flight, name, step=call)
                     landing.insert(0, flight)   # lands decode rows first
                     pad_rows += width - len(worker_rows)
         else:
-            with tracer.span("engine/packed"):
+            with tracer.span("engine/packed", step=call):
                 flight = None
                 if rows:
                     flight = self._dispatch(
                         self._step_fn, rows, self.ecfg.token_budget, sub,
-                        "engine/packed")
+                        "engine/packed", call)
                     pad_rows = self.ecfg.token_budget - len(rows)
                 if self._depth:
                     flight, self._inflight = self._inflight, flight
                 if flight is not None:
-                    self._fetch(flight, "engine/packed")
+                    self._fetch(flight, "engine/packed", step=call)
                     landing.append(flight)
         emit = alen = bstar = None
         if spec_live:
@@ -2382,10 +2425,10 @@ class ServingEngine:
                 committed[i] = req.tokens[req.n_cached]
                 posv[i] = req.n_cached
             cm, pv = jnp.asarray(committed), jnp.asarray(posv)
-            with tracer.span("engine/spec_draft"):
+            with tracer.span("engine/spec_draft", step=call):
                 drafted, self.dcache = self._spec_draft_fn(
                     self._draft_params, self.dcache, cm, pv)
-            with tracer.span("engine/spec_verify"):
+            with tracer.span("engine/spec_verify", step=call):
                 (self.cache, self.dcache, emit_d, alen_d,
                  bstar_d) = self._spec_verify_fn(
                      self.params, self.cache, self.dcache, cm, drafted,
@@ -2397,8 +2440,9 @@ class ServingEngine:
         if self.prefix_cache is not None and prefill_rows:
             # the rows are enqueued: whoever maps these blocks reads them
             # in a later step, which the device runs after this one
-            for req in {id(r[0]): r[0] for r in prefill_rows}.values():
-                self._maybe_insert_prefix(req)
+            with tracer.span("engine/prefix_insert", step=call):
+                for req in {id(r[0]): r[0] for r in prefill_rows}.values():
+                    self._maybe_insert_prefix(req)
 
         now = self._now()
         if tracer.enabled:
@@ -2407,21 +2451,22 @@ class ServingEngine:
             # share), so each participant's phase accumulates the full
             # step wall time. One batched tracer call per step.
             step_us = (now - t_start) * 1e6
-            tracer.request_slices(
-                [(req.uid, "decode_step", step_us) for req in
-                 {id(r[0]): r[0] for r in decode_rows}.values()]
-                + [(req.uid, "prefill_slice", step_us) for req in
-                   {id(r[0]): r[0] for r in prefill_rows}.values()]
-                + [(x[0].uid, "decode_step", step_us)
-                   for x in spec_live])
-        with tracer.span("engine/retirement"):
+            with tracer.span("engine/slices", step=call):
+                tracer.request_slices(
+                    [(req.uid, "decode_step", step_us) for req in
+                     {id(r[0]): r[0] for r in decode_rows}.values()]
+                    + [(req.uid, "prefill_slice", step_us) for req in
+                       {id(r[0]): r[0] for r in prefill_rows}.values()]
+                    + [(x[0].uid, "decode_step", step_us)
+                       for x in spec_live])
+        with tracer.span("engine/retirement", step=call):
             self._note_enqueued(rows)
             for flight in landing:
                 self._land(flight, now)
             if spec_live:
                 self._land_spec_round(round_state, emit, alen, bstar,
                                       now)
-        with tracer.span("engine/publish"):
+        with tracer.span("engine/publish", step=call) as published:
             self.stats.steps += 1
             self.stats.step_latency_s.append(now - t_start)
             self.stats.last_step_t = now
@@ -2433,7 +2478,9 @@ class ServingEngine:
             self.stats.queue_depth = self.queue_depth()
             self._publish_obs(now - t_start, len(decode_rows),
                               len(prefill_rows), pad_rows,
-                              "overlapped" if overlapped else "serial")
+                              "overlapped" if overlapped else "serial",
+                              (call, entered.t0_us, published, cleared,
+                               rolled))
         return len(rows) + len(spec_live)
 
     def _note_enqueued(self, rows) -> None:
@@ -2479,22 +2526,24 @@ class ServingEngine:
                     or (eos is not None and tok == eos)):
                 self._retire(req, now)
 
-    def _settle(self) -> int:
+    def _settle(self, step: Optional[int] = None) -> int:
         """Read and land the step in flight, if there is one; returns its
-        rows. :meth:`step` ends here when it has nothing to enqueue, and
-        whatever reads or moves request state between two steps
-        (:meth:`evict`, :meth:`export_session`, :meth:`export_prefixes`,
-        :meth:`drain`) starts here."""
+        rows. :meth:`step` ends here when it has nothing to enqueue (its
+        spans then carry the call's ``step``), and whatever reads or moves
+        request state between two steps (:meth:`evict`,
+        :meth:`export_session`, :meth:`export_prefixes`, :meth:`drain`)
+        starts here."""
         flight, self._inflight = self._inflight, None
         if flight is None:
             return 0
         tracer = get_tracer()
-        with tracer.span("engine/packed"):
-            self._fetch(flight, "engine/packed")
+        attrs = {} if step is None else {"step": step}
+        with tracer.span("engine/packed", **attrs):
+            self._fetch(flight, "engine/packed", **attrs)
         now = self._now()
-        with tracer.span("engine/retirement"):
+        with tracer.span("engine/retirement", **attrs):
             self._land(flight, now)
-        with tracer.span("engine/publish"):
+        with tracer.span("engine/publish", **attrs):
             self.stats.last_step_t = now
             self.stats.queue_depth = self.queue_depth()
             self._publish_obs(None, 0, 0, 0, None)
@@ -2514,7 +2563,8 @@ class ServingEngine:
 
     def _publish_obs(self, step_latency_s: Optional[float],
                      decode_rows: int, prefill_rows: int, pad_rows: int,
-                     kind: Optional[str]) -> None:
+                     kind: Optional[str],
+                     call: Optional[Tuple] = None) -> None:
         """Bridge :class:`EngineStats` into registry gauges, count the
         step by ``kind`` (``overlapped``: enqueued while the step before
         it had not been read; ``serial``; ``None`` with no latency: the
@@ -2522,8 +2572,11 @@ class ServingEngine:
         step's rows by kind where they were packed (over the steps that
         ran a worker the three kinds sum to steps x worker width),
         increment the family's declared counters by what its steps
-        counted since the last publish (``_counted``), and poll the
-        per-worker compile trackers. One bool check when obs is
+        counted since the last publish (``_counted``), poll the
+        per-worker compile trackers, and account the call's wall by cause
+        (:meth:`_account_call`; ``call`` is what ``step()`` knows of the
+        call: its number, its entry, its open publish span, the blocks its
+        hygiene cleared and the windows it rolled). One bool check when obs is
         disabled; the no-host-callback invariant holds — everything here
         runs after the compiled workers returned. Child handles are
         cached against the registry's reset generation so the steady
@@ -2531,8 +2584,11 @@ class ServingEngine:
         reg = get_registry()
         if not reg.enabled:
             return
+        compiled = False
         for tracker in self._compile_trackers.values():
+            seen = tracker.compiles
             tracker.poll()
+            compiled |= tracker.compiles > seen
         cache = self._obs_cache
         if (cache is None or cache.registry is not reg
                 or cache.generation != reg.generation):
@@ -2568,6 +2624,18 @@ class ServingEngine:
                 "filled them: a decoding slot's token, a prefill chunk's "
                 "token, or padding.",
                 labels=("kind",))
+            wall_c = reg.counter(
+                "nxd_engine_step_wall_seconds_total",
+                "Wall time of the step() calls that packed rows, entry to "
+                "return, by where it went: steady (a call under 3 x the "
+                "running median of the last 256 calls' walls, whole; of a "
+                "slower call the median), and the slower call's time over "
+                "the median by cause: host_pause (host/gc spans), device "
+                "(the fetch's ready_us over its median), transfer (copy_us, "
+                "engine/tables and /dispatch over theirs), compile, host "
+                "(every other span over its median and what no span "
+                "covers). The children sum to the calls' walls.",
+                labels=("where",))
             counted = {}
             for c in self._counters:
                 metric = reg.counter(c.name, c.help,
@@ -2585,6 +2653,8 @@ class ServingEngine:
                            for k in ("decode", "prefill", "pad")),
                 steps={k: steps_c.labels(kind=k)
                        for k in ("overlapped", "serial")},
+                wall={k: wall_c.labels(where=k)
+                      for k in ("steady",) + STALL_CAUSES},
                 counted=counted)
         st = self.stats
         for f, child in cache.fields.items():
@@ -2601,6 +2671,110 @@ class ServingEngine:
             for child, n in zip(children, since):
                 child.inc(int(n))
             since[:] = 0
+        if call is not None and get_tracer().enabled:
+            self._account_call(cache.wall, {
+                "decode_rows": decode_rows, "prefill_rows": prefill_rows,
+                "pad_rows": pad_rows, "kind": kind, "compiled": compiled},
+                *call)
+
+    def _account_call(self, wall_c, facts: Dict[str, Any], step: int,
+                      entry_us: float, published, cleared: int,
+                      rolled: int) -> None:
+        """Add this call's wall (entry to now, microseconds before it
+        returns) to ``nxd_engine_step_wall_seconds_total{where}``: all of
+        it to ``steady`` unless it is over ``STALL_FACTOR`` x the running
+        median, and then the median to ``steady`` and the rest by cause
+        (:meth:`_slow_call`). What the engine knows of the call goes onto
+        its publish span: the call after it may wait for what this one
+        enqueued."""
+        st = self._stall
+        totals = (self._admit_counter, self.stats.completed,
+                  self.stats.preempted, self.stats.cow_copies)
+        if st is None:
+            st = self._stall = types.SimpleNamespace(
+                walls=deque(maxlen=STALL_WINDOW), median=None, calls=0,
+                totals=(0, 0, 0, 0), before=(step, entry_us))
+        admitted, retired, preempted, cow_copies = (
+            now - was for now, was in zip(totals, st.totals))
+        st.totals = totals
+        facts.update(admitted=admitted, retired=retired, preempted=preempted,
+                     cow_copies=cow_copies, cleared=cleared, rolled=rolled)
+        for key, value in facts.items():
+            published.set_attribute(key, value)
+        wall = time.perf_counter_ns() / 1000.0 - entry_us
+        st.walls.append(wall)
+        st.calls += 1
+        if st.calls == STALL_FIRST or st.calls % STALL_REFRESH == 0:
+            st.median = statistics.median(st.walls)
+        before, st.before = st.before, (step, entry_us)
+        if st.median is None or wall <= STALL_FACTOR * st.median:
+            wall_c["steady"].inc(wall * 1e-6)
+            return
+        wall_c["steady"].inc(st.median * 1e-6)
+        self._slow_call(wall_c, facts, step, wall, st.median, before)
+
+    def _slow_call(self, wall_c, facts: Dict[str, Any], step: int,
+                   wall: float, median: float,
+                   before: Tuple[int, float]) -> None:
+        """Split a slow call's time over the median by where it sat, from
+        the call's own spans against each span's median (the tracer's
+        reservoirs, read here and nowhere else), and emit one
+        ``slow_step`` event with this call's facts and those of the call
+        before it: a long ``ready_us`` is the wait for the step *that*
+        call enqueued. All times in microseconds until the event."""
+        tracer = get_tracer()
+        records = tracer.step_records(since_us=before[1])
+        this = records.get(step, {"self_us": {}, "attrs": {}, "gc": []})
+        p50 = {name: s["p50_us"] for name, s in tracer.stats().items()}
+        own = dict(this["self_us"])
+        raw = dict.fromkeys(STALL_CAUSES, 0.0)
+        raw["host_pause"] = own.pop(GC_SPAN, 0.0)
+        # what no span covers: the publish span, still open, is part of it
+        raw["host"] = max(0.0, wall - sum(this["self_us"].values()))
+        ready = copy = 0.0
+        for name, us in own.items():
+            if name.endswith("/fetch"):
+                # the fetch is the ready wait but for the copies
+                attrs = this["attrs"][name]
+                waited = attrs.get("ready_us", us)
+                copied = attrs.get("copy_us", 0.0)
+                ready, copy = ready + waited, copy + copied
+                raw["device"] += max(0.0, waited - p50.get(name, 0.0))
+                raw["transfer"] += copied
+            elif name == "engine/tables" or name.endswith("/dispatch"):
+                raw["transfer"] += max(0.0, us - p50.get(name, 0.0))
+            else:
+                raw["host"] += max(0.0, us - p50.get(name, 0.0))
+        excess, total = wall - median, sum(raw.values())
+        if facts["compiled"]:
+            split = {"compile": excess}
+        elif total > 0.0:
+            split = {k: excess * v / total for k, v in raw.items() if v}
+        else:
+            split = {"host": excess}
+        for where, us in split.items():
+            wall_c[where].inc(us * 1e-6)
+
+        def ms(us):
+            return round(us * 1e-3, 3)
+
+        prev = records.get(before[0]) if before[0] != step else None
+        memory = next(iter(self.cache.lengths.devices())).memory_stats()
+        emit_event(
+            "slow_step", replica=self.name or "engine", step=step,
+            steps=self.stats.steps, wall_ms=ms(wall), median_ms=ms(median),
+            split_ms={k: ms(v) for k, v in split.items()},
+            spans_ms={k: ms(v) for k, v in sorted(own.items())},
+            ready_ms=ms(ready), copy_ms=ms(copy), call=facts,
+            call_before=None if prev is None else dict(
+                prev["attrs"].get("engine/publish", {}), step=before[0],
+                spans_ms={k: ms(v)
+                          for k, v in sorted(prev["self_us"].items())}),
+            gc_generation=max((g for g, _, _ in this["gc"]), default=None),
+            gc_ms=ms(raw["host_pause"]),
+            memory={k: memory[k] for k in (
+                "bytes_in_use", "largest_free_block_bytes", "num_allocs")
+                if k in memory} if memory else None)
 
     def _retire(self, req: _RequestState, now: float) -> None:
         if req.slot is not None:    # else it left its slot at the enqueue

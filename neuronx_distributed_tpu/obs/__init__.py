@@ -35,14 +35,19 @@ __all__ = [
 
 
 def enable() -> None:
-    """Turn on metrics collection and span recording process-wide."""
+    """Turn on metrics collection and span recording process-wide, and
+    make the interpreter's collections ``host/gc`` spans."""
     get_registry().enable()
-    get_tracer().enabled = True
+    tracer = get_tracer()
+    tracer.enabled = True
+    tracer.watch_gc(True)
 
 
 def disable() -> None:
     get_registry().disable()
-    get_tracer().enabled = False
+    tracer = get_tracer()
+    tracer.enabled = False
+    tracer.watch_gc(False)
 
 
 def enabled() -> bool:

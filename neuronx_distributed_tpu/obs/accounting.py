@@ -168,6 +168,11 @@ class CompileTracker:
         return cls(site, lambda: cache_size(fn), **kw)
 
     @property
+    def compiles(self) -> int:
+        """Compiles this tracker has seen at its polls so far."""
+        return self._last
+
+    @property
     def _reg(self) -> MetricsRegistry:
         return (self._registry if self._registry is not None
                 else get_registry())

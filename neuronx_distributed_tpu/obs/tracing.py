@@ -27,6 +27,17 @@ Three surfaces:
   spans are then in the ``.xplane.pb`` itself, on the profiler's clock,
   beside the device operations.
 
+Host pauses are spans too. ``watch_gc(True)`` (``obs.enable()`` calls it)
+puts a hook into ``gc.callbacks``: every collection of the interpreter
+becomes a span ``host/gc`` (``generation``, ``collected``) on the thread
+that ran it. The hook runs wherever an allocation happened to trigger the
+collection, the tracer's own ``_append_event`` under ``_lock`` included, so
+it takes **no lock**: it appends its readings to a ``deque`` and the tracer
+folds them into its events at its next record or snapshot.
+:meth:`SpanTracer.step_records` groups the events that carry a ``step``
+attribute (the serving engine's, one number a ``step()`` call) into
+per-call records: a view of the events held, not a second store.
+
 ``chrome_trace()`` / ``save()`` snapshot everything **under the lock**. A
 ``span()`` is recorded when it closes, so one still open at the snapshot
 is not in it; a request trace still live is, as a zero-duration
@@ -35,7 +46,9 @@ is not in it; a request trace still live is, as a zero-duration
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
 import json
 import math
 import os
@@ -45,7 +58,37 @@ import time
 import zlib
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-from .metrics import HISTOGRAM_RESERVOIR, QUANTILES
+from .metrics import HISTOGRAM_RESERVOIR, QUANTILES, get_registry
+
+#: the span a collection of the interpreter leaves, outside ``engine/`` on
+#: purpose: the benchmark's span metrics take ``engine/`` names only
+GC_SPAN = "host/gc"
+
+#: an event's own keys. A span's attributes lie flat beside them in the
+#: tracer's list (``args`` is built at export): a dict of strings and numbers
+#: alone is not tracked by the interpreter's collector, one that holds a
+#: dict is, and some 45,000 tracked events a 40 s serving window brought a
+#: generation-2 pass of 90 ms into every traced run (PR 50's first chip
+#: runs: the parent's events, without attributes, had brought none)
+_EVENT_KEYS = ("name", "ph", "ts", "dur", "pid", "tid")
+_RESERVED = frozenset(_EVENT_KEYS + ("args",))
+
+
+def _attrs_of(ev: Dict[str, Any]) -> Dict[str, Any]:
+    """An event's attributes, whichever way it holds them."""
+    if "args" in ev:
+        return ev["args"]
+    return {k: v for k, v in ev.items() if k not in _RESERVED}
+
+
+def _exported(ev: Dict[str, Any]) -> Dict[str, Any]:
+    """A copy of an event in the chrome-trace form (``args`` nested)."""
+    if len(ev) == len(_EVENT_KEYS) or "args" in ev:
+        return dict(ev)
+    out = {k: ev[k] for k in _EVENT_KEYS}
+    out["args"] = _attrs_of(ev)
+    return out
+
 
 #: live request traces kept before the oldest is evicted — a leak guard
 #: for callers that begin traces and never retire them, not a window.
@@ -109,6 +152,7 @@ class _NullSpan:
     """Returned when tracing is disabled: one shared, reentrant no-op."""
 
     __slots__ = ()
+    t0_us = 0.0
 
     def __enter__(self):
         return self
@@ -187,6 +231,10 @@ class SpanTracer:
         self._requests: Dict[str, _RequestTrace] = {}
         self._lock = threading.Lock()
         self._tls = threading.local()
+        # collections the hook has timed and no record has folded in yet:
+        # (start ns, end ns, generation, collected, thread)
+        self._gc_pending: collections.deque = collections.deque(maxlen=4096)
+        self._gc_open: Optional[Tuple[int, Any]] = None
 
     # -- plumbing ---------------------------------------------------
     def _stack(self) -> List[Span]:
@@ -216,14 +264,139 @@ class SpanTracer:
             "name": span.name, "ph": "X", "ts": span.t0_us, "dur": dur,
             "pid": os.getpid(), "tid": threading.get_ident() % 10000,
         }
-        args = dict(span.attrs)
+        attrs = span.attrs
         if span.parent is not None:
-            args["parent"] = span.parent
-        if args:
-            ev["args"] = args
+            attrs["parent"] = span.parent
+        if _RESERVED.isdisjoint(attrs):
+            ev.update(attrs)
+        else:
+            ev["args"] = attrs
         with self._lock:
+            if self._gc_pending:
+                self._fold_gc()
             self._append_event(ev)
             self._add_stat(span.name, dur)
+
+    # -- host pauses --------------------------------------------------
+    def watch_gc(self, on: bool) -> None:
+        """Install (or remove) the ``gc.callbacks`` hook that makes every
+        collection a ``host/gc`` span. Idempotent."""
+        installed = self._on_gc in gc.callbacks
+        if on and not installed:
+            gc.callbacks.append(self._on_gc)
+        elif installed and not on:
+            gc.callbacks.remove(self._on_gc)
+            self._gc_open = None
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """The hook: two clock readings a collection into ``_gc_pending``.
+        It runs in whatever thread allocated last, possibly under
+        ``_lock`` or the registry's lock, and takes neither. Inside
+        ``profile_step`` the pause is a profiler annotation as well, as
+        every span is there."""
+        if phase == "start":
+            if not self.enabled:
+                return
+            annotation = None
+            if self._annotate:
+                import jax
+
+                annotation = jax.profiler.TraceAnnotation(GC_SPAN)
+                annotation.__enter__()
+            self._gc_open = (time.perf_counter_ns(), annotation)
+            return
+        end = time.perf_counter_ns()
+        opened, self._gc_open = self._gc_open, None
+        if opened is None:
+            return
+        if opened[1] is not None:
+            opened[1].__exit__(None, None, None)
+        self._gc_pending.append((opened[0], end, info["generation"],
+                                 info["collected"], threading.get_ident()))
+
+    def _fold_gc(self) -> None:
+        # caller holds self._lock
+        pending = self._gc_pending
+        reg = get_registry()
+        seconds = reg.counter(
+            "nxd_host_gc_seconds_total",
+            "Seconds the interpreter spent in garbage collections, by "
+            "generation (the host/gc spans' durations).",
+            labels=("generation",)) if reg.enabled else None
+        while pending:
+            t0, end, generation, collected, ident = pending.popleft()
+            dur = (end - t0) / 1000.0
+            self._append_event({
+                "name": GC_SPAN, "ph": "X", "ts": t0 / 1000.0, "dur": dur,
+                "pid": os.getpid(), "tid": ident % 10000,
+                "generation": generation, "collected": collected})
+            self._add_stat(GC_SPAN, dur)
+            if seconds is not None:
+                seconds.labels(generation=str(generation)).inc(dur * 1e-6)
+
+    # -- per-call records ---------------------------------------------
+    def step_records(self, since_us: float = 0.0
+                     ) -> Dict[int, Dict[str, Any]]:
+        """The events that closed at or after ``since_us`` and carry a
+        ``step`` attribute, grouped by it: one record a call,
+        ``{"step", "entry_us", "return_us", "self_us": {span name: its
+        time less what opened inside it}, "attrs": {span name: its other
+        attributes}, "gc": [(generation, collected, us), ...]}``. The
+        ``host/gc`` spans of the call's thread between its entry and its
+        return are its ``gc`` and count as children of the span they fell
+        in (``self_us["host/gc"]`` is their sum). A view of the events
+        the tracer holds, built when asked for; a span still open is in
+        no record yet."""
+        with self._lock:
+            if self._gc_pending:
+                self._fold_gc()
+            n = len(self._events)
+            newest = (self._next - 1) if n == self.max_events else n - 1
+            tail = []
+            for k in range(n):
+                ev = self._events[(newest - k) % n]
+                if ev["ts"] + ev["dur"] >= since_us:
+                    tail.append(ev)
+                elif ev["name"] != GC_SPAN:     # a pause is folded in late
+                    break
+        calls: Dict[int, List[Dict[str, Any]]] = {}
+        pauses = []
+        for ev in tail:
+            step = _attrs_of(ev).get("step")
+            if step is not None:
+                calls.setdefault(step, []).append(ev)
+            elif ev["name"] == GC_SPAN:
+                pauses.append(ev)
+        out = {}
+        for step, evs in calls.items():
+            entry = min(ev["ts"] for ev in evs)
+            ret = max(ev["ts"] + ev["dur"] for ev in evs)
+            tid = evs[0]["tid"]
+            inside = [p for p in pauses if p["tid"] == tid
+                      and entry <= p["ts"] and p["ts"] + p["dur"] <= ret]
+            self_us: Dict[str, float] = {}
+            attrs: Dict[str, Dict[str, Any]] = {}
+            stack: List[list] = []              # [end, name, self_us]
+            for ev in sorted(evs + inside,
+                             key=lambda e: (e["ts"], -e["dur"])):
+                while stack and stack[-1][0] <= ev["ts"]:
+                    _, name, own = stack.pop()
+                    self_us[name] = self_us.get(name, 0.0) + max(own, 0.0)
+                if stack:
+                    stack[-1][2] -= ev["dur"]
+                stack.append([ev["ts"] + ev["dur"], ev["name"], ev["dur"]])
+                if ev["name"] != GC_SPAN:
+                    attrs.setdefault(ev["name"], {}).update(
+                        (k, v) for k, v in _attrs_of(ev).items()
+                        if k not in ("step", "parent"))
+            for _, name, own in stack:
+                self_us[name] = self_us.get(name, 0.0) + max(own, 0.0)
+            out[step] = {
+                "step": step, "entry_us": entry, "return_us": ret,
+                "self_us": self_us, "attrs": attrs,
+                "gc": [(p["generation"], p["collected"], p["dur"])
+                       for p in inside]}
+        return out
 
     # -- span surface -----------------------------------------------
     def span(self, name: str, **attrs: Any):
@@ -410,15 +583,19 @@ class SpanTracer:
 
     # -- jax.profiler glue ------------------------------------------
     @contextlib.contextmanager
-    def profile_step(self, logdir: str = "/tmp/nxd_profile"):
+    def profile_step(self, logdir: str = "/tmp/nxd_profile",
+                     profiler_options=None):
         """Attach an XLA device trace (viewable in Perfetto/TensorBoard)
         to a host span, so device and host timelines cross-reference.
         Every span opened while it is open (this one included) is also a
         ``jax.profiler.TraceAnnotation``: it lands on the host plane of
-        the written ``.xplane.pb``, on the clock of the device events."""
+        the written ``.xplane.pb``, on the clock of the device events.
+        ``profiler_options`` (a ``jax.profiler.ProfileOptions``) goes to
+        ``start_trace``: a window of seconds wants the Python tracer off
+        (``python_tracer_level = 0``)."""
         import jax
 
-        jax.profiler.start_trace(logdir)
+        jax.profiler.start_trace(logdir, profiler_options=profiler_options)
         was, self._annotate = self._annotate, True
         try:
             with self.span("profile_step", logdir=logdir):
@@ -438,6 +615,8 @@ class SpanTracer:
         """
         now = time.perf_counter_ns() / 1000.0
         with self._lock:
+            if self._gc_pending:
+                self._fold_gc()
             if len(self._events) < self.max_events:
                 events = list(self._events)
             else:  # unroll the ring into chronological order
@@ -446,7 +625,7 @@ class SpanTracer:
             open_requests = [
                 (tr.uid, tr.trace_id, tr.t0_us, dict(tr.phase_us))
                 for tr in self._requests.values()]
-        events = [dict(ev) for ev in events]
+        events = [_exported(ev) for ev in events]
         for uid, trace_id, start, phase_us in sorted(open_requests):
             events.append({
                 "name": "request:%s" % uid, "ph": "X", "ts": start,
@@ -469,6 +648,8 @@ class SpanTracer:
         total, mean, min and max over every span recorded, quantiles over
         the name's reservoir."""
         with self._lock:
+            if self._gc_pending:
+                self._fold_gc()
             snap = {name: (st.count, st.total, st.min, st.max,
                            list(st.reservoir))
                     for name, st in self._stats.items()}
@@ -495,6 +676,7 @@ class SpanTracer:
             self._next = 0
             self._stats.clear()
             self._requests.clear()
+            self._gc_pending.clear()
 
 
 #: process-wide default tracer; enabled/disabled in lockstep with the
@@ -508,6 +690,8 @@ def get_tracer() -> SpanTracer:
     if _DEFAULT is None:
         with _DEFAULT_LOCK:
             if _DEFAULT is None:
-                _DEFAULT = SpanTracer(
+                tracer = SpanTracer(
                     enabled=os.environ.get("NXD_OBS", "0") == "1")
+                tracer.watch_gc(tracer.enabled)
+                _DEFAULT = tracer
     return _DEFAULT

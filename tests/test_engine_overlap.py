@@ -4,7 +4,10 @@ takes it there, and what the host knows of a step at its enqueue is kept
 apart from what needs its values. Every request's tokens are those of the
 engine's serial modes and of a plain greedy loop over the model."""
 
+import dataclasses
 import functools
+import gc
+import time
 
 import jax
 import jax.numpy as jnp
@@ -317,3 +320,242 @@ def test_the_forward_returns_tables_and_lengths_as_it_got_them(
     np.testing.assert_array_equal(np.asarray(out.block_tables), table)
     assert np.asarray(out.lengths).tolist() == [0, 5, 0]
     assert not np.array_equal(np.asarray(out.pos), np.asarray(cache.pos))
+
+
+# ---------------------------------------------------------------------------
+# a stalled step says what it waited for (obs on)
+# ---------------------------------------------------------------------------
+
+PAUSE_S = 0.12
+
+
+class _SlowToBeReady:
+    """What a step returned, on a device that takes ``PAUSE_S`` longer."""
+
+    def __init__(self, array):
+        self.array = array
+
+    def block_until_ready(self):
+        time.sleep(PAUSE_S)
+        self.array.block_until_ready()
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.array)
+
+
+def _pause_in(eng, where, monkeypatch):
+    """Arrange for the next ``step()`` of ``eng`` to lose ``PAUSE_S`` in
+    ``where``; returns the thunk that takes the pause out again."""
+    if where == "pack":                 # inside engine/packed/pack
+        count = eng._count_step
+        monkeypatch.setattr(eng, "_count_step", lambda *a: (
+            time.sleep(PAUSE_S), count(*a))[1])
+    elif where == "gc":                 # a collection, inside the same span
+        count = eng._count_step
+        # a generation-2 pass over some hundred thousand containers
+        held = [[i] for i in range(400_000)]
+        monkeypatch.setattr(eng, "_count_step", lambda *a: (
+            gc.collect(), count(*a))[1])
+        return lambda: (monkeypatch.undo(), held.clear())
+    elif where == "ready":              # the fetch's wait for the device
+        fetch = eng._fetch
+
+        def slow_fetch(flight, span, **attrs):
+            flight.sampled = _SlowToBeReady(flight.sampled)
+            return fetch(flight, span, **attrs)
+
+        monkeypatch.setattr(eng, "_fetch", slow_fetch)
+    elif where == "tables":             # an upload that does not leave
+        put = jax.device_put
+        monkeypatch.setattr(jax, "device_put", lambda *a, **kw: (
+            time.sleep(PAUSE_S / 2), put(*a, **kw))[1])
+    return monkeypatch.undo
+
+
+def _wall_children():
+    return {c.labels["where"]: c.value for c in obs.get_registry().get(
+        "nxd_engine_step_wall_seconds_total").children()}
+
+
+@pytest.mark.parametrize("where,cause", [
+    ("pack", "host"), ("ready", "device"), ("tables", "transfer"),
+    ("gc", "host_pause")])
+def test_a_slow_call_puts_its_excess_under_its_cause(tiny_model, where,
+                                                     cause, monkeypatch):
+    obs.enable()
+    events = []
+    unsubscribe = obs.subscribe(
+        lambda name, fields: events.append((name, fields)))
+    eng = _engine(tiny_model)
+    for i in range(3):
+        eng.submit(_prompt(i, 5, tiny_model[0].vocab_size), 24, uid=f"r{i}")
+    for _ in range(12):                 # the rule has its median at 8
+        eng.step()
+    before = _wall_children()
+    undo = _pause_in(eng, where, monkeypatch)
+    eng.step()
+    undo()
+    slow_call = eng._calls
+    after = _wall_children()
+    while eng.has_work():
+        eng.step()
+    unsubscribe()
+
+    gained = {k: after[k] - before.get(k, 0.0) for k in after}
+    median_s = eng._stall.median * 1e-6
+    # the median to steady, the rest by cause: nearly all under this one
+    assert gained["steady"] == pytest.approx(median_s, rel=0.5)
+    excess = sum(gained.values()) - gained["steady"]
+    assert excess > 0.8 * PAUSE_S / (2 if where == "gc" else 1) \
+        or where == "gc" and excess > 10 * median_s
+    assert gained[cause] > 0.8 * excess, gained
+    # every call's wall is in the counter, once
+    children = _wall_children()
+    assert set(children) == {"steady", "host_pause", "device", "transfer",
+                             "compile", "host"}
+    assert sum(children.values()) == pytest.approx(
+        sum(eng._stall.walls) * 1e-6, abs=1e-6)
+    assert eng._stall.calls == eng.stats.steps == len(eng._stall.walls)
+
+    # one event for the slow call, with both calls' facts
+    [fields] = [f for name, f in events
+                if name == "slow_step" and f["step"] == slow_call]
+    assert fields["wall_ms"] > 3 * fields["median_ms"] > 0
+    assert sum(fields["split_ms"].values()) == pytest.approx(
+        fields["wall_ms"] - fields["median_ms"], abs=0.01)
+    assert max(fields["split_ms"], key=fields["split_ms"].get) == cause
+    # (a young collection may fall into any call; the forced one is old)
+    assert (fields["gc_generation"] == 2) == (where == "gc")
+    assert (fields["gc_ms"] > 0.5 * fields["wall_ms"]) == (where == "gc")
+    assert fields["ready_ms"] > 0 and fields["copy_ms"] >= 0
+    assert {"engine/packed/pack", "engine/packed/fetch", "engine/tables",
+            "engine/admission"} <= set(fields["spans_ms"])
+    this, prev = fields["call"], fields["call_before"]
+    assert this["decode_rows"] == 3 and this["kind"] == "overlapped"
+    assert prev["step"] == slow_call - 1 and prev["decode_rows"] == 3
+    for facts in (this, prev):
+        assert {"decode_rows", "prefill_rows", "pad_rows", "kind",
+                "admitted", "retired", "preempted", "cleared", "cow_copies",
+                "rolled", "compiled"} <= set(facts)
+    assert "engine/packed/dispatch" in prev["spans_ms"]
+    assert fields["memory"] is None     # the CPU backend reports none
+    counted = {c.labels["event"]: c.value for c in obs.get_registry().get(
+        "nxd_events_total").children()}
+    assert counted["slow_step"] == sum(
+        1 for name, _ in events if name == "slow_step")
+    # the tokens are those of an engine nobody paused
+    assert all(len(r.tokens) == 24 for r in eng.results.values())
+
+
+def test_a_call_in_which_a_worker_compiled_is_compile(tiny_model):
+    obs.enable()
+    events = []
+    unsubscribe = obs.subscribe(
+        lambda name, fields: events.append((name, fields)))
+    eng = _engine(tiny_model)
+    for i in range(3):
+        eng.submit(_prompt(i, 5, tiny_model[0].vocab_size), 24, uid=f"r{i}")
+    for _ in range(12):
+        eng.step()
+    before = _wall_children()
+    eng._rng = jax.random.key(0, impl="rbg")    # a key of another type:
+    eng.step()                          # the packed step compiles again
+    unsubscribe()
+    gained = {k: v - before.get(k, 0.0)
+              for k, v in _wall_children().items()}
+    assert gained["compile"] > 0
+    assert all(gained[k] == 0 for k in ("host", "device", "transfer",
+                                        "host_pause"))
+    [fields] = [f for name, f in events if name == "slow_step"]
+    assert fields["call"]["compiled"] and set(fields["split_ms"]) == {
+        "compile"}
+
+
+@pytest.mark.parametrize("where", ["events", "state", "stats_fields",
+                                   "hook", "spans"])
+def test_with_obs_off_a_paused_step_leaves_nothing(tiny_model, where,
+                                                   monkeypatch):
+    assert not obs.enabled()
+    events = []
+    unsubscribe = obs.subscribe(
+        lambda name, fields: events.append((name, fields)))
+    eng = _engine(tiny_model)
+    for i in range(3):
+        eng.submit(_prompt(i, 5, tiny_model[0].vocab_size), 16, uid=f"r{i}")
+    for _ in range(10):
+        eng.step()
+    put = jax.device_put
+    monkeypatch.setattr(jax, "device_put", lambda *a, **kw: (
+        time.sleep(PAUSE_S / 2), put(*a, **kw))[1])
+    eng.step()
+    monkeypatch.undo()
+    while eng.has_work():
+        eng.step()
+    unsubscribe()
+    if where == "events":
+        assert events == []
+    elif where == "state":
+        assert eng._stall is None and eng._obs_cache is None
+        assert obs.get_registry().get(
+            "nxd_engine_step_wall_seconds_total") is None
+    elif where == "stats_fields":
+        assert [f.name for f in dataclasses.fields(eng.stats)] == [
+            "steps", "completed", "rejected", "preempted", "resubmitted",
+            "queue_depth", "tokens_generated", "cow_copies",
+            "prefix_hit_tokens", "prefill_tokens", "migrated_in",
+            "migrated_out", "migrated_tokens", "integrity_rejects",
+            "spec_rounds", "spec_accepted_tokens", "ttft_s",
+            "step_latency_s", "occupancy", "shared_fraction",
+            "first_step_t", "last_step_t"]
+    elif where == "hook":
+        assert not any(getattr(cb, "__self__", None) is obs.get_tracer()
+                       for cb in gc.callbacks)
+    else:
+        assert obs.get_tracer().chrome_trace()["traceEvents"] == []
+
+
+@pytest.mark.parametrize("engine_kw", [{}, {"prefix_sharing": True},
+                                       {"disaggregated": True}],
+                         ids=["packed", "prefix_sharing", "disaggregated"])
+def test_a_calls_spans_cover_its_wall(tiny_model, engine_kw):
+    """Every ``engine/*`` span of a call carries its number, the fetch
+    says which wait it was, and what a call leaves outside any span is
+    under 1% of its wall at a step of a real length (30 ms here; the
+    spans' own bookkeeping is some tens of microseconds a call)."""
+    obs.enable()
+    eng = _engine(tiny_model, **engine_kw)
+    for worker in ("_step_fn", "_prefill_fn", "_decode_fn"):
+        fn = getattr(eng, worker)
+        if fn is not None:
+            setattr(eng, worker, lambda *a, fn=fn: (
+                time.sleep(0.03), fn(*a))[1])
+    requests = _requests(SCENARIOS["prefix_hit"]["requests"],
+                         tiny_model[0].vocab_size)
+    eng.step()                          # a call with nothing to do
+    _serve(eng, requests)
+    tracer = obs.get_tracer()
+    spans = [ev for ev in tracer.chrome_trace()["traceEvents"]
+             if ev["name"].startswith("engine/")]
+    assert spans and all("step" in ev["args"] for ev in spans)
+    # held flat: the collector does not see the events a run piles up
+    assert not any(gc.is_tracked(ev) for ev in tracer._events
+                   if ev["name"].startswith("engine/"))
+    fetches = [ev for ev in spans if ev["name"].endswith("/fetch")]
+    assert fetches and all(
+        0 <= ev["args"]["ready_us"] and 0 <= ev["args"]["copy_us"]
+        and ev["args"]["ready_us"] + ev["args"]["copy_us"] <= ev["dur"]
+        for ev in fetches)
+    records = tracer.step_records()
+    packed = [r for r in records.values()
+              if "kind" in r["attrs"].get("engine/publish", {})]
+    assert len(packed) == eng.stats.steps and len(records) > len(packed)
+    uncovered = [1.0 - sum(r["self_us"].values())
+                 / (r["return_us"] - r["entry_us"]) for r in packed]
+    assert float(np.median(uncovered)) < 0.01, sorted(uncovered)[-5:]
+    names = set().union(*(r["self_us"] for r in packed))
+    assert "engine/slices" in names
+    assert ("engine/prefix_insert" in names) == bool(
+        engine_kw.get("prefix_sharing"))
+    # the numbers are the engine's own calls, in order, one a call
+    assert sorted(records) == list(range(eng._calls - len(records) + 1,
+                                         eng._calls + 1))

@@ -7,6 +7,7 @@ import contextlib
 import json
 import logging
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -404,6 +405,190 @@ def test_profiler_alone_does_not_annotate_spans(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# host pauses: the gc hook, host/gc spans, per-call records
+# ---------------------------------------------------------------------------
+
+
+def _gc_hooks():
+    import gc
+
+    return [cb for cb in gc.callbacks
+            if getattr(cb, "__func__", None) is SpanTracer._on_gc]
+
+
+@pytest.mark.parametrize("switch", ["enable_disable", "enable_twice",
+                                    "disable_twice", "reset_keeps_it"])
+def test_gc_hook_is_installed_exactly_while_obs_is_enabled(switch):
+    assert not obs.enabled() and _gc_hooks() == []
+    obs.enable()
+    assert [cb.__self__ for cb in _gc_hooks()] == [obs.get_tracer()]
+    if switch == "enable_twice":
+        obs.enable()
+        assert len(_gc_hooks()) == 1
+    if switch == "reset_keeps_it":
+        obs.reset()
+        assert len(_gc_hooks()) == 1
+    obs.disable()
+    assert _gc_hooks() == []
+    if switch == "disable_twice":
+        obs.disable()
+        assert _gc_hooks() == []
+
+
+@pytest.mark.parametrize("generation", [0, 2])
+def test_a_collection_inside_a_span_leaves_a_host_gc_span(generation):
+    import gc
+
+    obs.enable()
+    tracer = obs.get_tracer()
+    gc.collect()                    # what is lying about is not the subject
+    tracer.reset()
+    obs.get_registry().reset()
+    with tracer.span("outer", step=7):
+        gc.collect(generation)
+    events = tracer.chrome_trace()["traceEvents"]
+    pauses = [ev for ev in events if ev["name"] == "host/gc"
+              and ev["args"]["generation"] == generation]
+    outer, = [ev for ev in events if ev["name"] == "outer"]
+    assert pauses and set(pauses[0]["args"]) == {"generation", "collected"}
+    assert all(outer["ts"] <= ev["ts"]
+               and ev["ts"] + ev["dur"] <= outer["ts"] + outer["dur"]
+               and ev["tid"] == outer["tid"] for ev in pauses)
+    # the counter holds the spans' seconds, by generation
+    seconds = {c.labels["generation"]: c.value for c in obs.get_registry()
+               .get("nxd_host_gc_seconds_total").children()}
+    in_events = sum(ev["dur"] for ev in events if ev["name"] == "host/gc"
+                    and ev["args"]["generation"] == generation)
+    assert seconds[str(generation)] == pytest.approx(in_events * 1e-6)
+    # and the call's record takes the pause out of the span it fell in
+    record = tracer.step_records()[7]
+    in_call = sum(us for _, _, us in record["gc"])
+    assert (generation, pauses[0]["args"]["collected"],
+            pauses[0]["dur"]) in record["gc"]
+    assert record["self_us"]["host/gc"] == pytest.approx(in_call)
+    assert record["self_us"]["outer"] == pytest.approx(
+        outer["dur"] - in_call)
+
+
+@pytest.mark.parametrize("held", ["tracer", "registry"])
+def test_a_collection_under_a_lock_of_ours_does_not_deadlock(held):
+    """The hook runs wherever an allocation triggers a collection, the
+    tracer's ``_append_event`` under its (non-reentrant) lock included:
+    it must take neither that lock nor the registry's."""
+    import gc
+
+    obs.enable()
+    tracer = obs.get_tracer()
+    lock = tracer._lock if held == "tracer" else obs.get_registry()._lock
+    done = threading.Event()
+
+    def collect_under_the_lock():
+        with lock:
+            gc.collect()
+        done.set()
+
+    worker = threading.Thread(target=collect_under_the_lock, daemon=True)
+    worker.start()
+    worker.join(timeout=20)
+    assert done.is_set() and not worker.is_alive()
+    # the pause is folded in at the next record
+    with tracer.span("after"):
+        pass
+    names = [ev["name"] for ev in tracer.chrome_trace()["traceEvents"]]
+    assert "host/gc" in names and names[-1] == "after"
+    assert tracer.stats()["host/gc"]["count"] >= 1.0
+
+
+def test_a_collection_inside_profile_step_is_a_profiler_annotation(
+        tmp_path):
+    import gc
+
+    obs.enable()
+    tracer = obs.get_tracer()
+    with tracer.profile_step(str(tmp_path / "prof")):
+        with tracer.span("inside/annotated"):
+            gc.collect()
+            jnp.ones((4,)).block_until_ready()
+    gc.collect()                    # outside: a span, no annotation
+    assert "host/gc" in _host_plane_names(tmp_path / "prof")
+    assert tracer.stats()["host/gc"]["count"] >= 2.0
+
+
+def test_with_obs_off_a_collection_leaves_nothing():
+    import gc
+
+    assert not obs.enabled()
+    gc.collect()
+    tracer = obs.get_tracer()
+    assert tracer.chrome_trace()["traceEvents"] == []
+    assert not tracer._gc_pending
+    assert obs.get_registry().get("nxd_host_gc_seconds_total") is None
+
+
+@pytest.mark.parametrize("attrs,tracked", [
+    ({}, False), ({"step": 7}, False),
+    ({"step": 7, "kind": "overlapped", "compiled": False, "us": 1.5}, False),
+    ({"ts": "collides", "step": 7}, True),         # nested: args is a dict
+    ({"rows": [1, 2]}, True)])                      # it holds a container
+def test_a_spans_event_is_not_tracked_by_the_collector(attrs, tracked):
+    """The tracer holds tens of thousands of events through a serving run.
+    One that is a dict of strings and numbers alone is invisible to the
+    interpreter's collector; one that holds a dict is not, and enough of
+    those bring on the generation-2 pass that the tracer is there to
+    find. So a span's attributes lie flat in the held event, and
+    ``chrome_trace`` nests them."""
+    import gc
+
+    tracer = SpanTracer()
+    with tracer.span("outer"):
+        with tracer.span("inner", **attrs):
+            pass
+    inner, outer = tracer._events
+    assert gc.is_tracked(inner) is tracked and not gc.is_tracked(outer)
+    exported = tracer.chrome_trace()["traceEvents"][0]
+    assert exported["args"] == dict(attrs, parent="outer")
+    assert set(exported) == {"name", "ph", "ts", "dur", "pid", "tid", "args"}
+    assert exported is not inner
+    assert tracer.step_records().get(7, {}).get("step") == attrs.get("step")
+
+
+@pytest.mark.parametrize("case", ["nested", "since", "ring"])
+def test_step_records_group_the_events_by_their_step(case):
+    """``step_records`` is a view of the events the tracer holds: spans by
+    their ``step``, self time by nesting, the other attributes by name."""
+    tracer = SpanTracer(max_events=8 if case == "ring" else 1000)
+    marks = []
+    for step in (1, 2, 3):
+        marks.append(time.perf_counter_ns() / 1000.0)
+        with tracer.span("a", step=step):
+            pass
+        with tracer.span("b", step=step, rows=step * 10) as b:
+            with tracer.span("b/child", step=step):
+                time.sleep(0.002)
+            b.set_attribute("late", True)
+        with tracer.span("unnumbered"):
+            pass
+    since = marks[1] if case == "since" else 0.0
+    records = tracer.step_records(since_us=since)
+    want = {"nested": {1, 2, 3}, "since": {2, 3}, "ring": {2, 3}}[case]
+    assert set(records) == want
+    by_name = {}
+    for ev in tracer.chrome_trace()["traceEvents"]:
+        by_name.setdefault(ev["name"], []).append(ev)
+    for step, rec in records.items():
+        child = next(ev for ev in by_name["b/child"]
+                     if ev["args"]["step"] == step)
+        b = next(ev for ev in by_name["b"] if ev["args"]["step"] == step)
+        assert set(rec["self_us"]) == {"a", "b", "b/child"}
+        assert rec["self_us"]["b/child"] == pytest.approx(child["dur"])
+        assert rec["self_us"]["b"] == pytest.approx(b["dur"] - child["dur"])
+        assert rec["attrs"]["b"] == {"rows": step * 10, "late": True}
+        assert rec["attrs"]["a"] == {} and rec["gc"] == []
+        assert rec["return_us"] == pytest.approx(b["ts"] + b["dur"])
+        assert rec["entry_us"] <= b["ts"] and rec["step"] == step
+
+
+# ---------------------------------------------------------------------------
 # compile tracking
 # ---------------------------------------------------------------------------
 
@@ -551,7 +736,7 @@ def test_engine_packed_step_is_covered_by_flat_spans():
         "engine/admission", "engine/cow", "engine/hygiene",
         "engine/tables", "engine/packed", "engine/packed/pack",
         "engine/packed/dispatch", "engine/packed/fetch",
-        "engine/retirement", "engine/publish"]
+        "engine/slices", "engine/retirement", "engine/publish"]
     parents = {ev["name"]: ev.get("args", {}).get("parent")
                for ev in spans}
     children = {n for n in parents if n.startswith("engine/packed/")}
