@@ -276,16 +276,31 @@ MOE_HELD = CounterFamily(
     "slot here and adds nothing. Counted on the device, fetched with the "
     "step's tokens.",
     ("held", "elsewhere"))
+MOE_IDENTITY = CounterFamily(
+    "nxd_moe_identity_total",
+    "Router choices of the serving workers' real rows (top_k a row an "
+    "expert layer) by what the chosen slot is: identity, a "
+    "zero-computation expert, whose weight times the row's own input is "
+    "added where the row is and which takes no expert's slot anywhere, or "
+    "routed, a real expert (held here or elsewhere: nxd_moe_held_total). "
+    "Counted on the device, fetched with the step's tokens.",
+    ("identity", "routed"))
 
 #: the ``moe_counts`` leaf of a family that declares it
 #: (:class:`ServingFamily`), as the kind that builds it lays it out: the
 #: assignments kept and dropped, and, where the device holds a share of
-#: the experts, a third count of those that chose an expert held elsewhere
+#: the experts, a third count of those that chose an expert held elsewhere;
+#: where the router also scores identity experts, a fourth of the choices
+#: of one (the first three are then of the real experts alone)
 MOE_KEPT_DROPPED = DeviceCounts(
     "moe_counts", 2, ((MOE_ASSIGNMENTS, ((0,), (1,))),))
 MOE_KEPT_DROPPED_ELSEWHERE = DeviceCounts(
     "moe_counts", 3, ((MOE_ASSIGNMENTS, ((0,), (1,))),
                       (MOE_HELD, ((0, 1), (2,)))))
+MOE_KEPT_DROPPED_ELSEWHERE_IDENTITY = DeviceCounts(
+    "moe_counts", 4, ((MOE_ASSIGNMENTS, ((0,), (1,))),
+                      (MOE_HELD, ((0, 1), (2,))),
+                      (MOE_IDENTITY, ((3,), (0, 1, 2)))))
 
 #: ``counts`` of a sparse-state cache: a counter family and, in its kinds'
 #: order, the names of :data:`..ops.sparse_attention.COUNT_KINDS` it reads
@@ -656,12 +671,16 @@ class LatentCache(FullCache):
     (the lanes past the two stay zero). There is no V pool: a value is
     the latent's lanes of the same row (:mod:`..ops.mla_attention`). Every position stays, so block
     mapping, allocation, copy-on-write and preemption are
-    :class:`FullCache`'s."""
+    :class:`FullCache`'s. A decoder layer with more than one latent
+    attention (``attentions``; the family says how many) has as many
+    layers of rows in the stack, side by side (:meth:`stack_index`)."""
 
     row: int = 640
-    name = "latent"
+    #: latent attentions, hence layers of rows, a decoder layer
+    attentions: int = 1
     #: how the kind lays out the ``moe_counts`` of a family that declares it
-    moe_leaf = MOE_KEPT_DROPPED
+    moe_leaf: DeviceCounts = MOE_KEPT_DROPPED
+    name = "latent"
     counters = (PAGED_COLUMNS, PAGED_BLOCK_VISITS, MLA_BLOCK_FETCHES)
 
     def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
@@ -677,6 +696,11 @@ class LatentCache(FullCache):
             served, geo.heads, self.row, geo.block_size, geo.itemsize)
         return counts
 
+    def stack_index(self, layer, which: int = 0):
+        """Where in the row stack attention ``which`` of decoder layer
+        ``layer`` (an int or a traced index) keeps its rows."""
+        return layer * self.attentions + which
+
     def init_cache(self, model_cfg, *, num_blocks: int, block_size: int,
                    table_rows: int, max_blocks_per_seq: int, dtype: Any,
                    quantized: bool = False) -> "LatentPagedCache":
@@ -685,8 +709,8 @@ class LatentCache(FullCache):
                              "latent and rotary key want scales of their "
                              "own, and the kernel reads neither")
         return LatentPagedCache(
-            rows=jnp.zeros((model_cfg.num_layers, num_blocks, block_size,
-                            self.row), dtype),
+            rows=jnp.zeros((self.stack_index(model_cfg.num_layers),
+                            num_blocks, block_size, self.row), dtype),
             moe_counts=(jnp.zeros((self.moe_leaf.entries,), jnp.int32)
                         if model_cfg.serving_family().moe_counts else None),
             pos=jnp.full((num_blocks, block_size), PAD_POSITION, jnp.int32),
@@ -899,9 +923,12 @@ class ServingFamily:
     ``moe_counts`` the routed-expert assignments of the step's real
     rows that were kept and that were dropped (``[2]``; ``[3]`` where
     the device holds a share of the experts: and those that chose an
-    expert held elsewhere), which the engine fetches with the step's
-    tokens (``nxd_moe_assignments_total``, ``nxd_moe_held_total``); the
-    family's cache kind builds the leaf."""
+    expert held elsewhere; ``[4]`` where the router also scores identity
+    experts: and the choices of one, the first three then of the real
+    experts alone), which the engine fetches with the step's tokens
+    (``nxd_moe_assignments_total``, ``nxd_moe_held_total``,
+    ``nxd_moe_identity_total``); the family's cache kind builds the leaf
+    (its ``moe_leaf``)."""
 
     forward: Callable
     cache_kind: Any = FULL_CACHE
@@ -986,10 +1013,13 @@ class LatentPagedCache(_BlockPool, struct.PyTreeNode):
     """The cache of :class:`LatentCache`. ``rows`` ``[L, num_blocks,
     block_size, row]``: a position's latent, its rotary key and idle
     lanes, one row for all heads, and the only leaf that holds the
-    sequence (no V, nothing a head); ``moe_counts [2]``, where the family
-    declares it (:class:`ServingFamily`; else None), the routed experts'
-    assignments of the last step's real rows that were kept and that were
-    dropped, summed over the expert layers; ``pos``, ``block_tables`` and
+    sequence (no V, nothing a head), ``L`` the kind's layers of rows
+    (:meth:`LatentCache.stack_index`: a decoder layer's attentions side by
+    side); ``moe_counts``, where the family declares it
+    (:class:`ServingFamily`; else None), the routed experts' assignments
+    of the last step's real rows as the kind's ``moe_leaf`` lays them out
+    (kept and dropped; elsewhere and identity where the kind says so),
+    summed over the expert layers; ``pos``, ``block_tables`` and
     ``lengths`` as :class:`PagedKVCache`."""
 
     rows: jax.Array
@@ -1161,6 +1191,12 @@ class LatentLayerView(struct.PyTreeNode):
     write_idx: jax.Array
     q_pos: jax.Array
     walk: Any = None
+
+    def next_attention(self) -> "LatentLayerView":
+        """The view of the same decoder layer's next latent attention
+        (:meth:`LatentCache.stack_index`: side by side), over the stack as
+        the attention that handed this view back left it."""
+        return self.replace(layer=self.layer + 1)
 
 
 class StateLayerView(struct.PyTreeNode):

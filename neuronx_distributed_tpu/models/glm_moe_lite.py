@@ -58,8 +58,12 @@ from ..parallel import mesh as ps
 from .llama import LlamaConfig, LlamaMLP, _ScanBody, run_layers
 
 KINDS = ("dense", "moe")
-#: what of the cache's stacks a layer reads and writes, either kind
-CARRIED = dict.fromkeys(KINDS, ("rows", "moe_counts"))
+
+
+def carried(cfg):
+    """What of the cache's stacks a layer of a latent family reads and
+    writes, whatever its kind."""
+    return {kind: ("rows", "moe_counts") for kind, _, _ in cfg.runs()}
 
 
 @dataclass(frozen=True)
@@ -86,6 +90,11 @@ class GlmMoeLiteConfig(LlamaConfig):
     moe_intermediate_size: int = 1536
     num_shared_experts: int = 1
     routed_scaling_factor: float = 1.8
+    #: what the normed low-rank query and the normed latent are multiplied
+    #: by (:class:`LatentAttention`; 1.0 multiplies nothing): constants of
+    #: this family, no fields
+    q_lora_scale = 1.0
+    kv_lora_scale = 1.0
     #: which feed-forward this layer has (set by :meth:`kind_config`)
     ff_kind: str = "moe"
 
@@ -138,6 +147,10 @@ class GlmMoeLiteConfig(LlamaConfig):
         return tuple((kind, 0, self.layers_of(kind)) for kind in KINDS
                      if self.layers_of(kind))
 
+    def rows_layer(self, kind: str, layer):
+        """A layer's rows in the cache's stack: the dense layers lead."""
+        return layer + (0 if kind == "dense" else self.first_k_dense)
+
     def serving_family(self):
         from ..inference.paging import LatentCache, ServingFamily
 
@@ -157,14 +170,20 @@ class GlmMoeLiteConfig(LlamaConfig):
 
 class LatentAttention(nn.Module):
     """Multi-head latent attention, ``kv_b`` absorbed, behind
-    :class:`.llama.LlamaAttention`'s call. ``cos``/``sin`` are the rows'
+    :class:`.llama.LlamaAttention`'s call, for any config that has the
+    latent fields (``num_heads``, ``q_lora_rank``, ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+    ``head_dim_`` the pool row's lanes, and ``q_lora_scale`` /
+    ``kv_lora_scale``, what the normed low-rank query and the normed
+    latent are multiplied by: the cached row holds the scaled latent).
+    ``cos``/``sin`` are the rows'
     own (``[S, rope / 2]``: :func:`..modules.attention.rope_rows`). No cache:
     the whole sequence, causal, positions ``0..S-1``. A
     :class:`..inference.paging.LatentLayerView`: this step's rows are
     written into the view's layer of the row stack and attended through
     the table."""
 
-    cfg: GlmMoeLiteConfig
+    cfg: LlamaConfig
 
     @nn.compact
     def __call__(self, x, cos, sin, positions=None, cache=None,
@@ -185,6 +204,11 @@ class LatentAttention(nn.Module):
         with device_scope("attn.proj"):
             c_q = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="q_a_norm")(
                 dense("q_a", cfg.q_lora_rank))
+            if cfg.q_lora_scale != 1.0:
+                # the factor in float32: rounded to bf16 it would be off
+                # by a fixed part in a thousand at every position
+                c_q = (c_q.astype(jnp.float32) * cfg.q_lora_scale
+                       ).astype(c_q.dtype)
             q = pl.ColumnParallelLinear(
                 features=cfg.num_heads * (nope + rope), use_bias=False,
                 gather_output=False, dtype=cfg.dtype,
@@ -193,6 +217,9 @@ class LatentAttention(nn.Module):
             kv = dense("kv_a", rank + rope)
             latent = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
                              name="kv_a_norm")(kv[..., :rank])
+            if cfg.kv_lora_scale != 1.0:
+                latent = (latent.astype(jnp.float32) * cfg.kv_lora_scale
+                          ).astype(latent.dtype)
             k_rope = attn_mod.apply_rotary(kv[..., None, rank:], cos, sin)
             q_rope = attn_mod.apply_rotary(q[..., nope:], cos, sin)
             k_up, v_up = (self.param(
@@ -246,9 +273,10 @@ class LatentAttention(nn.Module):
 
 class GlmMoeLiteModel(nn.Module):
     """Embedding, the layer pattern, final norm: positions ``0..S-1``, no
-    cache (tests, small training)."""
+    cache (tests, small training). Any latent family's: the config gives
+    ``runs()``, ``kind_config()`` and the layer module."""
 
-    cfg: GlmMoeLiteConfig
+    cfg: LlamaConfig
 
     @nn.compact
     def __call__(self, input_ids: jax.Array) -> jax.Array:
@@ -276,13 +304,13 @@ class GlmMoeLiteModel(nn.Module):
             stacks = {kind: meta.unbox(
                 self.variables["params"][f"layers_{kind}"])
                 for kind, _, _ in cfg.runs()}
-            x, _ = run_layers(cfg, stacks, x, cos, sin, CARRIED)
+            x, _ = run_layers(cfg, stacks, x, cos, sin, carried(cfg))
         with device_scope("norm"):
             return RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="norm")(x)
 
 
 class GlmMoeLiteForCausalLM(nn.Module):
-    cfg: GlmMoeLiteConfig
+    cfg: LlamaConfig
 
     @nn.compact
     def __call__(self, input_ids: jax.Array,
@@ -302,23 +330,26 @@ class GlmMoeLiteForCausalLM(nn.Module):
         return logits
 
 
-def glm_moe_lite_forward_with_cache(cfg: GlmMoeLiteConfig, params,
-                                    input_ids, positions, kv_cache,
-                                    slot_ids=None, **unsupported):
-    """The paged forward of the packed serving step, with
+def latent_forward_with_cache(cfg: LlamaConfig, params, input_ids,
+                              positions, kv_cache, slot_ids=None,
+                              **unsupported):
+    """The paged forward of a latent family's packed serving step, with
     :func:`.llama.llama_forward_with_cache`'s paged signature:
     ``input_ids``, ``positions [1, T]``, ``slot_ids [T]``, ``kv_cache`` a
     :class:`..inference.paging.LatentPagedCache`; returns ``(logits [1,
     T, V], new cache)``. The row stack and the routed assignments' counts
-    (of this step alone) are the carry of every run's scan."""
+    (of this step alone) are the carry of every run's scan;
+    ``cfg.rows_layer(kind, layer)`` says where in the stack a layer keeps
+    its (first attention's) rows."""
     from ..inference import paging
     from ..inference.kv_cache import PAD_POSITION
 
+    family = type(cfg).__name__
     if any(unsupported.values()):
-        raise ValueError(f"glm_moe_lite serves through the packed paged "
+        raise ValueError(f"{family} serves through the packed paged "
                          f"step only; got {sorted(unsupported)}")
     if not isinstance(kv_cache, paging.LatentPagedCache):
-        raise ValueError("glm_moe_lite is served from the cache its cache "
+        raise ValueError(f"{family} is served from the cache its cache "
                          "kind builds (paging.init_serving_cache)")
     p = params["params"]
     q_pos = jnp.asarray(positions, jnp.int32)[0]
@@ -348,11 +379,10 @@ def glm_moe_lite_forward_with_cache(cfg: GlmMoeLiteConfig, params,
                              force_pallas=cfg.attn_force_pallas)
 
     def view_of(kind, carry, layer):
-        # a layer's rows in the stack: the dense layers lead
-        at = layer + (0 if kind == "dense" else cfg.first_k_dense)
         return paging.LatentLayerView(
-            rows=carry["rows"], layer=at, pos=pool_pos, tables=tables,
-            write_idx=write_idx, q_pos=q_pos, walk=walk)
+            rows=carry["rows"], layer=cfg.rows_layer(kind, layer),
+            pos=pool_pos, tables=tables, write_idx=write_idx, q_pos=q_pos,
+            walk=walk)
 
     def merge(carry, view, assignments):
         return dict(rows=view.rows,
@@ -362,7 +392,8 @@ def glm_moe_lite_forward_with_cache(cfg: GlmMoeLiteConfig, params,
                  moe_counts=jnp.zeros_like(kv_cache.moe_counts))
     stacks = {kind: p["model"][f"layers_{kind}"]
               for kind, _, _ in cfg.runs()}
-    x, carry = run_layers(cfg, stacks, x, cos, sin, CARRIED, carry, view_of,
+    x, carry = run_layers(cfg, stacks, x, cos, sin, carried(cfg), carry,
+                          view_of,
                           merge, valid=(q_pos < PAD_POSITION)[None])
     with device_scope("norm"):
         x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype).apply(
@@ -373,3 +404,6 @@ def glm_moe_lite_forward_with_cache(cfg: GlmMoeLiteConfig, params,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
             {"params": p["lm_head"]}, x)
     return logits, kv_cache.replace(pos=pool_pos, **carry)
+
+
+glm_moe_lite_forward_with_cache = latent_forward_with_cache

@@ -241,6 +241,14 @@ class LlamaConfig:
         ``attn``."""
         return LlamaAttention(self, tp_sync=tp_sync, name="attn")
 
+    def decoder_layer(self, **module):
+        """The decoder layer module of this config (of this kind's, for a
+        config :meth:`kind_config` derived): :class:`LlamaDecoderLayer`
+        unless the family's layer is no norm-attention-norm-feed-forward
+        (``models/longcat_flash.py``'s double layer). Whatever it is takes
+        that layer's call and returns its ``(x, aux, new_cache)``."""
+        return LlamaDecoderLayer(self, **module)
+
     def __post_init__(self) -> None:
         if self.attention_kind not in ATTENTION_KINDS:
             raise ValueError(
@@ -986,7 +994,8 @@ def run_layers(cfg, stacks, x, cos, sin, carried, carry=None, view_of=None,
     """The layer pattern of a model whose layers differ in kind: one
     ``lax.scan`` a run of like layers (``cfg.runs()``: ``(kind, first,
     count)``, ``first`` the run's first index in its kind's stack), each
-    kind's layer :class:`LlamaDecoderLayer` under ``cfg.kind_config(kind)``.
+    kind's layer ``cfg.kind_config(kind).decoder_layer()``
+    (:class:`LlamaDecoderLayer` unless the family says otherwise).
     ``stacks[kind]`` is that kind's parameter stack (leaves lead with the
     kind's depth); ``carry`` a dict of the cache's stacks, handed from
     layer to layer and run to run (None: no cache), ``carried[kind]`` the
@@ -1000,7 +1009,7 @@ def run_layers(cfg, stacks, x, cos, sin, carried, carry=None, view_of=None,
     T]`` or None) are the rows' positions for a layer whose view does not
     carry them (:class:`..inference.paging.PagedCacheView`)."""
     for kind, first, count in cfg.runs():
-        layer = LlamaDecoderLayer(cfg.kind_config(kind))
+        layer = cfg.kind_config(kind).decoder_layer()
         stack = stacks[kind]["layer"]
 
         def body(state, i, layer=layer, stack=stack, kind=kind):
@@ -1059,7 +1068,7 @@ class _ScanBody(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin, positions):
-        x, aux, _ = LlamaDecoderLayer(self.cfg, name="layer")(
+        x, aux, _ = self.cfg.decoder_layer(name="layer")(
             x, cos, sin, positions)
         return x, aux
 

@@ -9,7 +9,7 @@ from .config_validator import validate_moe_config
 from .expert_mlps import ExpertMLPs, build_dispatch_combine, compute_capacity
 from .model import MoE, SharedExperts
 from .routing import (GroupLimitedRouter, RouterSigmoid, RouterSinkhorn,
-                      RouterTopK)
+                      RouterSoftmaxBias, RouterTopK)
 
 __all__ = [
     "config_validator",
@@ -26,5 +26,6 @@ __all__ = [
     "GroupLimitedRouter",
     "RouterSinkhorn",
     "RouterSigmoid",
+    "RouterSoftmaxBias",
     "RouterTopK",
 ]

@@ -18,13 +18,14 @@ from ...parallel import mesh as ps
 from .. import glu
 from .expert_mlps import ExpertMLPs
 from .routing import (GroupLimitedRouter, RouterSigmoid, RouterSinkhorn,
-                      RouterTopK)
+                      RouterSoftmaxBias, RouterTopK)
 
 ROUTERS = {
     "top_k": RouterTopK,
     "sinkhorn": RouterSinkhorn,
     "group_limited": GroupLimitedRouter,
     "sigmoid": RouterSigmoid,
+    "softmax_bias": RouterSoftmaxBias,
 }
 
 
@@ -79,11 +80,18 @@ class MoE(nn.Module):
     router_scale: float = 1.0
     shared_expert_intermediate: int = 0
     # ``(first, count)``: the experts this device holds of the
-    # ``num_experts`` the router scores (None: all of them). The bank has
+    # ``num_experts`` real experts (None: all of them), whatever else the
+    # router scores beside them (``identity_experts``). The bank has
     # ``count`` experts, an assignment to an expert held elsewhere takes
     # no slot and adds nothing, the shared expert is whole; capacity
     # dispatch of float experts, no ep axis (:class:`ExpertMLPs`)
     held: Optional[Tuple[int, int]] = None
+    # router slots past the ``num_experts`` real ones that are identity
+    # (zero-computation) experts: the router scores ``num_experts +
+    # identity_experts`` slots, a choice of slot ``num_experts`` or later
+    # takes no slot of the bank, is nowhere else either, and adds its
+    # weight times the row's own input, on the device that owns the row
+    identity_experts: int = 0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -94,20 +102,30 @@ class MoE(nn.Module):
         experts only) marks the real rows of a packed serving step: the
         others take no expert's slot and ``aux["assignments"]`` counts the
         real rows' ``[kept, dropped]`` (with ``held``: ``[kept, dropped,
-        elsewhere]``)."""
+        elsewhere]``, of the real experts; with ``identity_experts``, given
+        ``valid`` or not: ``[kept, dropped, elsewhere, identity]``)."""
         orig_shape = x.shape
         h = self.hidden_size
         flat = x.reshape(-1, h)
+        held = self.held
+        if self.identity_experts:
+            # a choice past the real experts is one of an expert not held
+            # here as far as the bank goes: the held dispatch leaves it out
+            held = held or (0, self.num_experts)
+            if valid is None:
+                valid = jnp.ones(orig_shape[:-1], bool)
 
         router_cls = ROUTERS[self.router_type]
-        router_kw = dict(num_experts=self.num_experts, dtype=self.dtype,
-                         param_dtype=self.param_dtype, name="router")
+        router_kw = dict(num_experts=self.num_experts + self.identity_experts,
+                         dtype=self.dtype, param_dtype=self.param_dtype,
+                         name="router")
         if self.router_type != "sinkhorn":
             router_kw["top_k"] = self.top_k
-        if self.router_type == "sigmoid" or self.router_scale != 1.0:
+        if (self.router_type in ("sigmoid", "softmax_bias")
+                or self.router_scale != 1.0):
             router_kw["scale"] = self.router_scale
-        if self.held is not None and (self.expert_impl != "float"
-                                      or valid is None):
+        if held is not None and (self.expert_impl != "float"
+                                 or valid is None):
             raise ValueError("MoE: a share of the experts (held) is float "
                              "experts under the packed step's valid rows")
         with device_scope("ffn.router"):
@@ -156,8 +174,8 @@ class MoE(nn.Module):
             raise ValueError(f"unknown expert_impl {self.expert_impl!r}")
         else:
             experts = ExpertMLPs(
-                num_experts=(self.num_experts if self.held is None
-                             else self.held[1]), hidden_size=h,
+                num_experts=(self.num_experts if held is None
+                             else held[1]), hidden_size=h,
                 intermediate_size=self.intermediate_size,
                 top_k=gates.shape[-1], capacity_factor=self.capacity_factor,
                 dispatch_mode=self.dispatch_mode,
@@ -166,7 +184,7 @@ class MoE(nn.Module):
                 ep_wire_dtype=self.ep_wire_dtype,
                 ep_overlap=self.ep_overlap,
                 dtype=self.dtype, param_dtype=self.param_dtype,
-                held=self.held, name="experts")
+                held=held, name="experts")
         # the routed experts: dispatch, the bank's products, combine
         with device_scope("ffn.experts"):
             if valid is None:
@@ -179,6 +197,17 @@ class MoE(nn.Module):
                     y, eaux = experts(flat, gates, idx,
                                       valid=valid.reshape(-1))
         aux.update(eaux)
+
+        if self.identity_experts:
+            with device_scope("ffn.identity"):
+                chose = (idx >= self.num_experts) & valid.reshape(-1, 1)
+                weight = jnp.sum(jnp.where(chose, gates, 0.0), axis=-1)
+                y = (y.astype(jnp.float32) + weight[:, None]
+                     * flat.astype(jnp.float32)).astype(y.dtype)
+                identity = jnp.sum(chose).astype(jnp.int32)
+                kept, dropped, elsewhere = aux["assignments"]
+                aux["assignments"] = jnp.stack(
+                    [kept, dropped, elsewhere - identity, identity])
 
         if self.shared_expert_intermediate > 0:
             with device_scope("ffn.shared"):

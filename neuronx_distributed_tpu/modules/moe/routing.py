@@ -113,6 +113,39 @@ class RouterSigmoid(RouterBase):
         return gates.astype(jnp.float32), idx, aux
 
 
+class RouterSoftmaxBias(RouterBase):
+    """Softmax router with a selection bias over slots that need not all
+    be experts (LongCat-Flash): ``p = softmax(logits)`` over every slot;
+    the ``top_k`` largest ``p + bias`` are chosen (``bias``, the
+    load-balancing correction, takes no gradient from the task; equal
+    scores take the lower index, as ``lax.top_k`` does) and weighed by
+    ``p`` alone, **not** normalised over the chosen, times ``scale``
+    (``routed_scaling_factor``). What a slot is (an expert held here, one
+    held elsewhere, an identity expert) is :class:`.model.MoE`'s business:
+    ``num_experts`` here counts the slots."""
+
+    top_k: int = 2
+    scale: float = 1.0
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array, Dict]:
+        logits = self.logits(x)  # [T, slots]
+        bias = self.param(
+            "bias", nn.with_partitioning(nn.initializers.zeros_init(),
+                                         (None,)),
+            (self.num_experts,), jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias.astype(jnp.float32)),
+            self.top_k)
+        gates = jnp.take_along_axis(probs, idx, axis=-1) * self.scale
+        mask = jnp.sum(jax.nn.one_hot(idx, self.num_experts,
+                                      dtype=jnp.float32), axis=1)
+        aux = {"load_balance_loss": _load_balance_loss(probs, mask),
+               "z_loss": _z_loss(logits)}
+        return gates.astype(jnp.float32), idx, aux
+
+
 class RouterSinkhorn(RouterBase):
     """Sinkhorn-balanced top-1 router (reference ``RouterSinkhorn:213``):
     iteratively normalise the token×expert matrix toward doubly-stochastic

@@ -579,6 +579,112 @@ def test_latent_forward_writes_its_rows_in_place(chip, on_one_chip):
     assert big and all(pattern.search(x) for x in big), big
 
 
+# -- the latent cache at two attentions a decoder layer (LongCat-Flash-Chat) -----
+# The cell longcat-flash-chat.serve-agentic: 128 rows, 64 heads over one row
+# of a 512 latent and a 64 rotary key, 160 table columns, 3,328 blocks of
+# 128, 8 layers of rows for 4 double layers, 16 of 512 experts held.
+
+def test_mla_paged_attention_at_64_heads(chip):
+    """A tile of 8 rows x 64 heads (512 stacked rows) fits and tiles: a
+    decode row's blocks run by eight over its own 64 stacked rows, a
+    tile's shared blocks a unit each (float32 scores [512, 128] are
+    ``SCORE_BYTES`` already)."""
+    from neuronx_distributed_tpu.ops import mla_attention as mla
+
+    tokens, heads, rank, bs, cols, nb, layers = 128, 64, 512, 128, 160, \
+        3328, 8
+    row = mla.row_width(rank, 64)
+    assert row == 640 and mla.stacked_heads(heads) == 64
+    fn = functools.partial(mla._mla_attention_pallas, rank=rank,
+                           scale=192 ** -0.5, interpret=False)
+    text = _assert_kernel_compiles(
+        fn, chip((tokens, heads, row), jnp.bfloat16),
+        chip((layers, nb, bs, row), jnp.bfloat16), chip((nb, bs), jnp.int32),
+        chip((tokens, cols), jnp.int32), chip((tokens,), jnp.int32),
+        chip((), jnp.int32))
+    assert _kernel_instruction_names(text) == {"mla_paged_attention"}
+    assert {"tpu.matmul", "tpu.enqueue_dma"} <= _mosaic_ops(text)
+    assert mla._unit_lengths(64, 8 * 64, row, bs, 2) == (8, 1)
+
+
+def test_double_layer_latent_step_at_the_published_widths(chip, topo,
+                                                          on_one_chip):
+    """The packed step of the cell's configuration file: it compiles for
+    the chip with the latent kernel in it, holds what the configuration
+    says it holds, writes the row stack in place (temporaries under a
+    layer of rows), opens the scopes the double layer names and no
+    fusion with a matmul inside reads another layer's."""
+    import re
+
+    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
+                                                            sample)
+    from neuronx_distributed_tpu.obs.device_scopes import (device_scope,
+                                                           scope_of)
+
+    config, models = _cell_config("longcat-flash-chat", None)
+    assert set(config["reduced"]) == {"num_layers", "n_routed_experts",
+                                      "vocab_size"}
+    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
+                                                         models)
+    nb = config["serve"]["num_blocks"]
+    assert cache.rows.shape == (8, nb, 128, 640)
+    assert cache.moe_counts.shape == (4,)
+    layer = params["params"]["model"]["layers_double"]["layer"]
+    assert layer["moe"]["experts"]["gate"].shape == (4, 16, 6144, 2048)
+    assert layer["moe"]["router"]["kernel"].shape == (4, 6144, 768)
+    assert layer["attn_1"]["k_up"].shape == (4, 64, 128, 512)
+
+    def step_fn(params, cache, tokens, positions, slot_ids, rng):
+        logits, cache = forward(cfg, params, tokens, positions, cache,
+                                slot_ids=slot_ids)
+        with device_scope("sample"):
+            return sample(logits[0], rng, SamplingConfig()), cache
+
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
+        chip(rng.shape, rng.dtype)).compile()
+    text = compiled.as_text()
+    _STEP_TEXTS.setdefault(("longcat-flash-chat", None), text)
+    assert _kernel_instruction_names(text) == {"mla_paged_attention"}
+    gib = 2.0 ** 30
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) / gib
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params)) / gib
+    assert 9.6 < weights < 9.7                       # 5,172.7M in bfloat16
+    assert mem.temp_size_in_bytes < cache.rows.size * 2 / 8   # one layer's
+    aot = config["assumed"]["serve_aot_gib"]
+    assert abs(held - aot["total"]) < 0.05 and 0.85 <= held / 15.75 <= 0.90
+    assert abs(held / 15.75 - aot["of_chip"]) < 0.005
+
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape == f"8,{nb},128,640"]
+    assert len(stacks) == 1 and set(stacks) <= aliased, (stacks, header)
+    # no layer's experts or dense feed-forward is sliced out of its stack
+    large = _top_level_results(text, 6144 * 2048)
+    assert not [r for r in large if re.search(
+        r"\[(16,)?(6144,2048|2048,6144)\]|\[(6144,12288|12288,6144)\]",
+        r[2])]
+
+    total, differ, kernels = scope_disagreements(text)
+    assert total > 0 and kernels == {"attn.kernel"}
+    top = [d for d in differ if d[1].split(".")[0] != d[2].split(".")[0]]
+    assert sum(d[3] for d in top) <= 0.02 * total, top
+    fusions, _ = _matmul_fusions(text)
+    assert {scope_of(own) for _, own, _, _ in fusions} == {
+        "attn.proj", "ffn.dense", "ffn.experts", "ffn.router", "head"}
+    seen = {scope_of(m) for m in re.findall(r'op_name="([^"]*)"', text)}
+    assert {"ffn.identity", "ffn", "attn.pool_write", "attn.walk", "norm",
+            "embed", "sample"} <= seen
+
+
 # -- grouped GLU decode (MoE serving) at OLMoE's widths (ROADMAP R1): hidden
 # 2048, expert width 1024. Mixtral's 4096 compiles too, in about ten seconds.
 
@@ -765,7 +871,7 @@ def test_train_scan_writes_gate_and_up_gradients_in_place(chip):
 # cell's configuration, layers compiled (None: the cell's own)
 _SCOPED_STEPS = {"mistral-7b-serve": 2, "mixtral-8x7b": 2, "evabyte-6.5b": 2,
                  "minicpm-sala-9b": None, "glm-4.7-flash": None,
-                 "mistral-7b": 2}
+                 "longcat-flash-chat": None, "mistral-7b": 2}
 
 
 def _matmul_fusions(hlo_text):
@@ -1473,7 +1579,8 @@ def test_delta_rule_state_pool_step_at_the_published_widths(
 
 @pytest.mark.parametrize("config_name, layers", [
     ("mistral-7b-serve", 2), ("evabyte-6.5b", 2),
-    ("minicpm-sala-9b", None), ("glm-4.7-flash", None)])
+    ("minicpm-sala-9b", None), ("glm-4.7-flash", None),
+    ("longcat-flash-chat", None)])
 def test_the_engines_step_donates_its_pool_and_not_the_hosts_leaves(
         chip, on_one_chip, monkeypatch, config_name, layers):
     import re

@@ -39,7 +39,8 @@ SERVING = {"llama": "tiny-mistral-serve", "mixtral": "tiny-mixtral",
            "granite_hybrid": "tiny-granite-hybrid",
            "laguna": "tiny-laguna",
            "mimo_v2_flash": "tiny-mimo-v2-flash",
-           "solar_open2": "tiny-solar-open2"}
+           "solar_open2": "tiny-solar-open2",
+           "longcat_flash": "tiny-longcat-flash"}
 #: the children a family's step must open, and no other family's may
 OWN = {"attn.select": {"minicpm_sala"},
        "attn.state": {"minicpm_sala", "granite_hybrid", "solar_open2"},
@@ -48,9 +49,9 @@ OWN = {"attn.select": {"minicpm_sala"},
        "attn.kernel.full": {"laguna", "mimo_v2_flash"},
        "attn.kernel.window": {"laguna", "mimo_v2_flash"},
        "ffn.experts": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
-                       "solar_open2"},
+                       "solar_open2", "longcat_flash"},
        "ffn.router": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
-                      "solar_open2"},
+                      "solar_open2", "longcat_flash"},
        "ffn.shared": {"glm_moe_lite", "laguna", "solar_open2"}}
 #: the operations that carry a step's device time
 HEAVY = ("stablehlo.dot_general", "stablehlo.custom_call",
@@ -269,7 +270,8 @@ def test_a_step_opens_the_children_its_family_has_and_no_others(which):
     for child, families in OWN.items():
         assert (child in seen) == (which in families), (child, seen)
     dense = which in ("llama", "evabyte", "minicpm_sala", "glm_moe_lite",
-                      "granite_hybrid", "laguna", "mimo_v2_flash", "train")
+                      "granite_hybrid", "laguna", "mimo_v2_flash",
+                      "longcat_flash", "train")
     assert ("ffn.dense" in seen) == dense
     if which == "train":
         assert "optimizer" not in seen      # elementwise: no heavy operation
@@ -283,6 +285,15 @@ def test_a_serving_step_samples_under_its_own_scope(which):
     paths = {path for _, path in _operations(_lowered(which))}
     assert any(scope_of(p) == "sample" for p in paths)
     assert not any(scope_of(p) in ("loss", "optimizer") for p in paths)
+
+
+@pytest.mark.parametrize("which", list(SERVING))
+def test_the_identity_experts_term_has_a_scope_of_its_own(which):
+    """No heavy operation (a sum of a row's identity weights and a scaled
+    add), so the children's table above cannot see it: the step of the
+    one family that has identity experts opens ``ffn.identity``."""
+    scopes = {scope_of(path) for _, path in _operations(_lowered(which))}
+    assert ("ffn.identity" in scopes) == (which == "longcat_flash")
 
 
 def test_the_train_step_marks_its_loss_and_its_optimizer():

@@ -946,10 +946,16 @@ DECLARED["mimo_v2"] = DECLARED["laguna"]
 #: assignments its step counts into the kind's ``moe_counts``
 DECLARED["solar_open2"] = {**DECLARED["granite_hybrid"], **_MOE,
                            "nxd_moe_held_total": ("held", "elsewhere")}
+#: the second latent family: GLM's, and what a router that also scores
+#: identity experts over a share of the real ones counts
+DECLARED["longcat_flash"] = {**DECLARED["glm_moe_lite"],
+                             "nxd_moe_held_total": ("held", "elsewhere"),
+                             "nxd_moe_identity_total": ("identity", "routed")}
 #: the leaves a family's step counts into on the device, and their lengths
 ON_DEVICE = {"minicpm_sala": {"counts": 10}, "glm_moe_lite": {"moe_counts": 2},
              "laguna": {"moe_counts": 3}, "mimo_v2": {"moe_counts": 3},
-             "solar_open2": {"moe_counts": 3}}
+             "solar_open2": {"moe_counts": 3},
+             "longcat_flash": {"moe_counts": 4}}
 
 
 def _tiny_family(which):
@@ -959,6 +965,8 @@ def _tiny_family(which):
         f"neuronx_distributed_tpu.models.{which}")
     if which == "glm_moe_lite":     # a config alone: nothing is built
         return module.GlmMoeLiteConfig().serving_family()
+    if which == "longcat_flash":
+        return module.LongcatFlashConfig().serving_family()
     make = tiny_moe_config if which == "mixtral" else module.tiny_config
     return make().serving_family()
 
@@ -982,13 +990,24 @@ def test_a_family_declares_its_steps_counters(which):
         read = leaf.read(np.arange(1, leaf.entries + 1))
         assert {n: len(v) for n, v in read.items()} \
             == {c.name: max(1, len(c.kinds)) for c, _ in leaf.reads}
-        # every entry of the leaf is read, each kind from entries of its own
+        # every entry of the leaf is read, each kind of a family from
+        # entries of its own
         used = [i for _, entries in leaf.reads for e in entries for i in e]
         assert set(used) == set(range(leaf.entries))
+        for _, entries in leaf.reads:
+            own = [i for e in entries for i in e]
+            assert len(own) == len(set(own))
     if which in ("laguna", "mimo_v2", "solar_open2"):
         assert leaves[0].read(np.array([5, 2, 4])) == {
             "nxd_moe_assignments_total": [5, 2],
             "nxd_moe_held_total": [7, 4]}
+    if which == "longcat_flash":
+        # [kept, dropped, elsewhere, identity]: the first three are of the
+        # real experts, and together the routed choices
+        assert leaves[0].read(np.array([5, 2, 4, 6])) == {
+            "nxd_moe_assignments_total": [5, 2],
+            "nxd_moe_held_total": [7, 4],
+            "nxd_moe_identity_total": [6, 11]}
 
 
 @pytest.mark.parametrize("which", list(DECLARED))
