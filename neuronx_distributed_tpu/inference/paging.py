@@ -174,6 +174,14 @@ MLA_BLOCK_FETCHES = CounterFamily(
     "all of them); alone, a one-row pair that is a unit by itself; whole, "
     "a pair that rows of the tile share, computed over the whole tile.",
     ("in_run", "alone", "whole"))
+MLA_SHARED_BLOCKS = CounterFamily(
+    "nxd_mla_shared_blocks_total",
+    "Pool blocks that rows of a tile share (nxd_mla_block_fetches_total's "
+    "whole) by the unit of the mla_paged_attention kernel they rode: "
+    "in_unit, with one or more other shared blocks of the tile in one unit "
+    "(one ring half of copies, one step of the online softmax a slab of "
+    "the tile over all of them); alone, a unit by itself.",
+    ("in_unit", "alone"))
 STATE_RESETS = CounterFamily(
     "nxd_state_resets_total",
     "Packed rows at position 0: each starts its slot's per-slot states (a "
@@ -681,19 +689,26 @@ class LatentCache(FullCache):
     #: how the kind lays out the ``moe_counts`` of a family that declares it
     moe_leaf: DeviceCounts = MOE_KEPT_DROPPED
     name = "latent"
-    counters = (PAGED_COLUMNS, PAGED_BLOCK_VISITS, MLA_BLOCK_FETCHES)
+    counters = (PAGED_COLUMNS, PAGED_BLOCK_VISITS, MLA_BLOCK_FETCHES,
+                MLA_SHARED_BLOCKS)
 
     def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
                    held: Sequence[int], rolled: int) -> Dict[str, Any]:
         """As :meth:`FullCache.count_step`, with the pool blocks by how the
         mla_paged_attention kernel's units come by them in place of the
-        paged kernel's pairs (it takes its pairs in runs)."""
-        from ..ops.mla_attention import block_fetches
+        paged kernel's pairs (it takes its pairs in runs), and the shared
+        ones by the unit they rode."""
+        from ..ops import mla_attention as mla
+        from ..ops.paged_attention import host_pairs
 
         counts, served = _count_walk(self, geo, PAGED_COLUMNS, positions,
                                      slot_ids, tables)
-        counts[MLA_BLOCK_FETCHES.name] = block_fetches(
-            served, geo.heads, self.row, geo.block_size, geo.itemsize)
+        shapes = (geo.heads, self.row, geo.block_size, geo.itemsize)
+        pairs = host_pairs(served, mla.stacked_heads(geo.heads))
+        counts[MLA_BLOCK_FETCHES.name] = mla.block_fetches(served, *shapes,
+                                                           pairs)
+        counts[MLA_SHARED_BLOCKS.name] = mla.shared_blocks(served, *shapes,
+                                                           pairs)
         return counts
 
     def stack_index(self, layer, which: int = 0):

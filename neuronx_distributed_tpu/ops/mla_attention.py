@@ -22,12 +22,22 @@ Two implementations behind one signature, as :mod:`.paged_attention` has:
   query heads as its ``n_rep``, padded to whole sublanes
   (:func:`stacked_heads`: 20 heads ride as 24) so that a decode row's
   heads are exactly one narrow group of the tile: a tile is ``tile_rows``
-  packed rows (8) times the stacked heads (192 MXU rows). The kernel's
+  packed rows (8) times the stacked heads (192 MXU rows at 24 stacked
+  heads, 512 at 64). The kernel's
   unit of work is a *run* of pairs (:func:`.paged_attention.pair_runs`,
   the one cut of a walk into units, which the paged kernel takes too): up
   to 8 pairs that one row alone names (a decode row's own blocks, against
-  its 24 stacked heads), or up to 4 that rows of the tile share (a chunk's
-  blocks, against the whole tile; :func:`.paged_attention.unit_blocks`).
+  its stacked heads), or as many pairs that rows of the tile share (a
+  chunk's blocks) as the scores of a *slab* of the tile allow
+  (:func:`_unit_lengths`, from the shapes alone: the walk, the kernel and
+  the host's counts call the one rule). A tile of 192 stacked rows is
+  one slab and its shared unit 4 blocks against the whole tile. A tile of
+  512 (64 heads), whose float32 scores would leave a unit one block, is
+  scored in slabs of 2 packed rows (128 stacked rows) over a unit of 4
+  blocks: the statistics of a step are ``[128, 1]`` beside scores ``[128,
+  512]``, whether a packed row names a block is one scalar from its
+  table row (the stacked heads of a packed row share it), and a slab
+  that names none of the unit's blocks is skipped.
   A unit's blocks are copied side by side into one half of a ring while
   the unit before it is computed, scored in one product ``[rows, row] x [row, blocks x
   block_size]`` from the stored operands into float32 and taken through
@@ -50,14 +60,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..inference.kv_cache import PAD_POSITION
-from .paged_attention import (RunWalk, TileWalk, paged_attention_impl,
-                              tile_rows, tile_walk, unit_blocks)
+from .paged_attention import (RunWalk, TileWalk, host_pairs,
+                              paged_attention_impl, tile_rows, tile_walk,
+                              unit_blocks)
 from .paged_attention import _p_times_v_stacked as _p_times_v
 from .paged_attention import block_fetches as _block_fetches
 from .paged_attention import run_walk as _run_walk
 from .pallas_utils import compiler_params as _compiler_params
 
 LANES = 128
+#: stacked rows of a slab of a tile too tall to be scored whole
+#: (:func:`_unit_lengths`): the MXU's rows
+SLAB_ROWS = 128
 
 
 def row_width(rank: int, rope: int) -> int:
@@ -111,34 +125,69 @@ def step_walk(tables, q_pos, block_size: int, num_blocks: int, row: int,
 
 def _unit_lengths(heads: int, wide: int, row: int, block_size: int,
                   itemsize: int):
-    """Blocks of a run of one row's pairs and of a run of pairs that a
-    tile of ``wide`` stacked rows shares
-    (:func:`.paged_attention.unit_blocks` of a block of latent rows)."""
+    """``(run, whole_run, slab)``: blocks of a run of one row's pairs,
+    blocks of a unit of pairs that a tile of ``wide`` stacked rows shares,
+    and the stacked rows such a unit is scored at a time
+    (:func:`.paged_attention.unit_blocks` of a block of latent rows). A
+    tile whose scores leave room for two blocks or more is one slab (192
+    rows of 24 stacked heads: 4 blocks). A taller one, which would take
+    its shared pairs a block a unit, is scored in slabs of whole packed
+    rows, halved until the slab is :data:`SLAB_ROWS` stacked rows or one
+    packed row's heads, and a unit is as many blocks as a slab's scores
+    allow (512 rows of 64 heads: slabs of 128, 4 blocks)."""
     block_bytes = block_size * row * itemsize
-    return (unit_blocks(heads, block_bytes, block_size),
-            unit_blocks(wide, block_bytes, block_size))
+    slab, whole_run = wide, unit_blocks(wide, block_bytes, block_size)
+    if whole_run == 1:
+        while slab > max(SLAB_ROWS, heads) and slab % (2 * heads) == 0:
+            slab //= 2
+        whole_run = unit_blocks(slab, block_bytes, block_size)
+    return unit_blocks(heads, block_bytes, block_size), whole_run, slab
 
 
 def run_walk(walk: TileWalk, num_blocks: int, heads: int, row: int,
              block_size: int, itemsize: int) -> RunWalk:
     """:func:`.paged_attention.run_walk` of a step's walk at the lengths
     the kernel takes for these shapes."""
-    run, whole_run = _unit_lengths(heads, walk.served.shape[1], row,
-                                   block_size, itemsize)
+    run, whole_run, _ = _unit_lengths(heads, walk.served.shape[1], row,
+                                      block_size, itemsize)
     # the kernel reads a shared unit's pairs to its full length, a run's
     # as far as the run goes
     return _run_walk(walk, num_blocks, heads, run, whole_run, room=whole_run)
 
 
 def block_fetches(served, num_heads: int, row: int, block_size: int,
-                  itemsize: int) -> np.ndarray:
+                  itemsize: int, pairs=None) -> np.ndarray:
     """:func:`.paged_attention.block_fetches` of one layer of a packed
     step, ``[in_run, alone, whole]``, at the run the kernel takes for
-    these shapes (``nxd_mla_block_fetches_total``)."""
+    these shapes (``nxd_mla_block_fetches_total``; ``pairs``:
+    :func:`.paged_attention.host_pairs` of the same step at
+    :func:`stacked_heads`, where the caller has them)."""
     heads = stacked_heads(num_heads)
-    run, _ = _unit_lengths(heads, tile_rows(heads, len(served)) * heads, row,
-                           block_size, itemsize)
-    return _block_fetches(served, heads, run)
+    run, _, _ = _unit_lengths(heads, tile_rows(heads, len(served)) * heads,
+                              row, block_size, itemsize)
+    return _block_fetches(served, heads, run, pairs)
+
+
+def shared_blocks(served, num_heads: int, row: int, block_size: int,
+                  itemsize: int, pairs=None) -> np.ndarray:
+    """The pairs that rows of a tile share (``block_fetches``' ``whole``)
+    of one layer of a packed step by the unit they rode, ``[in_unit,
+    alone]``: with one or more other blocks in one unit over the tile (one
+    ring half of copies, one step of the online softmax a slab), or a
+    unit by itself: every one where a unit is one block, else a tile's
+    last where its count leaves one over. What :func:`run_walk` makes of
+    the step, counted on the host at the unit the kernel takes for these
+    shapes (``nxd_mla_shared_blocks_total``; ``pairs``:
+    :func:`.paged_attention.host_pairs` of the same step at
+    :func:`stacked_heads`)."""
+    heads = stacked_heads(num_heads)
+    wide = tile_rows(heads, len(served)) * heads
+    _, whole_run, _ = _unit_lengths(heads, wide, row, block_size, itemsize)
+    *_, start, group = pairs or host_pairs(served, heads)
+    # a tile's shared pairs are cut into units, the last shorter
+    many = np.bincount((group - start)[start < 0] // wide)
+    alone = many if whole_run == 1 else many % whole_run == 1
+    return np.array([many.sum() - alone.sum(), alone.sum()], np.int64)
 
 
 def absorb_queries(q_nope, q_rope, k_up, row: int):
@@ -180,7 +229,7 @@ def _mla_attention_xla(q, pool, pool_pos, tables, q_pos, layer, rank, scale):
 def _mla_kernel(units_ref, blocks_ref, cols_ref, narrow_ref, lens_ref,
                 layer_ref, served_ref, qpos_ref, q_ref, pool_hbm, pos_hbm,
                 o_ref, row_buf, pos_buf, sems, m_ref, l_ref, acc_ref, *,
-                pairs: int, group: int, run: int, whole_run: int,
+                pairs: int, group: int, run: int, whole_run: int, slab: int,
                 scale: float, rank: int):
     """One tile of packed rows (every row's heads stacked) against the
     pool blocks its rows attend, a unit of the walk at a time
@@ -191,8 +240,10 @@ def _mla_kernel(units_ref, blocks_ref, cols_ref, narrow_ref, lens_ref,
     other, and are one step of the online softmax
     (:func:`.paged_attention._paged_kernel`'s, over ``blocks x
     block_size`` positions) of the rows it serves: one group's for a run
-    of one row's pairs, the tile's for pairs that its rows share, each
-    block under the mask of the rows that name it."""
+    of one row's pairs, the tile's for pairs that its rows share (the
+    tile whole, or ``slab`` stacked rows of it at a time where it is
+    taller: ``served_ref`` is then the packed rows' table rows in SMEM),
+    each block under the mask of the rows that name it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -201,6 +252,7 @@ def _mla_kernel(units_ref, blocks_ref, cols_ref, narrow_ref, lens_ref,
     layer = layer_ref[0]
     base = tile * pairs
     bs = pool_hbm.shape[2]
+    wide = q_ref.shape[0]
     operand = (jnp.bfloat16 if q_ref.dtype == jnp.bfloat16
                and row_buf.dtype != jnp.float32 else jnp.float32)
 
@@ -272,8 +324,9 @@ def _mla_kernel(units_ref, blocks_ref, cols_ref, narrow_ref, lens_ref,
             ok = (pos <= qpos_ref[rows, :]) & (lane < n * bs)
             attend(rows, ok, row_buf[side], row_buf[side, :, :rank])
 
-        @pl.when(start < 0)
-        def _whole():
+        span = whole_run * bs
+
+        def whole():
             served = served_ref[...]                    # [wide, maxb]
             column = jax.lax.broadcasted_iota(jnp.int32, served.shape, 1)
             ok = []
@@ -285,10 +338,47 @@ def _mla_kernel(units_ref, blocks_ref, cols_ref, narrow_ref, lens_ref,
                     axis=1, keepdims=True) > 0          # [wide, 1]
                 ok.append((pos_buf[side, :, k * bs:(k + 1) * bs]
                            <= qpos_ref[...]) & named & (k < n))
-            span = whole_run * bs
             attend(slice(None), jnp.concatenate(ok, axis=1),
                    row_buf[side, :span], row_buf[side, :span, :rank])
 
+        def slabs():
+            # a tall tile, a slab of whole packed rows at a time. The
+            # stacked heads of a packed row share its table row, so
+            # whether it names a block is one scalar (``served_ref``: the
+            # tile's packed rows, in SMEM), and a slab none of whose rows
+            # names a block of the unit (rows past a chunk's end, decode
+            # rows beside it) is skipped
+            each = slab // group
+
+            def one(s, carry):
+                named = [[(served_ref[s * each + r,
+                                      cols_ref[base + first + k]]
+                           == blocks_ref[base + first + k]) & (k < n)
+                          for r in range(each)] for k in range(whole_run)]
+
+                @pl.when(functools.reduce(
+                    jnp.logical_or, (x for of in named for x in of)))
+                def _attend():
+                    rows = pl.ds(pl.multiple_of(s * slab, slab), slab)
+                    q_pos = qpos_ref[rows, :]               # [slab, 1]
+                    packed = jax.lax.broadcasted_iota(
+                        jnp.int32, q_pos.shape, 0) // group
+                    ok = []
+                    for k, of in enumerate(named):
+                        live = jnp.zeros_like(q_pos)
+                        for r, mine in enumerate(of):
+                            live = jnp.where(packed == r,
+                                             mine.astype(jnp.int32), live)
+                        ok.append((pos_buf[side, :, k * bs:(k + 1) * bs]
+                                   <= q_pos) & (live > 0))
+                    attend(rows, jnp.concatenate(ok, axis=1),
+                           row_buf[side, :span], row_buf[side, :span, :rank])
+
+                return carry
+
+            jax.lax.fori_loop(0, wide // slab, one, 0)
+
+        pl.when(start < 0)(whole if slab == wide else slabs)
         return first + n
 
     jax.lax.fori_loop(0, units, unit, 0)
@@ -311,10 +401,20 @@ def _mla_attention_pallas(q, pool, pool_pos, tables, q_pos, layer, rank,
     tiles, wide, _ = walk.served.shape          # wide = rows * heads
     rows = wide // heads
     pairs = rows * maxb
-    run, whole_run = _unit_lengths(heads, wide, row, bs, pool.dtype.itemsize)
+    run, whole_run, slab = _unit_lengths(heads, wide, row, bs,
+                                         pool.dtype.itemsize)
 
     def row_block(last):
         return pl.BlockSpec((None, wide, last), lambda i, *_: (i, 0, 0))
+
+    served, served_block = walk.served, row_block(maxb)
+    if slab < wide:
+        # slabs ask a packed row's table row for a scalar: every
+        # ``heads``-th of the tile's stacked rows, in SMEM
+        served = served[:, ::heads]
+        served_block = pl.BlockSpec((None, rows, maxb),
+                                    lambda i, *_: (i, 0, 0),
+                                    memory_space=pltpu.SMEM)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     # a tile's queries, each row's heads stacked (and padded with heads
@@ -323,12 +423,12 @@ def _mla_attention_pallas(q, pool, pool_pos, tables, q_pos, layer, rank,
                       ).reshape(tiles, wide, row)
     out = pl.pallas_call(
         functools.partial(_mla_kernel, pairs=pairs, group=heads, run=run,
-                          whole_run=whole_run, scale=scale, rank=rank),
+                          whole_run=whole_run, slab=slab, scale=scale,
+                          rank=rank),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
             grid=(tiles,),
-            in_specs=[row_block(maxb), row_block(1), row_block(row), hbm,
-                      hbm],
+            in_specs=[served_block, row_block(1), row_block(row), hbm, hbm],
             out_specs=row_block(rank),
             scratch_shapes=[
                 pltpu.VMEM((2, run * bs, row), pool.dtype),
@@ -342,7 +442,7 @@ def _mla_attention_pallas(q, pool, pool_pos, tables, q_pos, layer, rank,
         compiler_params=None if interpret else _compiler_params(),
         name="mla_paged_attention",
     )(walk.units, walk.blocks, walk.cols, walk.narrow, walk.lens,
-      jnp.asarray(layer, jnp.int32).reshape(1), walk.served, walk.q_pos,
+      jnp.asarray(layer, jnp.int32).reshape(1), served, walk.q_pos,
       q_tiles, pool, pool_pos.reshape(nb, 1, bs))
     return out.reshape(tiles * rows, heads, rank)[:t, :n]
 

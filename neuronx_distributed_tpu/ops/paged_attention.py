@@ -518,8 +518,9 @@ def unit_blocks(rows: int, block_bytes: int, block_size: int) -> int:
     two, 8 at most, whose two ring halves fit :data:`RING_BYTES` and
     whose scores ``[rows, blocks x block_size]`` fit :data:`SCORE_BYTES`.
     The latent kernel's rows of 640 bf16 lanes in blocks of 128 (160
-    KiB): 8 blocks for a run of 24 stacked rows and 4 for a tile of 192
-    (``PERF.md``, Findings, PR 39). The paged kernel's runs: 8 for a
+    KiB): 8 blocks for a run of 24 or 64 stacked rows, 4 for a tile of
+    192 and for a slab of 128 of a tile of 512, which would take 1 whole
+    (``PERF.md``, Findings, PR 39 and 54). The paged kernel's runs: 8 for a
     block of 320 KiB (4 K heads of 192 beside 4 V heads of 128) or less,
     4 for 512 KiB (8 K/V heads of 128), 1 for 2 MiB (32 heads), which is
     the walk and the kernel without runs (``PERF.md``, Findings, PR 46)."""

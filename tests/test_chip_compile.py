@@ -493,7 +493,7 @@ def test_mla_paged_attention(chip):
     assert {"tpu.matmul", "tpu.enqueue_dma"} <= _mosaic_ops(text)
     # at these widths a decode row's blocks run by eight (a ring of two
     # halves of 8 x 160 KiB) and a tile's shared blocks by four
-    assert mla._unit_lengths(24, 8 * 24, row, bs, 2) == (8, 4)
+    assert mla._unit_lengths(24, 8 * 24, row, bs, 2) == (8, 4, 192)
 
 
 def test_latent_forward_writes_its_rows_in_place(chip, on_one_chip):
@@ -587,8 +587,9 @@ def test_latent_forward_writes_its_rows_in_place(chip, on_one_chip):
 def test_mla_paged_attention_at_64_heads(chip):
     """A tile of 8 rows x 64 heads (512 stacked rows) fits and tiles: a
     decode row's blocks run by eight over its own 64 stacked rows, a
-    tile's shared blocks a unit each (float32 scores [512, 128] are
-    ``SCORE_BYTES`` already)."""
+    tile's shared blocks by four against slabs of 2 packed rows (float32
+    scores [512, 128] are ``SCORE_BYTES`` already, so the tile is not
+    scored whole; the packed rows' table rows ride in SMEM)."""
     from neuronx_distributed_tpu.ops import mla_attention as mla
 
     tokens, heads, rank, bs, cols, nb, layers = 128, 64, 512, 128, 160, \
@@ -604,7 +605,7 @@ def test_mla_paged_attention_at_64_heads(chip):
         chip((), jnp.int32))
     assert _kernel_instruction_names(text) == {"mla_paged_attention"}
     assert {"tpu.matmul", "tpu.enqueue_dma"} <= _mosaic_ops(text)
-    assert mla._unit_lengths(64, 8 * 64, row, bs, 2) == (8, 1)
+    assert mla._unit_lengths(64, 8 * 64, row, bs, 2) == (8, 4, 128)
 
 
 def test_double_layer_latent_step_at_the_published_widths(chip, topo,

@@ -377,10 +377,19 @@ LOWERED_AT_PR_48 = {
 }
 
 
+#: and the second latent family's (the shortcut-connected double layer
+#: over two layers of rows), as PR 54's tree lowers it, which is how PR
+#: 53's did: the one family that had no recorded text
+LOWERED_AT_PR_54 = {
+    "longcat_flash":
+        "ea14780e48fd92dc155782f7b3801ed414c66a3400b83e42d8267d6457fd76bd",
+}
+
+
 @pytest.mark.parametrize("which", list(LOWERED_AT_PR_38)
                          + list(LOWERED_AT_PR_39) + list(LOWERED_AT_PR_42)
                          + list(LOWERED_AT_PR_43) + list(LOWERED_AT_PR_45)
-                         + list(LOWERED_AT_PR_48))
+                         + list(LOWERED_AT_PR_48) + list(LOWERED_AT_PR_54))
 def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     """PR 39 changed the latent kernel and its walk alone: the packed
     steps of the five families that run the shared helpers of
@@ -400,11 +409,20 @@ def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     PR 48 gave the state-pool kind a ``moe_counts`` leaf, built only where
     a family declares it, and a counter of held bytes that the host
     counts: Granite's step, Laguna's and MiMo's are the text they were,
-    and the delta-rule family's is recorded. A PR that changes one of
-    these programs on purpose records its new hash here."""
+    and the delta-rule family's is recorded. PR 54 changed the latent
+    kernel's shared unit (``ops/mla_attention.py``: slabs of a tall tile,
+    a unit's blocks from the slab's scores) and a docstring of
+    ``ops/paged_attention.py``: the nine others are the text they were,
+    GLM's among them (its tile is one slab), and the second latent
+    family's step, the one without a recorded text, is recorded as that
+    tree and its parent lower it (off the chip a latent step gathers:
+    the kernel's own text is ``tests/test_chip_compile.py``'s). A PR
+    that changes one of these programs on purpose records its new hash
+    here."""
     import hashlib
 
     text = _stripped(_lowered(which))
     assert hashlib.sha256(text.encode()).hexdigest() == {
         **LOWERED_AT_PR_38, **LOWERED_AT_PR_39, **LOWERED_AT_PR_42,
-        **LOWERED_AT_PR_43, **LOWERED_AT_PR_45, **LOWERED_AT_PR_48}[which]
+        **LOWERED_AT_PR_43, **LOWERED_AT_PR_45, **LOWERED_AT_PR_48,
+        **LOWERED_AT_PR_54}[which]

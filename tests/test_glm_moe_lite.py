@@ -251,13 +251,20 @@ def test_kernel_equals_the_xla_path_at_the_published_row(dtype, atol):
     # and the kernel's units of it, at the lengths these shapes take
     live = np.asarray(pa.column_live(tok_tables, np.arange(maxb),
                                      q_pos[:, None], bs))
-    lengths = mla._unit_lengths(24, 8 * 24, row, bs, 4)
+    lengths = mla._unit_lengths(24, 8 * 24, row, bs, 4)[:2]
+    units = np.zeros((2,), np.int64)
     kinds = check_tile_walk(
         type(walk)(*(None if x is None else np.asarray(x) for x in walk)),
         live, tok_tables, 8, 24,
-        runs=(mla.run_walk(walk, nb, 24, row, bs, 4), *lengths))
+        runs=(mla.run_walk(walk, nb, 24, row, bs, 4), *lengths),
+        shared_units=units)
+    served = np.where(live, tok_tables, -1)
     assert tuple(kinds) == tuple(mla.block_fetches(
-        np.where(live, tok_tables, -1), n, row, bs, 4)) == (6, 2, 6)
+        served, n, row, bs, 4)) == (6, 2, 6)
+    # the first tile's three shared pairs are one unit, the second's
+    # three another
+    assert tuple(units) == tuple(mla.shared_blocks(
+        served, n, row, bs, 4)) == (6, 0)
 
 
 def _latent_scene(lengths, shared=(), nb=48, bs=16, maxb=12, n=20,
@@ -369,12 +376,16 @@ def test_a_decode_rows_blocks_are_walked_in_runs(case, monkeypatch):
     walk = pa.tile_walk(jnp.asarray(tok_tables, jnp.int32),
                         jnp.asarray(q_pos, jnp.int32), bs, nb, 24)
     runs = mla.run_walk(walk, nb, 24, 256, bs, 4)
+    units = np.zeros((2,), np.int64)
     kinds = check_tile_walk(
         type(walk)(*(None if x is None else np.asarray(x) for x in walk)),
-        live, tok_tables, 8, 24, runs=(runs, 4, 2))
-    fetches = mla.block_fetches(np.where(live, tok_tables, -1), n, 256, bs,
-                                4)
+        live, tok_tables, 8, 24, runs=(runs, 4, 2), shared_units=units)
+    served = np.where(live, tok_tables, -1)
+    fetches = mla.block_fetches(served, n, 256, bs, 4)
     assert tuple(fetches) == tuple(kinds) == counts
+    # the shared pairs in units of 2: a tile's odd one out is alone
+    assert tuple(mla.shared_blocks(served, n, 256, bs, 4)) == tuple(units)
+    assert units.sum() == counts[2] and units[1] == counts[2] % 2
     assert fetches.sum() == int(np.asarray(walk.count).sum())
 
 
@@ -520,6 +531,7 @@ def served():
         for name in ("nxd_moe_assignments_total", "nxd_paged_columns_total",
                      "nxd_paged_block_visits_total",
                      "nxd_mla_block_fetches_total",
+                     "nxd_mla_shared_blocks_total",
                      "nxd_engine_rows_total")}
     check_registered_counters(obs.get_registry(), cfg.serving_family())
     obs.disable()
@@ -567,6 +579,10 @@ def test_the_counters_of_the_latent_walk_and_of_the_experts(served):
     assert set(fetches) == {"in_run", "alone", "whole"}
     assert sum(fetches.values()) == visits["fetched"]
     assert fetches["in_run"] > 0 and fetches["whole"] > 0
+    # and every shared one rode a unit of several blocks or was one
+    shared = counters["nxd_mla_shared_blocks_total"]
+    assert set(shared) == {"in_unit", "alone"}
+    assert sum(shared.values()) == fetches["whole"] and shared["in_unit"] > 0
 
 
 def test_prefix_sharing_maps_latent_blocks_and_copies_on_write():

@@ -46,6 +46,7 @@ if BENCH not in sys.path:
 import harness  # noqa: E402  (benchmarks/)
 from counter_checks import check_registered_counters  # noqa: E402  (tests/)
 from longcat_faults import faults  # noqa: E402  (tests/)
+from walk_checks import check_tile_walk  # noqa: E402  (tests/)
 from runners import serve  # noqa: E402
 
 BS = 16
@@ -374,53 +375,165 @@ def test_four_shares_routed_sums_and_one_identity_term_are_the_layer():
 
 def test_the_walk_at_24_stacked_heads_is_what_it_was_and_64_heads_tile():
     """GLM's 20 heads ride as 24 in a tile of 8 rows, a decode row's run
-    8 blocks and a tile's shared pairs 4, as before this family; 64 heads
-    are their own number of whole sublanes, a tile of 8 rows is 512
-    stacked rows, a run 8 blocks and a shared pair a unit by itself: all
-    from the shapes (``unit_blocks``), no family's name."""
+    8 blocks and a tile's shared pairs 4 over the whole tile (one slab of
+    192 rows), as before this family; 64 heads are their own number of
+    whole sublanes, a tile of 8 rows is 512 stacked rows, a run 8 blocks,
+    and a tile that tall (float32 scores ``[512, 128]`` are
+    ``SCORE_BYTES`` already) is scored in slabs of 2 packed rows (128
+    stacked rows), a shared unit 4 blocks: all from the shapes
+    (``unit_blocks``), no family's name."""
     row = mla.row_width(512, 64)
     assert (mla.stacked_heads(20), pa.tile_rows(24, 128)) == (24, 8)
-    assert mla._unit_lengths(24, 8 * 24, row, 128, 2) == (8, 4)
+    assert mla._unit_lengths(24, 8 * 24, row, 128, 2) == (8, 4, 8 * 24)
     assert (mla.stacked_heads(64), pa.tile_rows(64, 128)) == (64, 8)
-    assert mla._unit_lengths(64, 8 * 64, row, 128, 2) == (8, 1)
+    assert mla._unit_lengths(64, 8 * 64, row, 128, 2) == (8, 4, 2 * 64)
     assert pa.narrow_rows(64) == 64
+    # a slab is whole packed rows: 128 heads a row are a slab each, and
+    # a tile that already takes two blocks a unit is not cut
+    assert mla._unit_lengths(128, 8 * 128, row, 128, 2) == (4, 4, 128)
+    assert mla._unit_lengths(32, 8 * 32, row, 128, 2) == (8, 2, 8 * 32)
+    # the tests' blocks of 16 positions are an eighth of the cell's: at
+    # an eighth of the scores' room the rule is the cell's
+    assert mla._unit_lengths(64, 8 * 64, row, 16, 4) == (8, 8, 8 * 64)
 
 
-def test_kernel_equals_the_xla_path_at_64_heads_of_the_published_row():
-    """Rows of 576 values on 640 lanes, values of 512, 64 heads (a tile
-    of 8 rows x 64): a chunk in unaligned pieces, decode rows of which
-    two share prefix blocks, an unmapped row and a pad row."""
-    rng = np.random.RandomState(6)
-    layers, nb, bs, maxb, n, rank, rope = 2, 24, 16, 6, 64, 512, 64
+def _slabs_as_in_the_cell(monkeypatch):
+    """Blocks of 16 positions under scores of 48 KiB: the cell's blocks
+    of 128 under its 384 KiB, so a tile of 512 stacked rows is cut as the
+    cell's is."""
+    monkeypatch.setattr(pa, "SCORE_BYTES", pa.SCORE_BYTES // 8)
+    assert mla._unit_lengths(64, 8 * 64, 640, 16, 4) == (8, 4, 2 * 64)
+
+
+#: rows of a step, ``(slot, position)``, over the slots of
+#: :func:`_scene_64`; a pad row is ``(5, PAD_POSITION)``, slot 4 unmapped
+_PAD = (5, PAD_POSITION)
+_KERNEL_CASES = {
+    # the tile whole (the tests' small blocks leave the scores room): a
+    # chunk in unaligned pieces, decode rows of which two share prefix
+    # blocks, an unmapped row and a pad row
+    "one_slab": (False, [(0, p) for p in range(41, 48)]
+                 + [(1, 39), (2, 89), (3, 4), (4, 7), _PAD]),
+    # a chunk of 20 rows over three tiles (8, 8 and 4 rows): the first
+    # tile's five shared blocks are a unit of four and one alone, the
+    # others' six a unit of four and a short one of two; the third
+    # tile's last two slabs name nothing and are skipped
+    "a_chunk_over_three_tiles": (True, [(2, p) for p in range(70, 90)]),
+    # two chunk rows in the first slab, decode rows in the others: the
+    # shared units skip three slabs of four
+    "slabs_that_name_no_block": (
+        True, [(0, 58), (0, 59), (2, 89), (3, 4), (1, 39), (4, 7), _PAD,
+               _PAD]),
+    # slots 0 and 1 share their first two blocks: packed rows 1 and 2,
+    # in two slabs, each beside a row that names neither block
+    "a_prefix_shared_across_slabs": (
+        True, [(2, 89), (0, 59), (1, 39), (3, 4)]),
+    # and in one slab, with a pad row, an unmapped row and a decode row
+    # beside them
+    "a_prefix_shared_in_one_slab": (
+        True, [(0, 59), (1, 39), _PAD, (4, 7), (2, 89), (3, 4)]),
+}
+
+
+def _scene_64(rng, nb=24, bs=16, maxb=6, rank=512, rope=64):
     row = mla.row_width(rank, rope)
-    pool = rng.randn(layers, nb, bs, row)
+    pool = rng.randn(2, nb, bs, row)
     pool[..., rank + rope:] = 0
     tables = np.full((5, maxb), -1)
     tables[0, :4] = [3, 7, 1, 9]            # 60 positions
     tables[1, :3] = [3, 7, 12]              # shares its first two blocks
     tables[2, :6] = rng.permutation(np.arange(13, 24))[:6]
     tables[3, :1] = [2]
-    lengths = [60, 40, 90, 5]
     pos = np.full((nb, bs), PAD_POSITION)
-    for s, length in enumerate(lengths):
+    for s, length in enumerate([60, 40, 90, 5]):
         for p in range(length):
             pos[tables[s, p // bs], p % bs] = p
-    rows = ([(0, p) for p in range(41, 48)] + [(1, 39), (2, 89), (3, 4)]
-            + [(4, 7)] + [(5, PAD_POSITION)])
+    return pool, pos, tables, row
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("case", list(_KERNEL_CASES))
+def test_kernel_equals_the_xla_path_at_64_heads_of_the_published_row(
+        case, dtype, atol, monkeypatch):
+    """Rows of 576 values on 640 lanes, values of 512, 64 heads (a tile
+    of 8 rows x 64), float32 and bf16 pools, in interpret mode against
+    the gather reference: the tile whole, and cut into slabs of 2 packed
+    rows with shared units of 4 blocks as the cell's is
+    (``_KERNEL_CASES``). The walk serves every live (row, column) once
+    and the host's counts of the fetches and of the shared pairs' units
+    are the walk's."""
+    slabs, rows = _KERNEL_CASES[case]
+    if slabs:
+        _slabs_as_in_the_cell(monkeypatch)
+    rng = np.random.RandomState(6)
+    n, rank, rope, nb, bs = 64, 512, 64, 24, 16
+    pool, pos, tables, row = _scene_64(rng)
     slot, q_pos = (np.array(x) for x in zip(*rows))
     tok_tables = tables[np.minimum(slot, 4)]
     q = rng.randn(len(rows), n, row)
     q[..., rank + rope:] = 0
-    args = (jnp.asarray(q, jnp.float32), jnp.asarray(pool, jnp.float32),
+    args = (jnp.asarray(q, dtype), jnp.asarray(pool, dtype),
             jnp.asarray(pos, jnp.int32), jnp.asarray(tok_tables, jnp.int32),
             jnp.asarray(q_pos, jnp.int32), 1, rank, 192 ** -0.5)
-    want = mla.mla_paged_attention(*args, force_pallas=False)
-    got = mla.mla_paged_attention(*args, force_pallas=True)
+    want = np.asarray(mla.mla_paged_attention(*args, force_pallas=False),
+                      np.float32)
+    got = np.asarray(mla.mla_paged_attention(*args, force_pallas=True),
+                     np.float32)
     assert got.shape == want.shape == (len(rows), n, rank)
-    live = np.arange(len(rows)) < 10
-    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
-                               atol=2e-5)
-    assert (np.asarray(got)[~live] == 0).all()
+    live = (slot < 4) & (q_pos < PAD_POSITION)
+    np.testing.assert_allclose(got[live], want[live], atol=atol)
+    assert (got[~live] == 0).all()
+    if dtype != jnp.float32:
+        return
+    # the walk in the kernel's units, and the host's counts of it
+    lengths = mla._unit_lengths(n, 8 * n, row, bs, 4)
+    attends = np.asarray(pa.column_live(tok_tables, np.arange(6),
+                                        q_pos[:, None], bs))
+    walk = pa.tile_walk(jnp.asarray(tok_tables, jnp.int32),
+                        jnp.asarray(q_pos, jnp.int32), bs, nb, n)
+    units = np.zeros((2,), np.int64)
+    kinds = check_tile_walk(
+        type(walk)(*(None if x is None else np.asarray(x) for x in walk)),
+        attends, tok_tables, 8, n,
+        runs=(mla.run_walk(walk, nb, n, row, bs, 4), *lengths[:2]),
+        shared_units=units)
+    served = np.where(attends, tok_tables, -1)
+    assert tuple(mla.block_fetches(served, n, row, bs, 4)) == tuple(kinds)
+    assert tuple(mla.shared_blocks(served, n, row, bs, 4)) == tuple(units)
+    assert units.tolist() == {
+        "one_slab": [3, 0], "a_chunk_over_three_tiles": [16, 1],
+        "slabs_that_name_no_block": [4, 0],
+        "a_prefix_shared_across_slabs": [2, 0],
+        "a_prefix_shared_in_one_slab": [2, 0]}[case]
+
+
+def test_a_shared_pair_left_over_is_a_unit_alone(monkeypatch):
+    """A tile whose shared pairs are one more than its units hold: the
+    last is a unit by itself, by the walk's ``lens`` and by the host's
+    count; where the scores leave a unit one block, every one is."""
+    _slabs_as_in_the_cell(monkeypatch)
+    bs, nb, n, row = 16, 24, 64, 640
+    tables = np.full((2, 6), -1)
+    tables[:, :5] = [3, 7, 1, 9, 11]        # one prefix of five blocks
+    tables[1, 5] = 12
+    q_pos = np.array([79, 95])
+    attends = np.asarray(pa.column_live(tables, np.arange(6),
+                                        q_pos[:, None], bs))
+    served = np.where(attends, tables, -1)
+    walk = pa.tile_walk(jnp.asarray(tables, jnp.int32),
+                        jnp.asarray(q_pos, jnp.int32), bs, nb, n)
+    units = np.zeros((2,), np.int64)
+    check_tile_walk(
+        type(walk)(*(None if x is None else np.asarray(x) for x in walk)),
+        attends, tables, 8, n,
+        runs=(mla.run_walk(walk, nb, n, row, bs, 4), 8, 4),
+        shared_units=units)
+    assert tuple(mla.shared_blocks(served, n, row, bs, 4)) == tuple(
+        units) == (4, 1)
+    monkeypatch.setattr(mla, "unit_blocks", lambda rows, *_: 1)
+    assert mla._unit_lengths(n, 8 * n, row, bs, 4) == (1, 1, 2 * n)
+    assert tuple(mla.shared_blocks(served, n, row, bs, 4)) == (0, 5)
 
 
 # -- through ServingEngine ------------------------------------------------------
@@ -455,6 +568,7 @@ def served():
         for name in ("nxd_moe_assignments_total", "nxd_moe_held_total",
                      "nxd_moe_identity_total", "nxd_paged_columns_total",
                      "nxd_mla_block_fetches_total",
+                     "nxd_mla_shared_blocks_total",
                      "nxd_engine_rows_total")}
     check_registered_counters(obs.get_registry(), cfg.serving_family())
     obs.disable()
@@ -489,6 +603,8 @@ def test_the_counters_tell_identity_from_routed_and_held(served):
         "held": identity["routed"], "elsewhere": 0}
     fetches = counters["nxd_mla_block_fetches_total"]
     assert fetches["in_run"] > 0 and fetches["whole"] > 0
+    shared = counters["nxd_mla_shared_blocks_total"]
+    assert sum(shared.values()) == fetches["whole"] and shared["in_unit"] > 0
 
 
 def test_the_cache_has_two_layers_of_rows_a_decoder_layer():

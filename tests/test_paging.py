@@ -928,6 +928,7 @@ DECLARED = {
         "nxd_paged_columns_total": ("skipped", "live"),
         "nxd_paged_block_visits_total": ("fetched", "shared"),
         "nxd_mla_block_fetches_total": ("in_run", "alone", "whole"),
+        "nxd_mla_shared_blocks_total": ("in_unit", "alone"),
         **_MOE},
     "granite_hybrid": {**_PAGED, **_STATES, **_HELD},
     "laguna": {

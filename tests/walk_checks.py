@@ -18,7 +18,8 @@ def narrow_group(n_rep):
     return -(-(furthest + n_rep) // 8) * 8
 
 
-def check_tile_walk(walk, live, tables, rows, n_rep, runs=None):
+def check_tile_walk(walk, live, tables, rows, n_rep, runs=None,
+                    shared_units=None):
     """What every walk of the kernel must hold (a full cache's and a
     window-summary cache's alike, ``tests/test_evabyte.py``): each live
     (row, column) is served by exactly one pair of its tile, no pair is
@@ -29,7 +30,8 @@ def check_tile_walk(walk, live, tables, rows, n_rep, runs=None):
     earlier), so every pair that one packed row names is narrow.
     ``runs``, a kernel's cut of this walk into units (``(run_walk, run,
     whole_run)``), is held to :func:`check_pair_runs`,
-    whose count of the fetches by kind is returned."""
+    whose count of the fetches by kind is returned (``shared_units``: its
+    tally of the shared pairs by their unit)."""
     t, maxb = tables.shape
     tiles = len(walk.count)
     per = walk.blocks.size // tiles
@@ -62,10 +64,12 @@ def check_tile_walk(walk, live, tables, rows, n_rep, runs=None):
             np.testing.assert_array_equal(
                 served[r - i * rows, 0], np.where(live[r], tables[r], -1))
     if runs is not None:
-        return check_pair_runs(walk, live, tables, rows, n_rep, *runs)
+        return check_pair_runs(walk, live, tables, rows, n_rep, *runs,
+                               shared_units=shared_units)
 
 
-def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run):
+def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run,
+                    shared_units=None):
     """What :func:`..ops.paged_attention.pair_runs` must hold of a tile
     walk, by brute count: following a tile's units from its first pair,
     every pair of the walk (so every live (row, column)) lies in exactly
@@ -79,7 +83,11 @@ def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run):
     and ``lens`` is 0 off a unit's first pair. Returns the walk's fetches
     by kind, ``[in_run, alone, whole]``, which the caller holds the
     host's count to (:func:`..ops.paged_attention.block_fetches`, NumPy
-    over the tables themselves)."""
+    over the tables themselves). ``shared_units``, an array of two, has
+    the shared pairs added by the unit ``lens`` gives them, ``[in_unit,
+    alone]``: what the latent cache's ``nxd_mla_shared_blocks_total``
+    counts on the host (:func:`..ops.mla_attention.shared_blocks`); a
+    tile's shared units are full but its last."""
     t, maxb = tables.shape
     tiles = len(walk.count)
     per = rows * maxb
@@ -114,6 +122,11 @@ def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run):
             if start < 0:
                 assert 1 <= n <= whole_run
                 assert all(len(want[p]) > 1 for p in pairs)
+                # the shared pairs come first, in full units but the last
+                assert not last_of and (n == whole_run or first + n == sum(
+                    s < 0 for s in at_walk.values()))
+                if shared_units is not None:
+                    shared_units[int(n == 1)] += n
             else:
                 assert 1 <= n <= run and start % 8 == 0
                 for p in pairs:         # the group holds its namers' heads
