@@ -193,6 +193,17 @@ STATE_SLOT_STEPS = CounterFamily(
     "rows in the step and its states moved on by them, or held, the slot "
     "was occupied and the step left its states as they were.",
     ("advanced", "held"))
+STATE_SEGMENT_ROWS = CounterFamily(
+    "nxd_state_segment_rows_total",
+    "Real rows of the serving workers' packed steps that a state-pool "
+    "family's recurrent layers apply to per-slot states, by their place in "
+    "their segment (a slot's neighbouring rows: ops/ssd.py "
+    "step_segments): first, the row opens its segment and the state "
+    "update's kernel fetches the slot's state for it (a decode row, a "
+    "prefill chunk's first row), or later, it follows another row of its "
+    "own segment and meets the state where that row left it, one row "
+    "after another (the rest of a prefill chunk). One layer's worth.",
+    ("first", "later"))
 WINDOW_COLUMNS = CounterFamily(
     "nxd_window_columns_total",
     "Table columns that the serving workers' rows have mapped in the "
@@ -375,6 +386,17 @@ def _count_states(positions, slot_ids, held) -> Dict[str, Any]:
     advanced = len(np.unique(slot_ids[positions < PAD_POSITION]))
     return {STATE_RESETS.name: (np.count_nonzero(positions == 0),),
             STATE_SLOT_STEPS.name: (advanced, len(held) - advanced)}
+
+
+def _count_segment_rows(positions, slot_ids) -> Tuple[int, int]:
+    """The step's real rows that open a segment and those that follow
+    one of their own, as :func:`..ops.ssd.step_segments` cuts them: a
+    real row whose neighbour before it is a real row of the same slot
+    follows it."""
+    real = positions < PAD_POSITION
+    follows = real[1:] & real[:-1] & (slot_ids[1:] == slot_ids[:-1])
+    later = int(np.count_nonzero(follows))
+    return int(np.count_nonzero(real)) - later, later
 
 
 # ---------------------------------------------------------------------------
@@ -758,15 +780,18 @@ class StatePoolCache(FullCache):
 
     @property
     def counters(self) -> Tuple[CounterFamily, ...]:
-        return super().counters + (STATE_BYTES_HELD,)
+        return super().counters + (STATE_BYTES_HELD, STATE_SEGMENT_ROWS)
 
     def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
                    held: Sequence[int], rolled: int) -> Dict[str, Any]:
-        """As :meth:`FullCache.count_step`, and the bytes the occupied
-        slots hold: their entries in the leaves, by what each leaf is
-        counted as, and their mapped blocks over the pool's layers."""
+        """As :meth:`FullCache.count_step`, the bytes the occupied slots
+        hold (their entries in the leaves, by what each leaf is counted
+        as, and their mapped blocks over the pool's layers) and the
+        step's rows by their place in their slot's segment."""
         counts = super().count_step(geo, positions, slot_ids, tables, held,
                                     rolled)
+        counts[STATE_SEGMENT_ROWS.name] = _count_segment_rows(positions,
+                                                              slot_ids)
         a_slot = [sum(leaf.slot_bytes(geo.itemsize) for leaf in self.leaves
                       if leaf.counted_as == kind)
                   for kind in ("state", "tail")]

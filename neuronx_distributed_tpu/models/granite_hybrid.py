@@ -6,12 +6,21 @@ depthwise causal convolution of width ``mamba_d_conv`` over ``x B C``, the
 selective scan (:mod:`..ops.ssd`), a gated RMSNorm and ``out_proj``; or
 ``attention``, GQA without rotary embedding (NoPE) whose scores are
 multiplied by ``attention_multiplier`` and not by ``1 / sqrt(head_dim)``.
-Both are followed by the llama SwiGLU (the *shared* MLP; the family's
-routed experts are not built: ``num_local_experts`` is 0 in the dense
-models), under Granite's four multipliers: the embedding times
-``embedding_multiplier``, each residual branch times
-``residual_multiplier``, the logits over ``logits_scaling``, tied to the
-embedding.
+Both are followed by the feed-forward. In the dense models
+(``num_local_experts`` 0: granite-4.0-h-micro) it is the llama SwiGLU of
+``shared_intermediate_size`` (the *shared* MLP); in the others
+(granite-4.0-h-small: 72 experts, ten a row) it is
+:class:`..modules.moe.MoE`: a softmax router over all ``num_experts`` in
+float32 that keeps the ``top_k`` largest and weighs them by their
+probabilities over the chosen's sum (a softmax over the chosen logits),
+experts of ``expert_intermediate_size`` (the published
+``intermediate_size``) and the shared MLP beside them, unweighted, on
+every row. ``experts_held = (first, count)`` is the share of the routed
+experts this device holds: the router scores them all, an assignment to
+an expert held elsewhere takes no slot and adds nothing. All of it under
+Granite's four multipliers: the embedding times ``embedding_multiplier``,
+each residual branch times ``residual_multiplier``, the logits over
+``logits_scaling``, tied to the embedding.
 
 The layer is :class:`.llama.LlamaDecoderLayer` under one derived config a
 kind, the parameters one stack a kind, the layers one ``lax.scan`` a run
@@ -22,7 +31,10 @@ Served, a slot's sequence is in three places
 layers alone, and two per-slot state leaves of the mamba layers, ``ssm
 [Lm, J, d_state, d_inner]`` float32 and the convolution's tail ``conv
 [Lm, d_conv - 1, J, d_inner + 2 d_state]``. Heads of 64 lie two a pool
-row, so the Pallas paged kernel serves them.
+row, so the Pallas paged kernel serves them (heads of 128 one a row). A
+model with routed experts also counts a step's assignments into the
+cache's ``moe_counts`` (kept, dropped, held elsewhere), under both kinds
+of layer; the dense models' tree, step and cache have no such leaf.
 """
 
 from __future__ import annotations
@@ -37,6 +49,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from flax.core import meta
 
+from ..modules.moe import MoE
 from ..modules.norms import RMSNorm
 from ..obs.device_scopes import device_scope
 from ..ops import ssd
@@ -50,7 +63,29 @@ LAYER_KINDS = {"mamba": "mamba2", "attention": "full"}
 PUBLISHED_LAYERS = tuple("attention" if i % 10 == 5 else "mamba"
                          for i in range(40))
 #: what of the cache's stacks a layer of each kind reads and writes
+#: (and ``moe_counts`` where the model routes:
+#: :meth:`GraniteHybridConfig.carried`)
 CARRIED = {"full": ("k", "v"), "mamba2": ("ssm", "conv")}
+#: what a published config must say for this module to be its model
+_BUILT = {"model_type": "granitemoehybrid", "position_embedding_type": "nope",
+          "hidden_act": "silu", "normalization_function": "rmsnorm",
+          "attention_bias": False, "mamba_proj_bias": False,
+          "mamba_conv_bias": True, "tie_word_embeddings": True,
+          "mamba_n_groups": 1}
+#: published keys that nothing reads where the model is as :data:`_BUILT`
+#: says (no rotary embedding)
+_UNREAD = ("rope_theta", "rope_scaling")
+#: every key of a published config that
+#: :meth:`GraniteHybridConfig.from_published` reads, holds to
+#: :data:`_BUILT` or knows that nothing reads
+PUBLISHED_KEYS = frozenset(_BUILT) | frozenset(_UNREAD) | frozenset((
+    "vocab_size", "hidden_size", "intermediate_size",
+    "shared_intermediate_size", "num_hidden_layers", "num_attention_heads",
+    "num_key_value_heads", "max_position_embeddings", "rms_norm_eps",
+    "layer_types", "mamba_n_heads", "mamba_d_head", "mamba_d_state",
+    "mamba_d_conv", "mamba_expand", "mamba_chunk_size",
+    "num_local_experts", "num_experts_per_tok", "embedding_multiplier",
+    "residual_multiplier", "attention_multiplier", "logits_scaling"))
 
 
 @dataclass(frozen=True)
@@ -77,6 +112,15 @@ class GraniteHybridConfig(LlamaConfig):
     residual_multiplier: float = 0.22
     attention_multiplier: float = 0.015625
     logits_scaling: float = 8.0
+    #: routed experts the router scores (0: the dense models, whose
+    #: feed-forward is the shared MLP of ``intermediate_size`` alone)
+    num_experts: int = 0
+    top_k: int = 0
+    #: a routed expert's width (the published ``intermediate_size``; the
+    #: field ``intermediate_size`` here is the shared MLP's)
+    expert_intermediate_size: int = 0
+    #: ``(first, count)`` of the routed experts held here (None: all)
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self) -> None:
         super().__post_init__()
@@ -89,6 +133,63 @@ class GraniteHybridConfig(LlamaConfig):
         if self.mamba_n_groups != 1:
             raise ValueError("one group of B and C is what ops/ssd.py "
                              "computes")
+        held = self.experts_held
+        if held is not None and not (
+                0 <= held[0] and held[1] > 0
+                and held[0] + held[1] <= self.num_experts):
+            raise ValueError(f"experts_held {held} is no share of "
+                             f"{self.num_experts} experts")
+        if self.num_experts and not (
+                0 < self.top_k <= self.num_experts
+                and self.expert_intermediate_size > 0):
+            raise ValueError(
+                f"{self.num_experts} routed experts want top_k in 1.."
+                f"{self.num_experts} and an expert width, got top_k "
+                f"{self.top_k}, expert_intermediate_size "
+                f"{self.expert_intermediate_size}")
+
+    @classmethod
+    def from_published(cls, c: dict, **kw) -> "GraniteHybridConfig":
+        """The config of a published ``config.json``'s keys
+        (:data:`PUBLISHED_KEYS`): each is read here, is one that nothing
+        reads (:data:`_UNREAD`), or must say what this module builds
+        (:data:`_BUILT`: another value is refused by name). ``kw`` are
+        this class's fields (dtype, ``experts_held``; ``num_experts``
+        where the file's ``num_local_experts`` is a share)."""
+        wrong = {k: c.get(k) for k, v in _BUILT.items() if c.get(k) != v}
+        if (c["mamba_expand"] * c["hidden_size"]
+                != c["mamba_n_heads"] * c["mamba_d_head"]):
+            wrong["mamba_expand"] = c["mamba_expand"]
+        if bool(c["num_local_experts"]) != bool(c["num_experts_per_tok"]):
+            wrong["num_experts_per_tok"] = c["num_experts_per_tok"]
+        if wrong:
+            raise ValueError(
+                f"granite_hybrid builds {_BUILT}, d_inner = mamba_expand x "
+                f"hidden_size, and experts with a top-k or neither; the "
+                f"config says {wrong}")
+        return cls(**{**dict(
+            vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+            intermediate_size=c["shared_intermediate_size"],
+            num_layers=c["num_hidden_layers"],
+            num_heads=c["num_attention_heads"],
+            num_kv_heads=c["num_key_value_heads"],
+            max_seq_len=int(c["max_position_embeddings"]),
+            rms_eps=float(c["rms_norm_eps"]),
+            layer_types=tuple(c["layer_types"]),
+            mamba_n_heads=c["mamba_n_heads"],
+            mamba_d_head=c["mamba_d_head"],
+            mamba_d_state=c["mamba_d_state"],
+            mamba_d_conv=c["mamba_d_conv"],
+            mamba_chunk_size=c["mamba_chunk_size"],
+            embedding_multiplier=float(c["embedding_multiplier"]),
+            residual_multiplier=float(c["residual_multiplier"]),
+            attention_multiplier=float(c["attention_multiplier"]),
+            logits_scaling=float(c["logits_scaling"]),
+            num_experts=c["num_local_experts"],
+            top_k=c["num_experts_per_tok"],
+            expert_intermediate_size=(c["intermediate_size"]
+                                      if c["num_local_experts"] else 0)),
+            **kw})
 
     @property
     def d_inner(self) -> int:
@@ -119,8 +220,34 @@ class GraniteHybridConfig(LlamaConfig):
             return Mamba2Mixer(self, tp_sync=tp_sync, name="attn")
         return super().attention(tp_sync)
 
+    def feed_forward(self, h: jax.Array, tp_sync: bool = True, valid=None):
+        """The dense models: the shared MLP alone
+        (:class:`.llama.LlamaMLP`), ``(output, None)``. With routed
+        experts: ``(output, [kept, dropped, elsewhere])``, the routed
+        assignments of the real rows, by capacity over the held experts
+        at the capacity of the step's rows, so nothing held can drop."""
+        if not self.num_experts:
+            return super().feed_forward(h, tp_sync, valid)
+        if valid is None:
+            valid = jnp.ones(h.shape[:-1], bool)
+        out, aux = MoE(
+            num_experts=self.num_experts, hidden_size=self.hidden_size,
+            intermediate_size=self.expert_intermediate_size,
+            top_k=self.top_k, capacity_factor=None, router_type="top_k",
+            shared_expert_intermediate=self.intermediate_size,
+            held=self.experts_held or (0, self.num_experts),
+            dtype=self.dtype, param_dtype=self.param_dtype,
+            name="moe")(h, valid=valid)
+        return out, aux["assignments"]
+
     def kinds(self) -> Tuple[str, ...]:
         return tuple(LAYER_KINDS[t] for t in self.layer_types)
+
+    def carried(self):
+        """:data:`CARRIED`, and the step's ``moe_counts`` under both kinds
+        where the model routes."""
+        counts = ("moe_counts",) if self.num_experts else ()
+        return {kind: names + counts for kind, names in CARRIED.items()}
 
     def layers_of(self, kind: str) -> int:
         return self.kinds().count(kind)
@@ -145,6 +272,7 @@ class GraniteHybridConfig(LlamaConfig):
                               jnp.float32),
                     StateLeaf("conv", (layers, self.mamba_d_conv - 1),
                               (self.conv_channels,), counted_as="tail"))),
+            moe_counts=bool(self.num_experts),
             unsupported={
                 "prefix_sharing": "a mamba layer's state and convolution "
                 "tail are no blocks: a shared prefix's blocks carry "
@@ -328,9 +456,11 @@ def granite_hybrid_forward_with_cache(cfg: GraniteHybridConfig, params,
     ``input_ids``, ``positions [1, T]``, ``slot_ids [T]``, ``kv_cache`` a
     :class:`..inference.paging.StatePoolPagedCache`; returns ``(logits
     [1, T, V], new cache)``. The cache's stacks (K/V of the attention
-    layers, the mamba layers' states and tails) are the carry of every
-    run's scan."""
+    layers, the mamba layers' states and tails) and, where the model
+    routes, the routed assignments' counts (of this step alone) are the
+    carry of every run's scan."""
     from ..inference import paging
+    from ..inference.kv_cache import PAD_POSITION
     from ..ops import paged_attention as pa
 
     if any(unsupported.values()):
@@ -375,9 +505,18 @@ def granite_hybrid_forward_with_cache(cfg: GraniteHybridConfig, params,
             ssm=carry["ssm"], conv=carry["conv"], layer=layer, seg=seg)
 
     carry = dict(k=kv_cache.k, v=kv_cache.v, **kv_cache.states)
+    routed = {}
+    if cfg.num_experts:
+        def merge(carry, view, assignments):
+            return {**{name: getattr(view, name) for name in carry
+                       if name != "moe_counts"},
+                    "moe_counts": carry["moe_counts"] + assignments}
+
+        carry["moe_counts"] = jnp.zeros((3,), jnp.int32)
+        routed = dict(merge=merge, valid=(q_pos < PAD_POSITION)[None])
     stacks = {kind: p["model"][f"layers_{kind}"] for kind in CARRIED}
-    x, carry = run_layers(cfg, stacks, x, None, None, CARRIED, carry,
-                          view_of, positions=q_pos[None])
+    x, carry = run_layers(cfg, stacks, x, None, None, cfg.carried(), carry,
+                          view_of, positions=q_pos[None], **routed)
     with device_scope("norm"):
         x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype).apply(
             {"params": p["model"]["norm"]}, x)
@@ -387,4 +526,6 @@ def granite_hybrid_forward_with_cache(cfg: GraniteHybridConfig, params,
             gather_output=True) / cfg.logits_scaling
     return logits, kv_cache.replace(
         k=carry["k"], v=carry["v"], pos=pool_pos,
-        states={name: carry[name] for name in kv_cache.states})
+        states={name: carry[name] for name in kv_cache.states},
+        moe_counts=(None if kv_cache.moe_counts is None
+                    else carry["moe_counts"]))

@@ -1572,6 +1572,104 @@ def test_delta_rule_state_pool_step_at_the_published_widths(
     assert sum(d[3] for d in top) <= 0.02 * total, top
 
 
+# -- the state-pool cache with held experts (Granite-4.0-H-Small) ----------------
+# The cell granite-4.0-h-small.serve-agentic: 128 rows, 64 slots; 9 mamba
+# layers of 128 heads over float32 states [9, 64, 128, 8192] and tails [9, 3,
+# 64, 8448], 1 attention layer of 32 heads over 8 K/V heads of 128; after
+# every layer 36 of 72 experts of 768 held beside a shared MLP of 1,536;
+# half the vocabulary, tied to the head.
+
+def test_hybrid_expert_state_pool_step_at_the_published_widths(
+        chip, topo, on_one_chip, monkeypatch):
+    """The packed step of Granite-4.0-H-Small's configuration file: it
+    compiles for the chip with both kernels in it (the scan's state
+    update at a ``[128, 8192]`` state: a 4 MiB block a slot in and out,
+    sixteen tiles a row), holds what the configuration says it holds
+    (``assumed.serve_aot_gib`` is this analysis), leaves no stack copied,
+    hands the pool, the states and the tails back in the buffers they
+    came in, and the routed experts, the router and the shared MLP have
+    their scopes under both kinds of layer."""
+    import re
+
+    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
+                                                            sample)
+    from neuronx_distributed_tpu.obs.device_scopes import device_scope
+    from neuronx_distributed_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "on_tpu", lambda: True)
+    config, models = _cell_config("granite-4.0-h-small", None)
+    assert sorted(config["reduced"]) == [
+        "layer_types", "num_hidden_layers", "num_local_experts",
+        "vocab_size"]
+    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
+                                                         models)
+    s = config["serve"]
+    slots, blocks = s["max_slots"], s["num_blocks"]
+    assert cache.k.shape == (1, blocks, 128, 8, 128) == cache.v.shape
+    assert cache.states["ssm"].shape == (9, slots, 128, 8192)
+    assert cache.states["ssm"].dtype == jnp.float32
+    assert cache.states["conv"].shape == (9, 3, slots, 8448)
+    assert cache.moe_counts.shape == (3,)
+    tree = params["params"]["model"]
+    mamba = tree["layers_mamba2"]["layer"]
+    assert mamba["attn"]["in_proj"]["kernel"].shape == (9, 4096, 16768)
+    assert mamba["attn"]["conv_kernel"].shape == (9, 8448, 4)
+    assert mamba["attn"]["A_log"].shape == (9, 128)
+    assert mamba["moe"]["router"]["kernel"].shape == (9, 4096, 72)
+    assert mamba["moe"]["experts"]["gate"].shape == (9, 36, 4096, 768)
+    assert mamba["moe"]["experts"]["down"].shape == (9, 36, 768, 4096)
+    assert mamba["moe"]["shared"]["down"]["kernel"].shape == (9, 1536, 4096)
+    full = tree["layers_full"]["layer"]
+    assert full["attn"]["qkv"]["k_kernel"].shape == (1, 4096, 1024)
+    assert full["moe"]["experts"]["up"].shape == (1, 36, 4096, 768)
+    assert tree["embed"]["embedding"].shape == (50176, 4096)
+    assert "lm_head" not in params["params"]
+
+    def step_fn(params, cache, tokens, positions, slot_ids, rng):
+        logits, cache = forward(cfg, params, tokens, positions, cache,
+                                slot_ids=slot_ids)
+        with device_scope("sample"):
+            return sample(logits[0], rng, SamplingConfig()), cache
+
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
+        chip(rng.shape, rng.dtype)).compile()
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"paged_attention",
+                                               "ssd_state_update"}
+    gib = 2.0 ** 30
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) / gib
+    leaves = jax.tree_util.tree_leaves(params)
+    assert sum(x.size for x in leaves) == 4_757_211_776
+    weights = sum(x.size * x.dtype.itemsize for x in leaves)  # 8.861 GiB
+    aot = config["assumed"]["serve_aot_gib"]
+    assert abs(weights / gib - aot["weights"]) < 0.01
+    assert abs(mem.temp_size_in_bytes / gib - aot["temporaries"]) < 0.05
+    assert abs(held - aot["total"]) < 0.05, (held, mem)
+    assert held >= 0.85 * 15.75                      # the file's share
+    assert mem.temp_size_in_bytes < slots * 128 * 8192 * 4    # a layer's
+
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape in (f"1,{blocks},128,8,128", f"9,{slots},128,8192",
+                     f"9,3,{slots},8448")]
+    assert len(stacks) == 4 and set(stacks) <= aliased, (stacks, header)
+
+    total, differ, kernels = scope_disagreements(text)
+    assert total > 0 and kernels == {"attn.kernel", "attn.state"}
+    top = [d for d in differ if d[1].split(".")[0] != d[2].split(".")[0]]
+    assert sum(d[3] for d in top) <= 0.02 * total, top
+    for scope in ("ffn.router", "ffn.experts", "ffn.shared"):
+        assert f"nxd.{scope}" in text, scope
+
+
 # -- the engine's own packed step: one deep in flight -------------------------
 # The CPU tests never donate, so only a compile for the chip shows what the
 # step's operands are there: the pool donated and written in place, the

@@ -909,7 +909,8 @@ _PAGED = {"nxd_paged_columns_total": ("skipped", "live"),
 _STATES = {"nxd_state_resets_total": (),
            "nxd_state_slot_steps_total": ("advanced", "held")}
 _MOE = {"nxd_moe_assignments_total": ("kept", "dropped")}
-_HELD = {"nxd_state_bytes_held_total": ("state", "tail", "kv")}
+_HELD = {"nxd_state_bytes_held_total": ("state", "tail", "kv"),
+         "nxd_state_segment_rows_total": ("first", "later")}
 DECLARED = {
     "llama": _PAGED,
     "mixtral": _PAGED,
@@ -947,6 +948,9 @@ DECLARED["mimo_v2"] = DECLARED["laguna"]
 #: assignments its step counts into the kind's ``moe_counts``
 DECLARED["solar_open2"] = {**DECLARED["granite_hybrid"], **_MOE,
                            "nxd_moe_held_total": ("held", "elsewhere")}
+#: Granite with routed experts (granite-4.0-h-small) declares what Solar
+#: does: the dense models' and the routed assignments
+DECLARED["granite_moe_hybrid"] = DECLARED["solar_open2"]
 #: the second latent family: GLM's, and what a router that also scores
 #: identity experts over a share of the real ones counts
 DECLARED["longcat_flash"] = {**DECLARED["glm_moe_lite"],
@@ -956,6 +960,7 @@ DECLARED["longcat_flash"] = {**DECLARED["glm_moe_lite"],
 ON_DEVICE = {"minicpm_sala": {"counts": 10}, "glm_moe_lite": {"moe_counts": 2},
              "laguna": {"moe_counts": 3}, "mimo_v2": {"moe_counts": 3},
              "solar_open2": {"moe_counts": 3},
+             "granite_moe_hybrid": {"moe_counts": 3},
              "longcat_flash": {"moe_counts": 4}}
 
 
@@ -963,11 +968,16 @@ def _tiny_family(which):
     import importlib
 
     module = importlib.import_module(
-        f"neuronx_distributed_tpu.models.{which}")
+        "neuronx_distributed_tpu.models."
+        + {"granite_moe_hybrid": "granite_hybrid"}.get(which, which))
     if which == "glm_moe_lite":     # a config alone: nothing is built
         return module.GlmMoeLiteConfig().serving_family()
     if which == "longcat_flash":
         return module.LongcatFlashConfig().serving_family()
+    if which == "granite_moe_hybrid":
+        return module.tiny_config(
+            num_experts=8, top_k=3, expert_intermediate_size=32,
+            experts_held=(0, 4)).serving_family()
     make = tiny_moe_config if which == "mixtral" else module.tiny_config
     return make().serving_family()
 
@@ -998,7 +1008,7 @@ def test_a_family_declares_its_steps_counters(which):
         for _, entries in leaf.reads:
             own = [i for e in entries for i in e]
             assert len(own) == len(set(own))
-    if which in ("laguna", "mimo_v2", "solar_open2"):
+    if which in ("laguna", "mimo_v2", "solar_open2", "granite_moe_hybrid"):
         assert leaves[0].read(np.array([5, 2, 4])) == {
             "nxd_moe_assignments_total": [5, 2],
             "nxd_moe_held_total": [7, 4]}
@@ -1040,6 +1050,24 @@ def test_a_kind_counts_a_step_under_the_names_it_declares(which):
         assert state > 0 < tail
         assert list(counts["nxd_state_bytes_held_total"]) == [
             state, tail, kind.pool_layers * 8 * 2 * 2 * 16 * 4]
+        # the slot's two rows are one segment: its first row, and one more
+        assert list(counts["nxd_state_segment_rows_total"]) == [1, 1]
+
+
+def test_segment_rows_are_counted_as_step_segments_cuts_them():
+    """A decode row, a chunk of three, a pad row between two rows of one
+    slot (two segments), and pad rows: by hand, and by ``ops/ssd.py``."""
+    from neuronx_distributed_tpu.inference.paging import _count_segment_rows
+    from neuronx_distributed_tpu.ops import ssd
+
+    slot_ids = np.array([2, 0, 0, 0, 9, 4, 9, 4, 4, 9], np.int32)
+    positions = np.array([20, 0, 1, 2, PAD_POSITION, 7, PAD_POSITION, 8, 9,
+                          PAD_POSITION], np.int32)
+    assert _count_segment_rows(positions, slot_ids) == (4, 3)
+    seg = ssd.step_segments(jnp.asarray(slot_ids), jnp.asarray(positions), 5)
+    assert int(seg.count[0]) == 4
+    assert int(np.sum(np.asarray(seg.since) > 0)) == 3
+    assert _count_segment_rows(positions[4:5], slot_ids[4:5]) == (0, 0)
 
 
 def test_the_benchmarks_counters_are_declared_and_documented():
