@@ -38,7 +38,8 @@ from ..parallel import mappings
 from ..parallel import mesh as ps
 
 from ..lora import LoraConfig
-from ..utils.remat import resolve_remat_policy, validate_remat_policy
+from ..utils.remat import (DEFAULT_REMAT_POLICY, resolve_remat_policy,
+                           validate_remat_policy)
 
 
 def _lora_kw(cfg: "LlamaConfig", name: str) -> dict:
@@ -110,14 +111,12 @@ class LlamaConfig:
     param_dtype: Any = jnp.float32
     sequence_parallel: bool = False
     remat: bool = False
-    # what the rematerialised layer body saves across fwd→bwd:
-    #   "nothing"        — recompute everything (max memory savings);
-    #   "save_attention" — save flash outputs + log-sum-exp so the backward
-    #     skips re-running the attention forward kernel (the single biggest
-    #     recompute item, ~13% of step compute at bench shapes; the flash
-    #     backward only ever needed out+lse — see
-    #     ops/flash_attention.py::_flash_pallas_vjp_fwd).
-    remat_policy: str = "nothing"
+    # what the rematerialised layer body keeps across fwd→bwd (names and
+    # bytes in utils/remat.py). The default keeps the flash kernel's own
+    # pair, output and log-sum-exp, wherever a flash path ran: its backward
+    # takes them as residuals, so the recomputed forward holds no attention
+    # kernel. "nothing" recomputes that too and gives the bytes back.
+    remat_policy: str = DEFAULT_REMAT_POLICY
     scan_layers: bool = True
     use_flash_attention: bool = False
     # force the Pallas flash kernel (interpret mode on CPU) instead of the

@@ -29,6 +29,7 @@ from ..parallel import loss_functions as lf
 from ..parallel import mappings
 from ..parallel import mesh as ps
 from ..pipeline import spmd_engine as eng
+from ..utils.remat import resolve_remat_policy
 from .llama import LlamaConfig, _ScanBody
 
 PIPELINE_LOGICAL_RULES = {"layers": ps.PP_AXIS}
@@ -110,7 +111,7 @@ def pipelined_loss_fn(cfg: LlamaConfig, num_microbatches: int,
 
         if cfg.remat:
             stage_fn = jax.checkpoint(
-                stage_fn, policy=jax.checkpoint_policies.nothing_saveable)
+                stage_fn, policy=resolve_remat_policy(cfg.remat_policy))
 
         outs = eng.pipeline_spmd(stage_fn, ids_mb, S, M, input_fn=input_fn)
 
@@ -398,7 +399,7 @@ def make_1f1b_grad_fn(cfg: LlamaConfig, num_microbatches: int,
 
         if cfg.remat:
             stage_fn = jax.checkpoint(
-                stage_fn, policy=jax.checkpoint_policies.nothing_saveable)
+                stage_fn, policy=resolve_remat_policy(cfg.remat_policy))
 
         tied = cfg.tie_embeddings
 

@@ -29,6 +29,7 @@ from ..parallel import loss_functions as lf
 from ..parallel import mappings
 from ..parallel import mesh as ps
 from ..pipeline import spmd_engine as eng
+from ..utils.remat import resolve_remat_policy
 from .llama import _ScanBody
 from .llama_pipeline import PIPELINE_LOGICAL_RULES  # noqa: F401 (re-export)
 from .mixtral import MixtralConfig
@@ -102,7 +103,7 @@ def pipelined_moe_loss_fn(cfg: MixtralConfig, num_microbatches: int,
 
         if cfg.remat:
             stage_fn = jax.checkpoint(
-                stage_fn, policy=jax.checkpoint_policies.nothing_saveable)
+                stage_fn, policy=resolve_remat_policy(cfg.remat_policy))
 
         outs, aux_local = eng.pipeline_spmd(stage_fn, ids_mb, S, M,
                                             with_aux=True,
@@ -241,7 +242,7 @@ def make_moe_1f1b_grad_fn(cfg: MixtralConfig, num_microbatches: int,
 
         if cfg.remat:
             stage_fn = jax.checkpoint(
-                stage_fn, policy=jax.checkpoint_policies.nothing_saveable)
+                stage_fn, policy=resolve_remat_policy(cfg.remat_policy))
 
         def head_loss_fn(hp, act, lb):
             h = norm_mod.apply({"params": hp["norm"]}, act)

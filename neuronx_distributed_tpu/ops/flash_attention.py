@@ -157,10 +157,10 @@ def _flash_xla(q, k, v, seed, causal, block_k, scale, dropout_p):
 def _flash_xla_vjp_fwd(q, k, v, seed, causal, block_k, scale, dropout_p):
     out, lse = _flash_xla_impl(q, k, v, causal, block_k, scale, dropout_p,
                                seed[0])
-    # same named residuals as the Pallas path, so remat_policy=
-    # "save_attention" is NOT a silent no-op when shapes demote the dispatch
-    # to the XLA fallback (review finding r5): the saved out+lse feed
-    # _flash_bwd_from_lse directly.
+    # same named residuals as the Pallas path, so what a rematerialised
+    # layer keeps (utils/remat.py) is NOT a silent no-op when shapes demote
+    # the dispatch to the XLA fallback (review finding r5): the saved
+    # out+lse feed _flash_bwd_from_lse directly.
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, seed, out, lse)
@@ -632,11 +632,11 @@ def _flash_pallas_vjp_fwd(q, k, v, seed, causal, block_q, block_k, scale,
                                  scale, interpret, dropout_p)
     # Residual names for rematerialisation policies: under
     # ``jax.checkpoint(policy=save_only_these_names('flash_out',
-    # 'flash_lse'))`` (models expose this as ``remat_policy=
-    # 'save_attention'``) the backward pass reuses the saved output +
+    # 'flash_lse'))`` (``remat_policy='save_attention'``, the models'
+    # default: utils/remat.py) the backward pass reuses the saved output +
     # softmax stats instead of re-running the forward kernel — the flash
     # backward only ever needed (q, k, v, out, lse), and q/k/v fall out of
-    # the (cheap) projection recompute. This trades O(B·S·N·D) saved bytes
+    # the projection recompute. This trades O(B·S·N·D) saved bytes
     # for skipping the full attention forward in the backward pass.
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")

@@ -162,19 +162,21 @@ def param_count(m: ModelSpec) -> int:
 def step_flops(m: ModelSpec, remat: bool) -> float:
     """Training FLOPs for one optimizer step: ``6·N·T`` for the dense
     matmuls (fwd 2, bwd 4) plus the quadratic attention term; full remat
-    re-runs the forward once more (≈ ×4/3). MoE only pays for the
-    ``top_k`` routed experts."""
+    re-runs the matmuls' forward once more (×4/3) and not the attention: a
+    rematerialised layer keeps the flash kernel's output and log-sum-exp
+    (``utils/remat.py``). MoE only pays for the ``top_k`` routed
+    experts."""
     n_matmul = param_count(m) - m.vocab * m.hidden  # embed lookup is free
     if m.num_experts > 1 and m.top_k:
         active = 3 * m.hidden * m.intermediate * min(m.top_k, m.num_experts)
         total = 3 * m.hidden * m.intermediate * m.num_experts
         n_matmul -= m.layers * (total - active)
     flops = 6.0 * n_matmul * m.tokens_per_step
+    if remat:
+        flops *= 4.0 / 3.0
     # causal attention: 2 matmuls of [S, D]x[D, S] per head, halved by the
     # causal mask, fwd+bwd -> 6 * T * S * hidden
     flops += 6.0 * m.tokens_per_step * m.seq * m.heads * m.head_dim_ * 0.5
-    if remat:
-        flops *= 4.0 / 3.0
     return flops
 
 
@@ -448,7 +450,9 @@ def memory_bytes(plan: Plan, m: ModelSpec, hw: HardwareSpec,
                  serving: Optional[ServingSpec] = None) -> dict:
     """Per-device bytes: fp32 masters + bf16 compute copy + fp32 grads +
     Adam moments (ZeRO-1 shards the moments over the dp group), layer
-    activations under remat/SP, and the paged-KV pool for serving.
+    activations under remat/SP (a rematerialised layer holds its boundary
+    and the flash kernel's output and log-sum-exp), and the paged-KV pool
+    for serving.
 
     A serving plan carries *inference* state: one compute-dtype weight
     copy and the paged pool (÷ cp for the long-context tier) — no
@@ -472,6 +476,10 @@ def memory_bytes(plan: Plan, m: ModelSpec, hw: HardwareSpec,
     tp_eff = plan.tp if (plan.sequence_parallel and plan.tp > 1) else 1
     if plan.remat:
         per_layer = tokens_mb * m.hidden * m.act_bytes * 2 / tp_eff
+        # what the layer keeps beside its boundary: the flash kernel's
+        # output [tokens, heads/tp, head_dim] and its float32 log-sum-exp
+        per_layer += tokens_mb * m.heads * (m.head_dim_ * m.act_bytes
+                                            + 4.0) / plan.tp
     else:
         per_layer = tokens_mb * (18 * m.hidden + 4 * m.intermediate) \
             * m.act_bytes / tp_eff

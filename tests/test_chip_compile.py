@@ -989,7 +989,7 @@ def _scoped_step(chip, topo, config_name, layers):
 def _compile_scoped_step(chip, topo, config_name, layers):
     config, models = _cell_config(config_name, layers)
     if config["runner"] == "train":
-        return _scoped_train_step(topo, config, models)
+        return _cell_train_step(topo, config_name, layers).as_text()
     from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
                                                             sample)
 
@@ -1011,7 +1011,23 @@ def _compile_scoped_step(chip, topo, config_name, layers):
         chip(rng.shape, rng.dtype)).compile().as_text()
 
 
-def _scoped_train_step(topo, config, models):
+_TRAIN_STEPS = {}
+
+
+def _cell_train_step(topo, config_name, layers, **model_kw):
+    """A training cell's compiled step, once for the cases that read it."""
+    key = (config_name, layers, tuple(sorted(model_kw.items())))
+    if key not in _TRAIN_STEPS:
+        _TRAIN_STEPS[key] = _scoped_train_step(
+            topo, *_cell_config(config_name, layers), **model_kw)
+    return _TRAIN_STEPS[key]
+
+
+def _scoped_train_step(topo, config, models, **model_kw):
+    """The cell's train step, compiled for the described 2x2;
+    ``model_kw`` replaces fields of the configured model."""
+    import dataclasses
+
     import neuronx_distributed_tpu as nxd
     from flax.core import meta
     from jax.sharding import NamedSharding, PartitionSpec
@@ -1035,7 +1051,8 @@ def _scoped_train_step(topo, config, models):
             dtype=models.dtype_of(s["compute_dtype"]),
             param_dtype=models.dtype_of(s["param_dtype"]),
             use_flash_attention=s["flash_attention"])
-        model = type(module)(nxd.configure_model(cfg, base))
+        model = type(module)(dataclasses.replace(
+            nxd.configure_model(cfg, base), **model_kw))
         mesh = ps.get_mesh()
         boxed = jax.eval_shape(model.init, jax.random.key(0),
                                jnp.zeros((batch, seq), jnp.int32))
@@ -1069,7 +1086,7 @@ def _scoped_train_step(topo, config, models):
         ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
                                    sharding=everywhere)
         return trainer.make_train_step(pm, tx, shardings).lower(
-            state, {"input_ids": ids, "labels": ids}).compile().as_text()
+            state, {"input_ids": ids, "labels": ids}).compile()
     finally:
         ps.destroy_model_parallel()
 
@@ -1104,6 +1121,76 @@ def test_a_fusion_reads_the_layer_of_its_heaviest_matmul(
     # a layer (attn, ffn, head..) misread for at most 2% of the matmuls'
     # parameters; a child misread within its layer is PERF.md section 7's
     assert sum(d[3] for d in top) <= 0.02 * total, top
+
+
+def _kernels_by_computation(hlo_text):
+    """``{computation: [kernel instruction stems]}`` of a compiled
+    program: a ``while`` body is one computation, and the layer scan's
+    forward and backward are two."""
+    found, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+        elif name and 'custom_call_target="tpu_custom_call"' in line:
+            found.setdefault(name, []).extend(_kernel_instruction_names(line))
+    return found
+
+
+def _carried_bytes(hlo_text):
+    """Bytes of the largest tuple a ``while`` of the program carries: the
+    backward layer scan's, which holds what the forward scan stacked."""
+    sizes = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+
+    def tuple_bytes(shape):
+        return sum(sizes[d] * math.prod(int(n) for n in dims.split(",") if n)
+                   for d, dims in re.findall(r"(\w+)\[([\d,]*)\]", shape))
+    return max(tuple_bytes(m.group(1)) for m in re.finditer(
+        r"^\s*(?:ROOT )?%\S+ = (\(.*?\)) while\(", hlo_text, re.M))
+
+
+@pytest.fixture
+def train_steps(chip, topo, monkeypatch):
+    """``train_steps(**model_kw)``: ``mistral-7b``'s train step at two
+    layers, compiled for the described 2x2 with the flash dispatcher
+    steered to the compiled kernel."""
+    from neuronx_distributed_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    return functools.partial(_cell_train_step, topo, "mistral-7b",
+                             _SCOPED_STEPS["mistral-7b"])
+
+
+@pytest.mark.parametrize("policy", [None, "nothing"],
+                         ids=["default", "nothing"])
+def test_train_step_recomputes_no_flash_forward(train_steps, policy):
+    """Full checkpointing as the cell asks for it keeps the kernel's
+    output and log-sum-exp: ``flash_attention_fwd`` is in the forward
+    scan's body and not beside ``_bwd_dq`` and ``_bwd_dkv`` in the
+    backward's. ``remat_policy="nothing"`` runs it in both."""
+    text = train_steps(**({"remat_policy": policy} if policy else {})
+                       ).as_text()
+    bodies = sorted(sorted(k) for k in _kernels_by_computation(text).values())
+    backward = ["flash_attention_bwd_dkv", "flash_attention_bwd_dq"]
+    assert bodies == [backward + ["flash_attention_fwd"] * bool(policy),
+                      ["flash_attention_fwd"]], bodies
+
+
+def test_train_step_holds_the_flash_output_and_log_sum_exp(train_steps):
+    """What the default keeps over ``"nothing"``: a chip's share of the
+    kernel's output, 2 x 4,096 x 8 x 128 bf16, and of its log-sum-exp,
+    2 x 8 x 4,096 float32, 16.25 MiB a layer, stacked by the forward scan
+    and carried by the backward's. The program's temporaries grow by no
+    more than that: the backward body no longer holds the forward kernel's
+    working set, and at two layers they shrink."""
+    layers, mib = _SCOPED_STEPS["mistral-7b"], 2 ** 20
+    kept, nothing = train_steps(), train_steps(remat_policy="nothing")
+    a_layer = (_carried_bytes(kept.as_text())
+               - _carried_bytes(nothing.as_text())) / layers / mib
+    assert 0.9 * 16.25 <= a_layer <= 1.1 * 16.25, a_layer
+    grown = (kept.memory_analysis().temp_size_in_bytes
+             - nothing.memory_analysis().temp_size_in_bytes) / layers / mib
+    assert grown <= 1.1 * 16.25, grown
 
 
 def test_the_sparse_step_scores_its_compressed_keys_in_the_pool(

@@ -11,6 +11,7 @@
 """
 
 import contextlib
+import dataclasses
 import io
 
 import numpy as np
@@ -28,6 +29,7 @@ from neuronx_distributed_tpu.models.llama import (LlamaConfig,
 from neuronx_distributed_tpu.parallel import mesh as ps
 from neuronx_distributed_tpu.trainer import initialize_parallel_model
 from neuronx_distributed_tpu.utils.remat import resolve_remat_policy
+from remat_checks import assert_flash_forward_runs, flash_kernel_calls
 
 
 def _batch(cfg, b=2, s=32, seed=0):
@@ -160,11 +162,31 @@ def _pallas_cfg(**kw):
     return tiny_config(**base)
 
 
-@pytest.mark.parametrize("policy", ["save_attention", "dots_and_attention"])
+def _cell_cfg(policy=None, pipeline_parallel_size=1, **kw):
+    """``(framework config, model config)`` of the forced-Pallas toy built
+    the way the train cell builds its model: full activation checkpointing
+    asked of the framework, then ``configure_model``; what a layer keeps
+    is said only where ``policy`` says it."""
+    ps.destroy_model_parallel()
+    cfg = nxd.neuronx_distributed_config(
+        tensor_parallel_size=1,
+        pipeline_parallel_size=pipeline_parallel_size,
+        activation_checkpoint_config=nxd.ActivationCheckpointConfig(
+            mode="full"))
+    if policy:
+        kw["remat_policy"] = policy
+    mcfg = nxd.configure_model(cfg, _pallas_cfg(remat=False, **kw))
+    assert mcfg.remat
+    # configure_model sets the config's compute dtype (bf16): float32 here
+    return cfg, dataclasses.replace(mcfg, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("policy", ["save_attention", "dots_and_attention",
+                                    None], ids=lambda p: p or "default")
 def test_remat_policy_grads_match_nothing(policy):
     ps.initialize_model_parallel(tensor_model_parallel_size=1)
     cfg_n = _pallas_cfg(remat_policy="nothing")
-    cfg_s = _pallas_cfg(remat_policy=policy)
+    cfg_s = _pallas_cfg(remat_policy=policy) if policy else _cell_cfg()[1]
     ids, labels = _batch(cfg_n, b=1, s=64)
     from flax.core import meta
 
@@ -177,6 +199,49 @@ def test_remat_policy_grads_match_nothing(policy):
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4),
         grads_n, grads_s)
+
+
+@pytest.mark.parametrize("policy", [None, "nothing"],
+                         ids=["default", "nothing"])
+@pytest.mark.parametrize("scan", [True, False], ids=["scanned", "unrolled"])
+def test_a_rematerialised_layer_runs_the_flash_forward_once(scan, policy):
+    """Asked for full checkpointing and nothing else, a layer keeps the
+    kernel's output and log-sum-exp: its backward pass recomputes no
+    ``flash_attention_fwd``. ``remat_policy="nothing"`` still does."""
+    _, cfg = _cell_cfg(policy, scan_layers=scan)
+    ids, labels = _batch(cfg, b=1, s=64)
+    model = LlamaForCausalLM(cfg)
+    params = jax.eval_shape(model.init, jax.random.key(1), ids)
+    calls = flash_kernel_calls(jax.make_jaxpr(jax.value_and_grad(
+        lambda p: model.apply(p, ids, labels=labels)))(params).jaxpr)
+    assert_flash_forward_runs(calls, 1 if scan else cfg.num_layers,
+                              recomputed=policy == "nothing")
+
+
+@pytest.mark.parametrize("policy", [None, "nothing"],
+                         ids=["default", "nothing"])
+@pytest.mark.parametrize("schedule,runs", [("gpipe", 1), ("1f1b", 2)])
+def test_a_pipeline_stage_keeps_what_the_layer_keeps(schedule, runs, policy):
+    """The pipeline engines checkpoint their stage by the same resolution:
+    differentiating a stage runs the forward kernel ``runs`` times (1F1B
+    runs a stage forward once in its own slot and once under ``vjp``), and
+    once more only where the model was told to keep nothing."""
+    from neuronx_distributed_tpu.models import llama_pipeline as lpp
+
+    cfg, mcfg = _cell_cfg(policy, pipeline_parallel_size=2, num_layers=4)
+    ids, labels = _batch(mcfg, b=8, s=64)
+    batch = {"input_ids": ids, "labels": labels}
+    pm, params = initialize_parallel_model(
+        cfg, LlamaForCausalLM(mcfg), jax.random.key(1), ids,
+        logical_axis_rules=lpp.PIPELINE_LOGICAL_RULES)
+    grad_fn = lpp.make_pipeline_grad_fn(
+        mcfg, num_microbatches=2, param_specs=pm.param_specs,
+        schedule=schedule)
+    calls = flash_kernel_calls(jax.make_jaxpr(grad_fn)(params, batch).jaxpr)
+    forward = sum(n for (name, _), n in calls.items()
+                  if name == "flash_attention_fwd")
+    assert forward == runs + (policy == "nothing"), calls
+    assert calls["flash_attention_bwd_dq", True] == 1, calls
 
 
 def _saved_residual_report(cfg, params, ids, labels):

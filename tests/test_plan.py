@@ -175,6 +175,32 @@ def test_zero1_shards_optimizer_memory():
     assert m_z["total"] < m_d["total"]
 
 
+# the train cell's slice of Mistral-7B: 2 x 4,096 tokens at tp=4
+MISTRAL = ModelSpec(name="mistral-7b", vocab=32768, hidden=4096,
+                    intermediate=14336, layers=11, heads=32, kv_heads=8,
+                    seq=4096, global_batch=2)
+
+
+def test_remat_reruns_the_matmuls_and_not_the_attention():
+    """A rematerialised layer keeps the flash kernel's output and
+    log-sum-exp (``utils/remat.py``): the x4/3 is the matmuls'."""
+    attention = 6.0 * MISTRAL.tokens_per_step * 4096 * 32 * 128 * 0.5
+    plain = planner.step_flops(MISTRAL, remat=False)
+    assert planner.step_flops(MISTRAL, remat=True) == pytest.approx(
+        (plain - attention) * 4.0 / 3.0 + attention)
+
+
+@pytest.mark.parametrize("tp,kept_mib", [(4, 16.25), (1, 65.0)])
+def test_remat_holds_the_flash_output_and_log_sum_exp(tp, kept_mib):
+    """Beside its boundary a layer holds B*S*N*D/tp bf16 and B*N*S/tp
+    float32: 16.25 MiB a chip at the cell's tp=4 (what its compiled step
+    carries, ``tests/test_chip_compile.py``), 65 at tp=1."""
+    acts = memory_bytes(Plan(devices=tp, tp=tp, remat=True), MISTRAL,
+                        HW)["acts"]
+    boundary = 2 * 4096 * 4096 * 2 * 2
+    assert acts / MISTRAL.layers - boundary == kept_mib * 2 ** 20
+
+
 def test_serving_charges_kv_pool():
     # serving memory is inference state: one compute-dtype weight copy
     # plus the paged pool — no grads/opt/training activations

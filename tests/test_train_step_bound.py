@@ -24,9 +24,10 @@ from neuronx_distributed_tpu.trainer import (initialize_parallel_model,
                                              initialize_parallel_optimizer,
                                              make_train_step)
 from neuronx_distributed_tpu.trainer import trainer as tr
+from remat_checks import assert_flash_forward_runs, flash_kernel_calls
 
 
-def _step_and_state(tp=4, seq=16, **cfg_kw):
+def _step_and_state(tp=4, seq=16, model_kw=None, **cfg_kw):
     ps.destroy_model_parallel()
     cfg = nxd.neuronx_distributed_config(
         tensor_parallel_size=tp,
@@ -37,7 +38,8 @@ def _step_and_state(tp=4, seq=16, **cfg_kw):
     # heads than ranks replicate them, and a replicated head's q/k/v entry
     # keeps its monolithic all-reduce
     mcfg = nxd.configure_model(
-        cfg, tiny_config(param_dtype=jnp.float32, num_kv_heads=4))
+        cfg, tiny_config(**{"param_dtype": jnp.float32, "num_kv_heads": 4,
+                            **(model_kw or {})}))
     # configure_model sets the config's compute dtype (bf16): float32 here
     mcfg = dataclasses.replace(mcfg, dtype=jnp.float32)
     model = LlamaForCausalLM(mcfg)
@@ -100,6 +102,24 @@ def test_default_step_at_tp4_is_the_bound_step_and_equals_gspmd(monkeypatch):
             # rounding of its large ones
             np.testing.assert_allclose(g, w, rtol=1e-5,
                                        atol=5e-5 * np.abs(w).max())
+
+
+@pytest.mark.parametrize("policy", [None, "nothing"],
+                         ids=["default", "nothing"])
+def test_the_bound_step_runs_the_flash_forward_once_a_layer(policy):
+    """The train cell's path (full checkpointing, the flash kernel inside
+    ``shard_map`` with the tp axis bound, one head a rank): the layer scan's
+    backward pass recomputes no ``flash_attention_fwd`` unless the model was
+    told to keep nothing."""
+    model_kw = dict(hidden_size=512, head_dim=128, use_flash_attention=True,
+                    attn_force_pallas=True)
+    if policy:
+        model_kw["remat_policy"] = policy
+    step, state, batch = _step_and_state(model_kw=model_kw)
+    jaxpr = jax.make_jaxpr(step)(state, batch).jaxpr
+    assert "ppermute" in _scan_body_primitives(jaxpr)
+    assert_flash_forward_runs(flash_kernel_calls(jaxpr), 1,
+                              recomputed=policy == "nothing")
 
 
 @pytest.mark.parametrize("kw", [
