@@ -190,11 +190,16 @@ class AotExecutableCache:
             if header != self.environment():
                 raise ValueError(
                     f"environment skew: entry built under {header}")
-            payload, in_tree, out_tree = pickle.loads(blob[header_end + 1:])
+            payload, in_tree, out_tree, device_ids = pickle.loads(
+                blob[header_end + 1:])
             from jax.experimental import serialize_executable
 
+            # onto the devices it was compiled for: the default is every
+            # device of the backend, which a program of fewer cannot run on
+            by_id = {d.id: d for d in jax.devices()}
             compiled = serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception as e:  # any read failure degrades to a miss
             self._evict(key, f"{type(e).__name__}: {e}")
             self.misses += 1
@@ -219,10 +224,13 @@ class AotExecutableCache:
 
             payload, in_tree, out_tree = serialize_executable.serialize(
                 compiled)
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
             blob = (_MAGIC
                     + json.dumps(self.environment(),
                                  sort_keys=True).encode() + b"\n"
-                    + pickle.dumps((payload, in_tree, out_tree)))
+                    + pickle.dumps((payload, in_tree, out_tree,
+                                    device_ids)))
         except Exception as e:
             self.serialize_skips += 1
             emit_event("aot_cache_serialize_skipped", key=key[:16],

@@ -6,7 +6,20 @@ code runs unchanged on ``--xla_force_host_platform_device_count=8`` CPU
 devices.
 """
 
+import os
+
 from neuronx_distributed_tpu.utils.cpu_mesh import force_cpu_platform
+
+# A test's program runs once or a few times, and most of a test is the CPU
+# backend compiling it: at optimisation level 0 the gate takes a fifth less
+# (762 -> 613 s, CHANGES.md, PR 57). The tests hold the program's
+# arithmetic, not LLVM's vectoriser; what is held to the bit against a
+# recorded file is recorded in an interpreter without the flag
+# (test_evabyte.py). ``force_cpu_platform`` appends to what is set here.
+_LEVEL = "--xla_backend_optimization_level"
+if _LEVEL not in os.environ.get("XLA_FLAGS", ""):   # once: a worker inherits
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + f" {_LEVEL}=0").strip()
 
 # Tests always run on the virtual CPU mesh, whatever the machine holds.
 # Must run before the CPU backend initialises.
@@ -39,5 +52,8 @@ def _free_compiled_programs():
     yield
     import gc
 
+    import family_checks
+
+    family_checks.forget()      # a family file's models and paged steps
     jax.clear_caches()
     gc.collect()

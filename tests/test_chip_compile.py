@@ -617,10 +617,7 @@ def test_double_layer_latent_step_at_the_published_widths(chip, topo,
     fusion with a matmul inside reads another layer's."""
     import re
 
-    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
-                                                            sample)
-    from neuronx_distributed_tpu.obs.device_scopes import (device_scope,
-                                                           scope_of)
+    from neuronx_distributed_tpu.obs.device_scopes import scope_of
 
     config, models = _cell_config("longcat-flash-chat", None)
     assert set(config["reduced"]) == {"num_layers", "n_routed_experts",
@@ -635,17 +632,7 @@ def test_double_layer_latent_step_at_the_published_widths(chip, topo,
     assert layer["moe"]["router"]["kernel"].shape == (4, 6144, 768)
     assert layer["attn_1"]["k_up"].shape == (4, 64, 128, 512)
 
-    def step_fn(params, cache, tokens, positions, slot_ids, rng):
-        logits, cache = forward(cfg, params, tokens, positions, cache,
-                                slot_ids=slot_ids)
-        with device_scope("sample"):
-            return sample(logits[0], rng, SamplingConfig()), cache
-
-    rng = jax.eval_shape(lambda: jax.random.key(0))
-    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
-        params, cache, chip((1, tokens), jnp.int32),
-        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
-        chip(rng.shape, rng.dtype)).compile()
+    compiled = _packed_step(chip, cfg, forward, params, cache, tokens)
     text = compiled.as_text()
     _STEP_TEXTS.setdefault(("longcat-flash-chat", None), text)
     assert _kernel_instruction_names(text) == {"mla_paged_attention"}
@@ -949,6 +936,27 @@ def _cell_config(config_name, layers):
     return config, models
 
 
+def _packed_step(chip, cfg, forward, params, cache, tokens):
+    """The packed serving step as the engine builds it (forward and
+    sampling, the cache donated) over :func:`_serving_parts`, compiled
+    for the described chip."""
+    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
+                                                            sample)
+    from neuronx_distributed_tpu.obs.device_scopes import device_scope
+
+    def step_fn(params, cache, tokens, positions, slot_ids, rng):
+        logits, cache = forward(cfg, params, tokens, positions, cache,
+                                slot_ids=slot_ids)
+        with device_scope("sample"):
+            return sample(logits[0], rng, SamplingConfig()), cache
+
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    return jax.jit(step_fn, donate_argnums=(1,)).lower(
+        params, cache, chip((1, tokens), jnp.int32),
+        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
+        chip(rng.shape, rng.dtype)).compile()
+
+
 def _serving_parts(chip, config, models):
     """``(cfg, forward, params, cache, width)`` of a serving cell, the
     arrays abstract and on the described chip."""
@@ -990,25 +998,8 @@ def _compile_scoped_step(chip, topo, config_name, layers):
     config, models = _cell_config(config_name, layers)
     if config["runner"] == "train":
         return _cell_train_step(topo, config_name, layers).as_text()
-    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
-                                                            sample)
-
-    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
-                                                         models)
-
-    def step_fn(params, cache, tokens, positions, slot_ids, rng):
-        from neuronx_distributed_tpu.obs.device_scopes import device_scope
-
-        logits, cache = forward(cfg, params, tokens, positions, cache,
-                                slot_ids=slot_ids)
-        with device_scope("sample"):
-            return sample(logits[0], rng, SamplingConfig()), cache
-
-    rng = jax.eval_shape(lambda: jax.random.key(0))
-    return jax.jit(step_fn, donate_argnums=(1,)).lower(
-        params, cache, chip((1, tokens), jnp.int32),
-        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
-        chip(rng.shape, rng.dtype)).compile().as_text()
+    return _packed_step(chip, *_serving_parts(chip, config, models)
+                        ).as_text()
 
 
 _TRAIN_STEPS = {}
@@ -1240,9 +1231,6 @@ def test_state_pool_step_at_the_published_widths(chip, topo, on_one_chip,
     fusion with a matmul inside reads the scope of its heaviest one."""
     import re
 
-    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
-                                                            sample)
-    from neuronx_distributed_tpu.obs.device_scopes import device_scope
     from neuronx_distributed_tpu.ops import ssd
 
     monkeypatch.setattr(ssd, "on_tpu", lambda: True)
@@ -1255,17 +1243,7 @@ def test_state_pool_step_at_the_published_widths(chip, topo, on_one_chip,
     assert cache.states["ssm"].shape == (36, slots, 128, 4096)
     assert cache.states["conv"].shape == (36, 3, slots, 4352)
 
-    def step_fn(params, cache, tokens, positions, slot_ids, rng):
-        logits, cache = forward(cfg, params, tokens, positions, cache,
-                                slot_ids=slot_ids)
-        with device_scope("sample"):
-            return sample(logits[0], rng, SamplingConfig()), cache
-
-    rng = jax.eval_shape(lambda: jax.random.key(0))
-    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
-        params, cache, chip((1, tokens), jnp.int32),
-        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
-        chip(rng.shape, rng.dtype)).compile()
+    compiled = _packed_step(chip, cfg, forward, params, cache, tokens)
     text = compiled.as_text()
     assert _kernel_instruction_names(text) == {"paged_attention",
                                                "ssd_state_update"}
@@ -1342,9 +1320,6 @@ def test_window_pool_step_at_the_published_widths(chip, topo, on_one_chip):
     of its heaviest one."""
     import re
 
-    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
-                                                            sample)
-    from neuronx_distributed_tpu.obs.device_scopes import device_scope
 
     config, models = _cell_config("laguna-s-2.1", None)
     assert sorted(config["reduced"]) == [
@@ -1367,17 +1342,7 @@ def test_window_pool_step_at_the_published_widths(chip, topo, on_one_chip):
         "q_kernel"].shape == (3, 3072, 9216)
     assert params["params"]["lm_head"]["kernel"].shape == (3072, 50176)
 
-    def step_fn(params, cache, tokens, positions, slot_ids, rng):
-        logits, cache = forward(cfg, params, tokens, positions, cache,
-                                slot_ids=slot_ids)
-        with device_scope("sample"):
-            return sample(logits[0], rng, SamplingConfig()), cache
-
-    rng = jax.eval_shape(lambda: jax.random.key(0))
-    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
-        params, cache, chip((1, tokens), jnp.int32),
-        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
-        chip(rng.shape, rng.dtype)).compile()
+    compiled = _packed_step(chip, cfg, forward, params, cache, tokens)
     text = compiled.as_text()
     assert _kernel_instruction_names(text) == {"paged_attention",
                                                "swa_attention"}
@@ -1462,9 +1427,6 @@ def test_wide_key_window_pool_step_at_the_published_widths(chip, topo,
     says it holds, and hands all four pool leaves (the keys in whole
     lanes, the values by head, two head counts) back in the buffers they
     came in."""
-    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
-                                                            sample)
-    from neuronx_distributed_tpu.obs.device_scopes import device_scope
 
     config, models = _cell_config("mimo-v2-flash", None)
     assert sorted(config["reduced"]) == [
@@ -1492,17 +1454,7 @@ def test_wide_key_window_pool_step_at_the_published_widths(chip, topo,
     assert "sink" not in full
     assert params["params"]["lm_head"]["kernel"].shape == (4096, 19072)
 
-    def step_fn(params, cache, tokens, positions, slot_ids, rng):
-        logits, cache = forward(cfg, params, tokens, positions, cache,
-                                slot_ids=slot_ids)
-        with device_scope("sample"):
-            return sample(logits[0], rng, SamplingConfig()), cache
-
-    rng = jax.eval_shape(lambda: jax.random.key(0))
-    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
-        params, cache, chip((1, tokens), jnp.int32),
-        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
-        chip(rng.shape, rng.dtype)).compile()
+    compiled = _packed_step(chip, cfg, forward, params, cache, tokens)
     text = compiled.as_text()
     assert _kernel_instruction_names(text) == {"paged_attention",
                                                "swa_attention"}
@@ -1579,9 +1531,6 @@ def test_delta_rule_state_pool_step_at_the_published_widths(
     matmul inside reads the scope of its heaviest one."""
     import re
 
-    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
-                                                            sample)
-    from neuronx_distributed_tpu.obs.device_scopes import device_scope
     from neuronx_distributed_tpu.ops import kda
 
     monkeypatch.setattr(kda, "on_tpu", lambda: True)
@@ -1616,17 +1565,7 @@ def test_delta_rule_state_pool_step_at_the_published_widths(
     assert gqa["moe"]["shared"]["down"]["kernel"].shape == (2, 1280, 4096)
     assert params["params"]["lm_head"]["kernel"].shape == (4096, 24576)
 
-    def step_fn(params, cache, tokens, positions, slot_ids, rng):
-        logits, cache = forward(cfg, params, tokens, positions, cache,
-                                slot_ids=slot_ids)
-        with device_scope("sample"):
-            return sample(logits[0], rng, SamplingConfig()), cache
-
-    rng = jax.eval_shape(lambda: jax.random.key(0))
-    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
-        params, cache, chip((1, tokens), jnp.int32),
-        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
-        chip(rng.shape, rng.dtype)).compile()
+    compiled = _packed_step(chip, cfg, forward, params, cache, tokens)
     text = compiled.as_text()
     assert _kernel_instruction_names(text) == {"paged_attention",
                                                "kda_state_update"}
@@ -1678,9 +1617,6 @@ def test_hybrid_expert_state_pool_step_at_the_published_widths(
     their scopes under both kinds of layer."""
     import re
 
-    from neuronx_distributed_tpu.inference.sampling import (SamplingConfig,
-                                                            sample)
-    from neuronx_distributed_tpu.obs.device_scopes import device_scope
     from neuronx_distributed_tpu.ops import ssd
 
     monkeypatch.setattr(ssd, "on_tpu", lambda: True)
@@ -1712,17 +1648,7 @@ def test_hybrid_expert_state_pool_step_at_the_published_widths(
     assert tree["embed"]["embedding"].shape == (50176, 4096)
     assert "lm_head" not in params["params"]
 
-    def step_fn(params, cache, tokens, positions, slot_ids, rng):
-        logits, cache = forward(cfg, params, tokens, positions, cache,
-                                slot_ids=slot_ids)
-        with device_scope("sample"):
-            return sample(logits[0], rng, SamplingConfig()), cache
-
-    rng = jax.eval_shape(lambda: jax.random.key(0))
-    compiled = jax.jit(step_fn, donate_argnums=(1,)).lower(
-        params, cache, chip((1, tokens), jnp.int32),
-        chip((1, tokens), jnp.int32), chip((tokens,), jnp.int32),
-        chip(rng.shape, rng.dtype)).compile()
+    compiled = _packed_step(chip, cfg, forward, params, cache, tokens)
     text = compiled.as_text()
     assert _kernel_instruction_names(text) == {"paged_attention",
                                                "ssd_state_update"}

@@ -191,9 +191,12 @@ def test_error_feedback_converges_over_steps():
     xs = _per_rank(m=512)
     ref = np.asarray(jnp.mean(xs, axis=0))
     err = jnp.zeros_like(xs)
+    # one traced step, called 24 times: each ``shard_map`` closure of
+    # ``_allreduce`` is otherwise traced and compiled again
+    step = jax.jit(lambda x, e: _allreduce(x, INT8, mesh, error=e))
     outs = []
     for _ in range(24):
-        y, err = _allreduce(xs, INT8, mesh, error=err)
+        y, err = step(xs, err)
         outs.append(np.asarray(y)[0])
     single = np.abs(outs[0] - ref).max()
     avged = np.abs(np.mean(outs, axis=0) - ref).max()

@@ -22,17 +22,13 @@ import jax
 import jax.numpy as jnp
 from flax.core import meta
 
-from neuronx_distributed_tpu import obs
 from neuronx_distributed_tpu.inference import paging
-from neuronx_distributed_tpu.inference.engine import (EngineConfig,
-                                                      ServingEngine)
+from neuronx_distributed_tpu.inference.engine import ServingEngine
 from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
-from neuronx_distributed_tpu.inference.speculative import SpeculationConfig
 from neuronx_distributed_tpu.modules.moe import (ExpertMLPs, RouterSigmoid,
                                                  build_dispatch_combine)
 from neuronx_distributed_tpu.ops import mla_attention as mla
 from neuronx_distributed_tpu.ops import paged_attention as pa
-from neuronx_distributed_tpu.parallel import mesh as ps
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.join(os.path.dirname(HERE), "benchmarks")
@@ -40,7 +36,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402  (benchmarks/)
-from counter_checks import check_registered_counters  # noqa: E402  (tests/)
+import family_checks as fc  # noqa: E402  (tests/)
 from runners import serve  # noqa: E402
 from walk_checks import check_tile_walk  # noqa: E402  (tests/)
 
@@ -59,27 +55,19 @@ PUBLISHED = dict(
     family="glm_moe_lite", reference="glm_moe_lite_f32")
 
 
+@fc.once_a_module
 def _model(**kw):
-    ps.initialize_model_parallel()
     family = harness.load_plugin("families", "glm_moe_lite")
     cfg, model, forward = family.build(
         PUBLISHED, dtype=jnp.float32, param_dtype=jnp.float32, **kw)
-    shapes = meta.unbox(model.init(jax.random.key(0),
-                                   jnp.zeros((1, 8), jnp.int32)))
+    shapes = meta.unbox(jax.eval_shape(model.init, jax.random.key(0),
+                                       jnp.zeros((1, 8), jnp.int32)))
 
-    def draw(path, x):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(jax.random.key(5),
-                                 sum(map(ord, name)) % 2 ** 31)
-        noise = jax.random.normal(key, x.shape, x.dtype)
-        if name.endswith("['scale']"):
-            return 1.0 + 0.3 * noise
+    def special(name, noise, x, key):
         if name.endswith("['bias']"):
             return 0.2 * noise          # of the scores' own spread
-        return 0.08 * noise
 
-    return cfg, model, forward, jax.tree_util.tree_map_with_path(draw,
-                                                                 shapes)
+    return cfg, model, forward, fc.seeded_weights(shapes, special)
 
 
 def _reference(params):
@@ -88,19 +76,9 @@ def _reference(params):
                 params, PUBLISHED))
 
 
-def _ecfg(**kw):
-    base = dict(block_size=BS, num_blocks=40, max_slots=3,
-                max_blocks_per_seq=12, token_budget=16,
-                kv_dtype=jnp.float32)
-    base.update(kw)
-    return EngineConfig(**base)
-
-
-def _greedy_by_reference(params, prompt, tokens):
+def _reference_logits(params, tokens):
     ref, weights = _reference(params)
-    logits, _ = ref.forward(weights, np.asarray([prompt + tokens]),
-                            PUBLISHED)
-    return np.argmax(np.asarray(logits)[0, len(prompt) - 1:-1], -1).tolist()
+    return ref.forward(weights, np.asarray(tokens), PUBLISHED)[0]
 
 
 # -- the module's full forward ----------------------------------------------
@@ -183,7 +161,8 @@ def test_paged_forward_matches_the_references_expanded_keys_and_values(
     assert any(len(rows) < 16 for rows in schedule)          # pad rows
     assert any({s for s, _ in rows} == {0, 1} for rows in schedule)
     with jax.default_matmul_precision("highest"):
-        seqs, got = serve.probe_logits(7, cfg, forward, params, _ecfg(), chk)
+        seqs, got = serve.probe_logits(7, cfg, forward, params,
+                                       fc.engine_config(), chk)
     ref, weights = _reference(params)
     want = np.asarray(ref.forward(weights, seqs, PUBLISHED)[0])
     assert got.shape == want.shape == (2, 62, 256)
@@ -513,54 +492,27 @@ def served():
     """Three requests, one of them preempted on the way, through one
     engine."""
     cfg, _, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg(num_blocks=9, max_slots=2))
-    rng = np.random.RandomState(11)
-    prompts = {"a": rng.randint(0, 256, (70,)).tolist(),
-               "b": rng.randint(0, 256, (40,)).tolist(),
-               "c": rng.randint(0, 256, (5,)).tolist()}
-    new = {"a": 30, "b": 12, "c": 4}
-    obs.enable()
-    obs.get_registry().reset()
-    for uid, prompt in prompts.items():
-        eng.submit(prompt, new[uid], uid=uid)
-    while eng.has_work():
-        eng.step()
-    counters = {
-        name: {c.labels.get("kind", ""): c.value
-               for c in obs.get_registry().get(name).children()}
-        for name in ("nxd_moe_assignments_total", "nxd_paged_columns_total",
-                     "nxd_paged_block_visits_total",
-                     "nxd_mla_block_fetches_total",
-                     "nxd_mla_shared_blocks_total",
-                     "nxd_engine_rows_total")}
-    check_registered_counters(obs.get_registry(), cfg.serving_family())
-    obs.disable()
-    ps.destroy_model_parallel()
-    return cfg, params, eng, prompts, new, counters
+    return fc.serve_three(cfg, params, (
+        "nxd_moe_assignments_total", "nxd_paged_columns_total",
+        "nxd_paged_block_visits_total", "nxd_mla_block_fetches_total",
+        "nxd_mla_shared_blocks_total", "nxd_engine_rows_total"),
+        lengths=[70, 40, 5], new=[30, 12, 4], num_blocks=9, max_slots=2)
 
 
 def test_engine_greedy_tokens_equal_the_reference(served):
-    cfg, params, eng, prompts, new, _ = served
-    for uid, prompt in prompts.items():
-        assert eng.results[uid].status == "completed"
-        tokens = eng.results[uid].tokens
-        assert len(tokens) == new[uid]
-        assert tokens == _greedy_by_reference(params, prompt, tokens), uid
+    fc.check_engine_greedy_tokens_equal_the_reference(served,
+                                                      _reference_logits)
 
 
 def test_a_preempted_request_is_readmitted_and_gives_the_same_tokens(served):
     """9 blocks do not hold a and b: one is preempted, re-admitted, and
     still decodes what the reference does (above); the pool is whole at
     the end and the step compiled once."""
-    _, _, eng, *_ = served
-    assert eng.stats.preempted >= 1
-    assert eng.allocator.num_allocated == 0
-    assert (eng._tables == -1).all()
-    assert eng.compile_count() == 1
+    fc.check_preempted_and_whole(served.eng)
 
 
 def test_the_counters_of_the_latent_walk_and_of_the_experts(served):
-    cfg, *_, counters = served
+    counters = served.counters
     rows = counters["nxd_engine_rows_total"]
     moe = counters["nxd_moe_assignments_total"]
     # top_k an expert layer a real row, pads none, nothing dropped
@@ -590,7 +542,7 @@ def test_prefix_sharing_maps_latent_blocks_and_copies_on_write():
     rng = np.random.RandomState(12)
     common = rng.randint(0, 256, (40,)).tolist()   # two blocks and a half
     prompts = [common + rng.randint(0, 256, (9,)).tolist() for _ in range(2)]
-    eng = ServingEngine(cfg, params, _ecfg(prefix_sharing=True))
+    eng = ServingEngine(cfg, params, fc.engine_config(prefix_sharing=True))
     out = []
     for prompt in prompts:
         uid = eng.submit(prompt, 6)
@@ -599,12 +551,13 @@ def test_prefix_sharing_maps_latent_blocks_and_copies_on_write():
         out.append(eng.results[uid].tokens)
     assert eng.stats.prefix_hit_tokens >= 2 * BS
     for prompt, tokens in zip(prompts, out):
-        assert tokens == _greedy_by_reference(params, prompt, tokens)
+        assert tokens == fc.greedy_by_reference(_reference_logits, params,
+                                                prompt, tokens)
 
 
 def test_a_sessions_latent_blocks_are_shipped_and_landed():
     cfg, _, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg())
+    eng = ServingEngine(cfg, params, fc.engine_config())
     eng.submit(list(range(20)), 8)
     for _ in range(3):
         eng.step()
@@ -612,7 +565,7 @@ def test_a_sessions_latent_blocks_are_shipped_and_landed():
     payload = paging.extract_blocks(eng.cache, blocks, PAD_POSITION)
     assert set(payload) == {"rows", "pos"}
     assert payload["rows"].shape == (3, len(blocks), BS, 128)
-    other = ServingEngine(cfg, params, _ecfg())
+    other = ServingEngine(cfg, params, fc.engine_config())
     landed = paging.inject_blocks(other.cache, [5, 6][:len(blocks)], payload)
     np.testing.assert_array_equal(
         np.asarray(landed.rows[:, 5]), payload["rows"][:, 0])
@@ -620,22 +573,15 @@ def test_a_sessions_latent_blocks_are_shipped_and_landed():
         jnp.abs(landed.rows[:, 5, :BS]).max()) > 0
 
 
-@pytest.mark.parametrize("feature,kw", [
-    ("speculation", dict(speculation=SpeculationConfig())),
-    ("cp", dict(cp=2)),
-    ("quantized", dict(quantized=True)),
-])
+@pytest.mark.parametrize("feature,kw", fc.REFUSED_FEATURES[1:])
 def test_refused_features_raise_by_name_with_their_reason(feature, kw):
     cfg, _, _, params = _model()
-    reason = cfg.serving_family().unsupported[feature]
-    with pytest.raises(ValueError, match=feature) as e:
-        ServingEngine(cfg, params, _ecfg(**kw))
-    assert reason[:30] in str(e.value)
+    fc.check_refused_features(cfg, params, {feature: kw}, reason=True)
 
 
 def test_the_cache_has_one_leaf_of_rows_and_none_of_heads():
     cfg, _, _, params = _model()
-    cache = ServingEngine(cfg, params, _ecfg()).cache
+    cache = ServingEngine(cfg, params, fc.engine_config()).cache
     assert isinstance(cache, paging.LatentPagedCache)
     assert cache.rows.shape == (3, 40, BS, 128)
     assert cache.capacity == 40 * BS and cache.max_slots == 3
@@ -684,5 +630,5 @@ def test_the_family_and_not_the_cache_kind_declares_the_experts_counter():
     assert family.cache_kind.init_cache(Dense(), **geometry).moe_counts \
         is None
     assert [leaf.leaf for leaf in
-            ServingEngine(cfg, params, _ecfg())._device_counts] \
+            ServingEngine(cfg, params, fc.engine_config())._device_counts] \
         == ["moe_counts"]

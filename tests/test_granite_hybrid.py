@@ -22,12 +22,8 @@ import jax
 import jax.numpy as jnp
 from flax.core import meta
 
-from neuronx_distributed_tpu import obs
 from neuronx_distributed_tpu.inference import paging
-from neuronx_distributed_tpu.inference.engine import (EngineConfig,
-                                                      ServingEngine)
 from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
-from neuronx_distributed_tpu.inference.speculative import SpeculationConfig
 from neuronx_distributed_tpu.models import granite_hybrid as gh
 from neuronx_distributed_tpu.ops import paged_attention as pa
 from neuronx_distributed_tpu.ops import ssd
@@ -39,7 +35,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402  (benchmarks/)
-from counter_checks import check_registered_counters  # noqa: E402  (tests/)
+import family_checks as fc  # noqa: E402  (tests/)
 
 BS = 16
 LAYERS = ["mamba", "attention", "mamba", "mamba", "attention"]
@@ -61,10 +57,10 @@ H, P, N, W = 4, 32, 16, 4
 HI = jax.lax.Precision.HIGHEST
 
 
+@fc.once_a_module
 def _model(**kw):
     """The package's own config (no seeded mapping: the tests' weights
     hold the scan's parameters as they are), its module and weights."""
-    ps.initialize_model_parallel()
     cfg = gh.GraniteHybridConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=5,
         num_heads=4, num_kv_heads=2, max_seq_len=4096,
@@ -76,19 +72,13 @@ def _model(**kw):
     init = meta.unbox(model.init(jax.random.key(3),
                                  jnp.zeros((1, 8), jnp.int32)))
 
-    def draw(path, x):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(jax.random.key(5),
-                                 sum(map(ord, name)) % 2 ** 31)
-        noise = jax.random.normal(key, x.shape, x.dtype)
-        if name.endswith("['scale']"):
-            return 1.0 + 0.3 * noise
-        if any(leaf in name for leaf in ("A_log", "dt_bias", "['D']",
-                                         "conv_kernel")):
+    def special(name, noise, x, key):
+        if not name.endswith("['scale']") and any(
+                leaf in name for leaf in ("A_log", "dt_bias", "['D']",
+                                          "conv_kernel")):
             return x                       # the module's Mamba-2 draws
-        return 0.08 * noise
 
-    return cfg, model, jax.tree_util.tree_map_with_path(draw, init)
+    return cfg, model, fc.seeded_weights(init, special)
 
 
 class _AsPublished:
@@ -113,14 +103,6 @@ def _reference_logits(params, tokens):
     ref = harness.load_plugin("reference", "granite_hybrid_f32")
     return np.asarray(ref.forward(_AsPublished(params), np.asarray(tokens),
                                   PUBLISHED)[0])
-
-
-def _ecfg(**kw):
-    base = dict(block_size=BS, num_blocks=40, max_slots=3,
-                max_blocks_per_seq=12, token_budget=16,
-                kv_dtype=jnp.float32)
-    base.update(kw)
-    return EngineConfig(**base)
 
 
 # -- (a) the scan's two forms -----------------------------------------------
@@ -420,34 +402,21 @@ def test_full_forward_matches_the_reference():
     np.testing.assert_allclose(got, want, atol=2e-4 * np.std(want))
 
 
-def _paged_logits(cfg, params, tokens, chunks, cache=None, slot=1,
-                  forward=None):
-    """One sequence through the paged forward in ``chunks`` rows a step
-    (prefill chunks, then single decode rows) beside pad rows."""
-    forward = forward or gh.granite_hybrid_forward_with_cache
+def _in_slot_1(cfg, params, tokens, chunks, cache=None, **kw):
+    """One sequence through the paged driver in ``chunks`` rows a step
+    (prefill chunks, then single decode rows) beside pad rows, in slot 1,
+    whose blocks are mapped out of order."""
     if cache is None:
         cache = paging.init_serving_cache(
             cfg, num_blocks=16, block_size=BS, table_rows=3,
             max_blocks_per_seq=6, dtype=jnp.float32)
     table = np.full((3, 6), -1, np.int32)
-    table[slot] = [3, 5, 7, 9, 11, 2]
-    cache = cache.replace(block_tables=jnp.asarray(table))
-    step = jax.jit(lambda p, c, t, pos, s: forward(cfg, p, t, pos, c,
-                                                   slot_ids=s))
-    width, done, out = max(chunks), 0, []
-    for count in chunks:
-        tok = np.zeros((1, width), np.int32)
-        pos = np.full((1, width), PAD_POSITION, np.int32)
-        ids = np.full((width,), 3, np.int32)
-        tok[0, :count] = tokens[done:done + count]
-        pos[0, :count] = np.arange(done, done + count)
-        ids[:count] = slot
-        with jax.default_matmul_precision("highest"):
-            logits, cache = step(params, cache, *map(jnp.asarray,
-                                                     (tok, pos, ids)))
-        out.append(np.asarray(logits[0, :count]))
-        done += count
-    return np.concatenate(out), cache
+    table[1] = [3, 5, 7, 9, 11, 2]
+    got, cache = fc.paged_logits(
+        cfg, params, [tokens], fc.chunked(chunks), max(chunks),
+        cache=cache.replace(block_tables=jnp.asarray(table)), slots=[1],
+        block_size=BS, **kw)
+    return np.stack([got[0, p] for p in range(sum(chunks))]), cache
 
 
 CHUNKS = [7, 16, 2, 1, 3, 9] + [1] * 7          # 45 positions
@@ -458,7 +427,7 @@ def test_paged_prefill_then_decode_matches_the_reference(impl):
     cfg, _, params = _model(
         attn_force_pallas=True if impl == "pallas-interpret" else None)
     tokens = np.random.RandomState(2).randint(0, 256, (45,))
-    got, cache = _paged_logits(cfg, params, tokens, CHUNKS)
+    got, cache = _in_slot_1(cfg, params, tokens, CHUNKS)
     want = _reference_logits(params, tokens[None])[0]
     np.testing.assert_allclose(got, want, atol=2e-4 * np.std(want))
     assert cache.k.shape == (2, 16, BS, 1, 32)       # two heads a row
@@ -468,17 +437,13 @@ def test_paged_prefill_then_decode_matches_the_reference(impl):
 
 # -- (d) the control: what the comparison must not pass ----------------------
 
-def _relative_error(got, want):
-    return float(np.abs(got - want).max() / np.std(want))
-
-
 @pytest.fixture(scope="module")
 def sound():
     cfg, _, params = _model()
     tokens = np.random.RandomState(2).randint(0, 256, (45,))
     want = _reference_logits(params, tokens[None])[0]
-    got, cache = _paged_logits(cfg, params, tokens, CHUNKS)
-    err = _relative_error(got, want)
+    got, cache = _in_slot_1(cfg, params, tokens, CHUNKS)
+    err = fc.worst(got, want)
     print("sound run: largest logit difference over the spread", err)
     assert err < 2e-5
     ps.destroy_model_parallel()
@@ -496,8 +461,9 @@ def test_a_stale_state_fails_the_comparison(sound, monkeypatch):
         return real(*a)._replace(zero=jnp.zeros_like(real(*a).zero))
 
     monkeypatch.setattr(ssd, "step_segments", never_fresh)
-    got, _ = _paged_logits(cfg, params, tokens, CHUNKS, cache=cache)
-    assert _relative_error(got, want) > limit
+    got, _ = _in_slot_1(cfg, params, tokens, CHUNKS, cache=cache,
+                        fresh=True)
+    assert fc.worst(got, want) > limit
 
 
 def test_a_dropped_convolution_tail_fails_the_comparison(sound, monkeypatch):
@@ -511,8 +477,8 @@ def test_a_dropped_convolution_tail_fails_the_comparison(sound, monkeypatch):
         return real(x, tails, *a)[0], tails
 
     monkeypatch.setattr(ssd, "causal_conv_step", tail_lost)
-    got, _ = _paged_logits(cfg, params, tokens, CHUNKS)
-    assert _relative_error(got, want) > limit
+    got, _ = _in_slot_1(cfg, params, tokens, CHUNKS, fresh=True)
+    assert fc.worst(got, want) > limit
 
 
 def test_a_bfloat16_state_fails_the_comparison(sound):
@@ -523,8 +489,8 @@ def test_a_bfloat16_state_fails_the_comparison(sound):
         max_blocks_per_seq=6, dtype=jnp.float32)
     cache = cache.replace(states=dict(
         cache.states, ssm=cache.states["ssm"].astype(jnp.bfloat16)))
-    got, _ = _paged_logits(cfg, params, tokens, CHUNKS, cache=cache)
-    assert _relative_error(got, want) > limit
+    got, _ = _in_slot_1(cfg, params, tokens, CHUNKS, cache=cache)
+    assert fc.worst(got, want) > limit
 
 
 # -- (e) the benchmark's family: seeded weights and checkpoint names ---------
@@ -564,7 +530,7 @@ def test_the_family_reads_normal_draws_as_mamba2s_initialisation():
         weights("lm_head")
     # the seeded forward and the reference read the same parameters
     tokens = np.random.RandomState(4).randint(0, 256, (30,))
-    got, _ = _paged_logits(cfg, params, tokens, [16, 13, 1], forward=forward)
+    got, _ = _in_slot_1(cfg, params, tokens, [16, 13, 1])
     ref = harness.load_plugin("reference", "granite_hybrid_f32")
     want = np.asarray(ref.forward(weights, tokens[None], PUBLISHED)[0])[0]
     np.testing.assert_allclose(got, want, atol=5e-4 * np.std(want))
@@ -574,49 +540,21 @@ def test_the_family_reads_normal_draws_as_mamba2s_initialisation():
 
 # -- (f) through ServingEngine --------------------------------------------------
 
-def _greedy_by_reference(params, prompt, tokens):
-    logits = _reference_logits(params, [prompt + tokens])
-    return np.argmax(logits[0, len(prompt) - 1:-1], -1).tolist()
-
-
 @pytest.fixture(scope="module")
 def served():
     """Three requests through one engine of two slots whose pool holds
     seven blocks: the youngest is preempted on the way."""
     cfg, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg(num_blocks=7, max_slots=2))
-    rng = np.random.RandomState(11)
-    prompts = {"a": rng.randint(0, 256, (50,)).tolist(),
-               "b": rng.randint(0, 256, (40,)).tolist(),
-               "c": rng.randint(0, 256, (5,)).tolist()}
-    new = {"a": 30, "b": 12, "c": 4}
-    obs.enable()
-    obs.get_registry().reset()
-    for uid, prompt in prompts.items():
-        eng.submit(prompt, new[uid], uid=uid)
-    steps = 0
-    while eng.has_work():
-        eng.step()
-        steps += 1
-    counters = {
-        name: {c.labels.get("kind", ""): c.value
-               for c in obs.get_registry().get(name).children()}
-        for name in ("nxd_state_slot_steps_total", "nxd_state_resets_total",
-                     "nxd_paged_columns_total", "nxd_engine_rows_total",
-                     "nxd_engine_steps_total")}
-    check_registered_counters(obs.get_registry(), cfg.serving_family())
-    obs.disable()
-    ps.destroy_model_parallel()
-    return cfg, params, eng, prompts, new, counters, steps
+    return fc.serve_three(cfg, params, (
+        "nxd_state_slot_steps_total", "nxd_state_resets_total",
+        "nxd_paged_columns_total", "nxd_engine_rows_total",
+        "nxd_engine_steps_total"),
+        lengths=[50, 40, 5], new=[30, 12, 4], num_blocks=7, max_slots=2)
 
 
 def test_engine_greedy_tokens_equal_the_reference(served):
-    cfg, params, eng, prompts, new, *_ = served
-    for uid, prompt in prompts.items():
-        assert eng.results[uid].status == "completed"
-        tokens = eng.results[uid].tokens
-        assert len(tokens) == new[uid]
-        assert tokens == _greedy_by_reference(params, prompt, tokens), uid
+    fc.check_engine_greedy_tokens_equal_the_reference(served,
+                                                      _reference_logits)
 
 
 def test_a_preempted_request_decodes_as_a_fresh_one(served):
@@ -624,18 +562,15 @@ def test_a_preempted_request_decodes_as_a_fresh_one(served):
     into a slot whose states and tails another request left, and still
     decodes what the reference does (above); the pool is whole at the
     end, and no leaf was ever cleared by the host."""
-    _, _, eng, *_ = served
-    assert eng.stats.preempted >= 1
-    assert eng.allocator.num_allocated == 0
-    assert (eng._tables == -1).all()
-    assert eng.compile_count() == 1
+    eng = served.eng
+    fc.check_preempted_and_whole(eng)
     assert float(jnp.abs(eng.cache.states["ssm"]).max()) > 0
     assert float(jnp.abs(eng.cache.states["conv"].astype(
         jnp.float32)).max()) > 0
 
 
 def test_state_counters(served):
-    *_, counters, steps = served
+    counters = served.counters
     slot_steps = counters["nxd_state_slot_steps_total"]
     assert set(slot_steps) == {"advanced", "held"}
     # every enqueued step advanced a slot; a slot whose prefill waits for
@@ -649,28 +584,18 @@ def test_state_counters(served):
     assert cols["live"] > 0 and cols["skipped"] > 0
 
 
-@pytest.mark.parametrize("feature,kw", [
-    ("prefix_sharing", dict(prefix_sharing=True)),
-    ("speculation", dict(speculation=SpeculationConfig())),
-    ("cp", dict(cp=2)),
-    ("quantized", dict(quantized=True)),
-])
+@pytest.mark.parametrize("feature,kw", fc.REFUSED_FEATURES)
 def test_refused_features_raise_by_name(feature, kw):
     cfg, _, params = _model()
-    with pytest.raises(ValueError, match=feature):
-        ServingEngine(cfg, params, _ecfg(**kw))
+    fc.check_refused_features(cfg, params, {feature: kw})
 
 
 def test_session_export_is_refused_and_the_cache_is_the_kinds():
     cfg, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg())
-    uid = eng.submit([1, 2, 3], 4)
-    eng.step()
-    with pytest.raises(ValueError, match="session_export"):
-        eng.export_session(uid)
+    eng = fc.check_session_export_is_refused(
+        cfg, params, paging.StatePoolPagedCache, paging.StatePoolCache)
     cache, kind = eng.cache, cfg.serving_family().cache_kind
-    assert isinstance(cache, paging.StatePoolPagedCache)
-    assert isinstance(kind, paging.StatePoolCache) and kind.pack == 2
+    assert kind.pack == 2
     assert [leaf.name for leaf in kind.leaves] == ["ssm", "conv"]
     assert cache.k.shape == (2, 40, BS, 1, 32) == cache.v.shape
     assert cache.states["ssm"].shape == (3, 3, 16, 128)

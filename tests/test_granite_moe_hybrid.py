@@ -26,13 +26,9 @@ import jax
 import jax.numpy as jnp
 from flax.core import meta
 
-from counter_checks import check_registered_counters
+import family_checks as fc
 from granite_faults import faults
-from neuronx_distributed_tpu import obs
 from neuronx_distributed_tpu.inference import paging
-from neuronx_distributed_tpu.inference.engine import (EngineConfig,
-                                                      ServingEngine)
-from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
 from neuronx_distributed_tpu.models import granite_hybrid as gh
 from neuronx_distributed_tpu.models.llama import LlamaMLP
 from neuronx_distributed_tpu.modules.moe import MoE
@@ -69,11 +65,11 @@ def _reference():
     return harness.load_plugin("reference", "granite_moe_hybrid_f32")
 
 
+@fc.once_a_module
 def _model(**kw):
     """The family's config from the published keys, its module and seeded
     weights: what ``make_weights`` would draw for the scan's leaves (the
     family reads them as Mamba-2's), order one elsewhere."""
-    ps.initialize_model_parallel()
     cfg, model, _ = _family().build(
         PUBLISHED, **{"dtype": jnp.float32, "param_dtype": jnp.float32,
                       **kw})
@@ -82,19 +78,14 @@ def _model(**kw):
     init = meta.unbox(jax.eval_shape(model.init, jax.random.key(3),
                                      jnp.zeros((1, 8), jnp.int32)))
 
-    def draw(path, x):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(jax.random.key(5),
-                                 sum(map(ord, name)) % 2 ** 31)
-        noise = jax.random.normal(key, x.shape, x.dtype)
-        if name.endswith("['scale']"):
-            return 1.0 + 0.3 * noise
+    def special(name, noise, x, key):
         if any(leaf in name for leaf in ("A_log", "dt_bias", "['D']",
                                          "conv_kernel")):
             return 0.02 * noise
-        return (1.0 if "router" in name else 0.08) * noise
+        if "router" in name and not name.endswith("['scale']"):
+            return 1.0 * noise
 
-    _CASE["params"] = jax.tree_util.tree_map_with_path(draw, init)
+    _CASE["params"] = fc.seeded_weights(init, special)
     return cfg, model, _CASE["params"]
 
 
@@ -112,10 +103,6 @@ def _case():
         _CASE["tokens"], _CASE["want"] = tokens, _reference_logits(params,
                                                                    tokens)
     return _CASE["tokens"], _CASE["want"]
-
-
-def _worst(got, want):
-    return float(np.abs(got - want).max() / np.std(want))
 
 
 # -- (a) the model and the paged forward against the reference --------------
@@ -257,57 +244,7 @@ def test_full_forward_matches_the_reference():
                                  ).with_mamba2_init(params, 0.02)
     with jax.default_matmul_precision("highest"):
         got = np.asarray(jax.jit(model.apply)(served, jnp.asarray(tokens)))
-    assert _worst(got, want) < SOUND
-
-
-def _paged_logits(cfg, params, seqs, steps, width=BS, cache=None):
-    """Sequences ``seqs [n, S]`` through the family's paged forward by
-    ``steps``, each a list of rows ``(sequence, position)`` (sequence
-    ``s`` in slot ``s``), padded to ``width``; blocks are mapped in order
-    as the engine maps them. ``({(s, p): logits}, cache)``."""
-    if cache is None:
-        cache = paging.init_serving_cache(
-            cfg, num_blocks=24, block_size=BS, table_rows=3,
-            max_blocks_per_seq=8, dtype=jnp.float32)
-    table = np.array(cache.block_tables)
-    mapped = int((table >= 0).sum())
-    forward = cfg.serving_family().forward
-    step = jax.jit(lambda p, c, t, pos, s: forward(cfg, p, t, pos, c,
-                                                   slot_ids=s))
-    out = {}
-    for rows in steps:
-        tok = np.zeros((1, width), np.int32)
-        pos = np.full((1, width), PAD_POSITION, np.int32)
-        ids = np.full((width,), table.shape[0], np.int32)
-        for i, (s, p) in enumerate(rows):
-            tok[0, i], pos[0, i], ids[i] = seqs[s][p], p, s
-            if table[s, p // BS] < 0:
-                table[s, p // BS], mapped = mapped, mapped + 1
-        cache = cache.replace(block_tables=jnp.asarray(table))
-        with jax.default_matmul_precision("highest"):
-            logits, cache = step(params, cache, *map(jnp.asarray,
-                                                     (tok, pos, ids)))
-        for i, row in enumerate(rows):
-            out[row] = np.asarray(logits[0, i])
-    return out, cache
-
-
-def _schedule(length, chunks):
-    """Sequence 0 prefills in ``chunks`` and then decodes a row a step to
-    ``length``; sequence 1 prefills beside its decode rows, in chunks of
-    what the step has left, unaligned to the blocks."""
-    steps, done = [], [0, 0]
-    for n in chunks:
-        steps.append([(0, done[0] + i) for i in range(n)])
-        done[0] += n
-    while min(done) < length:
-        rows = [(0, done[0])] if done[0] < length else []
-        done[0] += len(rows)
-        n = min(BS - len(rows) - len(steps) % 2, length - done[1])
-        rows += [(1, done[1] + i) for i in range(n)]
-        done[1] += n
-        steps.append(rows)
-    return steps
+    assert fc.worst(got, want) < SOUND
 
 
 @pytest.mark.parametrize("impl,length", [("xla", LENGTH),
@@ -319,8 +256,8 @@ def test_paged_prefill_then_decode_matches_the_reference(impl, length):
     cfg, _, params = _model(
         attn_force_pallas=True if impl == "pallas-interpret" else None)
     seqs, want = _case()
-    steps = _schedule(length, [3, 8, 2, 1, 5])
-    got, cache = _paged_logits(cfg, params, seqs, steps)
+    steps = fc.schedule(length, [3, 8, 2, 1, 5], BS)
+    got, cache = fc.paged_logits(cfg, params, seqs, steps, BS)
     assert len(got) == 2 * length
     for (s, p), logits in got.items():
         np.testing.assert_allclose(logits, want[s, p],
@@ -348,18 +285,17 @@ def test_what_the_comparison_must_not_pass(fault):
     fault put in reads over its stated multiple of it."""
     cfg, _, params = _model()
     seqs, want = _case()
-    steps = _schedule(30, [4, 5, 3, 4, 4])[:12]
+    steps = fc.schedule(30, [4, 5, 3, 4, 4], BS)[:12]
 
-    def worst():
-        got, _ = _paged_logits(cfg, params, seqs, steps)
-        return max(np.abs(v - want[s, p]).max() for (s, p), v in got.items()
-                   ) / np.std(want)
+    def worst(**kw):
+        got, _ = fc.paged_logits(cfg, params, seqs, steps, BS, **kw)
+        return fc.worst_at(got, want)
 
     if "sound" not in _CASE:
         _CASE["sound"] = worst()
     assert _CASE["sound"] < SOUND
     with faults(4, 5)[fault]():
-        read = worst()
+        read = worst(fresh=True)
     print(fault, "reads", read)
     assert read > FAULTS[fault] * SOUND
 
@@ -474,53 +410,30 @@ def test_two_shares_routed_sums_and_the_shared_mlp_once_are_the_layer():
 
 # -- (d) through ServingEngine -------------------------------------------------
 
-def _greedy_by_reference(params, prompt, tokens):
-    logits = _reference_logits(params, [prompt + tokens])
-    return np.argmax(logits[0, len(prompt) - 1:-1], -1).tolist()
-
-
 @pytest.fixture(scope="module")
 def served():
     """One request of 20 prompt tokens and 3 new ones through an engine
     whose steps hold 16 rows: a chunk of 16, a chunk of 4, two decode
     rows."""
     cfg, _, params = _model()
-    eng = ServingEngine(cfg, params, EngineConfig(
-        block_size=BS, num_blocks=40, max_slots=3, max_blocks_per_seq=12,
-        token_budget=16, kv_dtype=jnp.float32))
-    prompt = np.random.RandomState(11).randint(0, 256, (20,)).tolist()
-    obs.enable()
-    obs.get_registry().reset()
-    eng.submit(prompt, 3, uid="a")
-    while eng.has_work():
-        eng.step()
-    counters = {
-        name: {c.labels.get("kind", ""): c.value
-               for c in obs.get_registry().get(name).children()}
-        for name in ("nxd_moe_assignments_total", "nxd_moe_held_total",
-                     "nxd_state_segment_rows_total",
-                     "nxd_state_bytes_held_total")}
-    check_registered_counters(obs.get_registry(), cfg.serving_family())
-    obs.disable()
-    ps.destroy_model_parallel()
-    return cfg, params, eng, prompt, counters
+    return fc.serve_three(cfg, params, (
+        "nxd_moe_assignments_total", "nxd_moe_held_total",
+        "nxd_state_segment_rows_total", "nxd_state_bytes_held_total"),
+        lengths=[20], new=[3])
 
 
 def test_engine_greedy_tokens_equal_the_reference(served):
-    _, params, eng, prompt, _ = served
-    assert eng.results["a"].status == "completed"
-    tokens = eng.results["a"].tokens
-    assert len(tokens) == 3
-    assert tokens == _greedy_by_reference(params, prompt, tokens)
-    assert eng.compile_count() == 1
-    assert eng.cache.moe_counts.shape == (3,)
+    fc.check_engine_greedy_tokens_equal_the_reference(served,
+                                                      _reference_logits)
+    assert served.eng.compile_count() == 1
+    assert served.eng.cache.moe_counts.shape == (3,)
 
 
 def test_the_routed_assignments_and_the_segments_rows_are_counted(served):
     """By hand: 22 real rows (20 prompt positions, two decode rows) in
     four steps, each one segment: a first row a step, the chunks' 15 and
     3 rows after theirs; three choices a row in each of five layers."""
-    *_, counters = served
+    counters = served.counters
     assert counters["nxd_state_segment_rows_total"] == {"first": 4,
                                                         "later": 18}
     kept_dropped = counters["nxd_moe_assignments_total"]

@@ -23,12 +23,9 @@ import jax
 import jax.numpy as jnp
 from flax.core import meta
 
-from neuronx_distributed_tpu import obs
 from neuronx_distributed_tpu.inference import paging
-from neuronx_distributed_tpu.inference.engine import (EngineConfig,
-                                                      ServingEngine)
+from neuronx_distributed_tpu.inference.engine import ServingEngine
 from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
-from neuronx_distributed_tpu.inference.speculative import SpeculationConfig
 from neuronx_distributed_tpu.models import laguna
 from neuronx_distributed_tpu.modules import attention as attn_mod
 from neuronx_distributed_tpu.modules.moe import MoE
@@ -41,9 +38,11 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402  (benchmarks/)
-from counter_checks import check_registered_counters  # noqa: E402  (tests/)
+import family_checks as fc  # noqa: E402  (tests/)
 
 BS, WINDOW, RING = 4, 8, 3
+#: the paged driver's pools: blocks of 4 under two sequences of 45
+POOL = dict(num_blocks=40, max_blocks_per_seq=16)
 LAYERS = ["full_attention"] + ["sliding_attention"] * 3 + ["full_attention"]
 PUBLISHED = dict(
     model_type="laguna", vocab_size=256, hidden_size=64,
@@ -79,27 +78,22 @@ def _reference():
     return harness.load_plugin("reference", "laguna_f32")
 
 
+@fc.once_a_module
 def _model(published=PUBLISHED, **kw):
     """The family's config from the published keys, its module and seeded
     weights."""
-    ps.initialize_model_parallel()
     cfg, model, _ = _family().build(
         published, **{"dtype": jnp.float32, "param_dtype": jnp.float32,
                       **kw})
     init = meta.unbox(model.init(jax.random.key(3),
                                  jnp.zeros((1, 8), jnp.int32)))
 
-    def draw(path, x):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(jax.random.key(5),
-                                 sum(map(ord, name)) % 2 ** 31)
-        noise = jax.random.normal(key, x.shape, x.dtype)
-        if name.endswith("['scale']"):
-            return 1.0 + 0.3 * noise
+    def special(name, noise, x, key):
         # a router of order one, so that the choices are not all ties
-        return (1.0 if "router" in name else 0.08) * noise
+        if "router" in name and not name.endswith("['scale']"):
+            return 1.0 * noise
 
-    return cfg, model, jax.tree_util.tree_map_with_path(draw, init)
+    return cfg, model, fc.seeded_weights(init, special)
 
 
 def _reference_logits(params, tokens, published=PUBLISHED):
@@ -185,58 +179,6 @@ def test_full_forward_matches_the_reference():
     np.testing.assert_allclose(got, want, atol=3e-4 * np.std(want))
 
 
-def _init_cache(cfg, num_blocks=40, rows=3, columns=16):
-    return paging.init_serving_cache(
-        cfg, num_blocks=num_blocks, block_size=BS, table_rows=rows,
-        max_blocks_per_seq=columns, dtype=jnp.float32)
-
-
-def _paged_logits(cfg, params, seqs, steps, cache=None, width=BS):
-    """Sequences ``seqs [n, S]`` through the paged forward by ``steps``,
-    each a list of rows ``(sequence, position)`` (sequence ``s`` in slot
-    ``s``), padded to ``width``; full-pool blocks are mapped in order as
-    the engine maps them. ``{(s, p): logits}``."""
-    cache = _init_cache(cfg) if cache is None else cache
-    table = np.array(cache.block_tables)
-    mapped = int((table >= 0).sum())
-    step = jax.jit(lambda p, c, t, pos, s: laguna.laguna_forward_with_cache(
-        cfg, p, t, pos, c, slot_ids=s))
-    out = {}
-    for rows in steps:
-        tok = np.zeros((1, width), np.int32)
-        pos = np.full((1, width), PAD_POSITION, np.int32)
-        ids = np.full((width,), table.shape[0], np.int32)
-        for i, (s, p) in enumerate(rows):
-            tok[0, i], pos[0, i], ids[i] = seqs[s][p], p, s
-            if table[s, p // BS] < 0:
-                table[s, p // BS], mapped = mapped, mapped + 1
-        cache = cache.replace(block_tables=jnp.asarray(table))
-        with jax.default_matmul_precision("highest"):
-            logits, cache = step(params, cache, *map(jnp.asarray,
-                                                     (tok, pos, ids)))
-        for i, row in enumerate(rows):
-            out[row] = np.asarray(logits[0, i])
-    return out, cache
-
-
-def _schedule(length, chunks):
-    """Sequence 0 prefills in ``chunks`` and then decodes a row a step to
-    ``length``; sequence 1 prefills beside its decode rows, in chunks of
-    what the step has left, unaligned to the blocks."""
-    steps, done = [], [0, 0]
-    for n in chunks:
-        steps.append([(0, done[0] + i) for i in range(n)])
-        done[0] += n
-    while min(done) < length:
-        rows = [(0, done[0])] if done[0] < length else []
-        done[0] += len(rows)
-        n = min(BS - len(rows) - len(steps) % 2, length - done[1])
-        rows += [(1, done[1] + i) for i in range(n)]
-        done[1] += n
-        steps.append(rows)
-    return steps
-
-
 @pytest.mark.parametrize("impl", ["xla", "pallas-interpret"])
 def test_paged_prefill_then_decode_matches_the_reference(impl):
     """45 positions pass the window five times and wrap the ring of 12
@@ -245,8 +187,9 @@ def test_paged_prefill_then_decode_matches_the_reference(impl):
     cfg, _, params = _model(
         attn_force_pallas=True if impl == "pallas-interpret" else None)
     seqs = np.random.RandomState(2).randint(0, 256, (2, 45))
-    got, cache = _paged_logits(cfg, params, seqs,
-                               _schedule(45, [3, 4, 2, 1, 4, 4]))
+    got, cache = fc.paged_logits(
+        cfg, params, seqs, fc.schedule(45, [3, 4, 2, 1, 4, 4], BS), BS,
+        **POOL)
     want = _reference_logits(params, seqs)
     assert len(got) == 90
     for (s, p), logits in got.items():
@@ -275,13 +218,12 @@ def test_a_window_off_by_a_block_or_a_bf16_router_fails_the_comparison(
 
     cfg, _, params = _model()
     seqs = np.random.RandomState(2).randint(0, 256, (1, 30))
-    steps = _schedule(30, [4] * 7 + [2])[:8]
-    want = _reference_logits(params, seqs)[0]
+    steps = fc.schedule(30, [4] * 7 + [2], BS)[:8]
+    want = _reference_logits(params, seqs)
 
-    def worst(cfg):
-        got, _ = _paged_logits(cfg, params, seqs, steps)
-        return max(np.abs(v - want[p]).max() for (_, p), v in got.items()
-                   ) / np.std(want)
+    def worst(cfg, **kw):
+        got, _ = fc.paged_logits(cfg, params, seqs, steps, BS, **POOL, **kw)
+        return fc.worst_at(got, want)
 
     assert worst(cfg) < 3e-4
     assert worst(dataclasses.replace(cfg, sliding_window=WINDOW + BS)) > 0.05
@@ -292,7 +234,7 @@ def test_a_window_off_by_a_block_or_a_bf16_router_fails_the_comparison(
         routing.RouterBase, "logits",
         lambda self, x: logits(self, x).astype(jnp.bfloat16).astype(
             jnp.float32))
-    assert worst(cfg) > 3e-3
+    assert worst(cfg, fresh=True) > 3e-3
 
 
 # -- (c) the window, exact at its edge, in both implementations --------------
@@ -522,21 +464,9 @@ def test_held_dispatch_is_the_dispatch_over_the_held_experts():
 
 # -- (e) the engine: the ring stays a slot's, preemption, counters -----------
 
-def _ecfg(**kw):
-    base = dict(block_size=BS, num_blocks=64, max_slots=3,
-                max_blocks_per_seq=48, token_budget=BS,
-                kv_dtype=jnp.float32)
-    base.update(kw)
-    return EngineConfig(**base)
-
-
-def _greedy_by_reference(params, prompt, generated):
-    """The reference's greedy choice at each generated position, given the
-    tokens generated before it (one forward: causal)."""
-    seq = np.asarray(list(prompt) + list(generated))[None]
-    logits = _reference_logits(params, seq)[0]
-    return [int(np.argmax(logits[len(prompt) - 1 + i]))
-            for i in range(len(generated))]
+#: over ``family_checks.engine_config``: blocks and steps of 4 rows
+ENGINE = dict(block_size=BS, num_blocks=64, max_blocks_per_seq=48,
+              token_budget=BS)
 
 
 @pytest.fixture(scope="module")
@@ -546,43 +476,23 @@ def served():
     preempted on the way and re-admitted into a slot whose ring another
     request left."""
     cfg, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg(num_blocks=52, max_slots=2))
-    rng = np.random.RandomState(11)
-    prompts = {"a": rng.randint(0, 256, (130,)).tolist(),
-               "b": rng.randint(0, 256, (40,)).tolist(),
-               "c": rng.randint(0, 256, (5,)).tolist()}
-    new = {"a": 30, "b": 25, "c": 4}
-    obs.enable()
-    obs.get_registry().reset()
-    for uid, prompt in prompts.items():
-        eng.submit(prompt, new[uid], uid=uid)
-    ring_blocks = []
-    while eng.has_work():
-        eng.step()
-        ring_blocks.append(int((np.asarray(eng.cache.wpos).reshape(
-            2, RING, BS) < PAD_POSITION).any(-1).sum(-1).max()))
-    names = ("nxd_window_columns_total", "nxd_kv_blocks_held_total",
-             "nxd_moe_held_total", "nxd_moe_assignments_total",
-             "nxd_paged_columns_total", "nxd_paged_pairs_total",
-             "nxd_paged_shared_pairs_total",
-             "nxd_paged_block_visits_total")
-    counters = {
-        name: {c.labels.get("kind", ""): c.value
-               for c in obs.get_registry().get(name).children()}
-        for name in names}
-    check_registered_counters(obs.get_registry(), cfg.serving_family())
-    obs.disable()
-    ps.destroy_model_parallel()
-    return cfg, params, eng, prompts, new, counters, ring_blocks
+
+    def ring_blocks(eng):
+        return int((np.asarray(eng.cache.wpos).reshape(
+            2, RING, BS) < PAD_POSITION).any(-1).sum(-1).max())
+
+    return fc.serve_three(cfg, params, (
+        "nxd_window_columns_total", "nxd_kv_blocks_held_total",
+        "nxd_moe_held_total", "nxd_moe_assignments_total",
+        "nxd_paged_columns_total", "nxd_paged_pairs_total",
+        "nxd_paged_shared_pairs_total", "nxd_paged_block_visits_total"),
+        lengths=[130, 40, 5], new=[30, 25, 4], watch=ring_blocks,
+        **dict(ENGINE, num_blocks=52, max_slots=2))
 
 
 def test_engine_greedy_tokens_equal_the_reference(served):
-    cfg, params, eng, prompts, new, *_ = served
-    for uid, prompt in prompts.items():
-        assert eng.results[uid].status == "completed"
-        tokens = eng.results[uid].tokens
-        assert len(tokens) == new[uid]
-        assert tokens == _greedy_by_reference(params, prompt, tokens), uid
+    fc.check_engine_greedy_tokens_equal_the_reference(served,
+                                                      _reference_logits)
 
 
 def test_a_slots_ring_stays_bounded_and_survives_preemption(served):
@@ -590,12 +500,9 @@ def test_a_slots_ring_stays_bounded_and_survives_preemption(served):
     three, reused lap after lap; the full pool's are released whole; the
     preempted request decodes what the reference does (above) from a ring
     that the host never cleared."""
-    cfg, _, eng, *_, ring_blocks = served
-    assert eng.stats.preempted >= 1
+    eng, ring_blocks = served.eng, served.seen
     assert max(ring_blocks) == RING and ring_blocks[-1] == RING
-    assert eng.allocator.num_allocated == 0
-    assert (eng._tables == -1).all()
-    assert eng.compile_count() == 1
+    fc.check_preempted_and_whole(eng)
     kind = eng._cache_kind
     assert isinstance(kind, paging.WindowPoolCache) and kind.ring is None
     assert (kind.full_layers, kind.window_layers, kind.window) == (2, 3, 8)
@@ -610,7 +517,7 @@ def test_a_slots_ring_stays_bounded_and_survives_preemption(served):
 
 
 def test_window_counters(served):
-    *_, counters, _ = served
+    counters = served.counters
     cols = counters["nxd_window_columns_total"]
     assert set(cols) == {"live", "behind"}
     # a row's window is two or three of the columns it has mapped: of a
@@ -634,7 +541,7 @@ def test_no_decode_rows_pair_runs_over_the_whole_tile(served):
     pair that one packed row names is narrow whatever the row's place
     (here a chunk's too: its four rows of two heads lie in one group of
     8), and with the shared ones they are the pairs fetched."""
-    *_, counters, _ = served
+    counters = served.counters
     pairs = counters["nxd_paged_pairs_total"]
     assert set(pairs) == {"narrow", "one_row_whole"}
     assert pairs["narrow"] > 0 and pairs["one_row_whole"] == 0
@@ -643,28 +550,19 @@ def test_no_decode_rows_pair_runs_over_the_whole_tile(served):
         "nxd_paged_block_visits_total"]["fetched"]
 
 
-@pytest.mark.parametrize("feature,kw", [
-    ("prefix_sharing", dict(prefix_sharing=True)),
-    ("speculation", dict(speculation=SpeculationConfig())),
-    ("cp", dict(cp=2)),
-    ("quantized", dict(quantized=True)),
-])
+@pytest.mark.parametrize("feature,kw", fc.REFUSED_FEATURES)
 def test_refused_features_raise_by_name(feature, kw):
     cfg, _, params = _model()
-    with pytest.raises(ValueError, match=feature):
-        ServingEngine(cfg, params, _ecfg(**kw))
+    fc.check_refused_features(cfg, params, {feature: kw}, **ENGINE)
 
 
 def test_session_export_is_refused_and_a_step_wider_than_a_block():
     cfg, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg())
-    uid = eng.submit([1, 2, 3], 4)
-    eng.step()
-    with pytest.raises(ValueError, match="session_export"):
-        eng.export_session(uid)
-    assert isinstance(eng.cache, paging.WindowPoolPagedCache)
+    fc.check_session_export_is_refused(
+        cfg, params, paging.WindowPoolPagedCache, **ENGINE)
     with pytest.raises(ValueError, match="token_budget"):
-        ServingEngine(cfg, params, _ecfg(token_budget=2 * BS))
+        ServingEngine(cfg, params, fc.engine_config(
+            **dict(ENGINE, token_budget=2 * BS)))
 
 
 def test_the_kernel_alone_serves_the_pools_on_a_tpu(monkeypatch):
@@ -677,7 +575,7 @@ def test_the_kernel_alone_serves_the_pools_on_a_tpu(monkeypatch):
         assert pa.paged_attention_impl(128, 128, None,
                                        kernel_only=True) == "pallas"
         with pytest.raises(ValueError, match="don't tile"):
-            _paged_logits(cfg, params, np.zeros((1, 4), np.int64),
-                          [[(0, 0)]])
+            fc.paged_logits(cfg, params, np.zeros((1, 4), np.int64),
+                            [[(0, 0)]], BS, fresh=True, **POOL)
     finally:
         pa.paged_attention_impl.cache_clear()

@@ -27,12 +27,9 @@ import jax
 import jax.numpy as jnp
 from flax.core import meta
 
-from neuronx_distributed_tpu import obs
 from neuronx_distributed_tpu.inference import paging
-from neuronx_distributed_tpu.inference.engine import (EngineConfig,
-                                                      ServingEngine)
+from neuronx_distributed_tpu.inference.engine import ServingEngine
 from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
-from neuronx_distributed_tpu.inference.speculative import SpeculationConfig
 from neuronx_distributed_tpu.modules.moe import MoE, RouterSoftmaxBias
 from neuronx_distributed_tpu.ops import mla_attention as mla
 from neuronx_distributed_tpu.ops import paged_attention as pa
@@ -44,7 +41,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402  (benchmarks/)
-from counter_checks import check_registered_counters  # noqa: E402  (tests/)
+import family_checks as fc  # noqa: E402  (tests/)
 from longcat_faults import faults  # noqa: E402  (tests/)
 from walk_checks import check_tile_walk  # noqa: E402  (tests/)
 from runners import serve  # noqa: E402
@@ -71,28 +68,20 @@ def _family():
     return harness.load_plugin("families", "longcat_flash")
 
 
+@fc.once_a_module
 def _model(published=PUBLISHED, **kw):
-    ps.initialize_model_parallel()
     cfg, model, forward = _family().build(
         published, dtype=jnp.float32, param_dtype=jnp.float32, **kw)
-    shapes = meta.unbox(model.init(jax.random.key(0),
-                                   jnp.zeros((1, 8), jnp.int32)))
+    shapes = meta.unbox(jax.eval_shape(model.init, jax.random.key(0),
+                                       jnp.zeros((1, 8), jnp.int32)))
 
-    def draw(path, x):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(jax.random.key(5),
-                                 sum(map(ord, name)) % 2 ** 31)
-        noise = jax.random.normal(key, x.shape, x.dtype)
-        if name.endswith("['scale']"):
-            return 1.0 + 0.3 * noise
+    def special(name, noise, x, key):
         if name.endswith("['bias']"):
             # served at 0.2 of the leaf (families/longcat_flash.py): 0.03,
             # of the spread of p over 12 slots
             return 0.15 * noise
-        return 0.08 * noise
 
-    return cfg, model, forward, jax.tree_util.tree_map_with_path(draw,
-                                                                 shapes)
+    return cfg, model, forward, fc.seeded_weights(shapes, special)
 
 
 def _reference(params, published=PUBLISHED):
@@ -108,16 +97,9 @@ def _full(model, params, tokens):
             _family().with_seeded_bias(params, STD), jnp.asarray(tokens)))
 
 
-def _ecfg(**kw):
-    base = dict(block_size=BS, num_blocks=40, max_slots=3,
-                max_blocks_per_seq=12, token_budget=16,
-                kv_dtype=jnp.float32)
-    base.update(kw)
-    return EngineConfig(**base)
-
-
-def _worst(got, want):
-    return float(np.abs(got - want).max() / np.std(want))
+def _reference_logits(params, tokens):
+    ref, weights = _reference(params)
+    return ref.forward(weights, np.asarray(tokens), PUBLISHED)[0]
 
 
 # -- the module's full forward ----------------------------------------------
@@ -164,13 +146,13 @@ def test_full_forward_matches_the_reference_with_every_mechanism_on():
     ref, weights = _reference(params)
     want, margins = ref.forward(weights, tokens, PUBLISHED)
     assert got.shape == want.shape == (2, 70, 256)
-    assert _worst(got, want) < SOUND
+    assert fc.worst(got, want) < SOUND
     assert margins.shape == (2, 2, 70) and float(margins.min()) >= 0
     # the bias changes the choice: without it the logits differ
     bare = jax.tree_util.tree_map_with_path(
         lambda p, x: x * 0 if jax.tree_util.keystr(p).endswith("['bias']")
         else x, params)
-    assert _worst(_full(model, bare, tokens), want) > 1e-2
+    assert fc.worst(_full(model, bare, tokens), want) > 1e-2
     at = np.array([0, 33, 69])
     np.testing.assert_allclose(
         ref.forward(weights, tokens, PUBLISHED, positions=at)[0],
@@ -196,11 +178,12 @@ def test_paged_forward_matches_the_references_expanded_keys_and_values(
     assert any(len(rows) < 16 for rows in schedule)          # pad rows
     assert any({s for s, _ in rows} == {0, 1} for rows in schedule)
     with jax.default_matmul_precision("highest"):
-        seqs, got = serve.probe_logits(7, cfg, forward, params, _ecfg(), chk)
+        seqs, got = serve.probe_logits(7, cfg, forward, params,
+                                       fc.engine_config(), chk)
     ref, weights = _reference(params)
     want = np.asarray(ref.forward(weights, seqs, PUBLISHED)[0])
     assert got.shape == want.shape == (2, 62, 256)
-    assert _worst(got, want) < SOUND
+    assert fc.worst(got, want) < SOUND
 
 
 def test_a_share_of_the_experts_is_the_references_share():
@@ -213,10 +196,11 @@ def test_a_share_of_the_experts_is_the_references_share():
     assert layer["moe"]["router"]["kernel"].shape == (2, 64, 12)
     chk = dict(prompt_tokens=40, decode_steps=6)
     with jax.default_matmul_precision("highest"):
-        seqs, got = serve.probe_logits(9, cfg, forward, params, _ecfg(), chk)
+        seqs, got = serve.probe_logits(9, cfg, forward, params,
+                                       fc.engine_config(), chk)
     ref, weights = _reference(params, SHARE)
     want = np.asarray(ref.forward(weights, seqs, SHARE)[0])
-    assert _worst(got, want) < SOUND
+    assert fc.worst(got, want) < SOUND
     with pytest.raises(KeyError, match="held elsewhere"):
         weights("mlp.experts.gate_proj", 0, 4)
 
@@ -240,7 +224,8 @@ def test_a_fault_put_into_the_program_fails_the_comparison(fault):
 
     def probe():
         with jax.default_matmul_precision("highest"):
-            return serve.probe_logits(9, cfg, forward, params, _ecfg(), chk)
+            return serve.probe_logits(9, cfg, forward, params,
+                                      fc.engine_config(), chk)
 
     if fault in flags:
         seqs, got = probe()
@@ -249,7 +234,7 @@ def test_a_fault_put_into_the_program_fails_the_comparison(fault):
             seqs, got = probe()
     ref, weights = _reference(params)
     want = np.asarray(ref.forward(weights, seqs, PUBLISHED)[0])
-    assert _worst(got, want) > 3e-2, fault
+    assert fc.worst(got, want) > 3e-2, fault
 
 
 # -- the router ---------------------------------------------------------------
@@ -538,58 +523,28 @@ def test_a_shared_pair_left_over_is_a_unit_alone(monkeypatch):
 
 # -- through ServingEngine ------------------------------------------------------
 
-def _greedy_by_reference(params, prompt, tokens):
-    ref, weights = _reference(params)
-    logits, _ = ref.forward(weights, np.asarray([prompt + tokens]),
-                            PUBLISHED)
-    return np.argmax(np.asarray(logits)[0, len(prompt) - 1:-1], -1).tolist()
-
-
 @pytest.fixture(scope="module")
 def served():
     """Three requests, one of them preempted on the way, through one
     engine."""
     cfg, _, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg(num_blocks=9, max_slots=2))
-    rng = np.random.RandomState(11)
-    prompts = {"a": rng.randint(0, 256, (70,)).tolist(),
-               "b": rng.randint(0, 256, (40,)).tolist(),
-               "c": rng.randint(0, 256, (5,)).tolist()}
-    new = {"a": 30, "b": 12, "c": 4}
-    obs.enable()
-    obs.get_registry().reset()
-    for uid, prompt in prompts.items():
-        eng.submit(prompt, new[uid], uid=uid)
-    while eng.has_work():
-        eng.step()
-    counters = {
-        name: {c.labels.get("kind", ""): c.value
-               for c in obs.get_registry().get(name).children()}
-        for name in ("nxd_moe_assignments_total", "nxd_moe_held_total",
-                     "nxd_moe_identity_total", "nxd_paged_columns_total",
-                     "nxd_mla_block_fetches_total",
-                     "nxd_mla_shared_blocks_total",
-                     "nxd_engine_rows_total")}
-    check_registered_counters(obs.get_registry(), cfg.serving_family())
-    obs.disable()
-    ps.destroy_model_parallel()
-    return cfg, params, eng, prompts, new, counters
+    return fc.serve_three(cfg, params, (
+        "nxd_moe_assignments_total", "nxd_moe_held_total",
+        "nxd_moe_identity_total", "nxd_paged_columns_total",
+        "nxd_mla_block_fetches_total", "nxd_mla_shared_blocks_total",
+        "nxd_engine_rows_total"),
+        lengths=[70, 40, 5], new=[30, 12, 4], num_blocks=9, max_slots=2)
 
 
 def test_engine_greedy_tokens_equal_the_reference(served):
-    cfg, params, eng, prompts, new, _ = served
-    for uid, prompt in prompts.items():
-        assert eng.results[uid].status == "completed"
-        tokens = eng.results[uid].tokens
-        assert len(tokens) == new[uid]
-        assert tokens == _greedy_by_reference(params, prompt, tokens), uid
+    fc.check_engine_greedy_tokens_equal_the_reference(served,
+                                                      _reference_logits)
     # one of the two was preempted and re-admitted on the way
-    assert eng.stats.preempted >= 1 and eng.allocator.num_allocated == 0
-    assert eng.compile_count() == 1
+    fc.check_preempted_and_whole(served.eng)
 
 
 def test_the_counters_tell_identity_from_routed_and_held(served):
-    cfg, *_, counters = served
+    counters = served.counters
     rows = counters["nxd_engine_rows_total"]
     choices = (rows["decode"] + rows["prefill"]) * 3 * 2
     identity = counters["nxd_moe_identity_total"]
@@ -609,7 +564,7 @@ def test_the_counters_tell_identity_from_routed_and_held(served):
 
 def test_the_cache_has_two_layers_of_rows_a_decoder_layer():
     cfg, _, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg())
+    eng = ServingEngine(cfg, params, fc.engine_config())
     cache = eng.cache
     assert isinstance(cache, paging.LatentPagedCache)
     assert cache.rows.shape == (4, 40, BS, 128)
@@ -644,7 +599,7 @@ def test_a_rows_second_attention_reads_its_own_layer_of_rows():
     unlike rows side by side in the stack, every layer of rows holds the
     positions written, and none past them."""
     cfg, _, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg())
+    eng = ServingEngine(cfg, params, fc.engine_config())
     eng.submit(list(range(20)), 4)
     for _ in range(3):
         eng.step()
@@ -657,17 +612,10 @@ def test_a_rows_second_attention_reads_its_own_layer_of_rows():
     assert (rows[..., 40:] == 0).all()           # the idle lanes
 
 
-@pytest.mark.parametrize("feature,kw", [
-    ("speculation", dict(speculation=SpeculationConfig())),
-    ("cp", dict(cp=2)),
-    ("quantized", dict(quantized=True)),
-])
+@pytest.mark.parametrize("feature,kw", fc.REFUSED_FEATURES[1:])
 def test_refused_features_raise_by_name_with_their_reason(feature, kw):
     cfg, _, _, params = _model()
-    reason = cfg.serving_family().unsupported[feature]
-    with pytest.raises(ValueError, match=feature) as e:
-        ServingEngine(cfg, params, _ecfg(**kw))
-    assert reason[:30] in str(e.value)
+    fc.check_refused_features(cfg, params, {feature: kw}, reason=True)
 
 
 def test_prefix_sharing_maps_latent_blocks_of_both_attentions():
@@ -675,7 +623,7 @@ def test_prefix_sharing_maps_latent_blocks_of_both_attentions():
     rng = np.random.RandomState(12)
     common = rng.randint(0, 256, (40,)).tolist()   # two blocks and a half
     prompts = [common + rng.randint(0, 256, (9,)).tolist() for _ in range(2)]
-    eng = ServingEngine(cfg, params, _ecfg(prefix_sharing=True))
+    eng = ServingEngine(cfg, params, fc.engine_config(prefix_sharing=True))
     out = []
     for prompt in prompts:
         uid = eng.submit(prompt, 6)
@@ -684,7 +632,8 @@ def test_prefix_sharing_maps_latent_blocks_of_both_attentions():
         out.append(eng.results[uid].tokens)
     assert eng.stats.prefix_hit_tokens >= 2 * BS
     for prompt, tokens in zip(prompts, out):
-        assert tokens == _greedy_by_reference(params, prompt, tokens)
+        assert tokens == fc.greedy_by_reference(_reference_logits, params,
+                                                prompt, tokens)
 
 
 @pytest.mark.parametrize("key,value", [

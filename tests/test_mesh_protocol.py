@@ -157,10 +157,12 @@ def test_schedule_json_round_trips_and_is_stable():
 # CLI
 # ---------------------------------------------------------------------------
 
+CLI = [sys.executable, "-m", "neuronx_distributed_tpu.analysis"]
+
+
 def _cli(*args):
-    return subprocess.run(
-        [sys.executable, "-m", "neuronx_distributed_tpu.analysis", *args],
-        cwd=REPO, capture_output=True, text=True)
+    return subprocess.run([*CLI, *args], cwd=REPO, capture_output=True,
+                          text=True)
 
 
 def test_cli_mesh_protocol_register_fixture_fails():
@@ -174,12 +176,15 @@ def test_cli_mesh_protocol_register_fixture_fails():
 
 def test_cli_emit_schedule_writes_stable_json(tmp_path):
     out1, out2 = str(tmp_path / "s1.json"), str(tmp_path / "s2.json")
-    r1 = _cli("--mesh-protocol", "--register", GOOD,
-              "--emit-schedule", out1)
-    r2 = _cli("--mesh-protocol", "--register", GOOD,
-              "--emit-schedule", out2)
-    assert r1.returncode == 0, r1.stdout + r1.stderr
-    assert r2.returncode == 0, r2.stdout + r2.stderr
+    # two interpreters of their own, side by side: what is held is that
+    # two runs write the same bytes, not that one waits for the other
+    runs = [subprocess.Popen(
+        [*CLI, "--mesh-protocol", "--register", GOOD, "--emit-schedule", out],
+        cwd=REPO, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+        for out in (out1, out2)]
+    for run in runs:
+        stdout, stderr = run.communicate()
+        assert run.returncode == 0, stdout + stderr
     with open(out1) as f1, open(out2) as f2:
         b1, b2 = f1.read(), f2.read()
     assert b1 == b2

@@ -26,9 +26,7 @@ import jax
 import jax.numpy as jnp
 from flax.core import meta
 
-from neuronx_distributed_tpu.inference import paging
-from neuronx_distributed_tpu.inference.engine import (EngineConfig,
-                                                      ServingEngine)
+import family_checks as fc
 from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
 from neuronx_distributed_tpu.models import mimo_v2
 from neuronx_distributed_tpu.modules.moe import MoE
@@ -72,6 +70,8 @@ PUBLISHED = dict(
 
 #: the seeded weights and the comparison's case, made once
 _CASE = {}
+#: the paged driver's pools: blocks of 4 under two sequences of 33
+POOL = dict(num_blocks=40, max_blocks_per_seq=16)
 
 
 def _family():
@@ -82,10 +82,10 @@ def _reference():
     return harness.load_plugin("reference", "mimo_v2_flash_f32")
 
 
+@fc.once_a_module
 def _model(**kw):
     """The family's config from the published keys, its module and seeded
     weights."""
-    ps.initialize_model_parallel()
     cfg, model, _ = _family().build(
         PUBLISHED, **{"dtype": jnp.float32, "param_dtype": jnp.float32,
                       **kw})
@@ -94,19 +94,14 @@ def _model(**kw):
     init = meta.unbox(jax.eval_shape(model.init, jax.random.key(3),
                                      jnp.zeros((1, 8), jnp.int32)))
 
-    def draw(path, x):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(jax.random.key(5),
-                                 sum(map(ord, name)) % 2 ** 31)
+    def special(name, noise, x, key):
         if name.endswith("['sink']"):
             return 3.0 * jax.random.uniform(key, x.shape, x.dtype)
-        noise = jax.random.normal(key, x.shape, x.dtype)
-        if name.endswith("['scale']"):
-            return 1.0 + 0.3 * noise
         # a router of order one, so that the choices are not all ties
-        return (1.0 if "router" in name else 0.08) * noise
+        if "router" in name and not name.endswith("['scale']"):
+            return 1.0 * noise
 
-    _CASE["params"] = jax.tree_util.tree_map_with_path(draw, init)
+    _CASE["params"] = fc.seeded_weights(init, special)
     return cfg, model, _CASE["params"]
 
 
@@ -311,59 +306,6 @@ def test_full_forward_matches_the_reference_and_a_wide_window_does_not():
         > 20 * SOUND
 
 
-def _init_cache(cfg, num_blocks=40, rows=3, columns=16):
-    return paging.init_serving_cache(
-        cfg, num_blocks=num_blocks, block_size=BS, table_rows=rows,
-        max_blocks_per_seq=columns, dtype=jnp.float32)
-
-
-def _paged_logits(cfg, params, seqs, steps, width=BS):
-    """Sequences ``seqs [n, S]`` through the paged forward by ``steps``,
-    each a list of rows ``(sequence, position)`` (sequence ``s`` in slot
-    ``s``), padded to ``width``; full-pool blocks are mapped in order as
-    the engine maps them. ``{(s, p): logits}``."""
-    cache = _init_cache(cfg)
-    table = np.array(cache.block_tables)
-    mapped = 0
-    forward = cfg.serving_family().forward
-    step = jax.jit(lambda p, c, t, pos, s: forward(cfg, p, t, pos, c,
-                                                   slot_ids=s))
-    out = {}
-    for rows in steps:
-        tok = np.zeros((1, width), np.int32)
-        pos = np.full((1, width), PAD_POSITION, np.int32)
-        ids = np.full((width,), table.shape[0], np.int32)
-        for i, (s, p) in enumerate(rows):
-            tok[0, i], pos[0, i], ids[i] = seqs[s][p], p, s
-            if table[s, p // BS] < 0:
-                table[s, p // BS], mapped = mapped, mapped + 1
-        cache = cache.replace(block_tables=jnp.asarray(table))
-        with jax.default_matmul_precision("highest"):
-            logits, cache = step(params, cache, *map(jnp.asarray,
-                                                     (tok, pos, ids)))
-        for i, row in enumerate(rows):
-            out[row] = np.asarray(logits[0, i])
-    return out, cache
-
-
-def _schedule(length, chunks):
-    """Sequence 0 prefills in ``chunks`` and then decodes a row a step to
-    ``length``; sequence 1 prefills beside its decode rows, in chunks of
-    what the step has left, unaligned to the blocks."""
-    steps, done = [], [0, 0]
-    for n in chunks:
-        steps.append([(0, done[0] + i) for i in range(n)])
-        done[0] += n
-    while min(done) < length:
-        rows = [(0, done[0])] if done[0] < length else []
-        done[0] += len(rows)
-        n = min(BS - len(rows) - len(steps) % 2, length - done[1])
-        rows += [(1, done[1] + i) for i in range(n)]
-        done[1] += n
-        steps.append(rows)
-    return steps
-
-
 @pytest.mark.parametrize("impl,length", [("xla", LENGTH),
                                          ("pallas-interpret", 17)])
 def test_paged_prefill_then_decode_matches_the_reference(impl, length):
@@ -374,8 +316,8 @@ def test_paged_prefill_then_decode_matches_the_reference(impl, length):
     cfg, _, params = _model(
         attn_force_pallas=True if impl == "pallas-interpret" else None)
     seqs, want = _case()
-    got, cache = _paged_logits(cfg, params, seqs,
-                               _schedule(length, [3, 4, 2, 1, 4, 4][:3]))
+    got, cache = fc.paged_logits(
+        cfg, params, seqs, fc.schedule(length, [3, 4, 2], BS), BS, **POOL)
     assert len(got) == 2 * length
     for (s, p), logits in got.items():
         np.testing.assert_allclose(logits, want[s, p],
@@ -402,12 +344,12 @@ def test_what_the_comparison_must_not_pass(monkeypatch):
     case): each reads over twenty times what a sound run may."""
     cfg, _, params = _model()
     seqs, want = _case()
-    steps = _schedule(30, [4] * 7 + [2])[:8]
+    steps = fc.schedule(30, [4] * 7 + [2], BS)[:8]
 
-    def worst(cfg):
-        got, _ = _paged_logits(cfg, params, seqs[:1], steps)
-        return max(np.abs(v - want[0, p]).max() for (_, p), v in got.items()
-                   ) / np.std(want)
+    def worst(cfg, **kw):
+        got, _ = fc.paged_logits(cfg, params, seqs[:1], steps, BS, **POOL,
+                                 **kw)
+        return fc.worst_at(got, want)
 
     assert worst(cfg) < SOUND
     assert worst(dataclasses.replace(cfg, swa_sink=False)) > 0.05
@@ -419,7 +361,7 @@ def test_what_the_comparison_must_not_pass(monkeypatch):
         routing.RouterBase, "logits",
         lambda self, x: logits(self, x).astype(jnp.bfloat16).astype(
             jnp.float32))
-    assert worst(cfg) > 20 * SOUND
+    assert worst(cfg, fresh=True) > 20 * SOUND
 
 
 # -- (d) the shares add up to the uncut layer --------------------------------
@@ -495,22 +437,8 @@ def test_engine_greedy_tokens_equal_the_reference():
     then decode: the longer passes the window and wraps its ring; the
     tokens are the reference's greedy choices."""
     cfg, _, params = _model()
-    eng = ServingEngine(cfg, params, EngineConfig(
-        block_size=BS, num_blocks=40, max_slots=2, max_blocks_per_seq=16,
-        token_budget=BS, kv_dtype=jnp.float32))
-    rng = np.random.RandomState(11)
-    prompts = {"a": rng.randint(0, 256, (LENGTH - 8,)).tolist(),
-               "b": rng.randint(0, 256, (LENGTH - 4,)).tolist()}
-    new = {"a": 8, "b": 4}
-    for uid, prompt in prompts.items():
-        eng.submit(prompt, new[uid], uid=uid)
-    while eng.has_work():
-        eng.step()
-    ps.destroy_model_parallel()
-    for uid, prompt in prompts.items():
-        assert eng.results[uid].status == "completed"
-        tokens = eng.results[uid].tokens
-        assert len(tokens) == new[uid]
-        logits = _reference_logits(params, np.asarray(prompt + tokens)[None])
-        assert tokens == [int(np.argmax(logits[0, len(prompt) - 1 + i]))
-                          for i in range(len(tokens))], uid
+    served = fc.serve_three(
+        cfg, params, (), lengths=[LENGTH - 8, LENGTH - 4], new=[8, 4],
+        block_size=BS, token_budget=BS, max_slots=2, max_blocks_per_seq=16)
+    fc.check_engine_greedy_tokens_equal_the_reference(served,
+                                                      _reference_logits)

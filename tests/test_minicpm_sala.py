@@ -20,16 +20,12 @@ import jax
 import jax.numpy as jnp
 from flax.core import meta
 
-from neuronx_distributed_tpu import obs
 from neuronx_distributed_tpu.inference import paging
-from neuronx_distributed_tpu.inference.engine import (EngineConfig,
-                                                      ServingEngine)
+from neuronx_distributed_tpu.inference.engine import ServingEngine
 from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
-from neuronx_distributed_tpu.inference.speculative import SpeculationConfig
 from neuronx_distributed_tpu.ops import lightning_attention as la
 from neuronx_distributed_tpu.ops import paged_attention as pa
 from neuronx_distributed_tpu.ops import sparse_attention as sp
-from neuronx_distributed_tpu.parallel import mesh as ps
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.join(os.path.dirname(HERE), "benchmarks")
@@ -37,7 +33,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402  (benchmarks/)
-from counter_checks import check_registered_counters  # noqa: E402  (tests/)
+import family_checks as fc  # noqa: E402  (tests/)
 from runners import serve  # noqa: E402
 from walk_checks import (check_score_walk, check_sparse_walk,  # noqa: E402
                          scored_pairs)
@@ -60,25 +56,14 @@ PUBLISHED = dict(
     family="minicpm_sala", reference="minicpm_sala_f32")
 
 
+@fc.once_a_module
 def _model(**kw):
-    ps.initialize_model_parallel()
     family = harness.load_plugin("families", "minicpm_sala")
     cfg, model, forward = family.build(
         PUBLISHED, dtype=jnp.float32, param_dtype=jnp.float32, **kw)
-    shapes = meta.unbox(model.init(jax.random.key(0),
-                                   jnp.zeros((1, 8), jnp.int32)))
-
-    def draw(path, x):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(jax.random.key(5),
-                                 sum(map(ord, name)) % 2 ** 31)
-        noise = jax.random.normal(key, x.shape, x.dtype)
-        if name.endswith("['scale']"):
-            return 1.0 + 0.3 * noise
-        return 0.08 * noise
-
-    return cfg, model, forward, jax.tree_util.tree_map_with_path(draw,
-                                                                 shapes)
+    shapes = meta.unbox(jax.eval_shape(model.init, jax.random.key(0),
+                                       jnp.zeros((1, 8), jnp.int32)))
+    return cfg, model, forward, fc.seeded_weights(shapes)
 
 
 def _reference(params):
@@ -87,19 +72,9 @@ def _reference(params):
                 params, PUBLISHED))
 
 
-def _ecfg(**kw):
-    base = dict(block_size=BS, num_blocks=40, max_slots=3,
-                max_blocks_per_seq=12, token_budget=16,
-                kv_dtype=jnp.float32)
-    base.update(kw)
-    return EngineConfig(**base)
-
-
-def _greedy_by_reference(params, prompt, tokens):
+def _reference_logits(params, tokens):
     ref, weights = _reference(params)
-    logits, _ = ref.forward(weights, np.asarray([prompt + tokens]),
-                            PUBLISHED)
-    return np.argmax(np.asarray(logits)[0, len(prompt) - 1:-1], -1).tolist()
+    return ref.forward(weights, np.asarray(tokens), PUBLISHED)[0]
 
 
 # -- (a) the module's full forward ------------------------------------------
@@ -150,7 +125,8 @@ def test_paged_forward_matches_the_reference_across_the_threshold(impl):
     assert any(len(rows) < 16 for rows in schedule)          # pad rows
     assert any({s for s, _ in rows} == {0, 1} for rows in schedule)
     with jax.default_matmul_precision("highest"):
-        seqs, got = serve.probe_logits(7, cfg, forward, params, _ecfg(), chk)
+        seqs, got = serve.probe_logits(7, cfg, forward, params,
+                                       fc.engine_config(), chk)
     ref, weights = _reference(params)
     want = np.asarray(ref.forward(weights, seqs, PUBLISHED)[0])
     assert got.shape == want.shape == (2, 110, 256)
@@ -657,67 +633,45 @@ def served():
     """Three requests that cross the dense threshold, one of them preempted
     on the way, through one engine."""
     cfg, _, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg(num_blocks=13, max_slots=2))
-    rng = np.random.RandomState(11)
-    prompts = {"a": rng.randint(0, 256, (100,)).tolist(),
-               "b": rng.randint(0, 256, (70,)).tolist(),
-               "c": rng.randint(0, 256, (5,)).tolist()}
-    new = {"a": 40, "b": 12, "c": 4}
-    obs.enable()
-    obs.get_registry().reset()
-    for uid, prompt in prompts.items():
-        eng.submit(prompt, new[uid], uid=uid)
-    steps, dispatch = [], eng._dispatch
+    steps = []
 
-    def recording(fn, rows, width, *rest):
-        # every step's rows as the device sees them: table and position
-        steps.append(([eng._tables[r[0].slot].copy() for r in rows]
-                      + [np.full(12, -1)] * (width - len(rows)),
-                      [r[2] for r in rows]
-                      + [PAD_POSITION] * (width - len(rows))))
-        return dispatch(fn, rows, width, *rest)
+    def record(eng):
+        dispatch = eng._dispatch
 
-    eng._dispatch = recording
-    while eng.has_work():
-        eng.step()
-    counters = {
-        name: {c.labels.get("kind", ""): c.value
-               for c in obs.get_registry().get(name).children()}
-        for name in ("nxd_sparse_columns_total",
-                     "nxd_sparse_positions_total",
-                     "nxd_sparse_block_visits_total",
-                     "nxd_sparse_key_visits_total",
-                     "nxd_state_resets_total", "nxd_engine_rows_total")}
-    counters["steps"] = steps
-    check_registered_counters(obs.get_registry(), cfg.serving_family())
-    obs.disable()
-    ps.destroy_model_parallel()
-    return cfg, params, eng, prompts, new, counters
+        def recording(fn, rows, width, *rest):
+            # every step's rows as the device sees them: table, position
+            steps.append(([eng._tables[r[0].slot].copy() for r in rows]
+                          + [np.full(12, -1)] * (width - len(rows)),
+                          [r[2] for r in rows]
+                          + [PAD_POSITION] * (width - len(rows))))
+            return dispatch(fn, rows, width, *rest)
+
+        eng._dispatch = recording
+
+    served = fc.serve_three(cfg, params, (
+        "nxd_sparse_columns_total", "nxd_sparse_positions_total",
+        "nxd_sparse_block_visits_total", "nxd_sparse_key_visits_total",
+        "nxd_state_resets_total", "nxd_engine_rows_total"),
+        lengths=[100, 70, 5], new=[40, 12, 4], before=record,
+        num_blocks=13, max_slots=2)
+    return served._replace(seen=steps)
 
 
 def test_engine_greedy_tokens_equal_the_reference(served):
-    cfg, params, eng, prompts, new, _ = served
-    for uid, prompt in prompts.items():
-        assert eng.results[uid].status == "completed"
-        tokens = eng.results[uid].tokens
-        assert len(tokens) == new[uid]
-        assert tokens == _greedy_by_reference(params, prompt, tokens), uid
+    fc.check_engine_greedy_tokens_equal_the_reference(served,
+                                                      _reference_logits)
 
 
 def test_a_preempted_request_decodes_as_a_fresh_one(served):
     """13 blocks do not hold a and b: b is preempted, re-admitted into a
     slot whose lightning states another request left, and still decodes
     what the reference does (above); the pool is whole at the end."""
-    _, _, eng, *_ = served
-    assert eng.stats.preempted >= 1
-    assert eng.allocator.num_allocated == 0
-    assert (eng._tables == -1).all()
-    assert eng.compile_count() == 1
-    assert float(jnp.abs(eng.cache.state).max()) > 0    # never cleared
+    fc.check_preempted_and_whole(served.eng)
+    assert float(jnp.abs(served.eng.cache.state).max()) > 0  # never cleared
 
 
 def test_sparse_and_state_counters(served):
-    *_, counters = served
+    counters = served.counters
     cols = counters["nxd_sparse_columns_total"]
     assert set(cols) == {"selected", "forced", "dense", "skipped"}
     assert all(v > 0 for v in cols.values())
@@ -738,7 +692,7 @@ def test_sparse_and_state_counters(served):
     # tables and positions: one tile of 16 rows, three sparse layers
     keys = counters["nxd_sparse_key_visits_total"]
     want = [0, 0]
-    for tables, positions in counters["steps"]:
+    for tables, positions in served.seen:
         for pairs in scored_pairs(np.stack(tables), positions, SPEC, BS, 16):
             want[0] += 3 * len(pairs)
             want[1] += 3 * (sum(map(len, pairs.values())) - len(pairs))
@@ -748,34 +702,25 @@ def test_sparse_and_state_counters(served):
     assert counters["nxd_state_resets_total"][""] >= 4
 
 
-@pytest.mark.parametrize("feature,kw", [
-    ("prefix_sharing", dict(prefix_sharing=True)),
-    ("speculation", dict(speculation=SpeculationConfig())),
-    ("cp", dict(cp=2)),
-    ("quantized", dict(quantized=True)),
-])
+@pytest.mark.parametrize("feature,kw", fc.REFUSED_FEATURES)
 def test_refused_features_raise_by_name(feature, kw):
     cfg, _, _, params = _model()
-    with pytest.raises(ValueError, match=feature):
-        ServingEngine(cfg, params, _ecfg(**kw))
+    fc.check_refused_features(cfg, params, {feature: kw})
 
 
 def test_session_export_is_refused_and_the_cache_is_the_kinds():
     cfg, _, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg())
-    uid = eng.submit([1, 2, 3], 4)
-    eng.step()
-    with pytest.raises(ValueError, match="session_export"):
-        eng.export_session(uid)
-    cache = eng.cache
-    assert isinstance(cache, paging.SparseStatePagedCache)
+    cache = fc.check_session_export_is_refused(
+        cfg, params, paging.SparseStatePagedCache,
+        paging.SparseStateCache).cache
     assert cache.k.shape == (3, 40, 2, BS, 16) == cache.v.shape
     assert cache.ck.shape == (3, 40 * 8, 2 * 16)
     assert cache.state.shape == (3, 4, 3, 16, 16)
     assert cache.state.dtype == jnp.float32
     assert cache.capacity == 40 * BS and cache.max_slots == 3
     with pytest.raises(ValueError, match="whole selection blocks"):
-        ServingEngine(cfg, params, _ecfg(block_size=4, token_budget=4))
+        ServingEngine(cfg, params,
+                      fc.engine_config(block_size=4, token_budget=4))
 
 
 # -- (f) the constructor and the two old kinds --------------------------------

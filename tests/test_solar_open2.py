@@ -25,13 +25,9 @@ import jax
 import jax.numpy as jnp
 from flax.core import meta
 
-from counter_checks import check_registered_counters
-from neuronx_distributed_tpu import obs
+import family_checks as fc
 from neuronx_distributed_tpu.inference import paging
-from neuronx_distributed_tpu.inference.engine import (EngineConfig,
-                                                      ServingEngine)
 from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
-from neuronx_distributed_tpu.inference.speculative import SpeculationConfig
 from neuronx_distributed_tpu.models import solar_open2 as so
 from neuronx_distributed_tpu.modules.moe import MoE
 from neuronx_distributed_tpu.ops import kda, ssd
@@ -67,11 +63,11 @@ def _reference():
     return harness.load_plugin("reference", "solar_open2_f32")
 
 
+@fc.once_a_module
 def _model(**kw):
     """The family's config from the published keys, its module and seeded
     weights: what ``make_weights`` would draw for the decay's leaves (the
     family reads them as KDA's), order one elsewhere."""
-    ps.initialize_model_parallel()
     cfg, model, _ = _family().build(
         PUBLISHED, **{"dtype": jnp.float32, "param_dtype": jnp.float32,
                       **kw})
@@ -80,20 +76,15 @@ def _model(**kw):
     init = meta.unbox(jax.eval_shape(model.init, jax.random.key(3),
                                      jnp.zeros((1, 8), jnp.int32)))
 
-    def draw(path, x):
-        name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(jax.random.key(5),
-                                 sum(map(ord, name)) % 2 ** 31)
-        noise = jax.random.normal(key, x.shape, x.dtype)
-        if name.endswith("['scale']"):
-            return 1.0 + 0.3 * noise
+    def special(name, noise, x, key):
         if name.endswith(("['A_log']", "['dt_bias']")):
             return 0.02 * noise
         # a router and a beta of order one; a convolution whose taps differ
-        return (1.0 if "router" in name or "conv" in name
-                else 0.08) * noise
+        if not name.endswith("['scale']") and (
+                "router" in name or "conv" in name):
+            return 1.0 * noise
 
-    _CASE["params"] = jax.tree_util.tree_map_with_path(draw, init)
+    _CASE["params"] = fc.seeded_weights(init, special)
     return cfg, model, _CASE["params"]
 
 
@@ -363,10 +354,6 @@ def test_every_published_key_is_read_or_refused():
             PUBLISHED["linear_attn_config"], num_kv_heads=1)))
 
 
-def _worst(got, want):
-    return float(np.abs(got - want).max() / np.std(want))
-
-
 def test_full_forward_matches_the_reference():
     cfg, model, params = _model()
     tokens, want = _case()
@@ -374,57 +361,7 @@ def test_full_forward_matches_the_reference():
     served = _family().with_kda_init(params, 0.02)
     with jax.default_matmul_precision("highest"):
         got = np.asarray(jax.jit(model.apply)(served, jnp.asarray(tokens)))
-    assert _worst(got, want) < SOUND
-
-
-def _paged_logits(cfg, params, seqs, steps, width=BS, cache=None):
-    """Sequences ``seqs [n, S]`` through the family's paged forward by
-    ``steps``, each a list of rows ``(sequence, position)`` (sequence
-    ``s`` in slot ``s``), padded to ``width``; blocks are mapped in order
-    as the engine maps them. ``({(s, p): logits}, cache)``."""
-    if cache is None:
-        cache = paging.init_serving_cache(
-            cfg, num_blocks=24, block_size=BS, table_rows=3,
-            max_blocks_per_seq=8, dtype=jnp.float32)
-    table = np.array(cache.block_tables)
-    mapped = int((table >= 0).sum())
-    forward = cfg.serving_family().forward
-    step = jax.jit(lambda p, c, t, pos, s: forward(cfg, p, t, pos, c,
-                                                   slot_ids=s))
-    out = {}
-    for rows in steps:
-        tok = np.zeros((1, width), np.int32)
-        pos = np.full((1, width), PAD_POSITION, np.int32)
-        ids = np.full((width,), table.shape[0], np.int32)
-        for i, (s, p) in enumerate(rows):
-            tok[0, i], pos[0, i], ids[i] = seqs[s][p], p, s
-            if table[s, p // BS] < 0:
-                table[s, p // BS], mapped = mapped, mapped + 1
-        cache = cache.replace(block_tables=jnp.asarray(table))
-        with jax.default_matmul_precision("highest"):
-            logits, cache = step(params, cache, *map(jnp.asarray,
-                                                     (tok, pos, ids)))
-        for i, row in enumerate(rows):
-            out[row] = np.asarray(logits[0, i])
-    return out, cache
-
-
-def _schedule(length, chunks):
-    """Sequence 0 prefills in ``chunks`` and then decodes a row a step to
-    ``length``; sequence 1 prefills beside its decode rows, in chunks of
-    what the step has left, unaligned to the blocks."""
-    steps, done = [], [0, 0]
-    for n in chunks:
-        steps.append([(0, done[0] + i) for i in range(n)])
-        done[0] += n
-    while min(done) < length:
-        rows = [(0, done[0])] if done[0] < length else []
-        done[0] += len(rows)
-        n = min(BS - len(rows) - len(steps) % 2, length - done[1])
-        rows += [(1, done[1] + i) for i in range(n)]
-        done[1] += n
-        steps.append(rows)
-    return steps
+    assert fc.worst(got, want) < SOUND
 
 
 @pytest.mark.parametrize("impl,length", [("xla", LENGTH),
@@ -436,8 +373,8 @@ def test_paged_prefill_then_decode_matches_the_reference(impl, length):
     cfg, _, params = _model(
         attn_force_pallas=True if impl == "pallas-interpret" else None)
     seqs, want = _case()
-    got, cache = _paged_logits(cfg, params, seqs,
-                               _schedule(length, [3, 8, 2, 1, 5]))
+    steps = fc.schedule(length, [3, 8, 2, 1, 5], BS)
+    got, cache = fc.paged_logits(cfg, params, seqs, steps, BS)
     assert len(got) == 2 * length
     for (s, p), logits in got.items():
         np.testing.assert_allclose(logits, want[s, p],
@@ -448,8 +385,8 @@ def test_paged_prefill_then_decode_matches_the_reference(impl, length):
     assert cache.states["conv"].shape == (5, 3, 3, 768)  # [L, W-1, J, C]
     # [kept, dropped, elsewhere] of the last step's rows, 7 layers x top 3
     counts = np.asarray(cache.moe_counts)
-    last = len(_schedule(length, [3, 8, 2, 1, 5])[-1])
-    assert counts.sum() == last * 7 * 3 and counts[1] == 0 < counts[2]
+    assert counts.sum() == len(steps[-1]) * 7 * 3
+    assert counts[1] == 0 < counts[2]
 
 
 FAULTS = {
@@ -471,7 +408,7 @@ def test_what_the_comparison_must_not_pass(fault, monkeypatch):
     fault put in reads over its stated multiple of it."""
     cfg, _, params = _model()
     seqs, want = _case()
-    steps = _schedule(30, [4, 5, 3, 4, 4])[:12]
+    steps = fc.schedule(30, [4, 5, 3, 4, 4], BS)[:12]
     packed, conv = kda.kda_packed, ssd.causal_conv_step
 
     def stale(q, k, v, g, beta, state, layer, seg, **kw):
@@ -495,17 +432,16 @@ def test_what_the_comparison_must_not_pass(fault, monkeypatch):
             *packed(*a, **kw))),
     }
 
-    def worst(cfg):
-        got, _ = _paged_logits(cfg, params, seqs, steps)
-        return max(np.abs(v - want[s, p]).max() for (s, p), v in got.items()
-                   ) / np.std(want)
+    def worst(cfg, **kw):
+        got, _ = fc.paged_logits(cfg, params, seqs, steps, BS, **kw)
+        return fc.worst_at(got, want)
 
     assert worst(cfg) < SOUND
     if fault == "the shared expert left out":
         cfg = dataclasses.replace(cfg, shared_expert_intermediate_size=0)
     else:
         monkeypatch.setattr(*patches[fault])
-    read = worst(cfg)
+    read = worst(cfg, fresh=True)
     print(fault, "reads", read)
     assert read > FAULTS[fault] * SOUND
 
@@ -556,7 +492,8 @@ def test_the_family_reads_normal_draws_as_kdas_initialisation():
                                          np.float32)[:, 256:].T)
     # the seeded forward and the reference read the same parameters
     seqs = np.random.RandomState(4).randint(0, 256, (2, 21))
-    got, _ = _paged_logits(cfg, params, seqs, _schedule(21, [8, 5])[:9])
+    got, _ = fc.paged_logits(cfg, params, seqs,
+                             fc.schedule(21, [8, 5], BS)[:9], BS)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(_reference().forward(weights, seqs, PUBLISHED)[0])
     for (s, p), logits in got.items():
@@ -657,17 +594,8 @@ def test_sixteen_shares_routed_sums_and_one_shared_expert_are_the_layer():
 
 # -- (d) through ServingEngine -------------------------------------------------
 
-def _ecfg(**kw):
-    base = dict(block_size=BS, num_blocks=24, max_slots=3,
-                max_blocks_per_seq=12, token_budget=BS,
-                kv_dtype=jnp.float32)
-    base.update(kw)
-    return EngineConfig(**base)
-
-
-def _greedy_by_reference(params, prompt, tokens):
-    logits = _reference_logits(params, [prompt + tokens])
-    return np.argmax(logits[0, len(prompt) - 1:-1], -1).tolist()
+#: over ``family_checks.engine_config``: blocks and steps of 8 rows
+ENGINE = dict(block_size=BS, num_blocks=24, token_budget=BS)
 
 
 @pytest.fixture(scope="module")
@@ -675,44 +603,23 @@ def served():
     """Three requests through one engine of two slots whose pool holds
     nine blocks: the youngest is preempted on the way."""
     cfg, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg(num_blocks=9, max_slots=2))
-    rng = np.random.RandomState(11)
-    prompts = {"a": rng.randint(0, 256, (30,)).tolist(),
-               "b": rng.randint(0, 256, (22,)).tolist(),
-               "c": rng.randint(0, 256, (5,)).tolist()}
-    new = {"a": 20, "b": 10, "c": 4}
-    obs.enable()
-    obs.get_registry().reset()
-    for uid, prompt in prompts.items():
-        eng.submit(prompt, new[uid], uid=uid)
-    while eng.has_work():
-        eng.step()
-    counters = {
-        name: {c.labels.get("kind", ""): c.value
-               for c in obs.get_registry().get(name).children()}
-        for name in ("nxd_state_bytes_held_total",
-                     "nxd_state_slot_steps_total", "nxd_moe_held_total",
-                     "nxd_moe_assignments_total")}
-    check_registered_counters(obs.get_registry(), cfg.serving_family())
-    obs.disable()
-    ps.destroy_model_parallel()
-    return cfg, params, eng, prompts, new, counters
+    return fc.serve_three(cfg, params, (
+        "nxd_state_bytes_held_total", "nxd_state_slot_steps_total",
+        "nxd_moe_held_total", "nxd_moe_assignments_total"),
+        lengths=[30, 22, 5], new=[20, 10, 4], **dict(ENGINE, num_blocks=9,
+                                                     max_slots=2))
 
 
 def test_engine_greedy_tokens_equal_the_reference(served):
-    cfg, params, eng, prompts, new, _ = served
-    for uid, prompt in prompts.items():
-        assert eng.results[uid].status == "completed"
-        tokens = eng.results[uid].tokens
-        assert len(tokens) == new[uid]
-        assert tokens == _greedy_by_reference(params, prompt, tokens), uid
+    fc.check_engine_greedy_tokens_equal_the_reference(served,
+                                                      _reference_logits)
 
 
 def test_a_preempted_request_decodes_as_a_fresh_one(served):
     """Nine blocks do not hold a and b: b is preempted and re-admitted
     into a slot whose state and tail another request left, and still
     decodes what the reference does (above)."""
-    _, _, eng, *_ = served
+    eng = served.eng
     assert eng.stats.preempted >= 1
     assert eng.allocator.num_allocated == 0
     assert eng.compile_count() == 1
@@ -720,7 +627,7 @@ def test_a_preempted_request_decodes_as_a_fresh_one(served):
 
 
 def test_the_held_bytes_and_the_routed_assignments_are_counted(served):
-    cfg, *_, counters = served
+    counters = served.counters
     held = counters["nxd_state_bytes_held_total"]
     slots = sum(counters["nxd_state_slot_steps_total"].values())
     # an occupied slot a step: five layers' float32 states of [2, 128,
@@ -735,29 +642,20 @@ def test_the_held_bytes_and_the_routed_assignments_are_counted(served):
     assert (counters["nxd_moe_assignments_total"]["kept"] == moe["held"])
 
 
-@pytest.mark.parametrize("feature,kw", [
-    ("prefix_sharing", dict(prefix_sharing=True)),
-    ("speculation", dict(speculation=SpeculationConfig())),
-    ("cp", dict(cp=2)),
-    ("quantized", dict(quantized=True)),
-])
+@pytest.mark.parametrize("feature,kw", fc.REFUSED_FEATURES)
 def test_refused_features_raise_by_name(feature, kw):
     cfg, _, params = _model()
-    with pytest.raises(ValueError, match=feature):
-        ServingEngine(cfg, params, _ecfg(**kw))
+    fc.check_refused_features(cfg, params, {feature: kw}, **ENGINE)
 
 
 def test_session_export_is_refused_and_the_cache_is_the_kinds():
     cfg, _, params = _model()
-    eng = ServingEngine(cfg, params, _ecfg())
-    uid = eng.submit([1, 2, 3], 4)
-    eng.step()
-    with pytest.raises(ValueError, match="session_export"):
-        eng.export_session(uid)
+    eng = fc.check_session_export_is_refused(
+        cfg, params, paging.StatePoolPagedCache, paging.StatePoolCache,
+        **ENGINE)
     cache, family = eng.cache, cfg.serving_family()
     kind = family.cache_kind
-    assert isinstance(cache, paging.StatePoolPagedCache)
-    assert isinstance(kind, paging.StatePoolCache) and kind.pack == 1
+    assert kind.pack == 1
     assert [(leaf.name, leaf.counted_as) for leaf in kind.leaves] == [
         ("kda", "state"), ("conv", "tail")]
     assert family.moe_counts and cache.moe_counts.shape == (3,)

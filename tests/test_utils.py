@@ -66,11 +66,14 @@ def test_tensor_capture_and_replacement():
         tc.apply_with_replacements(model, params, {"params/nope": ids}, ids)
 
 
-def test_checkpoint_converter_cli_families(tmp_path):
+def test_checkpoint_converter_cli_families(tmp_path, monkeypatch):
     """The converter CLI accepts every family (reference ships one
     CheckpointConverterBase subclass per family); smoke vit end to end."""
     import pickle
 
+    # the test's time is its imports: torch's ViT needs no TensorFlow,
+    # which transformers would import (with keras) because it is installed
+    monkeypatch.setenv("USE_TF", "0")
     import torch
     import transformers
 
