@@ -3,15 +3,13 @@
 Analogue of the reference's NKI flash attention wrapper
 (``kernels/flash_attn.py:162`` → ``nki.kernels.attention.flash_fwd/bwd``).
 
-Current implementation: blockwise online-softmax attention expressed with
-``lax.scan`` over KV blocks — O(S) memory instead of O(S²), fp32 accumulation,
-differentiable through JAX autodiff (the scan's VJP recomputes per-block,
-which is exactly the flash-backward memory profile). XLA fuses each block's
-QK^T → rescale → PV chain onto the MXU.
-
-A hand-tiled Pallas (Mosaic) kernel can be slotted in behind the same
-signature; this scan formulation is the golden reference for it (the
-reference keeps torch fallbacks for its NKI kernels the same way).
+Two implementations behind one signature (``flash_attention``). The XLA
+one is blockwise online-softmax attention expressed with ``lax.scan`` over
+KV blocks — O(S) memory instead of O(S²), fp32 accumulation, its backward a
+second scan from the saved log-sum-exp. The hand-tiled Pallas (Mosaic)
+kernels — one forward, two backward — take the call on the TPU when the
+shapes tile; the scan formulation is their golden reference and fallback
+(the reference keeps torch fallbacks for its NKI kernels the same way).
 """
 
 from __future__ import annotations
@@ -209,13 +207,29 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Pallas (Mosaic) TPU kernel — the hand-tiled fast path. Grid is
+# Pallas (Mosaic) TPU kernels — the hand-tiled fast path: the forward here,
+# the two backward kernels (dq; dk and dv) further down, all three from the
+# same (seed, head, q, k) dropout mask. The forward's grid is
 # (batch*heads, q_blocks, k_blocks) with the KV dim innermost (sequential on
 # TPU): K/V stream through VMEM one (block_k, d) tile at a time while
 # m/l/acc accumulate in VMEM scratch — constant VMEM regardless of sequence
-# length. Forward only; the backward is the VJP of the scan formulation
-# above (same recompute profile as a flash backward, one golden
-# implementation to maintain).
+# length. It returns the output and each row's log-sum-exp; the backward
+# kernels recompute p = exp(s - lse) from those, and the XLA scan above
+# stays as the fallback and the golden reference of both directions.
+#
+# Layout of the statistics. A score tile is [block_q, block_k] with a query
+# row along a sublane, and everything the online softmax does with a row's
+# maximum m and sum l is a row-wise operand against that tile or against
+# the [block_q, d] accumulator. So m and l live as [block_q, 128] scratch,
+# a row's value repeated along its lanes: the lane reductions (max, sum)
+# leave their result there, the running maximum, the correction and the
+# sum are whole-vreg elementwise operations, and the operand against the
+# tile is the same vregs side by side (``_along_lanes``). Kept as
+# one-dimensional [block_q] (values along the lanes, as the lse output
+# wants them) every tile moved 512 values from sublanes to lanes to store
+# them and back to use them: 1,168 of the tile's 2,758 bundles were those
+# permutations' and their stores', for 0.68 us of MXU work. The one move
+# left is ``_rows_to_lanes`` at ``_finalize``, once a query block.
 # ---------------------------------------------------------------------------
 
 def _tile_keep_mask(seed_ref, head, qi, kb, block_q, block_k, sk, dropout_p):
@@ -227,6 +241,53 @@ def _tile_keep_mask(seed_ref, head, qi, kb, block_q, block_k, sk, dropout_p):
     return dropout_keep_mask(seed_ref[0], head, q_pos, k_pos, sk, dropout_p)
 
 
+# A masked score. Finite, so ``exp(s - m)`` is zero on a masked entry by
+# itself and the running maximum is a number from the first tile on: the
+# forward pays no ``isfinite`` select over the score tile. A row whose
+# maximum never rose above it met no live key (``_finalize``).
+_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+_LANES = 128
+
+
+def _along_lanes(stat, width: int):
+    """A row statistic ``[rows, 128]`` (a row's value in every lane) as an
+    operand against ``[rows, width]``: the same vregs side by side where
+    ``width`` is whole lanes, no move across sublanes or lanes."""
+    if width == _LANES:
+        return stat
+    if width % _LANES == 0:
+        return jnp.concatenate([stat] * (width // _LANES), axis=1)
+    # interpret mode's loose blocks
+    return jnp.broadcast_to(stat[:, :1], (stat.shape[0], width))
+
+
+def _mxu_pair(a, b):
+    """Two operands of one product in the narrower's type, bf16 at the
+    least. At default precision the MXU runs a float32 product in one
+    pass over operands it rounds to bf16 (bit for bit what ``astype``
+    gives: chip probe, PR 58), so beside a bf16 operand the other loses
+    nothing by being handed over in bf16, at half the vregs."""
+    t = jnp.promote_types(jnp.bfloat16, min(a.dtype, b.dtype,
+                                            key=lambda t: t.itemsize))
+    return a.astype(t), b.astype(t)
+
+
+def _rows_to_lanes(stat):
+    """``[rows, 128]`` row statistic -> ``[1, rows]``: row ``r`` of a
+    chunk of 128 rows keeps lane ``r`` alone and the chunk's rows fold
+    into one, so the move is a select and maxima over whole vregs, not a
+    permutation a row."""
+    rows = stat.shape[0]
+    if rows % _LANES:
+        return stat[:, 0][None, :]  # interpret mode's loose blocks
+    shape = (_LANES, _LANES)
+    own = (lax.broadcasted_iota(jnp.int32, shape, 0)
+           == lax.broadcasted_iota(jnp.int32, shape, 1))
+    return jnp.concatenate(
+        [jnp.max(jnp.where(own, stat[c:c + _LANES], -jnp.inf), axis=0,
+                 keepdims=True) for c in range(0, rows, _LANES)], axis=1)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref, m_ref,
                       l_ref, acc_ref, *, block_q: int, block_k: int,
                       num_kb: int, causal: bool, scale: float,
@@ -236,61 +297,71 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, seed_ref, o_ref, lse_ref, m_ref,
     head = pl.program_id(0)  # hoisted: program_id has no lowering inside
     qi = pl.program_id(1)    # pl.when bodies in interpret mode
     kb = pl.program_id(2)
+    d = acc_ref.shape[1]
 
     @pl.when(kb == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+        m_ref[:] = jnp.full_like(m_ref, _MASK_VALUE)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # skip blocks strictly above the causal diagonal
-    @pl.when((not causal) or (kb * block_k <= qi * block_q + block_q - 1))
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale       # [BQ, D]
-        k_blk = k_ref[0].astype(jnp.float32)           # [BK, D]
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if causal:
+    def _tile(on_diagonal: bool):
+        # the product takes its operands as they come (bf16 from the
+        # models) and the scale follows it
+        s = jax.lax.dot_general(*_mxu_pair(q_ref[0], k_ref[0]),
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if on_diagonal:
             q_pos = qi * block_q + lax.broadcasted_iota(
                 jnp.int32, s.shape, 0)
             k_pos = kb * block_k + lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        m_prev = m_ref[:]
-        l_prev = l_ref[:]
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - m_safe[:, None]), 0.0)
-        corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
+            s = jnp.where(q_pos >= k_pos, s, _MASK_VALUE)
+        m_prev = m_ref[:]                                   # [BQ, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _along_lanes(m_new, block_k))
+        corr = jnp.exp(m_prev - m_new)
         m_ref[:] = m_new
-        l_ref[:] = l_prev * corr + jnp.sum(p, axis=-1)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
         if dropout_p > 0.0:
             # normaliser l accumulates UNdropped p; only the PV accumulation
             # sees the mask (survivor rescale happens once, in _finalize)
             keep = _tile_keep_mask(seed_ref, head, qi, kb,
                                    block_q, block_k, sk, dropout_p)
-            p_acc = jnp.where(keep, p, 0.0)
-        else:
-            p_acc = p
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
-            p_acc, v_blk, (((1,), (0,)), ((), ())),
+            p = jnp.where(keep, p, 0.0)
+        acc_ref[:] = acc_ref[:] * _along_lanes(corr, d) + jax.lax.dot_general(
+            *_mxu_pair(p, v_ref[0]), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    if causal:
+        # the first and the last key of the tile against the block's
+        # first and last query: wholly above the diagonal a tile is
+        # skipped, wholly under it nothing is masked, and only a tile the
+        # diagonal crosses pays the iotas, the compare and the select
+        crosses = kb * block_k + block_k - 1 > qi * block_q
+        live = kb * block_k <= qi * block_q + block_q - 1
+        pl.when(jnp.logical_not(crosses))(lambda: _tile(False))
+        pl.when(jnp.logical_and(crosses, live))(lambda: _tile(True))
+    else:
+        _tile(False)
 
     @pl.when(kb == num_kb - 1)
     def _finalize():
         inv_keep = 1.0 / (1.0 - dropout_p) if dropout_p > 0.0 else 1.0
-        o_ref[0] = (acc_ref[:] * inv_keep
-                    / jnp.maximum(l_ref[:], 1e-30)[:, None]
-                    ).astype(o_ref.dtype)
-        # log-sum-exp per query row (softmax stats for the flash backward).
+        m = m_ref[:]
+        l = jnp.maximum(l_ref[:], 1e-30)
+        # a row that met no live key (its sums are of masked entries):
+        # zero output and lse = -inf
+        met = m > _MASK_VALUE
+        o_ref[0] = (acc_ref[:] * _along_lanes(
+            jnp.where(met, inv_keep / l, 0.0), d)).astype(o_ref.dtype)
+        lse = jnp.where(met, m + jnp.log(l), -jnp.inf)
+        # log-sum-exp per query row (softmax stats for the flash backward),
+        # the one place the statistics leave the rows for the lanes.
         # lse block is (1, 1, block_q): 3D so the sublane dim (=1) equals the
         # array dim — Mosaic's (8, 128) tiling rule for 2D blocks would
         # reject a (1, block_q) block on a (b*n, sq) array.
-        lse_ref[0, 0] = jnp.where(
-            l_ref[:] > 0, m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30)),
-            -jnp.inf)
+        lse_ref[0] = _rows_to_lanes(lse)
 
 
 def _causal_kv_index(causal, block_q, block_k):
@@ -336,8 +407,8 @@ def _flash_pallas_fwd(q, k, v, seed, causal, block_q, block_k, scale,
         out_specs=[pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),
                    pl.BlockSpec((1, 1, block_q),
                                 lambda i, j, kb: (i, 0, j))],
-        scratch_shapes=[pltpu.VMEM((block_q,), jnp.float32),
-                        pltpu.VMEM((block_q,), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),

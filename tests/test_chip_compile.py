@@ -71,20 +71,45 @@ def _kernel_instruction_names(hlo_text):
         r'"tpu_custom_call"', hlo_text)}
 
 
-# -- flash attention: b=1 s=2048 n=32 d=128 (Llama-2-7B, the smoke's) -------
+# -- flash attention: b=1 s=2048 n=32 d=128 (Llama-2-7B, the smoke's), and
+# mistral-7b.train-tp4's own call: 2 sequences x 8 local heads, S = 4096
 
-def _flash_args(chip):
-    qkv = chip((1, 2048, 32, 128), jnp.bfloat16)
+_FLASH_SHAPES = {"smoke": (1, 2048, 32, 128), "train_tp4": (2, 4096, 8, 128)}
+
+
+def _flash_args(chip, shape="smoke"):
+    qkv = chip(_FLASH_SHAPES[shape], jnp.bfloat16)
     return qkv, qkv, qkv, chip((1,), jnp.uint32)
 
 
-def _flash(dropout_p):
+def _flash(dropout_p, causal=True):
+    """The kernel under the blocks ``flash_attention`` derives for these
+    sequences (its defaults: 512 divides both)."""
     from neuronx_distributed_tpu.ops.flash_attention import _flash_pallas
 
     def fwd(q, k, v, seed):
-        return _flash_pallas(q, k, v, seed, True, 512, 512,
+        return _flash_pallas(q, k, v, seed, causal, 512, 512,
                              1.0 / math.sqrt(128), False, dropout_p)
     return fwd
+
+
+def _flash_loss(causal=True):
+    fwd = _flash(0.0, causal)
+
+    def loss(q, k, v, seed):
+        return jnp.sum(fwd(q, k, v, seed).astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+_FLASH_KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dq",
+                  "flash_attention_bwd_dkv")
+
+
+def _assert_flash_kernels(text, want):
+    got = _kernel_instruction_names(text)
+    assert len(got) == len(want), got
+    for name in want:
+        assert sum(name in g for g in got) == 1, (name, got)
 
 
 @pytest.mark.parametrize("dropout_p", [0.0, 0.1], ids=["plain", "dropout"])
@@ -93,13 +118,21 @@ def test_flash_forward(chip, dropout_p):
 
 
 def test_flash_forward_backward(chip):
-    fwd = _flash(0.0)
+    _assert_kernel_compiles(_flash_loss(), *_flash_args(chip))
 
-    def loss(q, k, v, seed):
-        return jnp.sum(fwd(q, k, v, seed).astype(jnp.float32))
 
-    _assert_kernel_compiles(jax.grad(loss, argnums=(0, 1, 2)),
-                            *_flash_args(chip))
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_forward_at_the_train_cell(chip, causal):
+    text = _assert_kernel_compiles(_flash(0.0, causal),
+                                   *_flash_args(chip, "train_tp4"))
+    _assert_flash_kernels(text, _FLASH_KERNELS[:1])
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_forward_backward_at_the_train_cell(chip, causal):
+    text = _assert_kernel_compiles(_flash_loss(causal),
+                                   *_flash_args(chip, "train_tp4"))
+    _assert_flash_kernels(text, _FLASH_KERNELS)
 
 
 def test_kernel_names_are_the_device_instruction_names(chip):
@@ -110,18 +143,8 @@ def test_kernel_names_are_the_device_instruction_names(chip):
     here; through the jitted ``flash_attention`` the name stands alone).
     Without a name the call takes its enclosing scope's (``shard_map``,
     ``jvp_jit_flash_attention__``)."""
-    fwd = _flash(0.0)
-
-    def loss(q, k, v, seed):
-        return jnp.sum(fwd(q, k, v, seed).astype(jnp.float32))
-
-    text = _assert_kernel_compiles(jax.grad(loss, argnums=(0, 1, 2)),
-                                   *_flash_args(chip))
-    got = _kernel_instruction_names(text)
-    assert len(got) == 3
-    for want in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                 "flash_attention_bwd_dkv"):
-        assert sum(want in name for name in got) == 1, (want, got)
+    text = _assert_kernel_compiles(_flash_loss(), *_flash_args(chip))
+    _assert_flash_kernels(text, _FLASH_KERNELS)
 
 
 # -- paged attention: the packed serving step's shapes ----------------------

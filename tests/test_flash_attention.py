@@ -151,3 +151,142 @@ def test_pallas_head_dim_64_via_lane_padding():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4,
                                    err_msg=f"d{name}")
+
+
+# (causal, sq, sk, block_q, block_k, d, dropout_p): the forward's tile keeps
+# its statistics by rows whatever the tile's shape, masks only the tiles the
+# diagonal crosses, and draws the mask the XLA path draws
+_FWD_CASES = {
+    "causal": (True, 256, 256, 128, 128, 128, 0.0),
+    "full": (False, 256, 256, 128, 128, 128, 0.0),
+    "causal-bq256-bk512": (True, 1024, 1024, 256, 512, 128, 0.0),
+    "causal-bq512-bk256": (True, 1024, 1024, 512, 256, 128, 0.0),
+    "full-bq256-bk512": (False, 512, 1024, 256, 512, 128, 0.0),
+    "full-sq128-sk384": (False, 128, 384, 128, 128, 128, 0.0),
+    "causal-sq128-sk256": (True, 128, 256, 64, 128, 128, 0.0),
+    "causal-d64": (True, 256, 256, 128, 128, 64, 0.0),
+    "full-d64": (False, 128, 256, 128, 128, 64, 0.0),
+    "causal-loose-blocks": (True, 96, 96, 24, 48, 128, 0.0),
+    "causal-dropout": (True, 256, 256, 128, 128, 128, 0.1),
+    "full-dropout": (False, 128, 256, 64, 128, 128, 0.1),
+    "causal-d64-dropout": (True, 256, 256, 128, 64, 64, 0.1),
+    "causal-bq256-bk512-dropout": (True, 1024, 1024, 256, 512, 128, 0.1),
+}
+
+
+@pytest.mark.parametrize("case", list(_FWD_CASES))
+def test_pallas_forward_matches_reference_and_xla_lse(case):
+    """The Pallas forward (interpret mode) against ``sdpa_reference``, and
+    its log-sum-exp and, under dropout, its output against the XLA scan's:
+    one mask a seed on every path."""
+    from neuronx_distributed_tpu.ops.flash_attention import (
+        _flash_pallas_fwd, _flash_xla_impl)
+
+    causal, sq, sk, bq, bk, d, dropout_p = _FWD_CASES[case]
+    b, n = 2, 2
+    ks = jax.random.split(jax.random.key(6), 3)
+    q = jax.random.normal(ks[0], (b, sq, n, d))
+    k = jax.random.normal(ks[1], (b, sk, n, d))
+    v = jax.random.normal(ks[2], (b, sk, n, d))
+    seed = jnp.uint32(1234)
+    kw = dict(dropout_p=dropout_p, dropout_seed=seed) if dropout_p else {}
+
+    out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk,
+                          force_pallas=True, **kw)
+    ref = sdpa_reference(q, k, v, causal=causal, **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+    scale = 1.0 / np.sqrt(d)
+    padw = ((0, 0), (0, 0), (0, 0), (0, -d % 128))
+    got, lse = _flash_pallas_fwd(
+        *(jnp.pad(x, padw) for x in (q, k, v)), seed.reshape((1,)), causal,
+        bq, bk, scale, interpret=True, dropout_p=dropout_p)
+    want, want_lse = _flash_xla_impl(q, k, v, causal, bk, scale, dropout_p,
+                                     seed)
+    assert lse.shape == (b, n, sq)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got[..., :d]), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_pallas_forward_takes_bf16_operands(causal):
+    """bf16 inputs (what the models hand over) go to the products as they
+    are, and ``p`` beside a bf16 ``v`` in bf16: what the MXU made of the
+    float32 operands it was given before. Against float32 arithmetic on
+    the same values the output is off by bf16's rounding of ``p`` alone."""
+    from neuronx_distributed_tpu.ops.flash_attention import (
+        _flash_pallas_fwd, _flash_xla_impl)
+
+    ks = jax.random.split(jax.random.key(8), 3)
+    q, k, v = (jax.random.normal(kk, (1, 256, 2, 128)).astype(jnp.bfloat16)
+               for kk in ks)
+    scale = 1.0 / np.sqrt(128)
+    out, lse = _flash_pallas_fwd(q, k, v, jnp.zeros((1,), jnp.uint32),
+                                 causal, 128, 128, scale, interpret=True)
+    assert out.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    want, want_lse = _flash_xla_impl(*(x.astype(jnp.float32)
+                                       for x in (q, k, v)),
+                                     causal, 128, scale, 0.0, None)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                               np.asarray(want), rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_row_with_no_live_key_is_zero(causal):
+    """A query row whose every score is ``-inf`` met no key: its output
+    row is zero, its log-sum-exp ``-inf`` and it moves no gradient, on
+    the Pallas path (interpret mode) as on the XLA path."""
+    from neuronx_distributed_tpu.ops.flash_attention import (
+        _flash_pallas_fwd, _flash_xla_impl)
+
+    b, s, n, d, blk, dead = 1, 256, 2, 128, 128, 130
+    ks = jax.random.split(jax.random.key(7), 3)
+    q = jax.random.normal(ks[0], (b, s, n, d))
+    # keys of one sign: the dead row's products overflow to -inf on every
+    # key, the other rows stay ordinary
+    k = -jnp.abs(jax.random.normal(ks[1], (b, s, n, d))) - 0.5
+    v = jax.random.normal(ks[2], (b, s, n, d))
+    q = q.at[:, dead, 0].set(3e38)
+    scale = 1.0 / np.sqrt(d)
+    zseed = jnp.zeros((1,), jnp.uint32)
+
+    out, lse = _flash_pallas_fwd(q, k, v, zseed, causal, blk, blk, scale,
+                                 interpret=True)
+    want, want_lse = _flash_xla_impl(q, k, v, causal, blk, scale, 0.0, None)
+    assert np.all(np.asarray(out[:, dead, 0]) == 0.0)
+    assert np.all(np.asarray(lse[:, 0, dead]) == -np.inf)
+    assert np.isfinite(np.asarray(lse[:, 1])).all()
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.isneginf(np.asarray(lse)),
+                                  np.isneginf(np.asarray(want_lse)))
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=causal, block_q=blk,
+                                       block_k=blk, force_pallas=True) ** 2)
+
+    dq, dk, dv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    assert np.all(np.asarray(dq[:, dead, 0]) == 0.0)
+    for g in (dq, dk, dv):
+        assert np.isfinite(np.asarray(g)).all()
+    # the dead row adds nothing to dk and dv: with the row alive, they are
+    # these plus that row's own part
+    q_live = q.at[:, dead, 0].set(0.0)
+
+    def row_loss(k, v):
+        out = flash_attention(q_live, k, v, causal=causal, block_q=blk,
+                              block_k=blk, force_pallas=True)
+        return jnp.sum(out[:, dead, 0] ** 2)
+
+    _, dk_live, dv_live = jax.grad(loss, argnums=(0, 1, 2))(q_live, k, v)
+    dk_row, dv_row = jax.grad(row_loss, argnums=(0, 1))(k, v)
+    np.testing.assert_allclose(np.asarray(dk), np.asarray(dk_live - dk_row),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(dv), np.asarray(dv_live - dv_row),
+                               rtol=1e-4, atol=1e-4)
