@@ -295,6 +295,13 @@ MOE_HELD = CounterFamily(
     "slot here and adds nothing. Counted on the device, fetched with the "
     "step's tokens.",
     ("held", "elsewhere"))
+MOE_EXPERTS_HIT = CounterFamily(
+    "nxd_moe_experts_hit_total",
+    "Experts this device holds (a step and an expert layer) by whether "
+    "at least one real row of the step chose them (hit) or none did "
+    "(idle: the step needed none of the expert's weights). Counted on the "
+    "device, fetched with the step's tokens.",
+    ("hit", "idle"))
 MOE_IDENTITY = CounterFamily(
     "nxd_moe_identity_total",
     "Router choices of the serving workers' real rows (top_k a row an "
@@ -320,6 +327,12 @@ MOE_KEPT_DROPPED_ELSEWHERE_IDENTITY = DeviceCounts(
     "moe_counts", 4, ((MOE_ASSIGNMENTS, ((0,), (1,))),
                       (MOE_HELD, ((0, 1), (2,))),
                       (MOE_IDENTITY, ((3,), (0, 1, 2)))))
+
+#: a share of the experts whose family also counts the held experts a step
+#: hit and left idle (``MoE(count_hit=True)``)
+MOE_KEPT_DROPPED_ELSEWHERE_HIT = DeviceCounts(
+    "moe_counts", 5, MOE_KEPT_DROPPED_ELSEWHERE.reads
+    + ((MOE_EXPERTS_HIT, ((3,), (4,))),))
 
 #: ``counts`` of a sparse-state cache: a counter family and, in its kinds'
 #: order, the names of :data:`..ops.sparse_attention.COUNT_KINDS` it reads
@@ -773,10 +786,10 @@ class StatePoolCache(FullCache):
     pool_layers: int = 0
     pack: int = 1
     leaves: Tuple[StateLeaf, ...] = ()
-    name = "state_pool"
     #: as :attr:`LatentCache.moe_leaf`; its device may hold a share of the
     #: experts
-    moe_leaf = MOE_KEPT_DROPPED_ELSEWHERE
+    moe_leaf: DeviceCounts = MOE_KEPT_DROPPED_ELSEWHERE
+    name = "state_pool"
 
     @property
     def counters(self) -> Tuple[CounterFamily, ...]:
@@ -965,10 +978,12 @@ class ServingFamily:
     the device holds a share of the experts: and those that chose an
     expert held elsewhere; ``[4]`` where the router also scores identity
     experts: and the choices of one, the first three then of the real
-    experts alone), which the engine fetches with the step's tokens
-    (``nxd_moe_assignments_total``, ``nxd_moe_held_total``,
-    ``nxd_moe_identity_total``); the family's cache kind builds the leaf
-    (its ``moe_leaf``)."""
+    experts alone; ``[5]`` where a share's family counts its experts
+    instead: and the held experts the step hit and left idle), which the
+    engine fetches with the step's tokens (``nxd_moe_assignments_total``,
+    ``nxd_moe_held_total``, ``nxd_moe_identity_total``,
+    ``nxd_moe_experts_hit_total``); the family's cache kind builds the
+    leaf (its ``moe_leaf``)."""
 
     forward: Callable
     cache_kind: Any = FULL_CACHE
@@ -1076,9 +1091,9 @@ class StatePoolPagedCache(_BlockPool, struct.PyTreeNode):
     num_blocks, block_size, KV / pack, D * pack]`` over the ``La``
     attention layers; ``states`` the family's per-slot leaves by name
     (:class:`StateLeaf`: ``lead + (table rows,) + trail`` each);
-    ``moe_counts [3]`` where the family declares it
-    (:class:`ServingFamily`; else None); ``pos``, ``block_tables`` and
-    ``lengths`` as :class:`PagedKVCache`."""
+    ``moe_counts`` (the kind's ``moe_leaf``: ``[3]``, or ``[5]``) where
+    the family declares it (:class:`ServingFamily`; else None); ``pos``,
+    ``block_tables`` and ``lengths`` as :class:`PagedKVCache`."""
 
     k: jax.Array
     v: jax.Array

@@ -40,6 +40,7 @@ of layer; the dense models' tree, step and cache have no such leaf.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
@@ -50,7 +51,7 @@ from flax import linen as nn
 from flax.core import meta
 
 from ..modules.moe import MoE
-from ..modules.norms import RMSNorm
+from ..modules.norms import GroupRMSNorm, RMSNorm
 from ..obs.device_scopes import device_scope
 from ..ops import ssd
 from ..parallel import layers as pl
@@ -66,6 +67,17 @@ PUBLISHED_LAYERS = tuple("attention" if i % 10 == 5 else "mamba"
 #: (and ``moe_counts`` where the model routes:
 #: :meth:`GraniteHybridConfig.carried`)
 CARRIED = {"full": ("k", "v"), "mamba2": ("ssm", "conv")}
+#: what no family of per-slot Mamba-2 states serves, each with why
+UNSUPPORTED = {
+    "prefix_sharing": "a mamba layer's state and convolution tail are no "
+    "blocks: a shared prefix's blocks carry neither to resume from",
+    "speculation": "a lane clone copies blocks, and a rejected draft row "
+    "has already advanced its slot's state and shifted its tail",
+    "cp": "the per-slot states are not sharded over a cp axis",
+    "quantized": "the states are float32 beside the pool, and two heads a "
+    "pool row want scales of their own",
+    "session_export": "a shipped session's blocks leave its states and "
+    "tails behind"}
 #: what a published config must say for this module to be its model
 _BUILT = {"model_type": "granitemoehybrid", "position_embedding_type": "nope",
           "hidden_act": "silu", "normalization_function": "rmsnorm",
@@ -130,9 +142,11 @@ class GraniteHybridConfig(LlamaConfig):
                 f"layer_types must name one of {sorted(LAYER_KINDS)} for "
                 f"each of the {self.num_layers} layers, got "
                 f"{self.layer_types}")
-        if self.mamba_n_groups != 1:
-            raise ValueError("one group of B and C is what ops/ssd.py "
-                             "computes")
+        groups = self.mamba_n_groups
+        if groups < 1 or self.mamba_n_heads % groups:
+            raise ValueError(
+                f"{self.mamba_n_heads} heads do not lie in "
+                f"{self.mamba_n_groups} groups of B and C")
         held = self.experts_held
         if held is not None and not (
                 0 <= held[0] and held[1] > 0
@@ -197,7 +211,7 @@ class GraniteHybridConfig(LlamaConfig):
 
     @property
     def conv_channels(self) -> int:
-        return self.d_inner + 2 * self.mamba_d_state
+        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
     def pool_pack(self) -> int:
@@ -273,18 +287,7 @@ class GraniteHybridConfig(LlamaConfig):
                     StateLeaf("conv", (layers, self.mamba_d_conv - 1),
                               (self.conv_channels,), counted_as="tail"))),
             moe_counts=bool(self.num_experts),
-            unsupported={
-                "prefix_sharing": "a mamba layer's state and convolution "
-                "tail are no blocks: a shared prefix's blocks carry "
-                "neither to resume from",
-                "speculation": "a lane clone copies blocks, and a rejected "
-                "draft row has already advanced its slot's state and "
-                "shifted its tail",
-                "cp": "the per-slot states are not sharded over a cp axis",
-                "quantized": "the states are float32 beside the pool, and "
-                "two heads a pool row want scales of their own",
-                "session_export": "a shipped session's blocks leave its "
-                "states and tails behind"})
+            unsupported=UNSUPPORTED)
 
 
 def tiny_config(**kw) -> GraniteHybridConfig:
@@ -320,7 +323,12 @@ def _conv_init(key, shape, dtype):
 class Mamba2Mixer(nn.Module):
     """The Mamba-2 mixer in :class:`.llama.LlamaAttention`'s place.
     ``cache`` is None (a whole sequence at positions ``0..S-1``) or a
-    :class:`..inference.paging.StateSpaceLayerView` of the packed step."""
+    :class:`..inference.paging.StateSpaceLayerView` of the packed step.
+    ``cfg`` is this family's config or another's with its ``mamba_*``
+    fields (:mod:`.nemotron_h`). With ``mamba_n_groups`` ``G`` > 1 the
+    heads read ``B`` and ``C`` by group (:mod:`..ops.ssd`) and the gated
+    norm takes its mean square over each group's ``d_inner / G`` channels
+    (one weight a channel, as with one group)."""
 
     cfg: GraniteHybridConfig
     tp_sync: bool = True
@@ -332,6 +340,7 @@ class Mamba2Mixer(nn.Module):
         heads, width, n = cfg.mamba_n_heads, cfg.mamba_d_head, \
             cfg.mamba_d_state
         inner, chans, taps = cfg.d_inner, cfg.conv_channels, cfg.mamba_d_conv
+        groups = cfg.mamba_n_groups
         with device_scope("attn.proj"):
             zxbcdt = pl.ColumnParallelLinear(
                 features=inner + chans + heads, use_bias=False,
@@ -365,7 +374,9 @@ class Mamba2Mixer(nn.Module):
                     cache.seg)
                 xbc = conv.astype(cfg.dtype)[None]
         with device_scope("attn.state"):
-            xs, b, c = jnp.split(xbc, (inner, inner + n), axis=-1)
+            xs, b, c = jnp.split(xbc, (inner, inner + groups * n), axis=-1)
+            if groups > 1:
+                b, c = (v.reshape(b_, s_, groups, n) for v in (b, c))
             dt = jax.nn.softplus(dt.astype(jnp.float32)
                                  + dt_bias.astype(jnp.float32))
             xs = xs.reshape(b_, s_, heads, width)
@@ -380,9 +391,12 @@ class Mamba2Mixer(nn.Module):
                 y = y[None]
                 new_cache = cache.replace(ssm=states, conv=tails)
         with device_scope("attn.proj"):
-            # gate first, then one norm over all of d_inner
+            # gate first, then one norm over each group's share of d_inner
+            # (all of it with one group)
             y = y.reshape(b_, s_, inner) * jax.nn.silu(z.astype(jnp.float32))
-            y = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="norm")(y)
+            norm = (RMSNorm if groups == 1
+                    else functools.partial(GroupRMSNorm, groups=groups))
+            y = norm(eps=cfg.rms_eps, dtype=cfg.dtype, name="norm")(y)
             out = pl.RowParallelLinear(
                 features=cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype, tp_sync=self.tp_sync,
@@ -448,36 +462,17 @@ class GraniteHybridForCausalLM(nn.Module):
         return logits
 
 
-def granite_hybrid_forward_with_cache(cfg: GraniteHybridConfig, params,
-                                      input_ids, positions, kv_cache,
-                                      slot_ids=None, **unsupported):
-    """The paged forward of the packed serving step, with
-    :func:`.llama.llama_forward_with_cache`'s paged signature:
-    ``input_ids``, ``positions [1, T]``, ``slot_ids [T]``, ``kv_cache`` a
-    :class:`..inference.paging.StatePoolPagedCache`; returns ``(logits
-    [1, T, V], new cache)``. The cache's stacks (K/V of the attention
-    layers, the mamba layers' states and tails) and, where the model
-    routes, the routed assignments' counts (of this step alone) are the
-    carry of every run's scan."""
+def mixer_views(cfg, kv_cache, q_pos, slot_ids):
+    """What a packed step builds once for the mixers of a state-pool
+    family (this one's, :mod:`.nemotron_h`'s): the rows' block tables,
+    write indices and the paged kernel's walk, the step's segments, and
+    the pool's positions with the step's rows written. ``(pool_pos,
+    view_of)``: ``view_of(mixer, carry, layer)`` is the view a layer whose
+    mixer is ``"full"`` or ``"mamba2"`` is given of the carried stacks at
+    its index ``layer`` among the layers of that mixer."""
     from ..inference import paging
-    from ..inference.kv_cache import PAD_POSITION
     from ..ops import paged_attention as pa
 
-    if any(unsupported.values()):
-        raise ValueError(f"granite_hybrid serves through the packed paged "
-                         f"step only; got {sorted(unsupported)}")
-    if not isinstance(kv_cache, paging.StatePoolPagedCache):
-        raise ValueError("granite_hybrid is served from the cache its cache "
-                         "kind builds (paging.init_serving_cache)")
-    p = params["params"]
-    q_pos = jnp.asarray(positions, jnp.int32)[0]
-    slot_ids = jnp.asarray(slot_ids, jnp.int32)
-    with device_scope("embed"):
-        x = pl.ParallelEmbedding(
-            num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
-            {"params": p["model"]["embed"]}, input_ids) \
-            * cfg.embedding_multiplier
     kind = cfg.serving_family().cache_kind.geometry(kv_cache.block_size)
     with device_scope("attn.walk"):
         tables = kv_cache.block_tables[
@@ -495,8 +490,8 @@ def granite_hybrid_forward_with_cache(cfg: GraniteHybridConfig, params,
         pool_pos = paging.write_pool_positions(kv_cache.pos, q_pos,
                                                write_idx)
 
-    def view_of(kind, carry, layer):
-        if kind == "full":
+    def view_of(mixer, carry, layer):
+        if mixer == "full":
             return paging.PagedCacheView(
                 k=carry["k"], v=carry["v"], k_scale=None, v_scale=None,
                 layer=layer, pos=pool_pos, tables=tables,
@@ -504,6 +499,39 @@ def granite_hybrid_forward_with_cache(cfg: GraniteHybridConfig, params,
         return paging.StateSpaceLayerView(
             ssm=carry["ssm"], conv=carry["conv"], layer=layer, seg=seg)
 
+    return pool_pos, view_of
+
+
+def granite_hybrid_forward_with_cache(cfg: GraniteHybridConfig, params,
+                                      input_ids, positions, kv_cache,
+                                      slot_ids=None, **unsupported):
+    """The paged forward of the packed serving step, with
+    :func:`.llama.llama_forward_with_cache`'s paged signature:
+    ``input_ids``, ``positions [1, T]``, ``slot_ids [T]``, ``kv_cache`` a
+    :class:`..inference.paging.StatePoolPagedCache`; returns ``(logits
+    [1, T, V], new cache)``. The cache's stacks (K/V of the attention
+    layers, the mamba layers' states and tails) and, where the model
+    routes, the routed assignments' counts (of this step alone) are the
+    carry of every run's scan."""
+    from ..inference import paging
+    from ..inference.kv_cache import PAD_POSITION
+
+    if any(unsupported.values()):
+        raise ValueError(f"granite_hybrid serves through the packed paged "
+                         f"step only; got {sorted(unsupported)}")
+    if not isinstance(kv_cache, paging.StatePoolPagedCache):
+        raise ValueError("granite_hybrid is served from the cache its cache "
+                         "kind builds (paging.init_serving_cache)")
+    p = params["params"]
+    q_pos = jnp.asarray(positions, jnp.int32)[0]
+    slot_ids = jnp.asarray(slot_ids, jnp.int32)
+    with device_scope("embed"):
+        x = pl.ParallelEmbedding(
+            num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
+            {"params": p["model"]["embed"]}, input_ids) \
+            * cfg.embedding_multiplier
+    pool_pos, view_of = mixer_views(cfg, kv_cache, q_pos, slot_ids)
     carry = dict(k=kv_cache.k, v=kv_cache.v, **kv_cache.states)
     routed = {}
     if cfg.num_experts:

@@ -240,6 +240,15 @@ class LlamaConfig:
         ``attn``."""
         return LlamaAttention(self, tp_sync=tp_sync, name="attn")
 
+    def layer_blocks(self) -> Tuple[str, ...]:
+        """The blocks of this config's (of this kind's) decoder layer,
+        each under a norm of its own and into a residual add: ``"attn"``
+        (the mixer :meth:`attention` builds) and ``"ffn"``
+        (:meth:`feed_forward`), or one of the two where the family's
+        layers are a mixer or a feed-forward alone
+        (``models/nemotron_h.py``)."""
+        return ("attn", "ffn")
+
     def decoder_layer(self, **module):
         """The decoder layer module of this config (of this kind's, for a
         config :meth:`kind_config` derived): :class:`LlamaDecoderLayer`
@@ -931,10 +940,11 @@ class LlamaMLP(nn.Module):
 
 class LlamaDecoderLayer(nn.Module):
     """The decoder layer of every family: norm, attention, residual,
-    norm, the config's feed-forward, residual. Returns ``(x, aux,
-    new_cache)``: ``aux`` is the router's ``[load_balance, z]`` pair where
-    the feed-forward has a router and ``None`` where it has none,
-    ``new_cache`` ``None`` without a cache."""
+    norm, the config's feed-forward, residual (either pair absent where
+    the config's :meth:`LlamaConfig.layer_blocks` leaves it out). Returns
+    ``(x, aux, new_cache)``: ``aux`` is the router's ``[load_balance, z]``
+    pair where the feed-forward has a router and ``None`` where it has
+    none, ``new_cache`` ``None`` without a cache or a mixer."""
 
     cfg: LlamaConfig
     # False elides this layer's row-parallel exit all-reduces (o_proj and
@@ -946,31 +956,35 @@ class LlamaDecoderLayer(nn.Module):
                  positions: Optional[jax.Array] = None,
                  cache=None, cache_index=None, valid=None):
         cfg = self.cfg
+        blocks = cfg.layer_blocks()
+        aux = new_cache = None
         # a block's scope takes in the residual add that takes its output:
         # XLA fuses a matmul's epilogue into that add and names the fusion
         # by its root (obs/device_scopes.py)
-        with device_scope("norm"):
-            h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                        sequence_parallel=cfg.sequence_parallel,
-                        name="input_norm")(x)
-        with device_scope("attn"):
-            attn_out = cfg.attention(self.tp_sync)(
-                h, cos, sin, positions, cache=cache, cache_index=cache_index)
-            new_cache = None
-            if cache is not None:
-                attn_out, new_cache = attn_out
-            if cfg.residual_scale != 1.0:
-                attn_out = attn_out * cfg.residual_scale
-            x = x + attn_out
-        with device_scope("norm"):
-            h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
-                        sequence_parallel=cfg.sequence_parallel,
-                        name="post_norm")(x)
-        with device_scope("ffn"):
-            ff_out, aux = cfg.feed_forward(h, self.tp_sync, valid)
-            if cfg.residual_scale != 1.0:
-                ff_out = ff_out * cfg.residual_scale
-            x = x + ff_out
+        if "attn" in blocks:
+            with device_scope("norm"):
+                h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                            sequence_parallel=cfg.sequence_parallel,
+                            name="input_norm")(x)
+            with device_scope("attn"):
+                attn_out = cfg.attention(self.tp_sync)(
+                    h, cos, sin, positions, cache=cache,
+                    cache_index=cache_index)
+                if cache is not None:
+                    attn_out, new_cache = attn_out
+                if cfg.residual_scale != 1.0:
+                    attn_out = attn_out * cfg.residual_scale
+                x = x + attn_out
+        if "ffn" in blocks:
+            with device_scope("norm"):
+                h = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
+                            sequence_parallel=cfg.sequence_parallel,
+                            name="post_norm")(x)
+            with device_scope("ffn"):
+                ff_out, aux = cfg.feed_forward(h, self.tp_sync, valid)
+                if cfg.residual_scale != 1.0:
+                    ff_out = ff_out * cfg.residual_scale
+                x = x + ff_out
         return x, aux, new_cache
 
 

@@ -33,6 +33,15 @@ from ...parallel import mesh as ps
 from .. import glu
 
 
+#: the forms of an expert (:attr:`ExpertMLPs.act`)
+ACTS = ("swiglu", "relu2")
+
+
+def relu2(x: jax.Array) -> jax.Array:
+    """The squared ReLU of an ungated expert."""
+    return jnp.square(nn.relu(x))
+
+
 def compute_capacity(num_tokens: int, num_experts: int, top_k: int,
                      capacity_factor: float) -> int:
     """Per-expert capacity slots (reference capacity computation in
@@ -136,6 +145,13 @@ class ExpertMLPs(nn.Module):
     # own). Capacity dispatch under ``valid`` rows, no ep axis: the
     # exchange between devices that share a layer is not built
     held: Optional[Tuple[int, int]] = None
+    # an expert's form: "swiglu", ``down(silu(gate x) * up x)``, or
+    # "relu2", ungated, ``down(relu(up x)^2)``: two leaves ``up`` and
+    # ``down`` and no ``gate`` (capacity dispatch)
+    act: str = "swiglu"
+    # with ``held``: ``aux["experts_hit"]``, the held experts that took a
+    # row of the step and those that took none, ``[hit, idle]`` int32
+    count_hit: bool = False
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     tp_axis: str = ps.TP_AXIS
@@ -156,10 +172,20 @@ class ExpertMLPs(nn.Module):
         i_local = pl._maybe_local(self.intermediate_size, self.tp_axis)
         ep = comm._axis_size(self.ep_axis)
 
-        gate_up = glu.declare(
-            self, glu.EXPERTS, pl.default_kernel_init,
-            (self.ep_axis, None, self.tp_axis),
-            (e_local, self.hidden_size, i_local), self.param_dtype)
+        if self.act not in ACTS or (self.act != "swiglu"
+                                    and self.dispatch_mode != "capacity"):
+            raise ValueError(f"ExpertMLPs: act is one of {ACTS} (other "
+                             f"than swiglu: capacity dispatch), got "
+                             f"{self.act!r}")
+        in_axes = (self.ep_axis, None, self.tp_axis)
+        in_shape = (e_local, self.hidden_size, i_local)
+        if self.act == "swiglu":
+            gate_up = glu.declare(self, glu.EXPERTS, pl.default_kernel_init,
+                                  in_axes, in_shape, self.param_dtype)
+        else:
+            gate_up = (self.param("up", nn.with_partitioning(
+                pl.default_kernel_init, in_axes), in_shape,
+                self.param_dtype),)
         down = self.param(
             "down",
             nn.with_partitioning(pl.default_kernel_init,
@@ -203,8 +229,11 @@ class ExpertMLPs(nn.Module):
         # expert-fused column parallel (3-D einsum; reference
         # ExpertFusedColumnParallelLinear moe_parallel_layers.py:175)
         xin = mappings.copy_to_tensor_parallel_region(xin, self.tp_axis)
-        h = glu.gated(*glu.project(
-            xin, *(w.astype(self.dtype) for w in gate_up)))
+        if self.act == "swiglu":
+            h = glu.gated(*glu.project(
+                xin, *(w.astype(self.dtype) for w in gate_up)))
+        else:
+            h = relu2(jnp.matmul(xin, gate_up[0].astype(self.dtype)))
         out = jnp.einsum("eci,eih->ech", h, down.astype(self.dtype))
         # expert-fused row parallel exit (reference
         # ExpertFusedRowParallelLinear moe_parallel_layers.py:303)
@@ -229,6 +258,12 @@ class ExpertMLPs(nn.Module):
                                & (idx < first + count)).astype(jnp.int32)
                 aux["assignments"] = jnp.stack(
                     [kept, mine - kept, asked - mine])
+        if self.count_hit:
+            # an expert's slots fill from the first: it took a row of the
+            # step if and only if its slot 0 is taken
+            hit = jnp.sum(jnp.any(dispatch[:, :, 0] > 0, axis=0)
+                          ).astype(jnp.int32)
+            aux["experts_hit"] = jnp.stack([hit, self.num_experts - hit])
         return y.astype(self.dtype), aux
 
     def _run_grouped_glu(self, xs, gate_up, down, be, i_local):

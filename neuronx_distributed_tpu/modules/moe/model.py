@@ -16,7 +16,7 @@ from ...obs.device_scopes import device_scope
 from ...parallel import layers as pl
 from ...parallel import mesh as ps
 from .. import glu
-from .expert_mlps import ExpertMLPs
+from .expert_mlps import ExpertMLPs, relu2
 from .routing import (GroupLimitedRouter, RouterSigmoid, RouterSinkhorn,
                       RouterSoftmaxBias, RouterTopK)
 
@@ -30,25 +30,32 @@ ROUTERS = {
 
 
 class SharedExperts(nn.Module):
-    """Always-on dense GLU MLP added to the routed output (reference
-    ``shared_experts.py:73``)."""
+    """Always-on dense MLP added to the routed output (reference
+    ``shared_experts.py:73``): a GLU, or with ``act="relu2"`` ungated,
+    ``down(relu(up x)^2)``, as :class:`.expert_mlps.ExpertMLPs` has it."""
 
     hidden_size: int
     intermediate_size: int
+    act: str = "swiglu"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        i_local = pl._maybe_local(self.intermediate_size, ps.TP_AXIS)
-        gate, up = glu.declare(
-            self, glu.DENSE, pl.default_kernel_init, (None, ps.TP_AXIS),
-            (self.hidden_size, i_local), self.param_dtype)
         from ...parallel import mappings
 
+        i_local = pl._maybe_local(self.intermediate_size, ps.TP_AXIS)
+        axes, shape = (None, ps.TP_AXIS), (self.hidden_size, i_local)
         h = mappings.copy_to_tensor_parallel_region(x).astype(self.dtype)
-        g = glu.gated(*glu.project(h, gate.astype(self.dtype),
-                                   up.astype(self.dtype)))
+        if self.act == "swiglu":
+            gate, up = glu.declare(self, glu.DENSE, pl.default_kernel_init,
+                                   axes, shape, self.param_dtype)
+            g = glu.gated(*glu.project(h, gate.astype(self.dtype),
+                                       up.astype(self.dtype)))
+        else:
+            up = self.param(glu.DENSE[1], nn.with_partitioning(
+                pl.default_kernel_init, axes), shape, self.param_dtype)
+            g = relu2(jnp.matmul(h, up.astype(self.dtype)))
         return pl.RowParallelLinear(
             features=self.hidden_size, use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, name="down")(g)
@@ -92,6 +99,17 @@ class MoE(nn.Module):
     # takes no slot of the bank, is nowhere else either, and adds its
     # weight times the row's own input, on the device that owns the row
     identity_experts: int = 0
+    # the routed and the shared experts' form
+    # (:attr:`.expert_mlps.ExpertMLPs.act`; float experts)
+    expert_act: str = "swiglu"
+    # > 0: the routed experts work in a latent of this width: ``latent_in
+    # [H, latent]`` ahead of the bank and ``latent_out [latent, H]`` behind
+    # its weighted sum, linear, one pair a layer (what an exchange between
+    # the devices that share the layer would carry is latent rows); the
+    # router and the shared expert read the row itself
+    latent_size: int = 0
+    # ``aux["experts_hit"]``: :attr:`.expert_mlps.ExpertMLPs.count_hit`
+    count_hit: bool = False
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -128,6 +146,11 @@ class MoE(nn.Module):
                                  or valid is None):
             raise ValueError("MoE: a share of the experts (held) is float "
                              "experts under the packed step's valid rows")
+        if self.expert_impl != "float" and (
+                self.expert_act != "swiglu" or self.latent_size
+                or self.count_hit):
+            raise ValueError("MoE: expert_act, latent_size and count_hit "
+                             "are the float experts'")
         with device_scope("ffn.router"):
             gates, idx, aux = router_cls(**router_kw)(flat)
 
@@ -175,7 +198,8 @@ class MoE(nn.Module):
         else:
             experts = ExpertMLPs(
                 num_experts=(self.num_experts if held is None
-                             else held[1]), hidden_size=h,
+                             else held[1]),
+                hidden_size=self.latent_size or h,
                 intermediate_size=self.intermediate_size,
                 top_k=gates.shape[-1], capacity_factor=self.capacity_factor,
                 dispatch_mode=self.dispatch_mode,
@@ -184,19 +208,33 @@ class MoE(nn.Module):
                 ep_wire_dtype=self.ep_wire_dtype,
                 ep_overlap=self.ep_overlap,
                 dtype=self.dtype, param_dtype=self.param_dtype,
-                held=held, name="experts")
+                held=held, act=self.expert_act, count_hit=self.count_hit,
+                name="experts")
+        rows = flat
+        if self.latent_size:
+            latent_in, latent_out = (
+                self.param(name, nn.with_partitioning(
+                    pl.default_kernel_init, (None, None)), shape,
+                    self.param_dtype).astype(self.dtype)
+                for name, shape in (("latent_in", (h, self.latent_size)),
+                                    ("latent_out", (self.latent_size, h))))
+            with device_scope("ffn.latent"):
+                rows = jnp.matmul(flat.astype(self.dtype), latent_in)
         # the routed experts: dispatch, the bank's products, combine
         with device_scope("ffn.experts"):
             if valid is None:
-                y, eaux = experts(flat, gates, idx)
+                y, eaux = experts(rows, gates, idx)
             else:
                 # the older name of the same scope: the benchmark's
                 # moe_expert_share_pct.batch is held to it
                 # (tests/test_chip_compile.py)
                 with jax.named_scope("routed_experts"):
-                    y, eaux = experts(flat, gates, idx,
+                    y, eaux = experts(rows, gates, idx,
                                       valid=valid.reshape(-1))
         aux.update(eaux)
+        if self.latent_size:
+            with device_scope("ffn.latent"):
+                y = jnp.matmul(y, latent_out)
 
         if self.identity_experts:
             with device_scope("ffn.identity"):
@@ -214,6 +252,7 @@ class MoE(nn.Module):
                 y = y + SharedExperts(
                     hidden_size=h,
                     intermediate_size=self.shared_expert_intermediate,
-                    dtype=self.dtype, param_dtype=self.param_dtype,
+                    act=self.expert_act, dtype=self.dtype,
+                    param_dtype=self.param_dtype,
                     name="shared")(flat)
         return y.reshape(orig_shape), aux

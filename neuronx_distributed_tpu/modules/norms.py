@@ -44,6 +44,28 @@ class RMSNorm(nn.Module):
         return (y * scale.astype(jnp.float32)).astype(self.dtype)
 
 
+class GroupRMSNorm(nn.Module):
+    """:class:`RMSNorm` whose mean square is taken over each of ``groups``
+    runs of ``C / groups`` consecutive channels and not over all ``C``;
+    one weight a channel, ``scale [C]`` (Mamba-2's gated norm with
+    ``n_groups`` > 1)."""
+
+    groups: int
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.with_partitioning(
+            nn.initializers.ones_init(), (None,)), (x.shape[-1],),
+            self.param_dtype)
+        xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (self.groups, -1))
+        var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        y = (xf * jax.lax.rsqrt(var + self.eps)).reshape(x.shape)
+        return (y * scale.astype(jnp.float32)).astype(self.dtype)
+
+
 class LayerNorm(nn.Module):
     """LayerNorm with optional SP-aware weight grads (reference
     ``layer_norm.py:17``)."""

@@ -47,7 +47,7 @@ SCOPES = (
     "attn.kernel.window", "attn.walk", "attn.select",
     "attn.state", "attn.conv", "attn.summarise", "attn.pool_write",
     "ffn", "ffn.dense", "ffn.router", "ffn.experts", "ffn.shared",
-    "ffn.identity",
+    "ffn.identity", "ffn.latent",
     "head", "sample",
     "loss", "optimizer",
 )

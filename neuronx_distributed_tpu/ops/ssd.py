@@ -7,7 +7,9 @@ A head ``h`` of width ``P`` keeps a state ``S[h] in R^{P x N}`` (``N`` =
     y_t[h] = S_t[h] C_t + D[h] x_t[h]
 
 with ``A < 0``, ``dt > 0`` (after its softplus) and ``B_t, C_t in R^N``
-shared by the heads (one group). Two forms of the one recurrence, both in
+shared by the heads of a *group*: ``G`` groups of ``H / G`` consecutive
+heads, head ``h`` reading ``B_t[h // (H / G)]`` (``b, c [.., N]`` is one
+group, ``[.., G, N]`` several). Two forms of the one recurrence, both in
 float32 (``highest`` where a matmul carries a state):
 
 * :func:`ssd_full` - a whole sequence at positions ``0..S-1``, no cache:
@@ -33,7 +35,9 @@ The state of the packed form is laid for the kernel: ``ssm [L, J, N, H *
 P]`` float32, a slot's ``[N, H * P]`` one contiguous run with the
 ``d_inner = H * P`` channels on lanes, so that the decay and ``dt x`` of a
 row are lane vectors, ``B`` and ``C`` sublane vectors, and the read-out
-``sum_n C[n] S[n, :]`` adds vregs and reduces no lane.
+``sum_n C[n] S[n, :]`` adds vregs and reduces no lane. A group's heads are
+``d_inner / G`` consecutive channels, whole tiles of the kernel's, so the
+group of a tile is a function of the tile's index.
 
 :func:`causal_conv_step` is the depthwise convolution ahead of the scan
 in the packed step: a row needs its slot's ``d_conv - 1`` earlier inputs,
@@ -63,10 +67,20 @@ TILE = 512
 def ssd_full(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
              c: jax.Array, d: jax.Array, chunk: int = 256) -> jax.Array:
     """``x [B, S, H, P]``, ``dt [B, S, H]`` (positive), ``a [H]``
-    (negative), ``b, c [B, S, N]``, ``d [H]`` at positions ``0..S-1`` ->
-    ``y [B, S, H, P]`` float32."""
+    (negative), ``b, c [B, S, N]`` (one group) or ``[B, S, G, N]``, ``d
+    [H]`` at positions ``0..S-1`` -> ``y [B, S, H, P]`` float32."""
     bsz, s, h, p = x.shape
     n = b.shape[-1]
+    groups = 1 if b.ndim == 3 else b.shape[2]
+    if groups > 1:
+        # a group is the one-group scan over its own heads
+        x, dt = (v.reshape(v.shape[:2] + (groups, h // groups)
+                           + v.shape[3:]) for v in (x, dt))
+        y = jax.vmap(functools.partial(ssd_full, chunk=chunk),
+                     in_axes=(2, 2, 0, 2, 2, 0), out_axes=2)(
+            x, dt, a.reshape(groups, -1), b, c, d.reshape(groups, -1))
+        return y.reshape(bsz, s, h, p)
+    b, c = (v.reshape(bsz, s, n) for v in (b, c))
     size = min(chunk, s)
     pad = -s % size
     x, dt, b, c = (jnp.pad(v.astype(jnp.float32),
@@ -231,15 +245,17 @@ def _ssd_kernel(layer_ref, count_ref, slot_ref, start_ref, rows_ref,
                 s_out_ref, *, tile: int):
     """Grid step ``k``: segment ``k``'s rows against its slot's state.
     ``a_ref, u_ref [T, C]`` the rows' decays and ``dt x`` by channel,
-    ``bt_ref, ct_ref [N, T]`` their ``B`` and ``C`` by column, ``s_in_ref``
-    and ``s_out_ref [1, 1, N, C]`` the slot's state of this layer (one
-    array, aliased), ``y_ref [T, C]`` every row's read-out, resident for
-    the whole grid."""
+    ``bt_ref, ct_ref [G * N, T]`` their ``B`` and ``C`` by column, group
+    ``g``'s in rows ``g N .. (g + 1) N - 1``, ``s_in_ref`` and ``s_out_ref
+    [1, 1, N, C]`` the slot's state of this layer (one array, aliased),
+    ``y_ref [T, C]`` every row's read-out, resident for the whole grid.
+    Tile ``j`` of the channels is of group ``j * tile // (C / G)``."""
     from jax.experimental import pallas as pl
 
     k = pl.program_id(0)
     n, chans = s_out_ref.shape[2:]
     steps = a_ref.shape[0]
+    groups = bt_ref.shape[0] // n
 
     @pl.when(k == 0)
     def _():
@@ -257,11 +273,17 @@ def _ssd_kernel(layer_ref, count_ref, slot_ref, start_ref, rows_ref,
 
         def apply(t, src_ref, fresh):
             mine = column == t
-            b_t = jnp.sum(jnp.where(mine, bt_ref[...], 0.0), axis=1,
-                          keepdims=True)                          # [N, 1]
-            c_t = jnp.sum(jnp.where(mine, ct_ref[...], 0.0), axis=1,
-                          keepdims=True)
+
+            def of_row(ref, g):
+                # the row's column of group g, a sublane vector [N, 1]
+                rows = ref[...] if groups == 1 else ref[pl.ds(g * n, n), :]
+                return jnp.sum(jnp.where(mine, rows, 0.0), axis=1,
+                               keepdims=True)
+
             for j in range(chans // tile):
+                if j * tile * groups % chans == 0:
+                    g = j * tile * groups // chans
+                    b_t, c_t = of_row(bt_ref, g), of_row(ct_ref, g)
                 cols = pl.ds(j * tile, tile)
                 s = src_ref[0, 0, :, cols]
                 if fresh is not None:
@@ -281,8 +303,9 @@ def _ssd_kernel(layer_ref, count_ref, slot_ref, start_ref, rows_ref,
         jax.lax.fori_loop(1, rows, later, 0)
 
 
-def _state_tile(chans: int) -> int:
-    return TILE if chans % TILE == 0 else 128
+def _state_tile(chans: int, groups: int = 1) -> int:
+    """The kernel's tile of the channels: whole tiles a group."""
+    return TILE if chans // groups % TILE == 0 else 128
 
 
 def _ssd_update_pallas(a, u, bt, ct, ssm, layer, seg: StepSegments,
@@ -291,7 +314,8 @@ def _ssd_update_pallas(a, u, bt, ct, ssm, layer, seg: StepSegments,
     from jax.experimental.pallas import tpu as pltpu
 
     t, chans = a.shape
-    n = bt.shape[0]
+    n = ssm.shape[2]
+    rows = bt.shape[0]                                  # groups * n
     k = seg.slot.shape[0]
 
     def whole(shape):
@@ -304,11 +328,12 @@ def _ssd_update_pallas(a, u, bt, ct, ssm, layer, seg: StepSegments,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6, grid=(k,),
-        in_specs=[whole((t, chans)), whole((t, chans)), whole((n, t)),
-                  whole((n, t)), of_slot()],
+        in_specs=[whole((t, chans)), whole((t, chans)), whole((rows, t)),
+                  whole((rows, t)), of_slot()],
         out_specs=[whole((t, chans)), of_slot()])
     y, ssm = pl.pallas_call(
-        functools.partial(_ssd_kernel, tile=_state_tile(chans)),
+        functools.partial(_ssd_kernel,
+                          tile=_state_tile(chans, rows // n)),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((t, chans), jnp.float32),
                    jax.ShapeDtypeStruct(ssm.shape, ssm.dtype)],
@@ -323,8 +348,17 @@ def _ssd_update_pallas(a, u, bt, ct, ssm, layer, seg: StepSegments,
 
 def _ssd_update_xla(a, u, bt, ct, ssm, layer, seg: StepSegments):
     """The kernel's walk in XLA: gather the segments' states, apply the
-    rows in order, scatter the states back."""
+    rows in order, scatter the states back. ``bt, ct [G * N, T]`` as the
+    kernel's."""
     k, slots = seg.slot.shape[0], ssm.shape[1]
+    n, chans = ssm.shape[2:]
+    groups = bt.shape[0] // n
+
+    def by_channel(v):
+        """A row's ``[G * N]`` as ``[N, 1]`` (one group) or ``[N, C]``."""
+        if groups == 1:
+            return v[:, None]
+        return jnp.repeat(v.reshape(groups, n).T, chans // groups, axis=1)
     states = jax.lax.dynamic_index_in_dim(ssm, layer, 0, False)[
         jnp.minimum(seg.scatter_slot, slots - 1)]              # [K, N, C]
     states = jnp.where((seg.zero == 1)[:, None, None], 0.0, states)
@@ -332,9 +366,9 @@ def _ssd_update_xla(a, u, bt, ct, ssm, layer, seg: StepSegments):
     def row(states, r):
         a_t, u_t, b_t, c_t, own = r
         s = states[jnp.minimum(own, k - 1)]
-        s = jnp.where(own < k, a_t[None, :] * s + b_t[:, None] * u_t[None, :],
-                      s)
-        y = jnp.where(own < k, jnp.sum(c_t[:, None] * s, axis=0), 0.0)
+        s = jnp.where(own < k,
+                      a_t[None, :] * s + by_channel(b_t) * u_t[None, :], s)
+        y = jnp.where(own < k, jnp.sum(by_channel(c_t) * s, axis=0), 0.0)
         return states.at[own].set(s, mode="drop"), y
 
     states, y = jax.lax.scan(row, states, (a, u, bt.T, ct.T, seg.segment))
@@ -342,15 +376,16 @@ def _ssd_update_xla(a, u, bt, ct, ssm, layer, seg: StepSegments):
 
 
 def ssd_packed_impl(d_state: int, channels: int,
-                    force_pallas=None) -> str:
-    """What :func:`ssd_packed` runs for a state ``[d_state, channels]`` on
-    the default backend: ``"pallas"`` (the compiled kernel),
-    ``"pallas-interpret"`` (the kernel, forced, off the TPU) or ``"xla"``
-    (the gather, scan and scatter). The kernel wants the channels on whole
-    lanes and the state's rows on whole sublanes."""
+                    force_pallas=None, groups: int = 1) -> str:
+    """What :func:`ssd_packed` runs for a state ``[d_state, channels]`` of
+    ``groups`` groups on the default backend: ``"pallas"`` (the compiled
+    kernel), ``"pallas-interpret"`` (the kernel, forced, off the TPU) or
+    ``"xla"`` (the gather, scan and scatter). The kernel wants a group's
+    channels on whole lanes and the state's rows on whole sublanes."""
     if force_pallas is False:
         return "xla"
-    tiles = channels % 128 == 0 and d_state % 8 == 0
+    tiles = (channels % groups == 0 and channels // groups % 128 == 0
+             and d_state % 8 == 0)
     if not on_tpu():
         return "pallas-interpret" if force_pallas and tiles else "xla"
     if tiles:
@@ -365,16 +400,18 @@ def ssd_packed(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                c: jax.Array, d: jax.Array, ssm: jax.Array, layer,
                seg: StepSegments, force_pallas=None):
     """One packed step of one layer. ``x [T, H, P]``, ``dt [T, H]``
-    (positive), ``a [H]`` (negative), ``b, c [T, N]``, ``d [H]``; ``ssm
-    [L, J, N, H * P]`` float32, every layer's per-slot states, read and
-    written at ``layer``; ``seg`` the step's segments. Returns ``(y [T, H,
-    P] float32, ssm)``; a pad row's ``y`` is zero."""
+    (positive), ``a [H]`` (negative), ``b, c [T, N]`` (one group) or ``[T,
+    G, N]``, ``d [H]``; ``ssm [L, J, N, H * P]`` float32, every layer's
+    per-slot states, read and written at ``layer``; ``seg`` the step's
+    segments. Returns ``(y [T, H, P] float32, ssm)``; a pad row's ``y`` is
+    zero."""
     t, h, p = x.shape
     xf, dt = x.astype(jnp.float32), dt.astype(jnp.float32)
     decay = jnp.repeat(jnp.exp(dt * a.astype(jnp.float32)), p, axis=1)
     u = (dt[:, :, None] * xf).reshape(t, h * p)
-    bt, ct = b.astype(jnp.float32).T, c.astype(jnp.float32).T
-    impl = ssd_packed_impl(b.shape[-1], h * p, force_pallas)
+    groups = 1 if b.ndim == 2 else b.shape[1]
+    bt, ct = (v.astype(jnp.float32).reshape(t, -1).T for v in (b, c))
+    impl = ssd_packed_impl(b.shape[-1], h * p, force_pallas, groups)
     if impl == "xla":
         y, ssm = _ssd_update_xla(decay, u, bt, ct, ssm, layer, seg)
     else:

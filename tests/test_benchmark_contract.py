@@ -198,3 +198,53 @@ def test_the_evabyte_cell_is_sized_by_its_cache_kind():
     assert chk["prompt_tokens"] // config["window_size"] == 2
     assert ((chk["prompt_tokens"] + chk["decode_steps"])
             <= serve["max_blocks_per_seq"] * serve["block_size"])
+
+
+def test_the_nemotron_cell_holds_every_slot_at_its_longest():
+    """The mix's longest request holds the blocks the configuration says,
+    a slot's states are the bytes it says, and the cell lists its own
+    four metrics and none whose work function reads another family's
+    keys."""
+    from neuronx_distributed_tpu.models import nemotron_h as nh
+
+    name = "nemotron-3-super.serve-reasoning"
+    cell = harness.by_name(MANIFEST["workloads"], name, "workload")
+    assert (cell["traffic"], cell["chips"]) == ("offline-reasoning-mid", 1)
+    config = harness.read_json(os.path.join(
+        BENCH, "configs", "nemotron-3-super-120b-a12b.json"))
+    traffic = harness.read_json(harness.data_file("traffic",
+                                                  cell["traffic"]))
+    serve = config["serve"]
+    longest = (traffic["prompt_tokens"]["max"]
+               + traffic["answer_tokens"]["max"])
+    assert longest == 12288 == (serve["max_blocks_per_seq"]
+                                * serve["block_size"])
+    assert serve["num_blocks"] == (serve["max_slots"]
+                                   * serve["max_blocks_per_seq"])
+    cfg = nh.NemotronHConfig.from_published(
+        {k: config[k] for k in nh.PUBLISHED_KEYS}, num_experts=512,
+        experts_held=(0, config["n_routed_experts"]))
+    assert cfg == nh.NemotronHConfig(vocab_size=32768,
+                                     experts_held=(0, 128))
+    kind = cfg.serving_family().cache_kind
+    state, tail = (sum(leaf.slot_bytes(2) for leaf in kind.leaves
+                       if leaf.counted_as == what)
+                   for what in ("state", "tail"))
+    assert (state, tail) == (5 * 128 * 8192 * 4, 5 * 3 * 10240 * 2)
+    aot = config["assumed"]["serve_aot_gib"]
+    gib = 2.0 ** 30
+    assert abs(serve["max_slots"] * state / gib - aot["ssm_states"]) < 0.01
+    assert abs(serve["num_blocks"] * 128 * 2 * 128 * 2 * 2 / gib
+               - aot["kv_pool"]) < 0.01
+    listed = {m["name"] for m in MANIFEST["per_layer"]
+              if name in m.get("workloads", ())}
+    assert {"latent_moe_roofline", "grouped_ssd_state_roofline",
+            "latent_proj_share_pct.batch", "moe_experts_idle_pct.batch",
+            "moe_held_pct.batch", "moe_dropped_pct.batch",
+            "ssm_state_share_pct.batch", "state_bytes_held_pct.batch",
+            "paged_attn_share_pct.batch", "unscoped_share_pct.batch"
+            } <= listed and len(listed) == 35
+    assert not listed & {"ssd_state_roofline", "moe_experts_roofline",
+                         "paged_attention_roofline", "kda_state_roofline"}
+    assert name in E2E["serve_tok_s"]["workloads"]
+    assert len(MANIFEST["workloads"]) == 14

@@ -1706,6 +1706,100 @@ def test_hybrid_expert_state_pool_step_at_the_published_widths(
         assert f"nxd.{scope}" in text, scope
 
 
+# The cell nemotron-3-super.serve-reasoning: 128 rows, 128 slots; five
+# Mamba-2 layers of 128 heads in 8 groups over float32 states [5, 128, 128,
+# 8192] and tails [5, 3, 128, 10240], one attention layer of 32 heads over 2
+# K/V heads of 128; five LatentMoE layers: 128 of 512 squared-ReLU experts
+# of 2,688 in a latent of 1,024 beside a shared expert of 5,376; a quarter
+# of the vocabulary, the head untied.
+
+def test_nemotron_state_pool_step_at_the_published_widths(
+        chip, topo, on_one_chip, monkeypatch):
+    """The packed step of Nemotron-3-Super's configuration file: it
+    compiles for the chip with both kernels in it (the scan's state
+    update at a ``[128, 8192]`` state whose sixteen tiles a row read
+    ``B`` and ``C`` of eight groups), holds what the configuration says
+    it holds (``assumed.serve_aot_gib`` is this analysis), hands the
+    pool, the states and the tails back in the buffers they came in, and
+    the router, the latent pair, the bank and the shared expert have
+    their scopes."""
+    import re
+
+    from neuronx_distributed_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "on_tpu", lambda: True)
+    config, models = _cell_config("nemotron-3-super-120b-a12b", None)
+    assert sorted(config["reduced"]) == [
+        "hybrid_override_pattern", "n_routed_experts",
+        "num_hidden_layers", "num_nextn_predict_layers", "vocab_size"]
+    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
+                                                         models)
+    s = config["serve"]
+    slots, blocks = s["max_slots"], s["num_blocks"]
+    assert (slots, blocks, tokens) == (128, 12288, 128)
+    assert cache.k.shape == (1, blocks, 128, 2, 128) == cache.v.shape
+    assert cache.states["ssm"].shape == (5, slots, 128, 8192)
+    assert cache.states["ssm"].dtype == jnp.float32
+    assert cache.states["conv"].shape == (5, 3, slots, 10240)
+    assert cache.moe_counts.shape == (5,)
+    assert cfg.runs() == (("mamba2_moe", 0, 3), ("mamba2", 0, 1),
+                          ("full_moe", 0, 1), ("mamba2_moe", 3, 1))
+    tree = params["params"]["model"]
+    paired = tree["layers_mamba2_moe"]["layer"]
+    assert paired["attn"]["in_proj"]["kernel"].shape == (4, 4096, 18560)
+    assert paired["attn"]["conv_kernel"].shape == (4, 10240, 4)
+    assert paired["attn"]["norm"]["scale"].shape == (4, 8192)
+    assert paired["moe"]["router"]["kernel"].shape == (4, 4096, 512)
+    assert paired["moe"]["router"]["bias"].shape == (4, 512)
+    assert paired["moe"]["latent_in"].shape == (4, 4096, 1024)
+    assert paired["moe"]["latent_out"].shape == (4, 1024, 4096)
+    assert paired["moe"]["experts"]["up"].shape == (4, 128, 1024, 2688)
+    assert paired["moe"]["experts"]["down"].shape == (4, 128, 2688, 1024)
+    assert "gate" not in paired["moe"]["experts"]
+    assert paired["moe"]["shared"]["up_kernel"].shape == (4, 4096, 5376)
+    assert set(tree["layers_mamba2"]["layer"]) == {"attn", "input_norm"}
+    full = tree["layers_full_moe"]["layer"]
+    assert full["attn"]["qkv"]["k_kernel"].shape == (1, 4096, 256)
+    assert full["moe"]["experts"]["up"].shape == (1, 128, 1024, 2688)
+    assert tree["embed"]["embedding"].shape == (32768, 4096)
+    assert params["params"]["lm_head"]["kernel"].shape == (4096, 32768)
+
+    compiled = _packed_step(chip, cfg, forward, params, cache, tokens)
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"paged_attention",
+                                               "ssd_state_update"}
+    gib = 2.0 ** 30
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) / gib
+    leaves = jax.tree_util.tree_leaves(params)
+    assert sum(x.size for x in leaves) == 4_648_163_712
+    weights = sum(x.size * x.dtype.itemsize for x in leaves)  # 8.658 GiB
+    aot = config["assumed"]["serve_aot_gib"]
+    assert abs(weights / gib - aot["weights"]) < 0.01
+    assert abs(mem.temp_size_in_bytes / gib - aot["temporaries"]) < 0.05
+    assert abs(held - aot["total"]) < 0.05, (held, mem)
+    assert held >= 0.80 * 15.75                      # the file's share
+    assert mem.temp_size_in_bytes < slots * 128 * 8192 * 4    # a layer's
+
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape in (f"1,{blocks},128,2,128", f"5,{slots},128,8192",
+                     f"5,3,{slots},10240")]
+    assert len(stacks) == 4 and set(stacks) <= aliased, (stacks, header)
+
+    total, differ, kernels = scope_disagreements(text)
+    assert total > 0 and kernels == {"attn.kernel", "attn.state"}
+    top = [d for d in differ if d[1].split(".")[0] != d[2].split(".")[0]]
+    assert sum(d[3] for d in top) <= 0.02 * total, top
+    for scope in ("ffn.router", "ffn.latent", "ffn.experts", "ffn.shared",
+                  "attn.state", "attn.conv", "attn.proj"):
+        assert f"nxd.{scope}" in text, scope
+
+
 # -- the engine's own packed step: one deep in flight -------------------------
 # The CPU tests never donate, so only a compile for the chip shows what the
 # step's operands are there: the pool donated and written in place, the

@@ -40,19 +40,27 @@ SERVING = {"llama": "tiny-mistral-serve", "mixtral": "tiny-mixtral",
            "laguna": "tiny-laguna",
            "mimo_v2_flash": "tiny-mimo-v2-flash",
            "solar_open2": "tiny-solar-open2",
-           "longcat_flash": "tiny-longcat-flash"}
+           "longcat_flash": "tiny-longcat-flash",
+           "granite_moe_hybrid": "tiny-granite-moe-hybrid",
+           "nemotron_h": "tiny-nemotron-h"}
 #: the children a family's step must open, and no other family's may
 OWN = {"attn.select": {"minicpm_sala"},
-       "attn.state": {"minicpm_sala", "granite_hybrid", "solar_open2"},
-       "attn.conv": {"granite_hybrid", "solar_open2"},
+       "attn.state": {"minicpm_sala", "granite_hybrid", "solar_open2",
+                      "granite_moe_hybrid", "nemotron_h"},
+       "attn.conv": {"granite_hybrid", "solar_open2", "granite_moe_hybrid",
+                     "nemotron_h"},
        "attn.summarise": {"evabyte"},
        "attn.kernel.full": {"laguna", "mimo_v2_flash"},
        "attn.kernel.window": {"laguna", "mimo_v2_flash"},
        "ffn.experts": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
-                       "solar_open2", "longcat_flash"},
+                       "solar_open2", "longcat_flash", "granite_moe_hybrid",
+                       "nemotron_h"},
        "ffn.router": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
-                      "solar_open2", "longcat_flash"},
-       "ffn.shared": {"glm_moe_lite", "laguna", "solar_open2"}}
+                      "solar_open2", "longcat_flash", "granite_moe_hybrid",
+                      "nemotron_h"},
+       "ffn.shared": {"glm_moe_lite", "laguna", "solar_open2",
+                      "granite_moe_hybrid", "nemotron_h"},
+       "ffn.latent": {"nemotron_h"}}
 #: the operations that carry a step's device time
 HEAVY = ("stablehlo.dot_general", "stablehlo.custom_call",
          "stablehlo.scatter", "stablehlo.gather", "stablehlo.sort",
@@ -386,10 +394,23 @@ LOWERED_AT_PR_54 = {
 }
 
 
+#: the state-pool family with routed experts after both kinds of layer,
+#: as PR 58's tree lowers it (recorded there: it had no recorded text),
+#: and the family of one-block layers, new with PR 59, as that PR's tree
+#: lowers it
+LOWERED_AT_PR_59 = {
+    "granite_moe_hybrid":
+        "fd01532d7c67207eb447052b85fbdba2b9a807b6e8fb018363667dbe8ec9e201",
+    "nemotron_h":
+        "0d3d20b16ed2bed8375fa7567eed7d78f5a536e3bb78c56df27e19a3a269ddf9",
+}
+
+
 @pytest.mark.parametrize("which", list(LOWERED_AT_PR_38)
                          + list(LOWERED_AT_PR_39) + list(LOWERED_AT_PR_42)
                          + list(LOWERED_AT_PR_43) + list(LOWERED_AT_PR_45)
-                         + list(LOWERED_AT_PR_48) + list(LOWERED_AT_PR_54))
+                         + list(LOWERED_AT_PR_48) + list(LOWERED_AT_PR_54)
+                         + list(LOWERED_AT_PR_59))
 def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     """PR 39 changed the latent kernel and its walk alone: the packed
     steps of the five families that run the shared helpers of
@@ -416,13 +437,20 @@ def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     GLM's among them (its tile is one slab), and the second latent
     family's step, the one without a recorded text, is recorded as that
     tree and its parent lower it (off the chip a latent step gathers:
-    the kernel's own text is ``tests/test_chip_compile.py``'s). A PR
-    that changes one of these programs on purpose records its new hash
-    here."""
+    the kernel's own text is ``tests/test_chip_compile.py``'s). PR 59
+    gave the state-space scan groups of ``B`` and ``C`` and its gated
+    norm groups, the expert bank and the shared expert an ungated form,
+    ``MoE`` a latent pair and a count of the held experts a step hits,
+    and the decoder layer blocks that may be absent, each off where a
+    family does not ask for it: the ten are the text they were, both
+    Granite steps (the dense one, and the one with routed experts as its
+    parent's tree lowers it, recorded with that PR) and GLM's and
+    Laguna's gated banks among them. A PR that changes one of these
+    programs on purpose records its new hash here."""
     import hashlib
 
     text = _stripped(_lowered(which))
     assert hashlib.sha256(text.encode()).hexdigest() == {
         **LOWERED_AT_PR_38, **LOWERED_AT_PR_39, **LOWERED_AT_PR_42,
         **LOWERED_AT_PR_43, **LOWERED_AT_PR_45, **LOWERED_AT_PR_48,
-        **LOWERED_AT_PR_54}[which]
+        **LOWERED_AT_PR_54, **LOWERED_AT_PR_59}[which]

@@ -12,6 +12,7 @@ blocks of 16. The weights are seeded with norm multipliers of order one
 and the scan's own parameters by the module's Mamba-2 initialisers.
 """
 
+import functools
 import os
 import sys
 
@@ -620,3 +621,117 @@ def test_the_attention_scale_is_the_configs():
     assert cfg.kind_config("mamba2").residual_scale == 0.22
     with pytest.raises(ValueError, match="layer_types"):
         gh.tiny_config(layer_types=("mamba",))
+
+
+# -- groups of B and C (models/nemotron_h.py: eight) -------------------------
+
+def _grouped_recurrence(x, dt, a, b, c, d, state):
+    """Token by token in NumPy float64, head ``j`` reading group ``j //
+    (heads / groups)`` of ``b, c [S, G, N]``; ``state [heads, P, N]``."""
+    each = x.shape[1] // b.shape[1]
+    s, ys = state.copy(), []
+    for t in range(x.shape[0]):
+        b_h, c_h = (np.repeat(v[t], each, axis=0) for v in (b, c))
+        s = (np.exp(dt[t] * a)[:, None, None] * s
+             + (dt[t][:, None] * x[t])[:, :, None] * b_h[:, None, :])
+        ys.append(np.einsum("hpn,hn->hp", s, c_h) + d[:, None] * x[t])
+    return np.stack(ys), s
+
+
+@pytest.mark.parametrize("impl", ["full", "xla", "pallas-interpret"])
+@pytest.mark.parametrize("groups", [1, 2, 8])
+def test_the_scan_reads_b_and_c_by_group(groups, impl):
+    """Sixteen heads of 64 (a group of eight is two heads, one 128-channel
+    tile of the kernel) over a state of 8: the chunked scan over a whole
+    sequence, and the packed step (a chunk of five rows from position 0 in
+    slot 2, a decode row of slot 0 on the state it held, a pad row), each
+    against the token-by-token recurrence; one group handed as ``[.., 1,
+    N]`` is bit for bit the scan without groups."""
+    heads, width, n, slots = 16, 64, 8, 3
+    rng = np.random.default_rng(groups)
+    a, d = -np.exp(rng.normal(size=heads)), rng.normal(size=heads)
+    f32 = functools.partial(jnp.asarray, dtype=jnp.float32)
+
+    def inputs(length):
+        return (rng.normal(size=(length, heads, width)),
+                np.log1p(np.exp(rng.normal(size=(length, heads)))),
+                rng.normal(size=(length, groups, n)),
+                rng.normal(size=(length, groups, n)))
+
+    zero = np.zeros((heads, width, n))
+    if impl == "full":
+        x, dt, b, c = inputs(13)
+        want, _ = _grouped_recurrence(x, dt, a, b, c, d, zero)
+        with jax.default_matmul_precision("highest"):
+            got = ssd.ssd_full(f32(x)[None], f32(dt)[None], f32(a),
+                               f32(b)[None], f32(c)[None], f32(d), chunk=5)
+            flat = ssd.ssd_full(f32(x)[None], f32(dt)[None], f32(a),
+                                f32(b[:, 0])[None], f32(c[:, 0])[None],
+                                f32(d), chunk=5)
+        np.testing.assert_allclose(np.asarray(got[0]), want, atol=5e-5)
+        if groups == 1:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(flat))
+        else:
+            assert np.abs(np.asarray(got) - np.asarray(flat)).max() > 0.1
+        return
+    assert ssd.ssd_packed_impl(n, heads * width, impl != "xla",
+                               groups) == impl
+    x, dt, b, c = inputs(7)
+    held = rng.normal(size=(slots, heads, width, n))
+    # the cache's layout: [layers, slots, N, heads * P]
+    ssm = f32(held.transpose(0, 3, 1, 2).reshape(1, slots, n, -1))
+    slot_ids = np.array([0, 2, 2, 2, 2, 2, slots], np.int32)
+    positions = np.array([9, 0, 1, 2, 3, 4, PAD_POSITION], np.int32)
+    seg = ssd.step_segments(jnp.asarray(slot_ids), jnp.asarray(positions),
+                            slots)
+
+    def packed(b, c):
+        return ssd.ssd_packed(f32(x), f32(dt), f32(a), f32(b), f32(c),
+                              f32(d), ssm, 0, seg,
+                              force_pallas=impl != "xla")
+
+    y, new = packed(b, c)
+    decode, s0 = _grouped_recurrence(x[:1], dt[:1], a, b[:1], c[:1], d,
+                                     held[0])
+    chunk, s2 = _grouped_recurrence(x[1:6], dt[1:6], a, b[1:6], c[1:6], d,
+                                    zero)
+    np.testing.assert_allclose(np.asarray(y[:1]), decode, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(y[1:6]), chunk, atol=2e-4)
+    assert (np.asarray(y[6]) == 0).all()
+    new = np.asarray(new)[0].reshape(slots, n, heads, width).transpose(
+        0, 2, 3, 1)
+    np.testing.assert_allclose(new[0], s0, atol=2e-4)
+    np.testing.assert_allclose(new[2], s2, atol=2e-4)
+    np.testing.assert_array_equal(new[1], np.asarray(held[1], np.float32))
+    if groups == 1:
+        flat_y, flat_new = packed(b[:, 0], c[:, 0])
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(flat_y))
+        np.testing.assert_array_equal(new[0], np.asarray(flat_new)[0][
+            0].reshape(n, heads, width).transpose(1, 2, 0))
+
+
+def test_the_convolution_runs_over_every_groups_channels():
+    """``x | B | C`` of eight groups is ``d_inner + 2 x 8 x N`` channels:
+    a chunk across a step's boundary equals the whole sequence's."""
+    heads, width, n, groups, taps, slots = 4, 8, 4, 8, 4, 2
+    chans = heads * width + 2 * groups * n
+    rng = np.random.default_rng(3)
+    weight = jnp.asarray(rng.uniform(-.5, .5, (chans, taps)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=chans) * .1, jnp.float32)
+    xbc = jnp.asarray(rng.normal(size=(9, chans)), jnp.float32)
+    padded = jnp.pad(xbc, ((taps - 1, 0), (0, 0)))
+    want = jax.nn.silu(bias + sum(weight[:, k] * padded[k:k + 9]
+                                  for k in range(taps)))
+    tails = jnp.asarray(rng.normal(size=(1, taps - 1, slots, chans)),
+                        jnp.float32)
+    got = []
+    for lo, hi in ((0, 5), (5, 9)):
+        seg = ssd.step_segments(jnp.full((hi - lo,), 1, jnp.int32),
+                                jnp.arange(lo, hi, dtype=jnp.int32), slots)
+        act, tails = ssd.causal_conv_step(xbc[lo:hi], tails, 0, weight,
+                                          bias, seg)
+        got.append(np.asarray(act))
+    np.testing.assert_allclose(np.concatenate(got), np.asarray(want),
+                               atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(tails[0, :, 1]),
+                                  np.asarray(xbc[6:9]))

@@ -959,6 +959,10 @@ DECLARED["solar_open2"] = {**DECLARED["granite_hybrid"], **_MOE,
 #: Granite with routed experts (granite-4.0-h-small) declares what Solar
 #: does: the dense models' and the routed assignments
 DECLARED["granite_moe_hybrid"] = DECLARED["solar_open2"]
+#: the family of one-block layers (models/nemotron_h.py): Granite's with
+#: routed experts, and the held experts a step hit and left idle
+DECLARED["nemotron_h"] = {**DECLARED["granite_moe_hybrid"],
+                          "nxd_moe_experts_hit_total": ("hit", "idle")}
 #: the second latent family: GLM's, and what a router that also scores
 #: identity experts over a share of the real ones counts
 DECLARED["longcat_flash"] = {**DECLARED["glm_moe_lite"],
@@ -969,6 +973,7 @@ ON_DEVICE = {"minicpm_sala": {"counts": 10}, "glm_moe_lite": {"moe_counts": 2},
              "laguna": {"moe_counts": 3}, "mimo_v2": {"moe_counts": 3},
              "solar_open2": {"moe_counts": 3},
              "granite_moe_hybrid": {"moe_counts": 3},
+             "nemotron_h": {"moe_counts": 5},
              "longcat_flash": {"moe_counts": 4}}
 
 
@@ -1020,6 +1025,12 @@ def test_a_family_declares_its_steps_counters(which):
         assert leaves[0].read(np.array([5, 2, 4])) == {
             "nxd_moe_assignments_total": [5, 2],
             "nxd_moe_held_total": [7, 4]}
+    if which == "nemotron_h":
+        # [kept, dropped, elsewhere, hit, idle]: assignments, then experts
+        assert leaves[0].read(np.array([5, 2, 4, 3, 1])) == {
+            "nxd_moe_assignments_total": [5, 2],
+            "nxd_moe_held_total": [7, 4],
+            "nxd_moe_experts_hit_total": [3, 1]}
     if which == "longcat_flash":
         # [kept, dropped, elsewhere, identity]: the first three are of the
         # real experts, and together the routed choices
