@@ -26,6 +26,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from ..modules import attention as attn_mod
 from ..modules import glu
@@ -38,8 +39,7 @@ from ..parallel import mappings
 from ..parallel import mesh as ps
 
 from ..lora import LoraConfig
-from ..utils.remat import (DEFAULT_REMAT_POLICY, resolve_remat_policy,
-                           validate_remat_policy)
+from ..utils.remat import resolve_remat_policy, validate_remat_policy
 
 
 def _lora_kw(cfg: "LlamaConfig", name: str) -> dict:
@@ -112,11 +112,13 @@ class LlamaConfig:
     sequence_parallel: bool = False
     remat: bool = False
     # what the rematerialised layer body keeps across fwd→bwd (names and
-    # bytes in utils/remat.py). The default keeps the flash kernel's own
-    # pair, output and log-sum-exp, wherever a flash path ran: its backward
-    # takes them as residuals, so the recomputed forward holds no attention
-    # kernel. "nothing" recomputes that too and gives the bytes back.
-    remat_policy: str = DEFAULT_REMAT_POLICY
+    # bytes in utils/remat.py). None keeps the flash kernel's own pair,
+    # output and log-sum-exp, wherever a flash path ran (its backward takes
+    # them as residuals, so the recomputed forward holds no attention
+    # kernel), and gate's and up's products too where the train step finds
+    # a chip has the bytes (``make_train_step``). A name pins that policy:
+    # "nothing" recomputes everything and gives the bytes back.
+    remat_policy: Optional[str] = None
     scan_layers: bool = True
     use_flash_attention: bool = False
     # force the Pallas flash kernel (interpret mode on CPU) instead of the
@@ -344,6 +346,31 @@ class LlamaConfig:
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    def glu_products_bytes(self, tokens: int, tp: int) -> int:
+        """Bytes of gate's and up's products over ``tokens`` rows in every
+        layer, on one of ``tp`` ranks: what ``save_attention_and_glu``
+        keeps over ``save_attention`` (``utils/remat.py``)."""
+        return (self.num_layers * 2 * tokens * (self.intermediate_size // tp)
+                * jnp.dtype(self.dtype).itemsize)
+
+    def logits_bytes(self, batch: int, seq: int, tp: int) -> int:
+        """Bytes of the logits the loss holds at once over ``batch`` rows
+        of ``seq`` tokens on one of ``tp`` ranks: the head's product in
+        the compute dtype and the float32 copy the cross-entropy reads;
+        a chunk's where ``loss_chunk`` streams them."""
+        seq = min(seq, self.loss_chunk or seq)
+        return (batch * seq * -(-self.vocab_size // tp)
+                * (jnp.dtype(self.dtype).itemsize + 4))
+
+    def plain_layers(self) -> bool:
+        """Every layer is this class's own: attention, norm, dense
+        feed-forward. A family that brings its own layer, blocks, mixer or
+        feed-forward holds experts' buffers and states per layer that the
+        two methods above do not count."""
+        return all(getattr(type(self), hook) is getattr(LlamaConfig, hook)
+                   for hook in ("decoder_layer", "layer_blocks",
+                                "attention", "feed_forward"))
 
     @property
     def v_head_dim_(self) -> int:
@@ -795,6 +822,16 @@ class LlamaAttention(nn.Module):
         return out
 
 
+def _kept_glu(cfg: LlamaConfig, g: jax.Array, u: jax.Array):
+    """Gate's and up's products under the names a rematerialised layer may
+    keep (``utils/remat.py``: ``save_attention_and_glu``). Only a model
+    that sets ``remat`` traces the names: a served model's program holds
+    no trace of them."""
+    if not cfg.remat:
+        return g, u
+    return checkpoint_name(g, "glu_gate"), checkpoint_name(u, "glu_up")
+
+
 class LlamaMLP(nn.Module):
     cfg: LlamaConfig
     # False elides down's exit all-reduce (reduced-sync TP)
@@ -861,7 +898,7 @@ class LlamaMLP(nn.Module):
                       else cm.copy_matmul)
             g, u = matmul(x.astype(cfg.dtype), (gate, up), ps.TP_AXIS, 1,
                           impl=impl, wire=wire)
-            return down(glu.gated(g, u))
+            return down(glu.gated(*_kept_glu(cfg, g, u)))
         if cfg.sequence_parallel:
             x = mappings.gather_from_sequence_parallel_region(
                 x, seq_dim=1, to_model_parallel=True)
@@ -878,7 +915,7 @@ class LlamaMLP(nn.Module):
         if pl._bound_size(ps.TP_AXIS) is None:
             g, u = (ps.with_sharding_constraint(h, None, None, ps.TP_AXIS)
                     for h in (g, u))
-        return down(glu.gated(g, u))
+        return down(glu.gated(*_kept_glu(cfg, g, u)))
 
     def _quantized_call(self, x: jax.Array) -> jax.Array:
         """Weight-quantized (w8a16) gate_up + down: the fused [H, 2, I]

@@ -19,7 +19,9 @@ optimizer around it.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+import math
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -37,6 +39,8 @@ from ..parallel import comm
 from ..parallel import comm_compressed as cc
 from ..parallel import grads as grads_mod
 from ..parallel import mesh as ps
+from ..utils import remat
+from ..utils.device import memory_limit_bytes
 from . import optimizer as opt_mod
 
 
@@ -268,14 +272,84 @@ def _tp_rings_engage(pm: ParallelModel, mesh, batch) -> bool:
     (any size with ``tp_overlap_comm=True``, never with ``False``) and the
     batch's sequence tiles over it. Only tensor x data meshes: pipeline,
     context and expert parallelism keep their GSPMD step."""
-    sizes = dict(mesh.shape)
-    if any(sizes.get(ax, 1) > 1 for ax in (ps.PP_AXIS, ps.CP_AXIS)) \
-            or ps.get_expert_model_parallel_size() > 1:
+    if not _tensor_by_data(mesh):
         return False
     shape = tuple(jnp.shape(batch["input_ids"]))
     return len(shape) == 2 and cm.overlap_engaged_at(
-        pm.config.parallel.tp_overlap_comm, sizes.get(ps.TP_AXIS, 1),
-        shape + (1,), 1, needs_divisible=True)
+        pm.config.parallel.tp_overlap_comm,
+        dict(mesh.shape).get(ps.TP_AXIS, 1), shape + (1,), 1,
+        needs_divisible=True)
+
+
+def _tensor_by_data(mesh) -> bool:
+    """No pipeline, context or expert parallelism on ``mesh``."""
+    sizes = dict(mesh.shape)
+    return not (any(sizes.get(ax, 1) > 1 for ax in (ps.PP_AXIS, ps.CP_AXIS))
+                or ps.get_expert_model_parallel_size() > 1)
+
+
+def _bytes_a_chip(tree, shardings, cast=None) -> int:
+    """Bytes one chip holds of ``tree`` placed as ``shardings``, a tree
+    prefix of it, says; with ``cast``, of the copies in that dtype of the
+    leaves that have another."""
+    def itemsize(x):
+        if cast is None:
+            return x.dtype.itemsize
+        return jnp.dtype(cast).itemsize * (x.dtype != cast)
+
+    return sum(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+        lambda s, sub: sum(math.prod(s.shard_shape(x.shape)) * itemsize(x)
+                           for x in jax.tree_util.tree_leaves(sub)),
+        shardings, tree)))
+
+
+def _record_remat_choice(policy: str, kept_bytes: int) -> None:
+    """The bound step's trace-time decision, once a trace like
+    ``cm._record_decision``: ``nxd_train_remat_kept_bytes{policy}``."""
+    from ..obs.metrics import get_registry
+
+    reg = get_registry()
+    if not reg.enabled:
+        return
+    reg.gauge("nxd_train_remat_kept_bytes",
+              "Bytes of gate's and up's products a chip keeps across "
+              "forward and backward under the remat policy the bound "
+              "train step chose from the chip's memory (set once per "
+              "trace; 0 under save_attention).",
+              labels=("policy",)).labels(policy=policy).set(
+                  kept_bytes if policy == remat.RICH_REMAT_POLICY else 0)
+
+
+def _module_for_step(pm: ParallelModel, mesh, state, state_shardings,
+                     rows: Tuple[int, int], accumulating: bool) -> nn.Module:
+    """``pm.module`` with what its rematerialised layers keep decided for
+    this step (``utils/remat.choose_remat_policy``): a model that
+    checkpoints its layers and names no policy keeps gate's and up's
+    products when a chip has the bytes. Priced from ``rows`` (the batch
+    and sequence one chip's layers see a pass), the model's own widths,
+    the state a chip holds under ``state_shardings``, its gradients (twice
+    where the step sums microbatches' into an accumulator), compute-dtype
+    copies and logits, and the limit of a device this process holds; a
+    policy the model names stays. A model that checkpoints nothing or
+    whose layers the builder cannot price (a family's own:
+    ``LlamaConfig.plain_layers``), and any on a mesh with pipeline,
+    context or expert parallelism, is returned as it is."""
+    cfg = getattr(pm.module, "cfg", None)
+    if not (_tensor_by_data(mesh) and getattr(cfg, "remat", False)
+            and hasattr(cfg, "plain_layers") and cfg.plain_layers()):
+        return pm.module
+    tp = dict(mesh.shape).get(ps.TP_AXIS, 1)
+    kept = cfg.glu_products_bytes(math.prod(rows), tp)
+    params = state.params, state_shardings.params
+    step_bytes = (_bytes_a_chip(state, state_shardings)          # the state
+                  + _bytes_a_chip(*params) * (1 + accumulating)  # gradients
+                  + _bytes_a_chip(*params, cast=cfg.dtype)       # casts
+                  + cfg.logits_bytes(*rows, tp))
+    policy = remat.choose_remat_policy(
+        cfg.remat_policy, kept_bytes=kept, step_bytes=step_bytes,
+        limit_bytes=memory_limit_bytes(mesh.devices.flat))
+    _record_remat_choice(policy, kept)
+    return pm.module.clone(cfg=dataclasses.replace(cfg, remat_policy=policy))
 
 
 def make_train_step(
@@ -416,7 +490,7 @@ def make_train_step(
                     if use_ef and red_axes else None)
         use_ef = use_ef and ef_specs is not None
 
-        def inner(*args):
+        def inner(module, *args):
             p, input_ids, labels = args[:3]
             idx = 3
             rngs_in = None
@@ -437,9 +511,9 @@ def make_train_step(
 
             def local_loss(pp):
                 if rngs_in is not None:
-                    return pm.module.apply(pp, input_ids, labels,
-                                           method="loss", rngs=rngs_in)
-                return pm.module.apply(pp, input_ids, labels, method="loss")
+                    return module.apply(pp, input_ids, labels,
+                                        method="loss", rngs=rngs_in)
+                return module.apply(pp, input_ids, labels, method="loss")
 
             loss, g = jax.value_and_grad(local_loss)(p)
             if use_ef:
@@ -463,27 +537,43 @@ def make_train_step(
         out_specs = (PartitionSpec(), pm.param_specs)
         if use_ef:
             out_specs = out_specs + (ef_specs,)
-        sm_grad = ps.shard_map(inner, mesh, in_specs=tuple(in_specs),
-                               out_specs=out_specs)
 
-        def explicit_grad(params, batch, rngs, err):
+        def explicit_grad(module, params, batch, rngs, err):
             args = [params, batch["input_ids"], batch["labels"]]
             if with_rng:
                 args.append(rngs["dropout"])
             if use_ef:
                 args.append(err)
-            outs = sm_grad(*args)
+            outs = ps.shard_map(
+                functools.partial(inner, module), mesh,
+                in_specs=tuple(in_specs), out_specs=out_specs)(*args)
             if use_ef:
                 return outs
             return outs[0], outs[1], err
 
-    def one_grad(params, batch, rngs=None, err=None):
-        """→ ``(loss, grads, new_err)``; ``err`` passes through untouched
-        on the uncompressed paths (None stays None)."""
-        if explicit_grad is not None and (
+    batch_shardings = NamedSharding(mesh, batch_spec)
+
+    def bound_module(state, batch):
+        """The module the explicit path differentiates where this step
+        takes it (the shapes decide, at trace time), else None. There the
+        axes are bound and a chip's rows are known: what the layers keep
+        follows the chip's bytes."""
+        if explicit_grad is None or not (
                 compression is not None
                 or _tp_rings_engage(pm, mesh, batch)):
-            return explicit_grad(params, batch, rngs, err)
+            return None
+        batch_rows, seq = batch_shardings.shard_shape(
+            jnp.shape(batch["input_ids"]))
+        return _module_for_step(
+            pm, mesh, state, state_shardings,
+            (batch_rows // grad_accum_steps, seq), grad_accum_steps > 1)
+
+    def one_grad(params, batch, rngs=None, err=None, bound=None):
+        """→ ``(loss, grads, new_err)``; ``err`` passes through untouched
+        on the uncompressed paths (None stays None). ``bound``: the module
+        of the explicit path where the step takes it."""
+        if bound is not None:
+            return explicit_grad(bound, params, batch, rngs, err)
         if grad_fn is not None:
             loss, g = grad_fn(params, batch)
             return loss, g, err
@@ -495,7 +585,7 @@ def make_train_step(
             lambda p: loss_fn(pm.module, p, batch))(params)
         return loss, g, err
 
-    def accum_grad(params, batch, rngs=None, err=None):
+    def accum_grad(params, batch, rngs=None, err=None, bound=None):
         a = grad_accum_steps
 
         def slice_mb(x):
@@ -521,7 +611,7 @@ def make_train_step(
                         for k, r in rngs.items()})
             # with compression each microbatch reduce consumes/produces
             # the error-feedback residue through the scan carry
-            loss, g, e = one_grad(params, mb, mb_rngs, e)
+            loss, g, e = one_grad(params, mb, mb_rngs, e, bound)
             gacc = jax.tree_util.tree_map(jnp.add, gacc, g)
             return (loss_sum + loss, gacc, e), None
 
@@ -537,12 +627,10 @@ def make_train_step(
     def step_fn(state: TrainState, batch) -> Tuple[TrainState, dict]:
         rngs = (None if dropout_rng is None else
                 {"dropout": jax.random.fold_in(dropout_rng, state.step)})
-        if grad_accum_steps > 1:
-            loss, grads, new_err = accum_grad(state.params, batch, rngs,
-                                              state.comm_error)
-        else:
-            loss, grads, new_err = one_grad(state.params, batch, rngs,
-                                            state.comm_error)
+        grad = accum_grad if grad_accum_steps > 1 else one_grad
+        loss, grads, new_err = grad(state.params, batch, rngs,
+                                    state.comm_error,
+                                    bound_module(state, batch))
         with device_scope("optimizer"):
             grad_norm = optax.global_norm(grads)
             updates, new_opt = tx.update(grads, state.opt_state,
@@ -585,7 +673,6 @@ def make_train_step(
         return TrainState(step=state.step + 1, params=new_params,
                           opt_state=new_opt, comm_error=new_err), metrics
 
-    batch_shardings = NamedSharding(mesh, batch_spec)
     if scan_steps > 1:
         # run `scan_steps` optimizer steps in ONE dispatch: batch leaves gain
         # a leading scan dim. Keeps host round-trips and dispatch latency

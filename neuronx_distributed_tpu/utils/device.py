@@ -8,6 +8,7 @@ persistent compilation cache live?".
 from __future__ import annotations
 
 import os
+from typing import Optional
 
 
 def on_tpu() -> bool:
@@ -16,6 +17,22 @@ def on_tpu() -> bool:
     import jax
 
     return jax.default_backend() == "tpu"
+
+
+def memory_limit_bytes(devices) -> Optional[int]:
+    """``memory_stats()["bytes_limit"]``, the bytes a device's allocator
+    may hand out, of the first of ``devices`` that this process holds:
+    on several hosts each asks a chip of its own, and chips of one kind
+    answer alike. None where the backend reports none (the CPU) or no
+    device is attached (a described topology, whose client holds none).
+    An attached device that cannot answer raises: a limit that one host
+    read and another did not would trace two programs."""
+    devices = list(devices)
+    held = devices[0].client.local_devices()
+    for device in devices:
+        if device in held:
+            return (device.memory_stats() or {}).get("bytes_limit")
+    return None
 
 
 def place_compile_cache(checkout: str) -> str:

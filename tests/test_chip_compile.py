@@ -15,6 +15,7 @@ xdist worker imports every test file. Keep these compiles in this one
 file for the same reason.
 """
 
+import contextlib
 import functools
 import math
 import re
@@ -885,13 +886,8 @@ _SCOPED_STEPS = {"mistral-7b-serve": 2, "mixtral-8x7b": 2, "evabyte-6.5b": 2,
                  "longcat-flash-chat": None, "mistral-7b": 2}
 
 
-def _matmul_fusions(hlo_text):
-    """``(name, own op_name, heaviest matmul's op_name, its largest
-    operand's elements)`` of every top-level fusion whose body holds a
-    ``dot`` or a ``convolution``, and ``(name, op_name)`` of every
-    top-level custom call (a Mosaic kernel)."""
-    import re
-
+def _computation_bodies(hlo_text):
+    """``{computation: [its lines]}`` of a compiled program's text."""
     bodies, comp = {}, None
     for line in hlo_text.splitlines():
         head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
@@ -899,6 +895,17 @@ def _matmul_fusions(hlo_text):
             comp = bodies.setdefault(head.group(1), [])
         elif comp is not None:
             comp.append(line)
+    return bodies
+
+
+def _matmul_fusions(hlo_text):
+    """``(name, own op_name, heaviest matmul's op_name, its largest
+    operand's elements)`` of every top-level fusion whose body holds a
+    ``dot`` or a ``convolution``, and ``(name, op_name)`` of every
+    top-level custom call (a Mosaic kernel)."""
+    import re
+
+    bodies = _computation_bodies(hlo_text)
     fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.\-]+)", hlo_text))
 
     def op_name(line):
@@ -1037,9 +1044,11 @@ def _cell_train_step(topo, config_name, layers, **model_kw):
     return _TRAIN_STEPS[key]
 
 
-def _scoped_train_step(topo, config, models, **model_kw):
-    """The cell's train step, compiled for the described 2x2;
-    ``model_kw`` replaces fields of the configured model."""
+@contextlib.contextmanager
+def _train_parts(topo, config, models, **model_kw):
+    """``(pm, tx, state, shardings, batch)`` of the cell's train step as
+    shapes placed on the described 2x2, the mesh up while the block
+    runs; ``model_kw`` replaces fields of the configured model."""
     import dataclasses
 
     import neuronx_distributed_tpu as nxd
@@ -1099,10 +1108,19 @@ def _scoped_train_step(topo, config, models, **model_kw):
             comm_error=None)
         ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
                                    sharding=everywhere)
-        return trainer.make_train_step(pm, tx, shardings).lower(
-            state, {"input_ids": ids, "labels": ids}).compile()
+        yield pm, tx, state, shardings, {"input_ids": ids, "labels": ids}
     finally:
         ps.destroy_model_parallel()
+
+
+def _scoped_train_step(topo, config, models, **model_kw):
+    """The cell's train step, compiled for the described 2x2."""
+    from neuronx_distributed_tpu.trainer import trainer
+
+    with _train_parts(topo, config, models, **model_kw) as (
+            pm, tx, state, shardings, batch):
+        return trainer.make_train_step(pm, tx, shardings).lower(
+            state, batch).compile()
 
 
 def scope_disagreements(hlo_text):
@@ -1205,6 +1223,107 @@ def test_train_step_holds_the_flash_output_and_log_sum_exp(train_steps):
     grown = (kept.memory_analysis().temp_size_in_bytes
              - nothing.memory_analysis().temp_size_in_bytes) / layers / mib
     assert grown <= 1.1 * 16.25, grown
+
+
+_GLU_KEPT = "save_attention_and_glu"
+# gate's and up's products of a layer on a chip: 2 x [2, 4096, 3584] bf16
+_GLU_PAIR_MIB = 2 * 2 * 4096 * 3584 * 2 / 2 ** 20
+
+
+def _products_by_computation(hlo_text, result):
+    """``{computation: n}``: the matmuls with a result of shape ``result``
+    that a computation runs, its own and those of the fusions it calls
+    (a ``while`` body is one computation)."""
+    bodies = _computation_bodies(hlo_text)
+    fused = set(re.findall(r"fusion\([^\n]*calls=%([\w.\-]+)", hlo_text))
+    made = re.compile(r"= %s\S* (?:convolution|dot)\(" % re.escape(result))
+    own = {name: sum(bool(made.search(line)) for line in body)
+           for name, body in bodies.items()}
+    found = {}
+    for name, body in bodies.items():
+        if name in fused:
+            continue
+        n = own[name] + sum(
+            own[c] for line in body
+            for c in re.findall(r"fusion\([^\n]*calls=%([\w.\-]+)", line))
+        if n:
+            found[name] = n
+    return found
+
+
+@pytest.mark.parametrize("policy,recomputed", [(None, 2), (_GLU_KEPT, 0)],
+                         ids=["lean", "rich"])
+def test_train_step_recomputes_gate_and_up_only_without_the_bytes(
+        train_steps, policy, recomputed):
+    """The feed-forward's products of width 3,584 (14,336 over tp=4): the
+    forward scan's body runs gate and up; the backward's runs ``down``'s
+    transpose, and gate and up again only where the layer did not keep
+    them. A described device reports no memory limit, so the step that
+    names no policy is the lean one."""
+    text = train_steps(**({"remat_policy": policy} if policy else {})
+                       ).as_text()
+    products = sorted(_products_by_computation(
+        text, "bf16[2,4096,3584]").values())
+    assert products == sorted([2, 1 + recomputed]), products
+
+
+@pytest.mark.parametrize("what", ["carried", "peak"])
+def test_train_step_holds_gate_and_up_products(train_steps, what):
+    """What ``save_attention_and_glu`` keeps over the default: 112 MiB a
+    layer and chip, stacked by the forward scan and carried by the
+    backward's; the program's peak (arguments and temporaries as the
+    compiler lays them out, what it refuses a program by) grows by that
+    and a sixth at two layers (130 MiB a layer; 114 at eleven: AOT, PR
+    61). ``temp_size_in_bytes`` is not that number: it counts what one
+    scan hands the other in both."""
+    layers, mib = _SCOPED_STEPS["mistral-7b"], 2 ** 20
+    lean, rich = train_steps(), train_steps(remat_policy=_GLU_KEPT)
+    if what == "carried":
+        a_layer = (_carried_bytes(rich.as_text())
+                   - _carried_bytes(lean.as_text())) / layers / mib
+        assert 0.9 * _GLU_PAIR_MIB <= a_layer <= 1.1 * _GLU_PAIR_MIB, a_layer
+    else:
+        grown = (rich.memory_analysis().peak_memory_in_bytes
+                 - lean.memory_analysis().peak_memory_in_bytes) / layers / mib
+        assert 0.9 * _GLU_PAIR_MIB <= grown <= 1.2 * _GLU_PAIR_MIB, grown
+
+
+@pytest.mark.parametrize("layers,accum,chosen", [
+    (11, 1, _GLU_KEPT), (12, 1, "save_attention"), (13, 1, "save_attention"),
+    (11, 2, "save_attention"), (9, 2, _GLU_KEPT)],
+    ids=["11", "12", "13", "11-accumulating", "9-accumulating"])
+def test_the_train_step_keeps_gate_and_up_where_a_chip_has_the_bytes(
+        topo, monkeypatch, layers, accum, chosen):
+    """The rule at the cell's own bytes (``utils/remat.py``): on a chip
+    of 15.75 GiB the 11-layer job keeps the pair and the same job at 12
+    and 13 layers does not; summing two microbatches' gradients into an
+    accumulator, a second copy of them (2.48 GiB at 11 layers beside
+    0.60 fewer kept), the 11-layer job does not and the 9-layer one
+    does (AOT, PR 61: the rich step then peaks at 14.60 and 12.18 GiB).
+    The state a chip holds is counted as the compiler counts the step's
+    arguments."""
+    from neuronx_distributed_tpu.parallel import mesh as ps
+    from neuronx_distributed_tpu.trainer import trainer
+
+    monkeypatch.setattr(trainer, "memory_limit_bytes",
+                        lambda devices: 16909334528)
+    with _train_parts(topo, *_cell_config("mistral-7b", layers)) as (
+            pm, _, state, shardings, _):
+        held = trainer._bytes_a_chip(state, shardings)
+        # AOT's argument_size_in_bytes: 7.4547 GiB at 11 layers, 8.6734 at 13
+        assert abs(held / 2 ** 30 - (0.7516 + 0.609375 * layers)) < 1e-3
+        module = trainer._module_for_step(
+            pm, ps.get_mesh(), state, shardings, (2 // accum, 4096),
+            accum > 1)
+    assert module.cfg.remat_policy == chosen
+
+
+def test_a_described_chip_reports_no_limit(topo):
+    """No process holds a described topology's devices: an AOT compile
+    traces the step that keeps ``save_attention``."""
+    from neuronx_distributed_tpu.utils.device import memory_limit_bytes
+
+    assert memory_limit_bytes(topo.devices) is None
 
 
 def test_the_sparse_step_scores_its_compressed_keys_in_the_pool(

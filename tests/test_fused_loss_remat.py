@@ -182,7 +182,8 @@ def _cell_cfg(policy=None, pipeline_parallel_size=1, **kw):
 
 
 @pytest.mark.parametrize("policy", ["save_attention", "dots_and_attention",
-                                    None], ids=lambda p: p or "default")
+                                    "save_attention_and_glu", None],
+                         ids=lambda p: p or "default")
 def test_remat_policy_grads_match_nothing(policy):
     ps.initialize_model_parallel(tensor_model_parallel_size=1)
     cfg_n = _pallas_cfg(remat_policy="nothing")
@@ -277,6 +278,255 @@ def test_remat_policy_saves_flash_residuals(policy):
     assert "f32[2,1,64,2,128]" in rep_s, rep_s
     # the policy strictly grows the saved set
     assert len(rep_s.splitlines()) > len(rep_n.splitlines())
+
+
+def test_the_rich_policy_saves_gate_and_up_products():
+    """``save_attention_and_glu`` pins the feed-forward's two products at
+    model level, stacked over the layers by the scan ([L, B, S, I] =
+    [2,1,64,384]), beside the flash pair; ``save_attention`` pins
+    neither."""
+    ps.initialize_model_parallel(tensor_model_parallel_size=1)
+    cfg_l = _pallas_cfg(remat_policy="save_attention",
+                        intermediate_size=384)
+    cfg_r = _pallas_cfg(remat_policy="save_attention_and_glu",
+                        intermediate_size=384)
+    ids, labels = _batch(cfg_l, b=1, s=64)
+    from flax.core import meta
+
+    params = meta.unbox(
+        LlamaForCausalLM(cfg_l).init(jax.random.key(1), ids))
+    rep_l = _saved_residual_report(cfg_l, params, ids, labels)
+    rep_r = _saved_residual_report(cfg_r, params, ids, labels)
+    glu = [line for line in rep_r.splitlines() if "f32[2,1,64,384]" in line]
+    assert "f32[2,1,64,384]" not in rep_l
+    assert len(glu) == 2, rep_r
+    assert "f32[2,1,2,64]" in rep_r and "f32[2,1,64,2,128]" in rep_r
+
+
+@pytest.mark.parametrize("remat", [False, True],
+                         ids=["served", "rematerialised"])
+def test_gate_and_up_are_named_only_in_a_model_that_rematerialises(
+        monkeypatch, remat):
+    """``LlamaMLP`` traces ``glu_gate``/``glu_up`` only under ``cfg.remat``,
+    which a served model never sets: its jaxpr holds no ``name`` and its
+    lowered text is the text of a module that names nothing (the serving
+    families' recorded hashes in ``tests/test_device_scopes.py`` stand)."""
+    from neuronx_distributed_tpu.models import llama
+
+    ps.initialize_model_parallel(tensor_model_parallel_size=1)
+    cfg = _fp32(remat=remat)
+    mlp = llama.LlamaMLP(cfg)
+    x = jnp.ones((1, 8, cfg.hidden_size), jnp.float32)
+    params = mlp.init(jax.random.key(0), x)
+
+    def traced():
+        return (str(jax.make_jaxpr(mlp.apply)(params, x)),
+                jax.jit(mlp.apply).lower(params, x).as_text())
+
+    jaxpr, text = traced()
+    assert all((f"name={n}" in jaxpr) == remat
+               for n in ("glu_gate", "glu_up")), jaxpr
+    monkeypatch.setattr(llama, "_kept_glu", lambda cfg, g, u: (g, u))
+    unnamed_jaxpr, unnamed_text = traced()
+    assert "name=glu" not in unnamed_jaxpr
+    if not remat:
+        assert (jaxpr, text) == (unnamed_jaxpr, unnamed_text)
+
+
+# mistral-7b.train-tp4 on one v5e chip of a 2x2 (tp=4, fp32 parameters and
+# AdamW moments, bf16 compute, 2 x 4,096 tokens): bytes a chip
+_V5E_LIMIT = 16909334528          # memory_stats()["bytes_limit"], 15.75 GiB
+
+
+def _cell_bytes(layers, accum=1):
+    """``(kept, step)`` bytes of the train cell at ``layers`` layers and
+    ``accum`` microbatches a step: the feed-forward pair, and the state
+    with its gradients (and their accumulator), bf16 copies and logits."""
+    cfg = LlamaConfig(hidden_size=4096, intermediate_size=14336,
+                      num_layers=layers, num_heads=32, num_kv_heads=8,
+                      vocab_size=32768, dtype=jnp.bfloat16)
+    sharded = layers * (2 * 4096 * 4096 + 2 * 4096 * 1024
+                        + 3 * 4096 * 14336) + 2 * 32768 * 4096
+    params = sharded // 4 + (2 * layers + 1) * 4096
+    # fp32 parameters and two moments; gradients; one bf16 copy
+    held = params * (3 * 4 + 4 * (1 + (accum > 1)) + 2)
+    return (cfg.glu_products_bytes(2 // accum * 4096, 4),
+            held + cfg.logits_bytes(2 // accum, 4096, 4))
+
+
+@pytest.mark.parametrize("layers,accum,named,limit,chosen", [
+    (11, 1, None, _V5E_LIMIT, "save_attention_and_glu"),
+    (12, 1, None, _V5E_LIMIT, "save_attention"),
+    (13, 1, None, _V5E_LIMIT, "save_attention"),
+    (11, 2, None, _V5E_LIMIT, "save_attention"),
+    (9, 2, None, _V5E_LIMIT, "save_attention_and_glu"),
+    (11, 1, None, None, "save_attention"),
+    (11, 1, None, 0, "save_attention"),
+    (13, 1, "save_attention_and_glu", _V5E_LIMIT, "save_attention_and_glu"),
+    (11, 1, "nothing", _V5E_LIMIT, "nothing"),
+    (11, 1, "dots", None, "dots"),
+], ids=["cell", "12-layers", "13-layers", "accumulating", "9-accumulating",
+        "no-limit", "zero-limit", "named-rich", "named-nothing",
+        "named-no-limit"])
+def test_the_rule_reads_the_bytes(layers, accum, named, limit, chosen):
+    """``choose_remat_policy``: the cell's 11 layers keep gate's and up's
+    products (1.20 GiB a chip beside 11.56 of state, gradients, copies
+    and logits, of 15.75), the same job at 12 and 13 layers does not, nor
+    at 11 where the step sums two microbatches' gradients into a second
+    copy of them (2.48 GiB more), a backend that reports no limit gets
+    ``save_attention``, and a name pins its policy whatever the bytes."""
+    from neuronx_distributed_tpu.utils.remat import choose_remat_policy
+
+    kept, step = _cell_bytes(layers, accum)
+    if (layers, accum) == (11, 1):
+        assert kept == 11 * 112 * 2 ** 20
+        assert abs(step / 2 ** 30 - 11.56) < 0.01
+    assert choose_remat_policy(named, kept_bytes=kept, step_bytes=step,
+                               limit_bytes=limit) == chosen
+
+
+class _Chip:
+    """A device as ``memory_limit_bytes`` sees one: whose client holds it
+    or not, and what its ``memory_stats()`` says or raises."""
+
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        if isinstance(self.stats, Exception):
+            raise self.stats
+        return self.stats
+
+
+def _chips(held, stats):
+    """Devices of one client: ``held[i]`` says whether this process holds
+    the i-th, ``stats[i]`` what it answers."""
+    chips = [_Chip(s) for s in stats]
+    client = type("Client", (), {"local_devices": lambda self: [
+        c for c, mine in zip(chips, held) if mine]})()
+    for c in chips:
+        c.client = client
+    return chips
+
+
+_NOT_MINE = RuntimeError(
+    "MemoryStats is only supported for addressable PjRt devices.")
+
+
+@pytest.mark.parametrize("held,stats,limit", [
+    ([True, True], [{"bytes_limit": 5}, {"bytes_limit": 7}], 5),
+    ([False, False, True, True],
+     [_NOT_MINE, _NOT_MINE, {"bytes_limit": 7}, {"bytes_limit": 7}], 7),
+    ([False, False], [_NOT_MINE, _NOT_MINE], None),
+    ([True], [None], None),
+    ([True], [{"bytes_in_use": 1}], None),
+], ids=["first-held", "device-0-on-another-host", "described", "cpu",
+        "no-limit-key"])
+def test_the_limit_is_read_from_a_device_this_process_holds(
+        held, stats, limit):
+    """On several hosts the mesh's first device belongs to one of them:
+    every process asks a chip of its own, so all read the same limit and
+    trace the same step. A described topology's client holds no device
+    and the CPU reports no statistics: no limit."""
+    from neuronx_distributed_tpu.utils.device import memory_limit_bytes
+
+    assert memory_limit_bytes(iter(_chips(held, stats))) == limit
+
+
+def test_a_held_device_that_cannot_answer_is_an_error_not_no_limit():
+    from neuronx_distributed_tpu.utils.device import memory_limit_bytes
+
+    with pytest.raises(RuntimeError, match="allocator is gone"):
+        memory_limit_bytes(_chips([False, True], [
+            _NOT_MINE, RuntimeError("allocator is gone")]))
+
+
+def test_the_cpu_reports_no_limit():
+    """Its devices are held and have no statistics (a described v5e's are
+    nobody's: ``tests/test_chip_compile.py``)."""
+    from neuronx_distributed_tpu.utils.device import memory_limit_bytes
+
+    assert memory_limit_bytes(jax.devices()) is None
+
+
+@pytest.mark.parametrize("family,plain", [
+    ("llama", True), ("mixtral", False), ("glm_moe_lite", False),
+    ("longcat_flash", False), ("nemotron_h", False)])
+def test_only_a_plain_layer_is_priced(family, plain):
+    """A family whose layer, blocks or feed-forward are its own holds
+    bytes a layer that the train step's builder does not count: its
+    rematerialised layers keep what they kept, whatever the chip has."""
+    import importlib
+    import types
+
+    from neuronx_distributed_tpu.trainer import trainer
+
+    module = importlib.import_module(
+        "neuronx_distributed_tpu.models." + family)
+    config = next(c for c in vars(module).values()
+                  if isinstance(c, type) and issubclass(c, LlamaConfig)
+                  and c.__module__ == module.__name__)
+    assert (config.plain_layers(object.__new__(config))) == plain
+    if plain:
+        return
+    ps.initialize_model_parallel(tensor_model_parallel_size=1)
+    cfg = types.SimpleNamespace(remat=True, remat_policy=None,
+                                plain_layers=lambda: False)
+    pm = types.SimpleNamespace(module=types.SimpleNamespace(cfg=cfg))
+    assert trainer._module_for_step(
+        pm, ps.get_mesh(), None, None, (2, 64), False) is pm.module
+
+
+@pytest.mark.parametrize("limit,named,accum,chosen,kept", [
+    (None, None, 1, "save_attention", 0),
+    (1 << 30, None, 1, "save_attention_and_glu", 65536),
+    (1 << 30, None, 2, "save_attention_and_glu", 32768),
+    (1 << 16, None, 1, "save_attention", 0),
+    (1 << 30, "nothing", 1, "nothing", 0),
+], ids=["no-limit", "room", "room-accumulating", "no-room", "named"])
+def test_the_bound_step_keeps_what_the_chip_has_bytes_for(
+        monkeypatch, limit, named, accum, chosen, kept):
+    """``make_train_step`` over a bound tp axis: the CPU reports no limit
+    and traces today's program; told a limit with room, the step's layers
+    keep gate's and up's products (2 layers x 2 x 128 rows x 32 columns
+    of float32 a chip; half the rows a pass where the step sums two
+    microbatches), and the gauge says which policy and how many bytes;
+    the step's loss and gradient norm are the same either way."""
+    from neuronx_distributed_tpu.obs.metrics import get_registry
+    from neuronx_distributed_tpu.trainer import (
+        initialize_parallel_optimizer, make_train_step, trainer)
+
+    ps.destroy_model_parallel()
+    # nxdlint: disable=plan  -- the rings engage from tp=4: the bound step
+    cfg = nxd.neuronx_distributed_config(
+        tensor_parallel_size=4,
+        activation_checkpoint_config=nxd.ActivationCheckpointConfig(
+            mode="full"))
+    mcfg = dataclasses.replace(
+        nxd.configure_model(cfg, _fp32(remat_policy=named)),
+        dtype=jnp.float32)
+    ids, labels = _batch(mcfg, b=4, s=64)
+    batch = {"input_ids": ids, "labels": labels}
+    pm, params = initialize_parallel_model(
+        cfg, LlamaForCausalLM(mcfg), jax.random.key(0), ids)
+    tx, state, shardings = initialize_parallel_optimizer(pm, params)
+    _, lean = make_train_step(pm, tx, shardings, donate=False,
+                              grad_accum_steps=accum)(state, batch)
+    monkeypatch.setattr(trainer, "memory_limit_bytes", lambda devices: limit)
+    reg = get_registry()
+    was = reg.enabled
+    reg.enable()
+    try:
+        _, metrics = make_train_step(pm, tx, shardings, donate=False,
+                                     grad_accum_steps=accum)(state, batch)
+        gauge = reg.get("nxd_train_remat_kept_bytes")
+        assert gauge.labels(policy=chosen).value == kept
+    finally:
+        if not was:
+            reg.disable()
+    for name in ("loss", "grad_norm"):
+        np.testing.assert_allclose(float(metrics[name]), float(lean[name]),
+                                   rtol=1e-5)
 
 
 def test_save_attention_not_a_noop_on_xla_fallback():
