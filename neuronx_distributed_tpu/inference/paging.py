@@ -707,6 +707,13 @@ class SparseStateCache(FullCache):
             block_size=block_size)
 
 
+def _rows_by_context(positions) -> np.ndarray:
+    """A step's real rows by :data:`STEP_ROWS_BY_CONTEXT`'s kinds."""
+    at = positions[positions < PAD_POSITION]
+    return np.bincount(np.searchsorted((2048, 8192), at, side="right"),
+                       minlength=3)
+
+
 @dataclasses.dataclass(frozen=True)
 class LatentCache(FullCache):
     """A full cache whose row is no ``KV x D`` pair: one latent and one
@@ -725,14 +732,15 @@ class LatentCache(FullCache):
     moe_leaf: DeviceCounts = MOE_KEPT_DROPPED
     name = "latent"
     counters = (PAGED_COLUMNS, PAGED_BLOCK_VISITS, MLA_BLOCK_FETCHES,
-                MLA_SHARED_BLOCKS)
+                MLA_SHARED_BLOCKS, STEP_ROWS_BY_CONTEXT)
 
     def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
                    held: Sequence[int], rolled: int) -> Dict[str, Any]:
         """As :meth:`FullCache.count_step`, with the pool blocks by how the
         mla_paged_attention kernel's units come by them in place of the
-        paged kernel's pairs (it takes its pairs in runs), and the shared
-        ones by the unit they rode."""
+        paged kernel's pairs (it takes its pairs in runs), the shared
+        ones by the unit they rode, and the real rows by their position
+        (the kernel's walk is as long as a row's context)."""
         from ..ops import mla_attention as mla
         from ..ops.paged_attention import host_pairs
 
@@ -744,6 +752,7 @@ class LatentCache(FullCache):
                                                            pairs)
         counts[MLA_SHARED_BLOCKS.name] = mla.shared_blocks(served, *shapes,
                                                            pairs)
+        counts[STEP_ROWS_BY_CONTEXT.name] = _rows_by_context(positions)
         return counts
 
     def stack_index(self, layer, which: int = 0):
@@ -908,8 +917,7 @@ class WindowPoolCache(FullCache):
             for n, (kv, *widths) in zip(blocks, (self.full_rows,
                                                  self.window_rows))
             for width in widths)
-        counts[STEP_ROWS_BY_CONTEXT.name] = np.bincount(
-            np.searchsorted((2048, 8192), at, side="right"), minlength=3)
+        counts[STEP_ROWS_BY_CONTEXT.name] = _rows_by_context(positions)
         return counts
 
     def geometry(self, block_size: int, step_rows: int = 0

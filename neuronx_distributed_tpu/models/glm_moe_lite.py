@@ -66,8 +66,33 @@ def carried(cfg):
     return {kind: ("rows", "moe_counts") for kind, _, _ in cfg.runs()}
 
 
+class LatentGeometry:
+    """What :class:`LatentAttention` and :func:`latent_forward_with_cache`
+    ask of a latent family's config beyond its fields
+    (``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``rope_theta``), each with the plain family's answer: a mixin ahead
+    of :class:`.llama.LlamaConfig` in the family's bases."""
+
+    @property
+    def head_dim_(self) -> int:
+        """The width of a pool row as the kernel reads it, whole lanes:
+        what the paged machinery asks a family's ``head_dim_`` for."""
+        return mla.row_width(self.kv_lora_rank, self.qk_rope_head_dim)
+
+    @property
+    def score_scale(self) -> float:
+        """What a head's scores are multiplied by ahead of the softmax."""
+        return 1.0 / math.sqrt(self.qk_nope_head_dim
+                               + self.qk_rope_head_dim)
+
+    def rotary_rows(self, positions: jax.Array):
+        """cos and sin ``[T, qk_rope_head_dim // 2]`` at ``positions
+        [T]``, for the rotary key and the queries' rotary part."""
+        return rope_rows(positions, self.qk_rope_head_dim, self.rope_theta)
+
+
 @dataclass(frozen=True)
-class GlmMoeLiteConfig(LlamaConfig):
+class GlmMoeLiteConfig(LatentGeometry, LlamaConfig):
     vocab_size: int = 154880
     hidden_size: int = 2048
     #: the leading dense layers' SwiGLU width
@@ -104,12 +129,6 @@ class GlmMoeLiteConfig(LlamaConfig):
             raise ValueError(f"ff_kind must be one of {KINDS}")
         if not 0 <= self.first_k_dense <= self.num_layers:
             raise ValueError("first_k_dense must lie within num_layers")
-
-    @property
-    def head_dim_(self) -> int:
-        """The width of a pool row as the kernel reads it, whole lanes:
-        what the paged machinery asks a family's ``head_dim_`` for."""
-        return mla.row_width(self.kv_lora_rank, self.qk_rope_head_dim)
 
     def attention(self, tp_sync: bool = True):
         return LatentAttention(self, name="attn")
@@ -173,7 +192,8 @@ class LatentAttention(nn.Module):
     :class:`.llama.LlamaAttention`'s call, for any config that has the
     latent fields (``num_heads``, ``q_lora_rank``, ``kv_lora_rank``,
     ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
-    ``head_dim_`` the pool row's lanes, and ``q_lora_scale`` /
+    :class:`LatentGeometry`'s ``head_dim_`` (the pool row's lanes) and
+    ``score_scale``, and ``q_lora_scale`` /
     ``kv_lora_scale``, what the normed low-rank query and the normed
     latent are multiplied by: the cached row holds the scaled latent).
     ``cos``/``sin`` are the rows'
@@ -228,7 +248,7 @@ class LatentAttention(nn.Module):
                 shape, cfg.param_dtype) for name, shape in (
                     ("k_up", (heads, nope, rank)),
                     ("v_up", (heads, rank, cfg.v_head_dim))))
-            scale = 1.0 / math.sqrt(nope + rope)
+            scale = cfg.score_scale
             row = cfg.head_dim_
             rows = jnp.concatenate(
                 [latent, k_rope[:, :, 0],
@@ -274,7 +294,8 @@ class LatentAttention(nn.Module):
 class GlmMoeLiteModel(nn.Module):
     """Embedding, the layer pattern, final norm: positions ``0..S-1``, no
     cache (tests, small training). Any latent family's: the config gives
-    ``runs()``, ``kind_config()`` and the layer module."""
+    ``runs()``, ``kind_config()``, the layer module, the rotary rows and
+    what the layers carry (``carry_in``, ``carry_out``)."""
 
     cfg: LlamaConfig
 
@@ -287,8 +308,8 @@ class GlmMoeLiteModel(nn.Module):
                 dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                 name="embed")(input_ids)
         with device_scope("attn.proj"):
-            cos, sin = rope_rows(jnp.arange(input_ids.shape[1]),
-                                 cfg.qk_rope_head_dim, cfg.rope_theta)
+            cos, sin = cfg.rotary_rows(jnp.arange(input_ids.shape[1]))
+        x = cfg.carry_in(x)
         if self.is_initializing():
             # the parameters: one stack a kind (the order the layers run
             # in is run_layers' business, and makes no parameter)
@@ -305,6 +326,7 @@ class GlmMoeLiteModel(nn.Module):
                 self.variables["params"][f"layers_{kind}"])
                 for kind, _, _ in cfg.runs()}
             x, _ = run_layers(cfg, stacks, x, cos, sin, carried(cfg))
+        x = cfg.carry_out(x)
         with device_scope("norm"):
             return RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype, name="norm")(x)
 
@@ -340,7 +362,9 @@ def latent_forward_with_cache(cfg: LlamaConfig, params, input_ids,
     T, V], new cache)``. The row stack and the routed assignments' counts
     (of this step alone) are the carry of every run's scan;
     ``cfg.rows_layer(kind, layer)`` says where in the stack a layer keeps
-    its (first attention's) rows."""
+    its (first attention's) rows, ``cfg.carry_in`` / ``carry_out`` what
+    the layers hand on of the embedded rows (themselves, unless the
+    family's residual is wider: ``models/xing4.py``)."""
     from ..inference import paging
     from ..inference.kv_cache import PAD_POSITION
 
@@ -360,8 +384,7 @@ def latent_forward_with_cache(cfg: LlamaConfig, params, input_ids,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype).apply(
             {"params": p["model"]["embed"]}, input_ids)
     with device_scope("attn.proj"):
-        cos, sin = rope_rows(jnp.minimum(q_pos, cfg.max_seq_len - 1),
-                             cfg.qk_rope_head_dim, cfg.rope_theta)
+        cos, sin = cfg.rotary_rows(jnp.minimum(q_pos, cfg.max_seq_len - 1))
     kind = cfg.serving_family().cache_kind.geometry(kv_cache.block_size)
     with device_scope("attn.walk"):
         tables = kv_cache.block_tables[
@@ -392,9 +415,10 @@ def latent_forward_with_cache(cfg: LlamaConfig, params, input_ids,
                  moe_counts=jnp.zeros_like(kv_cache.moe_counts))
     stacks = {kind: p["model"][f"layers_{kind}"]
               for kind, _, _ in cfg.runs()}
-    x, carry = run_layers(cfg, stacks, x, cos, sin, carried(cfg), carry,
-                          view_of,
-                          merge, valid=(q_pos < PAD_POSITION)[None])
+    x, carry = run_layers(cfg, stacks, cfg.carry_in(x), cos, sin,
+                          carried(cfg), carry, view_of, merge,
+                          valid=(q_pos < PAD_POSITION)[None])
+    x = cfg.carry_out(x)
     with device_scope("norm"):
         x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype).apply(
             {"params": p["model"]["norm"]}, x)

@@ -259,6 +259,21 @@ class LlamaConfig:
         that layer's call and returns its ``(x, aux, new_cache)``."""
         return LlamaDecoderLayer(self, **module)
 
+    def carry_in(self, x: jax.Array) -> jax.Array:
+        """The embedded tokens ``[B, T, hidden_size]`` as the carry the
+        decoder layers hand on: themselves, unless the family's
+        :meth:`decoder_layer` carries more a token than ``hidden_size``
+        values (``models/xing4.py``'s residual streams). Called once
+        behind the embedding by the models that run their layers through
+        :func:`run_layers` (the latent family's, with and without a
+        cache); such a family's layer takes and returns the wider ``x``."""
+        return x
+
+    def carry_out(self, x: jax.Array) -> jax.Array:
+        """What the final norm reads of the last layer's carry:
+        ``[B, T, hidden_size]`` again (:meth:`carry_in`'s way back)."""
+        return x
+
     def __post_init__(self) -> None:
         if self.attention_kind not in ATTENTION_KINDS:
             raise ValueError(

@@ -52,9 +52,8 @@ from flax import linen as nn
 from ..modules.moe import MoE
 from ..modules.norms import RMSNorm
 from ..obs.device_scopes import device_scope
-from ..ops import mla_attention as mla
 from .glm_moe_lite import (GlmMoeLiteForCausalLM, LatentAttention,
-                           latent_forward_with_cache)
+                           LatentGeometry, latent_forward_with_cache)
 from .llama import LlamaConfig, LlamaMLP
 
 KIND = "double"
@@ -63,7 +62,7 @@ ATTENTIONS = 2
 
 
 @dataclass(frozen=True)
-class LongcatFlashConfig(LlamaConfig):
+class LongcatFlashConfig(LatentGeometry, LlamaConfig):
     vocab_size: int = 131072
     hidden_size: int = 6144
     #: the two dense feed-forwards' SwiGLU width (``ffn_hidden_size``)
@@ -100,11 +99,6 @@ class LongcatFlashConfig(LlamaConfig):
             if not (0 <= first and count > 0
                     and first + count <= self.num_experts):
                 raise ValueError("experts_held must lie within num_experts")
-
-    @property
-    def head_dim_(self) -> int:
-        """The width of a pool row as the kernel reads it, whole lanes."""
-        return mla.row_width(self.kv_lora_rank, self.qk_rope_head_dim)
 
     @property
     def q_lora_scale(self) -> float:

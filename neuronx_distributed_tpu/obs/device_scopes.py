@@ -48,6 +48,7 @@ SCOPES = (
     "attn.state", "attn.conv", "attn.summarise", "attn.pool_write",
     "ffn", "ffn.dense", "ffn.router", "ffn.experts", "ffn.shared",
     "ffn.identity", "ffn.latent",
+    "hc", "hc.mix", "hc.apply",
     "head", "sample",
     "loss", "optimizer",
 )

@@ -247,4 +247,5 @@ def test_the_nemotron_cell_holds_every_slot_at_its_longest():
     assert not listed & {"ssd_state_roofline", "moe_experts_roofline",
                          "paged_attention_roofline", "kda_state_roofline"}
     assert name in E2E["serve_tok_s"]["workloads"]
-    assert len(MANIFEST["workloads"]) == 14
+    # the fourteenth cell: later ones come behind it
+    assert [w["name"] for w in MANIFEST["workloads"]].index(name) == 13

@@ -697,6 +697,104 @@ def test_double_layer_latent_step_at_the_published_widths(chip, topo,
             "embed", "sample"} <= seen
 
 
+# -- the latent cache under contexts of 64k (Xing4.0-29B-A4B) ---------------------
+# The cell xing4.0-29b-a4b.serve-longdocs: 128 rows, 32 heads over one row of
+# a 512 latent and a 64 rotary key, 260 table columns of 256 positions, 2,080
+# blocks, 7 layers of rows, a carry of 4 residual streams of 3,584.
+
+def test_mla_paged_attention_at_32_heads_and_blocks_of_256(chip):
+    """A tile of 8 rows x 32 heads (256 stacked rows) over blocks of 256
+    positions: a decode row's blocks run by eight, a tile's shared blocks
+    by two against slabs of 4 packed rows; the walk's four scalar arrays,
+    8 rows x 260 columns a tile, are half the chip's SMEM. At blocks of
+    128 the same contexts are 520 columns a row and the four arrays
+    1.02 MiB of its 1 MiB: the compiler refuses the kernel by name."""
+    from neuronx_distributed_tpu.ops import mla_attention as mla
+
+    tokens, heads, rank, layers = 128, 32, 512, 7
+    row = mla.row_width(rank, 64)
+    fn = functools.partial(mla._mla_attention_pallas, rank=rank,
+                           scale=0.14468, interpret=False)
+
+    def operands(bs, cols, nb):
+        return (chip((tokens, heads, row), jnp.bfloat16),
+                chip((layers, nb, bs, row), jnp.bfloat16),
+                chip((nb, bs), jnp.int32), chip((tokens, cols), jnp.int32),
+                chip((tokens,), jnp.int32), chip((), jnp.int32))
+
+    text = _assert_kernel_compiles(fn, *operands(256, 260, 2080))
+    assert _kernel_instruction_names(text) == {"mla_paged_attention"}
+    assert {"tpu.matmul", "tpu.enqueue_dma"} <= _mosaic_ops(text)
+    assert mla._unit_lengths(32, 8 * 32, row, 256, 2) == (8, 2, 128)
+    assert 4 * 4 * (16 * 8 * 260 + 2) < 2 ** 20 < 4 * 4 * (16 * 8 * 520 + 2)
+    with pytest.raises(Exception, match="smem"):
+        jax.jit(fn).lower(*operands(128, 520, 4160)).compile()
+
+
+def test_residual_streams_latent_step_at_the_published_widths(chip, topo,
+                                                              on_one_chip):
+    """The packed step of the cell's configuration file: it compiles for
+    the chip with the latent kernel in it, holds what the configuration
+    says it holds, writes the row stack in place, and a sublayer's mixing
+    is a handful of fusions: no loop, and no more instructions under the
+    two scopes than a sixth of what the Sinkhorn rounds would be as
+    reductions (95 a sublayer: AOT, PR 63)."""
+    import re
+
+    from neuronx_distributed_tpu.obs.device_scopes import scope_of
+
+    config, models = _cell_config("xing4.0-29b-a4b", None)
+    assert set(config["reduced"]) == {"num_hidden_layers",
+                                      "num_nextn_predict_layers"}
+    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
+                                                         models)
+    nb, bs = config["serve"]["num_blocks"], config["serve"]["block_size"]
+    assert cache.rows.shape == (7, nb, bs, 640) and (nb, bs) == (2080, 256)
+    assert cache.moe_counts.shape == (2,)
+    moe = params["params"]["model"]["layers_moe"]["layer"]
+    assert moe["moe"]["experts"]["gate"].shape == (5, 64, 3584, 1024)
+    assert moe["attn"]["k_up"].shape == (5, 32, 128, 512)
+    assert moe["hc_ffn"]["phi"].shape == (5, 4 * 3584, 24)
+    assert moe["hc_ffn"]["phi"].dtype == jnp.float32
+
+    compiled = _packed_step(chip, cfg, forward, params, cache, tokens)
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"mla_paged_attention"}
+    gib = 2.0 ** 30
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) / gib
+    count = sum(x.size for x in jax.tree_util.tree_leaves(params))
+    assert count == 4_920_866_746
+    assert mem.temp_size_in_bytes < cache.rows.size * 2 / 7   # one layer's
+    aot = config["assumed"]["serve_aot_gib"]
+    assert abs(held - aot["total"]) < 0.05 and 0.85 <= held / 15.75 <= 0.90
+    assert abs(held / 15.75 - aot["of_chip"]) < 0.005
+
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape == f"7,{nb},{bs},640"]
+    assert len(stacks) == 1 and set(stacks) <= aliased, (stacks, header)
+
+    # the mixing's instructions at the top level of a scan body: fusions
+    # and the product with Phi, two kinds of layer x two sublayers
+    top = [line for line in text.split("\n")
+           if re.match(r"\s+(ROOT )?%?[\w.-]+ = ", line)
+           and re.search(r" (fusion|convolution|custom-call|copy)\(", line)]
+    mixing = [line for line in top if scope_of(
+        re.search(r'op_name="([^"]*)"', line).group(1)
+        if "op_name" in line else "").startswith("hc")]
+    assert 4 <= len(mixing) <= 4 * 16, len(mixing)
+    assert sum(" convolution(" in line for line in mixing) == 4
+    seen = {scope_of(m) for m in re.findall(r'op_name="([^"]*)"', text)}
+    assert {"hc.mix", "hc.apply", "ffn.experts", "ffn.shared", "ffn.dense",
+            "attn.pool_write", "attn.walk", "norm", "embed",
+            "sample"} <= seen
+
+
 # -- grouped GLU decode (MoE serving) at OLMoE's widths (ROADMAP R1): hidden
 # 2048, expert width 1024. Mixtral's 4096 compiles too, in about ten seconds.
 

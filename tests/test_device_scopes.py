@@ -42,7 +42,8 @@ SERVING = {"llama": "tiny-mistral-serve", "mixtral": "tiny-mixtral",
            "solar_open2": "tiny-solar-open2",
            "longcat_flash": "tiny-longcat-flash",
            "granite_moe_hybrid": "tiny-granite-moe-hybrid",
-           "nemotron_h": "tiny-nemotron-h"}
+           "nemotron_h": "tiny-nemotron-h",
+           "xing4": "tiny-xing4"}
 #: the children a family's step must open, and no other family's may
 OWN = {"attn.select": {"minicpm_sala"},
        "attn.state": {"minicpm_sala", "granite_hybrid", "solar_open2",
@@ -54,13 +55,15 @@ OWN = {"attn.select": {"minicpm_sala"},
        "attn.kernel.window": {"laguna", "mimo_v2_flash"},
        "ffn.experts": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
                        "solar_open2", "longcat_flash", "granite_moe_hybrid",
-                       "nemotron_h"},
+                       "nemotron_h", "xing4"},
        "ffn.router": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
                       "solar_open2", "longcat_flash", "granite_moe_hybrid",
-                      "nemotron_h"},
+                      "nemotron_h", "xing4"},
        "ffn.shared": {"glm_moe_lite", "laguna", "solar_open2",
-                      "granite_moe_hybrid", "nemotron_h"},
-       "ffn.latent": {"nemotron_h"}}
+                      "granite_moe_hybrid", "nemotron_h", "xing4"},
+       "ffn.latent": {"nemotron_h"},
+       # the float32 product with Phi; hc.apply has no heavy operation
+       "hc.mix": {"xing4"}}
 #: the operations that carry a step's device time
 HEAVY = ("stablehlo.dot_general", "stablehlo.custom_call",
          "stablehlo.scatter", "stablehlo.gather", "stablehlo.sort",
@@ -279,7 +282,7 @@ def test_a_step_opens_the_children_its_family_has_and_no_others(which):
         assert (child in seen) == (which in families), (child, seen)
     dense = which in ("llama", "evabyte", "minicpm_sala", "glm_moe_lite",
                       "granite_hybrid", "laguna", "mimo_v2_flash",
-                      "longcat_flash", "train")
+                      "longcat_flash", "xing4", "train")
     assert ("ffn.dense" in seen) == dense
     if which == "train":
         assert "optimizer" not in seen      # elementwise: no heavy operation
@@ -302,6 +305,29 @@ def test_the_identity_experts_term_has_a_scope_of_its_own(which):
     one family that has identity experts opens ``ffn.identity``."""
     scopes = {scope_of(path) for _, path in _operations(_lowered(which))}
     assert ("ffn.identity" in scopes) == (which == "longcat_flash")
+
+
+@pytest.mark.parametrize("which", list(SERVING))
+def test_the_streams_mixing_has_scopes_of_its_own(which):
+    """The one family whose residual is several streams opens ``hc.mix``
+    (the statistic, the product with ``Phi``, the sigmoids, the Sinkhorn
+    rounds) and ``hc.apply`` (the read, the write, the widening and the
+    sum at the ends; no heavy operation, so the children's table above
+    cannot see it), each twice a layer; no other step opens either, and
+    in this one the unrolled rounds are elementwise operations alone: no
+    reduction is traced under ``hc.mix`` but the statistic's."""
+    ops = _operations(_lowered(which))
+    scopes = {scope_of(path) for _, path in ops}
+    assert ({"hc.mix", "hc.apply"} <= scopes) == (which == "xing4")
+    assert not within("hc", scopes) or which == "xing4"
+    if which == "xing4":
+        mix = [op for op, path in ops if scope_of(path) == "hc.mix"]
+        # two kinds of layer, two sublayers each: one mean of squares and
+        # one product a sublayer
+        assert mix.count("stablehlo.reduce") == 4
+        assert mix.count("stablehlo.dot_general") == 4
+        assert mix.count("stablehlo.divide") >= 4 * 40 * 16
+        assert "stablehlo.while" not in mix
 
 
 def test_the_train_step_marks_its_loss_and_its_optimizer():
@@ -406,11 +432,20 @@ LOWERED_AT_PR_59 = {
 }
 
 
+#: the family of several residual streams (``models/xing4.py``: GLM's
+#: attention, experts and served forward behind the carry's, the score's
+#: and the rotary rows' hooks), new with PR 63, as that PR's tree lowers it
+LOWERED_AT_PR_63 = {
+    "xing4":
+        "277888add9250afbe8c96c65ce4c4f60000af0365341d7e020638b4d831914b2",
+}
+
+
 @pytest.mark.parametrize("which", list(LOWERED_AT_PR_38)
                          + list(LOWERED_AT_PR_39) + list(LOWERED_AT_PR_42)
                          + list(LOWERED_AT_PR_43) + list(LOWERED_AT_PR_45)
                          + list(LOWERED_AT_PR_48) + list(LOWERED_AT_PR_54)
-                         + list(LOWERED_AT_PR_59))
+                         + list(LOWERED_AT_PR_59) + list(LOWERED_AT_PR_63))
 def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     """PR 39 changed the latent kernel and its walk alone: the packed
     steps of the five families that run the shared helpers of
@@ -445,7 +480,12 @@ def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     family does not ask for it: the ten are the text they were, both
     Granite steps (the dense one, and the one with routed experts as its
     parent's tree lowers it, recorded with that PR) and GLM's and
-    Laguna's gated banks among them. A PR that changes one of these
+    Laguna's gated banks among them. PR 63 gave the latent family's
+    config three hooks (the carry's way in and out, identity by default;
+    the score's scale and the rotary rows as the config's, GLM's and
+    LongCat's what they were) and the latent cache kind a host counter:
+    the twelve are the text they were, and the family of several
+    residual streams is recorded. A PR that changes one of these
     programs on purpose records its new hash here."""
     import hashlib
 
@@ -453,4 +493,4 @@ def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     assert hashlib.sha256(text.encode()).hexdigest() == {
         **LOWERED_AT_PR_38, **LOWERED_AT_PR_39, **LOWERED_AT_PR_42,
         **LOWERED_AT_PR_43, **LOWERED_AT_PR_45, **LOWERED_AT_PR_48,
-        **LOWERED_AT_PR_54, **LOWERED_AT_PR_59}[which]
+        **LOWERED_AT_PR_54, **LOWERED_AT_PR_59, **LOWERED_AT_PR_63}[which]

@@ -938,6 +938,7 @@ DECLARED = {
         "nxd_paged_block_visits_total": ("fetched", "shared"),
         "nxd_mla_block_fetches_total": ("in_run", "alone", "whole"),
         "nxd_mla_shared_blocks_total": ("in_unit", "alone"),
+        "nxd_step_rows_by_context_total": ("to_2k", "to_8k", "past_8k"),
         **_MOE},
     "granite_hybrid": {**_PAGED, **_STATES, **_HELD},
     "laguna": {
