@@ -444,34 +444,50 @@ def _ag_matmul_decomposed(x: Array, ws: Tuple[Array, ...], axis, dim: int,
     # bitwise (fp wire: encode/open are identities and this is just x)
     own = _open(pair, wire, x.dtype)
 
-    outs = []
-    for w in ws:
-        shape = list(x.shape[:-1]) + list(w.shape[1:])
-        shape[dim] = n * l
-        outs.append(jnp.zeros(tuple(shape), jnp.result_type(own, w)))
+    # The output's n blocks are one dimension of their own while the ring
+    # runs, ``[..., n, l, ...]``: a product is one index of it, so the
+    # matmul's output fusion writes the block where it stays (XLA updates
+    # a slice of a middle dimension, ``[..., n * l, ...]`` at ``src * l``,
+    # by copying the whole output, once a hop), and the reshape at the end
+    # moves nothing. lax primitives: a ring is traced a dozen times a step.
+    zero = np.int32(0)
 
-    def write(outs, chunk, src):
-        return [lax.dynamic_update_slice_in_dim(o, _contract(chunk, w),
-                                                src * l, axis=dim)
-                for o, w in zip(outs, ws)]
+    def place(bufs, chunk, ahead):
+        """``chunk``'s products, the shard of the rank ``ahead`` ranks on,
+        into their block of every output (made at the first)."""
+        src = lax.rem(lax.add(idx, np.int32(ahead)), np.int32(n))
+        out = []
+        for buf, w in zip(bufs, ws):
+            p = _contract(chunk, w)
+            block = p.shape[:dim] + (1,) + p.shape[dim:]
+            if buf is None:
+                buf = lax.full(p.shape[:dim] + (n,) + p.shape[dim:], 0,
+                               p.dtype)
+            starts = [zero] * len(block)
+            starts[dim] = src
+            out.append(lax.dynamic_update_slice_p.bind(
+                buf, lax.reshape(p, block), *starts))
+        return out
 
-    outs = write(outs, own, idx)  # own block first — no transfer needed
+    bufs = place([None] * len(ws), own, 0)  # own block first: no transfer
     if not bidi:
         for t in range(1, n):
             # receive the next shard from the right neighbour; the matmul
             # below consumes the *previous* chunk's successor, so transfer
             # t+1 can fly while block t multiplies
             pair = _ship(pair, axis, _shift_perm(n, -1))
-            outs = write(outs, _open(pair, wire, x.dtype), (idx + t) % n)
-        return tuple(outs)
-    fwd = bwd = pair
-    for t in range(1, n // 2 + 1):
-        fwd = _ship(fwd, axis, _shift_perm(n, -1))
-        outs = write(outs, _open(fwd, wire, x.dtype), (idx + t) % n)
-        if t != n - t:  # at t == n/2 both streams carry the same shard
-            bwd = _ship(bwd, axis, _shift_perm(n, +1))
-            outs = write(outs, _open(bwd, wire, x.dtype), (idx - t) % n)
-    return tuple(outs)
+            bufs = place(bufs, _open(pair, wire, x.dtype), t)
+    else:
+        fwd = bwd = pair
+        for t in range(1, n // 2 + 1):
+            fwd = _ship(fwd, axis, _shift_perm(n, -1))
+            bufs = place(bufs, _open(fwd, wire, x.dtype), t)
+            if t != n - t:  # at t == n/2 both streams carry the same shard
+                bwd = _ship(bwd, axis, _shift_perm(n, +1))
+                bufs = place(bufs, _open(bwd, wire, x.dtype), n - t)
+    return tuple(
+        lax.reshape(b, b.shape[:dim] + (n * l,) + b.shape[dim + 2:])
+        for b in bufs)
 
 
 def _ag_matmul_monolithic(x: Array, ws: Tuple[Array, ...], axis, dim: int,

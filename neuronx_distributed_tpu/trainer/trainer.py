@@ -14,7 +14,16 @@ row-parallel exit's all-reduce is a true dependence no scheduler hides, so
 where the projections' decomposed rings (``ops/collective_matmul``) would
 engage, the default step computes loss and gradients inside ``shard_map``
 with the mesh's axes bound (``_tp_rings_engage``) and GSPMD keeps the
-optimizer around it.
+optimizer around it. There the step also lays the residual stream out
+(``_sharded_stream_cfg``): between a row-parallel exit and the next
+column-parallel entry it is sharded over the sequence
+(``sequence_parallel=True`` on the clone the step differentiates), so the
+exit is the reduce-scatter ring alone and its all-gather half rides inside
+the next projection's ring (``cm.all_gather_matmul``) instead of standing
+exposed behind the exit. The layout changes no number the model computes
+and no parameter, spec, optimizer state or checkpoint: like what a
+rematerialised layer keeps, it is the step's to choose from what it can
+see at trace time, with no option.
 """
 
 from __future__ import annotations
@@ -320,36 +329,95 @@ def _record_remat_choice(policy: str, kept_bytes: int) -> None:
                   kept_bytes if policy == remat.RICH_REMAT_POLICY else 0)
 
 
+def _record_stream_layout(shards: int) -> None:
+    """The bound step's other trace-time decision, beside
+    :func:`_record_remat_choice`: ``nxd_train_residual_sequence_shards``."""
+    from ..obs.metrics import get_registry
+
+    reg = get_registry()
+    if not reg.enabled:
+        return
+    reg.gauge("nxd_train_residual_sequence_shards",
+              "Tensor-parallel ranks the bound train step's residual "
+              "stream is sharded over along the sequence between two "
+              "projections (set once per trace; 1: replicated, every "
+              "row-parallel exit all-gathers).").set(shards)
+
+
+def _sharded_stream_cfg(cfg):
+    """``cfg`` with the residual stream sharded over the sequence, or None
+    where the model cannot take it or has it already: no such field, a
+    config whose own checks refuse it (``activation_sync_fraction < 1``
+    elides exits that a reduce-scatter cannot, quantized linears enter
+    through ``copy_to``), or LoRA (an adapted projection reads the
+    gathered activations and keeps its monolithic entry: no ring would
+    hide the gather)."""
+    if (not dataclasses.is_dataclass(cfg)
+            or getattr(cfg, "sequence_parallel", None) is not False
+            or getattr(cfg, "lora", None) is not None):
+        return None
+    try:
+        return dataclasses.replace(cfg, sequence_parallel=True)
+    except ValueError:
+        return None
+
+
 def _module_for_step(pm: ParallelModel, mesh, state, state_shardings,
-                     rows: Tuple[int, int], accumulating: bool) -> nn.Module:
-    """``pm.module`` with what its rematerialised layers keep decided for
-    this step (``utils/remat.choose_remat_policy``): a model that
+                     rows: Tuple[int, int], accumulating: bool,
+                     rings: bool = False) -> nn.Module:
+    """``pm.module`` as the explicit path differentiates it: a clone with
+    the step's two trace-time choices, parameters and their specs
+    untouched.
+
+    **The residual stream's layout.** Where the projections' rings engage
+    (``rings``: :func:`_tp_rings_engage`, and no dropout stream, which the
+    step shares across tp) and the model can take it
+    (:func:`_sharded_stream_cfg`), the stream is sharded over the
+    sequence: a row-parallel exit is then its reduce-scatter ring alone
+    and the next entry's all-gather ring carries what the exit's
+    all-gather did, under that projection's matmuls. A model that sets
+    ``sequence_parallel`` itself keeps its setting.
+
+    **What its rematerialised layers keep**
+    (``utils/remat.choose_remat_policy``): a model that
     checkpoints its layers and names no policy keeps gate's and up's
     products when a chip has the bytes. Priced from ``rows`` (the batch
     and sequence one chip's layers see a pass), the model's own widths,
     the state a chip holds under ``state_shardings``, its gradients (twice
     where the step sums microbatches' into an accumulator), compute-dtype
     copies and logits, and the limit of a device this process holds; a
-    policy the model names stays. A model that checkpoints nothing or
-    whose layers the builder cannot price (a family's own:
+    policy the model names stays. The pair's bytes do not depend on the
+    stream's layout (the products span the whole sequence and a rank's
+    share of the width); the layer boundary that "full" keeps is inside
+    the rule's second count of the pair and is a quarter under the sharded
+    stream, so the sum errs higher there. A model that checkpoints nothing
+    or whose layers the builder cannot price (a family's own:
     ``LlamaConfig.plain_layers``), and any on a mesh with pipeline,
-    context or expert parallelism, is returned as it is."""
+    context or expert parallelism, keeps its policy."""
     cfg = getattr(pm.module, "cfg", None)
-    if not (_tensor_by_data(mesh) and getattr(cfg, "remat", False)
-            and hasattr(cfg, "plain_layers") and cfg.plain_layers()):
-        return pm.module
     tp = dict(mesh.shape).get(ps.TP_AXIS, 1)
-    kept = cfg.glu_products_bytes(math.prod(rows), tp)
-    params = state.params, state_shardings.params
-    step_bytes = (_bytes_a_chip(state, state_shardings)          # the state
-                  + _bytes_a_chip(*params) * (1 + accumulating)  # gradients
-                  + _bytes_a_chip(*params, cast=cfg.dtype)       # casts
-                  + cfg.logits_bytes(*rows, tp))
-    policy = remat.choose_remat_policy(
-        cfg.remat_policy, kept_bytes=kept, step_bytes=step_bytes,
-        limit_bytes=memory_limit_bytes(mesh.devices.flat))
-    _record_remat_choice(policy, kept)
-    return pm.module.clone(cfg=dataclasses.replace(cfg, remat_policy=policy))
+    sharded = _sharded_stream_cfg(cfg) if rings else None
+    if sharded is not None:
+        cfg = sharded
+    _record_stream_layout(
+        tp if getattr(cfg, "sequence_parallel", False) else 1)
+    if (_tensor_by_data(mesh) and getattr(cfg, "remat", False)
+            and hasattr(cfg, "plain_layers") and cfg.plain_layers()):
+        kept = cfg.glu_products_bytes(math.prod(rows), tp)
+        params = state.params, state_shardings.params
+        step_bytes = (
+            _bytes_a_chip(state, state_shardings)            # the state
+            + _bytes_a_chip(*params) * (1 + accumulating)    # gradients
+            + _bytes_a_chip(*params, cast=cfg.dtype)         # casts
+            + cfg.logits_bytes(*rows, tp))
+        policy = remat.choose_remat_policy(
+            cfg.remat_policy, kept_bytes=kept, step_bytes=step_bytes,
+            limit_bytes=memory_limit_bytes(mesh.devices.flat))
+        _record_remat_choice(policy, kept)
+        cfg = dataclasses.replace(cfg, remat_policy=policy)
+    if cfg is getattr(pm.module, "cfg", None):
+        return pm.module
+    return pm.module.clone(cfg=cfg)
 
 
 def make_train_step(
@@ -422,7 +490,11 @@ def make_train_step(
     custom ``loss_fn``/``grad_fn``, loss and grads come from GSPMD. Like
     ``grad_accum_steps``, the explicit path's loss over data-parallel ranks
     is the mean of their means: the global mean when ranks carry equal
-    valid-token counts.
+    valid-token counts. Where the rings engage the step also shards the
+    residual stream over the sequence (``_module_for_step``: the model's
+    ``sequence_parallel`` on the clone it differentiates, unless a
+    ``dropout_rng`` is threaded or the model cannot take it), so that a
+    row-parallel exit's all-gather rides inside the next projection's ring.
 
     ``compression``: a ``parallel.CompressionConfig`` (typically
     ``comm_compressed.from_config(pm.config)``) switching gradient
@@ -557,16 +629,21 @@ def make_train_step(
         """The module the explicit path differentiates where this step
         takes it (the shapes decide, at trace time), else None. There the
         axes are bound and a chip's rows are known: what the layers keep
-        follows the chip's bytes."""
-        if explicit_grad is None or not (
-                compression is not None
-                or _tp_rings_engage(pm, mesh, batch)):
+        follows the chip's bytes, and where the rings engage the residual
+        stream is sharded over the sequence (a dropout stream is shared
+        across tp, so a step that threads one keeps the stream whole;
+        ``compression=`` alone engages no ring and changes no layout)."""
+        if explicit_grad is None:
+            return None
+        rings = _tp_rings_engage(pm, mesh, batch)
+        if compression is None and not rings:
             return None
         batch_rows, seq = batch_shardings.shard_shape(
             jnp.shape(batch["input_ids"]))
         return _module_for_step(
             pm, mesh, state, state_shardings,
-            (batch_rows // grad_accum_steps, seq), grad_accum_steps > 1)
+            (batch_rows // grad_accum_steps, seq), grad_accum_steps > 1,
+            rings=rings and dropout_rng is None)
 
     def one_grad(params, batch, rngs=None, err=None, bound=None):
         """→ ``(loss, grads, new_err)``; ``err`` passes through untouched

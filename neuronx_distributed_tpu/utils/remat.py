@@ -94,7 +94,12 @@ microbatches of 4,096 tokens:
     10      2       6.845   13.83       rich    12.96      13.39
     11      2       7.455   15.06       lean    14.10      14.60
 
-against 9/10 of the limit, 14.17 GiB. With these, over 7, 9 and 10
+against 9/10 of the limit, 14.17 GiB. (Since PR 65 the bound step shards
+the residual stream over the sequence where its rings engage,
+``trainer._module_for_step``: the boundary "full" keeps is 16 MiB a layer
+for 64 and the 11-layer job peaks at 11.71 GiB lean and 12.81 rich. The
+pair's bytes do not depend on that layout, so the rule's terms stand and
+its sum errs higher by 48 MiB a layer.) With these, over 7, 9 and 10
 layers, one and two passes, a vocabulary of 32,768 and of 131,072 and
 the loss whole and in chunks of 512, 28 jobs: the rule chose rich in
 15, whose rich step leaves 2.35 GiB or more, and lean in 13: two that

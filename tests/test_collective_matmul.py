@@ -288,6 +288,127 @@ def test_tuple_kernels_share_one_gathered_stream():
     _assert_trees_equal(run("decomposed"), run("monolithic"))
 
 
+def _gather_ring_case(tp, dtype, bidi, kernels, wire):
+    """Inputs of one all-gather ring: ``x`` sharded over the sequence,
+    ``kernels`` column-parallel kernels of unlike widths, the wire."""
+    rng = np.random.RandomState(7)
+    x = jnp.asarray(rng.randn(2, 4 * tp, 32), dtype)
+    ws = tuple(jnp.asarray(rng.randn(32, f * tp), dtype)
+               for f in (6, 3, 5)[:kernels])
+    return x, ws, cm.wire_config(wire, 16)
+
+
+@pytest.mark.parametrize("wire", [None, "int8"], ids=["full_wire", "int8"])
+@pytest.mark.parametrize("kernels", [1, 3], ids=["one_kernel", "three"])
+@pytest.mark.parametrize("bidi", [False, True], ids=["one_stream", "two"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("tp", [2, 4])
+def test_the_gather_ring_places_every_product_where_the_gather_has_it(
+        tp, dtype, bidi, kernels, wire):
+    """The ring writes each block's product once, into its own index of a
+    block dimension: the outputs and every kernel's gradient (the gather
+    forms) are the monolithic gather's to the bit, whatever the dtype, the
+    streams, the kernels that share the ring and the wire. ``x``'s gradient
+    is the dual, a reduce-scatter: the bit in float32, rounding below it,
+    where that ring adds as it forwards."""
+    mesh = _tp_mesh(tp)
+    x, ws, wire = _gather_ring_case(tp, dtype, bidi, kernels, wire)
+
+    def run(impl):
+        def f(xl, wl):
+            def loss(xv, wv):
+                ys = cm.all_gather_matmul(xv, wv, "tp", 1, impl=impl,
+                                          bidirectional=bidi, wire=wire)
+                return sum(jnp.sum(jnp.sin(y.astype(jnp.float32)))
+                           for y in ys), ys
+
+            (_, ys), grads = jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True)(xl, wl)
+            return ys, grads
+
+        w_specs = (P(None, "tp"),) * kernels
+        return _jit_shard(
+            f, mesh, (P(None, "tp", None), w_specs),
+            ((P(None, None, "tp"),) * kernels,
+             (P(None, "tp", None), w_specs)))(x, ws)
+
+    (ys, (dx, dws)), (ys_mono, (dx_mono, dws_mono)) = (
+        run("decomposed"), run("monolithic"))
+    assert ys[0].shape == (2, 4 * tp, 6 * tp)
+    _assert_trees_equal((ys, dws), (ys_mono, dws_mono))
+    if dtype == jnp.float32:
+        _assert_trees_equal(dx, dx_mono)
+    else:
+        want = np.asarray(dx_mono, np.float32)
+        np.testing.assert_allclose(np.asarray(dx, np.float32), want, rtol=0,
+                                   atol=0.03 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("bidi", [False, True], ids=["one_stream", "two"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("tp", [2, 4])
+def test_a_row_exits_input_gradient_rides_the_same_ring(tp, dtype, bidi):
+    """``matmul_reduce_scatter``'s backward runs the all-gather ring for
+    ``dx`` (``_mm_rs_bwd``): under one cotangent it is the monolithic
+    gather's to the bit, and ``dw`` with it."""
+    mesh = _tp_mesh(tp)
+    rng = np.random.RandomState(8)
+    x = jnp.asarray(rng.randn(2, 4 * tp, 8 * tp), dtype)
+    w = jnp.asarray(rng.randn(8 * tp, 6), dtype)
+    c = jnp.asarray(rng.randn(2, 4 * tp, 6), dtype)
+
+    def run(impl):
+        def f(xl, wl, cl):
+            # linear in y: the cotangent is ``cl`` under either forward
+            return jax.grad(lambda xv, wv: jnp.sum(
+                (cm.matmul_reduce_scatter(xv, wv, "tp", 1, impl=impl,
+                                          bidirectional=bidi)
+                 * cl).astype(jnp.float32)), argnums=(0, 1))(xl, wl)
+
+        specs = (P(None, None, "tp"), P("tp", None))
+        return _jit_shard(f, mesh, specs + (P(None, "tp", None),),
+                          specs)(x, w, c)
+
+    _assert_trees_equal(run("decomposed"), run("monolithic"))
+
+
+def test_the_gather_ring_writes_a_product_into_its_own_block():
+    """The lowered ring at tp=4, three kernels: a product is one index of a
+    block dimension ``[2, 4, l, f]`` and the output is that buffer
+    reshaped, so the compiler's matmul writes the block where it stays. The
+    parent updated rows ``src * l`` of ``[2, 4 * l, f]``, four updates a
+    kernel into a zeroed output of the output's shape, and XLA copied the
+    whole output at each (26 ms of the train cell's step: PERF.md)."""
+    tp, kernels = 4, 3
+    mesh = _tp_mesh(tp)
+    x, ws, _ = _gather_ring_case(tp, jnp.float32, None, kernels, None)
+
+    def f(xl, wl):
+        return cm.all_gather_matmul(xl, wl, "tp", 1, impl="decomposed")
+
+    w_specs = (P(None, "tp"),) * kernels
+    text = jax.jit(ps.shard_map(
+        f, mesh, in_specs=(P(None, "tp", None), w_specs),
+        out_specs=(P(None, None, "tp"),) * kernels)).lower(x, ws).as_text()
+    updates = re.findall(
+        r"stablehlo\.dynamic_update_slice .*: \(tensor<([\dx]+)xf32>, "
+        r"tensor<([\dx]+)xf32>", text)
+    widths = sorted(w.shape[1] // tp for w in ws)
+    # one update a product, each a whole block of its buffer
+    assert sorted(updates) == sorted(
+        (f"2x{tp}x4x{f}", f"2x1x4x{f}") for f in widths for _ in range(tp))
+    for f in widths:
+        # nothing of the output's shape is zeroed or updated in place
+        assert not re.search(
+            rf"(broadcast_in_dim|constant|dynamic_update_slice).*"
+            rf"-> tensor<2x{4 * tp}x{f}xf32>", text)
+        assert len(re.findall(
+            rf"stablehlo\.reshape .*tensor<2x{tp}x4x{f}xf32>\) -> "
+            rf"tensor<2x{4 * tp}x{f}xf32>", text)) == 1
+
+
 # ---------------------------------------------------------------------------
 # fallback + engagement resolution (static on shapes, never an error)
 # ---------------------------------------------------------------------------

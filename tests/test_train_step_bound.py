@@ -4,8 +4,12 @@
 inside ``shard_map`` when the tp axis has ``cm.MIN_AUTO_AXIS_SIZE`` ranks
 and the batch's sequence tiles over it (``trainer._tp_rings_engage``), so
 the projections take their decomposed collective-matmuls; below that it is
-the GSPMD step, text for text. Tiny llama, float32, the 8-device CPU mesh
-(tp=4 x dp=2), full remat and ZeRO-1 as the train cell has them.
+the GSPMD step, text for text. There it also shards the residual stream
+over the sequence (``trainer._sharded_stream_cfg``): a row-parallel exit is
+its reduce-scatter ring and the next entry's all-gather ring carries the
+rest, where the model can take it and no dropout stream is threaded. Tiny
+llama, float32, the 8-device CPU mesh (tp=4 x dp=2), full remat and ZeRO-1
+as the train cell has them.
 """
 
 import dataclasses
@@ -27,7 +31,7 @@ from neuronx_distributed_tpu.trainer import trainer as tr
 from remat_checks import assert_flash_forward_runs, flash_kernel_calls
 
 
-def _step_and_state(tp=4, seq=16, model_kw=None, **cfg_kw):
+def _step_and_state(tp=4, seq=16, model_kw=None, step_kw=None, **cfg_kw):
     ps.destroy_model_parallel()
     cfg = nxd.neuronx_distributed_config(
         tensor_parallel_size=tp,
@@ -50,7 +54,8 @@ def _step_and_state(tp=4, seq=16, model_kw=None, **cfg_kw):
                                            batch["input_ids"])
     tx, state, sh = initialize_parallel_optimizer(pm, params,
                                                   learning_rate=1e-2)
-    return make_train_step(pm, tx, sh, donate=False), state, batch
+    return make_train_step(pm, tx, sh, donate=False,
+                           **(step_kw or {})), state, batch
 
 
 def _gspmd(monkeypatch):
@@ -70,23 +75,52 @@ def _run(step, state, batch, steps=3):
     return losses, moments
 
 
-def _scan_body_primitives(jaxpr, inside=False, out=None):
-    """Names of the primitives inside the ``scan`` bodies of a jaxpr: the
-    layer scan's forward and its backward."""
-    out = set() if out is None else out
+def _scan_bodies(jaxpr, out=None):
+    """``[{(primitive, result shapes)}]`` of a jaxpr's ``scan`` bodies, one
+    set a scan in program order with what its body nests: the layer scan's
+    forward, then its backward with the recomputation."""
+    out = [] if out is None else out
     for eqn in jaxpr.eqns:
-        here = inside or eqn.primitive.name == "scan"
-        if inside:
-            out.add(eqn.primitive.name)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _scan_body_primitives(sub, here, out)
+        if eqn.primitive.name == "scan":
+            out.append(_inside(eqn, set()))
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _scan_bodies(sub, out)
     return out
+
+
+def _inside(eqn, seen):
+    for sub in jax.core.jaxprs_in_params(eqn.params):
+        for inner in sub.eqns:
+            seen.add((inner.primitive.name,
+                      tuple(tuple(v.aval.shape) for v in inner.outvars)))
+            _inside(inner, seen)
+    return seen
+
+
+def _scan_body_primitives(jaxpr):
+    return {name for body in _scan_bodies(jaxpr) for name, _ in body}
+
+
+def _of_shape(body, primitive, shape):
+    return [s for name, shapes in body if name == primitive
+            for s in shapes if s == shape]
 
 
 def test_default_step_at_tp4_is_the_bound_step_and_equals_gspmd(monkeypatch):
     step, state, batch = _step_and_state()
-    names = _scan_body_primitives(jax.make_jaxpr(step)(state, batch).jaxpr)
-    assert "ppermute" in names and "psum" not in names, sorted(names)
+    forward, backward = _scan_bodies(
+        jax.make_jaxpr(step)(state, batch).jaxpr)
+    # the residual of one data-parallel rank, whole: [2, 16, hidden]. The
+    # forward's layers reduce and gather nothing of that shape, only hops
+    # of a ring; the backward all-gathers it for dW alone (section 7 of
+    # PERF.md: the entries' x, the exits' cotangent), and no scan
+    # all-reduces it
+    residual = (2, 16, tiny_config().hidden_size)
+    for body in (forward, backward):
+        assert any(name == "ppermute" for name, _ in body)
+        assert not _of_shape(body, "psum", residual), sorted(body)
+    assert not _of_shape(forward, "all_gather", residual), sorted(forward)
     assert "collective_permute" in step.lower(state, batch).as_text()
     bound = _run(step, state, batch)
 
@@ -134,20 +168,78 @@ def test_below_the_rule_the_step_is_the_gspmd_step(monkeypatch, kw):
     assert "collective_permute" not in text
 
 
-def test_the_bound_step_counts_its_ring_decisions():
+def _reduced_sync():
+    return dict(model_kw=dict(scan_layers=False),
+                tp_activation_sync_fraction=0.5)
+
+
+def _lora():
+    from neuronx_distributed_tpu.lora import LoraConfig
+
+    return dict(model_kw=dict(lora=LoraConfig(
+        r=4, target_modules=("qkv", "o_proj"))))
+
+
+def _dropout_rng():
+    return dict(step_kw=dict(dropout_rng=jax.random.key(0)),
+                model_kw=dict(attention_dropout=0.1))
+
+
+def _the_models_own_setting():
+    return dict(sequence_parallel=True)
+
+
+def _compression_alone():
+    from neuronx_distributed_tpu.parallel import comm_compressed
+
+    return dict(tp=2, step_kw=dict(
+        compression=comm_compressed.CompressionConfig(
+            dtype="int8", error_feedback=False)))
+
+
+@pytest.mark.parametrize("case", [
+    _reduced_sync, _lora, _dropout_rng, _the_models_own_setting,
+    _compression_alone], ids=lambda case: case.__name__.strip("_"))
+def test_a_step_that_cannot_shard_its_stream_is_the_step_it_was(
+        monkeypatch, case):
+    """The bound step where the residual stream stays as the model has it:
+    exits that a reduce-scatter cannot elide, adapters that read the
+    gathered activations, a dropout stream shared across tp, a model that
+    shards the stream itself, and ``compression=`` below the rings' rule
+    (the explicit path with no ring to hide a gather in). Each lowers the
+    text it lowers with the layout's choice taken out."""
+    step, state, batch = _step_and_state(**case())
+    text = step.lower(state, batch).as_text()
+    # the explicit path all the same: shard_map's manual axes
+    assert "sdy.manual_computation" in text or "shard_map" in text
+    monkeypatch.setattr(tr, "_sharded_stream_cfg", lambda cfg: None)
+    step, state, batch = _step_and_state(**case())
+    assert text == step.lower(state, batch).as_text()
+
+
+@pytest.mark.parametrize("case,ops,shards", [
+    (dict, {"all_gather_matmul", "matmul_reduce_scatter"}, 4),
+    (_dropout_rng, {"copy_matmul", "matmul_all_reduce"}, 1)],
+    ids=["sharded", "replicated"])
+def test_the_bound_step_counts_its_ring_decisions(case, ops, shards):
+    """With the stream sharded over the sequence q/k/v, gate/up and the
+    head enter through ``all_gather_matmul`` and ``o_proj`` and ``down``
+    leave through ``matmul_reduce_scatter`` (the layer scan's body is one
+    site each): no exit is an all-reduce with a gather standing behind it.
+    The step as it was (a ``dropout_rng``) enters through ``copy_matmul``
+    and leaves through ``matmul_all_reduce``. The gauge says which."""
     obs.reset()
     obs.enable()
     try:
-        step, state, batch = _step_and_state()
+        step, state, batch = _step_and_state(**case())
         step.lower(state, batch)
-        counter = obs.get_registry().get("nxd_tp_collective_matmuls_total")
-        seen = {(c.labels["impl"], c.labels["op"]): c.value
-                for c in counter.children()}
+        registry = obs.get_registry()
+        seen = {(c.labels["impl"], c.labels["op"]): c.value for c in
+                registry.get("nxd_tp_collective_matmuls_total").children()}
+        gauge = registry.get("nxd_train_residual_sequence_shards").value
     finally:
         obs.disable()
         obs.reset()
-    # o_proj and down leave through matmul_all_reduce, q/k/v and gate/up
-    # enter through copy_matmul; the layer scan's body is one site each
-    assert set(seen) == {("decomposed", "matmul_all_reduce"),
-                         ("decomposed", "copy_matmul")}, seen
+    assert set(seen) == {("decomposed", op) for op in ops}, seen
     assert all(v >= 2 for v in seen.values()), seen
+    assert gauge == shards
