@@ -34,6 +34,17 @@ def say(tag: str, **fields) -> None:
           flush=True)
 
 
+#: every number the run's check compared, beside its limit, by a short
+#: plain name and in the order compared: ``{name: [number, limit]}``.
+#: ``run.py`` prints it last, on standard error and in the result's line.
+COMPARED: Dict[str, List[float]] = {}
+
+
+def compared(name: str, number: float, limit: float) -> None:
+    """Keep one number of the check of ``correct`` beside its limit."""
+    COMPARED[name] = [float(number), float(limit)]
+
+
 def read_json(path: str) -> Any:
     with open(path) as f:
         return json.load(f)

@@ -36,4 +36,6 @@ def work(obs):
 
 
 def read(args: dict, obs):
+    if "num_layers" not in obs.config:     # no double layers: nothing to read
+        return None
     return mla_roofline.read(args, _an_attention_a_layer(obs))

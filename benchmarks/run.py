@@ -8,8 +8,10 @@ configuration file the manifest names, the traffic mix at
 ``benchmarks/runners/<config.runner>.py`` and, in a traced run, each
 per-layer metric at ``benchmarks/layer_metrics/<name>.json`` with its
 reader at ``benchmarks/readers/<kind>.py``. The last line of standard
-output is the result; anything else worth keeping is on the lines before
-it or under ``benchmarks/out/``. No TPU, too few chips, or a device that
+output is the result, whose last key ``compared`` holds each number the
+check compared beside its limit (they are the last lines of standard
+error too); anything else worth keeping is on the lines before it or
+under ``benchmarks/out/``. No TPU, too few chips, or a device that
 ``peaks.json`` does not know, is a non-zero exit and no result.
 """
 
@@ -140,7 +142,13 @@ def main(argv=None) -> int:
         line["breakdown"] = {
             "device_ops": [[n, s] for n, s in xplane.top_ops(red, 10)],
             "idle_gaps": [[n, s] for n, s in red.idle_gaps[:10]]}
+    # each number compared beside its limit: the result's last key, and
+    # the last lines of standard error
+    line["compared"] = dict(harness.COMPARED)
     print(json.dumps(line), flush=True)
+    for name, (number, limit) in line["compared"].items():
+        print(f"compared {name} {number} limit {limit}", file=sys.stderr,
+              flush=True)
     return 0
 
 

@@ -332,25 +332,50 @@ def _end_to_end(cell, engine, sample, requests, uids, open_loop, tokens,
 
 # -- the logit check ---------------------------------------------------------
 
-def probe_schedule(prompt_len: int, decode: int, width: int
-                   ) -> List[List[Tuple[int, int]]]:
+def probe_schedule(prompt_len: int, decode: int, width: int, group: int = 1,
+                   rewrite: bool = False) -> List[List[Tuple[int, int]]]:
     """Rows ``(sequence, position)`` of each packed step for two sequences,
     packed as the engine packs: decode rows first, prefill chunks in what
     is left of ``width``. Sequence 0 prefills alone; sequence 1 prefills
-    beside sequence 0's decode rows; then both decode among pad rows."""
+    beside sequence 0's decode rows; then both decode among pad rows.
+
+    With ``group`` g the positions ``[k * g, (k + 1) * g)`` of a sequence
+    enter in one step: a decoding sequence gives its next g rows a step,
+    and a prefill chunk is what is left of ``width`` cut down to a multiple
+    of g. A length that g does not divide, or a width that cannot hold two
+    groups beside a chunk of one, is refused (g = 1 takes every width, as
+    it always did). Under ``rewrite`` a decode group enters twice: the step
+    before its own rows as ``(sequence + 2, position)``, a first writing
+    of the same positions of the same slot under other tokens."""
+    if group < 1:
+        raise BenchError(f"logit_check.group wants 1 or more, got {group}")
+    if group > 1:
+        for key, n in (("prompt_tokens", prompt_len),
+                       ("decode_steps", decode)):
+            if n % group:
+                raise BenchError(f"logit_check.{key} {n} is not a multiple "
+                                 f"of logit_check.group {group}")
+        if width < 3 * group:
+            raise BenchError(
+                f"token_budget {width} is under three of logit_check.group "
+                f"{group}: two decode groups leave no room for a chunk")
     steps, done, total = [], [0, 0], prompt_len + decode
+    drafted = [False, False]
     while min(done) < total:
         rows = []
         for s in (0, 1):
             if prompt_len <= done[s] < total and (s == 0 or done[0] > 0):
-                rows.append((s, done[s]))
+                drafted[s] = rewrite and not drafted[s]
+                rows += [(s + 2 * drafted[s], done[s] + i)
+                         for i in range(group)]
         for s in (0, 1):
             if done[s] < prompt_len and (s == 0 or done[0] >= prompt_len):
                 n = min(width - len(rows), prompt_len - done[s])
-                rows += [(s, done[s] + i) for i in range(n)]
+                rows += [(s, done[s] + i) for i in range(n - n % group)]
                 break
         for s, p in rows:
-            done[s] = max(done[s], p + 1)
+            if s < 2:
+                done[s] = max(done[s], p + 1)
         steps.append(rows)
     return steps
 
@@ -393,7 +418,12 @@ def probe_logits(seed: int, mcfg, forward, params, ecfg, chk):
     decode rows and pad rows; before a row runs, the columns its cache
     kind names for its position are mapped to fresh blocks in order, as
     the engine maps them): ``(tokens [2, S], logits [2, P, V])``, the
-    logits at the ``P`` positions of :func:`compared_positions`."""
+    logits at the ``P`` positions of :func:`compared_positions`.
+
+    ``logit_check.group`` (default 1) is the row group of
+    :func:`probe_schedule`; under ``logit_check.rewrite`` each decode
+    group is written first with other tokens drawn from ``[seed, 2]``,
+    whose logits are dropped, and in the next step with its own."""
     import functools
 
     import jax
@@ -403,8 +433,18 @@ def probe_logits(seed: int, mcfg, forward, params, ecfg, chk):
 
     plen, ndec, width = chk["prompt_tokens"], chk["decode_steps"], \
         ecfg.token_budget
+    group, rewrite = int(chk.get("group", 1)), bool(chk.get("rewrite"))
+    if "group" in chk or "rewrite" in chk:
+        say("check", group=group, rewrite=rewrite)
     rng = np.random.default_rng([seed, 1])
     seqs = rng.integers(0, mcfg.vocab_size, (2, plen + ndec))
+    fed = seqs
+    if rewrite:
+        # rows (s + 2, p) of the schedule: a first writing, never sequence
+        # s's own token
+        fed = np.concatenate([seqs, (seqs + np.random.default_rng(
+            [seed, 2]).integers(1, mcfg.vocab_size, seqs.shape))
+            % mcfg.vocab_size])
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def probe(params, cache, tokens, positions, slot_ids):
@@ -419,16 +459,16 @@ def probe_logits(seed: int, mcfg, forward, params, ecfg, chk):
     row_of = np.full(plen + ndec, -1)
     row_of[compared] = np.arange(compared.size)
     got = np.zeros((2, compared.size, mcfg.vocab_size), np.float32)
-    for rows in probe_schedule(plen, ndec, width):
+    for rows in probe_schedule(plen, ndec, width, group, rewrite):
         tok = np.zeros((1, width), np.int32)
         pos = np.full((1, width), PAD_POSITION, np.int32)
         slot = np.full((width,), ecfg.max_slots, np.int32)
         before = mapped
         for i, (s, p) in enumerate(rows):
-            tok[0, i], pos[0, i], slot[i] = seqs[s, p], p, s
+            tok[0, i], pos[0, i], slot[i] = fed[s, p], p, s % 2
             for column in kind.columns_to_map(p, ecfg.block_size):
-                if table[s, column] < 0:
-                    table[s, column], mapped = mapped, mapped + 1
+                if table[s % 2, column] < 0:
+                    table[s % 2, column], mapped = mapped, mapped + 1
         if mapped > ecfg.num_blocks:
             raise BenchError(
                 f"the logit check's two sequences need {mapped} blocks "
@@ -439,7 +479,7 @@ def probe_logits(seed: int, mcfg, forward, params, ecfg, chk):
                               jnp.asarray(pos), jnp.asarray(slot))
         logits = np.asarray(logits)
         for i, (s, p) in enumerate(rows):
-            if row_of[p] >= 0:
+            if s < 2 and row_of[p] >= 0:
                 got[s, row_of[p]] = logits[i]
     del cache
     return seqs, got
@@ -486,6 +526,10 @@ def judge_logits(got, want, chk) -> List[str]:
         say("limits", part=part, median_rel_err=chk["typical_rtol"],
             share_over_outlier_rtol=chk["outlier_share"][part],
             outlier_rtol=chk["outlier_rtol"])
+        harness.compared(f"{part}.median_rel_err", typical,
+                         chk["typical_rtol"])
+        harness.compared(f"{part}.share_over_outlier_rtol", outliers,
+                         chk["outlier_share"][part])
         if not np.isfinite(e).all():
             why.append(f"logit check ({part}): non-finite logits")
         if typical > chk["typical_rtol"]:

@@ -129,6 +129,7 @@ def run(cell) -> RunResult:
         del want_logits
         say("check", sequence=i, loss=got, reference_loss=want,
             abs_diff=abs(got - want), atol=atol)
+        harness.compared(f"sequence_{i}.abs_loss_diff", abs(got - want), atol)
         if not abs(got - want) <= atol:
             why.append(f"loss of sequence {i} of the first batch, {got}, "
                        f"differs from the reference's {want} by more "

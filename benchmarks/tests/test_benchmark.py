@@ -493,8 +493,15 @@ def test_rehearsal_runs_end_to_end(cell, trace, env):
               str(2 ** 31 + 7), "--seconds", "1.5", "--trace", trace], env)
     assert p.returncode == 0, p.stderr[-2000:]
     line = _last_json(p.stdout)
-    assert set(line) == {"correct", "attempted", "failed", "metrics",
-                         "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    # each number compared beside its limit: the result's last key, and
+    # the last lines of standard error
+    assert line["compared"] and all(
+        number <= limit for number, limit in line["compared"].values())
+    said = p.stderr.strip().splitlines()[-len(line["compared"]):]
+    assert said == [f"compared {name} {number} limit {limit}"
+                    for name, (number, limit) in line["compared"].items()]
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["device"]["platform"] == "cpu"
     # never under a device metric's name

@@ -19,6 +19,7 @@ ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402
+from manifest_checks import brought_for  # noqa: E402
 
 REAL = "glm-4.7-flash.serve-agentic"
 CELL = "tiny-glm-moe-lite.serve-agentic"
@@ -68,9 +69,8 @@ def test_the_manifest_holds_the_cell_its_metrics_and_its_files():
     real = harness.load_manifest()
     shared = _shared(real)
     assert set(NEW_METRICS) < set(shared) and "serve_tok_s" in shared
-    listed = [x for x in real["per_layer"]
-              if x.get("workloads") == [REAL]]
-    assert sorted(x["name"] for x in listed) == sorted(NEW_METRICS)
+    # a later PR's metrics of the same kernel head their lists with it too
+    assert set(NEW_METRICS) <= set(brought_for(real, REAL))
     cell = harness.by_name(real["workloads"], REAL, "cell")
     assert (cell["chips"], cell["traffic"]) == (1, "offline-agentic-code")
     traffic = harness.read_json(harness.data_file("traffic",

@@ -18,6 +18,7 @@ ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402
+from manifest_checks import begins_with  # noqa: E402
 
 REAL = "granite-4.0-h-micro.serve-longgen"
 CELL = "tiny-granite-hybrid.serve-longgen"
@@ -99,7 +100,7 @@ def test_the_cell_its_mix_and_its_metrics_are_in_the_manifest():
         assert harness.load_plugin("readers", spec["reader"]["kind"]).read
     for name in NEW_METRICS:
         listed = harness.by_name(real["per_layer"], name, "metric")
-        assert listed["workloads"] == [REAL]
+        assert begins_with(listed, [REAL])
         assert listed["moves"] == "serve_tok_s"
 
 

@@ -38,7 +38,7 @@ def _manifest(tmp_path) -> str:
                            "why": "rehearsal of " + REAL})
     shared = [x["name"] for x in real["end_to_end"] + real["per_layer"]
               if REAL in x.get("workloads", ())]
-    assert set(NEW_METRICS) < set(shared) and len(shared) == 16
+    assert set(NEW_METRICS) < set(shared)
     have = {x["name"] for x in m["end_to_end"] + m["per_layer"]}
     for x in m["end_to_end"] + m["per_layer"]:
         if x["name"] in shared:

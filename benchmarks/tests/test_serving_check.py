@@ -7,6 +7,7 @@ from the cache kind, and a long context is compared at chosen positions.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import sys
@@ -55,13 +56,10 @@ class StateCache(paging.PagedKVCache):
 class StateKind(paging.FullCache):
     name = "full_with_state"
 
-    def init_cache(self, mcfg, num_blocks, block_size, table_rows,
-                   max_blocks_per_seq, dtype):
-        pool = paging.init_paged_kv_cache(
-            mcfg.num_layers, num_blocks, block_size, mcfg.num_kv_heads,
-            mcfg.head_dim_, table_rows, max_blocks_per_seq, dtype=dtype)
+    def init_cache(self, model_cfg, **geometry):
+        pool = super().init_cache(model_cfg, **geometry)
         return StateCache(
-            state=jnp.zeros((table_rows,), jnp.float32),
+            state=jnp.zeros((geometry["table_rows"],), jnp.float32),
             **{f.name: getattr(pool, f.name)
                for f in dataclasses.fields(pool)})
 
@@ -112,57 +110,62 @@ def _spy(forward, leaf: str, seen: list):
     return spying
 
 
-def test_a_family_with_a_cache_of_its_own_is_checked_from_files_only(
-        tmp_path, monkeypatch):
-    """A family file (with its cache kind over ``FullCache`` and the cache
-    that kind builds), a reference file, a configuration and two manifest
-    entries; no file that was there is edited. The probe's cache is the
-    family's, because the probe takes it from ``ServingEngine``'s
-    constructor, and its extra leaf comes through every packed step.
-
-    The package's ``ServingEngine._init_cache`` builds every family's
-    cache itself today; the patch below is the two lines by which it
-    would ask the kind instead (left to a later PR: ``PERF.md``, section
-    7). The harness needs no edit either way."""
-    import jax
-
-    from neuronx_distributed_tpu.inference.engine import ServingEngine
-
-    config = harness.read_json(os.path.join(HERE, "configs",
-                                            "tiny-mistral-serve.json"))
-    config.update(family="stateful_family", reference="stateful_reference")
-    config["serve"]["logit_check"]["compare"] = {"every": 4, "tail": 6}
+@contextlib.contextmanager
+def _added(tmp_path, config: dict, family: str, reference: str):
+    """A family file, a reference file, a configuration and two manifest
+    entries, added and taken away again; no file that was there is edited.
+    Yields the manifest and the cell's name."""
+    name = config["family"].replace("_", "-")
     added = {
-        os.path.join(BENCH, "families", "stateful_family.py"): FAMILY,
-        os.path.join(BENCH, "reference", "stateful_reference.py"): REFERENCE,
-        os.path.join(HERE, "configs", "stateful-arch.json"):
-            json.dumps(config)}
+        os.path.join(BENCH, "families", config["family"] + ".py"): family,
+        os.path.join(BENCH, "reference", config["reference"] + ".py"):
+            reference,
+        os.path.join(HERE, "configs", name + ".json"): json.dumps(config)}
     m = harness.load_manifest(os.path.join(HERE, "manifest.json"))
-    m["configs"].append({"name": "stateful-arch", "source": "none (rehearsal)",
-                         "file": "benchmarks/tests/configs/stateful-arch.json",
+    m["configs"].append({"name": name, "source": "none (rehearsal)",
+                         "file": f"benchmarks/tests/configs/{name}.json",
                          "reduced": [], "why": "added by the test"})
-    m["workloads"].append({"name": "stateful-arch.serve-batch",
-                           "config": "stateful-arch",
+    m["workloads"].append({"name": name + ".serve-batch", "config": name,
                            "traffic": "tiny-offline", "chips": 1,
                            "why": "added by the test"})
     manifest = tmp_path / "manifest.json"
     manifest.write_text(json.dumps(m))
-
-    def init_cache(self):
-        e = self.ecfg
-        self._sharding = jax.devices()[0]
-        return jax.device_put(self._cache_kind.init_cache(
-            self.model_cfg, self._pool_blocks, e.block_size,
-            self._table_rows, e.max_blocks_per_seq,
-            e.kv_dtype or self.model_cfg.dtype), self._sharding)
-
-    monkeypatch.setattr(ServingEngine, "_init_cache", init_cache)
     try:
         for path, body in added.items():
             assert not os.path.exists(path)
             with open(path, "w") as f:
                 f.write(body)
-        cell = _cell(str(manifest), "stateful-arch.serve-batch")
+        yield str(manifest), name + ".serve-batch"
+    finally:
+        for path in added:
+            if os.path.exists(path):
+                os.remove(path)
+        sys.modules.pop("families." + config["family"], None)
+        sys.modules.pop("reference." + config["reference"], None)
+
+
+def _tiny_config(**keys) -> dict:
+    config = harness.read_json(os.path.join(HERE, "configs",
+                                            "tiny-mistral-serve.json"))
+    check = keys.pop("logit_check", {})
+    config.update(keys)
+    config["serve"]["logit_check"].update(check)
+    return config
+
+
+def test_a_family_with_a_cache_of_its_own_is_checked_from_files_only(
+        tmp_path):
+    """A family file (with its cache kind over ``FullCache`` and the cache
+    that kind builds), a reference file, a configuration and two manifest
+    entries. The probe's cache is the family's, because the probe takes it
+    from ``ServingEngine``'s constructor, which asks the family's kind
+    (``inference/paging.init_serving_cache``), and its extra leaf comes
+    through every packed step."""
+    config = _tiny_config(family="stateful_family",
+                          reference="stateful_reference",
+                          logit_check={"compare": {"every": 4, "tail": 6}})
+    with _added(tmp_path, config, FAMILY, REFERENCE) as (manifest, name):
+        cell = _cell(manifest, name)
         family = harness.load_plugin("families", "stateful_family")
         mcfg, forward, params, ecfg = serve.prepare(cell)
         cache, kind = serve.serving_cache(mcfg, params, ecfg)
@@ -179,12 +182,6 @@ def test_a_family_with_a_cache_of_its_own_is_checked_from_files_only(
         steps = len(serve.probe_schedule(
             chk["prompt_tokens"], chk["decode_steps"], ecfg.token_budget))
         assert [float(s[0]) for s in states] == list(range(1, steps + 1))
-    finally:
-        for path in added:
-            if os.path.exists(path):
-                os.remove(path)
-        sys.modules.pop("families.stateful_family", None)
-        sys.modules.pop("reference.stateful_reference", None)
 
 
 @pytest.mark.parametrize("cell_name,compare", [

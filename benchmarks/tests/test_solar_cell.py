@@ -21,6 +21,7 @@ ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402
+from manifest_checks import begins_with  # noqa: E402
 
 REAL = "solar-open2-250b.serve-reasoning"
 GRANITE = "granite-4.0-h-micro.serve-longgen"
@@ -132,7 +133,7 @@ def test_the_manifest_holds_the_cell_its_metrics_and_its_files():
         assert harness.load_plugin("readers", spec["reader"]["kind"]).read
     for name in NEW_METRICS:
         metric = harness.by_name(real["per_layer"], name, "metric")
-        assert metric["workloads"] == [REAL]
+        assert begins_with(metric, [REAL])
         assert metric["moves"] == "serve_tok_s"
 
 

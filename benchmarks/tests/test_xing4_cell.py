@@ -19,6 +19,7 @@ ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
 import harness  # noqa: E402
+from manifest_checks import begins_with, file_holds_entry  # noqa: E402
 
 REAL = "xing4.0-29b-a4b.serve-longdocs"
 CELL = "tiny-xing4.serve-longdocs"
@@ -68,11 +69,12 @@ def test_the_manifest_holds_the_cell_its_mix_its_metric_and_its_files():
         "xing4.0-29b-a4b", "offline-long-docs-64k", 1)
     assert len(cell["why"]) <= 200 and "7 of 40 layers" in cell["why"]
     # found by name: a later cell or metric comes behind these
-    assert harness.by_name(real["per_layer"], "hc_share_pct.batch",
-                           "metric") == {
+    hc = harness.by_name(real["per_layer"], "hc_share_pct.batch", "metric")
+    assert begins_with(hc, [REAL])
+    assert file_holds_entry({
         "name": "hc_share_pct.batch", "unit": "%", "better": "lower",
         "source": "device_trace", "layer": "kernels",
-        "moves": "serve_tok_s", "workloads": [REAL]}
+        "moves": "serve_tok_s"}, hc)
     listed = _listed(real, REAL)
     assert {"serve_tok_s", "hc_share_pct.batch", "mla_attn_share_pct.batch",
             "mla_attention_roofline", "mla_run_fetch_pct.batch",
