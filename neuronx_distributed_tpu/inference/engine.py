@@ -21,6 +21,13 @@ request is preempted (blocks freed, restarted from its prompt later) —
 admission control rejects requests that could never fit. Finished slots
 (EOS / max tokens) free their blocks at the same step boundary, so new
 requests are admitted mid-flight.
+
+A family that decodes a block of positions at a time
+(``serving_family().block``: generation by diffusion over blocks) packs a
+decoding slot's whole block a step, keeps the block on the device between
+its passes and delivers it when its store pass has run:
+:mod:`.block_serving` holds what differs, and nothing of it runs for any
+other family.
 """
 
 from __future__ import annotations
@@ -51,6 +58,7 @@ from ..resilience.integrity import (
     kv_payload_fingerprints,
 )
 from .aot_cache import AotExecutableCache, AotWorker, source_fingerprint
+from .block_serving import BlockServing
 from .kv_cache import PAD_POSITION
 from .paging import (COUNT_LEAVES, PAYLOAD_BLOCK_AXES, BlockAllocator,
                      CacheExhaustedError, PrefixCache, cow_copy_blocks,
@@ -238,6 +246,11 @@ class _RequestState:
     take_row: int = -1
     finished: bool = False
     epoch: int = 0                  # restarts so far: older rows land stale
+    # a family that decodes blocks (``block_serving``): the prompt's whole
+    # blocks, which alone are prefilled (None: the whole prompt), and
+    # whether the device holds the request's block in progress
+    prefill_len: Optional[int] = None
+    block_started: bool = False
 
     @property
     def prompt_len(self) -> int:
@@ -250,7 +263,8 @@ class _RequestState:
     @property
     def decoding(self) -> bool:
         # prefill done and one sampled token waits to be fed back
-        return self.n_cached >= self.prompt_len
+        return self.n_cached >= (self.prompt_len if self.prefill_len is None
+                                 else self.prefill_len)
 
     def restart(self) -> None:
         self.generated = []
@@ -266,8 +280,10 @@ class _RequestState:
         self.spec_accepted = 0
         # a restart re-prefills, which re-warms the draft pool too
         self.spec_ok = True
-        # the token a row in flight samples is discarded with the others
+        # the token a row in flight samples is discarded with the others,
+        # and so is a half-done block
         self.in_flight = 0
+        self.block_started = False
         self.epoch += 1
 
 
@@ -552,9 +568,10 @@ class _InFlight:
     epochs: List[int]
     sampled: Any
     counts: Sequence[Tuple[Any, Any]] = ()
+    groups: int = 0     # decode groups leading ``rows`` (``block_serving``)
 
 
-class ServingEngine:
+class ServingEngine(BlockServing):
     """Request queue + slot map + token-budget scheduler over one
     compiled fixed-shape step.
 
@@ -595,6 +612,9 @@ class ServingEngine:
             if asked.get(feature):
                 self._refuse(feature)
         self._forward_fn = family.forward
+        #: how the family decodes a block of positions at a time (None: a
+        #: token a sequence a step); ``block_serving`` has what then differs
+        self._block = family.block
         # elastic-fleet hooks: an AOT cache makes worker construction
         # load-or-compile (replicas after the first spin up without
         # compiling); a name scopes this engine's obs compile-tracker
@@ -785,6 +805,8 @@ class ServingEngine:
             if engine_cfg.prefix_sharing else None)
         self.cache = self._init_cache()
         self.dcache = self._init_draft_cache()
+        if self._block is not None:
+            self._init_block()
         if cp > 1:
             # two workers, two fixed widths: the packed worker decodes
             # (and could chunk-prefill short prompts) at token_budget,
@@ -978,6 +1000,8 @@ class ServingEngine:
         on_accel = on_tpu()
         if self._cp > 1:
             return self._build_cp_step(prefill=False)
+        if self._block is not None:
+            return self._build_block_step()
         if self._spec is None:
             def step_fn(params, pool, held, tokens, positions, slot_ids,
                         prev, take, rng):
@@ -1206,7 +1230,9 @@ class ServingEngine:
                 # the step's own operands are part of the program: an
                 # executable cached for another signature must miss
                 source_fingerprint(self._forward_fn, sample,
-                                   ServingEngine._build_step, _hold_out),
+                                   ServingEngine._build_step, _hold_out,
+                                   *((BlockServing._build_block_step,)
+                                     if self._block is not None else ())),
                 params_spec) + spec_fp + cp_fp
 
     def _example_args(self, width: int):
@@ -1222,6 +1248,8 @@ class ServingEngine:
         if self._cp > 1:
             return (self.params, self.cache, tokens, positions, slot_ids,
                     self._rng)
+        if self._block is not None:
+            return self._block_example_args(width)
         return (self.params, *_hold_out(self.cache), tokens, positions,
                 slot_ids, self._prev_sampled(width),
                 jnp.full((width,), -1, jnp.int32), self._rng)
@@ -1306,6 +1334,9 @@ class ServingEngine:
         """Whether a request of this size could ever run on this engine
         (alone, with the whole pool to itself)."""
         total = int(prompt_len) + int(max_new_tokens)
+        if self._block is not None:
+            # the last block is run whole
+            total += -total % self._block.block_length
         if self._cp > 1 and prompt_len > self._cp_width:
             return False    # one ring pass must cover the whole prompt
         return prompt_len > 0 and total <= self.max_model_len()
@@ -1341,6 +1372,9 @@ class ServingEngine:
                 req, "never_fits",
                 f"{uid}: prompt_len={req.prompt_len} "
                 f"max_new={req.max_new_tokens} cannot fit this engine")
+        if self._block is not None:
+            req.prefill_len = (req.prompt_len
+                               - req.prompt_len % self._block.block_length)
         self._queue.append(req)
         self.stats.queue_depth = self.queue_depth()
         return uid
@@ -1946,6 +1980,8 @@ class ServingEngine:
         their decode advances through the draft/verify workers instead
         of a packed decode row."""
         e = self.ecfg
+        if self._block is not None:
+            return self._build_block_schedule()
         if self._cp > 1:
             decode_budget = e.token_budget
             prefill_budget = 0      # prompts go through the ring worker
@@ -2075,6 +2111,8 @@ class ServingEngine:
         caller's open span: two of its children split the host's packing
         from the uploads and the enqueue, the third is :meth:`_fetch`'s;
         ``step`` is the number of the ``step()`` call they belong to."""
+        if self._block is not None:
+            return self._dispatch_block(fn, rows, width, rng, span, step)
         tracer = get_tracer()
         with tracer.span(span + "/pack", step=step):
             tokens = np.zeros((1, width), np.int32)
@@ -2491,6 +2529,8 @@ class ServingEngine:
         blocks can be handed on: the device runs steps in order, so the
         next step's writes come after this one's reads). Its result is
         published when the token lands."""
+        if self._block is not None:
+            return self._note_block_enqueued(rows)
         for i, (req, _, pos, produce) in enumerate(rows):
             if req.decoding and pos == req.n_cached:
                 req.n_cached += 1
@@ -2508,6 +2548,8 @@ class ServingEngine:
         that row's token is dropped. A request preempted since the row was
         enqueued restarts from its prompt: the token is counted as
         generated, as the others it loses were, and dropped."""
+        if self._block is not None:
+            return self._land_block(flight, now)
         eos = self.ecfg.eos_id
         for i, ((req, _, _, produce), epoch) in enumerate(
                 zip(flight.rows, flight.epochs)):

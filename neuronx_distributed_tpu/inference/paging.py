@@ -312,6 +312,32 @@ MOE_IDENTITY = CounterFamily(
     "Counted on the device, fetched with the step's tokens.",
     ("identity", "routed"))
 
+BLOCK_PASSES = CounterFamily(
+    "nxd_block_passes_total",
+    "Passes of the decode groups of a family that decodes blocks (a slot's "
+    "block of positions run once) by what the pass was: denoise, begun "
+    "with a masked row, or store, begun with none, whose K/V later blocks "
+    "see. A pass an idle slot was packed for is none. Counted on the "
+    "device, fetched with the step's tokens.",
+    ("denoise", "store"))
+BLOCK_ROWS = CounterFamily(
+    "nxd_block_rows_total",
+    "Real rows of the decode groups, each once a pass, by what the pass "
+    "did with the row: uncovered it by_threshold (its confidence over the "
+    "threshold) or by_quota (among the pass's most confident where too few "
+    "were over it), left it masked, found it already_uncovered (a denoise "
+    "pass's rows uncovered earlier, the prompt's remainder among them), or "
+    "stored it (a store pass's rows). They sum to the block length times "
+    "nxd_block_passes_total. Counted on the device.",
+    ("by_threshold", "by_quota", "left_masked", "already_uncovered",
+     "stored"))
+BLOCKS_FINISHED = CounterFamily(
+    "nxd_blocks_finished_total",
+    "Blocks whose store pass ran. Counted on the device.")
+#: what the step of a family that decodes blocks counts
+#: (:attr:`ServingFamily.block`; ``block_serving``), behind its tokens
+BLOCK_COUNTERS = (BLOCK_PASSES, BLOCK_ROWS, BLOCKS_FINISHED)
+
 #: the ``moe_counts`` leaf of a family that declares it
 #: (:class:`ServingFamily`), as the kind that builds it lays it out: the
 #: assignments kept and dropped, and, where the device holds a share of
@@ -973,6 +999,25 @@ class WindowPoolCache(FullCache):
 
 
 @dataclasses.dataclass(frozen=True)
+class CountedFullCache(FullCache):
+    """:class:`FullCache` for a family of routed experts that declares
+    ``moe_counts`` and holds every expert: the cache is a
+    :class:`StatePoolPagedCache` over all the layers with no state leaf,
+    so that the step has a ``moe_counts [2]`` leaf to count into. Block
+    mapping, the host's counters and everything else are
+    :class:`FullCache`'s."""
+
+    moe_leaf = MOE_KEPT_DROPPED
+
+    def init_cache(self, model_cfg, *, quantized: bool = False, **geometry):
+        if quantized:
+            raise ValueError("a counted full cache has no int8 pool")
+        return StatePoolCache(
+            pool_layers=model_cfg.num_layers, moe_leaf=self.moe_leaf
+        ).init_cache(model_cfg, **geometry)
+
+
+@dataclasses.dataclass(frozen=True)
 class ServingFamily:
     """What :class:`.engine.ServingEngine` asks of a model config
     (``model_cfg.serving_family()``): the cached forward with the
@@ -991,12 +1036,18 @@ class ServingFamily:
     engine fetches with the step's tokens (``nxd_moe_assignments_total``,
     ``nxd_moe_held_total``, ``nxd_moe_identity_total``,
     ``nxd_moe_experts_hit_total``); the family's cache kind builds the
-    leaf (its ``moe_leaf``)."""
+    leaf (its ``moe_leaf``). ``block``: how a family that decodes a block
+    of positions at a time decodes it
+    (:class:`.sampling.BlockDecoding`; None: a token a sequence a step).
+    The engine then packs a decoding slot's whole block a step, keeps the
+    block's tokens on the device between passes and delivers a block when
+    its store pass has run."""
 
     forward: Callable
     cache_kind: Any = FULL_CACHE
     unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
     moe_counts: bool = False
+    block: Any = None
 
     def device_counts(self) -> Tuple[DeviceCounts, ...]:
         """The leaves of the family's cache that its step counts into:
@@ -1007,10 +1058,12 @@ class ServingFamily:
 
     def counters(self) -> Tuple[CounterFamily, ...]:
         """Every counter family of the family's step: those its kind
-        counts on the host and those its leaves feed."""
+        counts on the host, those its leaves feed and, where it decodes
+        blocks, the blocks' own."""
         return tuple(self.cache_kind.counters) + tuple(
             family for leaf in self.device_counts()
-            for family, _ in leaf.reads)
+            for family, _ in leaf.reads) + (
+                BLOCK_COUNTERS if self.block is not None else ())
 
 
 class _BlockPool:

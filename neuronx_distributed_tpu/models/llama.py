@@ -220,6 +220,15 @@ class LlamaConfig:
     # in float32; norms, projections and the MLP still in ``dtype``)
     residual_fp32: bool = False
 
+    @property
+    def block_decoding(self):
+        """How the family decodes a block of positions at a time
+        (:class:`..inference.sampling.BlockDecoding`, a field of a config
+        that does: ``models/sdar.py``): a row then attends through its
+        block's last position, with or without a cache. None: a row
+        attends through its own."""
+        return None
+
     def serving_family(self):
         """What :class:`..inference.engine.ServingEngine` asks of a model
         family: its cached forward, its cache kind, what it cannot do."""
@@ -502,8 +511,12 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view,
         else:
             new_k, new_v = write(view.k, k_rows), write(view.v, v_rows)
             new_ks = new_vs = None
+    # the position a row attends through: its own, or its block's last
+    # where the family decodes blocks (rotary and the write keep its own)
+    block = cfg.block_decoding
     out = paged_attention(
-        q[0], new_k, new_v, view.pos, view.tables, positions[0],
+        q[0], new_k, new_v, view.pos, view.tables,
+        positions[0] if block is None else block.through(positions[0]),
         view.layer, k_scale=new_ks, v_scale=new_vs,
         scale=cfg.attn_scale_,
         force_pallas=cfg.attn_force_pallas,
@@ -792,10 +805,14 @@ class LlamaAttention(nn.Module):
                 else:
                     k = attn_mod.repeat_kv(k, n_q_local // n_kv_local)
                     v = attn_mod.repeat_kv(v, n_q_local // n_kv_local)
-                    out = attn_mod.sdpa_reference(q, k, v, causal=True,
-                                                  scale=cfg.attn_scale,
-                                                  dropout_p=dropout_p,
-                                                  dropout_seed=dropout_seed)
+                    block = cfg.block_decoding
+                    out = attn_mod.sdpa_reference(
+                        q, k, v, causal=True,
+                        # causal between blocks, whole inside one
+                        segment_positions=None if block is None else
+                        block.through(jnp.arange(s))[None],
+                        scale=cfg.attn_scale, dropout_p=dropout_p,
+                        dropout_seed=dropout_seed)
         with device_scope("attn.proj"):
             if cfg.attn_output_norm:
                 out = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
@@ -812,7 +829,7 @@ class LlamaAttention(nn.Module):
                 from ..quantization.mx_layers import MXQuantizedRowParallel
 
                 out = MXQuantizedRowParallel(
-                    features=cfg.num_heads * head_dim,
+                    features=cfg.hidden_size,
                     mx_format=cfg.weight_quant[2:], dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, name="o_proj")(out)
             elif cfg.weight_quant is not None:
@@ -820,13 +837,13 @@ class LlamaAttention(nn.Module):
                     QuantizedRowParallel
 
                 out = QuantizedRowParallel(
-                    features=cfg.num_heads * head_dim,
+                    features=cfg.hidden_size,
                     quantized_dtype=_weight_quant_dtype(cfg.weight_quant),
                     dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                     name="o_proj")(out)
             else:
                 out = pl.RowParallelLinear(
-                    features=cfg.num_heads * head_dim, use_bias=False,
+                    features=cfg.hidden_size, use_bias=False,
                     dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                     sequence_parallel=cfg.sequence_parallel,
                     overlap_comm=cfg.overlap_comm, name="o_proj",

@@ -162,11 +162,12 @@ class ExpertMLPs(nn.Module):
                  valid: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, Dict]:
         """x: [T, H] flat tokens; gates/idx: [T, K]. Returns ([T, H], aux).
-        ``valid [T]`` (bool; capacity dispatch, no ep axis) marks the real
-        rows of a packed serving step: a pad row takes no expert's slot,
-        and ``aux`` then holds ``assignments``, the real rows' ``[kept,
-        dropped]`` int32 (with ``held``, ``[kept, dropped, elsewhere]``:
-        ``dropped`` of the held experts' alone)."""
+        ``valid [T]`` (bool; no ep axis) marks the real rows of a packed
+        serving step: under the capacity dispatch a pad row takes no
+        expert's slot, and ``aux`` then holds ``assignments``, the real
+        rows' ``[kept, dropped]`` int32 (with ``held``, ``[kept, dropped,
+        elsewhere]``: ``dropped`` of the held experts' alone); under the
+        blockwise dispatch, which drops nothing, ``[kept, 0]``."""
         t = x.shape[0]
         e_local = pl._maybe_local(self.num_experts, self.ep_axis)
         i_local = pl._maybe_local(self.intermediate_size, self.tp_axis)
@@ -192,22 +193,30 @@ class ExpertMLPs(nn.Module):
                                  (self.ep_axis, self.tp_axis, None)),
             (e_local, i_local, self.hidden_size), self.param_dtype)
 
-        if valid is not None and (self.dispatch_mode != "capacity"
-                                  or (ep is not None and ep > 1)):
+        if valid is not None and (
+                self.dispatch_mode not in ("capacity", "blockwise")
+                or (ep is not None and ep > 1)):
             raise ValueError("ExpertMLPs: valid rows are threaded through "
-                             "the capacity dispatch without an ep axis "
-                             "alone")
+                             "the capacity and the blockwise dispatch "
+                             "without an ep axis alone")
         if self.held is not None and (valid is None
+                                      or self.dispatch_mode != "capacity"
                                       or self.held[1] != self.num_experts):
             raise ValueError("ExpertMLPs: held=(first, count) is a bank of "
                              "count experts under the packed step's valid "
-                             "rows")
+                             "rows and the capacity dispatch")
         if self.dispatch_mode == "blockwise":
             if ep is not None and ep > 1:
                 return self._forward_blockwise_ep(x, gates, idx, gate_up,
                                                   down, i_local, e_local)
-            return self._forward_blockwise(x, gates, idx, gate_up, down,
-                                           i_local)
+            y, aux = self._forward_blockwise(x, gates, idx, gate_up, down,
+                                             i_local)
+            if valid is not None:
+                # dropless: every real row's choice has a slot (a pad row's
+                # has one too, and its product is the caller's to discard)
+                asked = jnp.sum(valid).astype(jnp.int32) * idx.shape[1]
+                aux["assignments"] = jnp.stack([asked, jnp.zeros_like(asked)])
+            return y, aux
         if self.dispatch_mode != "capacity":
             raise ValueError(
                 f"unknown dispatch_mode {self.dispatch_mode!r}")

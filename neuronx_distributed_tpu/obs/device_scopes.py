@@ -49,7 +49,7 @@ SCOPES = (
     "ffn", "ffn.dense", "ffn.router", "ffn.experts", "ffn.shared",
     "ffn.identity", "ffn.latent",
     "hc", "hc.mix", "hc.apply",
-    "head", "sample",
+    "head", "sample", "sample.uncover",
     "loss", "optimizer",
 )
 

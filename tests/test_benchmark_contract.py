@@ -249,3 +249,53 @@ def test_the_nemotron_cell_holds_every_slot_at_its_longest():
     assert name in E2E["serve_tok_s"]["workloads"]
     # the fourteenth cell: later ones come behind it
     assert [w["name"] for w in MANIFEST["workloads"]].index(name) == 13
+
+
+def test_the_block_cell_fits_its_pool_and_lists_its_own_rooflines():
+    """The mix's longest request and its last block fit a table row, the
+    step is every slot's block and a prefill chunk, the family is built
+    from the file with its block, and the cell lists its five metrics and
+    neither roofline whose work function counts another family's step."""
+    from neuronx_distributed_tpu.inference.sampling import BlockDecoding
+    from runners import models
+
+    name = "sdar-30b-a3b-chat.serve-blockgen"
+    cell = harness.by_name(MANIFEST["workloads"], name, "workload")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "sdar-30b-a3b-chat", "offline-blockgen", 1)
+    config = harness.read_json(os.path.join(
+        BENCH, "configs", "sdar-30b-a3b-chat.json"))
+    traffic = harness.read_json(harness.data_file("traffic",
+                                                  cell["traffic"]))
+    serve = config["serve"]
+    block = config["block_length"]
+    longest = (traffic["prompt_tokens"]["max"]
+               + traffic["answer_tokens"]["max"])
+    assert longest + block <= (serve["max_blocks_per_seq"]
+                               * serve["block_size"])
+    assert serve["token_budget"] == serve["max_slots"] * block + 128
+    assert serve["block_size"] % block == 0
+    assert serve["logit_check"]["group"] == block
+    cfg, _, _ = models.build(config)
+    assert cfg.block_decoding == BlockDecoding(4, 4, 0.9, 151669)
+    assert cfg.serving_family().block is cfg.block_decoding
+    assert (cfg.num_experts, cfg.top_k, cfg.intermediate_size,
+            cfg.num_heads * cfg.head_dim_, cfg.hidden_size) == (
+        128, 8, 768, 4096, 2048)
+    aot = config["assumed"]["serve_aot_gib"]
+    gib = 2.0 ** 30
+    pool = (config["num_hidden_layers"] * serve["num_blocks"]
+            * serve["block_size"] * 2 * 4 * 128 * 2)
+    assert abs(pool / gib - aot["pool"]) < 0.01
+    assert 0.60 <= aot["peak"] / 15.75 <= 0.90
+    listed = {m["name"] for m in MANIFEST["per_layer"]
+              if name in m.get("workloads", ())}
+    assert {"block_rows_uncovered_pct.batch", "block_store_pass_pct.batch",
+            "uncover_share_pct.batch", "sdar_moe_experts_roofline",
+            "block_paged_attention_roofline", "moe_dropped_pct.batch",
+            "paged_attn_share_pct.batch", "step_ms.batch"} <= listed
+    assert not listed & {"moe_experts_roofline", "paged_attention_roofline",
+                         "moe_held_pct.batch"}
+    assert name in E2E["serve_tok_s"]["workloads"]
+    # the sixteenth cell: later ones come behind it
+    assert [w["name"] for w in MANIFEST["workloads"]].index(name) == 15

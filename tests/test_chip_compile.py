@@ -2120,7 +2120,7 @@ def test_the_engines_step_donates_its_pool_and_not_the_hosts_leaves(
     cfg, forward, params, cache, width = _serving_parts(chip, config,
                                                         models)
     step = eng.ServingEngine._build_step(types.SimpleNamespace(
-        model_cfg=cfg, _forward_fn=forward, _cp=1, _spec=None,
+        model_cfg=cfg, _forward_fn=forward, _cp=1, _spec=None, _block=None,
         ecfg=types.SimpleNamespace(sampling=SamplingConfig(greedy=True))))
     pool, held = eng._hold_out(cache)
     assert set(held) >= {"block_tables", "lengths"}
@@ -2159,3 +2159,85 @@ def test_the_engines_step_donates_its_pool_and_not_the_hosts_leaves(
         assert round(memory.argument_size_in_bytes / gib, 3) == 12.817
         assert memory.temp_size_in_bytes / gib < 0.0125
         assert _kernel_instruction_names(text) == {"mla_paged_attention"}
+
+
+# -- the block family's packed step (models/sdar.py) at the published widths:
+# the step of inference/block_serving.py, as the engine builds it
+
+def test_block_step_at_the_published_widths(chip, topo, on_one_chip,
+                                            monkeypatch):
+    """The packed step of ``sdar-30b-a3b-chat``: forward, the draw with
+    its confidence, the uncover rule and the device-held block state. It
+    compiles for the chip with the paged kernel and the grouped product
+    in it, holds what the configuration's ``assumed.serve_aot_gib`` says,
+    writes the pool in place, and keeps one layer's bank as temporaries:
+    the grouped kernel is a custom call, and the scan's slice of the
+    stacks is copied for it (PERF.md, PR 67: the cell's first finding)."""
+    import re
+    import types
+
+    from neuronx_distributed_tpu.inference import block_serving, engine
+    from neuronx_distributed_tpu.obs.device_scopes import scope_of
+    from neuronx_distributed_tpu.ops import blockwise_moe
+
+    monkeypatch.setattr(block_serving, "on_tpu", lambda: True)
+    monkeypatch.setattr(blockwise_moe, "on_tpu", lambda: True)
+    config, models = _cell_config("sdar-30b-a3b-chat", None)
+    assert set(config["reduced"]) == {"num_hidden_layers"}
+    cfg, forward, params, cache, width = _serving_parts(chip, config, models)
+    s, layers = config["serve"], config["num_hidden_layers"]
+    nb, bs = s["num_blocks"], s["block_size"]
+    assert cache.k.shape == cache.v.shape == (layers, nb, bs, 4, 128)
+    assert cache.moe_counts.shape == (2,) and cache.states == {}
+    bank = params["params"]["model"]["layers"]["layer"]["moe"]["experts"]
+    assert bank["gate"].shape == bank["up"].shape == (layers, 128, 2048, 768)
+    assert bank["down"].shape == (layers, 128, 768, 2048)
+    attn = params["params"]["model"]["layers"]["layer"]["attn"]
+    assert attn["o_proj"]["kernel"].shape == (layers, 4096, 2048)
+    assert attn["q_norm"]["scale"].shape == (layers, 128)
+    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) \
+        == 4_361_055_744
+
+    ecfg = engine.EngineConfig(
+        block_size=bs, num_blocks=nb, max_slots=s["max_slots"],
+        max_blocks_per_seq=s["max_blocks_per_seq"], token_budget=width,
+        kv_dtype=cfg.dtype)
+    step = block_serving.BlockServing._build_block_step(types.SimpleNamespace(
+        model_cfg=cfg, ecfg=ecfg, _forward_fn=forward,
+        _block=cfg.block_decoding))
+    b, slots = cfg.block_decoding.block_length, s["max_slots"] + 1
+    state = dict(start=chip((slots,), jnp.int32),
+                 tok=chip((slots, b), jnp.int32),
+                 masked=chip((slots, b), jnp.bool_),
+                 npass=chip((slots,), jnp.int32),
+                 done=chip((slots,), jnp.bool_))
+    pool, held = engine._hold_out(cache)
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = step.lower(
+        params, pool, held, chip((1, width), jnp.int32),
+        chip((1, width), jnp.int32), chip((width,), jnp.int32), state,
+        chip((3, width // b), jnp.int32), chip(rng.shape, rng.dtype)
+    ).compile()
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"paged_attention",
+                                               "grouped_glu_fwd"}
+    gib = 2.0 ** 30
+    mem = compiled.memory_analysis()
+    aot = config["assumed"]["serve_aot_gib"]
+    assert abs(mem.argument_size_in_bytes / gib - aot["arguments"]) < 0.01
+    assert abs(mem.temp_size_in_bytes / gib - aot["temporaries"]) < 0.05
+    assert abs(mem.peak_memory_in_bytes / gib - aot["peak"]) < 0.05
+    assert 0.60 <= mem.peak_memory_in_bytes / gib / 15.75 <= 0.90
+    # one layer's bank, copied for the custom call, and little else
+    one_bank = 3 * 128 * 2048 * 768 * 2
+    assert one_bank <= mem.temp_size_in_bytes < one_bank + 0.15 * gib
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape == f"{layers},{nb},{bs},4,128"]
+    assert len(stacks) == 2 and set(stacks) <= aliased, (stacks, header)
+    seen = {scope_of(m) for m in re.findall(r'op_name="([^"]*)"', text)}
+    assert {"sample", "sample.uncover", "ffn.experts", "ffn.router",
+            "attn.kernel", "attn.pool_write", "attn.walk"} <= seen

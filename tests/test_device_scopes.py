@@ -43,7 +43,7 @@ SERVING = {"llama": "tiny-mistral-serve", "mixtral": "tiny-mixtral",
            "longcat_flash": "tiny-longcat-flash",
            "granite_moe_hybrid": "tiny-granite-moe-hybrid",
            "nemotron_h": "tiny-nemotron-h",
-           "xing4": "tiny-xing4"}
+           "xing4": "tiny-xing4", "sdar_moe": "tiny-sdar"}
 #: the children a family's step must open, and no other family's may
 OWN = {"attn.select": {"minicpm_sala"},
        "attn.state": {"minicpm_sala", "granite_hybrid", "solar_open2",
@@ -55,15 +55,17 @@ OWN = {"attn.select": {"minicpm_sala"},
        "attn.kernel.window": {"laguna", "mimo_v2_flash"},
        "ffn.experts": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
                        "solar_open2", "longcat_flash", "granite_moe_hybrid",
-                       "nemotron_h", "xing4"},
+                       "nemotron_h", "xing4", "sdar_moe"},
        "ffn.router": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
                       "solar_open2", "longcat_flash", "granite_moe_hybrid",
-                      "nemotron_h", "xing4"},
+                      "nemotron_h", "xing4", "sdar_moe"},
        "ffn.shared": {"glm_moe_lite", "laguna", "solar_open2",
                       "granite_moe_hybrid", "nemotron_h", "xing4"},
        "ffn.latent": {"nemotron_h"},
        # the float32 product with Phi; hc.apply has no heavy operation
-       "hc.mix": {"xing4"}}
+       "hc.mix": {"xing4"},
+       # a block family's rule: its top_k and the state's scatters
+       "sample.uncover": {"sdar_moe"}}
 #: the operations that carry a step's device time
 HEAVY = ("stablehlo.dot_general", "stablehlo.custom_call",
          "stablehlo.scatter", "stablehlo.gather", "stablehlo.sort",

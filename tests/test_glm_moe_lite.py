@@ -464,16 +464,26 @@ def test_no_assignment_is_dropped_and_none_taken_by_a_pad_row(real):
     assert float(dropped) > 0 and float(masked) == 0.0
 
 
-def test_the_dropless_dispatch_refuses_valid_rows():
-    """Pad rows are told from real ones under the capacity dispatch alone
-    (the one the family runs: ROADMAP R1)."""
+def test_the_dropless_dispatch_counts_valid_rows_and_drops_none():
+    """Under the blockwise dispatch, which drops nothing, ``valid`` rows
+    only count (since PR 67: a family that declares ``moe_counts`` over
+    the grouped product): every real row's choice is kept, the output is
+    what it is without ``valid``, and a share of the experts (``held``)
+    stays the capacity dispatch's alone (the one this family runs:
+    ROADMAP R1)."""
     x, gates, idx, valid = _crowded_step(64)
-    bank = ExpertMLPs(num_experts=64, hidden_size=32, intermediate_size=16,
-                      top_k=4, dispatch_mode="blockwise", block_size=8,
-                      dtype=jnp.float32)
+    kw = dict(num_experts=64, hidden_size=32, intermediate_size=16,
+              top_k=4, dispatch_mode="blockwise", block_size=8,
+              dtype=jnp.float32)
+    bank = ExpertMLPs(**kw)
     params = bank.init(jax.random.key(0), x, gates, idx)
+    plain, _ = bank.apply(params, x, gates, idx)
+    y, aux = bank.apply(params, x, gates, idx, valid=jnp.asarray(valid))
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(plain))
+    assert aux["assignments"].tolist() == [int(np.sum(valid)) * 4, 0]
     with pytest.raises(ValueError, match="capacity dispatch"):
-        bank.apply(params, x, gates, idx, valid=jnp.asarray(valid))
+        ExpertMLPs(**kw, held=(0, 64)).apply(params, x, gates, idx,
+                                             valid=jnp.asarray(valid))
 
 
 @pytest.mark.parametrize("key,value", [

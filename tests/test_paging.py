@@ -14,7 +14,7 @@ from neuronx_distributed_tpu.inference import kv_cache as kvc
 from neuronx_distributed_tpu.inference.kv_cache import (PAD_POSITION,
                                                         quantize_kv)
 from neuronx_distributed_tpu.inference.paging import (
-    BlockAllocator, CacheExhaustedError, flat_write_indices,
+    BLOCK_COUNTERS, BlockAllocator, CacheExhaustedError, flat_write_indices,
     init_paged_kv_cache, init_quantized_paged_kv_cache, step_counter,
     write_pool_rows)
 from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
@@ -969,8 +969,17 @@ DECLARED["nemotron_h"] = {**DECLARED["granite_moe_hybrid"],
 DECLARED["longcat_flash"] = {**DECLARED["glm_moe_lite"],
                              "nxd_moe_held_total": ("held", "elsewhere"),
                              "nxd_moe_identity_total": ("identity", "routed")}
+#: the family that decodes blocks (models/sdar.py): the full cache's, the
+#: routed assignments of a bank held whole, and the blocks' own counters,
+#: which the step leaves behind its tokens
+DECLARED["sdar"] = {
+    **_PAGED, **_MOE,
+    "nxd_block_passes_total": ("denoise", "store"),
+    "nxd_block_rows_total": ("by_threshold", "by_quota", "left_masked",
+                             "already_uncovered", "stored"),
+    "nxd_blocks_finished_total": ()}
 #: the leaves a family's step counts into on the device, and their lengths
-ON_DEVICE = {"minicpm_sala": {"counts": 10}, "glm_moe_lite": {"moe_counts": 2},
+ON_DEVICE = {"sdar": {"moe_counts": 2}, "minicpm_sala": {"counts": 10}, "glm_moe_lite": {"moe_counts": 2},
              "laguna": {"moe_counts": 3}, "mimo_v2": {"moe_counts": 3},
              "solar_open2": {"moe_counts": 3},
              "granite_moe_hybrid": {"moe_counts": 3},
@@ -1009,6 +1018,7 @@ def test_a_family_declares_its_steps_counters(which):
     assert {leaf.leaf: leaf.entries for leaf in leaves} \
         == ON_DEVICE.get(which, {})
     fed = [c for leaf in leaves for c, _ in leaf.reads]
+    fed += list(BLOCK_COUNTERS) if family.block is not None else []
     assert on_host | {c.name for c in fed} == set(DECLARED[which])
     assert not on_host & {c.name for c in fed}
     for leaf in leaves:
