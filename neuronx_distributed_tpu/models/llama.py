@@ -29,7 +29,7 @@ from flax import linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
 from ..modules import attention as attn_mod
-from ..modules import glu
+from ..modules import glu, layer_stack
 from ..modules.norms import RMSNorm
 from ..obs.device_scopes import device_scope
 from ..ops import collective_matmul as cm
@@ -1100,12 +1100,20 @@ def run_layers(cfg, stacks, x, cos, sin, carried, carry=None, view_of=None,
             # constant: behind the barrier it stays an index, and the
             # layer's weights are read where they lie in the stack, as a
             # longer run reads them (a constant index made each a slice:
-            # a copy of the layer's weights, every step)
+            # a copy of the layer's weights, every step). Every leaf's
+            # stack and the index travel beside its slice, in a second
+            # read-only collection (modules/layer_stack.py): a module
+            # whose kernel is a custom call cannot take the slice without
+            # that copy and reads its leaves there (the grouped product's
+            # expert banks, gate, up and down: ExpertMLPs); nothing else
+            # looks, and the slice of a leaf read as a stack is dead
             i = jax.lax.optimization_barrier(i)
             weights = jax.tree_util.tree_map(lambda w: w[i], stack)
             view = None if cache is None else view_of(kind, cache, i)
-            h, aux, new = layer.apply({"params": weights}, h, cos, sin,
-                                      positions, cache=view, valid=valid)
+            h, aux, new = layer.apply(
+                {"params": weights,
+                 layer_stack.COLLECTION: layer_stack.beside(stack, i)},
+                h, cos, sin, positions, cache=view, valid=valid)
             if cache is not None and merge is not None:
                 cache = merge(cache, new, aux)
             elif cache is not None:

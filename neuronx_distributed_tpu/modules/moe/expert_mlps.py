@@ -30,7 +30,7 @@ from flax import linen as nn
 from ...parallel import comm, ep_dispatch, mappings
 from ...parallel import layers as pl
 from ...parallel import mesh as ps
-from .. import glu
+from .. import glu, layer_stack
 
 
 #: the forms of an expert (:attr:`ExpertMLPs.act`)
@@ -114,6 +114,27 @@ def _held_dispatch_combine(gates, idx, capacity, valid, held):
     combine = jnp.einsum("tk,tke,tkc->tec", gates, keep, slot)
     dropped = 1.0 - jnp.sum(keep) / jnp.maximum(jnp.sum(choice), 1.0)
     return dispatch, combine, dropped
+
+
+def _record_operands(operands: str) -> None:
+    """One grouped-product call site's operand form, counted at trace time
+    (once a compiled call site, a layer scan's body being one; never per
+    execution): ``nxd_moe_grouped_calls_total{operands}``."""
+    from ...obs.metrics import get_registry
+
+    reg = get_registry()
+    if not reg.enabled:
+        return
+    reg.counter("nxd_moe_grouped_calls_total",
+                "Grouped expert product call sites traced (the blockwise "
+                "dispatch), by what the kernel is handed: stack, the "
+                "layers' stacks [L, E, H, I] and the layer's index, read "
+                "where the bank lies; slice, one layer's banks [E, H, I] "
+                "(outside a layer scan, under an ep axis, or a tree stored "
+                "in another dtype than the step's; under a scan XLA copies "
+                "the bank out for the custom call). Counted once per "
+                "trace.", labels=("operands",)).labels(
+                    operands=operands).inc()
 
 
 class ExpertMLPs(nn.Module):
@@ -279,7 +300,10 @@ class ExpertMLPs(nn.Module):
         """Shared kernel dispatch for both blockwise paths: bi-tile
         fallback + training kernel vs forward-only decode kernel
         (``sentinel_empty``: reads only hit experts' weights — token blocks
-        innermost, empty blocks sentinel'd)."""
+        innermost, empty blocks sentinel'd). Where a layer scan handed this
+        bank's stacks in beside the slices (:mod:`..layer_stack`) and no
+        ep axis divides the bank, the kernel gets the stacks and the
+        layer's index, and no bank is copied out for the custom call."""
         from . import blockwise as bw
 
         bi = min(self.block_i, i_local)
@@ -287,8 +311,15 @@ class ExpertMLPs(nn.Module):
             bi = i_local
         kernel = (bw.grouped_glu_decode if self.sentinel_empty
                   else bw.grouped_glu)
+        ep = comm._axis_size(self.ep_axis)
+        stacked = None if ep is not None and ep > 1 else layer_stack.of(
+            self, (*glu.EXPERTS, "down"), (*gate_up, down), self.dtype)
+        _record_operands("slice" if stacked is None else "stack")
         # force_pallas=None: Pallas on TPU, the bit-exact jnp reference on
         # CPU (ops.blockwise_moe auto-dispatch)
+        if stacked is not None:
+            stacks, layer = stacked
+            return kernel(xs, *stacks, be, self.block_size, bi, layer=layer)
         return kernel(xs, *(w.astype(self.dtype) for w in gate_up),
                       down.astype(self.dtype), be, self.block_size, bi)
 

@@ -34,6 +34,20 @@ Weight layouts are the stacked expert banks of
 (padding or non-local EP pairs): their compute is skipped and their output
 rows are zero; their weight-tile index clamps to the last real expert so a
 sentinel run costs no extra DMA.
+
+**The layers' stacks.** A model whose layers run under a scan keeps every
+layer's bank in one leaf, ``gate``, ``up`` ``[L, E, H, I]`` and ``down``
+``[L, E, I, H]``, and the scan hands a layer ``stack[i]``. An XLA matmul
+takes that ``dynamic-slice`` into its own fusion; a Mosaic kernel is a
+custom call whose operands must be buffers, so XLA wrote layer ``i``'s three
+banks out in front of every call (0.4 GB read and written, 21.7 ms of
+``sdar-30b-a3b-chat``'s 58 ms serving step on a v5e: ``PERF.md``, PR 68).
+The forward entries therefore also take the stacks themselves with ``layer``,
+an int32 scalar: it is prefetched beside ``block_expert``, the weight index
+maps lead with it (``(layer, expert, 0, ib)`` over a squeezed leading
+dimension, as :mod:`.paged_attention` reads ``pool[layer]``), and the body,
+the grid, the clamp and the elided refetch are the same. Forward-only:
+serving runs it, and training differentiates the ``[E, H, I]`` entry.
 """
 
 from __future__ import annotations
@@ -172,26 +186,50 @@ def _glu_dw_kernel(be_ref, x_ref, g_ref, u_ref, dn_ref, dy_ref, dg_ref,
         du_ref[0] = du_ref[0] + duw.astype(du_ref.dtype)
 
 
-def _weight_specs(pl, h, block_i, we, b_first: bool):
+def _weight_specs(pl, h, block_i, we, b_first: bool, stacked: bool = False):
     """BlockSpecs of one block's expert tiles: gate and up ``[1, H, bI]``,
     down ``[1, bI, H]``, on a grid ``(b, ib)`` if ``b_first`` else
-    ``(ib, b)``; ``we`` clamps a sentinel's expert id."""
+    ``(ib, b)``; ``we`` clamps a sentinel's expert id. ``stacked``: the
+    operands are the layers' stacks ``[L, E, ., .]`` and the second
+    prefetched scalar names the layer: the same tiles behind a squeezed
+    leading dimension, fetched from ``stack[layer]`` where it lies."""
     def at(i, j):
         return (i, j) if b_first else (j, i)
 
-    def col():
-        return pl.BlockSpec(
-            (1, h, block_i),
-            lambda i, j, be: (we(be[at(i, j)[0]]), 0, at(i, j)[1]))
+    def spec(shape, tile):
+        def bank(i, j, be):
+            b, ib = at(i, j)
+            return tile(we(be[b]), ib)
 
-    row = pl.BlockSpec(
-        (1, block_i, h),
-        lambda i, j, be: (we(be[at(i, j)[0]]), at(i, j)[1], 0))
-    return [col(), col(), row]
+        if not stacked:
+            return pl.BlockSpec(shape, bank)
+        return pl.BlockSpec(
+            (None,) + shape,
+            lambda i, j, be, layer: (layer[0],) + bank(i, j, be))
+
+    def col():
+        return spec((1, h, block_i), lambda e, ib: (e, 0, ib))
+
+    return [col(), col(), spec((1, block_i, h), lambda e, ib: (e, ib, 0))]
+
+
+def _stack_operands(kernel, block_expert, layer):
+    """``(kernel, scalar operands)`` of a call over ``[E, ., .]`` banks
+    (``layer`` None) or over the layers' stacks: there the layer's index
+    is prefetched beside ``block_expert``, for the index maps alone (the
+    body never reads it)."""
+    if layer is None:
+        return kernel, (block_expert,)
+
+    def body(be_ref, layer_ref, *refs):
+        del layer_ref
+        kernel(be_ref, *refs)
+
+    return body, (block_expert, jnp.reshape(layer, (1,)).astype(jnp.int32))
 
 
 def _grouped_glu_pallas(xs, gate, up, down, block_expert, block_size,
-                        block_i, interpret, num_real):
+                        block_i, interpret, num_real, layer=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -207,24 +245,26 @@ def _grouped_glu_pallas(xs, gate, up, down, block_expert, block_size,
     # are refetched per block — the layout that favours training, where
     # nb ~ E. Decode uses the (ib, b) grid of :func:`grouped_glu_decode`.
     we = functools.partial(jnp.minimum, num_real - 1)
+    kernel, scalars = _stack_operands(
+        functools.partial(_glu_fwd_kernel, num_ib=num_ib, num_real=num_real),
+        block_expert, layer)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(scalars),
         grid=(nb, num_ib),
         in_specs=[
-            pl.BlockSpec((block_size, h), lambda b, ib, be: (b, 0)),
-            *_weight_specs(pl, h, block_i, we, True),
+            pl.BlockSpec((block_size, h), lambda b, ib, *_: (b, 0)),
+            *_weight_specs(pl, h, block_i, we, True, layer is not None),
         ],
-        out_specs=pl.BlockSpec((block_size, h), lambda b, ib, be: (b, 0)),
+        out_specs=pl.BlockSpec((block_size, h), lambda b, ib, *_: (b, 0)),
     )
     return pl.pallas_call(
-        functools.partial(_glu_fwd_kernel, num_ib=num_ib,
-                          num_real=num_real),
+        kernel,
         out_shape=jax.ShapeDtypeStruct((p, h), xs.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
         name="grouped_glu_fwd",
-    )(block_expert, xs, gate, up, down)
+    )(*scalars, xs, gate, up, down)
 
 
 def _glu_fwd_decode_kernel(be_ref, x_ref, g_ref, u_ref, dn_ref, y_ref, *,
@@ -249,7 +289,7 @@ def _glu_fwd_decode_kernel(be_ref, x_ref, g_ref, u_ref, dn_ref, y_ref, *,
 
 
 def _grouped_glu_decode_pallas(xs, gate, up, down, block_expert, block_size,
-                               block_i, interpret):
+                               block_i, interpret, layer=None):
     """Forward-only grouped GLU tuned for decode HBM traffic.
 
     Grid order (ib, b) — token blocks INNERMOST — so consecutive blocks of
@@ -269,30 +309,33 @@ def _grouped_glu_decode_pallas(xs, gate, up, down, block_expert, block_size,
     from jax.experimental.pallas import tpu as pltpu
 
     p, h = xs.shape
-    num_real, _, i = gate.shape
+    num_real, _, i = gate.shape[-3:]
     nb = p // block_size
     num_ib = i // block_i
     we = functools.partial(jnp.minimum, num_real - 1)
-    partial = pl.pallas_call(
+    kernel, scalars = _stack_operands(
         functools.partial(_glu_fwd_decode_kernel, num_real=num_real),
+        block_expert, layer)
+    partial = pl.pallas_call(
+        kernel,
         # fp32 partials: the per-ib contributions are summed below, and a
         # bf16 round-trip through HBM before that sum loses mantissa bits
         # the kernel already paid fp32 accumulation for (advisor r3)
         out_shape=jax.ShapeDtypeStruct((num_ib, p, h), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(scalars),
             grid=(num_ib, nb),
             in_specs=[
-                pl.BlockSpec((block_size, h), lambda ib, b, be: (b, 0)),
-                *_weight_specs(pl, h, block_i, we, False),
+                pl.BlockSpec((block_size, h), lambda ib, b, *_: (b, 0)),
+                *_weight_specs(pl, h, block_i, we, False, layer is not None),
             ],
             out_specs=pl.BlockSpec((1, block_size, h),
-                                   lambda ib, b, be: (ib, b, 0)),
+                                   lambda ib, b, *_: (ib, b, 0)),
         ),
         interpret=interpret,
         compiler_params=None if interpret else _compiler_params(),
         name="grouped_glu_fwd_decode",
-    )(block_expert, xs, gate, up, down)
+    )(*scalars, xs, gate, up, down)
     return jnp.sum(partial, axis=0).astype(xs.dtype)
 
 
@@ -606,8 +649,55 @@ def use_pallas(force_pallas=None) -> bool:
     return bool(force_pallas)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _grouped_glu_stacked(xs, gate, up, down, block_expert, layer,
+                         block_size, block_i, decode, interpret):
+    """Either forward over the layers' stacks ``[L, E, ., .]`` at
+    ``layer``; ``interpret`` None: the reference on ``stack[layer]`` (XLA
+    fuses the index into the reference's reads)."""
+    if interpret is None:
+        banks = tuple(lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+                      for w in (gate, up, down))
+        if decode:
+            return _ref_decode_fwd(xs, *banks, block_expert, block_size,
+                                   block_i)
+        return _ref_fwd(xs, *banks, block_expert, block_size, block_i,
+                        gate.shape[1])
+    if decode:
+        return _grouped_glu_decode_pallas(xs, gate, up, down, block_expert,
+                                          block_size, block_i, interpret,
+                                          layer)
+    return _grouped_glu_pallas(xs, gate, up, down, block_expert, block_size,
+                               block_i, interpret, gate.shape[1], layer)
+
+
+def _stacked_fwd(*args):
+    raise TypeError(
+        "grouped_glu over the layers' stacks (gate, up [L, E, H, I], down "
+        "[L, E, I, H] and a layer's index) is forward-only: it is the "
+        "serving step's operand form. Differentiate grouped_glu on "
+        "stack[layer], [E, H, I], whose custom_vjp holds the backward "
+        "kernels")
+
+
+_grouped_glu_stacked.defvjp(_stacked_fwd, lambda *args: None)
+
+
+def _stacked(xs, gate, up, down, block_expert, layer, block_size, block_i,
+             force_pallas, decode):
+    if not gate.ndim == up.ndim == down.ndim == 4:
+        raise ValueError(
+            "grouped_glu with a layer's index takes the layers' stacks, "
+            f"gate and up [L, E, H, I] and down [L, E, I, H]; got "
+            f"{gate.shape}, {up.shape}, {down.shape}")
+    interpret = (not on_tpu()) if use_pallas(force_pallas) else None
+    return _grouped_glu_stacked(xs, gate, up, down, block_expert,
+                                jnp.asarray(layer, jnp.int32), block_size,
+                                block_i, decode, interpret)
+
+
 def grouped_glu(xs, gate, up, down, block_expert, block_size, block_i,
-                force_pallas=None):
+                force_pallas=None, layer=None):
     """Block-sparse grouped GLU: ``ys[b] = silu(x_b@Wg_e)·(x_b@Wu_e) @ Wd_e``
     with ``e = block_expert[b]`` (the dropless expert matmul; training
     fwd+bwd).
@@ -616,7 +706,15 @@ def grouped_glu(xs, gate, up, down, block_expert, block_size, block_i,
     are *sentinels* (padding / bound-EP non-local pairs): their compute is
     skipped and their output rows are zero. Deriving the sentinel threshold
     from the array shape (rather than a parameter) guarantees every real
-    expert owns >= 1 block, so no dW tile is left unwritten."""
+    expert owns >= 1 block, so no dW tile is left unwritten.
+
+    With ``layer`` (an int32 scalar) the weights are the layers' stacks,
+    ``[L, E, H, I]`` twice and ``[L, E, I, H]``, and the product is layer
+    ``layer``'s, bit for bit, read where the bank lies (module docstring,
+    "The layers' stacks"); forward-only."""
+    if layer is not None:
+        return _stacked(xs, gate, up, down, block_expert, layer, block_size,
+                        block_i, force_pallas, False)
     if use_pallas(force_pallas):
         interpret = not on_tpu()
         return _grouped_glu_kernel(xs, gate, up, down, block_expert,
@@ -626,10 +724,14 @@ def grouped_glu(xs, gate, up, down, block_expert, block_size, block_i,
 
 
 def grouped_glu_decode(xs, gate, up, down, block_expert, block_size,
-                       block_i, force_pallas=None):
+                       block_i, force_pallas=None, layer=None):
     """Forward-only grouped GLU tuned for decode HBM traffic (token blocks
     innermost so one expert's weight DMA serves its whole block run; pair
-    with ``sentinel_empty`` metadata so only hit experts are read)."""
+    with ``sentinel_empty`` metadata so only hit experts are read).
+    ``layer``: as :func:`grouped_glu`'s, over the layers' stacks."""
+    if layer is not None:
+        return _stacked(xs, gate, up, down, block_expert, layer, block_size,
+                        block_i, force_pallas, True)
     if use_pallas(force_pallas):
         interpret = not on_tpu()
         return _grouped_glu_decode_pallas(xs, gate, up, down, block_expert,

@@ -99,3 +99,72 @@ def test_every_real_expert_owns_a_block_training_metadata():
     _, _, _, be, _, _ = bw.compute_block_metadata(idx, 4, 4)
     owned = set(np.asarray(be).tolist())
     assert {0, 1, 2, 3} <= owned
+
+
+# -- the layers' stacks: [L, E, H, I] and the layer's index -------------------
+
+def _stacked_problem(decode: bool, depth=3):
+    """A stack of ``depth`` banks that differ, with the blocks the case
+    needs: sentinel blocks (``sentinel_empty`` metadata: experts 1 and 3
+    take no row) and a run of two blocks of expert 0."""
+    T, K, E, B = 12, 1, 4, 4
+    idx = jnp.zeros((T, K), jnp.int32).at[0, 0].set(2)   # 11 rows on expert 0
+    xs, _, _, be, B, _ = _problem(T=T, K=K, E=E, B=B, sentinel_empty=True,
+                                  idx=idx)
+    be_np = np.asarray(be)
+    assert np.any(be_np >= E), "the fixture must produce sentinel blocks"
+    assert np.any((be_np[1:] == be_np[:-1]) & (be_np[1:] < E)), \
+        "the fixture must produce a run of two blocks of one expert"
+    ks = jax.random.split(jax.random.key(11), 3)
+    H, I = xs.shape[1], 16
+    gate, up = (jax.random.normal(k, (depth, E, H, I), jnp.float32) * 0.3
+                for k in ks[:2])
+    down = jax.random.normal(ks[2], (depth, E, I, H), jnp.float32) * 0.3
+    fn = ops_bw.grouped_glu_decode if decode else ops_bw.grouped_glu
+    return fn, xs, (gate, up, down), be, B, I // 2
+
+
+@pytest.mark.parametrize("force_pallas", [True, False],
+                         ids=["interpret", "reference"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("decode", [False, True],
+                         ids=["grouped_glu", "grouped_glu_decode"])
+def test_stacked_entry_is_the_bank_entry_on_the_layers_slice(
+        decode, layer, force_pallas):
+    """``fn(xs, stacks, ..., layer=l)`` is ``fn(xs, stacks[l], ...)`` to
+    the bit, kernel (interpret mode) and reference alike, for the first, a
+    middle and the last layer, the index traced (as a scan's is)."""
+    fn, xs, stacks, be, B, bi = _stacked_problem(decode)
+    stacked = jax.jit(lambda l: fn(xs, *stacks, be, B, bi,
+                                   force_pallas=force_pallas, layer=l))
+    y_s = stacked(jnp.int32(layer))
+    y_b = fn(xs, *(w[layer] for w in stacks), be, B, bi,
+             force_pallas=force_pallas)
+    np.testing.assert_array_equal(np.asarray(y_s), np.asarray(y_b))
+    # the layers differ, so a layer read from the wrong place would show
+    other = fn(xs, *(w[(layer + 1) % 3] for w in stacks), be, B, bi,
+               force_pallas=force_pallas)
+    assert not np.array_equal(np.asarray(y_s), np.asarray(other))
+    sent = np.repeat(np.asarray(be) >= stacks[0].shape[1], B)
+    assert np.all(np.asarray(y_s)[sent] == 0.0)
+
+
+@pytest.mark.parametrize("force_pallas", [True, False],
+                         ids=["interpret", "reference"])
+@pytest.mark.parametrize("decode", [False, True],
+                         ids=["grouped_glu", "grouped_glu_decode"])
+def test_stacked_entry_refuses_to_be_differentiated(decode, force_pallas):
+    fn, xs, stacks, be, B, bi = _stacked_problem(decode)
+
+    def loss(gate):
+        return jnp.sum(fn(xs, gate, *stacks[1:], be, B, bi,
+                          force_pallas=force_pallas, layer=1))
+
+    with pytest.raises(TypeError, match="forward-only"):
+        jax.grad(loss)(stacks[0])
+
+
+def test_stacked_entry_refuses_a_bank():
+    fn, xs, stacks, be, B, bi = _stacked_problem(False)
+    with pytest.raises(ValueError, match=r"\[L, E, H, I\]"):
+        fn(xs, *(w[0] for w in stacks), be, B, bi, layer=0)

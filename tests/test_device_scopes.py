@@ -443,11 +443,22 @@ LOWERED_AT_PR_63 = {
 }
 
 
+#: the family that decodes blocks (``models/sdar.py``), as PR 68's tree
+#: lowers it: the one step that PR changed (off the chip the grouped
+#: product's reference indexes the banks' stacks at the layer, where the
+#: parent's tree sliced every leaf), and it had no recorded text
+LOWERED_AT_PR_68 = {
+    "sdar_moe":
+        "6e76b244972957f850e0f9e3058f2b21164eaab5694d835093a77b5d086604de",
+}
+
+
 @pytest.mark.parametrize("which", list(LOWERED_AT_PR_38)
                          + list(LOWERED_AT_PR_39) + list(LOWERED_AT_PR_42)
                          + list(LOWERED_AT_PR_43) + list(LOWERED_AT_PR_45)
                          + list(LOWERED_AT_PR_48) + list(LOWERED_AT_PR_54)
-                         + list(LOWERED_AT_PR_59) + list(LOWERED_AT_PR_63))
+                         + list(LOWERED_AT_PR_59) + list(LOWERED_AT_PR_63)
+                         + list(LOWERED_AT_PR_68))
 def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     """PR 39 changed the latent kernel and its walk alone: the packed
     steps of the five families that run the shared helpers of
@@ -487,12 +498,19 @@ def test_a_family_off_the_latent_kernel_lowers_to_its_recorded_text(which):
     the score's scale and the rotary rows as the config's, GLM's and
     LongCat's what they were) and the latent cache kind a host counter:
     the twelve are the text they were, and the family of several
-    residual streams is recorded. A PR that changes one of these
-    programs on purpose records its new hash here."""
+    residual streams is recorded. PR 68 made ``run_layers`` hand every
+    layer its leaves' stacks and its index beside the slices, in a second
+    collection that only the blockwise expert bank reads
+    (``modules/layer_stack.py``): the thirteen are the text they were,
+    the ten whose layers run under ``run_layers`` among them, and the
+    block family's step, the one it changed, is recorded.
+    A PR that changes one of these programs on purpose records its new
+    hash here."""
     import hashlib
 
     text = _stripped(_lowered(which))
     assert hashlib.sha256(text.encode()).hexdigest() == {
         **LOWERED_AT_PR_38, **LOWERED_AT_PR_39, **LOWERED_AT_PR_42,
         **LOWERED_AT_PR_43, **LOWERED_AT_PR_45, **LOWERED_AT_PR_48,
-        **LOWERED_AT_PR_54, **LOWERED_AT_PR_59, **LOWERED_AT_PR_63}[which]
+        **LOWERED_AT_PR_54, **LOWERED_AT_PR_59, **LOWERED_AT_PR_63,
+        **LOWERED_AT_PR_68}[which]

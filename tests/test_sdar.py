@@ -186,6 +186,100 @@ def test_a_fault_put_into_the_program_fails_the_check(fault):
         fault != "first_writing_left_live"), (fault, why)
 
 
+# -- the expert banks' stacks through the layer scan --------------------------
+
+def _probe_logits(cfg, forward, params):
+    ecfg = EngineConfig(block_size=8, num_blocks=16, max_slots=2,
+                        max_blocks_per_seq=4, token_budget=16,
+                        kv_dtype=cfg.dtype)
+    chk = dict(CHECK, group=B, rewrite=True)
+    with jax.default_matmul_precision("highest"):
+        return serve.probe_logits(7, cfg, forward, params, ecfg, chk)[1]
+
+
+def _grouped_calls():
+    """The ``operands`` that ``nxd_moe_grouped_calls_total`` counted since
+    the registry was last emptied (a trace counts its call site once, and
+    a jit may trace twice), which this empties again."""
+    family = obs.get_registry().get("nxd_moe_grouped_calls_total")
+    seen = set() if family is None else {
+        child.labels["operands"] for child in family.children()
+        if child.value > 0}
+    obs.get_registry().reset()
+    return seen
+
+
+@pytest.fixture
+def kernel_forced(monkeypatch):
+    """The grouped product's Mosaic kernel (interpret mode here) where the
+    dispatcher would take its reference, and the registry on and empty."""
+    from neuronx_distributed_tpu.ops import blockwise_moe
+
+    monkeypatch.setattr(blockwise_moe, "use_pallas", lambda force=None: True)
+    obs.enable()
+    obs.get_registry().reset()
+
+
+def test_the_scan_hands_the_kernel_the_stacks_and_each_layer_reads_its_own(
+        kernel_forced, monkeypatch):
+    """Under ``run_layers`` the forced kernel is handed the banks' stacks
+    and the layer's index (the counter says ``stack``) and the packed step
+    delivers the reference path's logits and tokens, at layers whose banks
+    differ; a scan that names layer 0 to every layer passes the kernel's
+    own parity and fails here."""
+    from neuronx_distributed_tpu.modules import layer_stack
+    from neuronx_distributed_tpu.ops import blockwise_moe
+
+    cfg, _, forward, params = _model()
+    _grouped_calls()            # the model's own init, outside any scan
+    bank = params["params"]["model"]["layers"]["layer"]["moe"]["experts"]
+    assert all(not np.array_equal(bank[n][0], bank[n][1])
+               for n in ("gate", "up", "down"))
+    assert _probe(cfg, forward, params, group=B, rewrite=True) == []
+    kernel = _probe_logits(cfg, forward, params)
+    assert _grouped_calls() == {"stack"}
+
+    monkeypatch.setattr(blockwise_moe, "use_pallas", lambda force=None: False)
+    reference = _probe_logits(cfg, forward, params)
+    assert _grouped_calls() == {"stack"}
+    np.testing.assert_array_equal(kernel, reference)   # so the tokens too
+    # without the second collection the slices go to the [E, H, I] entry:
+    # the same logits, to the bit
+    monkeypatch.setattr(layer_stack, "beside", lambda stack, layer: {})
+    sliced = _probe_logits(cfg, forward, params)
+    np.testing.assert_array_equal(sliced, reference)
+    assert _grouped_calls() == {"slice"}
+
+    monkeypatch.setattr(blockwise_moe, "use_pallas", lambda force=None: True)
+    beside = layer_stack.LayerStack
+    monkeypatch.setattr(
+        layer_stack, "beside", lambda stack, layer: jax.tree_util.tree_map(
+            lambda w: beside(w, jnp.zeros_like(layer)), stack))
+    why = _probe(cfg, forward, params, group=B, rewrite=True)
+    assert any("prefill" in w for w in why) and any("decode" in w
+                                                    for w in why), why
+
+
+def test_a_tree_stored_in_another_dtype_keeps_the_slice(kernel_forced):
+    """float32 leaves under a bfloat16 step: a cast of the stack would
+    convert every layer's bank for the one the kernel reads, so the
+    kernel gets the cast slice as before, and the counter says so."""
+    cfg, _, forward, params = _model()
+    _grouped_calls()
+    low = dataclasses.replace(cfg, dtype=jnp.bfloat16)
+    got = _probe_logits(low, forward, params)
+    assert _grouped_calls() == {"slice"}
+    assert np.isfinite(got).all()
+    stored = jax.tree_util.tree_map_with_path(
+        lambda path, w: w.astype(jnp.bfloat16)
+        if "experts" in jax.tree_util.keystr(path) else w, params)
+    same = _probe_logits(low, forward, stored)
+    assert _grouped_calls() == {"stack"}
+    # bf16 weights either way: cast from the slice, or stored and read
+    # where they lie
+    np.testing.assert_array_equal(got, same)
+
+
 # -- the uncover rule --------------------------------------------------------
 
 def _uncover_np(conf, masked, quota, threshold):
