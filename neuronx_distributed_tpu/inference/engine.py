@@ -46,11 +46,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.llama import LlamaConfig
+from ..obs import host as obs_host
 from ..obs.accounting import CompileTracker
 from ..obs.device_scopes import device_scope
 from ..obs.events import emit_event
 from ..obs.metrics import get_registry
-from ..obs.tracing import GC_SPAN, get_tracer
+from ..obs.tracing import GC_SPAN, attrs_of, get_tracer
 from ..utils.device import on_tpu
 from ..resilience.integrity import (
     IntegrityError,
@@ -90,6 +91,12 @@ STALL_FACTOR, STALL_WINDOW, STALL_FIRST, STALL_REFRESH = 3.0, 256, 8, 64
 #: where a slow call's time over the median went
 #: (``nxd_engine_step_wall_seconds_total{where}``; ``steady`` is the rest)
 STALL_CAUSES = ("host_pause", "device", "transfer", "compile", "host")
+
+#: and why, whatever span the stepping thread sat in
+#: (``nxd_engine_stall_cause_seconds_total{cause}``, the same seconds):
+#: nothing of the process ran, the stepping thread was runnable and had no
+#: core, or neither
+STALL_WHYS = ("process_stopped", "cpu_wait", "other")
 
 
 def _hold_out(cache):
@@ -2358,6 +2365,9 @@ class ServingEngine(BlockServing):
         tracer = get_tracer()
         self._calls += 1
         call = self._calls
+        # what the stepping thread has done so far (its CPU time, switches,
+        # faults, run-queue delay): ``_account_call`` reads it again
+        began = tracer.thread_reading() if tracer.enabled else None
         with tracer.span("engine/admission", step=call) as entered:
             self._admit()
             round_state = self._begin_spec_round()
@@ -2518,7 +2528,7 @@ class ServingEngine(BlockServing):
                               len(prefill_rows), pad_rows,
                               "overlapped" if overlapped else "serial",
                               (call, entered.t0_us, published, cleared,
-                               rolled))
+                               rolled, began))
         return len(rows) + len(spec_live)
 
     def _note_enqueued(self, rows) -> None:
@@ -2618,7 +2628,8 @@ class ServingEngine(BlockServing):
         per-worker compile trackers, and account the call's wall by cause
         (:meth:`_account_call`; ``call`` is what ``step()`` knows of the
         call: its number, its entry, its open publish span, the blocks its
-        hygiene cleared and the windows it rolled). One bool check when obs is
+        hygiene cleared, the windows it rolled and its thread's reading at
+        the entry). One bool check when obs is
         disabled; the no-host-callback invariant holds — everything here
         runs after the compiled workers returned. Child handles are
         cached against the registry's reset generation so the steady
@@ -2678,6 +2689,19 @@ class ServingEngine(BlockServing):
                 "(every other span over its median and what no span "
                 "covers). The children sum to the calls' walls.",
                 labels=("where",))
+            cause_c = reg.counter(
+                "nxd_engine_stall_cause_seconds_total",
+                "The same walls as nxd_engine_step_wall_seconds_total, the "
+                "slow calls' time over the median by why and not by where: "
+                "process_stopped (the host/stopped spans inside the call, "
+                "as far as the call's wall less its thread's CPU time "
+                "covers them: nothing of the process ran), cpu_wait (of the "
+                "rest, the stepping thread's run-queue delay beyond the "
+                "stops: runnable, and no core), other (a wait the device or "
+                "the runtime imposed, a collection, a compile, host work). "
+                "steady is steady; the other children sum to the same "
+                "seconds as the first counter's.",
+                labels=("cause",))
             counted = {}
             for c in self._counters:
                 metric = reg.counter(c.name, c.help,
@@ -2697,6 +2721,8 @@ class ServingEngine(BlockServing):
                        for k in ("overlapped", "serial")},
                 wall={k: wall_c.labels(where=k)
                       for k in ("steady",) + STALL_CAUSES},
+                cause={k: cause_c.labels(cause=k)
+                       for k in ("steady",) + STALL_WHYS},
                 counted=counted)
         st = self.stats
         for f, child in cache.fields.items():
@@ -2714,21 +2740,26 @@ class ServingEngine(BlockServing):
                 child.inc(int(n))
             since[:] = 0
         if call is not None and get_tracer().enabled:
-            self._account_call(cache.wall, {
+            self._account_call(cache, {
                 "decode_rows": decode_rows, "prefill_rows": prefill_rows,
                 "pad_rows": pad_rows, "kind": kind, "compiled": compiled},
                 *call)
 
-    def _account_call(self, wall_c, facts: Dict[str, Any], step: int,
+    def _account_call(self, counters, facts: Dict[str, Any], step: int,
                       entry_us: float, published, cleared: int,
-                      rolled: int) -> None:
+                      rolled: int, began) -> None:
         """Add this call's wall (entry to now, microseconds before it
-        returns) to ``nxd_engine_step_wall_seconds_total{where}``: all of
-        it to ``steady`` unless it is over ``STALL_FACTOR`` x the running
-        median, and then the median to ``steady`` and the rest by cause
-        (:meth:`_slow_call`). What the engine knows of the call goes onto
-        its publish span: the call after it may wait for what this one
-        enqueued."""
+        returns) to ``nxd_engine_step_wall_seconds_total{where}`` and to
+        ``nxd_engine_stall_cause_seconds_total{cause}``: all of it to
+        ``steady`` unless it is over ``STALL_FACTOR`` x the running
+        median, and then the median to ``steady`` and the rest by place
+        and by cause (:meth:`_slow_call`). What the engine knows of the
+        call goes onto its publish span, the stepping thread's CPU time
+        over the call (``cpu_us``) with it: the call after it may wait for
+        what this one enqueued."""
+        ended = get_tracer().thread_reading() if began is not None else None
+        if ended is not None:
+            facts["cpu_us"] = round((ended[0] - began[0]) * 1e-3, 1)
         st = self._stall
         totals = (self._admit_counter, self.stats.completed,
                   self.stats.preempted, self.stats.cow_copies)
@@ -2749,21 +2780,38 @@ class ServingEngine(BlockServing):
         if st.calls == STALL_FIRST or st.calls % STALL_REFRESH == 0:
             st.median = statistics.median(st.walls)
         before, st.before = st.before, (step, entry_us)
-        if st.median is None or wall <= STALL_FACTOR * st.median:
-            wall_c["steady"].inc(wall * 1e-6)
-            return
-        wall_c["steady"].inc(st.median * 1e-6)
-        self._slow_call(wall_c, facts, step, wall, st.median, before)
+        steady = wall
+        if st.median is not None and wall > STALL_FACTOR * st.median:
+            steady = st.median
+            self._slow_call(counters, facts, step, entry_us, wall, steady,
+                            before, began, ended)
+        counters.wall["steady"].inc(steady * 1e-6)
+        counters.cause["steady"].inc(steady * 1e-6)
 
-    def _slow_call(self, wall_c, facts: Dict[str, Any], step: int,
-                   wall: float, median: float,
-                   before: Tuple[int, float]) -> None:
-        """Split a slow call's time over the median by where it sat, from
-        the call's own spans against each span's median (the tracer's
-        reservoirs, read here and nowhere else), and emit one
-        ``slow_step`` event with this call's facts and those of the call
-        before it: a long ``ready_us`` is the wait for the step *that*
-        call enqueued. All times in microseconds until the event."""
+    def _slow_call(self, counters, facts: Dict[str, Any], step: int,
+                   entry_us: float, wall: float, median: float,
+                   before: Tuple[int, float], began, ended) -> None:
+        """Split a slow call's time over the median twice, and emit one
+        ``slow_step`` event with both splits, this call's facts and those
+        of the call before it (a long ``ready_us`` is the wait for the
+        step *that* call enqueued). All times in microseconds until the
+        event.
+
+        **By place** (``where``): where the stepping thread sat, from the
+        call's own spans against each span's median (the tracer's
+        reservoirs, read here and nowhere else).
+
+        **By cause** (``cause``), in this order: ``process_stopped`` is
+        what the witness's ``host/stopped`` spans cover of the call
+        (entry to now), capped by the excess and by the call's wall less
+        what its thread ran, its CPU time or its own collections' spans,
+        whichever is more (a stepping thread that burned CPU while the
+        witness starved on the interpreter's lock ran); ``cpu_wait`` is,
+        of what is left, the thread's run-queue delay over the call beyond
+        those stops (a throttled process's threads wait on the run queue
+        through the stop: counted once); ``other`` is the rest. A stop
+        that ends as the call does is waited for, 40 ms at most
+        (``tracer.stopped_since``), and not accounted a call late."""
         tracer = get_tracer()
         records = tracer.step_records(since_us=before[1])
         this = records.get(step, {"self_us": {}, "attrs": {}, "gc": []})
@@ -2795,13 +2843,29 @@ class ServingEngine(BlockServing):
         else:
             split = {"host": excess}
         for where, us in split.items():
-            wall_c[where].inc(us * 1e-6)
+            counters.wall[where].inc(us * 1e-6)
+
+        now_us = entry_us + wall
+        stops = tracer.stopped_since(entry_us)
+        stopped = sum(max(0.0, min(ev["ts"] + ev["dur"], now_us)
+                          - max(ev["ts"], entry_us)) for ev in stops)
+        thread = obs_host.since(began, ended) if ended is not None else {}
+        # what the thread is known to have run: its CPU time, or its own
+        # collections where the host's CPU clock is the coarser (10 ms ticks
+        # under a sandbox's kernel)
+        ran = max(thread.get("cpu_us", 0.0), raw["host_pause"])
+        why = {"process_stopped": max(0.0, min(stopped, excess, wall - ran))}
+        left = excess - why["process_stopped"]
+        why["cpu_wait"] = min(left, max(
+            0.0, thread.get("runq_us", 0.0) - stopped))
+        why["other"] = left - why["cpu_wait"]
+        for cause, us in why.items():
+            counters.cause[cause].inc(us * 1e-6)
 
         def ms(us):
-            return round(us * 1e-3, 3)
+            return None if us is None else round(us * 1e-3, 3)
 
         prev = records.get(before[0]) if before[0] != step else None
-        memory = next(iter(self.cache.lengths.devices())).memory_stats()
         emit_event(
             "slow_step", replica=self.name or "engine", step=step,
             steps=self.stats.steps, wall_ms=ms(wall), median_ms=ms(median),
@@ -2814,9 +2878,13 @@ class ServingEngine(BlockServing):
                           for k, v in sorted(prev["self_us"].items())}),
             gc_generation=max((g for g, _, _ in this["gc"]), default=None),
             gc_ms=ms(raw["host_pause"]),
-            memory={k: memory[k] for k in (
-                "bytes_in_use", "largest_free_block_bytes", "num_allocs")
-                if k in memory} if memory else None)
+            cause_ms={k: ms(v) for k, v in why.items()},
+            cpu_ms=ms(thread.get("cpu_us")), stopped_ms=ms(stopped),
+            cpu_wait_ms=ms(thread.get("runq_us")),
+            core=obs_host.current_core(),
+            stops=[dict(attrs_of(ev), at_ms=ms(ev["ts"] - entry_us),
+                        ms=ms(ev["dur"])) for ev in stops],
+            **{k: v for k, v in thread.items() if not k.endswith("_us")})
 
     def _retire(self, req: _RequestState, now: float) -> None:
         if req.slot is not None:    # else it left its slot at the enqueue

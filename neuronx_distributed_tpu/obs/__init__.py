@@ -35,12 +35,14 @@ __all__ = [
 
 
 def enable() -> None:
-    """Turn on metrics collection and span recording process-wide, and
-    make the interpreter's collections ``host/gc`` spans."""
+    """Turn on metrics collection and span recording process-wide, make
+    the interpreter's collections ``host/gc`` spans and start the witness
+    thread whose late wake-ups are ``host/stopped`` spans."""
     get_registry().enable()
     tracer = get_tracer()
     tracer.enabled = True
     tracer.watch_gc(True)
+    tracer.watch_host(True)
 
 
 def disable() -> None:
@@ -48,6 +50,7 @@ def disable() -> None:
     tracer = get_tracer()
     tracer.enabled = False
     tracer.watch_gc(False)
+    tracer.watch_host(False)
 
 
 def enabled() -> bool:

@@ -34,6 +34,15 @@ that ran it. The hook runs wherever an allocation happened to trigger the
 collection, the tracer's own ``_append_event`` under ``_lock`` included, so
 it takes **no lock**: it appends its readings to a ``deque`` and the tracer
 folds them into its events at its next record or snapshot.
+``watch_host(True)`` (``obs.enable()`` calls it too) starts a witness of
+the process: one daemon thread that sleeps ``WITNESS_PERIOD_NS`` and reads
+the clock. A wake-up ``STOP_MIN_NS`` or more after it was due is a time in
+which a thread that had nothing to do but wake did not run: a span
+``host/stopped`` (from the moment the wake-up was due, as long as it was
+late, with what the host's counters gained meanwhile: :mod:`.host`) through
+the same ``deque`` and fold. While the host is watched the tracer also
+reads what a thread did, for whoever asks (:meth:`SpanTracer.thread_reading`:
+the serving engine, at each ``step()`` call's entry and return).
 :meth:`SpanTracer.step_records` groups the events that carry a ``step``
 attribute (the serving engine's, one number a ``step()`` call) into
 per-call records: a view of the events held, not a second store.
@@ -58,11 +67,34 @@ import time
 import zlib
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
+from . import host
 from .metrics import HISTOGRAM_RESERVOIR, QUANTILES, get_registry
 
 #: the span a collection of the interpreter leaves, outside ``engine/`` on
 #: purpose: the benchmark's span metrics take ``engine/`` names only
 GC_SPAN = "host/gc"
+#: the span a stop of the process leaves, on the witness's thread; outside
+#: ``engine/`` for the same reason (the benchmark nests the ``engine/``
+#: events as one thread's)
+STOPPED_SPAN = "host/stopped"
+#: the spans that reach the events through ``_pending``, after they ended
+PAUSE_SPANS = (GC_SPAN, STOPPED_SPAN)
+
+#: the witness: its thread's name, how long it sleeps (a wake-up that
+#: finds the stepping thread in Python takes the interpreter's lock from
+#: it: at 5 ms that was most of 0.07-0.09 ms a step on the chip's host, PR
+#: 69's traced pairs; a stop reads up to a period short), how late a
+#: wake-up is a stop (PR 50's hand run: a thread that only sleeps never ran
+#: more than 20 ms late in 190 sound seconds, and 108-113 ms late in every
+#: stalled step; on the chip's host 1.1 ms at the most beside a sleeping
+#: main thread and 7.1 beside one that runs Python), how old its baseline
+#: of the host's counters may grow, and how long
+#: :meth:`SpanTracer.stopped_since` lets it catch up
+WITNESS_THREAD = "nxd-host-witness"
+WITNESS_PERIOD_NS = 10_000_000
+STOP_MIN_NS = 50_000_000
+WITNESS_BASELINE_NS = 1_000_000_000
+WITNESS_SETTLE_NS = 40_000_000
 
 #: an event's own keys. A span's attributes lie flat beside them in the
 #: tracer's list (``args`` is built at export): a dict of strings and numbers
@@ -74,7 +106,7 @@ _EVENT_KEYS = ("name", "ph", "ts", "dur", "pid", "tid")
 _RESERVED = frozenset(_EVENT_KEYS + ("args",))
 
 
-def _attrs_of(ev: Dict[str, Any]) -> Dict[str, Any]:
+def attrs_of(ev: Dict[str, Any]) -> Dict[str, Any]:
     """An event's attributes, whichever way it holds them."""
     if "args" in ev:
         return ev["args"]
@@ -86,7 +118,7 @@ def _exported(ev: Dict[str, Any]) -> Dict[str, Any]:
     if len(ev) == len(_EVENT_KEYS) or "args" in ev:
         return dict(ev)
     out = {k: ev[k] for k in _EVENT_KEYS}
-    out["args"] = _attrs_of(ev)
+    out["args"] = attrs_of(ev)
     return out
 
 
@@ -231,10 +263,17 @@ class SpanTracer:
         self._requests: Dict[str, _RequestTrace] = {}
         self._lock = threading.Lock()
         self._tls = threading.local()
-        # collections the hook has timed and no record has folded in yet:
-        # (start ns, end ns, generation, collected, thread)
-        self._gc_pending: collections.deque = collections.deque(maxlen=4096)
+        # pauses that have ended and no record has folded in yet (the
+        # collector's hook and the witness append, neither takes a lock):
+        # (span name, start ns, end ns, thread, attributes)
+        self._pending: collections.deque = collections.deque(maxlen=4096)
         self._gc_open: Optional[Tuple[int, Any]] = None
+        # while the host is watched: the witness's thread and what stops
+        # it, when it last woke, and the meter of the threads that ask
+        self._witness: Optional[Tuple[threading.Thread,
+                                      threading.Event]] = None
+        self._witness_seen_ns: Optional[int] = None
+        self._meter: Optional[host.ThreadMeter] = None
 
     # -- plumbing ---------------------------------------------------
     def _stack(self) -> List[Span]:
@@ -272,8 +311,8 @@ class SpanTracer:
         else:
             ev["args"] = attrs
         with self._lock:
-            if self._gc_pending:
-                self._fold_gc()
+            if self._pending:
+                self._fold_pauses()
             self._append_event(ev)
             self._add_stat(span.name, dur)
 
@@ -289,7 +328,7 @@ class SpanTracer:
             self._gc_open = None
 
     def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
-        """The hook: two clock readings a collection into ``_gc_pending``.
+        """The hook: two clock readings a collection into ``_pending``.
         It runs in whatever thread allocated last, possibly under
         ``_lock`` or the registry's lock, and takes neither. Inside
         ``profile_step`` the pause is a profiler annotation as well, as
@@ -311,30 +350,133 @@ class SpanTracer:
             return
         if opened[1] is not None:
             opened[1].__exit__(None, None, None)
-        self._gc_pending.append((opened[0], end, info["generation"],
-                                 info["collected"], threading.get_ident()))
+        self._pending.append((
+            GC_SPAN, opened[0], end, threading.get_ident(),
+            {"generation": info["generation"],
+             "collected": info["collected"]}))
 
-    def _fold_gc(self) -> None:
+    def watch_host(self, on: bool) -> None:
+        """Start (or stop and join) the witness thread, and with it open
+        (or close) the meter behind :meth:`thread_reading`. Idempotent."""
+        witness = self._witness
+        if on and witness is None:
+            stop = threading.Event()
+            thread = threading.Thread(
+                target=self._witness_loop, args=(stop,), name=WITNESS_THREAD,
+                daemon=True)
+            self._meter = host.ThreadMeter()
+            self._witness = (thread, stop)
+            thread.start()
+        elif witness is not None and not on:
+            self._witness = None
+            witness[1].set()
+            witness[0].join(timeout=1.0)
+            self._witness_seen_ns = None
+            meter, self._meter = self._meter, None
+            meter.close()
+
+    def _witness_loop(self, stop: threading.Event,
+                      clock=time.perf_counter_ns, sleep=time.sleep) -> None:
+        """The witness: sleep a period, read the clock, and where the
+        wake-up came ``STOP_MIN_NS`` late say so into ``_pending`` with
+        what the host's counters gained since the baseline (read again
+        about once a second, and after a stop). Like ``_on_gc`` it takes no
+        lock, and a wake-up is some microseconds of the interpreter's
+        (``time.sleep`` and no ``Event.wait``, which is a dozen calls of
+        Python): it looks at ``stop`` once it is awake."""
+        counters = host.HostCounters()
+        base, base_at = counters.read(), clock()
+        due = base_at + WITNESS_PERIOD_NS
+        ident = threading.get_ident()
+        while True:
+            sleep(WITNESS_PERIOD_NS * 1e-9)
+            if stop.is_set():
+                return
+            now = clock()
+            if now - due >= STOP_MIN_NS:
+                after = counters.read()
+                if self.enabled:
+                    self._pending.append((STOPPED_SPAN, due, now, ident,
+                                          host.deltas(base, after)))
+                base, base_at = after, now
+            elif now - base_at >= WITNESS_BASELINE_NS:
+                base, base_at = counters.read(), now
+            # after the append: who sees a late wake-up sees its stop
+            self._witness_seen_ns = now
+            due = now + WITNESS_PERIOD_NS
+
+    def thread_reading(self) -> Optional[host.Reading]:
+        """The calling thread's cumulative CPU time, switches, faults and
+        run-queue delay (:class:`.host.ThreadMeter`), or ``None`` while the
+        host is not watched; :func:`.host.since` subtracts two of them."""
+        meter = self._meter
+        return None if meter is None else meter.read()
+
+    def stopped_since(self, since_us: float) -> List[Dict[str, Any]]:
+        """The ``host/stopped`` spans that ended at or after ``since_us``,
+        oldest first. The witness wakes from a stop when the caller does
+        and needs the interpreter's lock to say so: where it has not been
+        seen for two periods the caller sleeps, half a millisecond at a
+        time and ``WITNESS_SETTLE_NS`` at most, until it has. (A wake-up it
+        has been seen at since is later than any stop that has ended: a
+        stop keeps it away for ``STOP_MIN_NS`` at least.)"""
+        give_up = time.perf_counter_ns() + WITNESS_SETTLE_NS
+        while self._witness is not None:
+            seen, now = self._witness_seen_ns, time.perf_counter_ns()
+            if (seen is None or now - seen <= 2 * WITNESS_PERIOD_NS
+                    or now >= give_up):
+                break
+            time.sleep(0.0005)
+        return [ev for ev in reversed(self._closed_since(since_us))
+                if ev["name"] == STOPPED_SPAN]
+
+    def _fold_pauses(self) -> None:
         # caller holds self._lock
-        pending = self._gc_pending
+        pending = self._pending
         reg = get_registry()
-        seconds = reg.counter(
-            "nxd_host_gc_seconds_total",
-            "Seconds the interpreter spent in garbage collections, by "
-            "generation (the host/gc spans' durations).",
-            labels=("generation",)) if reg.enabled else None
         while pending:
-            t0, end, generation, collected, ident = pending.popleft()
+            name, t0, end, ident, attrs = pending.popleft()
             dur = (end - t0) / 1000.0
-            self._append_event({
-                "name": GC_SPAN, "ph": "X", "ts": t0 / 1000.0, "dur": dur,
-                "pid": os.getpid(), "tid": ident % 10000,
-                "generation": generation, "collected": collected})
-            self._add_stat(GC_SPAN, dur)
-            if seconds is not None:
-                seconds.labels(generation=str(generation)).inc(dur * 1e-6)
+            ev = {"name": name, "ph": "X", "ts": t0 / 1000.0, "dur": dur,
+                  "pid": os.getpid(), "tid": ident % 10000}
+            ev.update(attrs)
+            self._append_event(ev)
+            self._add_stat(name, dur)
+            if not reg.enabled:
+                continue
+            if name == GC_SPAN:
+                reg.counter(
+                    "nxd_host_gc_seconds_total",
+                    "Seconds the interpreter spent in garbage collections, "
+                    "by generation (the host/gc spans' durations).",
+                    labels=("generation",)).labels(
+                        generation=str(attrs["generation"])).inc(dur * 1e-6)
+            else:
+                reg.counter(
+                    "nxd_host_stopped_seconds_total",
+                    "Seconds in which no thread of the process ran: the "
+                    "host/stopped spans' durations, each a wake-up of the "
+                    "witness thread that came 50 ms or more late.").inc(
+                        dur * 1e-6)
 
     # -- per-call records ---------------------------------------------
+    def _closed_since(self, since_us: float) -> List[Dict[str, Any]]:
+        """The events held that closed at or after ``since_us``, newest
+        first, the pauses that ended meanwhile folded in."""
+        with self._lock:
+            if self._pending:
+                self._fold_pauses()
+            n = len(self._events)
+            newest = (self._next - 1) if n == self.max_events else n - 1
+            tail = []
+            for k in range(n):
+                ev = self._events[(newest - k) % n]
+                if ev["ts"] + ev["dur"] >= since_us:
+                    tail.append(ev)
+                elif ev["name"] not in PAUSE_SPANS:
+                    break               # a pause is folded in late
+        return tail
+
     def step_records(self, since_us: float = 0.0
                      ) -> Dict[int, Dict[str, Any]]:
         """The events that closed at or after ``since_us`` and carry a
@@ -347,22 +489,11 @@ class SpanTracer:
         in (``self_us["host/gc"]`` is their sum). A view of the events
         the tracer holds, built when asked for; a span still open is in
         no record yet."""
-        with self._lock:
-            if self._gc_pending:
-                self._fold_gc()
-            n = len(self._events)
-            newest = (self._next - 1) if n == self.max_events else n - 1
-            tail = []
-            for k in range(n):
-                ev = self._events[(newest - k) % n]
-                if ev["ts"] + ev["dur"] >= since_us:
-                    tail.append(ev)
-                elif ev["name"] != GC_SPAN:     # a pause is folded in late
-                    break
+        tail = self._closed_since(since_us)
         calls: Dict[int, List[Dict[str, Any]]] = {}
         pauses = []
         for ev in tail:
-            step = _attrs_of(ev).get("step")
+            step = attrs_of(ev).get("step")
             if step is not None:
                 calls.setdefault(step, []).append(ev)
             elif ev["name"] == GC_SPAN:
@@ -387,7 +518,7 @@ class SpanTracer:
                 stack.append([ev["ts"] + ev["dur"], ev["name"], ev["dur"]])
                 if ev["name"] != GC_SPAN:
                     attrs.setdefault(ev["name"], {}).update(
-                        (k, v) for k, v in _attrs_of(ev).items()
+                        (k, v) for k, v in attrs_of(ev).items()
                         if k not in ("step", "parent"))
             for _, name, own in stack:
                 self_us[name] = self_us.get(name, 0.0) + max(own, 0.0)
@@ -615,8 +746,8 @@ class SpanTracer:
         """
         now = time.perf_counter_ns() / 1000.0
         with self._lock:
-            if self._gc_pending:
-                self._fold_gc()
+            if self._pending:
+                self._fold_pauses()
             if len(self._events) < self.max_events:
                 events = list(self._events)
             else:  # unroll the ring into chronological order
@@ -648,8 +779,8 @@ class SpanTracer:
         total, mean, min and max over every span recorded, quantiles over
         the name's reservoir."""
         with self._lock:
-            if self._gc_pending:
-                self._fold_gc()
+            if self._pending:
+                self._fold_pauses()
             snap = {name: (st.count, st.total, st.min, st.max,
                            list(st.reservoir))
                     for name, st in self._stats.items()}
@@ -676,7 +807,7 @@ class SpanTracer:
             self._next = 0
             self._stats.clear()
             self._requests.clear()
-            self._gc_pending.clear()
+            self._pending.clear()
 
 
 #: process-wide default tracer; enabled/disabled in lockstep with the
@@ -693,5 +824,6 @@ def get_tracer() -> SpanTracer:
                 tracer = SpanTracer(
                     enabled=os.environ.get("NXD_OBS", "0") == "1")
                 tracer.watch_gc(tracer.enabled)
+                tracer.watch_host(tracer.enabled)
                 _DEFAULT = tracer
     return _DEFAULT

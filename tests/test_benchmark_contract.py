@@ -243,7 +243,7 @@ def test_the_nemotron_cell_holds_every_slot_at_its_longest():
             "moe_held_pct.batch", "moe_dropped_pct.batch",
             "ssm_state_share_pct.batch", "state_bytes_held_pct.batch",
             "paged_attn_share_pct.batch", "unscoped_share_pct.batch"
-            } <= listed and len(listed) == 35
+            } <= listed and len(listed) >= 35   # later PRs append theirs
     assert not listed & {"ssd_state_roofline", "moe_experts_roofline",
                          "paged_attention_roofline", "kda_state_roofline"}
     assert name in E2E["serve_tok_s"]["workloads"]

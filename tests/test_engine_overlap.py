@@ -7,6 +7,7 @@ engine's serial modes and of a plain greedy loop over the model."""
 import dataclasses
 import functools
 import gc
+import threading
 import time
 
 import jax
@@ -25,6 +26,7 @@ from neuronx_distributed_tpu.inference.sampling import SamplingConfig
 from neuronx_distributed_tpu.models.llama import (LlamaForCausalLM,
                                                   llama_forward_with_cache,
                                                   tiny_config)
+from neuronx_distributed_tpu.obs.tracing import STOPPED_SPAN, WITNESS_THREAD
 from neuronx_distributed_tpu.parallel import mesh as ps
 
 MAX_LEN = 32
@@ -332,24 +334,54 @@ PAUSE_S = 0.12
 class _SlowToBeReady:
     """What a step returned, on a device that takes ``PAUSE_S`` longer."""
 
-    def __init__(self, array):
-        self.array = array
+    def __init__(self, array, how=""):
+        self.array, self.how = array, how
 
     def block_until_ready(self):
-        time.sleep(PAUSE_S)
+        _pause(self.how, PAUSE_S)
         self.array.block_until_ready()
 
     def __array__(self, dtype=None, copy=None):
         return np.asarray(self.array)
 
 
+def _pause(how, seconds):
+    """Lose ``seconds`` where this is called: asleep (``how`` ""), asleep
+    while the witness finds the process ``stopped`` (its late wake-up is
+    put where the witness puts it), awake and ``busy`` with the
+    interpreter's lock held while the witness starves on it (late as well),
+    or asleep with the thread on the ``runq`` all the while."""
+    tracer = obs.get_tracer()
+    t0 = time.perf_counter_ns()
+    if how == "busy":
+        while time.perf_counter_ns() - t0 < seconds * 1e9:
+            pass
+    else:
+        time.sleep(seconds)
+    if how in ("stopped", "busy"):
+        tracer._pending.append((
+            STOPPED_SPAN, t0, time.perf_counter_ns(), tracer._witness[0].ident,
+            {"throttled": 2.0, "pressure_us": seconds * 1e6}))
+    elif how == "runq":
+        _pause.runq_ns += int(seconds * 1e9)
+
+
+_pause.runq_ns = 0
+
+
 def _pause_in(eng, where, monkeypatch):
     """Arrange for the next ``step()`` of ``eng`` to lose ``PAUSE_S`` in
-    ``where``; returns the thunk that takes the pause out again."""
+    ``where`` (``<how>_<where>``: the manner of :func:`_pause`); returns
+    the thunk that takes the pause out again."""
+    how, _, where = where.rpartition("_")
+    if how == "runq":
+        reading = obs.get_tracer().thread_reading
+        monkeypatch.setattr(obs.get_tracer(), "thread_reading", lambda: (
+            lambda r: r[:5] + ((r[5] or 0) + _pause.runq_ns,))(reading()))
     if where == "pack":                 # inside engine/packed/pack
         count = eng._count_step
         monkeypatch.setattr(eng, "_count_step", lambda *a: (
-            time.sleep(PAUSE_S), count(*a))[1])
+            _pause(how, PAUSE_S), count(*a))[1])
     elif where == "gc":                 # a collection, inside the same span
         count = eng._count_step
         # a generation-2 pass over some hundred thousand containers
@@ -361,14 +393,14 @@ def _pause_in(eng, where, monkeypatch):
         fetch = eng._fetch
 
         def slow_fetch(flight, span, **attrs):
-            flight.sampled = _SlowToBeReady(flight.sampled)
+            flight.sampled = _SlowToBeReady(flight.sampled, how)
             return fetch(flight, span, **attrs)
 
         monkeypatch.setattr(eng, "_fetch", slow_fetch)
     elif where == "tables":             # an upload that does not leave
         put = jax.device_put
         monkeypatch.setattr(jax, "device_put", lambda *a, **kw: (
-            time.sleep(PAUSE_S / 2), put(*a, **kw))[1])
+            _pause(how, PAUSE_S / 2), put(*a, **kw))[1])
     return monkeypatch.undo
 
 
@@ -377,12 +409,27 @@ def _wall_children():
         "nxd_engine_step_wall_seconds_total").children()}
 
 
-@pytest.mark.parametrize("where,cause", [
-    ("pack", "host"), ("ready", "device"), ("tables", "transfer"),
-    ("gc", "host_pause")])
+def _cause_children():
+    return {c.labels["cause"]: c.value for c in obs.get_registry().get(
+        "nxd_engine_stall_cause_seconds_total").children()}
+
+
+@pytest.mark.parametrize("where,cause,why", [
+    # the witness on time: a wait is a wait, wherever it was
+    ("pack", "host", "other"), ("ready", "device", "other"),
+    ("tables", "transfer", "other"), ("gc", "host_pause", "other"),
+    # the process stopped: the same places, another cause
+    ("stopped_ready", "device", "process_stopped"),
+    ("stopped_tables", "transfer", "process_stopped"),
+    ("stopped_pack", "host", "process_stopped"),
+    # the witness late because the stepping thread held the lock: it ran
+    ("busy_pack", "host", "other"),
+    # runnable all the while, and no core
+    ("runq_pack", "host", "cpu_wait")])
 def test_a_slow_call_puts_its_excess_under_its_cause(tiny_model, where,
-                                                     cause, monkeypatch):
+                                                     cause, why, monkeypatch):
     obs.enable()
+    _pause.runq_ns = 0
     events = []
     unsubscribe = obs.subscribe(
         lambda name, fields: events.append((name, fields)))
@@ -391,17 +438,19 @@ def test_a_slow_call_puts_its_excess_under_its_cause(tiny_model, where,
         eng.submit(_prompt(i, 5, tiny_model[0].vocab_size), 24, uid=f"r{i}")
     for _ in range(12):                 # the rule has its median at 8
         eng.step()
-    before = _wall_children()
+    before, why_before = _wall_children(), _cause_children()
     undo = _pause_in(eng, where, monkeypatch)
     eng.step()
     undo()
     slow_call = eng._calls
-    after = _wall_children()
+    after, why_after = _wall_children(), _cause_children()
     while eng.has_work():
         eng.step()
     unsubscribe()
 
     gained = {k: after[k] - before.get(k, 0.0) for k in after}
+    why_gained = {k: why_after[k] - why_before.get(k, 0.0)
+                  for k in why_after}
     median_s = eng._stall.median * 1e-6
     # the median to steady, the rest by cause: nearly all under this one
     assert gained["steady"] == pytest.approx(median_s, rel=0.5)
@@ -409,21 +458,66 @@ def test_a_slow_call_puts_its_excess_under_its_cause(tiny_model, where,
     assert excess > 0.8 * PAUSE_S / (2 if where == "gc" else 1) \
         or where == "gc" and excess > 10 * median_s
     assert gained[cause] > 0.8 * excess, gained
-    # every call's wall is in the counter, once
-    children = _wall_children()
+    # the same seconds by cause: nine tenths of them under this one
+    assert why_gained["steady"] == pytest.approx(gained["steady"], abs=1e-9)
+    assert sum(why_gained.values()) - why_gained["steady"] == pytest.approx(
+        excess, abs=1e-9)
+    # (of the pause that was injected: a machine that other tests load
+    # adds waits of its own to the call, keeps a runnable thread off its
+    # core, which is cpu_wait by right, the witness too, whose stop is
+    # then real and says so, and a thread that would burn CPU off it for
+    # part of the time, so that one is held to half)
+    [fields] = [f for name, f in events
+                if name == "slow_step" and f["step"] == slow_call]
+    real = [stop for stop in fields["stops"] if stop.get("throttled") != 2.0]
+    mine = why_gained[why]
+    if why == "other":
+        mine += why_gained["cpu_wait"] + (
+            why_gained["process_stopped"] if real else 0)
+    assert mine > min(excess, PAUSE_S) * (
+        0.5 if where in ("gc", "busy_pack") else 0.9), why_gained
+    # every call's wall is in each counter, once
+    children, whys = _wall_children(), _cause_children()
     assert set(children) == {"steady", "host_pause", "device", "transfer",
                              "compile", "host"}
-    assert sum(children.values()) == pytest.approx(
-        sum(eng._stall.walls) * 1e-6, abs=1e-6)
+    assert set(whys) == {"steady", "process_stopped", "cpu_wait", "other"}
+    for counted in (children, whys):
+        assert sum(counted.values()) == pytest.approx(
+            sum(eng._stall.walls) * 1e-6, abs=1e-6)
+    assert sum(children.values()) - children["steady"] == pytest.approx(
+        sum(whys.values()) - whys["steady"], abs=1e-6)
     assert eng._stall.calls == eng.stats.steps == len(eng._stall.walls)
 
     # one event for the slow call, with both calls' facts
-    [fields] = [f for name, f in events
-                if name == "slow_step" and f["step"] == slow_call]
     assert fields["wall_ms"] > 3 * fields["median_ms"] > 0
     assert sum(fields["split_ms"].values()) == pytest.approx(
         fields["wall_ms"] - fields["median_ms"], abs=0.01)
     assert max(fields["split_ms"], key=fields["split_ms"].get) == cause
+    assert sum(fields["cause_ms"].values()) == pytest.approx(
+        sum(fields["split_ms"].values()), abs=0.01)
+    assert max(fields["cause_ms"], key=fields["cause_ms"].get) == why or real
+    assert 0 <= fields["cpu_ms"] <= fields["wall_ms"]
+    # (a collection of 100 ms holds the interpreter's lock: the witness is
+    # late by right, and the thread's own CPU time says that it ran)
+    late = where.startswith(("stopped", "busy"))
+    ran = where.startswith("busy") or where == "gc"
+    assert (fields["stopped_ms"] > 0.9 * PAUSE_S * 1e3) == late or ran \
+        or real
+    assert (fields["cpu_ms"] > 0.5 * fields["wall_ms"]) == ran
+    assert fields["cause_ms"]["process_stopped"] <= max(
+        0.0, fields["wall_ms"] - fields["cpu_ms"]) + 0.01
+    injected = [stop for stop in fields["stops"] if stop not in real]
+    assert [set(stop) for stop in injected] == [
+        {"at_ms", "ms", "throttled", "pressure_us"}] * (
+            (2 if where.endswith("tables") else 1) if late else 0)
+    assert all(0 <= stop["at_ms"] <= fields["wall_ms"] for stop in injected)
+    assert (fields["cpu_wait_ms"] or 0) >= (
+        PAUSE_S * 1e3 if where.startswith("runq") else 0)
+    assert fields["core"] is None or fields["core"] >= 0
+    assert {"switches_voluntary", "switches_involuntary", "faults_minor",
+            "faults_major"} <= set(fields)
+    assert fields["switches_voluntary"] >= (0 if where in (
+        "gc", "busy_pack") else 1)      # a sleep gives the core up
     # (a young collection may fall into any call; the forced one is old)
     assert (fields["gc_generation"] == 2) == (where == "gc")
     assert (fields["gc_ms"] > 0.5 * fields["wall_ms"]) == (where == "gc")
@@ -436,9 +530,15 @@ def test_a_slow_call_puts_its_excess_under_its_cause(tiny_model, where,
     for facts in (this, prev):
         assert {"decode_rows", "prefill_rows", "pad_rows", "kind",
                 "admitted", "retired", "preempted", "cleared", "cow_copies",
-                "rolled", "compiled"} <= set(facts)
+                "rolled", "compiled", "cpu_us"} <= set(facts)
     assert "engine/packed/dispatch" in prev["spans_ms"]
-    assert fields["memory"] is None     # the CPU backend reports none
+    assert "memory" not in fields       # nothing read it (PR 69)
+    # every call, slow or not, says what CPU time its thread took
+    packed = [r for r in obs.get_tracer().step_records().values()
+              if "kind" in r["attrs"]["engine/publish"]]
+    assert len(packed) == eng.stats.steps and all(
+        0 <= r["attrs"]["engine/publish"]["cpu_us"]
+        <= r["return_us"] - r["entry_us"] + 1e3 for r in packed)
     counted = {c.labels["event"]: c.value for c in obs.get_registry().get(
         "nxd_events_total").children()}
     assert counted["slow_step"] == sum(
@@ -472,10 +572,17 @@ def test_a_call_in_which_a_worker_compiled_is_compile(tiny_model):
 
 
 @pytest.mark.parametrize("where", ["events", "state", "stats_fields",
-                                   "hook", "spans"])
+                                   "hook", "spans", "witness"])
 def test_with_obs_off_a_paused_step_leaves_nothing(tiny_model, where,
                                                    monkeypatch):
     assert not obs.enabled()
+    if where == "witness":              # nor does a step read its thread
+        def read(*_):
+            raise AssertionError("a reading of the thread with obs off")
+
+        monkeypatch.setattr(time, "thread_time_ns", read)
+        monkeypatch.setattr("resource.getrusage", read)
+        monkeypatch.setattr(type(obs.get_tracer()), "thread_reading", read)
     events = []
     unsubscribe = obs.subscribe(
         lambda name, fields: events.append((name, fields)))
@@ -510,6 +617,9 @@ def test_with_obs_off_a_paused_step_leaves_nothing(tiny_model, where,
     elif where == "hook":
         assert not any(getattr(cb, "__self__", None) is obs.get_tracer()
                        for cb in gc.callbacks)
+    elif where == "witness":
+        assert WITNESS_THREAD not in [t.name for t in threading.enumerate()]
+        assert obs.get_tracer()._meter is None and eng._stall is None
     else:
         assert obs.get_tracer().chrome_trace()["traceEvents"] == []
 

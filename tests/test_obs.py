@@ -4,8 +4,13 @@ compile tracking, wire-byte accounting vs the codec's predictions, the
 event channel, and the logger satellites."""
 
 import contextlib
+import gc
 import json
 import logging
+import os
+import signal
+import subprocess
+import sys
 import threading
 import time
 
@@ -16,6 +21,8 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from neuronx_distributed_tpu import obs
+from neuronx_distributed_tpu.obs import host as obs_host
+from neuronx_distributed_tpu.obs import tracing
 from neuronx_distributed_tpu.obs.metrics import MetricsRegistry
 from neuronx_distributed_tpu.obs.tracing import SpanTracer
 from neuronx_distributed_tpu.parallel import mesh as ps
@@ -521,7 +528,7 @@ def test_with_obs_off_a_collection_leaves_nothing():
     gc.collect()
     tracer = obs.get_tracer()
     assert tracer.chrome_trace()["traceEvents"] == []
-    assert not tracer._gc_pending
+    assert not tracer._pending
     assert obs.get_registry().get("nxd_host_gc_seconds_total") is None
 
 
@@ -586,6 +593,188 @@ def test_step_records_group_the_events_by_their_step(case):
         assert rec["attrs"]["a"] == {} and rec["gc"] == []
         assert rec["return_us"] == pytest.approx(b["ts"] + b["dur"])
         assert rec["entry_us"] <= b["ts"] and rec["step"] == step
+
+
+# ---------------------------------------------------------------------------
+# a stop of the process: the witness thread, host/stopped spans
+# ---------------------------------------------------------------------------
+
+
+def _witnesses():
+    return [t for t in threading.enumerate()
+            if t.name == tracing.WITNESS_THREAD]
+
+
+@pytest.mark.parametrize("switch", ["enable_disable", "enable_twice",
+                                    "disable_twice", "reset_keeps_it"])
+def test_the_witness_lives_exactly_while_obs_is_enabled(switch):
+    tracer = obs.get_tracer()
+    assert not obs.enabled() and _witnesses() == []
+    assert tracer.thread_reading() is None
+    obs.enable()
+    witness, = _witnesses()
+    assert witness.daemon and witness is tracer._witness[0]
+    if switch == "enable_twice":
+        obs.enable()
+        assert _witnesses() == [witness]
+    if switch == "reset_keeps_it":
+        obs.reset()
+        assert _witnesses() == [witness]
+    # while it lives a thread can ask what it has done so far
+    began = tracer.thread_reading()
+    sum(range(200_000))
+    did = obs_host.since(began, tracer.thread_reading())
+    assert did["cpu_us"] > 0 and did["faults_major"] >= 0
+    assert {"switches_voluntary", "switches_involuntary",
+            "faults_minor"} <= set(did)
+    obs.disable()
+    assert _witnesses() == [] and not witness.is_alive()
+    assert tracer.thread_reading() is None and tracer._meter is None
+    if switch == "disable_twice":
+        obs.disable()
+        assert _witnesses() == []
+
+
+@pytest.mark.parametrize("counted", [0, 3])
+def test_a_meter_asks_only_a_kernel_that_counts_switches(counted,
+                                                        monkeypatch):
+    """A sandbox's kernel reads five zeros for 5.7 us: the thread that
+    makes the meter looks once, and no reading asks again."""
+    import resource
+
+    real, calls = resource.getrusage, []
+
+    def getrusage(who):
+        calls.append(who)
+        ru = list(real(who))
+        ru[14] = ru[15] = counted   # ru_nvcsw, ru_nivcsw
+        return resource.struct_rusage(ru)
+
+    monkeypatch.setattr(resource, "getrusage", getrusage)
+    meter = obs_host.ThreadMeter()
+    try:
+        began = meter.read()
+        time.sleep(0.002)
+        did = obs_host.since(began, meter.read())
+    finally:
+        meter.close()
+    assert len(calls) == (3 if counted else 1)
+    assert did["cpu_us"] >= 0 and did["switches_voluntary"] == 0
+    assert meter._fds == {}
+
+
+class _Wakes:
+    """The clock and the sleep of a witness that wakes as often as the
+    clock has readings left, and then finds ``stop`` set."""
+
+    def __init__(self, ticks_ms):
+        # the loop reads the clock once before its first sleep
+        self.ticks = [int(t * 1e6) for t in ticks_ms]
+        self.at = -1
+        self.stop = threading.Event()
+
+    def clock(self):
+        self.at += 1
+        return self.ticks[self.at]
+
+    def sleep(self, seconds):
+        assert seconds == pytest.approx(tracing.WITNESS_PERIOD_NS * 1e-9)
+        if self.at == len(self.ticks) - 1:
+            self.stop.set()
+
+
+@pytest.mark.parametrize("ticks_ms,stops", [
+    ([0, 10, 20, 30.2], []),                    # on time
+    ([0, 10, 59, 69], []),                      # 39 ms late: no stop
+    ([0, 10, 140, 150], [(20, 120)]),           # due at 20, woke at 140
+    ([0, 10, 140, 150, 320.5], [(20, 120), (160, 160.5)])])
+def test_a_late_wake_up_leaves_one_host_stopped_span(ticks_ms, stops,
+                                                     monkeypatch):
+    counts = iter(range(100))
+    monkeypatch.setattr(obs_host.HostCounters, "read", lambda self: {
+        "throttled": float(next(counts)), "pressure_us": 7.0})
+    obs.get_registry().enable()
+    tracer = SpanTracer()
+    wakes = _Wakes(ticks_ms)
+    tracer._witness_loop(wakes.stop, clock=wakes.clock, sleep=wakes.sleep)
+    assert tracer._witness_seen_ns == wakes.ticks[-1]
+    events = [ev for ev in tracer.chrome_trace()["traceEvents"]
+              if ev["name"] == "host/stopped"]
+    assert [(ev["ts"] * 1e-3, ev["dur"] * 1e-3) for ev in events] == [
+        pytest.approx(stop) for stop in stops]
+    # what the host's counters gained since the baseline, flat numbers
+    assert [ev["args"] for ev in events] == [
+        {"throttled": 1.0, "pressure_us": 0.0}] * len(stops)
+    held = [ev for ev in tracer._events if ev["name"] == "host/stopped"]
+    assert all("args" not in ev and not gc.is_tracked(ev) for ev in held)
+    assert len({ev["tid"] for ev in events}) <= 1
+    assert tracer.stopped_since(0.0) == held
+    assert tracer.stopped_since(150e3) == held[1:]
+    counter = obs.get_registry().get("nxd_host_stopped_seconds_total")
+    if not stops:
+        assert counter is None
+    else:
+        assert counter.value == pytest.approx(
+            sum(ms for _, ms in stops) * 1e-3)
+        assert tracer.stats()["host/stopped"]["count"] == len(stops)
+    # a span closing later does not hide a stop that was folded in late
+    with tracer.span("later", step=1):
+        pass
+    assert tracer.stopped_since(0.0) == held
+    assert set(tracer.step_records()) == {1}
+
+
+def test_with_the_tracer_off_a_late_wake_up_leaves_nothing():
+    tracer = SpanTracer(enabled=False)
+    wakes = _Wakes([0, 10, 140, 150])
+    tracer._witness_loop(wakes.stop, clock=wakes.clock, sleep=wakes.sleep)
+    assert not tracer._pending and tracer._events == []
+
+
+_STOPPED_CHILD = """
+import json, sys
+from neuronx_distributed_tpu import obs
+obs.enable()
+print("ready", flush=True)
+sys.stdin.readline()
+stops = obs.get_tracer().stopped_since(0.0)
+seconds = obs.get_registry().get("nxd_host_stopped_seconds_total")
+print(json.dumps({"stops": [ev["dur"] * 1e-6 for ev in stops],
+                  "keys": sorted(set().union(*stops)),
+                  "seconds": seconds and seconds.value}), flush=True)
+"""
+
+
+@pytest.mark.skipif(not hasattr(signal, "SIGSTOP"),
+                    reason="no SIGSTOP on this platform")
+def test_a_process_frozen_by_sigstop_reports_one_stop():
+    """The real thing: a child with obs on, frozen for 0.3 s and
+    continued, holds one ``host/stopped`` span of that length."""
+    child = subprocess.Popen(
+        [sys.executable, "-c", _STOPPED_CHILD], stdin=subprocess.PIPE,
+        stdout=subprocess.PIPE, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", NXD_OBS="0"))
+    limit = threading.Timer(60.0, child.kill)   # the test's own time limit
+    limit.start()
+    try:
+        assert child.stdout.readline().strip() == "ready"
+        time.sleep(0.2)
+        child.send_signal(signal.SIGSTOP)
+        time.sleep(0.3)
+        child.send_signal(signal.SIGCONT)
+        time.sleep(0.2)
+        out, _ = child.communicate("done\n")
+    finally:
+        limit.cancel()
+        child.kill()
+    assert child.returncode == 0
+    got = json.loads(out.splitlines()[-1])
+    # (a loaded machine may stop a child for 50 ms of its own accord)
+    long = [s for s in got["stops"] if s >= 0.2]
+    assert len(long) == 1 and 0.25 <= long[0] <= 0.45, got
+    assert got["seconds"] == pytest.approx(sum(got["stops"]))
+    assert {"name", "ts", "dur", "tid"} <= set(got["keys"])
+    assert "args" not in got["keys"]
 
 
 # ---------------------------------------------------------------------------

@@ -1131,7 +1131,8 @@ def test_the_benchmarks_counters_are_declared_and_documented():
     assert by_families == set(declared_anywhere())
     assert read <= by_families | {"nxd_engine_rows_total",
                                   "nxd_engine_steps_total",
-                                  "nxd_engine_step_wall_seconds_total"}
+                                  "nxd_engine_step_wall_seconds_total",
+                                  "nxd_engine_stall_cause_seconds_total"}
     with open(os.path.join(root, "docs", "observability.md")) as f:
         catalog = {line.split("`")[1] for line in f
                    if line.startswith("| `nxd_")}
