@@ -96,8 +96,10 @@ class StepGeometry(NamedTuple):
     the model it was bound to (:func:`step_counter`): the pool's block
     size, its blocks and the bytes of one of its elements, the model's
     query heads, the query heads the paged kernel sees a K/V head (the
-    model's, times the K/V heads the kind lays on a row), and the K and V
-    values a position holds a layer (:meth:`FullCache.row_values`)."""
+    model's, times the K/V heads the kind lays on a row), the K and V
+    values a position holds a layer (:meth:`FullCache.row_values`), and
+    the packed rows a slot holds side by side in a step (a block family's
+    block length, :attr:`ServingFamily.block`; 1 for every other)."""
 
     block_size: int
     pool_blocks: int
@@ -105,16 +107,19 @@ class StepGeometry(NamedTuple):
     heads: int
     n_rep: int
     row_values: int
+    slot_rows: int = 1
 
 
 def step_counter(kind, model_cfg, *, block_size: int, pool_blocks: int,
                  itemsize: int) -> Callable[..., Dict[str, Any]]:
     """``kind.count_step`` bound, once an engine, to the geometry it
     counts by."""
+    block = model_cfg.serving_family().block
     return functools.partial(kind.count_step, StepGeometry(
         block_size, pool_blocks, itemsize, model_cfg.num_heads,
         model_cfg.num_heads // model_cfg.num_kv_heads * kind.pack,
-        kind.row_values(model_cfg)))
+        kind.row_values(model_cfg),
+        1 if block is None else block.block_length))
 
 
 PAGED_COLUMNS = CounterFamily(
@@ -406,12 +411,12 @@ def _count_pairs(geo: StepGeometry, served) -> Dict[str, Any]:
     from ..ops.paged_attention import (block_fetches, host_pairs, pair_kinds,
                                        run_length)
 
-    pairs = host_pairs(served, geo.n_rep, geo.pool_blocks)
+    pairs = host_pairs(served, geo.n_rep, geo.pool_blocks, geo.slot_rows)
     narrow, one_row_whole, shared = pair_kinds(served, geo.n_rep,
                                                geo.pool_blocks, pairs)
     run = run_length(geo.n_rep,
                      geo.block_size * geo.row_values * geo.itemsize,
-                     geo.block_size, served.shape[1])
+                     geo.block_size, served.shape[1], geo.slot_rows)
     return {PAGED_PAIRS.name: (narrow, one_row_whole),
             PAGED_SHARED_PAIRS.name: (shared,),
             PAGED_BLOCK_FETCHES.name: block_fetches(served, geo.n_rep, run,

@@ -521,7 +521,8 @@ def _paged_cache_attend(cfg: LlamaConfig, q, k, v, positions, view,
         scale=cfg.attn_scale_,
         force_pallas=cfg.attn_force_pallas,
         combine_axis=combine, walk=view.walk, sliding=view.sliding,
-        sink=sink)[None]
+        sink=sink,
+        slot_rows=1 if block is None else block.block_length)[None]
     new_view = view.replace(k=new_k, v=new_v, k_scale=new_ks,
                             v_scale=new_vs)
     return out.astype(cfg.dtype), new_view

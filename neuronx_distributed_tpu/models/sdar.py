@@ -159,7 +159,8 @@ def sdar_forward_with_cache(cfg: SdarConfig, params, input_ids, positions,
             kv_cache.block_size, kv_cache.num_blocks, cfg.head_dim_,
             cfg.num_heads // cfg.num_kv_heads,
             force_pallas=cfg.attn_force_pallas,
-            pools=(kv_cache.k, kv_cache.v))
+            pools=(kv_cache.k, kv_cache.v),
+            slot_rows=cfg.block_decoding.block_length)
     with device_scope("attn.pool_write"):
         pool_pos = paging.write_pool_positions(kv_cache.pos, q_pos,
                                                write_idx)

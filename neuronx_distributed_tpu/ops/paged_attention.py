@@ -56,6 +56,20 @@ Two implementations behind one signature, following
   each; under GQA-4 two packed rows share a group and their blocks
   interleave in its runs.
 
+  The group is as tall as the packed rows a slot holds side by side in
+  the step (``slot_rows``, the family's own number: 1, or the block
+  length of a family that decodes blocks, whose slot packs its block's
+  rows a step, all attending through the block's last position and so
+  all naming each of the slot's pool blocks). Where those rows' heads
+  fill whole sublanes the group is all of them, ``slot_rows * n_rep``
+  stacked rows beginning on a multiple of that (32 for 4 rows of 8
+  heads, four groups a tile of 128): a decoding slot's blocks are narrow
+  pairs of its own group and ride its runs, each block under the rows
+  that name it, and only what rows beyond one group name (a prefill
+  chunk's blocks) or a group that the tile's end cuts runs over the whole
+  tile. With one row a slot every function here returns what it returned
+  before the argument was there.
+
   The walk (:func:`tile_walk`) lists, a tile, the distinct pairs that
   are live for at least one of its rows, each once. Live is
   :func:`column_live` (a window-summary cache:
@@ -326,18 +340,33 @@ def tile_rows(n_rep: int, tokens: int) -> int:
     return min(rows, -(-tokens // 8) * 8)
 
 
-def narrow_rows(n_rep: int) -> int:
+def narrow_rows(n_rep: int, slot_rows: int = 1) -> int:
     """Rows (query heads stacked) of the narrow product of a pair that a
     single packed row names: whole sublanes that hold the row's ``n_rep``
     heads wherever they begin. Row ``r``'s heads begin at stacked row ``r
     * n_rep``, a multiple of ``gcd(n_rep, 8)`` past a whole sublane, so
     at most ``8 - gcd(n_rep, 8)`` past it: 8 rows for 1, 4 and 8 heads,
     16 for 6, 9 and 10, and their own number for heads of whole
-    sublanes, which begin on one."""
-    return -(-(8 - math.gcd(n_rep, 8) + n_rep) // 8) * 8
+    sublanes, which begin on one. Where a slot packs ``slot_rows`` rows
+    side by side (a family that decodes blocks: its block's rows, which
+    name the same pool blocks) and their heads are whole sublanes, the
+    group is all of them: 32 rows for a block of 4 rows of 8 heads."""
+    return (slot_group(n_rep, slot_rows)
+            or -(-(8 - math.gcd(n_rep, 8) + n_rep) // 8) * 8)
 
 
-def narrow_start(first, last, n_rep: int, wide: int, xp=jnp):
+def slot_group(n_rep: int, slot_rows: int) -> int:
+    """The stacked rows of a narrow group that is a slot's ``slot_rows``
+    packed rows' heads (:func:`narrow_rows`), or 0 where the group is one
+    row's: more rows than one, whose heads fill whole sublanes. Such
+    groups lie side by side from the tile's first row, each on a multiple
+    of its height."""
+    heads = slot_rows * n_rep
+    return heads if slot_rows > 1 and heads % 8 == 0 else 0
+
+
+def narrow_start(first, last, n_rep: int, wide: int, xp=jnp,
+                 slot_rows: int = 1):
     """Where the narrow product of a pair begins in a tile of ``wide``
     stacked rows, or -1 where it runs over the whole tile: ``first`` the
     stacked row of the first head that names the pair, ``last`` one past
@@ -347,8 +376,17 @@ def narrow_start(first, last, n_rep: int, wide: int, xp=jnp):
     would pass the tile's end from its last rows: at ``n_rep`` 6 row
     19's heads lie at 114..119 of 120, and its group begins at 104). A
     pair that one packed row names always fits; one that neighbouring
-    rows name, where they all do. Broadcasts over jnp arrays (the walk)
-    and NumPy ones (:func:`pair_kinds`, the tests)."""
+    rows name, where they all do. A slot's group (:func:`slot_group`)
+    begins on the multiple of its height that ``first`` lies in, not on
+    the sublane: a pair first named inside one group and last named in
+    the next belongs to neither, and neither does one whose group the
+    tile's end cuts. Broadcasts over jnp arrays (the walk) and NumPy
+    ones (:func:`pair_kinds`, the tests)."""
+    group = slot_group(n_rep, slot_rows)
+    if group:
+        start = first // group * group
+        return xp.where((last <= start + group) & (start + group <= wide),
+                        start, -1)
     group = narrow_rows(n_rep)
     start = first // 8 * 8
     if group > -(-n_rep // 8) * 8:      # else no row's group passes the end
@@ -388,7 +426,8 @@ def tile_pairs(served, rows: int, num_blocks: int, xp=jnp):
             (key // num_blocks).astype(xp.int32))
 
 
-def pair_kinds(served, n_rep: int, num_blocks: int, pairs=None):
+def pair_kinds(served, n_rep: int, num_blocks: int, pairs=None,
+               slot_rows: int = 1):
     """A step's pairs (one layer's worth) by how the kernel computes
     them, ``[narrow, one_row_whole, shared]``, from ``served [T,
     max_blocks_per_seq]`` (NumPy: a row's table entry in the columns it
@@ -400,13 +439,15 @@ def pair_kinds(served, n_rep: int, num_blocks: int, pairs=None):
     engine's ``nxd_paged_pairs_total`` and
     ``nxd_paged_shared_pairs_total``, counted on the host (``pairs``:
     :func:`host_pairs` of the same step, where the caller has them)."""
-    first, last, start, _ = pairs or host_pairs(served, n_rep, num_blocks)
+    first, last, start, _ = pairs or host_pairs(served, n_rep, num_blocks,
+                                                slot_rows)
     narrow = start >= 0
     return np.array([narrow.sum(), (~narrow & (first == last)).sum(),
                      (~narrow & (first != last)).sum()], np.int64)
 
 
-def host_pairs(served, n_rep: int, num_blocks: Optional[int] = None):
+def host_pairs(served, n_rep: int, num_blocks: Optional[int] = None,
+               slot_rows: int = 1):
     """A step's pairs from ``served [T, max_blocks_per_seq]`` (NumPy), one
     entry a (tile, column, block) that some row of the tile attends:
     ``(first, last, start, group)``, the first and last row of its tile
@@ -425,7 +466,7 @@ def host_pairs(served, n_rep: int, num_blocks: Optional[int] = None):
     np.maximum.at(last, pair, row % rows)
     wide = rows * n_rep
     start = narrow_start(first * n_rep, (last + 1) * n_rep, n_rep, wide,
-                         xp=np)
+                         xp=np, slot_rows=slot_rows)
     return first, last, start, key // (maxb * blocks) * wide + start
 
 
@@ -457,7 +498,7 @@ class TileWalk(NamedTuple):
 
 
 def tile_walk(tables, q_pos, block_size: int, num_blocks: int, n_rep: int,
-              window=None, sliding=None) -> TileWalk:
+              window=None, sliding=None, slot_rows: int = 1) -> TileWalk:
     """The walk of one packed step, from ``tables [T, max_blocks_per_seq]``
     and ``q_pos [T]`` alone: routing, built once a step beside the write
     indices and handed to every layer's kernel. ``T`` is padded to whole
@@ -490,7 +531,8 @@ def tile_walk(tables, q_pos, block_size: int, num_blocks: int, n_rep: int,
         == blocks[:, :, None])                           # [tiles, P, rows]
     first = jnp.argmax(names, axis=-1) * n_rep
     last = (rows - jnp.argmax(names[:, :, ::-1], axis=-1)) * n_rep
-    narrow = narrow_start(first, last, n_rep, rows * n_rep).astype(jnp.int32)
+    narrow = narrow_start(first, last, n_rep, rows * n_rep,
+                          slot_rows=slot_rows).astype(jnp.int32)
 
     def by_tile(x):
         return jnp.repeat(x, n_rep, axis=0).reshape(
@@ -530,22 +572,25 @@ def unit_blocks(rows: int, block_bytes: int, block_size: int) -> int:
 
 
 def run_length(n_rep: int, block_bytes: int, block_size: int,
-               max_cols: int) -> int:
+               max_cols: int, slot_rows: int = 1) -> int:
     """Blocks of a run of the paged kernel for rows of ``n_rep`` query
     heads a K/V head over blocks of ``block_bytes`` (keys and values)
     under tables of ``max_cols`` columns: :func:`unit_blocks` of a narrow
     group's rows, and no more than a row has columns (a ring of two is a
     run of two). 1 is the kernel without runs."""
-    return min(unit_blocks(narrow_rows(n_rep), block_bytes, block_size),
+    return min(unit_blocks(narrow_rows(n_rep, slot_rows), block_bytes,
+                           block_size),
                1 << max_cols.bit_length() - 1)
 
 
-def run_blocks(k_pool, v_pool, n_rep: int, max_cols: int) -> int:
+def run_blocks(k_pool, v_pool, n_rep: int, max_cols: int,
+               slot_rows: int = 1) -> int:
     """:func:`run_length` over these pools (the stacks ``[L, num_blocks,
     block_size, ...]``, arrays or their shapes' structs)."""
     block_bytes = sum(math.prod(x.shape[2:]) * jnp.dtype(x.dtype).itemsize
                       for x in (k_pool, v_pool))
-    return run_length(n_rep, block_bytes, k_pool.shape[2], max_cols)
+    return run_length(n_rep, block_bytes, k_pool.shape[2], max_cols,
+                      slot_rows)
 
 
 class RunWalk(NamedTuple):
@@ -616,22 +661,25 @@ def pair_runs(count, blocks, cols, narrow, num_blocks: int, max_cols: int,
             jnp.where(lone, (kind - 1) * stride, -1), lens)
 
 
-def run_stride(n_rep: int) -> int:
+def run_stride(n_rep: int, slot_rows: int = 1) -> int:
     """What the first rows of a tile's narrow groups are multiples of
     (:func:`narrow_start`): the group itself where it is one packed row's
-    heads (``n_rep`` whole sublanes), else a sublane."""
-    return n_rep if n_rep % 8 == 0 else 8
+    heads (``n_rep`` whole sublanes) or a slot's rows' heads
+    (:func:`slot_group`), else a sublane."""
+    return (slot_group(n_rep, slot_rows)
+            or (n_rep if n_rep % 8 == 0 else 8))
 
 
 def run_walk(walk: TileWalk, num_blocks: int, n_rep: int, run: int,
-             whole_run: int, room: Optional[int] = None) -> RunWalk:
+             whole_run: int, room: Optional[int] = None,
+             slot_rows: int = 1) -> RunWalk:
     """:func:`pair_runs` of a step's :class:`TileWalk` over rows of
     ``n_rep`` stacked heads, in runs of ``run`` blocks of a narrow group
     and ``whole_run`` that a tile shares, with ``room`` entries past the
     last tile's pairs: as far as the kernel reads a unit's pairs whatever
     its length (the longer kind of unit, unless told)."""
     tiles, wide, max_cols = walk.served.shape
-    stride = run_stride(n_rep)
+    stride = run_stride(n_rep, slot_rows)
     units, *pairs = pair_runs(
         walk.count, *(x.reshape(tiles, -1) for x in
                       (walk.blocks, walk.cols, walk.narrow)),
@@ -644,7 +692,8 @@ def run_walk(walk: TileWalk, num_blocks: int, n_rep: int, run: int,
                    q_lo=walk.q_lo)
 
 
-def block_fetches(served, n_rep: int, run: int, pairs=None) -> np.ndarray:
+def block_fetches(served, n_rep: int, run: int, pairs=None,
+                  slot_rows: int = 1) -> np.ndarray:
     """Pool blocks a kernel that takes its pairs in runs fetches for one
     layer of a packed step whose rows attend ``served [T,
     max_blocks_per_seq]`` (NumPy: a row's table entry in the columns it
@@ -657,7 +706,8 @@ def block_fetches(served, n_rep: int, run: int, pairs=None) -> np.ndarray:
     ``nxd_paged_block_fetches_total``, ``nxd_mla_block_fetches_total``;
     ``tests/walk_checks.py`` holds the two to each other; ``pairs``:
     :func:`host_pairs` of the same step, where the caller has them)."""
-    *_, start, group = pairs or host_pairs(served, n_rep)
+    *_, start, group = pairs or host_pairs(served, n_rep,
+                                           slot_rows=slot_rows)
     # a (tile, group)'s narrow pairs are cut into runs, the last shorter
     many = np.bincount(group[start >= 0])
     alone = many if run == 1 else many % run == 1
@@ -667,20 +717,23 @@ def block_fetches(served, n_rep: int, run: int, pairs=None) -> np.ndarray:
 
 def step_walk(tables, q_pos, block_size: int, num_blocks: int, head_dim: int,
               n_rep: int, window=None, force_pallas: Optional[bool] = None,
-              sliding=None, *, pools):
+              sliding=None, *, pools, slot_rows: int = 1):
     """The walk of the layers of one step over ``pools``, the K and V
     stacks they attend, or ``None`` where :func:`paged_attention` runs the
     XLA reference for these shapes (:func:`paged_attention_impl`):
     :func:`tile_walk`, and where the kernel takes these pools' pairs in
     runs (:func:`run_blocks` more than 1) its cut into units
     (:func:`run_walk`; a pair that a tile shares stays a unit by itself).
-    A run of 1 is the tile walk as it is: no second sort."""
+    A run of 1 is the tile walk as it is: no second sort. ``slot_rows``:
+    the packed rows a slot holds side by side in the step, a family's
+    own number (a block family's block length; :func:`narrow_rows`)."""
     if paged_attention_impl(head_dim, block_size, force_pallas) == "xla":
         return None
     walk = tile_walk(tables, q_pos, block_size, num_blocks, n_rep, window,
-                     sliding)
-    run = run_blocks(*pools, n_rep, tables.shape[1])
-    return walk if run == 1 else run_walk(walk, num_blocks, n_rep, run, 1)
+                     sliding, slot_rows)
+    run = run_blocks(*pools, n_rep, tables.shape[1], slot_rows)
+    return walk if run == 1 else run_walk(walk, num_blocks, n_rep, run, 1,
+                                          slot_rows=slot_rows)
 
 
 def _head_rows(block_ref):
@@ -1108,7 +1161,8 @@ def _paged_run_kernel(units_ref, blocks_ref, cols_ref, narrow_ref, lens_ref,
 
 def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
                             layer, k_scale, v_scale, scale, interpret=False,
-                            window=None, walk=None, sliding=None, sink=None):
+                            window=None, walk=None, sliding=None, sink=None,
+                            slot_rows=1):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1123,11 +1177,12 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
     maxb = tables.shape[1]
     n_rep = n // kv
     quantized = k_scale is not None
-    run = run_blocks(k_pool, v_pool, n_rep, maxb)
+    run = run_blocks(k_pool, v_pool, n_rep, maxb, slot_rows)
     if walk is None:
-        walk = tile_walk(tables, q_pos, bs, nb, n_rep, window, sliding)
+        walk = tile_walk(tables, q_pos, bs, nb, n_rep, window, sliding,
+                         slot_rows)
         if run > 1:
-            walk = run_walk(walk, nb, n_rep, run, 1)
+            walk = run_walk(walk, nb, n_rep, run, 1, slot_rows=slot_rows)
     if isinstance(walk, RunWalk) != (run > 1):
         raise ValueError(
             f"the kernel takes these pools' pairs in runs of {run} and was "
@@ -1191,7 +1246,9 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
                 pltpu.VMEM((kv, wide, 1), jnp.float32),
                 pltpu.VMEM((kv, wide, dv), jnp.float32)]
 
-    shared = dict(pairs=pairs, group=narrow_rows(n_rep), scale=scale,
+    # no taller than the tile: the walk gives such a group no pair
+    group = min(narrow_rows(n_rep, slot_rows), wide)
+    shared = dict(pairs=pairs, group=group, scale=scale,
                   quantized=quantized, window=window, key_at=key_at,
                   sink=sink is not None)
     if run == 1:
@@ -1199,10 +1256,10 @@ def _paged_attention_pallas(q, k_pool, v_pool, pool_pos, tables, q_pos,
         scalars = (walk.count, walk.blocks, walk.cols, walk.narrow)
     else:
         # a group that is one packed row's heads names every block of its
-        # runs; any other shares its sublanes with its neighbours' heads
+        # runs; any other shares its sublanes with its neighbours' heads,
+        # and a slot's rows name the same blocks only by the schedule
         kernel = functools.partial(
-            _paged_run_kernel, run=run,
-            whole_named=narrow_rows(n_rep) == n_rep, **shared)
+            _paged_run_kernel, run=run, whole_named=group == n_rep, **shared)
         scalars = (walk.units, walk.blocks, walk.cols, walk.narrow,
                    walk.lens)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1282,7 +1339,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     window: Optional[tuple] = None,
                     walk: Optional[TileWalk] = None,
                     sliding: Optional[int] = None,
-                    sink: Optional[jax.Array] = None) -> jax.Array:
+                    sink: Optional[jax.Array] = None,
+                    slot_rows: int = 1) -> jax.Array:
     """Paged decode attention.
 
     ``q [T, N, D]`` one query row per packed token; ``k_pool``/``v_pool``
@@ -1332,6 +1390,11 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     ``sink [N]``: one logit a query head that stands in the softmax's
     denominator and holds no value (``p_j = exp(s_j) / (exp(sink) + sum
     exp(s))``). Not with ``combine_axis``.
+
+    ``slot_rows``: the packed rows a slot holds side by side in the step,
+    what :func:`step_walk` was given (a block family's block length): the
+    height of the kernel's narrow group in packed rows. The reference
+    takes no notice of it.
     """
     t, n, d = q.shape
     if sink is not None and combine_axis is not None:
@@ -1367,7 +1430,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             wide, k_pool, v_pool, pool_pos, tables, q_pos, layer,
             scale=scale_, force_pallas=force_pallas,
             combine_axis=combine_axis, window=window, walk=walk,
-            sliding=sliding, sink=sink)
+            sliding=sliding, sink=sink, slot_rows=slot_rows)
         return jnp.take_along_axis(
             out.reshape(t, n, pack, d), share[None, :, None, None],
             axis=2)[:, :, 0]
@@ -1404,4 +1467,4 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                                    q_pos, layer, k_scale, v_scale, scale_,
                                    interpret=impl == "pallas-interpret",
                                    window=window, walk=walk, sliding=sliding,
-                                   sink=sink)
+                                   sink=sink, slot_rows=slot_rows)

@@ -2229,9 +2229,20 @@ def test_block_step_at_the_published_widths(chip, topo, on_one_chip,
     from neuronx_distributed_tpu.inference import block_serving, engine
     from neuronx_distributed_tpu.obs.device_scopes import scope_of
     from neuronx_distributed_tpu.ops import blockwise_moe
+    from neuronx_distributed_tpu.ops import paged_attention as pa
 
     monkeypatch.setattr(block_serving, "on_tpu", lambda: True)
     monkeypatch.setattr(blockwise_moe, "on_tpu", lambda: True)
+    # the paged kernel as the step builds it: its group is a slot's four
+    # rows' heads, 32 of the tile's 128 stacked rows (PR 70)
+    groups = []
+    run_kernel = pa._paged_run_kernel
+
+    def spy(*refs, group, whole_named, **kw):
+        groups.append((group, whole_named, kw["run"]))
+        return run_kernel(*refs, group=group, whole_named=whole_named, **kw)
+
+    monkeypatch.setattr(pa, "_paged_run_kernel", spy)
     config, models = _cell_config("sdar-30b-a3b-chat", None)
     assert set(config["reduced"]) == {"num_hidden_layers"}
     cfg, forward, params, cache, width = _serving_parts(chip, config, models)
@@ -2271,6 +2282,7 @@ def test_block_step_at_the_published_widths(chip, topo, on_one_chip,
     text = compiled.as_text()
     assert _kernel_instruction_names(text) == {"paged_attention",
                                                "grouped_glu_fwd"}
+    assert set(groups) == {(32, False, 8)}
     gib = 2.0 ** 30
     mem = compiled.memory_analysis()
     arguments = config["assumed"]["serve_aot_gib"]["arguments"]

@@ -30,7 +30,8 @@ from neuronx_distributed_tpu.ops.paged_attention import (column_live,
                                                           tile_walk)
 from neuronx_distributed_tpu.parallel import mesh as ps
 from counter_checks import declared_anywhere
-from walk_checks import check_paged_runs, check_tile_walk, narrow_group
+from walk_checks import (check_paged_runs, check_tile_walk, group_start,
+                         narrow_group)
 
 
 # ---------------------------------------------------------------------------
@@ -375,37 +376,43 @@ def test_tile_walk_serves_every_live_column_by_one_pair_of_its_tile(case):
         assert int(live[8:16].sum()) > 2 * count[1]
 
 
-def _decode_rows_beside_a_chunk(n_rep, bs=4, maxb=5, seed=8):
+def _decode_rows_beside_a_chunk(n_rep, bs=4, maxb=5, seed=8, slot_rows=1):
     """A packed step as the engine packs it, two tiles of rows: decode
     rows of slots that share nothing, a whole tile of them and more (so
     every place of a tile holds one, its last among them), then a chunk
     of one slot; rows 1 and 2, and rows 4 and 5, have forked from one
-    prefix block. ``(tables, q_pos, num_blocks, rows)``."""
+    prefix block. With ``slot_rows`` a decoding slot packs that many rows
+    on a multiple of it, all at one position (a block family's, which
+    attend through their block's last), and nothing is forked. ``(tables,
+    q_pos, num_blocks, rows)``."""
     rng = np.random.RandomState(seed)
     rows = tile_rows(n_rep, 2 * 128)
     t = 2 * rows
-    chunk = min(rows // 2, 12)
-    slots = t - chunk + 1
+    chunk = t - (t - min(rows // 2, 12)) // slot_rows * slot_rows
+    slots = (t - chunk) // slot_rows + 1
     tables = np.full((t, maxb), -1, np.int64)
     q_pos = np.zeros((t,), np.int64)
     for r in range(t):
-        slot = min(r, slots - 1)
+        slot = min(r // slot_rows, slots - 1)
         if slot < slots - 1:
-            q_pos[r] = rng.randint(0, bs * maxb)
+            q_pos[r] = (rng.randint(0, bs * maxb) if r % slot_rows == 0
+                        else q_pos[r - 1])
         else:
-            q_pos[r] = bs * maxb - chunk + (r - slot)
+            q_pos[r] = bs * maxb - chunk + (r - slot * slot_rows)
         held = q_pos[r] // bs + 1
         tables[r, :held] = slot * maxb + np.arange(held)
-    tables[2, 0] = tables[1, 0]
-    tables[5, 0] = tables[4, 0]
+    if slot_rows == 1:
+        tables[2, 0] = tables[1, 0]
+        tables[5, 0] = tables[4, 0]
     return tables, q_pos, slots * maxb, rows
 
 
-def _pairs_by_brute_count(live, tables, rows, n_rep, group):
+def _pairs_by_brute_count(live, tables, rows, n_rep, group, slot_rows=1):
     """``[narrow, one_row_whole, shared]`` over a step's tiles by loops:
     a pair is narrow where ``group`` rows from the sublane of the first
     head that names it (from the tile's last group, if earlier) hold the
-    last one too."""
+    last one too; with ``slot_rows``, where the slot's group that holds
+    the first does (``walk_checks.group_start``)."""
     kinds = [0, 0, 0]
     for i in range(-(-len(tables) // rows)):
         want = {}
@@ -414,8 +421,10 @@ def _pairs_by_brute_count(live, tables, rows, n_rep, group):
                 want.setdefault((int(c), int(tables[r, c])), []).append(
                     r - i * rows)
         for namers in want.values():
-            begin = min(namers[0] * n_rep // 8 * 8, rows * n_rep - group)
-            if (namers[-1] + 1) * n_rep <= begin + group:
+            first, last = namers[0] * n_rep, (namers[-1] + 1) * n_rep
+            begin = min(first // 8 * 8, rows * n_rep - group)
+            if (last <= begin + group if slot_rows == 1 else group_start(
+                    first, last, n_rep, rows * n_rep, slot_rows) >= 0):
                 kinds[0] += 1
             else:
                 kinds[1 if len(namers) == 1 else 2] += 1
@@ -474,7 +483,7 @@ def test_pair_kinds_are_the_brute_count_of_a_steps_tables(monkeypatch, n_rep,
     kernel's group and, at 6 heads, at the 8 rows the group was before
     PR 43 (rows whose heads cross a multiple of 8 ran over the whole
     tile)."""
-    monkeypatch.setattr(pa, "narrow_rows", lambda n: group)
+    monkeypatch.setattr(pa, "narrow_rows", lambda n, slot_rows=1: group)
     tables, q_pos, nb, rows = _decode_rows_beside_a_chunk(n_rep, seed=9)
     live = column_live(tables, np.arange(tables.shape[1]), q_pos[:, None],
                        4)
@@ -543,14 +552,17 @@ _UNMAPPED = -1      # a real row whose table maps nothing: attends nothing
 
 
 def _run_scene(rows, heads, d=16, dv=None, bs=4, maxb=8, sliding=None,
-               quantized=False, sink=False, seed=0):
+               quantized=False, sink=False, seed=0, slot_rows=1,
+               dtype=np.float32):
     """A pool in which every slot holds its own blocks in a scrambled
     order and ``rows`` (``(slot, position)``, ``None`` a pad row,
     ``(_UNMAPPED, position)`` a row that attends nothing) are one packed
     step: ``(args, kwargs, tables, q_pos, live)`` for ``paged_attention``.
     ``sliding``: a slot's ring of ``sliding // bs + 1`` blocks, later laps
     overwriting earlier ones. ``dv``: values narrower than the keys, the K
-    pool in whole lanes."""
+    pool in whole lanes. ``slot_rows``: the rows a slot packs side by side
+    (a block family's), handed to the kernel; ``dtype``: the queries' and
+    the pools'."""
     n, kv = heads
     dv = dv or d
     rng = np.random.RandomState(seed)
@@ -572,10 +584,12 @@ def _run_scene(rows, heads, d=16, dv=None, bs=4, maxb=8, sliding=None,
             pos[table[s][p // bs % maxb], p % bs] = p
     tables = np.stack([table[r[0] if r else _UNMAPPED] for r in rows])
     q_pos = np.array([r[1] if r else PAD_POSITION for r in rows], np.int64)
-    q = jnp.asarray(rng.randn(len(rows), n, d).astype(np.float32))
-    k = jnp.asarray(rng.randn(2, nb, bs, kv, d).astype(np.float32))
-    v = jnp.asarray(rng.randn(2, nb, bs, kv, dv).astype(np.float32))
+    q = jnp.asarray(rng.randn(len(rows), n, d), dtype)
+    k = jnp.asarray(rng.randn(2, nb, bs, kv, d), dtype)
+    v = jnp.asarray(rng.randn(2, nb, bs, kv, dv), dtype)
     kw = dict(sliding=sliding)
+    if slot_rows != 1:
+        kw["slot_rows"] = slot_rows
     if quantized:
         (k, kw["k_scale"]), (v, kw["v_scale"]) = quantize_kv(k), quantize_kv(v)
     if dv != d:
@@ -593,6 +607,32 @@ def _run_scene(rows, heads, d=16, dv=None, bs=4, maxb=8, sliding=None,
 
 def _decode_rows(lengths, first=0):
     return [(first + s, n - 1) for s, n in enumerate(lengths)]
+
+
+def _block_step(lengths, chunk, b=4, dead=1):
+    """A step as ``inference/block_serving.py`` packs it for a family that
+    decodes blocks of ``b``: a slot of each of ``lengths`` packs its
+    block's ``b`` rows on a multiple of ``b``, every one attending through
+    the block's last position; ``dead`` groups of pad rows among them (a
+    slot that finished); then a prefill chunk of ``chunk`` rows of one
+    more slot, from its position 8."""
+    rows = []
+    for s, n in enumerate(lengths):
+        rows += [(s, (n - 1) // b * b + b - 1)] * b
+        if s < dead:
+            rows += [None] * b
+    return rows + [(len(lengths), p // b * b + b - 1)
+                   for p in range(8, 8 + chunk)]
+
+
+def _scattered(rows, seed=3):
+    """The rows in an order no engine packs: the kernel's contract holds
+    whatever the order, a scattered one shares less."""
+    order = np.random.RandomState(seed).permutation(len(rows))
+    return [rows[i] for i in order]
+
+
+_BLOCK_STEP = _block_step([30, 9, 14, 32, 5, 21, 27], 20)
 
 
 #: name -> (the scene's arguments, ``[in_run, alone, whole]`` under runs
@@ -632,6 +672,21 @@ _RUN_KERNEL_CASES = {
     "a_pad_row_and_a_row_that_attends_nothing": (dict(
         rows=[(0, 22), None, (1, 9), (_UNMAPPED, 5), (2, 17), None],
         heads=(32, 2)), (13, 1, 0)),
+    # a block family's step at GQA-8, tiles of 16 rows: a slot's four rows
+    # are one group of 32 stacked rows and its 8, 3, 4, 8, 2, 6 and 7
+    # blocks are that group's runs, a dead group attends nothing, and the
+    # chunk's blocks, which more rows name than a group holds, stay whole-
+    # tile pairs of the two tiles it lies in
+    "a_block_familys_step": (dict(
+        rows=_BLOCK_STEP, heads=(16, 2), slot_rows=4), None),
+    "a_block_familys_step_scattered": (dict(
+        rows=_scattered(_BLOCK_STEP), heads=(16, 2), slot_rows=4), None),
+    "a_block_familys_step_over_bf16_pools": (dict(
+        rows=_BLOCK_STEP, heads=(16, 2), slot_rows=4, dtype=jnp.bfloat16),
+        None),
+    # GQA-2 at blocks of 4 rows (the toy model's): the group is 8 rows
+    "a_block_familys_step_at_two_heads": (dict(
+        rows=_BLOCK_STEP, heads=(4, 2), slot_rows=4), None),
 }
 
 
@@ -654,40 +709,244 @@ def test_paged_kernel_in_runs_matches_xla(monkeypatch, case, run):
     run = min(run, 1 << tables.shape[1].bit_length() - 1)
     monkeypatch.setattr(pa, "run_blocks", lambda *_: run)
     if case not in _XLA_ANSWERS:
+        # in float32 over the operands' own values, bf16 ones too
+        exact = tuple(x.astype(jnp.float32) if x.dtype == jnp.bfloat16
+                      else x for x in args[:3]) + args[3:]
         _XLA_ANSWERS[case] = np.asarray(paged_attention(
-            *args, force_pallas=False, **kw))
+            *exact, force_pallas=False, **kw))
     ref = _XLA_ANSWERS[case]
-    ker = np.asarray(paged_attention(*args, force_pallas=True, **kw))
+    ker = np.asarray(paged_attention(*args, force_pallas=True, **kw)
+                     .astype(jnp.float32))
     assert ker.shape == ref.shape
     real = live.any(axis=1)
-    np.testing.assert_allclose(ker[real], ref[real], rtol=1e-5, atol=1e-5)
+    # a bf16 output is rounded once, at the kernel's end
+    tol = 1e-5 if args[0].dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(ker[real], ref[real], rtol=tol, atol=tol)
     assert not ker[~real].any()
     if run == 1:
         return
     nb = args[3].shape[0]
+    slot_rows = scene.get("slot_rows", 1)
     kinds = check_paged_runs(tables, q_pos, live, 4, nb, n_rep, run,
-                             sliding=scene.get("sliding"))
+                             sliding=scene.get("sliding"),
+                             slot_rows=slot_rows)
     if run == 4 and counts is not None:
         assert tuple(kinds) == counts
     assert kinds[0] > 0
+    if slot_rows > 1 and "scattered" not in case:
+        # the decoding slots' blocks are their groups' and ride in runs:
+        # what is whole is the chunk's, and with one row a group (the
+        # parent's rule) every block of a slot of four rows was
+        served = np.where(live, tables, -1)
+        slots = len({r[0] for r in scene["rows"] if r}) - 1
+        assert kinds[2] <= 2 * 8 and kinds[1] <= slots
+        if narrow_group(n_rep) < narrow_group(n_rep, slot_rows):
+            assert pa.block_fetches(served, n_rep, run)[2] == kinds.sum()
 
 
 @pytest.mark.parametrize("run", [2, 4, 8])
-@pytest.mark.parametrize("n_rep", [1, 4, 6, 8, 9, 16])
-def test_a_narrow_groups_pairs_are_cut_into_runs(n_rep, run):
+@pytest.mark.parametrize("n_rep,slot_rows", [
+    (1, 1), (4, 1), (6, 1), (8, 1), (9, 1), (16, 1),
+    (8, 4), (4, 4), (16, 2), (6, 8), (9, 4)],
+    ids=lambda x: str(x))
+def test_a_narrow_groups_pairs_are_cut_into_runs(n_rep, slot_rows, run):
     """:func:`pair_runs` keyed by a group's first row, at every kind of
     group: one packed row's heads (8, 16), two rows' in one sublane (4),
     eight rows' (1), neighbours whose groups overlap (6, 9): decode rows
-    in every place of two tiles beside a chunk."""
-    tables, q_pos, nb, rows = _decode_rows_beside_a_chunk(n_rep)
+    in every place of two tiles beside a chunk. And a slot's rows' heads,
+    where a slot packs several (a block family's four rows of 8 heads: 32
+    stacked rows on a multiple of 32; four rows of 4, two of 16; eight
+    rows of 6, 48 of a tile of 120, whose end cuts the third group: that
+    slot's pairs run over the whole tile; four rows of 9 heads fill no
+    whole sublanes, and the group stays one row's 16)."""
+    tables, q_pos, nb, rows = _decode_rows_beside_a_chunk(
+        n_rep, slot_rows=slot_rows)
     live = column_live(tables, np.arange(tables.shape[1]), q_pos[:, None],
                        4)
-    kinds = check_paged_runs(tables, q_pos, live, 4, nb, n_rep, run)
-    narrow, one_row_whole, shared = pair_kinds(np.where(live, tables, -1),
-                                               n_rep, nb)
+    kinds = check_paged_runs(tables, q_pos, live, 4, nb, n_rep, run,
+                             slot_rows=slot_rows)
+    served = np.where(live, tables, -1)
+    narrow, one_row_whole, shared = pair_kinds(served, n_rep, nb,
+                                               slot_rows=slot_rows)
     assert (kinds[0] + kinds[1], kinds[2]) == (narrow + one_row_whole,
                                                shared)
+    group = narrow_group(n_rep, slot_rows)
+    assert pa.narrow_rows(n_rep, slot_rows) == group
+    if group < slot_rows * n_rep:
+        # four rows of 9 heads: no group holds a slot's rows, as before
+        assert kinds[0] == kinds[1] == 0 < kinds[2]
+        return
     assert kinds[0] > kinds[1] > 0
+    if slot_rows == 1:
+        return
+    assert group == {(8, 4): 32, (4, 4): 16, (16, 2): 32,
+                     (6, 8): 48}[n_rep, slot_rows]
+    assert [narrow, one_row_whole, shared] == _pairs_by_brute_count(
+        live, tables, rows, n_rep, group, slot_rows)
+    # a decoding slot's blocks are narrow pairs of its own group, on a
+    # multiple of the group, where the parent's rule (one row a group) ran
+    # every one of them over the whole tile
+    cut = rows * n_rep % group > 0
+    assert (shared > tables.shape[1]) == cut
+    old = pair_kinds(served, n_rep, nb)
+    assert old[0] == 0 and old[2] == narrow + shared
+    first, last, start, _ = pa.host_pairs(served, n_rep, nb, slot_rows)
+    assert (start[start >= 0] % group == 0).all()
+    assert (first[start >= 0] * n_rep >= start[start >= 0]).all()
+    assert ((last[start >= 0] + 1) * n_rep
+            <= start[start >= 0] + group).all()
+
+
+@pytest.mark.parametrize("first,last,n_rep,wide,slot_rows,start", [
+    (0, 32, 8, 128, 4, 0),          # a slot's four rows, the tile's first
+    (96, 128, 8, 128, 4, 96),       # and its last
+    (40, 48, 8, 128, 4, 32),        # one row inside a group: the group's
+    (40, 64, 8, 128, 4, 32),        # first named off the boundary, inside
+    (40, 72, 8, 128, 4, -1),        # ... and past the group's end: whole
+    (8, 40, 8, 128, 4, -1),         # four rows across two groups: whole
+    (0, 128, 8, 128, 4, -1),        # a chunk's tile
+    (40, 48, 8, 128, 1, 40),        # one row a group: the row's own
+    (40, 72, 8, 128, 1, -1),
+    (16, 48, 16, 128, 2, -1),       # GQA-16, two rows: off the boundary
+    (32, 64, 16, 128, 2, 32),
+    (48, 96, 6, 120, 8, 48),        # GQA-6, eight rows: 48 of 120
+    (96, 102, 6, 120, 8, -1),       # the group the tile's end cuts
+    (36, 72, 9, 72, 4, -1),         # 36 heads fill no whole sublanes: one
+    (36, 45, 9, 72, 4, 32),         # row a group, as without slot_rows
+    (0, 128, 8, 128, 16, 0),        # a group as tall as the tile
+    (0, 64, 8, 64, 16, -1),         # and one taller than a short tile
+], ids=lambda x: str(x))
+def test_a_slots_group_begins_on_a_multiple_of_its_height(first, last, n_rep,
+                                                          wide, slot_rows,
+                                                          start):
+    """:func:`narrow_start` by cases: a slot's group is floored to the
+    group and not to the sublane, so a pair first named off a group's
+    boundary is its group's if the group holds its last namer and the
+    whole tile's if not, never the next group's."""
+    for xp in (np, jnp):
+        got = pa.narrow_start(xp.asarray(first), xp.asarray(last), n_rep,
+                              wide, xp=xp, slot_rows=slot_rows)
+        assert int(got) == start
+    assert group_start(first, last, n_rep, wide, slot_rows) == start
+    if start >= 0:
+        group = pa.narrow_rows(n_rep, slot_rows)
+        assert start % pa.run_stride(n_rep, slot_rows) == 0
+        assert start <= first and last <= start + group <= wide
+
+
+#: ``_paged_attention_pallas``'s static arguments and the digest of
+#: ``step_walk``'s jaxpr at the serving cells' shapes with one row a slot,
+#: recorded on the parent of PR 70 (a5a0aa2): the proof that the cells of
+#: the other families run the programs they ran
+_PARENTS_PROGRAMS = {
+    "gqa4_runs_of_4": (dict(n=32, kv=8, t=256, maxb=20), "e1b4d6a4161bea7b",
+                       dict(kernel="_paged_run_kernel", grid=(8,), run=4,
+                            whole_named=False, pairs=640, group=8)),
+    "gqa8_runs_of_8": (dict(n=32, kv=4, t=640, maxb=32), "a52fc1b4b5100410",
+                       dict(kernel="_paged_run_kernel", grid=(40,), run=8,
+                            whole_named=True, pairs=512, group=8)),
+    "mha_a_pair_a_turn": (dict(n=32, kv=32, t=128, maxb=40),
+                          "329e3bd1d0d4457b",
+                          dict(kernel="_paged_kernel", grid=(1,), pairs=5120,
+                               group=8)),
+    "gqa6_runs_of_4": (dict(n=48, kv=8, t=256, maxb=20), "07f1f2a5f6103244",
+                       dict(kernel="_paged_run_kernel", grid=(13,), run=4,
+                            whole_named=False, pairs=400, group=16)),
+    # the block family's pools are ``gqa8_runs_of_8``'s: what its four
+    # rows a slot change is the group, and with it the comparison by name
+    "gqa8_four_rows_a_slot": (dict(n=32, kv=4, t=640, maxb=32, slot_rows=4),
+                              None,
+                              dict(kernel="_paged_run_kernel", grid=(40,),
+                                   run=8, whole_named=False, pairs=512,
+                                   group=32)),
+}
+
+
+def _traced_program(monkeypatch, n, kv, t, maxb, slot_rows=None, d=128,
+                    nb=64, bs=128):
+    """``(digest of step_walk's jaxpr, the kernel's static arguments)`` at
+    bf16 pools ``[2, nb, bs, kv, d]``; ``slot_rows`` None: not handed."""
+    import hashlib
+
+    from jax.experimental import pallas as pl
+
+    kw = {} if slot_rows is None else dict(slot_rows=slot_rows)
+    pool = jax.ShapeDtypeStruct((2, nb, bs, kv, d), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((t, maxb), jnp.int32)
+    q_pos = jax.ShapeDtypeStruct((t,), jnp.int32)
+    text = str(jax.make_jaxpr(lambda tb, qp: pa.step_walk(
+        tb, qp, bs, nb, d, n // kv, force_pallas=True, pools=(pool, pool),
+        **kw))(tables, q_pos))
+    seen = {}
+
+    def spy(kernel, **call):
+        seen.update(kernel=kernel.func.__name__, name=call["name"],
+                    grid=call["grid_spec"].grid, **kernel.keywords)
+        raise StopIteration
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    with pytest.raises(StopIteration):
+        jax.eval_shape(
+            lambda q, k, v, pos, tb, qp: pa._paged_attention_pallas(
+                q, k, v, pos, tb, qp, 1, None, None, 0.1, interpret=True,
+                **kw),
+            jax.ShapeDtypeStruct((t, n, d), jnp.bfloat16), pool, pool,
+            jax.ShapeDtypeStruct((nb, bs), jnp.int32), tables, q_pos)
+    return hashlib.sha256(text.encode()).hexdigest()[:16], seen
+
+
+@pytest.mark.parametrize("case", list(_PARENTS_PROGRAMS))
+def test_one_row_a_slot_is_the_parents_program(monkeypatch, case):
+    """With one row a slot (``slot_rows`` 1, or not handed at all) the
+    walk's arrays are computed by the parent's operations and the kernel
+    is built with the parent's static arguments, at the cells' shapes:
+    Mistral's and Mixtral's, Granite's, EvaByte's, Laguna's. The block
+    family's own program differs from Granite's by its group alone."""
+    shape, digest, static = _PARENTS_PROGRAMS[case]
+    got, seen = _traced_program(monkeypatch, **shape)
+    assert seen.pop("name") == "paged_attention"
+    assert {k: seen[k] for k in static} == static
+    assert (seen["quantized"], seen["window"], seen["key_at"],
+            seen["sink"]) == (False, None, None, False)
+    if digest is None:
+        plain = _traced_program(monkeypatch, **dict(shape, slot_rows=None))
+        assert got != plain[0] and plain[1]["group"] == 8
+        return
+    assert got == digest
+    assert _traced_program(monkeypatch, **shape, slot_rows=1)[0] == digest
+
+
+def test_the_hosts_count_takes_a_block_familys_rows_a_slot():
+    """``StepGeometry`` learns the rows a slot packs from the family
+    (``serving_family().block``) and from nothing else, and the host's
+    ``nxd_paged_pairs_total`` and ``nxd_paged_block_fetches_total`` then
+    count a decoding slot's blocks as its group's runs, as the kernel
+    takes them."""
+    from neuronx_distributed_tpu.models import sdar
+
+    cfg = sdar.tiny_config(num_heads=16, num_kv_heads=2)
+    kind = cfg.serving_family().cache_kind
+    bound = step_counter(kind, cfg, block_size=4, pool_blocks=64, itemsize=4)
+    assert bound.args[0].slot_rows == cfg.block_decoding.block_length == 4
+    assert bound.args[0].n_rep == 8
+    plain = step_counter(kind, tiny_config(num_heads=16, num_kv_heads=2),
+                         block_size=4, pool_blocks=64, itemsize=4)
+    assert plain.args[0].slot_rows == 1
+    # three slots of 9, 3 and 5 blocks, four rows each, and a dead group
+    held = [9, 3, 5]
+    tables = np.full((4, 12), -1, np.int32)
+    for s, many in enumerate(held):
+        tables[s, :many] = 20 * s + np.arange(many)
+    slot_ids = np.repeat([0, 1, 3, 2], 4).astype(np.int32)
+    positions = np.repeat([35, 11, PAD_POSITION, 19], 4).astype(np.int32)
+    counts = bound(positions, slot_ids, tables, [9, 3, 5], 0)
+    assert list(counts["nxd_paged_pairs_total"]) == [17, 0]
+    assert list(counts["nxd_paged_shared_pairs_total"]) == [0]
+    # runs of 8: the slot of nine leaves one alone
+    assert list(counts["nxd_paged_block_fetches_total"]) == [16, 1, 0]
+    counts = plain(positions, slot_ids, tables, [9, 3, 5], 0)
+    assert list(counts["nxd_paged_pairs_total"]) == [0, 0]
+    assert list(counts["nxd_paged_block_fetches_total"]) == [0, 0, 17]
 
 
 def test_run_blocks_follow_the_pools_shapes():
@@ -705,6 +964,8 @@ def test_run_blocks_follow_the_pools_shapes():
     assert pa.run_blocks(*pools(4, 128), 8, 20) == 8          # Granite
     assert pa.run_blocks(*pools(32, 128), 1, 40) == 1         # EvaByte
     assert pa.run_blocks(*pools(8, 128, dtype=jnp.int8), 4, 20) == 8
+    assert pa.run_blocks(*pools(4, 128), 8, 32, 4) == 8       # SDAR: 32 rows
+    assert pa.unit_blocks(32, 256 << 10, 128) == 8
 
 
 def test_a_walk_of_the_other_form_is_refused(monkeypatch):

@@ -169,6 +169,42 @@ def test_paged_forward_in_groups_written_twice_equals_the_reference():
                   compare=dict(every=4, tail=4)) == []
 
 
+def test_the_kernel_takes_a_slots_rows_as_one_group(monkeypatch):
+    """The paged forward through the kernel (interpret mode) equals the
+    reference as the gather path does, and what reaches the walk and the
+    kernel is the family's block length, from the config alone: the step's
+    walk is built with ``slot_rows`` 4 and every layer's call carries the
+    same (at the toy's two heads a K/V head a group of 8 stacked rows, a
+    slot's four rows' heads; at the published 8, 32)."""
+    from neuronx_distributed_tpu.ops import paged_attention as pa
+
+    cfg, _, forward, params = _model(attn_force_pallas=True)
+    assert cfg.attn_force_pallas and cfg.block_decoding.block_length == B
+    seen = {"walk": [], "kernel": []}
+    step_walk, kernel = pa.step_walk, pa._paged_attention_pallas
+
+    def walk_spy(*a, **kw):
+        seen["walk"].append(kw.get("slot_rows"))
+        return step_walk(*a, **kw)
+
+    def kernel_spy(*a, **kw):
+        seen["kernel"].append((kw.get("slot_rows"),
+                               type(kw["walk"]).__name__))
+        return kernel(*a, **kw)
+
+    monkeypatch.setattr(pa, "step_walk", walk_spy)
+    monkeypatch.setattr(pa, "_paged_attention_pallas", kernel_spy)
+    assert _probe(cfg, forward, params, group=B, rewrite=True) == []
+    assert set(seen["walk"]) == {B}
+    assert set(seen["kernel"]) == {(B, "RunWalk")}
+    assert len(seen["kernel"]) == len(seen["walk"])    # once a traced scan
+    assert pa.narrow_rows(2, B) == 8 and pa.narrow_rows(8, B) == 32
+    # the toy's widths, a decode group of one slot: narrow on its group
+    served = np.tile(np.array([[3, 5, -1, -1]]), (B, 1))
+    assert pa.pair_kinds(served, 8, 8, slot_rows=B).tolist() == [2, 0, 0]
+    assert pa.pair_kinds(served, 8, 8).tolist() == [0, 0, 2]
+
+
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 def test_a_fault_put_into_the_program_fails_the_check(fault):
     """A causal mask inside the block, the last chosen expert left out,

@@ -9,17 +9,37 @@ import numpy as np
 from neuronx_distributed_tpu.inference.kv_cache import PAD_POSITION
 
 
-def narrow_group(n_rep):
+def narrow_group(n_rep, slot_rows=1):
     """Stacked rows of the kernel's narrow product, from ``n_rep`` alone:
     a packed row's ``n_rep`` heads begin a multiple of ``gcd(n_rep, 8)``
     past a whole sublane, and the group is the whole sublanes that hold
-    them from the furthest such offset (1, 4, 8 -> 8; 6, 9, 10 -> 16)."""
+    them from the furthest such offset (1, 4, 8 -> 8; 6, 9, 10 -> 16).
+    Where a slot packs ``slot_rows`` rows side by side and their heads
+    are whole sublanes, the group is all of them."""
+    if slot_rows > 1 and slot_rows * n_rep % 8 == 0:
+        return slot_rows * n_rep
     furthest = max(r * n_rep % 8 for r in range(8))
     return -(-(furthest + n_rep) // 8) * 8
 
 
+def group_start(first, last, n_rep, wide, slot_rows=1):
+    """Where the narrow product of a pair begins that the stacked rows
+    ``first`` to ``last`` (one past) of a tile of ``wide`` name, -1 over
+    the whole tile, by the rule in words: a slot's group begins on the
+    multiple of its height that ``first`` lies in and is the pair's if it
+    holds ``last`` and lies in the tile; any other group begins on
+    ``first``'s sublane, or where the tile's last group does if that is
+    earlier."""
+    group = narrow_group(n_rep, slot_rows)
+    if group == slot_rows * n_rep and slot_rows > 1:
+        begin = first // group * group
+        return begin if last <= begin + group <= wide else -1
+    begin = min(first // 8 * 8, wide - group)
+    return begin if last <= begin + group else -1
+
+
 def check_tile_walk(walk, live, tables, rows, n_rep, runs=None,
-                    shared_units=None):
+                    shared_units=None, slot_rows=1):
     """What every walk of the kernel must hold (a full cache's and a
     window-summary cache's alike, ``tests/test_evabyte.py``): each live
     (row, column) is served by exactly one pair of its tile, no pair is
@@ -27,7 +47,9 @@ def check_tile_walk(walk, live, tables, rows, n_rep, runs=None,
     pairs, and a pair is narrow exactly if one group of rows holds the
     heads that name it: :func:`narrow_group` rows from the whole sublane
     the first of them lies in (from the tile's last group, if that is
-    earlier), so every pair that one packed row names is narrow.
+    earlier), so every pair that one packed row names is narrow; with
+    ``slot_rows``, the slot's group that holds the first of them
+    (:func:`group_start`).
     ``runs``, a kernel's cut of this walk into units (``(run_walk, run,
     whole_run)``), is held to :func:`check_pair_runs`,
     whose count of the fetches by kind is returned (``shared_units``: its
@@ -36,7 +58,7 @@ def check_tile_walk(walk, live, tables, rows, n_rep, runs=None,
     tiles = len(walk.count)
     per = walk.blocks.size // tiles
     assert per == rows * maxb and tiles * rows >= t
-    group = narrow_group(n_rep)
+    group = narrow_group(n_rep, slot_rows)
     for i in range(tiles):
         mine = range(i * rows, min((i + 1) * rows, t))
         want = {}           # (column, block) -> the tile's rows that name it
@@ -52,11 +74,15 @@ def check_tile_walk(walk, live, tables, rows, n_rep, runs=None,
             first = (want[pair][0] - i * rows) * n_rep
             last = (want[pair][-1] - i * rows + 1) * n_rep
             start = int(walk.narrow[i * per + j])
-            begin = min(first // 8 * 8, rows * n_rep - group)
-            if last <= begin + group:
-                assert start == begin and start % 8 == 0
+            assert start == group_start(first, last, n_rep, rows * n_rep,
+                                        slot_rows)
+            if start >= 0:
+                assert start % 8 == 0
             else:
-                assert start == -1 and len(want[pair]) > 1
+                # several rows' pair, or a slot's group that the tile's
+                # end cuts
+                assert len(want[pair]) > 1 or (
+                    first // group * group + group > rows * n_rep)
         # what the kernel masks by: a row's entry where it attends
         served = walk.served[i].reshape(rows, n_rep, maxb)
         assert (served == served[:, :1]).all()
@@ -65,11 +91,12 @@ def check_tile_walk(walk, live, tables, rows, n_rep, runs=None,
                 served[r - i * rows, 0], np.where(live[r], tables[r], -1))
     if runs is not None:
         return check_pair_runs(walk, live, tables, rows, n_rep, *runs,
-                               shared_units=shared_units)
+                               shared_units=shared_units,
+                               slot_rows=slot_rows)
 
 
 def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run,
-                    shared_units=None):
+                    shared_units=None, slot_rows=1):
     """What :func:`..ops.paged_attention.pair_runs` must hold of a tile
     walk, by brute count: following a tile's units from its first pair,
     every pair of the walk (so every live (row, column)) lies in exactly
@@ -78,7 +105,8 @@ def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run,
     ``narrow`` for each of them, the group's first row: the rows that
     name them have their heads inside it; one packed row under 8 or 16
     heads, the two that share a sublane under 4, the neighbours whose
-    groups begin on one under 6 and 9), in order of column and block, or
+    groups begin on one under 6 and 9, a slot's ``slot_rows`` rows on a
+    multiple of their heads), in order of column and block, or
     pairs that no group holds; a group's units are full but its last;
     and ``lens`` is 0 off a unit's first pair. Returns the walk's fetches
     by kind, ``[in_run, alone, whole]``, which the caller holds the
@@ -91,7 +119,7 @@ def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run,
     t, maxb = tables.shape
     tiles = len(walk.count)
     per = rows * maxb
-    group = narrow_group(n_rep)
+    group = narrow_group(n_rep, slot_rows)
     units, blocks, cols, narrow, lens = (np.asarray(x) for x in runs[:5])
     kinds = np.zeros((3,), np.int64)        # in_run, alone, whole
     for i in range(tiles):
@@ -129,6 +157,8 @@ def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run,
                     shared_units[int(n == 1)] += n
             else:
                 assert 1 <= n <= run and start % 8 == 0
+                if slot_rows > 1 and group == slot_rows * n_rep:
+                    assert start % group == 0
                 for p in pairs:         # the group holds its namers' heads
                     assert start <= (want[p][0] - i * rows) * n_rep
                     assert ((want[p][-1] - i * rows + 1) * n_rep
@@ -148,11 +178,13 @@ def check_pair_runs(walk, live, tables, rows, n_rep, runs, run, whole_run,
 
 
 def check_paged_runs(tables, q_pos, live, block_size, num_blocks, n_rep, run,
-                     window=None, sliding=None):
+                     window=None, sliding=None, slot_rows=1):
     """The paged kernel's walk of a step in runs of ``run``: the tile walk
     and its cut into units hold (:func:`check_tile_walk`,
     :func:`check_pair_runs`), and the host's count of the fetches by kind
-    is the walk's. Returns ``[in_run, alone, whole]``."""
+    is the walk's (``slot_rows``: a slot's rows are one group, in the
+    walk and in the host's count alike). Returns ``[in_run, alone,
+    whole]``."""
     import jax.numpy as jnp
 
     from neuronx_distributed_tpu.ops import paged_attention as pa
@@ -161,13 +193,14 @@ def check_paged_runs(tables, q_pos, live, block_size, num_blocks, n_rep, run,
     rows = pa.tile_rows(n_rep, len(q_pos))
     walk = pa.tile_walk(jnp.asarray(tables, jnp.int32),
                         jnp.asarray(q_pos, jnp.int32), block_size,
-                        num_blocks, n_rep, window, sliding)
-    runs = pa.run_walk(walk, num_blocks, n_rep, run, 1)
+                        num_blocks, n_rep, window, sliding, slot_rows)
+    runs = pa.run_walk(walk, num_blocks, n_rep, run, 1, slot_rows=slot_rows)
     assert runs.q_lo is walk.q_lo and runs.served is walk.served
     kinds = check_tile_walk(
         type(walk)(*(None if x is None else np.asarray(x) for x in walk)),
-        live, tables, rows, n_rep, runs=(runs, run, 1))
-    fetches = pa.block_fetches(np.where(live, tables, -1), n_rep, run)
+        live, tables, rows, n_rep, runs=(runs, run, 1), slot_rows=slot_rows)
+    fetches = pa.block_fetches(np.where(live, tables, -1), n_rep, run,
+                               slot_rows=slot_rows)
     assert tuple(fetches) == tuple(kinds)
     assert fetches.sum() == int(np.asarray(walk.count).sum())
     return kinds
