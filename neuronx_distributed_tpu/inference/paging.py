@@ -342,6 +342,35 @@ BLOCKS_FINISHED = CounterFamily(
 #: what the step of a family that decodes blocks counts
 #: (:attr:`ServingFamily.block`; ``block_serving``), behind its tokens
 BLOCK_COUNTERS = (BLOCK_PASSES, BLOCK_ROWS, BLOCKS_FINISHED)
+DSA_POSITIONS = CounterFamily(
+    "nxd_dsa_positions_total",
+    "Causal positions of the serving workers' real rows, summed over the "
+    "layers, of a family whose rows attend a learned selection of their "
+    "context (an indexer's top positions): selected, the positions a row "
+    "attended, or passed_over, those it scored and left. Counted on the "
+    "device, fetched with the step's tokens.",
+    ("selected", "passed_over"))
+DSA_ROWS = CounterFamily(
+    "nxd_dsa_rows_total",
+    "Real rows of such a family, summed over the layers: selecting, a row "
+    "whose context is longer than the selection, or whole, one that "
+    "attends every causal position.",
+    ("selecting", "whole"))
+DSA_BLOCKS = CounterFamily(
+    "nxd_dsa_blocks_total",
+    "Pool blocks of the real rows' contexts, a (row, block, layer) each: "
+    "named, the block holds a position the row selected, or unnamed, it "
+    "holds none: what a kernel that reads whole blocks would and would "
+    "not have to read.",
+    ("named", "unnamed"))
+DSA_SELECTED = CounterFamily(
+    "nxd_dsa_selected_total",
+    "Selected positions of the real rows, summed over the layers: "
+    "shared_with_previous_row, the row before it is the position before "
+    "it of the same sequence (a chunk's neighbour) and selected the "
+    "position too, or new: what a kernel that fetches a row once a tile "
+    "could and could not save.",
+    ("shared_with_previous_row", "new"))
 
 #: the ``moe_counts`` leaf of a family that declares it
 #: (:class:`ServingFamily`), as the kind that builds it lays it out: the
@@ -811,6 +840,47 @@ class LatentCache(FullCache):
 
 
 @dataclasses.dataclass(frozen=True)
+class IndexedLatentCache(LatentCache):
+    """A latent cache whose positions hold a second row a layer: the key
+    of an indexer that scores a row's context and selects the positions it
+    attends (:mod:`..ops.indexed_attention`). Two pool leaves under one
+    table and one allocator, ``rows`` and ``index_keys`` (``index_row``
+    lanes), so that a block, whoever maps, copies, ships or frees it,
+    carries the pair; and a ``counts`` leaf for what the selection did,
+    which the device alone knows."""
+
+    index_row: int = 128
+    name = "indexed_latent"
+    counters = (STEP_ROWS_BY_CONTEXT,)
+
+    @property
+    def device_counts(self) -> Tuple[DeviceCounts, ...]:
+        from ..ops.indexed_attention import COUNT_KINDS
+
+        families = (DSA_POSITIONS, DSA_ROWS, DSA_BLOCKS, DSA_SELECTED)
+        return (DeviceCounts("counts", len(COUNT_KINDS), tuple(
+            (family, tuple((COUNT_KINDS.index(kind),)
+                           for kind in family.kinds))
+            for family in families)),)
+
+    def count_step(self, geo: StepGeometry, positions, slot_ids, tables,
+                   held: Sequence[int], rolled: int) -> Dict[str, Any]:
+        """The real rows by their position: no kernel walks the table's
+        blocks here, and what the selection reads is the device's to
+        count."""
+        return {STEP_ROWS_BY_CONTEXT.name: _rows_by_context(positions)}
+
+    def init_cache(self, model_cfg, **geometry) -> "IndexedLatentPagedCache":
+        latent = super().init_cache(model_cfg, **geometry)
+        return IndexedLatentPagedCache(
+            index_keys=jnp.zeros(latent.rows.shape[:-1] + (self.index_row,),
+                                 latent.rows.dtype),
+            counts=jnp.zeros((self.device_counts[0].entries,), jnp.int32),
+            **{f.name: getattr(latent, f.name)
+               for f in dataclasses.fields(latent)})
+
+
+@dataclasses.dataclass(frozen=True)
 class StatePoolCache(FullCache):
     """A full K/V pool over the ``pool_layers`` attention layers only, and
     the family's named per-slot state leaves (:class:`StateLeaf`) for the
@@ -1152,6 +1222,19 @@ class LatentPagedCache(_BlockPool, struct.PyTreeNode):
     POOL_LEAVES = ("rows",)
 
 
+class IndexedLatentPagedCache(LatentPagedCache):
+    """The cache of :class:`IndexedLatentCache`: :class:`LatentPagedCache`
+    with ``index_keys`` ``[L, num_blocks, block_size, index_row]``, a
+    position's index key beside its row in ``rows`` (the same layer, block
+    and slot), and ``counts``, what the last step's selections did
+    (:data:`..ops.indexed_attention.COUNT_KINDS`, summed over the
+    layers)."""
+
+    index_keys: jax.Array = None
+    counts: jax.Array = None
+    POOL_LEAVES = ("rows", "index_keys")
+
+
 class StatePoolPagedCache(_BlockPool, struct.PyTreeNode):
     """The cache of :class:`StatePoolCache`. ``k``/``v`` ``[La,
     num_blocks, block_size, KV / pack, D * pack]`` over the ``La``
@@ -1303,7 +1386,10 @@ class LatentLayerView(struct.PyTreeNode):
     place: the row stack (the layer scan's carry), the layer's index in
     it, the pool's positions, the per-token block tables, the flat write
     indices, the rows' true positions (PAD_POSITION for padding) and the
-    kernel's walk of the step (None where the XLA path serves)."""
+    kernel's walk of the step (None where the XLA path serves). Of an
+    :class:`IndexedLatentPagedCache` also the index keys' stack and the
+    selections' counts so far in the step (both the scan's carry; None for
+    a plain latent cache) and the table's rows (``slots``)."""
 
     rows: jax.Array
     layer: jax.Array
@@ -1312,6 +1398,9 @@ class LatentLayerView(struct.PyTreeNode):
     write_idx: jax.Array
     q_pos: jax.Array
     walk: Any = None
+    index_keys: Optional[jax.Array] = None
+    counts: Optional[jax.Array] = None
+    slots: int = struct.field(pytree_node=False, default=0)
 
     def next_attention(self) -> "LatentLayerView":
         """The view of the same decoder layer's next latent attention
@@ -1454,7 +1543,8 @@ def init_serving_cache(model_cfg, *, num_blocks: int, block_size: int,
     :func:`init_paged_kv_cache`'s (``quantized``:
     :func:`init_quantized_paged_kv_cache`'s) pytree; a
     :class:`SparseStateCache` is a :class:`SparseStatePagedCache`, a
-    :class:`LatentCache` a :class:`LatentPagedCache`, a
+    :class:`LatentCache` a :class:`LatentPagedCache`, an
+    :class:`IndexedLatentCache` an :class:`IndexedLatentPagedCache`, a
     :class:`StatePoolCache` a :class:`StatePoolPagedCache`, a
     :class:`WindowPoolCache` a :class:`WindowPoolPagedCache`."""
     return model_cfg.serving_family().cache_kind.init_cache(
@@ -1982,7 +2072,7 @@ class PrefixCache:
 #: per-block integrity fingerprints over shipped payloads
 #: (``resilience.integrity.kv_payload_fingerprints``).
 PAYLOAD_BLOCK_AXES = {"k": 1, "v": 1, "pos": 0, "k_scale": 1, "v_scale": 1,
-                      "rows": 1}
+                      "rows": 1, "index_keys": 1}
 
 
 def extract_blocks(cache: Any, blocks: Sequence[int],

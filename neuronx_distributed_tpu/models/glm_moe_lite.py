@@ -60,10 +60,10 @@ from .llama import LlamaConfig, LlamaMLP, _ScanBody, run_layers
 KINDS = ("dense", "moe")
 
 
-def carried(cfg):
-    """What of the cache's stacks a layer of a latent family reads and
-    writes, whatever its kind."""
-    return {kind: ("rows", "moe_counts") for kind, _, _ in cfg.runs()}
+def carried(cfg, leaves=("rows", "moe_counts")):
+    """What of the cache's stacks (``leaves``) a layer of a latent family
+    reads and writes, whatever its kind."""
+    return {kind: tuple(leaves) for kind, _, _ in cfg.runs()}
 
 
 class LatentGeometry:
@@ -89,6 +89,16 @@ class LatentGeometry:
         """cos and sin ``[T, qk_rope_head_dim // 2]`` at ``positions
         [T]``, for the rotary key and the queries' rotary part."""
         return rope_rows(positions, self.qk_rope_head_dim, self.rope_theta)
+
+    def step_walk(self, tables, q_pos, kv_cache):
+        """The walk of one packed step that the family's attention kernel
+        takes (once for all layers; None where the XLA path serves): the
+        ``mla_paged_attention`` kernel's over the rows' whole contexts."""
+        return mla.step_walk(tables, q_pos, kv_cache.block_size,
+                             kv_cache.num_blocks, self.head_dim_,
+                             self.kv_lora_rank, self.num_heads,
+                             kv_cache.rows.dtype.itemsize,
+                             force_pallas=self.attn_force_pallas)
 
 
 @dataclass(frozen=True)
@@ -248,38 +258,13 @@ class LatentAttention(nn.Module):
                 shape, cfg.param_dtype) for name, shape in (
                     ("k_up", (heads, nope, rank)),
                     ("v_up", (heads, rank, cfg.v_head_dim))))
-            scale = cfg.score_scale
             row = cfg.head_dim_
             rows = jnp.concatenate(
                 [latent, k_rope[:, :, 0],
                  jnp.zeros((b, s, row - rank - rope), latent.dtype)], axis=-1)
 
             q_row = mla.absorb_queries(q[..., :nope], q_rope, k_up, row)
-        new_cache = None
-        if cache is None:
-            with device_scope("attn.kernel"):
-                scores = jnp.einsum(
-                    "btnw,bkw->bntk", q_row.astype(jnp.float32),
-                    rows.astype(jnp.float32)) * scale
-                causal = jnp.tril(jnp.ones((s, s), bool))
-                probs = jax.nn.softmax(jnp.where(causal, scores, -1e30),
-                                       axis=-1)
-                ctx = jnp.einsum("bntk,bkr->btnr", probs,
-                                 rows[..., :rank].astype(jnp.float32)
-                                 ).astype(cfg.dtype)
-        else:
-            from ..inference import paging
-
-            with device_scope("attn.pool_write"):
-                pool = paging.write_pool_rows(cache.rows, rows[0],
-                                              cache.write_idx, cache.layer)
-            with device_scope("attn.kernel"):
-                ctx = mla.mla_paged_attention(
-                    q_row[0], pool, cache.pos, cache.tables, cache.q_pos,
-                    cache.layer, rank, scale,
-                    force_pallas=cfg.attn_force_pallas,
-                    walk=cache.walk)[None]
-            new_cache = cache.replace(rows=pool)
+        ctx, new_cache = self.attend(x, c_q, cos, sin, q_row, rows, cache)
         with device_scope("attn.proj"):
             out = mla.expand_values(ctx, v_up)
             out = pl.RowParallelLinear(
@@ -289,6 +274,44 @@ class LatentAttention(nn.Module):
         if cache is not None:
             return out, new_cache
         return out
+
+    @nn.nowrap
+    def attend(self, x, c_q, cos, sin, q_row, rows, cache):
+        """``(ctx [B, S, N, rank], the view handed back or None)``: the
+        absorbed queries ``q_row [B, S, N, row]`` over the step's own
+        ``rows [B, S, row]`` (no cache: the whole sequence, causal) or,
+        once those are written, over the pool through the view's table.
+        ``x`` (the layer's input) and ``c_q`` (the normed low-rank query)
+        are for a family whose rows choose what they attend
+        (:mod:`.deepseek_v32`); called inside ``__call__``, so it may
+        declare parameters."""
+        cfg = self.cfg
+        rank, scale = cfg.kv_lora_rank, cfg.score_scale
+        if cache is None:
+            with device_scope("attn.kernel"):
+                s = q_row.shape[1]
+                scores = jnp.einsum(
+                    "btnw,bkw->bntk", q_row.astype(jnp.float32),
+                    rows.astype(jnp.float32)) * scale
+                causal = jnp.tril(jnp.ones((s, s), bool))
+                probs = jax.nn.softmax(jnp.where(causal, scores, -1e30),
+                                       axis=-1)
+                ctx = jnp.einsum("bntk,bkr->btnr", probs,
+                                 rows[..., :rank].astype(jnp.float32)
+                                 ).astype(cfg.dtype)
+            return ctx, None
+        from ..inference import paging
+
+        with device_scope("attn.pool_write"):
+            pool = paging.write_pool_rows(cache.rows, rows[0],
+                                          cache.write_idx, cache.layer)
+        with device_scope("attn.kernel"):
+            ctx = mla.mla_paged_attention(
+                q_row[0], pool, cache.pos, cache.tables, cache.q_pos,
+                cache.layer, rank, scale,
+                force_pallas=cfg.attn_force_pallas,
+                walk=cache.walk)[None]
+        return ctx, cache.replace(rows=pool)
 
 
 class GlmMoeLiteModel(nn.Module):
@@ -395,28 +418,33 @@ def latent_forward_with_cache(cfg: LlamaConfig, params, input_ids,
         pool_pos = paging.write_pool_positions(kv_cache.pos, q_pos,
                                                write_idx)
     with device_scope("attn.walk"):
-        walk = mla.step_walk(tables, q_pos, kv_cache.block_size,
-                             kv_cache.num_blocks, cfg.head_dim_,
-                             cfg.kv_lora_rank, cfg.num_heads,
-                             kv_cache.rows.dtype.itemsize,
-                             force_pallas=cfg.attn_force_pallas)
+        walk = cfg.step_walk(tables, q_pos, kv_cache)
+    # the stacks a layer writes (the pool's leaves) and the counts it adds
+    # to, of this step alone (``counts``: of a family whose rows select)
+    beside = tuple(name for name in ("counts",)
+                   if getattr(kv_cache, name, None) is not None)
+    written = kv_cache.POOL_LEAVES + beside
 
     def view_of(kind, carry, layer):
         return paging.LatentLayerView(
-            rows=carry["rows"], layer=cfg.rows_layer(kind, layer),
-            pos=pool_pos, tables=tables, write_idx=write_idx, q_pos=q_pos,
-            walk=walk)
+            layer=cfg.rows_layer(kind, layer), pos=pool_pos, tables=tables,
+            write_idx=write_idx, q_pos=q_pos, walk=walk,
+            slots=kv_cache.max_slots,
+            **{name: carry[name] for name in written})
 
     def merge(carry, view, assignments):
-        return dict(rows=view.rows,
+        return dict({name: getattr(view, name) for name in written},
                     moe_counts=carry["moe_counts"] + assignments)
 
-    carry = dict(rows=kv_cache.rows,
-                 moe_counts=jnp.zeros_like(kv_cache.moe_counts))
+    carry = dict({name: getattr(kv_cache, name)
+                  for name in kv_cache.POOL_LEAVES},
+                 moe_counts=jnp.zeros_like(kv_cache.moe_counts),
+                 **{name: jnp.zeros_like(getattr(kv_cache, name))
+                    for name in beside})
     stacks = {kind: p["model"][f"layers_{kind}"]
               for kind, _, _ in cfg.runs()}
     x, carry = run_layers(cfg, stacks, cfg.carry_in(x), cos, sin,
-                          carried(cfg), carry, view_of, merge,
+                          carried(cfg, carry), carry, view_of, merge,
                           valid=(q_pos < PAD_POSITION)[None])
     x = cfg.carry_out(x)
     with device_scope("norm"):

@@ -85,6 +85,10 @@ class MoE(nn.Module):
     # what the chosen experts' weights are multiplied by (a checkpoint's
     # ``routed_scaling_factor``; the sigmoid and the top-k router)
     router_scale: float = 1.0
+    # the sigmoid router's limit by groups
+    # (:class:`.routing.RouterSigmoid`: 1 limits nothing)
+    n_group: int = 1
+    topk_group: int = 1
     shared_expert_intermediate: int = 0
     # ``(first, count)``: the experts this device holds of the
     # ``num_experts`` real experts (None: all of them), whatever else the
@@ -142,6 +146,11 @@ class MoE(nn.Module):
         if (self.router_type in ("sigmoid", "softmax_bias")
                 or self.router_scale != 1.0):
             router_kw["scale"] = self.router_scale
+        if self.n_group > 1:
+            if self.router_type != "sigmoid":
+                raise ValueError("MoE: n_group is the sigmoid router's")
+            router_kw.update(n_group=self.n_group,
+                             topk_group=self.topk_group)
         if held is not None and (self.expert_impl != "float"
                                  or valid is None):
             raise ValueError("MoE: a share of the experts (held) is float "

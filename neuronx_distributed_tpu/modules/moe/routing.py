@@ -86,10 +86,17 @@ class RouterSigmoid(RouterBase):
     the task) and weighed by ``s_e`` alone, normalised over the chosen
     (the checkpoints' ``norm_topk_prob``) and times ``scale``
     (``routed_scaling_factor``). Equal scores take the lower expert
-    index, as ``lax.top_k`` does."""
+    index, as ``lax.top_k`` does. With ``n_group`` > 1 the choice is
+    limited by groups (DeepSeek-V3): the experts are ``n_group`` runs of
+    consecutive indices, a group scores the sum of its two largest ``s_e +
+    bias_e``, the ``topk_group`` highest groups stay and the others'
+    biased scores are ``-inf`` ahead of the choice; ``n_group`` 1 limits
+    nothing and is the router without groups to the bit."""
 
     top_k: int = 2
     scale: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array, Dict]:
@@ -99,9 +106,10 @@ class RouterSigmoid(RouterBase):
                                          (None,)),
             (self.num_experts,), jnp.float32)
         scores = jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)),
-            self.top_k)
+        biased = scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+        if self.n_group > 1:
+            biased = self.limit_to_groups(biased)
+        _, idx = jax.lax.top_k(biased, self.top_k)
         gates = jnp.take_along_axis(scores, idx, axis=-1)
         gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
         gates = gates * self.scale
@@ -111,6 +119,28 @@ class RouterSigmoid(RouterBase):
         aux = {"load_balance_loss": _load_balance_loss(probs, mask),
                "z_loss": _z_loss(logits)}
         return gates.astype(jnp.float32), idx, aux
+
+    @nn.nowrap
+    def limit_to_groups(self, biased: jax.Array) -> jax.Array:
+        """``biased [T, E]`` with the experts of every group but the
+        ``topk_group`` highest at ``-inf`` (equal groups: the lower)."""
+        per, rest = divmod(self.num_experts, self.n_group)
+        if rest or per < 2 or not 0 < self.topk_group <= self.n_group:
+            raise ValueError(
+                f"RouterSigmoid: {self.num_experts} experts are no "
+                f"{self.n_group} groups of two or more, or topk_group "
+                f"{self.topk_group} is none of them")
+        if self.top_k > self.topk_group * per:
+            raise ValueError(
+                f"RouterSigmoid: top_k {self.top_k} exceeds the "
+                f"{self.topk_group * per} experts of topk_group groups")
+        grouped = biased.reshape(biased.shape[:-1] + (self.n_group, per))
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, stay = jax.lax.top_k(group_score, self.topk_group)
+        kept = jnp.sum(jax.nn.one_hot(stay, self.n_group, dtype=jnp.int32),
+                       axis=-2) > 0
+        return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(
+            biased.shape)
 
 
 class RouterSoftmaxBias(RouterBase):

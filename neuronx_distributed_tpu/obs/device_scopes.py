@@ -44,7 +44,7 @@ UNSCOPED = "(unscoped)"
 SCOPES = (
     "embed", "norm",
     "attn", "attn.proj", "attn.kernel", "attn.kernel.full",
-    "attn.kernel.window", "attn.walk", "attn.select",
+    "attn.kernel.window", "attn.walk", "attn.select", "attn.index",
     "attn.state", "attn.conv", "attn.summarise", "attn.pool_write",
     "ffn", "ffn.dense", "ffn.router", "ffn.experts", "ffn.shared",
     "ffn.identity", "ffn.latent",

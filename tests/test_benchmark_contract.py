@@ -299,3 +299,60 @@ def test_the_block_cell_fits_its_pool_and_lists_its_own_rooflines():
     assert name in E2E["serve_tok_s"]["workloads"]
     # the sixteenth cell: later ones come behind it
     assert [w["name"] for w in MANIFEST["workloads"]].index(name) == 15
+
+
+def test_the_indexed_latent_cell_fits_two_leaves_and_comes_last_in_its_lists():
+    """A position of the cell holds a latent row and an index key a layer;
+    every request fits a table row and the pool every table row; the
+    family is built from the file with its share; the cell's entries
+    stand behind the ones that were there; it lists its own six metrics
+    and none that reads the latent kernel, which does not run."""
+    from neuronx_distributed_tpu.inference import paging
+    from runners import models
+
+    name = "deepseek-v3.2.serve-longdocs"
+    cell = harness.by_name(MANIFEST["workloads"], name, "workload")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "deepseek-v3.2", "offline-long-docs-32k", 1)
+    config = harness.read_json(os.path.join(BENCH, "configs",
+                                            "deepseek-v3.2.json"))
+    traffic = harness.read_json(harness.data_file("traffic",
+                                                  cell["traffic"]))
+    serve = config["serve"]
+    longest = (traffic["prompt_tokens"]["max"]
+               + traffic["answer_tokens"]["max"])
+    assert longest <= serve["max_blocks_per_seq"] * serve["block_size"]
+    assert serve["num_blocks"] == (serve["max_slots"]
+                                   * serve["max_blocks_per_seq"])
+    cfg, _, _ = models.build(config)
+    kind = cfg.serving_family().cache_kind
+    assert isinstance(kind, paging.IndexedLatentCache)
+    assert (kind.row, kind.index_row) == (640, 128)
+    assert (cfg.num_experts, cfg.experts_held, cfg.top_k, cfg.n_group,
+            cfg.topk_group, cfg.num_heads, cfg.index_n_heads,
+            cfg.index_topk, cfg.first_k_dense, cfg.num_layers) == (
+        256, (0, 16), 8, 8, 4, 128, 64, 2048, 1, 5)
+    aot = config["assumed"]["serve_aot_gib"]
+    pool = (config["num_hidden_layers"] * serve["num_blocks"]
+            * serve["block_size"] * (kind.row + kind.index_row) * 2)
+    assert abs(pool / 2.0 ** 30 - aot["pool"]) < 0.01
+    assert 0.25 <= aot["peak"] / 15.75 <= 0.90
+    listed = {m["name"] for m in MANIFEST["per_layer"]
+              if name in m.get("workloads", ())}
+    own = {"dsa_index_share_pct.batch", "dsa_context_kept_pct.batch",
+           "dsa_blocks_named_pct.batch", "dsa_selection_shared_pct.batch",
+           "dsa_index_roofline", "dsa_attention_roofline"}
+    assert own | {"moe_held_pct.batch", "select_scope_share_pct.batch",
+                  "long_context_row_pct.batch", "step_ms.batch"} <= listed
+    assert not {m for m in listed if m.startswith(("mla_", "paged_"))}
+    assert name in E2E["serve_tok_s"]["workloads"]
+    # the seventeenth cell and configuration, the last six metrics as
+    # they came: later ones come behind them
+    assert [w["name"] for w in MANIFEST["workloads"]].index(name) == 16
+    assert [c["name"] for c in MANIFEST["configs"]].index(
+        "deepseek-v3.2") == 15
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    assert {names.index(m) for m in own} == set(range(92, 98))
+    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
+        if name in m.get("workloads", ()) and m["name"] not in own:
+            assert m["workloads"].index(name) >= 1

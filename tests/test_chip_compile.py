@@ -795,6 +795,101 @@ def test_residual_streams_latent_step_at_the_published_widths(chip, topo,
             "sample"} <= seen
 
 
+# -- latent attention over a learned selection (DeepSeek-V3.2) -------------------
+# The cell deepseek-v3.2.serve-longdocs: 128 rows, 64 index heads of 128
+# over index keys of 128, 260 table columns of 256 positions, 2,080 blocks,
+# 5 layers of rows and of index keys.
+
+def test_index_key_scores(chip):
+    """A tile of 32 rows x 64 index heads (2,048 stacked rows) against one
+    block of 256 keys a grid step, over a grid as long as the step's
+    pairs: the kernel compiles for the chip with its product on the MXU
+    and no copy of its own (the blocks ride the pipeline), and the walk's
+    five scalar arrays fit SMEM at 66,560 positions a slot."""
+    from neuronx_distributed_tpu.ops import indexed_attention as ia
+
+    tokens, heads, width, bs, cols, nb, slots = 128, 64, 128, 256, 260, \
+        2080, 8
+
+    def fn(q, w, keys, tables, q_pos, layer):
+        walk = ia.index_walk(tables, q_pos, bs, heads, width, slots, True)
+        return ia._index_scores_pallas(q, w, keys, layer, q_pos, cols, walk,
+                                       width ** -0.5, interpret=False)
+
+    text = _assert_kernel_compiles(
+        fn, chip((tokens, heads, width), jnp.bfloat16),
+        chip((tokens, heads), jnp.float32),
+        chip((5, nb, bs, width), jnp.bfloat16),
+        chip((tokens, cols), jnp.int32), chip((tokens,), jnp.int32),
+        chip((), jnp.int32))
+    assert _kernel_instruction_names(text) == {"index_key_scores"}
+    assert "tpu.matmul" in _mosaic_ops(text)
+    assert ia.tile_rows(tokens, heads) == 32
+    assert 5 * 4 * ia.max_pairs(tokens, heads, slots, cols) < ia.SMEM_BYTES
+    # blocks of 128 at the same contexts: 520 columns a slot still fit
+    assert 5 * 4 * ia.max_pairs(tokens, heads, slots, 520) < ia.SMEM_BYTES
+
+
+def test_indexed_latent_step_at_the_published_widths(chip, topo,
+                                                     on_one_chip):
+    """The packed step of the cell's configuration file: it compiles for
+    the chip with the score kernel in it and no latent kernel, holds what
+    the configuration says it holds, writes both pool leaves in place,
+    sorts once a layer kind and gathers the selected rows under
+    ``attn.kernel``."""
+    import re
+
+    from neuronx_distributed_tpu.obs.device_scopes import scope_of
+
+    config, models = _cell_config("deepseek-v3.2", None)
+    cfg, forward, params, cache, tokens = _serving_parts(chip, config,
+                                                         models)
+    nb, bs = config["serve"]["num_blocks"], config["serve"]["block_size"]
+    assert (nb, bs, tokens) == (2080, 256, config["serve"]["token_budget"])
+    assert cache.rows.shape == (5, nb, bs, 640)
+    assert cache.index_keys.shape == (5, nb, bs, 128)
+    assert cache.moe_counts.shape == (3,) and cache.counts.shape == (8,)
+    moe = params["params"]["model"]["layers_moe"]["layer"]
+    assert moe["moe"]["experts"]["gate"].shape == (4, 16, 7168, 2048)
+    assert moe["moe"]["router"]["kernel"].shape == (4, 7168, 256)
+    assert moe["attn"]["k_up"].shape == (4, 128, 128, 512)
+    assert moe["attn"]["index_q_b"].shape == (4, 1536, 64 * 128)
+    assert moe["attn"]["index_k_norm"]["bias"].shape == (4, 128)
+
+    compiled = _packed_step(chip, cfg, forward, params, cache, tokens)
+    text = compiled.as_text()
+    assert _kernel_instruction_names(text) == {"index_key_scores"}
+    gib = 2.0 ** 30
+    mem = compiled.memory_analysis()
+    count = sum(x.size for x in jax.tree_util.tree_leaves(params))
+    assert count == 4_635_518_208
+    aot = config["assumed"]["serve_aot_gib"]
+    assert abs(mem.argument_size_in_bytes / gib - aot["arguments"]) < 0.05
+    assert abs(mem.peak_memory_in_bytes / gib - aot["peak"]) < 0.1
+    assert 0.25 <= mem.peak_memory_in_bytes / gib / 15.75 <= 0.90
+
+    header, entry = text.split("\n", 1)[0], text.split("\nENTRY ", 1)[1]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    stacks = [int(n) for shape, n in re.findall(
+        r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
+        if shape in (f"5,{nb},{bs},640", f"5,{nb},{bs},128")]
+    assert len(stacks) == 2 and set(stacks) <= aliased, (stacks, header)
+    by_scope = {}
+    for line in text.split("\n"):
+        found = re.search(r' (sort|gather|custom-call)\(.*op_name="([^"]*)"',
+                          line)
+        if found:
+            by_scope.setdefault(scope_of(found.group(2)), set()).add(
+                found.group(1))
+    assert "sort" in by_scope["attn.select"]
+    assert "custom-call" in by_scope["attn.index"]
+    seen = {scope_of(m) for m in re.findall(r'op_name="([^"]*)"', text)}
+    assert {"attn.index", "attn.select", "attn.kernel", "attn.pool_write",
+            "attn.walk", "ffn.experts", "ffn.shared", "ffn.router",
+            "ffn.dense", "norm", "embed", "sample"} <= seen
+
+
 # -- grouped GLU decode (MoE serving) at OLMoE's widths (ROADMAP R1): hidden
 # 2048, expert width 1024. Mixtral's 4096 compiles too, in about ten seconds.
 

@@ -43,9 +43,12 @@ SERVING = {"llama": "tiny-mistral-serve", "mixtral": "tiny-mixtral",
            "longcat_flash": "tiny-longcat-flash",
            "granite_moe_hybrid": "tiny-granite-moe-hybrid",
            "nemotron_h": "tiny-nemotron-h",
-           "xing4": "tiny-xing4", "sdar_moe": "tiny-sdar"}
+           "xing4": "tiny-xing4", "sdar_moe": "tiny-sdar",
+           "deepseek_v32": "tiny-deepseek-v32"}
 #: the children a family's step must open, and no other family's may
-OWN = {"attn.select": {"minicpm_sala"},
+OWN = {"attn.select": {"minicpm_sala", "deepseek_v32"},
+       # the index scores of a family whose rows select single positions
+       "attn.index": {"deepseek_v32"},
        "attn.state": {"minicpm_sala", "granite_hybrid", "solar_open2",
                       "granite_moe_hybrid", "nemotron_h"},
        "attn.conv": {"granite_hybrid", "solar_open2", "granite_moe_hybrid",
@@ -55,12 +58,13 @@ OWN = {"attn.select": {"minicpm_sala"},
        "attn.kernel.window": {"laguna", "mimo_v2_flash"},
        "ffn.experts": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
                        "solar_open2", "longcat_flash", "granite_moe_hybrid",
-                       "nemotron_h", "xing4", "sdar_moe"},
+                       "nemotron_h", "xing4", "sdar_moe", "deepseek_v32"},
        "ffn.router": {"mixtral", "glm_moe_lite", "laguna", "mimo_v2_flash",
                       "solar_open2", "longcat_flash", "granite_moe_hybrid",
-                      "nemotron_h", "xing4", "sdar_moe"},
+                      "nemotron_h", "xing4", "sdar_moe", "deepseek_v32"},
        "ffn.shared": {"glm_moe_lite", "laguna", "solar_open2",
-                      "granite_moe_hybrid", "nemotron_h", "xing4"},
+                      "granite_moe_hybrid", "nemotron_h", "xing4",
+                      "deepseek_v32"},
        "ffn.latent": {"nemotron_h"},
        # the float32 product with Phi; hc.apply has no heavy operation
        "hc.mix": {"xing4"},
@@ -284,7 +288,7 @@ def test_a_step_opens_the_children_its_family_has_and_no_others(which):
         assert (child in seen) == (which in families), (child, seen)
     dense = which in ("llama", "evabyte", "minicpm_sala", "glm_moe_lite",
                       "granite_hybrid", "laguna", "mimo_v2_flash",
-                      "longcat_flash", "xing4", "train")
+                      "longcat_flash", "xing4", "deepseek_v32", "train")
     assert ("ffn.dense" in seen) == dense
     if which == "train":
         assert "optimizer" not in seen      # elementwise: no heavy operation

@@ -1239,8 +1239,19 @@ DECLARED["sdar"] = {
     "nxd_block_rows_total": ("by_threshold", "by_quota", "left_masked",
                              "already_uncovered", "stored"),
     "nxd_blocks_finished_total": ()}
+#: the latent family whose rows attend a selection (models/deepseek_v32.py):
+#: no kernel walks its table's blocks on the host's count; what the
+#: selection did is the device's, beside a share's routed assignments
+DECLARED["deepseek_v32"] = {
+    "nxd_step_rows_by_context_total": ("to_2k", "to_8k", "past_8k"),
+    "nxd_dsa_positions_total": ("selected", "passed_over"),
+    "nxd_dsa_rows_total": ("selecting", "whole"),
+    "nxd_dsa_blocks_total": ("named", "unnamed"),
+    "nxd_dsa_selected_total": ("shared_with_previous_row", "new"),
+    **_MOE, "nxd_moe_held_total": ("held", "elsewhere")}
 #: the leaves a family's step counts into on the device, and their lengths
-ON_DEVICE = {"sdar": {"moe_counts": 2}, "minicpm_sala": {"counts": 10}, "glm_moe_lite": {"moe_counts": 2},
+ON_DEVICE = {"deepseek_v32": {"counts": 8, "moe_counts": 3},
+             "sdar": {"moe_counts": 2}, "minicpm_sala": {"counts": 10}, "glm_moe_lite": {"moe_counts": 2},
              "laguna": {"moe_counts": 3}, "mimo_v2": {"moe_counts": 3},
              "solar_open2": {"moe_counts": 3},
              "granite_moe_hybrid": {"moe_counts": 3},
