@@ -299,8 +299,8 @@ class IndexedLatentAttention(LatentAttention):
                 index = jnp.where(at[None, :] <= at[:, None], index,
                                   -jnp.inf)
             with device_scope("attn.select"):
-                keep = jax.vmap(lambda one: ia.selected_mask(
-                    one, *ia.select_positions(one, top)))(index)
+                keep = ia.select_positions(
+                    index.reshape(-1, s), top).member.reshape(index.shape)
             with device_scope("attn.kernel"):
                 scores = jnp.einsum(
                     "btnw,bkw->bntk", q_row.astype(jnp.float32),
@@ -324,14 +324,13 @@ class IndexedLatentAttention(LatentAttention):
                 cfg.index_scale, cache.slots,
                 force_pallas=cfg.attn_force_pallas, walk=cache.walk)
         with device_scope("attn.select"):
-            positions, chosen, values = ia.select_positions(index, top)
+            selection = ia.select_positions(index, top)
             counts = ia.selection_counts(
-                index, positions, chosen, values, cache.tables, cache.q_pos,
-                pool.shape[2], top)
+                selection, cache.tables, cache.q_pos, pool.shape[2], top)
         with device_scope("attn.kernel"):
-            ctx = ia.attend_selected(q_row[0], pool, cache.layer,
-                                     cache.tables, positions, chosen, rank,
-                                     scale)[None]
+            ctx = ia.attend_selected(
+                q_row[0], pool, cache.layer, cache.tables,
+                selection.positions, selection.chosen, rank, scale)[None]
         return ctx, cache.replace(rows=pool, index_keys=keys,
                                   counts=cache.counts + counts)
 

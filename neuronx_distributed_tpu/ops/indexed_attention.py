@@ -9,7 +9,7 @@ with a small indexer (:func:`index_scores`)::
     I[t, s] = sum_h w[t, h] * relu(q_I[t, h] . k_I[s]) * scale,  s <= t
 
 keeps the ``top`` positions of highest score (:func:`select_positions`:
-exact, ``lax.top_k``; equal scores take the lower position; a row whose
+exact, by counting; equal scores take the lower position; a row whose
 context is ``top`` positions or fewer keeps them all) and attends those
 rows of the latent pool and no other (:func:`attend_selected`), in the
 absorbed form the pool stores. The work of the attention therefore
@@ -37,10 +37,16 @@ Three steps, each behind one signature:
   column) no pair visits is never written). Elsewhere the gather
   reference, every row's whole table (:func:`_index_scores_xla`: CPU
   tests).
-* :func:`select_positions`: ``lax.top_k`` over a row's ``[max_blocks x
-  block_size]`` scores in runs of :data:`SORT_WIDTH`, then over the runs'
-  winners: exact, and sorts all the same; an exact selection that is no
-  sort is ROADMAP R3's.
+* :func:`select_positions`: exact and no sort. The key of a row's
+  ``top``-th highest score by counting (:func:`kth_key`: 32 rounds of a
+  row reduction over keys that order as the scores do), the members read
+  off it (above it, and of those equal to it the lowest positions that
+  fill ``top``), their positions by rank (:func:`_ranked_positions`: the
+  mask as bytes and counts a chunk of 128 positions, a prefix over the
+  chunks, an output place's chunk by comparison, its bit by
+  ``population_count``): no ``sort``, no ``top_k``, no gather or scatter
+  of single elements, which cost the chip 10-16 ns each. A row's
+  positions come ascending.
 * :func:`attend_selected`: a row's selected pool rows gathered by flat
   index, ``[T, top, row]``, and one batched product a row with its heads
   as the MXU's rows (``[N, row] x [row, top]``, float32 softmax
@@ -61,6 +67,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..inference.kv_cache import PAD_POSITION
 from .paged_attention import paged_attention_impl
@@ -70,8 +77,9 @@ LANES = 128
 #: stacked rows (packed rows x index heads) of a tile of the score kernel:
 #: its float32 product against a block of 256 keys is 2 MiB
 TILE_STACKED_ROWS = 2048
-#: the widest run of scores :func:`select_positions` sorts at once
-SORT_WIDTH = 16384
+INT32_MIN = -(1 << 31)
+#: :func:`ordered_keys` of ``-inf``
+UNSCORED = INT32_MIN + 0x7fffff
 #: what the walk's scalar-prefetch arrays may take of the chip's SMEM
 SMEM_BYTES = 768 * 1024
 #: the kinds :func:`selection_counts` counts, in its order
@@ -302,54 +310,169 @@ def index_scores(q: jax.Array, w: jax.Array, keys: jax.Array, layer,
                                 interpret=impl == "pallas-interpret")
 
 
-def select_positions(scores: jax.Array, top: int,
-                     sort_width: int = SORT_WIDTH):
-    """``(positions [T, k] int32, chosen [T, k] bool, values [T, k])``,
-    ``k = min(top, the scores' width)``: a row's ``k`` positions of
-    highest score, exact, and their scores; equal scores take the lower
-    position; ``chosen`` is False where the row has fewer than ``k``
-    scored positions (a score of ``-inf``). Scores wider than
-    ``sort_width`` are taken in runs of at most that width: the ``k``
-    highest of each run (``lax.top_k``), then the ``k`` highest of those,
-    by a stable sort that carries their positions: the same set, to the
-    tie, at a fraction of one whole sort's time (XLA's sort of 66,560
-    values a row takes five times what five of 13,312 do)."""
+class Selection(NamedTuple):
+    """What :func:`select_positions` keeps of a step's rows: ``positions
+    [T, k]`` int32, ascending along a row, ``chosen [T, k]`` (which of them
+    count: a row's first ``min(k, its scored positions)``) and ``member
+    [T, P]`` bool, the same set as a mask over the scores' width."""
+
+    positions: jax.Array
+    chosen: jax.Array
+    member: jax.Array
+
+
+def ordered_keys(scores):
+    """int32 keys that order as the float32 scores compare: ``-0.0`` is
+    ``+0.0`` (the comparison ties them, their bits would not), a negative
+    score's magnitude bits are turned over, and ``-inf`` (a position the
+    row does not name) is :data:`UNSCORED`, below every number's key."""
+    scores = scores.astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0.0, 0.0, scores), jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7fffffff), bits)
+
+
+def _bisect(rounds: int, lowest, fits):
+    """``[T, 1]`` int32: a row's highest value that ``fits``, bit by bit
+    from bit ``rounds - 1`` down. ``fits(candidate [T, 1]) -> [T, 1]``
+    bool holds up to a row's answer and no further; ``lowest`` is the
+    order's lowest value with those bits clear in the order's sense
+    (``INT32_MIN`` for 32 rounds over signed values: the ``xor`` turns
+    the sign bit to 0, which is the higher half)."""
+    def narrow(i, value):
+        candidate = value ^ jnp.left_shift(jnp.int32(1), rounds - 1 - i)
+        return jnp.where(fits(candidate), candidate, value)
+
+    return jax.lax.fori_loop(0, rounds, narrow, lowest)
+
+
+def kth_key(keys: jax.Array, k: int) -> jax.Array:
+    """``[T, 1]`` int32: a row's ``k``-th highest of ``keys [T, P]`` int32,
+    equal keys counted each, by counting and no sort: 32 rounds of "do
+    ``k`` keys reach this candidate", from the top bit down, each a row
+    reduction over the keys (which the chip's compiler keeps in VMEM
+    through the rounds: 34 MB at the cell's ``[128, 66560]``, 12 us a
+    round where HBM's rate would make it 42)."""
+    return _bisect(
+        32, jnp.full((keys.shape[0], 1), INT32_MIN, jnp.int32),
+        lambda key: jnp.sum(keys >= key, axis=-1, keepdims=True,
+                            dtype=jnp.int32) >= k)
+
+
+def _tie_cut(tied, need):
+    """``[T, 1]`` int32: the position of a row's ``need``-th tied position
+    (``tied [T, P]`` bool, ``need [T, 1]`` at least 1 and at most the
+    row's ties), by the same bisection over the position."""
+    width = tied.shape[-1]
+    at = jnp.arange(width, dtype=jnp.int32)[None, :]
+    return _bisect(
+        width.bit_length(), jnp.zeros((tied.shape[0], 1), jnp.int32),
+        lambda cut: jnp.sum(tied & (at < cut), axis=-1, keepdims=True,
+                            dtype=jnp.int32) < need)
+
+
+def _set_bit(word, nth):
+    """The place of a uint32 word's ``nth`` set bit (from 0, from the low
+    end) in 5 halvings: the bit lies in the low half if that half holds
+    more than ``nth``."""
+    place = jnp.zeros_like(nth)
+    for half in (16, 8, 4, 2, 1):
+        low = word & jnp.uint32((1 << half) - 1)
+        held = jax.lax.population_count(low).astype(jnp.int32)
+        above = nth >= held
+        nth = jnp.where(above, nth - held, nth)
+        word = jnp.where(above, word >> half, low)
+        place = place + jnp.where(above, half, 0)
+    return place
+
+
+def _ranked_positions(member, k: int):
+    """``(positions [T, k], chosen [T, k])``: a row's ``j``-th member
+    position at place ``j``, by rank and no scatter. The mask in chunks
+    of 128 positions, each 16 bytes of bits and a count (one product with
+    a constant: exact, the operands are bits and powers of two); place
+    ``j`` lies in the chunk whose span of the counts' prefix holds it
+    (``[T, chunks, k]`` comparisons, as one-hot rows of a second product
+    that fetches the chunk's bytes, its number and the prefix before it);
+    then the word by its 4 counts and the bit by halving, elementwise."""
+    t, width = member.shape
+    chunks = -(-width // LANES)
+    lane = np.arange(LANES)
+    pack = np.zeros((LANES, 17), np.float32)
+    pack[lane, lane // 8], pack[:, 16] = 2.0 ** (lane % 8), 1.0
+    packed = jnp.einsum(
+        "tcl,ld->tcd",
+        jnp.pad(member, ((0, 0), (0, chunks * LANES - width))).reshape(
+            t, chunks, LANES).astype(jnp.bfloat16),
+        jnp.asarray(pack, jnp.bfloat16), preferred_element_type=jnp.float32)
+    held = packed[..., 16].astype(jnp.int32)                 # [T, chunks]
+    upto = jnp.cumsum(held, axis=-1)
+    before = upto - held
+    place = jnp.arange(k, dtype=jnp.int32)
+    spans = ((before[:, :, None] <= place)
+             & (place < upto[:, :, None]))                   # [T, chunks, k]
+    # every value of the fetch is a whole number under 256: exact in bf16
+    chunk = jnp.broadcast_to(jnp.arange(chunks, dtype=jnp.int32), (t, chunks))
+    fetch = jnp.concatenate(
+        [packed[..., :16]] + [x[..., None].astype(jnp.float32) for x in (
+            before & 255, chunk >> 8, chunk & 255)], axis=-1)
+    got = jnp.einsum("tcd,tck->tdk", fetch.astype(jnp.bfloat16),
+                     spans.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    byte = got[:, :16].astype(jnp.uint32)
+    words = [byte[:, 4 * i] | (byte[:, 4 * i + 1] << 8)
+             | (byte[:, 4 * i + 2] << 16) | (byte[:, 4 * i + 3] << 24)
+             for i in range(4)]
+    # a chunk holds at most 128: the rank within it from the low byte
+    nth = (place[None, :] - got[:, 16]) & 255
+    counts = [jax.lax.population_count(w).astype(jnp.int32) for w in words]
+    first, second = counts[0], counts[0] + counts[1]
+    third = second + counts[2]
+
+    def of_word(*values):
+        """``values[i]`` where the rank falls in word ``i``."""
+        return jnp.where(nth < first, values[0], jnp.where(
+            nth < second, values[1],
+            jnp.where(nth < third, values[2], values[3])))
+
+    positions = (((got[:, 17] << 8) + got[:, 18]) * LANES
+                 + of_word(0, 32, 64, 96)
+                 + _set_bit(of_word(*words),
+                            nth - of_word(0, first, second, third)))
+    chosen = place[None, :] < upto[:, -1:]
+    return jnp.where(chosen, positions, 0), chosen
+
+
+def select_positions(scores: jax.Array, top: int) -> Selection:
+    """A row's ``k = min(top, the scores' width)`` positions of highest
+    score (:class:`Selection`), exact and without a sort: the key of the
+    ``k``-th highest score by counting (:func:`kth_key`), the members
+    read off it (every position above it, and of those equal to it the
+    lowest positions that fill the ``k``: equal scores take the lower
+    position), their positions by rank (:func:`_ranked_positions`). A row
+    with ``k`` scored positions or fewer keeps them all (its ``k``-th key
+    is :data:`UNSCORED`, above which lie its scored positions and at
+    which nothing counts), so a pad row keeps none. One path for every
+    width and ``top``."""
     t, width = scores.shape
     k = min(top, width)
-    runs = -(-width // max(sort_width, k))
-    if runs == 1:
-        values, positions = jax.lax.top_k(scores, k)
-        return positions.astype(jnp.int32), values > -jnp.inf, values
-    run = max(-(-width // runs), k)
-    padded = jnp.pad(scores, ((0, 0), (0, runs * run - width)),
-                     constant_values=-jnp.inf).reshape(t, runs, run)
-    values, positions = jax.lax.top_k(padded, k)           # [T, runs, k]
-    positions = (positions.astype(jnp.int32)
-                 + jnp.arange(runs, dtype=jnp.int32)[:, None] * run)
-    # candidates in the order (run, rank in the run): of equal scores the
-    # lower position lies ahead, and a stable sort leaves it there
-    lowest, positions = jax.lax.sort(
-        (-values.reshape(t, runs * k), positions.reshape(t, runs * k)),
-        dimension=1, is_stable=True, num_keys=1)
-    values = -lowest[:, :k]
-    return positions[:, :k], values > -jnp.inf, values
+    keys = ordered_keys(scores)
+    kth = kth_key(keys, k)
+    above = keys > kth
+    tied = (keys == kth) & (kth > UNSCORED)
+    need = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    ties = jnp.sum(tied, axis=-1, keepdims=True, dtype=jnp.int32)
+    # the cut among the ties is a second search; no row of real scores
+    # holds more ties than it needs, and the step then skips it
+    cut = jax.lax.cond(
+        jnp.any(ties > need), lambda: _tie_cut(tied, need),
+        lambda: jnp.full((t, 1), width, jnp.int32))
+    at = jnp.arange(width, dtype=jnp.int32)[None, :]
+    member = above | (tied & (at <= cut))
+    return Selection(*_ranked_positions(member, k), member)
 
 
-def selected_mask(scores, positions, chosen, values):
-    """``[T, P]`` bool: the positions :func:`select_positions` chose, read
-    off the scores and no scatter: those above the last chosen value, and
-    of those equal to it the ones at or below the highest position chosen
-    among them (``lax.top_k`` takes equal scores from the lowest)."""
-    last = jnp.min(jnp.where(chosen, values, jnp.inf), axis=-1,
-                   keepdims=True)
-    cut = jnp.max(jnp.where(chosen & (values == last), positions, -1),
-                  axis=-1, keepdims=True)
-    at = jnp.arange(scores.shape[-1], dtype=jnp.int32)[None, :]
-    return (scores > last) | ((scores == last) & (at <= cut)
-                              & (scores > -jnp.inf))
-
-
-def selection_counts(scores, positions, chosen, values, tables, q_pos,
+def selection_counts(selection: Selection, tables, q_pos,
                      block_size: int, top: int) -> jax.Array:
     """``[8]`` int32 (:data:`COUNT_KINDS`) of one layer of one step: the
     real rows' causal positions by whether the row attended them
@@ -362,7 +485,7 @@ def selection_counts(scores, positions, chosen, values, tables, q_pos,
     before it of the same sequence (``shared_with_previous_row``), or
     not (``new``)."""
     real = q_pos < PAD_POSITION
-    member = selected_mask(scores, positions, chosen, values)
+    member = selection.member
     selected = jnp.sum(member, axis=-1, dtype=jnp.int32)
     causal = jnp.where(real, q_pos + 1, 0)
     selecting = real & (q_pos + 1 > top)

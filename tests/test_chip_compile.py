@@ -830,13 +830,49 @@ def test_index_key_scores(chip):
     assert 5 * 4 * ia.max_pairs(tokens, heads, slots, 520) < ia.SMEM_BYTES
 
 
+def _scoped_ops(text):
+    """The compiled text's sorts, gathers, scatters and custom calls by
+    device scope."""
+    import re
+
+    from neuronx_distributed_tpu.obs.device_scopes import scope_of
+
+    by_scope = {}
+    for line in text.split("\n"):
+        found = re.search(
+            r' (sort|gather|scatter|custom-call)\(.*op_name="([^"]*)"', line)
+        if found:
+            by_scope.setdefault(scope_of(found.group(2)), set()).add(
+                found.group(1))
+    return by_scope
+
+
+def test_the_selection_alone_at_the_cells_width(chip):
+    """The exact top-2,048 of 128 rows of 66,560 scores, compiled for the
+    chip: no sort, no gather, no scatter and no kernel of the repo's; the
+    32 rounds are one loop; the place-to-chunk one-hot ``[128, 520,
+    2048]`` is an operand the compiler builds inside the product that
+    reads it (272 MB in bf16 if it were written out)."""
+    import re
+
+    from neuronx_distributed_tpu.ops import indexed_attention as ia
+
+    compiled = jax.jit(lambda s: ia.select_positions(s, 2048)).lower(
+        chip((128, 66560), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert not re.search(r" (sort|gather|scatter)\(", text)
+    assert not _kernel_instruction_names(text)
+    assert len(re.findall(r" while\(", text)) == 2      # the tie search's too
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 def test_indexed_latent_step_at_the_published_widths(chip, topo,
                                                      on_one_chip):
     """The packed step of the cell's configuration file: it compiles for
     the chip with the score kernel in it and no latent kernel, holds what
     the configuration says it holds, writes both pool leaves in place,
-    sorts once a layer kind and gathers the selected rows under
-    ``attn.kernel``."""
+    selects without a sort, a gather or a scatter and gathers the selected
+    rows under ``attn.kernel``."""
     import re
 
     from neuronx_distributed_tpu.obs.device_scopes import scope_of
@@ -875,14 +911,9 @@ def test_indexed_latent_step_at_the_published_widths(chip, topo,
         r" = \w+\[([\d,]+)\]\S* parameter\((\d+)\)", entry)
         if shape in (f"5,{nb},{bs},640", f"5,{nb},{bs},128")]
     assert len(stacks) == 2 and set(stacks) <= aliased, (stacks, header)
-    by_scope = {}
-    for line in text.split("\n"):
-        found = re.search(r' (sort|gather|custom-call)\(.*op_name="([^"]*)"',
-                          line)
-        if found:
-            by_scope.setdefault(scope_of(found.group(2)), set()).add(
-                found.group(1))
-    assert "sort" in by_scope["attn.select"]
+    by_scope = _scoped_ops(text)
+    assert "attn.select" not in by_scope
+    assert "gather" in by_scope["attn.kernel"]
     assert "custom-call" in by_scope["attn.index"]
     seen = {scope_of(m) for m in re.findall(r'op_name="([^"]*)"', text)}
     assert {"attn.index", "attn.select", "attn.kernel", "attn.pool_write",

@@ -325,40 +325,86 @@ def test_equal_scores_take_the_lower_position_and_a_short_row_keeps_all():
     scores = jnp.asarray([[1., 3., 3., 2., 3., inf, inf, inf],
                           [5., 1., inf, inf, inf, inf, inf, inf],
                           [inf] * 8], jnp.float32)
-    positions, chosen, values = ia.select_positions(scores, 3)
+    positions, chosen, member = ia.select_positions(scores, 3)
     assert positions[0].tolist() == [1, 2, 4]       # not 3, nor 0
     assert chosen.tolist() == [[True] * 3, [True, True, False], [False] * 3]
-    assert sorted(positions[1][:2].tolist()) == [0, 1]
-    member = ia.selected_mask(scores, positions, chosen, values)
+    assert positions[1][:2].tolist() == [0, 1]      # ascending
     assert member.tolist() == [
         [False, True, True, False, True, False, False, False],
         [True, True] + [False] * 6, [False] * 8]
     # where the last chosen value is tied beyond the cut, the lower stay
     tied = jnp.asarray([[2., 2., 2., 2., 1., inf, inf, inf]], jnp.float32)
-    member = ia.selected_mask(tied, *ia.select_positions(tied, 3))
-    assert member.tolist() == [[True, True, True] + [False] * 5]
+    picked = ia.select_positions(tied, 3)
+    assert picked.member.tolist() == [[True, True, True] + [False] * 5]
+    assert picked.positions.tolist() == [[0, 1, 2]]
     # k is held to the table's width
     assert ia.select_positions(scores, 2048)[0].shape == (3, 8)
 
 
-@pytest.mark.parametrize("width,top,sort_width", [
-    (96, 8, 16), (100, 8, 32), (66, 16, 16), (64, 8, 64)])
-def test_scores_taken_in_runs_select_what_one_sort_selects(width, top,
-                                                           sort_width):
-    """Many equal scores, rows of every length: the positions, their order
-    and the tie at the last place are ``lax.top_k``'s over the whole."""
-    rng = np.random.RandomState(width + top)
-    scores = rng.randint(0, 7, (12, width)).astype(np.float32)
-    scores[np.arange(width)[None, :] > rng.randint(0, width, (12, 1))] = \
+def _rows_of_every_length(rng, rows, width, draw):
+    scores = draw((rows, width)).astype(np.float32)
+    scores[np.arange(width)[None, :] > rng.randint(0, width, (rows, 1))] = \
         -np.inf
     scores[0], scores[1, 3:] = -np.inf, -np.inf
-    want_values, want = jax.lax.top_k(jnp.asarray(scores), top)
-    positions, chosen, values = ia.select_positions(
-        jnp.asarray(scores), top, sort_width=sort_width)
-    assert (np.asarray(values) == np.asarray(want_values)).all()
-    assert (np.asarray(chosen) == (np.asarray(want_values) > -np.inf)).all()
-    assert (np.asarray(positions)[np.asarray(chosen)]
-            == np.asarray(want)[np.asarray(chosen)]).all()
+    return scores
+
+
+def _few_values(rng):
+    return lambda shape: rng.randint(0, 7, shape)
+
+
+def _signed_zeros(rng):
+    """Zeros of both signs (one value to the comparison) among negative
+    and positive scores."""
+    def draw(shape):
+        return rng.choice(np.asarray([0.0, -0.0, -1.5, 2.0, -0.25],
+                                     np.float32), shape)
+
+    return draw
+
+
+def _tied_at_the_cut(rng):
+    """A few scores above, and far more than ``top`` equal ones below
+    them: the cut falls among the equal ones in every long row."""
+    return lambda shape: np.where(rng.rand(*shape) < 0.02,
+                                  rng.randn(*shape) + 9.0, 1.0)
+
+
+def _normal(rng):
+    return rng.standard_normal
+
+
+@pytest.mark.parametrize("width,top,rows,draw", [
+    (96, 8, 12, _few_values), (100, 8, 12, _few_values),
+    (66, 16, 12, _few_values), (64, 8, 12, _few_values),
+    (5, 8, 3, _few_values),                       # narrower than ``top``
+    (300, 40, 12, _tied_at_the_cut),              # no multiple of 128
+    (4097, 300, 9, _tied_at_the_cut), (200, 16, 12, _signed_zeros),
+    (1024, 128, 10, _signed_zeros), (1300, 128, 10, _normal),
+    (33000, 2048, 4, _few_values),                # table numbers past 255
+    (66560, 2048, 3, _normal)])                   # the cell's own
+def test_the_selection_by_counting_selects_what_a_sort_selects(
+        width, top, rows, draw):
+    """Many equal scores, zeros of both signs, rows of every length, an
+    all-``-inf`` row: the set, its mask and the tie at the last place are
+    ``lax.top_k``'s over the whole row, the positions ascending.
+    (``lax.top_k`` orders ``-0.0`` below ``+0.0`` where the comparison,
+    the reference's stable sort and this selection tie them: the oracle
+    reads the scores with their zeros made one.)"""
+    rng = np.random.RandomState(width + top)
+    scores = _rows_of_every_length(rng, rows, width, draw(rng))
+    k = min(top, width)
+    want_values, want = map(np.asarray, jax.lax.top_k(
+        jnp.where(jnp.asarray(scores) == 0, 0.0, jnp.asarray(scores)), k))
+    positions, chosen, member = map(np.asarray, jax.jit(
+        lambda s: ia.select_positions(s, top))(jnp.asarray(scores)))
+    assert positions.shape == chosen.shape == (rows, k)
+    assert not chosen[0].any() and not member[0].any()
+    for r in range(rows):
+        kept = sorted(want[r][want_values[r] > -np.inf].tolist())
+        assert positions[r][chosen[r]].tolist() == kept, r
+        assert np.flatnonzero(member[r]).tolist() == kept, r
+        assert chosen[r][:len(kept)].all() and chosen[r].sum() == len(kept)
 
 
 def _scene(name):
@@ -437,12 +483,11 @@ def _recording(sets):
     step (unordered: a record says nothing of its layer)."""
     sound = ia.selection_counts
 
-    def counts(scores, positions, chosen, values, tables, q_pos, *rest):
+    def counts(selection, tables, q_pos, *rest):
         jax.debug.callback(
             lambda *a: sets.append(tuple(map(np.asarray, a))), tables,
-            q_pos, positions, chosen)
-        return sound(scores, positions, chosen, values, tables, q_pos,
-                     *rest)
+            q_pos, selection.positions, selection.chosen)
+        return sound(selection, tables, q_pos, *rest)
 
     return counts
 
@@ -603,7 +648,7 @@ def test_the_counts_of_one_step_are_the_brute_counts():
         rng.randint(0, 6, (24, 32)).astype(np.float32), -np.inf)
     picked = ia.select_positions(jnp.asarray(scores), 5)
     got = dict(zip(ia.COUNT_KINDS, np.asarray(ia.selection_counts(
-        jnp.asarray(scores), *picked, tables, q_pos, 8, 5)).tolist()))
+        picked, tables, q_pos, 8, 5)).tolist()))
     positions, chosen, _ = map(np.asarray, picked)
     sets = [set(positions[r][chosen[r]].tolist()) for r in range(24)]
     real = np.asarray(q_pos) < PAD_POSITION
